@@ -58,7 +58,7 @@ def first_varint(buf, fn_want, default=0):
 
 
 def main():
-    path = sys.argv[1] if len(sys.argv) > 1 else "/tmp/bench_trace"
+    path = sys.argv[1] if len(sys.argv) > 1 else "chiprun_out/bench_trace"
     line_filter = sys.argv[2] if len(sys.argv) > 2 else ""
     files = glob.glob(f"{path}/**/*.xplane.pb", recursive=True)
     if not files:

@@ -13,6 +13,7 @@ from pathlib import Path
 
 import numpy as np
 
+from scaling_tpu.compile_cache import enable_compile_cache
 from scaling_tpu.logging import logger
 from scaling_tpu.models.transformer import TransformerConfig
 from scaling_tpu.models.transformer.train import main
@@ -44,4 +45,5 @@ if __name__ == "__main__":
     )
     config = TransformerConfig.from_yaml(config_path)
     ensure_example_data(config)
+    enable_compile_cache()
     main(config)

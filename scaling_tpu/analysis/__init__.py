@@ -22,29 +22,7 @@ device environment before anything pulls jax in.
 
 from __future__ import annotations
 
-import os
-from typing import Optional
-
-__all__ = [
-    "main", "lint_paths", "Finding", "RULES", "resolve_test_cache_dir",
-]
-
-
-def resolve_test_cache_dir(
-    default: str = "/tmp/scaling_tpu_test_jaxcache",
-) -> Optional[str]:
-    """The SCALING_TPU_TEST_CACHE contract, in one place.
-
-    Returns the persistent XLA compile-cache directory every consumer
-    (tests/conftest.py, the analysis CLI, bench subprocesses) should
-    use, or None when the cache is disabled via the ``off``/``none``/
-    ``0``/empty sentinels — on some containers executables DESERIALIZED
-    from this cache mis-execute, and a sentinel value must never become
-    a literal ``./off`` cache directory."""
-    value = os.environ.get("SCALING_TPU_TEST_CACHE", default)
-    if value.lower() in ("off", "none", "0", ""):
-        return None
-    return value
+__all__ = ["main", "lint_paths", "Finding", "RULES"]
 
 
 def main(argv=None) -> int:

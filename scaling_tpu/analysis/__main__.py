@@ -17,24 +17,11 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except Exception:
-    pass
 # repeat runs (the CI gate, local loops) hit the compile cache instead of
-# re-paying the lowering; shares the test suite's cache by default.
-# SCALING_TPU_TEST_CACHE=off disables it (the shared contract lives in
-# resolve_test_cache_dir)
-from . import resolve_test_cache_dir  # noqa: E402
+# re-paying the lowering
+from ..compile_cache import enable_compile_cache  # noqa: E402
 
-_cache_dir = resolve_test_cache_dir()
-if _cache_dir is not None:
-    jax.config.update("jax_compilation_cache_dir", _cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:
-        pass
+enable_compile_cache()
 
 from .cli import main  # noqa: E402
 

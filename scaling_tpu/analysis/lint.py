@@ -185,7 +185,7 @@ SWALLOW_SCOPE_DIRS = (
     # scheduler/pool/device error here is a request that silently never
     # completes (the exact failure mode the TTFT gates exist to catch)
     "serve",
-    # ISSUE 15: the tuner grew CLI/serving-layout I/O (stale-capture
+    # ISSUE 15: the tuner grew CLI/serving-layout I/O (run-dir
     # records, emitted configs, goldens) — a swallowed read there turns
     # a corrupt calibration file into a silently wrong placement
     "tune",

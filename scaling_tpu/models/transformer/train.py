@@ -29,7 +29,7 @@ from .data.finetuning import (
 from .data.text_dataset import LegacyBlendedDataset, TextBlendedDataset, TextDataset
 from .model import init_model, init_optimizer, loss_function
 from .utils.get_tflops import (
-    HardwareType,
+    detect_hardware,
     get_flops_per_token,
     get_model_parameter_count,
     get_palm_mfu,
@@ -75,11 +75,12 @@ def log_metrics_fn(trainer: BaseTrainer, output, metrics: dict) -> dict:
         arch.vocab_size, arch.sequence_length, step_time,
         topo.global_batch_size, arch.mlp_factor,
     )
-    metrics["palm_mfu"] = get_palm_mfu(
-        param_count, arch.num_layers, arch.hidden_size, arch.sequence_length,
-        metrics["tokens_per_second"], topo.world_size,
-        hardware=HardwareType.TPU_V5P,
-    )
+    hardware = detect_hardware()
+    if hardware is not None:
+        metrics["palm_mfu"] = get_palm_mfu(
+            param_count, arch.num_layers, arch.hidden_size, arch.sequence_length,
+            metrics["tokens_per_second"], topo.world_size, hardware=hardware,
+        )
     return metrics
 
 
@@ -184,6 +185,7 @@ def main(config: TransformerConfig) -> TransformerTrainer:
         arch.hidden_size, arch.num_layers, arch.vocab_size, arch.mlp_factor,
         glu=arch.mlp_type.value == "swiglu",
     )
+    hardware = detect_hardware()
     trainer.telemetry.configure(
         flops_per_token=get_flops_per_token(
             param_count, arch.num_layers, arch.hidden_size,
@@ -191,7 +193,7 @@ def main(config: TransformerConfig) -> TransformerTrainer:
         ),
         tokens_per_step=topo.global_batch_size * arch.sequence_length,
         world_size=topo.world_size,
-        peak_tflops=HardwareType.TPU_V5P.max_tflops,
+        peak_tflops=None if hardware is None else hardware.max_tflops,
     )
     from ...resilience import controlplane_from_env
 

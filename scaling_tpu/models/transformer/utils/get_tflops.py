@@ -21,14 +21,62 @@ class HardwareType(Enum):
 
     @property
     def max_tflops(self) -> float:
-        return {
-            HardwareType.TPU_V4: 275.0,
-            HardwareType.TPU_V5E: 197.0,
-            HardwareType.TPU_V5P: 459.0,
-            HardwareType.TPU_V6E: 918.0,
-            HardwareType.A100: 312.0,
-            HardwareType.H100: 989.4,
-        }[self]
+        """Published dense bf16 peak per chip."""
+        return _PEAKS[self][0]
+
+    @property
+    def hbm_gbps(self) -> float:
+        """Published HBM bandwidth per chip, GB/s."""
+        return _PEAKS[self][1]
+
+    @classmethod
+    def from_device_kind(cls, device_kind: str) -> "HardwareType":
+        """The chip behind ``jax.devices()[0].device_kind``. A kind that
+        is not in the table raises: an assumed peak makes every MFU
+        computed from it wrong without saying so."""
+        try:
+            return _DEVICE_KINDS[device_kind]
+        except KeyError:
+            raise ValueError(
+                f"no published peak for device_kind {device_kind!r}; add it "
+                f"to the table in {__name__} (known: {sorted(_DEVICE_KINDS)})"
+            ) from None
+
+
+# (bf16 TFLOP/s, HBM GB/s) per chip. TPUs: Google Cloud documentation, the
+# "TPU v4" / "TPU v5e" / "TPU v5p" / "TPU v6e" system-architecture pages;
+# GPUs: NVIDIA A100 / H100 SXM datasheets (dense, no sparsity).
+_PEAKS = {
+    HardwareType.TPU_V4: (275.0, 1200.0),
+    HardwareType.TPU_V5E: (197.0, 819.0),
+    HardwareType.TPU_V5P: (459.0, 2765.0),
+    HardwareType.TPU_V6E: (918.0, 1640.0),
+    HardwareType.A100: (312.0, 2039.0),
+    HardwareType.H100: (989.4, 3350.0),
+}
+
+# ``device_kind`` as the JAX TPU runtime spells it
+_DEVICE_KINDS = {
+    "TPU v4": HardwareType.TPU_V4,
+    "TPU v5 lite": HardwareType.TPU_V5E,
+    "TPU v5e": HardwareType.TPU_V5E,
+    "TPU v5p": HardwareType.TPU_V5P,
+    "TPU v5": HardwareType.TPU_V5P,
+    "TPU v6 lite": HardwareType.TPU_V6E,
+    "TPU v6e": HardwareType.TPU_V6E,
+}
+
+
+def detect_hardware() -> Optional[HardwareType]:
+    """The attached accelerator's row of the peak table, or None on the
+    CPU backend (a host CPU has no published matmul peak, so no MFU is
+    computed there). An accelerator of unknown kind raises."""
+    import jax
+
+    device = jax.devices()[0]
+    if device.platform == "cpu":
+        return None
+    return HardwareType.from_device_kind(device.device_kind)
 
 
 def get_model_parameter_count(
@@ -145,7 +193,7 @@ def get_palm_mfu(
     sequence_length: int,
     tokens_per_second: float,
     world_size: int,
-    hardware: HardwareType = HardwareType.TPU_V5P,
+    hardware: HardwareType,
 ) -> float:
     """PaLM appendix-B MFU: observed tokens/s over peak-flop token rate
     (reference: get_tflops.py:337-401)."""

@@ -3,8 +3,8 @@
 The reference framework's native surface is all imported (NCCL, flash-attn,
 torch internals — reference SURVEY §2.3); here the compute hot path is
 XLA/Pallas and the *runtime* hot paths (data indexing) are first-party C++,
-compiled on demand with the system toolchain and loaded via ctypes. Missing
-compiler → the callers fall back to their Python implementations.
+compiled on demand with the system toolchain and loaded via ctypes. Without
+a compiler the callers use their Python implementations, and say so once.
 """
 
 from __future__ import annotations
@@ -16,31 +16,47 @@ from typing import Optional
 
 import numpy as np
 
+from ..logging import logger
+
 _SRC_DIR = Path(__file__).parent
 _LIB: Optional[ctypes.CDLL] = None
 _TRIED = False
 
 
 def _build_and_load() -> Optional[ctypes.CDLL]:
+    """Build ``pack_index.cpp`` beside its source when the library is
+    missing or older, and load it. Only "no compiler" and "the build
+    failed" mean the Python implementation serves instead, and the reason
+    is logged; anything else (a library that does not load, a missing
+    symbol) is a defect and raises."""
     src = _SRC_DIR / "pack_index.cpp"
     lib_path = _SRC_DIR / "libpack_index.so"
-    try:
-        if not lib_path.exists() or lib_path.stat().st_mtime < src.stat().st_mtime:
+    if not lib_path.exists() or lib_path.stat().st_mtime < src.stat().st_mtime:
+        try:
             subprocess.run(
                 ["g++", "-O3", "-shared", "-fPIC", "-o", str(lib_path), str(src)],
                 check=True, capture_output=True, timeout=120,
             )
-        lib = ctypes.CDLL(str(lib_path))
-        lib.build_pack_index.restype = ctypes.c_int64
-        lib.build_pack_index.argtypes = [
-            ctypes.POINTER(ctypes.c_int64), ctypes.c_int64, ctypes.c_int64,
-            ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
-            ctypes.c_int64,
-        ]
-        return lib
-    except Exception:
-        return None
+        except FileNotFoundError:
+            logger.warning("native pack index: no g++ on this machine; "
+                           "the Python implementation serves")
+            return None
+        except (subprocess.CalledProcessError, subprocess.TimeoutExpired) as e:
+            detail = getattr(e, "stderr", b"") or b""
+            logger.warning(
+                f"native pack index: building {src.name} failed ({e}); the "
+                f"Python implementation serves. {detail.decode()[-500:]}"
+            )
+            return None
+    lib = ctypes.CDLL(str(lib_path))
+    lib.build_pack_index.restype = ctypes.c_int64
+    lib.build_pack_index.argtypes = [
+        ctypes.POINTER(ctypes.c_int64), ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
+        ctypes.c_int64,
+    ]
+    return lib
 
 
 def _lib() -> Optional[ctypes.CDLL]:

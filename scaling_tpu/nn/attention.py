@@ -672,8 +672,6 @@ class ParallelSelfAttention(BaseLayer):
                 # together (enforced at pool init, serve/kvcache.py).
                 from jax.sharding import PartitionSpec as P
 
-                from ..parallel.sharding import shard_map
-
                 heads = P(None, None, MODEL_AXIS, None)
                 rep2, rep1 = P(None, None), P(None)
                 quant = view.quantized
@@ -692,7 +690,7 @@ class ParallelSelfAttention(BaseLayer):
                 ]
                 if quant:
                     operands += [new_view.scale_k, new_view.scale_v]
-                out = shard_map(
+                out = jax.shard_map(
                     run_shard, mesh=ctx.mesh, in_specs=tuple(in_specs),
                     out_specs=heads, check_vma=False,
                 )(*operands)

@@ -47,11 +47,12 @@ not a stand-in; the XLA gather branch stays config-selectable
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+from ..obs import count_kernel_build
 
 # pallas resolves lazily on first kernel build so importing scaling_tpu.nn
 # never pulls the pallas machinery on jax-light paths; the kernel body
@@ -71,9 +72,7 @@ def _ensure_pallas():
 
 def paged_kernel_interpret(platform: Optional[str] = None) -> bool:
     """Interpret mode off-TPU (CPU mesh tests run the real kernel body);
-    ``SCALING_TPU_PAGED_INTERPRET=1`` forces it for on-chip debugging."""
-    if os.environ.get("SCALING_TPU_PAGED_INTERPRET") == "1":
-        return True
+    on a TPU the kernel is always compiled."""
     return (platform or jax.default_backend()) != "tpu"
 
 
@@ -186,6 +185,7 @@ def paged_decode_attention(
     quantized = scale_k is not None
     if interpret is None:
         interpret = paged_kernel_interpret()
+    count_kernel_build("paged_attention", interpret)
 
     def _row(bi, j, tab, valid, base):
         del j, tab, valid, base

@@ -18,7 +18,9 @@ and the supervisor's relaunch path must not pay backend init.
 from .hardware import (
     StepTimeEMA,
     achieved_tflops,
+    count_kernel_build,
     device_memory_snapshot,
+    kernel_build_count,
     mfu,
     update_hardware_gauges,
 )
@@ -51,6 +53,7 @@ __all__ = [
     "StepTelemetry",
     "StepTimeEMA",
     "achieved_tflops",
+    "count_kernel_build",
     "current_span",
     "current_trace",
     "current_trace_id",
@@ -58,6 +61,7 @@ __all__ = [
     "device_memory_snapshot",
     "get_registry",
     "host_id",
+    "kernel_build_count",
     "mfu",
     "new_trace_id",
     "span",

@@ -68,6 +68,26 @@ def update_hardware_gauges(registry: Optional[MetricsRegistry] = None) -> Dict:
     }
 
 
+def _kernel_builds(kernel: str, interpret: bool):
+    return get_registry().counter(
+        "kernel_builds",
+        {"kernel": kernel, "interpret": str(bool(interpret)).lower()},
+    )
+
+
+def count_kernel_build(kernel: str, interpret: bool) -> None:
+    """Count one Pallas kernel build (trace), by kernel name and by whether
+    it was built for the interpreter, so that a run on the chip asserts
+    the path it took instead of inferring it from the configuration."""
+    _kernel_builds(kernel, interpret).inc()
+
+
+def kernel_build_count(kernel: str, interpret: bool) -> int:
+    """How often ``kernel`` was built so far, compiled (``interpret=False``)
+    or for the interpreter (chip_smoke.py and bench.py assert on it)."""
+    return int(_kernel_builds(kernel, interpret).value)
+
+
 class StepTimeEMA:
     """Exponential moving average of fetched step durations — the smooth
     signal regression gates and dashboards want, next to the raw

@@ -39,6 +39,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..obs import count_kernel_build
+
 _MIN_BLOCK = 128
 
 
@@ -112,6 +114,7 @@ def _make_kernel(num_q_heads: int, seq_len: int, block_q: int, block_kv: int,
         splash_attention_mask as sm,
     )
 
+    count_kernel_build("splash_attention", interpret)
     bq = _snap_block(block_q, seq_len)
     bkv = _snap_block(block_kv, seq_len)
     # mixed-head masks: leading heads are fully causal, the trailing
@@ -208,7 +211,6 @@ def flash_attention_fused(
         # GSPMD, which would otherwise gather heads to every device. With
         # uniform causal masks each model shard runs an identical kernel on
         # its contiguous slice of q (and kv) heads; batch splits over data.
-        from ..parallel.sharding import shard_map
         from jax.sharding import PartitionSpec as P
 
         from ..topology.topology import DATA_AXIS, MODEL_AXIS
@@ -228,7 +230,7 @@ def flash_attention_fused(
             return jax.vmap(one)(qq, kk, vv, seg)
 
         qkv_spec = P(DATA_AXIS, MODEL_AXIS, None, None)
-        out = shard_map(
+        out = jax.shard_map(
             run_shard,
             mesh=mesh,
             in_specs=(qkv_spec, qkv_spec, qkv_spec, P(DATA_AXIS, None)),

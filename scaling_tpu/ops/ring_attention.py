@@ -278,15 +278,13 @@ def ring_attention(
     ring. Requires seq divisible by the context axis size. ``kv_chunk``
     (STATIC — part of the trace, not a baked-in global) caps the inner
     score-tile width; default _DEFAULT_KV_CHUNK."""
-    from ..parallel.sharding import shard_map
-
     if segment_ids is None:
         segment_ids = jnp.zeros(q.shape[:2], jnp.int32)
 
     qkv_spec = P(DATA_AXIS, CONTEXT_AXIS, MODEL_AXIS, None)
     seg_spec = P(DATA_AXIS, CONTEXT_AXIS)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(
             _ring_attention_local,
             axis_name=CONTEXT_AXIS,

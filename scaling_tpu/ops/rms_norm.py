@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs import count_kernel_build
+
 _LANES = 128
 _DEFAULT_BLOCK_ROWS = 256
 
@@ -109,6 +111,7 @@ def _rms_fwd_impl(x: jax.Array, w: jax.Array, eps: float):
     x2 = _rows(x)
     n, d = x2.shape
     br = _block_rows(n)
+    count_kernel_build("rms_norm", _FORCE_INTERPRET)
     y = pl.pallas_call(
         functools.partial(_fwd_kernel, eps),
         grid=(n // br,),
@@ -190,7 +193,6 @@ def rms_norm_fused_sharded(
     local rows with the replicated gain; shard_map's transpose inserts the
     psum that reduces the per-shard weight grads (the manual analogue of
     GSPMD's backward collective for the XLA path)."""
-    from ..parallel.sharding import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..topology.topology import CONTEXT_AXIS, DATA_AXIS, MODEL_AXIS
@@ -205,7 +207,7 @@ def rms_norm_fused_sharded(
         seq_axes if seq_axes else None,
         None,
     )
-    return shard_map(
+    return jax.shard_map(
         lambda xx, ww: rms_norm_fused(xx, ww, eps),
         mesh=mesh,
         in_specs=(spec, P()),

@@ -119,15 +119,13 @@ def ulysses_attention(
 ) -> jax.Array:
     """shard_map entry mirroring ``ring_attention``'s contract: shards
     q/k/v over (data, context, model) and runs the head exchange."""
-    from ..parallel.sharding import shard_map
-
     if segment_ids is None:
         segment_ids = jnp.zeros(q.shape[:2], jnp.int32)
 
     qkv_spec = P(DATA_AXIS, CONTEXT_AXIS, MODEL_AXIS, None)
     seg_spec = P(DATA_AXIS, CONTEXT_AXIS)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(
             _ulysses_local,
             axis_name=CONTEXT_AXIS,

@@ -205,6 +205,15 @@ class Optimizer:
             )
         return NamedSharding(self.topology.mesh, P(*spec))
 
+    def _param_sharding(self, meta: ParamMeta, shape: tuple):
+        """Where the compute copy of a parameter lives: its own spec, plus
+        the data axis only under ZeRO stage 3 (``shard_params``'s rule)."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        if self.config.zero and self.config.zero_stage == 3:
+            return self._master_sharding(meta, shape)
+        return NamedSharding(self.topology.mesh, P(*meta.partition_spec))
+
     def abstract_state(self, params: Any) -> OptimizerState:
         """``init_state``'s output as ShapeDtypeStructs with the ZeRO
         master shardings attached.
@@ -278,12 +287,23 @@ class Optimizer:
             avgs.append(zeros())
             avg_sqs.append(zeros())
         unflatten = lambda ls: jax.tree.unflatten(self._treedef, ls)  # noqa: E731
+        scalars = (jnp.asarray(0, jnp.int32), self.loss_scaler.init_state())
+        if self.topology is not None:
+            # replicate the scalars on the mesh like every step's outputs
+            # will be: left off the mesh they key a second trace and a
+            # second full compile of the train step at step 2 (27 s of the
+            # first on-chip run, PERF.md "First on-chip run")
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            scalars = jax.device_put(
+                scalars, NamedSharding(self.topology.mesh, P())
+            )
         return OptimizerState(
-            step=jnp.asarray(0, jnp.int32),
+            step=scalars[0],
             master=unflatten(masters),
             exp_avg=unflatten(avgs),
             exp_avg_sq=unflatten(avg_sqs),
-            loss_scaler=self.loss_scaler.init_state(),
+            loss_scaler=scalars[1],
         )
 
     # ---------------------------------------------------------------- step
@@ -382,8 +402,9 @@ class Optimizer:
         bc2 = 1.0 - beta2**t
 
         new_p, new_m, new_a, new_s = [], [], [], []
-        for p, g, master, avg, avg_sq, gi in zip(
-            p_leaves, g32, m_leaves, a_leaves, s_leaves, self._group_index
+        for p, g, master, avg, avg_sq, gi, m in zip(
+            p_leaves, g32, m_leaves, a_leaves, s_leaves, self._group_index,
+            self._meta_leaves,
         ):
             if gi < 0:  # frozen
                 new_p.append(p)
@@ -410,7 +431,18 @@ class Optimizer:
             new_m.append(m2)
             new_a.append(a2)
             new_s.append(s2)
-            new_p.append(m2.astype(compute_dtype or p.dtype))
+            p2 = m2.astype(compute_dtype or p.dtype)
+            if self.topology is not None and c.zero:
+                # ZeRO-1's parameter re-gather, said out loud: without it
+                # the compiler leaves the new compute copy sharded over the
+                # data axis like the master it was cast from, the step's
+                # outputs stop matching its inputs' shardings, and step 2
+                # compiles a second executable (47 s on four chips,
+                # PERF.md "First on-chip run")
+                p2 = jax.lax.with_sharding_constraint(
+                    p2, self._param_sharding(m, p2.shape)
+                )
+            new_p.append(p2)
 
         unflatten = lambda ls: jax.tree.unflatten(jax.tree.structure(params), ls)  # noqa: E731
         new_state = OptimizerState(

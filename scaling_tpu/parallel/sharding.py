@@ -19,26 +19,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..topology.topology import CONTEXT_AXIS, DATA_AXIS, MODEL_AXIS, PIPE_AXIS
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True, **kw):
-    """Version-portable ``shard_map``: the top-level ``jax.shard_map``
-    (with its ``check_vma`` kwarg) moved out of ``jax.experimental`` only
-    in newer jax; older releases (this container ships 0.4.x) keep it in
-    ``jax.experimental.shard_map`` under the old ``check_rep`` spelling.
-    One shim here instead of four drifting call sites in ops/."""
-    import inspect
-
-    try:
-        from jax import shard_map as _shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _shard_map
-    try:
-        spells_vma = "check_vma" in inspect.signature(_shard_map).parameters
-    except (TypeError, ValueError):
-        spells_vma = True
-    kw["check_vma" if spells_vma else "check_rep"] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def _axis_in_mesh(mesh: Optional[Mesh], axis: str) -> bool:
     return mesh is not None and axis in mesh.axis_names
 

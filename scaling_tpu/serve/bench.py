@@ -1628,7 +1628,10 @@ def main(argv: Optional[List[str]] = None) -> int:
     # the proc-fleet HOST never builds an engine: the jax-importing
     # modules load only in the worker subprocesses
     if not proc_fleet:
+        from ..compile_cache import enable_compile_cache
         from .engine import EngineConfig, ServeEngine, install_drain_handler
+
+        enable_compile_cache()
 
     import os
 

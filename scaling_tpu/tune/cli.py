@@ -6,28 +6,19 @@ Calibration resolution (printed with the report — the tuner NEVER uses
 the legacy step-time/3.2 fudge):
 
 1. ``--run-dir DIR``: mean MFU of that obs run dir's step records.
-2. A fresh bench capture: ``benchmarks/artifacts/LAST_GOOD.json``'s MFU
-   — but ONLY while ``STALE.json`` is absent.
-3. While the bench capture is stale, the newest obs run dir under
-   ``--obs-root`` (ROADMAP "bench capture health"); the source used is
-   recorded INTO ``STALE.json`` under ``tuner_calibration`` so the
-   fallback is auditable.
-4. An explicit default (efficiency 0.5) that says it is uncalibrated.
+2. ``--obs-root ROOT``: the newest obs run dir under it.
+3. An explicit default (efficiency 0.5) that says it is uncalibrated.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 import time
 from pathlib import Path
 from typing import Optional, Tuple
 
-REPO_ROOT = Path(__file__).resolve().parents[2]
-LAST_GOOD_PATH = REPO_ROOT / "benchmarks" / "artifacts" / "LAST_GOOD.json"
-STALE_PATH = REPO_ROOT / "benchmarks" / "artifacts" / "STALE.json"
 GOLDEN_DIR = Path(__file__).resolve().parent / "goldens"
 
 # golden scores compare within this band (pure-python floats are
@@ -53,71 +44,21 @@ def _newest_run_dir(obs_root: Path) -> Optional[Path]:
     return newest[1]
 
 
-def _note_stale_calibration(source: str) -> None:
-    """Record into STALE.json which calibration source replaced the stale
-    bench capture — best effort, the marker is an audit trail."""
-    try:
-        rec = json.loads(STALE_PATH.read_text())
-    except (OSError, ValueError):
-        return
-    rec["tuner_calibration"] = {
-        "source": source,
-        "written": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
-        "note": "bench capture stale: the tuner calibrated its cost model "
-                "from this source instead of LAST_GOOD (never the 3.2-fudge "
-                "profile path)",
-    }
-    try:
-        tmp = STALE_PATH.with_suffix(".json.tmp")
-        tmp.write_text(json.dumps(rec, indent=1) + "\n")
-        os.replace(tmp, STALE_PATH)
-    except OSError as e:
-        print(f"# tune: STALE.json note failed ({e})", file=sys.stderr)
-
-
 def resolve_calibration(run_dir: Optional[str], obs_root: Optional[str]):
     from .costmodel import Calibration
 
-    if run_dir:
-        cal = Calibration.from_run_dir(run_dir)
-        if cal is None:
-            print(
-                f"# tune: {run_dir} has no MFU step records; falling back",
-                file=sys.stderr,
-            )
-        else:
-            return cal
-    stale = STALE_PATH.is_file()
-    if not stale and LAST_GOOD_PATH.is_file():
-        try:
-            rec = json.loads(LAST_GOOD_PATH.read_text())
-            mfu = float(rec["result"]["mfu"])
-            return Calibration.from_mfu(
-                mfu, f"bench:LAST_GOOD@{rec.get('captured')}"
-            )
-        except (OSError, ValueError, KeyError, TypeError) as e:
-            print(f"# tune: LAST_GOOD unreadable ({e})", file=sys.stderr)
-    if stale:
-        root = Path(obs_root) if obs_root else None
-        newest = _newest_run_dir(root) if root else None
+    candidates = [run_dir] if run_dir else []
+    if obs_root:
+        newest = _newest_run_dir(Path(obs_root))
         if newest is not None:
-            cal = Calibration.from_run_dir(newest)
-            if cal is not None:
-                _note_stale_calibration(cal.source)
-                return cal
-        cal = Calibration.default()
-        _note_stale_calibration(
-            cal.source if newest is None else f"{cal.source}; newest run dir "
-            f"{newest} had no MFU records"
-        )
-        print(
-            "# tune: bench capture is STALE and no obs run dir offered MFU "
-            "records; scoring with the uncalibrated default efficiency "
-            "(pass --run-dir or --obs-root)",
-            file=sys.stderr,
-        )
-        return cal
-    return None  # plain default, no stale marker to annotate
+            candidates.append(str(newest))
+    for candidate in candidates:
+        cal = Calibration.from_run_dir(candidate)
+        if cal is not None:
+            return cal
+        print(f"# tune: {candidate} has no MFU step records; falling back",
+              file=sys.stderr)
+    return None  # the uncalibrated default, which labels itself
 
 
 def golden_path(devices: int, model_name: str) -> Path:
@@ -429,8 +370,8 @@ def main(argv=None) -> int:
     parser.add_argument("--run-dir", help="obs run dir to calibrate "
                         "compute efficiency from (mean MFU)")
     parser.add_argument("--obs-root",
-                        help="root to search for the newest obs run dir "
-                        "when the bench capture is stale")
+                        help="calibrate from the newest obs run dir "
+                        "under this root")
     parser.add_argument("--correct-from-runs", metavar="ROOT",
                         help="accumulate tuner-prediction vs span-measured "
                         "pairs from every run dir under ROOT and apply the "

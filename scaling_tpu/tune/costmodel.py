@@ -19,7 +19,7 @@ Three ingredient sources, in decreasing fidelity:
   ring/ulysses context traffic, ZeRO-3 parameter all-gathers — the same
   textbook forms Megatron-LM/ATP use;
 - **calibration**: a compute-efficiency scalar taken from a real
-  measurement (obs run-dir MFU, bench LAST_GOOD MFU) so predicted step
+  measurement (an obs run dir's logged MFU) so predicted step
   times live in measured units, and the obs report's tuner section can
   score the prediction against span-measured step time per run
   (docs/TUNING.md "calibration loop").
@@ -284,7 +284,7 @@ class Calibration:
 
     @classmethod
     def default(cls) -> "Calibration":
-        return cls(0.5, "default (uncalibrated: no bench capture or obs "
+        return cls(0.5, "default (uncalibrated: no obs "
                         "run dir offered)")
 
     @classmethod

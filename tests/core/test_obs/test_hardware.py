@@ -90,3 +90,25 @@ def test_update_hardware_gauges_sets_registry():
     snap = reg.snapshot()["gauges"]
     assert "live_arrays" in snap
     assert any(k.startswith("device_bytes_in_use{") for k in snap)
+
+
+def test_peak_table_v5e_published_values():
+    """Google Cloud "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM. The v5e
+    reports itself as "TPU v5 lite"."""
+    hw = HardwareType.from_device_kind("TPU v5 lite")
+    assert hw is HardwareType.TPU_V5E
+    assert (hw.max_tflops, hw.hbm_gbps) == (197.0, 819.0)
+
+
+def test_peak_table_unknown_device_kind_raises():
+    """An assumed peak makes every MFU wrong without saying so."""
+    with pytest.raises(ValueError, match="no published peak"):
+        HardwareType.from_device_kind("TPU v9 mega")
+
+
+def test_no_mfu_peak_on_the_cpu_backend():
+    """The CPU has no published matmul peak: the trainer logs no MFU there
+    (train.py reads detect_hardware) rather than one against a TPU's."""
+    from scaling_tpu.models.transformer.utils.get_tflops import detect_hardware
+
+    assert detect_hardware() is None

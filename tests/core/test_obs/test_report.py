@@ -256,7 +256,7 @@ def _tuner_run_dir(tmp_path, predicted=1.1, steps=4, fwdbwd=0.01, sync=0.99,
     lines = [json.dumps({
         "event": "tuner-prediction", "ts": 0.0, "label": "pp1·dp8·mp1·z1",
         "predicted_step_s": predicted, "world_size": 8,
-        "source": "bench:LAST_GOOD@test",
+        "source": "run_dir:test",
     })]
     for s in range(10, 10 + steps):
         scale = 30.0 if s == 10 else 1.0  # compile outlier, dropped
