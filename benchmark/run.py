@@ -1,15 +1,18 @@
 """Run one cell of BENCHMARK.json once.
 
-    python benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+    python benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1|2>
 
 Loads the cell's configuration and traffic by name, makes the weights from
 the seed, warms up every shape the window uses (all of that is ``setup_s``),
 measures for ``--seconds``, checks the outputs against the plain reference
 and prints one JSON object as the last line of stdout: with ``--trace 0`` the
 cell's end-to-end metrics, with ``--trace 1`` its per-layer metrics from a
-profiled slice of the window. Without a TPU, or with fewer chips than the
-cell asks for, it exits non-zero and prints no result. ``--rehearse`` walks the
-same code on the CPU (kernels interpreted) and never prints a result line.
+profiled slice of the window, with ``--trace 2`` both: the window runs as
+under ``--trace 0``, its numbers are taken, and only then are a few seconds
+more of the same traffic traced, in the same process. Without a TPU, or with
+fewer chips than the cell asks for, it exits non-zero and prints no result.
+``--rehearse`` walks the same code on the CPU (kernels interpreted) and never
+prints a result line.
 """
 
 from __future__ import annotations
@@ -44,7 +47,7 @@ def parse(argv):
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--seconds", type=float, default=None,
                    help="length of the measured window (default: run_seconds)")
-    p.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    p.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     p.add_argument("--rehearse", action="store_true",
                    help="CPU walk-through, kernels interpreted; prints no result")
     p.add_argument("--benchmark-json", type=Path,
@@ -68,10 +71,11 @@ def run_cell(args) -> dict:
 
     traffic = cell.traffic
     tracer = Tracer(
-        enabled=bool(args.trace),
+        enabled=args.trace == 1,
         out_dir=args.root.resolve().parent / ".bench_trace" / cell.name,
         start_after_s=TRACE_AFTER_SHARE * args.seconds,
         min_s=min(float(traffic.get("trace_seconds", 1.0)), args.seconds / 3),
+        after_window=args.trace == 2,
     )
     def mark(label: str) -> None:
         """Where set-up time goes: seconds since the process started."""
@@ -92,6 +96,10 @@ def run_cell(args) -> dict:
     values = {"setup_s": outcome["setup_s"], **outcome["end_to_end"]}
     result = {"correct": outcome["correct"], "attempted": outcome["attempted"],
               "failed": outcome["failed"], **outcome.get("notes", {})}
+    # --trace 0: end-to-end; 1: per-layer; 2: both, side by side
+    metrics = {} if args.trace == 1 else {
+        m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
+        for m in cell.metrics("end_to_end")}
     if args.trace:
         reduction = None
         trace_file = tracer.trace_file()
@@ -115,14 +123,17 @@ def run_cell(args) -> dict:
             "device": {**device_out, "memory_peaks": peaks,
                        "peaks": None if args.rehearse else peaks_of(device["kind"])},
         }
-        metrics = {}
+        if args.trace == 2:
+            # peak_hbm_gb.* reads what was taken as the window closed, before
+            # any capture; the line's memory_peak_bytes is the whole run's
+            ctx["device"].update(memory_peaks=outcome["window_peaks"],
+                                 memory_peak_bytes=max(outcome["window_peaks"]))
         for m in cell.metrics("per_layer"):
             value = cells.load_reader(m["name"], args.root)(ctx)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
-    else:
-        metrics = {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
-                   for m in cell.metrics("end_to_end")}
+        if args.trace == 2:
+            tracer.discard()  # reduced and read: the trace goes
     result.update(metrics=metrics, device=device_out)
     return result
 

@@ -6,6 +6,8 @@ when the clock crosses its due time, tick while there is work, drain) and
 warm-up request off the clock, the path-taken asserts), with what those
 measure wrongly repaired: every request is timed FROM WHEN IT WAS DUE, the
 generator's lateness is reported, and the window is long enough for its tails.
+Under ``--trace 2`` the window and its drain run untraced, every number of the
+window is taken, and only then are a few seconds of the same traffic traced.
 """
 
 from __future__ import annotations
@@ -32,6 +34,11 @@ LOGIT_TOL = 0.05
 # and its gaps so far count, and its tokens so far are checked like any
 # other's. One that has no first token by then has failed.
 DRAIN_CAP_S = 20.0
+# --trace 2: when the window's numbers are taken, arrivals resume at the
+# cell's rate and the capture starts once a tick carries a prefill chunk,
+# or after this long at the latest: the traced part is then the cell's mix
+# of prefill and decode, and not the decode-only residue of the drain.
+TRACE_LEAD_S = 2.0
 
 
 def log(msg: str) -> None:
@@ -64,9 +71,10 @@ def build_engine(cell, seed: int):
 
 def check_against_reference(cell, inf, done, seed: int, count: int,
                             max_tokens: int, max_outputs: int):
-    """A seeded sample of requests, the engine's tokens teacher-forced through
-    the plain reference: (positions compared, largest gap of an engine token
-    below the reference's best logit). Every request is padded to the same
+    """A seeded sample of ``done`` (request, its tokens as the window
+    closed), the engine's tokens teacher-forced through the plain reference:
+    (positions compared, largest gap of an engine token below the reference's
+    best logit). Every request is padded to the same
     ``max_tokens`` and ``max_outputs`` (attention is causal, so padding after
     the last token changes nothing), so the check is two programs whatever
     the sample."""
@@ -86,13 +94,13 @@ def check_against_reference(cell, inf, done, seed: int, count: int,
         picked = jnp.take_along_axis(logits, got[:, None], axis=-1)[:, 0]
         return jnp.max(jnp.where(valid, logits.max(axis=-1) - picked, 0.0))
 
-    pool = [(r, s) for r, s in done
-            if s.generated and len(r.prompt) + len(s.generated) <= max_tokens]
+    pool = [(r, generated) for r, generated in done
+            if generated and len(r.prompt) + len(generated) <= max_tokens]
     picks = np.random.default_rng(seed).permutation(len(pool))[:count]
     compared, worst = 0, 0.0
     for i in picks:
-        request, seq = pool[int(i)]
-        prompt, got = request.prompt, seq.generated[:max_outputs]
+        request, generated = pool[int(i)]
+        prompt, got = request.prompt, generated[:max_outputs]
         tokens = np.zeros((max_tokens,), np.int32)
         tokens[:len(prompt)] = prompt
         tokens[len(prompt):len(prompt) + len(got) - 1] = got[:-1]
@@ -109,6 +117,37 @@ def check_against_reference(cell, inf, done, seed: int, count: int,
     return compared, worst
 
 
+def window_numbers(submitted, t0: float, seconds: float):
+    """What the result says of the window, taken as the run leaves it, from
+    copies where the objects go on living (under ``--trace 2`` the sequences
+    decode on through the traced part): output tokens stamped inside the
+    window; ``done``, the counted requests that had their first token, each
+    with its tokens so far; how many failed, finished, were cut while
+    decoding; the time-to-first-token and inter-token samples."""
+    window_end = t0 + seconds
+    stamps = [s for _, seq in submitted for s in getattr(seq, "token_stamps", ())]
+    tokens_in_window = sum(t0 <= s < window_end for s in stamps)
+    done, failed, finished, cut, ttft, itl = [], 0, 0, 0, [], []
+    for r, seq in submitted:
+        if not r.counted:
+            continue
+        # refused at submit (no sequence), no first token by the end of the
+        # run, or finished otherwise than completed at the length asked for
+        ok = getattr(seq, "first_token_s", None) is not None
+        if ok and seq.finished_s is not None:
+            finished += 1
+            ok = (seq.finish_status == "completed"
+                  and len(seq.generated) == r.output_len)
+        if not ok:
+            failed += 1
+            continue
+        done.append((r, list(seq.generated)))
+        cut += seq.finished_s is None
+        ttft.append(seq.first_token_s - (t0 + r.due_s))
+        itl.extend(b - a for a, b in zip(seq.token_stamps, seq.token_stamps[1:]))
+    return tokens_in_window, done, failed, finished, cut, ttft, itl
+
+
 def run(cell, args, env) -> dict:
     import jax
     import numpy as np
@@ -116,7 +155,7 @@ def run(cell, args, env) -> dict:
     from scaling_tpu.obs import kernel_build_count
 
     from . import traffic_gen
-    from .device import live_bytes
+    from .device import live_bytes, memory_peaks
     from .stats import percentile
 
     traffic = cell.traffic
@@ -125,7 +164,13 @@ def run(cell, args, env) -> dict:
     config, inf, engine = build_engine(cell, args.seed)
     env["mark"]("weights and KV pool on the device")
     arch = config.transformer_architecture
-    requests = traffic_gen.generate(traffic, args.seed, args.seconds, arch.vocab_size)
+    tracer = env["tracer"]
+    trace_s = float(traffic.get("trace_seconds", 1.0))
+    requests = traffic_gen.generate(
+        traffic, args.seed, args.seconds, arch.vocab_size,
+        traced_seconds=TRACE_LEAD_S + trace_s if tracer.after_window else 0.0)
+    after_window = [r for r in requests if r.traced]
+    requests = [r for r in requests if not r.traced]
     counted = [r for r in requests if r.counted]
     log(f"{len(requests)} requests ({len(counted)} counted), "
         f"{sum(len(r.prompt) for r in counted)} prompt and "
@@ -144,7 +189,6 @@ def run(cell, args, env) -> dict:
     gc.collect()
     gc.freeze()
 
-    tracer = env["tracer"]
     warm_s = float(traffic["warm_seconds"])
     submitted, late = [], []
     tick_s, decode_rows, context_tokens = [], [], []
@@ -197,28 +241,9 @@ def run(cell, args, env) -> dict:
     drained_s = time.monotonic() - end_of_arrivals
     compiles_in_window = env["compiles"].count - compiles_before
 
-    # -- the window's numbers
-    window_end = t0 + args.seconds
-    stamps = [s for _, seq in submitted for s in getattr(seq, "token_stamps", ())]
-    tokens_in_window = sum(t0 <= s < window_end for s in stamps)
-    done, failed, finished, ttft, itl = [], 0, 0, [], []
-    for r, seq in submitted:
-        if not r.counted:
-            continue
-        # refused at submit (no sequence), no first token by the end of the
-        # run, or finished otherwise than completed at the length asked for
-        ok = getattr(seq, "first_token_s", None) is not None
-        if ok and seq.finished_s is not None:
-            finished += 1
-            ok = (seq.finish_status == "completed"
-                  and len(seq.generated) == r.output_len)
-        if not ok:
-            failed += 1
-            continue
-        done.append((r, seq))
-        ttft.append(seq.first_token_s - (t0 + r.due_s))
-        itl.extend(b - a for a, b in zip(seq.token_stamps, seq.token_stamps[1:]))
-    cut = len(done) - sum(seq.finished_s is not None for _, seq in done)
+    # -- the window's numbers, fixed here whatever runs afterwards
+    tokens_in_window, done, failed, finished, cut, ttft, itl = window_numbers(
+        submitted, t0, args.seconds)
     log(f"{len(done)} of {len(counted)} counted requests had their first token "
         f"({finished} finished, {cut} cut while decoding, {failed} failed), ran "
         f"{drained_s:.1f} s past the last arrival with "
@@ -228,6 +253,33 @@ def run(cell, args, env) -> dict:
         f"{len(ttft)} time-to-first-token and {len(itl)} inter-token samples; "
         f"{len(tick_s)} ticks; preemptions {engine.scheduler.preemption_count}; "
         f"{compiles_in_window} program(s) lowered in the window")
+
+    # -- --trace 2: the window's numbers are taken; the same traffic goes
+    # on, uncounted, and a few seconds of it are traced. The peak is read
+    # before any capture starts.
+    window_peaks = None
+    if tracer.after_window:
+        window_peaks = memory_peaks(jax.devices()[:1], live)
+        tracer.open_after_window()
+        resumed, idx, prefilling = time.monotonic(), 0, False
+        while tracer.stopped_at is None:
+            now = time.monotonic()
+            while idx < len(after_window) and resumed + after_window[idx].due_s <= now:
+                r = after_window[idx]
+                engine.submit(r.prompt, r.output_len, arrival_s=resumed + r.due_s)
+                idx += 1
+            if prefilling or now - resumed >= TRACE_LEAD_S:
+                tracer.maybe_start(0.0)
+            if engine.scheduler.has_work:
+                tick = engine.tick()
+                prefilling = bool(tick.prefills)
+                if tracer.active:
+                    traced_context_tokens += sum(
+                        s.num_cached for s in tick.decodes + tick.prefills)
+            else:
+                time.sleep(0.001)
+            tracer.maybe_stop()
+        env["mark"]("traced part over")
 
     builds = kernel_build_count("paged_attention", interpret=args.rehearse)
     wrong_builds = kernel_build_count("paged_attention", interpret=not args.rehearse)
@@ -270,4 +322,5 @@ def run(cell, args, env) -> dict:
             "worst_logit_gap": worst, "drained_s": drained_s,
         },
         "devices": [jax.devices()[0].id], "live_bytes": live,
+        "window_peaks": window_peaks,
     }

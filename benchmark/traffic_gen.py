@@ -47,10 +47,13 @@ class Request:
     due_s: float          # offset from the window's start; < 0 is warm-up
     prompt: List[int]
     output_len: int
+    # of the traced part that follows the window (--trace 2): due_s is
+    # then an offset from when arrivals resume, and it is never counted
+    traced: bool = False
 
     @property
     def counted(self) -> bool:
-        return self.due_s >= 0.0
+        return self.due_s >= 0.0 and not self.traced
 
 
 def _lengths(rng, spec: dict, n: int) -> np.ndarray:
@@ -84,11 +87,16 @@ def _aged(history: dict, age_s: float, prompt: int, output: int):
     return prompt + generated, output - generated
 
 
-def generate(traffic: dict, seed: int, seconds: float, vocab: int) -> List[Request]:
+def generate(traffic: dict, seed: int, seconds: float, vocab: int,
+             traced_seconds: float = 0.0) -> List[Request]:
     """The requests still running out of the virtual history, due as the
     warm-up starts (``-warm_seconds``); the warm-up's, due in
     ``[-warm_seconds, 0)``; and the window's, due in ``[0, seconds)``; in
-    order of due time."""
+    order of due time. With ``traced_seconds`` (--trace 2) the arrivals of
+    the traced part follow them, marked ``traced`` and due in
+    ``[0, traced_seconds)`` after arrivals resume: a further phase with a
+    generator of its own, its token ids drawn last, so that everything
+    before it is the same request for request and token for token."""
     phase_rng = lambda k: np.random.default_rng([int(traffic["shape_seed"]), k])
     warm = float(traffic["warm_seconds"])
     history = traffic["history"]
@@ -101,9 +109,13 @@ def generate(traffic: dict, seed: int, seconds: float, vocab: int) -> List[Reque
     for k, start, span in ((1, -warm, warm), (2, 0.0, float(seconds))):
         shapes += [(start + off, int(p), int(o))
                    for off, p, o in zip(*_shapes(phase_rng(k), traffic, span))]
+    traced_from = len(shapes)
+    if traced_seconds > 0:
+        shapes += [(off, int(p), int(o)) for off, p, o in
+                   zip(*_shapes(phase_rng(3), traffic, float(traced_seconds)))]
     # the seed's part: the token ids
     token_rng = np.random.default_rng(seed)
     return [Request(due_s=float(due),
                     prompt=token_rng.integers(1, vocab, size=p).tolist(),
-                    output_len=o)
-            for due, p, o in shapes]
+                    output_len=o, traced=i >= traced_from)
+            for i, (due, p, o) in enumerate(shapes)]

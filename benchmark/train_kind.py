@@ -4,7 +4,8 @@ The harness calls what ``scaling_tpu.models.transformer.train.main`` calls
 (``init_model``, ``init_optimizer``, ``build_train_step``, the batch placement
 of ``shard_batch``) and times chunks of k steps that each end in
 ``block_until_ready``, the loop of ``bench.py`` ``measure``. The trainer's data
-loader, logging and checkpoints are outside this kind.
+loader, logging and checkpoints are outside this kind. Under ``--trace 2`` the
+window runs untraced and a traced second of the same chunks follows it.
 """
 
 from __future__ import annotations
@@ -41,7 +42,7 @@ def run(cell, args, env) -> dict:
     from scaling_tpu.topology import Topology
 
     from . import model, ops_count
-    from .device import live_bytes
+    from .device import live_bytes, memory_peaks
     from .reference import dense_decoder as ref
 
     traffic, arch_json = cell.traffic, cell.config["transformer_architecture"]
@@ -150,8 +151,21 @@ def run(cell, args, env) -> dict:
             chunks.append(time.monotonic() - now)
             tracer.maybe_stop()
         elapsed = time.monotonic() - t0
-    env["mark"]("window over")
-    compiles_in_window = env["compiles"].count - compiles_before
+        env["mark"]("window over")
+        compiles_in_window = env["compiles"].count - compiles_before
+        # -- --trace 2: more chunks on the same weights with fresh batches,
+        # traced; nothing below reads them. The peak is read before any
+        # capture starts, as far into the run as --trace 0's window goes
+        window_peaks = None
+        if tracer.after_window:
+            window_peaks = memory_peaks(devices, live)
+            tracer.open_after_window()
+            while tracer.stopped_at is None:
+                tracer.maybe_start(0.0)
+                params, opt_state, loss = run_steps(k, params, opt_state)
+                jax.block_until_ready(loss)
+                tracer.maybe_stop()
+            env["mark"]("traced part over")
 
     steps = k * len(chunks)
     tokens_per_step = batch_rows * seq
@@ -185,4 +199,5 @@ def run(cell, args, env) -> dict:
             "batch_rows": batch_rows, "seq": seq,
         },
         "devices": [d.id for d in devices], "live_bytes": live,
+        "window_peaks": window_peaks,
     }

@@ -298,6 +298,24 @@ class _Logger:
         self.info(f"config:\n{config.as_str()}")
 
     # -------------------------------------------------------------- events
+    def events_path(self) -> Optional[str]:
+        """Where structured events are appended, or None. Env first: the
+        field doc promises the env var OVERRIDES the config value (a
+        launcher redirecting a subprocess whose config already declares
+        a path must win)."""
+        import os as _os
+
+        return _os.environ.get("SCALING_TPU_EVENTS_PATH") or (
+            self._config.events_path if self._config is not None else None
+        )
+
+    def takes_events(self, level: str) -> bool:
+        """Whether a ``log_event`` record at ``level`` reaches anything:
+        the events file, or the log's mirror line. Per-tick span records
+        ask before they build and serialise one."""
+        return bool(self.events_path()) or self._log.isEnabledFor(
+            _LEVELS.get(level, _pylogging.INFO))
+
     def log_event(self, event: str, _level: str = "info",
                   _fsync: bool = True, **fields: Any) -> None:
         """Structured lifecycle event: one JSON line, append-only.
@@ -331,12 +349,7 @@ class _Logger:
                     rec.setdefault(k, v)
         line = _json.dumps(rec, sort_keys=True, default=str)
         getattr(self, _level, self.info)(f"EVENT {line}")
-        # env first: the field doc promises the env var OVERRIDES the
-        # config value (a launcher redirecting a subprocess whose config
-        # already declares a path must win)
-        path = _os.environ.get("SCALING_TPU_EVENTS_PATH") or (
-            self._config.events_path if self._config is not None else None
-        )
+        path = self.events_path()
         if path:
             try:
                 with open(path, "a") as f:

@@ -233,6 +233,7 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="paged_attention",  # the trace's and the HLO's name for it
     )(
         block_table.astype(jnp.int32),
         valid_len.astype(jnp.int32),

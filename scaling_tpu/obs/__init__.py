@@ -5,6 +5,8 @@
 - :mod:`.spans` — ``with obs.span("ckpt.commit", step=N)`` phase
   tracing, emitted through ``logger.log_event`` into the same stream as
   the supervision events.
+- :mod:`.capture` — the one start/stop control for all tracing of a
+  running process: the profiler, and the spans' sink on its clock.
 - :mod:`.hardware` — device memory / live-array gauges, step-time EMA,
   achieved-TFLOPs and MFU math.
 - :mod:`.telemetry` — the per-step driver the trainer owns.
@@ -15,6 +17,13 @@ jax-free at import time (functions import it lazily): the analyzer CLI
 and the supervisor's relaunch path must not pay backend init.
 """
 
+from .capture import (
+    Capture,
+    capturing,
+    last_capture,
+    start_capture,
+    stop_capture,
+)
 from .hardware import (
     StepTimeEMA,
     achieved_tflops,
@@ -45,6 +54,7 @@ from .spans import (
 from .telemetry import StepTelemetry
 
 __all__ = [
+    "Capture",
     "Counter",
     "Gauge",
     "Histogram",
@@ -53,6 +63,7 @@ __all__ = [
     "StepTelemetry",
     "StepTimeEMA",
     "achieved_tflops",
+    "capturing",
     "count_kernel_build",
     "current_span",
     "current_trace",
@@ -62,9 +73,12 @@ __all__ = [
     "get_registry",
     "host_id",
     "kernel_build_count",
+    "last_capture",
     "mfu",
     "new_trace_id",
     "span",
+    "start_capture",
+    "stop_capture",
     "trace_context",
     "update_hardware_gauges",
 ]

@@ -38,6 +38,10 @@ def device_memory_snapshot() -> List[Dict]:
             "platform": d.platform,
             "bytes_in_use": int(stats.get("bytes_in_use", 0)),
             "peak_bytes_in_use": int(stats.get("peak_bytes_in_use", 0)),
+            # the most the runtime reserved for a running program's
+            # temporaries: its own high-water mark, of another moment
+            # than peak_bytes_in_use (docs/OBSERVABILITY.md "Metric names")
+            "peak_bytes_reserved": int(stats.get("peak_bytes_reserved", 0)),
             "bytes_limit": int(stats.get("bytes_limit", 0)),
         })
     return out
@@ -51,19 +55,25 @@ def update_hardware_gauges(registry: Optional[MetricsRegistry] = None) -> Dict:
     reg = registry if registry is not None else get_registry()
     max_in_use = 0
     max_peak = 0
+    max_reserved = 0
     for rec in device_memory_snapshot():
         labels = {"device": str(rec["device"])}
         reg.gauge("device_bytes_in_use", labels).set(rec["bytes_in_use"])
         reg.gauge("device_peak_bytes_in_use", labels).set(
             rec["peak_bytes_in_use"]
         )
+        reg.gauge("device_peak_bytes_reserved", labels).set(
+            rec["peak_bytes_reserved"]
+        )
         max_in_use = max(max_in_use, rec["bytes_in_use"])
         max_peak = max(max_peak, rec["peak_bytes_in_use"])
+        max_reserved = max(max_reserved, rec["peak_bytes_reserved"])
     live = len(jax.live_arrays())
     reg.gauge("live_arrays").set(live)
     return {
         "device_bytes_in_use": max_in_use,
         "device_peak_bytes_in_use": max_peak,
+        "device_peak_bytes_reserved": max_reserved,
         "live_arrays": live,
     }
 

@@ -51,6 +51,9 @@ DEFAULT_BUCKETS: Tuple[float, ...] = (
 def _label_key(labels: Optional[Mapping[str, object]]) -> LabelKey:
     if not labels:
         return ()
+    if len(labels) == 1:  # every span's histogram: no generator, no sort
+        (k, v), = labels.items()
+        return ((str(k), str(v)),)
     return tuple(sorted((str(k), str(v)) for k, v in labels.items()))
 
 

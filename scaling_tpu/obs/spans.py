@@ -1,6 +1,6 @@
 """Phase tracing: ``with obs.span("ckpt.commit", step=N): ...``.
 
-Every span lands twice:
+Every span lands twice, and a third time while a capture is on:
 
 - as an observation in the default registry's ``span_seconds`` histogram
   (labelled by span name) — cheap, in-memory, flushed with the per-step
@@ -9,7 +9,13 @@ Every span lands twice:
   the PR 4 supervision events and the new telemetry share ONE stream and
   the run-dir analyzer (``python -m scaling_tpu.obs report``) can
   attribute barrier waits and checkpoint commits per host without a
-  second file format.
+  second file format. The record is built and serialised only when
+  something takes it: an events path is configured, or the logger
+  mirrors at the span's level;
+- while :func:`obs.start_capture` is on (``obs/capture.py``), as a
+  ``jax.profiler.TraceAnnotation`` of its name, which puts it on the
+  host plane of the profiler's trace on the device's clock, and as an
+  exact row of the capture's list. With no capture it does neither.
 
 Spans nest (thread-local stack; the parent's name is recorded on the
 child) and are exception-safe: a body that raises still emits the span,
@@ -49,6 +55,7 @@ from typing import Any, Iterator, Optional
 
 from ..logging import logger
 from ..logging.logger import set_trace_provider
+from . import capture as _capture
 from .registry import get_registry
 
 _local = threading.local()
@@ -137,12 +144,15 @@ set_trace_provider(_trace_event_fields)
 
 
 class Span:
-    """Handle yielded by :func:`span`; mutate it to enrich the record."""
+    """One traced phase: what :func:`span` returns, a context manager
+    that yields itself; mutate it in the body to enrich the record."""
 
     __slots__ = ("name", "fields", "_wait_for", "duration_s", "trace_id",
-                 "span_id", "parent_span_id")
+                 "span_id", "parent_span_id", "_step", "_level", "_registry",
+                 "_parent", "_capture", "_annotation", "_start")
 
-    def __init__(self, name: str, fields: dict):
+    def __init__(self, name: str, fields: dict, step: Optional[int] = None,
+                 level: str = "debug", registry=None):
         self.name = name
         self.fields = fields
         self._wait_for: Any = None
@@ -150,6 +160,7 @@ class Span:
         self.trace_id: Optional[str] = None
         self.span_id: Optional[str] = None
         self.parent_span_id: Optional[str] = None
+        self._step, self._level, self._registry = step, level, registry
 
     def wait_for(self, x: Any) -> Any:
         """Drain ``x`` (``jax.block_until_ready``) before the span closes,
@@ -161,57 +172,70 @@ class Span:
         """Attach extra fields to the emitted span event."""
         self.fields.update(fields)
 
+    def __enter__(self) -> "Span":
+        stack = _stack()
+        self._parent = stack[-1].name if stack else None
+        # resolve the trace lineage at entry, per thread: the enclosing
+        # span wins (its span_id becomes the parent link), else the
+        # adopted context; with neither the span stays trace-less and
+        # allocates no ids at all — the pre-tracing fast path,
+        # byte-identical records
+        if stack and stack[-1].trace_id:
+            self.trace_id = stack[-1].trace_id
+            self.parent_span_id = stack[-1].span_id
+        else:
+            ctx = getattr(_local, "trace", None)
+            if ctx is not None and ctx[0]:
+                self.trace_id, self.parent_span_id = ctx
+        if self.trace_id:
+            self.span_id = new_span_id()
+        stack.append(self)
+        self._capture = cap = _capture.active()
+        if cap is not None:
+            self._annotation = cap.annotation(self.name)
+            self._annotation.__enter__()
+        self._start = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        error = exc_type.__name__ if exc_type is not None else None
+        try:
+            if exc_type is None and self._wait_for is not None:
+                # drain INSIDE the measured window: the caller explicitly
+                # asked for SynchronizedTimer semantics on this span —
+                # opt-in via sp.wait_for(x), never the default
+                import jax
+
+                jax.block_until_ready(self._wait_for)
+        except BaseException as e:
+            error = type(e).__name__
+            raise
+        finally:
+            duration = time.perf_counter() - self._start
+            cap = self._capture
+            if cap is not None:
+                self._annotation.__exit__(None, None, None)
+                cap.close_span(self, self._parent, self._step, self._start,
+                               duration)
+            self.duration_s = duration
+            _stack().pop()
+            _emit(self, self._parent, duration, error is None, error,
+                  self._step, self._level, self._registry)
+        return False  # the body's exception propagates untouched
+
 
 def current_span() -> Optional[Span]:
     stack = _stack()
     return stack[-1] if stack else None
 
 
-@contextmanager
 def span(name: str, *, step: Optional[int] = None, level: str = "debug",
-         registry=None, **fields: Any) -> Iterator[Span]:
+         registry=None, **fields: Any) -> Span:
     """Trace one phase. ``level`` controls only the console mirror of the
     event (per-step phases default to ``debug`` so steady-state training
     does not quadruple its console output); the events file — when
     configured — receives every span regardless."""
-    sp = Span(name, dict(fields))
-    stack = _stack()
-    parent = stack[-1].name if stack else None
-    # resolve the trace lineage at entry, per thread: the enclosing span
-    # wins (its span_id becomes the parent link), else the adopted
-    # context; with neither the span stays trace-less and allocates no
-    # ids at all — the pre-tracing fast path, byte-identical records
-    if stack and stack[-1].trace_id:
-        sp.trace_id = stack[-1].trace_id
-        sp.parent_span_id = stack[-1].span_id
-    else:
-        ctx = getattr(_local, "trace", None)
-        if ctx is not None and ctx[0]:
-            sp.trace_id, sp.parent_span_id = ctx
-    if sp.trace_id:
-        sp.span_id = new_span_id()
-    stack.append(sp)
-    ok = True
-    error: Optional[str] = None
-    start = time.perf_counter()
-    try:
-        yield sp
-        if sp._wait_for is not None:
-            # drain INSIDE the measured window: the caller explicitly
-            # asked for SynchronizedTimer semantics on this span —
-            # opt-in via sp.wait_for(x), never the default
-            import jax
-
-            jax.block_until_ready(sp._wait_for)  # sta: disable=STA010
-    except BaseException as e:
-        ok = False
-        error = type(e).__name__
-        raise
-    finally:
-        duration = time.perf_counter() - start
-        sp.duration_s = duration
-        stack.pop()
-        _emit(sp, parent, duration, ok, error, step, level, registry)
+    return Span(name, fields, step, level, registry)
 
 
 def _emit(sp: Span, parent: Optional[str], duration: float, ok: bool,
@@ -219,6 +243,8 @@ def _emit(sp: Span, parent: Optional[str], duration: float, ok: bool,
           registry) -> None:
     reg = registry if registry is not None else get_registry()
     reg.histogram("span_seconds", labels={"span": sp.name}).observe(duration)
+    if not logger.takes_events(level):
+        return  # no events file and no mirror at this level: nobody reads it
     event_fields = dict(sp.fields)
     event_fields.update(span=sp.name, dur_s=round(duration, 6), ok=ok)
     if parent is not None:

@@ -12,7 +12,9 @@ and resumes on host 0 still reads as one finite, ordered timeline.
 
 Per trace the analyzer attributes wall time into phases:
 
-- ``queue_wait`` — submission until the first compute span touches it;
+- ``queue_wait`` — arrival until the scheduler first gave it a slot
+  (``queue_wait_s`` of its ``serve-request`` record; a run dir from
+  before that field: submission until the first compute span);
 - ``rpc``        — ``serve.replica.rpc_client`` time under the trace;
 - ``prefill``    — ``serve.prefill`` / ``serve.prefill_chunk`` plus the
   chunk share of ``serve.mixed`` ticks (``chunk_traces``);
@@ -158,7 +160,16 @@ def trace_phases(tid: str, recs: List[dict]) -> Dict[str, float]:
         if name in _COMPUTE_SPANS and (first_compute is None
                                        or r["_start"] < first_compute):
             first_compute = r["_start"]
-    if first_compute is not None:
+    # the scheduler's own stamp (arrival to the first slot, on the
+    # serve-request record) where the engine wrote one; older run dirs
+    # fall back to inferring it from the first compute span
+    stamped = next((r["queue_wait_s"] for r in recs
+                    if r.get("event") == "serve-request"
+                    and isinstance(r.get("queue_wait_s"), (int, float))),
+                   None)
+    if stamped is not None:
+        phases["queue_wait"] = max(0.0, float(stamped))
+    elif first_compute is not None:
         phases["queue_wait"] = max(0.0, first_compute - t0)
     # failover: the trace's host-stamped records jump hosts only when a
     # replica died (journal re-dispatch) or the router retried elsewhere

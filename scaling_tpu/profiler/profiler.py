@@ -9,9 +9,11 @@ them to rank 0 as JSON (profiler.py:79-104)). The TPU equivalents:
   single-controller analogue of a device sync;
 - the instruction loop is one fused XLA program, so per-instruction timers
   become per-step phase timers (data load / step / sync) plus an optional
-  ``jax.profiler`` trace of the window, which exposes the true per-op
-  schedule in TensorBoard / Perfetto — strictly more detail than the
-  reference's hand-rolled instruction timers;
+  ``jax.profiler`` trace of the window (started and stopped through
+  ``obs.start_capture`` / ``stop_capture``, so the trainer's ``step.*``
+  spans lie on its host plane), which exposes the true per-op schedule
+  in TensorBoard / Perfetto — strictly more detail than the reference's
+  hand-rolled instruction timers;
 - observations are written as one JSON, feeding the pipeline schedule
   simulator (parallel/pipeline_schedule.py) exactly like the reference's
   profile JSON feeds its SimulationEngine (base.py:276-595).
@@ -29,6 +31,7 @@ from pydantic import Field
 
 from ..config import BaseConfig
 from ..logging import logger
+from ..obs.capture import start_capture, stop_capture
 
 
 class ProfilerConfig(BaseConfig):
@@ -97,9 +100,9 @@ class Profiler:
             and step == c.profile_start_at_step
             and not self._tracing
         ):
-            trace_dir = Path(c.profiler_output).parent / "xla_trace"
-            trace_dir.mkdir(parents=True, exist_ok=True)
-            jax.profiler.start_trace(str(trace_dir))
+            # through the one control (obs/capture.py): the step spans
+            # of the window land on the profiler's clock with it
+            start_capture(Path(c.profiler_output).parent / "xla_trace")
             self._tracing = True
 
     def record(self, step: int, durations: Dict[str, float]) -> None:
@@ -112,8 +115,8 @@ class Profiler:
         last = c.profile_start_at_step + c.profile_steps - 1
         if step == last:
             if self._tracing:
-                jax.profiler.stop_trace()
                 self._tracing = False
+                stop_capture()
             self.flush()
 
     def close(self) -> None:
@@ -124,11 +127,11 @@ class Profiler:
         calls this from its ``finally`` so partial observations land.
         Idempotent — flush rewrites the same JSON on a clean exit."""
         if self._tracing:
+            self._tracing = False
             try:
-                jax.profiler.stop_trace()
+                stop_capture()
             except RuntimeError as e:
                 logger.warning(f"could not stop in-flight XLA trace: {e!r}")
-            self._tracing = False
         self.flush()
 
     def flush(self) -> None:

@@ -216,8 +216,7 @@ def run_bench(engine, workload, time_scale: float = 1.0,
                 # healthy bench sleeping between Poisson arrivals
                 watchdog.beat(engine.tick_index)
             if engine.scheduler.has_work:
-                with span("serve.tick", step=engine.tick_index):
-                    engine.tick()
+                engine.tick()
             elif engine.draining or idx >= len(pending):
                 break
             else:
@@ -348,7 +347,7 @@ def run_fleet_bench(router, workload, time_scale: float = 1.0,
     import threading
 
     from ..logging import logger
-    from ..obs import get_registry, new_trace_id, span, trace_context
+    from ..obs import get_registry, new_trace_id, trace_context
     from ..obs.report import percentile
 
     handles = list(router.replicas)
@@ -368,9 +367,7 @@ def run_fleet_bench(router, workload, time_scale: float = 1.0,
                     with handle.lock:
                         if not eng.scheduler.has_work:
                             continue
-                        with span("serve.tick", step=eng.tick_index,
-                                  replica=handle.replica_id):
-                            eng.tick()
+                        eng.tick()
                 else:
                     time.sleep(0.001)
         except BaseException as e:  # noqa: BLE001 — re-raised below

@@ -845,78 +845,87 @@ class ServeEngine:
         width = cfg.mixed_width
         if width not in self._mixed_fns:
             self._mixed_fns[width] = self._build_mixed_fn(width)
-        n = cfg.num_slots
-        tokens = np.zeros((n, width), np.int32)
-        new_lens = np.zeros((n,), np.int32)
-        ctx = np.zeros((n,), np.int32)
-        gen0 = np.zeros((n,), np.int32)
-        tables = np.zeros((n, cfg.max_blocks_per_seq), np.int32)
-        chunk_rows = []  # (seq, start, n_real)
-        for seq in t.prefills:
-            slot = seq.slot
-            prompt = seq.resume_prompt
-            start = seq.num_cached
-            n_real = min(cfg.prefill_chunk, seq.prefill_len - start)
-            assert n_real > 0, "chunk row scheduled with nothing to prefill"
-            tokens[slot, :n_real] = prompt[start:start + n_real]
-            new_lens[slot] = n_real
-            ctx[slot] = start
-            tables[slot, :len(seq.blocks)] = seq.blocks
-            if start == seq.prefix_cached:
-                # first chunk of this admission (prefix hits start past 0)
-                self._admit_slot(seq)
-            # the chunk's last REAL position must draw with the key plain
-            # decode uses for the request's first generated token
-            gen0[slot] = len(seq.generated) - (n_real - 1)
-            chunk_rows.append((seq, start, n_real))
-        for seq in t.decodes:
-            slot = seq.slot
-            d = seq.draft
-            tokens[slot, 0] = seq.generated[-1]
-            if d:
-                tokens[slot, 1:1 + len(d)] = d
-            new_lens[slot] = 1 + len(d)
-            ctx[slot] = seq.num_cached
-            tables[slot, :len(seq.blocks)] = seq.blocks
-            gen0[slot] = len(seq.generated)
-            self._gen[slot] = len(seq.generated)
-        # inactive rows keep all-trash tables + new_len 0: their writes
-        # land in the trash block and they expose zero visible slots
-        with self._span("serve.mixed", step=self.tick_index,
-                      decodes=len(t.decodes), chunks=len(t.prefills),
-                      **self._trace_fields(t.decodes),
-                      **self._trace_fields(t.prefills, "chunk_traces")):
-            operands = self._dev((
-                tables, ctx, tokens, new_lens, self._temp, self._topp,
-                self._topk, self._reqid, gen0,
-            ))
-            sampled, new_views = self._mixed_fns[width](
-                self.inf.params, self._pool_state(), *operands,
-                self._base_key,
-            )
-            # the tick's ONE deliberate device->host pull: the sampled
-            # token grid must land on host to be emitted to callers
-            host_samples = np.asarray(sampled)  # sta: disable=STA010
-        self._absorb(new_views)
-        now = time.monotonic()
-        sw = cfg.sample_width  # sampled grid covers positions g0..g0+sw-1
-        for seq, start, n_real in chunk_rows:
-            slot = seq.slot
-            seq.num_cached = start + n_real
-            self._tables[slot] = tables[slot]
-            self._ctx[slot] = seq.num_cached
-            if not self.warmup_mode:
-                self.prefilled_tokens += n_real
-                self._counter("serve_prefill_tokens_total").inc(n_real)
-            if seq.num_cached == seq.prefill_len:
-                # original position n_real - 1, gathered at index
-                # n_real - 1 - g0 with g0 = max(n_real - sw, 0)
-                tok = int(host_samples[slot, min(n_real, sw) - 1])
-                self._tok[slot] = tok
-                self._emit_token(seq, tok, now)
-        for seq in t.decodes:
-            self._tables[seq.slot] = tables[seq.slot]
-            self._accept_speculative(seq, host_samples[seq.slot], now)
+        step = self.tick_index
+        with self._span("serve.mixed", step=step,
+                        decodes=len(t.decodes), chunks=len(t.prefills),
+                        **self._trace_fields(t.decodes),
+                        **self._trace_fields(t.prefills, "chunk_traces")):
+            with self._span("serve.mixed.build", step=step):
+                n = cfg.num_slots
+                tokens = np.zeros((n, width), np.int32)
+                new_lens = np.zeros((n,), np.int32)
+                ctx = np.zeros((n,), np.int32)
+                gen0 = np.zeros((n,), np.int32)
+                tables = np.zeros((n, cfg.max_blocks_per_seq), np.int32)
+                chunk_rows = []  # (seq, start, n_real)
+                for seq in t.prefills:
+                    slot = seq.slot
+                    prompt = seq.resume_prompt
+                    start = seq.num_cached
+                    n_real = min(cfg.prefill_chunk, seq.prefill_len - start)
+                    assert n_real > 0, \
+                        "chunk row scheduled with nothing to prefill"
+                    tokens[slot, :n_real] = prompt[start:start + n_real]
+                    new_lens[slot] = n_real
+                    ctx[slot] = start
+                    tables[slot, :len(seq.blocks)] = seq.blocks
+                    if start == seq.prefix_cached:
+                        # first chunk of this admission (prefix hits
+                        # start past 0)
+                        self._admit_slot(seq)
+                    # the chunk's last REAL position must draw with the
+                    # key plain decode uses for the request's first
+                    # generated token
+                    gen0[slot] = len(seq.generated) - (n_real - 1)
+                    chunk_rows.append((seq, start, n_real))
+                for seq in t.decodes:
+                    slot = seq.slot
+                    d = seq.draft
+                    tokens[slot, 0] = seq.generated[-1]
+                    if d:
+                        tokens[slot, 1:1 + len(d)] = d
+                    new_lens[slot] = 1 + len(d)
+                    ctx[slot] = seq.num_cached
+                    tables[slot, :len(seq.blocks)] = seq.blocks
+                    gen0[slot] = len(seq.generated)
+                    self._gen[slot] = len(seq.generated)
+                # inactive rows keep all-trash tables + new_len 0: their
+                # writes land in the trash block and they expose zero
+                # visible slots
+            with self._span("serve.mixed.dispatch", step=step):
+                operands = self._dev((
+                    tables, ctx, tokens, new_lens, self._temp, self._topp,
+                    self._topk, self._reqid, gen0,
+                ))
+                sampled, new_views = self._mixed_fns[width](
+                    self.inf.params, self._pool_state(), *operands,
+                    self._base_key,
+                )
+            with self._span("serve.mixed.wait", step=step):
+                # the tick's ONE deliberate device->host pull: the sampled
+                # token grid must land on host to be emitted to callers
+                host_samples = np.asarray(sampled)  # sta: disable=STA010
+        with self._span("serve.emit", step=step):
+            self._absorb(new_views)
+            now = time.monotonic()
+            sw = cfg.sample_width  # sampled grid covers g0..g0+sw-1
+            for seq, start, n_real in chunk_rows:
+                slot = seq.slot
+                seq.num_cached = start + n_real
+                self._tables[slot] = tables[slot]
+                self._ctx[slot] = seq.num_cached
+                if not self.warmup_mode:
+                    self.prefilled_tokens += n_real
+                    self._counter("serve_prefill_tokens_total").inc(n_real)
+                if seq.num_cached == seq.prefill_len:
+                    # original position n_real - 1, gathered at index
+                    # n_real - 1 - g0 with g0 = max(n_real - sw, 0)
+                    tok = int(host_samples[slot, min(n_real, sw) - 1])
+                    self._tok[slot] = tok
+                    self._emit_token(seq, tok, now)
+            for seq in t.decodes:
+                self._tables[seq.slot] = tables[seq.slot]
+                self._accept_speculative(seq, host_samples[seq.slot], now)
 
     def _accept_speculative(self, seq: Sequence, row_samples, now) -> None:
         """Exact speculative acceptance (Leviathan et al., arxiv
@@ -1038,6 +1047,12 @@ class ServeEngine:
             # the trace's terminal record: obs/trace.py reads e2e_s and
             # status from here and anchors the timeline's end on ts
             fields["trace"] = seq.request.trace_id
+        if seq.admitted_s is not None:
+            # arrival to the first slot, stamped by the scheduler: a
+            # request shed by its deadline while waiting never had one
+            fields["queue_wait_s"] = round(
+                seq.admitted_s - seq.request.arrival_s, 6
+            )
         if seq.first_token_s is not None:
             # a TTFT-deadline timeout never produced a first token — the
             # analyzer's percentiles must not see a fabricated sample
@@ -1073,22 +1088,56 @@ class ServeEngine:
             self._retire(seq, now, "timeout")
 
     def tick(self) -> Tick:
-        """One engine step: expire deadlines, draft speculative
-        candidates, schedule, run the fused mixed program (or the
-        legacy separate programs), retire completions, flush the
-        request journal."""
+        """One engine step, each phase a span at the place of the work
+        (docs/OBSERVABILITY.md "Span taxonomy"): ``serve.schedule``
+        (expire deadlines, draft speculative candidates, schedule),
+        ``serve.mixed`` (the fused program; or the legacy separate
+        programs' spans), ``serve.emit``, ``serve.retire`` (retire
+        completions, flush the request journal, gauges), all under the
+        ``serve.tick`` this opens itself."""
         get_fault_plan().fire("serve.tick")
+        step = self.tick_index
+        with self._span("serve.tick", step=step,
+                        **self._replica_fields) as tick_span:
+            with self._span("serve.schedule", step=step):
+                t = self._schedule_tick(step)
+            if tick_span is not None:
+                tick_span.annotate(decodes=len(t.decodes),
+                                   chunks=len(t.prefills))
+            if self.config.fused:
+                if t.prefills or t.decodes:
+                    self._run_mixed(t)
+            else:
+                chunked = self.config.prefill_chunk is not None
+                for seq in t.prefills:
+                    if chunked:
+                        self._run_prefill_chunk(seq)
+                    else:
+                        self._run_prefill(seq)
+                if t.decodes:
+                    self._run_decode(t.decodes)
+            with self._span("serve.retire", step=step):
+                self._retire_tick(t)
+        return t
+
+    def _schedule_tick(self, step: int) -> Tick:
+        """Everything a tick decides before its programs run."""
         self._expire_deadlines(time.monotonic())
         if self.config.spec_k > 0:
-            with self._span("serve.draft", step=self.tick_index):
+            with self._span("serve.draft", step=step):
                 self.scheduler.propose_drafts()
         t = self.scheduler.schedule()
+        if not self.warmup_mode:
+            for seq in t.first_admitted:
+                self._histogram("serve_queue_wait_seconds").observe(
+                    seq.admitted_s - seq.request.arrival_s
+                )
         if t.preempted:
             self._counter("serve_preemptions_total").inc(len(t.preempted))
             # a zero-width marker span: records WHICH traced requests
             # got pushed back to waiting this tick, so a trace's timeline
             # shows the preemption that explains its decode gap
-            with self._span("serve.preempt", step=self.tick_index,
+            with self._span("serve.preempt", step=step,
                             count=len(t.preempted),
                             **self._trace_fields(t.preempted)):
                 pass
@@ -1104,24 +1153,16 @@ class ServeEngine:
             # prefill rows — so their traces are the ones the copy work
             # advanced (Tick flattens the per-seq pairs; the row list is
             # the per-request attribution that survives)
-            with self._span("serve.cow", step=self.tick_index,
+            with self._span("serve.cow", step=step,
                             pairs=len(t.cow_pairs),
                             **self._trace_fields(t.prefills)):
                 self._apply_cow(t.cow_pairs)
         else:
             self._apply_cow(t.cow_pairs)
-        if self.config.fused:
-            if t.prefills or t.decodes:
-                self._run_mixed(t)
-        else:
-            chunked = self.config.prefill_chunk is not None
-            for seq in t.prefills:
-                if chunked:
-                    self._run_prefill_chunk(seq)
-                else:
-                    self._run_prefill(seq)
-            if t.decodes:
-                self._run_decode(t.decodes)
+        return t
+
+    def _retire_tick(self, t: Tick) -> None:
+        """Everything a tick settles after its tokens are out."""
         if len(t.prefills) > self.max_concurrent_prefills:
             self.max_concurrent_prefills = len(t.prefills)
         now = time.monotonic()
@@ -1145,7 +1186,6 @@ class ServeEngine:
         self.tick_index += 1
         if self.tick_index % self.config.flush_interval == 0:
             self._reg.flush_step(self.tick_index)
-        return t
 
     @property
     def spec_accept_rate(self) -> Optional[float]:

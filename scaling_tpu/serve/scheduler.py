@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import time
 from collections import deque
 from typing import Deque, Dict, List, Optional, Tuple
 
@@ -105,7 +106,11 @@ class Sequence:
     # speculative decoding: this tick's drafted candidate tokens (set by
     # propose_drafts, consumed by the engine's mixed program)
     draft: List[int] = dataclasses.field(default_factory=list)
-    # telemetry stamps (engine fills these; monotonic seconds)
+    # telemetry stamps (monotonic seconds): the scheduler stamps the
+    # FIRST time the sequence gets a slot (a re-admission after a
+    # preemption keeps it: the request's queue wait ended there); the
+    # engine fills the rest
+    admitted_s: Optional[float] = None
     first_token_s: Optional[float] = None
     finished_s: Optional[float] = None
     token_stamps: List[float] = dataclasses.field(default_factory=list)
@@ -497,12 +502,15 @@ class Tick:
     tick (the whole prompt, or ONE chunk each under chunked prefill),
     which decode, who got preempted to make room, and which shared
     blocks must be copy-on-write forked (``(src, dst)`` pool block
-    pairs the engine copies BEFORE running the tick's programs)."""
+    pairs the engine copies BEFORE running the tick's programs);
+    ``first_admitted`` are the sequences that got their first slot this
+    tick (``admitted_s`` stamped: the engine observes their queue wait)."""
 
     prefills: List[Sequence]
     decodes: List[Sequence]
     preempted: List[Sequence]
     cow_pairs: List[Tuple[int, int]] = dataclasses.field(default_factory=list)
+    first_admitted: List[Sequence] = dataclasses.field(default_factory=list)
 
 
 class ContinuousBatchingScheduler:
@@ -748,6 +756,7 @@ class ContinuousBatchingScheduler:
            remain.
         """
         preempted: List[Sequence] = []
+        first_admitted: List[Sequence] = []
         cow_pairs: List[Tuple[int, int]] = []
         chunk = self.config.prefill_chunk
         # freshly-completed full prompt blocks enter the prefix trie
@@ -880,6 +889,9 @@ class ContinuousBatchingScheduler:
                 break
             head.blocks = matched_blocks + self._take(need)
             head.slot = self._free_slots.popleft()
+            if head.admitted_s is None:
+                head.admitted_s = time.monotonic()
+                first_admitted.append(head)
             head.state = SequenceState.RUNNING
             head.num_cached = matched
             head.prefix_cached = matched
@@ -903,7 +915,7 @@ class ContinuousBatchingScheduler:
             and not (chunk is not None and self.running[slot].prefilling)
         ]
         return Tick(prefills=prefills, decodes=decodes, preempted=preempted,
-                    cow_pairs=cow_pairs)
+                    cow_pairs=cow_pairs, first_admitted=first_admitted)
 
     def _preempt_youngest(self, for_seq: Sequence,
                           preempted: List[Sequence]) -> bool:

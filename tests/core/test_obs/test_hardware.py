@@ -78,6 +78,7 @@ def test_device_memory_snapshot_cpu_safe():
     for rec in snap:
         assert rec["bytes_in_use"] >= 0
         assert rec["peak_bytes_in_use"] >= 0
+        assert rec["peak_bytes_reserved"] >= 0
         assert "platform" in rec
 
 
@@ -85,11 +86,35 @@ def test_update_hardware_gauges_sets_registry():
     reg = MetricsRegistry()
     summary = update_hardware_gauges(reg)
     assert set(summary) == {
-        "device_bytes_in_use", "device_peak_bytes_in_use", "live_arrays"
+        "device_bytes_in_use", "device_peak_bytes_in_use",
+        "device_peak_bytes_reserved", "live_arrays"
     }
     snap = reg.snapshot()["gauges"]
     assert "live_arrays" in snap
     assert any(k.startswith("device_bytes_in_use{") for k in snap)
+    assert any(k.startswith("device_peak_bytes_reserved{") for k in snap)
+
+
+def test_hardware_gauges_carry_the_reserved_high_water_mark(monkeypatch):
+    """The TPU runtime keeps ``peak_bytes_reserved`` (a running program's
+    temporaries) apart from ``peak_bytes_in_use`` (live arrays): a gauge
+    of the second alone understates the peak (PERF.md, Findings PR 24)."""
+    import jax
+
+    class Device:
+        id, platform = 0, "tpu"
+
+        def memory_stats(self):
+            return {"bytes_in_use": 5, "peak_bytes_in_use": 7,
+                    "peak_bytes_reserved": 11, "bytes_limit": 16}
+
+    monkeypatch.setattr(jax, "local_devices", lambda: [Device()])
+    (rec,) = device_memory_snapshot()
+    assert (rec["peak_bytes_in_use"], rec["peak_bytes_reserved"]) == (7, 11)
+    reg = MetricsRegistry()
+    assert update_hardware_gauges(reg)["device_peak_bytes_reserved"] == 11
+    assert reg.snapshot()["gauges"][
+        "device_peak_bytes_reserved{device=0}"] == 11
 
 
 def test_peak_table_v5e_published_values():
