@@ -68,10 +68,13 @@ SPEC_ROWS = 5        # s = spec_k + 1 at the `--spec-k 4` of docs/SERVING.md
 # such matmuls, so outputs and gradients are held to 2% of the reference's
 # largest magnitude (~5 roundings), not to float32 agreement.
 SPLASH_RTOL = 2e-2
-# the paged kernel reads bf16 (or int8 + f32 scale) blocks but computes in
-# float32 exactly like the reference, and differs only in summation order
-# over blocks (online softmax): 1e-2 of the largest magnitude covers the
-# final cast of the output to bf16 (2**-9 relative) with room for reordering.
+# the paged kernel multiplies in the pool's dtype (bf16, or int8 unpacked to
+# bf16 with the f32 scales on the products) and accumulates in float32; the
+# reference computes in float32 throughout. They differ by the probabilities'
+# rounding to bf16 before PV (2**-9 relative each, averaging down over the
+# context), the summation order over tiles (online softmax) and the final
+# cast of the output to bf16 (2**-9 relative): measured 2.6e-3 of the largest
+# magnitude on the chip (PERF.md, PR 26), held to 1e-2.
 PAGED_RTOL = 1e-2
 # logits at the 0.5B width reach magnitude ~16, where bf16's step is 2**-4;
 # the engine's paged path and generate's dense cache sum in different orders,

@@ -7,22 +7,44 @@ row's FULL block window per layer — ``pool[block_table]`` gathers
 a single token's attention runs. On a chip that is pure HBM traffic the
 MXU never sees twice: once to build the window, once to read it.
 
-This kernel removes the window. A ``PrefetchScalarGridSpec`` prefetches
-the block table so the BlockSpec ``index_map`` can address the pool
-directly: grid step ``(row, j)`` DMAs pool block ``table[row, j]`` into
-VMEM and folds it into a flash-style online softmax (running max ``m``,
-normalizer ``l``, unnormalized accumulator in f32 scratch — Dao et al.,
-arxiv 2205.14135), so each KV byte moves HBM->VMEM exactly once and no
-``(rows, window)`` buffer ever exists. Blocks past a row's context are
-skipped with ``pl.when`` (their DMA still lands, but no FLOPs run).
+This kernel removes the window. The grid is the rows; the block table is
+scalar-prefetched, the pools stay in HBM, and each row loops over its own
+TILES: ``_blocks_per_tile`` consecutive table entries (a function of the
+shapes: 32 blocks = 512 tokens at the serving shapes), fetched block by
+block with the kernel's own DMAs into one of two VMEM tile buffers, the
+next tile in flight while this one is folded into a flash-style online
+softmax (running max ``m``, normalizer ``l``, unnormalized accumulator in
+f32 scratch — Dao et al., arxiv 2205.14135). Each KV byte moves HBM->VMEM
+exactly once, no ``(rows, window)`` buffer ever exists, and what a row
+cannot see costs nothing: the loop ends at the row's last tile and the
+last tile fetches only the blocks that hold visible slots (an inactive
+row runs no tile at all).
+
+Inside a tile the work is MXU-shaped and in the pool's own dtype. The GQA
+group is folded into the matmul's rows: the caller-side wrapper lays the
+queries out per KV head as ``(s * group, h)``, position-major, so per KV
+head QK^T is ``(s * group, h) @ (h, tile)`` and PV is ``(s * group, tile)
+@ (tile, h)``, both with float32 accumulation; K and V are never cast to
+float32 nor repeated across the group. One head's ``(tile, h)`` matrix is
+a sublane-strided read of the ``(tile, n_kv, h)`` buffer (``_head_tiles``:
+32-bit words, so bf16 and int8 heads are unpacked from the words that pack
+them). The probabilities meet V in the queries' dtype (bf16 when serving;
+``l`` sums them in float32), as the splash kernel's do. Rows with at most
+``_SHORT_QUERIES`` real positions — decode rows, decode rows with drafts —
+run the same loop over their first folded rows only; prefill chunks take
+the full width. Which path a row takes is read from ``valid_len -
+q_slot_base``, the row's ``new_len``.
 
 Variants share one kernel body:
 
 - native: pool blocks arrive in the pool dtype and are attended as-is;
 - int8: pool blocks arrive quantized; the kernel dequantizes IN VMEM with
   the same per-slot-per-head ``kv_quantize_int8`` scales the pool writer
-  produced (``nn.attention.paged_scatter_kv``) — the f32 window the XLA
-  path materialized in HBM never exists here either.
+  produced (``nn.attention.paged_scatter_kv``). int8 is exact in bf16, so
+  the scales go onto the products (``q . (k * scale) = (q . k) * scale``,
+  ``p @ (v * scale) = (p * scale) @ v``); the wrapper gathers the rows'
+  scales (1/h of the window's bytes) so a tile's lie along the lanes. The
+  f32 window the XLA path materialized in HBM never exists here either.
 
 Masking follows the paged-decode contract exactly (``nn/attention.py``
 ``_paged_attention``): LOGICAL slot indices are the causal clock; slot
@@ -35,13 +57,14 @@ call because ``valid_len`` never admits them) — K/V are scattered into
 the pool by the caller before attending, and the same per-row
 ``valid_len``/``q_slot_base`` math serves every row kind, so one fused
 program covers a whole mixed tick (serve/engine.py ``_build_mixed_fn``).
-Rows past their real tokens (``new_len`` pads) produce garbage query
-outputs that the host discards; their writes land in the trash block.
+Positions past a row's real tokens (``new_len`` pads) come back finite —
+garbage or zeros — and the host discards them; their writes land in the
+trash block.
 
-Off-TPU the kernel runs with ``interpret=True`` (the whole grid executes
-as traced jax ops), so the CPU-mesh tests exercise the REAL kernel body,
-not a stand-in; the XLA gather branch stays config-selectable
-(``EngineConfig.paged_kernel = 'xla'``) as the fallback.
+Off-TPU the kernel runs with ``interpret=True`` (the whole grid, the DMAs
+and the semaphores execute as traced jax ops), so the CPU-mesh tests
+exercise the REAL kernel body, not a stand-in; the XLA gather branch stays
+config-selectable (``EngineConfig.paged_kernel = 'xla'``) as the fallback.
 """
 
 from __future__ import annotations
@@ -76,87 +99,242 @@ def paged_kernel_interpret(platform: Optional[str] = None) -> bool:
     return (platform or jax.default_backend()) != "tpu"
 
 
+# KV tokens one tile (one step of a row's loop) aims to hold: enough bytes
+# in flight to approach the HBM rate, few enough that the float32 scores of
+# one KV head stay a few hundred KiB
+_TILE_TOKENS = 512
+# VMEM the two double-buffered pool tiles (K and V) may take together
+_TILE_VMEM_BYTES = 8 << 20
+# query positions of the short-query path: a decode row has 1 real
+# position, a decode row with drafts spec_k + 1
+_SHORT_QUERIES = 8
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _blocks_per_tile(block_size: int, max_blocks: int, n_kv: int, h: int,
+                     itemsize: int) -> int:
+    """Consecutive table entries one tile takes: a function of the shapes.
+
+    The VMEM tile of a pool keeps the pool's ``(tokens, n_kv, h)`` order,
+    whose ``(n_kv, h)`` minor dims pad to whole ``(8 * 4 / itemsize, 128)``
+    memory tiles; K and V, double-buffered, are four such tiles."""
+    sublanes = 8 * max(1, 4 // itemsize)
+    block_bytes = (
+        block_size * _round_up(n_kv, sublanes) * _round_up(h, 128) * itemsize
+    )
+    by_vmem = _TILE_VMEM_BYTES // (4 * block_bytes)
+    return max(1, min(max_blocks, _TILE_TOKENS // block_size, by_vmem))
+
+
+def _unpack_head(words, i: int, packing: int):
+    """Head ``i`` of the ``packing`` a 32-bit word holds, widened in place:
+    bf16 to float32 (exact), int8 to int32."""
+    if packing == 1:
+        return words
+    if packing == 2:
+        bits = words << 16 if i == 0 else words & jnp.int32(-65536)
+        return jax.lax.bitcast_convert_type(bits, jnp.float32)
+    return (words << (24 - 8 * i)) >> 24
+
+
+def _for_each_head(k_tile, v_tile, fold) -> None:
+    """``fold(g, k, v)`` for every KV head ``g`` of two ``(tile, n_kv, h)``
+    VMEM tiles, ``k`` and ``v`` being the head's ``(tile, h)`` matrices.
+
+    Heads are the tiles' second-minor dim, so one head's rows lie ``n_kv``
+    apart: a sublane-strided load, which Mosaic has for 32-bit words only.
+    Narrower pools pack 2 (bf16) or 4 (int8) consecutive heads of a token
+    into a word, so the tile is read as words, a column of words at a time
+    (a loop unrolled when lowered: ``fold`` is traced once a packed head,
+    not once a head), and each word's heads are unpacked with shifts. A
+    head count the packing does not divide (an int8 pool sharded down to 2
+    heads) reads head by head."""
+    tile, n_kv, h = k_tile.shape
+    packing = 4 // k_tile.dtype.itemsize
+    if n_kv % packing:
+        for g in range(n_kv):
+            fold(g, k_tile[:, g, :], v_tile[:, g, :])
+        return
+    words = [buf.reshape(tile * n_kv, h) for buf in (k_tile, v_tile)]
+    if packing > 1:
+        words = [buf.bitcast(jnp.int32) for buf in words]
+    columns = n_kv // packing
+
+    def column(j, carry):
+        k_words, v_words = (
+            buf[pl.ds(j, tile, stride=columns), :] for buf in words
+        )
+        for i in range(packing):
+            fold(
+                j * packing + i,
+                _unpack_head(k_words, i, packing),
+                _unpack_head(v_words, i, packing),
+            )
+        return carry
+
+    jax.lax.fori_loop(0, columns, column, 0, unroll=True)
+
+
 def _paged_attention_kernel(
-    # scalar prefetch (available to the index_maps before the body runs)
+    # scalar prefetch (SMEM)
     tab_ref,      # (rows, max_blocks) int32 pool block ids
     valid_ref,    # (rows,) int32 valid slot count per row (ctx + new real)
     base_ref,     # (rows,) int32 slot of each row's first query token
-    # blocks (VMEM)
-    q_ref,        # (1, s, n, h)
-    k_ref,        # (1, block_size, n_kv, h) pool dtype (or int8)
-    v_ref,
-    *rest,        # [scale_k_ref, scale_v_ref,] o_ref, m_ref, l_ref, acc_ref
+    # blocks
+    q_ref,        # (1, n_kv, m, h) VMEM: queries folded per KV head
+    pool_k_ref,   # (num_blocks, block_size, n_kv, h) left in HBM
+    pool_v_ref,
+    *rest,        # [scale_k_ref, scale_v_ref,] o_ref, then the scratch
     block_size: int,
+    tile_blocks: int,
     sm_scale: float,
-    num_repeat_kv: int,
+    group: int,
+    m_short: int,
     quantized: bool,
 ):
     if quantized:
-        scale_k_ref, scale_v_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        scale_k_ref, scale_v_ref = None, None
-        o_ref, m_ref, l_ref, acc_ref = rest
+        # (1, n_kv, window) VMEM: the row's scales, one lane a slot
+        scale_k_ref, scale_v_ref, *rest = rest
+    o_ref, k_buf, v_buf, sems, m_ref, l_ref, acc_ref = rest
+    pools = ((pool_k_ref, k_buf), (pool_v_ref, v_buf))
+    _, n_kv, m_full, _ = q_ref.shape
+    tile = tile_blocks * block_size
     row = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
     valid_len = valid_ref[row]
+    base = base_ref[row]
+    num_tiles = pl.cdiv(valid_len, tile)
 
-    @pl.when(j * block_size < valid_len)
-    def _block():
-        q = q_ref[0].astype(jnp.float32)  # (s, n, h)
-        k = k_ref[0].astype(jnp.float32)  # (bs, n_kv, h)
-        v = v_ref[0].astype(jnp.float32)
-        if quantized:
-            # dequant-in-kernel: the same kv_quantize_int8 scales the pool
-            # writer produced; the f32 window never round-trips HBM
-            k = k * scale_k_ref[0].astype(jnp.float32)[..., None]
-            v = v * scale_v_ref[0].astype(jnp.float32)[..., None]
-        if num_repeat_kv > 1:
-            bs, n_kv, h = k.shape
-            k = jnp.broadcast_to(
-                k[:, :, None, :], (bs, n_kv, num_repeat_kv, h)
-            ).reshape(bs, n_kv * num_repeat_kv, h)
-            v = jnp.broadcast_to(
-                v[:, :, None, :], (bs, n_kv, num_repeat_kv, h)
-            ).reshape(bs, n_kv * num_repeat_kv, h)
-        s = q.shape[0]
-        scores = jnp.einsum("snh,knh->snk", q, k) * sm_scale  # (s, n, bs)
-        # logical slots this grid step covers, vs each query's slot
-        slot = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, block_size), 2
-        )
-        q_slot = base_ref[row] + jax.lax.broadcasted_iota(
-            jnp.int32, (s, 1, 1), 0
-        )
-        allowed = (slot < valid_len) & (slot <= q_slot)
-        scores = jnp.where(allowed, scores, -jnp.inf)
-        # online softmax: all-masked tails keep m at -inf; the safe shift
-        # avoids exp(-inf - -inf) = nan without branching
-        m_old = m_ref[...]  # (s, n)
-        m_new = jnp.maximum(m_old, scores.max(axis=-1))
-        m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
-        p = jnp.where(allowed, jnp.exp(scores - m_safe[..., None]), 0.0)
-        alpha = jnp.where(m_old == -jnp.inf, 0.0, jnp.exp(m_old - m_safe))
-        l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1)
-        acc_ref[...] = (
-            alpha[..., None] * acc_ref[...] + jnp.einsum("snk,knh->snh", p, v)
-        )
-        m_ref[...] = m_new
+    @pl.when(row == 0)
+    def _clear_v_tiles():
+        # a tile's tail past the row's last block keeps what an earlier
+        # tile left there. Its scores are masked whatever K holds; its
+        # probabilities are 0, and 0 * v is 0 once v is finite
+        v_buf[...] = jnp.zeros_like(v_buf)
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finalize():
-        l = l_ref[...]
-        # rows with zero visible slots (fully-trash inactive rows can't
-        # reach here, but keep the guard total) emit zeros, not nan
-        o_ref[0] = (
-            acc_ref[...] / jnp.where(l == 0.0, 1.0, l)[..., None]
-        ).astype(o_ref.dtype)
+    def blocks_held(t):
+        """How many blocks of tile ``t`` hold slots this row can see."""
+        return jnp.clip(
+            pl.cdiv(valid_len - t * tile, block_size), 0, tile_blocks
+        )
+
+    def block_copies(block, i, slot):
+        """The DMAs of pool block ``block`` to place ``i`` of a tile."""
+        return [
+            pltpu.make_async_copy(
+                pool.at[block],
+                buf.at[slot, pl.ds(i * block_size, block_size)],
+                sems.at[slot, which],
+            )
+            for which, (pool, buf) in enumerate(pools)
+        ]
+
+    def start_tile(t, slot):
+        def one(i, carry):
+            block = tab_ref[row, t * tile_blocks + i]
+            for copy in block_copies(block, i, slot):
+                copy.start()
+            return carry
+
+        jax.lax.fori_loop(0, blocks_held(t), one, 0)
+
+    def wait_tile(t, slot):
+        def one(i, carry):
+            # a wait counts the bytes of its shape only: any block will do
+            for copy in block_copies(0, i, slot):
+                copy.wait()
+            return carry
+
+        jax.lax.fori_loop(0, blocks_held(t), one, 0)
+
+    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(num_tiles > 0)
+    def _first():
+        start_tile(0, 0)
+
+    # operands narrower than float32 multiply exactly in one MXU pass; an
+    # ambient jax.default_matmul_precision must not ask Mosaic for more
+    precision = (
+        None if q_ref.dtype == jnp.float32 else jax.lax.Precision.DEFAULT
+    )
+
+    def attend(m_run: int):
+        """Fold every tile of the row into the first ``m_run`` folded
+        query rows' online softmax (running max, normaliser, accumulator:
+        float32)."""
+        q_slot = base + jax.lax.broadcasted_iota(
+            jnp.int32, (m_run, 1), 0
+        ) // group
+
+        def one_tile(t, carry):
+            slot = t % 2
+
+            @pl.when(t + 1 < num_tiles)
+            def _prefetch():
+                start_tile(t + 1, 1 - slot)
+
+            wait_tile(t, slot)
+            kv_slot = t * tile + jax.lax.broadcasted_iota(
+                jnp.int32, (1, tile), 1
+            )
+            allowed = (kv_slot < valid_len) & (kv_slot <= q_slot)
+            if quantized:
+                span = pl.ds(pl.multiple_of(t * tile, tile), tile)
+            def fold(g, k, v):
+                q = q_ref[0, g, :m_run, :]
+                k, v = k.astype(q.dtype), v.astype(q.dtype)
+                # (s_q * group, h) @ (h, tile): one MXU matmul a KV head
+                scores = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32, precision=precision,
+                ) * sm_scale
+                if quantized:
+                    # int8 is exact in the queries' dtype; the writer's
+                    # kv_quantize_int8 scales, one a slot, go onto the
+                    # products: q . (k * scale) = (q . k) * scale
+                    scores = scores * scale_k_ref[0, pl.ds(g, 1), span]
+                scores = jnp.where(allowed, scores, -jnp.inf)
+                # online softmax: all-masked tiles keep m at -inf; the safe
+                # shift avoids exp(-inf - -inf) = nan without branching
+                m_old = m_ref[g, :m_run]
+                m_new = jnp.maximum(
+                    m_old, scores.max(axis=-1, keepdims=True)
+                )
+                m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+                p = jnp.exp(scores - m_safe)
+                alpha = jnp.exp(m_old - m_safe)
+                l_ref[g, :m_run] = (
+                    alpha * l_ref[g, :m_run] + p.sum(axis=-1, keepdims=True)
+                )
+                if quantized:
+                    p = p * scale_v_ref[0, pl.ds(g, 1), span]
+                acc_ref[g, :m_run] = alpha * acc_ref[g, :m_run] + jnp.dot(
+                    p.astype(v.dtype), v, preferred_element_type=jnp.float32,
+                    precision=precision,
+                )
+                m_ref[g, :m_run] = m_new
+
+            _for_each_head(k_buf.at[slot], v_buf.at[slot], fold)
+            return carry
+
+        jax.lax.fori_loop(0, num_tiles, one_tile, 0)
+
+    if m_short < m_full:
+        few = valid_len - base <= _SHORT_QUERIES
+        pl.when(few)(lambda: attend(m_short))
+        pl.when(jnp.logical_not(few))(lambda: attend(m_full))
+    else:
+        attend(m_full)
+
+    l = l_ref[...]
+    # folded rows that saw no slot (an inactive row, or the rows the short
+    # path leaves out) emit zeros, not nan; the caller discards them
+    o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -179,64 +357,99 @@ def paged_decode_attention(
     scatters through ``nn.attention.paged_scatter_kv`` first — ONE pool
     writer, so kernel and XLA fallback read identical bytes)."""
     _ensure_pallas()
-    rows, s, n, h = q.shape
-    _, block_size, n_kv, _ = pool_k.shape
-    max_blocks = block_table.shape[1]
-    quantized = scale_k is not None
+    _, block_size, n_kv, h = pool_k.shape
     if interpret is None:
         interpret = paged_kernel_interpret()
     count_kernel_build("paged_attention", interpret)
+    return _paged_call(
+        q, pool_k, pool_v, block_table, valid_len, q_slot_base,
+        scale_k, scale_v, sm_scale=float(sm_scale), group=num_repeat_kv,
+        tile_blocks=_blocks_per_tile(
+            block_size, block_table.shape[1], n_kv, h, pool_k.dtype.itemsize
+        ),
+        interpret=interpret,
+    )
 
-    def _row(bi, j, tab, valid, base):
-        del j, tab, valid, base
+
+# jitted on its own: a model calls the kernel once a layer with the same
+# shapes, and tracing the body (two query paths, the DMA loops) is slow
+# Python — done once here, not once a layer, and lowered as one function
+@functools.partial(
+    jax.jit, static_argnames=("sm_scale", "group", "tile_blocks", "interpret")
+)
+def _paged_call(
+    q, pool_k, pool_v, block_table, valid_len, q_slot_base, scale_k, scale_v,
+    *, sm_scale: float, group: int, tile_blocks: int, interpret: bool,
+):
+    rows, s, n, h = q.shape
+    _, block_size, n_kv, _ = pool_k.shape
+    max_blocks = block_table.shape[1]
+    assert n == n_kv * group, (n, n_kv, group)
+    quantized = scale_k is not None
+    tile = tile_blocks * block_size
+    # fold the GQA group into the matmul's rows: per KV head the queries are
+    # (s_pad * group, h), position-major, so the first positions of a row
+    # are the first folded rows. 16 rows fill a packed bf16 register.
+    s_pad = _round_up(s, 8)
+    m_full = s_pad * group
+    m_short = min(m_full, _round_up(_SHORT_QUERIES * group, 16))
+    folded = jnp.pad(q, ((0, 0), (0, s_pad - s), (0, 0), (0, 0)))
+    folded = folded.reshape(rows, s_pad, n_kv, group, h)
+    folded = folded.transpose(0, 2, 1, 3, 4).reshape(rows, n_kv, m_full, h)
+
+    def _row(bi, *_):  # the row's block; the prefetched scalars play no part
         return (bi, 0, 0, 0)
 
-    def _blk(bi, j, tab, valid, base):
-        del valid, base
-        return (tab[bi, j], 0, 0, 0)
-
-    def _blk_scale(bi, j, tab, valid, base):
-        del valid, base
-        return (tab[bi, j], 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, s, n, h), _row),
-        pl.BlockSpec((1, block_size, n_kv, h), _blk),
-        pl.BlockSpec((1, block_size, n_kv, h), _blk),
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec((1, n_kv, m_full, h), _row), in_hbm, in_hbm]
+    scratch = [
+        pltpu.VMEM((2, tile, n_kv, h), pool_k.dtype),
+        pltpu.VMEM((2, tile, n_kv, h), pool_v.dtype),
     ]
+    operands = [folded, pool_k, pool_v]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, block_size, n_kv), _blk_scale),
-            pl.BlockSpec((1, block_size, n_kv), _blk_scale),
-        ]
+        # the rows' scales, gathered (1/h of the int8 window's bytes) and
+        # turned slot-minor, so a tile's are one lane-dense (1, tile) strip
+        window = pl.cdiv(max_blocks, tile_blocks) * tile
+        for scale in (scale_k, scale_v):
+            scale = scale[block_table].reshape(rows, -1, n_kv)
+            scale = jnp.pad(
+                scale, ((0, 0), (0, window - scale.shape[1]), (0, 0))
+            )
+            operands.append(scale.transpose(0, 2, 1))
+            in_specs.append(
+                pl.BlockSpec((1, n_kv, window), lambda bi, *_: (bi, 0, 0))
+            )
+    scratch += [
+        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.VMEM((n_kv, m_full, 1), jnp.float32),   # running max m
+        pltpu.VMEM((n_kv, m_full, 1), jnp.float32),   # normalizer l
+        pltpu.VMEM((n_kv, m_full, h), jnp.float32),   # unnormalized acc
+    ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(rows, max_blocks),
+        grid=(rows,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, s, n, h), _row),
-        scratch_shapes=[
-            pltpu.VMEM((s, n), jnp.float32),      # running max m
-            pltpu.VMEM((s, n), jnp.float32),      # normalizer l
-            pltpu.VMEM((s, n, h), jnp.float32),   # unnormalized accumulator
-        ],
+        out_specs=pl.BlockSpec((1, n_kv, m_full, h), _row),
+        scratch_shapes=scratch,
     )
     kernel = functools.partial(
         _paged_attention_kernel,
-        block_size=block_size, sm_scale=sm_scale,
-        num_repeat_kv=num_repeat_kv, quantized=quantized,
+        block_size=block_size, tile_blocks=tile_blocks, sm_scale=sm_scale,
+        group=group, m_short=m_short, quantized=quantized,
     )
-    operands = [q, pool_k, pool_v]
-    if quantized:
-        operands += [scale_k, scale_v]
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(folded.shape, q.dtype),
         interpret=interpret,
         name="paged_attention",  # the trace's and the HLO's name for it
     )(
         block_table.astype(jnp.int32),
-        valid_len.astype(jnp.int32),
+        # a row sees no slot past its table
+        jnp.minimum(valid_len.astype(jnp.int32), max_blocks * block_size),
         q_slot_base.astype(jnp.int32),
         *operands,
     )
+    out = out.reshape(rows, n_kv, s_pad, group, h).transpose(0, 2, 1, 3, 4)
+    return out.reshape(rows, s_pad, n, h)[:, :s]

@@ -13,7 +13,7 @@ wholesale (docs/TUNING.md):
   vs training's 6; plus the attention window term), divided over the
   replica's mp shards at the calibrated efficiency of the generation's
   peak. Small blocks pay a per-block streaming overhead in the paged
-  kernel (one grid step per block: ``1 + PAGED_BLOCK_OVERHEAD /
+  kernel (one DMA per block and pool: ``1 + PAGED_BLOCK_OVERHEAD /
   block_size``); large blocks pay internal fragmentation instead (a
   sequence wastes half a block on average), priced in memory.
 - **comm**: mp > 1 costs the SAME Megatron activation all-reduces
@@ -53,10 +53,14 @@ from .costmodel import (
 )
 from .layouts import Layout, ModelSpec
 
-# paged-kernel streaming overhead: one grid step per KV block — fixed
-# per-block cost (DMA issue, mask math) expressed in token-equivalents,
-# so cost multiplies by (1 + OVERHEAD / block_size). Small blocks pack
-# the pool tighter but pay more grid steps; the sweep prices the trade.
+# paged-kernel streaming overhead: a fixed per-block cost expressed in
+# token-equivalents, so cost multiplies by (1 + OVERHEAD / block_size).
+# Small blocks pack the pool tighter but pay more of it; the sweep prices
+# the trade. STALE since ISSUE 26: the value was sized for a kernel that
+# took one grid step (and its mask math) per KV block; today a grid step
+# is a row, a tile holds 512 tokens whatever the block size, and what a
+# block still costs is one DMA issue per pool. The constant (and the
+# tuner's goldens) wait for a measured block_size sweep (ROADMAP S1).
 PAGED_BLOCK_OVERHEAD = 4.0
 
 # steady-state KV residency per slot of token budget: the pool must hold

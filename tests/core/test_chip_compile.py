@@ -28,7 +28,6 @@ HEAD_DIM = 128
 # the serve phase of chip_smoke.py: 8 slots x 4k context in blocks of 16,
 # plus the trash block; 4 KV heads under 16 query heads
 SLOTS, BLOCK_SIZE, MAX_BLOCKS, KV_HEADS, Q_HEADS = 8, 16, 256, 4, 16
-POOL = (SLOTS * MAX_BLOCKS + 1, BLOCK_SIZE, KV_HEADS, HEAD_DIM)
 
 
 @pytest.fixture(scope="module")
@@ -105,28 +104,34 @@ def test_splash_survives_shard_map_on_2x2(topo):
 @pytest.mark.parametrize(
     "s", [1, 32, 5], ids=["decode-s1", "prefill-chunk-s32", "spec-s5"]
 )
-def test_paged_kernel_compiles(one_chip, s, kv_dtype):
+@pytest.mark.parametrize(
+    "slots,q_heads,kv_heads",
+    [(SLOTS, Q_HEADS, KV_HEADS), (16, 32, 8), (16, 36, 4)],
+    ids=["smoke-16q4kv", "serve-cell-32q8kv", "pharia-36q4kv-group9"],
+)
+def test_paged_kernel_compiles(one_chip, slots, q_heads, kv_heads, s, kv_dtype):
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     quantized = kv_dtype == "int8"
-    pool = shape(POOL, jnp.int8 if quantized else jnp.bfloat16)
+    pool_dims = (slots * MAX_BLOCKS + 1, BLOCK_SIZE, kv_heads, HEAD_DIM)
+    pool = shape(pool_dims, jnp.int8 if quantized else jnp.bfloat16)
     scales = (
-        {"scale_k": shape(POOL[:3], jnp.float32),
-         "scale_v": shape(POOL[:3], jnp.float32)}
+        {"scale_k": shape(pool_dims[:3], jnp.float32),
+         "scale_v": shape(pool_dims[:3], jnp.float32)}
         if quantized else {}
     )
 
     def attend(q, pool_k, pool_v, table, valid_len, base, scales):
         return paged_decode_attention(
             q, pool_k, pool_v, table, valid_len, base,
-            sm_scale=HEAD_DIM ** -0.5, num_repeat_kv=Q_HEADS // KV_HEADS,
+            sm_scale=HEAD_DIM ** -0.5, num_repeat_kv=q_heads // kv_heads,
             interpret=False, **scales,
         )
 
     compiled = jax.jit(attend).lower(
-        shape((SLOTS, s, Q_HEADS, HEAD_DIM), jnp.bfloat16), pool, pool,
-        shape((SLOTS, MAX_BLOCKS), jnp.int32), shape((SLOTS,), jnp.int32),
-        shape((SLOTS,), jnp.int32), scales,
+        shape((slots, s, q_heads, HEAD_DIM), jnp.bfloat16), pool, pool,
+        shape((slots, MAX_BLOCKS), jnp.int32), shape((slots,), jnp.int32),
+        shape((slots,), jnp.int32), scales,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()

@@ -3,7 +3,10 @@ the streaming online-softmax kernel (nn/paged_attention.py, interpret
 mode on the CPU mesh) against a straight dense reference that gathers
 the block window and softmaxes it whole — native and int8-dequant-in-
 kernel, single decode tokens and multi-token prefill chunks, GQA
-repeat, and the all-trash inactive row."""
+repeat, and the all-trash inactive row. ISSUE 26 re-tiled the kernel
+(several pool blocks a tile, the GQA group folded into the matmul's
+rows, a short-query path); its cases force several tiles a row by
+shrinking the tile the shapes would derive."""
 
 import numpy as np
 import pytest
@@ -11,6 +14,7 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
+from scaling_tpu.nn import paged_attention  # noqa: E402
 from scaling_tpu.nn.attention import kv_quantize_int8  # noqa: E402
 from scaling_tpu.nn.paged_attention import (  # noqa: E402
     paged_decode_attention,
@@ -23,7 +27,7 @@ def dense_reference(q, pool_k, pool_v, tab, valid_len, base, n_rep):
     """Gather-the-window attention, mirroring the XLA fallback's masking
     discipline (slot < valid_len, slot <= q_slot)."""
     b, s, n, h = q.shape
-    window = MAXB * BS
+    window = tab.shape[1] * pool_k.shape[1]
     gk = pool_k[tab].reshape(b, window, -1, h)
     gv = pool_v[tab].reshape(b, window, -1, h)
     if n_rep > 1:
@@ -39,7 +43,7 @@ def dense_reference(q, pool_k, pool_v, tab, valid_len, base, n_rep):
     allowed = (slots_k[:, None, :] < valid_len[:, None, None]) & (
         slots_k[:, None, :] <= slots_q[:, :, None]
     )
-    scores = jnp.einsum("bqnh,bknh->bnqk", q, gk) * H ** -0.5
+    scores = jnp.einsum("bqnh,bknh->bnqk", q, gk) * h ** -0.5
     scores = jnp.where(allowed[:, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bnqk,bknh->bqnh", probs, gv)
@@ -125,3 +129,170 @@ def test_kernel_respects_new_token_visibility():
     np.testing.assert_allclose(
         np.asarray(out1[:, 0]), np.asarray(out2[:, 0]), atol=1e-5
     )
+
+
+# ---- ISSUE 26: the re-tiled kernel. Rows own consecutive pool blocks; a
+# tile of `tile_tokens` is forced through the module's target, so a few
+# dozen tokens of context already span several tiles.
+
+def tiled_case(rng, *, block_size, max_blocks, n_kv, group, s, ctx, new_len,
+               dtype=jnp.float32, h=H):
+    rows = len(ctx)
+    pool_shape = (rows * max_blocks + 1, block_size, n_kv, h)
+    pool_k = jnp.asarray(rng.normal(size=pool_shape), dtype)
+    pool_v = jnp.asarray(rng.normal(size=pool_shape), dtype)
+    ctx, new_len = np.asarray(ctx, np.int32), np.asarray(new_len, np.int32)
+    tab = 1 + np.arange(rows * max_blocks, dtype=np.int32).reshape(
+        rows, max_blocks)
+    for r in range(rows):  # blocks past the row's slots are trash
+        tab[r, -(-int(ctx[r] + new_len[r]) // block_size):] = 0
+    q = jnp.asarray(rng.normal(size=(rows, s, n_kv * group, h)), dtype)
+    return (q, pool_k, pool_v, jnp.asarray(tab), jnp.asarray(ctx),
+            jnp.asarray(new_len))
+
+
+def check_rows(q, pool_k, pool_v, tab, ctx, new_len, group, **kernel_kwargs):
+    """Run the kernel; every position, padded ones too, must be finite."""
+    h = q.shape[-1]
+    out = paged_decode_attention(
+        q, pool_k, pool_v, tab, ctx + new_len, ctx,
+        sm_scale=h ** -0.5, num_repeat_kv=group, interpret=True,
+        **kernel_kwargs,
+    )
+    assert out.shape == q.shape and out.dtype == q.dtype
+    assert bool(jnp.all(jnp.isfinite(out.astype(jnp.float32))))
+    return out
+
+
+def assert_real_positions(out, ref, new_len, atol):
+    for row, real in enumerate(np.asarray(new_len)):
+        np.testing.assert_allclose(
+            np.asarray(out[row, :real], np.float32),
+            np.asarray(ref[row, :real], np.float32), atol=atol,
+            err_msg=f"row {row} ({real} real positions)",
+        )
+
+
+@pytest.mark.parametrize("group", [4, 9])
+@pytest.mark.parametrize("s", [1, 5, 16])
+def test_gqa_group_folds_into_the_matmul_rows(monkeypatch, group, s):
+    """Group 9 (Pharia: 36 q / 4 KV heads) makes the folded rows no
+    multiple of the sublane count; s = 5 (drafts) pads the positions."""
+    monkeypatch.setattr(paged_attention, "_TILE_TOKENS", 8)
+    rng = np.random.default_rng(10)
+    q, pk, pv, tab, ctx, new = tiled_case(
+        rng, block_size=4, max_blocks=6, n_kv=2, group=group, s=s,
+        ctx=[0, 7, 24 - s], new_len=[s, s, s],
+    )
+    out = check_rows(q, pk, pv, tab, ctx, new, group)
+    ref = dense_reference(q, pk, pv, tab, ctx + new, ctx, group)
+    assert_real_positions(out, ref, new, 1e-5)
+
+
+@pytest.mark.parametrize(
+    "valid", [6, 8, 9, 15, 16, 17],
+    ids=["mid-block", "on-tile-boundary", "one-past-boundary",
+         "last-slot-of-tile-2", "on-boundary-2", "one-past-boundary-2"],
+)
+def test_context_ends_around_a_tile_boundary(monkeypatch, valid):
+    """Tiles of 8 tokens (2 blocks of 4): the decode token's slot is the
+    context's last, so `valid` walks it across the tile's edge."""
+    monkeypatch.setattr(paged_attention, "_TILE_TOKENS", 8)
+    rng = np.random.default_rng(11)
+    q, pk, pv, tab, ctx, new = tiled_case(
+        rng, block_size=4, max_blocks=5, n_kv=2, group=2, s=1,
+        ctx=[valid - 1, 0], new_len=[1, 1],
+    )
+    out = check_rows(q, pk, pv, tab, ctx, new, 2)
+    ref = dense_reference(q, pk, pv, tab, ctx + new, ctx, 2)
+    assert_real_positions(out, ref, new, 1e-5)
+
+
+@pytest.mark.parametrize("kv_dtype", ["native", "int8"])
+@pytest.mark.parametrize(
+    "block_size,max_blocks,tile_tokens",
+    [(4, 5, 8), (16, 3, 32), (4, 7, 12)],
+    ids=["bs4-5blocks-by2", "bs16-3blocks-by2", "bs4-7blocks-by3"],
+)
+def test_one_call_mixes_every_row_kind(
+    monkeypatch, block_size, max_blocks, tile_tokens, kv_dtype
+):
+    """One mixed tick: a decode row (1 real position), a draft row (5), a
+    chunk row (the full width), a chunk row shorter than the width, and
+    an all-trash row; `max_blocks` is no multiple of the blocks a tile
+    takes, so the last tile of a full row is cut short by the table."""
+    monkeypatch.setattr(paged_attention, "_TILE_TOKENS", tile_tokens)
+    assert max_blocks % paged_attention._blocks_per_tile(
+        block_size, max_blocks, 2, H, 4) != 0
+    s, window = 16, block_size * max_blocks
+    rng = np.random.default_rng(12)
+    new = [1, 5, s, s - 3, 0]
+    ctx = [window - 1, window // 2, window - s, 3, 0]
+    q, pk, pv, tab, ctx, new = tiled_case(
+        rng, block_size=block_size, max_blocks=max_blocks, n_kv=2, group=2,
+        s=s, ctx=ctx, new_len=new,
+    )
+    if kv_dtype == "int8":
+        qk, sk = kv_quantize_int8(pk)
+        qv, sv = kv_quantize_int8(pv)
+        out = check_rows(q, qk, qv, tab, ctx, new, 2, scale_k=sk, scale_v=sv)
+        pk = qk.astype(jnp.float32) * sk[..., None]
+        pv = qv.astype(jnp.float32) * sv[..., None]
+    else:
+        out = check_rows(q, pk, pv, tab, ctx, new, 2)
+    ref = dense_reference(q, pk, pv, tab, ctx + new, ctx, 2)
+    assert_real_positions(out, ref, new, 1e-5)
+    # the all-trash row comes back as zeros, and so does a decode row past
+    # its first positions: that row took the short-query path
+    assert not bool(jnp.any(out[4]))
+    assert not bool(jnp.any(out[0, paged_attention._SHORT_QUERIES:]))
+
+
+@pytest.mark.parametrize("dtype,n_kv", [
+    (jnp.bfloat16, 2), (jnp.bfloat16, 4), (jnp.bfloat16, 3),
+    (jnp.int8, 4), (jnp.int8, 8), (jnp.int8, 2),
+], ids=["bf16-2kv", "bf16-4kv", "bf16-3kv-unpacked",
+        "int8-4kv", "int8-8kv", "int8-2kv-unpacked"])
+def test_narrow_pools_unpack_their_heads_from_words(monkeypatch, dtype, n_kv):
+    """bf16 and int8 pools are read as 32-bit words holding 2 or 4
+    consecutive heads of a token and unpacked with shifts; a head count
+    the packing does not divide takes the plain per-head read."""
+    monkeypatch.setattr(paged_attention, "_TILE_TOKENS", 8)
+    rng = np.random.default_rng(13)
+    q, pk, pv, tab, ctx, new = tiled_case(
+        rng, block_size=4, max_blocks=5, n_kv=n_kv, group=2, s=4,
+        ctx=[13, 2, 0], new_len=[4, 1, 0], dtype=jnp.bfloat16,
+    )
+    if dtype == jnp.int8:
+        qk, sk = kv_quantize_int8(pk)
+        qv, sv = kv_quantize_int8(pv)
+        out = check_rows(q, qk, qv, tab, ctx, new, 2, scale_k=sk, scale_v=sv)
+        pk = qk.astype(jnp.float32) * sk[..., None]
+        pv = qv.astype(jnp.float32) * sv[..., None]
+    else:
+        out = check_rows(q, pk, pv, tab, ctx, new, 2)
+    ref = dense_reference(
+        q.astype(jnp.float32), pk.astype(jnp.float32),
+        pv.astype(jnp.float32), tab, ctx + new, ctx, 2,
+    )
+    # bf16 queries, probabilities and output: 2**-8 of the values' scale
+    assert_real_positions(out, ref, new, 3e-2)
+
+
+@pytest.mark.parametrize(
+    "block_size,max_blocks,n_kv,h,itemsize,expect",
+    [
+        (16, 256, 8, 128, 2, 32),   # the serve cell: 512 tokens a tile
+        (16, 256, 4, 128, 2, 32),   # Pharia: 4 KV heads pad to 8 sublanes
+        (16, 256, 8, 128, 1, 32),   # int8 pools
+        (16, 256, 32, 128, 4, 8),   # float32, 32 KV heads: VMEM decides
+        (4, 4, 2, 16, 4, 4),        # the CPU tests' pool: the whole table
+        (16, 3, 8, 128, 2, 3),
+        (1024, 8, 8, 128, 2, 1),    # a block larger than the target
+    ],
+)
+def test_blocks_per_tile_is_a_function_of_the_shapes(
+    block_size, max_blocks, n_kv, h, itemsize, expect
+):
+    assert paged_attention._blocks_per_tile(
+        block_size, max_blocks, n_kv, h, itemsize) == expect
