@@ -1,8 +1,8 @@
-"""From a configuration file to the program's model: config, weights, the
-reference's view of the same weights, and parameter counts.
+"""From a configuration file to the program's model: its config, its
+weights and optimizer state made on the device, parameter shapes and counts.
 
-This is the only place the benchmark names fields of ``scaling_tpu``'s
-config or leaves of its parameter tree.
+What depends on the architecture (the reference's view of the weights, the
+operations a trained token requires) is in ``views/``, one file a reference.
 """
 
 from __future__ import annotations
@@ -99,42 +99,30 @@ def count_params(shapes) -> int:
     return sum(math.prod(x.shape) for x in jax.tree.leaves(shapes))
 
 
-def matmul_param_count(shapes) -> int:
-    """Parameters that take part in a matrix multiplication: all but the
-    input embedding table (a lookup)."""
-    return count_params(shapes) - count_params(shapes["layer_0"])
+def engine_config(engine: dict):
+    """The program's ``EngineConfig`` for a configuration's ``"engine"``
+    object. ``num_slots`` and ``context`` (tokens a slot may hold) size the
+    KV pool: ``context // block_size`` blocks a sequence, that times the
+    slots plus the trash block in all. Every further key is a field of
+    ``EngineConfig`` by name and overrides what was derived (a pool sized
+    from the traffic, window pools, int8); one it does not have is an error
+    that names it."""
+    import dataclasses
 
+    from scaling_tpu.serve.engine import EngineConfig
 
-def reference_spec(arch: dict) -> dict:
-    return {
-        "num_heads": arch["num_attention_heads"],
-        "num_kv_heads": arch.get("attention_num_kv_heads") or arch["num_attention_heads"],
-        "head_dim": arch["hidden_size"] // arch["num_attention_heads"],
-        "norm": "rms" if arch["norm_type"] == "rms" else "layernorm",
-        "mlp": "swiglu" if arch["mlp_type"] == "swiglu" else "gelu",
-        "eps": arch.get("layernorm", {}).get("layernorm_epsilon", 1e-5),
-        "rope_base": float(arch.get("rotary_embedding_base", 10000)),
-    }
-
-
-def reference_weights(params: dict, num_layers: int) -> dict:
-    """The program's parameter tree in the reference's plain layout (same
-    arrays, no copy, no cast). Layout of the tree: ``layer_0`` embedding,
-    ``layer_1..L`` blocks, ``layer_{L+1}`` final norm, ``layer_{L+2}`` head."""
-    def block(p):
-        attn, mlp = p["attention"], p["mlp"]
-        out = {"norm1": p["input_layernorm"], "norm2": p["post_attention_layernorm"],
-               "q": attn["query"], "k": attn["key"], "v": attn["value"],
-               "o": attn["dense"]}
-        if "gate_proj" in mlp:
-            out.update(gate=mlp["gate_proj"], up=mlp["up_proj"], down=mlp["down_proj"])
-        else:
-            out.update({"in": mlp["dense_in"], "out": mlp["dense_out"]})
-        return out
-
-    return {
-        "embedding": params["layer_0"]["embedding"]["weight"],
-        "layers": [block(params[f"layer_{i}"]) for i in range(1, num_layers + 1)],
-        "final_norm": params[f"layer_{num_layers + 1}"]["norm"],
-        "head": params[f"layer_{num_layers + 2}"]["linear"]["weight"],
-    }
+    given = dict(engine)
+    slots, context = int(given.pop("num_slots")), int(given.pop("context"))
+    fields = sorted(f.name for f in dataclasses.fields(EngineConfig))
+    unknown = sorted(set(given) - set(fields))
+    if unknown:
+        raise SystemExit(
+            f"benchmark: the configuration's \"engine\" has {unknown}, which "
+            f"EngineConfig does not (known besides num_slots and context: {fields})")
+    blocks_per_seq = context // int(given.get("block_size", EngineConfig().block_size))
+    return EngineConfig(**{
+        "num_slots": slots,
+        "num_blocks": slots * blocks_per_seq + 1,  # + the trash block
+        "max_blocks_per_seq": blocks_per_seq,
+        **given,
+    })

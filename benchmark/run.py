@@ -12,7 +12,8 @@ under ``--trace 0``, its numbers are taken, and only then are a few seconds
 more of the same traffic traced, in the same process. Without a TPU, or with
 fewer chips than the cell asks for, it exits non-zero and prints no result.
 ``--rehearse`` walks the same code on the CPU (kernels interpreted) and never
-prints a result line.
+prints a result line. ``--control fp8`` (never the driver's) also reads what
+the reference in that lower precision would give: ``benchmark/control.py``.
 """
 
 from __future__ import annotations
@@ -50,6 +51,9 @@ def parse(argv):
     p.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     p.add_argument("--rehearse", action="store_true",
                    help="CPU walk-through, kernels interpreted; prints no result")
+    p.add_argument("--control", default=None,
+                   help="also read what the reference in this lower precision "
+                        "would give (benchmark/control.py); never the driver's")
     p.add_argument("--benchmark-json", type=Path,
                    default=cells.REPO / "BENCHMARK.json", help=argparse.SUPPRESS)
     p.add_argument("--root", type=Path, default=cells.ROOT,
@@ -84,7 +88,7 @@ def run_cell(args) -> dict:
 
     mark("JAX has the device")
     env = {"t0": T0, "compiles": dev.CompileCounter(), "tracer": tracer,
-           "mark": mark}
+           "mark": mark, "control": args.control}
     outcome = kinds()[cell.kind](cell, args, env)
     tracer.maybe_stop(force=True)
 
@@ -114,8 +118,13 @@ def run_cell(args) -> dict:
         else:
             device_out["busy_s"] = reduction["busy_s"]
             device_out["window_s"] = reduction["window_s"]
-            result["breakdown"] = {"device_ops": reduction["top_ops"],
-                                   "idle_gaps": reduction["idle_gaps"]}
+            # a list holds ten entries: the five largest sums by stem, then
+            # the five largest single operations; stderr has ten of each
+            for name, seconds in reduction["top_stems"] + reduction["top_ops"]:
+                print(f"[trace] {seconds:9.6f} s  {name}", file=sys.stderr)
+            result["breakdown"] = {
+                "device_ops": reduction["top_stems"][:5] + reduction["top_ops"][:5],
+                "idle_gaps": reduction["idle_gaps"]}
         ctx = {
             "cell": cell.name, "kind": cell.kind, "chips": cell.chips,
             "config": cell.config, "traffic": traffic, "host": outcome["host"],

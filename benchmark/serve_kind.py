@@ -34,6 +34,14 @@ LOGIT_TOL = 0.05
 # and its gaps so far count, and its tokens so far are checked like any
 # other's. One that has no first token by then has failed.
 DRAIN_CAP_S = 20.0
+# How the window ends is the traffic's: ``"backlog": "fail"`` (the default)
+# is the rule above, for traffic the engine is meant to keep up with.
+# ``"cut"`` is for traffic offered above what the engine sustains, where a
+# growing queue is the design: a counted request that the engine had not
+# TAKEN (given a slot) when arrivals stopped is ``unserved``, neither failed
+# nor checked, and the run goes on only until every request taken by then
+# has its first token. Everything the engine took is held to the rule above.
+BACKLOGS = ("fail", "cut")
 # --trace 2: when the window's numbers are taken, arrivals resume at the
 # cell's rate and the capture starts once a tick carries a prefill chunk,
 # or after this long at the latest: the traced part is then the cell's mix
@@ -50,44 +58,43 @@ def build_engine(cell, seed: int):
 
     from scaling_tpu.models.transformer.inference import TransformerInferenceModule
     from scaling_tpu.models.transformer.model import init_model
-    from scaling_tpu.serve.engine import EngineConfig, ServeEngine
+    from scaling_tpu.serve.engine import ServeEngine
 
     from . import model
 
+    engine_config = model.engine_config(cell.config["engine"])
     config = model.transformer_config(cell.config, {})
     module = init_model(config, None)
     params = model.init_weights(module, seed)
     inf = TransformerInferenceModule(config, module, params)
-    slots, context = (int(cell.config["engine"][k]) for k in ("num_slots", "context"))
-    blocks_per_seq = context // EngineConfig().block_size
-    engine = ServeEngine(inf, EngineConfig(
-        num_slots=slots,
-        num_blocks=slots * blocks_per_seq + 1,  # + the trash block
-        max_blocks_per_seq=blocks_per_seq,
-    ))
+    engine = ServeEngine(inf, engine_config)
     jax.block_until_ready(params)
     return config, inf, engine
 
 
 def check_against_reference(cell, inf, done, seed: int, count: int,
-                            max_tokens: int, max_outputs: int):
+                            max_tokens: int, max_outputs: int, control=None):
     """A seeded sample of ``done`` (request, its tokens as the window
     closed), the engine's tokens teacher-forced through the plain reference:
     (positions compared, largest gap of an engine token below the reference's
-    best logit). Every request is padded to the same
-    ``max_tokens`` and ``max_outputs`` (attention is causal, so padding after
-    the last token changes nothing), so the check is two programs whatever
-    the sample."""
+    best logit, the control's largest gap). Every request is padded to the
+    same ``max_tokens`` and ``max_outputs`` (attention is causal, so padding
+    after the last token changes nothing), so the check is two programs
+    whatever the sample. With ``control`` (``benchmark/control.py``) the
+    reference runs once more over the same prompts and tokens in that lower
+    precision, and the gap read is that of the token IT puts first at each
+    position; without, that gap is None."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from . import model
-    from .reference import dense_decoder as ref
+    from .control import lower_precision
 
+    ref, view = cell.reference, cell.view
     arch = cell.config["transformer_architecture"]
-    weights = model.reference_weights(inf.params, arch["num_layers"])
-    spec = model.reference_spec(arch)
+    weights = view.reference_weights(inf.params, arch)
+    spec = view.reference_spec(arch)
+    lowered = lower_precision(weights, control) if control else None
 
     @jax.jit
     def largest_gap(logits, got, valid):
@@ -97,7 +104,7 @@ def check_against_reference(cell, inf, done, seed: int, count: int,
     pool = [(r, generated) for r, generated in done
             if generated and len(r.prompt) + len(generated) <= max_tokens]
     picks = np.random.default_rng(seed).permutation(len(pool))[:count]
-    compared, worst = 0, 0.0
+    compared, worst, control_worst = 0, 0.0, 0.0 if control else None
     for i in picks:
         request, generated = pool[int(i)]
         prompt, got = request.prompt, generated[:max_outputs]
@@ -110,24 +117,37 @@ def check_against_reference(cell, inf, done, seed: int, count: int,
         padded[:len(got)] = got
         logits = ref.forward(weights, jnp.asarray(tokens), spec,
                              head_positions=jnp.asarray(positions))
-        gap = largest_gap(logits, jnp.asarray(padded),
-                          jnp.arange(max_outputs) < len(got))
+        valid = jnp.arange(max_outputs) < len(got)
+        gap = largest_gap(logits, jnp.asarray(padded), valid)
         compared += len(got)
         worst = max(worst, float(gap))
-    return compared, worst
+        if control:
+            first = ref.forward(lowered, jnp.asarray(tokens), spec,
+                                head_positions=jnp.asarray(positions)).argmax(-1)
+            control_worst = max(control_worst, float(largest_gap(logits, first, valid)))
+    return compared, worst, control_worst
 
 
-def window_numbers(submitted, t0: float, seconds: float):
+def taken_by(seq, when: float) -> bool:
+    """Whether the engine had given ``seq`` a slot by ``when`` (the
+    scheduler stamps ``admitted_s`` the first time it does)."""
+    admitted = getattr(seq, "admitted_s", None)
+    return admitted is not None and admitted < when
+
+
+def window_numbers(submitted, t0: float, seconds: float, backlog: str = "fail"):
     """What the result says of the window, taken as the run leaves it, from
     copies where the objects go on living (under ``--trace 2`` the sequences
     decode on through the traced part): output tokens stamped inside the
     window; ``done``, the counted requests that had their first token, each
     with its tokens so far; how many failed, finished, were cut while
-    decoding; the time-to-first-token and inter-token samples."""
+    decoding; the time-to-first-token and inter-token samples; and, with
+    ``backlog`` ``"cut"``, how many the engine had not taken when arrivals
+    stopped and that never had a token (``unserved``; else 0)."""
     window_end = t0 + seconds
     stamps = [s for _, seq in submitted for s in getattr(seq, "token_stamps", ())]
     tokens_in_window = sum(t0 <= s < window_end for s in stamps)
-    done, failed, finished, cut, ttft, itl = [], 0, 0, 0, [], []
+    done, failed, finished, cut, ttft, itl, unserved = [], 0, 0, 0, [], [], 0
     for r, seq in submitted:
         if not r.counted:
             continue
@@ -139,13 +159,17 @@ def window_numbers(submitted, t0: float, seconds: float):
             ok = (seq.finish_status == "completed"
                   and len(seq.generated) == r.output_len)
         if not ok:
-            failed += 1
+            waiting = (backlog == "cut" and hasattr(seq, "first_token_s")
+                       and seq.first_token_s is None
+                       and not taken_by(seq, window_end))
+            unserved += waiting
+            failed += not waiting
             continue
         done.append((r, list(seq.generated)))
         cut += seq.finished_s is None
         ttft.append(seq.first_token_s - (t0 + r.due_s))
         itl.extend(b - a for a, b in zip(seq.token_stamps, seq.token_stamps[1:]))
-    return tokens_in_window, done, failed, finished, cut, ttft, itl
+    return tokens_in_window, done, failed, finished, cut, ttft, itl, unserved
 
 
 def run(cell, args, env) -> dict:
@@ -154,19 +178,26 @@ def run(cell, args, env) -> dict:
 
     from scaling_tpu.obs import kernel_build_count
 
-    from . import traffic_gen
     from .device import live_bytes, memory_peaks
     from .stats import percentile
 
     traffic = cell.traffic
     if cell.chips != 1:
         sys.exit("benchmark: kind serve drives one engine on one chip")
+    backlog = traffic.get("backlog", "fail")
+    if backlog not in BACKLOGS:
+        sys.exit(f"benchmark: \"backlog\" is one of {BACKLOGS}, not {backlog!r}")
+    cut_backlog = backlog == "cut"
+    # the program counts its kernel builds for the whole process, so a
+    # build in the wrong mode is one made during THIS run: an earlier one (a
+    # test that compiled the kernel for a described chip) is not its path
+    wrong_before = kernel_build_count("paged_attention", interpret=not args.rehearse)
     config, inf, engine = build_engine(cell, args.seed)
     env["mark"]("weights and KV pool on the device")
     arch = config.transformer_architecture
     tracer = env["tracer"]
     trace_s = float(traffic.get("trace_seconds", 1.0))
-    requests = traffic_gen.generate(
+    requests = cell.generate(
         traffic, args.seed, args.seconds, arch.vocab_size,
         traced_seconds=TRACE_LEAD_S + trace_s if tracer.after_window else 0.0)
     after_window = [r for r in requests if r.traced]
@@ -191,7 +222,7 @@ def run(cell, args, env) -> dict:
 
     warm_s = float(traffic["warm_seconds"])
     submitted, late = [], []
-    tick_s, decode_rows, context_tokens = [], [], []
+    tick_s, tick_at, decode_rows, prefill_rows, context_tokens = [], [], [], [], []
     traced_context_tokens = 0
     idx = 0
     t0 = time.monotonic() + warm_s            # the window opens at t0
@@ -219,6 +250,8 @@ def run(cell, args, env) -> dict:
             b = time.monotonic()
             if a >= t0:
                 tick_s.append(b - a)
+                tick_at.append(a - t0)
+                prefill_rows.append(len(tick.prefills))
                 decode_rows.append(len(tick.decodes))
                 context_tokens.append(sum(
                     s.num_cached for s in tick.decodes + tick.prefills))
@@ -228,7 +261,9 @@ def run(cell, args, env) -> dict:
             if b > end_of_arrivals and idx >= len(requests) and (
                     b > end_of_arrivals + DRAIN_CAP_S
                     or all(seq.first_token_s is not None
-                           for r, seq in submitted if r.counted)):
+                           for r, seq in submitted if r.counted and (
+                               not cut_backlog
+                               or taken_by(seq, end_of_arrivals)))):
                 break
         elif idx >= len(requests):
             break
@@ -242,10 +277,11 @@ def run(cell, args, env) -> dict:
     compiles_in_window = env["compiles"].count - compiles_before
 
     # -- the window's numbers, fixed here whatever runs afterwards
-    tokens_in_window, done, failed, finished, cut, ttft, itl = window_numbers(
-        submitted, t0, args.seconds)
+    (tokens_in_window, done, failed, finished, cut, ttft, itl,
+     unserved) = window_numbers(submitted, t0, args.seconds, backlog)
     log(f"{len(done)} of {len(counted)} counted requests had their first token "
-        f"({finished} finished, {cut} cut while decoding, {failed} failed), ran "
+        f"({finished} finished, {cut} cut while decoding, {failed} failed, "
+        f"{unserved} unserved), ran "
         f"{drained_s:.1f} s past the last arrival with "
         f"{len(engine.scheduler.waiting)} waiting; ttft p50 "
         f"{1e3 * percentile(ttft, 50) if ttft else -1:.0f} ms, p95 "
@@ -253,6 +289,18 @@ def run(cell, args, env) -> dict:
         f"{len(ttft)} time-to-first-token and {len(itl)} inter-token samples; "
         f"{len(tick_s)} ticks; preemptions {engine.scheduler.preemption_count}; "
         f"{compiles_in_window} program(s) lowered in the window")
+    if tick_s:  # what a run-to-run difference of the tokens completed is made of
+        p50 = percentile(tick_s, 50)
+        slow = sorted((i for i, x in enumerate(tick_s) if x > 1.5 * p50),
+                      key=lambda i: -tick_s[i])
+        log(f"ticks: mean {1e3 * sum(tick_s) / len(tick_s):.3f} ms, p50 {1e3 * p50:.3f}, "
+            f"p99 {1e3 * percentile(tick_s, 99):.3f}; {sum(tick_s):.2f} s inside ticks, "
+            f"{tick_at[-1] + tick_s[-1] - sum(tick_s):.2f} s between them; decode rows a "
+            f"tick {sum(decode_rows) / len(decode_rows):.3f}; {len(slow)} tick(s) over 1.5 "
+            f"x p50 holding {sum(tick_s[i] - p50 for i in slow):.3f} s more than p50, the "
+            "longest as (at s, ms, decode rows, prefill rows): " + ", ".join(
+                f"({tick_at[i]:.2f}, {1e3 * tick_s[i]:.1f}, {decode_rows[i]}, "
+                f"{prefill_rows[i]})" for i in slow[:8]))
 
     # -- --trace 2: the window's numbers are taken; the same traffic goes
     # on, uncounted, and a few seconds of it are traced. The peak is read
@@ -282,7 +330,8 @@ def run(cell, args, env) -> dict:
         env["mark"]("traced part over")
 
     builds = kernel_build_count("paged_attention", interpret=args.rehearse)
-    wrong_builds = kernel_build_count("paged_attention", interpret=not args.rehearse)
+    wrong_builds = kernel_build_count(
+        "paged_attention", interpret=not args.rehearse) - wrong_before
     num_slots = engine.config.num_slots
     pool_tokens = (engine.config.num_blocks - 1) * engine.config.block_size
     # -- correct: outside the window, with the pools' memory given back
@@ -290,16 +339,22 @@ def run(cell, args, env) -> dict:
     del engine
     gc.unfreeze()
     gc.collect()
-    compared, worst = check_against_reference(
+    compared, worst, control_worst = check_against_reference(
         cell, inf, done, args.seed, int(traffic.get("check_requests", 4)),
-        int(traffic.get("check_max_tokens", 2048)), int(traffic["output"]["max"]))
+        int(traffic.get("check_max_tokens", 2048)), int(traffic["output"]["max"]),
+        control=env.get("control"))
     env["mark"]("checked against the reference")
     log(f"engine vs reference: {compared} positions teacher-forced, largest "
         f"gap of an engine token below the reference's best logit {worst:.4f} "
         f"(tolerance {LOGIT_TOL}); paged_attention: {builds} build(s), "
         f"{wrong_builds} in the wrong mode")
+    if control_worst is not None:
+        log(f"control ({env['control']} weights in the reference, same prompts "
+            f"and tokens): largest gap of the token it puts first {control_worst:.4f}")
     correct = (failed == 0 and compared > 0 and worst <= LOGIT_TOL
-               and builds > 0 and wrong_builds == 0 and compiles_in_window == 0)
+               and builds > 0 and wrong_builds == 0 and compiles_in_window == 0
+               # a backlog that is cut must still have served someone to the end
+               and (finished > 0 or not cut_backlog))
     end_to_end = {"serve_tokens_per_s": tokens_in_window / args.seconds}
     if ttft:
         end_to_end["ttft_p95_ms"] = 1e3 * percentile(ttft, 95)
@@ -309,7 +364,7 @@ def run(cell, args, env) -> dict:
         "correct": bool(correct),
         "attempted": len(counted),
         "failed": failed,
-        "notes": {"cut": cut},
+        "notes": {"cut": cut, **({"unserved": unserved} if cut_backlog else {})},
         "setup_s": setup_s,
         "end_to_end": end_to_end,
         "host": {
@@ -320,6 +375,9 @@ def run(cell, args, env) -> dict:
             "ttft_p50_ms": 1e3 * percentile(ttft, 50) if ttft else None,
             "itl_p50_ms": 1e3 * percentile(itl, 50) if itl else None,
             "worst_logit_gap": worst, "drained_s": drained_s,
+            "control_logit_gap": control_worst,
+            "counted": len(counted),
+            "unserved": unserved if cut_backlog else None,
         },
         "devices": [jax.devices()[0].id], "live_bytes": live,
         "window_peaks": window_peaks,

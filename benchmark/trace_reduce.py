@@ -62,6 +62,13 @@ def display_name(event_name: str) -> str:
     return f"{head.lstrip('%')} {shape}"[:120].strip()
 
 
+def stem(event_name: str) -> str:
+    """The instruction's name without its number: ``copy.184`` -> ``copy``,
+    ``paged_attention.31`` -> ``paged_attention``. Instances of one stem
+    are the same operation at another place of the program."""
+    return re.sub(r"\.\d+$", "", short_name(event_name))
+
+
 def op_class(event_name: str) -> str:
     """``collective``; ``pallas:<stem>`` for a Pallas kernel (a custom call
     whose target is ``tpu_custom_call``), the stem being the instruction's
@@ -73,7 +80,7 @@ def op_class(event_name: str) -> str:
     if any(c in short for c in COLLECTIVES):
         return "collective"
     if PALLAS_TARGET in event_name:
-        return "pallas:" + re.sub(r"\.\d+$", "", short)
+        return "pallas:" + stem(event_name)
     return "other"
 
 
@@ -135,6 +142,8 @@ def reduce_events(events: dict, chips: int) -> dict:
     end = max(op[1] + op[2] for d in ids for op in devices[d]["ops"])
     busy_ns = 0.0
     op_ns: Dict[str, float] = defaultdict(float)  # by the instruction's text
+    stem_ns: Dict[str, float] = defaultdict(float)
+    stem_count: Dict[str, int] = defaultdict(int)
     class_ns: Dict[str, float] = defaultdict(float)
     for d in ids:
         ops = devices[d]["ops"]
@@ -142,6 +151,9 @@ def reduce_events(events: dict, chips: int) -> dict:
             [(s, s + dur) for _, s, dur in ops]))
         for name, _, dur in ops:
             op_ns[name] += dur
+            of = stem(name)
+            stem_ns[of] += dur
+            stem_count[of] += 1
             class_ns[op_class(name)] += dur
     modules: Dict[str, dict] = {}
     for name, _, dur in devices[ids[0]]["modules"]:
@@ -161,6 +173,10 @@ def reduce_events(events: dict, chips: int) -> dict:
                 else "(shorter gaps, not named)")
         gap_ns[name] += length
     top = sorted(op_ns.items(), key=lambda kv: -kv[1])[:10]
+    # many small instances of one operation hide below a few large ones
+    # (32 pool copies of 0.41 ms below 16 kernel calls, PR 25-26): the sums
+    # by stem, each with the number of events it sums
+    stems = sorted(stem_ns.items(), key=lambda kv: -kv[1])[:10]
     return {
         "busy_s": busy_ns / n / 1e9,
         "window_s": (end - start) / 1e9,
@@ -168,6 +184,8 @@ def reduce_events(events: dict, chips: int) -> dict:
         "class_s": {k: v / n / 1e9 for k, v in class_ns.items()},
         "modules": modules,
         "top_ops": [[display_name(k), v / n / 1e9] for k, v in top],
+        "top_stems": [[f"sum:{k} x{stem_count[k] // n}", v / n / 1e9]
+                      for k, v in stems],
         "idle_gaps": [[k, v / 1e9] for k, v in
                       sorted(gap_ns.items(), key=lambda kv: -kv[1])[:10]],
     }

@@ -1,4 +1,6 @@
-"""The one general generator of serving traffic: parameters in, requests out.
+"""The default generator of serving traffic: parameters in, requests out.
+(A traffic file that names a ``"generator"`` gets ``generators/<name>.py``
+instead, with this signature and this ``Request``: ``benchmark/cells.py``.)
 
 A traffic file of kind ``serve`` gives a Poisson arrival rate and two length
 distributions; this turns them into a list of requests, a pure function of

@@ -41,9 +41,8 @@ def run(cell, args, env) -> dict:
     from scaling_tpu.ops.flash_attention import force_flash_interpret
     from scaling_tpu.topology import Topology
 
-    from . import model, ops_count
+    from . import model
     from .device import live_bytes, memory_peaks
-    from .reference import dense_decoder as ref
 
     traffic, arch_json = cell.traffic, cell.config["transformer_architecture"]
     config = model.transformer_config(cell.config, traffic)
@@ -58,6 +57,12 @@ def run(cell, args, env) -> dict:
     batch_rows = topo.micro_batch_size * topo.data_parallel_size
     check_positions = min(int(traffic["check_positions"]), seq)
     key = model.prng_key(args.seed)
+    # the program counts its kernel builds for the whole process, so a
+    # build in the wrong mode is one made during THIS run: an earlier one (a
+    # test that compiled the kernel for a described chip) is not its path
+    kernel = traffic.get("kernel")
+    wrong_before = kernel_build_count(
+        kernel, interpret=not args.rehearse) if kernel else 0
 
     def make_batch(key, check: bool):
         """A fresh batch of log-uniform token ids; ``check`` keeps the loss
@@ -96,10 +101,22 @@ def run(cell, args, env) -> dict:
         check = make_batch(jax.random.fold_in(key, 1_000_003), True)
         tokens = check["token_ids"][0, 0, :check_positions]
         targets = check["target_token_ids"][0, 0, :check_positions]
-        logits = ref.forward(model.reference_weights(params, arch.num_layers),
-                             tokens, model.reference_spec(arch_json))
+        ref, view = cell.reference, cell.view
+        weights, spec = (view.reference_weights(params, arch_json),
+                         view.reference_spec(arch_json))
+        logits = ref.forward(weights, tokens, spec)
         ref_loss = float(ref.token_loss(logits, targets).mean())
         del logits
+        control_loss = None
+        if env.get("control"):  # benchmark/control.py: never in the driver's runs
+            from .control import lower_precision
+
+            control_loss = float(ref.token_loss(ref.forward(
+                lower_precision(weights, env["control"]), tokens, spec),
+                targets).mean())
+            log(f"control ({env['control']} weights in the reference): loss "
+                f"{control_loss:.5f}, {abs(control_loss - ref_loss):.2e} from the "
+                f"reference's (tolerance {LOSS_TOL})")
         env["mark"]("reference loss computed")
 
         opt_state = model.init_optimizer_state(optimizer, params)
@@ -171,17 +188,14 @@ def run(cell, args, env) -> dict:
     tokens_per_step = batch_rows * seq
     tokens_per_s = steps * tokens_per_step / elapsed
     finite = all(math.isfinite(x) for x in losses)
-    kernel = traffic.get("kernel")
     builds = kernel_build_count(kernel, interpret=args.rehearse) if kernel else 1
-    wrong_builds = kernel_build_count(
-        kernel, interpret=not args.rehearse) if kernel else 0
+    wrong_builds = (kernel_build_count(kernel, interpret=not args.rehearse)
+                    - wrong_before) if kernel else 0
     log(f"{steps} steps in {elapsed:.2f} s, loss {losses[0]:.4f} -> "
         f"{losses[-1]:.4f}; {kernel}: {builds} build(s), {wrong_builds} in "
         f"the wrong mode; {compiles_in_window} program(s) lowered in the window")
-    flops_per_token = ops_count.train_flops_per_token(
-        model.matmul_param_count(model.param_shapes(module)), arch.num_layers,
-        arch.num_attention_heads, arch.hidden_size // arch.num_attention_heads,
-        seq)
+    flops_per_token = cell.view.train_flops_per_token(
+        arch_json, model.param_shapes(module), seq)
     return {
         "correct": bool(loss_ok and finite and builds > 0 and wrong_builds == 0
                         and compiles_in_window == 0),
@@ -195,6 +209,7 @@ def run(cell, args, env) -> dict:
             "tokens_per_s": tokens_per_s, "tokens_per_step": tokens_per_step,
             "flops_per_token": flops_per_token,
             "first_loss": first_loss, "reference_loss": ref_loss,
+            "control_loss": control_loss,
             "micro_batch": topo.micro_batch_size,
             "batch_rows": batch_rows, "seq": seq,
         },

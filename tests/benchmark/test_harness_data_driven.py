@@ -4,10 +4,7 @@ benchmark edited; and what the contract asks of a run without a TPU, of the
 traffic generator and of the timing holds. Everything runs in this process
 on the CPU at toy size."""
 
-import filecmp
 import json
-import shutil
-import sys
 from pathlib import Path
 
 import pytest
@@ -15,30 +12,6 @@ import pytest
 from benchmark import cells, traffic_gen
 
 TOY = Path(__file__).parent / "data" / "toy"
-
-
-@pytest.fixture(scope="module")
-def run():
-    sys.path.insert(0, str(cells.REPO))
-    from benchmark import run as run_module
-
-    return run_module
-
-
-@pytest.fixture(scope="module")
-def grown(tmp_path_factory):
-    """A copy of ``benchmark/`` into which a later PR's files are ADDED."""
-    root = tmp_path_factory.mktemp("checkout") / "benchmark"
-    shutil.copytree(cells.ROOT, root, ignore=shutil.ignore_patterns("__pycache__"))
-    before = {p.relative_to(root) for p in root.rglob("*") if p.is_file()}
-    for part in ("configs", "traffic", "metrics", "readers"):
-        for f in (TOY / part).iterdir():
-            assert not (root / part / f.name).exists(), "a toy file shadows a real one"
-            shutil.copy(f, root / part / f.name)
-    # nothing the benchmark already had was touched
-    for rel in before:
-        assert filecmp.cmp(root / rel, cells.ROOT / rel, shallow=False)
-    return root
 
 
 def rehearse(run, grown, capsys, workload, trace):

@@ -9,6 +9,7 @@ import pytest
 
 from benchmark import model
 from benchmark.reference import dense_decoder as ref
+from benchmark.views import dense_decoder as view
 
 BASE = dict(vocab_size=96, hidden_size=64, num_attention_heads=4,
             attention_num_kv_heads=2, attention_qkv_in_one=False,
@@ -77,16 +78,16 @@ def test_reference_agrees_with_the_model(branch, precision, logit_atol, loss_ato
         loss, _ = loss_function(out, batch)
     got = np.asarray(out["activations"][0], np.float32)
 
-    logits = ref.forward(model.reference_weights(params, 2), jnp.asarray(tokens[0]),
-                         model.reference_spec(arch))
+    logits = ref.forward(view.reference_weights(params, arch), jnp.asarray(tokens[0]),
+                         view.reference_spec(arch))
     want = np.asarray(logits)
     assert want.dtype == np.float32
     np.testing.assert_allclose(got, want, atol=logit_atol, rtol=0)
     want_loss = float(ref.token_loss(logits[:20], jnp.asarray(targets[0, :20])).mean())
     assert abs(float(loss) - want_loss) < loss_atol
     # the tolerance has teeth: dropping the rotary positions moves the logits
-    spec = {**model.reference_spec(arch), "rope_base": 10.0}
-    moved = np.asarray(ref.forward(model.reference_weights(params, 2),
+    spec = {**view.reference_spec(arch), "rope_base": 10.0}
+    moved = np.asarray(ref.forward(view.reference_weights(params, arch),
                                    jnp.asarray(tokens[0]), spec))
     assert np.abs(moved - want).max() > 5 * logit_atol
 
@@ -95,7 +96,7 @@ def test_head_positions_and_padding():
     """Logits of chosen positions only, and padding after them changes
     nothing (attention is causal): what the serve check relies on."""
     arch, _, params, _ = build("rms-swiglu-nobias", "float32")
-    weights, spec = model.reference_weights(params, 2), model.reference_spec(arch)
+    weights, spec = view.reference_weights(params, arch), view.reference_spec(arch)
     tokens = jnp.arange(1, 25, dtype=jnp.int32)
     full = np.asarray(ref.forward(weights, tokens, spec))
     padded = jnp.concatenate([tokens[:16], jnp.zeros((16,), jnp.int32)])

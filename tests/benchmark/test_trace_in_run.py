@@ -2,8 +2,6 @@
 the per-layer metrics that read the program's own spans and counters."""
 
 import json
-import shutil
-import sys
 import types
 from pathlib import Path
 
@@ -16,24 +14,6 @@ TOY = DATA / "toy"
 CHAT = cells.load_json(cells.ROOT / "traffic" / "chat-0.8knee.json")
 NEW_METRICS = ("tick_host_ms_p50", "sched_ms_p50", "tick_dispatch_ms_p50",
                "prefill_token_pct", "idle_in_spans_pct")
-
-
-@pytest.fixture(scope="module")
-def run():
-    sys.path.insert(0, str(cells.REPO))
-    from benchmark import run as run_module
-
-    return run_module
-
-
-@pytest.fixture(scope="module")
-def grown(tmp_path_factory):
-    root = tmp_path_factory.mktemp("checkout") / "benchmark"
-    shutil.copytree(cells.ROOT, root, ignore=shutil.ignore_patterns("__pycache__"))
-    for part in ("configs", "traffic", "metrics", "readers"):
-        for f in (TOY / part).iterdir():
-            shutil.copy(f, root / part / f.name)
-    return root
 
 
 @pytest.fixture()
@@ -133,8 +113,8 @@ def test_numbers_of_the_window_are_fixed_when_they_are_taken():
         generated=[1, 2], token_stamps=[9.5, 9.6])
     submitted = [(warm, old), (request, live)]
     taken = serve_kind.window_numbers(submitted, 10.0, 51.0)
-    tokens, done, failed, finished, cut, ttft, itl = taken
-    assert (tokens, failed, finished, cut) == (3, 0, 0, 1)
+    tokens, done, failed, finished, cut, ttft, itl, unserved = taken
+    assert (tokens, failed, finished, cut, unserved) == (3, 0, 0, 1, 0)
     assert done == [(request, [3, 4, 5])]
     assert ttft == [pytest.approx(1.0)] and itl == [
         pytest.approx(0.1), pytest.approx(0.2)]
@@ -142,7 +122,7 @@ def test_numbers_of_the_window_are_fixed_when_they_are_taken():
     live.generated += [6, 7]
     live.token_stamps += [70.0, 70.1]
     live.finished_s = 70.1
-    assert (tokens, done, failed, finished, cut, ttft, itl) == taken
+    assert (tokens, done, failed, finished, cut, ttft, itl, unserved) == taken
     assert done[0][1] == [3, 4, 5] and len(itl) == 2
     again = serve_kind.window_numbers(submitted, 10.0, 51.0)
     assert again[1] != done  # taken later, it would have read otherwise

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from benchmark import cells, model, ops_count, trace_reduce
+from benchmark.views import dense_decoder as view
 
 SAMPLE = Path(__file__).parent / "data" / "tpu_trace_events.json"
 
@@ -111,7 +112,36 @@ def test_operation_and_byte_counts_by_hand():
 
     shapes = model.param_shapes(init_model(
         model.transformer_config(config, {}, num_layers=3), None))
-    n = model.matmul_param_count(shapes)
+    n = view.matmul_param_count(shapes)
     assert n == 3 * 218_112_000 + 4096 + 4096 * 32768 == 788_557_824
     assert ops_count.train_flops_per_token(n, 3, 32, 128, 4096) == (
         6 * 788_557_824 + 6 * 3 * 32 * 128 * 4096)
+
+
+def test_operations_are_summed_by_stem_beside_the_largest_instances(events):
+    """Many small instances of one operation must not hide below a few large
+    ones: the sums by stem (the instruction's name without its number), each
+    with the number of events it sums, against a sum made by hand here."""
+    import re
+
+    r = trace_reduce.reduce_events(events, chips=1)
+    ops = events["devices"]["0"]["ops"]
+    by_hand = {}
+    for name, _, dur in ops:
+        stem = re.sub(r"\.\d+$", "", name.split(" = ", 1)[0].lstrip("%"))
+        total, count = by_hand.get(stem, (0.0, 0))
+        by_hand[stem] = (total + dur * 1e-9, count + 1)
+    assert trace_reduce.stem("%copy.184 = bf16[4097,16,8,128]{3,2,1,0} copy(%p)") == "copy"
+    assert trace_reduce.stem("%paged_attention.31 = bf16[16,32,32,128] custom-call()") == "paged_attention"
+    assert len(r["top_stems"]) == min(10, len(by_hand))
+    assert [s for _, s in r["top_stems"]] == sorted((s for _, s in r["top_stems"]), reverse=True)
+    for label, seconds in r["top_stems"]:
+        stem, count = re.fullmatch(r"sum:(.+) x(\d+)", label).groups()
+        assert by_hand[stem] == (pytest.approx(seconds), int(count))
+    # the stems' largest is at least the largest instance: a sum holds it
+    assert r["top_stems"][0][1] >= r["top_ops"][0][1]
+    # over two chips a stem's count and seconds are a chip's share
+    two = {"devices": {"0": events["devices"]["0"], "1": events["devices"]["0"]},
+           "host": events["host"]}
+    assert trace_reduce.reduce_events(two, chips=2)["top_stems"] == [
+        [label, pytest.approx(seconds)] for label, seconds in r["top_stems"]]
