@@ -51,6 +51,16 @@ class MLPType(Enum):
     MOE = "moe"
 
 
+class KeyQueryNormScope(Enum):
+    """What ``key_query_norm`` normalises: each head's ``head_dim`` values
+    with one learned weight of ``head_dim`` (the reference's), or the whole
+    projection before it is split into heads, with one learned weight of
+    its full width (OLMoE's ``q_norm`` / ``k_norm``)."""
+
+    HEAD = "head"
+    PROJECTION = "projection"
+
+
 class RelativePositionEmbeddingType(Enum):
     NONE = "none"
     ROTARY = "rotary"
@@ -203,12 +213,24 @@ class TransformerArchitectureConfig(BaseConfig):
     moe_aux_loss_coef: float = Field(
         0.01, description="Switch-style load-balance loss coefficient", ge=0
     )
+    moe_norm_topk_prob: bool = Field(
+        True,
+        description="renormalise a token's top-k router probabilities to sum "
+        "to one (Switch/GShard practice); false uses them as the softmax over "
+        "all experts gave them (OLMoE's norm_topk_prob: false)",
+    )
     activation_function: ActivationFunction = Field(ActivationFunction.GELU, description="")
     precision: Precision = Field(Precision.FLOAT32, description="compute/param dtype")
     layernorm: LayerNormConfig = Field(LayerNormConfig(), description="")
     masked_softmax: MaskedSoftmaxConfig = Field(MaskedSoftmaxConfig(), description="")
     causal: bool = Field(True, description="use a causal attention mask")
     key_query_norm: bool = Field(False, description="normalise q/k per head")
+    key_query_norm_scope: KeyQueryNormScope = Field(
+        KeyQueryNormScope.HEAD,
+        description="with key_query_norm: 'head' normalises each head's "
+        "values, 'projection' the whole q (and k) projection before the "
+        "split into heads, one learned weight over its full width",
+    )
     weight_tying: bool = Field(False, description="tie lm head to the embedding")
     masked_softmax_fusion: bool = Field(True, description="kept for config parity")
     layernorm_epsilon: float = Field(1.0e-5, description="kept for config parity")
@@ -284,6 +306,12 @@ class TransformerArchitectureConfig(BaseConfig):
     def _validate(self):
         if self.num_local_attention_heads > 0 and self.local_attention_window_size is None:
             raise ValueError("local attention heads require local_attention_window_size")
+        if (self.key_query_norm_scope == KeyQueryNormScope.PROJECTION
+                and not self.key_query_norm):
+            raise ValueError(
+                "key_query_norm_scope 'projection' says which q/k norm the "
+                "model has; set key_query_norm true as well"
+            )
         if self.mlp_type == MLPType.MOE:
             if self.moe_top_k > self.moe_num_experts:
                 raise ValueError(

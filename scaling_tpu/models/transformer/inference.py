@@ -312,8 +312,15 @@ class TransformerInferenceModule:
         return cls(config, module, params, tokenizer)
 
     # ------------------------------------------------------------- forward
+    def _make_ctx(self):
+        """The context of every pass this module runs: deterministic, and
+        marked as serving (a routed MLP drops nothing: nn/moe.py)."""
+        ctx = self.module._make_ctx(deterministic=True, dropout_key=None)
+        ctx.serving = True
+        return ctx
+
     def _run_layers(self, params, batch, caches, offset, paged_kernel=None,
-                    gather_start=None, gather_width=None):
+                    gather_start=None, gather_width=None, moe_load=False):
         """One pass through the stack; TransformerLayers consume/produce the
         KV caches, edge layers run as in training (deterministic).
 
@@ -334,6 +341,11 @@ class TransformerInferenceModule:
         block nobody read). The returned logits then cover positions
         ``gather_start .. gather_start + gather_width - 1`` per row.
 
+        ``moe_load`` (static; a routed model on block-paged caches) adds a
+        third result: the (E,) int32 count of assignments each expert
+        received from the rows' REAL positions, summed over the layers
+        (nn/moe.py ``serve``).
+
         A pipelined (pp>1) stack wraps its TransformerLayers in a
         ``PipelinedBody``, which cannot consume KV caches: the cached path
         raises instead of silently decoding with no history (the caches
@@ -342,7 +354,7 @@ class TransformerInferenceModule:
         ``ParallelModule.forward``."""
         from ...parallel.pipeline import PipelinedBody
 
-        ctx = self.module._make_ctx(deterministic=True, dropout_key=None)
+        ctx = self._make_ctx()
         if paged_kernel is not None:
             ctx.paged_kernel = paged_kernel
         last_tl = None
@@ -394,6 +406,8 @@ class TransformerInferenceModule:
                 "were provided — a cache silently skipped here means "
                 "silently wrong decode output"
             )
+        if moe_load:
+            return x["activations"], new_caches, x["moe_load"]
         return x["activations"], new_caches
 
     def _make_batch(
@@ -469,7 +483,7 @@ class TransformerInferenceModule:
         pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
 
         def run(params, t, po):
-            ctx = self.module._make_ctx(deterministic=True, dropout_key=None)
+            ctx = self._make_ctx()
             x = self._make_batch(t, po)
             recorded = {}
             for i, layer in enumerate(self.module.layers):
@@ -513,7 +527,7 @@ class TransformerInferenceModule:
         one prompt pass can never diverge."""
         from ...parallel.pipeline import PipelinedBody
 
-        ctx = self.module._make_ctx(deterministic=True, dropout_key=None)
+        ctx = self._make_ctx()
         transformer_idxs = [
             i for i, l in enumerate(self.module.layers)
             if isinstance(l, TransformerLayer)

@@ -27,9 +27,11 @@ from ....nn import (
     normal_init,
     tree_prefix,
 )
+from ....nn.attention import PagedKVCacheView
 from ....nn.rotary import RotaryConfig
 from ..config import (
     AdapterConfig,
+    KeyQueryNormScope,
     MLPType,
     RelativePositionEmbeddingType,
     TransformerArchitectureConfig,
@@ -119,6 +121,9 @@ class TransformerLayer(BaseLayer):
             lora_config=arch.lora_config,
             norm_type=arch.norm_type,
             key_query_norm=arch.key_query_norm,
+            key_query_norm_over_projection=(
+                arch.key_query_norm_scope == KeyQueryNormScope.PROJECTION
+            ),
             layernorm_config=arch.layernorm,
             qkv_in_one=arch.attention_qkv_in_one
             and arch.attention_num_kv_heads is None,
@@ -138,6 +143,7 @@ class TransformerLayer(BaseLayer):
                 top_k=arch.moe_top_k,
                 capacity_factor=arch.moe_capacity_factor,
                 aux_loss_coef=arch.moe_aux_loss_coef,
+                norm_topk_prob=arch.moe_norm_topk_prob,
                 glu=True,
                 activation=arch.activation_function,
                 dtype=dtype,
@@ -274,8 +280,20 @@ class TransformerLayer(BaseLayer):
         h = h + attn.astype(h.dtype)
 
         normed = self.post_attention_layernorm(params["post_attention_layernorm"], h, ctx)
-        aux_loss = None
-        if self.is_moe:
+        aux_loss = moe_load = None
+        if self.is_moe and ctx.serving:
+            # nothing dropped, no auxiliary loss; on the engine's paged
+            # path the row's real positions are known and their
+            # assignments are counted (nn/moe.py, "Serving")
+            real = None
+            if isinstance(kv_cache, PagedKVCacheView):
+                new_len = kv_cache.new_len  # None: every position is real
+                real = (
+                    jnp.ones(h.shape[:2], bool) if new_len is None
+                    else jnp.arange(h.shape[1])[None, :] < new_len[:, None]
+                )
+            mlp_out, moe_load = self.mlp.serve(params["mlp"], normed, real)
+        elif self.is_moe:
             mlp_out, aux_loss = self.mlp(params["mlp"], normed, ctx)
         else:
             mlp_out = self.mlp(params["mlp"], normed, ctx)
@@ -291,6 +309,9 @@ class TransformerLayer(BaseLayer):
         if aux_loss is not None:
             # router load-balance loss rides the IO dict to the loss function
             out["aux_loss"] = x.get("aux_loss", 0.0) + aux_loss
+        if moe_load is not None:
+            # (E,) assignments of real positions, summed over the layers
+            out["moe_load"] = x.get("moe_load", 0) + moe_load
         if new_kv is not None:
             return out, new_kv
         return out

@@ -234,6 +234,7 @@ class ParallelSelfAttention(BaseLayer):
         lora_config: Optional[LoRaConfig] = None,
         norm_type: NormType = NormType.LAYERNORM,
         key_query_norm: bool = False,
+        key_query_norm_over_projection: bool = False,
         layernorm_config: Optional[LayerNormConfig] = None,
         qkv_in_one: bool = True,
         num_kv_heads: Optional[int] = None,
@@ -296,10 +297,18 @@ class ParallelSelfAttention(BaseLayer):
             self.rotary_embedding = RotaryEmbeddingComplex(rotary_config)
 
         # key/query norm
+        # per head (one weight of head_dim), or over the whole projection
+        # before the split into heads (one weight of its full width:
+        # OLMoE's q_norm / k_norm)
         self.key_query_norm = key_query_norm
+        self.key_query_norm_over_projection = key_query_norm_over_projection
         if key_query_norm:
-            self.norm_query = get_norm(norm_type, self.head_dim, layernorm_config, dtype, bitfit_bias_name)
-            self.norm_key = get_norm(norm_type, self.head_dim, layernorm_config, dtype, bitfit_bias_name)
+            heads_q, heads_k = (
+                (num_attention_heads, self.num_kv_heads)
+                if key_query_norm_over_projection else (1, 1)
+            )
+            self.norm_query = get_norm(norm_type, heads_q * self.head_dim, layernorm_config, dtype, bitfit_bias_name)
+            self.norm_key = get_norm(norm_type, heads_k * self.head_dim, layernorm_config, dtype, bitfit_bias_name)
 
         self.masked_softmax = MaskedSoftmax(self.masked_softmax_config)
 
@@ -406,7 +415,17 @@ class ParallelSelfAttention(BaseLayer):
         b, s, _ = x.shape
         q, k, v = self._qkv(params, x, ctx)
 
-        if self.key_query_norm:
+        if self.key_query_norm and self.key_query_norm_over_projection:
+            # the statistic runs over every head's values: under model
+            # parallelism the heads are sharded, and GSPMD reduces the sum
+            # of squares over the model axis (never a per-shard norm)
+            q = self.norm_query(
+                params["norm_query"], q.reshape(b, s, -1), ctx
+            ).reshape(q.shape)
+            k = self.norm_key(
+                params["norm_key"], k.reshape(b, s, -1), ctx
+            ).reshape(k.shape)
+        elif self.key_query_norm:
             q = self.norm_query(params["norm_query"], q, ctx)
             k = self.norm_key(params["norm_key"], k, ctx)
 

@@ -42,6 +42,11 @@ class ForwardContext:
     # kernel (nn/paged_attention.py). Only the serving engine's programs
     # flip this (TransformerInferenceModule._run_layers paged_kernel=).
     paged_kernel: str = "xla"
+    # an inference pass (static): TransformerInferenceModule sets it on
+    # every context it makes (generate, logits, the serving engine's
+    # programs). A routed MLP then drops no assignment and computes no
+    # auxiliary loss (nn/moe.py, "Serving").
+    serving: bool = False
 
     _key_counter: int = 0
 

@@ -17,6 +17,27 @@ TPU way — the GShard/Switch dense-dispatch formulation:
 
 Dropped tokens (over capacity) fall through on the residual path, exactly
 as in Switch Transformers (Fedus et al. 2021).
+
+Serving (``serve``; ``TransformerLayer`` takes it whenever the pass is one
+of ``TransformerInferenceModule``'s: ``ForwardContext.serving``) runs the
+same three einsums with ONE difference, the value of the capacity: every
+expert has room for the whole row (``C = s``; a token names an expert at
+most once, so no row can send an expert more), whatever
+``moe_capacity_factor`` says. A capacity taken over the rows of a serving
+batch has no meaning: a row of the engine's mixed program is a prefill
+chunk padded to its fixed width or a single decode token, so
+``capacity_factor * k * s / E`` would count padding as tokens, and which
+assignment fell over the edge would depend on how a prompt was cut into
+chunks: prefill-then-decode would no longer equal the full forward pass.
+Inference drops nothing, as the published models do not. Padded positions
+are routed like any other (their outputs are never read and their KV goes
+to the trash block), but they are left out of the load vector ``serve``
+returns: how many assignments of REAL positions each expert received. The
+auxiliary loss is a training term and is not computed when serving.
+
+``norm_topk_prob`` (a fact of the model, not a knob): whether a token's k
+gate weights are renormalised to sum to one (Switch/GShard; the default)
+or used as the softmax over all experts gave them (OLMoE).
 """
 
 from __future__ import annotations
@@ -43,6 +64,7 @@ class ParallelMoEMLP(BaseLayer):
         top_k: int = 2,
         capacity_factor: float = 1.25,
         aux_loss_coef: float = 0.01,
+        norm_topk_prob: bool = True,
         glu: bool = True,
         activation: ActivationFunction = ActivationFunction.SILU,
         dtype=None,
@@ -57,6 +79,7 @@ class ParallelMoEMLP(BaseLayer):
         self.top_k = top_k
         self.capacity_factor = capacity_factor
         self.aux_loss_coef = aux_loss_coef
+        self.norm_topk_prob = norm_topk_prob
         self.glu = glu
         self.activation_fn = get_activation_function(activation)
         self.dtype = dtype
@@ -119,28 +142,64 @@ class ParallelMoEMLP(BaseLayer):
     def __call__(
         self, params: dict, x: jax.Array, ctx: ForwardContext
     ) -> Tuple[jax.Array, jax.Array]:
-        """Returns (output (b,s,h), aux_loss scalar — already coefficient-
-        scaled, ready to add to the training loss)."""
-        b, s, h = x.shape
+        """Training: the static capacity of the module docstring. Returns
+        (output (b,s,h), aux_loss scalar — already coefficient-scaled,
+        ready to add to the training loss)."""
+        s = x.shape[1]
         E, k = self.num_experts, self.top_k
-        C = max(1, int(self.capacity_factor * k * s / E))
+        with jax.named_scope("moe"):
+            probs, gate_vals, gate_idx = self._route(params, x)
+            # Switch load-balance loss: E * sum_e mean_prob_e *
+            # assigned_frac_e, with assignment fractions from the top-1 choice
+            top1 = jnp.argmax(probs, axis=-1)
+            assigned = jax.nn.one_hot(top1, E, dtype=jnp.float32)  # (b, s, E)
+            aux = E * jnp.sum(probs.mean(axis=(0, 1)) * assigned.mean(axis=(0, 1)))
+            aux = (aux * self.aux_loss_coef).astype(jnp.float32)
+            capacity = max(1, int(self.capacity_factor * k * s / E))
+            return self._experts(params, x, gate_vals, gate_idx, capacity), aux
 
-        router_w = params["router"]["weight"]
-        logits = jnp.einsum("bsh,he->bse", x.astype(jnp.float32), router_w)
-        probs = jax.nn.softmax(logits, axis=-1)  # (b, s, E)
+    def serve(
+        self, params: dict, x: jax.Array, real: Optional[jax.Array] = None
+    ) -> Tuple[jax.Array, Optional[jax.Array]]:
+        """Serving: room for the whole row, so nothing is dropped, and no
+        auxiliary loss. ``real`` ((b, s) bool): which positions hold a
+        token. Returns (output (b,s,h), the (E,) int32 count of the real
+        positions' assignments each expert received; None without ``real``)."""
+        with jax.named_scope("moe"):
+            _, gate_vals, gate_idx = self._route(params, x)
+            y = self._experts(params, x, gate_vals, gate_idx, capacity=x.shape[1])
+            if real is None:
+                return y, None
+            chosen = jax.nn.one_hot(gate_idx, self.num_experts, dtype=jnp.int32)
+            load = (chosen * real[:, :, None, None].astype(jnp.int32)).sum((0, 1, 2))
+            return y, load
 
-        # Switch load-balance loss: E * sum_e mean_prob_e * assigned_frac_e,
-        # with assignment fractions from the top-1 choice
-        top1 = jnp.argmax(probs, axis=-1)
-        assigned = jax.nn.one_hot(top1, E, dtype=jnp.float32)  # (b, s, E)
-        aux = E * jnp.sum(probs.mean(axis=(0, 1)) * assigned.mean(axis=(0, 1)))
-        aux = (aux * self.aux_loss_coef).astype(jnp.float32)
-
-        # top-k choices per token, each with its gate weight
-        gate_vals, gate_idx = jax.lax.top_k(probs, k)  # (b, s, k)
-        gate_vals = gate_vals / jnp.maximum(
-            gate_vals.sum(axis=-1, keepdims=True), 1e-9
+    def _route(self, params: dict, x: jax.Array):
+        """Router probabilities over all experts in float32 (b, s, E), and
+        each token's top-k gate weights and expert indices (b, s, k)."""
+        # float32 in earnest: on a TPU a float32 matmul at the default
+        # precision rounds both operands to bf16, and a router logit moved
+        # by that much re-orders near-ties among the top k
+        logits = jnp.einsum(
+            "bsh,he->bse", x.astype(jnp.float32), params["router"]["weight"],
+            precision=jax.lax.Precision.HIGHEST,
         )
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, gate_idx = jax.lax.top_k(probs, self.top_k)
+        if self.norm_topk_prob:
+            gate_vals = gate_vals / jnp.maximum(
+                gate_vals.sum(axis=-1, keepdims=True), 1e-9
+            )
+        return probs, gate_vals, gate_idx
+
+    def _experts(
+        self, params: dict, x: jax.Array, gate_vals: jax.Array,
+        gate_idx: jax.Array, capacity: int,
+    ) -> jax.Array:
+        """Dispatch, expert FFNs, combine: three einsums over per-row
+        buffers of ``capacity`` places an expert."""
+        b, s, h = x.shape
+        E, k, C = self.num_experts, self.top_k, capacity
 
         # position of each (token, choice) in its expert's capacity buffer:
         # running count of prior tokens routed to the same expert. Choices
@@ -171,5 +230,4 @@ class ParallelMoEMLP(BaseLayer):
         else:
             act = self.activation_fn(up)
         out = jnp.einsum("ebcf,efh->ebch", act, params["w_out"].astype(x.dtype))
-        y = jnp.einsum("bsec,ebch->bsh", combine.astype(x.dtype), out)
-        return y, aux
+        return jnp.einsum("bsec,ebch->bsh", combine.astype(x.dtype), out)

@@ -240,6 +240,16 @@ class ServeEngine:
         self._chunk_fns: Dict[int, object] = {}  # chunk-size -> program
         # (width,) -> the ONE fused mixed program per (chunk, k) signature
         self._mixed_fns: Dict[int, object] = {}
+        # a routed model (mlp_type moe): the mixed program also returns,
+        # in the tick's one host read, how many assignments of real
+        # positions each expert received (0: a dense model, which pays
+        # nothing for it)
+        from ..models.transformer.config import MLPType
+
+        arch = inference_module.architecture
+        self.num_experts = (
+            arch.moe_num_experts if arch.mlp_type == MLPType.MOE else 0
+        )
         self.tick_index = 0
         self.finished: List[Sequence] = []
         self.max_concurrent_prefills = 0
@@ -629,9 +639,15 @@ class ServeEngine:
         and position ``new_len - 1`` for chunk rows, while the lm_head
         prices ``sample_width`` positions instead of all ``width``.
         Compiles once per (chunk, k) width signature — pinned in the
-        serve_decode golden."""
+        serve_decode golden.
+
+        A routed model's program returns ONE int32 vector instead of the
+        grid: the sampled grid flattened, then the (E,) load of the
+        tick's real positions (``_run_layers(moe_load=True)``), so that
+        the load costs the tick no second host read."""
         jnp = self._jax.numpy
         sample_width = self.config.sample_width
+        routed = self.num_experts > 0
 
         def mixed(params, state, tables, ctx_lens, tokens, new_lens,
                   temps, topps, topks, reqids, gen0, base_key):
@@ -642,10 +658,11 @@ class ServeEngine:
             views = self._views_from_state(state, tables, ctx_lens,
                                            new_lens)
             g0 = jnp.clip(new_lens - sample_width, 0, width - sample_width)
-            logits, new_views = self.inf._run_layers(
+            logits, new_views, *load = self.inf._run_layers(
                 params, batch, views, None,
                 paged_kernel=self.config.paged_kernel,
                 gather_start=g0, gather_width=sample_width,
+                moe_load=routed,
             )
             # gathered index j is original position g0 + j: shift the
             # per-row key-fold base so every sample still draws with the
@@ -653,6 +670,8 @@ class ServeEngine:
             sampled = self._sample_grid(
                 logits, temps, topps, topks, reqids, gen0 + g0, base_key
             )
+            if routed:
+                sampled = jnp.concatenate([sampled.reshape(-1), load[0]])
             return sampled, new_views
 
         donate = (1,) if self._jax.default_backend() != "cpu" else ()
@@ -905,10 +924,14 @@ class ServeEngine:
                 # the tick's ONE deliberate device->host pull: the sampled
                 # token grid must land on host to be emitted to callers
                 host_samples = np.asarray(sampled)  # sta: disable=STA010
-        with self._span("serve.emit", step=step):
+        sw = cfg.sample_width  # sampled grid covers g0..g0+sw-1
+        with self._span("serve.emit", step=step) as emit:
+            if self.num_experts:
+                load = host_samples[n * sw:]
+                host_samples = host_samples[:n * sw].reshape(n, sw)
+                self._record_moe_load(load, emit)
             self._absorb(new_views)
             now = time.monotonic()
-            sw = cfg.sample_width  # sampled grid covers g0..g0+sw-1
             for seq, start, n_real in chunk_rows:
                 slot = seq.slot
                 seq.num_cached = start + n_real
@@ -926,6 +949,17 @@ class ServeEngine:
             for seq in t.decodes:
                 self._tables[seq.slot] = tables[seq.slot]
                 self._accept_speculative(seq, host_samples[seq.slot], now)
+
+    def _record_moe_load(self, load, emit_span) -> None:
+        """One tick's (E,) assignments of real positions, summed over the
+        layers: the counter, and the tick's shape on its emit span."""
+        if self.warmup_mode:
+            return
+        self._counter("serve_moe_assignments_total").inc(int(load.sum()))
+        emit_span.annotate(
+            load_max=int(load.max()), load_mean=float(load.mean()),
+            experts_idle=int((load == 0).sum()),
+        )
 
     def _accept_speculative(self, seq: Sequence, row_samples, now) -> None:
         """Exact speculative acceptance (Leviathan et al., arxiv

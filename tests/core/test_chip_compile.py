@@ -106,8 +106,9 @@ def test_splash_survives_shard_map_on_2x2(topo):
 )
 @pytest.mark.parametrize(
     "slots,q_heads,kv_heads",
-    [(SLOTS, Q_HEADS, KV_HEADS), (16, 32, 8), (16, 36, 4)],
-    ids=["smoke-16q4kv", "serve-cell-32q8kv", "pharia-36q4kv-group9"],
+    [(SLOTS, Q_HEADS, KV_HEADS), (16, 32, 8), (16, 36, 4), (16, 16, 16)],
+    ids=["smoke-16q4kv", "serve-cell-32q8kv", "pharia-36q4kv-group9",
+         "olmoe-16q16kv-group1"],
 )
 def test_paged_kernel_compiles(one_chip, slots, q_heads, kv_heads, s, kv_dtype):
     def shape(dims, dtype):

@@ -110,3 +110,63 @@ def test_gradients_flow_to_router_and_experts():
     assert float(jnp.abs(grads["router"]["weight"]).sum()) > 0
     assert float(jnp.abs(grads["w_in"]).sum()) > 0
     assert float(jnp.abs(grads["w_out"]).sum()) > 0
+
+
+# ---- serving: nothing dropped, real positions counted, gates as published
+
+def crowded(layer, params):
+    """Every token of a 16-position row wants expert 0 first."""
+    params["router"]["weight"] = jnp.zeros((H, layer.num_experts)).at[:, 0].set(1.0)
+    return jnp.abs(jax.random.normal(jax.random.PRNGKey(2), (1, S, H))) + 0.1
+
+
+def test_serve_gives_every_expert_room_for_the_whole_row():
+    """The capacity factor that drops all but one token in training drops
+    nothing when serving: the same einsums, C = s."""
+    layer = make_layer(num_experts=2, top_k=1, capacity_factor=2.0 / S)
+    params = layer.init(jax.random.PRNGKey(0))
+    x = crowded(layer, params)
+    trained, aux = layer(params, x, ForwardContext())
+    served, load = layer.serve(params, x)
+    assert load is None and float(aux) > 0
+    assert np.count_nonzero(np.abs(np.asarray(trained[0])).sum(-1) > 1e-7) == 1
+    np.testing.assert_allclose(
+        np.asarray(served[0]), np.asarray(dense_expert(layer, params, x, 0)[0]),
+        atol=1e-5, rtol=1e-5)
+
+
+def test_serve_counts_the_assignments_of_real_positions_only():
+    layer = make_layer(num_experts=4, top_k=2)
+    params = layer.init(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (B, S, H), jnp.float32)
+    real = jnp.arange(S)[None, :] < jnp.asarray([5, 0])[:, None]
+    y, load = layer.serve(params, x, real)
+    assert load.shape == (4,) and load.dtype == jnp.int32
+    assert int(load.sum()) == 5 * 2 and int(load.max()) <= 5
+    # the padded positions are computed like any other: the output is that
+    # of the same row served with every position real
+    np.testing.assert_allclose(
+        np.asarray(y), np.asarray(layer.serve(params, x, jnp.ones((B, S), bool))[0]))
+    _, full = layer.serve(params, x, jnp.ones((B, S), bool))
+    assert int(full.sum()) == B * S * 2
+
+
+@pytest.mark.parametrize("norm_topk_prob", [True, False])
+def test_gates_are_renormalised_only_when_the_model_says_so(norm_topk_prob):
+    layer = make_layer(num_experts=8, top_k=2, norm_topk_prob=norm_topk_prob)
+    params = layer.init(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (B, S, H), jnp.float32) * 0.5
+    y, _ = layer(params, x, ForwardContext())
+    probs = jax.nn.softmax(jnp.einsum("bsh,he->bse", x, params["router"]["weight"]), -1)
+    gate_vals, gate_idx = jax.lax.top_k(probs, 2)
+    if norm_topk_prob:
+        gate_vals = gate_vals / gate_vals.sum(-1, keepdims=True)
+    else:
+        assert float(gate_vals.sum(-1).max()) < 0.6  # two of eight: far from one
+    expert_out = jnp.stack(
+        [dense_expert(layer, params, x, e) for e in range(8)], axis=2)
+    picked = jnp.take_along_axis(expert_out, gate_idx[..., None], axis=2)
+    ref = (picked * gate_vals[..., None]).sum(axis=2)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(ref), atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(layer.serve(params, x)[0]), np.asarray(ref), atol=1e-5, rtol=1e-5)
