@@ -8,6 +8,10 @@ measure wrongly repaired: every request is timed FROM WHEN IT WAS DUE, the
 generator's lateness is reported, and the window is long enough for its tails.
 Under ``--trace 2`` the window and its drain run untraced, every number of the
 window is taken, and only then are a few seconds of the same traffic traced.
+
+Two choices are the traffic file's, by name: ``"backlog"`` (``BACKLOGS``: how
+the window ends) and ``"tokens"`` (``TOKEN_RULES``: whose output tokens
+``serve_tokens_per_s`` counts).
 """
 
 from __future__ import annotations
@@ -42,6 +46,20 @@ DRAIN_CAP_S = 20.0
 # nor checked, and the run goes on only until every request taken by then
 # has its first token. Everything the engine took is held to the rule above.
 BACKLOGS = ("fail", "cut")
+# Whose output tokens ``serve_tokens_per_s`` counts is the traffic's too.
+# ``"tokens": "all"`` (the default): every token stamped inside the window,
+# whoever's. That is what the ENGINE completes, the metric of traffic offered
+# above the knee, where every slot is full all through the window and a
+# faster tick reads higher. ``"counted"``: the tokens of the counted requests
+# (those due inside the window) alone, for traffic BELOW the knee: what the
+# users who sent a request in the window got in the window. It is bounded by
+# what the trace offers, and a faster engine can only raise it. Under "all"
+# such a cell also counts the requests submitted before the window (the drawn
+# history and the warm-up), which decode one token a tick whatever the load:
+# the faster the tick, the more of their tokens are stamped BEFORE the window
+# opens, and the reading fell as the engine got faster (PERF.md, Findings
+# PR 29-30: 85.5 at a 45 ms tick, 79.7 at 27.5 ms).
+TOKEN_RULES = ("all", "counted")
 # --trace 2: when the window's numbers are taken, arrivals resume at the
 # cell's rate and the capture starts once a tick carries a prefill chunk,
 # or after this long at the latest: the traced part is then the cell's mix
@@ -135,18 +153,28 @@ def taken_by(seq, when: float) -> bool:
     return admitted is not None and admitted < when
 
 
-def window_numbers(submitted, t0: float, seconds: float, backlog: str = "fail"):
+def tokens_stamped(submitted, t0: float, seconds: float, tokens: str = "all") -> int:
+    """Output tokens stamped in ``[t0, t0 + seconds)``: of every submitted
+    sequence, or with ``tokens`` ``"counted"`` of the counted requests'
+    alone (``TOKEN_RULES``)."""
+    return sum(t0 <= s < t0 + seconds
+               for r, seq in submitted if tokens == "all" or r.counted
+               for s in getattr(seq, "token_stamps", ()))
+
+
+def window_numbers(submitted, t0: float, seconds: float, backlog: str = "fail",
+                   tokens: str = "all"):
     """What the result says of the window, taken as the run leaves it, from
     copies where the objects go on living (under ``--trace 2`` the sequences
     decode on through the traced part): output tokens stamped inside the
-    window; ``done``, the counted requests that had their first token, each
-    with its tokens so far; how many failed, finished, were cut while
-    decoding; the time-to-first-token and inter-token samples; and, with
-    ``backlog`` ``"cut"``, how many the engine had not taken when arrivals
-    stopped and that never had a token (``unserved``; else 0)."""
+    window, those ``tokens`` names (``TOKEN_RULES``); ``done``, the counted
+    requests that had their first token, each with its tokens so far; how
+    many failed, finished, were cut while decoding; the time-to-first-token
+    and inter-token samples; and, with ``backlog`` ``"cut"``, how many the
+    engine had not taken when arrivals stopped and that never had a token
+    (``unserved``; else 0)."""
     window_end = t0 + seconds
-    stamps = [s for _, seq in submitted for s in getattr(seq, "token_stamps", ())]
-    tokens_in_window = sum(t0 <= s < window_end for s in stamps)
+    tokens_in_window = tokens_stamped(submitted, t0, seconds, tokens)
     done, failed, finished, cut, ttft, itl, unserved = [], 0, 0, 0, [], [], 0
     for r, seq in submitted:
         if not r.counted:
@@ -188,6 +216,9 @@ def run(cell, args, env) -> dict:
     if backlog not in BACKLOGS:
         sys.exit(f"benchmark: \"backlog\" is one of {BACKLOGS}, not {backlog!r}")
     cut_backlog = backlog == "cut"
+    token_rule = traffic.get("tokens", "all")
+    if token_rule not in TOKEN_RULES:
+        sys.exit(f"benchmark: \"tokens\" is one of {TOKEN_RULES}, not {token_rule!r}")
     # the program counts its kernel builds for the whole process, so a
     # build in the wrong mode is one made during THIS run: an earlier one (a
     # test that compiled the kernel for a described chip) is not its path
@@ -278,7 +309,10 @@ def run(cell, args, env) -> dict:
 
     # -- the window's numbers, fixed here whatever runs afterwards
     (tokens_in_window, done, failed, finished, cut, ttft, itl,
-     unserved) = window_numbers(submitted, t0, args.seconds, backlog)
+     unserved) = window_numbers(submitted, t0, args.seconds, backlog, token_rule)
+    log("output tokens stamped in the window: " + ", ".join(
+        f"{tokens_stamped(submitted, t0, args.seconds, rule)} by \"tokens\": {rule!r}"
+        for rule in TOKEN_RULES) + f"; this traffic counts {token_rule!r}")
     log(f"{len(done)} of {len(counted)} counted requests had their first token "
         f"({finished} finished, {cut} cut while decoding, {failed} failed, "
         f"{unserved} unserved), ran "

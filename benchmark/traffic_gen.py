@@ -34,6 +34,13 @@ is gone; one still running is submitted as the warm-up starts, its prompt
 longer by the tokens it had generated and its answer shorter by as many, so
 its context and its remaining work are those of a request in mid-life. The
 warm-up then gives those prompts time to stream in.
+
+Two further keys are read by ``serve_kind.py`` and not here: ``"backlog"``
+(``"fail"``, the default, or ``"cut"``: how the window ends) and ``"tokens"``
+(``"all"``, the default, or ``"counted"``: whether ``serve_tokens_per_s``
+counts every output token stamped inside the window or those of the counted
+requests alone; traffic below the knee, as this generator's, wants
+``"counted"``: ``serve_kind.TOKEN_RULES`` says why).
 """
 
 from __future__ import annotations

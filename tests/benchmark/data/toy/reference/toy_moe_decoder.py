@@ -2,12 +2,14 @@
 
 Plain float32 ``jax.numpy``, nothing of ``scaling_tpu``; the attention half
 of the block is ``dense_decoder``'s. The routed half follows what
-``scaling_tpu/nn/moe.py`` computes today, which is a harness proof and not a
-published model. Departures, each where it is made: the top-k gate weights
-are renormalised to sum to one (OLMoE's ``norm_topk_prob`` is false); there
-is no capacity here, so the configuration must give the program one that
-drops nothing; the load-balance term is left out of the loss (the
-configuration sets its coefficient to 0).
+``scaling_tpu/nn/moe.py`` computes by default, which is a harness proof and
+not a published model: the reference of one, OLMoE's, with its gates as the
+softmax leaves them, is ``benchmark/reference/moe_decoder.py`` (PR 28).
+Departures from it, each where it is made: the top-k gate weights are
+renormalised to sum to one (OLMoE's ``norm_topk_prob`` is false; the program's
+``moe_norm_topk_prob`` defaults to true); there is no capacity here, so the
+configuration must give the program one that drops nothing; the load-balance
+term is left out of the loss (the configuration sets its coefficient to 0).
 
 Weights as ``dense_decoder``'s, a layer's MLP being ``"router": (H, E)``,
 ``"w_gate"`` and ``"w_in"``: (E, H, F), ``"w_out"``: (E, F, H). ``spec`` adds
@@ -32,7 +34,7 @@ def routed_mlp(x, p, top_k: int):
     a sum over the k experts a token uses."""
     probs = jax.nn.softmax(x @ p["router"], axis=-1)              # (s, E)
     gate_vals, gate_idx = jax.lax.top_k(probs, top_k)
-    # departure: renormalised over the chosen k, as nn/moe.py does today
+    # departure: renormalised over the chosen k, nn/moe.py's default
     gate_vals = gate_vals / gate_vals.sum(axis=-1, keepdims=True)
     weight = jnp.zeros_like(probs).at[
         jnp.arange(x.shape[0])[:, None], gate_idx].set(gate_vals)  # (s, E)

@@ -167,6 +167,11 @@ def test_a_token_altered_where_it_is_produced_is_not_correct(run, grown, capsys,
 
 # ---- the configuration's files -----------------------------------------
 
+# a configuration that names no "reference" gets the dense decoder's; the
+# routed one names its own (PR 28)
+REFERENCES = {"olmoe-1b-7b-serve": "moe_decoder"}
+
+
 @pytest.mark.parametrize("entry", BENCH["workloads"], ids=lambda w: w["name"])
 def test_every_cell_resolves_to_a_reference_a_view_and_a_generator(entry):
     cell = cells.load_cell(entry["name"])
@@ -174,8 +179,11 @@ def test_every_cell_resolves_to_a_reference_a_view_and_a_generator(entry):
         assert callable(getattr(cell.reference, name))
     for name in cells.VIEW_CONTRACT:
         assert callable(getattr(cell.view, name))
-    assert cell.reference_name == "dense_decoder"  # no file of the three names one
-    assert "reference" not in cell.config
+    named = REFERENCES.get(entry["config"])
+    assert cell.reference_name == (named or cells.DEFAULT_REFERENCE)
+    assert cell.config.get("reference") == named  # the dense files name none
+    assert Path(cell.reference.__file__).stem == Path(cell.view.__file__).stem \
+        == cell.reference_name
     if cell.kind == "serve":
         assert callable(cell.generate)
         want = traffic_gen.generate if "generator" not in cell.traffic else bursts
