@@ -63,6 +63,7 @@ from .kvcache import (
     build_layer_views,
     init_pools,
     serving_mesh,
+    state_from_views,
     write_prompt_kv,
 )
 from .scheduler import (
@@ -457,8 +458,8 @@ class ServeEngine:
                           new_len=None):
         return build_layer_views(state, block_table, context_len, new_len)
 
-    def _absorb(self, views) -> None:
-        self.pools.absorb_views(views)
+    def _absorb(self, state) -> None:
+        self.pools.absorb_state(state)
 
     def _span(self, name: str, **fields):
         """obs.span, silenced during bench warmup: warmup ticks carry the
@@ -554,12 +555,13 @@ class ServeEngine:
             next_tok = self._sample_last(
                 logits[:, -1], temp, topp, topk, reqid, gen, base_key
             )
-            return next_tok, new_views
+            return next_tok, state_from_views(new_views)
 
         # same lifecycle as decode: the old pool state dies with the call
-        # (absorb_views takes the returned arrays), so donation lets XLA
-        # scatter in place instead of copying every layer's pool per
-        # admitted prompt. CPU can't donate (every call would warn).
+        # (_absorb takes the returned state), and it comes back in the
+        # structure it went in (state_from_views), so the donated pools
+        # are scattered into in place. CPU can't donate (every call
+        # would warn).
         donate = (1,) if self._jax.default_backend() != "cpu" else ()
         return self._jax.jit(prefill, donate_argnums=donate)
 
@@ -592,8 +594,9 @@ class ServeEngine:
             next_tok = self._sample_last(
                 last, temp, topp, topk, reqid, gen, base_key
             )
-            return next_tok, new_views
+            return next_tok, state_from_views(new_views)
 
+        # donated and handed back as it came, as in decode
         donate = (1,) if self._jax.default_backend() != "cpu" else ()
         return self._jax.jit(chunk_prefill, donate_argnums=donate)
 
@@ -609,11 +612,13 @@ class ServeEngine:
             next_tok = self._sample_last(
                 logits[:, -1], temps, topps, topks, reqids, gens, base_key
             )
-            return next_tok, new_views
+            return next_tok, state_from_views(new_views)
 
-        # the pool state dies with each call — donating it lets XLA run
-        # the scatter updates in place instead of copying every pool
-        # block per token. CPU can't donate (every call would warn).
+        # the pool state dies with each call and comes back in the
+        # structure it went in (state_from_views), so each donated pool
+        # is aliased to the output computed from it and scattered into in
+        # place (the alias table is pinned in test_kvcache.py). CPU can't
+        # donate (every call would warn).
         donate = (1,) if self._jax.default_backend() != "cpu" else ()
         return self._jax.jit(decode, donate_argnums=donate)
 
@@ -672,8 +677,10 @@ class ServeEngine:
             )
             if routed:
                 sampled = jnp.concatenate([sampled.reshape(-1), load[0]])
-            return sampled, new_views
+            return sampled, state_from_views(new_views)
 
+        # donated and handed back as it came, as in decode: anything else
+        # costs a copy of every layer's whole pool every tick
         donate = (1,) if self._jax.default_backend() != "cpu" else ()
         return self._jax.jit(mixed, donate_argnums=donate)
 
@@ -726,14 +733,14 @@ class ServeEngine:
                 tokens, block_row, np.int32(len(prompt)),
                 *self._scalar_sample_args(seq),
             ))
-            next_tok, new_views = self._prefill_fns[bucket](
+            next_tok, state = self._prefill_fns[bucket](
                 self.inf.params, self._pool_state(), *operands,
                 self._base_key,
             )
             # deliberate sync: the prefilled token must land on host to
             # be emitted (one pull per prefill, inside the measured span)
             tok = int(np.asarray(next_tok)[0])  # sta: disable=STA010
-        self._absorb(new_views)
+        self._absorb(state)
         now = time.monotonic()
         slot = seq.slot
         self._tables[slot] = block_row
@@ -773,14 +780,14 @@ class ServeEngine:
                 np.asarray([n_real], np.int32),
                 *self._scalar_sample_args(seq),
             ))
-            next_tok, new_views = self._chunk_fns[chunk](
+            next_tok, state = self._chunk_fns[chunk](
                 self.inf.params, self._pool_state(), *operands,
                 self._base_key,
             )
             # deliberate sync: the chunk's sampled token must land on
             # host (one pull per chunk, inside the measured span)
             tok = int(np.asarray(next_tok)[0])  # sta: disable=STA010
-        self._absorb(new_views)
+        self._absorb(state)
         slot = seq.slot
         self._tables[slot] = block_row
         self._ctx[slot] = start + n_real
@@ -817,14 +824,14 @@ class ServeEngine:
                 tables, ctx, self._tok, self._temp, self._topp,
                 self._topk, self._reqid, self._gen,
             ))
-            next_tok, new_views = self._decode_fn(
+            next_tok, state = self._decode_fn(
                 self.inf.params, self._pool_state(), *operands,
                 self._base_key,
             )
             # the tick's ONE deliberate device->host pull: sampled tokens
             # must land on host to be emitted to callers
             toks = np.asarray(next_tok)  # sta: disable=STA010
-        self._absorb(new_views)
+        self._absorb(state)
         now = time.monotonic()
         for seq in decodes:
             slot = seq.slot
@@ -916,7 +923,7 @@ class ServeEngine:
                     tables, ctx, tokens, new_lens, self._temp, self._topp,
                     self._topk, self._reqid, gen0,
                 ))
-                sampled, new_views = self._mixed_fns[width](
+                sampled, state = self._mixed_fns[width](
                     self.inf.params, self._pool_state(), *operands,
                     self._base_key,
                 )
@@ -930,7 +937,7 @@ class ServeEngine:
                 load = host_samples[n * sw:]
                 host_samples = host_samples[:n * sw].reshape(n, sw)
                 self._record_moe_load(load, emit)
-            self._absorb(new_views)
+            self._absorb(state)
             now = time.monotonic()
             for seq, start, n_real in chunk_rows:
                 slot = seq.slot

@@ -81,12 +81,39 @@ def build_layer_views(
     ]
 
 
+def state_from_views(views: List[PagedKVCacheView]) -> Tuple:
+    """Inverse of :func:`build_layer_views`: the updated pools of the
+    per-layer views ``_run_layers`` / ``write_prompt_kv`` return, as the
+    state tuple ``(pool_k, pool_v, scale_k, scale_v)`` the program took
+    (``None`` scales stay ``None``).
+
+    What EVERY engine program returns beside its tokens. JAX pairs a
+    donated buffer with an output of its shape and dtype in FLATTENED
+    order, so only a state that leaves in the structure it entered in
+    aliases ``pool_k[i]`` to the output computed from ``pool_k[i]``, and
+    only then does XLA run the layer's scatter in place. The views
+    themselves flatten layer-major (``k0, v0, table, ctx, new_len, k1,
+    ...``): returned as they are they hand ``pool_k[1]``'s buffer to
+    output ``v0``, written before layer 1 has read it, and XLA copies
+    every pool but the first on every call. Their table, lengths and
+    ``new_len`` are the program's inputs and do not come back."""
+    quantized = views[0].scale_k is not None
+    return (
+        [v.pool_k for v in views],
+        [v.pool_v for v in views],
+        [v.scale_k for v in views] if quantized else None,
+        [v.scale_v for v in views] if quantized else None,
+    )
+
+
 class PagedKVPools:
     """Per-layer block pools (the engine builds per-layer views from the
-    raw state inside its jitted programs — ``_views_from_state``).
+    raw state inside its jitted programs — ``build_layer_views``).
 
-    Pytree-friendly: the device state is plain lists of arrays so the
-    jitted prefill/decode programs thread it straight through."""
+    Pytree-friendly: the device state is plain lists of arrays, handed to
+    the jitted programs as ``(pool_k, pool_v, scale_k, scale_v)`` and
+    taken back in that same structure (``state_from_views``), which is
+    what lets the donated pools be updated in place."""
 
     def __init__(self, pool_k: List[jax.Array], pool_v: List[jax.Array],
                  scale_k: Optional[List[jax.Array]],
@@ -110,13 +137,9 @@ class PagedKVPools:
     def num_blocks(self) -> int:
         return self.pool_k[0].shape[0]
 
-    def absorb_views(self, views: List[PagedKVCacheView]) -> None:
-        """Take back the updated pools a jitted program returned."""
-        self.pool_k = [v.pool_k for v in views]
-        self.pool_v = [v.pool_v for v in views]
-        if self.quantized:
-            self.scale_k = [v.scale_k for v in views]
-            self.scale_v = [v.scale_v for v in views]
+    def absorb_state(self, state: Tuple) -> None:
+        """Take back the updated state a jitted program returned."""
+        self.pool_k, self.pool_v, self.scale_k, self.scale_v = state
 
     def device_bytes(self) -> int:
         total = 0
@@ -152,7 +175,7 @@ def init_pools(inference_module, num_blocks: int, block_size: int,
     # commit the fresh pools to the device(s) the programs will run on:
     # an uncommitted zeros-array keys a SECOND executable-cache entry for
     # the engine's very first program call (every later call sees the
-    # committed jit outputs absorb_views hands back) — a silent 2x
+    # committed jit outputs absorb_state hands back) — a silent 2x
     # compile of the largest serving programs
     mesh = serving_mesh(inference_module)
     if mesh is None:

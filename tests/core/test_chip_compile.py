@@ -13,6 +13,7 @@ read back without one.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -136,3 +137,93 @@ def test_paged_kernel_compiles(one_chip, slots, q_heads, kv_heads, s, kv_dtype):
         shape((slots,), jnp.int32), scales,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_mixed_program_updates_its_donated_pools_in_place(one_chip, monkeypatch):
+    """The serve tick's program compiled with donation on, as the chip
+    runs it (ISSUE 31): XLA pairs every layer's K and V pool with the
+    output computed from it and copies no pool. What the CPU cannot show:
+    the lowered alias table (tests/core/test_serve/test_kvcache.py) says
+    which output a donated buffer is offered to, the compiled module says
+    whether the scatter then ran in place. A state returned as per-layer
+    views left a `copy` of each misaligned pool in this text."""
+    from scaling_tpu.models.transformer import TransformerConfig
+    from scaling_tpu.models.transformer.inference import (
+        TransformerInferenceModule,
+    )
+    from scaling_tpu.models.transformer.model import init_model
+    from scaling_tpu.serve.engine import EngineConfig, ServeEngine
+
+    monkeypatch.setattr(
+        "scaling_tpu.nn.paged_attention.paged_kernel_interpret",
+        lambda platform=None: False,
+    )
+    layers, slots, max_blocks = 2, 4, 16
+    # Mistral-7B's attention (benchmark/configs/mistral-7b-v0.3-serve.json)
+    # over a narrow MLP and vocabulary; the weights stay abstract
+    config = TransformerConfig.from_dict({
+        "topology": {
+            "model_parallel_size": 1, "pipe_parallel_size": 1,
+            "data_parallel_size": 1, "micro_batch_size": 1,
+            "gradient_accumulation_steps": 1,
+        },
+        "transformer_architecture": {
+            "vocab_size": 512, "hidden_size": 32 * HEAD_DIM,
+            "num_layers": layers, "num_attention_heads": 32,
+            "attention_num_kv_heads": 8, "attention_qkv_in_one": False,
+            "attention_bias": False, "mlp_type": "swiglu",
+            "mlp_factor": 0.25, "mlp_bias": False, "norm_type": "rms",
+            "relative_position_embedding_type": "rotary",
+            "sequence_length": BLOCK_SIZE * max_blocks,
+            "precision": "bfloat16", "weight_tying": False,
+        },
+        "optimizer": {"gradient_clipping": 1.0},
+        "learning_rate_scheduler": {
+            "learning_rate": 3e-4, "learning_rate_warmup_steps": 10,
+            "learning_rate_decay_iters": 100,
+        },
+        "trainer": {"train_iterations": 1, "seed": 0},
+    })
+    module = init_model(config, None)
+    params = jax.eval_shape(module.init_params, jax.random.PRNGKey(0))
+    engine = ServeEngine(
+        TransformerInferenceModule(config, module, params),
+        EngineConfig(num_slots=slots, block_size=BLOCK_SIZE,
+                     num_blocks=slots * max_blocks + 1,
+                     max_blocks_per_seq=max_blocks, prefill_chunk=32),
+    )
+    width = engine.config.mixed_width
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def rows(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct((slots, *shape), dtype, sharding=one_chip)
+
+    state = engine._pool_state()
+    pool = state[0][0]
+    assert pool.shape == (slots * max_blocks + 1, BLOCK_SIZE, 8, HEAD_DIM)
+    text = jax.jit(
+        engine._build_mixed_fn(width).__wrapped__, donate_argnums=(1,),
+        keep_unused=True,
+    ).lower(
+        jax.tree_util.tree_map(on_chip, params),
+        jax.tree_util.tree_map(on_chip, state),
+        rows(max_blocks), rows(), rows(width), rows(),
+        rows(dtype=jnp.float32), rows(dtype=jnp.float32), rows(), rows(),
+        rows(), on_chip(engine._base_key),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == layers  # the kernel, compiled
+
+    # parameters flatten (params, pool_k[0..], pool_v[0..], operands);
+    # outputs (tokens, pool_k[0..], pool_v[0..])
+    first = len(jax.tree_util.tree_leaves(params))
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    pairs = {
+        int(param): int(out) for out, param in
+        re.findall(r"\{(\d+)\}: \((\d+), \{\}, \S+-alias\)", aliases)
+    }
+    assert pairs == {first + j: 1 + j for j in range(2 * layers)}
+    dims = ",".join(map(str, pool.shape))
+    copies = re.findall(rf"= bf16\[{dims}\]\S* copy\S*\(", text)
+    assert not copies, f"{len(copies)} whole-pool copies in the compiled tick"
