@@ -416,9 +416,8 @@ def phase_serve(size, args, device) -> dict:
         max_blocks_per_seq=blocks_per_seq,
     ))
     cfg = engine.config
-    if (cfg.paged_kernel, cfg.fused, cfg.prefill_chunk,
-            cfg.enable_prefix_cache, cfg.block_size) != (
-            "pallas", True, PREFILL_CHUNK, True, BLOCK_SIZE):
+    if (cfg.prefill_chunk, cfg.enable_prefix_cache, cfg.block_size) != (
+            PREFILL_CHUNK, True, BLOCK_SIZE):
         sys.exit(f"chip_smoke: the engine's defaults moved: {cfg}")
     workload = sample_workload(
         size["requests"], rate=4.0, prompt_len=size["prompt_len"],

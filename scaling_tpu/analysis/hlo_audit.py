@@ -476,12 +476,9 @@ def audit_serve_decode_section(num_slots=2, block_size=4,
     shape config, and NOTHING per-request — a scheduler change that
     moves prompt lengths, prefill offsets, or draft contents into the
     signature shows up as golden drift here, not as a compile storm on
-    the chip. The static config also pins the paged-attention back-end
-    and the legacy prefill bucket ladder's floor (policy drift moves the
-    hash even though legacy prefill lowers per bucket), and
-    ``pallas_custom_calls`` counts the paged-attention kernel's custom
-    calls in the lowered HLO (0 off-TPU where the kernel runs
-    interpreted).
+    the chip. ``pallas_custom_calls`` counts the paged-attention
+    kernel's custom calls in the lowered HLO (0 off-TPU where the kernel
+    runs interpreted).
 
     ``mp > 1`` lowers the SHARDED mixed program (ISSUE 14): the engine's
     KV pools shard over the model axis, the program partitions SPMD over
@@ -496,9 +493,7 @@ def audit_serve_decode_section(num_slots=2, block_size=4,
         TransformerInferenceModule,
     )
     from scaling_tpu.models.transformer.model import init_model
-    from scaling_tpu.serve.engine import (
-        MIN_PREFILL_BUCKET, EngineConfig, ServeEngine,
-    )
+    from scaling_tpu.serve.engine import EngineConfig, ServeEngine
 
     config = make_train_config(mp=mp)
     topology = None
@@ -539,8 +534,6 @@ def audit_serve_decode_section(num_slots=2, block_size=4,
         "kind": "serve_mixed_step", "num_slots": num_slots,
         "block_size": block_size, "max_blocks_per_seq": max_blocks,
         "kv_dtype": engine.config.kv_dtype,
-        "min_prefill_bucket": MIN_PREFILL_BUCKET,
-        "paged_kernel": engine.config.paged_kernel,
         "prefill_chunk": prefill_chunk,
         "spec_k": spec_k,
         "mixed_width": width,

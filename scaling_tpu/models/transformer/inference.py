@@ -324,11 +324,10 @@ class TransformerInferenceModule:
         """One pass through the stack; TransformerLayers consume/produce the
         KV caches, edge layers run as in training (deterministic).
 
-        ``paged_kernel`` (static; serving engine only) selects the
-        attention back-end for block-paged caches: 'pallas' streams KV
-        blocks through the flash-style kernel (nn/paged_attention.py),
-        'xla' gathers each row's window (the fallback). Dense caches
-        ignore it.
+        ``paged_kernel`` (static) overrides the attention back-end for
+        block-paged caches, ``ForwardContext.paged_kernel``: by default
+        the Pallas kernel; ``'xla'`` is the gather formulation that tests
+        hold the kernel to. Dense caches ignore it.
 
         ``gather_start`` (a traced per-row (b,) start index) with
         ``gather_width`` (static) slices each row's window of trunk

@@ -70,9 +70,9 @@ class PagedKVCacheView(NamedTuple):
     ``new_len`` (per row, optional) is how many of the ``s`` presented
     tokens are REAL: a prefill CHUNK padded to its fixed program shape
     routes its pad tokens' KV to the trash block and excludes their
-    slots from every mask, so one compiled chunk program serves every
-    chunk length (Sarathi-style chunked prefill, serve/engine.py).
-    ``None`` means all ``s`` tokens are real (the decode step).
+    slots from every mask, so one compiled program serves every chunk
+    length (Sarathi-style chunked prefill, serve/engine.py). ``None``
+    means all ``s`` tokens are real.
     """
 
     pool_k: jax.Array
@@ -126,11 +126,10 @@ def paged_scatter_kv(view: PagedKVCacheView, flat: jax.Array,
                      k_rows: jax.Array, v_rows: jax.Array) -> PagedKVCacheView:
     """Scatter new K/V rows (``(n, n_kv, h)``) into the pool at flat
     slots ``flat`` (``(n,)``), quantizing when the pool is int8 — the ONE
-    pool writer shared by the decode step (``_paged_attention``) and the
-    prefill writer (serve/kvcache.py), so the cache a prompt left behind
-    and the cache decode appends to can never disagree about layout or
-    rounding. Returns the view with updated pools (tables/lengths
-    untouched)."""
+    pool writer (``_paged_attention`` calls it for chunk rows and decode
+    rows alike), so the cache a prompt left behind and the cache decode
+    appends to can never disagree about layout or rounding. Returns the
+    view with updated pools (tables/lengths untouched)."""
     num_blocks, block_size = view.pool_k.shape[0], view.pool_k.shape[1]
     flat_len = num_blocks * block_size
     pk = view.pool_k.reshape(flat_len, *view.pool_k.shape[2:])
@@ -629,16 +628,17 @@ class ParallelSelfAttention(BaseLayer):
         lives entirely in ``block_table``/``context_len``/``new_len``,
         never in shapes.
 
-        Two attention back-ends behind one scatter (``ctx.paged_kernel``):
+        Two formulations behind one scatter (``ctx.paged_kernel``):
 
-        - ``'pallas'`` — the flash-style streaming kernel
+        - ``'pallas'`` — what serves: the flash-style streaming kernel
           (nn/paged_attention.py): KV blocks DMA from the pool per row
           into an online softmax; no gathered window is materialized.
           Runs interpreted off-TPU, so the CPU mesh tests the real body.
-        - ``'xla'`` — the fallback: gather each row's blocks as one
-          contiguous (b, max_blocks*block_size, n_kv, h) window, then run
-          the unfused attention. Fine on CPU, pure extra HBM traffic on
-          a chip.
+        - ``'xla'`` — the tests' reference, not a serving option: gather
+          each row's blocks as one contiguous
+          (b, max_blocks*block_size, n_kv, h) window, then run the
+          unfused attention. Independent of the kernel, and pure extra
+          HBM traffic on a chip.
         """
         block_size = view.pool_k.shape[1]
         max_blocks = view.block_table.shape[1]
@@ -663,7 +663,7 @@ class ParallelSelfAttention(BaseLayer):
         )
 
         valid_len = ctx_len + new_len  # written slots per row
-        kernel = getattr(ctx, "paged_kernel", "xla")
+        kernel = ctx.paged_kernel
         if kernel == "pallas":
             import functools
 

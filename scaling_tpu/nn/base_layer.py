@@ -37,11 +37,12 @@ class ForwardContext:
     context_parallel_variant: str = "ring"
     # mesh is needed for explicit collectives; None on single device
     mesh: Optional[Any] = None
-    # paged-decode attention back-end (static): 'xla' gathers each row's
-    # block window, 'pallas' streams blocks through the flash-style
-    # kernel (nn/paged_attention.py). Only the serving engine's programs
-    # flip this (TransformerInferenceModule._run_layers paged_kernel=).
-    paged_kernel: str = "xla"
+    # paged-decode attention back-end (static), decided HERE: 'pallas'
+    # streams blocks through the flash-style kernel
+    # (nn/paged_attention.py) and is what serves; 'xla' gathers each
+    # row's block window, the formulation tests hold the kernel to
+    # (TransformerInferenceModule._run_layers paged_kernel='xla').
+    paged_kernel: str = "pallas"
     # an inference pass (static): TransformerInferenceModule sets it on
     # every context it makes (generate, logits, the serving engine's
     # programs). A routed MLP then drops no assignment and computes no

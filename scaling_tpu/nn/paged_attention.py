@@ -63,8 +63,7 @@ trash block.
 
 Off-TPU the kernel runs with ``interpret=True`` (the whole grid, the DMAs
 and the semaphores execute as traced jax ops), so the CPU-mesh tests
-exercise the REAL kernel body, not a stand-in; the XLA gather branch stays
-config-selectable (``EngineConfig.paged_kernel = 'xla'``) as the fallback.
+exercise the REAL kernel body, not a stand-in.
 """
 
 from __future__ import annotations

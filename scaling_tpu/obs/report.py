@@ -748,16 +748,10 @@ def serving_section(data: RunData) -> Tuple[List[str], Dict[str, float]]:
             + f", top critical-path phase: {top} "
             f"({counts.get(top, 0)} trace(s)) — see `obs trace`"
         )
-    # tick-time attribution: where the engine's device time actually went
-    # (serve.prefill_chunk = chunked prefill, serve.prefill = whole-prompt
-    # buckets, serve.decode = the per-tick decode step). This is the rail
-    # a prefill/decode-mix perf claim is judged on — a chunking change
-    # that quietly starves decode shows up here, not in averages.
+    # tick-time attribution: the engine's program against the host-side
+    # drafting that speculation adds before it
     phases = (
         ("mixed", "serve.mixed"),
-        ("decode", "serve.decode"),
-        ("prefill-chunk", "serve.prefill_chunk"),
-        ("prefill", "serve.prefill"),
         ("draft", "serve.draft"),
     )
     sums: Dict[str, Tuple[float, int]] = {}

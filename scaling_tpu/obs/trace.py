@@ -16,10 +16,9 @@ Per trace the analyzer attributes wall time into phases:
   (``queue_wait_s`` of its ``serve-request`` record; a run dir from
   before that field: submission until the first compute span);
 - ``rpc``        — ``serve.replica.rpc_client`` time under the trace;
-- ``prefill``    — ``serve.prefill`` / ``serve.prefill_chunk`` plus the
-  chunk share of ``serve.mixed`` ticks (``chunk_traces``);
-- ``decode``     — ``serve.decode`` plus the decode share of
-  ``serve.mixed`` (``traces``);
+- ``prefill``    — the chunk share of ``serve.mixed`` ticks
+  (``chunk_traces``);
+- ``decode``     — the decode share of ``serve.mixed`` (``traces``);
 - ``failover``   — positive gaps where consecutive host-stamped records
   of the trace jump hosts (replica death + re-dispatch, or a
   backpressure retry elsewhere); zero for a healthy single-replica
@@ -57,15 +56,11 @@ SCHEMA_VERSION = 1
 
 PHASES = ("queue_wait", "rpc", "prefill", "decode", "failover", "other")
 
-# span name -> phase it feeds (mixed is split by which list carries the
-# trace id, so it is handled out of band)
 _RPC_SPANS = ("serve.replica.rpc_client",)
-_PREFILL_SPANS = ("serve.prefill", "serve.prefill_chunk")
-_DECODE_SPANS = ("serve.decode",)
+# the span that marks "the engine is working on this request" — the end
+# of queue_wait is the first of these; admit/rpc are submission
+# machinery. It feeds prefill or decode by which list carries the trace
 _MIXED_SPAN = "serve.mixed"
-# spans that mark "the engine is working on this request" — the end of
-# queue_wait is the first of these; admit/rpc are submission machinery
-_COMPUTE_SPANS = set(_PREFILL_SPANS + _DECODE_SPANS + (_MIXED_SPAN,))
 
 
 # ------------------------------------------------------------ assembly
@@ -146,10 +141,6 @@ def trace_phases(tid: str, recs: List[dict]) -> Dict[str, float]:
         dur = float(r.get("dur_s") or 0.0)
         if name in _RPC_SPANS:
             phases["rpc"] += dur
-        elif name in _PREFILL_SPANS:
-            phases["prefill"] += dur
-        elif name in _DECODE_SPANS:
-            phases["decode"] += dur
         elif name == _MIXED_SPAN:
             # one mixed tick serves chunked prefills AND decodes: the
             # list the id rides in says which side this trace was on
@@ -157,8 +148,8 @@ def trace_phases(tid: str, recs: List[dict]) -> Dict[str, float]:
                 phases["prefill"] += dur
             if tid in (r.get("traces") or ()):
                 phases["decode"] += dur
-        if name in _COMPUTE_SPANS and (first_compute is None
-                                       or r["_start"] < first_compute):
+        if name == _MIXED_SPAN and (first_compute is None
+                                    or r["_start"] < first_compute):
             first_compute = r["_start"]
     # the scheduler's own stamp (arrival to the first slot, on the
     # serve-request record) where the engine wrote one; older run dirs
@@ -226,7 +217,7 @@ def analyze(data: RunData,
         if not isinstance(tid, str):
             continue
         recs = traces.get(tid) or []
-        if any(rec.get("span") in _COMPUTE_SPANS or
+        if any(rec.get("span") == _MIXED_SPAN or
                rec.get("span") in _RPC_SPANS or
                rec.get("span") == "serve.admit" for rec in recs):
             covered += 1

@@ -9,15 +9,12 @@ The "millions of users" half of the north star: turns the single-request
 - :mod:`.scheduler` — continuous batching (Orca, OSDI '22): admission
   from a request queue, per-tick prefill/decode mixing under a token
   budget, preemption on pool exhaustion, completed-slot recycling.
-- :mod:`.engine` — the jitted device programs: ONE fused mixed program
+- :mod:`.engine` — the jitted device program: ONE fused mixed program
   per tick covering the whole slot set — prefill-chunk rows and
   decode rows (each carrying up to ``spec_k`` self-drafted speculative
   candidates, accepted pathwise-exactly at any temperature) tagged by
   traced lengths (paged attention streamed through the Pallas kernel
-  in ``nn/paged_attention.py`` by default, XLA gather as the
-  fallback); legacy separate decode/chunk programs behind
-  ``fused_tick=False``, bucketed whole-prompt prefill in
-  ``prefill_chunk=None`` mode; per-request temperature/top-k/top-p
+  in ``nn/paged_attention.py``); per-request temperature/top-k/top-p
   sampling as traced per-row arrays (no per-request recompiles;
   signatures pinned in the ``serve_decode`` HLO audit section). The
   scheduler's prefix trie (``PrefixCache``) maps shared-prompt blocks

@@ -885,9 +885,7 @@ def _run_fleet_proc(args, workload, run_dir, journal_base) -> dict:
             "num_blocks": args.num_blocks,
             "max_blocks_per_seq": args.max_blocks_per_seq,
             "token_budget": args.token_budget, "kv_dtype": args.kv_dtype,
-            "prefill_chunk": args.prefill_chunk or None,
-            "paged_kernel": args.paged_kernel,
-            "fused_tick": not args.no_fused_tick,
+            "prefill_chunk": args.prefill_chunk,
             "enable_prefix_cache": not args.no_prefix_cache,
             "spec_k": args.spec_k,
             "default_deadline_ms": args.deadline_ms,
@@ -1219,7 +1217,7 @@ def _run_fleet_proc(args, workload, run_dir, journal_base) -> dict:
             "num_slots": args.num_slots, "block_size": args.block_size,
             "num_blocks": args.num_blocks,
             "token_budget": args.token_budget,
-            "prefill_chunk": args.prefill_chunk or None,
+            "prefill_chunk": args.prefill_chunk,
             "spec_k": args.spec_k,
         },
         # the process-fleet story (obs report's fleet section + the
@@ -1384,13 +1382,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     parser.add_argument("--prefill-chunk", type=int, default=32,
                         help="Sarathi-style chunked prefill: tokens per "
                         "chunk (prompts stream into the pool sharing the "
-                        "tick budget with decodes); 0 = legacy "
-                        "whole-prompt prefill")
-    parser.add_argument("--paged-kernel", choices=["pallas", "xla"],
-                        default="pallas",
-                        help="paged-decode attention back-end: the "
-                        "streaming Pallas kernel (interpreted off-TPU) or "
-                        "the XLA block-window gather fallback")
+                        "tick budget with decodes)")
     parser.add_argument("--spec-k", type=int, default=0,
                         help="self-drafting speculative decoding: n-gram "
                         "draft tokens scored per decode row per tick "
@@ -1481,10 +1473,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     parser.add_argument("--no-prefix-cache", action="store_true",
                         help="disable shared-prefix block reuse (the A/B "
                         "for --shared-prefix-len)")
-    parser.add_argument("--no-fused-tick", action="store_true",
-                        help="legacy dispatch: separate decode + "
-                        "per-sequence chunk programs instead of ONE "
-                        "mixed program per tick")
     parser.add_argument("--warmup", type=int, default=0,
                         help="serve N throwaway requests (excluded from "
                         "stats) before the open-loop clock starts, so "
@@ -1692,9 +1680,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             num_blocks=args.num_blocks,
             max_blocks_per_seq=args.max_blocks_per_seq,
             token_budget=args.token_budget, kv_dtype=args.kv_dtype,
-            prefill_chunk=args.prefill_chunk or None,
-            paged_kernel=args.paged_kernel,
-            fused_tick=not args.no_fused_tick,
+            prefill_chunk=args.prefill_chunk,
             enable_prefix_cache=not args.no_prefix_cache,
             spec_k=args.spec_k if spec_k is None else spec_k,
             default_deadline_ms=args.deadline_ms,
@@ -1817,9 +1803,7 @@ def main(argv: Optional[List[str]] = None) -> int:
               f"timeouts={stats['requests_timeout']} "
               f"drained={stats['drained']} "
               f"unsubmitted={stats['unsubmitted']}")
-    print(f"  hot path: paged_kernel={args.paged_kernel} "
-          f"prefill_chunk={args.prefill_chunk or 'off'} "
-          f"fused_tick={not args.no_fused_tick} "
+    print(f"  hot path: prefill_chunk={args.prefill_chunk} "
           f"max_concurrent_prefills={stats['max_concurrent_prefills']}")
     if args.mp > 1:
         print(f"  sharding: mp={args.mp} (KV pools sharded over the "

@@ -2,19 +2,11 @@
 
 Per tick the scheduler mixes prompt prefill work with decode work for
 every running sequence; the engine runs it all as **ONE fused
-Sarathi-style mixed program** (default): every slot row is either a
-prefill CHUNK (prompts stream into the paged pool in fixed-size chunks)
-or a decode row carrying its last token plus up to ``spec_k``
-self-drafted speculative candidates — tagged purely by traced per-row
-lengths, so a tick with 4 prefilling prompts dispatches 1 executable,
-not 5. Two fallback dispatch modes survive behind config:
-
-- ``fused_tick=False``: the PR 10 separate programs — one decode
-  program over the whole slot set plus one chunk program call per
-  prefilling sequence (parity-pinned against the mixed program);
-- ``prefill_chunk=None``: legacy whole-prompt prefill through the SAME
-  ``prefill_forward`` the dense-cache generate path uses, compiled once
-  per pow2 prompt-length bucket.
+Sarathi-style mixed program**: every slot row is either a prefill CHUNK
+(prompts stream into the paged pool in fixed-size chunks) or a decode
+row carrying its last token plus up to ``spec_k`` self-drafted
+speculative candidates — tagged purely by traced per-row lengths, so a
+tick with 4 prefilling prompts dispatches 1 executable, not 5.
 
 Shared-prefix block reuse and speculative acceptance ride the tick
 (docs/SERVING.md "Raw speed"): the scheduler's prefix trie maps cached
@@ -26,9 +18,8 @@ because every scored position draws with the (request, position) key
 plain decode would use.
 
 Paged attention streams KV blocks through the Pallas paged-decode
-kernel by default (``paged_kernel='pallas'``, nn/paged_attention.py —
-interpreted off-TPU so the CPU mesh runs the real kernel body); the
-XLA gather path stays config-selectable (``paged_kernel='xla'``).
+kernel (nn/paged_attention.py — interpreted off-TPU so the CPU mesh
+runs the real kernel body).
 
 No per-request recompiles, by construction: the mixed program compiles
 once per ``(prefill_chunk, spec_k)`` width signature — its shapes are
@@ -64,7 +55,6 @@ from .kvcache import (
     init_pools,
     serving_mesh,
     state_from_views,
-    write_prompt_kv,
 )
 from .scheduler import (
     Backpressure,
@@ -75,18 +65,6 @@ from .scheduler import (
     Tick,
 )
 
-MIN_PREFILL_BUCKET = 8
-
-
-def prefill_bucket(prompt_len: int) -> int:
-    """Power-of-two length ladder; every prompt length in a bucket shares
-    one compiled prefill program (whole-prompt mode only)."""
-    b = MIN_PREFILL_BUCKET
-    while b < prompt_len:
-        b *= 2
-    return b
-
-
 @dataclasses.dataclass
 class EngineConfig:
     num_slots: int = 8
@@ -95,21 +73,10 @@ class EngineConfig:
     max_blocks_per_seq: int = 16
     token_budget: int = 512
     kv_dtype: str = "native"  # 'native' | 'int8'
-    # Sarathi-style chunked prefill (tokens per chunk); None = legacy
-    # whole-prompt prefill through the pow2 bucket ladder
-    prefill_chunk: Optional[int] = 32
-    # paged-decode attention back-end: 'pallas' streams KV blocks through
-    # the flash-style kernel (nn/paged_attention.py; interpreted off-TPU),
-    # 'xla' gathers each row's whole block window (the fallback)
-    paged_kernel: str = "pallas"
-    # ONE fused mixed program per tick (Sarathi piggybacking): every
-    # row is a decode row (s>=1 with speculative drafts) or a prefill
-    # chunk, tagged by traced lengths — a tick with 4 prefilling prompts
-    # dispatches 1 program, not 5. Chunked mode only; False falls back
-    # to the PR 10 separate decode + per-sequence chunk programs.
-    fused_tick: bool = True
+    # Sarathi-style chunked prefill: prompt tokens per chunk row
+    prefill_chunk: int = 32
     # shared-prefix KV block reuse (RadixAttention-style trie admission;
-    # chunked mode only — see SchedulerConfig.prefix_cache)
+    # see SchedulerConfig.prefix_cache)
     enable_prefix_cache: bool = True
     # self-drafting speculative decoding: n-gram drafts scored k-at-once
     # through the mixed program's s>1 rows; 0 = off
@@ -138,23 +105,9 @@ class EngineConfig:
     replica_id: Optional[int] = None
 
     def __post_init__(self):
-        if self.paged_kernel not in ("pallas", "xla"):
-            raise ValueError(
-                f"paged_kernel must be 'pallas' or 'xla', "
-                f"got {self.paged_kernel!r}"
-            )
-        if self.spec_k > 0 and (self.prefill_chunk is None
-                                or not self.fused_tick):
-            raise ValueError(
-                "spec_k > 0 needs chunked prefill AND the fused mixed "
-                "program (drafts are scored through its s>1 rows)"
-            )
-
-    @property
-    def fused(self) -> bool:
-        """The mixed program replaces decode + chunk dispatch (chunked
-        mode only — whole-prompt mode keeps its bucket ladder)."""
-        return self.fused_tick and self.prefill_chunk is not None
+        # the scheduler's checks (prefill_chunk, spec_k, the watermarks)
+        # where the value was written, not at the engine's first tick
+        self.scheduler_config()
 
     @property
     def mixed_width(self) -> int:
@@ -162,7 +115,7 @@ class EngineConfig:
         ``prefill_chunk`` slots, speculative decode rows ``spec_k + 1``
         (last accepted token + k drafts). One program per (chunk, k)
         signature — the recompile key the serve_decode golden pins."""
-        return max(self.prefill_chunk or 1, self.spec_k + 1)
+        return max(self.prefill_chunk, self.spec_k + 1)
 
     @property
     def sample_width(self) -> int:
@@ -183,7 +136,7 @@ class EngineConfig:
             token_budget=self.token_budget,
             prefill_chunk=self.prefill_chunk,
             prefix_cache=self.enable_prefix_cache,
-            spec_k=self.spec_k if self.fused else 0,
+            spec_k=self.spec_k,
             shed_high_watermark=self.shed_high_watermark,
             shed_low_watermark=self.shed_low_watermark,
             max_waiting=self.max_waiting,
@@ -236,9 +189,6 @@ class ServeEngine:
         self._base_key = self._dev(
             jax.random.PRNGKey(self.config.sample_seed)
         )
-        self._decode_fn = None
-        self._prefill_fns: Dict[int, object] = {}  # whole-prompt buckets
-        self._chunk_fns: Dict[int, object] = {}  # chunk-size -> program
         # (width,) -> the ONE fused mixed program per (chunk, k) signature
         self._mixed_fns: Dict[int, object] = {}
         # a routed model (mlp_type moe): the mixed program also returns,
@@ -485,19 +435,6 @@ class ServeEngine:
                 out.append(tid)
         return {key: out} if out else {}
 
-    def _sample_last(self, logits, temps, topps, topks, reqids, gens,
-                     base_key):
-        """Shared sampling epilogue: per-row keys from (request, position),
-        then the per-row temperature/top-k/top-p sampler."""
-        from ..models.transformer.inference import (
-            request_sample_key, sample_rows,
-        )
-
-        keys = self._jax.vmap(
-            request_sample_key, in_axes=(None, 0, 0)
-        )(base_key, reqids, gens)
-        return sample_rows(logits, temps, topks, keys, top_ps=topps)
-
     def _sample_grid(self, logits, temps, topps, topks, reqids, gen0,
                      base_key):
         """Sample EVERY position of a (rows, s, vocab) logit grid with
@@ -507,8 +444,8 @@ class ServeEngine:
         temperature — the verifier computes the very token plain decode
         would have emitted, not merely one from the same distribution —
         and what lets chunk rows sample their first token at the last
-        real position with the same key the legacy chunk program used
-        (``gen0`` is per-row: chunk rows offset it so position
+        real position with the key of the request's first generated
+        token (``gen0`` is per-row: chunk rows offset it so position
         ``new_len - 1`` folds the true generated count)."""
         from ..models.transformer.inference import (
             request_sample_key, sample_rows,
@@ -531,104 +468,12 @@ class ServeEngine:
         )
         return flat.reshape(rows, s)
 
-    def _build_prefill_fn(self, bucket: int):
-        jnp = self._jax.numpy
-        block_size = self.config.block_size
-
-        def prefill(params, state, tokens, block_row, prompt_len,
-                    temp, topp, topk, reqid, gen, base_key):
-            b, L = tokens.shape  # (1, bucket)
-            pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[None], (b, L))
-            # bucket padding sits in its own segment: content never
-            # attends to it, it never attends to content
-            seg = jnp.where(pos < prompt_len, 0, 1).astype(jnp.int32)
-            logits, kvs = self.inf.prefill_forward(
-                params, tokens, pos, seg, last_index=prompt_len - 1
-            )
-            views = self._views_from_state(
-                state, block_row[None, :], jnp.zeros((1,), jnp.int32)
-            )
-            new_views = [
-                write_prompt_kv(view, k, v, block_row, prompt_len, block_size)
-                for view, (k, v) in zip(views, kvs)
-            ]
-            next_tok = self._sample_last(
-                logits[:, -1], temp, topp, topk, reqid, gen, base_key
-            )
-            return next_tok, state_from_views(new_views)
-
-        # same lifecycle as decode: the old pool state dies with the call
-        # (_absorb takes the returned state), and it comes back in the
-        # structure it went in (state_from_views), so the donated pools
-        # are scattered into in place. CPU can't donate (every call
-        # would warn).
-        donate = (1,) if self._jax.default_backend() != "cpu" else ()
-        return self._jax.jit(prefill, donate_argnums=donate)
-
-    def _build_chunk_fn(self, chunk: int):
-        """ONE compiled program per chunk size: scatter the chunk's KV at
-        the sequence's next slots and attend over the pool — the same
-        paged path decode uses, so a chunk sees every previous chunk's KV
-        without any per-prompt-length shapes. ``new_len`` routes the
-        final ragged chunk's padding to the trash block."""
-        jnp = self._jax.numpy
-
-        def chunk_prefill(params, state, tokens, block_row, ctx_len, new_len,
-                          temp, topp, topk, reqid, gen, base_key):
-            b, L = tokens.shape  # (1, chunk)
-            pos = ctx_len[:, None] + jnp.arange(L, dtype=jnp.int32)[None, :]
-            batch = self.inf._make_batch(tokens, pos)
-            views = self._views_from_state(
-                state, block_row[None, :], ctx_len, new_len
-            )
-            logits, new_views = self.inf._run_layers(
-                params, batch, views, None,
-                paged_kernel=self.config.paged_kernel,
-            )
-            # the chunk's last REAL position predicts the next token; it
-            # only counts when this chunk completes the prompt (host-side
-            # decision — mid-prompt samples are discarded)
-            last = self._jax.lax.dynamic_slice_in_dim(
-                logits, new_len[0] - 1, 1, axis=1
-            )[:, 0]
-            next_tok = self._sample_last(
-                last, temp, topp, topk, reqid, gen, base_key
-            )
-            return next_tok, state_from_views(new_views)
-
-        # donated and handed back as it came, as in decode
-        donate = (1,) if self._jax.default_backend() != "cpu" else ()
-        return self._jax.jit(chunk_prefill, donate_argnums=donate)
-
-    def _build_decode_fn(self):
-        def decode(params, state, tables, ctx_lens, tokens,
-                   temps, topps, topks, reqids, gens, base_key):
-            batch = self.inf._make_batch(tokens[:, None], ctx_lens[:, None])
-            views = self._views_from_state(state, tables, ctx_lens)
-            logits, new_views = self.inf._run_layers(
-                params, batch, views, None,
-                paged_kernel=self.config.paged_kernel,
-            )
-            next_tok = self._sample_last(
-                logits[:, -1], temps, topps, topks, reqids, gens, base_key
-            )
-            return next_tok, state_from_views(new_views)
-
-        # the pool state dies with each call and comes back in the
-        # structure it went in (state_from_views), so each donated pool
-        # is aliased to the output computed from it and scattered into in
-        # place (the alias table is pinned in test_kvcache.py). CPU can't
-        # donate (every call would warn).
-        donate = (1,) if self._jax.default_backend() != "cpu" else ()
-        return self._jax.jit(decode, donate_argnums=donate)
-
     def _build_mixed_fn(self, width: int):
         """ONE fused Sarathi-style program per tick: every slot row is a
         decode row (its last token plus up to ``spec_k`` drafted
         candidates) or a prefill chunk, tagged purely by traced per-row
-        lengths — a tick that used to dispatch one decode program plus
-        one chunk program PER prefilling sequence now dispatches exactly
-        one executable. Rows share the scatter-then-attend paged path
+        lengths — a tick dispatches exactly one executable, however many
+        sequences are prefilling. Rows share the scatter-then-attend paged path
         (``new_len`` routes each row's pads to the trash block; rows
         never share pool blocks, so fusing their writes is exact), and
         EVERY position is sampled with its plain-decode key
@@ -665,7 +510,6 @@ class ServeEngine:
             g0 = jnp.clip(new_lens - sample_width, 0, width - sample_width)
             logits, new_views, *load = self.inf._run_layers(
                 params, batch, views, None,
-                paged_kernel=self.config.paged_kernel,
                 gather_start=g0, gather_width=sample_width,
                 moe_load=routed,
             )
@@ -679,8 +523,13 @@ class ServeEngine:
                 sampled = jnp.concatenate([sampled.reshape(-1), load[0]])
             return sampled, state_from_views(new_views)
 
-        # donated and handed back as it came, as in decode: anything else
-        # costs a copy of every layer's whole pool every tick
+        # the pool state dies with each call (_absorb takes the returned
+        # one) and comes back in the structure it went in
+        # (state_from_views), so each donated pool is aliased to the
+        # output computed from it and scattered into in place (the alias
+        # table is pinned in test_kvcache.py): anything else costs a copy
+        # of every layer's whole pool every tick. CPU can't donate (every
+        # call would warn).
         donate = (1,) if self._jax.default_backend() != "cpu" else ()
         return self._jax.jit(mixed, donate_argnums=donate)
 
@@ -703,143 +552,6 @@ class ServeEngine:
         self._topk[slot] = seq.request.top_k or 0
         self._topp[slot] = seq.request.top_p or 0.0
         self._reqid[slot] = seq.request.req_id
-
-    def _scalar_sample_args(self, seq: Sequence):
-        np = self._np
-        return (
-            np.asarray([seq.request.temperature], np.float32),
-            np.asarray([seq.request.top_p or 0.0], np.float32),
-            np.asarray([seq.request.top_k or 0], np.int32),
-            np.asarray([seq.request.req_id], np.int32),
-            np.asarray([len(seq.generated)], np.int32),
-        )
-
-    def _run_prefill(self, seq: Sequence) -> None:
-        """Whole-prompt prefill (legacy mode): one pow2-bucketed program
-        pass over the entire resume prompt."""
-        np = self._np
-        prompt = seq.resume_prompt
-        bucket = prefill_bucket(len(prompt))
-        if bucket not in self._prefill_fns:
-            self._prefill_fns[bucket] = self._build_prefill_fn(bucket)
-        tokens = np.zeros((1, bucket), np.int32)
-        tokens[0, :len(prompt)] = prompt
-        block_row = np.zeros((self.config.max_blocks_per_seq,), np.int32)
-        block_row[:len(seq.blocks)] = seq.blocks
-        self._admit_slot(seq)
-        with self._span("serve.prefill", step=self.tick_index,
-                      tokens=len(prompt), **self._trace_fields([seq])):
-            operands = self._dev((
-                tokens, block_row, np.int32(len(prompt)),
-                *self._scalar_sample_args(seq),
-            ))
-            next_tok, state = self._prefill_fns[bucket](
-                self.inf.params, self._pool_state(), *operands,
-                self._base_key,
-            )
-            # deliberate sync: the prefilled token must land on host to
-            # be emitted (one pull per prefill, inside the measured span)
-            tok = int(np.asarray(next_tok)[0])  # sta: disable=STA010
-        self._absorb(state)
-        now = time.monotonic()
-        slot = seq.slot
-        self._tables[slot] = block_row
-        self._ctx[slot] = len(prompt)
-        self._tok[slot] = tok
-        seq.num_cached = len(prompt)
-        self._emit_token(seq, tok, now)
-        if not self.warmup_mode:
-            self.prefilled_tokens += len(prompt)
-            self._counter("serve_prefill_tokens_total").inc(len(prompt))
-
-    def _run_prefill_chunk(self, seq: Sequence) -> None:
-        """One fixed-size chunk of ``seq``'s prompt: scatter its KV into
-        the pool (pads to trash) and, when it completes the prompt, emit
-        the first token."""
-        np = self._np
-        chunk = self.config.prefill_chunk
-        if chunk not in self._chunk_fns:
-            self._chunk_fns[chunk] = self._build_chunk_fn(chunk)
-        prompt = seq.resume_prompt
-        start = seq.num_cached
-        n_real = min(chunk, len(prompt) - start)
-        assert n_real > 0, "chunk scheduled for a fully-prefilled sequence"
-        tokens = np.zeros((1, chunk), np.int32)
-        tokens[0, :n_real] = prompt[start:start + n_real]
-        block_row = np.zeros((self.config.max_blocks_per_seq,), np.int32)
-        block_row[:len(seq.blocks)] = seq.blocks
-        if start == seq.prefix_cached:
-            # first chunk of this admission (a prefix hit starts past 0)
-            self._admit_slot(seq)
-        finishing = start + n_real == len(prompt)
-        with self._span("serve.prefill_chunk", step=self.tick_index,
-                      tokens=n_real, start=start,
-                      **self._trace_fields([seq])):
-            operands = self._dev((
-                tokens, block_row, np.asarray([start], np.int32),
-                np.asarray([n_real], np.int32),
-                *self._scalar_sample_args(seq),
-            ))
-            next_tok, state = self._chunk_fns[chunk](
-                self.inf.params, self._pool_state(), *operands,
-                self._base_key,
-            )
-            # deliberate sync: the chunk's sampled token must land on
-            # host (one pull per chunk, inside the measured span)
-            tok = int(np.asarray(next_tok)[0])  # sta: disable=STA010
-        self._absorb(state)
-        slot = seq.slot
-        self._tables[slot] = block_row
-        self._ctx[slot] = start + n_real
-        seq.num_cached = start + n_real
-        if not self.warmup_mode:
-            self.prefilled_tokens += n_real
-            self._counter("serve_prefill_tokens_total").inc(n_real)
-        if finishing:
-            self._tok[slot] = tok
-            self._emit_token(seq, tok, time.monotonic())
-
-    def _run_decode(self, decodes: List[Sequence]) -> None:
-        np = self._np
-        if self._decode_fn is None:
-            self._decode_fn = self._build_decode_fn()
-        active = np.zeros((self.config.num_slots,), bool)
-        for seq in decodes:
-            # the scheduler may have grown this row's block list since the
-            # table row was last written (incremental allocation)
-            row = self._tables[seq.slot]
-            row[:] = 0
-            row[:len(seq.blocks)] = seq.blocks
-            self._gen[seq.slot] = len(seq.generated)
-            active[seq.slot] = True
-        # rows not decoding this tick (empty, or mid-prefill under
-        # chunked prefill) run against an all-trash table with ctx 0:
-        # their device-side writes can never land in blocks a prefilling
-        # sequence is about to fill
-        tables = np.where(active[:, None], self._tables, 0)
-        ctx = np.where(active, self._ctx, 0)
-        with self._span("serve.decode", step=self.tick_index,
-                      batch=len(decodes), **self._trace_fields(decodes)):
-            operands = self._dev((
-                tables, ctx, self._tok, self._temp, self._topp,
-                self._topk, self._reqid, self._gen,
-            ))
-            next_tok, state = self._decode_fn(
-                self.inf.params, self._pool_state(), *operands,
-                self._base_key,
-            )
-            # the tick's ONE deliberate device->host pull: sampled tokens
-            # must land on host to be emitted to callers
-            toks = np.asarray(next_tok)  # sta: disable=STA010
-        self._absorb(state)
-        now = time.monotonic()
-        for seq in decodes:
-            slot = seq.slot
-            self._ctx[slot] += 1
-            seq.num_cached += 1
-            tok = int(toks[slot])
-            self._tok[slot] = tok
-            self._emit_token(seq, tok, now)
 
     def _apply_cow(self, pairs) -> None:
         """Copy-on-write block forks the scheduler ordered this tick:
@@ -1132,8 +844,8 @@ class ServeEngine:
         """One engine step, each phase a span at the place of the work
         (docs/OBSERVABILITY.md "Span taxonomy"): ``serve.schedule``
         (expire deadlines, draft speculative candidates, schedule),
-        ``serve.mixed`` (the fused program; or the legacy separate
-        programs' spans), ``serve.emit``, ``serve.retire`` (retire
+        ``serve.mixed`` (the fused program), ``serve.emit``,
+        ``serve.retire`` (retire
         completions, flush the request journal, gauges), all under the
         ``serve.tick`` this opens itself."""
         get_fault_plan().fire("serve.tick")
@@ -1145,18 +857,8 @@ class ServeEngine:
             if tick_span is not None:
                 tick_span.annotate(decodes=len(t.decodes),
                                    chunks=len(t.prefills))
-            if self.config.fused:
-                if t.prefills or t.decodes:
-                    self._run_mixed(t)
-            else:
-                chunked = self.config.prefill_chunk is not None
-                for seq in t.prefills:
-                    if chunked:
-                        self._run_prefill_chunk(seq)
-                    else:
-                        self._run_prefill(seq)
-                if t.decodes:
-                    self._run_decode(t.decodes)
+            if t.prefills or t.decodes:
+                self._run_mixed(t)
             with self._span("serve.retire", step=step):
                 self._retire_tick(t)
         return t
@@ -1238,11 +940,9 @@ class ServeEngine:
 
     @property
     def prefill_program_count(self) -> int:
-        """Compiled prefill-side programs: pow2 buckets (whole-prompt
-        mode), chunk programs, and fused mixed programs (one per
-        (chunk, k) width signature)."""
-        return (len(self._prefill_fns) + len(self._chunk_fns)
-                + len(self._mixed_fns))
+        """Compiled mixed programs: one per (chunk, k) width signature,
+        so 1 for an engine's whole life."""
+        return len(self._mixed_fns)
 
     def stats_snapshot(self) -> dict:
         """One JSON-safe dict of the engine's load + lifetime tallies —

@@ -14,8 +14,7 @@ choices can never drift from the attention that fills the pool.
 
 ``kv_dtype='int8'`` stores values quantized with per-slot-per-head
 scales; the quantizer lives in ``nn/attention.py`` (``kv_quantize_int8``)
-so the prefill writer here and the decode-step write inside
-``ParallelSelfAttention`` round identically.
+so every write into the pool (``paged_scatter_kv``) rounds identically.
 
 **mp > 1 (sharded serving, docs/SERVING.md "The fleet"):** when the
 inference module rides a mesh with ``model_parallel_size > 1``, each
@@ -36,11 +35,7 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..nn.attention import (
-    PagedKVCacheView,
-    paged_flat_slots,
-    paged_scatter_kv,
-)
+from ..nn.attention import PagedKVCacheView
 
 
 def serving_mesh(inference_module):
@@ -83,11 +78,11 @@ def build_layer_views(
 
 def state_from_views(views: List[PagedKVCacheView]) -> Tuple:
     """Inverse of :func:`build_layer_views`: the updated pools of the
-    per-layer views ``_run_layers`` / ``write_prompt_kv`` return, as the
+    per-layer views ``_run_layers`` returns, as the
     state tuple ``(pool_k, pool_v, scale_k, scale_v)`` the program took
     (``None`` scales stay ``None``).
 
-    What EVERY engine program returns beside its tokens. JAX pairs a
+    What the engine's program returns beside its tokens. JAX pairs a
     donated buffer with an output of its shape and dtype in FLATTENED
     order, so only a state that leaves in the structure it entered in
     aliases ``pool_k[i]`` to the output computed from ``pool_k[i]``, and
@@ -231,27 +226,3 @@ def init_pools(inference_module, num_blocks: int, block_size: int,
                 placed((num_blocks, block_size, n_kv), jnp.float32, 2)
             )
     return PagedKVPools(pool_k, pool_v, scale_k, scale_v, block_size)
-
-
-def write_prompt_kv(
-    view: PagedKVCacheView,
-    k: jax.Array,  # (1, L_padded, n_kv, h) prompt keys (right-padded)
-    v: jax.Array,
-    block_row: jax.Array,  # (max_blocks,) the sequence's block table row
-    prompt_len: jax.Array,  # scalar: real tokens; pads write to trash
-    block_size: int,
-) -> PagedKVCacheView:
-    """Scatter one prefilled prompt's KV into the pool (traceable).
-
-    Tokens past ``prompt_len`` (the length-bucket padding) are routed to
-    the trash block, so a single jitted program per bucket serves every
-    prompt length in it."""
-    L = k.shape[1]
-    positions = jnp.arange(L, dtype=jnp.int32)[None, :]
-    # pads: send the flat slot into the trash block
-    real = positions < prompt_len
-    flat = paged_flat_slots(block_row[None, :], positions, block_size)
-    flat = jnp.where(real, flat, 0).reshape(-1)
-    return paged_scatter_kv(
-        view, flat, k.reshape(L, *k.shape[2:]), v.reshape(L, *v.shape[2:])
-    )

@@ -131,8 +131,8 @@ class Sequence:
     @property
     def prefilling(self) -> bool:
         """RUNNING but the prompt's KV is not fully in the pool yet —
-        under chunked prefill such a sequence streams chunks instead of
-        decoding (it has no first token to decode from)."""
+        such a sequence streams chunks instead of decoding (it has no
+        first token to decode from)."""
         return self.slot is not None and self.num_cached < self.prefill_len
 
     @property
@@ -416,22 +416,21 @@ def ngram_propose(history: List[int], k: int, max_n: int = 3,
 
 @dataclasses.dataclass
 class SchedulerConfig:
+    # Sarathi-style chunked prefill: prompts stream into the pool in
+    # chunks of this many tokens that share the tick budget with decode
+    # rows (no prompt ever monopolizes a tick). No default: the engine's
+    # is the one (EngineConfig.prefill_chunk)
+    prefill_chunk: int
     num_slots: int = 8  # decode-batch rows (the jitted batch size)
     block_size: int = 16  # tokens per KV block
     num_blocks: int = 128  # pool size incl. the trash block
     max_blocks_per_seq: int = 16  # block-table width (jitted shape)
     token_budget: int = 512  # prompt+decode tokens admitted per tick
-    # Sarathi-style chunked prefill: prompts stream into the pool in
-    # fixed-size chunks that share the tick budget with decode rows (no
-    # prompt ever monopolizes a tick); None = legacy whole-prompt
-    # prefill through the pow2 bucket ladder
-    prefill_chunk: Optional[int] = None
-    # shared-prefix block reuse (chunked mode only: whole-prompt mode
-    # can't resume a prefill mid-prompt)
+    # shared-prefix block reuse
     prefix_cache: bool = True
     # self-drafting speculative decoding: candidate tokens drafted per
-    # decoding row per tick (0 = off); requires chunked prefill — the
-    # drafts are scored through the mixed program's chunk-width rows
+    # decoding row per tick (0 = off), scored through the mixed
+    # program's chunk-width rows
     spec_k: int = 0
     # overload shedding (docs/SERVING.md "Resilience"): above the HIGH
     # pool-pressure watermark new submissions are rejected with a
@@ -448,18 +447,13 @@ class SchedulerConfig:
         cap = self.max_blocks_per_seq * self.block_size
         if cap < 2:
             raise ValueError("max_blocks_per_seq * block_size must be >= 2")
-        if self.prefill_chunk is not None and self.prefill_chunk < 1:
+        if not isinstance(self.prefill_chunk, int) or self.prefill_chunk < 1:
             raise ValueError(
-                f"prefill_chunk must be >= 1 (or None for whole-prompt "
-                f"prefill), got {self.prefill_chunk}"
+                f"prefill_chunk must be an int >= 1, "
+                f"got {self.prefill_chunk!r}"
             )
         if self.spec_k < 0:
             raise ValueError(f"spec_k must be >= 0, got {self.spec_k}")
-        if self.spec_k > 0 and self.prefill_chunk is None:
-            raise ValueError(
-                "speculative decoding (spec_k > 0) needs chunked prefill: "
-                "drafts are scored through the mixed program's s>1 rows"
-            )
         high, low = self.shed_high_watermark, self.shed_low_watermark
         if high is not None and not 0.0 < high <= 1.0:
             raise ValueError(
@@ -499,7 +493,7 @@ class Backpressure:
 @dataclasses.dataclass
 class Tick:
     """One scheduling decision: which sequences do prefill work this
-    tick (the whole prompt, or ONE chunk each under chunked prefill),
+    tick (ONE chunk each),
     which decode, who got preempted to make room, and which shared
     blocks must be copy-on-write forked (``(src, dst)`` pool block
     pairs the engine copies BEFORE running the tick's programs);
@@ -519,12 +513,9 @@ class ContinuousBatchingScheduler:
     def __init__(self, config: SchedulerConfig):
         self.config = config
         self.allocator = BlockAllocator(config.num_blocks)
-        # shared-prefix reuse needs chunked prefill (a prefix hit resumes
-        # the prefill mid-prompt, which only the chunk path can do)
         self.prefix_cache: Optional[PrefixCache] = (
             PrefixCache(self.allocator, config.block_size)
-            if config.prefix_cache and config.prefill_chunk is not None
-            else None
+            if config.prefix_cache else None
         )
         self.waiting: Deque[Sequence] = deque()
         self.running: Dict[int, Sequence] = {}  # slot -> sequence
@@ -737,23 +728,21 @@ class ContinuousBatchingScheduler:
         """One tick's worth of work.
 
         1. GROW: every running sequence gets the blocks its next tokens
-           need — one decode token, or its next prefill CHUNK under
-           chunked prefill (blocks are allocated incrementally, not
-           reserved for the whole horizon — that is what lets wildly
-           different lengths share one pool). On exhaustion the youngest
+           need — one decode token, or its next prefill CHUNK (blocks
+           are allocated incrementally, not reserved for the whole
+           horizon — that is what lets wildly different lengths share
+           one pool). On exhaustion the youngest
            running sequence is preempted recompute-style; a sequence that
            cannot grow even after every younger peer is gone preempts
            itself and waits. Oldest-first, so the oldest request always
            progresses — the policy cannot livelock.
-        2. CHUNKS (chunked prefill only): every mid-prefill sequence
-           streams its next chunk, oldest first, while budget remains;
-           the oldest mid-prefill sequence always gets its chunk even on
-           a spent budget (it must finish EVENTUALLY), and decode rows
-           are charged before any chunk — a long prompt can no longer
-           monopolize a tick the way the legacy sole-prefill rule let it.
+        2. CHUNKS: every mid-prefill sequence streams its next chunk,
+           oldest first, while budget remains; the oldest mid-prefill
+           sequence always gets its chunk even on a spent budget (it
+           must finish EVENTUALLY), and decode rows are charged before
+           any chunk — a long prompt cannot monopolize a tick.
         3. ADMIT: prefills from the waiting queue while a slot, enough
-           pool blocks (first chunk / whole prompt), and token budget
-           remain.
+           pool blocks for the first chunk, and token budget remain.
         """
         preempted: List[Sequence] = []
         first_admitted: List[Sequence] = []
@@ -768,7 +757,7 @@ class ContinuousBatchingScheduler:
                           key=lambda s: s.request.req_id):
             if seq.state is not SequenceState.RUNNING:
                 continue  # evicted earlier in this very loop
-            if chunk is not None and seq.prefilling:
+            if seq.prefilling:
                 step = min(chunk, seq.prefill_len - seq.num_cached)
             else:
                 # a decode row scores its last token plus this tick's
@@ -814,26 +803,22 @@ class ContinuousBatchingScheduler:
         # each surviving decoding sequence decodes one token this tick;
         # mid-prefill rows don't decode (they have no token yet) and are
         # charged per chunk below instead
-        decoding = [
-            s for s in self.running.values()
-            if not (chunk is not None and s.prefilling)
-        ]
+        decoding = [s for s in self.running.values() if not s.prefilling]
         budget = self.config.token_budget - len(decoding)
 
         prefills: List[Sequence] = []
-        if chunk is not None:
-            # already-running mid-prefill sequences stream their next
-            # chunk, oldest first; the first one is never budget-starved
-            # (decode rows recur every tick — waiting for a slack tick
-            # could starve the prompt forever)
-            for seq in sorted(self.running.values(),
-                              key=lambda s: s.request.req_id):
-                if not seq.prefilling:
-                    continue
-                if budget <= 0 and prefills:
-                    break
-                prefills.append(seq)
-                budget -= min(chunk, seq.prefill_len - seq.num_cached)
+        # already-running mid-prefill sequences stream their next
+        # chunk, oldest first; the first one is never budget-starved
+        # (decode rows recur every tick — waiting for a slack tick
+        # could starve the prompt forever)
+        for seq in sorted(self.running.values(),
+                          key=lambda s: s.request.req_id):
+            if not seq.prefilling:
+                continue
+            if budget <= 0 and prefills:
+                break
+            prefills.append(seq)
+            budget -= min(chunk, seq.prefill_len - seq.num_cached)
 
         while self.waiting and self._free_slots and budget > 0:
             # pop the head BEFORE any preemption: evicted victims re-enter
@@ -843,41 +828,29 @@ class ContinuousBatchingScheduler:
             prompt_tokens = len(head.resume_prompt)
             matched_blocks: List[int] = []
             matched = 0
-            if chunk is not None:
-                # shared-prefix reuse: map every cached full block of
-                # the prompt into the table — their prefill is already
-                # paid; only the tail streams chunks
-                if self.prefix_cache is not None:
-                    matched_blocks, matched = self.prefix_cache.match(
-                        head.resume_prompt
-                    )
-                # chunked mode admits at the chunk budget: the first
-                # chunk runs this tick, the rest stream on later ticks.
-                # A chunk that would cross the remaining budget defers to
-                # the next tick — unless the tick has no prefill work at
-                # all (the progress guarantee; overshoot is then bounded
-                # by one chunk, never by a whole prompt)
-                admit_tokens = min(chunk, prompt_tokens - matched)
-                if admit_tokens > budget and prefills:
-                    if matched_blocks:
-                        self.allocator.free(matched_blocks)
-                    self.waiting.appendleft(head)
-                    break
-                first_blocks = (
-                    self.blocks_needed(matched + admit_tokens)
-                    - len(matched_blocks)
+            # shared-prefix reuse: map every cached full block of the
+            # prompt into the table — their prefill is already paid;
+            # only the tail streams chunks
+            if self.prefix_cache is not None:
+                matched_blocks, matched = self.prefix_cache.match(
+                    head.resume_prompt
                 )
-            else:
-                # an over-budget prompt admits only as the tick's sole
-                # prefill (a prompt longer than the whole budget must
-                # still run EVENTUALLY; making it wait for an idle tick
-                # would starve it)
-                if prompt_tokens > budget and prefills:
-                    self.waiting.appendleft(head)
-                    break
-                admit_tokens = prompt_tokens
-                first_blocks = self.blocks_needed(prompt_tokens)
-            need = first_blocks
+            # admission is at the chunk budget: the first chunk runs
+            # this tick, the rest stream on later ticks. A chunk that
+            # would cross the remaining budget defers to the next tick —
+            # unless the tick has no prefill work at all (the progress
+            # guarantee; overshoot is then bounded by one chunk, never
+            # by a whole prompt)
+            admit_tokens = min(chunk, prompt_tokens - matched)
+            if admit_tokens > budget and prefills:
+                if matched_blocks:
+                    self.allocator.free(matched_blocks)
+                self.waiting.appendleft(head)
+                break
+            need = (
+                self.blocks_needed(matched + admit_tokens)
+                - len(matched_blocks)
+            )
             while (need > self.available_blocks()
                    and self._preempt_youngest(head, preempted)):
                 pass
@@ -912,7 +885,7 @@ class ContinuousBatchingScheduler:
         decodes = [
             self.running[slot] for slot in sorted(self.running)
             if id(self.running[slot]) not in new
-            and not (chunk is not None and self.running[slot].prefilling)
+            and not self.running[slot].prefilling
         ]
         return Tick(prefills=prefills, decodes=decodes, preempted=preempted,
                     cow_pairs=cow_pairs, first_admitted=first_admitted)

@@ -417,7 +417,7 @@ def main(argv=None) -> int:
                         "applies at spawn time)")
     parser.add_argument("--serve-calibrate-from", metavar="RUN_DIR",
                         help="scale predicted tick time by the measured "
-                        "serve.mixed/serve.decode spans of this serve "
+                        "serve.mixed spans of this serve "
                         "bench run dir (its serve-summary must carry the "
                         "engine shape facts)")
     args = parser.parse_args(argv)

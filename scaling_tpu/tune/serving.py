@@ -28,7 +28,7 @@ wholesale (docs/TUNING.md):
   dropped, not ranked.
 - **calibration**: the analytic tick time is scaled by a measured
   factor from real serve run dirs (:class:`ServeCalibration` — mean
-  ``serve.mixed``/``serve.decode`` span seconds vs the model's
+  ``serve.mixed`` span seconds vs the model's
   prediction for THAT run's engine shape, read from the serve-summary's
   ``engine`` facts), exactly like the training tuner's MFU calibration.
 
@@ -288,7 +288,7 @@ def rank_serving_points(
 class ServeCalibration:
     """Measured-vs-analytic tick-time factor from real serve run dirs.
 
-    A serve bench run leaves ``serve.mixed`` / ``serve.decode`` spans
+    A serve bench run leaves ``serve.mixed`` spans
     (the device tick) and a serve-summary carrying the engine SHAPE it
     ran (``engine``: mp/num_slots/block_size/token_budget...). The
     factor is measured mean tick seconds over the analytic prediction
@@ -316,7 +316,7 @@ class ServeCalibration:
         data = load_run_dir(run_dir)
         spans = [
             sp for sp in data.spans
-            if sp.get("span") in ("serve.mixed", "serve.decode")
+            if sp.get("span") == "serve.mixed"
             and sp.get("dur_s") is not None
         ]
         summaries = [

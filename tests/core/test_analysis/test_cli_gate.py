@@ -176,13 +176,12 @@ def test_audit_gate_serve_decode_matches_golden(tmp_path):
     assert sec["infeed_outfeed"] == 0
     static = sec["recompile_key"]["static"]
     assert static["kind"] == "serve_mixed_step"
-    # shapes in the signature come from engine CONFIG, never per request;
-    # the hot-path policy knobs (ISSUE 10/11) are pinned alongside —
-    # incl. the speculative draft length and the fused program width
-    assert {"num_slots", "block_size", "max_blocks_per_seq",
-            "min_prefill_bucket", "paged_kernel", "prefill_chunk",
-            "spec_k", "mixed_width"} <= set(static)
-    assert static["paged_kernel"] == "pallas"
+    # shapes in the signature come from engine CONFIG, never per request:
+    # the chunk, the speculative draft length and the widths they make,
+    # and no selector of a program or a back-end (there is one of each)
+    assert set(static) == {
+        "kind", "num_slots", "block_size", "max_blocks_per_seq", "kv_dtype",
+        "prefill_chunk", "spec_k", "mixed_width", "sample_width"}
     assert static["mixed_width"] == max(static["prefill_chunk"],
                                         static["spec_k"] + 1)
     # the separate chunk program is GONE — one mixed program replaced

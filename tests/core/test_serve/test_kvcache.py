@@ -1,6 +1,7 @@
-"""Paged KV pool mechanics (ISSUE 9): flat-slot addressing, prompt
-scatter + block gather round-trips, int8 quantization accuracy — all on
-hand-built pools, no model."""
+"""Paged KV pool mechanics (ISSUE 9): flat-slot addressing, chunk
+scatter + block gather round-trips, int8 quantization accuracy on
+hand-built pools; then the donated pool state through the engine's
+program, dense, routed and sharded over two devices."""
 
 import re
 
@@ -15,7 +16,7 @@ from scaling_tpu.nn.attention import (
     kv_quantize_int8,
     paged_flat_slots,
 )
-from scaling_tpu.serve.kvcache import write_prompt_kv
+from scaling_tpu.nn.base_layer import ForwardContext
 
 
 def test_paged_flat_slots_maps_through_block_table():
@@ -47,7 +48,10 @@ def test_int8_roundtrip_error_bounded():
     assert err <= float(np.asarray(scale).max()) / 2 + 1e-7
 
 
-def _empty_view(num_blocks=6, block_size=2, n_kv=2, h=4, quantized=False):
+def _empty_view(ctx_len, new_len, table, n_kv, h, quantized=False,
+                num_blocks=6, block_size=2):
+    """One row's view of an all-zero pool: ``ctx_len`` tokens cached,
+    ``new_len`` of the presented tokens real."""
     pool = jnp.zeros((num_blocks, block_size, n_kv, h), jnp.float32)
     scale = (
         jnp.zeros((num_blocks, block_size, n_kv), jnp.float32)
@@ -56,55 +60,12 @@ def _empty_view(num_blocks=6, block_size=2, n_kv=2, h=4, quantized=False):
     if quantized:
         pool = pool.astype(jnp.int8)
     return PagedKVCacheView(
-        pool_k=pool, pool_v=pool, block_table=jnp.zeros((1, 4), jnp.int32),
-        context_len=jnp.zeros((1,), jnp.int32),
+        pool_k=pool, pool_v=pool,
+        block_table=jnp.asarray([table], jnp.int32),
+        context_len=jnp.asarray([ctx_len], jnp.int32),
         scale_k=scale, scale_v=scale,
+        new_len=jnp.asarray([new_len], jnp.int32),
     )
-
-
-@pytest.mark.parametrize("quantized", [False, True], ids=["native", "int8"])
-def test_write_prompt_then_gather_roundtrips(quantized):
-    rng = np.random.default_rng(1)
-    block_size, prompt_len, bucket = 2, 5, 8
-    k = jnp.asarray(rng.normal(size=(1, bucket, 2, 4)).astype(np.float32))
-    v = jnp.asarray(rng.normal(size=(1, bucket, 2, 4)).astype(np.float32))
-    view = _empty_view(quantized=quantized)
-    block_row = jnp.asarray([3, 1, 4, 0], jnp.int32)  # scattered on purpose
-    new = write_prompt_kv(view, k, v, block_row, jnp.int32(prompt_len),
-                          block_size)
-    # gather the row back through the block table: logical order restored
-    gk = new.pool_k[block_row].reshape(8, 2, 4)
-    if quantized:
-        gs = new.scale_k[block_row].reshape(8, 2)
-        gk = kv_dequantize_int8(gk, gs, jnp.float32)
-    got = np.asarray(gk)[:prompt_len]
-    want = np.asarray(k)[0, :prompt_len]
-    tol = 0.02 if quantized else 0.0
-    assert np.abs(got - want).max() <= tol
-
-
-def test_prompt_padding_lands_in_trash_not_blocks():
-    rng = np.random.default_rng(2)
-    k = jnp.asarray(rng.normal(size=(1, 8, 2, 4)).astype(np.float32))
-    view = _empty_view()
-    block_row = jnp.asarray([3, 1, 0, 0], jnp.int32)
-    new = write_prompt_kv(view, k, k, block_row, jnp.int32(3), block_size=2)
-    pool = np.asarray(new.pool_k)
-    # real blocks 3 and 1 hold tokens 0..2; block 4 untouched (token 3 is pad)
-    assert np.allclose(pool[3], np.asarray(k)[0, 0:2])
-    assert np.allclose(pool[1, 0], np.asarray(k)[0, 2])
-    assert np.allclose(pool[1, 1], 0.0)  # slot for token 3 never written
-    assert np.allclose(pool[4], 0.0)
-    # pads went somewhere in trash block 0 (content irrelevant, only that
-    # no REAL block got them)
-    assert not np.allclose(pool[0], 0.0)
-
-
-# --- the donated pool state leaves each program as it entered (ISSUE 31) ---
-
-SLOTS, MAX_BLOCKS, CHUNK = 2, 8, 4
-PROGRAMS = [("prefill", 0), ("chunk", 0), ("decode", 0), ("mixed", 0),
-            ("mixed", 2)]
 
 
 @pytest.fixture(scope="module")
@@ -114,12 +75,104 @@ def toy_inference():
     return build_toy_inference(hidden=32, layers=3, vocab=64, heads=4)
 
 
-def _program_and_args(toy_inference, program, kv_dtype, spec_k):
-    """One of the engine's four programs as the plain function under its
-    ``jax.jit``, with toy arguments in its signature."""
+def _write_chunk(toy_inference, ctx_len, new_len, table, quantized, seed):
+    """One chunk row of width 8 through the pool's writer as the mixed
+    program reaches it (``_paged_attention``: flat slots from the row's
+    table, pads to trash, ``paged_scatter_kv``). Returns (k, new view)."""
+    attn = toy_inference.module.layers[1].attention
+    n, n_kv, h = attn.num_attention_heads, attn.num_kv_heads, attn.head_dim
+    rng = np.random.default_rng(seed)
+    q = jnp.asarray(rng.normal(size=(1, 8, n, h)).astype(np.float32))
+    k = jnp.asarray(rng.normal(size=(1, 8, n_kv, h)).astype(np.float32))
+    view = _empty_view(ctx_len, new_len, table, n_kv, h, quantized)
+    out, new = attn._paged_attention(q, k, k, view, 1, 8, ForwardContext())
+    assert np.isfinite(np.asarray(out)).all()
+    return np.asarray(k)[0], new
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["native", "int8"])
+def test_what_a_chunk_wrote_is_what_a_gather_reads_back(toy_inference,
+                                                        quantized):
+    # a ragged chunk in the middle of a prompt: 5 real tokens of 8 behind
+    # 3 cached ones, starting inside a block; blocks scattered on purpose
+    block_row = [3, 1, 4, 2]
+    k, new = _write_chunk(toy_inference, 3, 5, block_row, quantized, seed=1)
+    row = jnp.asarray(block_row)
+    # gather the row back through the block table: logical order restored
+    gk = new.pool_k[row].reshape(8, *k.shape[1:])
+    if quantized:
+        gs = new.scale_k[row].reshape(8, k.shape[1])
+        gk = kv_dequantize_int8(gk, gs, jnp.float32)
+    got = np.asarray(gk)
+    tol = 0.02 if quantized else 0.0
+    assert np.abs(got[3:8] - k[:5]).max() <= tol
+    assert not got[:3].any()  # the cached tokens' slots were not touched
+
+
+def test_chunk_padding_lands_in_trash_not_blocks(toy_inference):
+    k, new = _write_chunk(toy_inference, 0, 3, [3, 1, 4, 0], False, seed=2)
+    pool = np.asarray(new.pool_k)
+    # real blocks 3 and 1 hold tokens 0..2; token 3 is a pad: its slot in
+    # block 1 and the row's next block (4) stay untouched
+    assert np.allclose(pool[3], k[0:2])
+    assert np.allclose(pool[1, 0], k[2])
+    assert not pool[1, 1].any()
+    assert not pool[4].any() and not pool[2].any() and not pool[5].any()
+    # pads went somewhere in trash block 0 (content irrelevant, only that
+    # no REAL block got them)
+    assert pool[0].any()
+
+
+# --- the donated pool state leaves the program as it entered (ISSUE 31) ---
+
+SLOTS, MAX_BLOCKS, CHUNK = 2, 8, 4
+MODELS = ["dense", "routed", "mp2"]
+
+
+@pytest.fixture(scope="module")
+def inference_modules(toy_inference):
+    """The model kinds whose mixed programs differ in what they return or
+    where their pools live: dense; routed (its first output is the grid
+    flattened + the (E,) load); dense on a 2-device model-parallel
+    serving mesh (pools sharded over ``model``)."""
+    from scaling_tpu.models.transformer import TransformerConfig
+    from scaling_tpu.models.transformer.inference import (
+        TransformerInferenceModule,
+    )
+    from scaling_tpu.models.transformer.model import init_model
+    from scaling_tpu.serve.bench import build_toy_inference
+
+    routed = TransformerConfig.from_dict({
+        "topology": {"model_parallel_size": 1, "pipe_parallel_size": 1,
+                     "data_parallel_size": 1, "micro_batch_size": 1,
+                     "gradient_accumulation_steps": 1},
+        "transformer_architecture": {
+            "vocab_size": 64, "hidden_size": 32, "num_layers": 3,
+            "num_attention_heads": 4, "sequence_length": 256,
+            "mlp_type": "moe", "mlp_factor": 0.5, "moe_num_experts": 4,
+            "moe_top_k": 2, "norm_type": "rms", "weight_tying": False,
+            "activation_function": "silu", "mlp_bias": False},
+        "optimizer": {"gradient_clipping": 1.0},
+        "learning_rate_scheduler": {"learning_rate": 3e-4},
+        "trainer": {"train_iterations": 1, "seed": 0},
+        "data": {}, "logger": {"log_dir": None},
+    })
+    module = init_model(routed, None)
+    return {
+        "dense": toy_inference,
+        "routed": TransformerInferenceModule(
+            routed, module, module.init_params(jax.random.PRNGKey(0))),
+        "mp2": build_toy_inference(hidden=32, layers=3, vocab=64, heads=4,
+                                   mp=2),
+    }
+
+
+def _program_and_args(inf, kv_dtype, spec_k):
+    """The engine's program as the plain function under its ``jax.jit``,
+    with toy arguments in its signature."""
     from scaling_tpu.serve.engine import EngineConfig, ServeEngine
 
-    engine = ServeEngine(toy_inference, EngineConfig(
+    engine = ServeEngine(inf, EngineConfig(
         num_slots=SLOTS, block_size=4, num_blocks=2 * MAX_BLOCKS + 1,
         max_blocks_per_seq=MAX_BLOCKS, token_budget=64, prefill_chunk=CHUNK,
         kv_dtype=kv_dtype, spec_k=spec_k,
@@ -129,31 +182,28 @@ def _program_and_args(toy_inference, program, kv_dtype, spec_k):
     def z(*shape, dt=np.int32):
         return np.zeros(shape, dt)
 
-    def sampler(n):
-        return (z(n, dt=np.float32), z(n, dt=np.float32), z(n), z(n), z(n))
-
-    built, operands = {
-        "prefill": (lambda: engine._build_prefill_fn(8),
-                    (z(1, 8), z(MAX_BLOCKS), np.int32(5), *sampler(1))),
-        "chunk": (lambda: engine._build_chunk_fn(CHUNK),
-                  (z(1, CHUNK), z(MAX_BLOCKS), z(1), np.ones(1, np.int32),
-                   *sampler(1))),
-        "decode": (engine._build_decode_fn,
-                   (z(SLOTS, MAX_BLOCKS), z(SLOTS), z(SLOTS),
-                    *sampler(SLOTS))),
-        "mixed": (lambda: engine._build_mixed_fn(width),
-                  (z(SLOTS, MAX_BLOCKS), z(SLOTS), z(SLOTS, width),
-                   np.ones(SLOTS, np.int32), *sampler(SLOTS))),
-    }[program]
-    args = (toy_inference.params, engine._pool_state(), *operands,
-            engine._base_key)
-    return engine, built().__wrapped__, args
+    operands = engine._dev((
+        z(SLOTS, MAX_BLOCKS), z(SLOTS), z(SLOTS, width),
+        np.ones(SLOTS, np.int32), z(SLOTS, dt=np.float32),
+        z(SLOTS, dt=np.float32), z(SLOTS), z(SLOTS), z(SLOTS),
+    ))
+    args = (inf.params, engine._pool_state(), *operands, engine._base_key)
+    return engine, engine._build_mixed_fn(width).__wrapped__, args
 
 
-def _main_aliases(lowered_text):
-    """{argument number: its ``tf.aliasing_output``} off ``main``'s
-    signature in a lowered module's text."""
-    signature = lowered_text.split("@main(", 1)[1].split(") -> ", 1)[0]
+def _aliases(lowered):
+    """{argument number: the output it aliases}. On one device JAX pairs
+    them itself and writes ``tf.aliasing_output`` on ``main``'s
+    signature; on a mesh it only marks the donors (``jax.buffer_donor``)
+    and XLA pairs them when it compiles, so the table is read off the
+    compiled module's ``input_output_alias``."""
+    text = lowered.as_text()
+    signature = text.split("@main(", 1)[1].split(") -> ", 1)[0]
+    if "jax.buffer_donor" in signature:
+        table = re.search(r"input_output_alias=\{(.*?)\}, entry",
+                          lowered.compile().as_text()).group(1)
+        return {int(arg): int(out) for out, arg in
+                re.findall(r"\{(\d+)\}: \((\d+), \{\}, \S+-alias\)", table)}
     aliases = {}
     for arg in signature.split("%arg")[1:]:
         m = re.search(r"tf\.aliasing_output = (\d+)", arg)
@@ -164,42 +214,75 @@ def _main_aliases(lowered_text):
 
 @pytest.mark.parametrize("kv_dtype", ["native", "int8"])
 @pytest.mark.parametrize(
-    "program,spec_k", PROGRAMS,
-    ids=[f"{p}-spec{k}" if k else p for p, k in PROGRAMS],
+    "model,spec_k", [("dense", 0), ("dense", 2), ("routed", 0), ("mp2", 0)],
+    ids=["mixed", "mixed-spec2", "routed", "mp2"],
 )
 def test_donated_pool_aliases_the_output_computed_from_it(
-        toy_inference, program, spec_k, kv_dtype):
+        inference_modules, model, spec_k, kv_dtype):
     """JAX pairs a donated buffer with an output of its shape and dtype
     in flattened order, so ``pool_v[3]`` is updated in place only if the
     program's lowered ``main`` says its argument aliases the output leaf
     at ``pool_v[3]``'s place in the returned state. Lowered with donation
     forced (the CPU engine never donates; lowering alone warns of
     nothing). Returning the per-layer views instead (k0, v0, table, ctx,
-    k1, v1, ...), as every program did before ISSUE 31, fails this: three
-    layers' six pools were paired with outputs 1, 2, 4, 5, 7, 8 where
-    1, 2, 3, 4, 5, 6 compute from them, and XLA copied every pool but
-    the first on every call."""
-    _, fn, args = _program_and_args(toy_inference, program, kv_dtype, spec_k)
-    text = jax.jit(fn, donate_argnums=(1,), keep_unused=True).lower(
+    k1, v1, ...) fails this: three layers' six pools were paired with
+    outputs 1, 2, 4, 5, 7, 8 where 1, 2, 3, 4, 5, 6 compute from them,
+    and XLA copied every pool but the first on every call. A routed
+    model's first output is one vector (grid + load), still ONE leaf
+    ahead of the state; on the serving mesh every pool is sharded over
+    ``model`` and XLA does the pairing."""
+    _, fn, args = _program_and_args(
+        inference_modules[model], kv_dtype, spec_k)
+    lowered = jax.jit(fn, donate_argnums=(1,), keep_unused=True).lower(
         *args
-    ).as_text()
+    )
     first = len(jax.tree_util.tree_leaves(args[0]))  # params come first
     donated = jax.tree_util.tree_leaves(args[1])
     assert len(donated) == (12 if kv_dtype == "int8" else 6)
     # outputs flatten as (tokens, *state): state leaf j is output 1 + j
     want = {first + j: 1 + j for j in range(len(donated))}
-    assert _main_aliases(text) == want
+    assert _aliases(lowered) == want
 
 
 @pytest.mark.parametrize("kv_dtype", ["native", "int8"])
-@pytest.mark.parametrize("program", ["prefill", "chunk", "decode", "mixed"])
+@pytest.mark.parametrize("model", MODELS, ids=["mixed", "routed", "mp2"])
 def test_programs_return_the_state_in_pool_state_structure(
-        toy_inference, program, kv_dtype):
-    engine, fn, args = _program_and_args(toy_inference, program, kv_dtype, 0)
-    _, state = jax.eval_shape(fn, *args)
+        inference_modules, model, kv_dtype):
+    engine, fn, args = _program_and_args(
+        inference_modules[model], kv_dtype, 0)
+    sampled, state = jax.eval_shape(fn, *args)
+    sw = engine.config.sample_width
+    assert sampled.shape == (
+        (SLOTS * sw + engine.num_experts,) if model == "routed"
+        else (SLOTS, sw)
+    )
     structure = jax.tree_util.tree_structure
     assert structure(state) == structure(engine._pool_state())
     assert (state[2] is None) == (kv_dtype == "native")
     for got, held in zip(jax.tree_util.tree_leaves(state),
                          jax.tree_util.tree_leaves(engine._pool_state())):
         assert (got.shape, got.dtype) == (held.shape, held.dtype)
+
+
+def test_run_layers_on_paged_views_defaults_to_the_kernel(toy_inference):
+    """``_run_layers`` given block-paged views and NO ``paged_kernel``
+    traces the Pallas call: the back-end's default is written once, on
+    ``ForwardContext``, and a caller that names none cannot get the
+    gather (which stays reachable by name, as the tests' reference)."""
+    from scaling_tpu.serve.kvcache import build_layer_views
+
+    _, _, args = _program_and_args(toy_inference, "native", 0)
+    params, state, tables, ctx_lens, tokens, new_lens = args[:6]
+    pos = ctx_lens[:, None] + jnp.arange(tokens.shape[1])[None, :]
+    batch = toy_inference._make_batch(tokens, pos)
+
+    def pallas_calls(**named):
+        views = build_layer_views(state, tables, ctx_lens, new_lens)
+        jaxpr = jax.make_jaxpr(
+            lambda p: toy_inference._run_layers(p, batch, views, None,
+                                                **named)[0]
+        )(params)
+        return str(jaxpr).count("pallas_call")
+
+    assert pallas_calls() > 0
+    assert pallas_calls(paged_kernel="xla") == 0

@@ -11,6 +11,7 @@ from scaling_tpu.models.transformer import TransformerConfig
 from scaling_tpu.models.transformer.inference import TransformerInferenceModule
 from scaling_tpu.models.transformer.model import init_model
 from scaling_tpu.serve.engine import EngineConfig, ServeEngine
+from tests.transformer.test_serving import jitted_programs
 
 LAYERS, EXPERTS, TOP_K = 2, 8, 2
 
@@ -90,18 +91,21 @@ def test_a_dense_model_pays_nothing(tmp_path):
     assert emits and not any("load_max" in f for f in emits)
 
 
-def test_whole_prompt_prefill_and_the_unfused_tick_serve_the_same_tokens():
-    """Every program the engine has drops nothing: the fused tick, the
-    separate chunk and decode programs, and the legacy whole-prompt prefill
-    agree token for token on a crowded prompt."""
-    prompt = [7] * 24 + list(np.random.default_rng(1).integers(1, 90, 20))
-    outs = []
-    for changes in ({}, {"fused_tick": False}, {"prefill_chunk": None}):
-        engine = make_engine(changes, **ROUTED)
-        seq = engine.submit(prompt, 6)
-        engine.run_until_done()
-        outs.append(seq.generated)
-    assert outs[0] == outs[1] == outs[2]
+def test_one_program_serves_a_routed_model_whatever_the_tick_holds():
+    """A prompt shorter than a chunk, one many chunks long, drafts and a
+    preemption: the routed engine compiles its mixed program once and
+    holds no other jitted callable."""
+    engine = make_engine({"spec_k": 3, "num_blocks": 7}, **ROUTED)
+    cycle = [(i % 5) + 1 for i in range(40)]  # n-grams the proposer finds
+    seqs = [engine.submit(p, 6) for p in (cycle, cycle[3:], [9, 8, 7])]
+    engine.run_until_done()
+    assert all(len(s.generated) == 6 for s in seqs)
+    assert engine.scheduler.preemption_count > 0
+    assert engine.spec_drafted_tokens > 0
+    width = engine.config.mixed_width
+    assert jitted_programs(engine) == {"_mixed_fns": 1}
+    assert list(engine._mixed_fns) == [width]
+    assert engine._mixed_fns[width]._cache_size() == 1
 
 
 def test_projection_scope_needs_the_norm_switched_on():

