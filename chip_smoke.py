@@ -450,11 +450,11 @@ def phase_serve(size, args, device) -> dict:
           f"itl p50 {stats['itl_p50_s']:.4f} s")
 
     builds = require_compiled_kernel("paged_attention", args.rehearse)
-    width = cfg.mixed_width
+    width = cfg.mixed_widths[0]  # the token width nearly every tick runs
     n = cfg.num_slots
     zeros = lambda *shape, dt=np.int32: np.zeros(shape, dt)
     operands = engine._dev((
-        zeros(n, cfg.max_blocks_per_seq), zeros(n), zeros(n, width), zeros(n),
+        zeros(n, cfg.max_blocks_per_seq), zeros(n), zeros(width), zeros(n),
         zeros(n, dt=np.float32), zeros(n, dt=np.float32), zeros(n), zeros(n),
         zeros(n),
     ))

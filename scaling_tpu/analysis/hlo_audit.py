@@ -464,19 +464,28 @@ def _count_pallas_custom_calls(text: str) -> int:
     return len(re.findall(r"stablehlo\.custom_call\s*@tpu_custom_call", text))
 
 
-def audit_serve_decode_section(num_slots=2, block_size=4,
-                               max_blocks=4, prefill_chunk=8,
+def audit_serve_decode_section(num_slots=8, block_size=16,
+                               max_blocks=4, prefill_chunk=32,
                                spec_k=3, mp=1) -> dict:
-    """The serving engine's single MIXED program (serve/engine.py,
-    ISSUE 11): ONE jitted step per tick covers the whole slot set —
-    decode rows (last token + up to ``spec_k`` speculative drafts) and
-    prefill-chunk rows alike, tagged purely by traced per-row lengths.
-    Its recompile-key signature is the no-recompile-storm contract: the
-    key bakes the (chunk, draft-length) width signature plus the engine
-    shape config, and NOTHING per-request — a scheduler change that
-    moves prompt lengths, prefill offsets, or draft contents into the
-    signature shows up as golden drift here, not as a compile storm on
-    the chip. ``pallas_custom_calls`` counts the paged-attention
+    """The serving engine's MIXED program (serve/engine.py, ISSUE 11,
+    token-major since ISSUE 33): ONE jitted step per tick covers the
+    whole slot set — decode rows (last token + up to ``spec_k``
+    speculative drafts) and prefill-chunk rows alike, their real tokens
+    packed back to back into one of the engine's (at most two) token
+    widths, tagged purely by traced per-row lengths. Its recompile-key
+    signature is the no-recompile-storm contract: the key bakes the
+    (chunk, draft-length) widths plus the engine shape config, and
+    NOTHING per-request — a scheduler change that moves prompt lengths,
+    prefill offsets, or draft contents into the signature shows up as
+    golden drift here, not as a compile storm on the chip.
+
+    The section is the program of the SMALL width, which nearly every
+    tick runs; the full width's program is the same function at
+    another ``T`` and is pinned beside it under ``full_width`` (its own
+    signature hash, ``dot_general_count`` and ``flops``), so that a
+    change that makes the two differ in more than their width shows. The
+    defaults are the smallest engine with both widths (8 slots x 32: 128
+    under 256). ``pallas_custom_calls`` counts the paged-attention
     kernel's custom calls in the lowered HLO (0 off-TPU where the kernel
     runs interpreted).
 
@@ -485,7 +494,7 @@ def audit_serve_decode_section(num_slots=2, block_size=4,
     the serving mesh, and the collective inventory pins the model-axis
     activation all-reduces the sharded tick pays — plus the recompile
     key grows an ``mp`` entry (only when sharded, so the mp=1 section's
-    pinned hash stays byte-identical)."""
+    static config never names it)."""
     import jax
     import jax.numpy as jnp
 
@@ -512,44 +521,49 @@ def audit_serve_decode_section(num_slots=2, block_size=4,
         token_budget=64, prefill_chunk=prefill_chunk, spec_k=spec_k,
     ))
     base_key = engine._dev(jax.random.PRNGKey(0))
-    width = engine.config.mixed_width
-    mixed = engine._build_mixed_fn(width)
-    args = (
-        params, engine._pool_state(),
-        *engine._dev((
-            jnp.zeros((num_slots, max_blocks), jnp.int32),  # block tables
-            jnp.zeros((num_slots,), jnp.int32),     # context lengths
-            jnp.zeros((num_slots, width), jnp.int32),  # tokens
-            jnp.ones((num_slots,), jnp.int32),      # real per row
-            jnp.zeros((num_slots,), jnp.float32),   # temperatures
-            jnp.zeros((num_slots,), jnp.float32),   # top-ps
-            jnp.zeros((num_slots,), jnp.int32),     # top-ks
-            jnp.zeros((num_slots,), jnp.int32),     # request ids
-            jnp.zeros((num_slots,), jnp.int32),     # key-fold bases
-        )),
-        base_key,
-    )
-    lowered = mixed.lower(*args)
+    small, full = engine.config.mixed_widths
+
+    def lowered_at(width):
+        args = (
+            params, engine._pool_state(),
+            *engine._dev((
+                jnp.zeros((num_slots, max_blocks), jnp.int32),  # block tables
+                jnp.zeros((num_slots,), jnp.int32),     # context lengths
+                jnp.zeros((width,), jnp.int32),         # tokens, packed
+                jnp.ones((num_slots,), jnp.int32),      # real per row
+                jnp.zeros((num_slots,), jnp.float32),   # temperatures
+                jnp.zeros((num_slots,), jnp.float32),   # top-ps
+                jnp.zeros((num_slots,), jnp.int32),     # top-ks
+                jnp.zeros((num_slots,), jnp.int32),     # request ids
+                jnp.zeros((num_slots,), jnp.int32),     # key-fold bases
+            )),
+            base_key,
+        )
+        return engine._build_mixed_fn(width).lower(*args), args
+
     static = {
         "kind": "serve_mixed_step", "num_slots": num_slots,
         "block_size": block_size, "max_blocks_per_seq": max_blocks,
         "kv_dtype": engine.config.kv_dtype,
         "prefill_chunk": prefill_chunk,
         "spec_k": spec_k,
-        "mixed_width": width,
+        "mixed_width": engine.config.mixed_width,
         # positions gathered per row before the vocab projection — a
-        # change that silently re-projects every width position shows
-        # up as golden drift, not a quiet FLOPs regression
+        # change that silently re-projects every position shows up as
+        # golden drift, not a quiet FLOPs regression
         "sample_width": engine.config.sample_width,
+        # the token widths the engine builds programs at, and this one's
+        "token_widths": [small, full],
+        "token_width": small,
     }
     mesh = None
     if mp > 1:
-        # mp joins the recompile key ONLY when sharded: the mp=1
-        # section's pinned hash stays byte-identical
+        # mp joins the recompile key ONLY when sharded
         static["mp"] = mp
         mesh = MeshAxes(
             topology.mesh.axis_names, topology.mesh.devices.shape
         )
+    lowered, args = lowered_at(small)
     report = _audit_lowered(lowered, args, static, mesh=mesh)
     report["mesh"] = (
         dict(zip(topology.mesh.axis_names, topology.mesh.devices.shape))
@@ -558,6 +572,16 @@ def audit_serve_decode_section(num_slots=2, block_size=4,
     report["pallas_custom_calls"] = _count_pallas_custom_calls(
         lowered.as_text()
     )
+    lowered, args = lowered_at(full)
+    at_full = _audit_lowered(
+        lowered, args, {**static, "token_width": full}, mesh=mesh
+    )
+    report["full_width"] = {
+        "hash": at_full["recompile_key"]["hash"],
+        "dot_general_count": at_full["dot_general_count"],
+        "flops": at_full["flops"],
+        "collectives": at_full["collectives"],
+    }
     return report
 
 
@@ -671,13 +695,12 @@ def compare_to_golden(
         exact(field, golden.get(field), report.get(field))
     exact("recompile_key.hash", golden.get("recompile_key", {}).get("hash"),
           report.get("recompile_key", {}).get("hash"))
-    # serving sections pin a second program (chunked prefill) per golden
-    exact("chunk_program.hash",
-          (golden.get("chunk_program") or {}).get("hash"),
-          (report.get("chunk_program") or {}).get("hash"))
-    exact("chunk_program.pallas_custom_calls",
-          (golden.get("chunk_program") or {}).get("pallas_custom_calls"),
-          (report.get("chunk_program") or {}).get("pallas_custom_calls"))
+    # the serving sections pin the engine's second program, the mixed
+    # step at its full token width, beside the small one they audit
+    g_full = golden.get("full_width") or {}
+    c_full = report.get("full_width") or {}
+    for field in ("hash", "dot_general_count", "collectives"):
+        exact(f"full_width.{field}", g_full.get(field), c_full.get(field))
 
     def inv_map(inv):
         return {(r["op"], r["axis"]): r for r in inv or []}
@@ -705,12 +728,17 @@ def compare_to_golden(
                     f"{name}: collective {key} bytes {gb} -> {cb} "
                     f"(> {rtol:.0%} band)"
                 )
-    gf, cf = golden.get("flops"), report.get("flops")
-    if (gf is None) != (cf is None):
-        # cost analysis silently dying must not silently un-enforce the pin
-        drift.append(f"{name}: flops availability changed {gf!r} -> {cf!r}")
-    elif gf is not None and abs(cf - gf) > rtol * max(abs(gf), 1.0):
-        drift.append(f"{name}: flops {gf:.3g} -> {cf:.3g} (> {rtol:.0%} band)")
+    for what, g, c in (("flops", golden, report),
+                       ("full_width.flops", g_full, c_full)):
+        gf, cf = g.get("flops"), c.get("flops")
+        if (gf is None) != (cf is None):
+            # cost analysis silently dying must not silently un-enforce
+            # the pin
+            drift.append(
+                f"{name}: {what} availability changed {gf!r} -> {cf!r}")
+        elif gf is not None and abs(cf - gf) > rtol * max(abs(gf), 1.0):
+            drift.append(
+                f"{name}: {what} {gf:.3g} -> {cf:.3g} (> {rtol:.0%} band)")
     return drift
 
 

@@ -33,6 +33,7 @@ from .layers.layer import TransformerLayer
 from .model import get_transformer_layer_specs
 from .tokenizer import Tokenizer
 from ...checkpoint import load_model_checkpoint
+from ...nn.attention import PagedKVCacheView
 from ...parallel.parallel_module import ParallelModule
 
 
@@ -320,7 +321,7 @@ class TransformerInferenceModule:
         return ctx
 
     def _run_layers(self, params, batch, caches, offset, paged_kernel=None,
-                    gather_start=None, gather_width=None, moe_load=False):
+                    gather_index=None, moe_load=False):
         """One pass through the stack; TransformerLayers consume/produce the
         KV caches, edge layers run as in training (deterministic).
 
@@ -329,16 +330,16 @@ class TransformerInferenceModule:
         the Pallas kernel; ``'xla'`` is the gather formulation that tests
         hold the kernel to. Dense caches ignore it.
 
-        ``gather_start`` (a traced per-row (b,) start index) with
-        ``gather_width`` (static) slices each row's window of trunk
+        ``gather_index`` (a traced (rows, w) int32 array of FLAT positions
+        into the batch's ``b * s``) picks those positions' trunk
         activations AFTER the last TransformerLayer and BEFORE the
         post-trunk layers — which are position-pointwise, so only the
         positions that will actually be SAMPLED pay the final norm and
-        the vocab projection (the serving engine's fused mixed program
-        samples ≤ spec_k+1 of its ``mixed_width`` positions per row;
-        projecting all of them priced a (rows, width, vocab) logit
-        block nobody read). The returned logits then cover positions
-        ``gather_start .. gather_start + gather_width - 1`` per row.
+        the vocab projection (the serving engine's mixed program samples
+        ≤ spec_k+1 positions of each row out of the tick's packed
+        tokens; projecting all of them priced a logit block nobody
+        read). The returned logits are then (rows, w, vocab), entry
+        ``(r, j)`` that of position ``gather_index[r, j]``.
 
         ``moe_load`` (static; a routed model on block-paged caches) adds a
         third result: the (E,) int32 count of assignments each expert
@@ -357,17 +358,33 @@ class TransformerInferenceModule:
         if paged_kernel is not None:
             ctx.paged_kernel = paged_kernel
         last_tl = None
-        if gather_start is not None:
+        if gather_index is not None:
             tls = [
                 i for i, l in enumerate(self.module.layers)
                 if isinstance(l, TransformerLayer)
             ]
             if not tls:
                 raise ValueError(
-                    "gather_start needs a TransformerLayer trunk to "
+                    "gather_index needs a TransformerLayer trunk to "
                     "gather after (pipelined/edge-only stacks have none)"
                 )
             last_tl = max(tls)
+        shared = {}
+
+        def paged_layer_call(layer):
+            """Layers built from one architecture are one function of
+            (params, activations, cache): jitted on its own, the serving
+            engine's program traces and lowers it once, not once a layer
+            (the engine lowers a program a token width at its first tick,
+            and that is set-up time), and calls it with each layer's
+            parameters. XLA inlines the calls."""
+            key = (type(layer), id(layer.architecture))
+            if key not in shared:
+                shared[key] = jax.jit(
+                    lambda p, x, cache: layer(p, x, ctx, kv_cache=cache)
+                )
+            return shared[key]
+
         x = batch
         new_caches = []
         li = 0
@@ -377,16 +394,16 @@ class TransformerInferenceModule:
                 if caches is None:
                     x = layer(p, x, ctx)
                 else:
-                    x, kv = layer(p, x, ctx, kv_cache=caches[li], cache_offset=offset)
+                    if isinstance(caches[li], PagedKVCacheView):
+                        x, kv = paged_layer_call(layer)(p, x, caches[li])
+                    else:
+                        x, kv = layer(p, x, ctx, kv_cache=caches[li], cache_offset=offset)
                     new_caches.append(kv)
                     li += 1
                 if i == last_tl:
                     x = dict(x)
-                    x["activations"] = jax.vmap(
-                        lambda a, s: jax.lax.dynamic_slice_in_dim(
-                            a, s, gather_width, axis=0
-                        )
-                    )(x["activations"], gather_start)
+                    a = x["activations"]
+                    x["activations"] = a.reshape(-1, a.shape[-1])[gather_index]
             elif isinstance(layer, PipelinedBody):
                 if caches is not None:
                     raise ValueError(

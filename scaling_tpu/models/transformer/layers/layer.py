@@ -287,11 +287,7 @@ class TransformerLayer(BaseLayer):
             # assignments are counted (nn/moe.py, "Serving")
             real = None
             if isinstance(kv_cache, PagedKVCacheView):
-                new_len = kv_cache.new_len  # None: every position is real
-                real = (
-                    jnp.ones(h.shape[:2], bool) if new_len is None
-                    else jnp.arange(h.shape[1])[None, :] < new_len[:, None]
-                )
+                real = kv_cache.token_rows(h.shape[:2])[2]
             mlp_out, moe_load = self.mlp.serve(params["mlp"], normed, real)
         elif self.is_moe:
             mlp_out, aux_loss = self.mlp(params["mlp"], normed, ctx)

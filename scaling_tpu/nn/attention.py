@@ -52,6 +52,53 @@ def repeat_kv(x: jax.Array, n_rep: int) -> jax.Array:
     return x.reshape(b, s, n_kv * n_rep, h)
 
 
+class PagedTokenMap(NamedTuple):
+    """Which pool row each position of a TOKEN-MAJOR batch belongs to.
+
+    The serving engine packs a tick's real tokens back to back in row
+    order (row 0's ``new_len[0]`` tokens, then row 1's, ...) into a batch
+    of any shape ``(g, s)``, so that the trunk prices the tokens the tick
+    holds and not ``rows x row width`` padded positions. The paged branch
+    then needs, per position, the row whose block table and context
+    length address it and its offset among that row's new tokens; and,
+    per row, where its tokens lie, because the kernel attends row by row.
+    All three follow from ``new_len`` alone (:func:`packed_token_map`).
+
+    Positions past the tick's last real token carry the last row and an
+    offset at or past its ``new_len``: not real, so written to the trash
+    block and masked like a chunk's padding.
+    """
+
+    row: jax.Array         # (g, s) int32 pool row of each position
+    offset: jax.Array      # (g, s) int32 place among the row's new tokens
+    row_tokens: jax.Array  # (rows, w) int32 flat position (in g * s) of
+    #                        row r's j-th new token, clipped into the batch
+
+
+def packed_token_map(new_len: jax.Array, batch_shape: Tuple[int, int],
+                     row_width: int) -> PagedTokenMap:
+    """The :class:`PagedTokenMap` of a batch of shape ``batch_shape`` into
+    which the rows' ``new_len`` (rows,) tokens were packed back to back in
+    row order. ``row_width`` is the most tokens one row may bring: the
+    width of the per-row query blocks the attention regroups to."""
+    g, s = batch_shape
+    total = g * s
+    new_len = new_len.astype(jnp.int32)
+    ends = jnp.cumsum(new_len)
+    starts = ends - new_len
+    t = jnp.arange(total, dtype=jnp.int32)
+    # a token's row: how many rows end at or before it (empty rows end
+    # where they start and are stepped over)
+    row = jnp.sum(t[:, None] >= ends[None, :], axis=1, dtype=jnp.int32)
+    row = jnp.minimum(row, new_len.shape[0] - 1)
+    offset = t - starts[row]
+    row_tokens = starts[:, None] + jnp.arange(row_width, dtype=jnp.int32)
+    return PagedTokenMap(
+        row=row.reshape(g, s), offset=offset.reshape(g, s),
+        row_tokens=jnp.minimum(row_tokens, total - 1),
+    )
+
+
 class PagedKVCacheView(NamedTuple):
     """One layer's slice of the serving engine's block-paged KV pool
     (serve/kvcache.py), plus the batch's addressing state.
@@ -67,25 +114,49 @@ class PagedKVCacheView(NamedTuple):
     float (dense) or int8 with per-slot-per-head ``scale_k``/``scale_v``
     of shape ``(num_blocks, block_size, n_kv)`` (quantized KV).
 
-    ``new_len`` (per row, optional) is how many of the ``s`` presented
-    tokens are REAL: a prefill CHUNK padded to its fixed program shape
-    routes its pad tokens' KV to the trash block and excludes their
-    slots from every mask, so one compiled program serves every chunk
-    length (Sarathi-style chunked prefill, serve/engine.py). ``None``
-    means all ``s`` tokens are real.
+    ``new_len`` (per row, optional) is how many tokens the row REALLY
+    brings: a prefill CHUNK shorter than its fixed program shape routes
+    what is not a token to the trash block and excludes those slots from
+    every mask, so one compiled program serves every chunk length
+    (Sarathi-style chunked prefill, serve/engine.py). ``None`` means
+    every presented position is real.
+
+    ``token_map`` says which row each position of the batch belongs to.
+    ``None`` is the ROW-MAJOR batch ``(rows, s)``: position ``(r, j)`` is
+    row ``r``'s ``j``-th new token. The engine's mixed program packs the
+    tick's real tokens token-major instead and hands the map along
+    (:class:`PagedTokenMap`); both layouts go through the same scatter,
+    kernel and masks.
     """
 
     pool_k: jax.Array
     pool_v: jax.Array
-    block_table: jax.Array  # (b, max_blocks) int32 block ids; 0 = trash
-    context_len: jax.Array  # (b,) int32 tokens already cached per row
+    block_table: jax.Array  # (rows, max_blocks) int32 block ids; 0 = trash
+    context_len: jax.Array  # (rows,) int32 tokens already cached per row
     scale_k: Optional[jax.Array] = None
     scale_v: Optional[jax.Array] = None
-    new_len: Optional[jax.Array] = None  # (b,) int32 real tokens among s
+    new_len: Optional[jax.Array] = None  # (rows,) int32 real new tokens
+    token_map: Optional[PagedTokenMap] = None
 
     @property
     def quantized(self) -> bool:
         return self.scale_k is not None
+
+    def token_rows(self, batch_shape: Tuple[int, int]):
+        """``(row, offset, real)`` of every position of a batch of shape
+        ``(b, s)``: its pool row, its place among the row's new tokens,
+        and whether it holds a token at all."""
+        if self.token_map is None:
+            b, s = batch_shape
+            row = jnp.broadcast_to(
+                jnp.arange(b, dtype=jnp.int32)[:, None], (b, s))
+            offset = jnp.broadcast_to(
+                jnp.arange(s, dtype=jnp.int32)[None, :], (b, s))
+        else:
+            row, offset = self.token_map.row, self.token_map.offset
+        if self.new_len is None:
+            return row, offset, jnp.ones(batch_shape, bool)
+        return row, offset, offset < self.new_len.astype(jnp.int32)[row]
 
 
 def kv_quantize_int8(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -106,18 +177,21 @@ def kv_dequantize_int8(q: jax.Array, scale: jax.Array, dtype) -> jax.Array:
 
 
 def paged_flat_slots(block_table: jax.Array, positions: jax.Array,
-                     block_size: int) -> jax.Array:
-    """Map per-row logical token ``positions`` (b, s) to flat pool slots
-    ``block_id * block_size + offset`` via each row's block table.
+                     block_size: int,
+                     rows: Optional[jax.Array] = None) -> jax.Array:
+    """Map logical token ``positions`` (b, s) to flat pool slots
+    ``block_id * block_size + offset`` via their rows' block tables:
+    ``rows`` (b, s) names each position's table row, by default the
+    row-major batch's (position ``(r, j)`` reads table row ``r``).
     Positions past the table's reach route into the trash block (id 0 by
     convention sits at flat slots [0, block_size)) — NEVER into the
     row's last real block, where a clamped write would silently corrupt
     live cache."""
     max_blocks = block_table.shape[1]
+    if rows is None:
+        rows = jnp.arange(positions.shape[0], dtype=jnp.int32)[:, None]
     blk_idx = positions // block_size
-    blocks = jnp.take_along_axis(
-        block_table, jnp.clip(blk_idx, 0, max_blocks - 1), axis=1
-    )
+    blocks = block_table[rows, jnp.clip(blk_idx, 0, max_blocks - 1)]
     blocks = jnp.where(blk_idx < max_blocks, blocks, 0)
     return blocks * block_size + positions % block_size
 
@@ -622,11 +696,20 @@ class ParallelSelfAttention(BaseLayer):
     def _paged_attention(self, q, k, v, view: PagedKVCacheView, b: int, s: int,
                          ctx: ForwardContext):
         """Decode (or chunk-prefill) through the block-paged KV pool:
-        scatter the ``s`` new tokens per row into the pool, then attend
-        each row over its blocks with slot-validity + causal masking. One
+        scatter the batch's new tokens into the pool, then attend each
+        row over its blocks with slot-validity + causal masking. One
         jitted program serves every mix of sequence lengths — raggedness
-        lives entirely in ``block_table``/``context_len``/``new_len``,
-        never in shapes.
+        lives entirely in ``block_table``/``context_len``/``new_len``
+        (and ``token_map``), never in shapes.
+
+        The batch ``(b, s)`` is any layout of the rows' new tokens:
+        ``view.token_rows`` names each position's row and its offset
+        among the row's tokens (row-major by default, token-major under a
+        ``token_map``), and the scatter addresses the pool through that.
+        Attention itself runs row by row over ``(rows, w)`` query blocks:
+        a token-major batch is regrouped to them by one gather
+        (``token_map.row_tokens``) and the output gathered back to the
+        batch's order; the row-major batch is already in that shape.
 
         Two formulations behind one scatter (``ctx.paged_kernel``):
 
@@ -636,32 +719,48 @@ class ParallelSelfAttention(BaseLayer):
           Runs interpreted off-TPU, so the CPU mesh tests the real body.
         - ``'xla'`` — the tests' reference, not a serving option: gather
           each row's blocks as one contiguous
-          (b, max_blocks*block_size, n_kv, h) window, then run the
+          (rows, max_blocks*block_size, n_kv, h) window, then run the
           unfused attention. Independent of the kernel, and pure extra
           HBM traffic on a chip.
         """
         block_size = view.pool_k.shape[1]
-        max_blocks = view.block_table.shape[1]
+        rows, max_blocks = view.block_table.shape
         window = max_blocks * block_size
         ctx_len = view.context_len.astype(jnp.int32)
         if view.new_len is None:
-            new_len = jnp.full((b,), s, jnp.int32)
+            assert view.token_map is None, "a token_map is made from new_len"
+            new_len = jnp.full((rows,), s, jnp.int32)
         else:
             new_len = view.new_len.astype(jnp.int32)
 
-        # --- write: rows' next new_len slots (inactive rows: table is
-        # all-trash); chunk padding past new_len routes to the trash block
-        # — a clamped write into the row's own blocks would corrupt the
-        # slots the NEXT chunk is about to fill
-        positions = ctx_len[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-        real = jnp.arange(s, dtype=jnp.int32)[None, :] < new_len[:, None]
-        flat = paged_flat_slots(view.block_table, positions, block_size)
+        # --- write: each real token to its row's next slot (inactive
+        # rows: table is all-trash); what is not a token routes to the
+        # trash block — a clamped write into the row's own blocks would
+        # corrupt the slots the NEXT chunk is about to fill
+        row, offset, real = view.token_rows((b, s))
+        flat = paged_flat_slots(
+            view.block_table, ctx_len[row] + offset, block_size, row
+        )
         flat = jnp.where(real, flat, 0)
         new_view = paged_scatter_kv(
             view, flat.reshape(-1),
             k.reshape(b * s, *k.shape[2:]), v.reshape(b * s, *v.shape[2:]),
         )
 
+        # --- attend, row by row
+        if view.token_map is None:
+            return self._attend_rows(
+                q, new_view, ctx_len, new_len, window, ctx), new_view
+        q_rows = q.reshape(b * s, *q.shape[2:])[view.token_map.row_tokens]
+        out = self._attend_rows(q_rows, new_view, ctx_len, new_len, window, ctx)
+        return out[row, jnp.minimum(offset, q_rows.shape[1] - 1)], new_view
+
+    def _attend_rows(self, q, view: PagedKVCacheView, ctx_len, new_len,
+                     window: int, ctx: ForwardContext):
+        """``q`` (rows, w, n, h), row ``r``'s new tokens in order, over
+        the pool they were just scattered into; returns (rows, w, n, h)
+        (finite garbage past a row's ``new_len``)."""
+        rows, w = q.shape[:2]
         valid_len = ctx_len + new_len  # written slots per row
         kernel = ctx.paged_kernel
         if kernel == "pallas":
@@ -680,69 +779,67 @@ class ParallelSelfAttention(BaseLayer):
                 sm_scale=self.scaling_factor,
                 num_repeat_kv=self.num_repeat_kv,
             )
-            if mp > 1:
-                # mp>1 sharded serving: pallas calls are opaque to GSPMD
-                # (which would gather the whole pool to every device), so
-                # partition the kernel itself — each model shard streams
-                # its OWN (num_blocks, block_size, n_kv/mp, h) pool slice
-                # under its n/mp query heads. Addressing state (tables,
-                # lengths) is replicated; the GQA repeat factor is
-                # unchanged per shard because q and kv heads divide mp
-                # together (enforced at pool init, serve/kvcache.py).
-                from jax.sharding import PartitionSpec as P
-
-                heads = P(None, None, MODEL_AXIS, None)
-                rep2, rep1 = P(None, None), P(None)
-                quant = view.quantized
-                in_specs = [heads, heads, heads, rep2, rep1, rep1]
-                if quant:
-                    in_specs += [P(None, None, MODEL_AXIS)] * 2
-
-                def run_shard(qq, pk, pv, tab, vl, qb, *scales):
-                    sk, sv = scales if quant else (None, None)
-                    return call(qq, pk, pv, tab, vl, qb,
-                                scale_k=sk, scale_v=sv)
-
-                operands = [
-                    q, new_view.pool_k, new_view.pool_v,
+            if mp == 1:
+                return call(
+                    q, view.pool_k, view.pool_v,
                     view.block_table, valid_len, ctx_len,
-                ]
-                if quant:
-                    operands += [new_view.scale_k, new_view.scale_v]
-                out = jax.shard_map(
-                    run_shard, mesh=ctx.mesh, in_specs=tuple(in_specs),
-                    out_specs=heads, check_vma=False,
-                )(*operands)
-            else:
-                out = call(
-                    q, new_view.pool_k, new_view.pool_v,
-                    view.block_table, valid_len, ctx_len,
-                    scale_k=new_view.scale_k, scale_v=new_view.scale_v,
+                    scale_k=view.scale_k, scale_v=view.scale_v,
                 )
-            return out, new_view
+            # mp>1 sharded serving: pallas calls are opaque to GSPMD
+            # (which would gather the whole pool to every device), so
+            # partition the kernel itself — each model shard streams
+            # its OWN (num_blocks, block_size, n_kv/mp, h) pool slice
+            # under its n/mp query heads. Addressing state (tables,
+            # lengths) is replicated; the GQA repeat factor is
+            # unchanged per shard because q and kv heads divide mp
+            # together (enforced at pool init, serve/kvcache.py).
+            from jax.sharding import PartitionSpec as P
+
+            heads = P(None, None, MODEL_AXIS, None)
+            rep2, rep1 = P(None, None), P(None)
+            quant = view.quantized
+            in_specs = [heads, heads, heads, rep2, rep1, rep1]
+            if quant:
+                in_specs += [P(None, None, MODEL_AXIS)] * 2
+
+            def run_shard(qq, pk, pv, tab, vl, qb, *scales):
+                sk, sv = scales if quant else (None, None)
+                return call(qq, pk, pv, tab, vl, qb,
+                            scale_k=sk, scale_v=sv)
+
+            operands = [
+                q, view.pool_k, view.pool_v,
+                view.block_table, valid_len, ctx_len,
+            ]
+            if quant:
+                operands += [view.scale_k, view.scale_v]
+            return jax.shard_map(
+                run_shard, mesh=ctx.mesh, in_specs=tuple(in_specs),
+                out_specs=heads, check_vma=False,
+            )(*operands)
         assert kernel == "xla", (
             f"unknown paged_kernel {kernel!r} (expected 'pallas' or 'xla') "
             "— refusing to silently pick an attention path"
         )
 
         # --- gather: each row's blocks as one contiguous KV window
-        gk = new_view.pool_k[view.block_table]  # (b, max_blocks, bs, n_kv, h)
-        gv = new_view.pool_v[view.block_table]
-        gk = gk.reshape(b, window, *gk.shape[3:])
-        gv = gv.reshape(b, window, *gv.shape[3:])
+        gk = view.pool_k[view.block_table]  # (rows, max_blocks, bs, n_kv, h)
+        gv = view.pool_v[view.block_table]
+        gk = gk.reshape(rows, window, *gk.shape[3:])
+        gv = gv.reshape(rows, window, *gv.shape[3:])
         if view.quantized:
-            gsk = new_view.scale_k[view.block_table].reshape(b, window, -1)
-            gsv = new_view.scale_v[view.block_table].reshape(b, window, -1)
-            gk = kv_dequantize_int8(gk, gsk, k.dtype)
-            gv = kv_dequantize_int8(gv, gsv, v.dtype)
+            gsk = view.scale_k[view.block_table].reshape(rows, window, -1)
+            gsv = view.scale_v[view.block_table].reshape(rows, window, -1)
+            gk = kv_dequantize_int8(gk, gsk, q.dtype)
+            gv = kv_dequantize_int8(gv, gsv, q.dtype)
 
         # masking runs on LOGICAL slot indices (the causal clock), exactly
         # like the dense cache path: unwritten slots are invalid, written
         # slots obey causal order against the query's slot
         slots_k = jnp.broadcast_to(
-            jnp.arange(window, dtype=jnp.int32)[None, :], (b, window)
+            jnp.arange(window, dtype=jnp.int32)[None, :], (rows, window)
         )
-        slots_q = positions  # (b, s)
+        slots_q = ctx_len[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
         valid_k = slots_k < valid_len[:, None]
         allowed = valid_k[:, None, :] & (
             slots_k[:, None, :] <= slots_q[:, :, None]
@@ -751,10 +848,9 @@ class ParallelSelfAttention(BaseLayer):
 
         gk = repeat_kv(gk, self.num_repeat_kv)
         gv = repeat_kv(gv, self.num_repeat_kv)
-        out = multi_head_attention(
+        return multi_head_attention(
             q, gk, gv, mask, self.scaling_factor, self.masked_softmax, None
         )
-        return out, new_view
 
     def _project_out(self, params, out, ctx, b, s, new_kv):
         """Shared epilogue: heads -> hidden, dense projection + LoRA delta."""

@@ -21,11 +21,18 @@ Paged attention streams KV blocks through the Pallas paged-decode
 kernel (nn/paged_attention.py — interpreted off-TPU so the CPU mesh
 runs the real kernel body).
 
+The program's batch is TOKEN-MAJOR: the tick's real tokens, packed back
+to back in slot order into one of (at most) two token widths that follow
+from the configuration (``EngineConfig.mixed_widths``), so the trunk
+prices the tokens a tick holds and not ``num_slots x mixed_width`` padded
+positions.
+
 No per-request recompiles, by construction: the mixed program compiles
-once per ``(prefill_chunk, spec_k)`` width signature — its shapes are
-the fixed ``(num_slots, mixed_width, max_blocks_per_seq)`` batch, and
-sequence raggedness (prompt lengths, prefill offsets, draft lengths)
-lives in block tables / context lengths / new_lens, never in shapes.
+once per token width, every width at the engine's first tick — its
+shapes are the fixed ``(width,)`` tokens and ``(num_slots,
+max_blocks_per_seq)`` tables, and sequence raggedness (prompt lengths,
+prefill offsets, draft lengths) lives in block tables / context lengths
+/ new_lens, never in shapes.
 All signatures are pinned in the ``serve_decode`` HLO-audit section
 (analysis/goldens/serve_decode.json): a scheduler shape-bucketing or
 kernel change that would trigger a recompile storm on the chip shows up
@@ -44,7 +51,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 from .. import obs
 from ..logging import logger
@@ -64,6 +71,26 @@ from .scheduler import (
     Sequence,
     Tick,
 )
+
+# the minor dimension of a TPU vector register: the small token width is a
+# whole multiple of it
+LANES = 128
+# prompts streaming a chunk each that the small token bucket has room for
+# beside a decode row in every slot (EngineConfig.mixed_widths)
+SMALL_BUCKET_CHUNKS = 3
+
+
+def packed_batch_shape(width: int, row_width: int) -> Tuple[int, int]:
+    """The ``(g, s)`` a token width runs the trunk at: groups of
+    ``row_width`` tokens, the most one row brings (or of the widest
+    divisor of the width under it). The dense trunk does not care for
+    the shape; a routed MLP's one-hot dispatch is ``(g, s, E, C)`` with
+    ``C = s`` when serving (nn/moe.py), linear in the width while ``s``
+    stays fixed; and at this ``s`` the full width ``num_slots *
+    row_width`` is, shape for shape, the row-major batch it replaced."""
+    s = next(d for d in range(min(width, row_width), 0, -1) if width % d == 0)
+    return width // s, s
+
 
 @dataclasses.dataclass
 class EngineConfig:
@@ -111,11 +138,30 @@ class EngineConfig:
 
     @property
     def mixed_width(self) -> int:
-        """The mixed program's per-row token width: chunk rows need
-        ``prefill_chunk`` slots, speculative decode rows ``spec_k + 1``
-        (last accepted token + k drafts). One program per (chunk, k)
-        signature — the recompile key the serve_decode golden pins."""
+        """The most tokens one row brings to a tick: chunk rows up to
+        ``prefill_chunk``, speculative decode rows ``spec_k + 1`` (last
+        accepted token + k drafts). The width of the per-row query
+        blocks the paged kernel attends over."""
         return max(self.prefill_chunk, self.spec_k + 1)
+
+    @property
+    def mixed_widths(self) -> Tuple[int, ...]:
+        """Token widths ``T`` the engine builds its mixed program at, in
+        rising order; a tick runs at the smallest that holds its real
+        tokens (``sum(new_len)``). At most two, fixed by the
+        configuration: the full width ``num_slots * mixed_width`` holds
+        whatever the scheduler admits; the small one holds the common
+        tick, a decode row (1 + ``spec_k`` tokens) in every slot and
+        ``SMALL_BUCKET_CHUNKS`` prompts streaming a chunk each, rounded
+        up to whole ``LANES`` (below the chip's ridge a tick costs one
+        read of the weights whatever it holds, so a finer bucket buys
+        nothing and a third program costs its warm-up). Engines whose
+        full width is no larger build the one program."""
+        full = self.num_slots * self.mixed_width
+        small = (self.num_slots * (self.spec_k + 1)
+                 + SMALL_BUCKET_CHUNKS * self.prefill_chunk)
+        small = -(-small // LANES) * LANES
+        return (small, full) if small < full else (full,)
 
     @property
     def sample_width(self) -> int:
@@ -123,9 +169,8 @@ class EngineConfig:
         decode row reads its last token's sample plus one per draft
         (``spec_k + 1`` at most), a finishing chunk row exactly one.
         The program gathers this window of trunk activations per row
-        BEFORE the vocab projection, so the lm_head prices
-        ``sample_width`` positions instead of all ``mixed_width`` — at
-        the default chunk 32 / spec off, a 32x cut in projection work."""
+        BEFORE the vocab projection, so the lm_head prices ``num_slots
+        * sample_width`` positions whatever the tick's token width."""
         return min(self.mixed_width, self.spec_k + 1)
 
     def scheduler_config(self) -> SchedulerConfig:
@@ -189,8 +234,13 @@ class ServeEngine:
         self._base_key = self._dev(
             jax.random.PRNGKey(self.config.sample_seed)
         )
-        # (width,) -> the ONE fused mixed program per (chunk, k) signature
+        # token width -> the fused mixed program built at it: every width
+        # of config.mixed_widths, all lowered at the first tick
         self._mixed_fns: Dict[int, object] = {}
+        # token width -> ticks run at it / real tokens they held (warm-up
+        # apart): how often the small program serves, and the padding left
+        self.mixed_ticks: Dict[int, int] = {}
+        self.mixed_tokens: Dict[int, int] = {}
         # a routed model (mlp_type moe): the mixed program also returns,
         # in the tick's one host read, how many assignments of real
         # positions each expert received (0: a dense model, which pays
@@ -391,8 +441,10 @@ class ServeEngine:
             return self._jax.device_put(x)
         return self._jax.device_put(x, self._replicated)
 
-    def _counter(self, name: str):
-        return self._reg.counter(name, self._labels)
+    def _counter(self, name: str, **labels):
+        if labels:
+            labels.update(self._labels or {})
+        return self._reg.counter(name, labels or self._labels)
 
     def _gauge(self, name: str):
         return self._reg.gauge(name, self._labels)
@@ -403,10 +455,6 @@ class ServeEngine:
     def _pool_state(self):
         p = self.pools
         return (p.pool_k, p.pool_v, p.scale_k, p.scale_v)
-
-    def _views_from_state(self, state, block_table, context_len,
-                          new_len=None):
-        return build_layer_views(state, block_table, context_len, new_len)
 
     def _absorb(self, state) -> None:
         self.pools.absorb_state(state)
@@ -469,51 +517,79 @@ class ServeEngine:
         return flat.reshape(rows, s)
 
     def _build_mixed_fn(self, width: int):
-        """ONE fused Sarathi-style program per tick: every slot row is a
-        decode row (its last token plus up to ``spec_k`` drafted
-        candidates) or a prefill chunk, tagged purely by traced per-row
-        lengths — a tick dispatches exactly one executable, however many
-        sequences are prefilling. Rows share the scatter-then-attend paged path
-        (``new_len`` routes each row's pads to the trash block; rows
-        never share pool blocks, so fusing their writes is exact), and
-        EVERY position is sampled with its plain-decode key
+        """ONE fused Sarathi-style program per tick, over a TOKEN-MAJOR
+        batch: the tick's real tokens (a decode row's last token plus up
+        to ``spec_k`` drafted candidates, a chunk row's ``<=
+        prefill_chunk`` prompt tokens, nothing for an empty slot) lie
+        back to back in slot order in ``tokens`` (``width``,), and the
+        trunk (norms, QKV, rotary, MLP or routed MLP, output projection)
+        runs over those ``width`` positions, shaped
+        ``packed_batch_shape(width, mixed_width)`` — not over ``num_slots
+        x mixed_width`` padded ones. Below the chip's ridge a tick so costs
+        one read of the weights. Rows are tagged purely by traced per-row
+        lengths, so a tick dispatches exactly one executable, however
+        many sequences are prefilling.
+
+        Addressing is derived on the device from ``new_lens`` alone
+        (``packed_token_map``: a token's row by comparison against the
+        rows' running ends, its offset from the row's start), so the
+        program takes the nine operands it always took. Rotary positions
+        are ``ctx_lens[row] + offset``. The paged branch
+        (``Attention._paged_attention``) scatters each token's K/V through
+        its row's table (what is no token goes to the trash block; rows
+        never share pool blocks, so fusing their writes is exact),
+        regroups the queries to the ``(num_slots, mixed_width)`` blocks
+        the kernel takes and gathers its output back to token order.
+
+        EVERY sampled position draws with its plain-decode key
         (``_sample_grid``): decode rows read positions ``0..new_len-1``
         for speculative acceptance, a chunk row that completes its
         prompt reads position ``new_len - 1``. Only ``sample_width``
-        (= min(width, spec_k+1)) positions per row are ever read, so the
-        program GATHERS each row's sampling window of trunk activations
-        before the vocab projection (ISSUE 13 satellite): row window =
-        positions ``g0 .. g0 + sample_width - 1`` with
-        ``g0 = clip(new_len - sample_width, 0)`` — covers positions
-        ``0..new_len-1`` for decode rows (new_len ≤ spec_k+1 ⇒ g0 = 0)
-        and position ``new_len - 1`` for chunk rows, while the lm_head
-        prices ``sample_width`` positions instead of all ``width``.
-        Compiles once per (chunk, k) width signature — pinned in the
+        (= min(mixed_width, spec_k+1)) positions per row are ever read,
+        so the program GATHERS each row's sampling window of trunk
+        activations before the vocab projection (ISSUE 13 satellite): row
+        window = positions ``g0 .. g0 + sample_width - 1`` of the row's
+        tokens with ``g0 = clip(new_len - sample_width, 0)`` — covers
+        positions ``0..new_len-1`` for decode rows (new_len ≤ spec_k+1 ⇒
+        g0 = 0) and position ``new_len - 1`` for chunk rows, while the
+        lm_head prices ``num_slots * sample_width`` positions.
+
+        One program per width of ``EngineConfig.mixed_widths`` (two at
+        most), all lowered at the engine's first tick — pinned in the
         serve_decode golden.
 
         A routed model's program returns ONE int32 vector instead of the
         grid: the sampled grid flattened, then the (E,) load of the
         tick's real positions (``_run_layers(moe_load=True)``), so that
         the load costs the tick no second host read."""
+        from ..nn.attention import packed_token_map
+
         jnp = self._jax.numpy
         sample_width = self.config.sample_width
+        row_width = self.config.mixed_width
+        shape = packed_batch_shape(width, row_width)
         routed = self.num_experts > 0
 
         def mixed(params, state, tables, ctx_lens, tokens, new_lens,
                   temps, topps, topks, reqids, gen0, base_key):
-            pos = ctx_lens[:, None] + jnp.arange(
-                width, dtype=jnp.int32
-            )[None, :]
-            batch = self.inf._make_batch(tokens, pos)
-            views = self._views_from_state(state, tables, ctx_lens,
-                                           new_lens)
-            g0 = jnp.clip(new_lens - sample_width, 0, width - sample_width)
+            token_map = packed_token_map(new_lens, shape, row_width)
+            row, offset = token_map.row, token_map.offset
+            # what is no token keeps position 0 (finite rotary, whatever
+            # the last row's context)
+            pos = jnp.where(offset < new_lens[row], ctx_lens[row] + offset, 0)
+            batch = self.inf._make_batch(tokens.reshape(shape), pos)
+            views = build_layer_views(state, tables, ctx_lens, new_lens,
+                                      token_map)
+            g0 = jnp.clip(new_lens - sample_width, 0,
+                          row_width - sample_width)
+            window = g0[:, None] + jnp.arange(sample_width, dtype=jnp.int32)
             logits, new_views, *load = self.inf._run_layers(
                 params, batch, views, None,
-                gather_start=g0, gather_width=sample_width,
+                gather_index=jnp.take_along_axis(
+                    token_map.row_tokens, window, axis=1),
                 moe_load=routed,
             )
-            # gathered index j is original position g0 + j: shift the
+            # gathered index j is the row's token g0 + j: shift the
             # per-row key-fold base so every sample still draws with the
             # (request, position) key plain decode would use there
             sampled = self._sample_grid(
@@ -532,6 +608,28 @@ class ServeEngine:
         # call would warn).
         donate = (1,) if self._jax.default_backend() != "cpu" else ()
         return self._jax.jit(mixed, donate_argnums=donate)
+
+    def _lower_mixed_programs(self) -> None:
+        """Build the program of every token width and run each once on an
+        EMPTY tick (no row brings a token: every write lands in the trash
+        block, the samples are dropped), so that all of them are traced,
+        lowered and compiled (or loaded from the compile cache) at the
+        engine's first tick, through the very call path later ticks take.
+        A width first needed minutes into serving must not pay its
+        lowering then."""
+        np = self._np
+        n = self.config.num_slots
+        rows_i, rows_f = np.zeros((n,), np.int32), np.zeros((n,), np.float32)
+        tables = np.zeros((n, self.config.max_blocks_per_seq), np.int32)
+        for width in self.config.mixed_widths:
+            fn = self._mixed_fns[width] = self._build_mixed_fn(width)
+            operands = self._dev((
+                tables, rows_i, np.zeros((width,), np.int32), rows_i,
+                rows_f, rows_f, rows_i, rows_i, rows_i,
+            ))
+            _, state = fn(self.inf.params, self._pool_state(), *operands,
+                          self._base_key)
+            self._absorb(state)
 
     # ------------------------------------------------------------- ticking
     def _reset_rows(self, slots: List[int]) -> None:
@@ -573,24 +671,25 @@ class ServeEngine:
 
     def _run_mixed(self, t: Tick) -> None:
         """The fused tick (Sarathi piggybacking): ONE program call
-        covers every prefill chunk AND the whole decode batch, each row
-        tagged by its traced ``new_len``/``ctx_len``. Decode rows carry
-        their speculative drafts; acceptance happens host-side on the
-        returned per-position samples (``_accept_speculative``)."""
+        covers every prefill chunk AND the whole decode batch, the rows'
+        real tokens packed back to back in slot order into the smallest
+        token width that holds them, each row tagged by its traced
+        ``new_len``/``ctx_len``. Decode rows carry their speculative
+        drafts; acceptance happens host-side on the returned per-position
+        samples (``_accept_speculative``)."""
         np = self._np
-        jnp = self._jax.numpy
         cfg = self.config
-        width = cfg.mixed_width
-        if width not in self._mixed_fns:
-            self._mixed_fns[width] = self._build_mixed_fn(width)
+        if not self._mixed_fns:
+            self._lower_mixed_programs()
         step = self.tick_index
         with self._span("serve.mixed", step=step,
                         decodes=len(t.decodes), chunks=len(t.prefills),
                         **self._trace_fields(t.decodes),
-                        **self._trace_fields(t.prefills, "chunk_traces")):
+                        **self._trace_fields(t.prefills, "chunk_traces")
+                        ) as mixed_span:
             with self._span("serve.mixed.build", step=step):
                 n = cfg.num_slots
-                tokens = np.zeros((n, width), np.int32)
+                row_tokens: List[List[int]] = [[]] * n  # by slot
                 new_lens = np.zeros((n,), np.int32)
                 ctx = np.zeros((n,), np.int32)
                 gen0 = np.zeros((n,), np.int32)
@@ -603,7 +702,7 @@ class ServeEngine:
                     n_real = min(cfg.prefill_chunk, seq.prefill_len - start)
                     assert n_real > 0, \
                         "chunk row scheduled with nothing to prefill"
-                    tokens[slot, :n_real] = prompt[start:start + n_real]
+                    row_tokens[slot] = prompt[start:start + n_real]
                     new_lens[slot] = n_real
                     ctx[slot] = start
                     tables[slot, :len(seq.blocks)] = seq.blocks
@@ -618,18 +717,27 @@ class ServeEngine:
                     chunk_rows.append((seq, start, n_real))
                 for seq in t.decodes:
                     slot = seq.slot
-                    d = seq.draft
-                    tokens[slot, 0] = seq.generated[-1]
-                    if d:
-                        tokens[slot, 1:1 + len(d)] = d
-                    new_lens[slot] = 1 + len(d)
+                    row_tokens[slot] = [seq.generated[-1], *seq.draft]
+                    new_lens[slot] = 1 + len(seq.draft)
                     ctx[slot] = seq.num_cached
                     tables[slot, :len(seq.blocks)] = seq.blocks
                     gen0[slot] = len(seq.generated)
                     self._gen[slot] = len(seq.generated)
-                # inactive rows keep all-trash tables + new_len 0: their
-                # writes land in the trash block and they expose zero
-                # visible slots
+                # inactive rows keep all-trash tables + new_len 0: they
+                # bring no token and expose zero visible slots
+                packed = [tok for row in row_tokens for tok in row]
+                width = next(
+                    w for w in cfg.mixed_widths if len(packed) <= w
+                )
+                tokens = np.zeros((width,), np.int32)
+                tokens[:len(packed)] = packed
+            if not self.warmup_mode:  # mixed_span is a span
+                mixed_span.annotate(width=width, tokens=len(packed))
+                self.mixed_ticks[width] = self.mixed_ticks.get(width, 0) + 1
+                self.mixed_tokens[width] = (
+                    self.mixed_tokens.get(width, 0) + len(packed)
+                )
+                self._counter("serve_mixed_ticks_total", width=width).inc()
             with self._span("serve.mixed.dispatch", step=step):
                 operands = self._dev((
                     tables, ctx, tokens, new_lens, self._temp, self._topp,
@@ -940,8 +1048,9 @@ class ServeEngine:
 
     @property
     def prefill_program_count(self) -> int:
-        """Compiled mixed programs: one per (chunk, k) width signature,
-        so 1 for an engine's whole life."""
+        """Compiled mixed programs: one per token width of
+        ``config.mixed_widths`` from the first tick on, so at most 2 for
+        an engine's whole life."""
         return len(self._mixed_fns)
 
     def stats_snapshot(self) -> dict:
@@ -979,6 +1088,11 @@ class ServeEngine:
             "spec_accepted_tokens": self.spec_accepted_tokens,
             "prefill_compiles": self.prefill_program_count,
             "max_concurrent_prefills": self.max_concurrent_prefills,
+            # by token width (JSON keys are strings): ticks run at it and
+            # the real tokens they held; 1 - tokens / (ticks * width) is
+            # the padding left
+            "mixed_ticks": {str(w): c for w, c in self.mixed_ticks.items()},
+            "mixed_tokens": {str(w): c for w, c in self.mixed_tokens.items()},
         }
 
     def run_until_done(self, max_ticks: int = 100_000) -> List[Sequence]:

@@ -35,7 +35,7 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..nn.attention import PagedKVCacheView
+from ..nn.attention import PagedKVCacheView, PagedTokenMap
 
 
 def serving_mesh(inference_module):
@@ -53,6 +53,7 @@ def build_layer_views(
     block_table: jax.Array,          # (rows, max_blocks) int32
     context_len: jax.Array,          # (rows,) int32
     new_len: Optional[jax.Array] = None,  # (rows,) int32 real new tokens
+    token_map: Optional[PagedTokenMap] = None,  # a token-major batch's
 ) -> List[PagedKVCacheView]:
     """Per-layer :class:`PagedKVCacheView` s over the raw pool state —
     the shape the engine's jitted programs thread through ``_run_layers``.
@@ -62,7 +63,9 @@ def build_layer_views(
     program presents, only the first ``new_len`` per row are real — the
     attention path writes the rest to the trash block and masks their
     slots, so ONE compiled chunk program serves every chunk length
-    (including the final ragged chunk of every prompt)."""
+    (including the final ragged chunk of every prompt). ``token_map``
+    (``nn.attention.packed_token_map``) rides along when the batch holds
+    the rows' tokens packed token-major instead of one row a batch row."""
     pool_k, pool_v, scale_k, scale_v = state
     return [
         PagedKVCacheView(
@@ -70,7 +73,7 @@ def build_layer_views(
             block_table=block_table, context_len=context_len,
             scale_k=None if scale_k is None else scale_k[i],
             scale_v=None if scale_v is None else scale_v[i],
-            new_len=new_len,
+            new_len=new_len, token_map=token_map,
         )
         for i in range(len(pool_k))
     ]
@@ -87,11 +90,12 @@ def state_from_views(views: List[PagedKVCacheView]) -> Tuple:
     order, so only a state that leaves in the structure it entered in
     aliases ``pool_k[i]`` to the output computed from ``pool_k[i]``, and
     only then does XLA run the layer's scatter in place. The views
-    themselves flatten layer-major (``k0, v0, table, ctx, new_len, k1,
-    ...``): returned as they are they hand ``pool_k[1]``'s buffer to
+    themselves flatten layer-major (``k0, v0, table, ctx, new_len, map,
+    k1, ...``): returned as they are they hand ``pool_k[1]``'s buffer to
     output ``v0``, written before layer 1 has read it, and XLA copies
-    every pool but the first on every call. Their table, lengths and
-    ``new_len`` are the program's inputs and do not come back."""
+    every pool but the first on every call. Their table, lengths,
+    ``new_len`` and token map are the program's inputs (or derived from
+    them) and do not come back."""
     quantized = views[0].scale_k is not None
     return (
         [v.pool_k for v in views],

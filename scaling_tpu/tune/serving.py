@@ -63,6 +63,21 @@ from .layouts import Layout, ModelSpec
 # tuner's goldens) wait for a measured block_size sweep (ROADMAP S1).
 PAGED_BLOCK_OVERHEAD = 4.0
 
+# ``predict_tick_seconds`` below prices a tick as ``token_budget`` tokens of
+# compute with no floor, which was never the program's cost and since
+# ISSUE 33 is not its shape either: the engine packs a tick's real tokens
+# into one of two token widths (``EngineConfig.mixed_widths``: 128 and 512
+# at 16 slots x chunk 32), and under the chip's ridge (~240 positions on a
+# v5e) a tick costs one read of the weights whatever it holds. Measured on
+# a v5e (PERF.md section 6, PR 33; ``engine.tick()`` around its host read,
+# 16 slots, the benchmark's serve configurations): Mistral-7B, 16 layers,
+# 16.5 ms at the small width (16 decode rows) and 28.9 ms at the full one
+# (16 whole chunks), where the row-major program took 28.2 and 29.2;
+# OLMoE-1B-7B, 8 layers, 16.7 and 42.9 ms (37.9 and 38.3). A model that
+# ranks serving points has to know the weight-read floor and the two
+# widths; re-fitting it is not done here (ROADMAP S1), and no cell of the
+# benchmark runs this file.
+
 # steady-state KV residency per slot of token budget: the pool must hold
 # the CONTEXTS of every in-flight sequence, not just the tick's new
 # tokens. Derived from the engine defaults (num_slots * max context /

@@ -181,11 +181,21 @@ def test_audit_gate_serve_decode_matches_golden(tmp_path):
     # and no selector of a program or a back-end (there is one of each)
     assert set(static) == {
         "kind", "num_slots", "block_size", "max_blocks_per_seq", "kv_dtype",
-        "prefill_chunk", "spec_k", "mixed_width", "sample_width"}
+        "prefill_chunk", "spec_k", "mixed_width", "sample_width",
+        "token_widths", "token_width"}
     assert static["mixed_width"] == max(static["prefill_chunk"],
                                         static["spec_k"] + 1)
-    # the separate chunk program is GONE — one mixed program replaced
-    # the decode + per-sequence chunk dispatch
+    # the engine's two token widths (ISSUE 33): the section is the small
+    # width's program, the full width's is pinned beside it — the same
+    # function at another T: as many dots, no other collective, about
+    # twice the work — and there is no third
+    small, full = static["token_widths"]
+    assert static["token_width"] == small < full
+    assert full == static["num_slots"] * static["mixed_width"]
+    at_full = sec["full_width"]
+    assert at_full["hash"] != sec["recompile_key"]["hash"]
+    assert at_full["dot_general_count"] == sec["dot_general_count"]
+    assert 1.5 * sec["flops"] < at_full["flops"] < 2.0 * sec["flops"]
     assert sec.get("chunk_program") is None
     # off-TPU the paged kernel runs interpreted (inlined HLO, 0 custom
     # calls); an on-chip repin records the real custom-call count
@@ -206,6 +216,11 @@ def test_audit_gate_serve_decode_matches_golden(tmp_path):
     ), mp2["collectives"]
     assert mp2["host_callbacks"] == 0
     assert mp2["flops"] < sec["flops"]  # compute genuinely sharded
+    # the full width pays the same collectives, on twice the positions
+    counts = {(r["op"], r["count"]) for r in mp2["collectives"]}
+    assert counts == {(r["op"], r["count"])
+                      for r in mp2["full_width"]["collectives"]}
+    assert counts == {("all-gather", 1), ("all-reduce", 5)}
 
 
 def test_audit_gate_detects_seeded_drift(tmp_path):

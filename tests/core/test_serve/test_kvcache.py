@@ -125,7 +125,10 @@ def test_chunk_padding_lands_in_trash_not_blocks(toy_inference):
 
 # --- the donated pool state leaves the program as it entered (ISSUE 31) ---
 
-SLOTS, MAX_BLOCKS, CHUNK = 2, 8, 4
+# 8 slots x chunk 32: the smallest engine with BOTH token buckets (8 decode
+# tokens + 3 chunks round up to 128, under the full 256)
+SLOTS, MAX_BLOCKS, CHUNK = 8, 8, 32
+WIDTHS = (128, 256)
 MODELS = ["dense", "routed", "mp2"]
 
 
@@ -167,9 +170,9 @@ def inference_modules(toy_inference):
     }
 
 
-def _program_and_args(inf, kv_dtype, spec_k):
-    """The engine's program as the plain function under its ``jax.jit``,
-    with toy arguments in its signature."""
+def _program_and_args(inf, kv_dtype, spec_k, width=WIDTHS[0]):
+    """The engine's program at one of its token widths, as the plain
+    function under its ``jax.jit``, with toy arguments in its signature."""
     from scaling_tpu.serve.engine import EngineConfig, ServeEngine
 
     engine = ServeEngine(inf, EngineConfig(
@@ -177,13 +180,13 @@ def _program_and_args(inf, kv_dtype, spec_k):
         max_blocks_per_seq=MAX_BLOCKS, token_budget=64, prefill_chunk=CHUNK,
         kv_dtype=kv_dtype, spec_k=spec_k,
     ))
-    width = engine.config.mixed_width
+    assert engine.config.mixed_widths == WIDTHS
 
     def z(*shape, dt=np.int32):
         return np.zeros(shape, dt)
 
     operands = engine._dev((
-        z(SLOTS, MAX_BLOCKS), z(SLOTS), z(SLOTS, width),
+        z(SLOTS, MAX_BLOCKS), z(SLOTS), z(width),
         np.ones(SLOTS, np.int32), z(SLOTS, dt=np.float32),
         z(SLOTS, dt=np.float32), z(SLOTS), z(SLOTS), z(SLOTS),
     ))
@@ -212,13 +215,14 @@ def _aliases(lowered):
     return aliases
 
 
+@pytest.mark.parametrize("width", WIDTHS, ids=["small", "full"])
 @pytest.mark.parametrize("kv_dtype", ["native", "int8"])
 @pytest.mark.parametrize(
     "model,spec_k", [("dense", 0), ("dense", 2), ("routed", 0), ("mp2", 0)],
     ids=["mixed", "mixed-spec2", "routed", "mp2"],
 )
 def test_donated_pool_aliases_the_output_computed_from_it(
-        inference_modules, model, spec_k, kv_dtype):
+        inference_modules, model, spec_k, kv_dtype, width):
     """JAX pairs a donated buffer with an output of its shape and dtype
     in flattened order, so ``pool_v[3]`` is updated in place only if the
     program's lowered ``main`` says its argument aliases the output leaf
@@ -230,9 +234,10 @@ def test_donated_pool_aliases_the_output_computed_from_it(
     and XLA copied every pool but the first on every call. A routed
     model's first output is one vector (grid + load), still ONE leaf
     ahead of the state; on the serving mesh every pool is sharded over
-    ``model`` and XLA does the pairing."""
+    ``model`` and XLA does the pairing. Both token widths' programs
+    donate and return the same state."""
     _, fn, args = _program_and_args(
-        inference_modules[model], kv_dtype, spec_k)
+        inference_modules[model], kv_dtype, spec_k, width)
     lowered = jax.jit(fn, donate_argnums=(1,), keep_unused=True).lower(
         *args
     )
@@ -244,12 +249,13 @@ def test_donated_pool_aliases_the_output_computed_from_it(
     assert _aliases(lowered) == want
 
 
+@pytest.mark.parametrize("width", WIDTHS, ids=["small", "full"])
 @pytest.mark.parametrize("kv_dtype", ["native", "int8"])
 @pytest.mark.parametrize("model", MODELS, ids=["mixed", "routed", "mp2"])
 def test_programs_return_the_state_in_pool_state_structure(
-        inference_modules, model, kv_dtype):
+        inference_modules, model, kv_dtype, width):
     engine, fn, args = _program_and_args(
-        inference_modules[model], kv_dtype, 0)
+        inference_modules[model], kv_dtype, 0, width)
     sampled, state = jax.eval_shape(fn, *args)
     sw = engine.config.sample_width
     assert sampled.shape == (
@@ -272,8 +278,9 @@ def test_run_layers_on_paged_views_defaults_to_the_kernel(toy_inference):
     from scaling_tpu.serve.kvcache import build_layer_views
 
     _, _, args = _program_and_args(toy_inference, "native", 0)
-    params, state, tables, ctx_lens, tokens, new_lens = args[:6]
-    pos = ctx_lens[:, None] + jnp.arange(tokens.shape[1])[None, :]
+    params, state, tables, ctx_lens, _, new_lens = args[:6]
+    tokens = jnp.zeros((SLOTS, CHUNK), jnp.int32)  # a row-major batch
+    pos = ctx_lens[:, None] + jnp.arange(CHUNK)[None, :]
     batch = toy_inference._make_batch(tokens, pos)
 
     def pallas_calls(**named):

@@ -75,7 +75,7 @@ def test_load_feeds_the_counter_and_the_emit_span(tmp_path):
     assert all(0 <= f["experts_idle"] <= EXPERTS and f["load_max"] >= f["load_mean"]
                for f in emits)
     # one program, whose first result is ONE vector: the grid, then the load
-    assert list(engine._mixed_fns) == [engine.config.mixed_width]
+    assert list(engine._mixed_fns) == list(engine.config.mixed_widths)
 
 
 def test_a_dense_model_pays_nothing(tmp_path):
@@ -102,10 +102,37 @@ def test_one_program_serves_a_routed_model_whatever_the_tick_holds():
     assert all(len(s.generated) == 6 for s in seqs)
     assert engine.scheduler.preemption_count > 0
     assert engine.spec_drafted_tokens > 0
-    width = engine.config.mixed_width
+    width, = engine.config.mixed_widths  # a toy engine has one bucket
     assert jitted_programs(engine) == {"_mixed_fns": 1}
     assert list(engine._mixed_fns) == [width]
     assert engine._mixed_fns[width]._cache_size() == 1
+
+
+def test_packed_ticks_drop_no_assignment_and_count_real_positions_only():
+    """A routed engine with both token widths (8 slots x 32: 128 under
+    256), through ticks of either: every request's tokens are those of the
+    plain cached forward pass (which has room for everything, so nothing
+    was dropped by packing rows into shared groups), and the load counts
+    each real position's top_k choices once a layer — not the width's
+    empty tail."""
+    engine = make_engine({"num_slots": 8, "prefill_chunk": 32,
+                          "num_blocks": 8 * 8 + 1,
+                          "enable_prefix_cache": False}, **ROUTED)
+    assert engine.config.mixed_widths == (128, 256)
+    rng = np.random.default_rng(2)
+    lengths = (70, 40, 33, 90, 64, 5, 1)  # 5+ prompts stream at once: 256
+    prompts = [[int(t) for t in rng.integers(1, 90, n)] for n in lengths]
+    before = obs.get_registry().snapshot()["counters"].get(
+        "serve_moe_assignments_total", 0)
+    seqs = [engine.submit(p, 4) for p in prompts]
+    engine.run_until_done()
+    assert set(engine.mixed_ticks) == {128, 256}
+    moved = obs.get_registry().snapshot()["counters"][
+        "serve_moe_assignments_total"] - before
+    assert moved == sum(n + 4 - 1 for n in lengths) * TOP_K * LAYERS
+    for prompt, seq in zip(prompts, seqs):
+        want = engine.inf.generate(prompt, max_tokens=4, use_cache=True)
+        assert seq.generated == want.completion_ids
 
 
 def test_projection_scope_needs_the_norm_switched_on():

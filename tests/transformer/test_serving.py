@@ -136,11 +136,12 @@ def test_chunked_prefill_matches_dense(trained_inference,
     for i, ref in enumerate(reference_completions):
         assert seqs[i].generated == ref, (
             f"request {i}: {seqs[i].generated} != dense {ref}")
-    # every prompt took exactly the rows its length asks for, through ONE
-    # program of the chunk's width
+    # every prompt took exactly the rows its length asks for, through the
+    # ONE program of this toy width (4 slots x the chunk: no smaller
+    # bucket fits under it)
     assert chunk_rows == {
         i: -(-len(p) // chunk) for i, p in enumerate(PROMPTS)}
-    assert set(engine._mixed_fns) == {chunk}
+    assert set(engine._mixed_fns) == {4 * chunk}
     # several prompts prefilled in the same tick (the throughput point)
     assert engine.max_concurrent_prefills >= 2
 
@@ -195,11 +196,12 @@ def test_no_per_request_recompiles(trained_inference):
     assert engine.scheduler.preemption_count > 0
     assert engine.spec_drafted_tokens > 0
     # 4 prompts x 4 lengths x many offsets x ragged drafts -> ONE mixed
-    # program at width max(chunk=4, k+1=4)
+    # program: 4 slots x the row width max(chunk=4, k+1=4) tokens, and no
+    # smaller bucket under it (two buckets: test_packed_tick.py)
     assert jitted_programs(engine) == {"_mixed_fns": 1}
-    assert set(engine._mixed_fns) == {4}
+    assert set(engine._mixed_fns) == {16}
     assert engine.prefill_program_count == 1
-    mixed_fn = engine._mixed_fns[4]
+    mixed_fn = engine._mixed_fns[16]
     # a jax upgrade renaming the private probe must FAIL here (replace
     # the probe), not silently pass a recompile-storm regression
     assert hasattr(mixed_fn, "_cache_size")
