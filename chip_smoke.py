@@ -451,15 +451,10 @@ def phase_serve(size, args, device) -> dict:
 
     builds = require_compiled_kernel("paged_attention", args.rehearse)
     width = cfg.mixed_widths[0]  # the token width nearly every tick runs
-    n = cfg.num_slots
-    zeros = lambda *shape, dt=np.int32: np.zeros(shape, dt)
-    operands = engine._dev((
-        zeros(n, cfg.max_blocks_per_seq), zeros(n), zeros(width), zeros(n),
-        zeros(n, dt=np.float32), zeros(n, dt=np.float32), zeros(n), zeros(n),
-        zeros(n),
-    ))
+    empty, _ = engine._layout.host(width)
     text = engine._mixed_fns[width].lower(
-        inf.params, engine._pool_state(), *operands, engine._base_key,
+        inf.params, engine._pool_state(), engine._dev(empty),
+        engine._base_key,
     ).as_text()
     calls = text.count("tpu_custom_call")
     if calls < 1 and not args.rehearse:

@@ -496,7 +496,6 @@ def audit_serve_decode_section(num_slots=8, block_size=16,
     key grows an ``mp`` entry (only when sharded, so the mp=1 section's
     static config never names it)."""
     import jax
-    import jax.numpy as jnp
 
     from scaling_tpu.models.transformer.inference import (
         TransformerInferenceModule,
@@ -524,21 +523,11 @@ def audit_serve_decode_section(num_slots=8, block_size=16,
     small, full = engine.config.mixed_widths
 
     def lowered_at(width):
-        args = (
-            params, engine._pool_state(),
-            *engine._dev((
-                jnp.zeros((num_slots, max_blocks), jnp.int32),  # block tables
-                jnp.zeros((num_slots,), jnp.int32),     # context lengths
-                jnp.zeros((width,), jnp.int32),         # tokens, packed
-                jnp.ones((num_slots,), jnp.int32),      # real per row
-                jnp.zeros((num_slots,), jnp.float32),   # temperatures
-                jnp.zeros((num_slots,), jnp.float32),   # top-ps
-                jnp.zeros((num_slots,), jnp.int32),     # top-ks
-                jnp.zeros((num_slots,), jnp.int32),     # request ids
-                jnp.zeros((num_slots,), jnp.int32),     # key-fold bases
-            )),
-            base_key,
-        )
+        # the tick's ONE host operand (serve/engine.py TickLayout: tables,
+        # lengths, sampler rows, the packed tokens last), a token a row
+        packed, tick = engine._layout.host(width)
+        tick.new_lens[:] = 1
+        args = (params, engine._pool_state(), engine._dev(packed), base_key)
         return engine._build_mixed_fn(width).lower(*args), args
 
     static = {

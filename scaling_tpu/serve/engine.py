@@ -32,7 +32,9 @@ once per token width, every width at the engine's first tick — its
 shapes are the fixed ``(width,)`` tokens and ``(num_slots,
 max_blocks_per_seq)`` tables, and sequence raggedness (prompt lengths,
 prefill offsets, draft lengths) lives in block tables / context lengths
-/ new_lens, never in shapes.
+/ new_lens, never in shapes. All of that host state travels as ONE int32
+operand a tick (``TickLayout``), sliced apart on the device: a tick costs
+one host-to-device transfer.
 All signatures are pinned in the ``serve_decode`` HLO-audit section
 (analysis/goldens/serve_decode.json): a scheduler shape-bucketing or
 kernel change that would trigger a recompile storm on the chip shows up
@@ -51,7 +53,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 from .. import obs
 from ..logging import logger
@@ -90,6 +92,74 @@ def packed_batch_shape(width: int, row_width: int) -> Tuple[int, int]:
     row_width`` is, shape for shape, the row-major batch it replaced."""
     s = next(d for d in range(min(width, row_width), 0, -1) if width % d == 0)
     return width // s, s
+
+
+class TickFields(NamedTuple):
+    """The mixed program's per-tick host state, field by field."""
+
+    tables: object    # (num_slots, max_blocks_per_seq) int32 block tables
+    ctx_lens: object  # (num_slots,) int32 tokens already in the pool
+    new_lens: object  # (num_slots,) int32 real tokens the row brings
+    topks: object     # (num_slots,) int32
+    reqids: object    # (num_slots,) int32
+    gen0: object      # (num_slots,) int32 key-fold base of the row
+    temps: object     # (num_slots,) float32
+    topps: object     # (num_slots,) float32
+    tokens: object    # (width,) int32 the tick's tokens, packed by slot
+
+
+@dataclasses.dataclass(frozen=True)
+class TickLayout:
+    """Where each field of a tick lies in the ONE int32 vector the host
+    hands the mixed program: the tables flattened, the seven per-slot
+    rows in ``TickFields`` order (the two float32 rows as their bits),
+    the tokens LAST, so that every offset but the vector's length is the
+    same at every token width. The one definition: the host writes
+    through ``split`` of a numpy vector (views), the program reads
+    through ``split`` of the traced one (static slices)."""
+
+    num_slots: int
+    max_blocks_per_seq: int
+
+    @property
+    def head(self) -> int:
+        """Elements before the tokens."""
+        rows = len(TickFields._fields) - 2  # but the tables and the tokens
+        return self.num_slots * (self.max_blocks_per_seq + rows)
+
+    def size(self, width: int) -> int:
+        return self.head + width
+
+    def split(self, packed) -> TickFields:
+        """The fields of a packed vector of any width. A numpy vector
+        gives writable views of itself; a traced one static slices, the
+        float32 rows bitcast back (the same bits either way)."""
+        import numpy as np
+
+        n, m = self.num_slots, self.max_blocks_per_seq
+        on_host = isinstance(packed, np.ndarray)
+        fields = {"tables": packed[:n * m].reshape(n, m),
+                  "tokens": packed[self.head:]}
+        for i, name in enumerate(TickFields._fields[1:-1]):
+            row = packed[n * (m + i):n * (m + i + 1)]
+            if name in ("temps", "topps"):
+                if on_host:
+                    row = row.view(np.float32)
+                else:
+                    from jax import lax
+
+                    row = lax.bitcast_convert_type(row, np.float32)
+            fields[name] = row
+        return TickFields(**fields)
+
+    def host(self, width: int) -> Tuple[object, TickFields]:
+        """An all-zero host vector at ``width`` (an empty tick: no row
+        brings a token, every table points at the trash block) and its
+        fields to write through."""
+        import numpy as np
+
+        packed = np.zeros((self.size(width),), np.int32)
+        return packed, self.split(packed)
 
 
 @dataclasses.dataclass
@@ -221,16 +291,19 @@ class ServeEngine:
 
         self._np = np
         self._jax = jax
-        n, m = self.config.num_slots, self.config.max_blocks_per_seq
-        self._tables = np.zeros((n, m), np.int32)
-        self._ctx = np.zeros((n,), np.int32)
-        self._tok = np.zeros((n,), np.int32)
-        # per-slot sampler state (traced per-row arrays in the programs)
+        n = self.config.num_slots
+        # the ONE host operand of a tick and where its fields lie
+        self._layout = TickLayout(n, self.config.max_blocks_per_seq)
+        # per-slot sampler state, set at admission and copied into every
+        # tick's operand (traced per-row arrays in the program)
         self._temp = np.zeros((n,), np.float32)
         self._topk = np.zeros((n,), np.int32)
         self._topp = np.zeros((n,), np.float32)
         self._reqid = np.zeros((n,), np.int32)
-        self._gen = np.zeros((n,), np.int32)
+        # host arrays handed to the device by _dev, and those of them the
+        # counted (non-warm-up) ticks moved: one a tick
+        self.host_puts = 0
+        self.tick_operands = 0
         self._base_key = self._dev(
             jax.random.PRNGKey(self.config.sample_seed)
         )
@@ -429,16 +502,21 @@ class ServeEngine:
 
     # --------------------------------------------------- device programs
     def _dev(self, x):
-        """Host array(s) -> device operand(s). On a serving mesh the
-        host-side addressing state (tables, lengths, tokens, sampler
-        rows) is device_put REPLICATED so every program call mixes
-        cleanly with the mesh-sharded pools and params; off-mesh it is a
-        plain transfer to the engine's device. Accepts a tuple and moves
-        it as ONE batched device_put — the mixed program's nine per-tick
-        operands cost one dispatch, not nine (the host-side tick
-        overhead is what caps fleet thread overlap)."""
+        """ONE host array -> the operand a program call takes for it: one
+        host-to-device transfer. Off-mesh that is the numpy array itself,
+        which the jitted call's own argument path moves (on the chip 0.2-0.3
+        ms a tick less than a ``device_put`` ahead of the call below the
+        knee and no slower at 16 rows, PERF.md section 6, PR 37: one trip
+        through the runtime instead of two); on a serving mesh it is
+        device_put REPLICATED so the call mixes cleanly with the
+        mesh-sharded pools and params. A tick moves its whole host state
+        (tables, lengths, tokens, sampler rows) through here as the one
+        packed vector of ``TickLayout``: a transfer costs the host ~0.26 ms
+        whatever it carries, and the chip has nothing to run until the last
+        has landed."""
+        self.host_puts += 1
         if self._replicated is None:
-            return self._jax.device_put(x)
+            return x
         return self._jax.device_put(x, self._replicated)
 
     def _counter(self, name: str, **labels):
@@ -532,8 +610,11 @@ class ServeEngine:
 
         Addressing is derived on the device from ``new_lens`` alone
         (``packed_token_map``: a token's row by comparison against the
-        rows' running ends, its offset from the row's start), so the
-        program takes the nine operands it always took. Rotary positions
+        rows' running ends, its offset from the row's start). The tick's
+        whole host state arrives as ONE int32 vector (``TickLayout``:
+        tables, lengths, sampler rows, the tokens last) and the program
+        opens with static slices of it, so it takes four arguments:
+        params, the donated pool state, that vector, the key. Rotary positions
         are ``ctx_lens[row] + offset``. The paged branch
         (``Attention._paged_attention``) scatters each token's K/V through
         its row's table (what is no token goes to the trash block; rows
@@ -570,14 +651,16 @@ class ServeEngine:
         shape = packed_batch_shape(width, row_width)
         routed = self.num_experts > 0
 
-        def mixed(params, state, tables, ctx_lens, tokens, new_lens,
-                  temps, topps, topks, reqids, gen0, base_key):
+        def mixed(params, state, packed, base_key):
+            tick = self._layout.split(packed)
+            tables, ctx_lens, new_lens = (
+                tick.tables, tick.ctx_lens, tick.new_lens)
             token_map = packed_token_map(new_lens, shape, row_width)
             row, offset = token_map.row, token_map.offset
             # what is no token keeps position 0 (finite rotary, whatever
             # the last row's context)
             pos = jnp.where(offset < new_lens[row], ctx_lens[row] + offset, 0)
-            batch = self.inf._make_batch(tokens.reshape(shape), pos)
+            batch = self.inf._make_batch(tick.tokens.reshape(shape), pos)
             views = build_layer_views(state, tables, ctx_lens, new_lens,
                                       token_map)
             g0 = jnp.clip(new_lens - sample_width, 0,
@@ -593,7 +676,8 @@ class ServeEngine:
             # per-row key-fold base so every sample still draws with the
             # (request, position) key plain decode would use there
             sampled = self._sample_grid(
-                logits, temps, topps, topks, reqids, gen0 + g0, base_key
+                logits, tick.temps, tick.topps, tick.topks, tick.reqids,
+                tick.gen0 + g0, base_key
             )
             if routed:
                 sampled = jnp.concatenate([sampled.reshape(-1), load[0]])
@@ -617,31 +701,20 @@ class ServeEngine:
         engine's first tick, through the very call path later ticks take.
         A width first needed minutes into serving must not pay its
         lowering then."""
-        np = self._np
-        n = self.config.num_slots
-        rows_i, rows_f = np.zeros((n,), np.int32), np.zeros((n,), np.float32)
-        tables = np.zeros((n, self.config.max_blocks_per_seq), np.int32)
         for width in self.config.mixed_widths:
             fn = self._mixed_fns[width] = self._build_mixed_fn(width)
-            operands = self._dev((
-                tables, rows_i, np.zeros((width,), np.int32), rows_i,
-                rows_f, rows_f, rows_i, rows_i, rows_i,
-            ))
-            _, state = fn(self.inf.params, self._pool_state(), *operands,
-                          self._base_key)
+            empty, _ = self._layout.host(width)
+            _, state = fn(self.inf.params, self._pool_state(),
+                          self._dev(empty), self._base_key)
             self._absorb(state)
 
     # ------------------------------------------------------------- ticking
     def _reset_rows(self, slots: List[int]) -> None:
         for s in slots:
-            self._tables[s] = 0
-            self._ctx[s] = 0
-            self._tok[s] = 0
             self._temp[s] = 0.0
             self._topk[s] = 0
             self._topp[s] = 0.0
             self._reqid[s] = 0
-            self._gen[s] = 0
 
     def _admit_slot(self, seq: Sequence) -> None:
         """Per-slot sampler state for a newly-admitted sequence."""
@@ -690,10 +763,11 @@ class ServeEngine:
             with self._span("serve.mixed.build", step=step):
                 n = cfg.num_slots
                 row_tokens: List[List[int]] = [[]] * n  # by slot
-                new_lens = np.zeros((n,), np.int32)
-                ctx = np.zeros((n,), np.int32)
-                gen0 = np.zeros((n,), np.int32)
-                tables = np.zeros((n, cfg.max_blocks_per_seq), np.int32)
+                # written at the widest token width, handed over at the
+                # tick's own: the tokens lie last, so that is a prefix
+                packed, tick = self._layout.host(cfg.mixed_widths[-1])
+                tables, ctx, new_lens, gen0 = (
+                    tick.tables, tick.ctx_lens, tick.new_lens, tick.gen0)
                 chunk_rows = []  # (seq, start, n_real)
                 for seq in t.prefills:
                     slot = seq.slot
@@ -722,31 +796,31 @@ class ServeEngine:
                     ctx[slot] = seq.num_cached
                     tables[slot, :len(seq.blocks)] = seq.blocks
                     gen0[slot] = len(seq.generated)
-                    self._gen[slot] = len(seq.generated)
                 # inactive rows keep all-trash tables + new_len 0: they
                 # bring no token and expose zero visible slots
-                packed = [tok for row in row_tokens for tok in row]
-                width = next(
-                    w for w in cfg.mixed_widths if len(packed) <= w
-                )
-                tokens = np.zeros((width,), np.int32)
-                tokens[:len(packed)] = packed
+                real = [tok for row in row_tokens for tok in row]
+                width = next(w for w in cfg.mixed_widths if len(real) <= w)
+                tick.tokens[:len(real)] = real
+                tick.temps[:], tick.topps[:] = self._temp, self._topp
+                tick.topks[:], tick.reqids[:] = self._topk, self._reqid
+                packed = packed[:self._layout.size(width)]
             if not self.warmup_mode:  # mixed_span is a span
-                mixed_span.annotate(width=width, tokens=len(packed))
+                mixed_span.annotate(width=width, tokens=len(real))
                 self.mixed_ticks[width] = self.mixed_ticks.get(width, 0) + 1
                 self.mixed_tokens[width] = (
-                    self.mixed_tokens.get(width, 0) + len(packed)
+                    self.mixed_tokens.get(width, 0) + len(real)
                 )
                 self._counter("serve_mixed_ticks_total", width=width).inc()
-            with self._span("serve.mixed.dispatch", step=step):
-                operands = self._dev((
-                    tables, ctx, tokens, new_lens, self._temp, self._topp,
-                    self._topk, self._reqid, gen0,
-                ))
+            puts = self.host_puts
+            with self._span("serve.mixed.dispatch", step=step) as dispatch:
                 sampled, state = self._mixed_fns[width](
-                    self.inf.params, self._pool_state(), *operands,
+                    self.inf.params, self._pool_state(), self._dev(packed),
                     self._base_key,
                 )
+                if dispatch is not None:  # not warming up
+                    moved = self.host_puts - puts
+                    dispatch.annotate(operands=moved, bytes=packed.nbytes)
+                    self.tick_operands += moved
             with self._span("serve.mixed.wait", step=step):
                 # the tick's ONE deliberate device->host pull: the sampled
                 # token grid must land on host to be emitted to callers
@@ -762,8 +836,6 @@ class ServeEngine:
             for seq, start, n_real in chunk_rows:
                 slot = seq.slot
                 seq.num_cached = start + n_real
-                self._tables[slot] = tables[slot]
-                self._ctx[slot] = seq.num_cached
                 if not self.warmup_mode:
                     self.prefilled_tokens += n_real
                     self._counter("serve_prefill_tokens_total").inc(n_real)
@@ -771,10 +843,8 @@ class ServeEngine:
                     # original position n_real - 1, gathered at index
                     # n_real - 1 - g0 with g0 = max(n_real - sw, 0)
                     tok = int(host_samples[slot, min(n_real, sw) - 1])
-                    self._tok[slot] = tok
                     self._emit_token(seq, tok, now)
             for seq in t.decodes:
-                self._tables[seq.slot] = tables[seq.slot]
                 self._accept_speculative(seq, host_samples[seq.slot], now)
 
     def _record_moe_load(self, load, emit_span) -> None:
@@ -827,16 +897,12 @@ class ServeEngine:
                     accepted
                 )
         seq.draft = []
-        slot = seq.slot
-        # KV validity: slot ctx held the last token's write, plus one
+        # KV validity: the context held the last token's write, plus one
         # slot per accepted draft — rejected drafts' slots are simply
-        # overwritten by the next call (ctx never admits them)
+        # overwritten by the next call (the context never admits them)
         seq.num_cached += len(emitted)
-        self._ctx[slot] = seq.num_cached
         for tok in emitted:
-            self._tok[slot] = tok
             self._emit_token(seq, tok, now)
-        self._gen[slot] = len(seq.generated)
 
     def _emit_token(self, seq: Sequence, tok: int, now: float) -> None:
         seq.generated.append(tok)
@@ -1093,6 +1159,12 @@ class ServeEngine:
             # the padding left
             "mixed_ticks": {str(w): c for w, c in self.mixed_ticks.items()},
             "mixed_tokens": {str(w): c for w, c in self.mixed_tokens.items()},
+            # host arrays a counted tick handed the device, running mean:
+            # 1.0, the one packed operand (None before the first)
+            "tick_operands": (
+                self.tick_operands / sum(self.mixed_ticks.values())
+                if self.mixed_ticks else None
+            ),
         }
 
     def run_until_done(self, max_ticks: int = 100_000) -> List[Sequence]:

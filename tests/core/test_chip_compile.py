@@ -201,9 +201,6 @@ def test_mixed_program_updates_its_donated_pools_in_place(one_chip, monkeypatch,
     def on_chip(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
 
-    def rows(*shape, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct((slots, *shape), dtype, sharding=one_chip)
-
     state = engine._pool_state()
     pool = state[0][0]
     assert pool.shape == (slots * max_blocks + 1, BLOCK_SIZE, 8, HEAD_DIM)
@@ -213,14 +210,13 @@ def test_mixed_program_updates_its_donated_pools_in_place(one_chip, monkeypatch,
     ).lower(
         jax.tree_util.tree_map(on_chip, params),
         jax.tree_util.tree_map(on_chip, state),
-        rows(max_blocks), rows(),
-        jax.ShapeDtypeStruct((width,), jnp.int32, sharding=one_chip), rows(),
-        rows(dtype=jnp.float32), rows(dtype=jnp.float32), rows(), rows(),
-        rows(), on_chip(engine._base_key),
+        jax.ShapeDtypeStruct((engine._layout.size(width),), jnp.int32,
+                             sharding=one_chip),  # the tick's one operand
+        on_chip(engine._base_key),
     ).compile().as_text()
     assert text.count("tpu_custom_call") == layers  # the kernel, compiled
 
-    # parameters flatten (params, pool_k[0..], pool_v[0..], operands);
+    # parameters flatten (params, pool_k[0..], pool_v[0..], operand, key);
     # outputs (tokens, pool_k[0..], pool_v[0..])
     first = len(jax.tree_util.tree_leaves(params))
     aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)

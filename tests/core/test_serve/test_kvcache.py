@@ -182,15 +182,10 @@ def _program_and_args(inf, kv_dtype, spec_k, width=WIDTHS[0]):
     ))
     assert engine.config.mixed_widths == WIDTHS
 
-    def z(*shape, dt=np.int32):
-        return np.zeros(shape, dt)
-
-    operands = engine._dev((
-        z(SLOTS, MAX_BLOCKS), z(SLOTS), z(width),
-        np.ones(SLOTS, np.int32), z(SLOTS, dt=np.float32),
-        z(SLOTS, dt=np.float32), z(SLOTS), z(SLOTS), z(SLOTS),
-    ))
-    args = (inf.params, engine._pool_state(), *operands, engine._base_key)
+    packed, tick = engine._layout.host(width)
+    tick.new_lens[:] = 1
+    args = (inf.params, engine._pool_state(), engine._dev(packed),
+            engine._base_key)
     return engine, engine._build_mixed_fn(width).__wrapped__, args
 
 
@@ -277,8 +272,10 @@ def test_run_layers_on_paged_views_defaults_to_the_kernel(toy_inference):
     gather (which stays reachable by name, as the tests' reference)."""
     from scaling_tpu.serve.kvcache import build_layer_views
 
-    _, _, args = _program_and_args(toy_inference, "native", 0)
-    params, state, tables, ctx_lens, _, new_lens = args[:6]
+    engine, _, args = _program_and_args(toy_inference, "native", 0)
+    params, state, packed = args[:3]
+    tick = engine._layout.split(packed)
+    tables, ctx_lens, new_lens = tick.tables, tick.ctx_lens, tick.new_lens
     tokens = jnp.zeros((SLOTS, CHUNK), jnp.int32)  # a row-major batch
     pos = ctx_lens[:, None] + jnp.arange(CHUNK)[None, :]
     batch = toy_inference._make_batch(tokens, pos)

@@ -129,8 +129,10 @@ def row_major_program(engine):
     inf, cfg = engine.inf, engine.config
     width, sw = cfg.mixed_width, cfg.sample_width
 
-    def mixed(params, state, tables, ctx_lens, tokens, new_lens, temps,
-              topps, topks, reqids, gen0, base_key):
+    def mixed(params, state, packed, base_key):
+        (tables, ctx_lens, new_lens, topks, reqids, gen0, temps, topps,
+         tokens) = engine._layout.split(packed)
+        tokens = tokens.reshape(cfg.num_slots, width)
         pos = ctx_lens[:, None] + jnp.arange(width)[None, :]
         views = build_layer_views(state, tables, ctx_lens, new_lens)
         g0 = jnp.clip(new_lens - sw, 0, width - sw)
@@ -185,12 +187,21 @@ def random_tick(engine, rows, seed):
     return state, shared, tokens, packed
 
 
+def pack(engine, shared, tokens):
+    """The tick as the engine hands it over: ONE int32 vector, written
+    through the layout's own fields."""
+    packed, tick = engine._layout.host(len(tokens))
+    tick.tables[:], tick.ctx_lens[:] = shared["tables"], shared["ctx"]
+    tick.new_lens[:], tick.gen0[:] = shared["new_lens"], shared["gen0"]
+    tick.temps[:], tick.topps[:] = shared["temps"], shared["topps"]
+    tick.topks[:], tick.reqids[:] = shared["topks"], shared["reqids"]
+    tick.tokens[:] = tokens
+    return engine._dev(packed)
+
+
 def call(engine, fn, state, shared, tokens):
-    operands = engine._dev((
-        shared["tables"], shared["ctx"], tokens, shared["new_lens"],
-        shared["temps"], shared["topps"], shared["topks"], shared["reqids"],
-        shared["gen0"]))
-    return fn(engine.inf.params, state, *operands, engine._base_key)
+    return fn(engine.inf.params, state, pack(engine, shared, tokens),
+              engine._base_key)
 
 
 @pytest.mark.parametrize("tick", list(TICKS))
@@ -209,7 +220,7 @@ def test_packed_tick_is_the_row_major_tick(models, model, spec_k, kv_dtype,
     padded[:len(packed)] = packed
 
     want, want_state, want_load = call(
-        engine, row_major_program(engine), state, shared, tokens)
+        engine, row_major_program(engine), state, shared, tokens.ravel())
     got, got_state = call(
         engine, engine._build_mixed_fn(width), state, shared, padded)
 
@@ -358,11 +369,8 @@ def test_the_program_holds_one_layer_function_however_deep_the_stack():
         state, shared, _, packed = random_tick(engine, TICKS["common"], seed=1)
         padded = np.zeros((SMALL,), np.int32)
         padded[:len(packed)] = packed
-        operands = engine._dev((
-            shared["tables"], shared["ctx"], padded, shared["new_lens"],
-            shared["temps"], shared["topps"], shared["topks"],
-            shared["reqids"], shared["gen0"]))
         text = engine._build_mixed_fn(SMALL).lower(
-            engine.inf.params, state, *operands, engine._base_key).as_text()
+            engine.inf.params, state, pack(engine, shared, padded),
+            engine._base_key).as_text()
         dots.append(text.count("stablehlo.dot_general"))
     assert dots[0] == dots[1] > 0
