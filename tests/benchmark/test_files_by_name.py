@@ -64,8 +64,8 @@ def test_bursts_keep_inside_their_clips(seed):
         assert 1 <= r.output_len <= BURST["output"]["max"]
         assert len(r.prompt) + r.output_len <= BURST["max_total"]
         assert all(1 <= t < 32768 for t in r.prompt)
-    # lengths are chat-0.8knee's
-    chat = cells.load_json(cells.ROOT / "traffic" / "chat-0.8knee.json")
+    # lengths are chat-steady's
+    chat = cells.load_json(cells.ROOT / "traffic" / "chat-steady.json")
     assert all(BURST[k] == chat[k] for k in ("prompt", "output", "max_total"))
     # no burst before the window when there is no warm-up
     cold = bursts({**BURST, "warm_seconds": 0.0}, seed, 51, 32768)

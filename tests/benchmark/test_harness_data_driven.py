@@ -7,6 +7,7 @@ on the CPU at toy size."""
 import json
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 from benchmark import cells, traffic_gen
@@ -93,7 +94,7 @@ def test_unknown_cell_and_unknown_device_kind_fail():
         "flops_per_s": 197e12, "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9}
 
 
-CHAT = cells.load_json(cells.ROOT / "traffic" / "chat-0.8knee.json")
+CHAT = cells.load_json(cells.ROOT / "traffic" / "chat-steady.json")
 
 
 def test_traffic_is_a_pure_function_of_the_seed():
@@ -148,17 +149,28 @@ def test_history_ages_a_request_by_the_nominal_engine(age_s, prompt, output, lef
 
 def test_window_opens_on_the_requests_the_history_left_running():
     """Those out of the history are all due as the warm-up starts, are as
-    many as rate x a request's mean life (Little's law), and each is in
-    mid-life: less of its answer left than a fresh request has."""
+    many as rate x a request's mean life (Little's law) within what a Poisson
+    count of that mean varies by, and each is in mid-life: what the nominal
+    engine left of a request the history drew, less of its answer to come
+    than it asked for and as much more prompt. (Not less than a FRESH request
+    asks for: the requests a moment finds running are the long ones.)"""
     reqs = traffic_gen.generate(CHAT, 7, 51, 32768)
     warm_start = -CHAT["warm_seconds"]
     old = [r for r in reqs if r.due_s == warm_start][:-1]  # the last is the warm-up's first
     h = CHAT["history"]
     mean_life_s = h["tick_s"] * (560 / h["prefill_tokens_per_tick"] + 200)
-    assert 0.6 < len(old) / (CHAT["rate"] * mean_life_s) < 1.6
-    fresh = [r for r in reqs if r.due_s > warm_start]
-    mean = lambda xs: sum(xs) / len(xs)
-    assert mean([r.output_len for r in old]) < mean([r.output_len for r in fresh])
+    running_mean = CHAT["rate"] * mean_life_s
+    assert abs(len(old) - running_mean) < 2 * running_mean ** 0.5
+    drawn = zip(*traffic_gen._shapes(
+        np.random.default_rng([CHAT["shape_seed"], 0]), CHAT, float(h["seconds"])))
+    aged = [(int(p), int(o), traffic_gen._aged(h, h["seconds"] - off, int(p), int(o)))
+            for off, p, o in drawn]
+    running = [(p, o, *left) for p, o, left in aged if left is not None]
+    assert [(p_now, o_left) for _, _, p_now, o_left in running] == [
+        (len(r.prompt), r.output_len) for r in old]
+    assert all(0 <= o - o_left == p_now - p for p, o, p_now, o_left in running)
+    # most have generated something; one may still be streaming its prompt in
+    assert sum(o_left < o for _, o, _, o_left in running) >= len(running) - 2
 
 
 def test_every_metric_of_benchmark_json_has_its_file_and_reader():

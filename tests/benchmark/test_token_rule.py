@@ -2,11 +2,12 @@
 (``"tokens"``, PR 30), and below the knee the count may not punish speed.
 
 The cells' own schedules are replayed through a nominal engine written here
-(nothing of ``scaling_tpu``), at tick times from the sweep's 135 ms down to
-5 ms, and the stamped sequences go to ``serve_kind.window_numbers`` as a run's
-do. Counting every stamp, ``serve-mistral7b-chat`` read 85.5 tokens/s at a
-45 ms tick and 79.7 at 27.5 ms (ledger, PR 29: refused for it); the replay
-gives 85.0 and 79.3. Everything on the CPU, no JAX.
+(nothing of ``scaling_tpu``), at tick times from 27.5 ms (PR 31's) down to
+2.5 ms, and the stamped sequences go to ``serve_kind.window_numbers`` as a
+run's do. Counting every stamp, the retired ``serve-mistral7b-chat`` read 85.5
+tokens/s at a 45 ms tick and 79.7 at 27.5 ms (ledger, PR 29: refused for it);
+the schedule that replaced it (``chat-steady``, PR 39) shows the same fault,
+weaker, from a 14 ms tick down. Everything on the CPU, no JAX.
 """
 
 import functools
@@ -24,13 +25,21 @@ SERVE_CELLS = [w for w in BENCH["workloads"]
                if cells.load_json(cells.ROOT / "traffic" / f"{w['traffic']}.json")["kind"] == "serve"]
 WINDOW_S = 51.0
 T0 = 1000.0  # the host clock's reading as the window opens
-# the sweep's nominal tick (the traffic file's history), the ticks of the
-# ledger's PR 26 parent, PR 29 parent and PR 29 change, and beyond
-TICKS_MS = (135, 104, 60, 45, 41, 27.5, 20, 10, 5)
+# PR 31's tick, PR 33's neighbourhood, the traffic file's nominal tick (its
+# history: 15.6 ms), and beyond
+TICKS_MS = (27.5, 22, 20, 17, 15.6, 14, 12, 10, 7.5, 5, 2.5)
 
 
 def load_traffic(name):
     return cells.load_json(cells.ROOT / "traffic" / f"{name}.json")
+
+
+def generator_of(traffic):
+    """The traffic file's generator, found as ``cells.Cell.generate`` finds it."""
+    if "generator" not in traffic:
+        return traffic_gen.generate
+    return cells.load_module(cells.ROOT, "generators", traffic["generator"],
+                             cells.GENERATOR_CONTRACT).generate
 
 
 @functools.lru_cache(maxsize=None)
@@ -43,9 +52,7 @@ def replay(traffic_name: str, tick_ms: float, slots: int = 16, chunk: int = 32):
     too), one output token, stamped as the tick ends. The engine ticks
     while it has work and the run ends as ``serve_kind.run`` ends it."""
     traffic = load_traffic(traffic_name)
-    generate = traffic_gen.generate if "generator" not in traffic else cells.load_module(
-        cells.ROOT, "generators", traffic["generator"], cells.GENERATOR_CONTRACT).generate
-    requests = generate(traffic, 7, WINDOW_S, 32768)
+    requests = generator_of(traffic)(traffic, 7, WINDOW_S, 32768)
     cut = traffic.get("backlog", "fail") == "cut"
     tick_s = tick_ms / 1e3
     seqs = [types.SimpleNamespace(
@@ -108,28 +115,31 @@ def every_stamp(traffic_name, tick_ms):
 @pytest.mark.parametrize("slower, faster", list(zip(TICKS_MS, TICKS_MS[1:])),
                          ids=lambda ms: f"{ms}ms")
 def test_the_chat_cells_count_never_falls_as_the_tick_shortens(slower, faster):
-    slow = tokens_in_window("chat-0.8knee", slower)
-    fast = tokens_in_window("chat-0.8knee", faster)
+    slow = tokens_in_window("chat-steady", slower)
+    fast = tokens_in_window("chat-steady", faster)
     assert fast >= slow
-    # and it is bounded by what the window's 24 requests ask for
-    offered = sum(r.output_len for r, _ in replay("chat-0.8knee", faster) if r.counted)
-    assert 0 < slow <= fast <= offered == 4541
+    # and it is bounded by what the window's 138 requests ask for
+    offered = sum(r.output_len for r, _ in replay("chat-steady", faster) if r.counted)
+    assert 0 < slow <= fast <= offered == 26825
 
 
-def test_counting_every_stamp_punished_speed_in_the_chat_cell():
-    """Today's fault, pinned: the 16 requests submitted before the window
-    hold 1,864 output tokens, and the faster the tick the more of them are
-    stamped before it opens. The replay reads what the ledger holds."""
-    before = [r for r, _ in replay("chat-0.8knee", 45) if not r.counted]
-    assert (len(before), sum(r.output_len for r in before)) == (16, 1864)
-    at_45, at_27 = every_stamp("chat-0.8knee", 45), every_stamp("chat-0.8knee", 27.5)
-    assert at_45 == tokens_in_window("chat-0.8knee", 45, "all")
-    assert at_45 / WINDOW_S == pytest.approx(85.53, abs=0.6)   # ledger, PR 29, parent
-    assert at_27 / WINDOW_S == pytest.approx(79.73, abs=0.6)   # ledger, PR 29, change
-    assert at_27 < 0.98 * at_45      # a refusal, at the metric's bound of 2%
+def test_counting_every_stamp_punishes_speed_in_the_chat_cell():
+    """The fault of PR 29-30 on today's schedule, pinned: the 22 requests
+    submitted before the window hold 4,239 output tokens, and the faster the
+    tick the more of them are stamped before it opens, so the count of every
+    stamp falls from a 14 ms tick to a 2.5 ms one while the cell's own rises."""
+    before = [r for r, _ in replay("chat-steady", 14) if not r.counted]
+    assert (len(before), sum(r.output_len for r in before)) == (22, 4239)
+    at_14, at_2 = every_stamp("chat-steady", 14), every_stamp("chat-steady", 2.5)
+    assert at_14 == tokens_in_window("chat-steady", 14, "all")
+    assert (at_14, at_2) == (26985, 26781)  # a faster engine reads 0.8% LESS
+    # what falls is the share of the requests submitted before the window
+    assert at_14 - tokens_in_window("chat-steady", 14) == 1154
+    assert at_2 - tokens_in_window("chat-steady", 2.5) == 0
     # the same two replays by the cell's rule
-    assert tokens_in_window("chat-0.8knee", 27.5) > tokens_in_window("chat-0.8knee", 45)
-    assert tokens_in_window("chat-0.8knee", 45) / WINDOW_S == pytest.approx(71.65, abs=0.01)
+    assert tokens_in_window("chat-steady", 2.5) > tokens_in_window("chat-steady", 14)
+    # at the file's own nominal tick; PERF.md has the chip's reading beside it
+    assert tokens_in_window("chat-steady", 15.6) / WINDOW_S == pytest.approx(502.41, abs=0.01)
 
 
 def test_only_the_counted_requests_stamps_are_counted():

@@ -11,7 +11,7 @@ from benchmark import cells, serve_kind, traffic_gen
 
 DATA = Path(__file__).parent / "data"
 TOY = DATA / "toy"
-CHAT = cells.load_json(cells.ROOT / "traffic" / "chat-0.8knee.json")
+CHAT = cells.load_json(cells.ROOT / "traffic" / "chat-steady.json")
 NEW_METRICS = ("tick_host_ms_p50", "sched_ms_p50", "tick_dispatch_ms_p50",
                "prefill_token_pct", "idle_in_spans_pct")
 
@@ -93,7 +93,7 @@ def test_traced_arrivals_change_nothing_before_them(seed):
     assert traced and all(r.traced and not r.counted for r in traced)
     assert traced[0].due_s == 0.0 and all(0 <= r.due_s < 5.0 for r in traced)
     assert [r.due_s for r in traced] == sorted(r.due_s for r in traced)
-    assert len(plain) == 16 + 24
+    assert len(plain) == 22 + 138
     for r in traced:
         assert CHAT["prompt"]["min"] <= len(r.prompt) <= CHAT["prompt"]["max"]
         assert len(r.prompt) + r.output_len <= CHAT["max_total"]
@@ -133,7 +133,7 @@ def test_every_new_metric_reads_nothing_without_a_capture(no_capture):
     assert bench["trace_in_run"] is True
     by_name = {m["name"]: m for m in bench["per_layer"]}
     for name in NEW_METRICS:
-        assert by_name[name]["workloads"] == ["serve-mistral7b-chat"]
+        assert by_name[name]["workloads"] == ["serve-mistral7b-chat-steady"]
         spec = cells.load_json(cells.ROOT / "metrics" / f"{name}.json")
         assert spec["reader"] == f"program_spans:{name}"
         assert cells.load_reader(name)({}) is None
