@@ -232,6 +232,33 @@ class TransformerArchitectureConfig(BaseConfig):
         "split into heads, one learned weight over its full width",
     )
     weight_tying: bool = Field(False, description="tie lm head to the embedding")
+    loop_steps: int = Field(
+        1,
+        description="times the trunk of num_layers layers is run over the "
+        "SAME weights (a looped / universal transformer): step u starts from "
+        "step u-1's output after the final norm, which so runs at the end of "
+        "EVERY step; the K and V of (step u, layer l) are a cache line of "
+        "their own, u * num_layers + l. 1 is the plain decoder",
+        ge=1,
+    )
+    sandwich_norm: bool = Field(
+        False,
+        description="norm each sub-layer's OUTPUT before it is added to the "
+        "residual stream (h = h + norm(attn), h = h + norm(mlp)), beside the "
+        "pre-norms of its input: four norms a layer",
+    )
+    loop_exit_gate: bool = Field(
+        False,
+        description="a learned gate Linear(hidden, 1) + bias on each step's "
+        "normed output: lambda_u = sigmoid(w . h_u + b), from which the exit "
+        "distribution p_u over the steps is read (needs loop_steps > 1)",
+    )
+    loop_exit_threshold: float = Field(
+        1.0,
+        description="cumulative exit probability at which a token's last "
+        "step is taken; 1 runs every step for every token, the only value "
+        "served (under 1 the work would depend on the data)",
+    )
     masked_softmax_fusion: bool = Field(True, description="kept for config parity")
     layernorm_epsilon: float = Field(1.0e-5, description="kept for config parity")
 
@@ -323,6 +350,25 @@ class TransformerArchitectureConfig(BaseConfig):
                     "mlp_type 'moe' does not support mlp_bias; set it false "
                     "(experts are GLU FFNs without bias)"
                 )
+        if self.loop_exit_gate and self.loop_steps < 2:
+            raise ValueError(
+                "loop_exit_gate reads the exit distribution over the steps of "
+                "a looped trunk; set loop_steps > 1 or drop the gate"
+            )
+        if self.loop_exit_threshold != 1.0:
+            raise ValueError(
+                f"loop_exit_threshold {self.loop_exit_threshold}: only 1 is "
+                "served (every token runs every step and the logits are the "
+                "last step's); a threshold under 1 makes the steps a token "
+                "runs depend on the data, which scheduler, cache and program "
+                "do not do yet"
+            )
+        if self.loop_steps > 1 and self.mlp_type == MLPType.MOE:
+            raise ValueError(
+                "loop_steps > 1 with mlp_type 'moe': the routed MLP's load "
+                "and auxiliary loss are summed over a stack walked once; a "
+                "looped routed trunk is not supported"
+            )
         if self.mup is not None and self.weight_tying:
             raise ValueError(
                 "mup does not compose with weight_tying: the tied table "

@@ -30,7 +30,8 @@ import yaml
 
 from .config import TransformerConfig
 from .layers.layer import TransformerLayer
-from .model import get_transformer_layer_specs
+from .layers.lm_head import LayerNormWrapper, LoopExitGate, exit_distribution
+from .model import init_model
 from .tokenizer import Tokenizer
 from ...checkpoint import load_model_checkpoint
 from ...nn.attention import PagedKVCacheView
@@ -280,10 +281,7 @@ class TransformerInferenceModule:
             tdict.setdefault("micro_batch_size", 1)
             tdict.setdefault("gradient_accumulation_steps", 1)
             topo = Topology(TopologyConfig.from_dict(tdict))
-        specs = get_transformer_layer_specs(config.transformer_architecture, topo)
-        module = ParallelModule(
-            specs, topology=topo, compute_dtype=config.transformer_architecture.dtype
-        )
+        module = init_model(config, topo)
         if topo is None:
             params = module.init_params(jax.random.PRNGKey(0))
             params = module.ckpt_unview(
@@ -320,8 +318,28 @@ class TransformerInferenceModule:
         ctx.serving = True
         return ctx
 
+    def _paged_layer_calls(self, ctx):
+        """``call(layer)``: the layer as a function of (params, activations,
+        paged cache), jitted once an architecture. Layers built from one
+        architecture are one function: jitted on its own, the serving
+        engine's program traces and lowers it once, not once a layer (the
+        engine lowers a program a token width at its first tick, and that
+        is set-up time), and calls it with each layer's parameters. XLA
+        inlines the calls."""
+        shared = {}
+
+        def call(layer):
+            key = (type(layer), id(layer.architecture))
+            if key not in shared:
+                shared[key] = jax.jit(
+                    lambda p, x, cache: layer(p, x, ctx, kv_cache=cache)
+                )
+            return shared[key]
+
+        return call
+
     def _run_layers(self, params, batch, caches, offset, paged_kernel=None,
-                    gather_index=None, moe_load=False):
+                    gather_index=None, moe_load=False, exit_p=False):
         """One pass through the stack; TransformerLayers consume/produce the
         KV caches, edge layers run as in training (deterministic).
 
@@ -346,6 +364,12 @@ class TransformerInferenceModule:
         received from the rows' REAL positions, summed over the layers
         (nn/moe.py ``serve``).
 
+        ``exit_p`` (static; a looped model with an exit gate) adds a result:
+        the float32 exit distribution over the steps, ``(loop_steps, ...)``
+        of the positions the head reads (``_run_looped``). A looped model
+        takes one cache per (step, layer), or one paged view a LAYER whose
+        pools hold every step's blocks.
+
         A pipelined (pp>1) stack wraps its TransformerLayers in a
         ``PipelinedBody``, which cannot consume KV caches: the cached path
         raises instead of silently decoding with no history (the caches
@@ -357,6 +381,18 @@ class TransformerInferenceModule:
         ctx = self._make_ctx()
         if paged_kernel is not None:
             ctx.paged_kernel = paged_kernel
+        if self.architecture.loop_steps > 1:
+            pick = None
+            if gather_index is not None:
+                def pick(h):
+                    return h.reshape(-1, h.shape[-1])[gather_index]
+            logits, new_caches, p = self._run_looped(
+                params, batch, ctx, caches=caches, offset=offset, pick=pick,
+                exit_p=exit_p)
+            return (logits, new_caches, p) if exit_p else (logits, new_caches)
+        if exit_p:
+            raise ValueError("exit_p reads a looped model's exit gate; this "
+                             "model has loop_steps 1")
         last_tl = None
         if gather_index is not None:
             tls = [
@@ -369,21 +405,7 @@ class TransformerInferenceModule:
                     "gather after (pipelined/edge-only stacks have none)"
                 )
             last_tl = max(tls)
-        shared = {}
-
-        def paged_layer_call(layer):
-            """Layers built from one architecture are one function of
-            (params, activations, cache): jitted on its own, the serving
-            engine's program traces and lowers it once, not once a layer
-            (the engine lowers a program a token width at its first tick,
-            and that is set-up time), and calls it with each layer's
-            parameters. XLA inlines the calls."""
-            key = (type(layer), id(layer.architecture))
-            if key not in shared:
-                shared[key] = jax.jit(
-                    lambda p, x, cache: layer(p, x, ctx, kv_cache=cache)
-                )
-            return shared[key]
+        paged_layer_call = self._paged_layer_calls(ctx)
 
         x = batch
         new_caches = []
@@ -425,6 +447,152 @@ class TransformerInferenceModule:
         if moe_load:
             return x["activations"], new_caches, x["moe_load"]
         return x["activations"], new_caches
+
+    def _loop_plan(self):
+        """Where the parts of a looped stack lie in ``module.layers``: the
+        indices before the trunk, the trunk's, the final norm's, the exit
+        gate's (None without one), and those after."""
+        layers = self.module.layers
+        trunk = [i for i, l in enumerate(layers)
+                 if isinstance(l, TransformerLayer)]
+        norm = trunk[-1] + 1 if trunk else None
+        if (not trunk or trunk != list(range(trunk[0], norm))
+                or not isinstance(layers[norm], LayerNormWrapper)):
+            raise ValueError(
+                "a looped model's stack is embedding, a contiguous trunk of "
+                "TransformerLayers, the final norm, [the exit gate,] the "
+                f"head; got {[type(l).__name__ for l in layers]}"
+            )
+        gate = norm + 1 if isinstance(layers[norm + 1], LoopExitGate) else None
+        after = (norm if gate is None else gate) + 1
+        return (list(range(trunk[0])), trunk, norm, gate,
+                list(range(after, len(layers))))
+
+    def _run_looped(self, params, batch, ctx, caches=None, offset=None,
+                    return_kv=False, pick=None, exit_p=False):
+        """The walk of a looped model (``loop_steps > 1``): the trunk's
+        layers ``loop_steps`` times over the SAME parameters, each step
+        ending in the final norm (its output is what the next step starts
+        from) and, with ``exit_p``, in the exit gate. ``pick`` maps the last
+        normed ``(b, s, hidden)`` to the positions the gate and the head
+        read (default: all). Returns ``(logits, caches or K/V, p)``; ``p``
+        is the float32 exit distribution ``(loop_steps, ...)`` or None.
+
+        The K and V of (step ``u``, layer ``l``) are cache line ``u *
+        num_layers + l``:
+
+        - no cache, or ``return_kv`` (the prompt pass): the steps are ONE
+          rolled ``lax.scan`` whose body is the trunk, so the program holds
+          ``num_layers`` layer applications whatever ``loop_steps``; the
+          K/V come back as a list in line order.
+        - block-paged caches (the serving engine): ``caches`` is one
+          :class:`PagedKVCacheView` a LAYER, whose pools hold ``loop_steps x
+          num_blocks`` blocks; step ``u`` addresses its own through the
+          block table plus ``u * num_blocks`` (``PagedKVCacheView.at_step``;
+          block 0 of every step's share is trash, as block 0 of a plain pool
+          is), so kernel, scatter and view are the plain model's. The pools ride the scan's carry and
+          are scattered into in place.
+        - dense caches (``generate``): one ``(k, v)`` a line, the steps
+          unrolled.
+        """
+        layers = self.module.layers
+        steps = self.architecture.loop_steps
+        before, trunk, norm_i, gate_i, after = self._loop_plan()
+        num_layers = len(trunk)
+        paged = caches is not None and isinstance(caches[0], PagedKVCacheView)
+        if caches is not None:
+            want = num_layers if paged else steps * num_layers
+            if len(caches) != want:
+                raise ValueError(
+                    f"a looped stack of {num_layers} layers x {steps} steps "
+                    f"takes {want} {'paged views (one a layer)' if paged else 'KV caches (one a line)'}"
+                    f", got {len(caches)}: a cache silently skipped here "
+                    "means silently wrong decode output"
+                )
+        if exit_p and gate_i is None:
+            raise ValueError("exit_p reads the exit gate; set loop_exit_gate")
+
+        def lp(i):
+            return self.module._layer_params(params, i)
+
+        x = batch
+        for i in before:
+            x = layers[i](lp(i), x, ctx)
+        rest = {k: v for k, v in x.items() if k != "activations"}
+        pick = pick or (lambda h: h)
+        paged_layer_call = self._paged_layer_calls(ctx)
+
+        def step(h, step_caches):
+            """One pass of the trunk and the final norm over ``h``."""
+            x = {**rest, "activations": h}
+            out = []
+            for l, i in enumerate(trunk):
+                if step_caches is None and not return_kv:
+                    x = layers[i](lp(i), x, ctx)
+                    continue
+                if step_caches is None:
+                    x, kv = layers[i](lp(i), x, ctx, return_kv=True)
+                elif paged:
+                    x, kv = paged_layer_call(layers[i])(lp(i), x, step_caches[l])
+                else:
+                    x, kv = layers[i](lp(i), x, ctx, kv_cache=step_caches[l],
+                                      cache_offset=offset)
+                out.append(kv)
+            h = layers[norm_i](lp(norm_i), x, ctx)["activations"]
+            lam = None
+            if exit_p:
+                lam = layers[gate_i].exit_probability(lp(gate_i), pick(h))
+            return h, out, lam
+
+        def pools_of(view):
+            return (view.pool_k, view.pool_v, view.scale_k, view.scale_v)
+
+        h = x["activations"]
+        with jax.named_scope("loop"):
+            if caches is not None and not paged:
+                new_caches, lams = [], []
+                for u in range(steps):
+                    h, out, lam = step(
+                        h, caches[u * num_layers:(u + 1) * num_layers])
+                    new_caches += out
+                    lams.append(lam)
+                lams = jnp.stack(lams) if exit_p else None
+            else:
+                num_blocks = caches[0].pool_k.shape[0] // steps if paged else 0
+
+                def body(carry, u):
+                    h, pools = carry
+                    step_caches = None
+                    if paged:
+                        step_caches = [
+                            view._replace(
+                                pool_k=pk, pool_v=pv, scale_k=sk, scale_v=sv,
+                            ).at_step(u, num_blocks)
+                            for view, (pk, pv, sk, sv) in zip(caches, pools)
+                        ]
+                    h, out, lam = step(h, step_caches)
+                    if paged:
+                        pools, out = [pools_of(view) for view in out], None
+                    return (h, pools), (out, lam)
+
+                pools = [pools_of(view) for view in caches] if paged else None
+                (h, pools), (kvs, lams) = jax.lax.scan(
+                    body, (h, pools), jnp.arange(steps, dtype=jnp.int32))
+                if paged:
+                    new_caches = [
+                        view._replace(pool_k=pk, pool_v=pv, scale_k=sk, scale_v=sv)
+                        for view, (pk, pv, sk, sv) in zip(caches, pools)
+                    ]
+                else:  # stacked over the steps -> line order
+                    new_caches = [(k[u], v[u]) for u in range(steps)
+                                  for k, v in kvs]
+        x = {**rest, "activations": pick(h)}
+        if layers[norm_i].record_embeddings:
+            x["embeddings"] = x["activations"]
+        for i in after:
+            x = layers[i](lp(i), x, ctx)
+        p = exit_distribution(lams) if exit_p else None
+        return x["activations"], new_caches, p
 
     def _make_batch(
         self,
@@ -483,6 +651,20 @@ class TransformerInferenceModule:
             self.params, token_ids, pos, manipulation, bool(control_log_additive)
         )
 
+    def exit_probabilities(self, token_ids) -> jax.Array:
+        """A looped model's exit distribution over its steps at every
+        position, float32 ``(loop_steps, b, s)``, summing to 1 over the
+        steps (needs ``loop_exit_gate``)."""
+        token_ids = jnp.asarray(token_ids)
+        if token_ids.ndim == 1:
+            token_ids = token_ids[None]
+        b, s = token_ids.shape
+        pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+        return jax.jit(
+            lambda p, t, po: self._run_layers(
+                p, self._make_batch(t, po), None, None, exit_p=True)[2]
+        )(self.params, token_ids, pos)
+
     def hidden_states(
         self,
         token_ids,
@@ -498,18 +680,32 @@ class TransformerInferenceModule:
         b, s = token_ids.shape
         pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
 
+        # (step, layer index) in the order the stack is walked; a looped
+        # model walks its trunk and final norm once a step, and their states
+        # are keyed ``step_{u}_layer_{i}_{Class}``
+        order = [(None, i) for i in range(len(self.module.layers))]
+        if self.architecture.loop_steps > 1:
+            before, trunk, norm_i, _, _ = self._loop_plan()
+            order = (
+                [(None, i) for i in before]
+                + [(u, i) for u in range(self.architecture.loop_steps)
+                   for i in trunk + [norm_i]]
+                + [(None, i) for i in range(norm_i + 1, len(self.module.layers))]
+            )
+
         def run(params, t, po):
             ctx = self._make_ctx()
             x = self._make_batch(t, po)
             recorded = {}
-            for i, layer in enumerate(self.module.layers):
-                p = self.module._layer_params(params, i)
-                x = layer(p, x, ctx)
+            for u, i in order:
+                layer = self.module.layers[i]
+                x = layer(self.module._layer_params(params, i), x, ctx)
                 if include is not None and i not in include:
                     continue
                 if exclude is not None and i in exclude:
                     continue
-                recorded[f"layer_{i}_{type(layer).__name__}"] = x["activations"]
+                step = "" if u is None else f"step_{u}_"
+                recorded[f"{step}layer_{i}_{type(layer).__name__}"] = x["activations"]
             return recorded
 
         return jax.jit(run)(self.params, token_ids, pos)
@@ -544,6 +740,17 @@ class TransformerInferenceModule:
         from ...parallel.pipeline import PipelinedBody
 
         ctx = self._make_ctx()
+        if self.architecture.loop_steps > 1:
+            # a looped model: the K/V of every (step, layer), in line order
+            def pick(h):
+                if last_index is None:
+                    return h[:, -1:]
+                return jax.lax.dynamic_slice_in_dim(h, last_index, 1, axis=1)
+
+            batch = self._make_batch(token_ids, position_ids,
+                                     segment_ids=segment_ids)
+            return self._run_looped(params, batch, ctx, return_kv=True,
+                                    pick=pick)[:2]
         transformer_idxs = [
             i for i, l in enumerate(self.module.layers)
             if isinstance(l, TransformerLayer)

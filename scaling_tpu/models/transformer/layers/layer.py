@@ -2,7 +2,9 @@
 
 (reference: src/scaling/transformer/model/layers/layer.py:44-291) —
 pre-norm attention with residual, pre-norm MLP with residual, dropout after
-each block, optional bottleneck adapters after each block. Dropout keys come
+each block, optional bottleneck adapters after each block; with
+``sandwich_norm`` each sub-layer's output is normed once more before the
+residual takes it. Dropout keys come
 from the ForwardContext, which derives them deterministically per call —
 that is the whole of the reference's CudaRNGStateTracker on TPU: the same
 key is computed on every model-parallel shard, so masks agree by
@@ -132,6 +134,17 @@ class TransformerLayer(BaseLayer):
         self.post_attention_layernorm = get_norm(
             arch.norm_type, arch.hidden_size, arch.layernorm, dtype, bitfit
         )
+        # sandwich norms: each sub-layer's OUTPUT is normed before it is
+        # added to the residual stream (two further norms a layer)
+        self.output_norms = {}
+        if arch.sandwich_norm:
+            self.output_norms = {
+                name: get_norm(
+                    arch.norm_type, arch.hidden_size, arch.layernorm, dtype, bitfit
+                )
+                for name in ("post_attention_output_layernorm",
+                             "post_mlp_output_layernorm")
+            }
         self.is_moe = arch.mlp_type == MLPType.MOE
         if self.is_moe:
             from ....nn.moe import ParallelMoEMLP
@@ -190,6 +203,19 @@ class TransformerLayer(BaseLayer):
             "post_attention_layernorm": self.post_attention_layernorm.init(keys[2]),
             "mlp": self.mlp.init(keys[3]),
         }
+        for i, (name, norm) in enumerate(self.output_norms.items()):
+            # keys of their own: the six above stay what they were
+            params[name] = norm.init(jax.random.fold_in(key, 6 + i))
+            # a norm of ones would add 2 x num_layers sub-layer outputs, each
+            # as large as the stream it joins, every time the trunk is
+            # walked: the depth scaling of a residual branch (GPT-2 gives its
+            # output projections 1 / sqrt(2 L)) starts the branches' sum at
+            # the size of the walk's input. At ones, fresh weights are a
+            # chaotic map: on the chip the bf16 roundings of 192 layer
+            # applications moved the logits by 0.4-0.6 (PERF.md, PR 40)
+            scale = (2 * self.architecture.num_layers) ** -0.5
+            weight = params[name]["weight"]
+            params[name]["weight"] = (weight * scale).astype(weight.dtype)
         if self.adapter_attention is not None:
             params[f"adapter_attention_{self.adapter_name}"] = self.adapter_attention.init(keys[4])
         if self.adapter_mlp is not None:
@@ -205,6 +231,8 @@ class TransformerLayer(BaseLayer):
             ),
             "mlp": tree_prefix(self.mlp.param_metas(), "mlp"),
         }
+        for name, norm in self.output_norms.items():
+            metas[name] = tree_prefix(norm.param_metas(), name)
         if self.adapter_attention is not None:
             name = f"adapter_attention_{self.adapter_name}"
             metas[name] = tree_prefix(self.adapter_attention.param_metas(), name)
@@ -277,6 +305,9 @@ class TransformerLayer(BaseLayer):
             attn = attn + self.adapter_attention(
                 params[f"adapter_attention_{self.adapter_name}"], attn, ctx
             )
+        if self.output_norms:
+            attn = self.output_norms["post_attention_output_layernorm"](
+                params["post_attention_output_layernorm"], attn, ctx)
         h = h + attn.astype(h.dtype)
 
         normed = self.post_attention_layernorm(params["post_attention_layernorm"], h, ctx)
@@ -298,6 +329,9 @@ class TransformerLayer(BaseLayer):
             mlp_out = mlp_out + self.adapter_mlp(
                 params[f"adapter_mlp_{self.adapter_name}"], mlp_out, ctx
             )
+        if self.output_norms:
+            mlp_out = self.output_norms["post_mlp_output_layernorm"](
+                params["post_mlp_output_layernorm"], mlp_out, ctx)
         h = h + mlp_out.astype(h.dtype)
 
         out = dict(x)

@@ -52,6 +52,55 @@ class LayerNormWrapper(BaseLayer):
         return out
 
 
+class LoopExitGate(BaseLayer):
+    """The exit gate of a looped trunk (``loop_exit_gate``): ``Linear(hidden,
+    1)`` with a bias on each step's normed output, ``lambda_u = sigmoid(w .
+    h_u + b)``. Its place in the stack, after the final norm, holds its
+    parameters; walked as a layer it passes the activations on unchanged.
+    The looped walk (inference.py) calls :meth:`exit_probability` after
+    every step and :func:`exit_distribution` once over all of them.
+    Replicated over the model axis: 2049 parameters."""
+
+    def __init__(self, architecture: TransformerArchitectureConfig):
+        self.hidden_size = architecture.hidden_size
+        self.dtype = architecture.dtype
+
+    def init(self, key: jax.Array) -> dict:
+        return {"linear": {
+            "weight": xavier_normal_init(key, (self.hidden_size, 1), self.dtype),
+            "bias": jnp.zeros((1,), self.dtype),
+        }}
+
+    def param_metas(self) -> dict:
+        return {"linear": {
+            "weight": ParamMeta(parameter_name="linear.weight",
+                                partition_spec=(None, None),
+                                is_model_parallel_duplicate=True),
+            "bias": ParamMeta(parameter_name="linear.bias",
+                              partition_spec=(None,),
+                              is_model_parallel_duplicate=True),
+        }}
+
+    def __call__(self, params: dict, x: dict, ctx: ForwardContext) -> dict:
+        return x
+
+    def exit_probability(self, params: dict, h: jax.Array) -> jax.Array:
+        """``lambda`` of every position of ``h`` (..., hidden), float32: a
+        product and a sum on the vector unit, no matmul pass to round it."""
+        w = params["linear"]["weight"].astype(jnp.float32)[:, 0]
+        b = params["linear"]["bias"].astype(jnp.float32)[0]
+        return jax.nn.sigmoid(jnp.sum(h.astype(jnp.float32) * w, axis=-1) + b)
+
+
+def exit_distribution(lambdas: jax.Array) -> jax.Array:
+    """``p_u`` over the steps (leading axis) from the steps' gates: ``p_0 =
+    lambda_0``, ``p_u = lambda_u * prod_{j<u}(1 - lambda_j)``, and the last
+    step takes what is left, ``prod_{j<last}(1 - lambda_j)``: sums to 1."""
+    stay = jnp.cumprod(1.0 - lambdas, axis=0)  # prod_{j<=u}(1 - lambda_j)
+    before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]], axis=0)
+    return jnp.concatenate([(lambdas * before)[:-1], before[-1:]], axis=0)
+
+
 class TransformerLMHead(BaseLayer):
     """Untied head: column-parallel projection to the vocabulary
     (reference: lm_head.py:16-66). Under muP the readout zero-initializes

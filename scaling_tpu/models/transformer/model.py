@@ -24,6 +24,7 @@ from .layers.embedding import EmbeddingInput
 from .layers.layer import TransformerLayer
 from .layers.lm_head import (
     LayerNormWrapper,
+    LoopExitGate,
     TransformerEmbeddingHead,
     TransformerLMHead,
     TransformerLMHeadTied,
@@ -38,6 +39,11 @@ def get_transformer_layer_specs(
 ) -> List[LayerSpec]:
     """EmbeddingInput -> N x TransformerLayer -> final norm -> LM head
     [-> embedding head] (reference: model.py:122-216).
+
+    A looped model (``loop_steps > 1``) has the same list, the trunk's layers
+    ONCE: the steps share one set of parameters. Its exit gate's parameters
+    lie after the final norm. Who walks the list runs trunk and final norm
+    ``loop_steps`` times (inference.py ``_run_layers``).
 
     With pipe_parallel_size > 1 the homogeneous TransformerLayer run becomes
     one PipelineBodySpec executed as a stage-stacked spatial pipeline; edge
@@ -56,6 +62,13 @@ def get_transformer_layer_specs(
         specs = [LayerSpec(EmbeddingInput, architecture)]
 
     pp = topology.pipe_parallel_size if topology is not None else 1
+    if pp > 1 and architecture.loop_steps > 1:
+        raise ValueError(
+            f"pipe_parallel_size {pp} with loop_steps "
+            f"{architecture.loop_steps}: a looped trunk is not pipelined "
+            "(every stage would be revisited each step); use "
+            "pipe_parallel_size 1"
+        )
     if pp > 1:
         specs.append(
             PipelineBodySpec(TransformerLayer, architecture.num_layers, architecture)
@@ -67,6 +80,8 @@ def get_transformer_layer_specs(
     specs.append(
         LayerSpec(LayerNormWrapper, architecture, record_embeddings=has_embedding_head)
     )
+    if architecture.loop_exit_gate:
+        specs.append(LayerSpec(LoopExitGate, architecture))
 
     if architecture.weight_tying:
         specs.append(
@@ -266,12 +281,24 @@ def get_parameter_groups(
     return groups
 
 
+LOOPED_TRAINING_REFUSAL = (
+    "a looped model (loop_steps > 1) is served, not trained: its objective "
+    "(per-step losses weighted by the exit distribution, an entropy term) is "
+    "not in the configuration, and the plain walk of the layer list would run "
+    "the trunk once; run it through TransformerInferenceModule / ServeEngine"
+)
+
+
 def init_model(config: TransformerConfig, topology: Optional[Topology] = None) -> ParallelModule:
-    specs = get_transformer_layer_specs(config.transformer_architecture, topology)
+    architecture = config.transformer_architecture
+    specs = get_transformer_layer_specs(architecture, topology)
     return ParallelModule(
         specs,
         topology=topology,
-        compute_dtype=config.transformer_architecture.dtype,
+        compute_dtype=architecture.dtype,
+        forward_refusal=(
+            LOOPED_TRAINING_REFUSAL if architecture.loop_steps > 1 else None
+        ),
     )
 
 

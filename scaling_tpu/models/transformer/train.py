@@ -155,6 +155,10 @@ class TransformerTrainer(BaseTrainer):
 
 
 def main(config: TransformerConfig) -> TransformerTrainer:
+    if config.transformer_architecture.loop_steps > 1:
+        from .model import LOOPED_TRAINING_REFUSAL
+
+        raise NotImplementedError(f"train.main: {LOOPED_TRAINING_REFUSAL}")
     topology = Topology(config.topology)
     logger.configure(config.logger, name="transformer")
     logger.log_config(config)

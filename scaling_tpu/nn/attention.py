@@ -142,6 +142,15 @@ class PagedKVCacheView(NamedTuple):
     def quantized(self) -> bool:
         return self.scale_k is not None
 
+    def at_step(self, step, num_blocks: int) -> "PagedKVCacheView":
+        """A looped model's view of loop step ``step`` (traced or not): its
+        pools hold ``num_blocks`` blocks a step, step ``u``'s at ``[u *
+        num_blocks, (u + 1) * num_blocks)``, so the same rows' tables
+        shifted by ``step * num_blocks`` address the cache line of (step,
+        this layer). Block 0 of every step's share is trash: an all-trash
+        table stays one."""
+        return self._replace(block_table=self.block_table + step * num_blocks)
+
     def token_rows(self, batch_shape: Tuple[int, int]):
         """``(row, offset, real)`` of every position of a batch of shape
         ``(b, s)``: its pool row, its place among the row's new tokens,

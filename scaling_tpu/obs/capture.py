@@ -34,7 +34,8 @@ from .registry import MetricsRegistry, get_registry
 # (name, start_ns, duration_ns, fields): start_ns counts from the start
 # of the capture, which is the origin the profiler gives its planes to
 # within the time start_trace takes to return; fields holds the span's
-# step, its parent's name and its scalar annotations
+# step, its parent's name and its scalar annotations (and lists of
+# numbers: a looped model's exit distribution)
 SpanRow = Tuple[str, int, int, Dict[str, Any]]
 
 
@@ -75,7 +76,9 @@ class _Active:
         if _active is not self:
             return
         fields = {k: v for k, v in sp.fields.items()
-                  if isinstance(v, (bool, int, float, str))}
+                  if isinstance(v, (bool, int, float, str))
+                  or (isinstance(v, list) and v
+                      and all(isinstance(x, (int, float)) for x in v))}
         if step is not None:
             fields["step"] = step
         if parent is not None:

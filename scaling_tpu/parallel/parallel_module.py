@@ -141,7 +141,11 @@ class ParallelModule:
         layer_specs: List[LayerSpec],
         topology: Optional[Topology] = None,
         compute_dtype=jnp.float32,
+        forward_refusal: Optional[str] = None,
     ):
+        # why ``forward`` (so every train / eval step built on it) must not
+        # walk this layer list once front to back; None: it may
+        self.forward_refusal = forward_refusal
         self.layer_specs = layer_specs
         self.topology = topology
         self.compute_dtype = compute_dtype
@@ -367,6 +371,8 @@ class ParallelModule:
         return p
 
     def forward(self, params: dict, x: Any, ctx: ForwardContext) -> Any:
+        if self.forward_refusal is not None:
+            raise NotImplementedError(self.forward_refusal)
         ckpt_type = (
             self.topology.activation_checkpointing_type
             if self.topology is not None
@@ -422,6 +428,8 @@ class ParallelModule:
         Output loss/metrics are means over micro batches (reference:
         parallel_module.py:288, optimizer.py:99-105).
         """
+        if self.forward_refusal is not None:
+            raise NotImplementedError(self.forward_refusal)
         gas = self.topology.gradient_accumulation_steps if self.topology else 1
 
         scaler_enabled = optimizer.config.loss_scaler.enable

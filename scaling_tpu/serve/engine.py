@@ -324,6 +324,12 @@ class ServeEngine:
         self.num_experts = (
             arch.moe_num_experts if arch.mlp_type == MLPType.MOE else 0
         )
+        # a looped model (loop_steps > 1): every tick walks the trunk
+        # loop_steps times; with an exit gate the mixed program also
+        # returns, in the tick's one host read, the exit distribution over
+        # the steps summed over the tick's sampled positions
+        self.loop_steps = arch.loop_steps
+        self.loop_exit_gate = arch.loop_exit_gate
         self.tick_index = 0
         self.finished: List[Sequence] = []
         self.max_concurrent_prefills = 0
@@ -642,7 +648,10 @@ class ServeEngine:
         A routed model's program returns ONE int32 vector instead of the
         grid: the sampled grid flattened, then the (E,) load of the
         tick's real positions (``_run_layers(moe_load=True)``), so that
-        the load costs the tick no second host read."""
+        the load costs the tick no second host read. A looped model with
+        an exit gate appends likewise the (loop_steps,) float32 exit
+        distribution, summed over the sampled positions that hold a token,
+        as its bits (``_run_layers(exit_p=True)``)."""
         from ..nn.attention import packed_token_map
 
         jnp = self._jax.numpy
@@ -650,6 +659,7 @@ class ServeEngine:
         row_width = self.config.mixed_width
         shape = packed_batch_shape(width, row_width)
         routed = self.num_experts > 0
+        gated = self.loop_exit_gate
 
         def mixed(params, state, packed, base_key):
             tick = self._layout.split(packed)
@@ -666,11 +676,11 @@ class ServeEngine:
             g0 = jnp.clip(new_lens - sample_width, 0,
                           row_width - sample_width)
             window = g0[:, None] + jnp.arange(sample_width, dtype=jnp.int32)
-            logits, new_views, *load = self.inf._run_layers(
+            logits, new_views, *extra = self.inf._run_layers(
                 params, batch, views, None,
                 gather_index=jnp.take_along_axis(
                     token_map.row_tokens, window, axis=1),
-                moe_load=routed,
+                moe_load=routed, exit_p=gated,
             )
             # gathered index j is the row's token g0 + j: shift the
             # per-row key-fold base so every sample still draws with the
@@ -680,7 +690,16 @@ class ServeEngine:
                 tick.gen0 + g0, base_key
             )
             if routed:
-                sampled = jnp.concatenate([sampled.reshape(-1), load[0]])
+                sampled = jnp.concatenate([sampled.reshape(-1), extra[0]])
+            if gated:
+                # (loop_steps, rows, sample_width): the window's positions
+                # that hold a token
+                held = window < new_lens[:, None]
+                exit_p = jnp.sum(jnp.where(held[None], extra[-1], 0.0),
+                                 axis=(1, 2))
+                sampled = jnp.concatenate([
+                    sampled.reshape(-1),
+                    self._jax.lax.bitcast_convert_type(exit_p, jnp.int32)])
             return sampled, state_from_views(new_views)
 
         # the pool state dies with each call (_absorb takes the returned
@@ -727,7 +746,8 @@ class ServeEngine:
     def _apply_cow(self, pairs) -> None:
         """Copy-on-write block forks the scheduler ordered this tick:
         duplicate pool block ``src`` into freshly-allocated ``dst``
-        across every layer (K, V, and int8 scales) BEFORE the tick's
+        across every layer (K, V, and int8 scales), in every cache line
+        of the layer's pool (a looped model's steps), BEFORE the tick's
         programs run. Eager host-dispatched ops — forks never occur in
         the steady state (full-block prefix sharing places writes past
         every shared block), so this path stays off the hot loop."""
@@ -735,6 +755,7 @@ class ServeEngine:
             return
         p = self.pools
         for src, dst in pairs:
+            src, dst = p.line_blocks(src), p.line_blocks(dst)
             for arrs in (p.pool_k, p.pool_v, p.scale_k, p.scale_v):
                 if arrs is None:
                     continue
@@ -806,6 +827,10 @@ class ServeEngine:
                 packed = packed[:self._layout.size(width)]
             if not self.warmup_mode:  # mixed_span is a span
                 mixed_span.annotate(width=width, tokens=len(real))
+                if self.loop_steps > 1:
+                    mixed_span.annotate(loop_steps=self.loop_steps)
+                    self._counter("serve_loop_layer_passes_total").inc(
+                        self.loop_steps * self.pools.num_layers)
                 self.mixed_ticks[width] = self.mixed_ticks.get(width, 0) + 1
                 self.mixed_tokens[width] = (
                     self.mixed_tokens.get(width, 0) + len(real)
@@ -831,6 +856,11 @@ class ServeEngine:
                 load = host_samples[n * sw:]
                 host_samples = host_samples[:n * sw].reshape(n, sw)
                 self._record_moe_load(load, emit)
+            if self.loop_exit_gate:
+                exit_p = host_samples[n * sw:].view(np.float32)
+                host_samples = host_samples[:n * sw].reshape(n, sw)
+                self._record_exit(
+                    exit_p, int(np.minimum(new_lens, sw).sum()), emit)
             self._absorb(state)
             now = time.monotonic()
             for seq, start, n_real in chunk_rows:
@@ -856,6 +886,19 @@ class ServeEngine:
         emit_span.annotate(
             load_max=int(load.max()), load_mean=float(load.mean()),
             experts_idle=int((load == 0).sum()),
+        )
+
+    def _record_exit(self, exit_p, positions: int, emit_span) -> None:
+        """One tick's exit distribution over the loop's steps, summed by
+        the program over the tick's ``positions`` sampled positions: their
+        mean, and the steps a token would run if it left at its draw."""
+        if self.warmup_mode or not positions:
+            return
+        mean = [float(p) / positions for p in exit_p]
+        emit_span.annotate(
+            exit_p=[round(p, 6) for p in mean],
+            exit_expected_steps=round(
+                sum((u + 1) * p for u, p in enumerate(mean)), 6),
         )
 
     def _accept_speculative(self, seq: Sequence, row_samples, now) -> None:
@@ -1165,6 +1208,10 @@ class ServeEngine:
                 self.tick_operands / sum(self.mixed_ticks.values())
                 if self.mixed_ticks else None
             ),
+            # cache lines a token's K and V are written to (a looped model:
+            # steps x layers) and the bytes the pools really hold
+            "kv_lines": self.pools.kv_lines,
+            "kv_pool_bytes": self.pools.device_bytes(),
         }
 
     def run_until_done(self, max_ticks: int = 100_000) -> List[Sequence]:

@@ -26,6 +26,13 @@ host state (they are addressing, not content), the engine's jitted
 programs run SPMD over the serving mesh, and the Pallas paged kernel
 runs per-shard on its slice (nn/attention.py wraps it in shard_map —
 pallas calls are opaque to GSPMD).
+
+**Looped models** (``loop_steps > 1``, docs/SERVING.md "Looped models"):
+the K and V of every (step, layer) are a cache line of their own. A layer's
+pool then holds ``loop_steps x num_blocks`` blocks, step ``u``'s at
+``[u * num_blocks, (u + 1) * num_blocks)``; the scheduler's block ``b`` is
+the same 16 tokens in every line, at ``u * num_blocks + b`` of every
+layer's pool. Block 0 of every step's share is trash.
 """
 
 from __future__ import annotations
@@ -34,6 +41,7 @@ from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..nn.attention import PagedKVCacheView, PagedTokenMap
 
@@ -56,7 +64,9 @@ def build_layer_views(
     token_map: Optional[PagedTokenMap] = None,  # a token-major batch's
 ) -> List[PagedKVCacheView]:
     """Per-layer :class:`PagedKVCacheView` s over the raw pool state —
-    the shape the engine's jitted programs thread through ``_run_layers``.
+    the shape the engine's jitted programs thread through ``_run_layers``
+    (a looped model's too: one view a layer, whose pool holds every step's
+    blocks and whose table ``_run_layers`` shifts step by step).
 
     ``new_len`` carries the chunked-prefill pad contract (mid-prompt
     pad-to-trash routing): of the ``s`` tokens a fixed-size chunk
@@ -117,16 +127,24 @@ class PagedKVPools:
     def __init__(self, pool_k: List[jax.Array], pool_v: List[jax.Array],
                  scale_k: Optional[List[jax.Array]],
                  scale_v: Optional[List[jax.Array]],
-                 block_size: int):
+                 block_size: int, loop_steps: int = 1):
         self.pool_k = pool_k
         self.pool_v = pool_v
         self.scale_k = scale_k
         self.scale_v = scale_v
         self.block_size = block_size
+        # cache lines a layer's pool holds: a looped model's steps
+        self.loop_steps = loop_steps
 
     @property
     def num_layers(self) -> int:
         return len(self.pool_k)
+
+    @property
+    def kv_lines(self) -> int:
+        """Cache lines a token's K and V are written to: one a (step,
+        layer)."""
+        return self.loop_steps * len(self.pool_k)
 
     @property
     def quantized(self) -> bool:
@@ -134,7 +152,13 @@ class PagedKVPools:
 
     @property
     def num_blocks(self) -> int:
-        return self.pool_k[0].shape[0]
+        """Blocks the scheduler counts (each lies in every line)."""
+        return self.pool_k[0].shape[0] // self.loop_steps
+
+    def line_blocks(self, block: int):
+        """Where the scheduler's block ``block`` lies in a layer's pool:
+        once a step."""
+        return block + self.num_blocks * np.arange(self.loop_steps)
 
     def absorb_state(self, state: Tuple) -> None:
         """Take back the updated state a jitted program returned."""
@@ -171,6 +195,16 @@ def init_pools(inference_module, num_blocks: int, block_size: int,
         return inference_module.prefill_forward(p, t, po)[1]
 
     kv_shapes = jax.eval_shape(probe, params, probe_tokens, probe_pos)
+    # the probe returns the (k, v) of every cache line: a looped model's
+    # lines are its steps x its layers, and a layer's pool holds its steps'
+    loop_steps = inference_module.architecture.loop_steps
+    if len(kv_shapes) % loop_steps:
+        raise ValueError(
+            f"the layer stack produced {len(kv_shapes)} KV cache lines, not a "
+            f"multiple of loop_steps {loop_steps}"
+        )
+    kv_shapes = kv_shapes[:len(kv_shapes) // loop_steps]
+    pool_blocks = loop_steps * num_blocks
     # commit the fresh pools to the device(s) the programs will run on:
     # an uncommitted zeros-array keys a SECOND executable-cache entry for
     # the engine's very first program call (every later call sees the
@@ -220,13 +254,14 @@ def init_pools(inference_module, num_blocks: int, block_size: int,
     for k_aval, v_aval in kv_shapes:
         n_kv, h = k_aval.shape[2], k_aval.shape[3]
         store = jnp.int8 if kv_dtype == "int8" else k_aval.dtype
-        pool_k.append(placed((num_blocks, block_size, n_kv, h), store, 2))
-        pool_v.append(placed((num_blocks, block_size, n_kv, h), store, 2))
+        pool_k.append(placed((pool_blocks, block_size, n_kv, h), store, 2))
+        pool_v.append(placed((pool_blocks, block_size, n_kv, h), store, 2))
         if kv_dtype == "int8":
             scale_k.append(
-                placed((num_blocks, block_size, n_kv), jnp.float32, 2)
+                placed((pool_blocks, block_size, n_kv), jnp.float32, 2)
             )
             scale_v.append(
-                placed((num_blocks, block_size, n_kv), jnp.float32, 2)
+                placed((pool_blocks, block_size, n_kv), jnp.float32, 2)
             )
-    return PagedKVPools(pool_k, pool_v, scale_k, scale_v, block_size)
+    return PagedKVPools(pool_k, pool_v, scale_k, scale_v, block_size,
+                        loop_steps)

@@ -40,17 +40,37 @@ class ModelSpec:
     mlp_factor: float = 2.75
     glu: bool = True
     moe: bool = False
+    # a looped model walks its trunk this many times over the same weights:
+    # parameters are held once, read (and their FLOPs paid) every step, and
+    # a token's KV cache is a line per (step, layer)
+    loop_steps: int = 1
 
     @property
-    def parameter_count(self) -> int:
+    def trunk_parameter_count(self) -> int:
         per_layer = 4 * self.hidden_size * self.hidden_size + (
             3 if self.glu else 2
         ) * int(self.hidden_size * self.hidden_size * self.mlp_factor)
-        return self.num_layers * per_layer + self.vocab_size * self.hidden_size
+        return self.num_layers * per_layer
+
+    @property
+    def parameter_count(self) -> int:
+        """Parameters HELD (a looped trunk's once)."""
+        return self.trunk_parameter_count + self.vocab_size * self.hidden_size
+
+    @property
+    def kv_lines(self) -> int:
+        """Cache lines a token's K and V are written to."""
+        return self.loop_steps * self.num_layers
 
     @property
     def flops_per_token(self) -> float:
         """PaLM appendix-B train FLOPs/token: 6N + 12 L H S."""
+        if self.loop_steps > 1:
+            raise ValueError(
+                "a looped model (loop_steps > 1) is served, not trained: the "
+                "training cost model does not price it (its objective is not "
+                "in the configuration); tune its serving layout instead"
+            )
         return (
             6.0 * self.parameter_count
             + 12.0 * self.num_layers * self.hidden_size * self.sequence_length
@@ -90,6 +110,7 @@ class ModelSpec:
             mlp_factor=float(get("mlp_factor", 4.0)),
             glu=mlp_type == "swiglu",
             moe=mlp_type == "moe",
+            loop_steps=int(get("loop_steps", 1)),
         )
 
 

@@ -138,6 +138,8 @@ class ServingPoint:
                 "hidden_size": model.hidden_size,
                 "num_layers": model.num_layers,
                 "num_kv_heads": model.num_kv_heads,
+                **({"loop_steps": model.loop_steps}
+                   if model.loop_steps > 1 else {}),
             }
         return cfg
 
@@ -174,10 +176,13 @@ def serve_flops_per_token(model: ModelSpec, avg_context: float) -> float:
     """Inference FLOPs per generated/prefilled token: 2 per parameter
     (one forward MAC each) plus the attention window reads —
     ``4 * layers * hidden * context`` (QK^T and PV over the cached
-    context), the forward third of PaLM appendix-B's 12 L H S."""
+    context), the forward third of PaLM appendix-B's 12 L H S. A looped
+    model works its trunk's parameters and attends once a step."""
+    worked = model.parameter_count + (
+        model.loop_steps - 1) * model.trunk_parameter_count
     return (
-        2.0 * model.parameter_count
-        + 4.0 * model.num_layers * model.hidden_size * avg_context
+        2.0 * worked
+        + 4.0 * model.kv_lines * model.hidden_size * avg_context
     )
 
 
@@ -205,7 +210,7 @@ def predict_tick_seconds(
         link = link_for_axis(point.layout(), topo, "model")
         # Megatron TP inference forward: 2 activation ARs per layer over
         # the tick's activations (no backward at serving)
-        count = 2 * model.num_layers
+        count = 2 * model.kv_lines  # a looped trunk's layers once a step
         payload = count * point.token_budget * model.hidden_size * BF16
         comm_s = collective_seconds(
             "all-reduce", float(payload), count, point.mp, link
@@ -225,8 +230,8 @@ def serving_memory_gb(model: ModelSpec, point: ServingPoint) -> float:
     head = model.hidden_size // model.num_attention_heads
     pool_tokens = point.token_budget * POOL_TOKENS_PER_BUDGET_TOKEN
     pool_tokens += point.num_slots * point.block_size / 2.0  # fragmentation
-    pool = (
-        model.num_layers * 2.0 * pool_tokens
+    pool = (  # a cache line per (step, layer)
+        model.kv_lines * 2.0 * pool_tokens
         * (model.num_kv_heads / point.mp) * head * BF16
     )
     return (params + pool) / 1e9

@@ -139,17 +139,12 @@ def test_paged_kernel_compiles(one_chip, slots, q_heads, kv_heads, s, kv_dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("bucket", [0, 1], ids=["small", "full"])
-def test_mixed_program_updates_its_donated_pools_in_place(one_chip, monkeypatch,
-                                                          bucket):
-    """The serve tick's program at each of its two token widths, compiled
-    with donation on, as the chip runs it (ISSUE 31, 33): XLA pairs every
-    layer's K and V pool with the output computed from it and copies no
-    pool. What the CPU cannot show:
-    the lowered alias table (tests/core/test_serve/test_kvcache.py) says
-    which output a donated buffer is offered to, the compiled module says
-    whether the scatter then ran in place. A state returned as per-layer
-    views left a `copy` of each misaligned pool in this text."""
+def lowered_mixed_program(one_chip, monkeypatch, bucket, heads, kv_heads,
+                          layers=2, **architecture):
+    """The serve tick's program at one of its two token widths, lowered with
+    donation on for the described chip, over abstract weights: ``(lowered,
+    its parameter leaves, one pool)``. Attention at the given head counts
+    over a narrow MLP and vocabulary."""
     from scaling_tpu.models.transformer import TransformerConfig
     from scaling_tpu.models.transformer.inference import (
         TransformerInferenceModule,
@@ -161,9 +156,7 @@ def test_mixed_program_updates_its_donated_pools_in_place(one_chip, monkeypatch,
         "scaling_tpu.nn.paged_attention.paged_kernel_interpret",
         lambda platform=None: False,
     )
-    layers, slots, max_blocks = 2, 8, 16
-    # Mistral-7B's attention (benchmark/configs/mistral-7b-v0.3-serve.json)
-    # over a narrow MLP and vocabulary; the weights stay abstract
+    slots, max_blocks = 8, 16
     config = TransformerConfig.from_dict({
         "topology": {
             "model_parallel_size": 1, "pipe_parallel_size": 1,
@@ -171,14 +164,15 @@ def test_mixed_program_updates_its_donated_pools_in_place(one_chip, monkeypatch,
             "gradient_accumulation_steps": 1,
         },
         "transformer_architecture": {
-            "vocab_size": 512, "hidden_size": 32 * HEAD_DIM,
-            "num_layers": layers, "num_attention_heads": 32,
-            "attention_num_kv_heads": 8, "attention_qkv_in_one": False,
+            "vocab_size": 512, "hidden_size": heads * HEAD_DIM,
+            "num_layers": layers, "num_attention_heads": heads,
+            "attention_num_kv_heads": kv_heads, "attention_qkv_in_one": False,
             "attention_bias": False, "mlp_type": "swiglu",
             "mlp_factor": 0.25, "mlp_bias": False, "norm_type": "rms",
             "relative_position_embedding_type": "rotary",
             "sequence_length": BLOCK_SIZE * max_blocks,
             "precision": "bfloat16", "weight_tying": False,
+            **architecture,
         },
         "optimizer": {"gradient_clipping": 1.0},
         "learning_rate_scheduler": {
@@ -203,8 +197,11 @@ def test_mixed_program_updates_its_donated_pools_in_place(one_chip, monkeypatch,
 
     state = engine._pool_state()
     pool = state[0][0]
-    assert pool.shape == (slots * max_blocks + 1, BLOCK_SIZE, 8, HEAD_DIM)
-    text = jax.jit(
+    steps = config.transformer_architecture.loop_steps
+    assert pool.shape == (steps * (slots * max_blocks + 1), BLOCK_SIZE,
+                          kv_heads, HEAD_DIM)
+    assert len(state[0]) == layers and engine.pools.kv_lines == steps * layers
+    lowered = jax.jit(
         engine._build_mixed_fn(width).__wrapped__, donate_argnums=(1,),
         keep_unused=True,
     ).lower(
@@ -213,12 +210,13 @@ def test_mixed_program_updates_its_donated_pools_in_place(one_chip, monkeypatch,
         jax.ShapeDtypeStruct((engine._layout.size(width),), jnp.int32,
                              sharding=one_chip),  # the tick's one operand
         on_chip(engine._base_key),
-    ).compile().as_text()
-    assert text.count("tpu_custom_call") == layers  # the kernel, compiled
+    )
+    return lowered, jax.tree_util.tree_leaves(params), pool
 
-    # parameters flatten (params, pool_k[0..], pool_v[0..], operand, key);
-    # outputs (tokens, pool_k[0..], pool_v[0..])
-    first = len(jax.tree_util.tree_leaves(params))
+
+def assert_pools_updated_in_place(text, first, layers, pool):
+    """Parameters flatten (params, pool_k[0..], pool_v[0..], operand, key);
+    outputs (tokens, pool_k[0..], pool_v[0..])."""
     aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
     pairs = {
         int(param): int(out) for out, param in
@@ -231,3 +229,47 @@ def test_mixed_program_updates_its_donated_pools_in_place(one_chip, monkeypatch,
     copies = [c for c in re.findall(rf"= bf16\[{dims}\]\S* copy\S*\(", text)
               if "S(1)" not in c]
     assert not copies, f"{len(copies)} whole-pool copies in the compiled tick"
+
+
+@pytest.mark.parametrize("bucket", [0, 1], ids=["small", "full"])
+def test_mixed_program_updates_its_donated_pools_in_place(one_chip, monkeypatch,
+                                                          bucket):
+    """The serve tick's program at each of its two token widths, compiled
+    with donation on, as the chip runs it (ISSUE 31, 33): XLA pairs every
+    layer's K and V pool with the output computed from it and copies no
+    pool. What the CPU cannot show:
+    the lowered alias table (tests/core/test_serve/test_kvcache.py) says
+    which output a donated buffer is offered to, the compiled module says
+    whether the scatter then ran in place. A state returned as per-layer
+    views left a `copy` of each misaligned pool in this text. Mistral-7B's
+    attention (benchmark/configs/mistral-7b-v0.3-serve.json)."""
+    layers = 2
+    lowered, params, pool = lowered_mixed_program(
+        one_chip, monkeypatch, bucket, heads=32, kv_heads=8, layers=layers)
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") == layers  # the kernel, compiled
+    assert_pools_updated_in_place(text, len(params), layers, pool)
+
+
+@pytest.mark.parametrize("bucket", [0, 1], ids=["small", "full"])
+def test_looped_mixed_program_is_rolled_and_updates_its_pools_in_place(
+        one_chip, monkeypatch, bucket):
+    """A looped model's tick (ISSUE 40) at Ouro-2.6B's head shapes (16 query
+    and 16 KV heads x 128), 2 layers x 4 steps with sandwich norms and the
+    exit gate: the steps are ONE rolled loop whose body is the trunk, so the
+    lowered program holds the kernel calls and matmuls of ONE step (the head
+    and the gate apart, as many as the plain model of the same layers), each
+    layer's pool of 4 x the blocks rides the loop's carry, is aliased to the
+    output computed from it and is copied nowhere."""
+    layers, steps = 2, 4
+    looped, params, pool = lowered_mixed_program(
+        one_chip, monkeypatch, bucket, heads=16, kv_heads=16, layers=layers,
+        loop_steps=steps, sandwich_norm=True, loop_exit_gate=True)
+    plain, _, _ = lowered_mixed_program(
+        one_chip, monkeypatch, bucket, heads=16, kv_heads=16, layers=layers)
+    for op in ("tpu_custom_call", "stablehlo.dot_general"):
+        assert looped.as_text().count(op) == plain.as_text().count(op) > 0, op
+    assert "stablehlo.while" in looped.as_text()
+    text = looped.compile().as_text()
+    assert text.count("tpu_custom_call") == layers
+    assert_pools_updated_in_place(text, len(params), layers, pool)

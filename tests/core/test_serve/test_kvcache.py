@@ -129,7 +129,8 @@ def test_chunk_padding_lands_in_trash_not_blocks(toy_inference):
 # tokens + 3 chunks round up to 128, under the full 256)
 SLOTS, MAX_BLOCKS, CHUNK = 8, 8, 32
 WIDTHS = (128, 256)
-MODELS = ["dense", "routed", "mp2"]
+LOOP_STEPS = 4
+MODELS = ["dense", "routed", "mp2", "looped"]
 
 
 @pytest.fixture(scope="module")
@@ -137,7 +138,9 @@ def inference_modules(toy_inference):
     """The model kinds whose mixed programs differ in what they return or
     where their pools live: dense; routed (its first output is the grid
     flattened + the (E,) load); dense on a 2-device model-parallel
-    serving mesh (pools sharded over ``model``)."""
+    serving mesh (pools sharded over ``model``); looped (three layers'
+    pools of 4 x the blocks ride a rolled loop's carry, and its first
+    output is the grid flattened + the exit distribution's four numbers)."""
     from scaling_tpu.models.transformer import TransformerConfig
     from scaling_tpu.models.transformer.inference import (
         TransformerInferenceModule,
@@ -161,7 +164,18 @@ def inference_modules(toy_inference):
         "data": {}, "logger": {"log_dir": None},
     })
     module = init_model(routed, None)
+    looped = TransformerConfig.from_dict({
+        **routed.as_dict(), "transformer_architecture": {
+            "vocab_size": 64, "hidden_size": 32, "num_layers": 3,
+            "num_attention_heads": 4, "sequence_length": 256,
+            "mlp_type": "swiglu", "mlp_factor": 2.0, "norm_type": "rms",
+            "weight_tying": False, "mlp_bias": False, "loop_steps": LOOP_STEPS,
+            "sandwich_norm": True, "loop_exit_gate": True}})
+    looped_module = init_model(looped, None)
     return {
+        "looped": TransformerInferenceModule(
+            looped, looped_module,
+            looped_module.init_params(jax.random.PRNGKey(0))),
         "dense": toy_inference,
         "routed": TransformerInferenceModule(
             routed, module, module.init_params(jax.random.PRNGKey(0))),
@@ -213,8 +227,9 @@ def _aliases(lowered):
 @pytest.mark.parametrize("width", WIDTHS, ids=["small", "full"])
 @pytest.mark.parametrize("kv_dtype", ["native", "int8"])
 @pytest.mark.parametrize(
-    "model,spec_k", [("dense", 0), ("dense", 2), ("routed", 0), ("mp2", 0)],
-    ids=["mixed", "mixed-spec2", "routed", "mp2"],
+    "model,spec_k",
+    [("dense", 0), ("dense", 2), ("routed", 0), ("mp2", 0), ("looped", 0)],
+    ids=["mixed", "mixed-spec2", "routed", "mp2", "looped"],
 )
 def test_donated_pool_aliases_the_output_computed_from_it(
         inference_modules, model, spec_k, kv_dtype, width):
@@ -230,7 +245,9 @@ def test_donated_pool_aliases_the_output_computed_from_it(
     model's first output is one vector (grid + load), still ONE leaf
     ahead of the state; on the serving mesh every pool is sharded over
     ``model`` and XLA does the pairing. Both token widths' programs
-    donate and return the same state."""
+    donate and return the same state. A looped model's pools pass through
+    its rolled loop's carry on their way from argument to output: still
+    one pool a LAYER, each aliased to the output computed from it."""
     _, fn, args = _program_and_args(
         inference_modules[model], kv_dtype, spec_k, width)
     lowered = jax.jit(fn, donate_argnums=(1,), keep_unused=True).lower(
@@ -246,16 +263,17 @@ def test_donated_pool_aliases_the_output_computed_from_it(
 
 @pytest.mark.parametrize("width", WIDTHS, ids=["small", "full"])
 @pytest.mark.parametrize("kv_dtype", ["native", "int8"])
-@pytest.mark.parametrize("model", MODELS, ids=["mixed", "routed", "mp2"])
+@pytest.mark.parametrize("model", MODELS,
+                         ids=["mixed", "routed", "mp2", "looped"])
 def test_programs_return_the_state_in_pool_state_structure(
         inference_modules, model, kv_dtype, width):
     engine, fn, args = _program_and_args(
         inference_modules[model], kv_dtype, 0, width)
     sampled, state = jax.eval_shape(fn, *args)
     sw = engine.config.sample_width
+    tail = {"routed": engine.num_experts, "looped": LOOP_STEPS}
     assert sampled.shape == (
-        (SLOTS * sw + engine.num_experts,) if model == "routed"
-        else (SLOTS, sw)
+        (SLOTS * sw + tail[model],) if model in tail else (SLOTS, sw)
     )
     structure = jax.tree_util.tree_structure
     assert structure(state) == structure(engine._pool_state())
@@ -263,6 +281,10 @@ def test_programs_return_the_state_in_pool_state_structure(
     for got, held in zip(jax.tree_util.tree_leaves(state),
                          jax.tree_util.tree_leaves(engine._pool_state())):
         assert (got.shape, got.dtype) == (held.shape, held.dtype)
+    # one pool a layer; a looped model's holds every step's blocks
+    steps = LOOP_STEPS if model == "looped" else 1
+    assert len(state[0]) == 3 and engine.pools.kv_lines == 3 * steps
+    assert state[0][0].shape[0] == steps * (2 * MAX_BLOCKS + 1)
 
 
 def test_run_layers_on_paged_views_defaults_to_the_kernel(toy_inference):

@@ -109,6 +109,38 @@ def test_serving_point_config_is_runnable_shape():
     assert cfg["model"]["num_kv_heads"] % cfg["mp"] == 0
 
 
+def test_a_looped_model_pays_its_trunk_and_its_cache_once_a_step():
+    """``loop_steps`` (ISSUE 40): parameters are held once, so the weights'
+    share of the memory does not move; the pool holds a cache line per
+    (step, layer) and a tick works the trunk's parameters every step."""
+    import dataclasses
+
+    from scaling_tpu.tune.serving import serve_flops_per_token, serving_memory_gb
+
+    looped = dataclasses.replace(MODEL, loop_steps=4)
+    assert ModelSpec.from_arch({
+        "hidden_size": 2048, "num_layers": 8, "num_attention_heads": 16,
+        "attention_num_kv_heads": 4, "sequence_length": 2048,
+        "vocab_size": 32768, "mlp_factor": 2.75, "mlp_type": "swiglu",
+        "loop_steps": 4}) == looped
+    assert looped.parameter_count == MODEL.parameter_count
+    assert looped.kv_lines == 4 * MODEL.kv_lines == 32
+    point = ServingPoint(1, 8, 16, 256)
+    weights = MODEL.parameter_count * 2 / 1e9
+    assert serving_memory_gb(looped, point) - weights == pytest.approx(
+        4 * (serving_memory_gb(MODEL, point) - weights))
+    head = MODEL.vocab_size * MODEL.hidden_size
+    assert serve_flops_per_token(looped, 100.0) - 2.0 * head == pytest.approx(
+        4 * (serve_flops_per_token(MODEL, 100.0) - 2.0 * head))
+    plain = predict_tick_seconds(MODEL, point, TOPO)["tick_s"]
+    assert 3.0 < predict_tick_seconds(looped, point, TOPO)["tick_s"] / plain < 4.0
+    assert point.to_config(looped)["model"]["loop_steps"] == 4
+    assert "loop_steps" not in point.to_config(MODEL)["model"]
+    # the training cost model refuses it by name
+    with pytest.raises(ValueError, match="served, not trained"):
+        looped.flops_per_token
+
+
 def test_serve_calibration_scales_predictions(tmp_path):
     """A canned run dir with serve.mixed spans + a serve-summary
     carrying engine facts yields a measured/predicted factor that
