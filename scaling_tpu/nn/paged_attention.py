@@ -20,6 +20,27 @@ cannot see costs nothing: the loop ends at the row's last tile and the
 last tile fetches only the blocks that hold visible slots (an inactive
 row runs no tile at all).
 
+The double buffer runs over the CALL's flat list of (row, tile) steps, not
+row by row: while a row's last tile is folded, the first tile of the next
+row that holds a visible slot is already on its way into the other buffer,
+and that row begins by waiting for it. Only the call's first active row
+fetches its own first tile. A decode tick's rows are mostly one tile long,
+so without this every row's whole fetch was waited for with nothing to
+fold (ISSUE 41). Two things cross a grid step, both functions of
+``valid_len`` alone and therefore computed in the wrapper and handed over
+as two more scalar-prefetch vectors (``_pipeline_carry``), not carried in
+scratch: the tiles of the rows before a row (its parity is the buffer the
+row's first tile lies in; 0 says nobody has fetched it) and the next row
+that runs a tile (-1: none). The buffers and their DMA semaphores are
+scratch, which persists from one grid step to the next; the grid is
+declared sequential (``dimension_semantics=("arbitrary",)``), since a row's
+first tile is started by the row before it. A wait counts the blocks the
+starter issued, ``blocks_held`` of the WAITING row's own ``valid_len``,
+whoever started them; and the call's last active row prefetches nothing,
+so every DMA started in a call is waited for in that call: none is still
+writing VMEM, or holding a semaphore above zero, when the next kernel
+(this one again, a layer on) takes the core.
+
 Inside a tile the work is MXU-shaped and in the pool's own dtype. The GQA
 group is folded into the matmul's rows: the caller-side wrapper lays the
 queries out per KV head as ``(s * group, h)``, position-major, so per KV
@@ -128,6 +149,14 @@ def _blocks_per_tile(block_size: int, max_blocks: int, n_kv: int, h: int,
     return max(1, min(max_blocks, _TILE_TOKENS // block_size, by_vmem))
 
 
+def kernel_tile_tokens(block_size: int, max_blocks: int, n_kv: int, h: int,
+                       itemsize: int) -> int:
+    """KV tokens one tile of the kernel holds at these shapes."""
+    return block_size * _blocks_per_tile(
+        block_size, max_blocks, n_kv, h, itemsize
+    )
+
+
 def _unpack_head(words, i: int, packing: int):
     """Head ``i`` of the ``packing`` a 32-bit word holds, widened in place:
     bf16 to float32 (exact), int8 to int32."""
@@ -182,6 +211,8 @@ def _paged_attention_kernel(
     tab_ref,      # (rows, max_blocks) int32 pool block ids
     valid_ref,    # (rows,) int32 valid slot count per row (ctx + new real)
     base_ref,     # (rows,) int32 slot of each row's first query token
+    before_ref,   # (rows,) int32 tiles of the rows before this one
+    next_ref,     # (rows,) int32 next row that holds a visible slot, or -1
     # blocks
     q_ref,        # (1, n_kv, m, h) VMEM: queries folded per KV head
     pool_k_ref,   # (num_blocks, block_size, n_kv, h) left in HBM
@@ -205,6 +236,9 @@ def _paged_attention_kernel(
     valid_len = valid_ref[row]
     base = base_ref[row]
     num_tiles = pl.cdiv(valid_len, tile)
+    # the call's tiles alternate between the two buffers across rows too
+    tiles_before = before_ref[row]
+    next_row = next_ref[row]
 
     @pl.when(row == 0)
     def _clear_v_tiles():
@@ -213,10 +247,10 @@ def _paged_attention_kernel(
         # probabilities are 0, and 0 * v is 0 once v is finite
         v_buf[...] = jnp.zeros_like(v_buf)
 
-    def blocks_held(t):
-        """How many blocks of tile ``t`` hold slots this row can see."""
+    def blocks_held(t, of_row=row):
+        """How many blocks of tile ``t`` hold slots ``of_row`` can see."""
         return jnp.clip(
-            pl.cdiv(valid_len - t * tile, block_size), 0, tile_blocks
+            pl.cdiv(valid_ref[of_row] - t * tile, block_size), 0, tile_blocks
         )
 
     def block_copies(block, i, slot):
@@ -230,14 +264,14 @@ def _paged_attention_kernel(
             for which, (pool, buf) in enumerate(pools)
         ]
 
-    def start_tile(t, slot):
+    def start_tile(t, slot, of_row=row):
         def one(i, carry):
-            block = tab_ref[row, t * tile_blocks + i]
+            block = tab_ref[of_row, t * tile_blocks + i]
             for copy in block_copies(block, i, slot):
                 copy.start()
             return carry
 
-        jax.lax.fori_loop(0, blocks_held(t), one, 0)
+        jax.lax.fori_loop(0, blocks_held(t, of_row), one, 0)
 
     def wait_tile(t, slot):
         def one(i, carry):
@@ -252,8 +286,10 @@ def _paged_attention_kernel(
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(num_tiles > 0)
+    @pl.when((num_tiles > 0) & (tiles_before == 0))
     def _first():
+        # only the call's first active row fetches its own first tile: every
+        # later one finds it in flight, started under the fold before it
         start_tile(0, 0)
 
     # operands narrower than float32 multiply exactly in one MXU pass; an
@@ -271,12 +307,22 @@ def _paged_attention_kernel(
         ) // group
 
         def one_tile(t, carry):
-            slot = t % 2
+            slot = (tiles_before + t) % 2
+            last = t + 1 == num_tiles
 
-            @pl.when(t + 1 < num_tiles)
+            # the next step of the call's flat (row, tile) list is in flight
+            # while this one is folded: the row's next tile, or the first
+            # tile of the next row that runs one. Two branches, not one with
+            # the row and tile selected: measured 1% faster a call
+            @pl.when(jnp.logical_not(last))
             def _prefetch():
                 start_tile(t + 1, 1 - slot)
 
+            @pl.when(last & (next_row >= 0))
+            def _prefetch_next_row():
+                start_tile(0, 1 - slot, next_row)
+
+            # the row's own count, whoever started the tile
             wait_tile(t, slot)
             kv_slot = t * tile + jax.lax.broadcasted_iota(
                 jnp.int32, (1, tile), 1
@@ -370,6 +416,23 @@ def paged_decode_attention(
     )
 
 
+def _pipeline_carry(valid_len: jax.Array, tile: int):
+    """What the DMA pipeline carries from one grid step to the next, both a
+    function of ``valid_len`` alone: per row the tiles of the rows before
+    it (their parity is the buffer the row's first tile lies in; 0: nobody
+    has fetched it, the row starts it itself) and the next row that runs a
+    tile at all (-1: none, the row's last tile prefetches nothing)."""
+    rows = valid_len.shape[0]
+    tiles = -(-valid_len // tile)
+    tiles_before = jnp.cumsum(tiles) - tiles
+    active = jnp.where(tiles > 0, jnp.arange(rows, dtype=jnp.int32), rows)
+    following = jnp.append(jax.lax.cummin(active, reverse=True)[1:], rows)
+    return (
+        tiles_before.astype(jnp.int32),
+        jnp.where(following == rows, -1, following).astype(jnp.int32),
+    )
+
+
 # jitted on its own: a model calls the kernel once a layer with the same
 # shapes, and tracing the body (two query paths, the DMA loops) is slow
 # Python — done once here, not once a layer, and lowered as one function
@@ -425,8 +488,13 @@ def _paged_call(
         pltpu.VMEM((n_kv, m_full, 1), jnp.float32),   # normalizer l
         pltpu.VMEM((n_kv, m_full, h), jnp.float32),   # unnormalized acc
     ]
+    # a row sees no slot past its table
+    valid_len = jnp.minimum(
+        valid_len.astype(jnp.int32), max_blocks * block_size
+    )
+    tiles_before, next_row = _pipeline_carry(valid_len, tile)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=5,
         grid=(rows,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, n_kv, m_full, h), _row),
@@ -441,13 +509,19 @@ def _paged_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(folded.shape, q.dtype),
+        # the rows run in order on one core: a row's first tile is started
+        # by the row before it
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
         interpret=interpret,
         name="paged_attention",  # the trace's and the HLO's name for it
     )(
         block_table.astype(jnp.int32),
-        # a row sees no slot past its table
-        jnp.minimum(valid_len.astype(jnp.int32), max_blocks * block_size),
+        valid_len,
         q_slot_base.astype(jnp.int32),
+        tiles_before,
+        next_row,
         *operands,
     )
     out = out.reshape(rows, n_kv, s_pad, group, h).transpose(0, 2, 1, 3, 4)

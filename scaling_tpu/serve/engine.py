@@ -57,6 +57,7 @@ from typing import Dict, List, NamedTuple, Optional, Tuple
 
 from .. import obs
 from ..logging import logger
+from ..nn.paged_attention import kernel_tile_tokens
 from ..resilience.faults import get_fault_plan
 from .kvcache import (
     PagedKVPools,
@@ -291,6 +292,13 @@ class ServeEngine:
 
         self._np = np
         self._jax = jax
+        # KV tokens a tile of the paged kernel holds, at a shard's heads
+        _, _, n_kv, head = self.pools.pool_k[0].shape
+        self._kv_tile = kernel_tile_tokens(
+            self.config.block_size, self.config.max_blocks_per_seq,
+            n_kv // self.model_parallel, head,
+            self.pools.pool_k[0].dtype.itemsize,
+        )
         n = self.config.num_slots
         # the ONE host operand of a tick and where its fields lie
         self._layout = TickLayout(n, self.config.max_blocks_per_seq)
@@ -826,7 +834,15 @@ class ServeEngine:
                 tick.topks[:], tick.reqids[:] = self._topk, self._reqid
                 packed = packed[:self._layout.size(width)]
             if not self.warmup_mode:  # mixed_span is a span
-                mixed_span.annotate(width=width, tokens=len(real))
+                # rows that hold a visible slot and the paged kernel's tiles
+                # among them: of a call's kv_tiles fetches, kv_rows - 1 are
+                # first tiles that start under another row's fold
+                held = ctx + new_lens
+                mixed_span.annotate(
+                    width=width, tokens=len(real),
+                    kv_rows=int(np.count_nonzero(held)),
+                    kv_tiles=int((-(-held // self._kv_tile)).sum()),
+                )
                 if self.loop_steps > 1:
                     mixed_span.annotate(loop_steps=self.loop_steps)
                     self._counter("serve_loop_layer_passes_total").inc(

@@ -106,17 +106,20 @@ def test_splash_survives_shard_map_on_2x2(topo):
     "s", [1, 32, 5], ids=["decode-s1", "prefill-chunk-s32", "spec-s5"]
 )
 @pytest.mark.parametrize(
-    "slots,q_heads,kv_heads",
-    [(SLOTS, Q_HEADS, KV_HEADS), (16, 32, 8), (16, 36, 4), (16, 16, 16)],
+    "slots,q_heads,kv_heads,max_blocks",
+    [(SLOTS, Q_HEADS, KV_HEADS, MAX_BLOCKS), (16, 32, 8, MAX_BLOCKS),
+     (16, 36, 4, MAX_BLOCKS), (16, 16, 16, MAX_BLOCKS), (16, 16, 16, 40)],
     ids=["smoke-16q4kv", "serve-cell-32q8kv", "pharia-36q4kv-group9",
-         "olmoe-16q16kv-group1"],
+         "olmoe-16q16kv-group1", "looped-cell-16q16kv-40blocks"],
 )
-def test_paged_kernel_compiles(one_chip, slots, q_heads, kv_heads, s, kv_dtype):
+def test_paged_kernel_compiles(
+    one_chip, slots, q_heads, kv_heads, max_blocks, s, kv_dtype
+):
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     quantized = kv_dtype == "int8"
-    pool_dims = (slots * MAX_BLOCKS + 1, BLOCK_SIZE, kv_heads, HEAD_DIM)
+    pool_dims = (slots * max_blocks + 1, BLOCK_SIZE, kv_heads, HEAD_DIM)
     pool = shape(pool_dims, jnp.int8 if quantized else jnp.bfloat16)
     scales = (
         {"scale_k": shape(pool_dims[:3], jnp.float32),
@@ -133,7 +136,7 @@ def test_paged_kernel_compiles(one_chip, slots, q_heads, kv_heads, s, kv_dtype):
 
     compiled = jax.jit(attend).lower(
         shape((slots, s, q_heads, HEAD_DIM), jnp.bfloat16), pool, pool,
-        shape((slots, MAX_BLOCKS), jnp.int32), shape((slots,), jnp.int32),
+        shape((slots, max_blocks), jnp.int32), shape((slots,), jnp.int32),
         shape((slots,), jnp.int32), scales,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()

@@ -374,3 +374,40 @@ def test_the_program_holds_one_layer_function_however_deep_the_stack():
             engine._base_key).as_text()
         dots.append(text.count("stablehlo.dot_general"))
     assert dots[0] == dots[1] > 0
+
+
+# ------------------- the rows and kernel tiles a tick's KV reads span (ISSUE 41)
+@pytest.fixture(scope="module")
+def tiled_ticks(models, tmp_path_factory):
+    """A prompt of 70 tokens streaming in beside a short request, the paged
+    kernel's tile shrunk to 32 tokens (2 blocks) so that rows grow from one
+    tile to three: the ``serve.mixed`` span fields of the first four ticks."""
+    from scaling_tpu.nn import paged_attention
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(paged_attention, "_TILE_TOKENS", 32)
+        engine = make_engine(models["dense"])
+        rng = np.random.default_rng(41)
+        obs.start_capture(tmp_path_factory.mktemp("tiled") / "trace")
+        try:
+            engine.submit(list(rng.integers(1, 60, 70)), 3)
+            engine.submit(list(rng.integers(1, 60, 5)), 6)
+            for _ in range(4):
+                engine.tick()
+        finally:
+            capture = obs.stop_capture()
+    return [f for name, _, _, f in capture.spans if name == "serve.mixed"]
+
+
+@pytest.mark.parametrize("tick,held", [
+    (0, (32, 5)),   # both first chunks: one tile each
+    (1, (64, 6)),   # the prompt's second chunk fills its second tile
+    (2, (70, 7)),   # its last chunk reaches into a third
+    (3, (71, 8)),   # both rows decode
+])
+def test_the_span_counts_the_rows_and_tiles_the_kernel_reads(
+    tiled_ticks, tick, held
+):
+    fields = tiled_ticks[tick]
+    assert (fields["kv_rows"], fields["kv_tiles"]) == (
+        len(held), sum(-(-h // 32) for h in held))

@@ -6,7 +6,10 @@ kernel, single decode tokens and multi-token prefill chunks, GQA
 repeat, and the all-trash inactive row. ISSUE 26 re-tiled the kernel
 (several pool blocks a tile, the GQA group folded into the matmul's
 rows, a short-query path); its cases force several tiles a row by
-shrinking the tile the shapes would derive."""
+shrinking the tile the shapes would derive. ISSUE 41 ran the kernel's
+double buffer across rows (a row's first tile is fetched under the last
+fold of the active row before it); its cases mix rows of 1, 2 and 3
+tiles with inactive rows anywhere, and permute the rows of a call."""
 
 import numpy as np
 import pytest
@@ -296,3 +299,107 @@ def test_blocks_per_tile_is_a_function_of_the_shapes(
 ):
     assert paged_attention._blocks_per_tile(
         block_size, max_blocks, n_kv, h, itemsize) == expect
+
+
+# ---- ISSUE 41: the double buffer runs over the call's flat list of (row,
+# tile) steps. Tiles of 8 tokens (2 blocks of 4), so contexts of 1-8 / 9-16
+# / 17-24 tokens are rows of 1 / 2 / 3 tiles; a row's first tile lies in
+# the buffer the parity of the tiles before it says, and is fetched by the
+# last active row before it.
+
+CROSS_ROW_CASES = {
+    # valid_len per row (0: an inactive row, all-trash table)
+    "1-2-3-tiles-alternate": [5, 12, 20, 7, 16, 24],
+    "inactive-rows-first": [0, 0, 6, 13, 19],
+    "inactive-rows-between": [7, 0, 18, 0, 0, 11, 3],
+    "inactive-rows-last": [17, 4, 9, 0, 0],
+    "a-single-active-row": [0, 0, 21, 0],
+    "only-the-first-row-active": [14, 0, 0],
+    "an-all-inactive-call": [0, 0, 0],
+    "one-tile-after-three": [23, 2, 8, 1],
+    "three-tiles-after-one": [3, 22, 6, 24],
+    "every-row-starts-in-the-second-buffer": [8, 16, 24, 8, 16],
+    "one-tile-rows-only": [1, 8, 4, 7, 2, 5],
+}
+
+
+def cross_row_case(valid, kv_dtype, seed=14):
+    """Decode rows (one real position, the context's last slot) over
+    contexts of ``valid`` tokens; returns the kernel's arguments, the pools
+    the reference reads and the rows' ``new_len``."""
+    rng = np.random.default_rng(seed)
+    valid = np.asarray(valid, np.int32)
+    new = (valid > 0).astype(np.int32)
+    q, pk, pv, tab, ctx, new = tiled_case(
+        rng, block_size=4, max_blocks=6, n_kv=2, group=2, s=1,
+        ctx=valid - new, new_len=new,
+    )
+    kwargs = {}
+    ref_k, ref_v = pk, pv
+    if kv_dtype == "int8":
+        pk, sk = kv_quantize_int8(pk)
+        pv, sv = kv_quantize_int8(pv)
+        kwargs = {"scale_k": sk, "scale_v": sv}
+        ref_k = pk.astype(jnp.float32) * sk[..., None]
+        ref_v = pv.astype(jnp.float32) * sv[..., None]
+    return (q, pk, pv, tab, ctx, new), kwargs, (ref_k, ref_v)
+
+
+@pytest.mark.parametrize("kv_dtype", ["native", "int8"])
+@pytest.mark.parametrize("case", list(CROSS_ROW_CASES))
+def test_the_pipeline_crosses_rows(monkeypatch, case, kv_dtype):
+    """Whatever rows lie between two active ones, and whichever buffer a
+    row starts in, every row reads its own tiles whole."""
+    monkeypatch.setattr(paged_attention, "_TILE_TOKENS", 8)
+    valid = CROSS_ROW_CASES[case]
+    (q, pk, pv, tab, ctx, new), kwargs, (ref_k, ref_v) = cross_row_case(
+        valid, kv_dtype)
+    tiles = [-(-v // 8) for v in valid]
+    assert max(tiles) <= 3 and tab.shape[1] * 4 == 24
+    out = check_rows(q, pk, pv, tab, ctx, new, 2, **kwargs)
+    ref = dense_reference(q, ref_k, ref_v, tab, ctx + new, ctx, 2)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref) * (np.asarray(new) > 0)[
+            :, None, None, None],
+        atol=1e-5,  # float32 pools: far inside chip_smoke's PAGED_RTOL
+    )
+    # an inactive row emits zeros
+    assert not bool(jnp.any(out[np.asarray(valid) == 0]))
+
+
+@pytest.mark.parametrize("kv_dtype", ["native", "int8"])
+@pytest.mark.parametrize("order", [
+    [5, 4, 3, 2, 1, 0], [1, 0, 3, 2, 5, 4], [2, 5, 0, 3, 1, 4],
+], ids=["reversed", "swapped-pairs", "shuffled"])
+def test_permuting_the_rows_permutes_the_output_bit_for_bit(
+    monkeypatch, order, kv_dtype
+):
+    """A row's output is a function of the row alone: what another row left
+    in a buffer, or a tile that had not landed, would show as a difference
+    once the rows change places (and with them their starting buffers and
+    who fetches whose first tile)."""
+    monkeypatch.setattr(paged_attention, "_TILE_TOKENS", 8)
+    (q, pk, pv, tab, ctx, new), kwargs, _ = cross_row_case(
+        [20, 0, 6, 13, 24, 2], kv_dtype, seed=15)
+    out = check_rows(q, pk, pv, tab, ctx, new, 2, **kwargs)
+    order = np.asarray(order)
+    moved = check_rows(
+        q[order], pk, pv, tab[order], ctx[order], new[order], 2, **kwargs)
+    np.testing.assert_array_equal(np.asarray(moved), np.asarray(out)[order])
+
+
+@pytest.mark.parametrize("valid,before,following", [
+    ([5, 12, 20, 7], [0, 1, 3, 6], [1, 2, 3, -1]),
+    ([0, 0, 6, 13], [0, 0, 0, 1], [2, 2, 3, -1]),
+    ([7, 0, 18, 0], [0, 1, 1, 4], [2, 2, -1, -1]),
+    ([0, 0, 0], [0, 0, 0], [-1, -1, -1]),
+    ([30, 9], [0, 3], [1, -1]),  # clipped to the table's 24 slots
+], ids=["all-active", "inactive-first", "inactive-between-and-last",
+        "all-inactive", "past-the-table"])
+def test_what_crosses_a_grid_step_is_a_function_of_valid_len(
+    valid, before, following
+):
+    got_before, got_next = paged_attention._pipeline_carry(
+        jnp.minimum(jnp.asarray(valid, jnp.int32), 24), 8)
+    assert got_before.tolist() == before
+    assert got_next.tolist() == following
