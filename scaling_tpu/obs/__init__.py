@@ -5,8 +5,11 @@
 - :mod:`.spans` — ``with obs.span("ckpt.commit", step=N)`` phase
   tracing, emitted through ``logger.log_event`` into the same stream as
   the supervision events.
+- :mod:`.recorder` — the bounded ring every closed span lands in,
+  exactly, capture or not: ``recorded_spans`` / ``record_span``.
 - :mod:`.capture` — the one start/stop control for all tracing of a
-  running process: the profiler, and the spans' sink on its clock.
+  running process: the profiler, the spans' annotations on its clock,
+  and the recorder's rows between its two markers.
 - :mod:`.hardware` — device memory / live-array gauges, step-time EMA,
   achieved-TFLOPs and MFU math.
 - :mod:`.telemetry` — the per-step driver the trainer owns.
@@ -33,6 +36,7 @@ from .hardware import (
     mfu,
     update_hardware_gauges,
 )
+from .recorder import Row, record_span, recorded_spans, recorded_tail
 from .registry import (
     Counter,
     Gauge,
@@ -59,6 +63,7 @@ __all__ = [
     "Gauge",
     "Histogram",
     "MetricsRegistry",
+    "Row",
     "Span",
     "StepTelemetry",
     "StepTimeEMA",
@@ -76,6 +81,9 @@ __all__ = [
     "last_capture",
     "mfu",
     "new_trace_id",
+    "record_span",
+    "recorded_spans",
+    "recorded_tail",
     "span",
     "start_capture",
     "stop_capture",

@@ -13,22 +13,32 @@ While a capture is on, every :func:`obs.span` also opens a
 ``jax.profiler.TraceAnnotation`` of its name. That puts it on the host
 plane of the ``.xplane.pb`` (the line of the thread that ran it), on the
 clock the device's ``XLA Ops`` are on, so an idle gap of the chip can be
-laid beside the phase of the host it fell into. The span is also kept in
-the capture's own list, exactly (the ``span_seconds`` histogram is
-bucketed: no median can be read back from it). While none is on a span
-does neither and pays one global read.
+laid beside the phase of the host it fell into. While none is on a span
+does not and pays one global read.
+
+A capture keeps no spans of its own. Every closed span lands in the
+process's bounded recorder (``obs/recorder.py``) whether a capture is on
+or not; ``start_capture`` and ``stop_capture`` each append a marker row
+there (``obs.capture``, with the trace dir and its ``edge``), and
+:attr:`Capture.spans` is the recorder's rows between the two.
+
+The profiler runs with the interpreter's tracer OFF
+(``python_tracer_level`` 0) unless ``python_frames=True``: that tracer
+hooks every Python call, and on a serving tick's host code it made the
+scheduler read 18 times what it costs (PERF.md, Findings PR 42). The
+host tracer stays on, so the spans' annotations and the runtime's own
+``PjitFunction(...)`` events still lie on the thread's line.
 
 jax is imported lazily, as everywhere in ``obs``.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import threading
-import time
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Tuple
 
+from .recorder import CAPTURE_MARKER, _recorder, clock
 from .registry import MetricsRegistry, get_registry
 
 # (name, start_ns, duration_ns, fields): start_ns counts from the start
@@ -39,14 +49,29 @@ from .registry import MetricsRegistry, get_registry
 SpanRow = Tuple[str, int, int, Dict[str, Any]]
 
 
-@dataclasses.dataclass
 class Capture:
     """What one capture held."""
 
-    trace_dir: str              # where the profiler wrote (plugins/profile/...)
-    seconds: float              # start_capture's return to stop_capture's call
-    spans: List[SpanRow]        # every span opened and closed inside, in closing order
-    counters: Dict[str, float]  # registry counters that moved: rendered name -> difference
+    def __init__(self, trace_dir: str, seconds: float,
+                 counters: Dict[str, float],
+                 spans: Optional[List[SpanRow]] = None,
+                 markers: Optional[Tuple[tuple, tuple]] = None):
+        self.trace_dir = trace_dir  # where the profiler wrote (plugins/profile/...)
+        self.seconds = seconds      # start_capture's return to stop_capture's call
+        self.counters = counters    # registry counters that moved: rendered name -> difference
+        # a capture of THIS process is its two marker rows in the
+        # recorder; ``spans=`` is for one rebuilt from a file (a test's
+        # recorded capture), which has no recorder to cut from
+        self._markers, self._given = markers, spans
+
+    @property
+    def spans(self) -> List[SpanRow]:
+        """Every span opened and closed inside, in closing order: cut
+        from the recorder on each read, so read before ``RING_ROWS``
+        more spans have closed."""
+        if self._markers is None:
+            return self._given if self._given is not None else []
+        return _recorder.between(*self._markers)
 
     def trace_file(self) -> Optional[Path]:
         """The newest ``.xplane.pb`` under ``trace_dir``."""
@@ -55,36 +80,25 @@ class Capture:
 
 
 class _Active:
-    """A capture in progress: what a span needs at entry and exit."""
+    """A capture in progress: what a span needs at entry."""
 
-    __slots__ = ("trace_dir", "origin", "started", "spans",
-                 "counters_before", "registry", "annotation")
+    __slots__ = ("trace_dir", "started", "marker", "counters_before",
+                 "registry", "annotation")
 
     def __init__(self, trace_dir: str, registry: MetricsRegistry, annotation):
         self.trace_dir = trace_dir
         self.registry = registry
         self.annotation = annotation  # jax.profiler.TraceAnnotation
-        self.spans: List[SpanRow] = []
         self.counters_before = registry.snapshot()["counters"]
-        self.origin = time.perf_counter()
-        self.started = 0.0
+        self.started = 0.0       # start_trace's return, on the recorder's clock
+        self.marker: tuple = ()  # the start marker's row
 
-    def close_span(self, sp, parent: Optional[str], step: Optional[int],
-                   start: float, duration: float) -> None:
-        """Keep a span that was opened under this capture, if it is
-        still on (``start``: the span's ``time.perf_counter()``)."""
-        if _active is not self:
-            return
-        fields = {k: v for k, v in sp.fields.items()
-                  if isinstance(v, (bool, int, float, str))
-                  or (isinstance(v, list) and v
-                      and all(isinstance(x, (int, float)) for x in v))}
-        if step is not None:
-            fields["step"] = step
-        if parent is not None:
-            fields["parent"] = parent
-        self.spans.append((sp.name, int((start - self.origin) * 1e9),
-                           int(duration * 1e9), fields))
+
+def _marker(trace_dir: str, edge: str, start: float, duration: float) -> tuple:
+    row = (CAPTURE_MARKER, start, duration, None, None,
+           {"trace_dir": trace_dir, "edge": edge})
+    _recorder.append(row)
+    return row
 
 
 _lock = threading.Lock()  # start/stop only; a span reads _active without it
@@ -106,9 +120,13 @@ def last_capture() -> Optional[Capture]:
     return _last
 
 
-def start_capture(out_dir, registry: Optional[MetricsRegistry] = None) -> None:
+def start_capture(out_dir, registry: Optional[MetricsRegistry] = None,
+                  python_frames: bool = False) -> None:
     """Start the profiler into ``out_dir`` (created if missing) and turn
-    the spans' third sink on. One capture at a time."""
+    the spans' annotations on. One capture at a time. ``python_frames``
+    turns the interpreter's tracer on as well: every Python call becomes
+    an event of the host line, for someone who hunts a frame by hand, at
+    several times the host's cost (docs/OBSERVABILITY.md says when)."""
     global _active
     import jax
 
@@ -121,8 +139,15 @@ def start_capture(out_dir, registry: Optional[MetricsRegistry] = None) -> None:
         cap = _Active(str(out_dir),
                       registry if registry is not None else get_registry(),
                       jax.profiler.TraceAnnotation)
-        jax.profiler.start_trace(str(out_dir))
-        cap.started = time.perf_counter()
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 1 if python_frames else 0
+        origin = clock()
+        jax.profiler.start_trace(str(out_dir), profiler_options=options)
+        cap.started = clock()
+        # start = the origin, duration = what start_trace took: a span
+        # opened before its end was opened with no capture on
+        cap.marker = _marker(cap.trace_dir, "start", origin,
+                             cap.started - origin)
         _active = cap
 
 
@@ -138,12 +163,13 @@ def stop_capture() -> Capture:
         cap = _active
         if cap is None:
             raise RuntimeError("stop_capture: no capture is on")
-        seconds = time.perf_counter() - cap.started
+        now = clock()
         _active = None
+        markers = (cap.marker, _marker(cap.trace_dir, "stop", now, 0.0))
         after = cap.registry.snapshot()["counters"]
         moved = {k: v - cap.counters_before.get(k, 0.0) for k, v in after.items()
                  if v != cap.counters_before.get(k, 0.0)}
-        _last = Capture(trace_dir=cap.trace_dir, seconds=seconds,
-                        spans=cap.spans, counters=moved)
+        _last = Capture(trace_dir=cap.trace_dir, seconds=now - cap.started,
+                        counters=moved, markers=markers)
         jax.profiler.stop_trace()
         return _last

@@ -162,6 +162,9 @@ class MetricsRegistry:
         # which names already warned (once per name, not per call)
         self._series_per_name: Dict[str, int] = {}
         self._overflow_warned: set = set()
+        # span name -> its ``span_seconds`` histogram, kept by
+        # ``obs.span`` so that a span pays no lookup after its first
+        self.span_handles: Dict[str, Histogram] = {}
 
     def configure(self, *, metrics_path: Optional[str] = None,
                   textfile_path: Optional[str] = None) -> None:
@@ -323,6 +326,7 @@ class MetricsRegistry:
             self._metrics.clear()
             self._series_per_name.clear()
             self._overflow_warned.clear()
+            self.span_handles.clear()
 
 
 def _json_safe(obj):

@@ -1,7 +1,11 @@
 """Phase tracing: ``with obs.span("ckpt.commit", step=N): ...``.
 
-Every span lands twice, and a third time while a capture is on:
+Every span lands three times, and a fourth while a capture is on:
 
+- as an exact row ``(name, start_ns, duration_ns, step, parent,
+  fields)`` of the process's bounded recorder (``obs/recorder.py``),
+  appended as the span closes: what a median, a window's cut or a
+  capture's ``spans`` is read from, capture or not;
 - as an observation in the default registry's ``span_seconds`` histogram
   (labelled by span name) — cheap, in-memory, flushed with the per-step
   registry snapshot;
@@ -14,8 +18,8 @@ Every span lands twice, and a third time while a capture is on:
   mirrors at the span's level;
 - while :func:`obs.start_capture` is on (``obs/capture.py``), as a
   ``jax.profiler.TraceAnnotation`` of its name, which puts it on the
-  host plane of the profiler's trace on the device's clock, and as an
-  exact row of the capture's list. With no capture it does neither.
+  host plane of the profiler's trace on the device's clock. With no
+  capture it does not.
 
 Spans nest (thread-local stack; the parent's name is recorded on the
 child) and are exception-safe: a body that raises still emits the span,
@@ -48,7 +52,6 @@ from __future__ import annotations
 import hashlib
 import os
 import threading
-import time
 import uuid
 from contextlib import contextmanager
 from typing import Any, Iterator, Optional
@@ -56,7 +59,14 @@ from typing import Any, Iterator, Optional
 from ..logging import logger
 from ..logging.logger import set_trace_provider
 from . import capture as _capture
-from .registry import get_registry
+from .recorder import _recorder, clock as _clock
+from .registry import DEFAULT_BUCKETS, OVERFLOW_LABELS, get_registry
+
+# ``span_seconds`` alone resolves a serving tick's phases (schedule,
+# build, dispatch, emit, retire: 0.02-1 ms); every other histogram keeps
+# ``DEFAULT_BUCKETS``, whose first bound is 1 ms
+SPAN_BUCKETS = (1e-5, 5e-5, 1e-4, 5e-4) + DEFAULT_BUCKETS
+_record = _recorder.append
 
 _local = threading.local()
 
@@ -194,7 +204,7 @@ class Span:
         if cap is not None:
             self._annotation = cap.annotation(self.name)
             self._annotation.__enter__()
-        self._start = time.perf_counter()
+        self._start = _clock()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
@@ -211,12 +221,11 @@ class Span:
             error = type(e).__name__
             raise
         finally:
-            duration = time.perf_counter() - self._start
-            cap = self._capture
-            if cap is not None:
+            duration = _clock() - self._start
+            if self._capture is not None:
                 self._annotation.__exit__(None, None, None)
-                cap.close_span(self, self._parent, self._step, self._start,
-                               duration)
+            _record((self.name, self._start, duration, self._step,
+                     self._parent, self.fields))
             self.duration_s = duration
             _stack().pop()
             _emit(self, self._parent, duration, error is None, error,
@@ -242,7 +251,15 @@ def _emit(sp: Span, parent: Optional[str], duration: float, ok: bool,
           error: Optional[str], step: Optional[int], level: str,
           registry) -> None:
     reg = registry if registry is not None else get_registry()
-    reg.histogram("span_seconds", labels={"span": sp.name}).observe(duration)
+    # the handle is kept per (registry, span name): a lookup through
+    # the registry's lock on every span was most of what a span cost
+    hist = reg.span_handles.get(sp.name)
+    if hist is None:
+        hist = reg.histogram("span_seconds", labels={"span": sp.name},
+                             buckets=SPAN_BUCKETS)
+        if hist.labels != OVERFLOW_LABELS:  # a leaking name must not grow the dict
+            reg.span_handles[sp.name] = hist
+    hist.observe(duration)
     if not logger.takes_events(level):
         return  # no events file and no mirror at this level: nobody reads it
     event_fields = dict(sp.fields)

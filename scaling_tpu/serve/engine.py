@@ -52,6 +52,7 @@ sampled rows, including mid-speculation.
 from __future__ import annotations
 
 import dataclasses
+import statistics
 import time
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
@@ -81,6 +82,8 @@ LANES = 128
 # prompts streaming a chunk each that the small token bucket has room for
 # beside a decode row in every slot (EngineConfig.mixed_widths)
 SMALL_BUCKET_CHUNKS = 3
+# ticks whose spans ``stats_snapshot()["tick_phases_ms"]`` takes its medians over
+TICK_PHASES_TICKS = 512
 
 
 def packed_batch_shape(width: int, row_width: int) -> Tuple[int, int]:
@@ -361,6 +364,9 @@ class ServeEngine:
             if self.replica_id is not None else {}
         )
         self._prefix_hits_flushed = 0  # scheduler counter already mirrored
+        self._evict_flushed = (0.0, 0)  # scheduler's eviction totals, likewise
+        # spans the recorder holds from before this are another engine's
+        self._created_ns = time.monotonic_ns()
         self.prefilled_tokens = 0  # prompt tokens actually prefilled
         self.spec_drafted_tokens = 0
         self.spec_accepted_tokens = 0
@@ -975,9 +981,16 @@ class ServeEngine:
         if seq.first_token_s is None:
             seq.first_token_s = now
             if not self.warmup_mode:
-                self._histogram("serve_ttft_seconds").observe(
-                    now - seq.request.arrival_s
-                )
+                arrival = seq.request.arrival_s
+                self._histogram("serve_ttft_seconds").observe(now - arrival)
+                # one row a request, no span: arrival to first token,
+                # and how much of it was the wait for a slot. The stamps
+                # are on time.monotonic(), the recorder's clock.
+                obs.record_span(
+                    "serve.first_token", arrival, now - arrival,
+                    queue_s=seq.admitted_s - arrival,
+                    prompt_tokens=len(seq.request.prompt),
+                    req=seq.request.req_id, **self._replica_fields)
         elif seq.token_stamps and not self.warmup_mode:
             self._histogram("serve_itl_seconds").observe(
                 now - seq.token_stamps[-1]
@@ -1085,8 +1098,8 @@ class ServeEngine:
         step = self.tick_index
         with self._span("serve.tick", step=step,
                         **self._replica_fields) as tick_span:
-            with self._span("serve.schedule", step=step):
-                t = self._schedule_tick(step)
+            with self._span("serve.schedule", step=step) as sched_span:
+                t = self._schedule_tick(step, sched_span)
             if tick_span is not None:
                 tick_span.annotate(decodes=len(t.decodes),
                                    chunks=len(t.prefills))
@@ -1096,8 +1109,11 @@ class ServeEngine:
                 self._retire_tick(t)
         return t
 
-    def _schedule_tick(self, step: int) -> Tick:
-        """Everything a tick decides before its programs run."""
+    def _schedule_tick(self, step: int, sched_span=None) -> Tick:
+        """Everything a tick decides before its programs run. A tick
+        whose allocations ran the prefix cache's LRU eviction says so on
+        ``sched_span`` (``serve.schedule``): ``evict_ms`` inside
+        ``PrefixCache.evict`` and the blocks ``evicted``."""
         self._expire_deadlines(time.monotonic())
         if self.config.spec_k > 0:
             with self._span("serve.draft", step=step):
@@ -1118,6 +1134,13 @@ class ServeEngine:
                             **self._trace_fields(t.preempted)):
                 pass
         sched = self.scheduler
+        evict_s, evicted = self._evict_flushed
+        if sched.evict_seconds != evict_s:
+            self._evict_flushed = (sched.evict_seconds, sched.evicted_blocks)
+            if sched_span is not None:  # not warming up
+                sched_span.annotate(
+                    evict_ms=round(1e3 * (sched.evict_seconds - evict_s), 6),
+                    evicted=sched.evicted_blocks - evicted)
         if sched.prefix_hit_tokens > self._prefix_hits_flushed:
             self._counter("serve_prefix_hit_tokens_total").inc(
                 sched.prefix_hit_tokens - self._prefix_hits_flushed
@@ -1228,7 +1251,31 @@ class ServeEngine:
             # steps x layers) and the bytes the pools really hold
             "kv_lines": self.pools.kv_lines,
             "kv_pool_bytes": self.pools.device_bytes(),
+            # median ms of each serve.* span over this engine's last
+            # TICK_PHASES_TICKS ticks, read from the span recorder on
+            # the call ({} before the first counted tick)
+            "tick_phases_ms": self.tick_phases_ms(),
         }
+
+    def tick_phases_ms(self) -> Dict[str, float]:
+        """Where a tick's time goes, by phase: the median duration of
+        each ``serve.*`` span that lies inside one of this engine's last
+        ``TICK_PHASES_TICKS`` ``serve.tick`` spans (same ``step``, inside
+        it in time: an in-process fleet shares one recorder and its
+        replicas' steps collide). Nothing is kept per tick for this."""
+        rows = obs.recorded_tail("serve.tick", TICK_PHASES_TICKS)
+        ticks = {r.step: (r.start_ns, r.start_ns + r.duration_ns)
+                 for r in rows if r.name == "serve.tick"
+                 and r.start_ns >= self._created_ns
+                 and r.fields.get("replica") == self.replica_id}
+        by_name: Dict[str, List[int]] = {}
+        for r in rows:
+            start, end = ticks.get(r.step, (1, 0))
+            if (r.name.startswith("serve.") and start <= r.start_ns
+                    and r.start_ns + r.duration_ns <= end):
+                by_name.setdefault(r.name, []).append(r.duration_ns)
+        return {name: round(statistics.median(d) / 1e6, 6)
+                for name, d in sorted(by_name.items())}
 
     def run_until_done(self, max_ticks: int = 100_000) -> List[Sequence]:
         """Drain every submitted request; returns finished sequences in

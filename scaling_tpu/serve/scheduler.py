@@ -522,6 +522,11 @@ class ContinuousBatchingScheduler:
         self._free_slots: Deque[int] = deque(range(config.num_slots))
         self.preemption_count = 0
         self.prefix_hit_tokens = 0  # prefill tokens skipped via the trie
+        # LRU eviction under pool pressure, running totals: seconds
+        # inside ``PrefixCache.evict`` and blocks it freed (the engine
+        # puts each tick's share on its ``serve.schedule`` span)
+        self.evict_seconds = 0.0
+        self.evicted_blocks = 0
         # overload shedding hysteresis: True from the first admission
         # rejected above the high watermark until pressure falls to the
         # low watermark (admission must not flap at the boundary)
@@ -643,7 +648,9 @@ class ContinuousBatchingScheduler:
         get_fault_plan().fire("serve.pool")
         short = n - self.allocator.free_blocks
         if short > 0 and self.prefix_cache is not None:
-            self.prefix_cache.evict(short)
+            t = time.monotonic()
+            self.evicted_blocks += self.prefix_cache.evict(short)
+            self.evict_seconds += time.monotonic() - t
         return self.allocator.alloc(n)
 
     # -------------------------------------------------- speculative drafts

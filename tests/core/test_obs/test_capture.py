@@ -1,13 +1,13 @@
 """The one start/stop control for tracing (obs/capture.py): captures can
-be taken more than once in a process, the spans' third sink is on exactly
-while one is, and a span with no sink builds no event record."""
+be taken more than once in a process, the spans' annotations are on exactly
+while one is, the interpreter's tracer only when asked for, and a span with
+no sink builds no event record."""
 
 import json
 
 import pytest
 
 from scaling_tpu import obs
-from scaling_tpu.obs import capture as capture_module
 from scaling_tpu.obs import spans as spans_module
 from scaling_tpu.obs.registry import MetricsRegistry
 
@@ -103,19 +103,25 @@ def test_stop_is_safe_in_a_finally_when_the_profiler_fails(tmp_path, monkeypatch
 
 def test_without_a_capture_a_span_annotates_nothing_and_keeps_nothing(monkeypatch):
     """Booby-trap, as test_step_path.py does for syncs: with no capture
-    on, a span must not touch the profiler nor the capture's list."""
+    on, a span must not touch the profiler; it lands in the recorder (and
+    the histogram) alone, and no capture holds it."""
     import jax
 
     def boom(*a, **k):  # pragma: no cover - firing IS the failure
         raise AssertionError("a span reached the profiler with no capture on")
 
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
-    monkeypatch.setattr(capture_module._Active, "close_span", boom)
+    last = obs.last_capture()
+    held = len(last.spans) if last else 0
     reg = MetricsRegistry()
     with obs.span("serve.tick", step=1, registry=reg) as sp:
         with obs.span("serve.schedule", registry=reg):
             pass
     assert sp.duration_s is not None
+    assert [r.name for r in obs.recorded_spans()[-2:]] == [
+        "serve.schedule", "serve.tick"]
+    assert obs.last_capture() is last and (
+        last is None or len(last.spans) == held)
     assert reg.snapshot()["histograms"]["span_seconds{span=serve.tick}"]["count"] == 1
 
 
@@ -126,6 +132,28 @@ def test_a_span_opened_before_the_capture_is_not_kept(tmp_path):
             pass
     rec = obs.stop_capture()
     assert [s[0] for s in rec.spans] == ["inside"]
+
+
+@pytest.mark.parametrize("python_frames, level", [(None, 0), (False, 0), (True, 1)])
+def test_the_interpreters_tracer_is_on_only_when_asked_for(
+        python_frames, level, tmp_path, monkeypatch):
+    """``start_capture`` hands the profiler its options: the host tracer
+    as it comes (the spans' annotations), the interpreter's tracer off
+    unless ``python_frames=True``. The profiler is stubbed."""
+    import jax
+
+    seen = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda log_dir, **kw: seen.append((log_dir, kw)))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    kwargs = {} if python_frames is None else {"python_frames": python_frames}
+    obs.start_capture(tmp_path / "t", **kwargs)
+    obs.stop_capture()
+    (log_dir, kw), = seen
+    assert log_dir == str(tmp_path / "t") and set(kw) == {"profiler_options"}
+    options = kw["profiler_options"]
+    assert options.python_tracer_level == level
+    assert options.host_tracer_level == jax.profiler.ProfileOptions().host_tracer_level == 2
 
 
 EVENT_CASES = {
@@ -143,8 +171,7 @@ def test_event_record_is_byte_for_byte_what_it_was(case, tmp_path, monkeypatch):
     path = tmp_path / "events.jsonl"
     monkeypatch.setenv("SCALING_TPU_EVENTS_PATH", str(path))
     monkeypatch.setenv("SCALING_TPU_HOST_ID", "3")
-    monkeypatch.setattr(spans_module.time, "perf_counter",
-                        iter([10.0, 10.25]).__next__)
+    monkeypatch.setattr(spans_module, "_clock", iter([10.0, 10.25]).__next__)
     monkeypatch.setattr("time.time", lambda: 1234.5)
     monkeypatch.setattr(spans_module, "new_span_id", lambda: "abcd1234")
     with obs.trace_context(trace_id):

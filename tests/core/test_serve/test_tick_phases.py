@@ -1,8 +1,10 @@
 """The engine's tick by phase (ISSUE 25): every phase a span at the place
 of the work, ``serve.tick`` opened by ``tick()`` itself and so exactly
 once a tick whoever drives the engine, all of them on the profiler's
-host plane while a capture is on; and the scheduler's ``admitted_s``
-stamp behind ``queue_wait_s``."""
+host plane while a capture is on; the scheduler's ``admitted_s``
+stamp behind ``queue_wait_s``; and what ISSUE 42 records where the work
+happens: the eviction's cost on ``serve.schedule``, one
+``serve.first_token`` row a request, ``tick_phases_ms``."""
 
 import json
 import time
@@ -68,9 +70,12 @@ def test_under_a_capture_every_tick_lies_on_the_host_plane_by_phase(
     assert ticks > 4
     # the capture's list: one tick after the other, each phase once, in
     # the order the spans close, all of one tick under its step
-    assert [s[0] for s in rec.spans] == list(PHASES) * ticks
+    # (a request's serve.first_token row is no phase of a tick: below)
+    spans = [s for s in rec.spans if s[0] != "serve.first_token"]
+    assert len(rec.spans) - len(spans) == 3
+    assert [s[0] for s in spans] == list(PHASES) * ticks
     for i in range(ticks):
-        row = {s[0]: s for s in rec.spans[i * len(PHASES):(i + 1) * len(PHASES)]}
+        row = {s[0]: s for s in spans[i * len(PHASES):(i + 1) * len(PHASES)]}
         assert {s[3]["step"] for s in row.values()} == {first_tick + i}
         tick = row["serve.tick"]
         assert tick[3]["decodes"] + tick[3]["chunks"] > 0
@@ -200,3 +205,122 @@ def test_a_request_that_waited_for_a_slot_carries_its_queue_wait(
     inferred = old["aaaa000000000002"]["phases"]["queue_wait"]
     assert inferred > 0 and inferred != \
         by_req[second.request.req_id]["queue_wait_s"]
+
+
+def test_eviction_is_on_the_schedule_span_only_in_the_ticks_that_evict(
+        toy_inference):
+    """A pool of 7 blocks, prompts of two whole blocks each: the first
+    requests leave their prompt blocks in the prefix cache, a later one
+    finds the free list short and the scheduler's one call of
+    ``PrefixCache.evict`` runs. That tick's ``serve.schedule`` row carries
+    ``evict_ms`` and ``evicted``; the others carry neither, and paid no
+    clock read for it."""
+    e = make_engine(toy_inference, num_slots=1, num_blocks=8,
+                    max_blocks_per_seq=4)
+    since = time.monotonic_ns()
+    prompts = [[10 * i + j for j in range(8)] for i in range(1, 5)]
+    for p in prompts:
+        e.submit(p, 3)
+        e.run_until_done()
+    sched = e.scheduler
+    assert sched.evicted_blocks > 0 and sched.evict_seconds > 0
+    rows = obs.recorded_spans(since_ns=since, name="serve.schedule")
+    assert len(rows) == e.tick_index
+    evicting = [r for r in rows if "evict_ms" in r.fields]
+    assert evicting and len(evicting) < len(rows)
+    assert all("evicted" not in r.fields for r in rows if r not in evicting)
+    assert sum(r.fields["evicted"] for r in evicting) == sched.evicted_blocks
+    assert sum(r.fields["evict_ms"] for r in evicting) == pytest.approx(
+        1e3 * sched.evict_seconds, abs=1e-5 * len(evicting))
+    # the eviction lies inside the span that reports it
+    assert all(0 < r.fields["evict_ms"] <= r.duration_ns / 1e6 for r in evicting)
+
+
+def test_no_pressure_no_eviction_no_field_no_clock_read(toy_inference, monkeypatch):
+    from scaling_tpu.serve import scheduler as scheduler_module
+
+    e = make_engine(toy_inference)
+    calls = []
+    real = scheduler_module.PrefixCache.evict
+    monkeypatch.setattr(scheduler_module.PrefixCache, "evict",
+                        lambda self, n: calls.append(n) or real(self, n))
+    since = time.monotonic_ns()
+    for p in PROMPTS:
+        e.submit(p, 3)
+    e.run_until_done()
+    assert calls == [] and e.scheduler.evict_seconds == 0.0
+    rows = obs.recorded_spans(since_ns=since, name="serve.schedule")
+    assert len(rows) == e.tick_index
+    assert not any("evict_ms" in r.fields or "evicted" in r.fields for r in rows)
+
+
+def test_one_first_token_row_a_request_none_in_warm_up(toy_inference):
+    """One slot, three requests: each leaves one ``serve.first_token`` row
+    when its first token is stamped, from its arrival, as long as its time
+    to first token, with the queue wait that ``serve_queue_wait_seconds``
+    observed. Warm-up traffic leaves none."""
+    e = make_engine(toy_inference, num_slots=1)
+    since = time.monotonic_ns()
+    e.warmup_mode = True
+    e.submit([1, 2], 2)
+    e.run_until_done()
+    e.warmup_mode = False
+    assert obs.recorded_spans(since_ns=since, name="serve.first_token") == []
+
+    def hist(name):
+        return obs.get_registry().snapshot()["histograms"].get(
+            name, {"count": 0, "sum": 0.0})
+
+    before = hist("serve_queue_wait_seconds")
+    arrival = time.monotonic()
+    seqs = [e.submit(p, 3, arrival_s=arrival) for p in PROMPTS[:3]]
+    e.run_until_done()
+    rows = obs.recorded_spans(since_ns=since, name="serve.first_token")
+    assert [r.fields["req"] for r in rows] == [s.request.req_id for s in seqs]
+    for r, seq in zip(rows, seqs):
+        assert r.start_ns == round(arrival * 1e9) and r.step is None
+        assert r.duration_ns == round((seq.first_token_s - arrival) * 1e9)
+        assert r.fields == {
+            "queue_s": seq.admitted_s - arrival,
+            "prompt_tokens": len(seq.request.prompt),
+            "req": seq.request.req_id}
+        assert 0 <= r.fields["queue_s"] <= r.duration_ns / 1e9
+    after = hist("serve_queue_wait_seconds")
+    assert after["count"] - before["count"] == 3
+    assert sum(r.fields["queue_s"] for r in rows) == pytest.approx(
+        after["sum"] - before["sum"])
+    # the later ones waited for the slot: their time to first token is queue
+    assert rows[2].fields["queue_s"] > rows[1].fields["queue_s"] > \
+        rows[0].fields["queue_s"]
+
+
+def test_tick_phases_ms_is_the_median_of_each_phase_over_the_last_ticks(
+        toy_inference, monkeypatch):
+    from statistics import median
+
+    from scaling_tpu.serve import engine as engine_module
+
+    e = make_engine(toy_inference)
+    assert e.stats_snapshot()["tick_phases_ms"] == {}  # no tick yet
+    since = time.monotonic_ns()
+    for p in PROMPTS:
+        e.submit(p, 4)
+    e.run_until_done()
+    phases = e.stats_snapshot()["tick_phases_ms"]
+    assert sorted(phases) == sorted(PHASES)
+    json.dumps(phases)  # the replica's stats RPC carries it
+    rows = obs.recorded_spans(since_ns=since)
+    for name in PHASES:
+        mine = [r.duration_ns for r in rows if r.name == name]
+        assert len(mine) == e.tick_index
+        assert phases[name] == pytest.approx(median(mine) / 1e6, abs=1e-6)
+    assert phases["serve.tick"] >= phases["serve.mixed"] >= phases["serve.mixed.wait"]
+    # over the LAST ticks only: with room for 3, the first ticks fall out
+    monkeypatch.setattr(engine_module, "TICK_PHASES_TICKS", 3)
+    last = [r.duration_ns for r in rows if r.name == "serve.tick"][-3:]
+    assert e.stats_snapshot()["tick_phases_ms"]["serve.tick"] == pytest.approx(
+        median(last) / 1e6, abs=1e-6)
+    # another engine of the process (an in-process fleet's other replica)
+    # reads its own ticks, not these
+    other = make_engine(toy_inference, replica_id=7)
+    assert other.stats_snapshot()["tick_phases_ms"] == {}
