@@ -364,7 +364,9 @@ class ServeEngine:
             if self.replica_id is not None else {}
         )
         self._prefix_hits_flushed = 0  # scheduler counter already mirrored
-        self._evict_flushed = (0.0, 0)  # scheduler's eviction totals, likewise
+        # the scheduler's eviction totals, likewise: seconds, blocks, and
+        # the stale heap entries the cache skipped
+        self._evict_flushed = (0.0, 0, 0)
         # spans the recorder holds from before this are another engine's
         self._created_ns = time.monotonic_ns()
         self.prefilled_tokens = 0  # prompt tokens actually prefilled
@@ -1113,7 +1115,8 @@ class ServeEngine:
         """Everything a tick decides before its programs run. A tick
         whose allocations ran the prefix cache's LRU eviction says so on
         ``sched_span`` (``serve.schedule``): ``evict_ms`` inside
-        ``PrefixCache.evict`` and the blocks ``evicted``."""
+        ``PrefixCache.evict``, the blocks ``evicted`` and the stale
+        entries of its LRU heap it skipped on the way, ``evict_stale``."""
         self._expire_deadlines(time.monotonic())
         if self.config.spec_k > 0:
             with self._span("serve.draft", step=step):
@@ -1134,13 +1137,15 @@ class ServeEngine:
                             **self._trace_fields(t.preempted)):
                 pass
         sched = self.scheduler
-        evict_s, evicted = self._evict_flushed
+        evict_s, evicted, stale = self._evict_flushed
         if sched.evict_seconds != evict_s:
-            self._evict_flushed = (sched.evict_seconds, sched.evicted_blocks)
+            now = (sched.evict_seconds, sched.evicted_blocks,
+                   sched.prefix_cache.stale_skipped)
+            self._evict_flushed = now
             if sched_span is not None:  # not warming up
                 sched_span.annotate(
-                    evict_ms=round(1e3 * (sched.evict_seconds - evict_s), 6),
-                    evicted=sched.evicted_blocks - evicted)
+                    evict_ms=round(1e3 * (now[0] - evict_s), 6),
+                    evicted=now[1] - evicted, evict_stale=now[2] - stale)
         if sched.prefix_hit_tokens > self._prefix_hits_flushed:
             self._counter("serve_prefix_hit_tokens_total").inc(
                 sched.prefix_hit_tokens - self._prefix_hits_flushed
@@ -1231,6 +1236,10 @@ class ServeEngine:
             "output_tokens": sum(len(s.generated) for s in finished),
             "preemptions": sched.preemption_count,
             "prefix_hit_tokens": sched.prefix_hit_tokens,
+            # stale entries the prefix cache's LRU heap has had to skip
+            # while evicting (scheduler.PrefixCache.evict)
+            "evict_stale": (sched.prefix_cache.stale_skipped
+                            if sched.prefix_cache is not None else 0),
             "prefilled_tokens": self.prefilled_tokens,
             "spec_drafted_tokens": self.spec_drafted_tokens,
             "spec_accepted_tokens": self.spec_accepted_tokens,

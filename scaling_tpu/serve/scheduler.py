@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import heapq
 import time
 from collections import deque
 from typing import Deque, Dict, List, Optional, Tuple
@@ -240,7 +241,19 @@ class PrefixCache:
     the trie's is *evictable*: eviction is LRU over such leaves (a node
     in use — refcount > 1 — is refused, and since sharing walks root-
     down, an in-use descendant implies in-use ancestors, so leaf-first
-    LRU can never strand a live path)."""
+    LRU can never strand a live path).
+
+    The LRU order is kept, not searched: ``_lru`` is a min-heap of
+    ``(last_used, block)``, one entry pushed wherever a node BECOMES an
+    evictable leaf (its block's refcount falls to 1 while it has no
+    children; its last child is evicted while its block is evictable)
+    and nowhere else. Nothing is taken out when a node stops being one
+    (matched again, a child inserted under it): ``evict`` skips such
+    stale entries as it pops them. Two leaves never share a stamp
+    (``insert`` draws a fresh one; ``match`` gives one stamp to one
+    root-down path, on which at most one node is a leaf), so the order
+    is total and the victims are the ones a search of the whole trie
+    for the oldest evictable leaf would find, in the same order."""
 
     def __init__(self, allocator: BlockAllocator, block_size: int):
         self.allocator = allocator
@@ -252,17 +265,38 @@ class PrefixCache:
         # reference is the trie's. Kept current by the allocator's
         # refcount-transition hook so the scheduler's per-tick capacity
         # questions are O(1), not a trie DFS per sequence.
-        self._cached_blocks: set = set()
+        self._cached_blocks: Dict[int, PrefixNode] = {}  # block -> its node
         self._evictable: set = set()
+        self._lru: List[Tuple[int, int]] = []  # heap of (last_used, block)
+        # stale heap entries ``evict`` popped and skipped, running total
+        self.stale_skipped = 0
         allocator.on_ref_change = self._ref_changed
 
     def _ref_changed(self, block: int, rc: int) -> None:
-        if block not in self._cached_blocks:
+        node = self._cached_blocks.get(block)
+        if node is None:
             return
         if rc == 1:
             self._evictable.add(block)
+            if not node.children:
+                self._push_leaf(node)
         else:
             self._evictable.discard(block)
+
+    def _push_leaf(self, node: PrefixNode) -> None:
+        """``node`` has just become an evictable leaf. A prompt matched
+        and freed again and again pushes an entry each time and, with a
+        roomy pool, nothing pops them: once the heap is over twice the
+        trie's size it is rebuilt from the live evictable leaves (at
+        most one a node), which keeps it within a constant factor of
+        ``cached_blocks`` at an amortised O(1) a push."""
+        heapq.heappush(self._lru, (node.last_used, node.block))
+        if len(self._lru) > 2 * self._nodes:
+            self._lru = [
+                (n.last_used, b) for b in self._evictable
+                if not (n := self._cached_blocks[b]).children
+            ]
+            heapq.heapify(self._lru)
 
     def _tick(self) -> int:
         self._clock += 1
@@ -342,7 +376,7 @@ class PrefixCache:
         child = PrefixNode(key, block, node)
         child.last_used = self._tick()
         node.children[key] = child
-        self._cached_blocks.add(block)
+        self._cached_blocks[block] = child
         self.allocator.incref(block)  # the cache's own reference
         self._nodes += 1
         return True
@@ -359,28 +393,31 @@ class PrefixCache:
         """Reclaim up to ``n`` blocks, LRU over refcount-1 LEAVES
         (cascading: an evicted leaf may expose its parent). Refuses any
         node a sequence still maps (refcount > 1) — eviction must never
-        pull a live block out from under a running request. The leaf
-        walk only runs under pool pressure (the steady state never
-        enters here); the hot capacity question is ``evictable_count``,
-        which is O(1)."""
+        pull a live block out from under a running request. Entered in
+        steady state: once the pool has filled with what finished
+        requests left behind, every tick that allocates comes here. It
+        pops the heap until ``n`` blocks are free or it is empty,
+        O((n + stale) log leaves) with no walk of the trie; a popped
+        entry is stale (skipped, counted in ``stale_skipped``) when its
+        block is cached no longer or by a node stamped since, is mapped
+        by a sequence again, or has children again. The hot capacity
+        question is ``evictable_count``, which is O(1)."""
         freed = 0
-        while freed < n and self._evictable:
-            victim: Optional[PrefixNode] = None
-            stack = list(self._root.children.values())
-            while stack:
-                node = stack.pop()
-                if node.children:
-                    stack.extend(node.children.values())
-                elif node.block in self._evictable and (
-                        victim is None or node.last_used < victim.last_used):
-                    victim = node
-            if victim is None:
-                break
-            del victim.parent.children[victim.key]
+        while freed < n and self._lru:
+            stamp, block = heapq.heappop(self._lru)
+            victim = self._cached_blocks.get(block)
+            if (victim is None or victim.last_used != stamp
+                    or victim.children or block not in self._evictable):
+                self.stale_skipped += 1
+                continue
+            parent = victim.parent
+            del parent.children[victim.key]
             self._nodes -= 1
-            self.allocator.free([victim.block])  # trie ref -> free list
-            self._cached_blocks.discard(victim.block)
+            self.allocator.free([block])  # trie ref -> free list
+            del self._cached_blocks[block]
             freed += 1
+            if not parent.children and parent.block in self._evictable:
+                self._push_leaf(parent)
         return freed
 
 

@@ -20,6 +20,8 @@ PROMPTS = [[1, 2, 3, 4, 5], [7, 8, 9], [11, 12, 13, 14, 15, 16, 17, 18],
 PHASES = ("serve.schedule", "serve.mixed.build", "serve.mixed.dispatch",
           "serve.mixed.wait", "serve.mixed", "serve.emit", "serve.retire",
           "serve.tick")  # the order in which one tick's spans close
+# what serve.schedule says of a tick that evicted, and of no other
+EVICT_FIELDS = {"evict_ms", "evicted", "evict_stale"}
 
 
 @pytest.fixture(scope="module")
@@ -213,27 +215,36 @@ def test_eviction_is_on_the_schedule_span_only_in_the_ticks_that_evict(
     requests leave their prompt blocks in the prefix cache, a later one
     finds the free list short and the scheduler's one call of
     ``PrefixCache.evict`` runs. That tick's ``serve.schedule`` row carries
-    ``evict_ms`` and ``evicted``; the others carry neither, and paid no
-    clock read for it."""
+    ``evict_ms``, ``evicted`` and ``evict_stale``; the others carry none
+    of them, and paid no clock read for it. The second prompt extends the
+    first, so the first's last block is pushed as a leaf, matched again
+    and given a child: the stale entry that ``evict`` has to skip."""
     e = make_engine(toy_inference, num_slots=1, num_blocks=8,
                     max_blocks_per_seq=4)
     since = time.monotonic_ns()
     prompts = [[10 * i + j for j in range(8)] for i in range(1, 5)]
+    prompts.insert(1, prompts[0] + [7, 7, 7, 7, 7])
     for p in prompts:
         e.submit(p, 3)
         e.run_until_done()
     sched = e.scheduler
     assert sched.evicted_blocks > 0 and sched.evict_seconds > 0
+    assert sched.prefix_cache.stale_skipped > 0
     rows = obs.recorded_spans(since_ns=since, name="serve.schedule")
     assert len(rows) == e.tick_index
     evicting = [r for r in rows if "evict_ms" in r.fields]
     assert evicting and len(evicting) < len(rows)
-    assert all("evicted" not in r.fields for r in rows if r not in evicting)
+    assert all(EVICT_FIELDS <= set(r.fields) for r in evicting)
+    assert all(EVICT_FIELDS.isdisjoint(r.fields)
+               for r in rows if r not in evicting)
     assert sum(r.fields["evicted"] for r in evicting) == sched.evicted_blocks
+    assert sum(r.fields["evict_stale"] for r in evicting) == \
+        sched.prefix_cache.stale_skipped
     assert sum(r.fields["evict_ms"] for r in evicting) == pytest.approx(
         1e3 * sched.evict_seconds, abs=1e-5 * len(evicting))
     # the eviction lies inside the span that reports it
     assert all(0 < r.fields["evict_ms"] <= r.duration_ns / 1e6 for r in evicting)
+    assert e.stats_snapshot()["evict_stale"] == sched.prefix_cache.stale_skipped
 
 
 def test_no_pressure_no_eviction_no_field_no_clock_read(toy_inference, monkeypatch):
@@ -251,7 +262,8 @@ def test_no_pressure_no_eviction_no_field_no_clock_read(toy_inference, monkeypat
     assert calls == [] and e.scheduler.evict_seconds == 0.0
     rows = obs.recorded_spans(since_ns=since, name="serve.schedule")
     assert len(rows) == e.tick_index
-    assert not any("evict_ms" in r.fields or "evicted" in r.fields for r in rows)
+    assert all(EVICT_FIELDS.isdisjoint(r.fields) for r in rows)
+    assert e.stats_snapshot()["evict_stale"] == 0
 
 
 def test_one_first_token_row_a_request_none_in_warm_up(toy_inference):
