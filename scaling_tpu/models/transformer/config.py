@@ -51,6 +51,26 @@ class MLPType(Enum):
     MOE = "moe"
 
 
+class LayerKind(Enum):
+    """The ONE mixer a layer of a ``layer_pattern`` stack has (Nemotron-H's
+    ``hybrid_override_pattern``: ``M``, ``E``, ``*``)."""
+
+    MAMBA = "mamba"          # Mamba-2 state-space mixer (nn/mamba.py)
+    MOE = "moe"              # routed MLP (nn/moe.py)
+    ATTENTION = "attention"  # softmax attention
+
+
+class MoERouter(Enum):
+    """How a routed MLP scores the experts: ``softmax`` over all of them,
+    the top k taken from the probabilities; or ``sigmoid_bias``: each
+    expert's score ``s_e = sigmoid(logit_e)`` on its own, the k experts with
+    the largest ``s_e + b_e`` chosen (``b``: a selection bias, used for the
+    choice only) and gated by their ``s_e`` (DeepSeek-V3's router)."""
+
+    SOFTMAX = "softmax"
+    SIGMOID_BIAS = "sigmoid_bias"
+
+
 class KeyQueryNormScope(Enum):
     """What ``key_query_norm`` normalises: each head's ``head_dim`` values
     with one learned weight of ``head_dim`` (the reference's), or the whole
@@ -172,6 +192,13 @@ class TransformerArchitectureConfig(BaseConfig):
     attention_num_kv_heads: Optional[int] = Field(
         None, description="number of kv heads for grouped-query attention"
     )
+    attention_head_dim: Optional[int] = Field(
+        None,
+        description="size of one attention head when it is not hidden_size / "
+        "num_attention_heads (config.json's head_dim): q and the output "
+        "projection are then num_attention_heads * head_dim wide",
+        gt=0,
+    )
     attention_qkv_in_one: bool = Field(
         True, description="store q,k,v projections in one fused weight"
     )
@@ -219,6 +246,59 @@ class TransformerArchitectureConfig(BaseConfig):
         "to one (Switch/GShard practice); false uses them as the softmax over "
         "all experts gave them (OLMoE's norm_topk_prob: false)",
     )
+    moe_expert_width: Optional[int] = Field(
+        None,
+        description="intermediate width of ONE routed expert "
+        "(moe_intermediate_size); absent: mlp_factor * hidden_size",
+        gt=0,
+    )
+    moe_glu: bool = Field(
+        True,
+        description="experts are gated (act(x W_gate) * x W_in) W_out; false: "
+        "two matrices, act(x W_in) W_out",
+    )
+    moe_router: MoERouter = Field(MoERouter.SOFTMAX, description="")
+    moe_routed_scaling_factor: float = Field(
+        1.0, description="factor on the chosen experts' gates "
+        "(routed_scaling_factor)", gt=0,
+    )
+    moe_shared_expert_width: Optional[int] = Field(
+        None,
+        description="intermediate width of the ONE shared expert every token "
+        "runs beside its routed ones (un-gated like them when moe_glu is "
+        "false); absent: no shared expert",
+        gt=0,
+    )
+    moe_experts_first: int = Field(
+        0,
+        description="first expert this program holds: the routed layers hold "
+        "the contiguous range [first, first + held) of moe_num_experts, one "
+        "rank's share of an expert-parallel deployment. The router keeps "
+        "all moe_num_experts outputs and moe_top_k a token; gates of absent "
+        "experts are dropped, not renormalised",
+        ge=0,
+    )
+    moe_experts_held: Optional[int] = Field(
+        None, description="experts held from moe_experts_first on; absent: "
+        "all of them", gt=0,
+    )
+    layer_pattern: Optional[List[LayerKind]] = Field(
+        None,
+        description="a kind a layer: each layer is ONE norm, ONE mixer of its "
+        "kind and the residual (x <- x + Mixer(Norm(x))); absent: the "
+        "homogeneous stack of attention + MLP layers",
+    )
+    mamba_num_heads: int = Field(
+        64, description="heads of a Mamba-2 mixer; its inner width is "
+        "mamba_num_heads * mamba_head_dim", gt=0)
+    mamba_head_dim: int = Field(64, description="", gt=0)
+    ssm_state_size: int = Field(128, description="state size N a head", gt=0)
+    n_groups: int = Field(
+        8, description="groups sharing B and C, and of the gated norm", gt=0)
+    conv_kernel: int = Field(4, description="depthwise causal conv taps", gt=1)
+    time_step_min: float = Field(0.001, description="dt init range", gt=0)
+    time_step_max: float = Field(0.1, description="dt init range", gt=0)
+    time_step_floor: float = Field(1e-4, description="dt init floor", gt=0)
     activation_function: ActivationFunction = Field(ActivationFunction.GELU, description="")
     precision: Precision = Field(Precision.FLOAT32, description="compute/param dtype")
     layernorm: LayerNormConfig = Field(LayerNormConfig(), description="")
@@ -339,6 +419,26 @@ class TransformerArchitectureConfig(BaseConfig):
                 "key_query_norm_scope 'projection' says which q/k norm the "
                 "model has; set key_query_norm true as well"
             )
+        held = self.moe_experts_held
+        if held is not None and self.moe_experts_first + held > self.moe_num_experts:
+            raise ValueError(
+                f"experts [{self.moe_experts_first}, {self.moe_experts_first} + "
+                f"{held}) do not lie in moe_num_experts {self.moe_num_experts}"
+            )
+        if self.attention_head_dim is not None and self.layer_pattern is None:
+            raise ValueError(
+                "attention_head_dim without layer_pattern: the homogeneous "
+                "TransformerLayer sizes its heads hidden_size / "
+                "num_attention_heads (rotary, muP); a head size of its own is "
+                "built for the single-mixer stack only"
+            )
+        if self.attention_head_dim is not None and self.lora_config is not None:
+            raise ValueError(
+                "attention_head_dim with lora_config: the LoRA modules are "
+                "sized from hidden_size; not supported"
+            )
+        if self.layer_pattern is not None:
+            self._validate_pattern()
         if self.mlp_type == MLPType.MOE:
             if self.moe_top_k > self.moe_num_experts:
                 raise ValueError(
@@ -376,6 +476,60 @@ class TransformerArchitectureConfig(BaseConfig):
                 "once; untie the head to use mup"
             )
         return self
+
+    def _validate_pattern(self):
+        """What a ``layer_pattern`` stack does not build, each by name."""
+        if len(self.layer_pattern) != self.num_layers:
+            raise ValueError(
+                f"layer_pattern names {len(self.layer_pattern)} layers, "
+                f"num_layers is {self.num_layers}"
+            )
+        if self.loop_steps > 1:
+            raise ValueError(
+                "layer_pattern with loop_steps > 1: a looped trunk walks "
+                "TransformerLayers and one kind of cache; not supported"
+            )
+        if self.mamba_num_heads % self.n_groups:
+            raise ValueError(
+                f"mamba_num_heads {self.mamba_num_heads} is not a multiple "
+                f"of n_groups {self.n_groups}"
+            )
+        if LayerKind.MOE in self.layer_pattern:
+            if self.moe_top_k > self.moe_num_experts:
+                raise ValueError(
+                    f"moe_top_k ({self.moe_top_k}) cannot exceed "
+                    f"moe_num_experts ({self.moe_num_experts})"
+                )
+        for name in ("adapter_config", "lora_config", "bitfit_bias_config",
+                     "mup"):
+            if getattr(self, name) is not None:
+                raise ValueError(
+                    f"layer_pattern with {name}: the single-mixer layer "
+                    "builds none of the fine-tuning modules"
+                )
+        if self.sandwich_norm or self.key_query_norm:
+            raise ValueError(
+                "layer_pattern with sandwich_norm or key_query_norm: the "
+                "single-mixer layer has one norm, before its mixer"
+            )
+
+    @property
+    def moe_held(self) -> int:
+        """Experts a routed layer of this program holds."""
+        if self.moe_experts_held is not None:
+            return self.moe_experts_held
+        return self.moe_num_experts - self.moe_experts_first
+
+    @property
+    def has_routed_layers(self) -> bool:
+        if self.layer_pattern is not None:
+            return LayerKind.MOE in self.layer_pattern
+        return self.mlp_type == MLPType.MOE
+
+    @property
+    def recurrent_layers(self) -> int:
+        """Layers that keep a recurrent state a sequence (Mamba-2 mixers)."""
+        return sum(k == LayerKind.MAMBA for k in self.layer_pattern or ())
 
     @property
     def mup_width_mult(self) -> float:
@@ -506,6 +660,19 @@ class TransformerConfig(BaseConfig):
     determined_experiment_id: Optional[int] = Field(None, description="")
     determined_trial_id: Optional[int] = Field(None, description="")
     context: ContextConfig = Field(ContextConfig(), description="")
+
+    @model_validator(mode="after")
+    def _validate_layout(self):
+        if self.transformer_architecture.layer_pattern is not None:
+            for axis in ("pipe_parallel_size", "model_parallel_size"):
+                if getattr(self.topology, axis) > 1:
+                    raise ValueError(
+                        f"layer_pattern with {axis} "
+                        f"{getattr(self.topology, axis)}: layers of unequal "
+                        "kind are neither stage-stacked nor tensor-parallel "
+                        "yet; use 1"
+                    )
+        return self
 
     @classmethod
     def from_dict(cls, d: dict, overwrite_values: Optional[dict] = None):

@@ -29,13 +29,20 @@ import numpy as np
 import yaml
 
 from .config import TransformerConfig
-from .layers.layer import TransformerLayer
+from .layers.layer import MixerLayer, TransformerLayer
 from .layers.lm_head import LayerNormWrapper, LoopExitGate, exit_distribution
 from .model import init_model
 from .tokenizer import Tokenizer
 from ...checkpoint import load_model_checkpoint
 from ...nn.attention import PagedKVCacheView
+from ...nn.mamba import RecurrentStateView
 from ...parallel.parallel_module import ParallelModule
+
+
+# the layers of a trunk (they consume the serving state), and the views of
+# the serving engine's pools
+TRUNK_LAYERS = (TransformerLayer, MixerLayer)
+PAGED_VIEWS = (PagedKVCacheView, RecurrentStateView)
 
 
 class CompletionOutput(NamedTuple):
@@ -329,8 +336,14 @@ class TransformerInferenceModule:
         shared = {}
 
         def call(layer):
-            key = (type(layer), id(layer.architecture))
-            if key not in shared:
+            # a pattern stack's layers are one function a KIND of mixer; a
+            # layer that keeps no state takes the real positions instead
+            key = (type(layer), id(layer.architecture), layer.kind)
+            if key not in shared and layer.consumes is None:
+                shared[key] = jax.jit(
+                    lambda p, x, real: layer(p, x, ctx, real=real)
+                )
+            elif key not in shared:
                 shared[key] = jax.jit(
                     lambda p, x, cache: layer(p, x, ctx, kv_cache=cache)
                 )
@@ -397,7 +410,7 @@ class TransformerInferenceModule:
         if gather_index is not None:
             tls = [
                 i for i, l in enumerate(self.module.layers)
-                if isinstance(l, TransformerLayer)
+                if isinstance(l, TRUNK_LAYERS)
             ]
             if not tls:
                 raise ValueError(
@@ -406,20 +419,43 @@ class TransformerInferenceModule:
                 )
             last_tl = max(tls)
         paged_layer_call = self._paged_layer_calls(ctx)
+        paged = bool(caches) and isinstance(caches[0], PAGED_VIEWS)
+        real = None
+        if paged and self.architecture.layer_pattern is not None:
+            # which positions hold a token: the routed layers keep no state
+            # of their own to read it from
+            real = PagedKVCacheView.token_rows(
+                caches[0], batch["token_ids"].shape)[2]
 
         x = batch
         new_caches = []
         li = 0
         for i, layer in enumerate(self.module.layers):
             p = self.module._layer_params(params, i)
-            if isinstance(layer, TransformerLayer):
-                if caches is None:
+            if isinstance(layer, TRUNK_LAYERS):
+                # a layer is handed the state of ITS kind: a TransformerLayer
+                # and an attention mixer a KV cache, a Mamba-2 mixer its
+                # recurrent lines, a routed mixer nothing
+                consumes = layer.consumes
+                if consumes is None and real is not None:
+                    x = paged_layer_call(layer)(p, x, real)
+                elif caches is None or consumes is None:
                     x = layer(p, x, ctx)
                 else:
-                    if isinstance(caches[li], PagedKVCacheView):
-                        x, kv = paged_layer_call(layer)(p, x, caches[li])
+                    if li >= len(caches):
+                        raise ValueError(
+                            f"layer {i} consumes a {consumes!r} cache but only "
+                            f"{len(caches)} were provided")
+                    cache = caches[li]
+                    if (consumes == "ssm") != isinstance(cache, RecurrentStateView):
+                        raise ValueError(
+                            f"layer {i} consumes a {consumes!r} state and was "
+                            f"handed a {type(cache).__name__}: the caches are "
+                            "one a consuming layer, in layer order")
+                    if isinstance(cache, PAGED_VIEWS):
+                        x, kv = paged_layer_call(layer)(p, x, cache)
                     else:
-                        x, kv = layer(p, x, ctx, kv_cache=caches[li], cache_offset=offset)
+                        x, kv = layer(p, x, ctx, kv_cache=cache, cache_offset=offset)
                     new_caches.append(kv)
                     li += 1
                 if i == last_tl:
@@ -753,7 +789,7 @@ class TransformerInferenceModule:
                                     pick=pick)[:2]
         transformer_idxs = [
             i for i, l in enumerate(self.module.layers)
-            if isinstance(l, TransformerLayer)
+            if isinstance(l, TRUNK_LAYERS)
         ]
         if not transformer_idxs:
             if any(isinstance(l, PipelinedBody) for l in self.module.layers):
@@ -774,7 +810,8 @@ class TransformerInferenceModule:
         kvs = []
         for i, layer in enumerate(self.module.layers):
             p = self.module._layer_params(params, i)
-            if isinstance(layer, TransformerLayer):
+            if isinstance(layer, TRUNK_LAYERS) and layer.consumes is not None:
+                # attention: its (k, v); a Mamba-2 mixer: its (ssm, conv) lines
                 x, kv = layer(p, x, ctx, return_kv=True)
                 kvs.append(kv)
             else:
@@ -967,6 +1004,12 @@ class TransformerInferenceModule:
                 row_logits[i].append(step_logits[i])
                 finished[i] = row_tokens[i][-1] in stop
 
+        if use_cache and self.architecture.layer_pattern is not None:
+            raise ValueError(
+                "cached generate() keeps dense KV caches only; a layer_pattern "
+                "stack (recurrent state beside KV) decodes through ServeEngine, "
+                "or here with use_cache=False"
+            )
         if use_cache:
             max_len = prompt_len + max_tokens
             logits, caches = self._prefill(

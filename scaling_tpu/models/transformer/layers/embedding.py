@@ -20,6 +20,7 @@ from ....nn import (
     ForwardContext,
     ParamMeta,
     VocabParallelEmbedding,
+    normal_init,
     tree_prefix,
 )
 from ..config import SoftpromptConfig, TransformerArchitectureConfig
@@ -29,11 +30,19 @@ from .base import make_layer_io
 class EmbeddingInput(BaseLayer):
     def __init__(self, architecture: TransformerArchitectureConfig):
         self.architecture = architecture
+        extra = {}
+        if architecture.layer_pattern is not None:
+            # a stack of single-mixer layers starts its stream at unit
+            # variance (N(0, 1), the plain default of an embedding table),
+            # beside which each residual branch is small: layers/layer.py,
+            # MixerLayer.init
+            extra["init_method"] = normal_init(1.0)
         self.embedding = VocabParallelEmbedding(
             num_embeddings=architecture.vocab_size,
             embedding_dim=architecture.hidden_size,
             dtype=architecture.dtype,
             finetunable_token_ids=architecture.finetunable_token_ids or None,
+            **extra,
         )
         self.dropout_rate = architecture.dropout_embedding
         self.softprompt_config: Optional[SoftpromptConfig] = architecture.softprompt_config

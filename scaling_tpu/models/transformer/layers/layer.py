@@ -31,9 +31,11 @@ from ....nn import (
 )
 from ....nn.attention import PagedKVCacheView
 from ....nn.rotary import RotaryConfig
+from ....nn.mamba import Mamba2Mixer, RecurrentStateView
 from ..config import (
     AdapterConfig,
     KeyQueryNormScope,
+    LayerKind,
     MLPType,
     RelativePositionEmbeddingType,
     TransformerArchitectureConfig,
@@ -74,7 +76,182 @@ class Adapter(BaseLayer):
         return h @ params["up"].astype(x.dtype)
 
 
+def routed_mlp(arch: TransformerArchitectureConfig) -> BaseLayer:
+    """The routed MLP the configuration describes (``nn/moe.py``)."""
+    from ....nn.moe import ParallelMoEMLP
+
+    return ParallelMoEMLP(
+        io_features=arch.hidden_size,
+        intermediate_feature_factor=arch.mlp_factor,
+        num_experts=arch.moe_num_experts,
+        top_k=arch.moe_top_k,
+        capacity_factor=arch.moe_capacity_factor,
+        aux_loss_coef=arch.moe_aux_loss_coef,
+        norm_topk_prob=arch.moe_norm_topk_prob,
+        glu=arch.moe_glu,
+        activation=arch.activation_function,
+        dtype=arch.dtype,
+        intermediate=arch.moe_expert_width,
+        router=arch.moe_router.value,
+        routed_scaling_factor=arch.moe_routed_scaling_factor,
+        shared_expert_width=arch.moe_shared_expert_width,
+        experts_first=arch.moe_experts_first,
+        experts_held=arch.moe_experts_held,
+    )
+
+
+class MixerLayer(BaseLayer):
+    """A layer of a ``layer_pattern`` stack: ONE norm, ONE mixer of the
+    layer's kind, the residual: ``x <- x + Mixer(Norm(x))`` (Nemotron-H's
+    block). ``consumes`` names the serving state the mixer keeps: ``'kv'``
+    (attention: a paged KV cache line), ``'ssm'`` (Mamba-2: a recurrent-state
+    line a slot), or None (the routed MLP)."""
+
+    CONSUMES = {LayerKind.ATTENTION: "kv", LayerKind.MAMBA: "ssm",
+                LayerKind.MOE: None}
+
+    def __init__(self, architecture: TransformerArchitectureConfig, layer_index: int = 0):
+        arch = architecture
+        self.architecture = arch
+        self.layer_index = layer_index
+        self.kind = arch.layer_pattern[layer_index]
+        self.consumes = self.CONSUMES[self.kind]
+        dtype = arch.dtype
+        self.norm = get_norm(arch.norm_type, arch.hidden_size, arch.layernorm, dtype)
+        if self.kind == LayerKind.MAMBA:
+            self.mixer: BaseLayer = Mamba2Mixer(
+                hidden_size=arch.hidden_size, num_heads=arch.mamba_num_heads,
+                head_dim=arch.mamba_head_dim, state_size=arch.ssm_state_size,
+                n_groups=arch.n_groups, conv_kernel=arch.conv_kernel,
+                norm_eps=arch.layernorm.layernorm_epsilon,
+                time_step_min=arch.time_step_min,
+                time_step_max=arch.time_step_max,
+                time_step_floor=arch.time_step_floor, dtype=dtype,
+            )
+        elif self.kind == LayerKind.MOE:
+            self.mixer = routed_mlp(arch)
+        else:
+            rotary_config = None
+            head_dim = (arch.attention_head_dim
+                        or arch.hidden_size // arch.num_attention_heads)
+            if arch.relative_position_embedding_type != RelativePositionEmbeddingType.NONE:
+                rotary_config = RotaryConfig(
+                    dimensions=max(2, int(head_dim * arch.rotary_percentage)),
+                    base=arch.rotary_embedding_base,
+                    max_seq_length=arch.sequence_length,
+                )
+            self.mixer = ParallelSelfAttention(
+                hidden_size=arch.hidden_size,
+                num_attention_heads=arch.num_attention_heads,
+                masked_softmax_config=arch.masked_softmax,
+                causal=arch.causal,
+                rotary_config=rotary_config,
+                relative_position_embedding_type=arch.relative_position_embedding_type.value,
+                bias=arch.attention_bias,
+                dtype=dtype,
+                norm_type=arch.norm_type,
+                layernorm_config=arch.layernorm,
+                qkv_in_one=arch.attention_qkv_in_one
+                and arch.attention_num_kv_heads is None,
+                num_kv_heads=arch.attention_num_kv_heads,
+                head_dim=arch.attention_head_dim,
+            )
+
+    # a routed expert's output projection starts this much below a plain
+    # branch's (init, below)
+    ROUTED_OUTPUT_SCALE = 0.25
+
+    def init(self, key: jax.Array) -> dict:
+        """The mixer's own init, then the depth scaling of a residual
+        branch: every mixer's OUTPUT projection starts at ``1 / (2
+        sqrt(num_layers))`` of its Xavier scale (GPT-2 gives its output
+        projections ``1 / sqrt(2 L)``, Mamba's ``rescale_prenorm_residual``
+        ``1 / sqrt(L)``), beside an embedding at unit variance
+        (layers/embedding.py): the stream is then mostly the embedding and
+        each branch a small step, so that fresh weights are a stable map.
+        The routed experts' start a further ``ROUTED_OUTPUT_SCALE`` lower:
+        with fresh weights a sigmoid router's k chosen scores are all near
+        0.5 and their renormalised gates all near ``scale / k``, so a
+        near-tie at the edge of the choice, which a bf16 rounding of the
+        router's input breaks either way in ~7% of (token, layer), swaps
+        an expert that carries a k-th of the routed output. At Xavier scale
+        and an embedding of 0.005 that moved served logits by up to 0.67
+        against the float32 reference (PERF.md, PR 46); a trained router's
+        edge experts carry small scores."""
+        k1, k2 = jax.random.split(key)
+        mixer = self.mixer.init(k2)
+        scale = 0.5 * self.architecture.num_layers ** -0.5
+
+        def scaled(weight, by):
+            return (weight.astype(jnp.float32) * by).astype(weight.dtype)
+
+        if self.kind == LayerKind.MAMBA:
+            mixer["out_proj"]["weight"] = scaled(mixer["out_proj"]["weight"], scale)
+        elif self.kind == LayerKind.MOE:
+            mixer["w_out"] = scaled(mixer["w_out"], scale * self.ROUTED_OUTPUT_SCALE)
+            if "shared_out" in mixer:
+                mixer["shared_out"] = scaled(mixer["shared_out"], scale)
+        else:
+            mixer["dense"]["weight"] = scaled(mixer["dense"]["weight"], scale)
+        return {"norm": self.norm.init(k1), "mixer": mixer}
+
+    def param_metas(self) -> dict:
+        return {"norm": tree_prefix(self.norm.param_metas(), "norm"),
+                "mixer": tree_prefix(self.mixer.param_metas(), "mixer")}
+
+    def __call__(self, params: dict, x: dict, ctx: ForwardContext,
+                 kv_cache=None, cache_offset=None, return_kv: bool = False,
+                 real=None):
+        """``kv_cache``: the serving state of this layer's kind (a
+        ``PagedKVCacheView`` or dense ``(k, v)`` for attention, a
+        ``RecurrentStateView`` for Mamba-2); with it or ``return_kv`` the
+        result is ``(out, new state)``: attention's K/V or updated view,
+        Mamba-2's lines. ``real`` ((b, s) bool): the positions that hold a
+        token, for the routed MLP's load count when serving."""
+        h = x["activations"]
+        normed = self.norm(params["norm"], h, ctx)
+        out = dict(x)
+        state = None
+        if self.kind == LayerKind.MAMBA:
+            if kv_cache is not None and not isinstance(kv_cache, RecurrentStateView):
+                raise ValueError(
+                    "a Mamba-2 layer takes a RecurrentStateView (the serving "
+                    "engine's state pool), not a KV cache: cached generate() "
+                    "is not built for a layer_pattern stack; use "
+                    "use_cache=False or ServeEngine")
+            y = self.mixer(params["mixer"], normed, ctx, state=kv_cache,
+                           return_state=return_kv)
+            if return_kv or kv_cache is not None:
+                y, state = y
+        elif self.kind == LayerKind.MOE:
+            if ctx.serving:
+                y, load = self.mixer.serve(params["mixer"], normed, real)
+                if load is not None:
+                    out["moe_load"] = x.get("moe_load", 0) + load
+            else:
+                y, aux = self.mixer(params["mixer"], normed, ctx)
+                out["aux_loss"] = x.get("aux_loss", 0.0) + aux
+        else:
+            y = self.mixer(
+                params["mixer"], normed, ctx,
+                segment_ids=x["segment_ids"], position_ids=x["position_ids"],
+                kv_cache=kv_cache, cache_offset=cache_offset,
+                return_kv=return_kv,
+            )
+            if return_kv or kv_cache is not None:
+                y, state = y
+        out["activations"] = h + y.astype(h.dtype)
+        if self.consumes and (return_kv or kv_cache is not None):
+            return out, state
+        return out
+
+
 class TransformerLayer(BaseLayer):
+    # what a walk of the stack reads off a trunk layer (MixerLayer's differ a
+    # layer): the serving state it keeps, and no single mixer's kind
+    consumes = "kv"
+    kind = None
+
     def __init__(self, architecture: TransformerArchitectureConfig, layer_index: int = 0):
         arch = architecture
         self.architecture = arch
@@ -147,20 +324,7 @@ class TransformerLayer(BaseLayer):
             }
         self.is_moe = arch.mlp_type == MLPType.MOE
         if self.is_moe:
-            from ....nn.moe import ParallelMoEMLP
-
-            self.mlp: BaseLayer = ParallelMoEMLP(
-                io_features=arch.hidden_size,
-                intermediate_feature_factor=arch.mlp_factor,
-                num_experts=arch.moe_num_experts,
-                top_k=arch.moe_top_k,
-                capacity_factor=arch.moe_capacity_factor,
-                aux_loss_coef=arch.moe_aux_loss_coef,
-                norm_topk_prob=arch.moe_norm_topk_prob,
-                glu=True,
-                activation=arch.activation_function,
-                dtype=dtype,
-            )
+            self.mlp: BaseLayer = routed_mlp(arch)
         elif arch.mlp_type == MLPType.SWIGLU:
             self.mlp = ParallelSwiGLUMLP(
                 io_features=arch.hidden_size,

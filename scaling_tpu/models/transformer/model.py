@@ -21,7 +21,7 @@ from ...parallel.parallel_module import ParallelModule
 from ...topology import Topology
 from .config import TransformerConfig, TransformerArchitectureConfig
 from .layers.embedding import EmbeddingInput
-from .layers.layer import TransformerLayer
+from .layers.layer import MixerLayer, TransformerLayer
 from .layers.lm_head import (
     LayerNormWrapper,
     LoopExitGate,
@@ -69,7 +69,17 @@ def get_transformer_layer_specs(
             "(every stage would be revisited each step); use "
             "pipe_parallel_size 1"
         )
-    if pp > 1:
+    if architecture.layer_pattern is not None:
+        # a kind a layer: one norm + one mixer each (TransformerConfig refuses
+        # the pattern under pp > 1 or mp > 1)
+        if pp > 1:
+            raise ValueError(
+                f"pipe_parallel_size {pp} with layer_pattern: layers of "
+                "unequal kind are not stage-stacked; use pipe_parallel_size 1"
+            )
+        for layer_index in range(architecture.num_layers):
+            specs.append(LayerSpec(MixerLayer, architecture, layer_index))
+    elif pp > 1:
         specs.append(
             PipelineBodySpec(TransformerLayer, architecture.num_layers, architecture)
         )
@@ -289,16 +299,28 @@ LOOPED_TRAINING_REFUSAL = (
 )
 
 
+PATTERN_TRAINING_REFUSAL = (
+    "a layer_pattern stack is served, not trained: the chunked scan of the "
+    "Mamba-2 mixer has no memory-lean backward, the routed layers' load "
+    "balance has no objective in the configuration, and the optimizer's "
+    "groups do not know the mixers' float32 leaves; run it through "
+    "TransformerInferenceModule / ServeEngine"
+)
+
+
 def init_model(config: TransformerConfig, topology: Optional[Topology] = None) -> ParallelModule:
     architecture = config.transformer_architecture
     specs = get_transformer_layer_specs(architecture, topology)
+    refusal = None
+    if architecture.loop_steps > 1:
+        refusal = LOOPED_TRAINING_REFUSAL
+    elif architecture.layer_pattern is not None:
+        refusal = PATTERN_TRAINING_REFUSAL
     return ParallelModule(
         specs,
         topology=topology,
         compute_dtype=architecture.dtype,
-        forward_refusal=(
-            LOOPED_TRAINING_REFUSAL if architecture.loop_steps > 1 else None
-        ),
+        forward_refusal=refusal,
     )
 
 

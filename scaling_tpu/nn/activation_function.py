@@ -18,6 +18,9 @@ class ActivationFunction(Enum):
     RELU = "relu"
     TANH = "tanh"
     SIGMOID = "sigmoid"
+    # relu(x) ** 2 (Primer, So et al. 2021; the experts of the Nemotron-H
+    # family, ``mlp_hidden_act: relu2``)
+    RELU2 = "relu2"
 
 
 _FUNCTIONS: dict[ActivationFunction, Callable] = {
@@ -26,6 +29,7 @@ _FUNCTIONS: dict[ActivationFunction, Callable] = {
     ActivationFunction.RELU: jax.nn.relu,
     ActivationFunction.TANH: jnp.tanh,
     ActivationFunction.SIGMOID: jax.nn.sigmoid,
+    ActivationFunction.RELU2: lambda x: jnp.square(jax.nn.relu(x)),
 }
 
 

@@ -320,14 +320,18 @@ class ParallelSelfAttention(BaseLayer):
         layernorm_config: Optional[LayerNormConfig] = None,
         qkv_in_one: bool = True,
         num_kv_heads: Optional[int] = None,
+        head_dim: Optional[int] = None,
     ):
-        assert hidden_size % num_attention_heads == 0, (
+        assert head_dim is not None or hidden_size % num_attention_heads == 0, (
             f"hidden size ({hidden_size}) must be divisible by "
             f"num_attention_heads ({num_attention_heads})"
         )
         self.hidden_size = hidden_size
         self.num_attention_heads = num_attention_heads
-        self.head_dim = hidden_size // num_attention_heads
+        # a head's size is hidden / heads unless the model states one of its
+        # own (the projections' width, heads x head_dim, is then not hidden)
+        self.head_dim = head_dim or hidden_size // num_attention_heads
+        self.attention_width = num_attention_heads * self.head_dim
         self.causal = causal
         self.masked_softmax_config = masked_softmax_config or MaskedSoftmaxConfig()
         self.use_flash = self.masked_softmax_config.kernel == MaskedSoftmaxKernel.FLASH_ATTENTION
@@ -355,18 +359,19 @@ class ParallelSelfAttention(BaseLayer):
 
         common = dict(bias=bias, dtype=dtype, init_method=init_method,
                       bitfit_bias_name=bitfit_bias_name)
+        width = self.attention_width
         if qkv_in_one:
             self.query_key_value = ColumnParallelLinear(
-                hidden_size, hidden_size * 3, parallel_output=True, **common
+                hidden_size, width * 3, parallel_output=True, **common
             )
         else:
             kv_size = self.num_kv_heads * self.head_dim
-            self.query = ColumnParallelLinear(hidden_size, hidden_size, parallel_output=True, **common)
+            self.query = ColumnParallelLinear(hidden_size, width, parallel_output=True, **common)
             self.key = ColumnParallelLinear(hidden_size, kv_size, parallel_output=True, **common)
             self.value = ColumnParallelLinear(hidden_size, kv_size, parallel_output=True, **common)
 
         self.dense = RowParallelLinear(
-            hidden_size, hidden_size, parallel_input=True, parallel_output=True, **common
+            width, hidden_size, parallel_input=True, parallel_output=True, **common
         )
 
         # rotary
@@ -863,7 +868,7 @@ class ParallelSelfAttention(BaseLayer):
 
     def _project_out(self, params, out, ctx, b, s, new_kv):
         """Shared epilogue: heads -> hidden, dense projection + LoRA delta."""
-        out = out.reshape(b, s, self.hidden_size)
+        out = out.reshape(b, s, self.attention_width)
         y = self.dense(params["dense"], out, ctx)
         if self.lora_config:
             name = f"{LoRAModuleType.DENSE.value}_{self.lora_config.name}"

@@ -38,6 +38,25 @@ auxiliary loss is a training term and is not computed when serving.
 ``norm_topk_prob`` (a fact of the model, not a knob): whether a token's k
 gate weights are renormalised to sum to one (Switch/GShard; the default)
 or used as the softmax over all experts gave them (OLMoE).
+
+Further facts of a model, all from its configuration: ``router``
+(``'softmax'``, or ``'sigmoid_bias'``: every expert's ``s_e = sigmoid(logit_e)``
+on its own, the k with the largest ``s_e + b_e`` chosen, ``b`` a float32
+selection bias that takes part in the CHOICE only, the gates ``scale * s_e /
+(sum of the chosen s + 1e-20)``), ``glu`` (false: two matrices an expert,
+``act(x W_in) W_out``), and a ``shared expert`` of a width of its own that
+every token runs, added once inside the ``moe`` scope.
+
+**A share of the experts** (``experts_first``, ``experts_held``): the layer
+is TOLD which contiguous range ``[first, first + held)`` of the
+``num_experts`` it holds, one rank's share of an expert-parallel deployment.
+The router keeps its ``num_experts`` outputs and its k a token; dispatch,
+expert FFNs and combine run over the held experts only (their leaves are
+``(held, ...)``); the gates of absent experts are dropped and NOT
+renormalised over those present, so the shares of all ranks, the shared
+expert counted once, add up to the whole layer. Nothing stands in for the
+other ranks or their exchange. ``serve`` then counts the load over the held
+experts, and how many of the real positions' assignments fell on absent ones.
 """
 
 from __future__ import annotations
@@ -68,11 +87,27 @@ class ParallelMoEMLP(BaseLayer):
         glu: bool = True,
         activation: ActivationFunction = ActivationFunction.SILU,
         dtype=None,
+        intermediate: Optional[int] = None,
+        router: str = "softmax",
+        routed_scaling_factor: float = 1.0,
+        shared_expert_width: Optional[int] = None,
+        experts_first: int = 0,
+        experts_held: Optional[int] = None,
     ):
         dtype = dtype or jnp.float32
-        intermediate = int(io_features * intermediate_feature_factor)
-        assert float(intermediate) == io_features * intermediate_feature_factor
+        if intermediate is None:
+            intermediate = int(io_features * intermediate_feature_factor)
+            assert float(intermediate) == io_features * intermediate_feature_factor
         assert 1 <= top_k <= num_experts
+        assert router in ("softmax", "sigmoid_bias"), router
+        self.router = router
+        self.routed_scaling_factor = routed_scaling_factor
+        self.shared_expert_width = shared_expert_width
+        self.experts_first = experts_first
+        self.experts_held = (
+            num_experts - experts_first if experts_held is None else experts_held
+        )
+        assert 0 < self.experts_held <= num_experts - experts_first
         self.io_features = io_features
         self.intermediate = intermediate
         self.num_experts = num_experts
@@ -89,13 +124,15 @@ class ParallelMoEMLP(BaseLayer):
         import math
 
         ks = jax.random.split(key, 4)
+        # the router scores every expert; the leaves hold those held here
         E, h, f = self.num_experts, self.io_features, self.intermediate
+        held = self.experts_held
 
         def expert_init(k, shape, dtype):
             # xavier over the PER-EXPERT matmul fans (the leading expert dim
             # is a batch dim, not a fan — feeding it to a 2-D initializer
             # over-scales every expert)
-            _, fan_in, fan_out = shape
+            fan_in, fan_out = shape[-2:]
             std = math.sqrt(2.0 / (fan_in + fan_out))
             return (jax.random.normal(k, shape) * std).astype(dtype)
 
@@ -107,12 +144,27 @@ class ParallelMoEMLP(BaseLayer):
                     jnp.float32
                 )
             },
-            "w_in": expert_init(ks[1], (E, h, f), self.dtype),
-            "w_out": expert_init(ks[2], (E, f, h), self.dtype),
+            "w_in": expert_init(ks[1], (held, h, f), self.dtype),
+            "w_out": expert_init(ks[2], (held, f, h), self.dtype),
         }
         if self.glu:
-            params["w_gate"] = expert_init(ks[3], (E, h, f), self.dtype)
+            params["w_gate"] = expert_init(ks[3], (held, h, f), self.dtype)
+        if self.router == "sigmoid_bias":
+            # the selection bias: zeros (a trained model's balances the load)
+            params["router"]["bias"] = jnp.zeros((E,), jnp.float32)
+        fs = self.shared_expert_width
+        for i, name in enumerate(self._shared_leaves()):
+            # keys of their own: the four above stay what they were
+            shape = (fs, h) if name == "shared_out" else (h, fs)
+            params[name] = expert_init(
+                jax.random.fold_in(key, 4 + i), shape, self.dtype)
         return params
+
+    def _shared_leaves(self):
+        """The shared expert's leaves (none without one)."""
+        if not self.shared_expert_width:
+            return ()
+        return ("shared_in", "shared_out") + (("shared_gate",) if self.glu else ())
 
     def param_metas(self) -> dict:
         def expert_meta(name, spec):
@@ -137,6 +189,18 @@ class ParallelMoEMLP(BaseLayer):
         }
         if self.glu:
             metas["w_gate"] = expert_meta("w_gate", (DATA_AXIS, None, MODEL_AXIS))
+        if self.router == "sigmoid_bias":
+            metas["router"]["bias"] = ParamMeta(
+                parameter_name="router.bias", partition_spec=(None,),
+                is_model_parallel_duplicate=True,
+            )
+        for name in self._shared_leaves():
+            spec = (MODEL_AXIS, None) if name == "shared_out" else (None, MODEL_AXIS)
+            metas[name] = ParamMeta(
+                parameter_name=name, partition_spec=spec,
+                is_model_parallel=True,
+                model_parallel_dimension=spec.index(MODEL_AXIS),
+            )
         return metas
 
     def __call__(
@@ -156,23 +220,52 @@ class ParallelMoEMLP(BaseLayer):
             aux = E * jnp.sum(probs.mean(axis=(0, 1)) * assigned.mean(axis=(0, 1)))
             aux = (aux * self.aux_loss_coef).astype(jnp.float32)
             capacity = max(1, int(self.capacity_factor * k * s / E))
-            return self._experts(params, x, gate_vals, gate_idx, capacity), aux
+            y = self._experts(params, x, gate_vals, gate_idx, capacity)
+            return self._add_shared(params, x, y), aux
+
+    @property
+    def holds_all(self) -> bool:
+        return self.experts_held == self.num_experts
 
     def serve(
         self, params: dict, x: jax.Array, real: Optional[jax.Array] = None
     ) -> Tuple[jax.Array, Optional[jax.Array]]:
         """Serving: room for the whole row, so nothing is dropped, and no
         auxiliary loss. ``real`` ((b, s) bool): which positions hold a
-        token. Returns (output (b,s,h), the (E,) int32 count of the real
-        positions' assignments each expert received; None without ``real``)."""
+        token. Returns (output (b,s,h), the (held,) int32 count of the real
+        positions' assignments each HELD expert received; None without
+        ``real``). A layer that holds a share of the experts appends one
+        more count: the real positions' assignments that fell on absent
+        experts (the two sum to ``top_k`` a real position)."""
         with jax.named_scope("moe"):
             _, gate_vals, gate_idx = self._route(params, x)
             y = self._experts(params, x, gate_vals, gate_idx, capacity=x.shape[1])
+            y = self._add_shared(params, x, y)
             if real is None:
                 return y, None
-            chosen = jax.nn.one_hot(gate_idx, self.num_experts, dtype=jnp.int32)
+            chosen = jax.nn.one_hot(
+                self._local(gate_idx), self.experts_held, dtype=jnp.int32)
             load = (chosen * real[:, :, None, None].astype(jnp.int32)).sum((0, 1, 2))
+            if not self.holds_all:
+                absent = self.top_k * real.sum(dtype=jnp.int32) - load.sum()
+                load = jnp.concatenate([load, absent[None]])
             return y, load
+
+    def _local(self, gate_idx: jax.Array) -> jax.Array:
+        """The chosen experts' places among those held; an absent expert's
+        lies outside ``[0, held)``, where ``one_hot`` is all zero."""
+        return gate_idx - self.experts_first if self.experts_first else gate_idx
+
+    def _add_shared(self, params: dict, x: jax.Array, y: jax.Array) -> jax.Array:
+        """``y`` + the shared expert every token runs (none: ``y``)."""
+        if not self.shared_expert_width:
+            return y
+        up = x @ params["shared_in"].astype(x.dtype)
+        if self.glu:
+            act = self.activation_fn(x @ params["shared_gate"].astype(x.dtype)) * up
+        else:
+            act = self.activation_fn(up)
+        return y + act @ params["shared_out"].astype(x.dtype)
 
     def _route(self, params: dict, x: jax.Array):
         """Router probabilities over all experts in float32 (b, s, E), and
@@ -184,12 +277,24 @@ class ParallelMoEMLP(BaseLayer):
             "bsh,he->bse", x.astype(jnp.float32), params["router"]["weight"],
             precision=jax.lax.Precision.HIGHEST,
         )
+        if self.router == "sigmoid_bias":
+            probs = jax.nn.sigmoid(logits)
+            # the bias moves the CHOICE; the gates are the chosen s_e
+            _, gate_idx = jax.lax.top_k(
+                probs + params["router"]["bias"], self.top_k)
+            gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
+            if self.norm_topk_prob:
+                gate_vals = gate_vals / (
+                    gate_vals.sum(axis=-1, keepdims=True) + 1e-20)
+            return probs, gate_vals * self.routed_scaling_factor, gate_idx
         probs = jax.nn.softmax(logits, axis=-1)
         gate_vals, gate_idx = jax.lax.top_k(probs, self.top_k)
         if self.norm_topk_prob:
             gate_vals = gate_vals / jnp.maximum(
                 gate_vals.sum(axis=-1, keepdims=True), 1e-9
             )
+        if self.routed_scaling_factor != 1.0:
+            gate_vals = gate_vals * self.routed_scaling_factor
         return probs, gate_vals, gate_idx
 
     def _experts(
@@ -199,7 +304,10 @@ class ParallelMoEMLP(BaseLayer):
         """Dispatch, expert FFNs, combine: three einsums over per-row
         buffers of ``capacity`` places an expert."""
         b, s, h = x.shape
-        E, k, C = self.num_experts, self.top_k, capacity
+        # E: the experts held here; a choice that fell on an absent one has
+        # an all-zero row below and so takes no place and no part
+        E, k, C = self.experts_held, self.top_k, capacity
+        gate_idx = self._local(gate_idx)
 
         # position of each (token, choice) in its expert's capacity buffer:
         # running count of prior tokens routed to the same expert. Choices

@@ -287,9 +287,25 @@ class ServeEngine:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             self._replicated = NamedSharding(self.mesh, P())
+        # a model with recurrent layers (layer_pattern with Mamba-2 mixers):
+        # a state that is advanced, never indexed by position, so what
+        # skips or rewinds positions is refused, not approximated
+        arch = inference_module.architecture
+        self.ssm_lines = arch.recurrent_layers
+        if self.ssm_lines and self.config.enable_prefix_cache:
+            raise ValueError(
+                "enable_prefix_cache with recurrent (Mamba-2) layers: a prefix "
+                "hit starts a row past tokens its recurrent state never saw "
+                "(only the KV of a shared prefix is kept, no state snapshot); "
+                "set enable_prefix_cache=False")
+        if self.ssm_lines and self.config.spec_k > 0:
+            raise ValueError(
+                "spec_k > 0 with recurrent (Mamba-2) layers: a rejected draft "
+                "has already advanced the recurrent state and there is no "
+                "rollback; set spec_k=0")
         self.pools: PagedKVPools = init_pools(
             inference_module, self.config.num_blocks, self.config.block_size,
-            kv_dtype=self.config.kv_dtype,
+            kv_dtype=self.config.kv_dtype, num_slots=self.config.num_slots,
         )
         import numpy as np
 
@@ -329,12 +345,11 @@ class ServeEngine:
         # in the tick's one host read, how many assignments of real
         # positions each expert received (0: a dense model, which pays
         # nothing for it)
-        from ..models.transformer.config import MLPType
-
-        arch = inference_module.architecture
-        self.num_experts = (
-            arch.moe_num_experts if arch.mlp_type == MLPType.MOE else 0
-        )
+        routed = arch.has_routed_layers
+        # the experts this program HOLDS; a share of them also counts the
+        # assignments that fell on absent experts (one more entry)
+        self.num_experts = arch.moe_held if routed else 0
+        self.moe_partial = routed and arch.moe_held < arch.moe_num_experts
         # a looped model (loop_steps > 1): every tick walks the trunk
         # loop_steps times; with an exit gate the mixed program also
         # returns, in the tick's one host read, the exit distribution over
@@ -553,8 +568,7 @@ class ServeEngine:
         return self._reg.histogram(name, self._labels)
 
     def _pool_state(self):
-        p = self.pools
-        return (p.pool_k, p.pool_v, p.scale_k, p.scale_v)
+        return self.pools.state()
 
     def _absorb(self, state) -> None:
         self.pools.absorb_state(state)
@@ -688,7 +702,7 @@ class ServeEngine:
             pos = jnp.where(offset < new_lens[row], ctx_lens[row] + offset, 0)
             batch = self.inf._make_batch(tick.tokens.reshape(shape), pos)
             views = build_layer_views(state, tables, ctx_lens, new_lens,
-                                      token_map)
+                                      token_map, kinds=self.pools.kinds)
             g0 = jnp.clip(new_lens - sample_width, 0,
                           row_width - sample_width)
             window = g0[:, None] + jnp.arange(sample_width, dtype=jnp.int32)
@@ -851,6 +865,13 @@ class ServeEngine:
                     kv_rows=int(np.count_nonzero(held)),
                     kv_tiles=int((-(-held // self._kv_tile)).sum()),
                 )
+                if self.ssm_lines:
+                    # rows whose recurrent state advanced, in every M layer
+                    ssm_rows = int(np.count_nonzero(new_lens))
+                    mixed_span.annotate(ssm_rows=ssm_rows,
+                                        ssm_lines=self.ssm_lines)
+                    self._counter("serve_ssm_state_updates_total").inc(
+                        ssm_rows * self.ssm_lines)
                 if self.loop_steps > 1:
                     mixed_span.annotate(loop_steps=self.loop_steps)
                     self._counter("serve_loop_layer_passes_total").inc(
@@ -906,6 +927,12 @@ class ServeEngine:
         layers: the counter, and the tick's shape on its emit span."""
         if self.warmup_mode:
             return
+        if self.moe_partial:
+            # a share of the experts: the last entry counts the assignments
+            # that fell on absent ones; the load is over those held
+            load, absent = load[:-1], int(load[-1])
+            self._counter("serve_moe_absent_assignments_total").inc(absent)
+            emit_span.annotate(absent_assign=absent)
         self._counter("serve_moe_assignments_total").inc(int(load.sum()))
         emit_span.annotate(
             load_max=int(load.max()), load_mean=float(load.mean()),
@@ -1260,6 +1287,10 @@ class ServeEngine:
             # steps x layers) and the bytes the pools really hold
             "kv_lines": self.pools.kv_lines,
             "kv_pool_bytes": self.pools.device_bytes(),
+            # layers that keep a recurrent line a slot (Mamba-2 mixers; 0: a
+            # model without them) and the bytes of those lines
+            "state_lines": self.pools.state_lines,
+            "state_pool_bytes": self.pools.state_bytes(),
             # median ms of each serve.* span over this engine's last
             # TICK_PHASES_TICKS ticks, read from the span recorder on
             # the call ({} before the first counted tick)

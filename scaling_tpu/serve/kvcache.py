@@ -33,6 +33,16 @@ pool then holds ``loop_steps x num_blocks`` blocks, step ``u``'s at
 ``[u * num_blocks, (u + 1) * num_blocks)``; the scheduler's block ``b`` is
 the same 16 tokens in every line, at ``u * num_blocks + b`` of every
 layer's pool. Block 0 of every step's share is trash.
+
+**Recurrent state** (a ``layer_pattern`` stack with Mamba-2 layers,
+docs/SERVING.md "Hybrid models"): a second kind of state beside the paged
+pools. A Mamba-2 layer keeps, for every SLOT, one fixed-size line: ``ssm
+(num_slots, heads, head_dim, N)`` float32 and ``conv (num_slots, channels,
+K - 1)``, whatever the sequence's length; nothing is paged, the scheduler
+counts no block for it. KV pools exist for the attention layers only. The
+state the engine's program takes and returns is then ``(pool_k, pool_v,
+scale_k, scale_v, state_ssm, state_conv)``: ONE structure, so that the
+recurrent lines are donated and aliased like the pools.
 """
 
 from __future__ import annotations
@@ -44,6 +54,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..nn.attention import PagedKVCacheView, PagedTokenMap
+from ..nn.mamba import RecurrentStateView
 
 
 def serving_mesh(inference_module):
@@ -62,7 +73,8 @@ def build_layer_views(
     context_len: jax.Array,          # (rows,) int32
     new_len: Optional[jax.Array] = None,  # (rows,) int32 real new tokens
     token_map: Optional[PagedTokenMap] = None,  # a token-major batch's
-) -> List[PagedKVCacheView]:
+    kinds: Optional[List[str]] = None,  # 'kv' | 'ssm' a consuming layer
+) -> List:
     """Per-layer :class:`PagedKVCacheView` s over the raw pool state —
     the shape the engine's jitted programs thread through ``_run_layers``
     (a looped model's too: one view a layer, whose pool holds every step's
@@ -75,9 +87,14 @@ def build_layer_views(
     slots, so ONE compiled chunk program serves every chunk length
     (including the final ragged chunk of every prompt). ``token_map``
     (``nn.attention.packed_token_map``) rides along when the batch holds
-    the rows' tokens packed token-major instead of one row a batch row."""
-    pool_k, pool_v, scale_k, scale_v = state
-    return [
+    the rows' tokens packed token-major instead of one row a batch row.
+
+    A state that carries recurrent lines (six entries) comes with ``kinds``,
+    the kind of state each consuming layer takes in layer order: the views
+    are then one a consuming layer, a ``RecurrentStateView`` over the slots'
+    lines for ``'ssm'``, and lie in that order."""
+    pool_k, pool_v, scale_k, scale_v = state[:4]
+    kv_views = [
         PagedKVCacheView(
             pool_k=pool_k[i], pool_v=pool_v[i],
             block_table=block_table, context_len=context_len,
@@ -87,6 +104,15 @@ def build_layer_views(
         )
         for i in range(len(pool_k))
     ]
+    if len(state) == 4:
+        return kv_views
+    ssm_views = [
+        RecurrentStateView(ssm=ssm, conv=conv, context_len=context_len,
+                           new_len=new_len, token_map=token_map)
+        for ssm, conv in zip(*state[4:])
+    ]
+    by_kind = {"kv": iter(kv_views), "ssm": iter(ssm_views)}
+    return [next(by_kind[kind]) for kind in kinds]
 
 
 def state_from_views(views: List[PagedKVCacheView]) -> Tuple:
@@ -105,14 +131,20 @@ def state_from_views(views: List[PagedKVCacheView]) -> Tuple:
     output ``v0``, written before layer 1 has read it, and XLA copies
     every pool but the first on every call. Their table, lengths,
     ``new_len`` and token map are the program's inputs (or derived from
-    them) and do not come back."""
+    them) and do not come back. Recurrent views among them put the two lists
+    of their lines after the four of the pools."""
+    recurrent = [v for v in views if isinstance(v, RecurrentStateView)]
+    views = [v for v in views if isinstance(v, PagedKVCacheView)]
     quantized = views[0].scale_k is not None
-    return (
+    state = (
         [v.pool_k for v in views],
         [v.pool_v for v in views],
         [v.scale_k for v in views] if quantized else None,
         [v.scale_v for v in views] if quantized else None,
     )
+    if recurrent:
+        state += ([v.ssm for v in recurrent], [v.conv for v in recurrent])
+    return state
 
 
 class PagedKVPools:
@@ -127,7 +159,10 @@ class PagedKVPools:
     def __init__(self, pool_k: List[jax.Array], pool_v: List[jax.Array],
                  scale_k: Optional[List[jax.Array]],
                  scale_v: Optional[List[jax.Array]],
-                 block_size: int, loop_steps: int = 1):
+                 block_size: int, loop_steps: int = 1,
+                 state_ssm: Optional[List[jax.Array]] = None,
+                 state_conv: Optional[List[jax.Array]] = None,
+                 kinds: Optional[List[str]] = None):
         self.pool_k = pool_k
         self.pool_v = pool_v
         self.scale_k = scale_k
@@ -135,6 +170,12 @@ class PagedKVPools:
         self.block_size = block_size
         # cache lines a layer's pool holds: a looped model's steps
         self.loop_steps = loop_steps
+        # the recurrent lines, one (ssm, conv) pair a Mamba-2 layer (None: a
+        # model without such layers), and the kind of state each consuming
+        # layer takes, in layer order
+        self.state_ssm = state_ssm
+        self.state_conv = state_conv
+        self.kinds = kinds
 
     @property
     def num_layers(self) -> int:
@@ -160,9 +201,23 @@ class PagedKVPools:
         once a step."""
         return block + self.num_blocks * np.arange(self.loop_steps)
 
+    @property
+    def state_lines(self) -> int:
+        """Layers that keep a recurrent line a slot."""
+        return len(self.state_ssm or ())
+
+    def state(self) -> Tuple:
+        """What the jitted programs take (donated) and return."""
+        kv = (self.pool_k, self.pool_v, self.scale_k, self.scale_v)
+        if self.state_ssm is None:
+            return kv
+        return kv + (self.state_ssm, self.state_conv)
+
     def absorb_state(self, state: Tuple) -> None:
         """Take back the updated state a jitted program returned."""
-        self.pool_k, self.pool_v, self.scale_k, self.scale_v = state
+        self.pool_k, self.pool_v, self.scale_k, self.scale_v = state[:4]
+        if self.state_ssm is not None:
+            self.state_ssm, self.state_conv = state[4:]
 
     def device_bytes(self) -> int:
         total = 0
@@ -173,10 +228,19 @@ class PagedKVPools:
                 total += a.size * a.dtype.itemsize
         return total
 
+    def state_bytes(self) -> int:
+        """Bytes of the recurrent lines (beside ``device_bytes``)."""
+        return sum(a.size * a.dtype.itemsize
+                   for arrs in (self.state_ssm or (), self.state_conv or ())
+                   for a in arrs)
+
 
 def init_pools(inference_module, num_blocks: int, block_size: int,
-               kv_dtype: str = "native") -> PagedKVPools:
+               kv_dtype: str = "native", num_slots: int = 0) -> PagedKVPools:
     """Allocate zeroed pools shaped by probing the real layer stack.
+
+    A stack with Mamba-2 layers also gets their recurrent lines, ``num_slots``
+    of each, shaped by the same probe (the final state of a one-token pass).
 
     ``kv_dtype``: ``'native'`` keeps the probe's KV dtype (the model's
     compute dtype); ``'int8'`` stores int8 values + float32 scales.
@@ -195,6 +259,18 @@ def init_pools(inference_module, num_blocks: int, block_size: int,
         return inference_module.prefill_forward(p, t, po)[1]
 
     kv_shapes = jax.eval_shape(probe, params, probe_tokens, probe_pos)
+    # a pattern stack's probe holds a line of its kind a consuming layer, in
+    # layer order: (k, v) of an attention layer, (ssm, conv) of a Mamba-2 one
+    kinds = [layer.consumes for layer in inference_module.module.layers
+             if getattr(layer, "consumes", None)]
+    state_shapes = []
+    if "ssm" in kinds:
+        state_shapes = [l for l, kind in zip(kv_shapes, kinds) if kind == "ssm"]
+        kv_shapes = [l for l, kind in zip(kv_shapes, kinds) if kind == "kv"]
+    if not kv_shapes:
+        raise ValueError(
+            "the layer stack keeps no KV cache line: the paged engine serves "
+            "stacks with at least one attention layer")
     # the probe returns the (k, v) of every cache line: a looped model's
     # lines are its steps x its layers, and a layer's pool holds its steps'
     loop_steps = inference_module.architecture.loop_steps
@@ -263,5 +339,18 @@ def init_pools(inference_module, num_blocks: int, block_size: int,
             scale_v.append(
                 placed((pool_blocks, block_size, n_kv), jnp.float32, 2)
             )
+    state_ssm = state_conv = None
+    if state_shapes:
+        if mesh is not None:
+            raise ValueError("recurrent state is not sharded: serve a "
+                             "layer_pattern stack at model_parallel_size 1")
+        if num_slots <= 0:
+            raise ValueError("a stack with recurrent layers needs num_slots: "
+                             "it keeps one state line a slot")
+        state_ssm = [placed((num_slots, *ssm.shape[1:]), ssm.dtype, 1)
+                     for ssm, _ in state_shapes]
+        state_conv = [placed((num_slots, *conv.shape[1:]), conv.dtype, 1)
+                      for _, conv in state_shapes]
     return PagedKVPools(pool_k, pool_v, scale_k, scale_v, block_size,
-                        loop_steps)
+                        loop_steps, state_ssm, state_conv,
+                        kinds if state_shapes else None)

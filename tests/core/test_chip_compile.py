@@ -115,6 +115,26 @@ def test_splash_survives_shard_map_on_2x2(topo):
 def test_paged_kernel_compiles(
     one_chip, slots, q_heads, kv_heads, max_blocks, s, kv_dtype
 ):
+    compile_paged_kernel(one_chip, slots, q_heads, kv_heads, max_blocks, s,
+                         kv_dtype)
+
+
+@pytest.mark.parametrize(
+    "s", [1, 32], ids=["decode-s1", "prefill-chunk-s32"]
+)
+def test_paged_kernel_compiles_at_group_16_over_2_kv_heads(one_chip, s):
+    """``serve-nemotron3nano-reason-burst``'s attention: 32 query heads over
+    2 KV heads x 128, 64 slots of 40 blocks. 2 KV heads are under a sublane
+    tile: a bf16 pool packs them into ONE 32-bit word a token and the kernel
+    reads that word's column (``_for_each_head``), which Mosaic takes. An
+    int8 pool of 2 heads it refuses (a slice of 2 along a dimension tiled by
+    4): the cell serves the native dtype, and `kv_dtype='int8'` at 2 KV
+    heads is an open item (PERF.md section 7)."""
+    compile_paged_kernel(one_chip, 64, 32, 2, 40, s, "native")
+
+
+def compile_paged_kernel(one_chip, slots, q_heads, kv_heads, max_blocks, s,
+                         kv_dtype):
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
@@ -143,7 +163,8 @@ def test_paged_kernel_compiles(
 
 
 def lowered_mixed_program(one_chip, monkeypatch, bucket, heads, kv_heads,
-                          layers=2, **architecture):
+                          layers=2, kv_layers=None, engine=None,
+                          **architecture):
     """The serve tick's program at one of its two token widths, lowered with
     donation on for the described chip, over abstract weights: ``(lowered,
     its parameter leaves, one pool)``. Attention at the given head counts
@@ -167,7 +188,8 @@ def lowered_mixed_program(one_chip, monkeypatch, bucket, heads, kv_heads,
             "gradient_accumulation_steps": 1,
         },
         "transformer_architecture": {
-            "vocab_size": 512, "hidden_size": heads * HEAD_DIM,
+            "vocab_size": 512,
+            "hidden_size": architecture.pop("hidden_size", heads * HEAD_DIM),
             "num_layers": layers, "num_attention_heads": heads,
             "attention_num_kv_heads": kv_heads, "attention_qkv_in_one": False,
             "attention_bias": False, "mlp_type": "swiglu",
@@ -190,7 +212,8 @@ def lowered_mixed_program(one_chip, monkeypatch, bucket, heads, kv_heads,
         TransformerInferenceModule(config, module, params),
         EngineConfig(num_slots=slots, block_size=BLOCK_SIZE,
                      num_blocks=slots * max_blocks + 1,
-                     max_blocks_per_seq=max_blocks, prefill_chunk=32),
+                     max_blocks_per_seq=max_blocks, prefill_chunk=32,
+                     **(engine or {})),
     )
     assert engine.config.mixed_widths == (128, 256)
     width = engine.config.mixed_widths[bucket]
@@ -203,7 +226,8 @@ def lowered_mixed_program(one_chip, monkeypatch, bucket, heads, kv_heads,
     steps = config.transformer_architecture.loop_steps
     assert pool.shape == (steps * (slots * max_blocks + 1), BLOCK_SIZE,
                           kv_heads, HEAD_DIM)
-    assert len(state[0]) == layers and engine.pools.kv_lines == steps * layers
+    kv_layers = layers if kv_layers is None else kv_layers
+    assert len(state[0]) == kv_layers and engine.pools.kv_lines == steps * kv_layers
     lowered = jax.jit(
         engine._build_mixed_fn(width).__wrapped__, donate_argnums=(1,),
         keep_unused=True,
@@ -276,3 +300,35 @@ def test_looped_mixed_program_is_rolled_and_updates_its_pools_in_place(
     text = looped.compile().as_text()
     assert text.count("tpu_custom_call") == layers
     assert_pools_updated_in_place(text, len(params), layers, pool)
+
+
+@pytest.mark.parametrize("bucket", [0, 1], ids=["small", "full"])
+def test_hybrid_mixed_program_updates_pools_and_recurrent_lines_in_place(
+        one_chip, monkeypatch, bucket):
+    """A pattern stack's tick (ISSUE 46) at Nemotron-3-Nano's attention shape
+    (32 query heads over 2 KV heads x 128, hidden 2688) and its Mamba-2 state
+    (64 heads x 64 x 128 float32 a slot), one layer of each kind: the kernel is
+    compiled once (the ONE attention layer), every donated leaf (K and V pool,
+    ssm and conv lines) is aliased to the output computed from it, and neither
+    a pool nor the recurrent lines are copied."""
+    pattern = ["mamba", "attention", "moe"]
+    lowered, params, pool = lowered_mixed_program(
+        one_chip, monkeypatch, bucket, heads=32, kv_heads=2, layers=len(pattern),
+        kv_layers=1, hidden_size=2688, attention_head_dim=HEAD_DIM,
+        layer_pattern=pattern, relative_position_embedding_type="none",
+        mlp_type="moe", moe_num_experts=8, moe_top_k=2, moe_expert_width=256,
+        moe_glu=False, moe_router="sigmoid_bias", moe_shared_expert_width=512,
+        moe_experts_held=4, activation_function="relu2",
+        engine={"enable_prefix_cache": False})
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    pairs = {int(param): int(out) for out, param in
+             re.findall(r"\{(\d+)\}: \((\d+), \{\}, \S+-alias\)", aliases)}
+    first = len(params)
+    assert pairs == {first + j: 1 + j for j in range(4)}   # k, v, ssm, conv
+    dims = ",".join(map(str, pool.shape))
+    for shape in (rf"bf16\[{dims}\]", r"f32\[8,64,64,128\]"):
+        copies = [c for c in re.findall(rf"= {shape}\S* copy\S*\(", text)
+                  if "S(1)" not in c]
+        assert not copies, f"{len(copies)} whole copies of {shape} in the compiled tick"

@@ -1,0 +1,300 @@
+"""A stack of single-mixer layers (``layer_pattern``: Mamba-2 / routed experts
+with a share held / attention) through ``ServeEngine``: a recurrent-state pool
+beside the paged KV pools, donated and aliased like them; a slot reused, a
+sequence preempted and recomputed; what is refused, by name; the spans' new
+fields, the counters and the stats."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from scaling_tpu import obs
+from scaling_tpu.models.transformer import TransformerConfig
+from scaling_tpu.models.transformer.inference import TransformerInferenceModule
+from scaling_tpu.models.transformer.model import init_model
+from scaling_tpu.serve.engine import EngineConfig, ServeEngine
+
+VOCAB = 96
+KINDS = {"M": "mamba", "E": "moe", "*": "attention"}
+PATTERN = "MEM*EM"
+TOPOLOGY = {"model_parallel_size": 1, "pipe_parallel_size": 1,
+            "data_parallel_size": 1, "micro_batch_size": 1,
+            "gradient_accumulation_steps": 1}
+ARCH = {"vocab_size": VOCAB, "hidden_size": 48, "num_layers": len(PATTERN),
+        "layer_pattern": [KINDS[c] for c in PATTERN],
+        "num_attention_heads": 4, "attention_num_kv_heads": 2,
+        "attention_head_dim": 16, "attention_qkv_in_one": False,
+        "attention_bias": False, "mlp_type": "moe", "mlp_bias": False,
+        "moe_num_experts": 8, "moe_top_k": 3, "moe_expert_width": 40,
+        "moe_glu": False, "moe_router": "sigmoid_bias",
+        "moe_routed_scaling_factor": 2.5, "moe_shared_expert_width": 56,
+        "moe_experts_first": 0, "moe_experts_held": 4,
+        "activation_function": "relu2", "norm_type": "rms",
+        "mamba_num_heads": 4, "mamba_head_dim": 8, "ssm_state_size": 16,
+        "n_groups": 2, "conv_kernel": 4,
+        "relative_position_embedding_type": "none", "sequence_length": 128,
+        "precision": "float32", "weight_tying": False}
+M_LAYERS = PATTERN.count("M")
+
+
+def hybrid_config(topology=None, **arch):
+    return TransformerConfig.from_dict({
+        "topology": {**TOPOLOGY, **(topology or {})},
+        "transformer_architecture": {**ARCH, **arch},
+        "data": {}, "logger": {"log_dir": None}})
+
+
+@pytest.fixture(scope="module")
+def hybrid():
+    config = hybrid_config()
+    module = init_model(config, None)
+    params = module.init_params(jax.random.PRNGKey(3))
+    leaves, treedef = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(4), len(leaves))
+    params = jax.tree.unflatten(treedef, [
+        x + 0.3 * jax.random.normal(k, x.shape) for x, k in zip(leaves, keys)])
+    return TransformerInferenceModule(config, module, params)
+
+
+def prompts(lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, VOCAB, size=n).tolist() for n in lengths]
+
+
+def engine_of(inf, **config):
+    return ServeEngine(inf, EngineConfig(**{
+        "num_slots": 4, "block_size": 4, "num_blocks": 64,
+        "max_blocks_per_seq": 12, "token_budget": 64, "prefill_chunk": 8,
+        "enable_prefix_cache": False, **config}))
+
+
+def served(engine, requests, max_new):
+    for p in requests:
+        engine.submit(p, max_new_tokens=max_new)
+    return {s.request.req_id: s.generated for s in engine.run_until_done()}
+
+
+@pytest.fixture(scope="module")
+def undisturbed(hybrid):
+    """Each prompt alone through the UNCACHED forward, greedy: what no cache,
+    no state pool and no batching can have touched. Beside each token, how far
+    the runner-up lies below it."""
+    requests = prompts((9, 21, 14, 30, 17))
+    want, margins = [], []
+    for p in requests:
+        out = hybrid.generate(p, max_tokens=10, use_cache=False)
+        want.append(out.completion_ids)
+        top2 = np.sort(np.asarray(out.logits), -1)[:, -2:]
+        margins.append(float((top2[:, 1] - top2[:, 0]).min()))
+    # greedy tokens compare exactly only where no near-tie can break the
+    # other way under another order of summation (float32: ~1e-5)
+    assert min(margins) > 1e-3
+    return requests, want
+
+
+def test_the_state_pool_is_one_line_per_slot_and_mamba_layer(hybrid):
+    engine = engine_of(hybrid)
+    pools, stats = engine.pools, engine.stats_snapshot()
+    assert pools.kinds == ["ssm", "ssm", "kv", "ssm"]      # consuming layers, in order
+    assert pools.kv_lines == stats["kv_lines"] == 1        # KV for the * layer only
+    assert pools.state_lines == stats["state_lines"] == M_LAYERS == engine.ssm_lines
+    assert [a.shape for a in pools.state_ssm] == [(4, 4, 8, 16)] * M_LAYERS
+    assert [a.shape for a in pools.state_conv] == [(4, 4 * 8 + 2 * 2 * 16, 3)] * M_LAYERS
+    assert all(a.dtype == jnp.float32 for a in pools.state_ssm)
+    assert stats["state_pool_bytes"] == pools.state_bytes() == M_LAYERS * 4 * (
+        4 * 8 * 16 * 4 + 96 * 3 * 4)
+    assert pools.pool_k[0].shape == (64, 4, 2, 16)
+    assert len(engine._pool_state()) == 6
+    # a model without recurrent layers keeps the four-entry state
+    from scaling_tpu.serve.bench import build_toy_inference
+
+    plain = engine_of(build_toy_inference(hidden=32, layers=2, vocab=64, heads=4))
+    assert len(plain._pool_state()) == 4 and plain.pools.kinds is None
+    assert plain.stats_snapshot()["state_lines"] == 0
+    assert plain.stats_snapshot()["state_pool_bytes"] == 0
+
+
+def test_the_engine_serves_what_the_uncached_forward_gives(hybrid, undisturbed):
+    """Prefill in chunks of 8 whose edges fall mid-prompt (9, 21, 14, 30, 17),
+    four rows at once and a fifth in a reused slot, then decode."""
+    requests, want = undisturbed
+    got = served(engine_of(hybrid), requests, 10)
+    assert [got[i] for i in range(len(requests))] == want
+    assert len({tuple(w) for w in want}) > 1  # the weights say something
+
+
+def test_a_reused_slot_does_not_inherit_its_old_occupants_state(hybrid, undisturbed):
+    """One slot: five sequences follow one another through the same lines of
+    the state pool, no reset by the host in between."""
+    requests, want = undisturbed
+    engine = engine_of(hybrid, num_slots=1)
+    got = served(engine, requests, 10)
+    assert [got[i] for i in range(len(requests))] == want
+    assert float(jnp.abs(engine.pools.state_ssm[0]).max()) > 0
+
+
+def test_a_preempted_and_resumed_sequence_reproduces_its_tokens(hybrid, undisturbed):
+    """A pool too small for the rows forces recompute-style preemption: the
+    resumed sequence re-enters at context 0, so the program zeroes its state
+    and the recompute regenerates token for token."""
+    requests, want = undisturbed
+    engine = engine_of(hybrid, num_blocks=17)
+    got = served(engine, requests, 10)
+    assert engine.scheduler.preemption_count > 0
+    assert any(s.preemptions for s in engine.finished)
+    assert [got[i] for i in range(len(requests))] == want
+
+
+@pytest.mark.parametrize("config,message", [
+    ({"enable_prefix_cache": True}, "prefix hit .* recurrent state never saw"),
+    ({"spec_k": 2}, "rejected draft has already advanced"),
+])
+def test_what_would_skip_or_rewind_the_state_is_refused_by_name(hybrid, config, message):
+    with pytest.raises(ValueError, match=message):
+        engine_of(hybrid, **config)
+    # the default EngineConfig has the prefix cache on: refused too, not
+    # silently turned off
+    with pytest.raises(ValueError, match="enable_prefix_cache"):
+        ServeEngine(hybrid, EngineConfig())
+
+
+@pytest.mark.parametrize("topology,arch,message", [
+    ({"pipe_parallel_size": 2}, {}, "layer_pattern with pipe_parallel_size 2"),
+    ({"model_parallel_size": 2}, {}, "layer_pattern with model_parallel_size 2"),
+    ({}, {"loop_steps": 2}, "layer_pattern with loop_steps"),
+    ({}, {"layer_pattern": ["mamba"]}, "names 1 layers, num_layers is 6"),
+    ({}, {"sandwich_norm": True}, "sandwich_norm or key_query_norm"),
+    ({}, {"n_groups": 3}, "not a multiple of n_groups"),
+    ({}, {"moe_experts_first": 6, "moe_experts_held": 4}, "do not lie in moe_num_experts"),
+    ({}, {"adapter_config": {"attention_downsampling_factor": 0.25}}, "adapter_config"),
+    ({}, {"layer_pattern": None, "num_layers": 2}, "attention_head_dim without layer_pattern"),
+])
+def test_a_layout_the_pattern_stack_does_not_build_is_refused_by_name(
+        topology, arch, message):
+    with pytest.raises(ValueError, match=message):
+        hybrid_config(topology, **arch)
+
+
+def test_training_and_cached_generate_are_refused_by_name(hybrid):
+    from scaling_tpu.nn.base_layer import ForwardContext
+
+    with pytest.raises(NotImplementedError, match="layer_pattern stack is served"):
+        hybrid.module.forward(hybrid.params, {}, ForwardContext())
+    with pytest.raises(ValueError, match="cached generate\\(\\) keeps dense KV"):
+        hybrid.generate([1, 2, 3], max_tokens=2)
+    # and a cache of the wrong kind, handed to a layer, by name
+    from scaling_tpu.serve.kvcache import build_layer_views
+
+    engine = engine_of(hybrid)
+    views = build_layer_views(
+        engine._pool_state(), jnp.zeros((4, 12), jnp.int32), jnp.zeros((4,), jnp.int32),
+        jnp.ones((4,), jnp.int32), kinds=["kv", "ssm", "ssm", "ssm"])
+    batch = hybrid._make_batch(jnp.ones((4, 8), jnp.int32), jnp.zeros((4, 8), jnp.int32))
+    with pytest.raises(ValueError, match="consumes a 'ssm' state and was handed"):
+        hybrid._run_layers(hybrid.params, batch, views, None, paged_kernel="xla")
+    with pytest.raises(ValueError, match="consumed 4 KV cache"):
+        hybrid._run_layers(hybrid.params, batch, build_layer_views(
+            engine._pool_state(), jnp.zeros((4, 12), jnp.int32),
+            jnp.zeros((4,), jnp.int32), jnp.ones((4,), jnp.int32),
+            kinds=engine.pools.kinds) + views[:1], None, paged_kernel="xla")
+
+
+def test_spans_counters_and_load_of_a_share(hybrid, tmp_path):
+    engine = engine_of(hybrid)
+    assert engine.num_experts == 4 and engine.moe_partial
+    requests = prompts((9, 12), seed=8)
+    obs.start_capture(str(tmp_path))
+    try:
+        served(engine, requests, 6)
+    finally:
+        capture = obs.stop_capture()
+    mixed = [f for n, _, _, f in capture.spans if n == "serve.mixed"]
+    emits = [f for n, _, _, f in capture.spans if n == "serve.emit"]
+    assert mixed and len(emits) == len(mixed)
+    assert all(f["ssm_lines"] == M_LAYERS for f in mixed)
+    assert [f["ssm_rows"] for f in mixed] == [f["decodes"] + f["chunks"] for f in mixed]
+    assert capture.counters["serve_ssm_state_updates_total"] == M_LAYERS * sum(
+        f["ssm_rows"] for f in mixed)
+    # every real position's 3 assignments in each of the 2 routed layers fell
+    # on a held expert or on an absent one
+    routed = PATTERN.count("E")
+    held = capture.counters["serve_moe_assignments_total"]
+    absent = capture.counters["serve_moe_absent_assignments_total"]
+    assert held + absent == 3 * routed * sum(f["tokens"] for f in mixed)
+    assert held > 0 and absent > 0
+    assert absent == sum(f["absent_assign"] for f in emits)
+    for f in emits:   # the load is over the four HELD experts
+        assert 0 <= f["experts_idle"] <= 4 and f["load_max"] >= f["load_mean"] >= 0
+
+
+def test_a_model_that_holds_all_its_experts_counts_what_it_counted(tmp_path):
+    """OLMoE-shaped: ``serve_moe_assignments_total`` is every assignment, no
+    absent count, no new span field."""
+    config = hybrid_config(moe_experts_held=None)
+    module = init_model(config, None)
+    inf = TransformerInferenceModule(
+        config, module, module.init_params(jax.random.PRNGKey(0)))
+    engine = engine_of(inf)
+    assert engine.num_experts == 8 and not engine.moe_partial
+    obs.start_capture(str(tmp_path))
+    try:
+        served(engine, prompts((9,), seed=2), 4)
+    finally:
+        capture = obs.stop_capture()
+    tokens = sum(f["tokens"] for n, _, _, f in capture.spans if n == "serve.mixed")
+    assert capture.counters["serve_moe_assignments_total"] == 3 * 2 * tokens
+    assert "serve_moe_absent_assignments_total" not in capture.counters
+    assert not any("absent_assign" in f for n, _, _, f in capture.spans
+                   if n == "serve.emit")
+
+
+def test_a_plain_models_spans_are_what_they_were(tmp_path):
+    from scaling_tpu.serve.bench import build_toy_inference
+
+    engine = engine_of(build_toy_inference(hidden=32, layers=2, vocab=64, heads=4))
+    obs.start_capture(str(tmp_path))
+    try:
+        served(engine, [[1, 2, 3, 4, 5]], 3)
+    finally:
+        capture = obs.stop_capture()
+    fields = set().union(*(f for n, _, _, f in capture.spans if n == "serve.mixed"))
+    assert not fields & {"ssm_rows", "ssm_lines"}
+    assert "serve_ssm_state_updates_total" not in capture.counters
+
+
+@pytest.mark.parametrize("bucket", [0, 1], ids=["small", "full"])
+def test_donated_state_aliases_the_output_computed_from_it(hybrid, bucket):
+    """The alias pin of tests/core/test_serve/test_kvcache.py for the second
+    kind of state: lowered with donation forced, every donated leaf (1 K and 1
+    V pool, 3 ssm and 3 conv lines) aliases the output at its own place in the
+    returned state; a copy of the recurrent lines a tick would be 0.96 GB at
+    the cell's size."""
+    engine = engine_of(hybrid, num_slots=16, prefill_chunk=32, num_blocks=16 * 12 + 1)
+    assert engine.config.mixed_widths == (128, 512)
+    width = engine.config.mixed_widths[bucket]
+    packed, tick = engine._layout.host(width)
+    tick.new_lens[:] = 1
+    args = (hybrid.params, engine._pool_state(), engine._dev(packed), engine._base_key)
+    fn = engine._build_mixed_fn(width).__wrapped__
+    sampled, state = jax.eval_shape(fn, *args)
+    structure = jax.tree_util.tree_structure
+    assert structure(state) == structure(engine._pool_state())
+    for got, held in zip(jax.tree_util.tree_leaves(state),
+                         jax.tree_util.tree_leaves(engine._pool_state())):
+        assert (got.shape, got.dtype) == (held.shape, held.dtype)
+    # the grid, the 4 held experts' load, the absent count
+    assert sampled.shape == (16 * engine.config.sample_width + 4 + 1,)
+    lowered = jax.jit(fn, donate_argnums=(1,), keep_unused=True).lower(*args)
+    signature = lowered.as_text().split("@main(", 1)[1].split(") -> ", 1)[0]
+    aliases = {}
+    for arg in signature.split("%arg")[1:]:
+        m = re.search(r"tf\.aliasing_output = (\d+)", arg)
+        if m:
+            aliases[int(arg.split(":", 1)[0])] = int(m.group(1))
+    first = len(jax.tree_util.tree_leaves(args[0]))
+    donated = jax.tree_util.tree_leaves(args[1])
+    assert len(donated) == 2 + 2 * M_LAYERS
+    assert aliases == {first + j: 1 + j for j in range(len(donated))}

@@ -1,7 +1,8 @@
 """Paged KV pool mechanics (ISSUE 9): flat-slot addressing, chunk
 scatter + block gather round-trips, int8 quantization accuracy on
 hand-built pools; then the donated pool state through the engine's
-program, dense, routed and sharded over two devices."""
+program, dense, routed, sharded over two devices, looped, and a pattern stack
+whose state carries recurrent lines beside the pools."""
 
 import re
 
@@ -130,7 +131,7 @@ def test_chunk_padding_lands_in_trash_not_blocks(toy_inference):
 SLOTS, MAX_BLOCKS, CHUNK = 8, 8, 32
 WIDTHS = (128, 256)
 LOOP_STEPS = 4
-MODELS = ["dense", "routed", "mp2", "looped"]
+MODELS = ["dense", "routed", "mp2", "looped", "hybrid"]
 
 
 @pytest.fixture(scope="module")
@@ -140,7 +141,10 @@ def inference_modules(toy_inference):
     flattened + the (E,) load); dense on a 2-device model-parallel
     serving mesh (pools sharded over ``model``); looped (three layers'
     pools of 4 x the blocks ride a rolled loop's carry, and its first
-    output is the grid flattened + the exit distribution's four numbers)."""
+    output is the grid flattened + the exit distribution's four numbers);
+    a pattern stack (three layers: Mamba-2, attention, routed with 2 of 4
+    experts held: ONE KV pool, one recurrent line a slot, and its first
+    output the grid flattened + the held load + the absent count)."""
     from scaling_tpu.models.transformer import TransformerConfig
     from scaling_tpu.models.transformer.inference import (
         TransformerInferenceModule,
@@ -172,7 +176,22 @@ def inference_modules(toy_inference):
             "weight_tying": False, "mlp_bias": False, "loop_steps": LOOP_STEPS,
             "sandwich_norm": True, "loop_exit_gate": True}})
     looped_module = init_model(looped, None)
+    hybrid = TransformerConfig.from_dict({
+        **routed.as_dict(), "transformer_architecture": {
+            "vocab_size": 64, "hidden_size": 32, "num_layers": 3,
+            "layer_pattern": ["mamba", "attention", "moe"],
+            "num_attention_heads": 4, "sequence_length": 256,
+            "mlp_type": "moe", "moe_expert_width": 16, "moe_num_experts": 4,
+            "moe_top_k": 2, "moe_glu": False, "moe_router": "sigmoid_bias",
+            "moe_experts_held": 2, "activation_function": "relu2",
+            "mamba_num_heads": 4, "mamba_head_dim": 8, "ssm_state_size": 8,
+            "n_groups": 2, "norm_type": "rms", "weight_tying": False,
+            "relative_position_embedding_type": "none", "mlp_bias": False}})
+    hybrid_module = init_model(hybrid, None)
     return {
+        "hybrid": TransformerInferenceModule(
+            hybrid, hybrid_module,
+            hybrid_module.init_params(jax.random.PRNGKey(0))),
         "looped": TransformerInferenceModule(
             looped, looped_module,
             looped_module.init_params(jax.random.PRNGKey(0))),
@@ -193,6 +212,9 @@ def _program_and_args(inf, kv_dtype, spec_k, width=WIDTHS[0]):
         num_slots=SLOTS, block_size=4, num_blocks=2 * MAX_BLOCKS + 1,
         max_blocks_per_seq=MAX_BLOCKS, token_budget=64, prefill_chunk=CHUNK,
         kv_dtype=kv_dtype, spec_k=spec_k,
+        # refused for a stack with recurrent layers (a hit would skip
+        # tokens the state never saw)
+        enable_prefix_cache=not inf.architecture.recurrent_layers,
     ))
     assert engine.config.mixed_widths == WIDTHS
 
@@ -228,8 +250,9 @@ def _aliases(lowered):
 @pytest.mark.parametrize("kv_dtype", ["native", "int8"])
 @pytest.mark.parametrize(
     "model,spec_k",
-    [("dense", 0), ("dense", 2), ("routed", 0), ("mp2", 0), ("looped", 0)],
-    ids=["mixed", "mixed-spec2", "routed", "mp2", "looped"],
+    [("dense", 0), ("dense", 2), ("routed", 0), ("mp2", 0), ("looped", 0),
+     ("hybrid", 0)],
+    ids=["mixed", "mixed-spec2", "routed", "mp2", "looped", "hybrid"],
 )
 def test_donated_pool_aliases_the_output_computed_from_it(
         inference_modules, model, spec_k, kv_dtype, width):
@@ -247,7 +270,11 @@ def test_donated_pool_aliases_the_output_computed_from_it(
     ``model`` and XLA does the pairing. Both token widths' programs
     donate and return the same state. A looped model's pools pass through
     its rolled loop's carry on their way from argument to output: still
-    one pool a LAYER, each aliased to the output computed from it."""
+    one pool a LAYER, each aliased to the output computed from it. A
+    pattern stack's state carries, after the pools of its ONE attention
+    layer, the ssm and conv lines of its Mamba-2 layer: donated and aliased
+    like them (a copy of the cell's 0.96 GB of lines would cost ~2.3 ms a
+    tick)."""
     _, fn, args = _program_and_args(
         inference_modules[model], kv_dtype, spec_k, width)
     lowered = jax.jit(fn, donate_argnums=(1,), keep_unused=True).lower(
@@ -255,7 +282,10 @@ def test_donated_pool_aliases_the_output_computed_from_it(
     )
     first = len(jax.tree_util.tree_leaves(args[0]))  # params come first
     donated = jax.tree_util.tree_leaves(args[1])
-    assert len(donated) == (12 if kv_dtype == "int8" else 6)
+    if model == "hybrid":  # 1 K + 1 V pool (+ 2 scales), 1 ssm + 1 conv line
+        assert len(donated) == (6 if kv_dtype == "int8" else 4)
+    else:
+        assert len(donated) == (12 if kv_dtype == "int8" else 6)
     # outputs flatten as (tokens, *state): state leaf j is output 1 + j
     want = {first + j: 1 + j for j in range(len(donated))}
     assert _aliases(lowered) == want
@@ -264,14 +294,15 @@ def test_donated_pool_aliases_the_output_computed_from_it(
 @pytest.mark.parametrize("width", WIDTHS, ids=["small", "full"])
 @pytest.mark.parametrize("kv_dtype", ["native", "int8"])
 @pytest.mark.parametrize("model", MODELS,
-                         ids=["mixed", "routed", "mp2", "looped"])
+                         ids=["mixed", "routed", "mp2", "looped", "hybrid"])
 def test_programs_return_the_state_in_pool_state_structure(
         inference_modules, model, kv_dtype, width):
     engine, fn, args = _program_and_args(
         inference_modules[model], kv_dtype, 0, width)
     sampled, state = jax.eval_shape(fn, *args)
     sw = engine.config.sample_width
-    tail = {"routed": engine.num_experts, "looped": LOOP_STEPS}
+    tail = {"routed": engine.num_experts, "looped": LOOP_STEPS,
+            "hybrid": 2 + 1}  # the held experts' load + the absent count
     assert sampled.shape == (
         (SLOTS * sw + tail[model],) if model in tail else (SLOTS, sw)
     )
@@ -281,8 +312,15 @@ def test_programs_return_the_state_in_pool_state_structure(
     for got, held in zip(jax.tree_util.tree_leaves(state),
                          jax.tree_util.tree_leaves(engine._pool_state())):
         assert (got.shape, got.dtype) == (held.shape, held.dtype)
-    # one pool a layer; a looped model's holds every step's blocks
+    # one pool a layer; a looped model's holds every step's blocks; a
+    # pattern stack has pools for its attention layers only, and lines of
+    # recurrent state, one a slot, for its Mamba-2 layers
     steps = LOOP_STEPS if model == "looped" else 1
+    if model == "hybrid":
+        assert len(state) == 6 and len(state[0]) == 1 == engine.pools.kv_lines
+        assert [a.shape[0] for a in state[4] + state[5]] == [SLOTS, SLOTS]
+        return
+    assert len(state) == 4
     assert len(state[0]) == 3 and engine.pools.kv_lines == 3 * steps
     assert state[0][0].shape[0] == steps * (2 * MAX_BLOCKS + 1)
 

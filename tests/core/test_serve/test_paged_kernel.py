@@ -403,3 +403,44 @@ def test_what_crosses_a_grid_step_is_a_function_of_valid_len(
         jnp.minimum(jnp.asarray(valid, jnp.int32), 24), 8)
     assert got_before.tolist() == before
     assert got_next.tolist() == following
+
+
+def test_group_16_over_2_kv_heads_agrees_with_the_gather_formulation():
+    """Nemotron-3-Nano's attention: 32 query heads over 2 KV heads x 128 (GQA
+    group 16; 2 KV heads are under a sublane tile, one packed word a token),
+    through ``ParallelSelfAttention._paged_attention`` on a mixed tick (a
+    decode row, a chunk row, an empty one): the Pallas kernel against the
+    ``'xla'`` gather formulation it is held to. bf16 inputs, float32
+    accumulation on both sides: the outputs differ by the rounding of the
+    probabilities to bf16 before PV (2**-9 of values under 1 in magnitude)."""
+    from scaling_tpu.nn.attention import PagedKVCacheView, ParallelSelfAttention
+    from scaling_tpu.nn.base_layer import ForwardContext
+
+    hidden, heads, kv_heads, head_dim = 96, 32, 2, 128
+    attn = ParallelSelfAttention(
+        hidden, heads, num_kv_heads=kv_heads, head_dim=head_dim, qkv_in_one=False,
+        bias=False, dtype=jnp.bfloat16, relative_position_embedding_type="none")
+    params = attn.init(jax.random.PRNGKey(0))
+    assert params["query"]["weight"].shape == (hidden, heads * head_dim)
+    assert params["key"]["weight"].shape == (hidden, kv_heads * head_dim)
+    assert params["dense"]["weight"].shape == (heads * head_dim, hidden)
+    rows, s, block, max_blocks = 3, 8, 16, 4
+    x = jax.random.normal(jax.random.PRNGKey(1), (rows, s, hidden), jnp.bfloat16)
+    pool = jax.random.normal(
+        jax.random.PRNGKey(2), (rows * max_blocks + 1, block, kv_heads, head_dim),
+        jnp.bfloat16)
+    view = PagedKVCacheView(
+        pool_k=pool, pool_v=pool[::-1],
+        block_table=1 + jnp.arange(rows * max_blocks, dtype=jnp.int32).reshape(rows, -1),
+        context_len=jnp.asarray([37, 16, 0], jnp.int32),
+        new_len=jnp.asarray([1, 8, 0], jnp.int32))
+    outs = {}
+    for kernel in ("pallas", "xla"):
+        out, new_view = attn(params, x, ForwardContext(paged_kernel=kernel),
+                             kv_cache=view)
+        outs[kernel] = np.asarray(out.astype(jnp.float32))
+        assert new_view.pool_k.shape == pool.shape
+    for row, n in ((0, 1), (1, 8)):
+        np.testing.assert_allclose(outs["pallas"][row, :n], outs["xla"][row, :n],
+                                   atol=2e-2, rtol=2e-2)
+        assert np.abs(outs["xla"][row, :n]).max() > 0.1
