@@ -143,6 +143,46 @@ def _sampler_cache_id(sample: Callable) -> Any:
     return getattr(sample, "_sampler_key", sample)
 
 
+def _mask_top_k_top_p(
+    scaled: jax.Array,            # (rows, vocab) f32 logits / temperature
+    top_ks: jax.Array,            # (rows,) i32
+    top_ps: Optional[jax.Array],  # (rows,) f32
+) -> jax.Array:
+    """``scaled`` with what a row's top-k and then its nucleus cut away
+    at -inf: ``make_sampler``'s two masks with k and p as traced per-row
+    data, bit for bit, from ONE sort of the vocabulary."""
+    vocab = scaled.shape[-1]
+    # traced per-row k: make_sampler's static `sort(...)[..., -k]` becomes
+    # a take_along_axis at index vocab - k on the ascending sort — the
+    # identical cutoff value, so the masked logits match bit-for-bit
+    sorted_scaled = jnp.sort(scaled, axis=-1)
+    k_active = ((top_ks > 0) & (top_ks < vocab))[:, None]
+    k_idx = jnp.clip(vocab - top_ks, 0, vocab - 1)
+    kth = jnp.take_along_axis(sorted_scaled, k_idx[:, None], axis=-1)
+    masked = jnp.where(k_active & (scaled < kth), -jnp.inf, scaled)
+    if top_ps is None:
+        return masked
+    # nucleus cutoff AFTER top-k, exactly make_sampler's order: its mass is
+    # computed over the surviving (possibly -inf-masked) logits, descending.
+    # make_sampler sorts those again; the top-k mask is by value and what
+    # it masks is the smallest, so the same mask on the sort already made,
+    # reversed, holds the very same values
+    p_active = (top_ps > 0.0) & (top_ps < 1.0)
+    sorted_desc = jnp.where(
+        k_active & (sorted_scaled < kth), -jnp.inf, sorted_scaled
+    )[..., ::-1]
+    probs = jax.nn.softmax(sorted_desc, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    keep_sorted = cum - probs < top_ps[:, None]
+    kept = jnp.sum(keep_sorted, axis=-1, keepdims=True)
+    cutoff = jnp.take_along_axis(
+        sorted_desc, jnp.maximum(kept - 1, 0), axis=-1
+    )
+    return jnp.where(
+        p_active[:, None] & (masked < cutoff), -jnp.inf, masked
+    )
+
+
 def sample_rows(
     logits: jax.Array,       # (rows, vocab)
     temperatures: jax.Array,  # (rows,) f32; <= 0 means greedy
@@ -157,51 +197,39 @@ def sample_rows(
     sampler configuration must be traced per-row data, never baked-in
     constants (a per-config program would be a recompile per request —
     the exact storm the ``serve_decode`` golden pins against). The math
-    mirrors ``make_sampler`` op-for-op (same temperature clamp, same
-    sort-based top-k cutoff, same nucleus cutoff over the descending
-    sort, same ``jax.random.categorical``) so a row here and a
-    single-request ``generate()`` with the same settings and key draw
-    the SAME token — parity-pinned in tests/transformer/test_serving.py.
-    ``temperature <= 0`` short-circuits to argmax: greedy stays the
-    default AND the zero-temperature limit, with no randomness
-    consumed."""
-    vocab = logits.shape[-1]
-    greedy = jnp.argmax(logits, axis=-1)
-    scaled = logits.astype(jnp.float32) / jnp.maximum(
-        temperatures, 1e-6
-    )[:, None]
-    # traced per-row k: make_sampler's static `sort(...)[..., -k]` becomes
-    # a take_along_axis at index vocab - k on the ascending sort — the
-    # identical cutoff value, so the masked logits match bit-for-bit
-    sorted_scaled = jnp.sort(scaled, axis=-1)
-    k_active = (top_ks > 0) & (top_ks < vocab)
-    k_idx = jnp.clip(vocab - top_ks, 0, vocab - 1)
-    kth = jnp.take_along_axis(sorted_scaled, k_idx[:, None], axis=-1)
-    scaled = jnp.where(
-        k_active[:, None] & (scaled < kth), -jnp.inf, scaled
+    mirrors ``make_sampler`` (same temperature clamp, same sort-based
+    top-k cutoff, same nucleus cutoff over the descending sort, same
+    ``jax.random.categorical``) so a row here and a single-request
+    ``generate()`` with the same settings and key draw the SAME token —
+    parity-pinned in tests/transformer/test_serving.py.
+    ``temperature <= 0`` is argmax: greedy stays the default AND the
+    zero-temperature limit, with no randomness consumed.
+
+    The cost follows the rows: ONE ``lax.cond`` on the call's own
+    temperatures. A call in which no row samples runs the argmax and
+    nothing else; everything only a sampling row reads (the float32
+    divide, the sort, softmax / cumsum, the categorical's Gumbel draw)
+    lives in the other branch. Greedy is so neither a separate program
+    nor the sampling arithmetic, and a sampled row draws the same token
+    whichever branch the calls around it took."""
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def sampled_path():
+        scaled = logits.astype(jnp.float32) / jnp.maximum(
+            temperatures, 1e-6
+        )[:, None]
+        masked = _mask_top_k_top_p(scaled, top_ks, top_ps)
+        sampled = jax.vmap(
+            lambda key, row: jax.random.categorical(
+                key, row[None], axis=-1)[0]
+        )(keys, masked)
+        return jnp.where(
+            temperatures <= 0.0, greedy, sampled.astype(jnp.int32)
+        )
+
+    return jax.lax.cond(
+        jnp.any(temperatures > 0.0), sampled_path, lambda: greedy
     )
-    if top_ps is not None:
-        # nucleus cutoff AFTER top-k, exactly make_sampler's order; the
-        # math is already shape-static in p, so the per-row threshold
-        # simply rides in as traced data — same ops, bit-identical mask
-        p_active = (top_ps > 0.0) & (top_ps < 1.0)
-        # re-sort AFTER the top-k mask, like make_sampler: nucleus mass
-        # is computed over the surviving (possibly -inf-masked) logits
-        sorted_desc = jnp.sort(scaled, axis=-1)[..., ::-1]
-        probs = jax.nn.softmax(sorted_desc, axis=-1)
-        cum = jnp.cumsum(probs, axis=-1)
-        keep_sorted = cum - probs < top_ps[:, None]
-        kept = jnp.sum(keep_sorted, axis=-1, keepdims=True)
-        cutoff = jnp.take_along_axis(
-            sorted_desc, jnp.maximum(kept - 1, 0), axis=-1
-        )
-        scaled = jnp.where(
-            p_active[:, None] & (scaled < cutoff), -jnp.inf, scaled
-        )
-    sampled = jax.vmap(
-        lambda key, row: jax.random.categorical(key, row[None], axis=-1)[0]
-    )(keys, scaled)
-    return jnp.where(temperatures <= 0.0, greedy, sampled).astype(jnp.int32)
 
 
 def request_sample_key(base_key: jax.Array, req_id: jax.Array,

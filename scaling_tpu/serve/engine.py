@@ -42,7 +42,9 @@ as golden drift in CI instead.
 
 Sampling is per-request (``inference.sample_rows``): temperature /
 top-k / top-p ride the jitted programs as traced per-row arrays, greedy
-is the ``temperature=0`` default. Sample keys derive from (request id,
+is the ``temperature=0`` default, and a tick in which no row samples
+runs the sampler's argmax branch alone (one ``cond`` on the tick's
+temperatures; no sort, no draw). Sample keys derive from (request id,
 token position) — ``inference.request_sample_key`` — so a preempted-
 and-resumed sequence redraws the SAME tokens and recompute-style
 preemption (scheduler.py) stays invisible in the output even for
@@ -341,6 +343,9 @@ class ServeEngine:
         # apart): how often the small program serves, and the padding left
         self.mixed_ticks: Dict[int, int] = {}
         self.mixed_tokens: Dict[int, int] = {}
+        # of those ticks, the ones in which a row sampled (temperature > 0):
+        # the program's sampler took its sorting branch (sample_rows)
+        self.sampled_ticks = 0
         # a routed model (mlp_type moe): the mixed program also returns,
         # in the tick's one host read, how many assignments of real
         # positions each expert received (0: a dense model, which pays
@@ -860,11 +865,20 @@ class ServeEngine:
                 # among them: of a call's kv_tiles fetches, kv_rows - 1 are
                 # first tiles that start under another row's fold
                 held = ctx + new_lens
+                # the predicate of the program's sampler (sample_rows), known
+                # here before the call: a freed slot's temperature is 0
+                sampled_rows = int(np.count_nonzero(self._temp > 0.0))
                 mixed_span.annotate(
                     width=width, tokens=len(real),
+                    sampled_rows=sampled_rows,
                     kv_rows=int(np.count_nonzero(held)),
                     kv_tiles=int((-(-held // self._kv_tile)).sum()),
                 )
+                self.sampled_ticks += sampled_rows > 0
+                self._counter(
+                    "serve_sampler_ticks_total",
+                    path="sampled" if sampled_rows else "greedy",
+                ).inc()
                 if self.ssm_lines:
                     # rows whose recurrent state advanced, in every M layer
                     ssm_rows = int(np.count_nonzero(new_lens))
@@ -1281,6 +1295,12 @@ class ServeEngine:
             # 1.0, the one packed operand (None before the first)
             "tick_operands": (
                 self.tick_operands / sum(self.mixed_ticks.values())
+                if self.mixed_ticks else None
+            ),
+            # share of the counted ticks in which a row sampled: the rest
+            # ran the sampler's argmax branch and no sort
+            "sampled_tick_share": (
+                self.sampled_ticks / sum(self.mixed_ticks.values())
                 if self.mixed_ticks else None
             ),
             # cache lines a token's K and V are written to (a looped model:

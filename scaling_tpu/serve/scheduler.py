@@ -61,8 +61,8 @@ class Request:
     ``temperature`` / ``top_k`` / ``top_p`` are per-request sampler
     settings carried into the engine's jitted programs as traced per-row
     arrays (inference.sample_rows); ``temperature=0`` (the default) is
-    greedy — the zero-temperature special case, not a separate code
-    path."""
+    greedy — the zero-temperature special case, not a separate
+    program."""
 
     req_id: int
     prompt: List[int]

@@ -411,3 +411,76 @@ def test_the_span_counts_the_rows_and_tiles_the_kernel_reads(
     fields = tiled_ticks[tick]
     assert (fields["kv_rows"], fields["kv_tiles"]) == (
         len(held), sum(-(-h // 32) for h in held))
+
+
+# ------------- the tick says which branch its sampler takes (ISSUE 47)
+@pytest.fixture(scope="module")
+def sampler_ticks(models, tmp_path_factory):
+    """A greedy request of 8 tokens beside two sampling ones of 3 and 2:
+    rows with a temperature in the first three ticks, none afterwards."""
+    engine = make_engine(models["dense"])
+    obs.start_capture(tmp_path_factory.mktemp("sampler") / "trace")
+    try:
+        engine.submit([5, 6, 7], 8)
+        engine.submit([1, 2], 3, temperature=0.8, top_k=4)
+        engine.submit([3], 2, temperature=1.2, top_p=0.9)
+        engine.run_until_done()
+    finally:
+        capture = obs.stop_capture()
+    return engine, capture, [
+        f for name, _, _, f in capture.spans if name == "serve.mixed"]
+
+
+def test_the_span_counts_the_rows_that_sample(sampler_ticks):
+    """``sampled_rows`` = the slots whose request has a temperature > 0;
+    a finished request's slot stops counting with the tick after."""
+    _, _, spans = sampler_ticks
+    assert [f["sampled_rows"] for f in spans] == [2, 2, 1, 0, 0, 0, 0, 0]
+
+
+def test_ticks_are_counted_by_the_sampler_s_branch(sampler_ticks):
+    engine, capture, spans = sampler_ticks
+    assert capture.counters[
+        "serve_sampler_ticks_total{path=sampled}"] == 3
+    assert capture.counters[
+        "serve_sampler_ticks_total{path=greedy}"] == 5
+    assert engine.sampled_ticks == 3
+    assert engine.stats_snapshot()["sampled_tick_share"] == 3 / len(spans)
+
+
+def test_a_greedy_engine_never_counts_a_sampled_tick(served):
+    engine, _, capture, _ = served
+    spans = [f for name, _, _, f in capture.spans if name == "serve.mixed"]
+    assert {f["sampled_rows"] for f in spans} == {0}
+    assert capture.counters[
+        "serve_sampler_ticks_total{path=greedy}"] == len(spans)
+    assert "serve_sampler_ticks_total{path=sampled}" not in capture.counters
+    assert engine.stats_snapshot()["sampled_tick_share"] == 0.0
+
+
+def test_warm_up_and_untraced_ticks_of_a_sampling_request(models):
+    """Warm-up ticks write no field and move no counter, sampling or not;
+    with no capture running a counted tick's row holds the field all the
+    same, and no trace list when no request is traced."""
+    def counted():
+        return {k: v for k, v in obs.get_registry().snapshot()[
+            "counters"].items() if k.startswith("serve_sampler_ticks_total")}
+
+    engine = make_engine(models["dense"])
+    before, mark = counted(), obs.recorded_spans()[-1].start_ns + 1
+    engine.warmup_mode = True
+    engine.submit([1, 2], 3, temperature=0.8)
+    engine.run_until_done()
+    engine.warmup_mode = False
+    assert engine.sampled_ticks == 0 and counted() == before
+    assert engine.stats_snapshot()["sampled_tick_share"] is None
+    engine.submit([1, 2], 3, temperature=0.8)
+    engine.run_until_done()
+    rows = obs.recorded_spans(since_ns=mark, name="serve.mixed")
+    assert [r.fields["sampled_rows"] for r in rows] == [1, 1, 1]
+    assert all("traces" not in r.fields for r in rows)
+    assert engine.stats_snapshot()["sampled_tick_share"] == 1.0
+    moved = {k: v - before.get(k, 0) for k, v in counted().items()
+             if v != before.get(k, 0)}
+    assert moved == {"serve_sampler_ticks_total{path=sampled}": 3}
+

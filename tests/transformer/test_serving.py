@@ -402,6 +402,232 @@ def test_sample_rows_is_per_row():
     assert 0 <= int(toks[2]) < 31
 
 
+# ------------------------ the sampler does the work its rows ask for (ISSUE 47)
+# (temperature, top_k, top_p): the zoo of the parity test above
+SAMPLER_ZOO = [
+    (0.7, None, None), (1.0, 3, None), (1.3, 10, None), (0.2, 1, None),
+    (1.0, None, None), (2.5, 53, None), (1.0, None, 0.9), (0.7, None, 0.5),
+    (1.5, 10, 0.8), (1.0, 3, 0.99), (2.0, None, 0.05)]
+SAMPLING_ARITHMETIC = {"sort", "cumsum", "cumlogsumexp", "div", "exp", "log"}
+
+
+def _primitives(jaxpr, into_cond=True):
+    """Names of the primitives of a jaxpr and of every jaxpr it holds;
+    with ``into_cond`` off, a ``cond`` is named and not entered."""
+    from jax.extend import core
+
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        if eqn.primitive.name == "cond" and not into_cond:
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                if isinstance(sub, core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, core.Jaxpr):
+                    names.extend(_primitives(sub, into_cond))
+    return names
+
+
+def _spends_like_a_sampler(names):
+    return sorted({n for n in names
+                   if n in SAMPLING_ARITHMETIC or n.startswith(("random_", "threefry"))})
+
+
+def test_sample_rows_sorts_and_draws_only_inside_its_cond():
+    """ONE conditional on the call's temperatures: outside it and in its
+    greedy branch no sort, no divide, no softmax and no random bits; the
+    sampling branch holds one sort of the vocabulary, not two."""
+    import jax
+    import jax.numpy as jnp
+
+    from scaling_tpu.models.transformer.inference import sample_rows
+
+    rows, vocab = 6, 53
+    jaxpr = jax.make_jaxpr(sample_rows)(
+        jnp.zeros((rows, vocab), jnp.bfloat16), jnp.zeros((rows,)),
+        jnp.zeros((rows,), jnp.int32), jnp.zeros((rows, 2), jnp.uint32),
+        jnp.zeros((rows,))).jaxpr
+    outside = _primitives(jaxpr, into_cond=False)
+    assert outside.count("cond") == 1
+    assert _spends_like_a_sampler(outside) == []
+    cond, = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    greedy, sampled = (_primitives(b.jaxpr) for b in cond.params["branches"])
+    assert _spends_like_a_sampler(greedy) == []
+    assert sampled.count("sort") == 1
+    assert {"random_bits", "div", "cumsum"} <= set(sampled)
+
+
+@pytest.fixture(scope="module")
+def mixed_batch():
+    """Every setting of the zoo in ONE call, greedy rows among them, each
+    row with logits and a key of its own."""
+    import jax
+    import jax.numpy as jnp
+
+    from scaling_tpu.models.transformer.inference import sample_rows
+
+    settings = []
+    for i, setting in enumerate(SAMPLER_ZOO):
+        if i % 3 == 0:
+            settings.append((0.0, None, None))
+        settings.append(setting)
+    settings.append((0.0, 5, 0.5))  # greedy whatever else it carries
+    rng = np.random.default_rng(47)
+    operands = (
+        jnp.asarray(rng.normal(size=(len(settings), 53)) * 4.0, jnp.float32),
+        jnp.asarray([t for t, _, _ in settings], jnp.float32),
+        jnp.asarray([k or 0 for _, k, _ in settings], jnp.int32),
+        jnp.stack([jax.random.PRNGKey(100 + i)
+                   for i in range(len(settings))]),
+        jnp.asarray([p or 0.0 for _, _, p in settings], jnp.float32),
+    )
+    logits, temps, topks, keys, topps = operands
+    tokens = np.asarray(
+        jax.jit(sample_rows)(logits, temps, topks, keys, top_ps=topps))
+    return settings, operands, tokens
+
+
+@pytest.mark.parametrize("setting", SAMPLER_ZOO + [(0.0, None, None),
+                                                   (0.0, 5, 0.5)],
+                         ids=lambda s: "t{}-k{}-p{}".format(*s))
+def test_a_row_of_a_mixed_batch_draws_what_it_draws_alone(mixed_batch,
+                                                          setting):
+    """Greedy rows among sampling rows: each row gets bit for bit the
+    token it gets in a call of its own with its key (a greedy row's own
+    call takes the argmax branch, the batch's the sampling one), and a
+    sampling row the token ``make_sampler`` draws."""
+    import jax.numpy as jnp
+
+    from scaling_tpu.models.transformer.inference import (
+        make_sampler, sample_argmax, sample_rows,
+    )
+
+    settings, (logits, temps, topks, keys, topps), tokens = mixed_batch
+    mine = [i for i, s in enumerate(settings) if s == setting]
+    assert mine
+    for i in mine:
+        row = slice(i, i + 1)
+        alone = sample_rows(logits[row], temps[row], topks[row], keys[row],
+                            top_ps=topps[row])
+        assert int(alone[0]) == tokens[i]
+        temperature, top_k, top_p = setting
+        if temperature > 0:
+            ref = make_sampler(temperature=temperature, top_k=top_k,
+                               top_p=top_p)(logits[row], keys[i])
+        else:
+            ref = sample_argmax(logits[row])
+        assert int(jnp.ravel(ref)[0]) == tokens[i]
+
+
+def test_an_all_greedy_batch_is_the_argmax(mixed_batch):
+    import jax.numpy as jnp
+
+    from scaling_tpu.models.transformer.inference import (
+        sample_argmax, sample_rows,
+    )
+
+    _, (logits, temps, topks, keys, topps), _ = mixed_batch
+    got = sample_rows(logits, jnp.zeros_like(temps), topks, keys,
+                      top_ps=topps)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(got, sample_argmax(logits))
+    # a negative temperature is greedy too, and takes the argmax branch
+    np.testing.assert_array_equal(
+        sample_rows(logits, -jnp.ones_like(temps), topks, keys, top_ps=topps),
+        got)
+
+
+def _mask_with_two_sorts(scaled, top_ks, top_ps):
+    """The form ``sample_rows`` had (and ``make_sampler`` has): the
+    nucleus sorts the top-k-masked logits again. The reference only."""
+    import jax
+    import jax.numpy as jnp
+
+    vocab = scaled.shape[-1]
+    sorted_scaled = jnp.sort(scaled, axis=-1)
+    k_active = (top_ks > 0) & (top_ks < vocab)
+    k_idx = jnp.clip(vocab - top_ks, 0, vocab - 1)
+    kth = jnp.take_along_axis(sorted_scaled, k_idx[:, None], axis=-1)
+    scaled = jnp.where(k_active[:, None] & (scaled < kth), -jnp.inf, scaled)
+    p_active = (top_ps > 0.0) & (top_ps < 1.0)
+    sorted_desc = jnp.sort(scaled, axis=-1)[..., ::-1]
+    probs = jax.nn.softmax(sorted_desc, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    keep_sorted = cum - probs < top_ps[:, None]
+    kept = jnp.sum(keep_sorted, axis=-1, keepdims=True)
+    cutoff = jnp.take_along_axis(
+        sorted_desc, jnp.maximum(kept - 1, 0), axis=-1)
+    return jnp.where(p_active[:, None] & (scaled < cutoff), -jnp.inf, scaled)
+
+
+@pytest.mark.parametrize("logits_kind", ["ties", "minus-inf", "both", "plain"])
+def test_one_sort_masks_exactly_what_two_sorts_masked(logits_kind):
+    """Step 2: the nucleus reads the top-k-masked logits in descending
+    order off the ONE sort, masked by the same value and reversed; on
+    logits with ties (at the k-th value too) and with -inf entries the
+    masked logits, so cutoff and draw, are the two-sort form's exactly."""
+    import jax.numpy as jnp
+
+    from scaling_tpu.models.transformer.inference import _mask_top_k_top_p
+
+    rng = np.random.default_rng(11)
+    rows, vocab = 96, 67
+    scaled = rng.normal(size=(rows, vocab)) * 3.0
+    if logits_kind in ("ties", "both"):
+        scaled = np.round(scaled)  # ~20 distinct values a row
+    if logits_kind in ("minus-inf", "both"):
+        scaled[rng.random((rows, vocab)) < 0.3] = -np.inf
+        scaled[:, 0] = 1.0  # never a row of nothing
+    scaled = jnp.asarray(scaled, jnp.float32)
+    top_ks = jnp.asarray(rng.choice([0, 1, 2, 5, 20, 66, 67, 200], rows),
+                         jnp.int32)
+    top_ps = jnp.asarray(rng.choice([0.0, 1e-6, 0.05, 0.5, 0.9, 0.99, 1.0],
+                                    rows), jnp.float32)
+    got = np.asarray(_mask_top_k_top_p(scaled, top_ks, top_ps))
+    want = np.asarray(_mask_with_two_sorts(scaled, top_ks, top_ps))
+    np.testing.assert_array_equal(got, want)
+    assert np.isneginf(got).sum() > np.isneginf(np.asarray(scaled)).sum()
+
+
+def test_a_sampling_request_among_greedy_ones_changes_no_stream(
+        trained_inference):
+    """One request at temperature 0.9 decodes beside greedy ones, so the
+    ticks it lives in take the sampling branch and the others the argmax
+    one: every request's tokens are those it gets served alone under its
+    own id (the keys fold (request, position), never the tick)."""
+    requests = [
+        dict(prompt=PROMPTS[0], max_new_tokens=3),
+        dict(prompt=PROMPTS[1], max_new_tokens=MAX_NEW, temperature=0.9,
+             top_k=5, top_p=0.95),
+        dict(prompt=PROMPTS[2], max_new_tokens=10),
+    ]
+
+    def run(reqs):
+        engine = ServeEngine(trained_inference, EngineConfig(
+            num_slots=4, block_size=4, num_blocks=32, max_blocks_per_seq=8,
+            token_budget=64, prefill_chunk=4,
+        ))
+        for req_id, req in reqs:
+            engine.submit(req_id=req_id, **req)
+        finished = engine.run_until_done()
+        return engine, {s.request.req_id: s.generated for s in finished}
+
+    engine, together = run(list(enumerate(requests)))
+    # the sampling request came and went: ticks of both branches were run
+    ticks = sum(engine.mixed_ticks.values())
+    assert 0 < engine.sampled_ticks < ticks
+    assert engine.stats_snapshot()["sampled_tick_share"] == (
+        engine.sampled_ticks / ticks)
+    for req_id, req in enumerate(requests):
+        _, alone = run([(req_id, req)])
+        assert alone[req_id] == together[req_id], req_id
+    greedy = trained_inference.generate(
+        PROMPTS[2], max_tokens=10, use_cache=True).completion_ids
+    assert together[2] == greedy
+
+
 def test_top_p_is_per_row_and_deterministic(trained_inference):
     """Per-request top-p rides the programs as a traced per-row array:
     a tight nucleus on a peaked model collapses to greedy, and the same
