@@ -53,11 +53,14 @@ class MLPType(Enum):
 
 class LayerKind(Enum):
     """The ONE mixer a layer of a ``layer_pattern`` stack has (Nemotron-H's
-    ``hybrid_override_pattern``: ``M``, ``E``, ``*``)."""
+    ``hybrid_override_pattern``: ``M``, ``E``, ``*``). A block of two
+    pre-norm sub-blocks, operator then FFN (LFM2's), is two such layers."""
 
     MAMBA = "mamba"          # Mamba-2 state-space mixer (nn/mamba.py)
     MOE = "moe"              # routed MLP (nn/moe.py)
     ATTENTION = "attention"  # softmax attention
+    CONV = "conv"            # gated short convolution (nn/short_conv.py)
+    MLP = "mlp"              # dense MLP of mlp_type / mlp_factor (nn/mlp.py)
 
 
 class MoERouter(Enum):
@@ -262,6 +265,11 @@ class TransformerArchitectureConfig(BaseConfig):
         1.0, description="factor on the chosen experts' gates "
         "(routed_scaling_factor)", gt=0,
     )
+    moe_norm_topk_eps: float = Field(
+        1e-20, description="with moe_router 'sigmoid_bias' and "
+        "moe_norm_topk_prob: what is added to the sum of the chosen scores "
+        "before they are divided by it (Nemotron-H 1e-20, LFM2 1e-6)", ge=0,
+    )
     moe_shared_expert_width: Optional[int] = Field(
         None,
         description="intermediate width of the ONE shared expert every token "
@@ -295,7 +303,9 @@ class TransformerArchitectureConfig(BaseConfig):
     ssm_state_size: int = Field(128, description="state size N a head", gt=0)
     n_groups: int = Field(
         8, description="groups sharing B and C, and of the gated norm", gt=0)
-    conv_kernel: int = Field(4, description="depthwise causal conv taps", gt=1)
+    conv_kernel: int = Field(
+        4, description="depthwise causal conv taps, of a Mamba-2 mixer and of "
+        "a gated short convolution (LFM2's conv_L_cache)", gt=1)
     time_step_min: float = Field(0.001, description="dt init range", gt=0)
     time_step_max: float = Field(0.1, description="dt init range", gt=0)
     time_step_floor: float = Field(1e-4, description="dt init floor", gt=0)
@@ -507,10 +517,22 @@ class TransformerArchitectureConfig(BaseConfig):
                     f"layer_pattern with {name}: the single-mixer layer "
                     "builds none of the fine-tuning modules"
                 )
-        if self.sandwich_norm or self.key_query_norm:
+        if self.sandwich_norm:
             raise ValueError(
-                "layer_pattern with sandwich_norm or key_query_norm: the "
-                "single-mixer layer has one norm, before its mixer"
+                "layer_pattern with sandwich_norm: the single-mixer layer has "
+                "one norm, before its mixer"
+            )
+        if (self.key_query_norm
+                and self.key_query_norm_scope != KeyQueryNormScope.HEAD):
+            raise ValueError(
+                "layer_pattern with key_query_norm_scope 'projection': an "
+                "attention mixer norms q and k per head only (before rotary)"
+            )
+        if LayerKind.MLP in self.layer_pattern and self.mlp_type == MLPType.MOE:
+            raise ValueError(
+                "layer_pattern with 'mlp' layers and mlp_type 'moe': a dense "
+                "'mlp' layer is built from mlp_type 'swiglu' or 'default' and "
+                "mlp_factor; the routed layers are the pattern's 'moe'"
             )
 
     @property
@@ -530,6 +552,22 @@ class TransformerArchitectureConfig(BaseConfig):
     def recurrent_layers(self) -> int:
         """Layers that keep a recurrent state a sequence (Mamba-2 mixers)."""
         return sum(k == LayerKind.MAMBA for k in self.layer_pattern or ())
+
+    @property
+    def pattern_embedding_std(self) -> float:
+        """The deviation a ``layer_pattern`` stack's embedding table starts
+        at, which its residual branches are sized against
+        (``MixerLayer.init``): 1 (an embedding table's plain default), or,
+        where the table is the head too (``weight_tying``), a head's Xavier
+        deviation, so that the logits start where an untied head's do."""
+        if self.weight_tying:
+            return (2.0 / (self.vocab_size + self.hidden_size)) ** 0.5
+        return 1.0
+
+    @property
+    def conv_layers(self) -> int:
+        """Layers that keep a conv tail a sequence (gated short convolutions)."""
+        return sum(k == LayerKind.CONV for k in self.layer_pattern or ())
 
     @property
     def mup_width_mult(self) -> float:

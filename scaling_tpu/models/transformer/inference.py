@@ -35,14 +35,14 @@ from .model import init_model
 from .tokenizer import Tokenizer
 from ...checkpoint import load_model_checkpoint
 from ...nn.attention import PagedKVCacheView
-from ...nn.mamba import RecurrentStateView
 from ...parallel.parallel_module import ParallelModule
 
 
 # the layers of a trunk (they consume the serving state), and the views of
 # the serving engine's pools
 TRUNK_LAYERS = (TransformerLayer, MixerLayer)
-PAGED_VIEWS = (PagedKVCacheView, RecurrentStateView)
+# the views of the serving engine's state: paged KV, Mamba-2 lines, conv tails
+PAGED_VIEWS = tuple(MixerLayer.STATE_VIEWS.values())
 
 
 class CompletionOutput(NamedTuple):
@@ -463,7 +463,8 @@ class TransformerInferenceModule:
             if isinstance(layer, TRUNK_LAYERS):
                 # a layer is handed the state of ITS kind: a TransformerLayer
                 # and an attention mixer a KV cache, a Mamba-2 mixer its
-                # recurrent lines, a routed mixer nothing
+                # recurrent lines, a short convolution its tail, an MLP
+                # (routed or dense) nothing
                 consumes = layer.consumes
                 if consumes is None and real is not None:
                     x = paged_layer_call(layer)(p, x, real)
@@ -475,7 +476,10 @@ class TransformerInferenceModule:
                             f"layer {i} consumes a {consumes!r} cache but only "
                             f"{len(caches)} were provided")
                     cache = caches[li]
-                    if (consumes == "ssm") != isinstance(cache, RecurrentStateView):
+                    # a dense (k, v) pair is an attention layer's too
+                    if ((consumes != "kv" or isinstance(cache, PAGED_VIEWS))
+                            and not isinstance(
+                                cache, MixerLayer.STATE_VIEWS[consumes])):
                         raise ValueError(
                             f"layer {i} consumes a {consumes!r} state and was "
                             f"handed a {type(cache).__name__}: the caches are "
@@ -839,7 +843,8 @@ class TransformerInferenceModule:
         for i, layer in enumerate(self.module.layers):
             p = self.module._layer_params(params, i)
             if isinstance(layer, TRUNK_LAYERS) and layer.consumes is not None:
-                # attention: its (k, v); a Mamba-2 mixer: its (ssm, conv) lines
+                # attention: its (k, v); a Mamba-2 mixer: its (ssm, conv)
+                # lines; a short convolution: its tail
                 x, kv = layer(p, x, ctx, return_kv=True)
                 kvs.append(kv)
             else:

@@ -33,10 +33,11 @@ class EmbeddingInput(BaseLayer):
         extra = {}
         if architecture.layer_pattern is not None:
             # a stack of single-mixer layers starts its stream at unit
-            # variance (N(0, 1), the plain default of an embedding table),
+            # variance (N(0, 1), the plain default of an embedding table; a
+            # table that is the head too at a head's Xavier deviation),
             # beside which each residual branch is small: layers/layer.py,
             # MixerLayer.init
-            extra["init_method"] = normal_init(1.0)
+            extra["init_method"] = normal_init(architecture.pattern_embedding_std)
         self.embedding = VocabParallelEmbedding(
             num_embeddings=architecture.vocab_size,
             embedding_dim=architecture.hidden_size,

@@ -32,6 +32,7 @@ from ....nn import (
 from ....nn.attention import PagedKVCacheView
 from ....nn.rotary import RotaryConfig
 from ....nn.mamba import Mamba2Mixer, RecurrentStateView
+from ....nn.short_conv import ConvTailView, GatedShortConv
 from ..config import (
     AdapterConfig,
     KeyQueryNormScope,
@@ -88,6 +89,7 @@ def routed_mlp(arch: TransformerArchitectureConfig) -> BaseLayer:
         capacity_factor=arch.moe_capacity_factor,
         aux_loss_coef=arch.moe_aux_loss_coef,
         norm_topk_prob=arch.moe_norm_topk_prob,
+        norm_topk_eps=arch.moe_norm_topk_eps,
         glu=arch.moe_glu,
         activation=arch.activation_function,
         dtype=arch.dtype,
@@ -100,15 +102,41 @@ def routed_mlp(arch: TransformerArchitectureConfig) -> BaseLayer:
     )
 
 
+def dense_mlp(arch: TransformerArchitectureConfig, bitfit=None) -> BaseLayer:
+    """The dense MLP the configuration describes (``mlp_type`` 'swiglu' or
+    'default', ``mlp_factor``)."""
+    if arch.mlp_type == MLPType.SWIGLU:
+        return ParallelSwiGLUMLP(
+            io_features=arch.hidden_size,
+            intermediate_feature_factor=arch.mlp_factor,
+            bias=arch.mlp_bias,
+            dtype=arch.dtype,
+            bitfit_bias_name=bitfit,
+        )
+    return ParallelMLP(
+        io_features=arch.hidden_size,
+        intermediate_feature_factor=arch.mlp_factor,
+        activation=arch.activation_function,
+        bias=arch.mlp_bias,
+        dtype=arch.dtype,
+        bitfit_bias_name=bitfit,
+    )
+
+
 class MixerLayer(BaseLayer):
     """A layer of a ``layer_pattern`` stack: ONE norm, ONE mixer of the
     layer's kind, the residual: ``x <- x + Mixer(Norm(x))`` (Nemotron-H's
-    block). ``consumes`` names the serving state the mixer keeps: ``'kv'``
-    (attention: a paged KV cache line), ``'ssm'`` (Mamba-2: a recurrent-state
-    line a slot), or None (the routed MLP)."""
+    block; LFM2's block, an operator then an FFN each behind its own norm, is
+    two of them). ``consumes`` names the serving state the mixer keeps:
+    ``'kv'`` (attention: a paged KV cache line), ``'ssm'`` (Mamba-2: a
+    recurrent-state line a slot), ``'conv'`` (gated short convolution: a
+    conv-tail line a slot), or None (the routed and the dense MLP)."""
 
     CONSUMES = {LayerKind.ATTENTION: "kv", LayerKind.MAMBA: "ssm",
-                LayerKind.MOE: None}
+                LayerKind.CONV: "conv", LayerKind.MOE: None, LayerKind.MLP: None}
+    # the view of the engine's state pool each kind of state is handed
+    STATE_VIEWS = {"kv": PagedKVCacheView, "ssm": RecurrentStateView,
+                   "conv": ConvTailView}
 
     def __init__(self, architecture: TransformerArchitectureConfig, layer_index: int = 0):
         arch = architecture
@@ -130,6 +158,10 @@ class MixerLayer(BaseLayer):
             )
         elif self.kind == LayerKind.MOE:
             self.mixer = routed_mlp(arch)
+        elif self.kind == LayerKind.CONV:
+            self.mixer = GatedShortConv(arch.hidden_size, arch.conv_kernel, dtype)
+        elif self.kind == LayerKind.MLP:
+            self.mixer = dense_mlp(arch)
         else:
             rotary_config = None
             head_dim = (arch.attention_head_dim
@@ -150,6 +182,8 @@ class MixerLayer(BaseLayer):
                 bias=arch.attention_bias,
                 dtype=dtype,
                 norm_type=arch.norm_type,
+                # per head, before rotary (config.py refuses the other scope)
+                key_query_norm=arch.key_query_norm,
                 layernorm_config=arch.layernorm,
                 qkv_in_one=arch.attention_qkv_in_one
                 and arch.attention_num_kv_heads is None,
@@ -167,7 +201,9 @@ class MixerLayer(BaseLayer):
         sqrt(num_layers))`` of its Xavier scale (GPT-2 gives its output
         projections ``1 / sqrt(2 L)``, Mamba's ``rescale_prenorm_residual``
         ``1 / sqrt(L)``), beside an embedding at unit variance
-        (layers/embedding.py): the stream is then mostly the embedding and
+        (layers/embedding.py; times the table's deviation where a tied table
+        starts lower, ``pattern_embedding_std``: behind RMSNorms the stack is
+        the same map at any common scale): the stream is then mostly the embedding and
         each branch a small step, so that fresh weights are a stable map.
         The routed experts' start a further ``ROUTED_OUTPUT_SCALE`` lower:
         with fresh weights a sigmoid router's k chosen scores are all near
@@ -177,18 +213,40 @@ class MixerLayer(BaseLayer):
         an expert that carries a k-th of the routed output. At Xavier scale
         and an embedding of 0.005 that moved served logits by up to 0.67
         against the float32 reference (PERF.md, PR 46); a trained router's
-        edge experts carry small scores."""
+        edge experts carry small scores.
+
+        Where the head is TIED to the table (LFM2) the stream must NOT be
+        mostly the embedding: a token's logits are then ``e_t D E^T`` with
+        ``D`` the final norm's weight, a SYMMETRIC table, and greedy decoding
+        over a symmetric table climbs (``B[t0, t1] <= B[t1, t2] <= ...``) to
+        two tokens that are each other's best and alternates between them,
+        whatever the layers compute: the comparison of served logits with
+        the reference then sees two transitions a request (on the chip: a
+        largest gap of 0.0000 over 944 positions, PERF.md, PR 48). So there
+        the branches keep their Xavier scale relative to the table (no depth
+        factor: the stream is mostly what the layers computed) and the
+        routed experts start ``ROUTED_OUTPUT_SCALE ** 2`` below them, the
+        near-ties' share of the stream as small as before."""
         k1, k2 = jax.random.split(key)
         mixer = self.mixer.init(k2)
-        scale = 0.5 * self.architecture.num_layers ** -0.5
+        arch = self.architecture
+        # relative to where the stream starts: the embedding's deviation
+        scale = 0.5 * arch.num_layers ** -0.5 * arch.pattern_embedding_std
+        routed = self.ROUTED_OUTPUT_SCALE
+        if arch.weight_tying:
+            # a head tied to the table: no depth factor (the docstring)
+            scale, routed = arch.pattern_embedding_std, routed ** 2
 
         def scaled(weight, by):
             return (weight.astype(jnp.float32) * by).astype(weight.dtype)
 
-        if self.kind == LayerKind.MAMBA:
+        if self.kind in (LayerKind.MAMBA, LayerKind.CONV):
             mixer["out_proj"]["weight"] = scaled(mixer["out_proj"]["weight"], scale)
+        elif self.kind == LayerKind.MLP:
+            out = "down_proj" if "down_proj" in mixer else "dense_out"
+            mixer[out]["weight"] = scaled(mixer[out]["weight"], scale)
         elif self.kind == LayerKind.MOE:
-            mixer["w_out"] = scaled(mixer["w_out"], scale * self.ROUTED_OUTPUT_SCALE)
+            mixer["w_out"] = scaled(mixer["w_out"], scale * routed)
             if "shared_out" in mixer:
                 mixer["shared_out"] = scaled(mixer["shared_out"], scale)
         else:
@@ -204,20 +262,22 @@ class MixerLayer(BaseLayer):
                  real=None):
         """``kv_cache``: the serving state of this layer's kind (a
         ``PagedKVCacheView`` or dense ``(k, v)`` for attention, a
-        ``RecurrentStateView`` for Mamba-2); with it or ``return_kv`` the
-        result is ``(out, new state)``: attention's K/V or updated view,
-        Mamba-2's lines. ``real`` ((b, s) bool): the positions that hold a
+        ``RecurrentStateView`` for Mamba-2, a ``ConvTailView`` for a short
+        convolution); with it or ``return_kv`` the result is ``(out, new
+        state)``: attention's K/V or updated view, Mamba-2's lines, the
+        convolution's tail. ``real`` ((b, s) bool): the positions that hold a
         token, for the routed MLP's load count when serving."""
         h = x["activations"]
         normed = self.norm(params["norm"], h, ctx)
         out = dict(x)
         state = None
-        if self.kind == LayerKind.MAMBA:
-            if kv_cache is not None and not isinstance(kv_cache, RecurrentStateView):
+        if self.kind in (LayerKind.MAMBA, LayerKind.CONV):
+            view = self.STATE_VIEWS[self.consumes]
+            if kv_cache is not None and not isinstance(kv_cache, view):
                 raise ValueError(
-                    "a Mamba-2 layer takes a RecurrentStateView (the serving "
-                    "engine's state pool), not a KV cache: cached generate() "
-                    "is not built for a layer_pattern stack; use "
+                    f"a {self.kind.value} layer takes a {view.__name__} (the "
+                    "serving engine's state pool), not a KV cache: cached "
+                    "generate() is not built for a layer_pattern stack; use "
                     "use_cache=False or ServeEngine")
             y = self.mixer(params["mixer"], normed, ctx, state=kv_cache,
                            return_state=return_kv)
@@ -231,6 +291,9 @@ class MixerLayer(BaseLayer):
             else:
                 y, aux = self.mixer(params["mixer"], normed, ctx)
                 out["aux_loss"] = x.get("aux_loss", 0.0) + aux
+        elif self.kind == LayerKind.MLP:
+            with jax.named_scope("mlp"):
+                y = self.mixer(params["mixer"], normed, ctx)
         else:
             y = self.mixer(
                 params["mixer"], normed, ctx,
@@ -325,23 +388,8 @@ class TransformerLayer(BaseLayer):
         self.is_moe = arch.mlp_type == MLPType.MOE
         if self.is_moe:
             self.mlp: BaseLayer = routed_mlp(arch)
-        elif arch.mlp_type == MLPType.SWIGLU:
-            self.mlp = ParallelSwiGLUMLP(
-                io_features=arch.hidden_size,
-                intermediate_feature_factor=arch.mlp_factor,
-                bias=arch.mlp_bias,
-                dtype=dtype,
-                bitfit_bias_name=bitfit,
-            )
         else:
-            self.mlp = ParallelMLP(
-                io_features=arch.hidden_size,
-                intermediate_feature_factor=arch.mlp_factor,
-                activation=arch.activation_function,
-                bias=arch.mlp_bias,
-                dtype=dtype,
-                bitfit_bias_name=bitfit,
-            )
+            self.mlp = dense_mlp(arch, bitfit)
 
         self.adapter_attention: Optional[Adapter] = None
         self.adapter_mlp: Optional[Adapter] = None

@@ -37,9 +37,25 @@ class LayerNormWrapper(BaseLayer):
         self.norm = get_norm(arch.norm_type, arch.hidden_size, arch.layernorm,
                              arch.dtype, bitfit)
         self.record_embeddings = record_embeddings
+        self.random_signs = arch.layer_pattern is not None and arch.weight_tying
 
     def init(self, key: jax.Array) -> dict:
-        return {"norm": self.norm.init(key)}
+        """A norm's own init; before a head TIED to the table of a
+        ``layer_pattern`` stack the weight starts at random signs. A tied
+        head scores token ``v`` by ``sum_i w_i n_i E_vi``, and the stream
+        ``n`` holds the token's own embedding ``e``: at ``w = 1`` the token
+        itself scores its share of ``|e|^2``, up to ``sqrt(hidden)``
+        deviations above every other, so fresh weights repeat their input
+        whatever the layers compute and a comparison of logits sees none of
+        it. With signs that sum to nothing its score is one among the others
+        (a trained norm's weight is some vector too). ``MixerLayer.init``
+        has the rest of what a tied head asks of seeded weights."""
+        params = self.norm.init(key)
+        if self.random_signs:
+            weight = params["weight"]
+            signs = jax.random.rademacher(key, weight.shape, jnp.float32)
+            params["weight"] = weight * signs.astype(weight.dtype)
+        return {"norm": params}
 
     def param_metas(self) -> dict:
         return {"norm": tree_prefix(self.norm.param_metas(), "norm")}

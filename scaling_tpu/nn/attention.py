@@ -228,8 +228,10 @@ def paged_scatter_kv(view: PagedKVCacheView, flat: jax.Array,
         scale_k = scale_k.at[flat].set(sk).reshape(view.scale_k.shape)
         scale_v = scale_v.at[flat].set(sv).reshape(view.scale_v.shape)
     else:
-        pk = pk.at[flat].set(k_rows.astype(pk.dtype))
-        pv = pv.at[flat].set(v_rows.astype(pv.dtype))
+        # a pool of narrow heads keeps several a lane row
+        # (paged_attention.packed_kv_dims): the same values, regrouped
+        pk = pk.at[flat].set(k_rows.reshape(-1, *pk.shape[1:]).astype(pk.dtype))
+        pv = pv.at[flat].set(v_rows.reshape(-1, *pv.shape[1:]).astype(pv.dtype))
     return view._replace(
         pool_k=pk.reshape(view.pool_k.shape),
         pool_v=pv.reshape(view.pool_v.shape),
@@ -839,8 +841,9 @@ class ParallelSelfAttention(BaseLayer):
         # --- gather: each row's blocks as one contiguous KV window
         gk = view.pool_k[view.block_table]  # (rows, max_blocks, bs, n_kv, h)
         gv = view.pool_v[view.block_table]
-        gk = gk.reshape(rows, window, *gk.shape[3:])
-        gv = gv.reshape(rows, window, *gv.shape[3:])
+        # (a pool of narrow heads keeps several a lane row: back to heads)
+        gk = gk.reshape(rows, window, -1, q.shape[-1])
+        gv = gv.reshape(rows, window, -1, q.shape[-1])
         if view.quantized:
             gsk = view.scale_k[view.block_table].reshape(rows, window, -1)
             gsv = view.scale_v[view.block_table].reshape(rows, window, -1)

@@ -43,7 +43,8 @@ Further facts of a model, all from its configuration: ``router``
 (``'softmax'``, or ``'sigmoid_bias'``: every expert's ``s_e = sigmoid(logit_e)``
 on its own, the k with the largest ``s_e + b_e`` chosen, ``b`` a float32
 selection bias that takes part in the CHOICE only, the gates ``scale * s_e /
-(sum of the chosen s + 1e-20)``), ``glu`` (false: two matrices an expert,
+(sum of the chosen s + norm_topk_eps)``: 1e-20 in Nemotron-H, 1e-6 in LFM2),
+``glu`` (false: two matrices an expert,
 ``act(x W_in) W_out``), and a ``shared expert`` of a width of its own that
 every token runs, added once inside the ``moe`` scope.
 
@@ -84,6 +85,7 @@ class ParallelMoEMLP(BaseLayer):
         capacity_factor: float = 1.25,
         aux_loss_coef: float = 0.01,
         norm_topk_prob: bool = True,
+        norm_topk_eps: float = 1e-20,
         glu: bool = True,
         activation: ActivationFunction = ActivationFunction.SILU,
         dtype=None,
@@ -115,6 +117,7 @@ class ParallelMoEMLP(BaseLayer):
         self.capacity_factor = capacity_factor
         self.aux_loss_coef = aux_loss_coef
         self.norm_topk_prob = norm_topk_prob
+        self.norm_topk_eps = norm_topk_eps
         self.glu = glu
         self.activation_fn = get_activation_function(activation)
         self.dtype = dtype
@@ -285,7 +288,7 @@ class ParallelMoEMLP(BaseLayer):
             gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
             if self.norm_topk_prob:
                 gate_vals = gate_vals / (
-                    gate_vals.sum(axis=-1, keepdims=True) + 1e-20)
+                    gate_vals.sum(axis=-1, keepdims=True) + self.norm_topk_eps)
             return probs, gate_vals * self.routed_scaling_factor, gate_idx
         probs = jax.nn.softmax(logits, axis=-1)
         gate_vals, gate_idx = jax.lax.top_k(probs, self.top_k)

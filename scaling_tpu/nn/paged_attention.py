@@ -405,6 +405,21 @@ def paged_decode_attention(
     _, block_size, n_kv, h = pool_k.shape
     if interpret is None:
         interpret = paged_kernel_interpret()
+    pack = h // q.shape[-1]
+    if pack > 1:
+        # a pool of heads narrower than the 128 lanes holds `pack` KV heads
+        # a lane row (packed_kv_dims; LFM2's 64): see _pack_queries
+        assert scale_k is None, "an int8 pool is not packed"
+        rows, s, n, h_q = q.shape
+        out = paged_decode_attention(
+            _pack_queries(q, n_kv * pack, pack), pool_k, pool_v, block_table,
+            valid_len, q_slot_base, sm_scale=sm_scale,
+            num_repeat_kv=pack * num_repeat_kv, interpret=interpret)
+        # a query head's output lies in the lanes of its own KV head
+        out = out.reshape(rows, s, n_kv, pack, num_repeat_kv, pack, h_q)
+        own = jnp.arange(pack)
+        return out[:, :, :, own, :, own].transpose(1, 2, 3, 0, 4, 5).reshape(
+            rows, s, n, h_q)
     count_kernel_build("paged_attention", interpret)
     return _paged_call(
         q, pool_k, pool_v, block_table, valid_len, q_slot_base,
@@ -414,6 +429,44 @@ def paged_decode_attention(
         ),
         interpret=interpret,
     )
+
+
+_LANES = 128
+
+
+def packed_kv_dims(n_kv: int, h: int):
+    """The ``(heads, width)`` a native pool keeps a token's K (or V) in: ``(n_kv,
+    h)`` at the usual ``h = 128`` (or wider); for narrower heads whose count
+    fills whole lane rows, ``128 / h`` consecutive KV heads side by side in
+    one row of 128 lanes, ``(n_kv h / 128, 128)``. The kernel reads one head's
+    ``(tile, width)`` matrix with a strided load that Mosaic has for rows of
+    128 lanes only; and on the chip an array whose minor dimension is 64 is
+    tiled to 128 lanes anyway (twice the bytes), so a pool made as ``(.., n_kv,
+    64)`` and viewed as ``(.., n_kv / 2, 128)`` in the program costs a copy of
+    the whole pool a call (my chip runs, PR 48: 2.6 ms of a 20.6 ms tick).
+    The pool is therefore MADE in this shape (serve/kvcache.py) and written
+    in it (``nn.attention.paged_scatter_kv``)."""
+    pack = _LANES // h if h < _LANES and _LANES % h == 0 else 1
+    if pack == 1 or n_kv % pack:
+        return n_kv, h
+    return n_kv // pack, pack * h
+
+
+def _pack_queries(q: jax.Array, n_kv: int, pack: int) -> jax.Array:
+    """Queries for a pool that holds ``pack`` KV heads a lane row.
+
+    A query of KV head ``a`` among the ``pack`` of a row is widened to 128
+    lanes with zeros everywhere but in lanes ``[a h, (a + 1) h)``: its scores
+    against the wide head are those against its own KV head (the others'
+    lanes meet zeros), its softmax is its own row's, and lanes ``[a h, (a +
+    1) h)`` of its output are its output. The ``pack`` KV heads' groups become
+    one group of ``pack`` times the rows: the pool's bytes move once, the
+    MXU's contraction is ``pack`` times as long as the mathematics needs."""
+    rows, s, n, h = q.shape
+    group = n // n_kv
+    q = q.reshape(rows, s, n_kv // pack, pack, group, 1, h)
+    own = jnp.eye(pack, dtype=q.dtype).reshape(1, 1, 1, pack, 1, pack, 1)
+    return (q * own).reshape(rows, s, n, pack * h)
 
 
 def _pipeline_carry(valid_len: jax.Array, tile: int):

@@ -294,17 +294,22 @@ class ServeEngine:
         # skips or rewinds positions is refused, not approximated
         arch = inference_module.architecture
         self.ssm_lines = arch.recurrent_layers
-        if self.ssm_lines and self.config.enable_prefix_cache:
-            raise ValueError(
-                "enable_prefix_cache with recurrent (Mamba-2) layers: a prefix "
-                "hit starts a row past tokens its recurrent state never saw "
-                "(only the KV of a shared prefix is kept, no state snapshot); "
-                "set enable_prefix_cache=False")
-        if self.ssm_lines and self.config.spec_k > 0:
-            raise ValueError(
-                "spec_k > 0 with recurrent (Mamba-2) layers: a rejected draft "
-                "has already advanced the recurrent state and there is no "
-                "rollback; set spec_k=0")
+        # likewise the conv tails of gated short convolutions (LFM2)
+        self.conv_lines = arch.conv_layers
+        for lines, what, state in (
+                (self.ssm_lines, "recurrent (Mamba-2)", "recurrent state"),
+                (self.conv_lines, "short-convolution (conv)", "conv tail")):
+            if lines and self.config.enable_prefix_cache:
+                raise ValueError(
+                    f"enable_prefix_cache with {what} layers: a prefix "
+                    f"hit starts a row past tokens its {state} never saw "
+                    "(only the KV of a shared prefix is kept, no state "
+                    "snapshot); set enable_prefix_cache=False")
+            if lines and self.config.spec_k > 0:
+                raise ValueError(
+                    f"spec_k > 0 with {what} layers: a rejected draft "
+                    f"has already advanced the {state} and there is no "
+                    "rollback; set spec_k=0")
         self.pools: PagedKVPools = init_pools(
             inference_module, self.config.num_blocks, self.config.block_size,
             kv_dtype=self.config.kv_dtype, num_slots=self.config.num_slots,
@@ -879,13 +884,16 @@ class ServeEngine:
                     "serve_sampler_ticks_total",
                     path="sampled" if sampled_rows else "greedy",
                 ).inc()
-                if self.ssm_lines:
-                    # rows whose recurrent state advanced, in every M layer
-                    ssm_rows = int(np.count_nonzero(new_lens))
-                    mixed_span.annotate(ssm_rows=ssm_rows,
-                                        ssm_lines=self.ssm_lines)
-                    self._counter("serve_ssm_state_updates_total").inc(
-                        ssm_rows * self.ssm_lines)
+                # rows whose per-slot lines advanced, in every Mamba-2 layer
+                # (ssm) and in every short convolution (conv)
+                for kind, lines in (("ssm", self.ssm_lines),
+                                    ("conv", self.conv_lines)):
+                    if lines:
+                        rows = int(np.count_nonzero(new_lens))
+                        mixed_span.annotate(**{f"{kind}_rows": rows,
+                                               f"{kind}_lines": lines})
+                        self._counter(
+                            f"serve_{kind}_state_updates_total").inc(rows * lines)
                 if self.loop_steps > 1:
                     mixed_span.annotate(loop_steps=self.loop_steps)
                     self._counter("serve_loop_layer_passes_total").inc(
@@ -1307,8 +1315,9 @@ class ServeEngine:
             # steps x layers) and the bytes the pools really hold
             "kv_lines": self.pools.kv_lines,
             "kv_pool_bytes": self.pools.device_bytes(),
-            # layers that keep a recurrent line a slot (Mamba-2 mixers; 0: a
-            # model without them) and the bytes of those lines
+            # layers that keep a line a slot (Mamba-2 mixers' recurrent state,
+            # short convolutions' tails; 0: a model without them) and the
+            # bytes of those lines
             "state_lines": self.pools.state_lines,
             "state_pool_bytes": self.pools.state_bytes(),
             # median ms of each serve.* span over this engine's last

@@ -43,6 +43,13 @@ counts no block for it. KV pools exist for the attention layers only. The
 state the engine's program takes and returns is then ``(pool_k, pool_v,
 scale_k, scale_v, state_ssm, state_conv)``: ONE structure, so that the
 recurrent lines are donated and aliased like the pools.
+
+**Conv tails** (a ``layer_pattern`` stack with gated short convolutions,
+LFM2's): a third kind of state, a line of its own kind: ``tail (num_slots,
+K - 1, hidden)`` a layer, each channel's last ``K - 1`` filter inputs. A
+model with such layers puts ONE more list, ``state_tail``, at the end of
+that structure (after the Mamba-2 lists if it has those too), donated and
+aliased like the rest. Which lists a state carries follows from ``kinds``.
 """
 
 from __future__ import annotations
@@ -55,6 +62,7 @@ import numpy as np
 
 from ..nn.attention import PagedKVCacheView, PagedTokenMap
 from ..nn.mamba import RecurrentStateView
+from ..nn.short_conv import ConvTailView
 
 
 def serving_mesh(inference_module):
@@ -73,7 +81,7 @@ def build_layer_views(
     context_len: jax.Array,          # (rows,) int32
     new_len: Optional[jax.Array] = None,  # (rows,) int32 real new tokens
     token_map: Optional[PagedTokenMap] = None,  # a token-major batch's
-    kinds: Optional[List[str]] = None,  # 'kv' | 'ssm' a consuming layer
+    kinds: Optional[List[str]] = None,  # 'kv' | 'ssm' | 'conv' a consuming layer
 ) -> List:
     """Per-layer :class:`PagedKVCacheView` s over the raw pool state —
     the shape the engine's jitted programs thread through ``_run_layers``
@@ -89,10 +97,11 @@ def build_layer_views(
     (``nn.attention.packed_token_map``) rides along when the batch holds
     the rows' tokens packed token-major instead of one row a batch row.
 
-    A state that carries recurrent lines (six entries) comes with ``kinds``,
-    the kind of state each consuming layer takes in layer order: the views
-    are then one a consuming layer, a ``RecurrentStateView`` over the slots'
-    lines for ``'ssm'``, and lie in that order."""
+    A state that carries per-slot lines (more than four entries) comes with
+    ``kinds``, the kind of state each consuming layer takes in layer order:
+    the views are then one a consuming layer, a ``RecurrentStateView`` over
+    the slots' lines for ``'ssm'``, a ``ConvTailView`` for ``'conv'``, and lie
+    in that order."""
     pool_k, pool_v, scale_k, scale_v = state[:4]
     kv_views = [
         PagedKVCacheView(
@@ -106,12 +115,20 @@ def build_layer_views(
     ]
     if len(state) == 4:
         return kv_views
-    ssm_views = [
-        RecurrentStateView(ssm=ssm, conv=conv, context_len=context_len,
-                           new_len=new_len, token_map=token_map)
-        for ssm, conv in zip(*state[4:])
-    ]
-    by_kind = {"kv": iter(kv_views), "ssm": iter(ssm_views)}
+    lines = list(state[4:])
+    by_kind = {"kv": iter(kv_views)}
+    if "ssm" in kinds:
+        by_kind["ssm"] = iter([
+            RecurrentStateView(ssm=ssm, conv=conv, context_len=context_len,
+                               new_len=new_len, token_map=token_map)
+            for ssm, conv in zip(lines.pop(0), lines.pop(0))
+        ])
+    if "conv" in kinds:
+        by_kind["conv"] = iter([
+            ConvTailView(tail=tail, context_len=context_len, new_len=new_len,
+                         token_map=token_map)
+            for tail in lines.pop(0)
+        ])
     return [next(by_kind[kind]) for kind in kinds]
 
 
@@ -132,8 +149,10 @@ def state_from_views(views: List[PagedKVCacheView]) -> Tuple:
     every pool but the first on every call. Their table, lengths,
     ``new_len`` and token map are the program's inputs (or derived from
     them) and do not come back. Recurrent views among them put the two lists
-    of their lines after the four of the pools."""
+    of their lines after the four of the pools, conv-tail views their one
+    list after those."""
     recurrent = [v for v in views if isinstance(v, RecurrentStateView)]
+    tails = [v for v in views if isinstance(v, ConvTailView)]
     views = [v for v in views if isinstance(v, PagedKVCacheView)]
     quantized = views[0].scale_k is not None
     state = (
@@ -144,6 +163,8 @@ def state_from_views(views: List[PagedKVCacheView]) -> Tuple:
     )
     if recurrent:
         state += ([v.ssm for v in recurrent], [v.conv for v in recurrent])
+    if tails:
+        state += ([v.tail for v in tails],)
     return state
 
 
@@ -162,7 +183,8 @@ class PagedKVPools:
                  block_size: int, loop_steps: int = 1,
                  state_ssm: Optional[List[jax.Array]] = None,
                  state_conv: Optional[List[jax.Array]] = None,
-                 kinds: Optional[List[str]] = None):
+                 kinds: Optional[List[str]] = None,
+                 state_tail: Optional[List[jax.Array]] = None):
         self.pool_k = pool_k
         self.pool_v = pool_v
         self.scale_k = scale_k
@@ -176,6 +198,8 @@ class PagedKVPools:
         self.state_ssm = state_ssm
         self.state_conv = state_conv
         self.kinds = kinds
+        # the conv tails, one a gated short convolution (None: no such layer)
+        self.state_tail = state_tail
 
     @property
     def num_layers(self) -> int:
@@ -203,21 +227,26 @@ class PagedKVPools:
 
     @property
     def state_lines(self) -> int:
-        """Layers that keep a recurrent line a slot."""
-        return len(self.state_ssm or ())
+        """Layers that keep a line a slot: a recurrent state or a conv tail."""
+        return len(self.state_ssm or ()) + len(self.state_tail or ())
 
     def state(self) -> Tuple:
         """What the jitted programs take (donated) and return."""
-        kv = (self.pool_k, self.pool_v, self.scale_k, self.scale_v)
-        if self.state_ssm is None:
-            return kv
-        return kv + (self.state_ssm, self.state_conv)
+        state = (self.pool_k, self.pool_v, self.scale_k, self.scale_v)
+        if self.state_ssm is not None:
+            state += (self.state_ssm, self.state_conv)
+        if self.state_tail is not None:
+            state += (self.state_tail,)
+        return state
 
     def absorb_state(self, state: Tuple) -> None:
         """Take back the updated state a jitted program returned."""
         self.pool_k, self.pool_v, self.scale_k, self.scale_v = state[:4]
+        lines = list(state[4:])
         if self.state_ssm is not None:
-            self.state_ssm, self.state_conv = state[4:]
+            self.state_ssm, self.state_conv = lines.pop(0), lines.pop(0)
+        if self.state_tail is not None:
+            self.state_tail = lines.pop(0)
 
     def device_bytes(self) -> int:
         total = 0
@@ -229,18 +258,19 @@ class PagedKVPools:
         return total
 
     def state_bytes(self) -> int:
-        """Bytes of the recurrent lines (beside ``device_bytes``)."""
+        """Bytes of the per-slot lines (beside ``device_bytes``)."""
         return sum(a.size * a.dtype.itemsize
-                   for arrs in (self.state_ssm or (), self.state_conv or ())
-                   for a in arrs)
+                   for arrs in (self.state_ssm, self.state_conv, self.state_tail)
+                   for a in arrs or ())
 
 
 def init_pools(inference_module, num_blocks: int, block_size: int,
                kv_dtype: str = "native", num_slots: int = 0) -> PagedKVPools:
     """Allocate zeroed pools shaped by probing the real layer stack.
 
-    A stack with Mamba-2 layers also gets their recurrent lines, ``num_slots``
-    of each, shaped by the same probe (the final state of a one-token pass).
+    A stack with Mamba-2 layers or gated short convolutions also gets their
+    lines, ``num_slots`` of each, shaped by the same probe (the final state of
+    a one-token pass).
 
     ``kv_dtype``: ``'native'`` keeps the probe's KV dtype (the model's
     compute dtype); ``'int8'`` stores int8 values + float32 scales.
@@ -260,13 +290,15 @@ def init_pools(inference_module, num_blocks: int, block_size: int,
 
     kv_shapes = jax.eval_shape(probe, params, probe_tokens, probe_pos)
     # a pattern stack's probe holds a line of its kind a consuming layer, in
-    # layer order: (k, v) of an attention layer, (ssm, conv) of a Mamba-2 one
+    # layer order: (k, v) of an attention layer, (ssm, conv) of a Mamba-2 one,
+    # the tail of a gated short convolution
     kinds = [layer.consumes for layer in inference_module.module.layers
              if getattr(layer, "consumes", None)]
-    state_shapes = []
-    if "ssm" in kinds:
-        state_shapes = [l for l, kind in zip(kv_shapes, kinds) if kind == "ssm"]
-        kv_shapes = [l for l, kind in zip(kv_shapes, kinds) if kind == "kv"]
+    by_kind = {kind: [l for l, k in zip(kv_shapes, kinds) if k == kind]
+               for kind in ("kv", "ssm", "conv")}
+    state_shapes, tail_shapes = by_kind["ssm"], by_kind["conv"]
+    if state_shapes or tail_shapes:
+        kv_shapes = by_kind["kv"]
     if not kv_shapes:
         raise ValueError(
             "the layer stack keeps no KV cache line: the paged engine serves "
@@ -327,8 +359,13 @@ def init_pools(inference_module, num_blocks: int, block_size: int,
     pool_v: List[jax.Array] = []
     scale_k: Optional[List[jax.Array]] = [] if kv_dtype == "int8" else None
     scale_v: Optional[List[jax.Array]] = [] if kv_dtype == "int8" else None
+    from ..nn.paged_attention import packed_kv_dims
+
     for k_aval, v_aval in kv_shapes:
         n_kv, h = k_aval.shape[2], k_aval.shape[3]
+        if kv_dtype == "native" and mesh is None:
+            # heads narrower than the 128 lanes lie several a lane row
+            n_kv, h = packed_kv_dims(n_kv, h)
         store = jnp.int8 if kv_dtype == "int8" else k_aval.dtype
         pool_k.append(placed((pool_blocks, block_size, n_kv, h), store, 2))
         pool_v.append(placed((pool_blocks, block_size, n_kv, h), store, 2))
@@ -339,18 +376,24 @@ def init_pools(inference_module, num_blocks: int, block_size: int,
             scale_v.append(
                 placed((pool_blocks, block_size, n_kv), jnp.float32, 2)
             )
-    state_ssm = state_conv = None
-    if state_shapes:
+    state_ssm = state_conv = state_tail = None
+    if state_shapes or tail_shapes:
         if mesh is not None:
             raise ValueError("recurrent state is not sharded: serve a "
                              "layer_pattern stack at model_parallel_size 1")
         if num_slots <= 0:
             raise ValueError("a stack with recurrent layers needs num_slots: "
                              "it keeps one state line a slot")
-        state_ssm = [placed((num_slots, *ssm.shape[1:]), ssm.dtype, 1)
-                     for ssm, _ in state_shapes]
-        state_conv = [placed((num_slots, *conv.shape[1:]), conv.dtype, 1)
-                      for _, conv in state_shapes]
+
+        def lines(shapes):
+            return [placed((num_slots, *a.shape[1:]), a.dtype, 1) for a in shapes]
+
+        if state_shapes:
+            state_ssm = lines(ssm for ssm, _ in state_shapes)
+            state_conv = lines(conv for _, conv in state_shapes)
+        if tail_shapes:
+            state_tail = lines(tail_shapes)
     return PagedKVPools(pool_k, pool_v, scale_k, scale_v, block_size,
                         loop_steps, state_ssm, state_conv,
-                        kinds if state_shapes else None)
+                        kinds if state_shapes or tail_shapes else None,
+                        state_tail)

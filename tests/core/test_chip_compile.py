@@ -21,7 +21,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-from scaling_tpu.nn.paged_attention import paged_decode_attention
+from scaling_tpu.nn.paged_attention import packed_kv_dims, paged_decode_attention
 from scaling_tpu.ops.flash_attention import flash_attention_fused
 from scaling_tpu.topology.topology import DATA_AXIS, MODEL_AXIS
 
@@ -133,13 +133,28 @@ def test_paged_kernel_compiles_at_group_16_over_2_kv_heads(one_chip, s):
     compile_paged_kernel(one_chip, 64, 32, 2, 40, s, "native")
 
 
+@pytest.mark.parametrize(
+    "s", [1, 32], ids=["decode-s1", "prefill-chunk-s32"]
+)
+def test_paged_kernel_compiles_at_heads_of_64(one_chip, s):
+    """``serve-lfm2-24b-reason-burst``'s attention: 32 query heads over 8 KV
+    heads x 64, 64 slots of 40 blocks. Mosaic's strided load wants rows of 128
+    lanes ("The last dim size is not 128 in original base memref" at 64): the
+    pool is made as 4 heads of 128, two KV heads a lane row
+    (``paged_attention.packed_kv_dims``), and the queries widened to it."""
+    compile_paged_kernel(one_chip, 64, 32, 8, 40, s, "native", head_dim=64)
+
+
 def compile_paged_kernel(one_chip, slots, q_heads, kv_heads, max_blocks, s,
-                         kv_dtype):
+                         kv_dtype, head_dim=None):
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
+    HEAD_DIM = head_dim or globals()["HEAD_DIM"]
     quantized = kv_dtype == "int8"
     pool_dims = (slots * max_blocks + 1, BLOCK_SIZE, kv_heads, HEAD_DIM)
+    if not quantized:   # as init_pools makes a native pool
+        pool_dims = pool_dims[:2] + packed_kv_dims(kv_heads, HEAD_DIM)
     pool = shape(pool_dims, jnp.int8 if quantized else jnp.bfloat16)
     scales = (
         {"scale_k": shape(pool_dims[:3], jnp.float32),

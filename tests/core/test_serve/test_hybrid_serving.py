@@ -166,7 +166,7 @@ def test_what_would_skip_or_rewind_the_state_is_refused_by_name(hybrid, config, 
     ({"model_parallel_size": 2}, {}, "layer_pattern with model_parallel_size 2"),
     ({}, {"loop_steps": 2}, "layer_pattern with loop_steps"),
     ({}, {"layer_pattern": ["mamba"]}, "names 1 layers, num_layers is 6"),
-    ({}, {"sandwich_norm": True}, "sandwich_norm or key_query_norm"),
+    ({}, {"sandwich_norm": True}, "layer_pattern with sandwich_norm"),
     ({}, {"n_groups": 3}, "not a multiple of n_groups"),
     ({}, {"moe_experts_first": 6, "moe_experts_held": 4}, "do not lie in moe_num_experts"),
     ({}, {"adapter_config": {"attention_downsampling_factor": 0.25}}, "adapter_config"),

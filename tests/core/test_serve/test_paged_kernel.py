@@ -444,3 +444,76 @@ def test_group_16_over_2_kv_heads_agrees_with_the_gather_formulation():
         np.testing.assert_allclose(outs["pallas"][row, :n], outs["xla"][row, :n],
                                    atol=2e-2, rtol=2e-2)
         assert np.abs(outs["xla"][row, :n]).max() > 0.1
+
+
+@pytest.mark.parametrize("h,n_kv,n_rep", [(64, 8, 4), (64, 2, 1), (32, 4, 2)],
+                         ids=["lfm2-32q8kv-h64", "h64-group1", "h32-four-a-row"])
+@pytest.mark.parametrize("s", [1, 8])
+def test_heads_narrower_than_the_lanes_share_a_lane_row(h, n_kv, n_rep, s):
+    """LFM2's attention has heads of 64 where the kernel's strided read wants
+    rows of 128 lanes: the pool is MADE with two KV heads side by side in a
+    lane row (``packed_kv_dims``), each query widened with zeros outside its
+    own KV head's lanes. A decode row, a chunk row and an empty one against
+    the dense window over the same values head by head."""
+    rng = np.random.default_rng(h + s)
+    rows, blocks = 3, 6
+    pool_k = jnp.asarray(rng.normal(size=(rows * blocks + 1, BS, n_kv, h)), jnp.float32)
+    pool_v = jnp.asarray(rng.normal(size=(rows * blocks + 1, BS, n_kv, h)), jnp.float32)
+    tab = 1 + jnp.arange(rows * blocks, dtype=jnp.int32).reshape(rows, blocks)
+    q = jnp.asarray(rng.normal(size=(rows, s, n_kv * n_rep, h)), jnp.float32)
+    valid = jnp.asarray([21, s, 0], jnp.int32)
+    base = jnp.asarray([21 - s, 0, 0], jnp.int32)
+    packed = paged_attention.packed_kv_dims(n_kv, h)
+    assert packed == (n_kv * h // 128, 128)
+    assert paged_attention.packed_kv_dims(8, 128) == (8, 128)
+    assert paged_attention.packed_kv_dims(3, 64) == (3, 64)    # no whole lane rows
+    assert paged_attention.packed_kv_dims(4, 48) == (4, 48)
+    got = paged_decode_attention(
+        q, pool_k.reshape(*pool_k.shape[:2], *packed),
+        pool_v.reshape(*pool_v.shape[:2], *packed), tab, valid, base,
+        sm_scale=h ** -0.5, num_repeat_kv=n_rep)
+    want = dense_reference(q, pool_k, pool_v, tab, valid, base, n_rep)
+    np.testing.assert_allclose(np.asarray(got[:2]), np.asarray(want[:2]), atol=2e-5)
+    assert np.isfinite(np.asarray(got)).all()
+    # the widened queries: a head's own lanes hold it, the others zeros
+    wide = paged_attention._pack_queries(q, n_kv, 128 // h)
+    assert wide.shape == (rows, s, n_kv * n_rep, 128)
+    assert int((wide != 0).sum()) == int((q != 0).sum())
+
+
+def test_a_pool_of_narrow_heads_is_made_and_written_packed():
+    """``init_pools`` makes a native pool of 64-wide heads two a lane row, the
+    ONE writer regroups a token's K and V to it, and both formulations of
+    the paged branch read it (``ParallelSelfAttention._paged_attention``)."""
+    from scaling_tpu.nn.attention import PagedKVCacheView, ParallelSelfAttention
+    from scaling_tpu.nn.base_layer import ForwardContext
+    from scaling_tpu.nn.norm import NormType
+
+    hidden, heads, kv_heads, head_dim = 96, 8, 4, 64
+    attn = ParallelSelfAttention(
+        hidden, heads, num_kv_heads=kv_heads, head_dim=head_dim, qkv_in_one=False,
+        bias=False, key_query_norm=True, norm_type=NormType.RMS,
+        relative_position_embedding_type="none")
+    params = attn.init(jax.random.PRNGKey(0))
+    rows, s, block, max_blocks = 3, 8, 4, 6
+    x = jax.random.normal(jax.random.PRNGKey(1), (rows, s, hidden))
+    pool = jax.random.normal(
+        jax.random.PRNGKey(2), (rows * max_blocks + 1, block, kv_heads, head_dim))
+    table = 1 + jnp.arange(rows * max_blocks, dtype=jnp.int32).reshape(rows, -1)
+    lens = dict(context_len=jnp.asarray([13, 4, 0], jnp.int32),
+                new_len=jnp.asarray([1, 8, 0], jnp.int32))
+    plain = PagedKVCacheView(pool_k=pool, pool_v=pool[::-1], block_table=table, **lens)
+    packed_dims = (*pool.shape[:2], 2, 128)
+    packed = plain._replace(pool_k=pool.reshape(packed_dims),
+                            pool_v=pool[::-1].reshape(packed_dims))
+    outs = {}
+    for name, view, kernel in (("plain-xla", plain, "xla"), ("packed-xla", packed, "xla"),
+                               ("packed-pallas", packed, "pallas")):
+        out, new_view = attn(params, x, ForwardContext(paged_kernel=kernel), kv_cache=view)
+        assert new_view.pool_k.shape == view.pool_k.shape
+        outs[name] = (np.asarray(out), np.asarray(new_view.pool_k).reshape(pool.shape))
+    for name in ("packed-xla", "packed-pallas"):
+        for row, n in ((0, 1), (1, 8)):
+            np.testing.assert_allclose(outs[name][0][row, :n],
+                                       outs["plain-xla"][0][row, :n], atol=2e-5)
+        np.testing.assert_array_equal(outs[name][1], outs["plain-xla"][1])
