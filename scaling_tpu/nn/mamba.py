@@ -31,7 +31,11 @@ with ``a`` the running sum of ``dt A``: two small matmuls a head, and the state
 is read once and written once however many positions the run holds. A
 ``lax.scan`` over the positions would move it once a position. A position
 with ``dt = 0`` neither decays the state nor adds to it: that is how what is no
-token (a chunk's padding, an empty slot) leaves the state as it was.
+token (a chunk's padding, an empty slot) leaves the state as it was. The form
+pays for its width: ``w`` places of activations a row, and the read-out of
+``S_0`` beside its update. At ``w = 1`` it IS the recurrence's single step, ``S'
+= exp(dt A) S + (dt x) B^T``, ``y = S' C``, which reads the state once and takes
+the read-out from the value just computed (``_step_rows``).
 
 Two callers:
 
@@ -41,11 +45,21 @@ Two callers:
 - served (``state`` a :class:`RecurrentStateView`): the engine's mixed program
   holds one fixed-size line per (slot, layer), ``ssm (slots, heads, head_dim,
   N)`` float32 and ``conv (slots, inner + 2 G N, K - 1)`` (each channel's last
-  inputs). The tick's tokens arrive token-major; each row's are regrouped to
-  ``(rows, w)`` through the ``PagedTokenMap`` the attention branch uses, every
-  row advances from ITS line in one chunk, and the lines come back updated. A
-  row whose ``context_len`` is 0 starts from zeros: the program does it, so a
-  reused slot or a recomputed (preempted) sequence needs no reset by the host.
+  inputs), and every row advances from ITS line in the form its ``new_len``
+  asks for. The tick's tokens arrive token-major, ``T`` places for ``rows``
+  rows of at most ``w`` tokens. Below the full width (``T < rows * w``: the
+  engine's small program, where nearly every row decodes) a row that brings
+  ONE token takes the single step where it lies, and the few that bring more,
+  at most ``T // w`` (``split_capacity``: the engine sends a tick with more to
+  the full width), are gathered through the ``PagedTokenMap`` the attention
+  branch uses, run ``ssd_chunk`` as ``(R, w)`` whole rows and are written back
+  over their lines. At the full width ``R`` would be every row: nothing to
+  split, so every row is regrouped to ``(rows, w)`` and runs the chunk form
+  (``_chunk_rows``), as the row-major caller's rows do (``token_map`` None);
+  that whole-rows form is also what the tests hold the split to. The choice
+  follows from the shapes alone. A row whose ``context_len`` is 0 starts from
+  zeros in either form: the program does it, so a reused slot or a recomputed
+  (preempted) sequence needs no reset by the host.
 """
 
 from __future__ import annotations
@@ -79,6 +93,14 @@ class RecurrentStateView(NamedTuple):
     context_len: jax.Array  # (slots,) int32 tokens the state has seen
     new_len: jax.Array      # (slots,) int32 real tokens the row brings
     token_map: Optional[PagedTokenMap] = None  # token-major batches
+
+
+def split_capacity(width: int, row_width: int) -> int:
+    """Rows bringing MORE than one token that a token-major batch of ``width``
+    places advances beside its stepping rows (``Mamba2Mixer._split_rows``): as
+    many as it could hold at their widest. A tick with more of them runs at
+    the full width ``rows * row_width``, whose capacity is every row."""
+    return width // row_width
 
 
 def ssd_chunk(x, dt, A, B, C, S0, fresh=None):
@@ -290,38 +312,106 @@ class Mamba2Mixer(BaseLayer):
         return y.reshape(b, s, self.inner), (S, tail)
 
     def _serve(self, params, z, xBC, dt, view: RecurrentStateView):
-        """The tick's batch ``(g, s)`` against the slots' lines."""
+        """The tick's batch ``(g, s)`` against the slots' lines: whole rows
+        where the batch has a place for every row's widest chunk, else each
+        row in the form its ``new_len`` asks for."""
         g, s = xBC.shape[:2]
-        K = self.conv_kernel
-        ctx_len = view.context_len.astype(jnp.int32)
-        new_len = view.new_len.astype(jnp.int32)
+        lines = (view.ssm, view.conv, view.context_len.astype(jnp.int32),
+                 view.new_len.astype(jnp.int32))
         tmap = view.token_map
         if tmap is None:  # row-major: position (r, j) is row r's j-th token
-            rows_xBC, rows_dt = xBC, dt
+            y, S, tail = self._chunk_rows(params, xBC, dt, *lines)
         else:
-            flat = tmap.row_tokens                           # (rows, w)
-            rows_xBC = xBC.reshape(g * s, -1)[flat]
-            rows_dt = dt.reshape(g * s, -1)[flat]
-        rows, w = rows_dt.shape[:2]
+            rows, w = tmap.row_tokens.shape
+            xBC, dt = xBC.reshape(g * s, -1), dt.reshape(g * s, -1)
+            if g * s < rows * w:
+                y, S, tail = self._split_rows(params, xBC, dt, *lines, tmap)
+            else:
+                flat = tmap.row_tokens
+                y, S, tail = self._chunk_rows(params, xBC[flat], dt[flat], *lines)
+                # back to the batch's token order
+                y = y[tmap.row, jnp.minimum(tmap.offset, w - 1)]
+        new_view = view._replace(ssm=S.astype(view.ssm.dtype),
+                                 conv=tail.astype(view.conv.dtype))
+        return self._gated_out(params, y, z), new_view
+
+    def _chunk_rows(self, params, xBC, dt, ssm, conv, ctx_len, new_len):
+        """The whole-rows form: every row of ``(r, w)`` advances from its line
+        in one chunk, ``new_len`` of its places real. Returns ``(y (r, w,
+        inner), ssm, conv)``, float32 but the tail (``xBC``'s dtype)."""
+        r, w = dt.shape[:2]
+        K = self.conv_kernel
         real = jnp.arange(w, dtype=jnp.int32)[None, :] < new_len[:, None]
         # a row at context 0 starts from zeros, whatever its slot held
         fresh = (ctx_len == 0) & (new_len > 0)
-        tail = jnp.where(fresh[:, None, None], 0, view.conv)
+        tail = jnp.where(fresh[:, None, None], 0, conv)
         window = jnp.concatenate(
-            [jnp.swapaxes(tail, 1, 2).astype(rows_xBC.dtype), rows_xBC], axis=1)
+            [jnp.swapaxes(tail, 1, 2).astype(xBC.dtype), xBC], axis=1)
         conved = causal_conv(window, params["conv"]["weight"],
                              params["conv"]["bias"])
-        x, dt_r, A, B, C = self._ssm_inputs(params, conved, rows_dt, real)
-        y, S = ssd_chunk(x, dt_r, A, B, C, view.ssm.astype(F32), fresh)
+        x, dt, A, B, C = self._ssm_inputs(params, conved, dt, real)
+        y, S = ssd_chunk(x, dt, A, B, C, ssm.astype(F32), fresh)
         y = y + x * params["D"][:, None]
         # each channel's last K - 1 inputs, the row's new ones included: the
         # window's places new_len .. new_len + K - 2 (new_len 0: the old tail)
         last = new_len[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None, :]
         new_tail = jnp.take_along_axis(window, last[:, :, None], axis=1)
-        new_view = view._replace(
-            ssm=S.astype(view.ssm.dtype),
-            conv=jnp.swapaxes(new_tail, 1, 2).astype(view.conv.dtype))
-        y = y.reshape(rows, w, self.inner)
-        if tmap is not None:  # back to the batch's token order
-            y = y[tmap.row, jnp.minimum(tmap.offset, w - 1)]
-        return self._gated_out(params, y, z), new_view
+        return y.reshape(r, w, self.inner), S, jnp.swapaxes(new_tail, 1, 2)
+
+    def _step_rows(self, params, xBC, dt, ssm, conv, ctx_len, new_len):
+        """The recurrence's single step for the rows that bring ONE token,
+        ``xBC`` (r, conv_dim) and ``dt`` (r, nh) each row's first place of the
+        tick: the chunk form at ``w = 1`` with no ``w`` axis. Every other row
+        carries ``dt = 0`` and keeps its lines. The state is read once, and
+        the read-out comes from the value just computed. Returns ``(y (r,
+        inner), ssm, conv)`` as :meth:`_chunk_rows` does."""
+        r = dt.shape[0]
+        G, per = self.n_groups, self.num_heads // self.n_groups
+        steps = new_len == 1
+        fresh = steps & (ctx_len == 0)
+        tail = jnp.where(fresh[:, None, None], 0, conv).astype(xBC.dtype)
+        window = jnp.concatenate([tail, xBC[:, :, None]], axis=2)  # (r, c, K)
+        conved = causal_conv(jnp.swapaxes(window, 1, 2),
+                             params["conv"]["weight"], params["conv"]["bias"])
+        x, dt, A, B, C = self._ssm_inputs(
+            params, conved, dt[:, None], steps[:, None])
+        x, dt, B, C = x[:, 0], dt[:, 0], B[:, 0], C[:, 0]
+        xdt = (x * dt[..., None]).reshape(r, G, per, -1, 1)
+        carried = jnp.exp(dt * A).reshape(r, G, per, 1, 1) * ssm.astype(
+            F32).reshape(r, G, per, self.head_dim, self.state_size)
+        # as ssd_chunk: zeros chosen on what is computed from the state
+        carried = jnp.where(fresh[:, None, None, None, None], 0.0, carried)
+        S = carried + xdt * B[:, :, None, None, :]
+        y = jnp.sum(S * C[:, :, None, None, :], axis=-1)     # (r, G, per, P)
+        y = y.reshape(x.shape) + x * params["D"][:, None]
+        new_tail = jnp.where(steps[:, None, None], window[:, :, 1:], tail)
+        return y.reshape(r, self.inner), S.reshape(ssm.shape), new_tail
+
+    def _split_rows(self, params, xBC, dt, ssm, conv, ctx_len, new_len, tmap):
+        """A token-major batch ``(T, ..)`` narrower than ``rows x w``: rows
+        that bring one token step where they lie; the at most ``T // w`` that
+        bring more (the caller sees to that: ``split_capacity``) are gathered,
+        advanced as whole rows and written back over their lines. Returns
+        ``(y (g, s, inner), ssm, conv)``."""
+        rows, w = tmap.row_tokens.shape
+        R = split_capacity(xBC.shape[0], w)
+        first = tmap.row_tokens[:, 0]
+        y_step, S, tail = self._step_rows(
+            params, xBC[first], dt[first], ssm, conv, ctx_len, new_len)
+        multi = new_len > 1
+        # the multi-token rows in slot order, then `rows`: past the pool, so
+        # that nothing of a place no row fills is written back. A chunk row
+        # has stepped with dt = 0: its lines are still the old ones
+        at, = jnp.nonzero(multi, size=R, fill_value=rows)
+        held = jnp.minimum(at, rows - 1)
+        flat = tmap.row_tokens[held]                         # (R, w)
+        y_chunk, S_chunk, tail_chunk = self._chunk_rows(
+            params, xBC[flat], dt[flat], S[held], tail[held], ctx_len[held],
+            jnp.where(at < rows, new_len[held], 0))
+        S = S.at[at].set(S_chunk, mode="drop")
+        tail = tail.at[at].set(tail_chunk.astype(tail.dtype), mode="drop")
+        # a token reads its row's step, or its place in its row's chunk
+        place = jnp.cumsum(multi)[tmap.row] - 1
+        place = jnp.clip(place, 0, R - 1) * w + jnp.minimum(tmap.offset, w - 1)
+        y = jnp.concatenate([y_step, y_chunk.reshape(R * w, self.inner)])
+        return y[jnp.where(multi[tmap.row], rows + place, tmap.row)], S, tail

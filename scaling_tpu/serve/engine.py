@@ -60,6 +60,7 @@ from typing import Dict, List, NamedTuple, Optional, Tuple
 
 from .. import obs
 from ..logging import logger
+from ..nn.mamba import split_capacity
 from ..nn.paged_attention import kernel_tile_tokens
 from ..resilience.faults import get_fault_plan
 from .kvcache import (
@@ -860,7 +861,13 @@ class ServeEngine:
                 # inactive rows keep all-trash tables + new_len 0: they
                 # bring no token and expose zero visible slots
                 real = [tok for row in row_tokens for tok in row]
-                width = next(w for w in cfg.mixed_widths if len(real) <= w)
+                # recurrent lines advance row by row below the full width,
+                # which holds so many multi-token rows (nn/mamba.py)
+                multi = (int(np.count_nonzero(new_lens > 1))
+                         if self.ssm_lines else 0)
+                width = next(
+                    w for w in cfg.mixed_widths if len(real) <= w
+                    and multi <= split_capacity(w, cfg.mixed_width))
                 tick.tokens[:len(real)] = real
                 tick.temps[:], tick.topps[:] = self._temp, self._topp
                 tick.topks[:], tick.reqids[:] = self._topk, self._reqid
@@ -886,14 +893,24 @@ class ServeEngine:
                 ).inc()
                 # rows whose per-slot lines advanced, in every Mamba-2 layer
                 # (ssm) and in every short convolution (conv)
+                rows = int(np.count_nonzero(new_lens))
                 for kind, lines in (("ssm", self.ssm_lines),
                                     ("conv", self.conv_lines)):
                     if lines:
-                        rows = int(np.count_nonzero(new_lens))
                         mixed_span.annotate(**{f"{kind}_rows": rows,
                                                f"{kind}_lines": lines})
                         self._counter(
                             f"serve_{kind}_state_updates_total").inc(rows * lines)
+                if self.ssm_lines:
+                    # the form that advanced them (nn/mamba.py): at the full
+                    # width whole rows, below it a step or a gathered chunk
+                    mixed_span.annotate(
+                        ssm_step_rows=rows - multi, ssm_chunk_rows=multi)
+                    paths = ({"whole": rows} if width == cfg.mixed_widths[-1]
+                             else {"step": rows - multi, "chunk": multi})
+                    for path, count in paths.items():
+                        self._counter("serve_ssm_rows_total", path=path).inc(
+                            count * self.ssm_lines)
                 if self.loop_steps > 1:
                     mixed_span.annotate(loop_steps=self.loop_steps)
                     self._counter("serve_loop_layer_passes_total").inc(

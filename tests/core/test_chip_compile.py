@@ -256,6 +256,17 @@ def lowered_mixed_program(one_chip, monkeypatch, bucket, heads, kv_heads,
     return lowered, jax.tree_util.tree_leaves(params), pool
 
 
+def whole_copies(text, shape):
+    """The compiled module's copies of a whole array of ``shape`` (a regex)
+    from device memory to device memory. A copy INTO or OUT OF fast memory
+    (either side's layout ends in ``S(1)``) is the compiler staging a toy
+    array of a few MB there and back; a serving pool is 134 MB, as is a
+    Mamba-2 layer's state at 64 slots."""
+    return [line for line in text.splitlines()
+            if re.search(rf"= \(?{shape}\S*[^=]* copy(-start)?\(", line)
+            and "S(1)" not in line.split(" copy")[0]]
+
+
 def assert_pools_updated_in_place(text, first, layers, pool):
     """Parameters flatten (params, pool_k[0..], pool_v[0..], operand, key);
     outputs (tokens, pool_k[0..], pool_v[0..])."""
@@ -266,10 +277,7 @@ def assert_pools_updated_in_place(text, first, layers, pool):
     }
     assert pairs == {first + j: 1 + j for j in range(2 * layers)}
     dims = ",".join(map(str, pool.shape))
-    # (a copy INTO fast memory, layout `...S(1)`, is the compiler
-    # prefetching this toy pool of 4 MB; a serving pool is 134 MB)
-    copies = [c for c in re.findall(rf"= bf16\[{dims}\]\S* copy\S*\(", text)
-              if "S(1)" not in c]
+    copies = whole_copies(text, rf"bf16\[{dims}\]")
     assert not copies, f"{len(copies)} whole-pool copies in the compiled tick"
 
 
@@ -344,6 +352,48 @@ def test_hybrid_mixed_program_updates_pools_and_recurrent_lines_in_place(
     assert pairs == {first + j: 1 + j for j in range(4)}   # k, v, ssm, conv
     dims = ",".join(map(str, pool.shape))
     for shape in (rf"bf16\[{dims}\]", r"f32\[8,64,64,128\]"):
-        copies = [c for c in re.findall(rf"= {shape}\S* copy\S*\(", text)
-                  if "S(1)" not in c]
+        copies = whole_copies(text, shape)
         assert not copies, f"{len(copies)} whole copies of {shape} in the compiled tick"
+
+
+def test_a_one_token_row_reads_its_mamba_state_once_at_the_cells_size(one_chip):
+    """The Mamba-2 mixer alone at the hybrid cell's size (64 slots of 64 heads
+    x 64 x 128 float32 = 134 MB a layer, a token-major tick of 256 places for
+    rows of up to 32: ISSUE 49): the rows that bring one token advance in ONE
+    fusion that takes the donated state and gives both the read-out `(64, 64,
+    64)` and the new state, written over the old one; the at most 8 rows that
+    bring a chunk are sliced out and scattered back in place. No second pass
+    over the state, no copy of it, no `(64 rows, 32 places, ..)` tensor."""
+    from scaling_tpu.nn.attention import packed_token_map
+    from scaling_tpu.nn.base_layer import ForwardContext
+    from scaling_tpu.nn.mamba import Mamba2Mixer, RecurrentStateView
+
+    slots, w, places = 64, 32, 256
+    layer = Mamba2Mixer(2688, 64, 64, 128, 8, 4, dtype=jnp.bfloat16)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def tick(params, u, ssm, conv, ctx_len, new_len):
+        view = RecurrentStateView(ssm, conv, ctx_len, new_len,
+                                  packed_token_map(new_len, u.shape[:2], w))
+        out, view = layer(params, u, ForwardContext(), state=view)
+        return out, view.ssm, view.conv
+
+    params = jax.tree.map(lambda x: shape(x.shape, x.dtype),
+                          jax.eval_shape(layer.init, jax.random.PRNGKey(0)))
+    text = jax.jit(tick, donate_argnums=(2, 3)).lower(
+        params, shape((places // w, w, 2688), jnp.bfloat16),
+        shape((slots, 64, 64, 128), jnp.float32),
+        shape((slots, layer.conv_dim, 3), jnp.bfloat16),
+        shape((slots,), jnp.int32), shape((slots,), jnp.int32),
+    ).compile().as_text()
+    entry = text[text.index("\nENTRY "):]
+    state = r"f32\[64,64,64,128\]"
+    readers = [line for line in entry.splitlines()
+               if re.search(r"fusion\([^)]*%ssm", line)]
+    assert len(readers) == 1, readers      # the donated state has ONE reader
+    assert re.search(rf"= \(f32\[64,64,64\]\S*, {state}\S*\) fusion\(", readers[0])
+    assert not whole_copies(text, state)
+    assert not re.search(r"\[64,32,\d", entry)     # nothing is rows x places wide
+    assert "f32[8,64,64,128]" in entry             # the gathered chunk rows

@@ -9,7 +9,8 @@ import pytest
 
 from scaling_tpu.nn.attention import packed_token_map
 from scaling_tpu.nn.base_layer import ForwardContext
-from scaling_tpu.nn.mamba import Mamba2Mixer, RecurrentStateView, ssd_chunk
+from scaling_tpu.nn.mamba import (
+    Mamba2Mixer, RecurrentStateView, split_capacity, ssd_chunk)
 
 H, HEADS, P, N, G, K = 48, 4, 8, 16, 2, 4
 # float32 against float32, another order of summation: a few roundings of
@@ -108,50 +109,120 @@ def test_a_sequence_longer_than_a_chunk_is_walked_chunk_by_chunk(mixer, monkeypa
     assert outs[0][2].shape == (2, HEADS * P + 2 * G * N, K - 1)
 
 
+def run_tick(layer, params, u_rows, lines, ctx_len, new_len, w, width=None):
+    """One tick of the served path: row ``r`` brings ``u_rows[r]`` (new_len[r],
+    H). ``width`` None: the row-major caller; else token-major at that many
+    places. Returns ``([each row's outputs], ssm, conv)``."""
+    ssm, conv = lines
+    if width is None:
+        batch = jnp.stack([jnp.pad(r, ((0, w - r.shape[0]), (0, 0))) for r in u_rows])
+        tmap = None
+    else:
+        packed = jnp.concatenate(u_rows)
+        batch = jnp.pad(packed, ((0, width - packed.shape[0]), (0, 0)))
+        batch = batch.reshape(width // w, w, H)
+        tmap = packed_token_map(jnp.asarray(new_len, jnp.int32), batch.shape[:2], w)
+    out, view = jax.jit(lambda b, v: layer(params, b, ForwardContext(), state=v))(
+        batch, RecurrentStateView(
+            ssm, conv, jnp.asarray(ctx_len, jnp.int32),
+            jnp.asarray(new_len, jnp.int32), tmap))
+    out = np.asarray(out)
+    if width is None:
+        outs = [out[r, :n] for r, n in enumerate(new_len)]
+    else:
+        ends = np.cumsum(new_len)
+        outs = [out.reshape(-1, H)[e - n:e] for e, n in zip(ends, new_len)]
+    return outs, np.asarray(view.ssm), np.asarray(view.conv)
+
+
 @pytest.mark.parametrize("token_major", [False, True], ids=["row-major", "token-major"])
 def test_chunk_32_then_one_token_at_a_time_equals_one_pass(mixer, token_major):
     """A row served a chunk of 32, then 5 + 1 + 1 + 1 tokens against its
     line of the state pool, beside an empty slot and a row that starts later:
-    each row's outputs are its sequence's in one uncached pass."""
+    each row's outputs are its sequence's in one uncached pass. Token-major,
+    at the fewest whole rows of places that hold a tick's tokens, the ticks
+    cross both forms: whole rows, a step beside a gathered chunk, steps alone."""
     layer, params = mixer
-    ctx = ForwardContext()
     u = jax.random.normal(jax.random.PRNGKey(3), (2, 40, H))
-    want = np.asarray(layer(params, u, ctx))
+    want = np.asarray(layer(params, u, ForwardContext()))
     slots, w = 3, 32
-    view = RecurrentStateView(
-        ssm=jnp.full((slots, HEADS, P, N), 7.0),           # an old occupant's
-        conv=jnp.full((slots, HEADS * P + 2 * G * N, K - 1), 7.0),
-        context_len=None, new_len=None)
+    lines = (jnp.full((slots, HEADS, P, N), 7.0),           # an old occupant's
+             jnp.full((slots, HEADS * P + 2 * G * N, K - 1), 7.0))
     got = {0: [], 2: []}
     # (tokens of row 0, tokens of row 2) a tick; slot 1 stays empty
     seen = [0, 0]
     for n0, n2 in ((32, 0), (5, 32), (1, 1), (1, 1), (1, 6)):
-        new_len = jnp.asarray([n0, 0, n2], jnp.int32)
-        ctx_len = jnp.asarray([seen[0], 0, seen[1]], jnp.int32)
+        new_len, ctx_len = [n0, 0, n2], [seen[0], 0, seen[1]]
         rows = [u[0, seen[0]:seen[0] + n0], u[1, :0], u[1, seen[1]:seen[1] + n2]]
-        if token_major:
-            packed = jnp.concatenate(rows)
-            width = -(-packed.shape[0] // w) * w
-            batch = jnp.pad(packed, ((0, width - packed.shape[0]), (0, 0)))
-            batch = batch.reshape(width // w, w, H)
-            tmap = packed_token_map(new_len, batch.shape[:2], w)
-        else:
-            batch = jnp.stack([jnp.pad(r, ((0, w - r.shape[0]), (0, 0))) for r in rows])
-            tmap = None
-        out, view = layer(params, batch, ctx, state=view._replace(
-            context_len=ctx_len, new_len=new_len, token_map=tmap))
-        flat, at = np.asarray(out).reshape(-1, H), 0
-        for slot, n in ((0, n0), (1, 0), (2, n2)):
-            if slot in got and n:
-                rows_out = flat[at:at + n] if token_major else np.asarray(out)[slot, :n]
-                got[slot].append(rows_out)
-            at += n
+        width = -(-(n0 + n2) // w) * w if token_major else None
+        outs, *lines = run_tick(layer, params, rows, lines, ctx_len, new_len, w, width)
+        for slot in got:
+            got[slot].append(outs[slot])
         seen = [seen[0] + n0, seen[1] + n2]
     np.testing.assert_allclose(np.concatenate(got[0]), want[0], atol=ATOL, rtol=1e-5)
     np.testing.assert_allclose(np.concatenate(got[2]), want[1], atol=ATOL, rtol=1e-5)
     # the empty slot's lines were never written
-    assert np.array_equal(np.asarray(view.ssm[1]), np.full((HEADS, P, N), 7.0))
-    assert np.array_equal(np.asarray(view.conv[1]), np.full_like(view.conv[1], 7.0))
+    assert np.array_equal(lines[0][1], np.full((HEADS, P, N), 7.0))
+    assert np.array_equal(lines[1][1], np.full_like(lines[1][1], 7.0))
+
+
+def width_for(new_len, widths, w):
+    """The engine's rule (serve/engine.py ``_run_mixed``): the smallest width
+    that holds the tick's tokens AND its multi-token rows."""
+    return next(T for T in widths if sum(new_len) <= T
+                and sum(n > 1 for n in new_len) <= split_capacity(T, w))
+
+
+# 8 slots, rows of up to 4 tokens: a small program of 16 places (R = 4
+# multi-token rows) and the full one of 32. (new_len, ctx_len) a case
+SPLIT_W, SPLIT_WIDTHS = 4, (16, 32)
+SPLIT_TICKS = {
+    "every row decodes": ([1] * 8, [5, 9, 3, 7, 1, 2, 8, 4]),
+    "decode rows among chunks of 2..w": ([1, 3, 1, 2, 4, 1, 1, 1],
+                                         [5, 4, 3, 8, 4, 7, 6, 2]),
+    "empty rows": ([0, 1, 0, 0, 1, 0, 2, 0], [0, 3, 5, 0, 2, 9, 4, 0]),
+    "context 0 brings ONE token": ([1, 1, 1, 1, 0, 2, 0, 1],
+                                   [0, 3, 0, 6, 0, 5, 2, 0]),
+    "chunks in reused slots": ([3, 1, 4, 1, 0, 1, 2, 1], [0, 2, 0, 5, 0, 0, 0, 9]),
+    "exactly R multi-token rows": ([2, 2, 3, 2, 1, 1, 1, 1],
+                                   [0, 4, 8, 0, 1, 0, 3, 2]),
+    "R + 1 multi-token rows": ([2, 2, 2, 2, 2, 1, 1, 1], [0, 4, 8, 0, 1, 0, 3, 2]),
+    "nothing but a chunk": ([0, 0, 0, 4, 0, 0, 0, 0], [0] * 8),
+}
+
+
+@pytest.mark.parametrize("reference", ["row-major", "token-major at the full width"])
+@pytest.mark.parametrize("case", list(SPLIT_TICKS))
+def test_each_row_in_its_own_form_equals_the_whole_rows_form(mixer, case, reference):
+    """Below the full width a row that brings one token takes the single
+    step and the few that bring more are gathered into a chunk: outputs, state
+    lines and conv tails are the whole-rows form's, whichever caller reaches
+    that. A row at context 0 starts from zeros though its slot holds an old
+    occupant's NaNs; an empty row's lines are not touched."""
+    layer, params = mixer
+    new_len, ctx_len = SPLIT_TICKS[case]
+    slots, w, (small, full) = len(new_len), SPLIT_W, SPLIT_WIDTHS
+    ks = jax.random.split(jax.random.PRNGKey(len(case)), 3)
+    starts_over = [r for r in range(slots) if ctx_len[r] == 0 and new_len[r] > 0]
+    lines = tuple(
+        jax.random.normal(k, shape).at[jnp.asarray(starts_over, int)].set(jnp.nan)
+        for k, shape in ((ks[0], (slots, HEADS, P, N)),
+                         (ks[1], (slots, HEADS * P + 2 * G * N, K - 1))))
+    u = jax.random.normal(ks[2], (slots, w, H))
+    u_rows = [u[r, :n] for r, n in enumerate(new_len)]
+    width = width_for(new_len, SPLIT_WIDTHS, w)
+    assert width == (full if case.startswith("R + 1") else small)
+    got = run_tick(layer, params, u_rows, lines, ctx_len, new_len, w, width)
+    want = run_tick(layer, params, u_rows, lines, ctx_len, new_len, w,
+                    None if reference == "row-major" else full)
+    for r, n in enumerate(new_len):
+        np.testing.assert_allclose(got[0][r], want[0][r], atol=ATOL, rtol=1e-5)
+        assert not n or np.isfinite(got[0][r]).all()
+        if not n:   # bit for bit what the slot held
+            assert np.array_equal(got[1][r], np.asarray(lines[0][r]), equal_nan=True)
+            assert np.array_equal(got[2][r], np.asarray(lines[1][r]), equal_nan=True)
+    np.testing.assert_allclose(got[1], want[1], atol=ATOL, rtol=1e-5)
+    np.testing.assert_allclose(got[2], want[2], atol=ATOL, rtol=1e-5)
 
 
 def test_relu2_is_the_square_of_relu():

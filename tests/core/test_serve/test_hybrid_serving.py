@@ -216,8 +216,19 @@ def test_spans_counters_and_load_of_a_share(hybrid, tmp_path):
     assert mixed and len(emits) == len(mixed)
     assert all(f["ssm_lines"] == M_LAYERS for f in mixed)
     assert [f["ssm_rows"] for f in mixed] == [f["decodes"] + f["chunks"] for f in mixed]
+    assert [f["ssm_step_rows"] + f["ssm_chunk_rows"] for f in mixed] == [
+        f["ssm_rows"] for f in mixed]
+    # a decode row brings one token; so does the 9-token prompt's last chunk
+    assert [f["ssm_chunk_rows"] for f in mixed[:3]] == [2, 1, 0]
+    assert [f["chunks"] for f in mixed[:3]] == [2, 2, 0]
     assert capture.counters["serve_ssm_state_updates_total"] == M_LAYERS * sum(
         f["ssm_rows"] for f in mixed)
+    # 4 slots x chunk 8 build the one program, of the full width: whole rows
+    assert engine.config.mixed_widths == (32,)
+    assert {k: v for k, v in capture.counters.items()
+            if k.startswith("serve_ssm_rows_total")} == {
+        "serve_ssm_rows_total{path=whole}":
+            capture.counters["serve_ssm_state_updates_total"]}
     # every real position's 3 assignments in each of the 2 routed layers fell
     # on a held expert or on an absent one
     routed = PATTERN.count("E")
@@ -228,6 +239,39 @@ def test_spans_counters_and_load_of_a_share(hybrid, tmp_path):
     assert absent == sum(f["absent_assign"] for f in emits)
     for f in emits:   # the load is over the four HELD experts
         assert 0 <= f["experts_idle"] <= 4 and f["load_max"] >= f["load_mean"] >= 0
+
+
+def test_a_tick_with_more_chunk_rows_than_the_small_width_gathers_runs_whole(
+        hybrid, tmp_path):
+    """16 slots x chunk 32 build two programs; the one of 128 places advances
+    one-token rows by the single step and gathers up to 4 chunk rows. Six
+    short prompts and a long one arrive at once: the first tick holds 65
+    tokens, which fit 128 places, and 7 chunk rows, which do not fit 4, so it
+    runs whole rows at the full width; the long prompt's second chunk then
+    rides beside six decode rows in the small program. Every request gets the
+    uncached forward's tokens."""
+    requests = prompts((3, 4, 5, 6, 7, 8, 40), seed=5)
+    want = [hybrid.generate(p, max_tokens=6, use_cache=False).completion_ids
+            for p in requests]
+    engine = engine_of(hybrid, num_slots=16, prefill_chunk=32, token_budget=128,
+                       max_blocks_per_seq=16, num_blocks=16 * 16 + 1)
+    assert engine.config.mixed_widths == (128, 512)
+    obs.start_capture(str(tmp_path))
+    try:
+        got = served(engine, requests, 6)
+    finally:
+        capture = obs.stop_capture()
+    assert [got[i] for i in range(len(requests))] == want
+    mixed = [f for n, _, _, f in capture.spans if n == "serve.mixed"]
+    assert [(f["width"], f["tokens"], f["ssm_step_rows"], f["ssm_chunk_rows"])
+            for f in mixed[:3]] == [(512, 65, 0, 7), (128, 14, 6, 1), (128, 7, 7, 0)]
+    assert all(f["width"] == 128 for f in mixed[1:])
+    by_path = {path: capture.counters[f"serve_ssm_rows_total{{path={path}}}"]
+               for path in ("step", "chunk", "whole")}
+    assert by_path == {
+        "whole": M_LAYERS * 7, "chunk": M_LAYERS * 1,
+        "step": M_LAYERS * sum(f["ssm_step_rows"] for f in mixed[1:])}
+    assert sum(by_path.values()) == capture.counters["serve_ssm_state_updates_total"]
 
 
 def test_a_model_that_holds_all_its_experts_counts_what_it_counted(tmp_path):
