@@ -35,6 +35,7 @@ from .model import init_model
 from .tokenizer import Tokenizer
 from ...checkpoint import load_model_checkpoint
 from ...nn.attention import PagedKVCacheView
+from ...nn.moe import ParallelMoEMLP
 from ...parallel.parallel_module import ParallelModule
 
 
@@ -352,6 +353,19 @@ class TransformerInferenceModule:
         ctx = self.module._make_ctx(deterministic=True, dropout_key=None)
         ctx.serving = True
         return ctx
+
+    def moe_serve_rows(self, places: int):
+        """``(form, rows)`` of a pass over ``places`` positions: the form the
+        routed layers' expert matmuls take (``ParallelMoEMLP.serve_rows``)
+        and the rows they are given over all routed layers and loop steps
+        (the serving engine counts them a tick)."""
+        mesh = self._make_ctx().mesh
+        routed = [
+            mlp.serve_rows(places, mesh) for layer in self.module.layers
+            for mlp in (getattr(layer, "mlp", None), getattr(layer, "mixer", None))
+            if isinstance(mlp, ParallelMoEMLP)]
+        return routed[0][0], self.architecture.loop_steps * sum(
+            rows for _, rows in routed)
 
     def _paged_layer_calls(self, ctx):
         """``call(layer)``: the layer as a function of (params, activations,

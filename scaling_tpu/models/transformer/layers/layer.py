@@ -285,7 +285,8 @@ class MixerLayer(BaseLayer):
                 y, state = y
         elif self.kind == LayerKind.MOE:
             if ctx.serving:
-                y, load = self.mixer.serve(params["mixer"], normed, real)
+                y, load = self.mixer.serve(
+                    params["mixer"], normed, real, ctx.mesh)
                 if load is not None:
                     out["moe_load"] = x.get("moe_load", 0) + load
             else:
@@ -531,7 +532,8 @@ class TransformerLayer(BaseLayer):
             real = None
             if isinstance(kv_cache, PagedKVCacheView):
                 real = kv_cache.token_rows(h.shape[:2])[2]
-            mlp_out, moe_load = self.mlp.serve(params["mlp"], normed, real)
+            mlp_out, moe_load = self.mlp.serve(
+                params["mlp"], normed, real, ctx.mesh)
         elif self.is_moe:
             mlp_out, aux_loss = self.mlp(params["mlp"], normed, ctx)
         else:

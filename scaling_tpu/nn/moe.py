@@ -6,10 +6,10 @@ TPU way — the GShard/Switch dense-dispatch formulation:
 
 - the router scores every token against ``num_experts`` experts; top-k
   gating with a Switch-style load-balance auxiliary loss;
-- a static ``capacity_factor`` bounds tokens per expert, so every shape is
-  static and the whole block is three einsums on the MXU (dispatch,
+- training: a static ``capacity_factor`` bounds tokens per expert, so every
+  shape is static and the whole block is three einsums on the MXU (dispatch,
   expert FFN, combine) — no sorting, no ragged tensors, no host control
-  flow;
+  flow (serving sorts: below);
 - the expert dimension is sharded over the ``data`` mesh axis (canonical
   expert-parallel: EP reuses the DP devices) and the expert FFN's hidden
   dim over ``model``; GSPMD derives the token all-to-alls from these
@@ -19,21 +19,41 @@ Dropped tokens (over capacity) fall through on the residual path, exactly
 as in Switch Transformers (Fedus et al. 2021).
 
 Serving (``serve``; ``TransformerLayer`` takes it whenever the pass is one
-of ``TransformerInferenceModule``'s: ``ForwardContext.serving``) runs the
-same three einsums with ONE difference, the value of the capacity: every
-expert has room for the whole row (``C = s``; a token names an expert at
-most once, so no row can send an expert more), whatever
-``moe_capacity_factor`` says. A capacity taken over the rows of a serving
-batch has no meaning: a row of the engine's mixed program is a prefill
-chunk padded to its fixed width or a single decode token, so
-``capacity_factor * k * s / E`` would count padding as tokens, and which
-assignment fell over the edge would depend on how a prompt was cut into
-chunks: prefill-then-decode would no longer equal the full forward pass.
-Inference drops nothing, as the published models do not. Padded positions
-are routed like any other (their outputs are never read and their KV goes
-to the trash block), but they are left out of the load vector ``serve``
-returns: how many assignments of REAL positions each expert received. The
-auxiliary loss is a training term and is not computed when serving.
+of ``TransformerInferenceModule``'s: ``ForwardContext.serving``) drops
+nothing, as the published models do not, and has no capacity at all. A
+capacity taken over the rows of a serving batch has no meaning: a row of the
+engine's mixed program is a prefill chunk padded to its fixed width or a
+single decode token, so ``capacity_factor * k * s / E`` would count padding
+as tokens, and which assignment fell over the edge would depend on how a
+prompt was cut into chunks: prefill-then-decode would no longer equal the
+full forward pass. The one-hot form at room for the whole row (``C = s``;
+``_experts``) says that, and prices every held expert on every place: at a
+decode tick's 256 places and 64 experts, 16 x the FLOPs that 4 experts a
+token need, and the experts' weight read has become compute (PERF.md, PR
+50). So ``serve`` runs the experts over the tick's ``A = places x k``
+ASSIGNMENTS themselves (``_experts_grouped``): each takes the index of its
+held expert (or of a trailing group that has no matrix, where the expert is
+absent); they are
+ordered by that index, stable, which is the token-major order the one-hot's
+running count defines; the ``A`` rows of ``x`` are gathered in that order
+and the up / gate / down matrices are grouped matmuls over the
+``(held,)`` group sizes (``ops/grouped_matmul.py``: operands in ``x.dtype``,
+float32 accumulation; a Pallas kernel under this ``moe`` scope on the chip,
+``jax.lax.ragged_dot`` off it): an expert's matrices are read once and
+multiplied with its own rows, an expert nobody chose is not read; each row is
+weighted with its float32 gate, put back in ``(place, choice)`` order and
+summed over ``k``, the trailing group's rows selected out (a kernel leaves
+them unwritten). This equals ``_experts`` at ``C = s``, which stays as the tests' reference for it, as
+training's form (``__call__``: Switch's drop rule is another semantics, and
+its gradient runs through the einsums), and as ``serve``'s form wherever the
+expert leaves are sharded over a mesh axis (``serve_rows``: GSPMD partitions
+einsums, not a kernel). Padded positions are routed and computed like any
+other (their outputs are never read and their KV goes to the trash block;
+the kernel is bound by the matrices it reads, not by its rows, so leaving
+them out would buy nothing: PERF.md, PR 50), but they are left out of the
+load vector ``serve`` returns: how many assignments of REAL
+positions each expert received. The auxiliary loss is a training term and is
+not computed when serving.
 
 ``norm_topk_prob`` (a fact of the model, not a knob): whether a token's k
 gate weights are renormalised to sum to one (Switch/GShard; the default)
@@ -74,7 +94,9 @@ from ..topology.topology import DATA_AXIS, MODEL_AXIS
 
 
 class ParallelMoEMLP(BaseLayer):
-    """Top-k routed expert MLPs (SwiGLU or plain) behind one dense dispatch."""
+    """Top-k routed expert MLPs (SwiGLU or plain): a dense one-hot dispatch
+    at a capacity in training, grouped matmuls over the sorted assignments
+    when serving."""
 
     def __init__(
         self,
@@ -231,18 +253,24 @@ class ParallelMoEMLP(BaseLayer):
         return self.experts_held == self.num_experts
 
     def serve(
-        self, params: dict, x: jax.Array, real: Optional[jax.Array] = None
+        self, params: dict, x: jax.Array, real: Optional[jax.Array] = None,
+        mesh=None,
     ) -> Tuple[jax.Array, Optional[jax.Array]]:
-        """Serving: room for the whole row, so nothing is dropped, and no
-        auxiliary loss. ``real`` ((b, s) bool): which positions hold a
-        token. Returns (output (b,s,h), the (held,) int32 count of the real
-        positions' assignments each HELD expert received; None without
-        ``real``). A layer that holds a share of the experts appends one
-        more count: the real positions' assignments that fell on absent
+        """Serving: nothing is dropped, and no auxiliary loss. ``real``
+        ((b, s) bool): which positions hold a token. ``mesh``: the mesh the
+        leaves live on (``ForwardContext.mesh``), which decides the form
+        (``serve_rows``). Returns (output (b,s,h), the (held,) int32 count
+        of the real positions' assignments each HELD expert received; None
+        without ``real``). A layer that holds a share of the experts appends
+        one more count: the real positions' assignments that fell on absent
         experts (the two sum to ``top_k`` a real position)."""
         with jax.named_scope("moe"):
             _, gate_vals, gate_idx = self._route(params, x)
-            y = self._experts(params, x, gate_vals, gate_idx, capacity=x.shape[1])
+            if self.serve_rows(x.shape[0] * x.shape[1], mesh)[0] == "grouped":
+                y = self._experts_grouped(params, x, gate_vals, gate_idx)
+            else:
+                y = self._experts(
+                    params, x, gate_vals, gate_idx, capacity=x.shape[1])
             y = self._add_shared(params, x, y)
             if real is None:
                 return y, None
@@ -253,6 +281,21 @@ class ParallelMoEMLP(BaseLayer):
                 absent = self.top_k * real.sum(dtype=jnp.int32) - load.sum()
                 load = jnp.concatenate([load, absent[None]])
             return y, load
+
+    def serve_rows(self, places: int, mesh=None) -> Tuple[str, int]:
+        """The form ``serve`` takes over ``places`` positions, and the rows
+        its expert matmuls are given: ``("grouped", places x k)`` wherever
+        the expert leaves are whole on the device; ``("dense", held x
+        places)``, the one-hot at room for the whole row, where a mesh axis
+        their partition names (experts over ``data``, their width over
+        ``model``) has more than one device: GSPMD partitions the einsums,
+        it cannot partition the kernel. Static for a program (the engine
+        counts it: ``serve_moe_rows_total``)."""
+        sharded = mesh is not None and any(
+            mesh.shape.get(axis, 1) > 1 for axis in (DATA_AXIS, MODEL_AXIS))
+        if sharded:
+            return "dense", self.experts_held * places
+        return "grouped", places * self.top_k
 
     def _local(self, gate_idx: jax.Array) -> jax.Array:
         """The chosen experts' places among those held; an absent expert's
@@ -342,3 +385,45 @@ class ParallelMoEMLP(BaseLayer):
             act = self.activation_fn(up)
         out = jnp.einsum("ebcf,efh->ebch", act, params["w_out"].astype(x.dtype))
         return jnp.einsum("bsec,ebch->bsh", combine.astype(x.dtype), out)
+
+    def _experts_grouped(
+        self, params: dict, x: jax.Array, gate_vals: jax.Array,
+        gate_idx: jax.Array,
+    ) -> jax.Array:
+        """The held experts over the tick's assignments themselves: sorted
+        by expert, each expert's matrices multiplied with its own rows
+        (``ops/grouped_matmul.py``), weighted and summed back in place.
+        Equal to ``_experts`` at ``capacity = s``."""
+        from ..ops.grouped_matmul import grouped_matmul
+
+        b, s, h = x.shape
+        E, k = self.experts_held, self.top_k
+        places = b * s
+        # an assignment's group: its held expert, or, where the expert is
+        # absent, the trailing group E, which has no matrix and which no
+        # matmul visits
+        group = self._local(gate_idx).reshape(places * k)
+        taken = (group >= 0) & (group < E)
+        group = jnp.where(taken, group, E)
+        # stable: token-major within an expert, the order the one-hot's
+        # running count defines
+        order = jnp.argsort(group, stable=True)
+        place = jnp.zeros_like(order).at[order].set(
+            jnp.arange(places * k, dtype=order.dtype), unique_indices=True)
+        sizes = (group[:, None] == jnp.arange(E, dtype=group.dtype)).sum(
+            0, dtype=jnp.int32)
+
+        rows = x.reshape(places, h)[order // k]
+        up = grouped_matmul(rows, params["w_in"].astype(x.dtype), sizes)
+        if self.glu:
+            gate = grouped_matmul(rows, params["w_gate"].astype(x.dtype), sizes)
+            act = self.activation_fn(gate) * up
+        else:
+            act = self.activation_fn(up)
+        out = grouped_matmul(act, params["w_out"].astype(x.dtype), sizes)
+        # back in (place, choice) order; a row of the trailing group was
+        # never written: selected out, not multiplied by zero
+        out = out[place].reshape(b, s, k, h).astype(jnp.float32)
+        taken = taken.reshape(b, s, k, 1)
+        y = jnp.where(taken, gate_vals[..., None] * out, 0.0).sum(axis=2)
+        return y.astype(x.dtype)

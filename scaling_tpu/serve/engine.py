@@ -361,6 +361,12 @@ class ServeEngine:
         # assignments that fell on absent experts (one more entry)
         self.num_experts = arch.moe_held if routed else 0
         self.moe_partial = routed and arch.moe_held < arch.moe_num_experts
+        # token width -> (the form a routed layer's expert matmuls take at
+        # it, the rows they are given over all routed layers): static for a
+        # program (nn/moe.py serve_rows)
+        self._moe_rows = {
+            width: inference_module.moe_serve_rows(width)
+            for width in self.config.mixed_widths} if routed else {}
         # a looped model (loop_steps > 1): every tick walks the trunk
         # loop_steps times; with an exit gate the mixed program also
         # returns, in the tick's one host read, the exit distribution over
@@ -911,6 +917,14 @@ class ServeEngine:
                     for path, count in paths.items():
                         self._counter("serve_ssm_rows_total", path=path).inc(
                             count * self.ssm_lines)
+                if self.num_experts:
+                    # the rows the tick's expert matmuls were given; with
+                    # serve_moe_assignments_total (the real, held assignments
+                    # they are for) the share of them that is real work
+                    path, moe_rows = self._moe_rows[width]
+                    mixed_span.annotate(moe_rows=moe_rows)
+                    self._counter("serve_moe_rows_total", path=path).inc(
+                        moe_rows)
                 if self.loop_steps > 1:
                     mixed_span.annotate(loop_steps=self.loop_steps)
                     self._counter("serve_loop_layer_passes_total").inc(

@@ -195,6 +195,10 @@ def lowered_mixed_program(one_chip, monkeypatch, bucket, heads, kv_heads,
         "scaling_tpu.nn.paged_attention.paged_kernel_interpret",
         lambda platform=None: False,
     )
+    monkeypatch.setattr(
+        "scaling_tpu.ops.grouped_matmul.grouped_matmul_interpret",
+        lambda platform=None: False,
+    )
     slots, max_blocks = 8, 16
     config = TransformerConfig.from_dict({
         "topology": {
@@ -254,6 +258,19 @@ def lowered_mixed_program(one_chip, monkeypatch, bucket, heads, kv_heads,
         on_chip(engine._base_key),
     )
     return lowered, jax.tree_util.tree_leaves(params), pool
+
+
+def custom_calls(text):
+    """The compiled module's Mosaic kernels: their ``op_name``s."""
+    return [re.search(r'op_name="([^"]*)"', line).group(1)
+            for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def scope_of(op_name):
+    """The routed MLP's scope if the instruction was compiled from inside it
+    (what ``benchmark/xplane_hlo.instruction_scopes`` looks up)."""
+    return "moe" if "/moe/" in op_name else None
 
 
 def whole_copies(text, shape):
@@ -344,7 +361,10 @@ def test_hybrid_mixed_program_updates_pools_and_recurrent_lines_in_place(
         moe_experts_held=4, activation_function="relu2",
         engine={"enable_prefix_cache": False})
     text = lowered.compile().as_text()
-    assert text.count("tpu_custom_call") == 1
+    # the paged kernel once; the routed layer's two grouped matmuls (un-gated
+    # experts) are kernels too, under the `moe` scope
+    assert [scope_of(call) for call in custom_calls(text)].count("moe") == 2
+    assert len(custom_calls(text)) == 1 + 2
     aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
     pairs = {int(param): int(out) for out, param in
              re.findall(r"\{(\d+)\}: \((\d+), \{\}, \S+-alias\)", aliases)}
@@ -397,3 +417,65 @@ def test_a_one_token_row_reads_its_mamba_state_once_at_the_cells_size(one_chip):
     assert not whole_copies(text, state)
     assert not re.search(r"\[64,32,\d", entry)     # nothing is rows x places wide
     assert "f32[8,64,64,128]" in entry             # the gathered chunk rows
+
+
+ROUTED_CELLS = {
+    # serve-lfm2-24b-reason-burst: 64 SwiGLU experts all held, k = 4
+    "lfm2-64x2048x1536-k4": (dict(
+        io_features=2048, intermediate=1536, num_experts=64, top_k=4,
+        router="sigmoid_bias", norm_topk_eps=1e-6), 256),
+    # serve-nemotron3nano-reason-burst: 64 of 128 un-gated relu2 experts,
+    # k = 6, a shared expert; 1856 is no lane multiple
+    "nemotron-64of128x2688x1856-k6": (dict(
+        io_features=2688, intermediate=1856, num_experts=128, experts_held=64,
+        top_k=6, glu=False, router="sigmoid_bias", shared_expert_width=3712), 256),
+    # serve-olmoe-chat-burst: 64 SwiGLU experts, k = 8, a tick of 128 places
+    "olmoe-64x2048x1024-k8": (dict(
+        io_features=2048, intermediate=1024, num_experts=64, top_k=8,
+        norm_topk_prob=False), 128),
+}
+
+
+@pytest.mark.parametrize("full", [False, True], ids=["small", "full"])
+@pytest.mark.parametrize("cell", ROUTED_CELLS)
+def test_a_served_routed_layer_is_grouped_matmuls_under_the_moe_scope(
+        one_chip, monkeypatch, cell, full):
+    """A routed layer's ``serve`` at the three routed cells' shapes and both
+    token widths (ISSUE 50): Mosaic takes the tiles the shapes give; every
+    expert matrix meets a kernel whose instruction keeps ``/moe/`` in its
+    ``op_name`` (the benchmark's readers find the routed MLP's device time by
+    that scope; ``jax.lax.ragged_dot`` lowers to kernels the compiler renames
+    ``ragged-dot-none``, outside every scope); no ``(held, rows, 32, ..)``
+    capacity buffer is left; and no expert leaf is copied whole on its way
+    into a kernel (the chip keeps Nemotron's ``(64, 2688, 1856)`` with the
+    2688 along the lanes: ``ops/grouped_matmul.py`` reads its transpose)."""
+    from scaling_tpu.nn.moe import ParallelMoEMLP
+
+    monkeypatch.setattr(
+        "scaling_tpu.ops.grouped_matmul.grouped_matmul_interpret",
+        lambda platform=None: False)
+    kw, places = ROUTED_CELLS[cell]
+    places = 512 if full else places
+    layer = ParallelMoEMLP(
+        intermediate_feature_factor=1.0, dtype=jnp.bfloat16, **kw)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda x: shape(x.shape, x.dtype),
+                          jax.eval_shape(layer.init, jax.random.PRNGKey(0)))
+    rows = places // 32
+    text = jax.jit(layer.serve).lower(
+        params, shape((rows, 32, layer.io_features), jnp.bfloat16),
+        shape((rows, 32), jnp.bool_)).compile().as_text()
+    calls = custom_calls(text)
+    assert len(calls) == (3 if layer.glu else 2)
+    assert all(scope_of(call) == "moe" for call in calls), calls
+    assert "ragged-dot" not in text
+    entry = text[text.index("\nENTRY "):]
+    held = layer.experts_held
+    assert not re.search(rf"\[{held},{rows},32,\d", entry)
+    f, h = layer.intermediate, layer.io_features
+    for dims in (f"{held},{h},{f}", f"{held},{f},{h}"):
+        copies = whole_copies(text, rf"bf16\[{dims}\]")
+        assert not copies, f"{len(copies)} copies of a whole expert leaf"

@@ -170,3 +170,136 @@ def test_gates_are_renormalised_only_when_the_model_says_so(norm_topk_prob):
     np.testing.assert_allclose(np.asarray(y), np.asarray(ref), atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(
         np.asarray(layer.serve(params, x)[0]), np.asarray(ref), atol=1e-5, rtol=1e-5)
+
+
+def one_hot_serve(layer, params, x):
+    """``serve``'s reference: the one-hot form at room for the whole row."""
+    _, gate_vals, gate_idx = layer._route(params, x)
+    y = layer._experts(params, x, gate_vals, gate_idx, capacity=x.shape[1])
+    return layer._add_shared(params, x, y)
+
+
+GROUPED_CASES = {
+    "glu-k4": dict(num_experts=8, top_k=4),
+    "plain-k4": dict(num_experts=8, top_k=4, glu=False),
+    "k1": dict(num_experts=4, top_k=1),
+    "k8-of-8": dict(num_experts=8, top_k=8, norm_topk_prob=False),
+    "held-share": dict(num_experts=8, top_k=4, experts_first=2, experts_held=3,
+                       router="sigmoid_bias", glu=False, shared_expert_width=48),
+    "held-tail": dict(num_experts=8, top_k=2, experts_first=4),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", GROUPED_CASES)
+def test_grouped_serve_equals_the_one_hot_at_room_for_the_whole_row(case, dtype):
+    """``serve`` runs the experts over the assignments sorted by expert
+    (ISSUE 50); ``_experts`` at ``C = s`` is what it has to equal: gated or
+    not, every expert held or a share (the absent experts' gates dropped,
+    not renormalised), k from 1 to all."""
+    layer = make_layer(dtype=dtype, **GROUPED_CASES[case])
+    params = layer.init(jax.random.PRNGKey(0))
+    x = (jax.random.normal(jax.random.PRNGKey(1), (B, S, H)) * 0.5).astype(dtype)
+    assert layer.serve_rows(B * S) == ("grouped", B * S * layer.top_k)
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(
+        np.asarray(layer.serve(params, x)[0], np.float32),
+        np.asarray(one_hot_serve(layer, params, x), np.float32),
+        atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("case", ["glu-k4", "held-share"])
+def test_what_a_padded_position_holds_reaches_no_real_position(case):
+    """With ``real``: the real positions' outputs, the load and the absent
+    count are those of the one-hot form, and what lies at a padded position
+    (non-finite here; it is computed like any other, among the sorted rows
+    of the experts it names) reaches no real position's output."""
+    layer = make_layer(**GROUPED_CASES[case])
+    params = layer.init(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (B, S, H), jnp.float32) * 0.5
+    real = jnp.arange(S)[None, :] < jnp.asarray([5, 11])[:, None]
+    ref = one_hot_serve(layer, params, x)
+    _, ref_load = layer.serve(params, x, real)
+    poisoned = jnp.where(real[..., None], x, jnp.nan)
+    y, load = layer.serve(params, poisoned, real)
+    mask = np.asarray(real)
+    assert np.isfinite(np.asarray(y)[mask]).all()
+    np.testing.assert_allclose(
+        np.asarray(y)[mask], np.asarray(ref)[mask], atol=1e-5, rtol=1e-5)
+    np.testing.assert_array_equal(np.asarray(load), np.asarray(ref_load))
+    held = load if layer.holds_all else load[:-1]
+    absent = 0 if layer.holds_all else int(load[-1])
+    assert int(held.sum()) + absent == 16 * layer.top_k
+
+
+@pytest.mark.parametrize("crowd", ["one-expert", "an-idle-expert"])
+def test_grouped_serve_with_a_group_as_long_as_the_buffer_or_empty(crowd):
+    """Every position on ONE expert (its group is the whole buffer, every
+    other is empty), and an expert nobody chose between two that are."""
+    layer = make_layer(num_experts=4, top_k=1 if crowd == "one-expert" else 2)
+    params = layer.init(jax.random.PRNGKey(0))
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(2), (B, S, H))) + 0.1
+    weight = jnp.zeros((H, 4)).at[:, 0].set(1.0)
+    if crowd == "an-idle-expert":
+        weight = weight.at[:, 2].set(0.5).at[:, 1].set(-1.0).at[:, 3].set(-0.5)
+    params["router"]["weight"] = weight
+    y, load = layer.serve(params, x, jnp.ones((B, S), bool))
+    expect = [B * S, 0, 0, 0] if crowd == "one-expert" else [B * S, 0, B * S, 0]
+    assert np.asarray(load).tolist() == expect
+    np.testing.assert_allclose(
+        np.asarray(y), np.asarray(one_hot_serve(layer, params, x)),
+        atol=1e-5, rtol=1e-5)
+
+
+def test_sharded_expert_leaves_keep_the_one_hot_form():
+    """Where a mesh axis the leaves' partition names has more than one
+    device, GSPMD partitions the einsums and cannot partition the kernel:
+    ``serve`` keeps the one-hot form, and says so (``serve_rows``)."""
+    from jax.sharding import Mesh
+    from scaling_tpu.topology.topology import DATA_AXIS, MODEL_AXIS, PIPE_AXIS
+
+    layer = make_layer(num_experts=4, top_k=2)
+    devices = np.array(jax.devices()[:2])
+    whole = Mesh(devices.reshape(2, 1, 1), (PIPE_AXIS, DATA_AXIS, MODEL_AXIS))
+    split = Mesh(devices.reshape(1, 1, 2), (PIPE_AXIS, DATA_AXIS, MODEL_AXIS))
+    assert layer.serve_rows(64, whole) == ("grouped", 128)
+    assert layer.serve_rows(64, split) == ("dense", 4 * 64)
+    params = layer.init(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (B, S, H), jnp.float32) * 0.5
+    np.testing.assert_allclose(
+        np.asarray(layer.serve(params, x, mesh=split)[0]),
+        np.asarray(layer.serve(params, x)[0]), atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("transposed", [False, True], ids=["row-major", "transposed"])
+def test_the_grouped_kernel_under_the_interpreter_equals_ragged_dot(
+        transposed, monkeypatch):
+    """The chip's form (the Pallas kernel, here interpreted) against the CPU's
+    (``ragged_dot``) on rows that belong to a group; a matrix whose width is
+    no lane multiple is read through its transpose."""
+    from scaling_tpu.obs import kernel_build_count
+    from scaling_tpu.ops.grouped_matmul import grouped_matmul, grouped_tiles
+
+    m, k, n, groups = 64, 256, 192 if transposed else 256, 4
+    lhs = jax.random.normal(jax.random.PRNGKey(0), (m, k), jnp.float32)
+    rhs = jax.random.normal(jax.random.PRNGKey(1), (groups, k, n), jnp.float32)
+    sizes = jnp.asarray([9, 0, 30, 12], jnp.int32)
+    before = kernel_build_count("grouped_matmul", True)
+    got = grouped_matmul(lhs, rhs, sizes, interpret=True)
+    assert kernel_build_count("grouped_matmul", True) == before + 1
+    ref = grouped_matmul(lhs, rhs, sizes)
+    np.testing.assert_allclose(
+        np.asarray(got)[:51], np.asarray(ref)[:51], atol=1e-3, rtol=1e-4)
+    # cut into calls of 32 rows each (a buffer longer than VMEM keeps): the
+    # rows of a group that fall inside a call are its group there
+    monkeypatch.setattr(
+        "scaling_tpu.ops.grouped_matmul._LHS_VMEM_BYTES", 32 * k * 4)
+    cut = grouped_matmul(lhs, rhs, sizes, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(cut)[:51], np.asarray(ref)[:51], atol=1e-3, rtol=1e-4)
+    # the tiles are the shapes': a window holds twice the mean rows a group
+    # (32 to 128), the matrix is taken whole where two buffers of it fit
+    assert grouped_tiles(1024, 2048, 1536, 64) == (32, 1536)
+    assert grouped_tiles(4096, 2048, 1024, 64) == (128, 1024)
+    assert grouped_tiles(1024, 8192, 8192, 64)[1] % 128 == 0

@@ -314,6 +314,9 @@ def test_spans_counters_and_load_of_a_model_that_holds_every_expert(lfm2, tmp_pa
     for f in emits:   # all held: the load fields as OLMoE's, no absent_assign
         assert "absent_assign" not in f
         assert 0 <= f["experts_idle"] <= 8 and f["load_max"] >= f["load_mean"] >= 0
+    assert all(f["moe_rows"] == f["width"] * 3 * routed for f in mixed)
+    assert capture.counters["serve_moe_rows_total{path=grouped}"] == sum(
+        f["moe_rows"] for f in mixed)
 
 
 def test_the_operator_and_the_dense_ffn_lie_in_scopes_of_their_own(lfm2):

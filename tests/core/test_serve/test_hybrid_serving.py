@@ -239,6 +239,11 @@ def test_spans_counters_and_load_of_a_share(hybrid, tmp_path):
     assert absent == sum(f["absent_assign"] for f in emits)
     for f in emits:   # the load is over the four HELD experts
         assert 0 <= f["experts_idle"] <= 4 and f["load_max"] >= f["load_mean"] >= 0
+    # the experts' matmuls are given the width's assignments (held, absent
+    # and padding alike: the last two in the group no matmul visits)
+    assert all(f["moe_rows"] == f["width"] * 3 * routed for f in mixed)
+    assert capture.counters["serve_moe_rows_total{path=grouped}"] == sum(
+        f["moe_rows"] for f in mixed) >= held + absent
 
 
 def test_a_tick_with_more_chunk_rows_than_the_small_width_gathers_runs_whole(

@@ -76,6 +76,14 @@ def test_load_feeds_the_counter_and_the_emit_span(tmp_path):
                for f in emits)
     # one program, whose first result is ONE vector: the grid, then the load
     assert list(engine._mixed_fns) == list(engine.config.mixed_widths)
+    # the rows the experts' matmuls were given: the tick's width x top_k a
+    # layer (the grouped form: nn/moe.py), whatever the tick holds; with the
+    # assignments above, the share of them that was real work
+    mixed = [f for n, _, _, f in capture.spans if n == "serve.mixed"]
+    assert all(f["moe_rows"] == f["width"] * TOP_K * LAYERS for f in mixed)
+    assert capture.counters["serve_moe_rows_total{path=grouped}"] == sum(
+        f["moe_rows"] for f in mixed) >= moved
+    assert "serve_moe_rows_total{path=dense}" not in capture.counters
 
 
 def test_a_dense_model_pays_nothing(tmp_path):
@@ -89,6 +97,9 @@ def test_a_dense_model_pays_nothing(tmp_path):
     assert "serve_moe_assignments_total" not in capture.counters
     emits = [f for n, _, _, f in capture.spans if n == "serve.emit"]
     assert emits and not any("load_max" in f for f in emits)
+    assert not any(k.startswith("serve_moe_rows_total") for k in capture.counters)
+    assert not any("moe_rows" in f for n, _, _, f in capture.spans
+                   if n == "serve.mixed")
 
 
 def test_one_program_serves_a_routed_model_whatever_the_tick_holds():
