@@ -549,11 +549,6 @@ class TransformerArchitectureConfig(BaseConfig):
         return self.mlp_type == MLPType.MOE
 
     @property
-    def recurrent_layers(self) -> int:
-        """Layers that keep a recurrent state a sequence (Mamba-2 mixers)."""
-        return sum(k == LayerKind.MAMBA for k in self.layer_pattern or ())
-
-    @property
     def pattern_embedding_std(self) -> float:
         """The deviation a ``layer_pattern`` stack's embedding table starts
         at, which its residual branches are sized against
@@ -563,11 +558,6 @@ class TransformerArchitectureConfig(BaseConfig):
         if self.weight_tying:
             return (2.0 / (self.vocab_size + self.hidden_size)) ** 0.5
         return 1.0
-
-    @property
-    def conv_layers(self) -> int:
-        """Layers that keep a conv tail a sequence (gated short convolutions)."""
-        return sum(k == LayerKind.CONV for k in self.layer_pattern or ())
 
     @property
     def mup_width_mult(self) -> float:

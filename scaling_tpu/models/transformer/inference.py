@@ -39,11 +39,8 @@ from ...nn.moe import ParallelMoEMLP
 from ...parallel.parallel_module import ParallelModule
 
 
-# the layers of a trunk (they consume the serving state), and the views of
-# the serving engine's pools
+# the layers of a trunk (they consume the serving state)
 TRUNK_LAYERS = (TransformerLayer, MixerLayer)
-# the views of the serving engine's state: paged KV, Mamba-2 lines, conv tails
-PAGED_VIEWS = tuple(MixerLayer.STATE_VIEWS.values())
 
 
 class CompletionOutput(NamedTuple):
@@ -380,7 +377,8 @@ class TransformerInferenceModule:
         def call(layer):
             # a pattern stack's layers are one function a KIND of mixer; a
             # layer that keeps no state takes the real positions instead
-            key = (type(layer), id(layer.architecture), layer.kind)
+            key = (type(layer), id(layer.architecture),
+                   type(getattr(layer, "mixer", None)))
             if key not in shared and layer.consumes is None:
                 shared[key] = jax.jit(
                     lambda p, x, real: layer(p, x, ctx, real=real)
@@ -461,7 +459,10 @@ class TransformerInferenceModule:
                 )
             last_tl = max(tls)
         paged_layer_call = self._paged_layer_calls(ctx)
-        paged = bool(caches) and isinstance(caches[0], PAGED_VIEWS)
+        # the views of the serving engine's state this stack's layers declare
+        views = tuple({l.consumes for l in self.module.layers
+                       if getattr(l, "consumes", None)})
+        paged = bool(caches) and isinstance(caches[0], views)
         real = None
         if paged and self.architecture.layer_pattern is not None:
             # which positions hold a token: the routed layers keep no state
@@ -475,9 +476,8 @@ class TransformerInferenceModule:
         for i, layer in enumerate(self.module.layers):
             p = self.module._layer_params(params, i)
             if isinstance(layer, TRUNK_LAYERS):
-                # a layer is handed the state of ITS kind: a TransformerLayer
-                # and an attention mixer a KV cache, a Mamba-2 mixer its
-                # recurrent lines, a short convolution its tail, an MLP
+                # a layer is handed the state of ITS kind, the view its
+                # mixer declares (a TransformerLayer: attention's); an MLP
                 # (routed or dense) nothing
                 consumes = layer.consumes
                 if consumes is None and real is not None:
@@ -487,18 +487,18 @@ class TransformerInferenceModule:
                 else:
                     if li >= len(caches):
                         raise ValueError(
-                            f"layer {i} consumes a {consumes!r} cache but only "
+                            f"layer {i} consumes a {consumes.__name__} but only "
                             f"{len(caches)} were provided")
                     cache = caches[li]
+                    served = isinstance(cache, views)
                     # a dense (k, v) pair is an attention layer's too
-                    if ((consumes != "kv" or isinstance(cache, PAGED_VIEWS))
-                            and not isinstance(
-                                cache, MixerLayer.STATE_VIEWS[consumes])):
+                    if not isinstance(cache, consumes) and (
+                            served or consumes is not PagedKVCacheView):
                         raise ValueError(
-                            f"layer {i} consumes a {consumes!r} state and was "
+                            f"layer {i} consumes a {consumes.__name__} and was "
                             f"handed a {type(cache).__name__}: the caches are "
                             "one a consuming layer, in layer order")
-                    if isinstance(cache, PAGED_VIEWS):
+                    if served:
                         x, kv = paged_layer_call(layer)(p, x, cache)
                     else:
                         x, kv = layer(p, x, ctx, kv_cache=cache, cache_offset=offset)

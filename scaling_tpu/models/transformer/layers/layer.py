@@ -31,8 +31,8 @@ from ....nn import (
 )
 from ....nn.attention import PagedKVCacheView
 from ....nn.rotary import RotaryConfig
-from ....nn.mamba import Mamba2Mixer, RecurrentStateView
-from ....nn.short_conv import ConvTailView, GatedShortConv
+from ....nn.mamba import Mamba2Mixer
+from ....nn.short_conv import GatedShortConv
 from ..config import (
     AdapterConfig,
     KeyQueryNormScope,
@@ -127,23 +127,13 @@ class MixerLayer(BaseLayer):
     """A layer of a ``layer_pattern`` stack: ONE norm, ONE mixer of the
     layer's kind, the residual: ``x <- x + Mixer(Norm(x))`` (Nemotron-H's
     block; LFM2's block, an operator then an FFN each behind its own norm, is
-    two of them). ``consumes`` names the serving state the mixer keeps:
-    ``'kv'`` (attention: a paged KV cache line), ``'ssm'`` (Mamba-2: a
-    recurrent-state line a slot), ``'conv'`` (gated short convolution: a
-    conv-tail line a slot), or None (the routed and the dense MLP)."""
-
-    CONSUMES = {LayerKind.ATTENTION: "kv", LayerKind.MAMBA: "ssm",
-                LayerKind.CONV: "conv", LayerKind.MOE: None, LayerKind.MLP: None}
-    # the view of the engine's state pool each kind of state is handed
-    STATE_VIEWS = {"kv": PagedKVCacheView, "ssm": RecurrentStateView,
-                   "conv": ConvTailView}
+    two of them)."""
 
     def __init__(self, architecture: TransformerArchitectureConfig, layer_index: int = 0):
         arch = architecture
         self.architecture = arch
         self.layer_index = layer_index
         self.kind = arch.layer_pattern[layer_index]
-        self.consumes = self.CONSUMES[self.kind]
         dtype = arch.dtype
         self.norm = get_norm(arch.norm_type, arch.hidden_size, arch.layernorm, dtype)
         if self.kind == LayerKind.MAMBA:
@@ -190,6 +180,14 @@ class MixerLayer(BaseLayer):
                 num_kv_heads=arch.attention_num_kv_heads,
                 head_dim=arch.attention_head_dim,
             )
+
+    @property
+    def consumes(self):
+        """The view of the serving state this layer's mixer keeps, as the
+        mixer declares it (``STATE_VIEW``): attention's paged KV cache line,
+        or a view of lines a slot (Mamba-2's, a short convolution's); None
+        for the routed and the dense MLP."""
+        return getattr(self.mixer, "STATE_VIEW", None)
 
     # a routed expert's output projection starts this much below a plain
     # branch's (init, below)
@@ -260,19 +258,18 @@ class MixerLayer(BaseLayer):
     def __call__(self, params: dict, x: dict, ctx: ForwardContext,
                  kv_cache=None, cache_offset=None, return_kv: bool = False,
                  real=None):
-        """``kv_cache``: the serving state of this layer's kind (a
-        ``PagedKVCacheView`` or dense ``(k, v)`` for attention, a
-        ``RecurrentStateView`` for Mamba-2, a ``ConvTailView`` for a short
-        convolution); with it or ``return_kv`` the result is ``(out, new
-        state)``: attention's K/V or updated view, Mamba-2's lines, the
-        convolution's tail. ``real`` ((b, s) bool): the positions that hold a
+        """``kv_cache``: the serving state of this layer's kind, the view its
+        mixer declares (``consumes``; attention's may be a dense ``(k, v)``
+        too); with it or ``return_kv`` the result is ``(out, new state)``:
+        attention's K/V or updated view, a per-slot kind's final lines or
+        updated view. ``real`` ((b, s) bool): the positions that hold a
         token, for the routed MLP's load count when serving."""
         h = x["activations"]
         normed = self.norm(params["norm"], h, ctx)
         out = dict(x)
         state = None
-        if self.kind in (LayerKind.MAMBA, LayerKind.CONV):
-            view = self.STATE_VIEWS[self.consumes]
+        view = self.consumes
+        if hasattr(view, "LINES"):  # a mixer that keeps lines a slot
             if kv_cache is not None and not isinstance(kv_cache, view):
                 raise ValueError(
                     f"a {self.kind.value} layer takes a {view.__name__} (the "
@@ -305,16 +302,15 @@ class MixerLayer(BaseLayer):
             if return_kv or kv_cache is not None:
                 y, state = y
         out["activations"] = h + y.astype(h.dtype)
-        if self.consumes and (return_kv or kv_cache is not None):
+        if view is not None and (return_kv or kv_cache is not None):
             return out, state
         return out
 
 
 class TransformerLayer(BaseLayer):
     # what a walk of the stack reads off a trunk layer (MixerLayer's differ a
-    # layer): the serving state it keeps, and no single mixer's kind
-    consumes = "kv"
-    kind = None
+    # layer): the serving state it keeps, attention's
+    consumes = ParallelSelfAttention.STATE_VIEW
 
     def __init__(self, architecture: TransformerArchitectureConfig, layer_index: int = 0):
         arch = architecture

@@ -299,6 +299,9 @@ def multi_head_attention(
 
 
 class ParallelSelfAttention(BaseLayer):
+    # the view of the serving state a layer with this mixer is handed
+    STATE_VIEW = PagedKVCacheView
+
     def __init__(
         self,
         hidden_size: int,

@@ -88,6 +88,11 @@ class RecurrentStateView(NamedTuple):
     ``PagedKVCacheView`` for a layer whose state does not grow with the
     context. Row ``r`` of the tick is slot ``r``'s line."""
 
+    # the fields the pool owns, a list of each over the layers (the rest is the
+    # tick's addressing), and the name this kind's spans and counters carry
+    LINES = ("ssm", "conv")
+    NAME = "ssm"
+
     ssm: jax.Array          # (slots, heads, head_dim, N) float32
     conv: jax.Array         # (slots, inner + 2 G N, K - 1) last conv inputs
     context_len: jax.Array  # (slots,) int32 tokens the state has seen
@@ -158,6 +163,9 @@ def causal_conv(window, weight, bias):
 
 
 class Mamba2Mixer(BaseLayer):
+    # the view of the serving state a layer with this mixer is handed
+    STATE_VIEW = RecurrentStateView
+
     def __init__(self, hidden_size: int, num_heads: int, head_dim: int,
                  state_size: int, n_groups: int, conv_kernel: int,
                  norm_eps: float = 1e-5, time_step_min: float = 0.001,

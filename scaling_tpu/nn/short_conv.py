@@ -51,6 +51,10 @@ class ConvTailView(NamedTuple):
     (serve/kvcache.py), plus the tick's addressing: what ``RecurrentStateView``
     is to a Mamba-2 layer. Row ``r`` of the tick is slot ``r``'s line."""
 
+    # the field the pool owns and the name in spans and counters, as there
+    LINES = ("tail",)
+    NAME = "conv"
+
     tail: jax.Array         # (slots, K - 1, H) the last values of u = B * X
     context_len: jax.Array  # (slots,) int32 tokens the line has seen
     new_len: jax.Array      # (slots,) int32 real tokens the row brings
@@ -66,6 +70,8 @@ def row_major_map(rows: int, width: int) -> PagedTokenMap:
 
 
 class GatedShortConv(BaseLayer):
+    STATE_VIEW = ConvTailView
+
     def __init__(self, hidden_size: int, kernel: int, dtype=None):
         self.hidden_size = hidden_size
         self.kernel = kernel

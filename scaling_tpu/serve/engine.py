@@ -60,13 +60,14 @@ from typing import Dict, List, NamedTuple, Optional, Tuple
 
 from .. import obs
 from ..logging import logger
-from ..nn.mamba import split_capacity
+from ..nn.mamba import RecurrentStateView, split_capacity
 from ..nn.paged_attention import kernel_tile_tokens
 from ..resilience.faults import get_fault_plan
 from .kvcache import (
     PagedKVPools,
     build_layer_views,
     init_pools,
+    line_layers,
     serving_mesh,
     state_from_views,
 )
@@ -290,31 +291,29 @@ class ServeEngine:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             self._replicated = NamedSharding(self.mesh, P())
-        # a model with recurrent layers (layer_pattern with Mamba-2 mixers):
-        # a state that is advanced, never indexed by position, so what
-        # skips or rewinds positions is refused, not approximated
-        arch = inference_module.architecture
-        self.ssm_lines = arch.recurrent_layers
-        # likewise the conv tails of gated short convolutions (LFM2)
-        self.conv_lines = arch.conv_layers
-        for lines, what, state in (
-                (self.ssm_lines, "recurrent (Mamba-2)", "recurrent state"),
-                (self.conv_lines, "short-convolution (conv)", "conv tail")):
-            if lines and self.config.enable_prefix_cache:
-                raise ValueError(
-                    f"enable_prefix_cache with {what} layers: a prefix "
-                    f"hit starts a row past tokens its {state} never saw "
-                    "(only the KV of a shared prefix is kept, no state "
-                    "snapshot); set enable_prefix_cache=False")
-            if lines and self.config.spec_k > 0:
-                raise ValueError(
-                    f"spec_k > 0 with {what} layers: a rejected draft "
-                    f"has already advanced the {state} and there is no "
-                    "rollback; set spec_k=0")
         self.pools: PagedKVPools = init_pools(
             inference_module, self.config.num_blocks, self.config.block_size,
             kv_dtype=self.config.kv_dtype, num_slots=self.config.num_slots,
         )
+        # the layers that keep a line a slot, by the name their kind's spans
+        # and counters carry: a state that is advanced, never indexed by
+        # position, so what skips or rewinds positions is refused, not
+        # approximated
+        self.line_layers = {kind.NAME: layers for kind, layers
+                            in line_layers(self.pools.kinds).items()}
+        kept = f"layers that keep a line a slot ({self.line_layers})"
+        if self.line_layers and self.config.enable_prefix_cache:
+            raise ValueError(
+                f"enable_prefix_cache with {kept}: a prefix hit starts a row "
+                "past tokens its lines never saw (only the KV of a shared "
+                "prefix is kept, no snapshot of the lines); set "
+                "enable_prefix_cache=False")
+        if self.line_layers and self.config.spec_k > 0:
+            raise ValueError(
+                f"spec_k > 0 with {kept}: a rejected draft has already "
+                "advanced the lines and there is no rollback; set spec_k=0")
+        # Mamba-2's lines advance in a form of their own (nn/mamba.py)
+        self.ssm_lines = self.line_layers.get(RecurrentStateView.NAME, 0)
         import numpy as np
 
         self._np = np
@@ -356,6 +355,7 @@ class ServeEngine:
         # in the tick's one host read, how many assignments of real
         # positions each expert received (0: a dense model, which pays
         # nothing for it)
+        arch = inference_module.architecture
         routed = arch.has_routed_layers
         # the experts this program HOLDS; a share of them also counts the
         # assignments that fell on absent experts (one more entry)
@@ -897,16 +897,13 @@ class ServeEngine:
                     "serve_sampler_ticks_total",
                     path="sampled" if sampled_rows else "greedy",
                 ).inc()
-                # rows whose per-slot lines advanced, in every Mamba-2 layer
-                # (ssm) and in every short convolution (conv)
+                # rows whose per-slot lines advanced, in every layer of a kind
                 rows = int(np.count_nonzero(new_lens))
-                for kind, lines in (("ssm", self.ssm_lines),
-                                    ("conv", self.conv_lines)):
-                    if lines:
-                        mixed_span.annotate(**{f"{kind}_rows": rows,
-                                               f"{kind}_lines": lines})
-                        self._counter(
-                            f"serve_{kind}_state_updates_total").inc(rows * lines)
+                for kind, lines in self.line_layers.items():
+                    mixed_span.annotate(**{f"{kind}_rows": rows,
+                                           f"{kind}_lines": lines})
+                    self._counter(
+                        f"serve_{kind}_state_updates_total").inc(rows * lines)
                 if self.ssm_lines:
                     # the form that advanced them (nn/mamba.py): at the full
                     # width whole rows, below it a step or a gathered chunk

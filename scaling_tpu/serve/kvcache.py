@@ -34,35 +34,41 @@ pool then holds ``loop_steps x num_blocks`` blocks, step ``u``'s at
 the same 16 tokens in every line, at ``u * num_blocks + b`` of every
 layer's pool. Block 0 of every step's share is trash.
 
-**Recurrent state** (a ``layer_pattern`` stack with Mamba-2 layers,
-docs/SERVING.md "Hybrid models"): a second kind of state beside the paged
-pools. A Mamba-2 layer keeps, for every SLOT, one fixed-size line: ``ssm
-(num_slots, heads, head_dim, N)`` float32 and ``conv (num_slots, channels,
-K - 1)``, whatever the sequence's length; nothing is paged, the scheduler
-counts no block for it. KV pools exist for the attention layers only. The
-state the engine's program takes and returns is then ``(pool_k, pool_v,
-scale_k, scale_v, state_ssm, state_conv)``: ONE structure, so that the
-recurrent lines are donated and aliased like the pools.
+**Two rules, any number of kinds** (a ``layer_pattern`` stack,
+docs/SERVING.md "Hybrid models"). What a layer keeps while it is served is
+said in ONE place: its mixer names a view class (``STATE_VIEW``), and
+``layer.consumes`` is that class. This file reads it there and knows two
+rules, no kind:
 
-**Conv tails** (a ``layer_pattern`` stack with gated short convolutions,
-LFM2's): a third kind of state, a line of its own kind: ``tail (num_slots,
-K - 1, hidden)`` a layer, each channel's last ``K - 1`` filter inputs. A
-model with such layers puts ONE more list, ``state_tail``, at the end of
-that structure (after the Mamba-2 lists if it has those too), donated and
-aliased like the rest. Which lists a state carries follows from ``kinds``.
+- **paged**: ``PagedKVCacheView``, everything above. KV pools exist for the
+  layers that declare it only.
+- **a line a slot**: a view that declares ``LINES``, the names of the fields
+  the pool owns (the view's other fields, ``context_len``, ``new_len``,
+  ``token_map``, are the tick's addressing). Such a layer keeps, for every
+  SLOT, one fixed-size line a field whatever the sequence's length: the
+  probe's final state of the layer, one leaf a field in ``LINES``' order,
+  with its leading dimension set to ``num_slots``. Nothing is paged, the
+  scheduler counts no block for it. (Mamba-2's ``ssm`` and ``conv``,
+  nn/mamba.py; a gated short convolution's ``tail``, nn/short_conv.py.)
+
+The state the engine's program takes and returns is ONE structure, so that
+the lines are donated and aliased like the pools: ``(pool_k, pool_v, scale_k,
+scale_v)``, then for every per-slot kind, in the order the stack first meets
+them (``line_layers``), one list a field of its ``LINES``, each over that
+kind's layers in layer order. ``kinds``, the view class of every consuming
+layer in layer order, says which lists a state carries.
 """
 
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+from collections import Counter
+from typing import Iterable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..nn.attention import PagedKVCacheView, PagedTokenMap
-from ..nn.mamba import RecurrentStateView
-from ..nn.short_conv import ConvTailView
 
 
 def serving_mesh(inference_module):
@@ -75,13 +81,21 @@ def serving_mesh(inference_module):
     return topo.mesh
 
 
+def line_layers(kinds: Optional[Iterable[type]]) -> Counter:
+    """``{view class: layers}`` of the kinds among ``kinds`` that keep a line
+    a slot (their view declares ``LINES``), in the order the stack first meets
+    them: the order of their lists in the state, after the four of the
+    pools."""
+    return Counter(kind for kind in kinds or () if hasattr(kind, "LINES"))
+
+
 def build_layer_views(
     state: Tuple,                    # (pool_k, pool_v, scale_k, scale_v)
     block_table: jax.Array,          # (rows, max_blocks) int32
     context_len: jax.Array,          # (rows,) int32
     new_len: Optional[jax.Array] = None,  # (rows,) int32 real new tokens
     token_map: Optional[PagedTokenMap] = None,  # a token-major batch's
-    kinds: Optional[List[str]] = None,  # 'kv' | 'ssm' | 'conv' a consuming layer
+    kinds: Optional[List[type]] = None,  # the view class a consuming layer
 ) -> List:
     """Per-layer :class:`PagedKVCacheView` s over the raw pool state —
     the shape the engine's jitted programs thread through ``_run_layers``
@@ -98,10 +112,9 @@ def build_layer_views(
     the rows' tokens packed token-major instead of one row a batch row.
 
     A state that carries per-slot lines (more than four entries) comes with
-    ``kinds``, the kind of state each consuming layer takes in layer order:
-    the views are then one a consuming layer, a ``RecurrentStateView`` over
-    the slots' lines for ``'ssm'``, a ``ConvTailView`` for ``'conv'``, and lie
-    in that order."""
+    ``kinds``, the view class each consuming layer declares, in layer order:
+    the views are then one a consuming layer, of its class (a per-slot kind's
+    over the slots' lines of that layer), and lie in that order."""
     pool_k, pool_v, scale_k, scale_v = state[:4]
     kv_views = [
         PagedKVCacheView(
@@ -115,19 +128,14 @@ def build_layer_views(
     ]
     if len(state) == 4:
         return kv_views
-    lines = list(state[4:])
-    by_kind = {"kv": iter(kv_views)}
-    if "ssm" in kinds:
-        by_kind["ssm"] = iter([
-            RecurrentStateView(ssm=ssm, conv=conv, context_len=context_len,
-                               new_len=new_len, token_map=token_map)
-            for ssm, conv in zip(lines.pop(0), lines.pop(0))
-        ])
-    if "conv" in kinds:
-        by_kind["conv"] = iter([
-            ConvTailView(tail=tail, context_len=context_len, new_len=new_len,
-                         token_map=token_map)
-            for tail in lines.pop(0)
+    lists = iter(state[4:])
+    by_kind = {PagedKVCacheView: iter(kv_views)}
+    for kind in line_layers(kinds):
+        fields = [next(lists) for _ in kind.LINES]
+        by_kind[kind] = iter([
+            kind(**dict(zip(kind.LINES, lines)), context_len=context_len,
+                 new_len=new_len, token_map=token_map)
+            for lines in zip(*fields)
         ])
     return [next(by_kind[kind]) for kind in kinds]
 
@@ -148,23 +156,20 @@ def state_from_views(views: List[PagedKVCacheView]) -> Tuple:
     output ``v0``, written before layer 1 has read it, and XLA copies
     every pool but the first on every call. Their table, lengths,
     ``new_len`` and token map are the program's inputs (or derived from
-    them) and do not come back. Recurrent views among them put the two lists
-    of their lines after the four of the pools, conv-tail views their one
-    list after those."""
-    recurrent = [v for v in views if isinstance(v, RecurrentStateView)]
-    tails = [v for v in views if isinstance(v, ConvTailView)]
-    views = [v for v in views if isinstance(v, PagedKVCacheView)]
-    quantized = views[0].scale_k is not None
+    them) and do not come back. Per-slot views among them put the lists of
+    their lines after the four of the pools, grouped by the views' own class
+    (``line_layers``)."""
+    paged = [v for v in views if isinstance(v, PagedKVCacheView)]
+    quantized = paged[0].scale_k is not None
     state = (
-        [v.pool_k for v in views],
-        [v.pool_v for v in views],
-        [v.scale_k for v in views] if quantized else None,
-        [v.scale_v for v in views] if quantized else None,
+        [v.pool_k for v in paged],
+        [v.pool_v for v in paged],
+        [v.scale_k for v in paged] if quantized else None,
+        [v.scale_v for v in paged] if quantized else None,
     )
-    if recurrent:
-        state += ([v.ssm for v in recurrent], [v.conv for v in recurrent])
-    if tails:
-        state += ([v.tail for v in tails],)
+    for kind in line_layers(type(v) for v in views):
+        state += tuple([getattr(v, field) for v in views if type(v) is kind]
+                       for field in kind.LINES)
     return state
 
 
@@ -181,10 +186,8 @@ class PagedKVPools:
                  scale_k: Optional[List[jax.Array]],
                  scale_v: Optional[List[jax.Array]],
                  block_size: int, loop_steps: int = 1,
-                 state_ssm: Optional[List[jax.Array]] = None,
-                 state_conv: Optional[List[jax.Array]] = None,
-                 kinds: Optional[List[str]] = None,
-                 state_tail: Optional[List[jax.Array]] = None):
+                 kinds: Optional[List[type]] = None,
+                 lines: Tuple[List[jax.Array], ...] = ()):
         self.pool_k = pool_k
         self.pool_v = pool_v
         self.scale_k = scale_k
@@ -192,14 +195,11 @@ class PagedKVPools:
         self.block_size = block_size
         # cache lines a layer's pool holds: a looped model's steps
         self.loop_steps = loop_steps
-        # the recurrent lines, one (ssm, conv) pair a Mamba-2 layer (None: a
-        # model without such layers), and the kind of state each consuming
-        # layer takes, in layer order
-        self.state_ssm = state_ssm
-        self.state_conv = state_conv
+        # the view class each consuming layer declares, in layer order (None:
+        # a stack of paged layers alone), and the per-slot kinds' lists in
+        # state order: what the state carries after the four of the pools
         self.kinds = kinds
-        # the conv tails, one a gated short convolution (None: no such layer)
-        self.state_tail = state_tail
+        self.lines = tuple(lines)
 
     @property
     def num_layers(self) -> int:
@@ -227,26 +227,18 @@ class PagedKVPools:
 
     @property
     def state_lines(self) -> int:
-        """Layers that keep a line a slot: a recurrent state or a conv tail."""
-        return len(self.state_ssm or ()) + len(self.state_tail or ())
+        """Layers that keep a line a slot."""
+        return sum(line_layers(self.kinds).values())
 
     def state(self) -> Tuple:
         """What the jitted programs take (donated) and return."""
-        state = (self.pool_k, self.pool_v, self.scale_k, self.scale_v)
-        if self.state_ssm is not None:
-            state += (self.state_ssm, self.state_conv)
-        if self.state_tail is not None:
-            state += (self.state_tail,)
-        return state
+        return (self.pool_k, self.pool_v, self.scale_k, self.scale_v,
+                *self.lines)
 
     def absorb_state(self, state: Tuple) -> None:
         """Take back the updated state a jitted program returned."""
         self.pool_k, self.pool_v, self.scale_k, self.scale_v = state[:4]
-        lines = list(state[4:])
-        if self.state_ssm is not None:
-            self.state_ssm, self.state_conv = lines.pop(0), lines.pop(0)
-        if self.state_tail is not None:
-            self.state_tail = lines.pop(0)
+        self.lines = tuple(state[4:])
 
     def device_bytes(self) -> int:
         total = 0
@@ -260,17 +252,16 @@ class PagedKVPools:
     def state_bytes(self) -> int:
         """Bytes of the per-slot lines (beside ``device_bytes``)."""
         return sum(a.size * a.dtype.itemsize
-                   for arrs in (self.state_ssm, self.state_conv, self.state_tail)
-                   for a in arrs or ())
+                   for arrs in self.lines for a in arrs)
 
 
 def init_pools(inference_module, num_blocks: int, block_size: int,
                kv_dtype: str = "native", num_slots: int = 0) -> PagedKVPools:
     """Allocate zeroed pools shaped by probing the real layer stack.
 
-    A stack with Mamba-2 layers or gated short convolutions also gets their
-    lines, ``num_slots`` of each, shaped by the same probe (the final state of
-    a one-token pass).
+    A stack with layers that keep a line a slot also gets their lines,
+    ``num_slots`` of each, shaped by the same probe (the final state of a
+    one-token pass).
 
     ``kv_dtype``: ``'native'`` keeps the probe's KV dtype (the model's
     compute dtype); ``'int8'`` stores int8 values + float32 scales.
@@ -289,16 +280,14 @@ def init_pools(inference_module, num_blocks: int, block_size: int,
         return inference_module.prefill_forward(p, t, po)[1]
 
     kv_shapes = jax.eval_shape(probe, params, probe_tokens, probe_pos)
-    # a pattern stack's probe holds a line of its kind a consuming layer, in
-    # layer order: (k, v) of an attention layer, (ssm, conv) of a Mamba-2 one,
-    # the tail of a gated short convolution
+    # a pattern stack's probe holds the final state a consuming layer, in
+    # layer order: (k, v) of a paged layer, of a per-slot one its lines
     kinds = [layer.consumes for layer in inference_module.module.layers
              if getattr(layer, "consumes", None)]
-    by_kind = {kind: [l for l, k in zip(kv_shapes, kinds) if k == kind]
-               for kind in ("kv", "ssm", "conv")}
-    state_shapes, tail_shapes = by_kind["ssm"], by_kind["conv"]
-    if state_shapes or tail_shapes:
-        kv_shapes = by_kind["kv"]
+    per_slot = line_layers(kinds)
+    if per_slot:
+        finals = list(zip(kinds, kv_shapes))
+        kv_shapes = [f for kind, f in finals if kind not in per_slot]
     if not kv_shapes:
         raise ValueError(
             "the layer stack keeps no KV cache line: the paged engine serves "
@@ -376,24 +365,20 @@ def init_pools(inference_module, num_blocks: int, block_size: int,
             scale_v.append(
                 placed((pool_blocks, block_size, n_kv), jnp.float32, 2)
             )
-    state_ssm = state_conv = state_tail = None
-    if state_shapes or tail_shapes:
-        if mesh is not None:
-            raise ValueError("recurrent state is not sharded: serve a "
-                             "layer_pattern stack at model_parallel_size 1")
-        if num_slots <= 0:
-            raise ValueError("a stack with recurrent layers needs num_slots: "
-                             "it keeps one state line a slot")
-
-        def lines(shapes):
-            return [placed((num_slots, *a.shape[1:]), a.dtype, 1) for a in shapes]
-
-        if state_shapes:
-            state_ssm = lines(ssm for ssm, _ in state_shapes)
-            state_conv = lines(conv for _, conv in state_shapes)
-        if tail_shapes:
-            state_tail = lines(tail_shapes)
+    if not per_slot:
+        return PagedKVPools(pool_k, pool_v, scale_k, scale_v, block_size,
+                            loop_steps)
+    if mesh is not None:
+        raise ValueError("per-slot state lines are not sharded: serve a "
+                         "layer_pattern stack at model_parallel_size 1")
+    if num_slots <= 0:
+        raise ValueError("a stack with layers that keep a line a slot needs "
+                         "num_slots")
+    lines = []
+    for kind in per_slot:
+        # a layer's final state: one leaf a field of LINES, in that order
+        layers = [jax.tree_util.tree_leaves(f) for k, f in finals if k is kind]
+        lines += [[placed((num_slots, *a.shape[1:]), a.dtype, 1) for a in field]
+                  for field in zip(*layers)]
     return PagedKVPools(pool_k, pool_v, scale_k, scale_v, block_size,
-                        loop_steps, state_ssm, state_conv,
-                        kinds if state_shapes or tail_shapes else None,
-                        state_tail)
+                        loop_steps, kinds, lines)

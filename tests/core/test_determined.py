@@ -127,4 +127,8 @@ def test_glue_drives_training_preemption(monkeypatch, tmp_path):
     uploaded_dir, meta = core.uploaded[0]
     assert meta == {"steps_completed": 3}
     assert uploaded_dir.is_dir() and list(uploaded_dir.iterdir())
-    assert [s for s, _ in core.reported] == [1, 2]  # metrics up to the stop
+    # step 3 ran to its end and is the step the preemption checkpoint holds:
+    # its metrics reach the cluster before the exit (the trainer's contract,
+    # _run_training_loop: "the step that just completed is about to be saved
+    # ... its metrics must reach the sinks too")
+    assert [s for s, _ in core.reported] == [1, 2, 3]

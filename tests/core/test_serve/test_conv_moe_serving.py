@@ -18,7 +18,9 @@ from scaling_tpu import obs
 from scaling_tpu.models.transformer import TransformerConfig
 from scaling_tpu.models.transformer.inference import TransformerInferenceModule
 from scaling_tpu.models.transformer.model import init_model
+from scaling_tpu.nn.attention import PagedKVCacheView
 from scaling_tpu.nn.moe import ParallelMoEMLP
+from scaling_tpu.nn.short_conv import ConvTailView
 from scaling_tpu.serve.engine import EngineConfig, ServeEngine
 
 VOCAB = 96
@@ -118,16 +120,20 @@ def undisturbed(lfm2, reference):
 def test_the_state_pool_is_one_tail_per_slot_and_conv_layer(lfm2):
     engine = engine_of(lfm2)
     pools, stats = engine.pools, engine.stats_snapshot()
-    assert pools.kinds == ["conv", "conv", "kv", "conv"]   # consuming layers, in order
+    # the view each consuming layer's mixer declares, in layer order
+    assert pools.kinds == [ConvTailView] * 2 + [PagedKVCacheView, ConvTailView]
     assert pools.kv_lines == stats["kv_lines"] == 1        # KV for the attention layer only
-    assert pools.state_lines == stats["state_lines"] == CONV_LAYERS == engine.conv_lines
-    assert engine.ssm_lines == 0 and pools.state_ssm is None and pools.state_conv is None
-    assert [a.shape for a in pools.state_tail] == [(4, 2, 48)] * CONV_LAYERS
+    assert pools.state_lines == stats["state_lines"] == CONV_LAYERS
+    assert engine.line_layers == {"conv": CONV_LAYERS} and engine.ssm_lines == 0
+    # ONE sequence, in state order: the one list of the view's one field
+    assert ConvTailView.LINES == ("tail",)
+    (tails,) = pools.lines
+    assert [(a.shape, a.dtype) for a in tails] == [((4, 2, 48), jnp.float32)] * CONV_LAYERS
     assert stats["state_pool_bytes"] == pools.state_bytes() == CONV_LAYERS * 4 * 2 * 48 * 4
     assert pools.pool_k[0].shape == (64, 4, 2, 16)
     # ONE donated structure: the four of the pools, then the tails' list
     state = engine._pool_state()
-    assert len(state) == 5 and state[4] is pools.state_tail
+    assert len(state) == 5 and state[4] is tails
 
 
 def test_the_program_is_the_reference_at_every_position(lfm2, reference):
@@ -163,7 +169,7 @@ def test_a_reused_slot_does_not_inherit_its_old_occupants_tail(lfm2, undisturbed
     engine = engine_of(lfm2, num_slots=1)
     got = served(engine, requests, 10)
     assert [got[i] for i in range(len(requests))] == want
-    assert float(jnp.abs(engine.pools.state_tail[0]).max()) > 0
+    assert float(jnp.abs(engine.pools.lines[0][0]).max()) > 0
 
 
 def test_a_preempted_and_resumed_sequence_reproduces_its_tokens(lfm2, undisturbed):
@@ -194,8 +200,9 @@ def test_a_tail_that_is_never_written_serves_other_tokens(lfm2, undisturbed, mon
 
 @pytest.mark.parametrize("config,message", [
     ({"enable_prefix_cache": True},
-     "short-convolution \\(conv\\) layers: a prefix hit .* conv tail never saw"),
-    ({"spec_k": 2}, "short-convolution \\(conv\\) layers: a rejected draft has already"),
+     "keep a line a slot \\({'conv': 3}\\): a prefix hit .* lines never saw"),
+    ({"spec_k": 2},
+     "keep a line a slot \\({'conv': 3}\\): a rejected draft has already advanced"),
 ])
 def test_what_would_skip_or_rewind_the_tail_is_refused_by_name(lfm2, config, message):
     with pytest.raises(ValueError, match=message):
@@ -232,9 +239,9 @@ def test_training_and_cached_generate_are_refused_by_name(lfm2):
     engine = engine_of(lfm2)
     views = build_layer_views(
         engine._pool_state(), jnp.zeros((4, 12), jnp.int32), jnp.zeros((4,), jnp.int32),
-        jnp.ones((4,), jnp.int32), kinds=["kv", "conv", "conv", "conv"])
+        jnp.ones((4,), jnp.int32), kinds=[PagedKVCacheView] + [ConvTailView] * 3)
     batch = lfm2._make_batch(jnp.ones((4, 8), jnp.int32), jnp.zeros((4, 8), jnp.int32))
-    with pytest.raises(ValueError, match="consumes a 'conv' state and was handed"):
+    with pytest.raises(ValueError, match="consumes a ConvTailView and was handed a Paged"):
         lfm2._run_layers(lfm2.params, batch, views, None, paged_kernel="xla")
     ctx = lfm2._make_ctx()
     embedded = lfm2.module.layers[0](lfm2.params["layer_0"], batch, ctx)
@@ -328,37 +335,3 @@ def test_the_operator_and_the_dense_ffn_lie_in_scopes_of_their_own(lfm2):
         lfm2.params).as_text(debug_info=True)
     for scope in ("conv", "mlp", "moe"):
         assert re.search(rf'/{scope}/', hlo), scope
-
-
-@pytest.mark.parametrize("bucket", [0, 1], ids=["small", "full"])
-def test_donated_state_aliases_the_output_computed_from_it(lfm2, bucket):
-    """The alias pin of tests/core/test_serve/test_kvcache.py for the third
-    kind of state: lowered with donation forced, every donated leaf (1 K and 1
-    V pool, 3 conv tails) aliases the output at its own place in the returned
-    state."""
-    engine = engine_of(lfm2, num_slots=16, prefill_chunk=32, num_blocks=16 * 12 + 1)
-    assert engine.config.mixed_widths == (128, 512)
-    width = engine.config.mixed_widths[bucket]
-    packed, tick = engine._layout.host(width)
-    tick.new_lens[:] = 1
-    args = (lfm2.params, engine._pool_state(), engine._dev(packed), engine._base_key)
-    fn = engine._build_mixed_fn(width).__wrapped__
-    sampled, state = jax.eval_shape(fn, *args)
-    structure = jax.tree_util.tree_structure
-    assert structure(state) == structure(engine._pool_state())
-    for got, held in zip(jax.tree_util.tree_leaves(state),
-                         jax.tree_util.tree_leaves(engine._pool_state())):
-        assert (got.shape, got.dtype) == (held.shape, held.dtype)
-    # the grid, then the 8 experts' load
-    assert sampled.shape == (16 * engine.config.sample_width + 8,)
-    lowered = jax.jit(fn, donate_argnums=(1,), keep_unused=True).lower(*args)
-    signature = lowered.as_text().split("@main(", 1)[1].split(") -> ", 1)[0]
-    aliases = {}
-    for arg in signature.split("%arg")[1:]:
-        m = re.search(r"tf\.aliasing_output = (\d+)", arg)
-        if m:
-            aliases[int(arg.split(":", 1)[0])] = int(m.group(1))
-    first = len(jax.tree_util.tree_leaves(args[0]))
-    donated = jax.tree_util.tree_leaves(args[1])
-    assert len(donated) == 2 + CONV_LAYERS
-    assert aliases == {first + j: 1 + j for j in range(len(donated))}

@@ -4,8 +4,6 @@ beside the paged KV pools, donated and aliased like them; a slot reused, a
 sequence preempted and recomputed; what is refused, by name; the spans' new
 fields, the counters and the stats."""
 
-import re
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,6 +13,8 @@ from scaling_tpu import obs
 from scaling_tpu.models.transformer import TransformerConfig
 from scaling_tpu.models.transformer.inference import TransformerInferenceModule
 from scaling_tpu.models.transformer.model import init_model
+from scaling_tpu.nn.attention import PagedKVCacheView
+from scaling_tpu.nn.mamba import RecurrentStateView
 from scaling_tpu.serve.engine import EngineConfig, ServeEngine
 
 VOCAB = 96
@@ -98,16 +98,23 @@ def undisturbed(hybrid):
 def test_the_state_pool_is_one_line_per_slot_and_mamba_layer(hybrid):
     engine = engine_of(hybrid)
     pools, stats = engine.pools, engine.stats_snapshot()
-    assert pools.kinds == ["ssm", "ssm", "kv", "ssm"]      # consuming layers, in order
+    # the view each consuming layer's mixer declares, in layer order
+    assert pools.kinds == [RecurrentStateView] * 2 + [PagedKVCacheView, RecurrentStateView]
     assert pools.kv_lines == stats["kv_lines"] == 1        # KV for the * layer only
     assert pools.state_lines == stats["state_lines"] == M_LAYERS == engine.ssm_lines
-    assert [a.shape for a in pools.state_ssm] == [(4, 4, 8, 16)] * M_LAYERS
-    assert [a.shape for a in pools.state_conv] == [(4, 4 * 8 + 2 * 2 * 16, 3)] * M_LAYERS
-    assert all(a.dtype == jnp.float32 for a in pools.state_ssm)
+    assert engine.line_layers == {"ssm": M_LAYERS}
+    # ONE sequence, in state order: a list a field of the view's LINES
+    ssm, conv = pools.lines
+    assert RecurrentStateView.LINES == ("ssm", "conv")
+    assert [a.shape for a in ssm] == [(4, 4, 8, 16)] * M_LAYERS
+    assert [a.shape for a in conv] == [(4, 4 * 8 + 2 * 2 * 16, 3)] * M_LAYERS
+    assert all(a.dtype == jnp.float32 for a in ssm + conv)
     assert stats["state_pool_bytes"] == pools.state_bytes() == M_LAYERS * 4 * (
         4 * 8 * 16 * 4 + 96 * 3 * 4)
     assert pools.pool_k[0].shape == (64, 4, 2, 16)
-    assert len(engine._pool_state()) == 6
+    # ONE donated structure: the four of the pools, then the lines' lists
+    state = engine._pool_state()
+    assert len(state) == 6 and state[4] is ssm and state[5] is conv
     # a model without recurrent layers keeps the four-entry state
     from scaling_tpu.serve.bench import build_toy_inference
 
@@ -133,7 +140,7 @@ def test_a_reused_slot_does_not_inherit_its_old_occupants_state(hybrid, undistur
     engine = engine_of(hybrid, num_slots=1)
     got = served(engine, requests, 10)
     assert [got[i] for i in range(len(requests))] == want
-    assert float(jnp.abs(engine.pools.state_ssm[0]).max()) > 0
+    assert float(jnp.abs(engine.pools.lines[0][0]).max()) > 0
 
 
 def test_a_preempted_and_resumed_sequence_reproduces_its_tokens(hybrid, undisturbed):
@@ -149,8 +156,10 @@ def test_a_preempted_and_resumed_sequence_reproduces_its_tokens(hybrid, undistur
 
 
 @pytest.mark.parametrize("config,message", [
-    ({"enable_prefix_cache": True}, "prefix hit .* recurrent state never saw"),
-    ({"spec_k": 2}, "rejected draft has already advanced"),
+    ({"enable_prefix_cache": True},
+     "keep a line a slot \\({'ssm': 3}\\): a prefix hit .* lines never saw"),
+    ({"spec_k": 2},
+     "keep a line a slot \\({'ssm': 3}\\): a rejected draft has already advanced"),
 ])
 def test_what_would_skip_or_rewind_the_state_is_refused_by_name(hybrid, config, message):
     with pytest.raises(ValueError, match=message):
@@ -191,9 +200,9 @@ def test_training_and_cached_generate_are_refused_by_name(hybrid):
     engine = engine_of(hybrid)
     views = build_layer_views(
         engine._pool_state(), jnp.zeros((4, 12), jnp.int32), jnp.zeros((4,), jnp.int32),
-        jnp.ones((4,), jnp.int32), kinds=["kv", "ssm", "ssm", "ssm"])
+        jnp.ones((4,), jnp.int32), kinds=[PagedKVCacheView] + [RecurrentStateView] * 3)
     batch = hybrid._make_batch(jnp.ones((4, 8), jnp.int32), jnp.zeros((4, 8), jnp.int32))
-    with pytest.raises(ValueError, match="consumes a 'ssm' state and was handed"):
+    with pytest.raises(ValueError, match="consumes a RecurrentStateView and was handed a Paged"):
         hybrid._run_layers(hybrid.params, batch, views, None, paged_kernel="xla")
     with pytest.raises(ValueError, match="consumed 4 KV cache"):
         hybrid._run_layers(hybrid.params, batch, build_layer_views(
@@ -312,38 +321,3 @@ def test_a_plain_models_spans_are_what_they_were(tmp_path):
     fields = set().union(*(f for n, _, _, f in capture.spans if n == "serve.mixed"))
     assert not fields & {"ssm_rows", "ssm_lines"}
     assert "serve_ssm_state_updates_total" not in capture.counters
-
-
-@pytest.mark.parametrize("bucket", [0, 1], ids=["small", "full"])
-def test_donated_state_aliases_the_output_computed_from_it(hybrid, bucket):
-    """The alias pin of tests/core/test_serve/test_kvcache.py for the second
-    kind of state: lowered with donation forced, every donated leaf (1 K and 1
-    V pool, 3 ssm and 3 conv lines) aliases the output at its own place in the
-    returned state; a copy of the recurrent lines a tick would be 0.96 GB at
-    the cell's size."""
-    engine = engine_of(hybrid, num_slots=16, prefill_chunk=32, num_blocks=16 * 12 + 1)
-    assert engine.config.mixed_widths == (128, 512)
-    width = engine.config.mixed_widths[bucket]
-    packed, tick = engine._layout.host(width)
-    tick.new_lens[:] = 1
-    args = (hybrid.params, engine._pool_state(), engine._dev(packed), engine._base_key)
-    fn = engine._build_mixed_fn(width).__wrapped__
-    sampled, state = jax.eval_shape(fn, *args)
-    structure = jax.tree_util.tree_structure
-    assert structure(state) == structure(engine._pool_state())
-    for got, held in zip(jax.tree_util.tree_leaves(state),
-                         jax.tree_util.tree_leaves(engine._pool_state())):
-        assert (got.shape, got.dtype) == (held.shape, held.dtype)
-    # the grid, the 4 held experts' load, the absent count
-    assert sampled.shape == (16 * engine.config.sample_width + 4 + 1,)
-    lowered = jax.jit(fn, donate_argnums=(1,), keep_unused=True).lower(*args)
-    signature = lowered.as_text().split("@main(", 1)[1].split(") -> ", 1)[0]
-    aliases = {}
-    for arg in signature.split("%arg")[1:]:
-        m = re.search(r"tf\.aliasing_output = (\d+)", arg)
-        if m:
-            aliases[int(arg.split(":", 1)[0])] = int(m.group(1))
-    first = len(jax.tree_util.tree_leaves(args[0]))
-    donated = jax.tree_util.tree_leaves(args[1])
-    assert len(donated) == 2 + 2 * M_LAYERS
-    assert aliases == {first + j: 1 + j for j in range(len(donated))}

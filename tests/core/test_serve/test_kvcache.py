@@ -1,10 +1,13 @@
 """Paged KV pool mechanics (ISSUE 9): flat-slot addressing, chunk
 scatter + block gather round-trips, int8 quantization accuracy on
 hand-built pools; then the donated pool state through the engine's
-program, dense, routed, sharded over two devices, looped, and a pattern stack
-whose state carries recurrent lines beside the pools."""
+program, dense, routed, sharded over two devices, looped, and pattern stacks
+whose state carries lines a slot beside the pools (Mamba-2's, a short
+convolution's, and a kind this file defines: what a kind declares beside its
+mixer is all the pool reads)."""
 
 import re
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -13,11 +16,20 @@ import pytest
 
 from scaling_tpu.nn.attention import (
     PagedKVCacheView,
+    PagedTokenMap,
     kv_dequantize_int8,
     kv_quantize_int8,
     paged_flat_slots,
 )
-from scaling_tpu.nn.base_layer import ForwardContext
+from scaling_tpu.nn.base_layer import BaseLayer, ForwardContext
+from scaling_tpu.nn.mamba import RecurrentStateView
+from scaling_tpu.nn.short_conv import ConvTailView
+from scaling_tpu.serve.kvcache import (
+    build_layer_views,
+    init_pools,
+    line_layers,
+    state_from_views,
+)
 
 
 def test_paged_flat_slots_maps_through_block_table():
@@ -132,6 +144,10 @@ SLOTS, MAX_BLOCKS, CHUNK = 8, 8, 32
 WIDTHS = (128, 256)
 LOOP_STEPS = 4
 MODELS = ["dense", "routed", "mp2", "looped", "hybrid"]
+# the stacks of test_hybrid_serving.py and test_conv_moe_serving.py, in an
+# engine of 16 slots whose buckets are 128 and 512 places
+WIDE = {"num_slots": 16, "max_blocks_per_seq": 12, "num_blocks": 16 * 12 + 1}
+WIDE_WIDTHS = (128, 512)
 
 
 @pytest.fixture(scope="module")
@@ -144,13 +160,21 @@ def inference_modules(toy_inference):
     output is the grid flattened + the exit distribution's four numbers);
     a pattern stack (three layers: Mamba-2, attention, routed with 2 of 4
     experts held: ONE KV pool, one recurrent line a slot, and its first
-    output the grid flattened + the held load + the absent count)."""
+    output the grid flattened + the held load + the absent count); and the
+    two serving tests' own stacks, Mamba-2 ``MEM*EM`` and LFM2's blocks."""
     from scaling_tpu.models.transformer import TransformerConfig
     from scaling_tpu.models.transformer.inference import (
         TransformerInferenceModule,
     )
     from scaling_tpu.models.transformer.model import init_model
     from scaling_tpu.serve.bench import build_toy_inference
+    from tests.core.test_serve.test_conv_moe_serving import lfm2_config
+    from tests.core.test_serve.test_hybrid_serving import hybrid_config
+
+    def seeded(config):
+        module = init_model(config, None)
+        return TransformerInferenceModule(
+            config, module, module.init_params(jax.random.PRNGKey(0)))
 
     routed = TransformerConfig.from_dict({
         "topology": {"model_parallel_size": 1, "pipe_parallel_size": 1,
@@ -189,6 +213,8 @@ def inference_modules(toy_inference):
             "relative_position_embedding_type": "none", "mlp_bias": False}})
     hybrid_module = init_model(hybrid, None)
     return {
+        "mamba2": seeded(hybrid_config()),
+        "lfm2": seeded(lfm2_config()),
         "hybrid": TransformerInferenceModule(
             hybrid, hybrid_module,
             hybrid_module.init_params(jax.random.PRNGKey(0))),
@@ -203,20 +229,20 @@ def inference_modules(toy_inference):
     }
 
 
-def _program_and_args(inf, kv_dtype, spec_k, width=WIDTHS[0]):
+def _program_and_args(inf, kv_dtype, spec_k, width=WIDTHS[0], **config):
     """The engine's program at one of its token widths, as the plain
     function under its ``jax.jit``, with toy arguments in its signature."""
     from scaling_tpu.serve.engine import EngineConfig, ServeEngine
 
-    engine = ServeEngine(inf, EngineConfig(
-        num_slots=SLOTS, block_size=4, num_blocks=2 * MAX_BLOCKS + 1,
-        max_blocks_per_seq=MAX_BLOCKS, token_budget=64, prefill_chunk=CHUNK,
-        kv_dtype=kv_dtype, spec_k=spec_k,
-        # refused for a stack with recurrent layers (a hit would skip
-        # tokens the state never saw)
-        enable_prefix_cache=not inf.architecture.recurrent_layers,
-    ))
-    assert engine.config.mixed_widths == WIDTHS
+    engine = ServeEngine(inf, EngineConfig(**{
+        "num_slots": SLOTS, "block_size": 4, "num_blocks": 2 * MAX_BLOCKS + 1,
+        "max_blocks_per_seq": MAX_BLOCKS, "token_budget": 64,
+        "prefill_chunk": CHUNK, "kv_dtype": kv_dtype, "spec_k": spec_k,
+        # refused for a stack that keeps a line a slot (a hit would skip
+        # tokens the lines never saw)
+        "enable_prefix_cache": inf.architecture.layer_pattern is None,
+        **config}))
+    assert width in engine.config.mixed_widths
 
     packed, tick = engine._layout.host(width)
     tick.new_lens[:] = 1
@@ -246,16 +272,31 @@ def _aliases(lowered):
     return aliases
 
 
-@pytest.mark.parametrize("width", WIDTHS, ids=["small", "full"])
-@pytest.mark.parametrize("kv_dtype", ["native", "int8"])
-@pytest.mark.parametrize(
-    "model,spec_k",
-    [("dense", 0), ("dense", 2), ("routed", 0), ("mp2", 0), ("looped", 0),
-     ("hybrid", 0)],
-    ids=["mixed", "mixed-spec2", "routed", "mp2", "looped", "hybrid"],
-)
-def test_donated_pool_aliases_the_output_computed_from_it(
-        inference_modules, model, spec_k, kv_dtype, width):
+# (stack, spec_k, kv_dtype, KV layers, lists of lines, what follows the grid
+# in the first output): every stack whose state is donated
+ALIAS_CASES = [
+    pytest.param(model, spec_k, kv_dtype, kv_layers, lines, None,
+                 id=f"{name}-{kv_dtype}")
+    for name, model, spec_k, kv_layers, lines in [
+        ("mixed", "dense", 0, 3, 0), ("mixed-spec2", "dense", 2, 3, 0),
+        ("routed", "routed", 0, 3, 0), ("mp2", "mp2", 0, 3, 0),
+        ("looped", "looped", 0, 3, 0),
+        ("hybrid", "hybrid", 0, 1, 2)]  # 1 ssm + 1 conv line
+    for kv_dtype in ("native", "int8")
+] + [
+    # 3 ssm + 3 conv lines; the 4 held experts' load and the absent count
+    pytest.param("mamba2", 0, "native", 1, 6, 4 + 1, id="mamba2-wide"),
+    # 3 conv tails; the 8 experts' load
+    pytest.param("lfm2", 0, "native", 1, 3, 8, id="lfm2-wide"),
+]
+
+
+@pytest.mark.parametrize("bucket", [0, 1], ids=["small", "full"])
+@pytest.mark.parametrize("model,spec_k,kv_dtype,kv_layers,lines,tail",
+                         ALIAS_CASES)
+def test_donated_state_aliases_the_output_computed_from_it(
+        inference_modules, model, spec_k, kv_dtype, kv_layers, lines, tail,
+        bucket):
     """JAX pairs a donated buffer with an output of its shape and dtype
     in flattened order, so ``pool_v[3]`` is updated in place only if the
     program's lowered ``main`` says its argument aliases the output leaf
@@ -274,18 +315,28 @@ def test_donated_pool_aliases_the_output_computed_from_it(
     pattern stack's state carries, after the pools of its ONE attention
     layer, the ssm and conv lines of its Mamba-2 layer: donated and aliased
     like them (a copy of the cell's 0.96 GB of lines would cost ~2.3 ms a
-    tick)."""
-    _, fn, args = _program_and_args(
-        inference_modules[model], kv_dtype, spec_k, width)
+    tick); the two serving tests' stacks (three Mamba-2 layers' six lists of
+    lines, three short convolutions' tails) in their wider engine too, each
+    also held to the structure, shapes and dtypes it was handed."""
+    wide = tail is not None
+    engine, fn, args = _program_and_args(
+        inference_modules[model], kv_dtype, spec_k,
+        (WIDE_WIDTHS if wide else WIDTHS)[bucket], **(WIDE if wide else {}))
+    if wide:
+        sampled, state = jax.eval_shape(fn, *args)
+        assert (jax.tree_util.tree_structure(state)
+                == jax.tree_util.tree_structure(args[1]))
+        for got, held in zip(jax.tree_util.tree_leaves(state),
+                             jax.tree_util.tree_leaves(args[1])):
+            assert (got.shape, got.dtype) == (held.shape, held.dtype)
+        assert sampled.shape == (16 * engine.config.sample_width + tail,)
     lowered = jax.jit(fn, donate_argnums=(1,), keep_unused=True).lower(
         *args
     )
     first = len(jax.tree_util.tree_leaves(args[0]))  # params come first
     donated = jax.tree_util.tree_leaves(args[1])
-    if model == "hybrid":  # 1 K + 1 V pool (+ 2 scales), 1 ssm + 1 conv line
-        assert len(donated) == (6 if kv_dtype == "int8" else 4)
-    else:
-        assert len(donated) == (12 if kv_dtype == "int8" else 6)
+    # a K and a V pool (+ 2 scales) an attention layer, then the lines
+    assert len(donated) == kv_layers * (4 if kv_dtype == "int8" else 2) + lines
     # outputs flatten as (tokens, *state): state leaf j is output 1 + j
     want = {first + j: 1 + j for j in range(len(donated))}
     assert _aliases(lowered) == want
@@ -325,13 +376,146 @@ def test_programs_return_the_state_in_pool_state_structure(
     assert state[0][0].shape[0] == steps * (2 * MAX_BLOCKS + 1)
 
 
+# --- what a kind declares beside its mixer is all the pool reads (ISSUE 51) ---
+
+def _addressing(slots, max_blocks=2):
+    """A tick's addressing for ``slots`` rows: every row brings one token."""
+    table = 1 + jnp.arange(slots * max_blocks, dtype=jnp.int32).reshape(
+        slots, max_blocks)
+    return table, jnp.zeros((slots,), jnp.int32), jnp.ones((slots,), jnp.int32)
+
+
+@pytest.mark.parametrize("model,kinds", [
+    ("dense", None),
+    ("mamba2", [RecurrentStateView] * 2 + [PagedKVCacheView, RecurrentStateView]),
+    ("lfm2", [ConvTailView] * 2 + [PagedKVCacheView, ConvTailView]),
+], ids=["dense", "mamba2", "conv-tail"])
+def test_views_built_from_a_state_give_that_state_back(inference_modules, model,
+                                                       kinds):
+    """``state_from_views`` undoes ``build_layer_views`` for every kind that
+    exists: the same structure, the same buffers at the same places."""
+    pools = init_pools(inference_modules[model], 5, 4, num_slots=2)
+    assert pools.kinds == kinds
+    state = pools.state()
+    views = build_layer_views(state, *_addressing(2), kinds=pools.kinds)
+    assert [type(v) for v in views] == (kinds or [PagedKVCacheView] * 3)
+    back = state_from_views(views)
+    assert (jax.tree_util.tree_structure(back)
+            == jax.tree_util.tree_structure(state))
+    assert all(a is b for a, b in zip(jax.tree_util.tree_leaves(back),
+                                      jax.tree_util.tree_leaves(state)))
+
+
+class RunningSumView(NamedTuple):
+    """A fourth kind of per-slot state, known to this file alone: the sum of
+    the mixer's inputs over the tokens a slot has seen."""
+
+    LINES = ("total",)
+    NAME = "sum"
+
+    total: jax.Array        # (slots, hidden) float32
+    context_len: jax.Array
+    new_len: jax.Array
+    token_map: Optional[PagedTokenMap] = None
+
+
+class RunningSum(BaseLayer):
+    """``y_t = sum of x up to t``: a stub mixer that names its view, called as
+    the per-slot mixers are (``state``, ``return_state``)."""
+
+    STATE_VIEW = RunningSumView
+
+    def __call__(self, params, x, ctx, state=None, return_state=False):
+        if state is None:  # uncached: the whole sequence from zeros
+            y = jnp.cumsum(x, axis=1)
+            return (y, y[:, -1].astype(jnp.float32)) if return_state else y
+        # served, a row-major batch: row r is slot r, its first new_len
+        # positions real; a row at context 0 starts from zeros
+        real = jnp.arange(x.shape[1])[None, :] < state.new_len[:, None]
+        start = jnp.where(state.context_len[:, None] == 0, 0.0, state.total)
+        y = start[:, None] + jnp.cumsum(x * real[..., None], axis=1)
+        last = jnp.take_along_axis(
+            y, jnp.maximum(state.new_len - 1, 0)[:, None, None], axis=1)[:, 0]
+        total = jnp.where(state.new_len[:, None] > 0, last, state.total)
+        return y, state._replace(total=total.astype(state.total.dtype))
+
+
+def test_a_kind_defined_here_goes_through_the_pool_and_the_walk():
+    """A stack of all four kinds: Mamba-2, a short convolution, attention, and
+    in place of the second convolution's mixer the stub above. Its line is
+    allocated by the rule (the probe's final state, a line a slot), lies in
+    the state where the stack first meets its kind, is handed to its layer as
+    its own view, comes back updated in the structure it entered in, and is
+    counted; ``serve/kvcache.py`` and the walk name no kind."""
+    from scaling_tpu.models.transformer.inference import (
+        TransformerInferenceModule,
+    )
+    from scaling_tpu.models.transformer.model import init_model
+    from tests.core.test_serve.test_hybrid_serving import hybrid_config
+
+    config = hybrid_config(
+        num_layers=4, layer_pattern=["mamba", "conv", "attention", "conv"])
+    module = init_model(config, None)
+    inf = TransformerInferenceModule(
+        config, module, module.init_params(jax.random.PRNGKey(0)))
+    stub = [l for l in module.layers if getattr(l, "consumes", None)][-1]
+    stub.mixer = RunningSum()
+    assert stub.consumes is RunningSumView
+
+    slots, hidden = 3, 48
+    pools = init_pools(inf, 7, 4, num_slots=slots)
+    assert pools.kinds == [RecurrentStateView, ConvTailView, PagedKVCacheView,
+                           RunningSumView]
+    assert line_layers(pools.kinds) == {
+        RecurrentStateView: 1, ConvTailView: 1, RunningSumView: 1}
+    assert pools.state_lines == 3 and pools.kv_lines == 1
+    # the four of the pools, then a list a field in the order the stack meets
+    # the kinds: ssm, conv; tail; total
+    state = pools.state()
+    assert [len(part) for part in state[4:]] == [1, 1, 1, 1]
+    ssm, conv, tail, total = (part[0] for part in state[4:])
+    assert (total.shape, total.dtype) == ((slots, hidden), jnp.float32)
+    assert tail.shape == (slots, 3, hidden)
+    assert pools.state_bytes() == sum(
+        a.size * a.dtype.itemsize for a in (ssm, conv, tail, total))
+
+    table, ctx_len, new_len = _addressing(slots)
+    new_len = new_len.at[2].set(0)  # the last slot is empty
+    views = build_layer_views(state, table, ctx_len, new_len, kinds=pools.kinds)
+    assert [type(v) for v in views] == pools.kinds
+    assert views[3].total is total and views[3].new_len is new_len
+    back = state_from_views(views)
+    assert (jax.tree_util.tree_structure(back)
+            == jax.tree_util.tree_structure(state))
+    assert all(a is b for a, b in zip(jax.tree_util.tree_leaves(back),
+                                      jax.tree_util.tree_leaves(state)))
+
+    # one tick of the walk over those views: every layer is handed its own
+    batch = inf._make_batch(jnp.ones((slots, 2), jnp.int32),
+                            jnp.zeros((slots, 2), jnp.int32))
+    _, new_views = inf._run_layers(inf.params, batch, views, None,
+                                   paged_kernel="xla")
+    assert [type(v) for v in new_views] == pools.kinds
+    new_state = state_from_views(new_views)
+    assert (jax.tree_util.tree_structure(new_state)
+            == jax.tree_util.tree_structure(state))
+    pools.absorb_state(new_state)
+    assert jax.tree_util.tree_structure(pools.state()) == (
+        jax.tree_util.tree_structure(state))
+    advanced = np.asarray(pools.lines[-1][0])
+    assert np.abs(advanced[:2]).min() > 0 and not advanced[2].any()
+    # a view handed to a layer of another kind is refused by both names
+    with pytest.raises(ValueError, match="consumes a RunningSumView and was "
+                                         "handed a ConvTailView"):
+        inf._run_layers(inf.params, batch, views[:3] + views[1:2], None,
+                        paged_kernel="xla")
+
+
 def test_run_layers_on_paged_views_defaults_to_the_kernel(toy_inference):
     """``_run_layers`` given block-paged views and NO ``paged_kernel``
     traces the Pallas call: the back-end's default is written once, on
     ``ForwardContext``, and a caller that names none cannot get the
     gather (which stays reachable by name, as the tests' reference)."""
-    from scaling_tpu.serve.kvcache import build_layer_views
-
     engine, _, args = _program_and_args(toy_inference, "native", 0)
     params, state, packed = args[:3]
     tick = engine._layout.split(packed)
