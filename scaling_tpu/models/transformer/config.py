@@ -183,6 +183,42 @@ class MupConfig(BaseConfig):
     )
 
 
+class FixedMultipliers(BaseConfig):
+    """Constants a published configuration multiplies its activations by
+    (Falcon-H1's ``*_multiplier(s)`` keys): numbers of the model like an
+    epsilon, not ``MupConfig``, which derives scales from a base width and a
+    learning-rate rule. All ones: the plain model, and nothing is lowered for
+    them. A seeded init starts each matrix a multiplier FOLLOWS at its usual
+    scale divided by that multiplier (the owner of the multiplier does it),
+    so that fresh weights are the map they would be without multipliers."""
+
+    embedding: float = Field(1.0, description="on the embedded tokens", gt=0)
+    lm_head: float = Field(1.0, description="on the logits", gt=0)
+    attention_in: float = Field(
+        1.0, description="on the normed input of attention's projections", gt=0)
+    attention_out: float = Field(
+        1.0, description="on attention's output (after its projection)", gt=0)
+    key: float = Field(
+        1.0, description="on the keys, before rotary and the cache", gt=0)
+    ssm_in: float = Field(
+        1.0, description="on the normed input of a Mamba-2 mixer's in_proj",
+        gt=0)
+    ssm_out: float = Field(
+        1.0, description="on a Mamba-2 mixer's output (after out_proj)", gt=0)
+    ssm: List[float] = Field(
+        [1.0] * 5, description="on in_proj's output by segment: z, x, B, C, dt",
+        min_length=5, max_length=5)
+    mlp_gate: float = Field(
+        1.0, description="on a SwiGLU MLP's gate projection, inside the "
+        "activation", gt=0)
+    mlp_down: float = Field(
+        1.0, description="on a SwiGLU MLP's output (after down_proj)", gt=0)
+
+    @property
+    def plain(self) -> bool:
+        return self == FixedMultipliers()
+
+
 class TransformerArchitectureConfig(BaseConfig):
     """Model shape + feature switches
     (reference: src/scaling/transformer/context/config.py:126-330)."""
@@ -309,6 +345,20 @@ class TransformerArchitectureConfig(BaseConfig):
     time_step_min: float = Field(0.001, description="dt init range", gt=0)
     time_step_max: float = Field(0.1, description="dt init range", gt=0)
     time_step_floor: float = Field(1e-4, description="dt init floor", gt=0)
+    parallel_ssm: bool = Field(
+        False,
+        description="every layer of the homogeneous stack runs a Mamba-2 "
+        "mixer (mamba_num_heads x mamba_head_dim, ssm_state_size, n_groups, "
+        "conv_kernel) BESIDE its attention, both on the one normed input, "
+        "summed into one residual (Falcon-H1's block): x <- x + SSM(N(x)) + "
+        "Attn(N(x)), then the MLP sub-block. Served only: a layer then keeps "
+        "a paged KV line and a recurrent line a slot",
+    )
+    multipliers: FixedMultipliers = Field(
+        FixedMultipliers(),
+        description="published constants on the activations (see "
+        "FixedMultipliers); all ones by default",
+    )
     activation_function: ActivationFunction = Field(ActivationFunction.GELU, description="")
     precision: Precision = Field(Precision.FLOAT32, description="compute/param dtype")
     layernorm: LayerNormConfig = Field(LayerNormConfig(), description="")
@@ -435,13 +485,35 @@ class TransformerArchitectureConfig(BaseConfig):
                 f"experts [{self.moe_experts_first}, {self.moe_experts_first} + "
                 f"{held}) do not lie in moe_num_experts {self.moe_num_experts}"
             )
-        if self.attention_head_dim is not None and self.layer_pattern is None:
+        if (self.attention_head_dim is not None and self.layer_pattern is None
+                and not self.parallel_ssm):
             raise ValueError(
-                "attention_head_dim without layer_pattern: the homogeneous "
-                "TransformerLayer sizes its heads hidden_size / "
-                "num_attention_heads (rotary, muP); a head size of its own is "
-                "built for the single-mixer stack only"
+                "attention_head_dim without layer_pattern or parallel_ssm: "
+                "the homogeneous TransformerLayer sizes its heads hidden_size "
+                "/ num_attention_heads (rotary, muP); a head size of its own "
+                "is built for the single-mixer stack and the parallel block"
             )
+        if self.parallel_ssm:
+            self._validate_parallel_ssm()
+        if not self.multipliers.plain:
+            for name, what in (
+                    ("layer_pattern", "the single-mixer layer applies none"),
+                    ("mup", "MupConfig scales the logits and the attention "
+                     "scores itself")):
+                if getattr(self, name) is not None:
+                    raise ValueError(
+                        f"multipliers with {name}: {what}; not supported")
+            if self.mlp_type != MLPType.SWIGLU and (
+                    self.multipliers.mlp_gate != 1.0
+                    or self.multipliers.mlp_down != 1.0):
+                raise ValueError(
+                    "multipliers.mlp_gate / mlp_down are a SwiGLU MLP's; "
+                    f"mlp_type is {self.mlp_type.value!r}")
+            if self.weight_tying and (self.multipliers.embedding != 1.0
+                                      or self.multipliers.lm_head != 1.0):
+                raise ValueError(
+                    "multipliers.embedding / lm_head with weight_tying: the "
+                    "tied head applies none; not supported")
         if self.attention_head_dim is not None and self.lora_config is not None:
             raise ValueError(
                 "attention_head_dim with lora_config: the LoRA modules are "
@@ -486,6 +558,40 @@ class TransformerArchitectureConfig(BaseConfig):
                 "once; untie the head to use mup"
             )
         return self
+
+    def _validate_parallel_ssm(self):
+        """What the parallel block does not build, each by name."""
+        if self.layer_pattern is not None:
+            raise ValueError(
+                "parallel_ssm with layer_pattern: a pattern's layers have ONE "
+                "mixer each; the parallel block is the homogeneous stack's")
+        if self.loop_steps > 1:
+            raise ValueError(
+                "parallel_ssm with loop_steps > 1: a looped trunk keeps one "
+                "cache line a (step, layer) and no recurrent line; not "
+                "supported")
+        if self.mamba_num_heads % self.n_groups:
+            raise ValueError(
+                f"mamba_num_heads {self.mamba_num_heads} is not a multiple "
+                f"of n_groups {self.n_groups}")
+        for name in ("adapter_config", "lora_config", "bitfit_bias_config",
+                     "mup", "softprompt_config"):
+            if getattr(self, name) is not None:
+                raise ValueError(
+                    f"parallel_ssm with {name}: the parallel block builds "
+                    "none of the fine-tuning modules and is not trained")
+        if self.sandwich_norm:
+            raise ValueError(
+                "parallel_ssm with sandwich_norm: the two mixers' sum has no "
+                "published output norm; not supported")
+        if self.mlp_type == MLPType.MOE:
+            raise ValueError(
+                "parallel_ssm with mlp_type 'moe': the parallel block's MLP "
+                "is dense (mlp_type 'swiglu' or 'default')")
+        if self.num_local_attention_heads:
+            raise ValueError(
+                "parallel_ssm with num_local_attention_heads: windowed heads "
+                "beside a recurrent state are not built")
 
     def _validate_pattern(self):
         """What a ``layer_pattern`` stack does not build, each by name."""
@@ -691,13 +797,17 @@ class TransformerConfig(BaseConfig):
 
     @model_validator(mode="after")
     def _validate_layout(self):
-        if self.transformer_architecture.layer_pattern is not None:
+        arch = self.transformer_architecture
+        for name, given, why in (
+                ("layer_pattern", arch.layer_pattern is not None,
+                 "layers of unequal kind are"),
+                ("parallel_ssm", arch.parallel_ssm,
+                 "a block's Mamba-2 mixer and its recurrent lines are")):
             for axis in ("pipe_parallel_size", "model_parallel_size"):
-                if getattr(self.topology, axis) > 1:
+                if given and getattr(self.topology, axis) > 1:
                     raise ValueError(
-                        f"layer_pattern with {axis} "
-                        f"{getattr(self.topology, axis)}: layers of unequal "
-                        "kind are neither stage-stacked nor tensor-parallel "
+                        f"{name} with {axis} {getattr(self.topology, axis)}: "
+                        f"{why} neither stage-stacked nor tensor-parallel "
                         "yet; use 1"
                     )
         return self

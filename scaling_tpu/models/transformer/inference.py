@@ -35,6 +35,7 @@ from .model import init_model
 from .tokenizer import Tokenizer
 from ...checkpoint import load_model_checkpoint
 from ...nn.attention import PagedKVCacheView
+from ...nn.base_layer import state_views
 from ...nn.moe import ParallelMoEMLP
 from ...parallel.parallel_module import ParallelModule
 
@@ -379,7 +380,7 @@ class TransformerInferenceModule:
             # layer that keeps no state takes the real positions instead
             key = (type(layer), id(layer.architecture),
                    type(getattr(layer, "mixer", None)))
-            if key not in shared and layer.consumes is None:
+            if key not in shared and not state_views(layer):
                 shared[key] = jax.jit(
                     lambda p, x, real: layer(p, x, ctx, real=real)
                 )
@@ -460,8 +461,8 @@ class TransformerInferenceModule:
             last_tl = max(tls)
         paged_layer_call = self._paged_layer_calls(ctx)
         # the views of the serving engine's state this stack's layers declare
-        views = tuple({l.consumes for l in self.module.layers
-                       if getattr(l, "consumes", None)})
+        views = tuple({view for l in self.module.layers
+                       for view in state_views(l)})
         paged = bool(caches) and isinstance(caches[0], views)
         real = None
         if paged and self.architecture.layer_pattern is not None:
@@ -477,33 +478,37 @@ class TransformerInferenceModule:
             p = self.module._layer_params(params, i)
             if isinstance(layer, TRUNK_LAYERS):
                 # a layer is handed the state of ITS kind, the view its
-                # mixer declares (a TransformerLayer: attention's); an MLP
-                # (routed or dense) nothing
-                consumes = layer.consumes
-                if consumes is None and real is not None:
+                # mixer declares (a TransformerLayer: attention's; a block of
+                # two mixers: the pair, as a tuple); an MLP (routed or dense)
+                # nothing
+                consumes = state_views(layer)
+                if not consumes and real is not None:
                     x = paged_layer_call(layer)(p, x, real)
-                elif caches is None or consumes is None:
+                elif caches is None or not consumes:
                     x = layer(p, x, ctx)
                 else:
-                    if li >= len(caches):
+                    names = " and a ".join(c.__name__ for c in consumes)
+                    held = caches[li:li + len(consumes)]
+                    if len(held) < len(consumes):
                         raise ValueError(
-                            f"layer {i} consumes a {consumes.__name__} but only "
+                            f"layer {i} consumes a {names} but only "
                             f"{len(caches)} were provided")
-                    cache = caches[li]
-                    served = isinstance(cache, views)
-                    # a dense (k, v) pair is an attention layer's too
-                    if not isinstance(cache, consumes) and (
-                            served or consumes is not PagedKVCacheView):
-                        raise ValueError(
-                            f"layer {i} consumes a {consumes.__name__} and was "
-                            f"handed a {type(cache).__name__}: the caches are "
-                            "one a consuming layer, in layer order")
+                    served = isinstance(held[0], views)
+                    for cache, view in zip(held, consumes):
+                        # a dense (k, v) pair is an attention layer's too
+                        if not isinstance(cache, view) and (
+                                served or view is not PagedKVCacheView):
+                            raise ValueError(
+                                f"layer {i} consumes a {names} and was "
+                                f"handed a {type(cache).__name__}: the caches "
+                                "are one a consuming mixer, in layer order")
+                    cache = held[0] if len(consumes) == 1 else tuple(held)
                     if served:
                         x, kv = paged_layer_call(layer)(p, x, cache)
                     else:
                         x, kv = layer(p, x, ctx, kv_cache=cache, cache_offset=offset)
-                    new_caches.append(kv)
-                    li += 1
+                    new_caches.extend([kv] if len(consumes) == 1 else kv)
+                    li += len(consumes)
                 if i == last_tl:
                     x = dict(x)
                     a = x["activations"]
@@ -518,6 +523,10 @@ class TransformerInferenceModule:
                         "independent) or use generate(use_cache=False)"
                     )
                 x = layer(p, x, ctx, stacked=False, remat=False)
+            elif last_tl is not None and i > last_tl:
+                # final norm and head of the positions that are sampled
+                with jax.named_scope("head"):
+                    x = layer(p, x, ctx)
             else:
                 x = layer(p, x, ctx)
         if caches is not None and li != len(caches):
@@ -856,11 +865,12 @@ class TransformerInferenceModule:
         kvs = []
         for i, layer in enumerate(self.module.layers):
             p = self.module._layer_params(params, i)
-            if isinstance(layer, TRUNK_LAYERS) and layer.consumes is not None:
+            if isinstance(layer, TRUNK_LAYERS) and state_views(layer):
                 # attention: its (k, v); a Mamba-2 mixer: its (ssm, conv)
-                # lines; a short convolution: its tail
+                # lines; a short convolution: its tail; a block of two
+                # mixers: one entry each, in the order it declares them
                 x, kv = layer(p, x, ctx, return_kv=True)
-                kvs.append(kv)
+                kvs.extend([kv] if len(state_views(layer)) == 1 else kv)
             else:
                 x = layer(p, x, ctx)
             if i == last_tl:
@@ -1051,11 +1061,12 @@ class TransformerInferenceModule:
                 row_logits[i].append(step_logits[i])
                 finished[i] = row_tokens[i][-1] in stop
 
-        if use_cache and self.architecture.layer_pattern is not None:
+        arch = self.architecture
+        if use_cache and (arch.layer_pattern is not None or arch.parallel_ssm):
             raise ValueError(
                 "cached generate() keeps dense KV caches only; a layer_pattern "
-                "stack (recurrent state beside KV) decodes through ServeEngine, "
-                "or here with use_cache=False"
+                "stack or a parallel_ssm block (recurrent state beside KV) "
+                "decodes through ServeEngine, or here with use_cache=False"
             )
         if use_cache:
             max_len = prompt_len + max_tokens

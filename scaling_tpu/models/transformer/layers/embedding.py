@@ -23,6 +23,7 @@ from ....nn import (
     normal_init,
     tree_prefix,
 )
+from ....nn.base_layer import multiplied
 from ..config import SoftpromptConfig, TransformerArchitectureConfig
 from .base import make_layer_io
 
@@ -31,7 +32,7 @@ class EmbeddingInput(BaseLayer):
     def __init__(self, architecture: TransformerArchitectureConfig):
         self.architecture = architecture
         extra = {}
-        if architecture.layer_pattern is not None:
+        if architecture.layer_pattern is not None or architecture.parallel_ssm:
             # a stack of single-mixer layers starts its stream at unit
             # variance (N(0, 1), the plain default of an embedding table; a
             # table that is the head too at a head's Xavier deviation),
@@ -65,6 +66,11 @@ class EmbeddingInput(BaseLayer):
 
     def init(self, key: jax.Array) -> dict:
         params = {"embedding": self.embedding.init(key)}
+        # under a published multiplier the table starts that much lower: the
+        # stream starts where it would without one
+        params["embedding"]["weight"] = multiplied(
+            params["embedding"]["weight"],
+            1.0 / self.architecture.multipliers.embedding)
         if self.image_encoder is not None:
             params["image_encoder"] = self.image_encoder.init(jax.random.fold_in(key, 2))
         if self.softprompt_config is not None:
@@ -91,7 +97,9 @@ class EmbeddingInput(BaseLayer):
 
     def __call__(self, params: dict, batch: dict, ctx: ForwardContext) -> dict:
         token_ids = batch["token_ids"]
-        embeddings = self.embedding(params["embedding"], token_ids, ctx)
+        embeddings = multiplied(
+            self.embedding(params["embedding"], token_ids, ctx),
+            self.architecture.multipliers.embedding)
 
         if self.image_encoder is not None and batch.get("input_images") is not None:
             # splice 144 encoded prefix tokens per image at its location

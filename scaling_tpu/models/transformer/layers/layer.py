@@ -30,6 +30,7 @@ from ....nn import (
     tree_prefix,
 )
 from ....nn.attention import PagedKVCacheView
+from ....nn.base_layer import multiplied
 from ....nn.rotary import RotaryConfig
 from ....nn.mamba import Mamba2Mixer
 from ....nn.short_conv import GatedShortConv
@@ -112,6 +113,8 @@ def dense_mlp(arch: TransformerArchitectureConfig, bitfit=None) -> BaseLayer:
             bias=arch.mlp_bias,
             dtype=arch.dtype,
             bitfit_bias_name=bitfit,
+            gate_multiplier=arch.multipliers.mlp_gate,
+            down_multiplier=arch.multipliers.mlp_down,
         )
     return ParallelMLP(
         io_features=arch.hidden_size,
@@ -120,6 +123,21 @@ def dense_mlp(arch: TransformerArchitectureConfig, bitfit=None) -> BaseLayer:
         bias=arch.mlp_bias,
         dtype=arch.dtype,
         bitfit_bias_name=bitfit,
+    )
+
+
+def mamba_mixer(arch: TransformerArchitectureConfig) -> Mamba2Mixer:
+    """The Mamba-2 mixer the configuration describes (``nn/mamba.py``)."""
+    return Mamba2Mixer(
+        hidden_size=arch.hidden_size, num_heads=arch.mamba_num_heads,
+        head_dim=arch.mamba_head_dim, state_size=arch.ssm_state_size,
+        n_groups=arch.n_groups, conv_kernel=arch.conv_kernel,
+        norm_eps=arch.layernorm.layernorm_epsilon,
+        time_step_min=arch.time_step_min,
+        time_step_max=arch.time_step_max,
+        time_step_floor=arch.time_step_floor, dtype=arch.dtype,
+        in_multiplier=arch.multipliers.ssm_in,
+        multipliers=arch.multipliers.ssm,
     )
 
 
@@ -137,15 +155,7 @@ class MixerLayer(BaseLayer):
         dtype = arch.dtype
         self.norm = get_norm(arch.norm_type, arch.hidden_size, arch.layernorm, dtype)
         if self.kind == LayerKind.MAMBA:
-            self.mixer: BaseLayer = Mamba2Mixer(
-                hidden_size=arch.hidden_size, num_heads=arch.mamba_num_heads,
-                head_dim=arch.mamba_head_dim, state_size=arch.ssm_state_size,
-                n_groups=arch.n_groups, conv_kernel=arch.conv_kernel,
-                norm_eps=arch.layernorm.layernorm_epsilon,
-                time_step_min=arch.time_step_min,
-                time_step_max=arch.time_step_max,
-                time_step_floor=arch.time_step_floor, dtype=dtype,
-            )
+            self.mixer: BaseLayer = mamba_mixer(arch)
         elif self.kind == LayerKind.MOE:
             self.mixer = routed_mlp(arch)
         elif self.kind == LayerKind.CONV:
@@ -235,8 +245,7 @@ class MixerLayer(BaseLayer):
             # a head tied to the table: no depth factor (the docstring)
             scale, routed = arch.pattern_embedding_std, routed ** 2
 
-        def scaled(weight, by):
-            return (weight.astype(jnp.float32) * by).astype(weight.dtype)
+        scaled = multiplied   # float32 product, the leaf's dtype back
 
         if self.kind in (LayerKind.MAMBA, LayerKind.CONV):
             mixer["out_proj"]["weight"] = scaled(mixer["out_proj"]["weight"], scale)
@@ -308,9 +317,14 @@ class MixerLayer(BaseLayer):
 
 
 class TransformerLayer(BaseLayer):
-    # what a walk of the stack reads off a trunk layer (MixerLayer's differ a
-    # layer): the serving state it keeps, attention's
-    consumes = ParallelSelfAttention.STATE_VIEW
+    """Attention behind a norm, the residual; an MLP behind a norm, the
+    residual. With ``parallel_ssm`` a Mamba-2 mixer runs BESIDE the attention
+    on the same normed input and the two are summed into the one residual
+    (Falcon-H1's block): ``x <- x + s_out SSM(N(x)) + a_out Attn(a_in N(x))``,
+    the pattern's two-mixer case. Such a layer keeps state under BOTH rules of
+    the serving pool, a paged KV line and a recurrent line a slot
+    (``consumes`` is then the two views, attention's first), is handed the
+    pair and gives the pair back."""
 
     def __init__(self, architecture: TransformerArchitectureConfig, layer_index: int = 0):
         arch = architecture
@@ -324,7 +338,9 @@ class TransformerLayer(BaseLayer):
         )
         rotary_config = None
         if arch.relative_position_embedding_type != RelativePositionEmbeddingType.NONE:
-            head_dim = arch.hidden_size // arch.num_attention_heads
+            # a head size of its own only beside parallel_ssm (config.py)
+            head_dim = (arch.attention_head_dim
+                        or arch.hidden_size // arch.num_attention_heads)
             rotary_config = RotaryConfig(
                 dimensions=max(2, int(head_dim * arch.rotary_percentage)),
                 base=arch.rotary_embedding_base,
@@ -367,7 +383,12 @@ class TransformerLayer(BaseLayer):
             qkv_in_one=arch.attention_qkv_in_one
             and arch.attention_num_kv_heads is None,
             num_kv_heads=arch.attention_num_kv_heads,
+            head_dim=arch.attention_head_dim,
+            key_multiplier=arch.multipliers.key,
         )
+        # the second mixer of a parallel block, on the attention's input
+        self.ssm: Optional[Mamba2Mixer] = (
+            mamba_mixer(arch) if arch.parallel_ssm else None)
         self.post_attention_layernorm = get_norm(
             arch.norm_type, arch.hidden_size, arch.layernorm, dtype, bitfit
         )
@@ -403,6 +424,15 @@ class TransformerLayer(BaseLayer):
                     arch.hidden_size, cfg.mlp_downsampling_factor, cfg.init_std, dtype
                 )
 
+    @property
+    def consumes(self):
+        """What a walk of the stack reads off a trunk layer: the view of the
+        serving state it keeps, attention's; of a parallel block the two
+        mixers' views, attention's first (``nn.base_layer.state_views``)."""
+        if self.ssm is None:
+            return self.attention.STATE_VIEW
+        return (self.attention.STATE_VIEW, self.ssm.STATE_VIEW)
+
     # ------------------------------------------------------------------ init
     def init(self, key: jax.Array) -> dict:
         keys = jax.random.split(key, 6)
@@ -412,6 +442,23 @@ class TransformerLayer(BaseLayer):
             "post_attention_layernorm": self.post_attention_layernorm.init(keys[2]),
             "mlp": self.mlp.init(keys[3]),
         }
+        mult = self.architecture.multipliers
+        # a matrix that a published multiplier follows starts at its usual
+        # scale over that multiplier (the mixers do their own: the keys', the
+        # MLP's two, in_proj's columns)
+        dense = params["attention"]["dense"]
+        dense["weight"] = multiplied(dense["weight"], 1.0 / mult.attention_out)
+        if self.ssm is not None:
+            params["ssm"] = self.ssm.init(jax.random.fold_in(key, 8))
+            out = params["ssm"]["out_proj"]
+            out["weight"] = multiplied(out["weight"], 1.0 / mult.ssm_out)
+            # the depth scaling of a residual branch, as MixerLayer.init has
+            # it, beside an embedding at unit variance: three branches a block
+            scale = 0.5 * self.architecture.num_layers ** -0.5
+            mlp_out = params["mlp"]["down_proj" if "down_proj" in params["mlp"]
+                                    else "dense_out"]
+            for branch in (dense, out, mlp_out):
+                branch["weight"] = multiplied(branch["weight"], scale)
         for i, (name, norm) in enumerate(self.output_norms.items()):
             # keys of their own: the six above stay what they were
             params[name] = norm.init(jax.random.fold_in(key, 6 + i))
@@ -440,6 +487,8 @@ class TransformerLayer(BaseLayer):
             ),
             "mlp": tree_prefix(self.mlp.param_metas(), "mlp"),
         }
+        if self.ssm is not None:
+            metas["ssm"] = tree_prefix(self.ssm.param_metas(), "ssm")
         for name, norm in self.output_norms.items():
             metas[name] = tree_prefix(norm.param_metas(), name)
         if self.adapter_attention is not None:
@@ -487,28 +536,48 @@ class TransformerLayer(BaseLayer):
     def __call__(self, params: dict, x: dict, ctx: ForwardContext,
                  kv_cache=None, cache_offset=None, return_kv: bool = False):
         arch = self.architecture
+        mult = arch.multipliers
         h = x["activations"]
 
         normed = self.input_layernorm(params["input_layernorm"], h, ctx)
-        attn = self.attention(
-            params["attention"],
-            normed,
-            ctx,
-            segment_ids=x["segment_ids"],
-            position_ids=x["position_ids"],
-            kv_cache=kv_cache,
-            cache_offset=cache_offset,
-            attention_scores_manipulation=x.get("attention_scores_manipulation"),
-            # a STATIC python bool (threaded by inference.logits at trace
-            # time); never a traced leaf
-            attention_scores_manipulation_log_additive=x.get(
-                "attention_scores_manipulation_log_additive", True
-            ),
-            return_kv=return_kv,
-        )
+        lines = None
+        if self.ssm is not None and kv_cache is not None:
+            # a parallel block's state: (attention's, the Mamba-2 mixer's)
+            kv_cache, lines = kv_cache
+            if not isinstance(lines, self.ssm.STATE_VIEW):
+                raise ValueError(
+                    "a parallel block takes a PagedKVCacheView and a "
+                    "RecurrentStateView (the serving engine's state), not "
+                    "dense caches: cached generate() is not built for "
+                    "parallel_ssm; use use_cache=False or ServeEngine")
+        with jax.named_scope("attn"):
+            attn = self.attention(
+                params["attention"],
+                multiplied(normed, mult.attention_in),
+                ctx,
+                segment_ids=x["segment_ids"],
+                position_ids=x["position_ids"],
+                kv_cache=kv_cache,
+                cache_offset=cache_offset,
+                attention_scores_manipulation=x.get("attention_scores_manipulation"),
+                # a STATIC python bool (threaded by inference.logits at trace
+                # time); never a traced leaf
+                attention_scores_manipulation_log_additive=x.get(
+                    "attention_scores_manipulation_log_additive", True
+                ),
+                return_kv=return_kv,
+            )
         new_kv = None
         if return_kv or kv_cache is not None:
             attn, new_kv = attn
+        attn = multiplied(attn, mult.attention_out)
+        if self.ssm is not None:
+            y = self.ssm(params["ssm"], normed, ctx, state=lines,
+                         return_state=return_kv)
+            if new_kv is not None:
+                y, new_lines = y
+                new_kv = (new_kv, new_lines)
+            attn = attn + multiplied(y, mult.ssm_out).astype(attn.dtype)
         attn = ctx.dropout(attn, arch.dropout_after_attention)
         if self.adapter_attention is not None:
             attn = attn + self.adapter_attention(
@@ -533,7 +602,8 @@ class TransformerLayer(BaseLayer):
         elif self.is_moe:
             mlp_out, aux_loss = self.mlp(params["mlp"], normed, ctx)
         else:
-            mlp_out = self.mlp(params["mlp"], normed, ctx)
+            with jax.named_scope("mlp"):
+                mlp_out = self.mlp(params["mlp"], normed, ctx)
         mlp_out = ctx.dropout(mlp_out, arch.dropout_after_mlp)
         if self.adapter_mlp is not None:
             mlp_out = mlp_out + self.adapter_mlp(

@@ -21,6 +21,7 @@ from ....nn import (
     tree_prefix,
     xavier_normal_init,
 )
+from ....nn.base_layer import multiplied
 from ....parallel.sharding import constrain
 from ....topology.topology import MODEL_AXIS
 from ..config import EmbeddingHeadConfig, TransformerArchitectureConfig
@@ -125,6 +126,8 @@ class TransformerLMHead(BaseLayer):
     both (the two equivalent muP output formulations) over-suppresses
     updates by an extra 1/m, which the coordinate check catches."""
 
+    READOUT_LOGIT_STD = 0.5
+
     def __init__(self, architecture: TransformerArchitectureConfig):
         arch = architecture
         mup = arch.mup
@@ -134,6 +137,17 @@ class TransformerLMHead(BaseLayer):
             self.logit_mult = mup.output_mult
             if mup.readout_zero_init:
                 init_method = lambda key, shape, dtype: jnp.zeros(shape, dtype)  # noqa: E731
+        # a published constant on the logits (Falcon-H1's lm_head_multiplier):
+        # the seeded head starts that much higher, and from a deviation that
+        # counts its fan-in only, muP's readout: fresh logits then lie at a
+        # deviation of READOUT_LOGIT_STD whatever the vocabulary's size, where
+        # Xavier's fan-out term shrinks them with it (0.196 at 261,120 rows,
+        # under which the benchmark's fp8 control read 0.035 against its limit
+        # of 0.05; a 32,768-row Xavier head over 4096 gives 0.47: PERF.md, PR 52)
+        self.multiplier = arch.multipliers.lm_head
+        if self.multiplier != 1.0:
+            init_method = normal_init(
+                self.READOUT_LOGIT_STD * arch.hidden_size ** -0.5)
         self.linear = ColumnParallelLinear(
             arch.hidden_size,
             arch.vocab_size,
@@ -144,7 +158,9 @@ class TransformerLMHead(BaseLayer):
         )
 
     def init(self, key: jax.Array) -> dict:
-        return {"linear": self.linear.init(key)}
+        params = self.linear.init(key)
+        params["weight"] = multiplied(params["weight"], 1.0 / self.multiplier)
+        return {"linear": params}
 
     def param_metas(self) -> dict:
         return {"linear": tree_prefix(self.linear.param_metas(), "linear")}
@@ -154,7 +170,7 @@ class TransformerLMHead(BaseLayer):
         logits = self.linear(params["linear"], x["activations"], ctx)
         if self.logit_mult is not None:
             logits = logits * jnp.asarray(self.logit_mult, logits.dtype)
-        out["activations"] = logits
+        out["activations"] = multiplied(logits, self.multiplier)
         return out
 
 

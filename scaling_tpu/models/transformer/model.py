@@ -80,6 +80,11 @@ def get_transformer_layer_specs(
         for layer_index in range(architecture.num_layers):
             specs.append(LayerSpec(MixerLayer, architecture, layer_index))
     elif pp > 1:
+        if architecture.parallel_ssm:
+            raise ValueError(
+                f"pipe_parallel_size {pp} with parallel_ssm: a block's "
+                "recurrent lines are not stage-stacked; use "
+                "pipe_parallel_size 1")
         specs.append(
             PipelineBodySpec(TransformerLayer, architecture.num_layers, architecture)
         )
@@ -308,6 +313,14 @@ PATTERN_TRAINING_REFUSAL = (
 )
 
 
+PARALLEL_SSM_TRAINING_REFUSAL = (
+    "a parallel_ssm stack is served, not trained: the chunked scan of the "
+    "Mamba-2 mixer beside the attention has no memory-lean backward and the "
+    "optimizer's groups do not know the mixer's float32 leaves; run it "
+    "through TransformerInferenceModule / ServeEngine"
+)
+
+
 def init_model(config: TransformerConfig, topology: Optional[Topology] = None) -> ParallelModule:
     architecture = config.transformer_architecture
     specs = get_transformer_layer_specs(architecture, topology)
@@ -316,6 +329,8 @@ def init_model(config: TransformerConfig, topology: Optional[Topology] = None) -
         refusal = LOOPED_TRAINING_REFUSAL
     elif architecture.layer_pattern is not None:
         refusal = PATTERN_TRAINING_REFUSAL
+    elif architecture.parallel_ssm:
+        refusal = PARALLEL_SSM_TRAINING_REFUSAL
     return ParallelModule(
         specs,
         topology=topology,

@@ -28,7 +28,7 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .base_layer import BaseLayer, ForwardContext
+from .base_layer import BaseLayer, ForwardContext, multiplied
 from .linear import ColumnParallelLinear, RowParallelLinear, xavier_normal_init
 from .lora import LoRAModuleType, LoRaConfig, ParallelLoRa
 from .masked_softmax import MaskedSoftmax, MaskedSoftmaxConfig, MaskedSoftmaxKernel
@@ -326,6 +326,7 @@ class ParallelSelfAttention(BaseLayer):
         qkv_in_one: bool = True,
         num_kv_heads: Optional[int] = None,
         head_dim: Optional[int] = None,
+        key_multiplier: float = 1.0,
     ):
         assert head_dim is not None or hidden_size % num_attention_heads == 0, (
             f"hidden size ({hidden_size}) must be divisible by "
@@ -352,6 +353,12 @@ class ParallelSelfAttention(BaseLayer):
         )
         self.dtype = dtype
 
+        # a published constant on the keys, before rotary and the cache
+        # (Falcon-H1's key_multiplier); the key projection's seeded init
+        # starts that much higher
+        self.key_multiplier = float(key_multiplier)
+        assert key_multiplier == 1.0 or not qkv_in_one, (
+            "a key multiplier needs a key projection of its own")
         self.qkv_in_one = qkv_in_one
         self.num_kv_heads = num_kv_heads
         if num_kv_heads:
@@ -435,6 +442,8 @@ class ParallelSelfAttention(BaseLayer):
         else:
             params["query"] = self.query.init(keys[0])
             params["key"] = self.key.init(keys[1])
+            params["key"]["weight"] = multiplied(
+                params["key"]["weight"], 1.0 / self.key_multiplier)
             params["value"] = self.value.init(keys[2])
         params["dense"] = self.dense.init(keys[3])
         if self.key_query_norm:
@@ -506,6 +515,7 @@ class ParallelSelfAttention(BaseLayer):
     ):
         b, s, _ = x.shape
         q, k, v = self._qkv(params, x, ctx)
+        k = multiplied(k, self.key_multiplier)
 
         if self.key_query_norm and self.key_query_norm_over_projection:
             # the statistic runs over every head's values: under model

@@ -69,6 +69,29 @@ class ForwardContext:
         return jax.numpy.where(mask, x / keep, 0).astype(x.dtype)
 
 
+def state_views(layer) -> tuple:
+    """The view classes of the serving state ``layer`` keeps, as its
+    ``consumes`` declares them: none (no ``consumes``, or None: an MLP, an
+    edge layer), one (the view its one mixer names), or, where two mixers run
+    side by side in one block, a tuple of theirs. The order is that of the
+    layer's state wherever a walk of the stack lists it: its caches, the
+    finals of a probe, ``kinds`` of the serving pools."""
+    consumes = getattr(layer, "consumes", None)
+    if consumes is None:
+        return ()
+    return consumes if isinstance(consumes, tuple) else (consumes,)
+
+
+def multiplied(x: jax.Array, by: float) -> jax.Array:
+    """``x`` times a constant of the configuration, computed in float32 and
+    given back in ``x``'s dtype (a constant such as 0.0110485 rounded to bf16
+    first would be off by up to 0.4%); at 1 ``x`` itself, so that a model
+    without multipliers lowers to what it did."""
+    if by == 1.0:
+        return x
+    return (x.astype(jax.numpy.float32) * by).astype(x.dtype)
+
+
 class BaseLayer:
     """Stateless layer: owns hyperparameters, emits params/metas trees."""
 

@@ -65,13 +65,13 @@ Two callers:
 from __future__ import annotations
 
 import math
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 
 from .attention import PagedTokenMap
-from .base_layer import BaseLayer, ForwardContext
+from .base_layer import BaseLayer, ForwardContext, multiplied
 from .param import ParamMeta
 from ..topology.topology import MODEL_AXIS
 
@@ -170,7 +170,14 @@ class Mamba2Mixer(BaseLayer):
                  state_size: int, n_groups: int, conv_kernel: int,
                  norm_eps: float = 1e-5, time_step_min: float = 0.001,
                  time_step_max: float = 0.1, time_step_floor: float = 1e-4,
-                 dtype=None):
+                 dtype=None, in_multiplier: float = 1.0,
+                 multipliers: Optional[Sequence[float]] = None):
+        """``in_multiplier`` and ``multipliers`` are published constants of a
+        configuration (Falcon-H1's ``ssm_in_multiplier`` and
+        ``ssm_multipliers``): ``proj = ((in_multiplier * u) W_in) * m``, ``m``
+        one float32 vector over ``in_proj``'s columns that holds
+        ``multipliers`` = (z, x, B, C, dt) by segment. None or all ones:
+        nothing is multiplied (Nemotron-H's mixer)."""
         assert num_heads % n_groups == 0, (num_heads, n_groups)
         self.hidden_size = hidden_size
         self.num_heads = num_heads
@@ -184,6 +191,10 @@ class Mamba2Mixer(BaseLayer):
         self.inner = num_heads * head_dim
         self.conv_dim = self.inner + 2 * n_groups * state_size
         self.in_width = self.inner + self.conv_dim + num_heads
+        self.in_multiplier = float(in_multiplier)
+        self.multipliers = None
+        if multipliers is not None and any(m != 1.0 for m in multipliers):
+            self.multipliers = tuple(float(m) for m in multipliers)
 
     # ------------------------------------------------------------------ init
     def init(self, key: jax.Array) -> dict:
@@ -203,8 +214,13 @@ class Mamba2Mixer(BaseLayer):
                      * (math.log(hi) - math.log(lo)) + math.log(lo))
         dt = jnp.maximum(dt, floor)
         bound = 1.0 / math.sqrt(K)
+        w_in = xavier(ks[0], (H, self.in_width))
+        if self.multipliers is not None or self.in_multiplier != 1.0:
+            # each column starts at its Xavier scale over what multiplies it
+            by = self._column_multipliers() * self.in_multiplier
+            w_in = (w_in.astype(F32) / by).astype(self.dtype)
         return {
-            "in_proj": {"weight": xavier(ks[0], (H, self.in_width))},
+            "in_proj": {"weight": w_in},
             "conv": {
                 "weight": jax.random.uniform(
                     ks[1], (self.conv_dim, K), minval=-bound, maxval=bound
@@ -242,6 +258,15 @@ class Mamba2Mixer(BaseLayer):
         }
 
     # --------------------------------------------------------------- forward
+    def _column_multipliers(self):
+        """``multipliers`` over ``in_proj``'s columns, float32 ``(in_width,)``:
+        z | x | B | C | dt."""
+        GN = self.n_groups * self.state_size
+        widths = (self.inner, self.inner, GN, GN, self.num_heads)
+        return jnp.concatenate([
+            jnp.full((width,), m, F32)
+            for width, m in zip(widths, self.multipliers or (1.0,) * 5)])
+
     def _split(self, proj):
         z = proj[..., :self.inner]
         xBC = proj[..., self.inner:self.inner + self.conv_dim]
@@ -280,7 +305,11 @@ class Mamba2Mixer(BaseLayer):
         ``(ssm, conv)`` lines); with ``state`` the batch is the tick's, and the
         second result is the view with its lines advanced."""
         with jax.named_scope("ssm"):
+            u = multiplied(u, self.in_multiplier)
             proj = u @ params["in_proj"]["weight"].astype(u.dtype)
+            if self.multipliers is not None:
+                proj = (proj.astype(F32) * self._column_multipliers()).astype(
+                    proj.dtype)
             z, xBC, dt = self._split(proj)
             if state is not None:
                 return self._serve(params, z, xBC, dt, state)

@@ -14,7 +14,7 @@ from typing import Optional
 import jax
 
 from .activation_function import ActivationFunction, get_activation_function
-from .base_layer import BaseLayer, ForwardContext
+from .base_layer import BaseLayer, ForwardContext, multiplied
 from .linear import ColumnParallelLinear, RowParallelLinear, xavier_normal_init
 from .param import tree_prefix
 
@@ -67,7 +67,13 @@ class ParallelMLP(BaseLayer):
 
 
 class ParallelSwiGLUMLP(BaseLayer):
-    """silu(x W_gate) * (x W_up) -> W_down, all tensor-parallel."""
+    """silu(x W_gate) * (x W_up) -> W_down, all tensor-parallel.
+
+    ``gate_multiplier`` / ``down_multiplier``: published constants of a
+    configuration (Falcon-H1's ``mlp_multipliers``), ``down * ((silu(gate * (x
+    W_gate)) * (x W_up)) W_down)``; the seeded init starts ``W_gate`` and
+    ``W_down`` at their usual scale over their multiplier. At 1 nothing is
+    multiplied."""
 
     def __init__(
         self,
@@ -78,6 +84,8 @@ class ParallelSwiGLUMLP(BaseLayer):
         init_method=xavier_normal_init,
         bitfit_bias_name: Optional[str] = None,
         sequence_parallel_output: bool = False,
+        gate_multiplier: float = 1.0,
+        down_multiplier: float = 1.0,
     ):
         import jax.numpy as jnp
 
@@ -87,6 +95,8 @@ class ParallelSwiGLUMLP(BaseLayer):
         ), "io_features * intermediate_feature_factor must be a natural number"
         intermediate = int(io_features * intermediate_feature_factor)
         self.intermediate = intermediate
+        self.gate_multiplier = float(gate_multiplier)
+        self.down_multiplier = float(down_multiplier)
         self.silu = get_activation_function(ActivationFunction.SILU)
         self.gate_proj = ColumnParallelLinear(
             io_features, intermediate, bias=bias, dtype=dtype,
@@ -106,11 +116,15 @@ class ParallelSwiGLUMLP(BaseLayer):
 
     def init(self, key: jax.Array) -> dict:
         k1, k2, k3 = jax.random.split(key, 3)
-        return {
+        params = {
             "gate_proj": self.gate_proj.init(k1),
             "up_proj": self.up_proj.init(k2),
             "down_proj": self.down_proj.init(k3),
         }
+        for name, by in (("gate_proj", self.gate_multiplier),
+                         ("down_proj", self.down_multiplier)):
+            params[name]["weight"] = multiplied(params[name]["weight"], 1.0 / by)
+        return params
 
     def param_metas(self) -> dict:
         return {
@@ -120,6 +134,8 @@ class ParallelSwiGLUMLP(BaseLayer):
         }
 
     def __call__(self, params: dict, x: jax.Array, ctx: ForwardContext) -> jax.Array:
-        gate = self.silu(self.gate_proj(params["gate_proj"], x, ctx))
+        gate = self.silu(multiplied(
+            self.gate_proj(params["gate_proj"], x, ctx), self.gate_multiplier))
         up = self.up_proj(params["up_proj"], x, ctx)
-        return self.down_proj(params["down_proj"], gate * up, ctx)
+        return multiplied(self.down_proj(params["down_proj"], gate * up, ctx),
+                          self.down_multiplier)

@@ -60,6 +60,7 @@ from typing import Dict, List, NamedTuple, Optional, Tuple
 
 from .. import obs
 from ..logging import logger
+from ..nn.base_layer import state_views
 from ..nn.mamba import RecurrentStateView, split_capacity
 from ..nn.paged_attention import kernel_tile_tokens
 from ..resilience.faults import get_fault_plan
@@ -314,6 +315,11 @@ class ServeEngine:
                 "advanced the lines and there is no rollback; set spec_k=0")
         # Mamba-2's lines advance in a form of their own (nn/mamba.py)
         self.ssm_lines = self.line_layers.get(RecurrentStateView.NAME, 0)
+        # layers whose two mixers run side by side and keep state under both
+        # rules, a paged line and a line a slot (parallel_ssm)
+        self.par_lines = sum(
+            len(state_views(layer)) > 1
+            for layer in inference_module.module.layers)
         import numpy as np
 
         self._np = np
@@ -732,10 +738,11 @@ class ServeEngine:
             # gathered index j is the row's token g0 + j: shift the
             # per-row key-fold base so every sample still draws with the
             # (request, position) key plain decode would use there
-            sampled = self._sample_grid(
-                logits, tick.temps, tick.topps, tick.topks, tick.reqids,
-                tick.gen0 + g0, base_key
-            )
+            with self._jax.named_scope("head"):  # beside final norm and head
+                sampled = self._sample_grid(
+                    logits, tick.temps, tick.topps, tick.topks, tick.reqids,
+                    tick.gen0 + g0, base_key
+                )
             if routed:
                 sampled = jnp.concatenate([sampled.reshape(-1), extra[0]])
             if gated:
@@ -914,6 +921,10 @@ class ServeEngine:
                     for path, count in paths.items():
                         self._counter("serve_ssm_rows_total", path=path).inc(
                             count * self.ssm_lines)
+                if self.par_lines:
+                    mixed_span.annotate(par_lines=self.par_lines)
+                    self._counter("serve_parallel_mixer_passes_total").inc(
+                        self.par_lines)
                 if self.num_experts:
                     # the rows the tick's expert matmuls were given; with
                     # serve_moe_assignments_total (the real, held assignments

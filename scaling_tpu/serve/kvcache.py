@@ -57,6 +57,13 @@ scale_v)``, then for every per-slot kind, in the order the stack first meets
 them (``line_layers``), one list a field of its ``LINES``, each over that
 kind's layers in layer order. ``kinds``, the view class of every consuming
 layer in layer order, says which lists a state carries.
+
+**A layer under both rules** (a block whose attention and Mamba-2 mixer run
+side by side: ``parallel_ssm``). Its ``consumes`` is a tuple, the two mixers'
+views (``nn.base_layer.state_views``), and ``kinds`` holds an entry a consuming
+MIXER: the layer's paged entry, then its per-slot one. Nothing else here
+changes: the views are built and taken back one an entry, and the walk hands
+such a layer the pair.
 """
 
 from __future__ import annotations
@@ -69,6 +76,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..nn.attention import PagedKVCacheView, PagedTokenMap
+from ..nn.base_layer import state_views
 
 
 def serving_mesh(inference_module):
@@ -280,10 +288,11 @@ def init_pools(inference_module, num_blocks: int, block_size: int,
         return inference_module.prefill_forward(p, t, po)[1]
 
     kv_shapes = jax.eval_shape(probe, params, probe_tokens, probe_pos)
-    # a pattern stack's probe holds the final state a consuming layer, in
-    # layer order: (k, v) of a paged layer, of a per-slot one its lines
-    kinds = [layer.consumes for layer in inference_module.module.layers
-             if getattr(layer, "consumes", None)]
+    # a pattern stack's probe holds the final state a consuming mixer, in
+    # layer order: (k, v) of a paged one, of a per-slot one its lines (a
+    # block of two mixers: an entry each)
+    kinds = [view for layer in inference_module.module.layers
+             for view in state_views(layer)]
     per_slot = line_layers(kinds)
     if per_slot:
         finals = list(zip(kinds, kv_shapes))

@@ -145,6 +145,18 @@ def test_paged_kernel_compiles_at_heads_of_64(one_chip, s):
     compile_paged_kernel(one_chip, 64, 32, 8, 40, s, "native", head_dim=64)
 
 
+@pytest.mark.parametrize(
+    "s", [1, 32], ids=["decode-s1", "prefill-chunk-s32"]
+)
+def test_paged_kernel_compiles_at_group_5(one_chip, s):
+    """``serve-falconh1-34b-reason-burst``'s attention: 20 query heads over 4
+    KV heads x 128, 96 slots of 40 blocks. A group of 5 is no power of two: the
+    folded query block is ``s_pad * 5`` rows (40 at a decode row's 8 padded
+    positions, 160 at a chunk's 32), multiples of the 8 sublanes both, and
+    Mosaic takes it as it took Pharia's 9; no head is added or dropped."""
+    compile_paged_kernel(one_chip, 96, 20, 4, 40, s, "native")
+
+
 def compile_paged_kernel(one_chip, slots, q_heads, kv_heads, max_blocks, s,
                          kv_dtype, head_dim=None):
     def shape(dims, dtype):

@@ -233,3 +233,101 @@ def test_relu2_is_the_square_of_relu():
     np.testing.assert_array_equal(
         np.asarray(get_activation_function(ActivationFunction.RELU2)(x)),
         np.asarray([0.0, 0.0, 0.25, 9.0]))
+
+
+# ---- published multipliers on in_proj's input and output (Falcon-H1) --------
+SEGMENTS = ("z", "x", "B", "C", "dt")
+
+
+def multiplied_mixer(in_multiplier=1.0, multipliers=None):
+    return Mamba2Mixer(H, HEADS, P, N, G, K, in_multiplier=in_multiplier,
+                       multipliers=multipliers)
+
+
+@pytest.mark.parametrize("ones", [None, (1.0,) * 5], ids=["absent", "all-ones"])
+def test_multipliers_of_one_are_todays_mixer_bit_for_bit(mixer, ones):
+    """Nothing is multiplied and nothing divided: the same init, the same
+    outputs and the same lowered program as a mixer built without them."""
+    layer, params = mixer
+    same = multiplied_mixer(1.0, ones)
+    assert same.multipliers is None
+    fresh, was = same.init(jax.random.PRNGKey(5)), layer.init(jax.random.PRNGKey(5))
+    assert all(np.array_equal(a, b) for a, b in zip(jax.tree.leaves(fresh),
+                                                    jax.tree.leaves(was)))
+    u = jax.random.normal(jax.random.PRNGKey(2), (2, 9, H))
+    ctx = ForwardContext()
+    assert np.array_equal(np.asarray(same(params, u, ctx)),
+                          np.asarray(layer(params, u, ctx)))
+    assert (jax.jit(lambda p, u: same(p, u, ctx)).lower(params, u).as_text()
+            == jax.jit(lambda p, u: layer(p, u, ctx)).lower(params, u).as_text())
+
+
+@pytest.mark.parametrize("segment", range(5), ids=SEGMENTS)
+def test_a_segments_multiplier_scales_its_columns_of_in_proj_and_no_others(mixer, segment):
+    """``proj = ((s_in u) W_in) * m``: a mixer with a multiplier on ONE
+    segment equals the plain mixer whose ``W_in`` has that segment's columns
+    scaled, and moves the output."""
+    layer, params = mixer
+    m = [1.0] * 5
+    m[segment] = 0.3
+    scaled = multiplied_mixer(1.0, m)
+    inner, GN = HEADS * P, G * N
+    edges = np.cumsum([0, inner, inner, GN, GN, HEADS])
+    by = np.ones(edges[-1], np.float32)
+    by[edges[segment]:edges[segment + 1]] = 0.3
+    folded = dict(params, in_proj={"weight": params["in_proj"]["weight"] * by})
+    u = jax.random.normal(jax.random.PRNGKey(3), (2, 40, H))
+    ctx = ForwardContext()
+    got, (S, tail) = scaled(params, u, ctx, return_state=True)
+    want, (S_w, tail_w) = layer(folded, u, ctx, return_state=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=ATOL, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(S), np.asarray(S_w), atol=ATOL, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(tail), np.asarray(tail_w), atol=ATOL)
+    plain = np.asarray(layer(params, u, ctx))
+    assert np.abs(np.asarray(got) - plain).max() > 1e-3
+    # z gates the output and C reads the state out: the state sees neither
+    _, (S_plain, _) = layer(params, u, ctx, return_state=True)
+    assert np.array_equal(np.asarray(S), np.asarray(S_plain)) == (
+        SEGMENTS[segment] in ("z", "C"))
+
+
+def test_the_input_multiplier_and_the_served_path_see_the_same_constants(mixer):
+    """``s_in`` scales every column; a served tick (single steps and a
+    gathered chunk) applies the same constants as the uncached pass."""
+    layer, params = mixer
+    m = (0.35, 0.25, 0.18, 0.5, 0.35)
+    scaled = multiplied_mixer(0.25, m)
+    u = jax.random.normal(jax.random.PRNGKey(4), (1, 12, H))
+    ctx = ForwardContext()
+    whole, (S_whole, tail_whole) = scaled(params, u, ctx, return_state=True)
+    by = np.asarray(scaled._column_multipliers()) * 0.25
+    folded = dict(params, in_proj={"weight": params["in_proj"]["weight"] * by})
+    np.testing.assert_allclose(np.asarray(whole), np.asarray(layer(folded, u, ctx)),
+                               atol=ATOL, rtol=1e-5)
+    # served: 3 slots, slot 1 takes a chunk of 8 then four single steps
+    lines = (jnp.zeros((3, HEADS, P, N)), jnp.zeros((3, HEADS * P + 2 * G * N, K - 1)))
+    outs, ssm, conv = run_tick(scaled, params, [u[0, :0], u[0, :8], u[0, :0]], lines,
+                               [0, 0, 0], [0, 8, 0], 8, width=16)
+    got = [outs[1]]
+    for t in range(8, 12):
+        outs, ssm, conv = run_tick(scaled, params, [u[0, :0], u[0, t:t + 1], u[0, :0]],
+                                   (ssm, conv), [0, t, 0], [0, 1, 0], 8, width=16)
+        got.append(outs[1])
+    np.testing.assert_allclose(np.concatenate(got), np.asarray(whole[0]),
+                               atol=ATOL, rtol=1e-5)
+    np.testing.assert_allclose(ssm[1], np.asarray(S_whole[0]), atol=ATOL, rtol=1e-5)
+
+
+def test_the_seeded_in_proj_starts_at_its_xavier_scale_over_its_multipliers():
+    """muP's own init: each column's deviation is the plain one divided by
+    what multiplies the column, so ``proj`` starts where a plain mixer's does."""
+    m = (0.35, 0.25, 0.18, 0.5, 0.35)
+    scaled = multiplied_mixer(0.25, m)
+    plain = Mamba2Mixer(H, HEADS, P, N, G, K)
+    key = jax.random.PRNGKey(9)
+    w, w_plain = (layer.init(key)["in_proj"]["weight"] for layer in (scaled, plain))
+    by = np.asarray(scaled._column_multipliers()) * 0.25
+    np.testing.assert_allclose(np.asarray(w) * by, np.asarray(w_plain), rtol=1e-6)
+    others = set(scaled.init(key)) - {"in_proj"}
+    assert all(np.array_equal(a, b) for name in others for a, b in zip(
+        jax.tree.leaves(scaled.init(key)[name]), jax.tree.leaves(plain.init(key)[name])))
