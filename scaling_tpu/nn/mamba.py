@@ -52,14 +52,15 @@ Two callers:
   ONE token takes the single step where it lies, and the few that bring more,
   at most ``T // w`` (``split_capacity``: the engine sends a tick with more to
   the full width), are gathered through the ``PagedTokenMap`` the attention
-  branch uses, run ``ssd_chunk`` as ``(R, w)`` whole rows and are written back
-  over their lines. At the full width ``R`` would be every row: nothing to
-  split, so every row is regrouped to ``(rows, w)`` and runs the chunk form
-  (``_chunk_rows``), as the row-major caller's rows do (``token_map`` None);
-  that whole-rows form is also what the tests hold the split to. The choice
-  follows from the shapes alone. A row whose ``context_len`` is 0 starts from
-  zeros in either form: the program does it, so a reused slot or a recomputed
-  (preempted) sequence needs no reset by the host.
+  branch uses, run ``ssd_chunk`` as ``(R, w)`` whole rows from their lines,
+  each fetched by a read of its own, and are written back over them. At the
+  full width ``R`` would be every row: nothing to split, so every row is
+  regrouped to ``(rows, w)`` and runs the chunk form (``_chunk_rows``), as the
+  row-major caller's rows do (``token_map`` None); that whole-rows form is
+  also what the tests hold the split to. The choice follows from the shapes
+  alone. A row whose ``context_len`` is 0 starts from zeros in either form:
+  the program does it, so a reused slot or a recomputed (preempted) sequence
+  needs no reset by the host.
 """
 
 from __future__ import annotations
@@ -442,8 +443,13 @@ class Mamba2Mixer(BaseLayer):
         at, = jnp.nonzero(multi, size=R, fill_value=rows)
         held = jnp.minimum(at, rows - 1)
         flat = tmap.row_tokens[held]                         # (R, w)
+        # each line by a read of its own: a general gather over a state wider
+        # than the 128 lanes first copies EVERY slot's line into lane halves
+        S_held = jnp.stack([
+            jax.lax.dynamic_index_in_dim(S, row, 0, keepdims=False)
+            for row in held])
         y_chunk, S_chunk, tail_chunk = self._chunk_rows(
-            params, xBC[flat], dt[flat], S[held], tail[held], ctx_len[held],
+            params, xBC[flat], dt[flat], S_held, tail[held], ctx_len[held],
             jnp.where(at < rows, new_len[held], 0))
         S = S.at[at].set(S_chunk, mode="drop")
         tail = tail.at[at].set(tail_chunk.astype(tail.dtype), mode="drop")

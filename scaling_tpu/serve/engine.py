@@ -764,6 +764,9 @@ class ServeEngine:
         # of every layer's whole pool every tick. CPU can't donate (every
         # call would warn).
         donate = (1,) if self._jax.default_backend() != "cpu" else ()
+        # a profile keys an operation by its module's name and its own: two
+        # widths' programs under ONE name are taken for each other's there
+        mixed.__name__ = f"mixed_{width}"
         return self._jax.jit(mixed, donate_argnums=donate)
 
     def _lower_mixed_programs(self) -> None:

@@ -388,20 +388,35 @@ def test_hybrid_mixed_program_updates_pools_and_recurrent_lines_in_place(
         assert not copies, f"{len(copies)} whole copies of {shape} in the compiled tick"
 
 
-def test_a_one_token_row_reads_its_mamba_state_once_at_the_cells_size(one_chip):
-    """The Mamba-2 mixer alone at the hybrid cell's size (64 slots of 64 heads
-    x 64 x 128 float32 = 134 MB a layer, a token-major tick of 256 places for
-    rows of up to 32: ISSUE 49): the rows that bring one token advance in ONE
-    fusion that takes the donated state and gives both the read-out `(64, 64,
-    64)` and the new state, written over the old one; the at most 8 rows that
-    bring a chunk are sliced out and scattered back in place. No second pass
-    over the state, no copy of it, no `(64 rows, 32 places, ..)` tensor."""
+# (slots, hidden, heads, head_dim, state, groups) of a cell's Mamba-2 layer
+MAMBA_CELLS = {
+    # serve-nemotron3nano-reason-burst: 134 MB of float32 state a layer
+    # (ISSUE 49)
+    "nemotron-64x(64,64,128)": (64, 2688, 64, 64, 128, 8),
+    # serve-falconh1-34b-reason-burst: 403 MB a layer, a state of TWO lane
+    # tiles (ISSUE 53)
+    "falconh1-96x(32,128,256)": (96, 5120, 32, 128, 256, 2),
+}
+
+
+@pytest.mark.parametrize("cell", MAMBA_CELLS)
+def test_a_one_token_row_reads_its_mamba_state_once_at_the_cells_size(one_chip, cell):
+    """The Mamba-2 mixer alone at the two cells' sizes, a token-major tick of
+    256 places for rows of up to 32: the rows that bring one token advance in
+    ONE fusion that takes the donated state and gives both the read-out
+    `(slots, heads, head_dim)` and the new state, written over the old one;
+    the at most 8 rows that bring a chunk are sliced out line by line and
+    scattered back in place. No second pass over the state, no copy of it, no
+    `(slots, 32 places, ..)` tensor; and at a state of 256 no pass over its
+    two halves of 128 lanes, which a general gather of the 8 lines costs
+    there (1.2 ms a layer on the chip: ISSUE 53)."""
     from scaling_tpu.nn.attention import packed_token_map
     from scaling_tpu.nn.base_layer import ForwardContext
     from scaling_tpu.nn.mamba import Mamba2Mixer, RecurrentStateView
 
-    slots, w, places = 64, 32, 256
-    layer = Mamba2Mixer(2688, 64, 64, 128, 8, 4, dtype=jnp.bfloat16)
+    w, places = 32, 256
+    slots, hidden, heads, head_dim, n, groups = MAMBA_CELLS[cell]
+    layer = Mamba2Mixer(hidden, heads, head_dim, n, groups, 4, dtype=jnp.bfloat16)
 
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
@@ -415,20 +430,34 @@ def test_a_one_token_row_reads_its_mamba_state_once_at_the_cells_size(one_chip):
     params = jax.tree.map(lambda x: shape(x.shape, x.dtype),
                           jax.eval_shape(layer.init, jax.random.PRNGKey(0)))
     text = jax.jit(tick, donate_argnums=(2, 3)).lower(
-        params, shape((places // w, w, 2688), jnp.bfloat16),
-        shape((slots, 64, 64, 128), jnp.float32),
+        params, shape((places // w, w, hidden), jnp.bfloat16),
+        shape((slots, heads, head_dim, n), jnp.float32),
         shape((slots, layer.conv_dim, 3), jnp.bfloat16),
         shape((slots,), jnp.int32), shape((slots,), jnp.int32),
     ).compile().as_text()
     entry = text[text.index("\nENTRY "):]
-    state = r"f32\[64,64,64,128\]"
-    readers = [line for line in entry.splitlines()
-               if re.search(r"fusion\([^)]*%ssm", line)]
+    line = rf"{heads},{head_dim},{n}"
+    state = rf"f32\[{slots},{line}\]"
+    readers = [op for op in entry.splitlines()
+               if re.search(r"fusion\([^)]*%ssm", op)]
     assert len(readers) == 1, readers      # the donated state has ONE reader
-    assert re.search(rf"= \(f32\[64,64,64\]\S*, {state}\S*\) fusion\(", readers[0])
+    assert re.search(
+        rf"= \(f32\[{slots},{heads},{head_dim}\]\S*, {state}\S*\) fusion\(",
+        readers[0])
     assert not whole_copies(text, state)
-    assert not re.search(r"\[64,32,\d", entry)     # nothing is rows x places wide
-    assert "f32[8,64,64,128]" in entry             # the gathered chunk rows
+    # the state is written by that fusion and by the chunk rows' scatter, and
+    # no loop carries it
+    writers = [op for op in entry.splitlines()
+               if re.search(rf"= \(?[^=]*{state}\S*\)? fusion\(", op)]
+    assert len(writers) == 2 and writers[0] == readers[0], writers
+    assert "ssm/scatter" in writers[1]
+    assert not re.search(rf"{state}[^=]* while\(", entry)
+    if n > 128:     # nor is it passed over once more as its 128-lane tiles
+        assert not re.search(rf"f32\[{slots},{heads},{head_dim},128\]", entry)
+    if heads != w:  # nothing is rows x places wide (at 32 heads the state's
+        # own head-major tensors begin as one would: Nemotron's case holds it)
+        assert not re.search(rf"\[{slots},{w},\d", entry)
+    assert f"f32[8,{line}]" in entry               # the gathered chunk rows
 
 
 ROUTED_CELLS = {

@@ -191,6 +191,35 @@ SPLIT_TICKS = {
 }
 
 
+def assert_a_tick_equals_whole_rows(layer, params, seed, new_len, ctx_len, w,
+                                    width, reference_width):
+    """A token-major tick of ``width`` places against the whole-rows form
+    (``reference_width`` None: the row-major caller; else token-major at that
+    full width) from seeded lines in which every slot a row starts over in
+    holds NaNs: each row's outputs, every state line and conv tail agree, and
+    a row that brings nothing keeps its lines bit for bit."""
+    slots = len(new_len)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    starts_over = [r for r in range(slots) if ctx_len[r] == 0 and new_len[r] > 0]
+    lines = tuple(
+        jax.random.normal(k, shape).at[jnp.asarray(starts_over, int)].set(jnp.nan)
+        for k, shape in ((ks[0], (slots, HEADS, P, layer.state_size)),
+                         (ks[1], (slots, layer.conv_dim, K - 1))))
+    u = jax.random.normal(ks[2], (slots, w, H))
+    u_rows = [u[r, :n] for r, n in enumerate(new_len)]
+    got = run_tick(layer, params, u_rows, lines, ctx_len, new_len, w, width)
+    want = run_tick(layer, params, u_rows, lines, ctx_len, new_len, w,
+                    reference_width)
+    for r, n in enumerate(new_len):
+        np.testing.assert_allclose(got[0][r], want[0][r], atol=ATOL, rtol=1e-5)
+        assert not n or np.isfinite(got[0][r]).all()
+        if not n:   # bit for bit what the slot held
+            assert np.array_equal(got[1][r], np.asarray(lines[0][r]), equal_nan=True)
+            assert np.array_equal(got[2][r], np.asarray(lines[1][r]), equal_nan=True)
+    np.testing.assert_allclose(got[1], want[1], atol=ATOL, rtol=1e-5)
+    np.testing.assert_allclose(got[2], want[2], atol=ATOL, rtol=1e-5)
+
+
 @pytest.mark.parametrize("reference", ["row-major", "token-major at the full width"])
 @pytest.mark.parametrize("case", list(SPLIT_TICKS))
 def test_each_row_in_its_own_form_equals_the_whole_rows_form(mixer, case, reference):
@@ -201,28 +230,45 @@ def test_each_row_in_its_own_form_equals_the_whole_rows_form(mixer, case, refere
     occupant's NaNs; an empty row's lines are not touched."""
     layer, params = mixer
     new_len, ctx_len = SPLIT_TICKS[case]
-    slots, w, (small, full) = len(new_len), SPLIT_W, SPLIT_WIDTHS
-    ks = jax.random.split(jax.random.PRNGKey(len(case)), 3)
-    starts_over = [r for r in range(slots) if ctx_len[r] == 0 and new_len[r] > 0]
-    lines = tuple(
-        jax.random.normal(k, shape).at[jnp.asarray(starts_over, int)].set(jnp.nan)
-        for k, shape in ((ks[0], (slots, HEADS, P, N)),
-                         (ks[1], (slots, HEADS * P + 2 * G * N, K - 1))))
-    u = jax.random.normal(ks[2], (slots, w, H))
-    u_rows = [u[r, :n] for r, n in enumerate(new_len)]
-    width = width_for(new_len, SPLIT_WIDTHS, w)
+    small, full = SPLIT_WIDTHS
+    width = width_for(new_len, SPLIT_WIDTHS, SPLIT_W)
     assert width == (full if case.startswith("R + 1") else small)
-    got = run_tick(layer, params, u_rows, lines, ctx_len, new_len, w, width)
-    want = run_tick(layer, params, u_rows, lines, ctx_len, new_len, w,
-                    None if reference == "row-major" else full)
-    for r, n in enumerate(new_len):
-        np.testing.assert_allclose(got[0][r], want[0][r], atol=ATOL, rtol=1e-5)
-        assert not n or np.isfinite(got[0][r]).all()
-        if not n:   # bit for bit what the slot held
-            assert np.array_equal(got[1][r], np.asarray(lines[0][r]), equal_nan=True)
-            assert np.array_equal(got[2][r], np.asarray(lines[1][r]), equal_nan=True)
-    np.testing.assert_allclose(got[1], want[1], atol=ATOL, rtol=1e-5)
-    np.testing.assert_allclose(got[2], want[2], atol=ATOL, rtol=1e-5)
+    assert_a_tick_equals_whole_rows(
+        layer, params, len(case), new_len, ctx_len, SPLIT_W, width,
+        None if reference == "row-major" else full)
+
+
+# 6 slots, rows of up to 4 tokens, a small program of 8 places: R = 2 places
+# for rows that bring a chunk. (new_len, ctx_len) a case
+GATHER_W, GATHER_WIDTH = 4, 8
+GATHER_TICKS = {
+    "no chunk row": ([1, 1, 0, 1, 1, 1], [3, 5, 0, 2, 7, 1]),
+    "exactly R chunk rows": ([3, 1, 0, 1, 2, 1], [4, 5, 0, 2, 7, 1]),
+    # the place no row fills reads past the pool and is clamped to the LAST
+    # slot, which here holds a real chunk row: nothing of it may be written
+    "a chunk row in the last slot beside a filler place": (
+        [1, 1, 1, 0, 1, 3], [3, 5, 2, 0, 7, 6]),
+    "a fresh chunk row": ([1, 0, 4, 1, 1, 0], [3, 0, 0, 2, 7, 0]),
+    "a row that brings nothing in the last slot": ([0, 1, 2, 0, 1, 0],
+                                                   [4, 5, 1, 0, 7, 9]),
+}
+
+
+@pytest.mark.parametrize("state_size", [128, 256])
+@pytest.mark.parametrize("case", list(GATHER_TICKS))
+def test_chunk_rows_lines_fetched_row_by_row_equal_whole_rows(case, state_size):
+    """``_split_rows`` fetches each chunk row's line by a read of its own
+    (ISSUE 53: on the chip a general gather over a state wider than the 128
+    lanes copies every slot's line first), at a state of one lane tile and of
+    two: outputs, state lines and conv tails are ``_chunk_rows``' over whole
+    rows, and a row that brings nothing keeps its lines bit for bit, also in
+    the last slot, onto which the places no row fills are clamped."""
+    layer = Mamba2Mixer(H, HEADS, P, state_size, G, K)
+    new_len, ctx_len = GATHER_TICKS[case]
+    assert sum(n > 1 for n in new_len) <= split_capacity(GATHER_WIDTH, GATHER_W)
+    assert_a_tick_equals_whole_rows(
+        layer, layer.init(jax.random.PRNGKey(0)), len(case), new_len, ctx_len,
+        GATHER_W, GATHER_WIDTH, None)
 
 
 def test_relu2_is_the_square_of_relu():

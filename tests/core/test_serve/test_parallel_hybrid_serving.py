@@ -363,6 +363,43 @@ def test_spans_counters_and_stats_count_both_kinds_of_lines(falcon, tmp_path):
     assert not any("moe_rows" in f or "conv_rows" in f for f in mixed)
 
 
+def test_a_short_mixed_run_advances_its_rows_in_the_forms_it_did(falcon, tmp_path):
+    """16 slots x chunk 32 build two programs, 128 and 512 places; six short
+    prompts and a long one arrive at once. Which form advanced which rows is
+    what it was before the chunk rows' lines were fetched row by row (ISSUE
+    53: the same rows gathered, the same tick at the full width), tick by
+    tick and in the counters, and every request gets the uncached forward's
+    tokens."""
+    requests = prompts((3, 4, 5, 6, 7, 8, 40), seed=5)
+    want = [falcon.generate(p, max_tokens=6, use_cache=False).completion_ids
+            for p in requests]
+    engine = engine_of(falcon, num_slots=16, prefill_chunk=32, token_budget=128,
+                       max_blocks_per_seq=16, num_blocks=16 * 16 + 1)
+    assert engine.config.mixed_widths == (128, 512)
+    obs.start_capture(str(tmp_path))
+    try:
+        got = served(engine, requests, 6)
+    finally:
+        capture = obs.stop_capture()
+    assert [got[i] for i in range(len(requests))] == want
+    mixed = [f for n, _, _, f in capture.spans if n == "serve.mixed"]
+    assert [(f["width"], f["tokens"], f["ssm_step_rows"], f["ssm_chunk_rows"])
+            for f in mixed] == [
+        (512, 65, 0, 7), (128, 14, 6, 1), (128, 7, 7, 0), (128, 7, 7, 0),
+        (128, 7, 7, 0), (128, 7, 7, 0), (128, 1, 1, 0)]
+    assert {path: capture.counters[f"serve_ssm_rows_total{{path={path}}}"]
+            for path in ("step", "chunk", "whole")} == {
+        "whole": LAYERS * 7, "chunk": LAYERS * 1, "step": LAYERS * 35}
+    assert capture.counters["serve_ssm_state_updates_total"] == LAYERS * 43
+    # both programs ran under the capture, each under a name of its own: the
+    # benchmark's readers find an operation's scope by its module's name and
+    # its own (under ONE name a full-width tick in a traced slice made them
+    # read the small program's operations in the wide program's table)
+    from benchmark import xplane_hlo
+    modules = xplane_hlo.hlo_modules(capture.trace_file().read_bytes())
+    assert {"jit_mixed_128", "jit_mixed_512"} <= set(modules)
+
+
 def test_the_two_mixers_the_mlp_and_the_head_lie_in_scopes_of_their_own(falcon):
     """``attn``, ``ssm``, ``mlp`` and ``head`` name the instructions compiled
     from inside each: what the benchmark's readers look up in a trace's HLO.
