@@ -22,7 +22,7 @@ from ....nn import (
     xavier_normal_init,
 )
 from ....nn.base_layer import multiplied
-from ....parallel.sharding import constrain
+from ....parallel.sharding import constrain, shard_logits, vocab_shards
 from ....topology.topology import MODEL_AXIS
 from ..config import EmbeddingHeadConfig, TransformerArchitectureConfig
 
@@ -124,7 +124,20 @@ class TransformerLMHead(BaseLayer):
     and logits carry the tunable output_mult; the width correction is the
     readout's 1/m learning-rate scale, NOT a logit multiplier — applying
     both (the two equivalent muP output formulations) over-suppresses
-    updates by an extra 1/m, which the coordinate check catches."""
+    updates by an extra 1/m, which the coordinate check catches.
+
+    Under tensor parallelism the logits LEAVE the head as the matmul makes
+    them, ``(data, seq, model)``: each model rank holds its ``vocab / mp``
+    columns of one global ``(b, s, vocab)`` array (the reference gathers
+    them here, lm_head.py:60-66; so did this head until PR 54, and every
+    rank then ran the whole head from a gathered weight). Under pipeline
+    stages the weight's vocabulary lies over ``(pipe, model)`` and so do
+    the logits: every device of a data rank takes ``vocab / (pp * mp)``
+    columns of head and loss, where each used to gather the whole weight.
+    The loss (ops/cross_entropy.py) and the accuracy's argmax reduce over
+    those axes one number a position; a consumer that needs whole rows
+    (sampling, a caller's ``np.asarray``) has XLA gather them there. A
+    sharding is a layout: values are the same at every ``mp``."""
 
     READOUT_LOGIT_STD = 0.5
 
@@ -165,9 +178,20 @@ class TransformerLMHead(BaseLayer):
     def param_metas(self) -> dict:
         return {"linear": tree_prefix(self.linear.param_metas(), "linear")}
 
+    def vocab_shards(self, mesh) -> int:
+        """Over how many devices the logits' vocabulary is split when they
+        reach the loss (``ParallelModule.loss_vocab_shards``)."""
+        return vocab_shards(mesh)
+
     def __call__(self, params: dict, x: dict, ctx: ForwardContext) -> dict:
         out = dict(x)
-        logits = self.linear(params["linear"], x["activations"], ctx)
+        # ``self.linear``'s own matmul (it makes the weight and its metas),
+        # without the layout its call would pin: the reference's gather, or
+        # (data, seq, model) where stages put the vocabulary over (pipe, model)
+        h = x["activations"]
+        logits = h @ params["linear"]["weight"].astype(h.dtype)
+        if logits.ndim == 3:
+            logits = shard_logits(logits, ctx.mesh)
         if self.logit_mult is not None:
             logits = logits * jnp.asarray(self.logit_mult, logits.dtype)
         out["activations"] = multiplied(logits, self.multiplier)

@@ -124,7 +124,12 @@ def per_token_loss(logits, targets):
     (ops/cross_entropy.py): same fp32 forward math, but no fp32
     ``(b, s, vocab)`` log-softmax residual held to the backward — ~2 GB
     less live memory at the bench shape, measured via compiled buffer
-    assignment."""
+    assignment.
+
+    Under tensor parallelism ``logits`` arrive ``(data, seq, model)`` from
+    ``TransformerLMHead`` and stay so: the loss indexes nothing along the
+    vocabulary, and the argmax is a reduction GSPMD splits into a shard's
+    own (maximum, column) and a pick among the ``mp`` of them."""
     from ...ops.cross_entropy import cross_entropy_from_logits
 
     targets = targets.astype(jnp.int32)

@@ -14,6 +14,24 @@ the logits' own dtype (bf16 in mixed precision), halving the backward
 buffer too. Forward math is identical (logsumexp - target logit == the
 gathered log-softmax), in fp32 either way.
 
+Under tensor parallelism the logits arrive sharded over the vocabulary
+(``(data, seq, model)``, what ``TransformerLMHead`` leaves them in), and
+nothing here gathers it. The target's logit is selected by comparing an
+iota over the columns with the target, never by indexing along the
+vocabulary (a ``take_along_axis`` there is a gather GSPMD answers by
+replicating the whole row): each shard sums the one column it may own and
+zeros. What crosses the model axis is one number a position: all-reduces
+of ``f32[b, s]`` for the row's sum of exponentials and for the target's
+logit, and the row maximum (with its column, where the accuracy's argmax
+beside this loss shares the pass; GSPMD writes them). The backward is
+elementwise on the shard plus the saved ``(b, s)`` logsumexp, and its
+cotangent keeps the logits' layout, because the head's sharding constraint
+transposes to itself. One device runs the same code: the sum adds zeros to
+the target's logit, so the value is the gathered one bit for bit, and the
+gather had its price there too: it read a float32 copy of the logits that
+the head's matmul then wrote beside the bf16 one (1.07 GB at Mistral-7B's
+2 x 4096 x 32768, PERF.md, PR 54).
+
 (reference analogue: model.py:43-76 computes plain torch cross entropy;
 the memory shape of torch autograd is the same residual problem.)
 """
@@ -31,12 +49,17 @@ def cross_entropy_from_logits(logits: jax.Array, targets: jax.Array) -> jax.Arra
     return loss
 
 
+def _is_target(x, targets):
+    """``(..., vocab)`` mask of each position's target column; a target
+    outside the vocabulary selects nothing."""
+    columns = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    return columns == targets.astype(jnp.int32)[..., None]
+
+
 def _compute(logits, targets):
     x = logits.astype(jnp.float32)
     lse = jax.scipy.special.logsumexp(x, axis=-1)
-    target_logit = jnp.take_along_axis(
-        x, targets.astype(jnp.int32)[..., None], axis=-1
-    )[..., 0]
+    target_logit = jnp.where(_is_target(x, targets), x, 0.0).sum(axis=-1)
     return lse - target_logit, lse
 
 
@@ -52,7 +75,7 @@ def _bwd(res, g):
     logits, targets, lse = res
     x = logits.astype(jnp.float32)
     p = jnp.exp(x - lse[..., None])
-    onehot = jax.nn.one_hot(targets, logits.shape[-1], dtype=jnp.float32)
+    onehot = _is_target(x, targets).astype(jnp.float32)
     dlogits = (p - onehot) * g.astype(jnp.float32)[..., None]
     # cotangent in the primal dtype: bf16 logits get a bf16 gradient
     # buffer (autodiff of the fp32-upcast path would carry fp32 here and

@@ -36,7 +36,9 @@ from ..nn.base_layer import (
     PipelineBodySpec,
     TiedLayerSpec,
 )
+from ..logging import logger
 from ..nn.param import ParamMeta, named_parameters, tree_with_layer
+from ..obs.registry import get_registry
 from ..topology import ActivationCheckpointingType, Topology
 from ..topology.topology import MODEL_AXIS, PIPE_AXIS
 
@@ -414,6 +416,15 @@ class ParallelModule:
             mesh=topo.mesh if topo else None,
         )
 
+    def loss_vocab_shards(self) -> int:
+        """Over how many devices the vocabulary of the logits is split
+        between the head and the loss: what the last layer that declares
+        ``vocab_shards`` (``TransformerLMHead``) says of this mesh, 1 (whole
+        rows on every device) where none does."""
+        mesh = self.topology.mesh if self.topology else None
+        declaring = [l for l in self.layers if hasattr(l, "vocab_shards")]
+        return declaring[-1].vocab_shards(mesh) if declaring else 1
+
     # ------------------------------------------------------- train step
     def build_train_step(
         self,
@@ -431,6 +442,12 @@ class ParallelModule:
         if self.forward_refusal is not None:
             raise NotImplementedError(self.forward_refusal)
         gas = self.topology.gradient_accumulation_steps if self.topology else 1
+        # a property of the program being built, read here and never in the
+        # step: 1 = the loss sees whole rows, mp (pp * mp under stages) = it
+        # runs on that many shards of the vocabulary
+        shards = self.loss_vocab_shards()
+        get_registry().gauge("train_loss_vocab_shards").set(shards)
+        logger.info(f"train step: the loss runs over {shards} vocabulary shard(s)")
 
         scaler_enabled = optimizer.config.loss_scaler.enable
 

@@ -11,6 +11,7 @@ the named axis is active, so the same layer code runs on a single device.
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -78,6 +79,30 @@ def shard_activation_tp(x: jax.Array, mesh: Optional[Mesh]) -> jax.Array:
     if not _axis_in_mesh(mesh, MODEL_AXIS):
         return x
     return constrain(x, mesh, DATA_AXIS, _seq_axis(mesh), MODEL_AXIS)
+
+
+def _vocab_axes(mesh: Optional[Mesh]) -> tuple:
+    """The mesh axes an untied head's vocabulary is split over: the model
+    axis and, where stages exist, the pipe axis before it (``ParallelModule``
+    lifts an edge layer's model-parallel dim over ``(pipe, model)``)."""
+    if not _axis_in_mesh(mesh, MODEL_AXIS):
+        return ()
+    staged = _axis_in_mesh(mesh, PIPE_AXIS) and mesh.shape[PIPE_AXIS] > 1
+    return (PIPE_AXIS, MODEL_AXIS) if staged else (MODEL_AXIS,)
+
+
+def vocab_shards(mesh: Optional[Mesh]) -> int:
+    """Over how many devices :func:`shard_logits` splits the vocabulary."""
+    return math.prod(mesh.shape[axis] for axis in _vocab_axes(mesh))
+
+
+def shard_logits(x: jax.Array, mesh: Optional[Mesh]) -> jax.Array:
+    """(b, s, vocab) logits as the head's column-parallel matmul makes them:
+    the vocabulary sharded as the head's weight has it, never gathered."""
+    axes = _vocab_axes(mesh)
+    if not axes:
+        return x
+    return constrain(x, mesh, DATA_AXIS, _seq_axis(mesh), axes)
 
 
 def shard_activation_replicated_h(x: jax.Array, mesh: Optional[Mesh]) -> jax.Array:

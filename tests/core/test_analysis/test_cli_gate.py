@@ -220,7 +220,21 @@ def test_audit_gate_serve_decode_matches_golden(tmp_path):
     counts = {(r["op"], r["count"]) for r in mp2["collectives"]}
     assert counts == {(r["op"], r["count"])
                       for r in mp2["full_width"]["collectives"]}
-    assert counts == {("all-gather", 1), ("all-reduce", 5)}
+    # since PR 54 the head's logits stay sharded over the vocabulary: the
+    # ONE all-gather the tick paid until then was the head's whole WEIGHT
+    # (hidden x vocab, every tick: f32[128,512] here, 268 MB at Mistral-7B's
+    # widths). In its place the greedy pick gathers a (maximum, column) pair
+    # a shard of each of the 32 sampled positions (2 gathers of [32,2]), and
+    # the branch that runs only when a row samples sorts and masks its 32
+    # rows of logits across the two shards (2 all-to-alls, the cumulative
+    # sum's gather of [32,512], a permute, 5 all-reduces of [32]): rows x
+    # vocab at most, never hidden x vocab. The 5 all-reduces of the layers'
+    # activations are as before.
+    assert counts == {("all-gather", 5), ("all-reduce", 10),
+                      ("all-to-all", 2), ("collective-permute", 1)}
+    gathered = sum(r["bytes"] for r in mp2["collectives"]
+                   if r["op"] == "all-gather")
+    assert gathered < 128 * 512 * 4  # all five: a quarter of that weight
 
 
 def test_audit_gate_detects_seeded_drift(tmp_path):

@@ -520,3 +520,94 @@ def test_a_served_routed_layer_is_grouped_matmuls_under_the_moe_scope(
     for dims in (f"{held},{h},{f}", f"{held},{f},{h}"):
         copies = whole_copies(text, rf"bf16\[{dims}\]")
         assert not copies, f"{len(copies)} copies of a whole expert leaf"
+
+
+def test_pharia_train_step_never_holds_the_whole_vocabulary(topo, monkeypatch):
+    """``train-pharia7b-4chip``'s own step (the benchmark's configuration and
+    traffic files, TP=2 x DP=2 + ZeRO-1 + SP) at depth 1, compiled for the
+    described 2x2 over abstract weights and optimizer state (ISSUE 54): under
+    TP the logits stay ``(data, seq, model)`` from the head's matmul through
+    the loss and its backward. Until PR 54 the head replicated them over the
+    model axis, and XLA answered by gathering the head's WEIGHT
+    (``all-gather bf16[4608,128000]``), running head and loss over all 128,000
+    columns on both ranks of a TP pair and all-reducing a full-vocabulary
+    gradient: ``temp_size_in_bytes`` 6.47e9 where this reads 3.3e9 (with the
+    XLA attention both; the splash kernel is compiled here, as the chip runs
+    it). A later PR that gathers the vocabulary again fails here."""
+    import json
+    from pathlib import Path
+
+    from benchmark import model
+    from scaling_tpu.models.transformer.model import (
+        init_model, init_optimizer, loss_function,
+    )
+    from scaling_tpu.nn import ParamMeta
+    from scaling_tpu.obs import get_registry
+    from scaling_tpu.topology import Topology
+
+    monkeypatch.setattr(
+        "scaling_tpu.ops.flash_attention.flash_attention_supported",
+        lambda seq_len, head_dim, platform=None: True)
+    files = Path(model.__file__).parent
+    config = model.transformer_config(
+        json.loads((files / "configs" / "pharia-1-7b.json").read_text()),
+        json.loads((files / "traffic" / "pretrain-4k.json").read_text()),
+        num_layers=1)
+    arch, layout = config.transformer_architecture, config.topology
+    vocab, hidden, seq = arch.vocab_size, arch.hidden_size, arch.sequence_length
+    assert (layout.model_parallel_size, layout.data_parallel_size) == (2, 2)
+    assert (vocab, hidden, seq) == (128000, 4608, 4096)
+    topology = Topology(layout, devices=topo.devices[:4])
+    mesh = topology.mesh
+    module = init_model(config, topology)
+    optimizer = init_optimizer(config, module, topology)
+    replicated = NamedSharding(mesh, P())
+
+    def placed(shape, sharding=None):
+        return jax.ShapeDtypeStruct(shape.shape, shape.dtype,
+                                    sharding=sharding or replicated)
+
+    params = jax.tree.map(
+        lambda s, m: placed(s, NamedSharding(mesh, P(*m.partition_spec))),
+        jax.eval_shape(module.init_params, jax.random.PRNGKey(0)),
+        module.param_metas(), is_leaf=lambda x: isinstance(x, ParamMeta))
+    opt_state = jax.tree.map(
+        lambda s: placed(s, getattr(s, "sharding", None)),
+        optimizer.abstract_state(params))
+    rows = layout.micro_batch_size * layout.data_parallel_size
+    by_row = NamedSharding(mesh, P(None, DATA_AXIS, None))
+    ids = jax.ShapeDtypeStruct((1, rows, seq), jnp.int32, sharding=by_row)
+    batch = {"token_ids": ids, "target_token_ids": ids, "position_ids": ids,
+             "segment_ids": ids,
+             "loss_weights": jax.ShapeDtypeStruct(ids.shape, jnp.float32,
+                                                  sharding=by_row)}
+    step = module.build_train_step(optimizer, loss_function)
+    assert get_registry().gauge("train_loss_vocab_shards").value == 2
+    compiled = step.lower(
+        params, opt_state, batch,
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=replicated)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3  # splash: forward, dq, dkv
+
+    def with_dim(size):
+        return [line.strip()[:200] for line in text.splitlines()
+                if re.search(rf"\[(\d+,)*{size}(,\d+)*\]", line)]
+
+    # no array as wide as the vocabulary, whatever operation makes it
+    assert not with_dim(vocab), with_dim(vocab)[:3]
+    shard = vocab // 2
+    collectives = [line for line in with_dim(shard)
+                   if re.search(r" (all-gather|all-reduce|reduce-scatter)"
+                                r"(-start)?\(", line)]
+    # the head's gradient crosses chips as its own shard, over the data pairs
+    # (devices 0,2 and 1,3 of the (data, model) mesh) ...
+    grads = [line for line in collectives if " all-reduce" in line
+             and f"bf16[{hidden},{shard}]" in line]
+    assert grads and all("replica_groups={{0,2},{1,3}}" in line for line in grads)
+    # ... and no gather over the MODEL pairs yields anything of a shard's
+    # width: what is left are ZeRO-1's gathers of the updated shards over data
+    over_model = [line for line in collectives if " all-gather" in line
+                  and "replica_groups=[2,2]<=[2,2]T(1,0)" not in line
+                  and "replica_groups={{0,2},{1,3}}" not in line]
+    assert not over_model, over_model[:3]
+    assert compiled.memory_analysis().temp_size_in_bytes < 4.0e9

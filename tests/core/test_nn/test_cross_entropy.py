@@ -65,3 +65,90 @@ def test_backward_drops_the_fp32_residual():
     saved = temp_bytes(ref_loss) - temp_bytes(cross_entropy_from_logits)
     residual = b * s * v * 4  # the fp32 log-probabilities
     assert saved >= residual, (saved, residual)
+
+
+# -- the vocabulary sharded over the model axis (ISSUE 54) -----------------
+VOCAB = 1000  # two shards of 500; no other dimension below is 1000 or 500
+
+
+def sharded_case(devices, dtype):
+    """Logits ``(data, None, model)`` on a 2 x 2 mesh with the targets a
+    shard's ends can get wrong: the first and last column of each half."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from scaling_tpu.topology.topology import DATA_AXIS, MODEL_AXIS
+
+    mesh = Mesh(np.asarray(devices[:4]).reshape(2, 2), (DATA_AXIS, MODEL_AXIS))
+    rng = np.random.default_rng(2)
+    logits = jnp.asarray(rng.normal(size=(4, 6, VOCAB)) * 3, dtype)
+    targets = rng.integers(0, VOCAB, size=(4, 6))
+    targets[:, :4] = [0, VOCAB // 2 - 1, VOCAB // 2, VOCAB - 1]
+    targets = jnp.asarray(targets, jnp.int32)
+    weights = rng.uniform(0.1, 1.0, size=(4, 6))
+    weights[1] = 0.0  # a row masked whole
+    weights = jnp.asarray(weights, jnp.float32)
+    placed = (
+        jax.device_put(logits, NamedSharding(mesh, P(DATA_AXIS, None, MODEL_AXIS))),
+        jax.device_put(targets, NamedSharding(mesh, P(DATA_AXIS, None))),
+        jax.device_put(weights, NamedSharding(mesh, P(DATA_AXIS, None))),
+    )
+    return (logits, targets, weights), placed
+
+
+def weighted(loss_fn):
+    def f(logits, targets, weights):
+        loss = (loss_fn(logits, targets) * weights).sum() / weights.sum()
+        correct = (logits.argmax(-1) == targets).astype(jnp.float32)
+        return loss, (correct * weights).sum() / weights.sum()
+
+    return jax.jit(jax.value_and_grad(f, has_aux=True))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_sharded_vocabulary_gives_the_one_device_loss_and_gradient(devices, dtype):
+    """Loss, accuracy and cotangent over vocabulary shards equal the whole
+    rows' (and the gather-and-log_softmax reference's): targets on both ends
+    of each shard, one row of zero weights."""
+    whole, placed = sharded_case(devices, dtype)
+    (loss_s, acc_s), grad_s = weighted(cross_entropy_from_logits)(*placed)
+    (loss_1, acc_1), grad_1 = weighted(cross_entropy_from_logits)(*whole)
+    (loss_r, _), grad_r = weighted(ref_loss)(*whole)
+    assert grad_s.dtype == dtype and grad_s.sharding == placed[0].sharding
+    np.testing.assert_allclose(float(loss_s), float(loss_1), rtol=1e-6)
+    np.testing.assert_allclose(float(loss_s), float(loss_r), rtol=1e-6)
+    assert float(acc_s) == float(acc_1)
+    for other in (grad_1, grad_r):
+        np.testing.assert_allclose(
+            np.asarray(grad_s, np.float32), np.asarray(other, np.float32),
+            rtol=1e-5, atol=1e-6)
+    assert not np.asarray(grad_s, np.float32)[1].any()  # the masked row
+
+
+def test_sharded_vocabulary_is_never_gathered(devices):
+    """The compiled loss + backward over ``(data, None, model)`` logits holds
+    no array as wide as the vocabulary: what crosses the model axis is one
+    number a position."""
+    import re
+
+    _, placed = sharded_case(devices, jnp.bfloat16)
+    text = weighted(cross_entropy_from_logits).lower(*placed).compile().as_text()
+    assert re.search(rf"\[[0-9,]*\b{VOCAB // 2}\]", text)  # the shard is there
+    wide = [line for line in text.splitlines()
+            if re.search(rf"\[[0-9,]*\b{VOCAB}\b[0-9,]*\]", line)]
+    assert not wide, wide[:3]
+
+
+@pytest.mark.parametrize("target", [-1, VOCAB, VOCAB + 7])
+def test_a_target_outside_the_vocabulary_selects_no_column(target):
+    """No column compares equal: the loss is the row's logsumexp and the
+    cotangent the plain softmax (``one_hot`` gave the same zeros)."""
+    rng = np.random.default_rng(3)
+    logits = jnp.asarray(rng.normal(size=(1, 2, VOCAB)), jnp.float32)
+    targets = jnp.full((1, 2), target, jnp.int32)
+    loss, grad = jax.value_and_grad(
+        lambda lg: cross_entropy_from_logits(lg, targets).sum())(logits)
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    np.testing.assert_allclose(float(loss), float(lse.sum()), rtol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(grad), np.asarray(jax.nn.softmax(logits, axis=-1)),
+        rtol=1e-5, atol=1e-7)
