@@ -23,6 +23,7 @@ from ...nn.activation_function import ActivationFunction
 from ...nn.lora import LoRaConfig
 from ...nn.masked_softmax import MaskedSoftmaxConfig
 from ...nn.norm import LayerNormConfig, NormType
+from ...nn.rotary import RopeScalingConfig
 from ...optimizer import LearningRateSchedulerConfig, OptimizerConfig
 from ...topology import TopologyConfig
 from ...trainer import TrainerConfig
@@ -61,6 +62,9 @@ class LayerKind(Enum):
     ATTENTION = "attention"  # softmax attention
     CONV = "conv"            # gated short convolution (nn/short_conv.py)
     MLP = "mlp"              # dense MLP of mlp_type / mlp_factor (nn/mlp.py)
+    # multi-head latent attention (nn/latent_attention.py): low-rank q and kv
+    # projections, ONE latent line a token in the paged pool
+    LATENT = "latent"
 
 
 class MoERouter(Enum):
@@ -219,6 +223,11 @@ class FixedMultipliers(BaseConfig):
         return self == FixedMultipliers()
 
 
+# what sizes a 'latent' layer (nn/latent_attention.py)
+LATENT_FIELDS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                 "qk_rope_head_dim", "v_head_dim")
+
+
 class TransformerArchitectureConfig(BaseConfig):
     """Model shape + feature switches
     (reference: src/scaling/transformer/context/config.py:126-330)."""
@@ -326,6 +335,29 @@ class TransformerArchitectureConfig(BaseConfig):
         None, description="experts held from moe_experts_first on; absent: "
         "all of them", gt=0,
     )
+    moe_n_group: int = Field(
+        1, description="groups the router's experts are divided into "
+        "(n_group); only 1 is built: no group-limited choice", ge=1)
+    moe_topk_group: int = Field(
+        1, description="groups a token may choose its experts from "
+        "(topk_group); only 1 is built", ge=1)
+    q_lora_rank: Optional[int] = Field(
+        None, description="a 'latent' layer: width of the query latent "
+        "(x W_DQ, RMSNorm'd, then up to the heads' nope + rope queries)", gt=0)
+    kv_lora_rank: Optional[int] = Field(
+        None, description="a 'latent' layer: width of the KV latent c_kv, "
+        "what a token leaves in the cache beside its ONE rotary key", gt=0)
+    qk_nope_head_dim: Optional[int] = Field(
+        None, description="a 'latent' head's query/key part without "
+        "position", gt=0)
+    qk_rope_head_dim: Optional[int] = Field(
+        None, description="a 'latent' head's rotary query part, and the ONE "
+        "rotary key all heads share", gt=0)
+    v_head_dim: Optional[int] = Field(
+        None, description="a 'latent' head's value size", gt=0)
+    rope_scaling: Optional[RopeScalingConfig] = Field(
+        None, description="the checkpoint's rope_scaling (YaRN alone is "
+        "built, nn/rotary.py); applied by 'latent' layers")
     layer_pattern: Optional[List[LayerKind]] = Field(
         None,
         description="a kind a layer: each layer is ONE norm, ONE mixer of its "
@@ -521,6 +553,16 @@ class TransformerArchitectureConfig(BaseConfig):
             )
         if self.layer_pattern is not None:
             self._validate_pattern()
+        elif self.rope_scaling is not None:
+            raise ValueError(
+                "rope_scaling without layer_pattern: only a pattern stack's "
+                "'latent' layers apply YaRN")
+        if self.moe_n_group > 1 or self.moe_topk_group > 1:
+            raise ValueError(
+                f"moe_n_group {self.moe_n_group} / moe_topk_group "
+                f"{self.moe_topk_group}: the group-limited choice (top "
+                "experts from the topk_group best of n_group groups) is not "
+                "built; the router chooses among all experts (1 / 1)")
         if self.mlp_type == MLPType.MOE:
             if self.moe_top_k > self.moe_num_experts:
                 raise ValueError(
@@ -640,6 +682,36 @@ class TransformerArchitectureConfig(BaseConfig):
                 "'mlp' layer is built from mlp_type 'swiglu' or 'default' and "
                 "mlp_factor; the routed layers are the pattern's 'moe'"
             )
+        if LayerKind.LATENT in self.layer_pattern:
+            missing = [name for name in LATENT_FIELDS
+                       if getattr(self, name) is None]
+            if missing:
+                raise ValueError(
+                    f"layer_pattern with 'latent' layers needs {missing}: a "
+                    "latent attention layer is sized by q_lora_rank, "
+                    "kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim and "
+                    "v_head_dim")
+            if self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    f"qk_rope_head_dim {self.qk_rope_head_dim} is odd: rotary "
+                    "turns pairs of lanes")
+            if (self.relative_position_embedding_type
+                    != RelativePositionEmbeddingType.ROTARY):
+                raise ValueError(
+                    "layer_pattern with 'latent' layers and "
+                    "relative_position_embedding_type "
+                    f"{self.relative_position_embedding_type.value!r}: a "
+                    "latent head's position is its rotary slice; use 'rotary'")
+        elif self.rope_scaling is not None:
+            raise ValueError(
+                "rope_scaling without 'latent' layers: only the latent "
+                "attention mixer applies YaRN; the other attention mixers' "
+                "rotary tables take the base frequencies")
+
+    @property
+    def latent_layers(self) -> int:
+        """Layers whose mixer is latent attention."""
+        return (self.layer_pattern or []).count(LayerKind.LATENT)
 
     @property
     def moe_held(self) -> int:

@@ -32,6 +32,7 @@ from ....nn import (
 from ....nn.attention import PagedKVCacheView
 from ....nn.base_layer import multiplied
 from ....nn.rotary import RotaryConfig
+from ....nn.latent_attention import LatentSelfAttention
 from ....nn.mamba import Mamba2Mixer
 from ....nn.short_conv import GatedShortConv
 from ..config import (
@@ -162,6 +163,25 @@ class MixerLayer(BaseLayer):
             self.mixer = GatedShortConv(arch.hidden_size, arch.conv_kernel, dtype)
         elif self.kind == LayerKind.MLP:
             self.mixer = dense_mlp(arch)
+        elif self.kind == LayerKind.LATENT:
+            self.mixer = LatentSelfAttention(
+                hidden_size=arch.hidden_size,
+                num_attention_heads=arch.num_attention_heads,
+                q_lora_rank=arch.q_lora_rank,
+                kv_lora_rank=arch.kv_lora_rank,
+                qk_nope_head_dim=arch.qk_nope_head_dim,
+                qk_rope_head_dim=arch.qk_rope_head_dim,
+                v_head_dim=arch.v_head_dim,
+                rotary_config=RotaryConfig(
+                    dimensions=arch.qk_rope_head_dim,
+                    base=arch.rotary_embedding_base,
+                    max_seq_length=arch.sequence_length,
+                    scaling=arch.rope_scaling,
+                ),
+                layernorm_config=arch.layernorm,
+                masked_softmax_config=arch.masked_softmax,
+                dtype=dtype,
+            )
         else:
             rotary_config = None
             head_dim = (arch.attention_head_dim
@@ -301,6 +321,16 @@ class MixerLayer(BaseLayer):
         elif self.kind == LayerKind.MLP:
             with jax.named_scope("mlp"):
                 y = self.mixer(params["mixer"], normed, ctx)
+        elif self.kind == LayerKind.LATENT:
+            with jax.named_scope("attn"):
+                y = self.mixer(
+                    params["mixer"], normed, ctx,
+                    segment_ids=x["segment_ids"],
+                    position_ids=x["position_ids"],
+                    kv_cache=kv_cache, return_kv=return_kv,
+                )
+            if return_kv or kv_cache is not None:
+                y, state = y
         else:
             y = self.mixer(
                 params["mixer"], normed, ctx,

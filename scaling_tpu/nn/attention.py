@@ -112,7 +112,12 @@ class PagedKVCacheView(NamedTuple):
 
     ``pool_k``/``pool_v`` are ``(num_blocks, block_size, n_kv, h)``;
     float (dense) or int8 with per-slot-per-head ``scale_k``/``scale_v``
-    of shape ``(num_blocks, block_size, n_kv)`` (quantized KV).
+    of shape ``(num_blocks, block_size, n_kv)`` (quantized KV). A latent
+    attention layer's line has no head axis and two leaves of unequal width:
+    ``pool_k`` ``(num_blocks, block_size, kv_lora_rank)``, the normed KV
+    latent, and ``pool_v`` ``(num_blocks, block_size, rope_line_width)``, the
+    one rotary key (nn/latent_attention.py says which leaf holds what); the
+    addressing state below is the same.
 
     ``new_len`` (per row, optional) is how many tokens the row REALLY
     brings: a prefill CHUNK shorter than its fixed program shape routes

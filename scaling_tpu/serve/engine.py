@@ -61,6 +61,7 @@ from typing import Dict, List, NamedTuple, Optional, Tuple
 from .. import obs
 from ..logging import logger
 from ..nn.base_layer import state_views
+from ..nn.latent_paged_attention import latent_tile_tokens
 from ..nn.mamba import RecurrentStateView, split_capacity
 from ..nn.paged_attention import kernel_tile_tokens
 from ..resilience.faults import get_fault_plan
@@ -324,13 +325,26 @@ class ServeEngine:
 
         self._np = np
         self._jax = jax
+        # latent attention layers (nn/latent_attention.py): their lines are
+        # paged like K and V, written once a token and never rewound, but the
+        # kernel's rows are a decode token or a prompt chunk, not drafts
+        self.latent_layers = inference_module.architecture.latent_layers
+        if self.latent_layers and self.config.spec_k > 0:
+            raise ValueError(
+                "spec_k > 0 with latent attention layers: the latent kernel "
+                "folds a row of one token or a prompt chunk, and a decode row "
+                "with drafts has not been held to the reference; set spec_k=0")
         # KV tokens a tile of the paged kernel holds, at a shard's heads
-        _, _, n_kv, head = self.pools.pool_k[0].shape
-        self._kv_tile = kernel_tile_tokens(
-            self.config.block_size, self.config.max_blocks_per_seq,
-            n_kv // self.model_parallel, head,
-            self.pools.pool_k[0].dtype.itemsize,
-        )
+        if self.pools.pool_k[0].ndim == 3:   # a line without a head axis
+            self._kv_tile = latent_tile_tokens(
+                self.config.block_size, self.config.max_blocks_per_seq)
+        else:
+            _, _, n_kv, head = self.pools.pool_k[0].shape
+            self._kv_tile = kernel_tile_tokens(
+                self.config.block_size, self.config.max_blocks_per_seq,
+                n_kv // self.model_parallel, head,
+                self.pools.pool_k[0].dtype.itemsize,
+            )
         n = self.config.num_slots
         # the ONE host operand of a tick and where its fields lie
         self._layout = TickLayout(n, self.config.max_blocks_per_seq)
@@ -401,6 +415,9 @@ class ServeEngine:
             {"replica": self.replica_id}
             if self.replica_id is not None else {}
         )
+        if self.latent_layers:
+            # bytes a cached token takes in the pools, all layers
+            self._gauge("serve_kv_line_bytes").set(self.pools.line_bytes)
         self._prefix_hits_flushed = 0  # scheduler counter already mirrored
         # the scheduler's eviction totals, likewise: seconds, blocks, and
         # the stale heap entries the cache skipped
@@ -924,6 +941,18 @@ class ServeEngine:
                     for path, count in paths.items():
                         self._counter("serve_ssm_rows_total", path=path).inc(
                             count * self.ssm_lines)
+                if self.latent_layers:
+                    # what a latent layer's attention reads and multiplies
+                    # this tick: the lines of its rows (context + new), and
+                    # the (query, visible line) pairs
+                    n_new = new_lens.astype(np.int64)
+                    lines = int(held.sum())
+                    mixed_span.annotate(
+                        latent_layers=self.latent_layers, latent_lines=lines,
+                        latent_pairs=int(
+                            (n_new * ctx + n_new * (n_new + 1) // 2).sum()))
+                    self._counter("serve_latent_lines_read_total").inc(
+                        lines * self.latent_layers)
                 if self.par_lines:
                     mixed_span.annotate(par_lines=self.par_lines)
                     self._counter("serve_parallel_mixer_passes_total").inc(
@@ -1357,6 +1386,10 @@ class ServeEngine:
             # steps x layers) and the bytes the pools really hold
             "kv_lines": self.pools.kv_lines,
             "kv_pool_bytes": self.pools.device_bytes(),
+            # bytes a cached token takes in the pools, all layers (a latent
+            # layer: its KV latent and its rotary key's lane row)
+            "kv_line_bytes": self.pools.line_bytes,
+            "latent_layers": self.latent_layers,
             # layers that keep a line a slot (Mamba-2 mixers' recurrent state,
             # short convolutions' tails; 0: a model without them) and the
             # bytes of those lines

@@ -1,11 +1,15 @@
 """Block-paged KV cache pools (PagedAttention, SOSP '23).
 
-One device-resident pool per transformer layer: ``(num_blocks,
-block_size, n_kv, h)`` for keys and values, carved into fixed-size
+One device-resident pool per transformer layer: two leaves of
+``(num_blocks, block_size, ...)``, carved into fixed-size
 blocks that sequences of wildly different lengths share through
 per-sequence block tables (replacing the per-request fixed-capacity
 ``_alloc_caches`` buffers, whose dense ``(b, prompt+max_tokens)`` shape
-charged every row the longest row's memory).
+charged every row the longest row's memory). What the two leaves hold past
+the block is the layer's mixer's to say: softmax attention's are keys and
+values, ``(n_kv, h)`` each; a latent attention layer's have no head axis and
+differ in width, the normed KV latent ``(kv_lora_rank,)`` and the one rotary
+key ``(rope_line_width,)`` (nn/latent_attention.py).
 
 KV shapes come from an abstract probe of the real layer stack
 (``jax.eval_shape`` over ``prefill_forward``), the same idiom as
@@ -248,6 +252,13 @@ class PagedKVPools:
         self.pool_k, self.pool_v, self.scale_k, self.scale_v = state[:4]
         self.lines = tuple(state[4:])
 
+    @property
+    def line_bytes(self) -> int:
+        """Bytes a token's line takes in the pools, over all layers and
+        steps: what a cached token costs."""
+        return self.device_bytes() // (
+            self.pool_k[0].shape[0] // self.loop_steps * self.block_size)
+
     def device_bytes(self) -> int:
         total = 0
         for arrs in (self.pool_k, self.pool_v, self.scale_k, self.scale_v):
@@ -360,6 +371,19 @@ def init_pools(inference_module, num_blocks: int, block_size: int,
     from ..nn.paged_attention import packed_kv_dims
 
     for k_aval, v_aval in kv_shapes:
+        if k_aval.ndim == 3:
+            # a line without a head axis (latent attention): its two leaves
+            # as the probe gave them, a token's values minor
+            if kv_dtype != "native" or mesh is not None:
+                raise ValueError(
+                    "a latent attention layer's cache line has no head axis: "
+                    "neither the per-head int8 scales nor the model axis has "
+                    "anything to divide; serve it with kv_dtype='native' at "
+                    "model_parallel_size 1")
+            for pools, aval in ((pool_k, k_aval), (pool_v, v_aval)):
+                pools.append(placed(
+                    (pool_blocks, block_size, aval.shape[2]), aval.dtype, 2))
+            continue
         n_kv, h = k_aval.shape[2], k_aval.shape[3]
         if kv_dtype == "native" and mesh is None:
             # heads narrower than the 128 lanes lie several a lane row

@@ -189,6 +189,40 @@ def compile_paged_kernel(one_chip, slots, q_heads, kv_heads, max_blocks, s,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("tokens", [512, 5120], ids=["small", "full"])
+def test_latent_kernel_compiles_at_the_cells_size(one_chip, tokens):
+    """``serve-kimik2-longdoc-burst``'s kernel (nn/latent_paged_attention.py)
+    at both token widths of its engine (32 slots, ``prefill_chunk`` 160): 32
+    rows of up to 160 positions x 64 heads over lines of 512 + 128 lanes,
+    1,024 blocks a row; a chunk row's queries, accumulator and statistics are
+    ~47 MB of the kernel's 64 MiB of VMEM. What Mosaic refuses here it refuses
+    on the chip: a rotary key's leaf of 64 lanes ("must be aligned to tiling
+    (128)") was."""
+    from scaling_tpu.nn.latent_paged_attention import (
+        latent_paged_attention, rope_line_width,
+    )
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    rows, max_blocks, heads, lat, rope = 32, 1024, 64, 512, 64
+    blocks = rows * max_blocks + 1
+    assert rope_line_width(rope) == 128
+
+    def attend(q_lat, q_rope, pool_c, pool_r, table, valid_len, base, starts):
+        return latent_paged_attention(
+            q_lat, q_rope, pool_c, pool_r, table, valid_len, base, starts,
+            width=160, sm_scale=0.130861, interpret=False)
+
+    compiled = jax.jit(attend).lower(
+        shape((tokens, heads, lat)), shape((tokens, heads, rope)),
+        shape((blocks, BLOCK_SIZE, lat)), shape((blocks, BLOCK_SIZE, 128)),
+        shape((rows, max_blocks), jnp.int32), shape((rows,), jnp.int32),
+        shape((rows,), jnp.int32), shape((rows,), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def lowered_mixed_program(one_chip, monkeypatch, bucket, heads, kv_heads,
                           layers=2, kv_layers=None, engine=None,
                           **architecture):
