@@ -353,17 +353,22 @@ class TransformerInferenceModule:
         return ctx
 
     def moe_serve_rows(self, places: int):
-        """``(form, rows)`` of a pass over ``places`` positions: the form the
-        routed layers' expert matmuls take (``ParallelMoEMLP.serve_rows``)
-        and the rows they are given over all routed layers and loop steps
-        (the serving engine counts them a tick)."""
+        """``(form, rows, bounded)`` of a pass over ``places`` positions: the
+        form the routed layers' expert matmuls take
+        (``ParallelMoEMLP.serve_rows``), the rows they are given over all
+        routed layers and loop steps (the serving engine counts them a tick),
+        and whether those are a bound under the ``places x k`` assignments
+        (``serve_bound``: the load then ends in the passes beyond the first)."""
         mesh = self._make_ctx().mesh
-        routed = [
-            mlp.serve_rows(places, mesh) for layer in self.module.layers
+        mlps = [
+            mlp for layer in self.module.layers
             for mlp in (getattr(layer, "mlp", None), getattr(layer, "mixer", None))
             if isinstance(mlp, ParallelMoEMLP)]
+        routed = [mlp.serve_rows(places, mesh) for mlp in mlps]
+        bounded = any(form == "grouped" and rows < places * mlp.top_k
+                      for mlp, (form, rows) in zip(mlps, routed))
         return routed[0][0], self.architecture.loop_steps * sum(
-            rows for _, rows in routed)
+            rows for _, rows in routed), bounded
 
     def _paged_layer_calls(self, ctx):
         """``call(layer)``: the layer as a function of (params, activations,

@@ -78,6 +78,25 @@ renormalised over those present, so the shares of all ranks, the shared
 expert counted once, add up to the whole layer. Nothing stands in for the
 other ranks or their exchange. ``serve`` then counts the load over the held
 experts, and how many of the real positions' assignments fell on absent ones.
+
+**A small share** (under a quarter of the experts held: ``serve_bound``):
+most of a tick's assignments name an absent expert, and carrying all ``A``
+rows through the gather, the matmuls and the combine prices the tick by what
+the OTHER ranks hold (12 of 384 held: 97% of 4,096 rows, and a ``lhs`` the
+kernel has to cut into four calls a matrix: PERF.md, PR 56). After the stable
+sort the held assignments are the first ``sum(sizes)`` entries of the order,
+so the grouped form works on a static bound of rows, ``_SKEW_ROOM`` x the
+balanced share in whole row tiles: it gathers ``x`` for that many entries,
+runs the matmuls with the groups' sizes clipped to them, weights the rows
+with their gates and adds them at their places in a float32 ``(places, h)``
+result (a one-hot matmul: ``_sum_rows_at``). One such pass serves a common
+tick; what a skewed tick holds beyond the bound takes further passes of the
+same body (a rolled loop after the first), so nothing is ever dropped and the
+result is ``_experts``'s at ``C = s`` under any routing. ``serve`` returns the
+passes beyond the first after the absent count. With ``real`` given, a padded
+position's assignments go to the trailing group too: padding takes no row of
+the bound. From a quarter held the bound is ``A`` and the form is the one
+above, operation for operation.
 """
 
 from __future__ import annotations
@@ -91,6 +110,17 @@ from .activation_function import ActivationFunction, get_activation_function
 from .base_layer import BaseLayer, ForwardContext
 from .param import ParamMeta
 from ..topology.topology import DATA_AXIS, MODEL_AXIS
+
+# A rank that holds E_held of E experts is sent, at the deployment's load
+# (its further ranks' tokens beside its own), the balanced share of the
+# assignments it sees: rows x E_held / E. A tick's routing is skewed
+# (moe_load_max_over_mean reads 1.3-2.3 over the routed cells: PERF.md), so a
+# pass has room for this many times the share; what a tick holds beyond it
+# takes a further pass (`_experts_grouped`), never a drop
+_SKEW_ROOM = 4
+# rows a pass is rounded up to: the grouped kernel's largest window, so no
+# pass is padded (ops/grouped_matmul.py grouped_tiles)
+_ROW_TILE = 128
 
 
 class ParallelMoEMLP(BaseLayer):
@@ -263,11 +293,15 @@ class ParallelMoEMLP(BaseLayer):
         of the real positions' assignments each HELD expert received; None
         without ``real``). A layer that holds a share of the experts appends
         one more count: the real positions' assignments that fell on absent
-        experts (the two sum to ``top_k`` a real position)."""
+        experts (the two sum to ``top_k`` a real position); and where its
+        rows are bounded (``serve_bound``) one more after it: the passes
+        this call ran beyond its first."""
         with jax.named_scope("moe"):
             _, gate_vals, gate_idx = self._route(params, x)
+            extra = None
             if self.serve_rows(x.shape[0] * x.shape[1], mesh)[0] == "grouped":
-                y = self._experts_grouped(params, x, gate_vals, gate_idx)
+                y, extra = self._experts_grouped(
+                    params, x, gate_vals, gate_idx, real)
             else:
                 y = self._experts(
                     params, x, gate_vals, gate_idx, capacity=x.shape[1])
@@ -280,13 +314,25 @@ class ParallelMoEMLP(BaseLayer):
             if not self.holds_all:
                 absent = self.top_k * real.sum(dtype=jnp.int32) - load.sum()
                 load = jnp.concatenate([load, absent[None]])
+            if extra is not None:
+                load = jnp.concatenate([load, extra[None]])
             return y, load
+
+    def serve_bound(self, places: int) -> int:
+        """The rows a pass of the grouped form works on: all ``places x k``
+        assignments where a quarter of the experts or more are held, else
+        ``_SKEW_ROOM`` x the held share of them, in whole row tiles. From
+        what the layer is (``experts_held`` of ``num_experts``), never set."""
+        rows = places * self.top_k
+        share = -(-_SKEW_ROOM * rows * self.experts_held // self.num_experts)
+        return min(rows, -(-share // _ROW_TILE) * _ROW_TILE)
 
     def serve_rows(self, places: int, mesh=None) -> Tuple[str, int]:
         """The form ``serve`` takes over ``places`` positions, and the rows
-        its expert matmuls are given: ``("grouped", places x k)`` wherever
-        the expert leaves are whole on the device; ``("dense", held x
-        places)``, the one-hot at room for the whole row, where a mesh axis
+        its expert matmuls are given: ``("grouped", serve_bound(places))``,
+        the rows of one pass (``places x k`` where the bound does not bite),
+        wherever the expert leaves are whole on the device; ``("dense", held
+        x places)``, the one-hot at room for the whole row, where a mesh axis
         their partition names (experts over ``data``, their width over
         ``model``) has more than one device: GSPMD partitions the einsums,
         it cannot partition the kernel. Static for a program (the engine
@@ -295,7 +341,7 @@ class ParallelMoEMLP(BaseLayer):
             mesh.shape.get(axis, 1) > 1 for axis in (DATA_AXIS, MODEL_AXIS))
         if sharded:
             return "dense", self.experts_held * places
-        return "grouped", places * self.top_k
+        return "grouped", self.serve_bound(places)
 
     def _local(self, gate_idx: jax.Array) -> jax.Array:
         """The chosen experts' places among those held; an absent expert's
@@ -386,44 +432,111 @@ class ParallelMoEMLP(BaseLayer):
         out = jnp.einsum("ebcf,efh->ebch", act, params["w_out"].astype(x.dtype))
         return jnp.einsum("bsec,ebch->bsh", combine.astype(x.dtype), out)
 
+    def _expert_rows(
+        self, params: dict, rows: jax.Array, sizes: jax.Array,
+    ) -> jax.Array:
+        """The held experts' FFNs over ``rows`` sorted by expert, ``sizes``
+        rows each: two or three grouped matmuls and the activation."""
+        from ..ops.grouped_matmul import grouped_matmul
+
+        up = grouped_matmul(rows, params["w_in"].astype(rows.dtype), sizes)
+        if self.glu:
+            gate = grouped_matmul(
+                rows, params["w_gate"].astype(rows.dtype), sizes)
+            act = self.activation_fn(gate) * up
+        else:
+            act = self.activation_fn(up)
+        return grouped_matmul(act, params["w_out"].astype(rows.dtype), sizes)
+
     def _experts_grouped(
         self, params: dict, x: jax.Array, gate_vals: jax.Array,
-        gate_idx: jax.Array,
-    ) -> jax.Array:
+        gate_idx: jax.Array, real: Optional[jax.Array] = None,
+    ) -> Tuple[jax.Array, Optional[jax.Array]]:
         """The held experts over the tick's assignments themselves: sorted
         by expert, each expert's matrices multiplied with its own rows
         (``ops/grouped_matmul.py``), weighted and summed back in place.
-        Equal to ``_experts`` at ``capacity = s``."""
-        from ..ops.grouped_matmul import grouped_matmul
-
+        Equal to ``_experts`` at ``capacity = s``. Where the bound bites
+        (``serve_bound``; module docstring) the matmuls see ``bound`` rows a
+        pass, and the second result is the int32 count of the passes beyond
+        the first (None where the rows are all the assignments)."""
         b, s, h = x.shape
         E, k = self.experts_held, self.top_k
         places = b * s
+        bound = self.serve_bound(places)
+        bounded = bound < places * k
         # an assignment's group: its held expert, or, where the expert is
         # absent, the trailing group E, which has no matrix and which no
         # matmul visits
         group = self._local(gate_idx).reshape(places * k)
         taken = (group >= 0) & (group < E)
+        if bounded and real is not None:
+            # what a padded position would take of the bound's rows is left
+            # to the trailing group too: its output is never read
+            taken &= jnp.repeat(real.reshape(places), k)
         group = jnp.where(taken, group, E)
         # stable: token-major within an expert, the order the one-hot's
         # running count defines
         order = jnp.argsort(group, stable=True)
-        place = jnp.zeros_like(order).at[order].set(
-            jnp.arange(places * k, dtype=order.dtype), unique_indices=True)
-        sizes = (group[:, None] == jnp.arange(E, dtype=group.dtype)).sum(
-            0, dtype=jnp.int32)
 
-        rows = x.reshape(places, h)[order // k]
-        up = grouped_matmul(rows, params["w_in"].astype(x.dtype), sizes)
-        if self.glu:
-            gate = grouped_matmul(rows, params["w_gate"].astype(x.dtype), sizes)
-            act = self.activation_fn(gate) * up
-        else:
-            act = self.activation_fn(up)
-        out = grouped_matmul(act, params["w_out"].astype(x.dtype), sizes)
-        # back in (place, choice) order; a row of the trailing group was
-        # never written: selected out, not multiplied by zero
-        out = out[place].reshape(b, s, k, h).astype(jnp.float32)
-        taken = taken.reshape(b, s, k, 1)
-        y = jnp.where(taken, gate_vals[..., None] * out, 0.0).sum(axis=2)
-        return y.astype(x.dtype)
+        def group_sizes():  # each form counts them where it needs them
+            return (group[:, None] == jnp.arange(E, dtype=group.dtype)).sum(
+                0, dtype=jnp.int32)
+
+        if not bounded:
+            place = jnp.zeros_like(order).at[order].set(
+                jnp.arange(places * k, dtype=order.dtype), unique_indices=True)
+            sizes = group_sizes()
+            out = self._expert_rows(
+                params, x.reshape(places, h)[order // k], sizes)
+            # back in (place, choice) order; a row of the trailing group was
+            # never written: selected out, not multiplied by zero
+            out = out[place].reshape(b, s, k, h).astype(jnp.float32)
+            taken = taken.reshape(b, s, k, 1)
+            y = jnp.where(taken, gate_vals[..., None] * out, 0.0).sum(axis=2)
+            return y.astype(x.dtype), None
+
+        # the held assignments are the first sum(sizes) entries of order: a
+        # pass takes the next `bound` of them, each group's rows clipped to
+        # the pass (as grouped_matmul clips them to its own blocks)
+        sizes = group_sizes()
+        ends = jnp.cumsum(sizes)
+        starts, held_rows = ends - sizes, ends[-1]
+        xs = x.reshape(places, h)
+        order = jnp.pad(order, (0, bound))  # the last pass may reach past it
+        gates = gate_vals.reshape(places * k)
+        at = jnp.arange(bound, dtype=jnp.int32)
+
+        def one_pass(lo, y):
+            mine = jax.lax.dynamic_slice(order, (lo,), (bound,))
+            live = (lo + at < held_rows)[:, None]
+            out = self._expert_rows(
+                params, xs[mine // k],
+                jnp.clip(ends, lo, lo + bound) - jnp.clip(starts, lo, lo + bound))
+            # a row past the held ones was never written: selected out
+            out = jnp.where(
+                live, gates[mine][:, None] * out.astype(jnp.float32), 0.0)
+            return y + _sum_rows_at(out, mine // k, places)
+
+        # the first pass is every common tick's only one and runs unrolled
+        # (a `while` around it would be one device operation that spans the
+        # pass); what a skewed tick holds beyond the bound takes further
+        # passes of the same body, so nothing is ever dropped
+        y = one_pass(jnp.int32(0), jnp.zeros((places, h), jnp.float32))
+        lo, y = jax.lax.while_loop(
+            lambda carry: carry[0] < held_rows,
+            lambda carry: (carry[0] + bound, one_pass(*carry)),
+            (jnp.int32(bound), y))
+        # the loop leaves lo at bound x the passes run
+        return y.reshape(b, s, h).astype(x.dtype), lo // bound - 1
+
+
+def _sum_rows_at(rows: jax.Array, at: jax.Array, places: int) -> jax.Array:
+    """``(places, h)`` float32: ``rows[r]`` added at place ``at[r]``, as a
+    one-hot matmul in float32 in earnest (see ``_route``). On the chip at the
+    Kimi-K2 cell's 512 rows onto 512 places of 7,168 this is 0.06 ms where the
+    scatter-add (a sort, a gather and a segmented update) is 0.40 (PERF.md,
+    PR 56); it is quadratic in a tick's width, and level with the scatter at
+    5,120 x 5,120."""
+    hot = at[None, :] == jnp.arange(places, dtype=at.dtype)[:, None]
+    return jnp.dot(hot.astype(jnp.float32), rows,
+                   precision=jax.lax.Precision.HIGHEST)

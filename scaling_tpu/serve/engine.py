@@ -382,8 +382,10 @@ class ServeEngine:
         self.num_experts = arch.moe_held if routed else 0
         self.moe_partial = routed and arch.moe_held < arch.moe_num_experts
         # token width -> (the form a routed layer's expert matmuls take at
-        # it, the rows they are given over all routed layers): static for a
-        # program (nn/moe.py serve_rows)
+        # it, the rows they are given over all routed layers, whether those
+        # are a bound under the width's assignments: the load then ends in
+        # the passes run beyond the first): static for a program (nn/moe.py
+        # serve_rows, serve_bound)
         self._moe_rows = {
             width: inference_module.moe_serve_rows(width)
             for width in self.config.mixed_widths} if routed else {}
@@ -961,7 +963,7 @@ class ServeEngine:
                     # the rows the tick's expert matmuls were given; with
                     # serve_moe_assignments_total (the real, held assignments
                     # they are for) the share of them that is real work
-                    path, moe_rows = self._moe_rows[width]
+                    path, moe_rows, _ = self._moe_rows[width]
                     mixed_span.annotate(moe_rows=moe_rows)
                     self._counter("serve_moe_rows_total", path=path).inc(
                         moe_rows)
@@ -993,7 +995,8 @@ class ServeEngine:
             if self.num_experts:
                 load = host_samples[n * sw:]
                 host_samples = host_samples[:n * sw].reshape(n, sw)
-                self._record_moe_load(load, emit)
+                *_, bounded = self._moe_rows[width]
+                self._record_moe_load(load, emit, bounded)
             if self.loop_exit_gate:
                 exit_p = host_samples[n * sw:].view(np.float32)
                 host_samples = host_samples[:n * sw].reshape(n, sw)
@@ -1015,11 +1018,18 @@ class ServeEngine:
             for seq in t.decodes:
                 self._accept_speculative(seq, host_samples[seq.slot], now)
 
-    def _record_moe_load(self, load, emit_span) -> None:
+    def _record_moe_load(self, load, emit_span, bounded: bool) -> None:
         """One tick's (E,) assignments of real positions, summed over the
         layers: the counter, and the tick's shape on its emit span."""
         if self.warmup_mode:
             return
+        if bounded:
+            # the expert matmuls' rows are a bound (nn/moe.py serve_bound):
+            # the last entry counts the passes the routed layers ran beyond
+            # their first, 0 unless a tick's held assignments exceeded it
+            load, extra = load[:-1], int(load[-1])
+            self._counter("serve_moe_extra_passes_total").inc(extra)
+            emit_span.annotate(moe_extra_passes=extra)
         if self.moe_partial:
             # a share of the experts: the last entry counts the assignments
             # that fell on absent ones; the load is over those held

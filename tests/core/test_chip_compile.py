@@ -556,6 +556,52 @@ def test_a_served_routed_layer_is_grouped_matmuls_under_the_moe_scope(
         assert not copies, f"{len(copies)} copies of a whole expert leaf"
 
 
+def test_a_small_share_of_the_experts_meets_one_call_a_matrix_a_pass(
+        one_chip, monkeypatch):
+    """``serve-kimik2-longdoc-burst``'s routed layer (12 of 384 experts held,
+    k = 8, 7,168 x 2,048, a shared expert) at its tick of 512 places (ISSUE
+    56): the matmuls are given ``serve_bound`` = 512 rows a pass, not the
+    4,096 assignments, whose 58 MB of ``lhs`` the kernel cut into four calls
+    a matrix (nine a layer). The first pass lies in the entry computation,
+    three calls; the passes over what a skewed tick holds beyond the bound
+    are ONE rolled loop of the same three; all under ``/moe/``, and nothing
+    ``(4096, 7168)`` is left."""
+    from scaling_tpu.nn.moe import ParallelMoEMLP
+
+    monkeypatch.setattr(
+        "scaling_tpu.ops.grouped_matmul.grouped_matmul_interpret",
+        lambda platform=None: False)
+    layer = ParallelMoEMLP(
+        io_features=7168, intermediate=2048, intermediate_feature_factor=1.0,
+        num_experts=384, experts_held=12, top_k=8, router="sigmoid_bias",
+        routed_scaling_factor=2.827, shared_expert_width=2048,
+        dtype=jnp.bfloat16)
+    assert layer.serve_rows(512) == ("grouped", 512)
+    assert layer.serve_rows(5120) == ("grouped", 5120)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda x: shape(x.shape, x.dtype),
+                          jax.eval_shape(layer.init, jax.random.PRNGKey(0)))
+    text = jax.jit(layer.serve).lower(
+        params, shape((16, 32, 7168), jnp.bfloat16),
+        shape((16, 32), jnp.bool_)).compile().as_text()
+    bodies = re.findall(r" while\(.*body=%([\w.\-]+)", text)
+    assert len(bodies) == 1, bodies
+    by_computation = {}
+    for block in re.split(r"\n\n+", text):
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) ", block.lstrip())
+        if head:
+            by_computation[head.group(1)] = custom_calls(block)
+    entry = text[text.index("\nENTRY "):].split("\n\n")[0]
+    assert len(custom_calls(entry)) == 3
+    assert len(by_computation[bodies[0]]) == 3
+    calls = custom_calls(text)
+    assert len(calls) == 6 and all(scope_of(call) == "moe" for call in calls)
+    assert "ragged-dot" not in text and "[4096,7168]" not in text
+
+
 def test_pharia_train_step_never_holds_the_whole_vocabulary(topo, monkeypatch):
     """``train-pharia7b-4chip``'s own step (the benchmark's configuration and
     traffic files, TP=2 x DP=2 + ZeRO-1 + SP) at depth 1, compiled for the

@@ -233,6 +233,130 @@ def test_what_a_padded_position_holds_reaches_no_real_position(case):
     assert int(held.sum()) + absent == 16 * layer.top_k
 
 
+# a layer whose bound bites (ISSUE 56): 2 of 32 experts held, k = 4; 128
+# places bring 512 assignments and a pass works on 128 rows of them
+BOUND_PLACES, BOUND_ROWS = (2, 64), 128
+BOUNDED = dict(num_experts=32, top_k=4, experts_first=3, experts_held=2,
+               router="sigmoid_bias", shared_expert_width=48)
+
+
+def bounded_tick(dtype, routing, with_real):
+    """A layer of ``BOUNDED``, its parameters, a tick of 128 places and which
+    of them are real, and the held assignments the routing gives it. The
+    sigmoid router's selection bias forces the choice: ``balanced`` leaves it
+    to the scores; ``held`` puts both held experts among every position's 4;
+    ``exactly-R`` / ``R-plus-1`` give the share 128 / 129 assignments;
+    ``empty`` keeps every choice off the share."""
+    layer = make_layer(dtype=dtype, **BOUNDED)
+    assert layer.serve_bound(128) == BOUND_ROWS < 128 * layer.top_k
+    params = layer.init(jax.random.PRNGKey(0))
+    x = (jax.random.normal(jax.random.PRNGKey(1), BOUND_PLACES + (H,)) * 0.5
+         ).astype(dtype)
+    real = jnp.ones(BOUND_PLACES, bool)
+    if with_real:  # 100 of 128 real, the padding in the middle of a row too
+        real = (jnp.arange(128) % 32 < 25).reshape(BOUND_PLACES)
+    held = jnp.zeros((32,)).at[3:5].set(1.0)
+    if routing == "balanced":
+        return layer, params, x, real, None
+    if routing == "empty":
+        params["router"]["bias"] = -10.0 * held
+        return layer, params, x, real, 0
+    if routing == "held":
+        params["router"]["bias"] = 10.0 * held
+        return layer, params, x, real, 2 * int(real.sum())
+    # expert 3 among every position's 4 (128 rows), expert 4 at position 0
+    # alone or nowhere. The bias is one vector for all positions, so that
+    # position is told apart by its input: a feature the router's column 4
+    # reads, far above the other scores where it is set and far below elsewhere
+    assert not with_real, "the 100 real places of the padded tick hold fewer"
+    first = (jnp.arange(128) < (routing == "R-plus-1")).reshape(BOUND_PLACES)
+    x = x.at[..., 0].set(jnp.where(first, 8.0, -8.0).astype(dtype))
+    params["router"]["weight"] = params["router"]["weight"].at[0].set(
+        jnp.zeros((32,)).at[4].set(4.0))
+    params["router"]["bias"] = jnp.zeros((32,)).at[3].set(10.0)
+    return layer, params, x, real, BOUND_ROWS + (routing == "R-plus-1")
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("routing,with_real", [
+    ("balanced", False), ("balanced", True), ("held", False), ("held", True),
+    ("exactly-R", False), ("R-plus-1", False), ("empty", False), ("empty", True)],
+    ids=lambda v: {False: "all-real", True: "padded"}.get(v, v))
+def test_a_bounded_serve_equals_the_one_hot_under_any_routing(
+        routing, with_real, dtype):
+    """Where a small share of the experts is held, the grouped form's matmuls
+    see ``serve_bound`` rows a pass, not ``places x k`` (ISSUE 56): one pass
+    in a balanced tick, further passes over what a skewed tick holds beyond
+    the bound, so the result is the one-hot form's at ``C = s`` whatever the
+    routing, and the load's last entry counts the passes beyond the first."""
+    layer, params, x, real, held_rows = bounded_tick(dtype, routing, with_real)
+    assert layer.serve_rows(128) == ("grouped", BOUND_ROWS)
+    y, load = jax.jit(layer.serve)(params, x, real)
+    assert load.shape == (2 + 1 + 1,) and load.dtype == jnp.int32
+    if held_rows is None:  # balanced: ~1/16 of the assignments, one pass
+        held_rows = int(load[:2].sum())
+        assert 0 < held_rows < BOUND_ROWS
+    assert int(load[:2].sum()) == held_rows
+    assert int(load[-1]) == max(-(-held_rows // BOUND_ROWS) - 1, 0)
+    assert int(load[:3].sum()) == layer.top_k * int(real.sum())
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    mask = np.asarray(real)
+    np.testing.assert_allclose(
+        np.asarray(y, np.float32)[mask],
+        np.asarray(one_hot_serve(layer, params, x), np.float32)[mask],
+        atol=tol, rtol=tol)
+    # without `real` every place counts, and no count comes back
+    y_all, none = layer.serve(params, x)
+    assert none is None
+    np.testing.assert_allclose(
+        np.asarray(y_all, np.float32),
+        np.asarray(one_hot_serve(layer, params, x), np.float32),
+        atol=tol, rtol=tol)
+
+
+def test_a_padded_position_takes_no_row_of_the_bound():
+    """With ``real``, what a padded position's router chose goes to the
+    group no matmul visits: non-finite padding reaches no real position, and
+    a tick whose REAL held assignments fit the bound runs one pass however
+    many the padding would have added."""
+    layer, params, x, real, held_rows = bounded_tick(jnp.float32, "held", True)
+    real = (jnp.arange(128) % 32 < 16).reshape(BOUND_PLACES)  # 64 real: 128 rows
+    poisoned = jnp.where(real[..., None], x, jnp.nan)
+    y, load = layer.serve(params, poisoned, real)
+    assert np.asarray(load).tolist() == [64, 64, 2 * 64, 0]
+    mask = np.asarray(real)
+    np.testing.assert_allclose(
+        np.asarray(y)[mask], np.asarray(one_hot_serve(layer, params, x))[mask],
+        atol=1e-5, rtol=1e-5)
+
+
+def test_the_bound_leaves_a_layer_that_holds_a_quarter_or_more_as_it_was(
+        monkeypatch):
+    """``serve_bound`` is ``places x k`` from a quarter of the experts held
+    (OLMoE and LFM2 hold all, Nemotron half): the rule then changes nothing
+    of the lowered program, which is the one with the rule disabled; under a
+    quarter it does."""
+    x = jnp.zeros(BOUND_PLACES + (H,), jnp.float32)
+    real = jnp.ones(BOUND_PLACES, bool)
+
+    def lowered(layer):
+        params = layer.init(jax.random.PRNGKey(0))
+        return jax.jit(layer.serve).lower(params, x, real).as_text()
+
+    quarter = make_layer(num_experts=32, top_k=4, experts_held=8)
+    small = make_layer(**BOUNDED)
+    assert quarter.serve_bound(128) == 512 and small.serve_bound(128) == 128
+    assert [make_layer(num_experts=8, top_k=2, experts_held=e).serve_bound(96)
+            for e in (1, 2, 8)] == [128, 192, 192]  # whole tiles, at most all
+    with_rule = {"quarter": lowered(quarter), "small": lowered(small)}
+    monkeypatch.setattr("scaling_tpu.nn.moe._SKEW_ROOM", 10 ** 6)
+    assert small.serve_bound(128) == 512
+    assert lowered(quarter) == with_rule["quarter"]
+    assert lowered(small) != with_rule["small"]
+    assert "while" in with_rule["small"] and "while" not in with_rule["quarter"]
+
+
 @pytest.mark.parametrize("crowd", ["one-expert", "an-idle-expert"])
 def test_grouped_serve_with_a_group_as_long_as_the_buffer_or_empty(crowd):
     """Every position on ONE expert (its group is the whole buffer, every

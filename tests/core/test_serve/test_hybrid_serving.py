@@ -321,3 +321,74 @@ def test_a_plain_models_spans_are_what_they_were(tmp_path):
     fields = set().union(*(f for n, _, _, f in capture.spans if n == "serve.mixed"))
     assert not fields & {"ssm_rows", "ssm_lines"}
     assert "serve_ssm_state_updates_total" not in capture.counters
+
+
+def small_share(bias=None):
+    """The toy holding 2 of 64 experts: ``serve_bound`` bites (ISSUE 56). With
+    ``bias`` the sigmoid router's selection bias of the two held experts: far
+    above the scores, every position's 3 choices hold both."""
+    config = hybrid_config(moe_num_experts=64, moe_experts_held=2)
+    module = init_model(config, None)
+    params = module.init_params(jax.random.PRNGKey(3))
+    if bias is not None:
+        for layer in params.values():
+            router = layer.get("mixer", {}).get("router")
+            if router is not None:
+                router["bias"] = router["bias"].at[:2].set(bias)
+    return TransformerInferenceModule(config, module, params)
+
+
+@pytest.mark.parametrize("routing", ["balanced", "skewed"])
+def test_a_small_share_of_the_experts_is_given_the_bounds_rows(
+        routing, tmp_path, monkeypatch):
+    """2 of 64 experts held, 3 a token: a width of 128 / 512 places brings 384
+    / 1,536 assignments and the routed layers' matmuls are given 128 / 256 rows
+    a pass (``moe_rows``). A balanced router never exceeds them; one forced
+    onto the held experts does (two 32-token chunks beside eight decode rows:
+    144 held assignments a layer against 128 rows), the passes beyond the
+    first are counted, and the tokens are the one-hot form's."""
+    from scaling_tpu.nn.moe import ParallelMoEMLP
+
+    inf = small_share(10.0 if routing == "skewed" else None)
+    requests = prompts((3, 4, 5, 6, 7, 8, 32, 32, 32, 32), seed=6)
+    config = dict(num_slots=16, prefill_chunk=32, token_budget=128,
+                  max_blocks_per_seq=16, num_blocks=16 * 16 + 1)
+    engine = engine_of(inf, **config)
+    assert engine.config.mixed_widths == (128, 512) and engine.moe_partial
+    routed = PATTERN.count("E")
+    assert engine._moe_rows == {128: ("grouped", 128 * routed, True),
+                                512: ("grouped", 256 * routed, True)}
+    obs.start_capture(str(tmp_path))
+    try:
+        got = served(engine, requests, 6)
+    finally:
+        capture = obs.stop_capture()
+    mixed = [f for n, _, _, f in capture.spans if n == "serve.mixed"]
+    emits = [f for n, _, _, f in capture.spans if n == "serve.emit"]
+    assert {f["width"] for f in mixed} == {128, 512}  # eight chunk rows, then two
+    assert all(f["moe_rows"] == {128: 128, 512: 256}[f["width"]] * routed
+               for f in mixed)
+    assert capture.counters["serve_moe_rows_total{path=grouped}"] == sum(
+        f["moe_rows"] for f in mixed)
+    # a capture keeps the counters that moved
+    extra = capture.counters.get("serve_moe_extra_passes_total", 0)
+    assert extra == sum(f["moe_extra_passes"] for f in emits)
+    held = capture.counters["serve_moe_assignments_total"]
+    tokens = sum(f["tokens"] for f in mixed)
+    assert held + capture.counters["serve_moe_absent_assignments_total"] == (
+        3 * routed * tokens)
+    if routing == "balanced":
+        assert extra == 0 and 0 < held < 3 * routed * tokens // 8
+    else:
+        assert held == 2 * routed * tokens
+        # 2 x the tick's tokens a layer over the width's rows, less the first
+        assert extra == sum(
+            routed * max(-(-2 * f["tokens"] // (f["moe_rows"] // routed)) - 1, 0)
+            for f in mixed) > 0
+    # the one-hot form at room for the whole row, through the same engine
+    monkeypatch.setattr(
+        ParallelMoEMLP, "serve_rows",
+        lambda self, places, mesh=None: ("dense", self.experts_held * places))
+    dense = engine_of(inf, **config)
+    assert dense._moe_rows[128] == ("dense", 2 * 128 * routed, False)
+    assert served(dense, requests, 6) == got
