@@ -10,11 +10,14 @@ time) both go through here, so a trace can be taken from a process that
 is already running, more than once, and the spans always know.
 
 While a capture is on, every :func:`obs.span` also opens a
-``jax.profiler.TraceAnnotation`` of its name. That puts it on the host
-plane of the ``.xplane.pb`` (the line of the thread that ran it), on the
-clock the device's ``XLA Ops`` are on, so an idle gap of the chip can be
-laid beside the phase of the host it fell into. While none is on a span
-does not and pays one global read.
+``jax.profiler.TraceAnnotation`` of its name, with its ``step`` (where
+it has one) as the event's metadata. That puts it on the host plane of
+the ``.xplane.pb`` (the line of the thread that ran it), on the clock
+the device's ``XLA Ops`` are on, so an idle gap of the chip can be laid
+beside the phase of the host it fell into, and a row of the recorder is
+joined to its event by ``(name, step)``: :attr:`Capture.clock_offset_ns`
+is what that join gives, the trace's clock minus the capture's. While
+none is on a span does not and pays one global read.
 
 A capture keeps no spans of its own. Every closed span lands in the
 process's bounded recorder (``obs/recorder.py``) whether a capture is on
@@ -34,6 +37,7 @@ jax is imported lazily, as everywhere in ``obs``.
 
 from __future__ import annotations
 
+import statistics
 import threading
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Tuple
@@ -42,11 +46,13 @@ from .recorder import CAPTURE_MARKER, _recorder, clock
 from .registry import MetricsRegistry, get_registry
 
 # (name, start_ns, duration_ns, fields): start_ns counts from the start
-# of the capture, which is the origin the profiler gives its planes to
-# within the time start_trace takes to return; fields holds the span's
-# step, its parent's name and its scalar annotations (and lists of
-# numbers: a looped model's exit distribution)
+# of the capture (``Capture.origin_ns`` on the recorder's clock), which is
+# the origin the profiler gives its planes to within the time start_trace
+# takes to return: ``Capture.clock_offset_ns`` says how far within;
+# fields holds the span's step, its parent's name and its scalar
+# annotations (and lists of numbers: a looped model's exit distribution)
 SpanRow = Tuple[str, int, int, Dict[str, Any]]
+HOST_PLANE = "/host:CPU"
 
 
 class Capture:
@@ -63,6 +69,15 @@ class Capture:
         # recorder; ``spans=`` is for one rebuilt from a file (a test's
         # recorded capture), which has no recorder to cut from
         self._markers, self._given = markers, spans
+        self._clock_offset_ns: Optional[float] = None
+
+    @property
+    def origin_ns(self) -> Optional[int]:
+        """The capture's start on the recorder's clock: what a
+        ``recorded_spans()`` row's ``start_ns`` is counted down by to lie
+        beside :attr:`spans` (None for a capture rebuilt from a file)."""
+        return None if self._markers is None else round(
+            self._markers[0][1] * 1e9)
 
     @property
     def spans(self) -> List[SpanRow]:
@@ -77,6 +92,54 @@ class Capture:
         """The newest ``.xplane.pb`` under ``trace_dir``."""
         files = sorted(Path(self.trace_dir).glob("**/*.xplane.pb"))
         return files[-1] if files else None
+
+    def annotations(self) -> List[Tuple[str, Optional[int], float, float]]:
+        """The spans' annotations as the trace holds them, ``(name, step,
+        start_ns, duration_ns)`` on the TRACE's clock (the device lines'),
+        in order of time: the events of the host plane that bear a name
+        of :attr:`spans`. Parses the ``.xplane.pb``; [] without one."""
+        path = self.trace_file()
+        names = {row[0] for row in self.spans}
+        if path is None or not names:
+            return []
+        from jax.profiler import ProfileData
+
+        out = []
+        for plane in ProfileData.from_file(str(path)).planes:
+            if plane.name != HOST_PLANE:
+                continue
+            for line in plane.lines:
+                for event in line.events:
+                    if event.name in names:
+                        step = dict(event.stats).get("step")
+                        out.append((event.name, step, float(event.start_ns),
+                                    float(event.duration_ns)))
+        return sorted(out, key=lambda a: a[2])
+
+    @property
+    def clock_offset_ns(self) -> Optional[float]:
+        """The trace's clock minus the capture's: the median, over the
+        spans that carry a ``step``, of the annotation's start minus the
+        row's (joined by ``(name, step)``; a pair that occurs twice, as
+        in a fleet whose replicas' steps collide, is left out). A
+        ``spans`` row lies on the device's clock at ``start_ns +
+        clock_offset_ns``, any row of the recorder at ``start_ns -
+        origin_ns + clock_offset_ns``, to microseconds (an annotation
+        opens just before its span reads the clock). None where nothing
+        joins. Read once, then kept."""
+        if self._clock_offset_ns is None:
+            rows, seen = {}, {}
+            for name, start, _, fields in self.spans:
+                key = (name, fields.get("step"))
+                rows[key] = None if key in rows else start
+            for name, step, start, _ in self.annotations():
+                seen[(name, step)] = None if (name, step) in seen else start
+            diffs = [seen[key] - start for key, start in rows.items()
+                     if key[1] is not None and start is not None
+                     and seen.get(key) is not None]
+            if diffs:
+                self._clock_offset_ns = statistics.median(diffs)
+        return self._clock_offset_ns
 
 
 class _Active:

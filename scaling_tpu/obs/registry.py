@@ -133,6 +133,18 @@ class Histogram:
             self.sum += v
             self.count += 1
 
+    def observe_many(self, values) -> None:
+        """``observe`` of each value in order, under ONE take of the lock:
+        buckets, ``sum`` (added in the same order, so to the last bit)
+        and ``count`` end as they would after that many calls."""
+        placed = [(bisect.bisect_left(self.buckets, v), v)
+                  for v in map(float, values)]
+        with self._lock:
+            for idx, v in placed:
+                self._counts[idx] += 1
+                self.sum += v
+            self.count += len(placed)
+
     def bucket_counts(self) -> Dict[str, int]:
         """Cumulative counts keyed by upper bound (Prometheus ``le``)."""
         out: Dict[str, int] = {}

@@ -17,9 +17,10 @@ Every span lands three times, and a fourth while a capture is on:
   something takes it: an events path is configured, or the logger
   mirrors at the span's level;
 - while :func:`obs.start_capture` is on (``obs/capture.py``), as a
-  ``jax.profiler.TraceAnnotation`` of its name, which puts it on the
-  host plane of the profiler's trace on the device's clock. With no
-  capture it does not.
+  ``jax.profiler.TraceAnnotation`` of its name (its ``step``, where it
+  has one, as the event's metadata), which puts it on the host plane of
+  the profiler's trace on the device's clock. With no capture it does
+  not.
 
 Spans nest (thread-local stack; the parent's name is recorded on the
 child) and are exception-safe: a body that raises still emits the span,
@@ -60,12 +61,8 @@ from ..logging import logger
 from ..logging.logger import set_trace_provider
 from . import capture as _capture
 from .recorder import _recorder, clock as _clock
-from .registry import DEFAULT_BUCKETS, OVERFLOW_LABELS, get_registry
+from .registry import OVERFLOW_LABELS, get_registry
 
-# ``span_seconds`` alone resolves a serving tick's phases (schedule,
-# build, dispatch, emit, retire: 0.02-1 ms); every other histogram keeps
-# ``DEFAULT_BUCKETS``, whose first bound is 1 ms
-SPAN_BUCKETS = (1e-5, 5e-5, 1e-4, 5e-4) + DEFAULT_BUCKETS
 _record = _recorder.append
 
 _local = threading.local()
@@ -202,7 +199,11 @@ class Span:
         stack.append(self)
         self._capture = cap = _capture.active()
         if cap is not None:
-            self._annotation = cap.annotation(self.name)
+            # the span's step rides the annotation: a row of the recorder
+            # and its event of the trace are joined by (name, step)
+            self._annotation = (
+                cap.annotation(self.name) if self._step is None
+                else cap.annotation(self.name, step=self._step))
             self._annotation.__enter__()
         self._start = _clock()
         return self
@@ -255,8 +256,7 @@ def _emit(sp: Span, parent: Optional[str], duration: float, ok: bool,
     # the registry's lock on every span was most of what a span cost
     hist = reg.span_handles.get(sp.name)
     if hist is None:
-        hist = reg.histogram("span_seconds", labels={"span": sp.name},
-                             buckets=SPAN_BUCKETS)
+        hist = reg.histogram("span_seconds", labels={"span": sp.name})
         if hist.labels != OVERFLOW_LABELS:  # a leaking name must not grow the dict
             reg.span_handles[sp.name] = hist
     hist.observe(duration)

@@ -90,6 +90,11 @@ LANES = 128
 SMALL_BUCKET_CHUNKS = 3
 # ticks whose spans ``stats_snapshot()["tick_phases_ms"]`` takes its medians over
 TICK_PHASES_TICKS = 512
+# the spans that tile a tick: ``serve.tick`` and ``serve.mixed`` only hold
+# them, and ``serve.draft`` / ``.preempt`` / ``.cow`` nest in ``serve.schedule``
+LEAF_PHASES = frozenset((
+    "serve.schedule", "serve.mixed.build", "serve.mixed.dispatch",
+    "serve.mixed.wait", "serve.emit", "serve.retire"))
 
 
 def packed_batch_shape(width: int, row_width: int) -> Tuple[int, int]:
@@ -364,10 +369,9 @@ class ServeEngine:
         # token width -> the fused mixed program built at it: every width
         # of config.mixed_widths, all lowered at the first tick
         self._mixed_fns: Dict[int, object] = {}
-        # token width -> ticks run at it / real tokens they held (warm-up
-        # apart): how often the small program serves, and the padding left
+        # token width -> ticks run at it (warm-up apart): how often the
+        # small program serves
         self.mixed_ticks: Dict[int, int] = {}
-        self.mixed_tokens: Dict[int, int] = {}
         # of those ticks, the ones in which a row sampled (temperature > 0):
         # the program's sampler took its sorting branch (sample_rows)
         self.sampled_ticks = 0
@@ -417,13 +421,17 @@ class ServeEngine:
             {"replica": self.replica_id}
             if self.replica_id is not None else {}
         )
-        if self.latent_layers:
-            # bytes a cached token takes in the pools, all layers
-            self._gauge("serve_kv_line_bytes").set(self.pools.line_bytes)
+        # the registry's metrics this engine has touched, by (name, label
+        # values): _metric (as obs.span holds its histograms)
+        self._handles: Dict[object, object] = {}
+        # what one tick's rows emitted, counted by the tick in one go
+        # (_flush_tick_telemetry): inter-token gaps, tokens, prompt tokens
+        self._tick_itl: List[float] = []
+        self._tick_tokens = 0
+        self._tick_prefilled = 0
         self._prefix_hits_flushed = 0  # scheduler counter already mirrored
-        # the scheduler's eviction totals, likewise: seconds, blocks, and
-        # the stale heap entries the cache skipped
-        self._evict_flushed = (0.0, 0, 0)
+        # the scheduler's eviction totals, likewise: seconds and blocks
+        self._evict_flushed = (0.0, 0)
         # spans the recorder holds from before this are another engine's
         self._created_ns = time.monotonic_ns()
         self.prefilled_tokens = 0  # prompt tokens actually prefilled
@@ -598,16 +606,27 @@ class ServeEngine:
             return x
         return self._jax.device_put(x, self._replicated)
 
+    def _metric(self, kind: str, name: str, labels: dict):
+        """The registry's counter / gauge / histogram ``name`` under
+        ``labels`` (and this replica's): found through the registry's
+        lock at the label set's first use, from then on held here."""
+        key = (name, *labels.values()) if labels else name
+        handle = self._handles.get(key)
+        if handle is None:
+            if labels:
+                labels.update(self._labels or {})
+            handle = self._handles[key] = getattr(self._reg, kind)(
+                name, labels or self._labels)
+        return handle
+
     def _counter(self, name: str, **labels):
-        if labels:
-            labels.update(self._labels or {})
-        return self._reg.counter(name, labels or self._labels)
+        return self._metric("counter", name, labels)
 
     def _gauge(self, name: str):
-        return self._reg.gauge(name, self._labels)
+        return self._metric("gauge", name, {})
 
     def _histogram(self, name: str):
-        return self._reg.histogram(name, self._labels)
+        return self._metric("histogram", name, {})
 
     def _pool_state(self):
         return self.pools.state()
@@ -907,75 +926,6 @@ class ServeEngine:
                 tick.temps[:], tick.topps[:] = self._temp, self._topp
                 tick.topks[:], tick.reqids[:] = self._topk, self._reqid
                 packed = packed[:self._layout.size(width)]
-            if not self.warmup_mode:  # mixed_span is a span
-                # rows that hold a visible slot and the paged kernel's tiles
-                # among them: of a call's kv_tiles fetches, kv_rows - 1 are
-                # first tiles that start under another row's fold
-                held = ctx + new_lens
-                # the predicate of the program's sampler (sample_rows), known
-                # here before the call: a freed slot's temperature is 0
-                sampled_rows = int(np.count_nonzero(self._temp > 0.0))
-                mixed_span.annotate(
-                    width=width, tokens=len(real),
-                    sampled_rows=sampled_rows,
-                    kv_rows=int(np.count_nonzero(held)),
-                    kv_tiles=int((-(-held // self._kv_tile)).sum()),
-                )
-                self.sampled_ticks += sampled_rows > 0
-                self._counter(
-                    "serve_sampler_ticks_total",
-                    path="sampled" if sampled_rows else "greedy",
-                ).inc()
-                # rows whose per-slot lines advanced, in every layer of a kind
-                rows = int(np.count_nonzero(new_lens))
-                for kind, lines in self.line_layers.items():
-                    mixed_span.annotate(**{f"{kind}_rows": rows,
-                                           f"{kind}_lines": lines})
-                    self._counter(
-                        f"serve_{kind}_state_updates_total").inc(rows * lines)
-                if self.ssm_lines:
-                    # the form that advanced them (nn/mamba.py): at the full
-                    # width whole rows, below it a step or a gathered chunk
-                    mixed_span.annotate(
-                        ssm_step_rows=rows - multi, ssm_chunk_rows=multi)
-                    paths = ({"whole": rows} if width == cfg.mixed_widths[-1]
-                             else {"step": rows - multi, "chunk": multi})
-                    for path, count in paths.items():
-                        self._counter("serve_ssm_rows_total", path=path).inc(
-                            count * self.ssm_lines)
-                if self.latent_layers:
-                    # what a latent layer's attention reads and multiplies
-                    # this tick: the lines of its rows (context + new), and
-                    # the (query, visible line) pairs
-                    n_new = new_lens.astype(np.int64)
-                    lines = int(held.sum())
-                    mixed_span.annotate(
-                        latent_layers=self.latent_layers, latent_lines=lines,
-                        latent_pairs=int(
-                            (n_new * ctx + n_new * (n_new + 1) // 2).sum()))
-                    self._counter("serve_latent_lines_read_total").inc(
-                        lines * self.latent_layers)
-                if self.par_lines:
-                    mixed_span.annotate(par_lines=self.par_lines)
-                    self._counter("serve_parallel_mixer_passes_total").inc(
-                        self.par_lines)
-                if self.num_experts:
-                    # the rows the tick's expert matmuls were given; with
-                    # serve_moe_assignments_total (the real, held assignments
-                    # they are for) the share of them that is real work
-                    path, moe_rows, _ = self._moe_rows[width]
-                    mixed_span.annotate(moe_rows=moe_rows)
-                    self._counter("serve_moe_rows_total", path=path).inc(
-                        moe_rows)
-                if self.loop_steps > 1:
-                    mixed_span.annotate(loop_steps=self.loop_steps)
-                    self._counter("serve_loop_layer_passes_total").inc(
-                        self.loop_steps * self.pools.num_layers)
-                self.mixed_ticks[width] = self.mixed_ticks.get(width, 0) + 1
-                self.mixed_tokens[width] = (
-                    self.mixed_tokens.get(width, 0) + len(real)
-                )
-                self._counter("serve_mixed_ticks_total", width=width).inc()
             puts = self.host_puts
             with self._span("serve.mixed.dispatch", step=step) as dispatch:
                 sampled, state = self._mixed_fns[width](
@@ -986,6 +936,11 @@ class ServeEngine:
                     moved = self.host_puts - puts
                     dispatch.annotate(operands=moved, bytes=packed.nbytes)
                     self.tick_operands += moved
+            if not self.warmup_mode:  # mixed_span is a span
+                # every value it reads was known before the call: it runs
+                # here, while the chip is busy, not ahead of the dispatch
+                self._annotate_mixed(mixed_span, width, len(real), ctx,
+                                     new_lens, multi)
             with self._span("serve.mixed.wait", step=step):
                 # the tick's ONE deliberate device->host pull: the sampled
                 # token grid must land on host to be emitted to callers
@@ -1004,19 +959,124 @@ class ServeEngine:
                     exit_p, int(np.minimum(new_lens, sw).sum()), emit)
             self._absorb(state)
             now = time.monotonic()
+            rows = len(t.decodes)
+            drafted = self.spec_drafted_tokens
+            accepted = self.spec_accepted_tokens
             for seq, start, n_real in chunk_rows:
                 slot = seq.slot
                 seq.num_cached = start + n_real
-                if not self.warmup_mode:
-                    self.prefilled_tokens += n_real
-                    self._counter("serve_prefill_tokens_total").inc(n_real)
+                self._tick_prefilled += n_real
                 if seq.num_cached == seq.prefill_len:
                     # original position n_real - 1, gathered at index
                     # n_real - 1 - g0 with g0 = max(n_real - sw, 0)
                     tok = int(host_samples[slot, min(n_real, sw) - 1])
                     self._emit_token(seq, tok, now)
+                    rows += 1
             for seq in t.decodes:
                 self._accept_speculative(seq, host_samples[seq.slot], now)
+            self._flush_tick_telemetry(
+                emit, rows, self.spec_drafted_tokens - drafted,
+                self.spec_accepted_tokens - accepted)
+
+    def _flush_tick_telemetry(self, emit_span, rows: int, drafted: int,
+                              accepted: int) -> None:
+        """What the tick's rows emitted, counted once a tick where it was
+        once a row or a token: the counters take their sums, the
+        inter-token histogram one bulk observation. ``rows``: the rows
+        emitted for (chunk rows that finished their prompt, decode rows);
+        the speculative counters move only in a tick that drafted."""
+        tokens, prefilled = self._tick_tokens, self._tick_prefilled
+        itl, self._tick_itl = self._tick_itl, []
+        self._tick_tokens = self._tick_prefilled = 0
+        if self.warmup_mode:
+            return
+        emit_span.annotate(rows=rows, tokens=tokens)
+        if prefilled:
+            self.prefilled_tokens += prefilled
+            self._counter("serve_prefill_tokens_total").inc(prefilled)
+        if tokens:
+            self._counter("serve_tokens_generated_total").inc(tokens)
+        if itl:
+            self._histogram("serve_itl_seconds").observe_many(itl)
+        if drafted:
+            self._counter("serve_spec_drafted_tokens_total").inc(drafted)
+        if accepted:
+            self._counter("serve_spec_accepted_tokens_total").inc(accepted)
+
+    def _annotate_mixed(self, mixed_span, width: int, tokens: int, ctx,
+                        new_lens, multi: int) -> None:
+        """What a counted tick says of itself on ``serve.mixed`` and in
+        the per-tick counters: ``tokens`` real tokens at ``width``, the
+        rows' ``ctx`` / ``new_lens`` (the operand's own views), ``multi``
+        rows that bring more than one token to recurrent lines. Called
+        once the program is issued: nothing here depends on its result,
+        and the row is written when the span closes."""
+        np = self._np
+        # rows that hold a visible slot and the paged kernel's tiles
+        # among them: of a call's kv_tiles fetches, kv_rows - 1 are
+        # first tiles that start under another row's fold
+        held = ctx + new_lens
+        # the predicate of the program's sampler (sample_rows), known
+        # before the call: a freed slot's temperature is 0
+        sampled_rows = int(np.count_nonzero(self._temp > 0.0))
+        mixed_span.annotate(
+            width=width, tokens=tokens,
+            sampled_rows=sampled_rows,
+            kv_rows=int(np.count_nonzero(held)),
+            kv_tiles=int((-(-held // self._kv_tile)).sum()),
+        )
+        self.sampled_ticks += sampled_rows > 0
+        self._counter(
+            "serve_sampler_ticks_total",
+            path="sampled" if sampled_rows else "greedy",
+        ).inc()
+        # rows whose per-slot lines advanced, in every layer of a kind
+        rows = int(np.count_nonzero(new_lens))
+        for kind, lines in self.line_layers.items():
+            mixed_span.annotate(**{f"{kind}_rows": rows,
+                                   f"{kind}_lines": lines})
+            self._counter(
+                f"serve_{kind}_state_updates_total").inc(rows * lines)
+        if self.ssm_lines:
+            # the form that advanced them (nn/mamba.py): at the full
+            # width whole rows, below it a step or a gathered chunk
+            mixed_span.annotate(
+                ssm_step_rows=rows - multi, ssm_chunk_rows=multi)
+            paths = ({"whole": rows} if width == self.config.mixed_widths[-1]
+                     else {"step": rows - multi, "chunk": multi})
+            for path, count in paths.items():
+                self._counter("serve_ssm_rows_total", path=path).inc(
+                    count * self.ssm_lines)
+        if self.latent_layers:
+            # what a latent layer's attention reads and multiplies
+            # this tick: the lines of its rows (context + new), and
+            # the (query, visible line) pairs
+            n_new = new_lens.astype(np.int64)
+            lines = int(held.sum())
+            mixed_span.annotate(
+                latent_layers=self.latent_layers, latent_lines=lines,
+                latent_pairs=int(
+                    (n_new * ctx + n_new * (n_new + 1) // 2).sum()))
+            self._counter("serve_latent_lines_read_total").inc(
+                lines * self.latent_layers)
+        if self.par_lines:
+            mixed_span.annotate(par_lines=self.par_lines)
+            self._counter("serve_parallel_mixer_passes_total").inc(
+                self.par_lines)
+        if self.num_experts:
+            # the rows the tick's expert matmuls were given; with
+            # serve_moe_assignments_total (the real, held assignments
+            # they are for) the share of them that is real work
+            path, moe_rows, _ = self._moe_rows[width]
+            mixed_span.annotate(moe_rows=moe_rows)
+            self._counter("serve_moe_rows_total", path=path).inc(
+                moe_rows)
+        if self.loop_steps > 1:
+            mixed_span.annotate(loop_steps=self.loop_steps)
+            self._counter("serve_loop_layer_passes_total").inc(
+                self.loop_steps * self.pools.num_layers)
+        self.mixed_ticks[width] = self.mixed_ticks.get(width, 0) + 1
+        self._counter("serve_mixed_ticks_total", width=width).inc()
 
     def _record_moe_load(self, load, emit_span, bounded: bool) -> None:
         """One tick's (E,) assignments of real positions, summed over the
@@ -1083,16 +1143,9 @@ class ServeEngine:
         accepted = min(matched, len(emitted) - 1)
         if self.warmup_mode:
             draft = []
+        # the counters take the tick's sums (_flush_tick_telemetry)
         self.spec_drafted_tokens += len(draft)
         self.spec_accepted_tokens += accepted if draft else 0
-        if draft:
-            self._counter("serve_spec_drafted_tokens_total").inc(
-                len(draft)
-            )
-            if accepted:
-                self._counter("serve_spec_accepted_tokens_total").inc(
-                    accepted
-                )
         seq.draft = []
         # KV validity: the context held the last token's write, plus one
         # slot per accepted draft — rejected drafts' slots are simply
@@ -1123,13 +1176,10 @@ class ServeEngine:
                     queue_s=seq.admitted_s - arrival,
                     prompt_tokens=len(seq.request.prompt),
                     req=seq.request.req_id, **self._replica_fields)
-        elif seq.token_stamps and not self.warmup_mode:
-            self._histogram("serve_itl_seconds").observe(
-                now - seq.token_stamps[-1]
-            )
+        elif seq.token_stamps:
+            self._tick_itl.append(now - seq.token_stamps[-1])
         seq.token_stamps.append(now)
-        if not self.warmup_mode:
-            self._counter("serve_tokens_generated_total").inc()
+        self._tick_tokens += 1
 
     def _finish(self, seq: Sequence, now: float) -> None:
         self.scheduler.finish(seq)  # row reset rides the freed-slot drain
@@ -1237,16 +1287,19 @@ class ServeEngine:
                                    chunks=len(t.prefills))
             if t.prefills or t.decodes:
                 self._run_mixed(t)
-            with self._span("serve.retire", step=step):
-                self._retire_tick(t)
+            with self._span("serve.retire", step=step) as retire_span:
+                finished = self._retire_tick(t)
+                if retire_span is not None:  # not warming up
+                    retire_span.annotate(finished=finished)
         return t
 
     def _schedule_tick(self, step: int, sched_span=None) -> Tick:
         """Everything a tick decides before its programs run. A tick
         whose allocations ran the prefix cache's LRU eviction says so on
         ``sched_span`` (``serve.schedule``): ``evict_ms`` inside
-        ``PrefixCache.evict``, the blocks ``evicted`` and the stale
-        entries of its LRU heap it skipped on the way, ``evict_stale``."""
+        ``PrefixCache.evict`` and the blocks ``evicted`` (the stale
+        entries its LRU heap skipped on the way are a lifetime total,
+        ``stats_snapshot()["evict_stale"]``)."""
         self._expire_deadlines(time.monotonic())
         if self.config.spec_k > 0:
             with self._span("serve.draft", step=step):
@@ -1267,15 +1320,14 @@ class ServeEngine:
                             **self._trace_fields(t.preempted)):
                 pass
         sched = self.scheduler
-        evict_s, evicted, stale = self._evict_flushed
+        evict_s, evicted = self._evict_flushed
         if sched.evict_seconds != evict_s:
-            now = (sched.evict_seconds, sched.evicted_blocks,
-                   sched.prefix_cache.stale_skipped)
+            now = (sched.evict_seconds, sched.evicted_blocks)
             self._evict_flushed = now
             if sched_span is not None:  # not warming up
                 sched_span.annotate(
                     evict_ms=round(1e3 * (now[0] - evict_s), 6),
-                    evicted=now[1] - evicted, evict_stale=now[2] - stale)
+                    evicted=now[1] - evicted)
         if sched.prefix_hit_tokens > self._prefix_hits_flushed:
             self._counter("serve_prefix_hit_tokens_total").inc(
                 sched.prefix_hit_tokens - self._prefix_hits_flushed
@@ -1295,14 +1347,17 @@ class ServeEngine:
             self._apply_cow(t.cow_pairs)
         return t
 
-    def _retire_tick(self, t: Tick) -> None:
-        """Everything a tick settles after its tokens are out."""
+    def _retire_tick(self, t: Tick) -> int:
+        """Everything a tick settles after its tokens are out; returns
+        how many requests it retired."""
         if len(t.prefills) > self.max_concurrent_prefills:
             self.max_concurrent_prefills = len(t.prefills)
         now = time.monotonic()
+        finished = 0
         for seq in list(t.prefills) + list(t.decodes):
             if seq.done and seq.slot is not None:
                 self._finish(seq, now)
+                finished += 1
         self._reset_rows(self.scheduler.drain_freed_slots())
         if self.journal is not None and self._journal_pending:
             # ONE append for every row's tick tokens (completions
@@ -1320,6 +1375,7 @@ class ServeEngine:
         self.tick_index += 1
         if self.tick_index % self.config.flush_interval == 0:
             self._reg.flush_step(self.tick_index)
+        return finished
 
     @property
     def spec_accept_rate(self) -> Optional[float]:
@@ -1375,11 +1431,9 @@ class ServeEngine:
             "spec_accepted_tokens": self.spec_accepted_tokens,
             "prefill_compiles": self.prefill_program_count,
             "max_concurrent_prefills": self.max_concurrent_prefills,
-            # by token width (JSON keys are strings): ticks run at it and
-            # the real tokens they held; 1 - tokens / (ticks * width) is
-            # the padding left
+            # by token width (JSON keys are strings): ticks run at it (the
+            # real tokens they held: serve.mixed's `tokens` beside `width`)
             "mixed_ticks": {str(w): c for w, c in self.mixed_ticks.items()},
-            "mixed_tokens": {str(w): c for w, c in self.mixed_tokens.items()},
             # host arrays a counted tick handed the device, running mean:
             # 1.0, the one packed operand (None before the first)
             "tick_operands": (
@@ -1416,18 +1470,41 @@ class ServeEngine:
         each ``serve.*`` span that lies inside one of this engine's last
         ``TICK_PHASES_TICKS`` ``serve.tick`` spans (same ``step``, inside
         it in time: an in-process fleet shares one recorder and its
-        replicas' steps collide). Nothing is kept per tick for this."""
+        replicas' steps collide). Two more that no span holds:
+        ``"unspanned"``, a tick minus its leaf phases (``LEAF_PHASES``;
+        what nests in one of those is its parent's), and ``"between"``,
+        one tick's end to the next's start where the engine had work (the
+        earlier tick left rows running, the later one ran a program):
+        the driver's loop around ``tick()``. Nothing is kept per tick
+        for this."""
         rows = obs.recorded_tail("serve.tick", TICK_PHASES_TICKS)
+        mine = [r for r in rows if r.name == "serve.tick"
+                and r.start_ns >= self._created_ns
+                and r.fields.get("replica") == self.replica_id]
         ticks = {r.step: (r.start_ns, r.start_ns + r.duration_ns)
-                 for r in rows if r.name == "serve.tick"
-                 and r.start_ns >= self._created_ns
-                 and r.fields.get("replica") == self.replica_id}
+                 for r in mine}
         by_name: Dict[str, List[int]] = {}
+        leaves: Dict[int, int] = dict.fromkeys(ticks, 0)
+        retired: Dict[int, int] = {}
         for r in rows:
             start, end = ticks.get(r.step, (1, 0))
             if (r.name.startswith("serve.") and start <= r.start_ns
                     and r.start_ns + r.duration_ns <= end):
                 by_name.setdefault(r.name, []).append(r.duration_ns)
+                if r.name in LEAF_PHASES:
+                    leaves[r.step] += r.duration_ns
+                if r.name == "serve.retire":
+                    retired[r.step] = r.fields.get("finished", 0)
+        if mine:
+            by_name["unspanned"] = [
+                r.duration_ns - leaves[r.step] for r in mine]
+        between = [
+            b.start_ns - a.start_ns - a.duration_ns
+            for a, b in zip(mine, mine[1:])
+            if b.step == a.step + 1 and b.fields["decodes"] + b.fields["chunks"]
+            and a.fields["decodes"] + a.fields["chunks"] > retired.get(a.step, 0)]
+        if between:
+            by_name["between"] = between
         return {name: round(statistics.median(d) / 1e6, 6)
                 for name, d in sorted(by_name.items())}
 

@@ -51,6 +51,22 @@ def test_kind_conflict_raises():
         reg.gauge("x")
 
 
+def test_observe_many_leaves_what_observing_each_in_order_leaves():
+    """One take of the lock a batch; buckets, count and the sum, to the
+    last bit (added in the same order), as after a call a value."""
+    values = [0.05, 0.5, 1e-9, 0.1, 5.0, 50.0, 0.3333333333333333, 0.1]
+    one, many = (MetricsRegistry().histogram("lat", buckets=(0.1, 1.0, 10.0))
+                 for _ in range(2))
+    for v in values:
+        one.observe(v)
+    many.observe_many(values[:3])
+    many.observe_many(iter(values[3:]))  # any iterable
+    many.observe_many([])
+    assert many.bucket_counts() == one.bucket_counts()
+    assert (many.sum, many.count) == (one.sum, one.count)  # ==, not approx
+    assert one.count == 8 and one.sum == pytest.approx(sum(values))
+
+
 def test_histogram_buckets_cumulative():
     reg = MetricsRegistry()
     h = reg.histogram("lat", buckets=(0.1, 1.0, 10.0))

@@ -330,8 +330,8 @@ def test_spans_counters_and_gauge_of_a_latent_model(kimi, tmp_path):
     total = 4 * PATTERN.count("moe") * sum(f["tokens"] for f in mixed)
     assert (capture.counters["serve_moe_assignments_total"]
             + capture.counters["serve_moe_absent_assignments_total"]) == total
-    from scaling_tpu.obs import get_registry
-    assert get_registry().gauge("serve_kv_line_bytes").value == engine.pools.line_bytes
+    # a constant of the pools: stats_snapshot() has it, no gauge (ISSUE 57)
+    assert engine.stats_snapshot()["kv_line_bytes"] == engine.pools.line_bytes
 
 
 def test_the_mixer_lies_in_the_attn_scope_and_its_kernel_has_its_own_name(kimi):

@@ -21,7 +21,7 @@ PHASES = ("serve.schedule", "serve.mixed.build", "serve.mixed.dispatch",
           "serve.mixed.wait", "serve.mixed", "serve.emit", "serve.retire",
           "serve.tick")  # the order in which one tick's spans close
 # what serve.schedule says of a tick that evicted, and of no other
-EVICT_FIELDS = {"evict_ms", "evicted", "evict_stale"}
+EVICT_FIELDS = {"evict_ms", "evicted"}
 
 
 @pytest.fixture(scope="module")
@@ -215,10 +215,11 @@ def test_eviction_is_on_the_schedule_span_only_in_the_ticks_that_evict(
     requests leave their prompt blocks in the prefix cache, a later one
     finds the free list short and the scheduler's one call of
     ``PrefixCache.evict`` runs. That tick's ``serve.schedule`` row carries
-    ``evict_ms``, ``evicted`` and ``evict_stale``; the others carry none
-    of them, and paid no clock read for it. The second prompt extends the
-    first, so the first's last block is pushed as a leaf, matched again
-    and given a child: the stale entry that ``evict`` has to skip."""
+    ``evict_ms`` and ``evicted``; the others carry neither, and paid no
+    clock read for it. The second prompt extends the first, so the first's
+    last block is pushed as a leaf, matched again and given a child: the
+    stale entry that ``evict`` has to skip, a lifetime total of
+    ``stats_snapshot()`` and no field of the span (ISSUE 57)."""
     e = make_engine(toy_inference, num_slots=1, num_blocks=8,
                     max_blocks_per_seq=4)
     since = time.monotonic_ns()
@@ -238,8 +239,7 @@ def test_eviction_is_on_the_schedule_span_only_in_the_ticks_that_evict(
     assert all(EVICT_FIELDS.isdisjoint(r.fields)
                for r in rows if r not in evicting)
     assert sum(r.fields["evicted"] for r in evicting) == sched.evicted_blocks
-    assert sum(r.fields["evict_stale"] for r in evicting) == \
-        sched.prefix_cache.stale_skipped
+    assert not any("evict_stale" in r.fields for r in rows)
     assert sum(r.fields["evict_ms"] for r in evicting) == pytest.approx(
         1e3 * sched.evict_seconds, abs=1e-5 * len(evicting))
     # the eviction lies inside the span that reports it
@@ -319,13 +319,33 @@ def test_tick_phases_ms_is_the_median_of_each_phase_over_the_last_ticks(
         e.submit(p, 4)
     e.run_until_done()
     phases = e.stats_snapshot()["tick_phases_ms"]
-    assert sorted(phases) == sorted(PHASES)
+    # every span of a tick, and the two parts of the account that no span
+    # holds (ISSUE 57): a tick minus its leaves, and tick to tick
+    assert sorted(phases) == sorted(PHASES + ("between", "unspanned"))
     json.dumps(phases)  # the replica's stats RPC carries it
     rows = obs.recorded_spans(since_ns=since)
     for name in PHASES:
         mine = [r.duration_ns for r in rows if r.name == name]
         assert len(mine) == e.tick_index
         assert phases[name] == pytest.approx(median(mine) / 1e6, abs=1e-6)
+    ticks = [r for r in rows if r.name == "serve.tick"]
+    leaves = set(PHASES) - {"serve.tick", "serve.mixed"}
+    assert leaves == engine_module.LEAF_PHASES
+    unspanned = [t.duration_ns - sum(r.duration_ns for r in rows
+                                     if r.name in leaves and r.step == t.step)
+                 for t in ticks]
+    assert all(u >= 0 for u in unspanned)
+    assert phases["unspanned"] == pytest.approx(median(unspanned) / 1e6, abs=1e-6)
+    # run_until_done calls tick() back to back, and the engine has work
+    # from each tick to the next until the last retires the last request
+    retired = {r.step: r.fields["finished"] for r in rows if r.name == "serve.retire"}
+    assert sum(retired.values()) == len(PROMPTS) and retired[ticks[-1].step] > 0
+    between = [b.start_ns - a.start_ns - a.duration_ns
+               for a, b in zip(ticks, ticks[1:])
+               if a.fields["decodes"] + a.fields["chunks"] > retired[a.step]]
+    assert len(between) >= len(ticks) - len(PROMPTS) and min(between) >= 0
+    assert phases["between"] == pytest.approx(median(between) / 1e6, abs=1e-6)
+    assert phases["between"] + phases["unspanned"] < phases["serve.tick"]
     assert phases["serve.tick"] >= phases["serve.mixed"] >= phases["serve.mixed.wait"]
     # over the LAST ticks only: with room for 3, the first ticks fall out
     monkeypatch.setattr(engine_module, "TICK_PHASES_TICKS", 3)
