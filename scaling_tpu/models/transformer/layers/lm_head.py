@@ -22,6 +22,7 @@ from ....nn import (
     xavier_normal_init,
 )
 from ....nn.base_layer import multiplied
+from ....nn.linear import column_parallel_matmul
 from ....parallel.sharding import constrain, shard_logits, vocab_shards
 from ....topology.topology import MODEL_AXIS
 from ..config import EmbeddingHeadConfig, TransformerArchitectureConfig
@@ -189,7 +190,8 @@ class TransformerLMHead(BaseLayer):
         # without the layout its call would pin: the reference's gather, or
         # (data, seq, model) where stages put the vocabulary over (pipe, model)
         h = x["activations"]
-        logits = h @ params["linear"]["weight"].astype(h.dtype)
+        logits = column_parallel_matmul(
+            h, params["linear"]["weight"].astype(h.dtype), ctx)
         if logits.ndim == 3:
             logits = shard_logits(logits, ctx.mesh)
         if self.logit_mult is not None:

@@ -50,6 +50,20 @@ class ForwardContext:
     serving: bool = False
 
     _key_counter: int = 0
+    # the inputs of the TP regions this pass has entered through sequence
+    # parallelism's explicit collectives (nn/linear.py,
+    # ``column_parallel_matmul``), noted while tracing; kept, so that
+    # siblings that gather one input count as the one region they enter
+    _sp_region_inputs: list = field(default_factory=list)
+
+    def note_sp_region(self, x: jax.Array) -> None:
+        if not any(x is seen for seen in self._sp_region_inputs):
+            self._sp_region_inputs.append(x)
+
+    @property
+    def sp_manual_boundaries(self) -> int:
+        """How many TP regions this pass has entered by hand."""
+        return len(self._sp_region_inputs)
 
     def next_key(self) -> Optional[jax.Array]:
         """Derive a fresh dropout key; deterministic given call order."""

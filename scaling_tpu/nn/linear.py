@@ -4,10 +4,22 @@ Capability parity with the reference's Column/Row/VocabParallel layers
 (reference: src/scaling/core/nn/linear/column_parallel_linear.py:23,
 row_parallel_linear.py:16, vocab_parallel_embedding.py:19), re-designed for
 GSPMD: weights carry PartitionSpecs over the ``model`` mesh axis and
-activation sharding constraints make XLA emit the same collectives the
-reference hand-rolls (copy-to-region, all-gather, all-reduce,
-reduce-scatter-to-sequence-parallel). Weight layout is (in, out) —
-jnp convention — vs the reference's torch (out, in).
+activation sharding constraints make XLA emit the collectives the reference
+hand-rolls (copy-to-region, all-gather, all-reduce). Weight layout is
+(in, out) — jnp convention — vs the reference's torch (out, in).
+
+The one collective a constraint does NOT get is the reference's
+reduce-scatter-to-sequence-parallel. Asked for the SP layout behind a
+row-parallel matmul, this TPU compiler all-reduces the whole ``(b, s, h)``
+activation over the model axis and slices the rank's share of the sequence
+out of it, forward, and again for the cotangent of every column-parallel
+input, backward: twice the traffic of the reduce-scatter it was asked for.
+So under sequence parallelism a region's two boundaries are written out
+(``parallel/sharding.py``: ``sp_enter``, an all-gather feeding the
+column-parallel matmul inside one manual region; ``sp_leave``, the
+row-parallel matmul and a reduce-scatter), over the leading dimension of the
+2-D rows, the one form the compiler keeps; docs/PARALLELISM.md, "SP
+(Megatron)", says what was measured. Everything else here is a constraint.
 
 ``parallel_output`` / ``parallel_input`` keep the reference's fusion
 contract: a column-parallel with ``parallel_output=True`` feeds a
@@ -27,6 +39,9 @@ from ..parallel.sharding import (
     shard_activation_replicated_h,
     shard_activation_sp,
     shard_activation_tp,
+    sp_boundary_is_manual,
+    sp_enter,
+    sp_leave,
 )
 from ..topology.topology import DATA_AXIS, MODEL_AXIS
 from .base_layer import BaseLayer, ForwardContext
@@ -91,9 +106,7 @@ class ColumnParallelLinear(BaseLayer):
         return metas
 
     def __call__(self, params: dict, x: jax.Array, ctx: ForwardContext) -> jax.Array:
-        # entering the TP region: under SP the input arrives seq-sharded and
-        # XLA all-gathers it here (reference skips the copy op under SP)
-        y = x @ params["weight"].astype(x.dtype)
+        y = column_parallel_matmul(x, params["weight"].astype(x.dtype), ctx)
         if self.use_bias:
             y = y + params[self.bias_name].astype(x.dtype)
         if y.ndim == 3:
@@ -102,6 +115,22 @@ class ColumnParallelLinear(BaseLayer):
             else:
                 y = shard_activation_replicated_h(y, ctx.mesh)
         return y
+
+
+def column_parallel_matmul(x: jax.Array, weight: jax.Array, ctx: ForwardContext) -> jax.Array:
+    """``x @ weight`` for a column-parallel ``weight``: the matmul that ENTERS
+    a TP region. Under sequence parallelism ``x`` arrives sequence-sharded:
+    where the boundary can be written out (``sp_boundary_is_manual``) its
+    rows are all-gathered inside the matmul's own manual region, and ``ctx``
+    counts the region; elsewhere XLA gathers ``x`` where the matmul needs it
+    (the reference skips its copy op under SP). Siblings that share ``x``
+    (query, key and value; gate and up) each write the gather, and XLA keeps
+    one of them forward and one reduce-scatter of their summed cotangents
+    backward (tests/core/test_chip_compile.py pins both counts)."""
+    if ctx.sequence_parallel and sp_boundary_is_manual(x.shape, ctx.mesh):
+        ctx.note_sp_region(x)
+        return sp_enter(x, weight, ctx.mesh)
+    return x @ weight
 
 
 class RowParallelLinear(BaseLayer):
@@ -125,7 +154,7 @@ class RowParallelLinear(BaseLayer):
         self.init_method = init_method
         self.bitfit_bias_name = bitfit_bias_name
         self.parallel_input = parallel_input
-        self.parallel_output = parallel_output  # True => reduce-scatter to SP
+        self.parallel_output = parallel_output  # True => under SP, leave in the SP layout
 
     @property
     def bias_name(self) -> str:
@@ -145,15 +174,23 @@ class RowParallelLinear(BaseLayer):
         return metas
 
     def __call__(self, params: dict, x: jax.Array, ctx: ForwardContext) -> jax.Array:
-        y = x @ params["weight"].astype(x.dtype)
-        if y.ndim == 3:
-            if self.parallel_output and ctx.sequence_parallel:
-                # leave the TP region into sequence-parallel layout:
-                # XLA lowers this to a reduce-scatter along seq
-                y = shard_activation_sp(y, ctx.mesh)
-            else:
-                # all-reduce over the model axis (partial sums -> full)
-                y = shard_activation_replicated_h(y, ctx.mesh)
+        weight = params["weight"].astype(x.dtype)
+        to_sp = self.parallel_output and ctx.sequence_parallel
+        if to_sp and sp_boundary_is_manual(x.shape, ctx.mesh):
+            # leave the TP region into the sequence-parallel layout by a
+            # reduce-scatter of the partial sums, written out
+            y = sp_leave(x, weight, ctx.mesh)
+        else:
+            y = x @ weight
+            if y.ndim == 3:
+                if to_sp:
+                    # the same layout by constraint, where a context or pipe
+                    # axis is in play: the TPU compiler makes it an all-reduce
+                    # of the whole activation and a slice (module docstring)
+                    y = shard_activation_sp(y, ctx.mesh)
+                else:
+                    # all-reduce over the model axis (partial sums -> full)
+                    y = shard_activation_replicated_h(y, ctx.mesh)
         if self.use_bias:
             y = y + params[self.bias_name].astype(x.dtype)
         return y

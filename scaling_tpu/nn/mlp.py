@@ -3,6 +3,9 @@
 (reference: src/scaling/core/nn/mlp.py:21-167) ``ParallelMLP`` is
 column-parallel -> activation -> row-parallel; ``ParallelSwiGLUMLP`` gates a
 silu branch against a linear branch before the row-parallel projection.
+Under sequence parallelism both leave in the SP layout, as the attention
+does: the norms, residuals and dropout between two regions sit in it, and
+nothing there reads a hidden-replicated output.
 ``io_features * intermediate_feature_factor`` must be a natural number —
 same contract as the reference, so configs produce identical shapes.
 """
@@ -29,7 +32,6 @@ class ParallelMLP(BaseLayer):
         dtype=None,
         init_method=xavier_normal_init,
         bitfit_bias_name: Optional[str] = None,
-        sequence_parallel_output: bool = False,
     ):
         import jax.numpy as jnp
 
@@ -47,7 +49,7 @@ class ParallelMLP(BaseLayer):
         self.dense_out = RowParallelLinear(
             intermediate, io_features, bias=bias, dtype=dtype,
             init_method=init_method, bitfit_bias_name=bitfit_bias_name,
-            parallel_input=True, parallel_output=sequence_parallel_output,
+            parallel_input=True, parallel_output=True,
         )
 
     def init(self, key: jax.Array) -> dict:
@@ -83,7 +85,6 @@ class ParallelSwiGLUMLP(BaseLayer):
         dtype=None,
         init_method=xavier_normal_init,
         bitfit_bias_name: Optional[str] = None,
-        sequence_parallel_output: bool = False,
         gate_multiplier: float = 1.0,
         down_multiplier: float = 1.0,
     ):
@@ -111,7 +112,7 @@ class ParallelSwiGLUMLP(BaseLayer):
         self.down_proj = RowParallelLinear(
             intermediate, io_features, bias=bias, dtype=dtype,
             init_method=init_method, bitfit_bias_name=bitfit_bias_name,
-            parallel_input=True, parallel_output=sequence_parallel_output,
+            parallel_input=True, parallel_output=True,
         )
 
     def init(self, key: jax.Array) -> dict:

@@ -448,6 +448,11 @@ class ParallelModule:
         shards = self.loss_vocab_shards()
         get_registry().gauge("train_loss_vocab_shards").set(shards)
         logger.info(f"train step: the loss runs over {shards} vocabulary shard(s)")
+        # how many TP regions the traced step enters through sequence
+        # parallelism's explicit collectives (nn/linear.py); 0 until a trace
+        # says otherwise, and where SP is off or a constraint was kept
+        manual_boundaries = get_registry().gauge("train_sp_manual_boundaries")
+        manual_boundaries.set(0)
 
         scaler_enabled = optimizer.config.loss_scaler.enable
 
@@ -460,6 +465,7 @@ class ParallelModule:
             params = optimizer.freeze_frozen_params(params)
             ctx = self._make_ctx(deterministic=False, dropout_key=dropout_key)
             out = self.forward(params, mb, ctx)
+            manual_boundaries.set(ctx.sp_manual_boundaries)
             loss, metrics = loss_function(out, mb)
             scaled = loss.astype(jnp.float32) / gas
             if scaler_enabled:
@@ -494,6 +500,9 @@ class ParallelModule:
                 loss_scale,
             )
             zero_metrics = jax.tree.map(lambda m: jnp.zeros((), jnp.float32), metrics0)
+            logger.info(
+                f"train step: {int(manual_boundaries.value)} tensor-parallel "
+                "region(s) entered through explicit collectives")
 
             if gas == 1:
                 (grads, loss_sum, metrics_sum), _ = body(

@@ -15,6 +15,7 @@ import math
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..topology.topology import CONTEXT_AXIS, DATA_AXIS, MODEL_AXIS, PIPE_AXIS
@@ -120,6 +121,91 @@ def shard_activation_sp(x: jax.Array, mesh: Optional[Mesh]) -> jax.Array:
     seq = _seq_axis(mesh)
     sp_axes = (seq, MODEL_AXIS) if seq else MODEL_AXIS
     return constrain(x, mesh, DATA_AXIS, sp_axes, None)
+
+
+def sp_boundary_is_manual(x_shape: tuple, mesh: Optional[Mesh]) -> bool:
+    """Whether a ``(b, s, ...)`` activation crosses the boundary of a TP region
+    under sequence parallelism through :func:`sp_enter` / :func:`sp_leave`:
+    a model axis wider than 1 that alone shards the sequence (no context
+    axis in play), no pipe axis in play (inside the spatial pipeline the
+    operands are already stage-local, as for ops/flash_attention.py's
+    ``_tp_shardable``), batch and sequence divisible. Everything else keeps
+    :func:`shard_activation_sp`'s constraint: the layouts between regions are
+    the same, so entering by one form and leaving by the other is legal."""
+    if not _axis_in_mesh(mesh, MODEL_AXIS) or mesh.shape[MODEL_AXIS] <= 1:
+        return False
+    if any(_axis_in_mesh(mesh, axis) and mesh.shape[axis] > 1
+           for axis in (PIPE_AXIS, CONTEXT_AXIS)):
+        return False
+    if len(x_shape) != 3:
+        return False
+    return (x_shape[0] % mesh.shape[DATA_AXIS] == 0
+            and x_shape[1] % mesh.shape[MODEL_AXIS] == 0)
+
+
+def _rows_first(x: jax.Array) -> jax.Array:
+    """Local ``(b, s, h)`` -> ``(s, b * h)``: the sequence LEADS a 2-D array,
+    the one form in which this TPU compiler keeps a reduce-scatter (and its
+    transpose, the all-gather) what it is. Along dimension 1 of the 3-D
+    array it rewrites a written-out ``psum_scatter`` to all-reduce + slice
+    (tests/core/test_chip_compile.py,
+    ``test_pharia_train_step_crosses_tp_regions_by_reduce_scatter``). At
+    local batch 1 this is a reshape and moves nothing."""
+    b, s, h = x.shape
+    return jnp.swapaxes(x, 0, 1).reshape(s, b * h)
+
+
+def _batch_first(rows: jax.Array, b: int) -> jax.Array:
+    """``(s, b * h)`` -> ``(b, s, h)``, :func:`_rows_first`'s inverse."""
+    s = rows.shape[0]
+    return jnp.swapaxes(rows.reshape(s, b, -1), 0, 1)
+
+
+def sp_enter(x: jax.Array, weight: jax.Array, mesh: Mesh) -> jax.Array:
+    """ENTER a TP region under sequence parallelism: ``x`` ``(b, s, h)`` in
+    the SP layout (sequence over the model axis) against the column-parallel
+    ``weight`` ``(h, n)``; returns ``x @ weight`` ``(b, s, n)`` in the TP
+    layout (:func:`shard_activation_tp`'s).
+
+    The all-gather of the rows over the model axis and the matmul it feeds
+    are ONE manual region, so that the backward is the local ``dy @ w^T`` and
+    then a reduce-scatter. A gather in a region of its own, with the matmul
+    left to GSPMD, keeps GSPMD's backward all-reduce of the whole activation
+    and adds a reduce-scatter behind it. The gathered rows are a saved
+    residual (the weight gradient reads them)."""
+    assert sp_boundary_is_manual(x.shape, mesh), (x.shape, mesh)
+
+    def region(x, weight):
+        rows = jax.lax.all_gather(_rows_first(x), MODEL_AXIS, axis=0, tiled=True)
+        return _batch_first(rows, x.shape[0]) @ weight
+
+    return jax.shard_map(
+        region, mesh=mesh,
+        in_specs=(P(DATA_AXIS, MODEL_AXIS, None), P(None, MODEL_AXIS)),
+        out_specs=P(DATA_AXIS, None, MODEL_AXIS),
+    )(x, weight)
+
+
+def sp_leave(x: jax.Array, weight: jax.Array, mesh: Mesh) -> jax.Array:
+    """LEAVE a TP region under sequence parallelism: ``x`` ``(b, s, k)`` in the
+    TP layout against the row-parallel ``weight`` ``(k, h)``; returns ``x @
+    weight`` ``(b, s, h)`` in the SP layout. The local matmul's partial sums
+    are reduce-scattered over the leading dimension of their 2-D rows, each
+    rank keeping its share of the sequence: half an all-reduce's traffic,
+    where a sharding constraint here compiles to the whole all-reduce and a
+    slice (:func:`_rows_first`)."""
+    assert sp_boundary_is_manual(x.shape, mesh), (x.shape, mesh)
+
+    def region(x, weight):
+        rows = jax.lax.psum_scatter(
+            _rows_first(x @ weight), MODEL_AXIS, scatter_dimension=0, tiled=True)
+        return _batch_first(rows, x.shape[0])
+
+    return jax.shard_map(
+        region, mesh=mesh,
+        in_specs=(P(DATA_AXIS, None, MODEL_AXIS), P(MODEL_AXIS, None)),
+        out_specs=P(DATA_AXIS, MODEL_AXIS, None),
+    )(x, weight)
 
 
 def shard_param(x: jax.Array, mesh: Optional[Mesh], spec: tuple) -> jax.Array:
