@@ -226,6 +226,8 @@ class FixedMultipliers(BaseConfig):
 # what sizes a 'latent' layer (nn/latent_attention.py)
 LATENT_FIELDS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
                  "qk_rope_head_dim", "v_head_dim")
+# what makes the 'latent' layers SPARSE (nn/sparse_latent_attention.py)
+INDEX_FIELDS = ("index_n_heads", "index_head_dim", "index_topk")
 
 
 class TransformerArchitectureConfig(BaseConfig):
@@ -336,11 +338,13 @@ class TransformerArchitectureConfig(BaseConfig):
         "all of them", gt=0,
     )
     moe_n_group: int = Field(
-        1, description="groups the router's experts are divided into "
-        "(n_group); only 1 is built: no group-limited choice", ge=1)
+        1, description="contiguous groups the 'sigmoid_bias' router's "
+        "moe_num_experts are divided into (n_group); above 1 the choice is "
+        "group-limited: a group scores the sum of its two largest s_e + b_e "
+        "and a token chooses inside its moe_topk_group best groups", ge=1)
     moe_topk_group: int = Field(
         1, description="groups a token may choose its experts from "
-        "(topk_group); only 1 is built", ge=1)
+        "(topk_group)", ge=1)
     q_lora_rank: Optional[int] = Field(
         None, description="a 'latent' layer: width of the query latent "
         "(x W_DQ, RMSNorm'd, then up to the heads' nope + rope queries)", gt=0)
@@ -358,6 +362,19 @@ class TransformerArchitectureConfig(BaseConfig):
     rope_scaling: Optional[RopeScalingConfig] = Field(
         None, description="the checkpoint's rope_scaling (YaRN alone is "
         "built, nn/rotary.py); applied by 'latent' layers")
+    index_n_heads: Optional[int] = Field(
+        None, description="a SPARSE 'latent' layer "
+        "(nn/sparse_latent_attention.py): heads of the indexer that scores "
+        "every cached line for every query; with index_head_dim and "
+        "index_topk, all three or none", gt=0)
+    index_head_dim: Optional[int] = Field(
+        None, description="width of an indexer head and of the ONE index key "
+        "a token leaves in the cache (the third leaf of its line); its first "
+        "qk_rope_head_dim lanes are rotary", gt=0)
+    index_topk: Optional[int] = Field(
+        None, description="lines a query attends over: its index_topk best "
+        "by the indexer's scores, chosen exactly; every visible line while "
+        "there are no more than that", gt=0)
     layer_pattern: Optional[List[LayerKind]] = Field(
         None,
         description="a kind a layer: each layer is ONE norm, ONE mixer of its "
@@ -551,6 +568,15 @@ class TransformerArchitectureConfig(BaseConfig):
                 "attention_head_dim with lora_config: the LoRA modules are "
                 "sized from hidden_size; not supported"
             )
+        given = [name for name in INDEX_FIELDS if getattr(self, name) is not None]
+        if given and (len(given) < len(INDEX_FIELDS)
+                      or LayerKind.LATENT not in (self.layer_pattern or ())):
+            raise ValueError(
+                f"{given} without {[n for n in INDEX_FIELDS if n not in given]}"
+                " or without 'latent' layers: the indexer of a sparse latent "
+                "attention layer is sized by index_n_heads, index_head_dim "
+                "and index_topk together, and only a layer_pattern's "
+                "'latent' layers have one")
         if self.layer_pattern is not None:
             self._validate_pattern()
         elif self.rope_scaling is not None:
@@ -558,11 +584,19 @@ class TransformerArchitectureConfig(BaseConfig):
                 "rope_scaling without layer_pattern: only a pattern stack's "
                 "'latent' layers apply YaRN")
         if self.moe_n_group > 1 or self.moe_topk_group > 1:
-            raise ValueError(
-                f"moe_n_group {self.moe_n_group} / moe_topk_group "
-                f"{self.moe_topk_group}: the group-limited choice (top "
-                "experts from the topk_group best of n_group groups) is not "
-                "built; the router chooses among all experts (1 / 1)")
+            per_group = self.moe_num_experts // self.moe_n_group
+            if (self.moe_router != MoERouter.SIGMOID_BIAS
+                    or self.moe_num_experts % self.moe_n_group
+                    or self.moe_topk_group > self.moe_n_group
+                    or per_group < 2
+                    or self.moe_top_k > self.moe_topk_group * per_group):
+                raise ValueError(
+                    f"moe_n_group {self.moe_n_group} / moe_topk_group "
+                    f"{self.moe_topk_group}: the group-limited choice is the "
+                    "'sigmoid_bias' router's, over moe_num_experts "
+                    f"({self.moe_num_experts}) in whole groups of at least "
+                    "two, moe_topk_group <= moe_n_group of them holding "
+                    f"moe_top_k ({self.moe_top_k}) experts")
         if self.mlp_type == MLPType.MOE:
             if self.moe_top_k > self.moe_num_experts:
                 raise ValueError(
@@ -702,6 +736,12 @@ class TransformerArchitectureConfig(BaseConfig):
                     "relative_position_embedding_type "
                     f"{self.relative_position_embedding_type.value!r}: a "
                     "latent head's position is its rotary slice; use 'rotary'")
+            if (self.index_head_dim is not None
+                    and self.index_head_dim < self.qk_rope_head_dim):
+                raise ValueError(
+                    f"index_head_dim {self.index_head_dim} is narrower than "
+                    f"qk_rope_head_dim {self.qk_rope_head_dim}: an indexer "
+                    "head's first qk_rope_head_dim lanes are rotary")
         elif self.rope_scaling is not None:
             raise ValueError(
                 "rope_scaling without 'latent' layers: only the latent "
@@ -712,6 +752,11 @@ class TransformerArchitectureConfig(BaseConfig):
     def latent_layers(self) -> int:
         """Layers whose mixer is latent attention."""
         return (self.layer_pattern or []).count(LayerKind.LATENT)
+
+    @property
+    def sparse_layers(self) -> int:
+        """Latent layers that attend over an indexer's choice of lines."""
+        return self.latent_layers if self.index_topk is not None else 0
 
     @property
     def moe_held(self) -> int:

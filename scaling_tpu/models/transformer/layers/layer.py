@@ -33,6 +33,7 @@ from ....nn.attention import PagedKVCacheView
 from ....nn.base_layer import multiplied
 from ....nn.rotary import RotaryConfig
 from ....nn.latent_attention import LatentSelfAttention
+from ....nn.sparse_latent_attention import SparseLatentSelfAttention
 from ....nn.mamba import Mamba2Mixer
 from ....nn.short_conv import GatedShortConv
 from ..config import (
@@ -101,6 +102,8 @@ def routed_mlp(arch: TransformerArchitectureConfig) -> BaseLayer:
         shared_expert_width=arch.moe_shared_expert_width,
         experts_first=arch.moe_experts_first,
         experts_held=arch.moe_experts_held,
+        n_group=arch.moe_n_group,
+        topk_group=arch.moe_topk_group,
     )
 
 
@@ -164,7 +167,13 @@ class MixerLayer(BaseLayer):
         elif self.kind == LayerKind.MLP:
             self.mixer = dense_mlp(arch)
         elif self.kind == LayerKind.LATENT:
-            self.mixer = LatentSelfAttention(
+            sparse = {} if arch.index_topk is None else dict(
+                index_n_heads=arch.index_n_heads,
+                index_head_dim=arch.index_head_dim,
+                index_topk=arch.index_topk)
+            self.mixer = (SparseLatentSelfAttention if sparse
+                          else LatentSelfAttention)(
+                **sparse,
                 hidden_size=arch.hidden_size,
                 num_attention_heads=arch.num_attention_heads,
                 q_lora_rank=arch.q_lora_rank,

@@ -148,7 +148,9 @@ class LatentSelfAttention(BaseLayer):
     def _latents(self, params: dict, x: jax.Array, ctx: ForwardContext,
                  position_ids):
         """``(q_nope (b, s, n, nope), q_rope (b, s, n, rope), c_kv (b, s,
-        kv_lora_rank), k_r (b, s, rope))``, norms and rotary applied."""
+        kv_lora_rank), k_r (b, s, rope), c_q (b, s, q_lora_rank))``, norms and
+        rotary applied; ``c_q`` is the normed query latent the heads' queries
+        were projected from (a sparse layer's indexer reads it too)."""
         b, s, _ = x.shape
         n = self.num_heads
         c_q = self.q_a_norm(
@@ -162,7 +164,7 @@ class LatentSelfAttention(BaseLayer):
         k_r = kv[..., self.kv_lora_rank:][:, :, None, :]   # ONE key, no head
         q_rope, k_r = self.rotary_embedding(
             q_rope, k_r, position_ids, position_ids)
-        return q_nope, q_rope, c_kv, k_r[:, :, 0, :]
+        return q_nope, q_rope, c_kv, k_r[:, :, 0, :], c_q
 
     def _up_weights(self, params: dict, dtype):
         """``(W_UK (kv_lora_rank, n, nope), W_UV (kv_lora_rank, n, v))``: two
@@ -189,18 +191,35 @@ class LatentSelfAttention(BaseLayer):
         return_kv: bool = False,
     ):
         b, s, _ = x.shape
-        n = self.num_heads
-        q_nope, q_rope, c_kv, k_r = self._latents(params, x, ctx, position_ids)
+        q_nope, q_rope, c_kv, k_r, _ = self._latents(
+            params, x, ctx, position_ids)
         if isinstance(kv_cache, PagedKVCacheView):
             out, new_view = self._paged_attention(
                 params, q_nope, q_rope, c_kv, k_r, kv_cache, ctx)
             return self.dense(params["dense"], out, ctx), new_view
+        self._refuse_dense_cache(kv_cache)
+        if segment_ids is None:
+            segment_ids = jnp.zeros((b, s), dtype=jnp.int32)
+        mask = segment_ids_to_mask(segment_ids, None, causal=True,
+                                   positions_q=None, positions_k=None)
+        y = self._expanded(params, q_nope, q_rope, c_kv, k_r, mask, ctx)
+        if return_kv:
+            return y, self._line(c_kv, k_r)
+        return y
+
+    @staticmethod
+    def _refuse_dense_cache(kv_cache):
         if kv_cache is not None:
             raise ValueError(
                 "a latent attention layer takes a PagedKVCacheView (the "
                 "serving engine's pool), not a dense cache: cached generate() "
                 "is not built for it; use use_cache=False or ServeEngine")
-        # --- expanded: every head's keys and values from the latent
+
+    def _expanded(self, params, q_nope, q_rope, c_kv, k_r, forbidden, ctx):
+        """The expanded form: every head's keys and values from the latent,
+        the softmax under ``forbidden`` (b, 1, s, s), the output projection:
+        ``(b, s, hidden)``."""
+        b, s, n = *q_nope.shape[:2], self.num_heads
         kv = self.kv_b_proj(params["kv_b_proj"], c_kv, ctx).reshape(
             b, s, n, self.nope + self.v_dim)
         k = jnp.concatenate([
@@ -208,17 +227,10 @@ class LatentSelfAttention(BaseLayer):
             jnp.broadcast_to(k_r[:, :, None, :], (b, s, n, self.rope)),
         ], axis=-1)
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
-        if segment_ids is None:
-            segment_ids = jnp.zeros((b, s), dtype=jnp.int32)
-        mask = segment_ids_to_mask(segment_ids, None, causal=True,
-                                   positions_q=None, positions_k=None)
         out = multi_head_attention(
-            q, k, kv[..., self.nope:], mask, self.scaling_factor,
+            q, k, kv[..., self.nope:], forbidden, self.scaling_factor,
             self.masked_softmax)
-        y = self.dense(params["dense"], out.reshape(b, s, n * self.v_dim), ctx)
-        if return_kv:
-            return y, self._line(c_kv, k_r)
-        return y
+        return self.dense(params["dense"], out.reshape(b, s, n * self.v_dim), ctx)
 
     def _paged_attention(self, params, q_nope, q_rope, c_kv, k_r,
                          view: PagedKVCacheView, ctx: ForwardContext):

@@ -63,7 +63,11 @@ Further facts of a model, all from its configuration: ``router``
 (``'softmax'``, or ``'sigmoid_bias'``: every expert's ``s_e = sigmoid(logit_e)``
 on its own, the k with the largest ``s_e + b_e`` chosen, ``b`` a float32
 selection bias that takes part in the CHOICE only, the gates ``scale * s_e /
-(sum of the chosen s + norm_topk_eps)``: 1e-20 in Nemotron-H, 1e-6 in LFM2),
+(sum of the chosen s + norm_topk_eps)``: 1e-20 in Nemotron-H, 1e-6 in LFM2;
+with ``n_group > 1`` the choice is GROUP-LIMITED: the experts lie in
+``n_group`` contiguous groups, a group's score is the sum of its two largest
+``s_e + b_e``, and the k are chosen inside the ``topk_group`` best groups:
+``_group_limited``, over all ``num_experts`` outputs whatever share is held),
 ``glu`` (false: two matrices an expert,
 ``act(x W_in) W_out``), and a ``shared expert`` of a width of its own that
 every token runs, added once inside the ``moe`` scope.
@@ -147,6 +151,8 @@ class ParallelMoEMLP(BaseLayer):
         shared_expert_width: Optional[int] = None,
         experts_first: int = 0,
         experts_held: Optional[int] = None,
+        n_group: int = 1,
+        topk_group: int = 1,
     ):
         dtype = dtype or jnp.float32
         if intermediate is None:
@@ -154,7 +160,15 @@ class ParallelMoEMLP(BaseLayer):
             assert float(intermediate) == io_features * intermediate_feature_factor
         assert 1 <= top_k <= num_experts
         assert router in ("softmax", "sigmoid_bias"), router
+        assert n_group == 1 or (
+            router == "sigmoid_bias" and num_experts % n_group == 0
+            and 1 <= topk_group <= n_group
+            and top_k <= topk_group * (num_experts // n_group)
+            and num_experts // n_group >= 2), (
+            "a group-limited choice divides the sigmoid router's experts "
+            "into n_group groups of at least two", n_group, topk_group)
         self.router = router
+        self.n_group, self.topk_group = n_group, topk_group
         self.routed_scaling_factor = routed_scaling_factor
         self.shared_expert_width = shared_expert_width
         self.experts_first = experts_first
@@ -372,8 +386,10 @@ class ParallelMoEMLP(BaseLayer):
         if self.router == "sigmoid_bias":
             probs = jax.nn.sigmoid(logits)
             # the bias moves the CHOICE; the gates are the chosen s_e
-            _, gate_idx = jax.lax.top_k(
-                probs + params["router"]["bias"], self.top_k)
+            choice = probs + params["router"]["bias"]
+            if self.n_group > 1:
+                choice = self._group_limited(choice)
+            _, gate_idx = jax.lax.top_k(choice, self.top_k)
             gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
             if self.norm_topk_prob:
                 gate_vals = gate_vals / (
@@ -388,6 +404,27 @@ class ParallelMoEMLP(BaseLayer):
         if self.routed_scaling_factor != 1.0:
             gate_vals = gate_vals * self.routed_scaling_factor
         return probs, gate_vals, gate_idx
+
+    def _group_limited(self, choice: jax.Array) -> jax.Array:
+        """The choice scores ``(b, s, E)`` with every expert outside the
+        token's ``topk_group`` best groups at ``-inf``: a group's score is
+        the sum of its two largest choice scores, over ALL ``num_experts``
+        outputs in ``n_group`` contiguous groups, whatever share of them the
+        layer holds (DeepSeek-V3's ``noaux_tc``)."""
+        b, s, E = choice.shape
+        grouped = choice.reshape(b, s, self.n_group, E // self.n_group)
+        # the two largest of a group without a sort: the largest, and the
+        # largest of the rest (an equal score elsewhere in the group counts)
+        first = jnp.argmax(grouped, axis=-1, keepdims=True)
+        rest = jnp.where(
+            first == jnp.arange(E // self.n_group, dtype=first.dtype),
+            -jnp.inf, grouped)
+        group_score = grouped.max(axis=-1) + rest.max(axis=-1)
+        _, best = jax.lax.top_k(group_score, self.topk_group)
+        kept = jnp.any(
+            best[..., None] == jnp.arange(self.n_group, dtype=best.dtype),
+            axis=-2)                                       # (b, s, n_group)
+        return jnp.where(kept[..., None], grouped, -jnp.inf).reshape(b, s, E)
 
     def _experts(
         self, params: dict, x: jax.Array, gate_vals: jax.Array,

@@ -62,6 +62,7 @@ from .. import obs
 from ..logging import logger
 from ..nn.base_layer import state_views
 from ..nn.latent_paged_attention import latent_tile_tokens
+from ..nn.sparse_latent_attention import index_tile_tokens
 from ..nn.mamba import RecurrentStateView, split_capacity
 from ..nn.paged_attention import kernel_tile_tokens
 from ..resilience.faults import get_fault_plan
@@ -339,8 +340,24 @@ class ServeEngine:
                 "spec_k > 0 with latent attention layers: the latent kernel "
                 "folds a row of one token or a prompt chunk, and a decode row "
                 "with drafts has not been held to the reference; set spec_k=0")
-        # KV tokens a tile of the paged kernel holds, at a shard's heads
-        if self.pools.pool_k[0].ndim == 3:   # a line without a head axis
+        # of them the SPARSE ones (nn/sparse_latent_attention.py): a query
+        # attends over its index_topk best lines, chosen from index keys that
+        # are the second leaf of a line
+        self.sparse_layers = inference_module.architecture.sparse_layers
+        self.index_topk = inference_module.architecture.index_topk
+        if self.sparse_layers and self.config.enable_prefix_cache:
+            raise ValueError(
+                "enable_prefix_cache with sparse latent attention layers: a "
+                "prefix hit and a copy-on-write fork over a line that holds "
+                "the indexer's keys have not been held to the reference; set "
+                "enable_prefix_cache=False")
+        # KV tokens a tile of the paged kernel holds, at a shard's heads (a
+        # sparse latent layer: index keys one step of a row's score loop
+        # multiplies)
+        if self.sparse_layers:
+            self._kv_tile = index_tile_tokens(
+                self.config.block_size, self.config.max_blocks_per_seq)
+        elif self.pools.pool_k[0].ndim == 3:   # a line without a head axis
             self._kv_tile = latent_tile_tokens(
                 self.config.block_size, self.config.max_blocks_per_seq)
         else:
@@ -1059,6 +1076,31 @@ class ServeEngine:
                     (n_new * ctx + n_new * (n_new + 1) // 2).sum()))
             self._counter("serve_latent_lines_read_total").inc(
                 lines * self.latent_layers)
+        if self.sparse_layers:
+            # what a sparse layer's indexer scores and what its attention
+            # then reads: the index keys of the rows that bring tokens, the
+            # (query, visible line) pairs the indexer scores, and the pairs
+            # left after each query chose min(index_topk, what it sees)
+            n_new = new_lens.astype(np.int64)
+            seen = ctx.astype(np.int64)
+            pairs = n_new * seen + n_new * (n_new + 1) // 2
+            # a row's first `dense` new tokens still see no more than
+            # index_topk lines and choose them all
+            dense = np.clip(self.index_topk - seen, 0, n_new)
+            chosen = (dense * seen + dense * (dense + 1) // 2
+                      + (n_new - dense) * self.index_topk)
+            busy = held[new_lens > 0]
+            index_lines = int(busy.sum())
+            mixed_span.annotate(
+                sparse_layers=self.sparse_layers, index_lines=index_lines,
+                index_pairs=int(pairs.sum()), chosen_pairs=int(chosen.sum()),
+                # the least lines the attention reads: the union of a row's
+                # queries' choices is no smaller
+                chosen_lines=int(np.minimum(busy, self.index_topk).sum()))
+            self._counter("serve_index_lines_read_total").inc(
+                index_lines * self.sparse_layers)
+            self._counter("serve_sparse_chosen_pairs_total").inc(
+                int(chosen.sum()) * self.sparse_layers)
         if self.par_lines:
             mixed_span.annotate(par_lines=self.par_lines)
             self._counter("serve_parallel_mixer_passes_total").inc(
@@ -1454,6 +1496,7 @@ class ServeEngine:
             # layer: its KV latent and its rotary key's lane row)
             "kv_line_bytes": self.pools.line_bytes,
             "latent_layers": self.latent_layers,
+            "sparse_layers": self.sparse_layers,
             # layers that keep a line a slot (Mamba-2 mixers' recurrent state,
             # short convolutions' tails; 0: a model without them) and the
             # bytes of those lines

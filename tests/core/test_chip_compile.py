@@ -223,6 +223,97 @@ def test_latent_kernel_compiles_at_the_cells_size(one_chip, tokens):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def sparse_latent_layer(one_chip, tokens):
+    """``serve-dsv32-longdoc-burst``'s sparse latent mixer over the paged
+    pool at one of its engine's two token widths, compiled for the described
+    chip: ``(compiled, mixer)``. 16 rows of up to 320 positions, 128 heads,
+    lines of 640 (latent + rotary key) + 128 (index key) lanes, 2,048 blocks a
+    row."""
+    from scaling_tpu.nn.attention import PagedKVCacheView, packed_token_map
+    from scaling_tpu.nn.base_layer import ForwardContext
+    from scaling_tpu.nn.rotary import RotaryConfig
+    from scaling_tpu.nn.sparse_latent_attention import SparseLatentSelfAttention
+    from scaling_tpu.serve.engine import packed_batch_shape
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    rows, max_blocks, hidden, width = 16, 2048, 7168, 320
+    blocks = rows * max_blocks + 1
+    mixer = SparseLatentSelfAttention(
+        index_n_heads=64, index_head_dim=128, index_topk=2048,
+        hidden_size=hidden, num_attention_heads=128, q_lora_rank=1536,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, dtype=jnp.bfloat16,
+        rotary_config=RotaryConfig(dimensions=64, base=10000,
+                                   max_seq_length=32768))
+    params = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(mixer.init, jax.random.PRNGKey(0)))
+    batch = packed_batch_shape(tokens, width)
+
+    def layer(params, x, pool_c, pool_i, table, ctx_len, new_len):
+        token_map = packed_token_map(new_len, batch, width)
+        pos = ctx_len[token_map.row] + token_map.offset
+        view = PagedKVCacheView(
+            pool_k=pool_c, pool_v=pool_i, block_table=table,
+            context_len=ctx_len, new_len=new_len, token_map=token_map)
+        y, new = mixer(params, x, ForwardContext(serving=True,
+                                                 paged_kernel="pallas"),
+                       position_ids=pos, kv_cache=view)
+        return y, new.pool_k, new.pool_v
+
+    compiled = jax.jit(layer, donate_argnums=(2, 3)).lower(
+        params, shape((*batch, hidden)),
+        shape((blocks, BLOCK_SIZE, 640)), shape((blocks, BLOCK_SIZE, 128)),
+        shape((rows, max_blocks), jnp.int32), shape((rows,), jnp.int32),
+        shape((rows,), jnp.int32),
+    ).compile()
+    return compiled, mixer
+
+
+@pytest.mark.parametrize("tokens", [1024, 5120], ids=["small", "full"])
+def test_sparse_latent_layer_compiles_at_the_cells_size(one_chip, tokens):
+    """The row walk at both token widths of the cell's engine: index keys
+    gathered through the table, scores key tile by key tile, each query's
+    EXACT choice of 2,048 of up to 32,768 lines as a threshold found by
+    bisection (no sort, no approximate top-k in the compiled program), the
+    row's latent tiles streamed under the mask into an online softmax. It
+    compiles for the chip, and a layer's temporaries stay well inside what
+    weights (7.65 GB) and pool (4.83 GB) leave of the chip's 16 GB."""
+    compiled, mixer = sparse_latent_layer(one_chip, tokens)
+    text = compiled.as_text()
+    assert "approx" not in text.lower() and not re.search(r" sort\(|topk", text, re.I)
+    assert " while(" in text and " conditional(" in text
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 2.5e9, memory.temp_size_in_bytes
+    # both leaves are scattered into in place
+    assert memory.alias_size_in_bytes >= (16 * 2048 + 1) * 16 * 768 * 2
+
+
+@pytest.mark.parametrize("window", [4096, 32768], ids=["eighth", "whole"])
+def test_masked_latent_kernel_compiles_at_the_cells_size(one_chip, window):
+    """``serve-dsv32-longdoc-burst``'s chunk rows (nn/masked_latent_attention
+    .py): 320 positions x 128 heads against a row's window of 640-lane lines
+    under a per-query mask, at the smallest and the largest window the row walk
+    uses. The layer's compile above interprets the kernel (this process's
+    backend is the CPU): Mosaic is asked here."""
+    from scaling_tpu.nn.masked_latent_attention import masked_latent_attention
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def attend(q_line, lines, chosen, seen):
+        return masked_latent_attention(
+            q_line, lines, chosen, seen, lat=512, sm_scale=0.135234,
+            interpret=False)
+
+    compiled = jax.jit(attend).lower(
+        shape((320, 128, 640)), shape((window, 640)),
+        shape((320, window), jnp.bool_), shape((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def lowered_mixed_program(one_chip, monkeypatch, bucket, heads, kv_heads,
                           layers=2, kv_layers=None, engine=None,
                           **architecture):

@@ -427,3 +427,108 @@ def test_the_grouped_kernel_under_the_interpreter_equals_ragged_dot(
     assert grouped_tiles(1024, 2048, 1536, 64) == (32, 1536)
     assert grouped_tiles(4096, 2048, 1024, 64) == (128, 1024)
     assert grouped_tiles(1024, 8192, 8192, 64)[1] % 128 == 0
+
+
+# ---- the group-limited choice (DeepSeek-V3's noaux_tc: n_group, topk_group) --
+
+def group_limited_by_hand(choice, n_group, topk_group, top_k):
+    """The chosen experts of one token, in numpy: a group scores the sum of
+    its two largest, the best groups stay (a tie to the lower group), the
+    ``top_k`` largest inside them are chosen."""
+    groups = choice.reshape(n_group, -1)
+    score = np.sort(groups, axis=1)[:, -2:].sum(axis=1)
+    kept = np.argsort(-score, kind="stable")[:topk_group]
+    masked = np.full_like(groups, -np.inf)
+    masked[kept] = groups[kept]
+    return sorted(np.argsort(-masked.reshape(-1), kind="stable")[:top_k].tolist())
+
+
+def grouped_layer(first=0, held=None, n_group=8, topk_group=4, **kw):
+    return make_layer(num_experts=32, top_k=6, router="sigmoid_bias",
+                      routed_scaling_factor=2.5, norm_topk_eps=1e-20,
+                      shared_expert_width=16, experts_first=first,
+                      experts_held=held, n_group=n_group, topk_group=topk_group,
+                      **kw)
+
+
+@pytest.fixture(scope="module")
+def grouped_params():
+    params = grouped_layer().init(jax.random.PRNGKey(0))
+    params["router"]["weight"] = 30 * params["router"]["weight"]
+    params["router"]["bias"] = 0.2 * jax.random.normal(jax.random.PRNGKey(1), (32,))
+    return params
+
+
+@pytest.mark.parametrize("n_group,topk_group", [(8, 4), (4, 2), (8, 2), (2, 1), (8, 8)])
+def test_the_group_limited_choice_is_the_one_by_hand(grouped_params, n_group, topk_group):
+    """Every token's chosen experts lie in its ``topk_group`` best groups and
+    are the ones the equations give; the gates are the chosen scores' share
+    (the bias moves the choice alone)."""
+    layer = grouped_layer(n_group=n_group, topk_group=topk_group)
+    x = jax.random.normal(jax.random.PRNGKey(2), (B, S, H))
+    probs, gates, idx = layer._route(grouped_params, x)
+    choice = np.asarray(probs) + np.asarray(grouped_params["router"]["bias"])
+    for b in range(B):
+        for s in range(S):
+            want = group_limited_by_hand(choice[b, s], n_group, topk_group, 6)
+            assert sorted(np.asarray(idx[b, s]).tolist()) == want
+            assert len({e // (32 // n_group) for e in want}) <= topk_group
+    picked = np.take_along_axis(np.asarray(probs), np.asarray(idx), axis=-1)
+    np.testing.assert_allclose(
+        gates, 2.5 * picked / picked.sum(-1, keepdims=True), rtol=1e-6)
+    if (n_group, topk_group) != (8, 8):   # every group kept: no limit at all
+        plain, *_ = (np.asarray(a) for a in grouped_layer(n_group=1, topk_group=1)._route(
+            grouped_params, x)[2:])
+        assert (np.sort(plain, -1) != np.sort(np.asarray(idx), -1)).any()
+
+
+def test_one_group_lowers_the_program_it_lowered_before(grouped_params):
+    """``n_group`` 1 (every routed configuration the benchmark had) takes the
+    old path: no second ``top_k``, no mask, in the lowered text."""
+    x = jnp.zeros((1, 8, H))
+    text = lambda layer: jax.jit(
+        lambda p, x: layer.serve(p, x)[0]).lower(grouped_params, x).as_text()
+    plain, limited = text(grouped_layer(n_group=1, topk_group=1)), text(grouped_layer())
+    assert plain.count("top_k") < limited.count("top_k")
+    assert "top_k" in plain and plain == text(
+        make_layer(num_experts=32, top_k=6, router="sigmoid_bias",
+                   routed_scaling_factor=2.5, norm_topk_eps=1e-20,
+                   shared_expert_width=16))
+
+
+def test_all_thirty_two_shares_add_up_to_the_uncut_layer(grouped_params):
+    """The guide's share test under the group limit: 32 experts in 8 groups
+    of 4, a token keeping 4 groups and 6 experts; 32 ranks of ONE expert each
+    (a quarter of a group a rank), every rank routing over all 32 outputs and
+    all 8 groups. The ranks' ``serve`` outputs, the shared expert counted
+    once, add up to the uncut layer's."""
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, 24, H))
+    real = jnp.ones((1, 24), bool)
+    whole, load = grouped_layer().serve(grouped_params, x, real)
+    assert load.shape == (32,) and int(load.sum()) == 24 * 6
+    p = grouped_params
+    shared = (jax.nn.silu(x @ p["shared_gate"]) * (x @ p["shared_in"])) @ p["shared_out"]
+    parts, held_total = [], 0
+    for first in range(32):
+        rank = dict(grouped_params)
+        for leaf in ("w_in", "w_out", "w_gate"):
+            rank[leaf] = grouped_params[leaf][first:first + 1]
+        y, rank_load = grouped_layer(first, 1).serve(rank, x, real)
+        # the one held expert's count, then the absent assignments
+        assert int(rank_load[0]) == int(load[first])
+        assert int(rank_load[0]) + int(rank_load[1]) == 24 * 6
+        held_total += int(rank_load[0])
+        parts.append(y - shared)    # every rank added the shared expert
+    assert held_total == 24 * 6
+    np.testing.assert_allclose(sum(parts) + shared, whole, atol=1e-4)
+    sizes = [float(jnp.abs(part).max()) for part in parts]
+    assert max(sizes) > 1e-3 and sum(size > 1e-3 for size in sizes) > 16
+
+
+def test_a_group_limit_the_layer_cannot_build_is_refused():
+    with pytest.raises(AssertionError, match="group-limited choice"):
+        make_layer(num_experts=32, top_k=6, n_group=8, topk_group=4)   # softmax
+    with pytest.raises(AssertionError, match="group-limited choice"):
+        grouped_layer(n_group=5)
+    with pytest.raises(AssertionError, match="group-limited choice"):
+        grouped_layer(n_group=8, topk_group=1)   # 4 experts cannot hold 6 choices

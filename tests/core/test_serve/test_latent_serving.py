@@ -266,7 +266,7 @@ def test_speculative_rows_are_refused_by_name(kimi):
      "rope_scaling type 'linear': only 'yarn' is built"),
     ({}, {"rope_scaling": {**ARCH["rope_scaling"], "type": "dynamic"}},
      "rope_scaling type 'dynamic'"),
-    ({}, {"moe_n_group": 8, "moe_topk_group": 4},
+    ({}, {"moe_n_group": 8, "moe_topk_group": 4, "moe_num_experts": 12},
      "moe_n_group 8 / moe_topk_group 4: the group-limited choice"),
     ({}, {"moe_topk_group": 2}, "group-limited choice"),
     ({}, {"kv_lora_rank": None}, "'latent' layers needs \\['kv_lora_rank'\\]"),
