@@ -454,7 +454,7 @@ def phase_serve(size, args, device) -> dict:
     empty, _ = engine._layout.host(width)
     text = engine._mixed_fns[width].lower(
         inf.params, engine._pool_state(), engine._dev(empty),
-        engine._base_key,
+        engine._base_key, engine._prev,
     ).as_text()
     calls = text.count("tpu_custom_call")
     if calls < 1 and not args.rehearse:

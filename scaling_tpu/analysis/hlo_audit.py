@@ -527,7 +527,8 @@ def audit_serve_decode_section(num_slots=8, block_size=16,
         # lengths, sampler rows, the packed tokens last), a token a row
         packed, tick = engine._layout.host(width)
         tick.new_lens[:] = 1
-        args = (params, engine._pool_state(), engine._dev(packed), base_key)
+        args = (params, engine._pool_state(), engine._dev(packed), base_key,
+                engine._prev)
         return engine._build_mixed_fn(width).lower(*args), args
 
     static = {

@@ -24,6 +24,7 @@ from .capture import (
     Capture,
     capturing,
     last_capture,
+    settle_at_capture_edges,
     start_capture,
     stop_capture,
 )
@@ -84,6 +85,7 @@ __all__ = [
     "record_span",
     "recorded_spans",
     "recorded_tail",
+    "settle_at_capture_edges",
     "span",
     "start_capture",
     "stop_capture",

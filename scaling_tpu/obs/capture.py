@@ -39,8 +39,9 @@ from __future__ import annotations
 
 import statistics
 import threading
+import weakref
 from pathlib import Path
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from .recorder import CAPTURE_MARKER, _recorder, clock
 from .registry import MetricsRegistry, get_registry
@@ -167,6 +168,28 @@ def _marker(trace_dir: str, edge: str, start: float, duration: float) -> tuple:
 _lock = threading.Lock()  # start/stop only; a span reads _active without it
 _active: Optional[_Active] = None
 _last: Optional[Capture] = None
+# bound methods called before a capture starts and before it stops, so that
+# work a program has issued and not yet accounted for lands on the right side
+# of the edge (:func:`settle_at_capture_edges`); dead owners fall out
+_settlers: List["weakref.WeakMethod"] = []
+
+
+def settle_at_capture_edges(method: Callable[[], None]) -> None:
+    """Have ``method`` (a bound method, held weakly) called on the thread
+    that starts or stops a capture, before the edge is cut: a capture then
+    holds whole units of work, each with all its spans and counts. The serve
+    engine settles the tick whose program it has issued and not yet read."""
+    _settlers.append(weakref.WeakMethod(method))
+
+
+def _settle() -> None:
+    live = []
+    for ref in list(_settlers):
+        method = ref()
+        if method is not None:
+            live.append(ref)
+            method()
+    _settlers[:] = live
 
 
 def capturing() -> bool:
@@ -193,6 +216,7 @@ def start_capture(out_dir, registry: Optional[MetricsRegistry] = None,
     global _active
     import jax
 
+    _settle()
     with _lock:
         if _active is not None:
             raise RuntimeError(
@@ -222,6 +246,8 @@ def stop_capture() -> Capture:
     global _active, _last
     import jax
 
+    if _active is not None:
+        _settle()
     with _lock:
         cap = _active
         if cap is None:

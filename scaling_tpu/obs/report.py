@@ -748,18 +748,21 @@ def serving_section(data: RunData) -> Tuple[List[str], Dict[str, float]]:
             + f", top critical-path phase: {top} "
             f"({counts.get(top, 0)} trace(s)) — see `obs trace`"
         )
-    # tick-time attribution: the engine's program against the host-side
-    # drafting that speculation adds before it
+    # tick-time attribution: the engine's program (issued under
+    # serve.mixed, its samples waited for under serve.mixed.wait, one
+    # tick() call later) against the host-side drafting that speculation
+    # adds before it; counted once a program
     phases = (
-        ("mixed", "serve.mixed"),
-        ("draft", "serve.draft"),
+        ("mixed", ("serve.mixed", "serve.mixed.wait")),
+        ("draft", ("serve.draft",)),
     )
     sums: Dict[str, Tuple[float, int]] = {}
     for sp in data.spans:
-        for label, name in phases:
-            if sp.get("span") == name and sp.get("dur_s") is not None:
+        for label, names in phases:
+            if sp.get("span") in names and sp.get("dur_s") is not None:
                 total, count = sums.get(label, (0.0, 0))
-                sums[label] = (total + float(sp["dur_s"]), count + 1)
+                sums[label] = (total + float(sp["dur_s"]),
+                               count + (sp["span"] == names[0]))
     if sums:
         grand = sum(t for t, _ in sums.values())
         parts = []

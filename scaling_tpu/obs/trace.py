@@ -61,6 +61,10 @@ _RPC_SPANS = ("serve.replica.rpc_client",)
 # of queue_wait is the first of these; admit/rpc are submission
 # machinery. It feeds prefill or decode by which list carries the trace
 _MIXED_SPAN = "serve.mixed"
+# ...and the wait for its samples, which since the engine issues a tick ahead
+# of its reads lies outside it, one tick() call later, and carries the same
+# lists: a request sat in its program from the one's start to the other's end
+_MIXED_SPANS = (_MIXED_SPAN, "serve.mixed.wait")
 
 
 # ------------------------------------------------------------ assembly
@@ -141,7 +145,7 @@ def trace_phases(tid: str, recs: List[dict]) -> Dict[str, float]:
         dur = float(r.get("dur_s") or 0.0)
         if name in _RPC_SPANS:
             phases["rpc"] += dur
-        elif name == _MIXED_SPAN:
+        elif name in _MIXED_SPANS:
             # one mixed tick serves chunked prefills AND decodes: the
             # list the id rides in says which side this trace was on
             if tid in (r.get("chunk_traces") or ()):

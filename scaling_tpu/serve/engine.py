@@ -35,6 +35,14 @@ prefill offsets, draft lengths) lives in block tables / context lengths
 / new_lens, never in shapes. All of that host state travels as ONE int32
 operand a tick (``TickLayout``), sliced apart on the device: a tick costs
 one host-to-device transfer.
+
+The engine runs ONE TICK AHEAD of the tokens the host has read
+(``ServeEngine.tick``): it issues program N+1, then reads program N, so the
+chip never waits for the host's emit / schedule / build. The one thing the
+host needs of tick N to build tick N+1, a decoding row's last token, is fed
+from program N's samples on the device (``prev``); the scheduler works on
+the projected state (``Sequence.in_flight``), and where it needs a token's
+value (speculation, a possible preemption, a deadline) the read comes first.
 All signatures are pinned in the ``serve_decode`` HLO-audit section
 (analysis/goldens/serve_decode.json): a scheduler shape-bucketing or
 kernel change that would trigger a recompile storm on the chip shows up
@@ -55,6 +63,7 @@ from __future__ import annotations
 
 import dataclasses
 import statistics
+import threading
 import time
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
@@ -80,6 +89,7 @@ from .scheduler import (
     Request,
     SchedulerConfig,
     Sequence,
+    SequenceState,
     Tick,
 )
 
@@ -89,6 +99,12 @@ LANES = 128
 # prompts streaming a chunk each that the small token bucket has room for
 # beside a decode row in every slot (EngineConfig.mixed_widths)
 SMALL_BUCKET_CHUNKS = 3
+# in ``TickLayout``'s tokens, a decode row's last token while the host has not
+# read it: the mixed program takes it from the program before it
+IN_FLIGHT = -1
+# why a tick was not issued ahead of the read of the one before it
+# (``serve_ticks_synchronous_total``'s ``reason``)
+SYNC_REASONS = ("spec", "preempt", "deadline", "first", "drained")
 # ticks whose spans ``stats_snapshot()["tick_phases_ms"]`` takes its medians over
 TICK_PHASES_TICKS = 512
 # the spans that tile a tick: ``serve.tick`` and ``serve.mixed`` only hold
@@ -176,6 +192,27 @@ class TickLayout:
 
         packed = np.zeros((self.size(width),), np.int32)
         return packed, self.split(packed)
+
+
+@dataclasses.dataclass
+class IssuedTick:
+    """A tick whose program is issued and whose samples the host has not
+    read: what ``ServeEngine._read`` needs to emit and retire it."""
+
+    step: int
+    tick: Tick
+    width: int
+    sampled: object  # the program's samples, on the device
+    # rows that will have produced a token, each with the slot it ran in:
+    # chunk rows that complete their prompt (and the column of the row's
+    # sample), decode rows
+    firsts: List[Tuple[Sequence, int, int]]
+    decodes: List[Tuple[Sequence, int]]
+    prefilled: int  # prompt tokens its chunk rows brought
+    positions: int  # sampled positions that hold a token
+    # serve.mixed's links to the traced requests it advanced: the wait for
+    # its samples carries them too (obs/trace.py counts both)
+    traces: dict
 
 
 @dataclasses.dataclass
@@ -383,6 +420,20 @@ class ServeEngine:
         self._base_key = self._dev(
             jax.random.PRNGKey(self.config.sample_seed)
         )
+        # the last program's (num_slots, sample_width) samples, on the
+        # device: the next program's ``prev`` (zeros before the first)
+        self._prev = self._first_prev()
+        # the tick whose program is issued and not yet read, if any, and
+        # the thread that issued it
+        self._issued: Optional[IssuedTick] = None
+        self._tick_thread: Optional[int] = None
+        # a capture holds whole ticks: the one in flight is read before a
+        # capture starts and before it stops
+        obs.settle_at_capture_edges(self.settle)
+        # tick() calls that issued a program ahead of the read of the one
+        # before it, and the others by reason (SYNC_REASONS), warm-up apart
+        self.ticks_overlapped = 0
+        self.ticks_synchronous: Dict[str, int] = {}
         # token width -> the fused mixed program built at it: every width
         # of config.mixed_widths, all lowered at the first tick
         self._mixed_fns: Dict[int, object] = {}
@@ -468,8 +519,6 @@ class ServeEngine:
         # Guarded by its own lock: in a fleet the router's submit thread
         # increments while the replica's tick thread decrements, and a
         # lost update that read 0 would silently skip live deadlines.
-        import threading
-
         self._deadline_live = 0
         self._deadline_lock = threading.Lock()
 
@@ -623,6 +672,23 @@ class ServeEngine:
             return x
         return self._jax.device_put(x, self._replicated)
 
+    def _first_prev(self):
+        """Zeros in ``prev``'s shape that a program call takes exactly as
+        it takes a program's own samples, so that the call after a
+        program's first finds its executable: on a serving mesh replicated;
+        off it committed to the device the params or the pools are
+        committed to, and uncommitted where they are not (a jitted call's
+        outputs are committed if any operand is)."""
+        zeros = self._np.zeros(
+            (self.config.num_slots, self.config.sample_width), self._np.int32)
+        if self._replicated is not None:
+            return self._jax.device_put(zeros, self._replicated)
+        for leaf in self._jax.tree_util.tree_leaves(
+                (self.inf.params, self._pool_state())):
+            if getattr(leaf, "committed", False):
+                return self._jax.device_put(zeros, leaf.sharding)
+        return self._jax.numpy.asarray(zeros)
+
     def _metric(self, kind: str, name: str, labels: dict):
         """The registry's counter / gauge / histogram ``name`` under
         ``labels`` (and this replica's): found through the registry's
@@ -727,9 +793,18 @@ class ServeEngine:
         rows' running ends, its offset from the row's start). The tick's
         whole host state arrives as ONE int32 vector (``TickLayout``:
         tables, lengths, sampler rows, the tokens last) and the program
-        opens with static slices of it, so it takes four arguments:
-        params, the donated pool state, that vector, the key. Rotary positions
-        are ``ctx_lens[row] + offset``. The paged branch
+        opens with static slices of it, so it takes five arguments:
+        params, the donated pool state, that vector, the key, and
+        ``prev``: the ``(num_slots, sample_width)`` samples of the program
+        before this one, which never left the device (zeros before the
+        first). The engine issues a tick before it has read the one before
+        (``tick``), so a decode row whose last token is still in flight
+        brings ``IN_FLIGHT`` in its place and the program opens with
+        ``tokens = where(tokens < 0, prev[row, 0], tokens)``: a row that
+        decoded and a chunk row that completed its prompt in the tick before
+        both sampled at column 0 (a speculating engine, whose rows sample
+        wider, reads every tick before it schedules and never brings the
+        sentinel). Rotary positions are ``ctx_lens[row] + offset``. The paged branch
         (``Attention._paged_attention``) scatters each token's K/V through
         its row's table (what is no token goes to the trash block; rows
         never share pool blocks, so fusing their writes is exact),
@@ -753,8 +828,11 @@ class ServeEngine:
         most), all lowered at the engine's first tick — pinned in the
         serve_decode golden.
 
+        The program returns the samples twice: what the host reads, and
+        ``feed``, the grid alone, the next program's ``prev`` (one shape
+        whatever follows the grid in the host's read, and at either width).
         A routed model's program returns ONE int32 vector instead of the
-        grid: the sampled grid flattened, then the (E,) load of the
+        grid for the host: the sampled grid flattened, then the (E,) load of the
         tick's real positions (``_run_layers(moe_load=True)``), so that
         the load costs the tick no second host read. A looped model with
         an exit gate appends likewise the (loop_steps,) float32 exit
@@ -769,7 +847,7 @@ class ServeEngine:
         routed = self.num_experts > 0
         gated = self.loop_exit_gate
 
-        def mixed(params, state, packed, base_key):
+        def mixed(params, state, packed, base_key, prev):
             tick = self._layout.split(packed)
             tables, ctx_lens, new_lens = (
                 tick.tables, tick.ctx_lens, tick.new_lens)
@@ -778,7 +856,12 @@ class ServeEngine:
             # what is no token keeps position 0 (finite rotary, whatever
             # the last row's context)
             pos = jnp.where(offset < new_lens[row], ctx_lens[row] + offset, 0)
-            batch = self.inf._make_batch(tick.tokens.reshape(shape), pos)
+            # a decode row whose last token the host has not read brings
+            # IN_FLIGHT in its place: the token is the sample its row drew
+            # in the program before this one
+            tokens = tick.tokens.reshape(shape)
+            tokens = jnp.where(tokens < 0, prev[:, 0][row], tokens)
+            batch = self.inf._make_batch(tokens, pos)
             views = build_layer_views(state, tables, ctx_lens, new_lens,
                                       token_map, kinds=self.pools.kinds)
             g0 = jnp.clip(new_lens - sample_width, 0,
@@ -798,6 +881,7 @@ class ServeEngine:
                     logits, tick.temps, tick.topps, tick.topks, tick.reqids,
                     tick.gen0 + g0, base_key
                 )
+            feed = sampled  # the next program's ``prev``
             if routed:
                 sampled = jnp.concatenate([sampled.reshape(-1), extra[0]])
             if gated:
@@ -809,7 +893,7 @@ class ServeEngine:
                 sampled = jnp.concatenate([
                     sampled.reshape(-1),
                     self._jax.lax.bitcast_convert_type(exit_p, jnp.int32)])
-            return sampled, state_from_views(new_views)
+            return sampled, feed, state_from_views(new_views)
 
         # the pool state dies with each call (_absorb takes the returned
         # one) and comes back in the structure it went in
@@ -832,11 +916,16 @@ class ServeEngine:
         engine's first tick, through the very call path later ticks take.
         A width first needed minutes into serving must not pay its
         lowering then."""
-        for width in self.config.mixed_widths:
-            fn = self._mixed_fns[width] = self._build_mixed_fn(width)
+        # twice: a program's second call takes ``prev`` from a program, as
+        # every later one does, and must find the first call's executable
+        for width in 2 * self.config.mixed_widths:
+            fn = self._mixed_fns.get(width)
+            if fn is None:
+                fn = self._mixed_fns[width] = self._build_mixed_fn(width)
             empty, _ = self._layout.host(width)
-            _, state = fn(self.inf.params, self._pool_state(),
-                          self._dev(empty), self._base_key)
+            _, self._prev, state = fn(
+                self.inf.params, self._pool_state(), self._dev(empty),
+                self._base_key, self._prev)
             self._absorb(state)
 
     # ------------------------------------------------------------- ticking
@@ -875,33 +964,42 @@ class ServeEngine:
                     arrs[i] = arrs[i].at[dst].set(arrs[i][src])
         self._counter("serve_cow_forks_total").inc(len(pairs))
 
-    def _run_mixed(self, t: Tick) -> None:
-        """The fused tick (Sarathi piggybacking): ONE program call
-        covers every prefill chunk AND the whole decode batch, the rows'
-        real tokens packed back to back in slot order into the smallest
-        token width that holds them, each row tagged by its traced
+    def _issue(self, t: Tick, step: int) -> IssuedTick:
+        """The fused tick (Sarathi piggybacking), as far as the host can
+        take it without the program's result: ONE program call covers
+        every prefill chunk AND the whole decode batch, the rows' real
+        tokens packed back to back in slot order into the smallest token
+        width that holds them, each row tagged by its traced
         ``new_len``/``ctx_len``. Decode rows carry their speculative
-        drafts; acceptance happens host-side on the returned per-position
-        samples (``_accept_speculative``)."""
+        drafts; a decode row whose last token is still in flight carries
+        ``IN_FLIGHT`` for it and the program takes the token from ``prev``,
+        the samples of the program before it, on the device.
+
+        The rows' sequences are PROJECTED past this tick as it is issued
+        (``num_cached`` by what the row brings, ``in_flight`` by the token
+        it will produce), so the next tick can be scheduled and issued
+        before this one is read (``_read``: acceptance happens host-side
+        on the returned per-position samples, ``_accept_speculative``)."""
         np = self._np
         cfg = self.config
         if not self._mixed_fns:
             self._lower_mixed_programs()
-        step = self.tick_index
+        traces = {**self._trace_fields(t.decodes),
+                  **self._trace_fields(t.prefills, "chunk_traces")}
         with self._span("serve.mixed", step=step,
                         decodes=len(t.decodes), chunks=len(t.prefills),
-                        **self._trace_fields(t.decodes),
-                        **self._trace_fields(t.prefills, "chunk_traces")
-                        ) as mixed_span:
+                        **traces) as mixed_span:
             with self._span("serve.mixed.build", step=step):
                 n = cfg.num_slots
+                sw = cfg.sample_width  # sampled grid covers g0..g0+sw-1
                 row_tokens: List[List[int]] = [[]] * n  # by slot
                 # written at the widest token width, handed over at the
                 # tick's own: the tokens lie last, so that is a prefix
                 packed, tick = self._layout.host(cfg.mixed_widths[-1])
                 tables, ctx, new_lens, gen0 = (
                     tick.tables, tick.ctx_lens, tick.new_lens, tick.gen0)
-                chunk_rows = []  # (seq, start, n_real)
+                firsts = []  # (seq, slot, column of its row's sample)
+                prefilled = 0
                 for seq in t.prefills:
                     slot = seq.slot
                     prompt = seq.resume_prompt
@@ -921,14 +1019,25 @@ class ServeEngine:
                     # key plain decode uses for the request's first
                     # generated token
                     gen0[slot] = len(seq.generated) - (n_real - 1)
-                    chunk_rows.append((seq, start, n_real))
+                    seq.num_cached = start + n_real
+                    prefilled += n_real
+                    if seq.num_cached == seq.prefill_len:
+                        # original position n_real - 1, gathered at index
+                        # n_real - 1 - g0 with g0 = max(n_real - sw, 0)
+                        firsts.append((seq, slot, min(n_real, sw) - 1))
+                        seq.in_flight += 1
                 for seq in t.decodes:
                     slot = seq.slot
-                    row_tokens[slot] = [seq.generated[-1], *seq.draft]
+                    last = IN_FLIGHT if seq.in_flight else seq.generated[-1]
+                    row_tokens[slot] = [last, *seq.draft]
                     new_lens[slot] = 1 + len(seq.draft)
                     ctx[slot] = seq.num_cached
                     tables[slot, :len(seq.blocks)] = seq.blocks
-                    gen0[slot] = len(seq.generated)
+                    gen0[slot] = len(seq.generated) + seq.in_flight
+                    # the last token's write always stands; what a draft
+                    # adds is known at acceptance
+                    seq.num_cached += 1
+                    seq.in_flight += 1
                 # inactive rows keep all-trash tables + new_len 0: they
                 # bring no token and expose zero visible slots
                 real = [tok for row in row_tokens for tok in row]
@@ -940,15 +1049,21 @@ class ServeEngine:
                     w for w in cfg.mixed_widths if len(real) <= w
                     and multi <= split_capacity(w, cfg.mixed_width))
                 tick.tokens[:len(real)] = real
-                tick.temps[:], tick.topps[:] = self._temp, self._topp
+                # a row that brings no token samples nothing: a sequence
+                # whose last token is in flight keeps its slot a tick longer
+                # and must not hold the tick in the sampler's sorting branch
+                tick.temps[:] = np.where(new_lens > 0, self._temp, 0.0)
+                tick.topps[:] = self._topp
                 tick.topks[:], tick.reqids[:] = self._topk, self._reqid
                 packed = packed[:self._layout.size(width)]
             puts = self.host_puts
             with self._span("serve.mixed.dispatch", step=step) as dispatch:
-                sampled, state = self._mixed_fns[width](
+                sampled, self._prev, state = self._mixed_fns[width](
                     self.inf.params, self._pool_state(), self._dev(packed),
-                    self._base_key,
+                    self._base_key, self._prev,
                 )
+                # futures: the runtime queues the next program behind this
+                self._absorb(state)
                 if dispatch is not None:  # not warming up
                     moved = self.host_puts - puts
                     dispatch.annotate(operands=moved, bytes=packed.nbytes)
@@ -958,42 +1073,68 @@ class ServeEngine:
                 # here, while the chip is busy, not ahead of the dispatch
                 self._annotate_mixed(mixed_span, width, len(real), ctx,
                                      new_lens, multi)
-            with self._span("serve.mixed.wait", step=step):
-                # the tick's ONE deliberate device->host pull: the sampled
-                # token grid must land on host to be emitted to callers
-                host_samples = np.asarray(sampled)  # sta: disable=STA010
-        sw = cfg.sample_width  # sampled grid covers g0..g0+sw-1
+        if len(t.prefills) > self.max_concurrent_prefills:
+            self.max_concurrent_prefills = len(t.prefills)
+        return IssuedTick(
+            step=step, tick=t, width=width, sampled=sampled, firsts=firsts,
+            decodes=[(seq, seq.slot) for seq in t.decodes],
+            prefilled=prefilled,
+            positions=int(np.minimum(new_lens, sw).sum()), traces=traces)
+
+    def _read(self, issued: IssuedTick) -> None:
+        """What a tick owes once its program's samples are on the host, its
+        spans under the ``step`` it was issued at: ``serve.mixed.wait``,
+        ``serve.emit`` (acceptance, a token appended and stamped for every
+        row, the tick's counters) and ``serve.retire``. A row whose sequence
+        ended at the EOS read a tick before (``_emit_row``) is dropped."""
+        np = self._np
+        step = issued.step
+        n, sw = self.config.num_slots, self.config.sample_width
+        with self._span("serve.mixed.wait", step=step, **issued.traces):
+            # the tick's ONE deliberate device->host pull: the sampled
+            # token grid must land on host to be emitted to callers
+            host_samples = np.asarray(issued.sampled)
         with self._span("serve.emit", step=step) as emit:
             if self.num_experts:
                 load = host_samples[n * sw:]
                 host_samples = host_samples[:n * sw].reshape(n, sw)
-                *_, bounded = self._moe_rows[width]
+                *_, bounded = self._moe_rows[issued.width]
                 self._record_moe_load(load, emit, bounded)
             if self.loop_exit_gate:
                 exit_p = host_samples[n * sw:].view(np.float32)
                 host_samples = host_samples[:n * sw].reshape(n, sw)
-                self._record_exit(
-                    exit_p, int(np.minimum(new_lens, sw).sum()), emit)
-            self._absorb(state)
+                self._record_exit(exit_p, issued.positions, emit)
             now = time.monotonic()
-            rows = len(t.decodes)
+            rows = 0
             drafted = self.spec_drafted_tokens
             accepted = self.spec_accepted_tokens
-            for seq, start, n_real in chunk_rows:
-                slot = seq.slot
-                seq.num_cached = start + n_real
-                self._tick_prefilled += n_real
-                if seq.num_cached == seq.prefill_len:
-                    # original position n_real - 1, gathered at index
-                    # n_real - 1 - g0 with g0 = max(n_real - sw, 0)
-                    tok = int(host_samples[slot, min(n_real, sw) - 1])
-                    self._emit_token(seq, tok, now)
-                    rows += 1
-            for seq in t.decodes:
-                self._accept_speculative(seq, host_samples[seq.slot], now)
+            self._tick_prefilled += issued.prefilled
+            for seq, slot, column in issued.firsts:
+                rows += self._emit_row(
+                    seq, host_samples[slot, column:column + 1], now)
+            for seq, slot in issued.decodes:
+                rows += self._emit_row(seq, host_samples[slot], now)
             self._flush_tick_telemetry(
                 emit, rows, self.spec_drafted_tokens - drafted,
                 self.spec_accepted_tokens - accepted)
+        with self._span("serve.retire", step=step) as retire_span:
+            finished = self._retire_tick(issued.tick)
+            if retire_span is not None:  # not warming up
+                retire_span.annotate(finished=finished)
+
+    def _emit_row(self, seq: Sequence, row_samples, now: float) -> bool:
+        """One row's samples to its sequence; False for a row issued behind
+        a token that turned out to be the EOS: its sequence was finished
+        when that token was read, its sample is dropped (what the row wrote
+        lies in blocks and lines the next admission resets)."""
+        if seq.state is not SequenceState.RUNNING:
+            return False
+        seq.in_flight -= 1
+        self._accept_speculative(seq, row_samples, now)
+        eos = seq.request.eos_token_id
+        if eos is not None and seq.generated[-1] == eos:
+            seq.in_flight = 0  # the row already issued behind it is dropped
+        return True
 
     def _flush_tick_telemetry(self, emit_span, rows: int, drafted: int,
                               accepted: int) -> None:
@@ -1034,8 +1175,8 @@ class ServeEngine:
         # first tiles that start under another row's fold
         held = ctx + new_lens
         # the predicate of the program's sampler (sample_rows), known
-        # before the call: a freed slot's temperature is 0
-        sampled_rows = int(np.count_nonzero(self._temp > 0.0))
+        # before the call: the rows that bring a token and a temperature
+        sampled_rows = int(np.count_nonzero(self._temp[new_lens > 0] > 0.0))
         mixed_span.annotate(
             width=width, tokens=tokens,
             sampled_rows=sampled_rows,
@@ -1189,10 +1330,11 @@ class ServeEngine:
         self.spec_drafted_tokens += len(draft)
         self.spec_accepted_tokens += accepted if draft else 0
         seq.draft = []
-        # KV validity: the context held the last token's write, plus one
-        # slot per accepted draft — rejected drafts' slots are simply
-        # overwritten by the next call (the context never admits them)
-        seq.num_cached += len(emitted)
+        # KV validity: the context held the last token's write (counted as
+        # the row was issued), plus one slot per accepted draft — rejected
+        # drafts' slots are simply overwritten by the next call (the
+        # context never admits them)
+        seq.num_cached += len(emitted) - 1
         for tok in emitted:
             self._emit_token(seq, tok, now)
 
@@ -1290,50 +1432,129 @@ class ServeEngine:
         token yet. The scheduler releases slot + blocks (one reference
         each — trie-shared prefix blocks stay cached for the next
         requester), so the capacity is admissible THIS tick."""
+        for seq in self._expired(now):
+            self.scheduler.cancel(seq)
+            self._retire(seq, now, "timeout")
+
+    def _expired(self, now: float) -> List[Sequence]:
+        """The live requests past a deadline at ``now``."""
         if not self._deadline_live:
-            return
+            return []
         live = list(self.scheduler.running.values()) + list(
             self.scheduler.waiting
         )
+        out = []
         for seq in live:
             req = seq.request
             waited_ms = (now - req.arrival_s) * 1000.0
-            expired = (
+            if (
                 req.deadline_ms is not None and waited_ms > req.deadline_ms
             ) or (
                 req.ttft_deadline_ms is not None
                 and seq.first_token_s is None
                 and waited_ms > req.ttft_deadline_ms
-            )
-            if not expired:
-                continue
-            self.scheduler.cancel(seq)
-            self._retire(seq, now, "timeout")
+            ):
+                out.append(seq)
+        return out
 
     def tick(self) -> Tick:
-        """One engine step, each phase a span at the place of the work
-        (docs/OBSERVABILITY.md "Span taxonomy"): ``serve.schedule``
-        (expire deadlines, draft speculative candidates, schedule),
-        ``serve.mixed`` (the fused program), ``serve.emit``,
-        ``serve.retire`` (retire
-        completions, flush the request journal, gauges), all under the
-        ``serve.tick`` this opens itself."""
+        """One engine step, ONE TICK AHEAD of the tokens the host has read:
+        schedule tick ``step`` from the projected state and issue its
+        program (``serve.schedule``: expire deadlines, draft speculative
+        candidates, schedule; ``serve.mixed``: build, dispatch), and only
+        THEN read the tick issued by the call before (``_read``: its
+        ``serve.mixed.wait``, ``serve.emit`` and ``serve.retire`` carry ITS
+        ``step``, one less), so the chip runs this program while the host
+        emits that one's tokens. Each phase is a span at the place of the
+        work (docs/OBSERVABILITY.md "Span taxonomy"), all under the
+        ``serve.tick`` this opens itself. When nothing is left to schedule
+        the call only reads what is in flight.
+
+        Where the scheduler needs the tokens' VALUES the read comes first
+        and the tick is synchronous (``_read_first``: speculation, a
+        deadline that has run out, a pool that might preempt); the same
+        code, the read moved ahead of the schedule. ``seq.generated`` and
+        ``seq.token_stamps`` hold only tokens the host has read, whenever
+        this returns."""
         get_fault_plan().fire("serve.tick")
         step = self.tick_index
+        self._tick_thread = threading.get_ident()
         with self._span("serve.tick", step=step,
                         **self._replica_fields) as tick_span:
+            before = self._issued
+            reason = self._read_first(time.monotonic())
+            if before is not None and reason is not None:
+                self._read(before)
+                before = None
             with self._span("serve.schedule", step=step) as sched_span:
                 t = self._schedule_tick(step, sched_span)
-            if tick_span is not None:
+            if t.preempted and before is not None:
+                raise RuntimeError(
+                    "the scheduler preempted with a tick in flight: "
+                    "may_preempt() missed it")
+            self._issued = (self._issue(t, step)
+                            if t.prefills or t.decodes else None)
+            if reason is None and (before is None or self._issued is None):
+                reason = "drained" if self._issued is None else "first"
+            if before is not None:
+                self._read(before)
+                if not self.scheduler.has_work:
+                    # every row of the tick just issued was issued behind an
+                    # EOS: nobody would come back for it
+                    self.settle()
+            if not (t.prefills or t.decodes):
+                # no program of this step to settle: its retire says so (a
+                # reader keys what a tick retired by the tick's step)
+                with self._span("serve.retire", step=step) as retire_span:
+                    if retire_span is not None:  # not warming up
+                        retire_span.annotate(finished=0)
+            if tick_span is not None:  # not warming up
                 tick_span.annotate(decodes=len(t.decodes),
-                                   chunks=len(t.prefills))
-            if t.prefills or t.decodes:
-                self._run_mixed(t)
-            with self._span("serve.retire", step=step) as retire_span:
-                finished = self._retire_tick(t)
-                if retire_span is not None:  # not warming up
-                    retire_span.annotate(finished=finished)
+                                   chunks=len(t.prefills),
+                                   overlapped=reason is None)
+                if reason is None:
+                    self.ticks_overlapped += 1
+                    self._counter("serve_ticks_overlapped_total").inc()
+                else:
+                    self.ticks_synchronous[reason] = (
+                        self.ticks_synchronous.get(reason, 0) + 1)
+                    self._counter("serve_ticks_synchronous_total",
+                                  reason=reason).inc()
+            self.tick_index += 1
+            if self.tick_index % self.config.flush_interval == 0:
+                self._reg.flush_step(self.tick_index)
         return t
+
+    def settle(self) -> None:
+        """Read the tick in flight, if any, now: for a caller that needs
+        every token the engine has asked for on the host before the next
+        ``tick()`` (``obs`` calls it at a capture's edges). Only on the
+        thread that ticks: from another (an in-process fleet's main thread)
+        it leaves the tick to its own."""
+        issued = self._issued
+        if issued is not None and self._tick_thread == threading.get_ident():
+            self._issued = None
+            self._read(issued)
+
+    def _read_first(self, now: float) -> Optional[str]:
+        """Why the tick in flight must be read BEFORE the next is scheduled
+        (None: it need not be), from what the engine can observe:
+        ``"spec"``, n-gram drafting and acceptance work on the tokens, every
+        tick; ``"deadline"``, a running request has run out of time and its
+        cancellation must see its first token, if that is what is in
+        flight; ``"preempt"``, the pool might preempt
+        (``scheduler.may_preempt``: a bound) and a victim's
+        ``resume_prompt`` must hold every token it was given. A tick read
+        early for nothing costs one gap, never a token."""
+        if self.config.spec_k > 0:
+            return "spec"
+        if self._issued is None:
+            return None
+        if any(seq.slot is not None for seq in self._expired(now)):
+            return "deadline"
+        if self.scheduler.may_preempt():
+            return "preempt"
+        return None
 
     def _schedule_tick(self, step: int, sched_span=None) -> Tick:
         """Everything a tick decides before its programs run. A tick
@@ -1392,12 +1613,12 @@ class ServeEngine:
     def _retire_tick(self, t: Tick) -> int:
         """Everything a tick settles after its tokens are out; returns
         how many requests it retired."""
-        if len(t.prefills) > self.max_concurrent_prefills:
-            self.max_concurrent_prefills = len(t.prefills)
         now = time.monotonic()
         finished = 0
         for seq in list(t.prefills) + list(t.decodes):
-            if seq.done and seq.slot is not None:
+            # by length, the last token was this tick's; at an EOS the row
+            # already issued behind it is dropped (_emit_row)
+            if seq.done and not seq.in_flight and seq.slot is not None:
                 self._finish(seq, now)
                 finished += 1
         self._reset_rows(self.scheduler.drain_freed_slots())
@@ -1414,9 +1635,6 @@ class ServeEngine:
             self._gauge("serve_spec_accept_rate").set(
                 self.spec_accepted_tokens / self.spec_drafted_tokens
             )
-        self.tick_index += 1
-        if self.tick_index % self.config.flush_interval == 0:
-            self._reg.flush_step(self.tick_index)
         return finished
 
     @property
@@ -1476,6 +1694,10 @@ class ServeEngine:
             # by token width (JSON keys are strings): ticks run at it (the
             # real tokens they held: serve.mixed's `tokens` beside `width`)
             "mixed_ticks": {str(w): c for w, c in self.mixed_ticks.items()},
+            # tick() calls that issued a program ahead of the read of the
+            # one before it, and the others by reason (SYNC_REASONS)
+            "ticks_overlapped": self.ticks_overlapped,
+            "ticks_synchronous": dict(self.ticks_synchronous),
             # host arrays a counted tick handed the device, running mean:
             # 1.0, the one packed operand (None before the first)
             "tick_operands": (
@@ -1511,15 +1733,18 @@ class ServeEngine:
     def tick_phases_ms(self) -> Dict[str, float]:
         """Where a tick's time goes, by phase: the median duration of
         each ``serve.*`` span that lies inside one of this engine's last
-        ``TICK_PHASES_TICKS`` ``serve.tick`` spans (same ``step``, inside
-        it in time: an in-process fleet shares one recorder and its
-        replicas' steps collide). Two more that no span holds:
-        ``"unspanned"``, a tick minus its leaf phases (``LEAF_PHASES``;
-        what nests in one of those is its parent's), and ``"between"``,
-        one tick's end to the next's start where the engine had work (the
-        earlier tick left rows running, the later one ran a program):
-        the driver's loop around ``tick()``. Nothing is kept per tick
-        for this."""
+        ``TICK_PHASES_TICKS`` ``serve.tick`` spans (inside it in time, and
+        of its ``step`` or, the read of the tick before, of the one before:
+        an in-process fleet shares one recorder and its replicas' steps
+        collide). Three more that no span holds: ``"unspanned"``, a tick
+        minus the leaf phases inside it (``LEAF_PHASES``; what nests in one
+        of those is its parent's), ``"between"``, one tick's end to the
+        next's start where the engine had work (both ran a program, so the
+        earlier one left it in flight): the driver's loop around
+        ``tick()``, and ``"overlapped_pct"``, the share of those ticks that
+        issued their program ahead of the read of the one before
+        (``serve.tick``'s ``overlapped``). Nothing is kept per tick for
+        this."""
         rows = obs.recorded_tail("serve.tick", TICK_PHASES_TICKS)
         mine = [r for r in rows if r.name == "serve.tick"
                 and r.start_ns >= self._created_ns
@@ -1528,28 +1753,31 @@ class ServeEngine:
                  for r in mine}
         by_name: Dict[str, List[int]] = {}
         leaves: Dict[int, int] = dict.fromkeys(ticks, 0)
-        retired: Dict[int, int] = {}
         for r in rows:
-            start, end = ticks.get(r.step, (1, 0))
-            if (r.name.startswith("serve.") and start <= r.start_ns
-                    and r.start_ns + r.duration_ns <= end):
-                by_name.setdefault(r.name, []).append(r.duration_ns)
-                if r.name in LEAF_PHASES:
-                    leaves[r.step] += r.duration_ns
-                if r.name == "serve.retire":
-                    retired[r.step] = r.fields.get("finished", 0)
-        if mine:
-            by_name["unspanned"] = [
-                r.duration_ns - leaves[r.step] for r in mine]
+            if not r.name.startswith("serve.") or r.step is None:
+                continue
+            for step in (r.step, r.step + 1):
+                start, end = ticks.get(step, (1, 0))
+                if start <= r.start_ns and r.start_ns + r.duration_ns <= end:
+                    by_name.setdefault(r.name, []).append(r.duration_ns)
+                    if r.name in LEAF_PHASES:
+                        leaves[step] += r.duration_ns
+                    break
+        if not mine:
+            return {}
+        by_name["unspanned"] = [r.duration_ns - leaves[r.step] for r in mine]
         between = [
             b.start_ns - a.start_ns - a.duration_ns
             for a, b in zip(mine, mine[1:])
             if b.step == a.step + 1 and b.fields["decodes"] + b.fields["chunks"]
-            and a.fields["decodes"] + a.fields["chunks"] > retired.get(a.step, 0)]
+            and a.fields["decodes"] + a.fields["chunks"]]
         if between:
             by_name["between"] = between
-        return {name: round(statistics.median(d) / 1e6, 6)
-                for name, d in sorted(by_name.items())}
+        out = {name: round(statistics.median(d) / 1e6, 6)
+               for name, d in sorted(by_name.items())}
+        out["overlapped_pct"] = round(
+            100.0 * sum(r.fields["overlapped"] for r in mine) / len(mine), 6)
+        return out
 
     def run_until_done(self, max_ticks: int = 100_000) -> List[Sequence]:
         """Drain every submitted request; returns finished sequences in

@@ -107,6 +107,11 @@ class Sequence:
     # speculative decoding: this tick's drafted candidate tokens (set by
     # propose_drafts, consumed by the engine's mixed program)
     draft: List[int] = dataclasses.field(default_factory=list)
+    # tokens a program has been issued for and the host has not read yet
+    # (the engine runs one tick ahead of its reads): never in ``generated``
+    # or ``token_stamps``, counted wherever a LENGTH decides. ``num_cached``
+    # is advanced as a row is issued, so it needs no correction
+    in_flight: int = 0
     # telemetry stamps (monotonic seconds): the scheduler stamps the
     # FIRST time the sequence gets a slot (a re-admission after a
     # preemption keeps it: the request's queue wait ended there); the
@@ -127,7 +132,9 @@ class Sequence:
 
     @property
     def remaining_tokens(self) -> int:
-        return self.request.max_new_tokens - len(self.generated)
+        """Tokens still to be asked for: those in flight are asked for."""
+        return (self.request.max_new_tokens - len(self.generated)
+                - self.in_flight)
 
     @property
     def prefilling(self) -> bool:
@@ -138,7 +145,10 @@ class Sequence:
 
     @property
     def done(self) -> bool:
-        if len(self.generated) >= self.request.max_new_tokens:
+        """Nothing more to schedule: the budget is spent, counting what is
+        in flight, or the last token read is the EOS. The engine finishes
+        a ``done`` sequence once nothing of it is in flight."""
+        if self.remaining_tokens <= 0:
             return True
         eos = self.request.eos_token_id
         return eos is not None and bool(self.generated) and self.generated[-1] == eos
@@ -801,13 +811,12 @@ class ContinuousBatchingScheduler:
                           key=lambda s: s.request.req_id):
             if seq.state is not SequenceState.RUNNING:
                 continue  # evicted earlier in this very loop
-            if seq.prefilling:
-                step = min(chunk, seq.prefill_len - seq.num_cached)
-            else:
-                # a decode row scores its last token plus this tick's
-                # drafts in one call — blocks must cover every scored
-                # slot (rejected drafts' slots are simply overwritten)
-                step = 1 + len(seq.draft)
+            if seq.done:
+                continue  # its last token is in flight: it asks for nothing
+            # a decode row scores its last token plus this tick's drafts
+            # in one call — blocks must cover every scored slot (rejected
+            # drafts' slots are simply overwritten)
+            step = self._next_step(seq)
             need = self.blocks_needed(seq.num_cached + step) - len(seq.blocks)
             if need > self.available_blocks() and seq.draft:
                 # speculation is opportunistic: shed the drafts before
@@ -847,7 +856,8 @@ class ContinuousBatchingScheduler:
         # each surviving decoding sequence decodes one token this tick;
         # mid-prefill rows don't decode (they have no token yet) and are
         # charged per chunk below instead
-        decoding = [s for s in self.running.values() if not s.prefilling]
+        decoding = [s for s in self.running.values()
+                    if not s.prefilling and not s.done]
         budget = self.config.token_budget - len(decoding)
 
         prefills: List[Sequence] = []
@@ -923,16 +933,65 @@ class ContinuousBatchingScheduler:
         # prefill list (its slot is gone; it waits at the queue front)
         prefills = [s for s in prefills if s.state == SequenceState.RUNNING]
         # decodes: running sequences that were NOT just admitted (their
-        # prefill emits this tick's token), are not mid-prefill, and
-        # survived preemption
+        # prefill emits this tick's token), are not mid-prefill, survived
+        # preemption, and have a token left to ask for
         new = {id(s) for s in prefills}
         decodes = [
             self.running[slot] for slot in sorted(self.running)
             if id(self.running[slot]) not in new
             and not self.running[slot].prefilling
+            and not self.running[slot].done
         ]
         return Tick(prefills=prefills, decodes=decodes, preempted=preempted,
                     cow_pairs=cow_pairs, first_admitted=first_admitted)
+
+    def _next_step(self, seq: Sequence) -> int:
+        """Tokens a running sequence brings to its next tick: its next
+        prefill chunk, or its last token plus this tick's drafts."""
+        if seq.prefilling:
+            return min(self.config.prefill_chunk,
+                       seq.prefill_len - seq.num_cached)
+        return 1 + len(seq.draft)
+
+    def may_preempt(self) -> bool:
+        """Whether the next ``schedule()`` could preempt a running
+        sequence: a bound, never a miss, at O(running) and with nothing
+        allocated. The engine asks before it schedules a tick AHEAD of the
+        tokens it has read: a victim's ``resume_prompt`` must hold every
+        token it was given, so the tick in flight is read first. GROW
+        preempts when the rows' next steps, and a fork for every shared
+        block they would write into, need more blocks than are grantable;
+        ADMIT only for a head OLDER than a running sequence (a victim
+        resuming, pinned ids), bounded by a first chunk and every prompt
+        block the trie could pin for each head a free slot could take."""
+        bs = self.config.block_size
+        available = self.available_blocks()
+        need = 0
+        for seq in self.running.values():
+            if seq.done:
+                continue
+            step = self._next_step(seq)
+            need += self.blocks_needed(seq.num_cached + step) - len(seq.blocks)
+            first = seq.num_cached // bs
+            last = (seq.num_cached + step - 1) // bs
+            need += sum(self.allocator.refcount(b) > 1
+                        for b in seq.blocks[first:last + 1])
+        if need > available:
+            return True
+        if not self.waiting or not self.running:
+            return False
+        youngest = max(s.request.req_id for s in self.running.values())
+        cached = self.prefix_cache is not None
+        # by index: a fleet's submit thread appends while this one reads
+        for i in range(min(len(self._free_slots), len(self.waiting))):
+            head = self.waiting[i]
+            tokens = len(head.request.prompt) + len(head.generated)
+            need += self.blocks_needed(min(self.config.prefill_chunk, tokens))
+            if cached:  # a match takes its blocks out of the evictable set
+                need += (tokens - 1) // bs
+            if head.request.req_id < youngest and need > available:
+                return True
+        return False
 
     def _preempt_youngest(self, for_seq: Sequence,
                           preempted: List[Sequence]) -> bool:

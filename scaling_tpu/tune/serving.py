@@ -308,8 +308,8 @@ def rank_serving_points(
 class ServeCalibration:
     """Measured-vs-analytic tick-time factor from real serve run dirs.
 
-    A serve bench run leaves ``serve.mixed`` spans
-    (the device tick) and a serve-summary carrying the engine SHAPE it
+    A serve bench run leaves ``serve.mixed`` and ``serve.mixed.wait``
+    spans (the device tick: issued, and waited for) and a serve-summary carrying the engine SHAPE it
     ran (``engine``: mp/num_slots/block_size/token_budget...). The
     factor is measured mean tick seconds over the analytic prediction
     for that exact shape — applied multiplicatively to every candidate,
@@ -339,6 +339,13 @@ class ServeCalibration:
             if sp.get("span") == "serve.mixed"
             and sp.get("dur_s") is not None
         ]
+        # the wait for a program's samples lies outside serve.mixed, one
+        # tick() call later (the engine issues a tick ahead of its reads)
+        waited = sum(
+            float(sp["dur_s"]) for sp in data.spans
+            if sp.get("span") == "serve.mixed.wait"
+            and sp.get("dur_s") is not None
+        )
         summaries = [
             e for e in data.lifecycle if e.get("event") == "serve-summary"
         ]
@@ -357,7 +364,9 @@ class ServeCalibration:
             )
         except (KeyError, TypeError, ValueError):
             return None
-        measured = sum(float(sp["dur_s"]) for sp in spans) / len(spans)
+        measured = (
+            sum(float(sp["dur_s"]) for sp in spans) + waited
+        ) / len(spans)
         predicted = predict_tick_seconds(
             model, point, topo, calibration
         )["tick_s"]

@@ -393,6 +393,7 @@ def lowered_mixed_program(one_chip, monkeypatch, bucket, heads, kv_heads,
         jax.ShapeDtypeStruct((engine._layout.size(width),), jnp.int32,
                              sharding=one_chip),  # the tick's one operand
         on_chip(engine._base_key),
+        on_chip(engine._prev),  # the program before it's samples
     )
     return lowered, jax.tree_util.tree_leaves(params), pool
 
@@ -429,7 +430,9 @@ def assert_pools_updated_in_place(text, first, layers, pool):
         int(param): int(out) for out, param in
         re.findall(r"\{(\d+)\}: \((\d+), \{\}, \S+-alias\)", aliases)
     }
-    assert pairs == {first + j: 1 + j for j in range(2 * layers)}
+    # two outputs lie ahead of the state: the host's read and the grid the
+    # next program is fed
+    assert pairs == {first + j: 2 + j for j in range(2 * layers)}
     dims = ",".join(map(str, pool.shape))
     copies = whole_copies(text, rf"bf16\[{dims}\]")
     assert not copies, f"{len(copies)} whole-pool copies in the compiled tick"
@@ -506,7 +509,7 @@ def test_hybrid_mixed_program_updates_pools_and_recurrent_lines_in_place(
     pairs = {int(param): int(out) for out, param in
              re.findall(r"\{(\d+)\}: \((\d+), \{\}, \S+-alias\)", aliases)}
     first = len(params)
-    assert pairs == {first + j: 1 + j for j in range(4)}   # k, v, ssm, conv
+    assert pairs == {first + j: 2 + j for j in range(4)}   # k, v, ssm, conv
     dims = ",".join(map(str, pool.shape))
     for shape in (rf"bf16\[{dims}\]", r"f32\[8,64,64,128\]"):
         copies = whole_copies(text, shape)

@@ -247,7 +247,7 @@ def _program_and_args(inf, kv_dtype, spec_k, width=WIDTHS[0], **config):
     packed, tick = engine._layout.host(width)
     tick.new_lens[:] = 1
     args = (inf.params, engine._pool_state(), engine._dev(packed),
-            engine._base_key)
+            engine._base_key, engine._prev)
     return engine, engine._build_mixed_fn(width).__wrapped__, args
 
 
@@ -306,8 +306,8 @@ def test_donated_state_aliases_the_output_computed_from_it(
     k1, v1, ...) fails this: three layers' six pools were paired with
     outputs 1, 2, 4, 5, 7, 8 where 1, 2, 3, 4, 5, 6 compute from them,
     and XLA copied every pool but the first on every call. A routed
-    model's first output is one vector (grid + load), still ONE leaf
-    ahead of the state; on the serving mesh every pool is sharded over
+    model's first output is one vector (grid + load), still ONE leaf, and
+    with the grid the next program is fed TWO leaves lie ahead of the state; on the serving mesh every pool is sharded over
     ``model`` and XLA does the pairing. Both token widths' programs
     donate and return the same state. A looped model's pools pass through
     its rolled loop's carry on their way from argument to output: still
@@ -323,7 +323,7 @@ def test_donated_state_aliases_the_output_computed_from_it(
         inference_modules[model], kv_dtype, spec_k,
         (WIDE_WIDTHS if wide else WIDTHS)[bucket], **(WIDE if wide else {}))
     if wide:
-        sampled, state = jax.eval_shape(fn, *args)
+        sampled, _, state = jax.eval_shape(fn, *args)
         assert (jax.tree_util.tree_structure(state)
                 == jax.tree_util.tree_structure(args[1]))
         for got, held in zip(jax.tree_util.tree_leaves(state),
@@ -337,8 +337,9 @@ def test_donated_state_aliases_the_output_computed_from_it(
     donated = jax.tree_util.tree_leaves(args[1])
     # a K and a V pool (+ 2 scales) an attention layer, then the lines
     assert len(donated) == kv_layers * (4 if kv_dtype == "int8" else 2) + lines
-    # outputs flatten as (tokens, *state): state leaf j is output 1 + j
-    want = {first + j: 1 + j for j in range(len(donated))}
+    # outputs flatten as (tokens, the grid the next program is fed, *state):
+    # state leaf j is output 2 + j
+    want = {first + j: 2 + j for j in range(len(donated))}
     assert _aliases(lowered) == want
 
 
@@ -350,13 +351,17 @@ def test_programs_return_the_state_in_pool_state_structure(
         inference_modules, model, kv_dtype, width):
     engine, fn, args = _program_and_args(
         inference_modules[model], kv_dtype, 0, width)
-    sampled, state = jax.eval_shape(fn, *args)
+    sampled, feed, state = jax.eval_shape(fn, *args)
     sw = engine.config.sample_width
     tail = {"routed": engine.num_experts, "looped": LOOP_STEPS,
             "hybrid": 2 + 1}  # the held experts' load + the absent count
     assert sampled.shape == (
         (SLOTS * sw + tail[model],) if model in tail else (SLOTS, sw)
     )
+    # what the next program takes as ``prev``: the grid alone, one shape
+    # whatever follows it in the host's read
+    assert (feed.shape, feed.dtype) == (engine._prev.shape, engine._prev.dtype)
+    assert feed.shape == (SLOTS, sw)
     structure = jax.tree_util.tree_structure
     assert structure(state) == structure(engine._pool_state())
     assert (state[2] is None) == (kv_dtype == "native")

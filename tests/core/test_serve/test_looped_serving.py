@@ -129,9 +129,11 @@ def test_a_copy_on_write_fork_copies_the_block_in_every_line(looped):
     want = looped.generate(prompt, max_tokens=8).completion_ids
     engine = engine_of(looped, enable_prefix_cache=False)
     seq = engine.submit(prompt, max_new_tokens=8)
-    while len(seq.generated) < 2:
+    while len(seq.generated) < 3:
         engine.tick()
+    # a fourth token is in flight, its row's write counted in num_cached;
     # someone else now references the block the next token is written into
+    assert seq.in_flight == 1 and seq.num_cached == len(prompt) + 3
     filled = seq.num_cached % 4
     assert filled, "the next write must land inside a block"
     target = seq.blocks[seq.num_cached // 4]
@@ -163,12 +165,12 @@ def test_one_rolled_program_whatever_the_tick_holds(looped, undisturbed):
     assert fn._cache_size() == 1
     packed, _ = engine._layout.host(width)
     text = fn.lower(looped.params, engine._pool_state(), packed,
-                    engine._base_key).as_text()
+                    engine._base_key, engine._prev).as_text()
     plain = plain_inference()
     plain_engine = engine_of(plain, num_blocks=17)
     plain_text = plain_engine._build_mixed_fn(width).lower(
         plain.params, plain_engine._pool_state(), packed,
-        plain_engine._base_key).as_text()
+        plain_engine._base_key, plain_engine._prev).as_text()
     assert "stablehlo.while" in text
     assert text.count("stablehlo.dot_general") == plain_text.count(
         "stablehlo.dot_general") > 0

@@ -129,7 +129,7 @@ def row_major_program(engine):
     inf, cfg = engine.inf, engine.config
     width, sw = cfg.mixed_width, cfg.sample_width
 
-    def mixed(params, state, packed, base_key):
+    def mixed(params, state, packed, base_key, prev):
         (tables, ctx_lens, new_lens, topks, reqids, gen0, temps, topps,
          tokens) = engine._layout.split(packed)
         tokens = tokens.reshape(cfg.num_slots, width)
@@ -201,7 +201,7 @@ def pack(engine, shared, tokens):
 
 def call(engine, fn, state, shared, tokens):
     return fn(engine.inf.params, state, pack(engine, shared, tokens),
-              engine._base_key)
+              engine._base_key, engine._prev)
 
 
 @pytest.mark.parametrize("tick", list(TICKS))
@@ -221,11 +221,13 @@ def test_packed_tick_is_the_row_major_tick(models, model, spec_k, kv_dtype,
 
     want, want_state, want_load = call(
         engine, row_major_program(engine), state, shared, tokens.ravel())
-    got, got_state = call(
+    got, feed, got_state = call(
         engine, engine._build_mixed_fn(width), state, shared, padded)
 
     n, sw, new_lens = SLOTS, engine.config.sample_width, shared["new_lens"]
     got, want = np.asarray(got), np.asarray(want)
+    # what the next program is fed is the grid itself
+    assert np.asarray(feed).tolist() == got[:n * sw].reshape(n, sw).tolist()
     if engine.num_experts:
         # no assignment dropped, and only real positions counted: every
         # real token's top_k choices, in every layer
@@ -370,7 +372,7 @@ def test_the_program_holds_one_layer_function_however_deep_the_stack():
         padded[:len(packed)] = packed
         text = engine._build_mixed_fn(SMALL).lower(
             engine.inf.params, state, pack(engine, shared, padded),
-            engine._base_key).as_text()
+            engine._base_key, engine._prev).as_text()
         dots.append(text.count("stablehlo.dot_general"))
     assert dots[0] == dots[1] > 0
 

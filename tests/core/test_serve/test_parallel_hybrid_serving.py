@@ -166,11 +166,12 @@ def test_every_donated_leaf_is_aliased_to_the_output_computed_from_it(falcon):
     packed = jnp.zeros((engine._layout.size(width),), jnp.int32)
     text = jax.jit(engine._build_mixed_fn(width).__wrapped__, donate_argnums=(1,),
                    keep_unused=True).lower(
-        falcon.params, state, packed, engine._base_key).as_text()
+        falcon.params, state, packed, engine._base_key, engine._prev).as_text()
     leaves = len(jax.tree.leaves(state))
     assert leaves == 4 * LAYERS
     aliased = re.findall(r"tf\.aliasing_output = (\d+)", text)
-    assert sorted(map(int, aliased)) == list(range(1, leaves + 1))
+    # behind the host's read and the grid the next program is fed
+    assert sorted(map(int, aliased)) == list(range(2, leaves + 2))
 
 
 def test_the_program_is_the_reference_at_every_position(falcon, reference):
@@ -408,8 +409,8 @@ def test_the_two_mixers_the_mlp_and_the_head_lie_in_scopes_of_their_own(falcon):
     width = engine.config.mixed_widths[0]
     packed = jnp.zeros((engine._layout.size(width),), jnp.int32)
     hlo = jax.jit(engine._build_mixed_fn(width).__wrapped__).lower(
-        falcon.params, engine._pool_state(), packed, engine._base_key
-    ).compile().as_text()
+        falcon.params, engine._pool_state(), packed, engine._base_key,
+        engine._prev).compile().as_text()
     from benchmark.readers.parallel_hybrid import SCOPES
 
     names = set(re.findall(r'op_name="([^"]+)"', hlo))

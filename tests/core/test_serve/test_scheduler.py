@@ -417,3 +417,88 @@ def test_gauges_track_occupancy():
     assert g["serve_waiting_seqs"] == 0.0
     assert g["serve_free_blocks"] == 4.0
     assert g["serve_pool_utilization"] == pytest.approx(0.5)
+
+
+# ------------------- a tick ahead of the tokens the host has read (ISSUE 60)
+def issue(tick):
+    """What the engine does as it ISSUES a tick: the rows' sequences are
+    projected past it, the tokens they will produce counted in flight."""
+    for seq in tick.prefills:
+        seq.num_cached = len(seq.resume_prompt)
+        seq.in_flight += 1
+    for seq in tick.decodes:
+        seq.num_cached += 1
+        seq.in_flight += 1
+
+
+def read(tick):
+    for seq in tick.prefills + tick.decodes:
+        seq.in_flight -= 1
+        seq.generated.append(1)
+
+
+@pytest.mark.parametrize("read_tokens,in_flight,remaining,done", [
+    (0, 0, 3, False), (1, 1, 1, False), (2, 1, 0, True), (3, 0, 0, True)])
+def test_a_token_in_flight_counts_wherever_a_length_decides(
+        read_tokens, in_flight, remaining, done):
+    sched = make_sched()
+    seq = submit(sched, 0, max_new=3)
+    seq.generated += [5] * read_tokens
+    seq.in_flight = in_flight
+    assert (seq.remaining_tokens, seq.done) == (remaining, done)
+    assert 5 in seq.generated or not read_tokens  # never a placeholder
+
+
+def test_a_sequence_whose_last_token_is_in_flight_is_not_scheduled_again():
+    """Its budget is spent counting what is in flight: it keeps its slot
+    and its blocks until the engine has read that token, asks for no block
+    and brings no row; its neighbour decodes on."""
+    sched = make_sched()
+    short, long = submit(sched, 0, max_new=2), submit(sched, 1, max_new=5)
+    first = sched.schedule()
+    issue(first)                       # both first tokens in flight
+    second = sched.schedule()          # scheduled a tick ahead of the read
+    assert second.decodes == [short, long]
+    issue(second)
+    read(first)
+    assert short.done and short.in_flight == 1 and not long.done
+    blocks = list(short.blocks)
+    third = sched.schedule()
+    assert third.decodes == [long] and not third.preempted
+    assert short.slot is not None and short.blocks == blocks
+    issue(third)
+    read(second)
+    assert short.done and short.in_flight == 0   # the engine finishes it now
+    sched.finish(short)
+    assert short.state is SequenceState.FINISHED
+
+
+def test_may_preempt_is_false_with_room_and_true_before_a_grow_preempts():
+    sched = make_sched(num_slots=2, num_blocks=6)   # 5 usable blocks of 2
+    a, b = submit(sched, 0, prompt_len=3, max_new=6), submit(
+        sched, 1, prompt_len=3, max_new=6)
+    assert not sched.may_preempt()
+    first = sched.schedule()           # 2 blocks each: 1 left
+    issue(first)
+    read(first)
+    assert not sched.may_preempt()     # both rows write inside their blocks
+    issue(sched.schedule())
+    assert sched.may_preempt()         # each needs a third block: one is left
+    tick = sched.schedule()
+    assert tick.preempted == [b] and tick.decodes == [a]
+
+
+def test_may_preempt_counts_a_waiting_head_older_than_a_running_sequence():
+    """ADMIT preempts only on behalf of an OLDER request (a victim resuming,
+    ids pinned by a replay): a younger head never makes the bound fire."""
+    sched = make_sched(num_slots=3, num_blocks=8)   # 7 usable blocks of 2
+    submit(sched, 5, prompt_len=3, max_new=2)
+    young = submit(sched, 7, prompt_len=3, max_new=2)
+    issue(sched.schedule())            # 2 blocks each, 3 left
+    submit(sched, 9, prompt_len=8, max_new=2)     # younger: waits its turn
+    assert not sched.may_preempt()
+    assert not sched.schedule().preempted
+    sched.waiting.clear()
+    submit(sched, 3, prompt_len=8, max_new=2)     # older: it would preempt
+    assert sched.may_preempt()
+    assert sched.schedule().preempted == [young]

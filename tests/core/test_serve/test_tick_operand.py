@@ -5,8 +5,9 @@ opening slices give back: all nine arrays bit for bit, the float32
 sampler rows included, at both token widths. (b) An engine tick hands
 the device exactly one host array, and the ``serve.mixed.dispatch``
 span and ``stats_snapshot()`` say so: dense and routed, one device and
-the mp = 2 serving mesh. (c) The lowered program takes four arguments:
-the parameters, the donated pool state, one ``s32[N]`` and the key.
+the mp = 2 serving mesh. (c) The lowered program takes five arguments:
+the parameters, the donated pool state, one ``s32[N]``, the key and the
+samples of the program before it, which never left the device.
 """
 
 import jax
@@ -145,18 +146,22 @@ def test_no_tick_counted_reads_as_none(models):
 # ------------------------------------------- (c) the program's signature
 @pytest.mark.parametrize("width", [SMALL, FULL], ids=["small", "full"])
 @pytest.mark.parametrize("model", ["dense", "routed", "mp2"])
-def test_the_lowered_program_takes_four_arguments(models, model, width):
+def test_the_lowered_program_takes_five_arguments(models, model, width):
     """Flattened, ``main`` takes the parameters' leaves, the pool state's,
-    ONE ``s32[N]`` and the key: nothing else of a tick is an argument."""
+    ONE ``s32[N]``, the key and the samples of the program before it
+    (``prev``, already on the device: a decode row's last token while the
+    host has not read it): nothing else of a tick is an argument."""
     engine = make_engine(models[model])
     packed, _ = engine._layout.host(width)
     args = (engine.inf.params, engine._pool_state(), engine._dev(packed),
-            engine._base_key)
+            engine._base_key, engine._prev)
     text = engine._build_mixed_fn(width).lower(*args).as_text()
     signature = text.split("@main(", 1)[1].split(") -> ", 1)[0]
     types = [a.split("tensor<", 1)[1].split(">", 1)[0]
              for a in signature.split("%arg")[1:]]
     leaves = [len(jax.tree_util.tree_leaves(a)) for a in args]
-    assert leaves[2:] == [1, 1] and len(types) == sum(leaves)
-    assert types[-2] == f"{engine._layout.size(width)}xi32"
-    assert types[-1] == "2xui32"
+    assert leaves[2:] == [1, 1, 1] and len(types) == sum(leaves)
+    assert types[-3] == f"{engine._layout.size(width)}xi32"
+    assert types[-2] == "2xui32"
+    assert types[-1] == (
+        f"{engine.config.num_slots}x{engine.config.sample_width}xi32")

@@ -70,28 +70,49 @@ def test_under_a_capture_every_tick_lies_on_the_host_plane_by_phase(
         rec = obs.stop_capture()
     ticks = e.tick_index - first_tick
     assert ticks > 4
-    # the capture's list: one tick after the other, each phase once, in
-    # the order the spans close, all of one tick under its step
+    # the capture's list: one tick() call after the other, each phase once
+    # a program, in the order the spans close (ISSUE 60): a call issues the
+    # program of ITS step, then reads the program of the step before it,
+    # whose wait, emit and retire carry that step. Only the first call has
+    # nothing to read and only the last nothing to issue.
     # (a request's serve.first_token row is no phase of a tick: below)
     spans = [s for s in rec.spans if s[0] != "serve.first_token"]
     assert len(rec.spans) - len(spans) == 3
-    assert [s[0] for s in spans] == list(PHASES) * ticks
+    from tests.core.test_serve.test_tick_telemetry import (
+        READ as read_phases, closing_order)
+
+    expected = closing_order(first_tick, ticks)
+    assert [(s[0], s[3]["step"]) for s in spans] == expected
+    assert set(PHASES) == {name for name, _ in expected}
+    row = {(s[0], s[3]["step"]): s for s in spans}
     for i in range(ticks):
-        row = {s[0]: s for s in spans[i * len(PHASES):(i + 1) * len(PHASES)]}
-        assert {s[3]["step"] for s in row.values()} == {first_tick + i}
-        tick = row["serve.tick"]
-        assert tick[3]["decodes"] + tick[3]["chunks"] > 0
-        children = [row[n] for n in ("serve.schedule", "serve.mixed",
-                                     "serve.emit", "serve.retire")]
+        step = first_tick + i
+        tick = row["serve.tick", step]
+        issued = i < ticks - 1
+        assert (tick[3]["decodes"] + tick[3]["chunks"] > 0) == issued
+        assert tick[3]["overlapped"] == (0 < i < ticks - 1)
+        children = [row["serve.schedule", step]]
+        mixed = []
+        if issued:
+            children.append(row["serve.mixed", step])
+            mixed = [row[n, step] for n in ("serve.mixed.build",
+                                            "serve.mixed.dispatch")]
+            assert all(c[3]["parent"] == "serve.mixed" for c in mixed)
+            assert row["serve.mixed", step][2] >= sum(c[2] for c in mixed)
+        if i:  # the read of the program before, inside THIS call
+            children += [row[n, step - 1] for n in read_phases]
+        if not issued:
+            children.append(row["serve.retire", step])
         assert all(c[3]["parent"] == "serve.tick" for c in children)
         assert tick[2] >= sum(c[2] for c in children)
-        mixed = [row[n] for n in ("serve.mixed.build", "serve.mixed.dispatch",
-                                  "serve.mixed.wait")]
-        assert all(c[3]["parent"] == "serve.mixed" for c in mixed)
-        assert row["serve.mixed"][2] >= sum(c[2] for c in mixed)
-        # in time too: each child inside its tick
+        # in time too: each child inside its tick() call, the program
+        # issued before the one before it is waited for
         assert all(tick[1] <= c[1] and c[1] + c[2] <= tick[1] + tick[2]
                    for c in children + mixed)
+        if i and issued:
+            wait = row["serve.mixed.wait", step - 1]
+            assert row["serve.mixed", step][1] + row[
+                "serve.mixed", step][2] <= wait[1]
     # the same spans on the host plane of the .xplane.pb, by name
     names = {}
     for plane in ProfileData.from_file(str(rec.trace_file())).planes:
@@ -100,7 +121,7 @@ def test_under_a_capture_every_tick_lies_on_the_host_plane_by_phase(
                 for event in line.events:
                     names[event.name] = names.get(event.name, 0) + 1
     for phase in PHASES:
-        assert names.get(phase) == ticks, phase
+        assert names.get(phase) == sum(n == phase for n, _ in expected), phase
     # and the counters the capture differenced are the traced ticks'
     assert rec.counters["serve_prefill_tokens_total"] == sum(
         len(p) for p in PROMPTS[:3])
@@ -319,34 +340,51 @@ def test_tick_phases_ms_is_the_median_of_each_phase_over_the_last_ticks(
         e.submit(p, 4)
     e.run_until_done()
     phases = e.stats_snapshot()["tick_phases_ms"]
-    # every span of a tick, and the two parts of the account that no span
-    # holds (ISSUE 57): a tick minus its leaves, and tick to tick
-    assert sorted(phases) == sorted(PHASES + ("between", "unspanned"))
+    # every span of a tick, and the parts of the account that no span holds:
+    # a tick minus its leaves and tick to tick (ISSUE 57), and the share of
+    # the ticks that issued their program ahead of a read (ISSUE 60)
+    assert sorted(phases) == sorted(
+        PHASES + ("between", "unspanned", "overlapped_pct"))
     json.dumps(phases)  # the replica's stats RPC carries it
     rows = obs.recorded_spans(since_ns=since)
+    ticks = [r for r in rows if r.name == "serve.tick"]
+    assert len(ticks) == e.tick_index
     for name in PHASES:
         mine = [r.duration_ns for r in rows if r.name == name]
-        assert len(mine) == e.tick_index
+        # the last call only reads: one program fewer than tick() calls
+        assert len(mine) == e.tick_index - (
+            name not in ("serve.tick", "serve.schedule", "serve.retire"))
         assert phases[name] == pytest.approx(median(mine) / 1e6, abs=1e-6)
-    ticks = [r for r in rows if r.name == "serve.tick"]
+    assert phases["overlapped_pct"] == pytest.approx(
+        100.0 * (len(ticks) - 2) / len(ticks))
     leaves = set(PHASES) - {"serve.tick", "serve.mixed"}
     assert leaves == engine_module.LEAF_PHASES
-    unspanned = [t.duration_ns - sum(r.duration_ns for r in rows
-                                     if r.name in leaves and r.step == t.step)
-                 for t in ticks]
+    # a call's leaves by TIME: its own schedule, build and dispatch, and the
+    # wait, emit and retire of the step before it
+    unspanned = [t.duration_ns - sum(
+        r.duration_ns for r in rows if r.name in leaves
+        and t.start_ns <= r.start_ns
+        and r.start_ns + r.duration_ns <= t.start_ns + t.duration_ns)
+        for t in ticks]
     assert all(u >= 0 for u in unspanned)
     assert phases["unspanned"] == pytest.approx(median(unspanned) / 1e6, abs=1e-6)
     # run_until_done calls tick() back to back, and the engine has work
-    # from each tick to the next until the last retires the last request
+    # from each tick to the next: a program is in flight until the last
+    # call, which reads it and retires the last request
     retired = {r.step: r.fields["finished"] for r in rows if r.name == "serve.retire"}
-    assert sum(retired.values()) == len(PROMPTS) and retired[ticks[-1].step] > 0
+    assert sum(retired.values()) == len(PROMPTS)
+    assert retired[ticks[-2].step] > 0 and retired[ticks[-1].step] == 0
     between = [b.start_ns - a.start_ns - a.duration_ns
                for a, b in zip(ticks, ticks[1:])
-               if a.fields["decodes"] + a.fields["chunks"] > retired[a.step]]
-    assert len(between) >= len(ticks) - len(PROMPTS) and min(between) >= 0
+               if a.fields["decodes"] + a.fields["chunks"]
+               and b.fields["decodes"] + b.fields["chunks"]]
+    assert len(between) == len(ticks) - 2 and min(between) >= 0
     assert phases["between"] == pytest.approx(median(between) / 1e6, abs=1e-6)
     assert phases["between"] + phases["unspanned"] < phases["serve.tick"]
-    assert phases["serve.tick"] >= phases["serve.mixed"] >= phases["serve.mixed.wait"]
+    # the wait lies outside serve.mixed now: the program is issued, and
+    # the call goes on to read the one before it
+    assert phases["serve.tick"] >= phases["serve.mixed"] >= phases["serve.mixed.dispatch"]
+    assert phases["serve.tick"] >= phases["serve.mixed.wait"]
     # over the LAST ticks only: with room for 3, the first ticks fall out
     monkeypatch.setattr(engine_module, "TICK_PHASES_TICKS", 3)
     last = [r.duration_ns for r in rows if r.name == "serve.tick"][-3:]

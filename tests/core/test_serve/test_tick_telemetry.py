@@ -21,10 +21,36 @@ WORK = [([1, 2, 3, 4, 5], 6), ([7, 8, 9], 4),
         ([11, 12, 13, 14, 15, 16, 17, 18, 19], 5), ([3, 1, 4], 3)]
 TOKENS = sum(n for _, n in WORK)
 PROMPT_TOKENS = sum(len(p) for p, _ in WORK)
-TICKS = 11  # the scheduler's, for WORK on two slots
+# tick() calls for WORK on two slots: 12 programs (a finished request's slot
+# is free a tick later than when every tick was read as it was issued, ISSUE
+# 60: one program more for each of the two requests that waited for a slot)
+# and the call that only reads the last
+TICKS = 13
 PHASES = ("serve.schedule", "serve.mixed.build", "serve.mixed.dispatch",
           "serve.mixed.wait", "serve.mixed", "serve.emit", "serve.retire",
           "serve.tick")
+# the order in which the spans of one tick() call close (ISSUE 60): the
+# program of its own step is issued, then the one before it is read
+ISSUE = ("serve.schedule", "serve.mixed.build", "serve.mixed.dispatch",
+         "serve.mixed")
+READ = ("serve.mixed.wait", "serve.emit", "serve.retire")
+
+
+def closing_order(first, calls):
+    """``(name, step)`` of every span that ``calls`` tick() calls close, the
+    first at step ``first``, when only the first has nothing to read and
+    only the last nothing to issue."""
+    out = []
+    for i in range(calls):
+        step = first + i
+        out += [(name, step) for name in (
+            ISSUE if i < calls - 1 else ISSUE[:1])]
+        if i:
+            out += [(name, step - 1) for name in READ]
+        if i == calls - 1:  # no program of its own: an empty retire says so
+            out.append(("serve.retire", step))
+        out.append(("serve.tick", step))
+    return out
 # what serve.mixed's row held at the parent of ISSUE 57, for a dense model
 MIXED_FIELDS = {"decodes", "chunks", "width", "tokens", "sampled_rows",
                 "kv_rows", "kv_tiles"}
@@ -113,8 +139,13 @@ def test_after_a_fixed_run_every_serve_metric_reads_a_per_token_count(
         named("serve_requests_completed_total"): 4.0,
         named("serve_tokens_generated_total"): float(TOKENS),
         named("serve_prefill_tokens_total"): float(PROMPT_TOKENS),
-        named("serve_sampler_ticks_total", path="greedy"): float(TICKS),
-        named("serve_mixed_ticks_total", width=8): float(TICKS),
+        named("serve_sampler_ticks_total", path="greedy"): float(TICKS - 1),
+        named("serve_mixed_ticks_total", width=8): float(TICKS - 1),
+        # every call but the first (nothing to run ahead of) and the last
+        # (nothing left to issue) issued its program before it read
+        named("serve_ticks_overlapped_total"): float(TICKS - 2),
+        named("serve_ticks_synchronous_total", reason="first"): 1.0,
+        named("serve_ticks_synchronous_total", reason="drained"): 1.0,
     }
     sched = engine.scheduler
     assert gauges.items() >= {
@@ -146,7 +177,9 @@ def test_emit_and_retire_say_what_they_handled_and_a_tick_closes_8_spans(
     rows = obs.recorded_spans(since_ns=since)
     spans = [r for r in rows if r.name != "serve.first_token"]
     ticks = [r for r in spans if r.name == "serve.tick"]
-    assert [r.name for r in spans] == list(PHASES) * len(ticks)
+    assert len(ticks) == TICKS
+    assert [(r.name, r.step) for r in spans] == closing_order(
+        ticks[0].step, TICKS)
     emits = [r for r in spans if r.name == "serve.emit"]
     assert sum(r.fields["tokens"] for r in emits) == TOKENS
     by_step = {r.step: r for r in ticks}
@@ -197,11 +230,14 @@ def test_a_tick_looks_nothing_up_in_the_registry_after_a_label_sets_first_use(
     lookups = []
     real = MetricsRegistry._get
     monkeypatch.setattr(MetricsRegistry, "_get", lambda self, cls, name, labels, **kw: (
-        lookups.append(name) or real(self, cls, name, labels, **kw)))
+        lookups.append((name, *sorted((labels or {}).items())))
+        or real(self, cls, name, labels, **kw)))
     engine.submit(*WORK[0])
     engine.run_until_done()
     first = list(lookups)
-    assert "serve_tokens_generated_total" in first and len(set(first)) == len(first)
+    # once a label set: serve_ticks_synchronous_total under its two reasons
+    assert ("serve_tokens_generated_total",) in first
+    assert len(set(first)) == len(first)
     engine.submit(*WORK[1])
     engine.run_until_done()
     assert lookups == first  # the second request's ticks: handles only
@@ -239,12 +275,13 @@ def test_under_a_capture_an_annotation_carries_its_step_and_the_offset_joins(
         engine.run_until_done()
     finally:
         capture = obs.stop_capture()
-    steps = set(range(first, engine.tick_index))
     spans = [s for s in capture.spans if s[0] != "serve.first_token"]
     annotations = capture.annotations()
-    assert len(annotations) == len(spans) == len(PHASES) * len(steps)
-    assert {(name, step) for name, step, _, _ in annotations} == {
-        (name, step) for name in PHASES for step in steps}
+    expected = closing_order(first, engine.tick_index - first)
+    assert [(name, fields["step"]) for name, _, _, fields in spans] == expected
+    assert len(annotations) == len(expected)
+    assert {(name, step) for name, step, _, _ in annotations} == set(expected)
+    assert {name for name, _ in expected} == set(PHASES)
     # the offset lays every row on its annotation, to microseconds (nine in
     # ten: a row whose thread lost the CPU between the annotation's start
     # and the span's clock read lies off by that pause, on any clock)

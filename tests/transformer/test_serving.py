@@ -699,7 +699,11 @@ def test_decode_rows_never_starve_behind_long_prompt(trained_inference):
     ))
     short = engine.submit([5, 6, 7], max_new_tokens=12)
     engine.tick()  # admits + fully prefills the short prompt (one chunk)
-    assert len(short.generated) == 1
+    # the engine runs a tick ahead of its reads (ISSUE 60): the first token
+    # is in flight, and on the host one tick() later
+    assert short.generated == [] and short.in_flight == 1
+    engine.tick()  # issues the first decode row, then reads that token
+    assert len(short.generated) == 1 and short.in_flight == 1
     long = engine.submit(list(range(1, 18)), max_new_tokens=2)
     ticks_while_prefilling = 0
     while long.prefilling or long.slot is None:
