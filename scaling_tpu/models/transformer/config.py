@@ -226,7 +226,8 @@ class FixedMultipliers(BaseConfig):
 # what sizes a 'latent' layer (nn/latent_attention.py)
 LATENT_FIELDS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
                  "qk_rope_head_dim", "v_head_dim")
-# what makes the 'latent' layers SPARSE (nn/sparse_latent_attention.py)
+# what makes the 'latent' layers (nn/sparse_latent_attention.py) or, in a
+# stack without them, the 'attention' layers (nn/sparse_attention.py) SPARSE
 INDEX_FIELDS = ("index_n_heads", "index_head_dim", "index_topk")
 
 
@@ -363,14 +364,17 @@ class TransformerArchitectureConfig(BaseConfig):
         None, description="the checkpoint's rope_scaling (YaRN alone is "
         "built, nn/rotary.py); applied by 'latent' layers")
     index_n_heads: Optional[int] = Field(
-        None, description="a SPARSE 'latent' layer "
-        "(nn/sparse_latent_attention.py): heads of the indexer that scores "
-        "every cached line for every query; with index_head_dim and "
-        "index_topk, all three or none", gt=0)
+        None, description="a SPARSE attention layer: a layer_pattern's "
+        "'latent' layers (nn/sparse_latent_attention.py) or, in a pattern "
+        "without them, its 'attention' layers (nn/sparse_attention.py): "
+        "heads of the indexer that scores every cached line for every query; "
+        "with index_head_dim and index_topk, all three or none", gt=0)
     index_head_dim: Optional[int] = Field(
         None, description="width of an indexer head and of the ONE index key "
-        "a token leaves in the cache (the third leaf of its line); its first "
-        "qk_rope_head_dim lanes are rotary", gt=0)
+        "a token leaves in the cache (the third leaf of its line); in a "
+        "'latent' layer its first qk_rope_head_dim lanes are rotary (the "
+        "indexer's query comes from the query latent), in an 'attention' "
+        "layer all of them (its query comes from the hidden state)", gt=0)
     index_topk: Optional[int] = Field(
         None, description="lines a query attends over: its index_topk best "
         "by the indexer's scores, chosen exactly; every visible line while "
@@ -569,14 +573,16 @@ class TransformerArchitectureConfig(BaseConfig):
                 "sized from hidden_size; not supported"
             )
         given = [name for name in INDEX_FIELDS if getattr(self, name) is not None]
-        if given and (len(given) < len(INDEX_FIELDS)
-                      or LayerKind.LATENT not in (self.layer_pattern or ())):
+        pattern = self.layer_pattern or ()
+        if given and (len(given) < len(INDEX_FIELDS) or (
+                (LayerKind.LATENT in pattern) == (LayerKind.ATTENTION in pattern))):
             raise ValueError(
                 f"{given} without {[n for n in INDEX_FIELDS if n not in given]}"
-                " or without 'latent' layers: the indexer of a sparse latent "
-                "attention layer is sized by index_n_heads, index_head_dim "
-                "and index_topk together, and only a layer_pattern's "
-                "'latent' layers have one")
+                " or without ONE kind of attention layer to make sparse: the "
+                "indexer of a sparse attention layer is sized by "
+                "index_n_heads, index_head_dim and index_topk together, and "
+                "a layer_pattern's 'latent' layers have one, or, in a pattern "
+                "without them, its 'attention' layers")
         if self.layer_pattern is not None:
             self._validate_pattern()
         elif self.rope_scaling is not None:
@@ -747,6 +753,30 @@ class TransformerArchitectureConfig(BaseConfig):
                 "rope_scaling without 'latent' layers: only the latent "
                 "attention mixer applies YaRN; the other attention mixers' "
                 "rotary tables take the base frequencies")
+        if self.index_topk is not None and LayerKind.ATTENTION in self.layer_pattern:
+            # a sparse grouped-query layer (nn/sparse_attention.py): what it
+            # does not build, each by name
+            if self.attention_qkv_in_one and self.attention_num_kv_heads is None:
+                raise ValueError(
+                    "index_* with attention_qkv_in_one: a sparse attention "
+                    "layer has a query, a key and a value projection of its "
+                    "own; set attention_qkv_in_one false or "
+                    "attention_num_kv_heads")
+            if (self.relative_position_embedding_type
+                    != RelativePositionEmbeddingType.ROTARY):
+                raise ValueError(
+                    "index_* with relative_position_embedding_type "
+                    f"{self.relative_position_embedding_type.value!r}: the "
+                    "indexer's whole head is rotary at the block's base; use "
+                    "'rotary'")
+            if self.index_head_dim % 2:
+                raise ValueError(
+                    f"index_head_dim {self.index_head_dim} is odd: rotary "
+                    "turns pairs of lanes")
+            if self.num_local_attention_heads:
+                raise ValueError(
+                    "index_* with num_local_attention_heads: a window over a "
+                    "learned choice of lines is not built")
 
     @property
     def latent_layers(self) -> int:
@@ -755,8 +785,12 @@ class TransformerArchitectureConfig(BaseConfig):
 
     @property
     def sparse_layers(self) -> int:
-        """Latent layers that attend over an indexer's choice of lines."""
-        return self.latent_layers if self.index_topk is not None else 0
+        """Layers that attend over an indexer's choice of lines: the latent
+        layers or, in a pattern without them, the attention layers."""
+        if self.index_topk is None:
+            return 0
+        return self.latent_layers or self.layer_pattern.count(
+            LayerKind.ATTENTION)
 
     @property
     def moe_held(self) -> int:

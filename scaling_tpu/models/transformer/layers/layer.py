@@ -13,6 +13,7 @@ construction (reference: rng_tracker.py:59-96).
 
 from __future__ import annotations
 
+import contextlib
 from typing import Optional
 
 import jax
@@ -33,6 +34,7 @@ from ....nn.attention import PagedKVCacheView
 from ....nn.base_layer import multiplied
 from ....nn.rotary import RotaryConfig
 from ....nn.latent_attention import LatentSelfAttention
+from ....nn.sparse_attention import SparseSelfAttention
 from ....nn.sparse_latent_attention import SparseLatentSelfAttention
 from ....nn.mamba import Mamba2Mixer
 from ....nn.short_conv import GatedShortConv
@@ -158,6 +160,12 @@ class MixerLayer(BaseLayer):
         self.kind = arch.layer_pattern[layer_index]
         dtype = arch.dtype
         self.norm = get_norm(arch.norm_type, arch.hidden_size, arch.layernorm, dtype)
+        # an indexer, where the configuration has one, makes this stack's
+        # latent or attention layers sparse (config.py: one kind of them)
+        sparse = {} if arch.index_topk is None else dict(
+            index_n_heads=arch.index_n_heads,
+            index_head_dim=arch.index_head_dim,
+            index_topk=arch.index_topk)
         if self.kind == LayerKind.MAMBA:
             self.mixer: BaseLayer = mamba_mixer(arch)
         elif self.kind == LayerKind.MOE:
@@ -167,10 +175,6 @@ class MixerLayer(BaseLayer):
         elif self.kind == LayerKind.MLP:
             self.mixer = dense_mlp(arch)
         elif self.kind == LayerKind.LATENT:
-            sparse = {} if arch.index_topk is None else dict(
-                index_n_heads=arch.index_n_heads,
-                index_head_dim=arch.index_head_dim,
-                index_topk=arch.index_topk)
             self.mixer = (SparseLatentSelfAttention if sparse
                           else LatentSelfAttention)(
                 **sparse,
@@ -201,7 +205,9 @@ class MixerLayer(BaseLayer):
                     base=arch.rotary_embedding_base,
                     max_seq_length=arch.sequence_length,
                 )
-            self.mixer = ParallelSelfAttention(
+            self.mixer = (SparseSelfAttention if sparse
+                          else ParallelSelfAttention)(
+                **sparse,
                 hidden_size=arch.hidden_size,
                 num_attention_heads=arch.num_attention_heads,
                 masked_softmax_config=arch.masked_softmax,
@@ -341,12 +347,16 @@ class MixerLayer(BaseLayer):
             if return_kv or kv_cache is not None:
                 y, state = y
         else:
-            y = self.mixer(
-                params["mixer"], normed, ctx,
-                segment_ids=x["segment_ids"], position_ids=x["position_ids"],
-                kv_cache=kv_cache, cache_offset=cache_offset,
-                return_kv=return_kv,
-            )
+            # a sparse mixer's operations carry the scope its metrics read, as
+            # the latent mixers' do; a plain one's never had it
+            sparse = isinstance(self.mixer, SparseSelfAttention)
+            with jax.named_scope("attn") if sparse else contextlib.nullcontext():
+                y = self.mixer(
+                    params["mixer"], normed, ctx,
+                    segment_ids=x["segment_ids"], position_ids=x["position_ids"],
+                    kv_cache=kv_cache, cache_offset=cache_offset,
+                    return_kv=return_kv,
+                )
             if return_kv or kv_cache is not None:
                 y, state = y
         out["activations"] = h + y.astype(h.dtype)

@@ -117,7 +117,10 @@ class PagedKVCacheView(NamedTuple):
     ``pool_k`` ``(num_blocks, block_size, kv_lora_rank)``, the normed KV
     latent, and ``pool_v`` ``(num_blocks, block_size, rope_line_width)``, the
     one rotary key (nn/latent_attention.py says which leaf holds what); the
-    addressing state below is the same.
+    addressing state below is the same. A SPARSE grouped-query layer's line
+    has a THIRD leaf beside K and V, ``pool_i`` ``(num_blocks, block_size,
+    index_head_dim)``: the indexer's one key a token, no head axis
+    (nn/sparse_attention.py); ``None`` for every other layer.
 
     ``new_len`` (per row, optional) is how many tokens the row REALLY
     brings: a prefill CHUNK shorter than its fixed program shape routes
@@ -142,6 +145,7 @@ class PagedKVCacheView(NamedTuple):
     scale_v: Optional[jax.Array] = None
     new_len: Optional[jax.Array] = None  # (rows,) int32 real new tokens
     token_map: Optional[PagedTokenMap] = None
+    pool_i: Optional[jax.Array] = None   # a sparse layer's index keys
 
     @property
     def quantized(self) -> bool:
@@ -211,12 +215,15 @@ def paged_flat_slots(block_table: jax.Array, positions: jax.Array,
 
 
 def paged_scatter_kv(view: PagedKVCacheView, flat: jax.Array,
-                     k_rows: jax.Array, v_rows: jax.Array) -> PagedKVCacheView:
+                     k_rows: jax.Array, v_rows: jax.Array,
+                     i_rows: Optional[jax.Array] = None) -> PagedKVCacheView:
     """Scatter new K/V rows (``(n, n_kv, h)``) into the pool at flat
     slots ``flat`` (``(n,)``), quantizing when the pool is int8 — the ONE
     pool writer (``_paged_attention`` calls it for chunk rows and decode
     rows alike), so the cache a prompt left behind and the cache decode
-    appends to can never disagree about layout or rounding. Returns the
+    appends to can never disagree about layout or rounding. ``i_rows``
+    (``(n, index_head_dim)``): the third leaf of a sparse layer's line, its
+    index keys, to the same slots of ``pool_i``. Returns the
     view with updated pools (tables/lengths untouched)."""
     num_blocks, block_size = view.pool_k.shape[0], view.pool_k.shape[1]
     flat_len = num_blocks * block_size
@@ -237,11 +244,16 @@ def paged_scatter_kv(view: PagedKVCacheView, flat: jax.Array,
         # (paged_attention.packed_kv_dims): the same values, regrouped
         pk = pk.at[flat].set(k_rows.reshape(-1, *pk.shape[1:]).astype(pk.dtype))
         pv = pv.at[flat].set(v_rows.reshape(-1, *pv.shape[1:]).astype(pv.dtype))
-    return view._replace(
+    view = view._replace(
         pool_k=pk.reshape(view.pool_k.shape),
         pool_v=pv.reshape(view.pool_v.shape),
         scale_k=scale_k, scale_v=scale_v,
     )
+    if i_rows is None:
+        return view
+    pi = view.pool_i.reshape(flat_len, -1)
+    pi = pi.at[flat].set(i_rows.astype(pi.dtype))
+    return view._replace(pool_i=pi.reshape(view.pool_i.shape))
 
 
 def flash_path_active(
@@ -505,19 +517,11 @@ class ParallelSelfAttention(BaseLayer):
                         v = v + delta
         return q, k, v
 
-    def __call__(
-        self,
-        params: dict,
-        x: jax.Array,  # (b, s, hidden)
-        ctx: ForwardContext,
-        segment_ids: Optional[jax.Array] = None,  # (b, s) packed-doc ids
-        position_ids: Optional[jax.Array] = None,  # (b, s)
-        kv_cache: Optional[Tuple[jax.Array, jax.Array]] = None,
-        cache_offset: Optional[jax.Array] = None,
-        attention_scores_manipulation: Optional[jax.Array] = None,
-        attention_scores_manipulation_log_additive: bool = True,
-        return_kv: bool = False,
-    ):
+    def _heads(self, params: dict, x: jax.Array, ctx: ForwardContext,
+               position_ids):
+        """``(q (b, s, n, h), k (b, s, n_kv, h), v (b, s, n_kv, h))`` as the
+        attention meets them: projected, the key's multiplier, the key/query
+        norm and rotary applied."""
         b, s, _ = x.shape
         q, k, v = self._qkv(params, x, ctx)
         k = multiplied(k, self.key_multiplier)
@@ -538,6 +542,23 @@ class ParallelSelfAttention(BaseLayer):
 
         if self.rotary_embedding is not None:
             q, k = self.rotary_embedding(q, k, position_ids, position_ids)
+        return q, k, v
+
+    def __call__(
+        self,
+        params: dict,
+        x: jax.Array,  # (b, s, hidden)
+        ctx: ForwardContext,
+        segment_ids: Optional[jax.Array] = None,  # (b, s) packed-doc ids
+        position_ids: Optional[jax.Array] = None,  # (b, s)
+        kv_cache: Optional[Tuple[jax.Array, jax.Array]] = None,
+        cache_offset: Optional[jax.Array] = None,
+        attention_scores_manipulation: Optional[jax.Array] = None,
+        attention_scores_manipulation_log_additive: bool = True,
+        return_kv: bool = False,
+    ):
+        b, s, _ = x.shape
+        q, k, v = self._heads(params, x, ctx, position_ids)
 
         new_kv = (k, v) if return_kv else None
 

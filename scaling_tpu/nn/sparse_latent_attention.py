@@ -41,7 +41,8 @@ gathers single chosen lines pays one gathered row a line, not two (a row costs
 rotation of query and key, which is orthogonal and leaves every ``q . k`` as
 it is: left out with the quantisation it serves.)
 
-A tick works row by row (``_attend_rows``): the rows that bring ONE token
+A tick works row by row (``_attend_rows``, over ``sparse_rows.walk_rows``, the
+walk this mixer shares with the sparse grouped-query one): the rows that bring ONE token
 (decode rows) are taken ``SINGLE_ROWS`` at a time, the rows that bring a chunk
 are then walked in order, both rolled loops. Either way a row (1) gathers its index keys block by
 block through its table and scores its queries key tile by key tile up to its
@@ -82,11 +83,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs import count_kernel_build
-from .attention import (
-    PagedKVCacheView,
-    paged_flat_slots,
-    paged_scatter_kv,
-)
+from .attention import PagedKVCacheView, paged_scatter_kv
 from .base_layer import ForwardContext
 from .latent_attention import LatentSelfAttention
 from .linear import ColumnParallelLinear
@@ -96,87 +93,13 @@ from .masked_latent_attention import (
 from .norm import NormType, get_norm
 from .paged_attention import paged_kernel_interpret
 from .seq_packing import segment_ids_to_mask
-
-# index keys one step of a row's score loop multiplies
-INDEX_TILE = 2048
-# passes of the bisection that finds a query's threshold: one a bit of a
-# float32's order
-THRESHOLD_PASSES = 33
-# rows of ONE token (decode rows) a pass of the row walk takes together: a
-# pass streams the windows of all its rows up to the longest, so a tick's few
-# decode rows beside a prompt's chunk must not pay for every slot, and a walk
-# row by row would pay the bisection's latency a row
-SINGLE_ROWS = 4
-
-
-def index_tile_tokens(block_size: int, max_blocks: int) -> int:
-    """Index keys one step of a row's score loop holds at these shapes."""
-    return block_size * max(1, min(max_blocks, INDEX_TILE // block_size))
-
-
-def _windows(num_tiles: int, least: int):
-    """The widths, in tiles, a row's scores and masks are computed at: the
-    whole window, and its halves down to an eighth while they still hold
-    ``least`` tiles (the lines a query keeps)."""
-    widths = {num_tiles}
-    for shift in (1, 2, 3):
-        if num_tiles % (1 << shift) == 0 and (num_tiles >> shift) >= max(least, 1):
-            widths.add(num_tiles >> shift)
-    return sorted(widths)
-
-
-def index_scores(q_i: jax.Array, k_i: jax.Array, w: jax.Array) -> jax.Array:
-    """``I[t, s] = sum_j w[t, j] relu(q_i[t, j] . k_i[s])`` in float32:
-    ``q_i`` (..., t, j, d), ``k_i`` (..., s, d), ``w`` (..., t, j) float32."""
-    dots = jnp.einsum("...tjd,...sd->...tjs", q_i, k_i,
-                      preferred_element_type=jnp.float32)
-    return jnp.einsum("...tjs,...tj->...ts", jax.nn.relu(dots), w)
-
-
-def choose_lines(scores: jax.Array, visible: jax.Array, topk: int):
-    """The exact choice: ``(idx (..., k), held (..., k))`` with ``k =
-    min(topk, lines)``, the positions of each query's ``k`` largest visible
-    ``scores`` (..., lines) and which of them hold a line at all (a query
-    that sees fewer than ``k``). Among equal scores the lower position wins
-    (``jax.lax.top_k``'s order)."""
-    k = min(topk, scores.shape[-1])
-    _, idx = jax.lax.top_k(jnp.where(visible, scores, -jnp.inf), k)
-    seen = jnp.sum(visible, axis=-1, keepdims=True)
-    return idx, jnp.arange(k) < seen
-
-
-def ordered_bits(x: jax.Array) -> jax.Array:
-    """float32 -> int32 with the same order (``-inf`` lowest; NaN is no
-    score)."""
-    bits = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
-    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
-
-
-def threshold_choice(scores: jax.Array, visible: jax.Array, topk: int):
-    """The exact choice as a mask ``(..., lines)`` bool: each query's
-    ``min(topk, seen)`` visible lines of largest score, a tie going to the
-    lower position; ``choose_lines``' set without a sort. The ``topk``-th
-    largest visible score is found by bisection on the ordered bits (the
-    largest value that at least ``topk`` scores reach); everything above it is
-    chosen, and of the scores equal to it the first ``topk - (those above)``
-    by position."""
-    bits = ordered_bits(jnp.where(visible, scores, -jnp.inf))
-    low = jnp.full(bits.shape[:-1], jnp.iinfo(jnp.int32).min, jnp.int32)
-    high = jnp.full(bits.shape[:-1], jnp.iinfo(jnp.int32).max, jnp.int32)
-
-    def halve(_, bounds):
-        # at least topk scores reach `low`; fewer reach `high` (or it is the top)
-        low, high = bounds
-        mid = (low >> 1) + (high >> 1) + (low & high & 1)
-        mid = jnp.where(mid == low, high, mid)
-        enough = jnp.sum(bits >= mid[..., None], axis=-1) >= topk
-        return jnp.where(enough, mid, low), jnp.where(enough, high, mid)
-
-    low, _ = jax.lax.fori_loop(0, THRESHOLD_PASSES, halve, (low, high))
-    above = bits > low[..., None]
-    equal = bits == low[..., None]
-    room = topk - jnp.sum(above, axis=-1, keepdims=True)
-    return visible & (above | (equal & (jnp.cumsum(equal, axis=-1) <= room)))
+# the scores, the choice and the walk over a tick's rows are the two sparse
+# mixers' (nn/sparse_attention.py is the other); named here as they were
+from .sparse_rows import (  # noqa: F401
+    INDEX_TILE, SINGLE_ROWS, THRESHOLD_PASSES, _windows, choose_lines,
+    chosen_mask, index_scores, index_tile_tokens, ordered_bits, row_addresses,
+    threshold_choice, tile_of, walk_rows,
+)
 
 
 class SparseLatentSelfAttention(LatentSelfAttention):
@@ -252,11 +175,8 @@ class SparseLatentSelfAttention(LatentSelfAttention):
                                         positions_q=None, positions_k=None)
         with jax.named_scope("indexer"), jax.named_scope("index_select"):
             visible = ~forbidden[:, 0]                          # (b, s, s)
-            idx, held = choose_lines(
+            chosen = chosen_mask(
                 index_scores(q_i, k_i, w), visible, self.index_topk)
-            chosen = jnp.zeros((b, s, s), bool).at[
-                jnp.arange(b)[:, None, None], jnp.arange(s)[None, :, None],
-                idx].max(held)
         y = self._expanded(params, q_nope, q_rope, c_kv, k_r,
                            ~chosen[:, None], ctx)
         if return_kv:
@@ -289,27 +209,12 @@ class SparseLatentSelfAttention(LatentSelfAttention):
         b, s = q_nope.shape[:2]
         n = self.num_heads
         tokens = b * s
-        block_size = view.pool_k.shape[1]
-        rows = view.block_table.shape[0]
-        ctx_len = view.context_len.astype(jnp.int32)
-        if view.new_len is None:
-            new_len = jnp.full((rows,), s, jnp.int32)
-        else:
-            new_len = view.new_len.astype(jnp.int32)
-        row, offset, real = view.token_rows((b, s))
-        flat = paged_flat_slots(
-            view.block_table, ctx_len[row] + offset, block_size, row)
-        flat = jnp.where(real, flat, 0).reshape(-1)
+        ctx_len, new_len, row, offset, real, flat, starts, width = row_addresses(
+            view, (b, s))
         line, key = self._sparse_line(c_kv, k_r, k_i)
         with jax.named_scope("indexer"):   # the scatter writes both leaves
             new_view = paged_scatter_kv(
                 view, flat, line.reshape(tokens, -1), key.reshape(tokens, -1))
-        if view.token_map is None:      # row-major: row r's tokens at r * s
-            starts = jnp.arange(rows, dtype=jnp.int32) * s
-            width = s
-        else:
-            starts = view.token_map.row_tokens[:, 0]
-            width = view.token_map.row_tokens.shape[1]
         w_uk, w_uv = self._up_weights(params, q_nope.dtype)
         q_lat = jnp.einsum("bsnd,cnd->bsnc", q_nope, w_uk)
         # a query against a whole line: [q', q_rope, zeros] . [c_kv, k_r, 0]
@@ -343,65 +248,27 @@ class SparseLatentSelfAttention(LatentSelfAttention):
     def _attend_rows(self, q_i, w, q_line, view, ctx_len, new_len, starts,
                      width: int, interpret: bool):
         """The absorbed attention of every token over the lines it chose:
-        ``(tokens, n, kv_lora_rank)``; what no row owns gives zeros.
-
-        A tick pays for the rows' real shapes, not for ``rows x width`` padded
-        queries against every window: the rows that bring ONE token are
-        taken ``SINGLE_ROWS`` at a time (a walk row by row would pay the
-        bisection's 33 passes a row, one batch of every slot would stream the
-        windows of the slots that decode nothing), the rows that bring more
-        are walked in order (a rolled loop), each at its ``width`` positions. Scores and masks span the smallest of
-        ``_windows`` that holds what is visible, and every loop over a row's
-        tiles ends at its visible length."""
-        pool_l, pool_i = view.pool_k, view.pool_v
+        ``(tokens, n, kv_lora_rank)``; what no row owns gives zeros. The walk
+        over the rows, the scores and the choice are ``sparse_rows.walk_rows``'
+        (shared with the sparse grouped-query mixer); what is this line's: the
+        one-token rows' latent tiles folded into an online softmax in plain
+        XLA, a chunk row's window through ``masked_latent_attention``."""
+        pool_l = view.pool_k
         tokens, n, _ = q_line.shape
-        rows, max_blocks = view.block_table.shape
-        block_size = pool_i.shape[1]
-        k = min(self.index_topk, max_blocks * block_size)
-        tile = index_tile_tokens(block_size, max_blocks)
+        block_size = pool_l.shape[1]
+        tile = index_tile_tokens(block_size, view.block_table.shape[1])
         tile_blocks = tile // block_size
-        num_tiles = -(-max_blocks // tile_blocks)
-        # a table's tail past its last whole tile addresses the trash block
-        table = jnp.pad(view.block_table.astype(jnp.int32),
-                        ((0, 0), (0, num_tiles * tile_blocks - max_blocks)))
-        windows = _windows(num_tiles, -(-k // tile))
-        valid = ctx_len + new_len
 
-        def tile_of(pool, tables, t):
-            """Tile ``t`` of each of ``tables``' rows: ``(r, tile, lanes)``."""
-            blocks = jax.lax.dynamic_slice_in_dim(
-                tables, t * tile_blocks, tile_blocks, 1)
-            return pool[blocks].reshape(tables.shape[0], tile, -1)
-
-        def choose(tables, base, seen, q_i, w, tiles: int):
-            """What ``r`` rows of ``p`` consecutive queries from slot ``base``
-            on attend to, each row over its own ``seen`` slots: q_i (r, p, j,
-            d), w (r, p, j) -> (r, p, tiles * tile) bool."""
-            r, p = q_i.shape[:2]
-            with jax.named_scope("indexer"), jax.named_scope("index_select"):
-                scores = jax.lax.fori_loop(
-                    0, -(-jnp.max(seen) // tile),
-                    lambda t, scores: jax.lax.dynamic_update_slice_in_dim(
-                        scores,
-                        index_scores(q_i, tile_of(pool_i, tables, t), w),
-                        t * tile, 2),
-                    jnp.zeros((r, p, tiles * tile), jnp.float32))
-                slots = jnp.arange(tiles * tile, dtype=jnp.int32)
-                at = base[:, None] + jnp.arange(p, dtype=jnp.int32)
-                visible = ((slots < seen[:, None, None])
-                           & (slots <= at[..., None]))
-                return self._chosen(scores, visible, k)
-
-        def stream(tables, seen, q_line, chosen):
+        def stream(tables, seen, q_line, chosen, tiles: int):
             """The rows' latent tiles folded into an online softmax under
             ``chosen``, in plain XLA (the batch of one-token rows: a tile of
-            scores there is ``heads`` rows a row): q_line (r, p, n, line) ->
-            (r, p, n, kv_lora_rank)."""
+            scores there is ``heads`` rows a row): q_line (r, 1, n, line) ->
+            (r, n, kv_lora_rank)."""
             r, p = q_line.shape[:2]
 
             def fold(t, carry):
                 top, total, acc = carry
-                lines = tile_of(pool_l, tables, t)
+                lines = tile_of(pool_l, tables, t, tile_blocks)
                 s = jnp.einsum("rpnc,rkc->rpnk", q_line, lines,
                                preferred_element_type=jnp.float32)
                 mask = jax.lax.dynamic_slice_in_dim(chosen, t * tile, tile, 2)
@@ -423,87 +290,24 @@ class SparseLatentSelfAttention(LatentSelfAttention):
                     jnp.zeros((r, p, n), jnp.float32),
                     jnp.zeros((r, p, n, self.kv_lora_rank), jnp.float32)))
             return (acc / jnp.where(total == 0.0, 1.0, total)[..., None]
-                    ).astype(q_line.dtype)
+                    ).astype(q_line.dtype)[:, 0]
 
-        def at_window(fn, slots_seen):
-            """``fn(tiles)`` at the first of ``windows`` that holds
-            ``slots_seen``."""
-            return jax.lax.switch(
-                jnp.sum(slots_seen > jnp.asarray(windows) * tile),
-                [lambda tiles=tiles: fn(tiles) for tiles in windows])
+        def whole_chunk(table, seen, q_line, chosen, tiles: int):
+            # the row's window of lines, whole blocks through its table, for
+            # the kernel's plain tiles
+            lines = pool_l[table[:tiles * tile_blocks]]
+            return masked_latent_attention(
+                q_line, lines.reshape(tiles * tile, -1), chosen, seen,
+                lat=self.kv_lora_rank, sm_scale=float(self.scaling_factor),
+                interpret=interpret)
 
-        # ---- the rows of one token, ``group`` of them a pass, so that a tick
-        # with few of them beside a prompt's chunk does not stream every
-        # slot's window
-        single = new_len == 1
-        group = min(SINGLE_ROWS, rows)
-        count = jnp.sum(single)
-        # place g of a pass holds the g-th of them (no sort: a scatter by rank)
-        order = jnp.zeros((rows + -rows % group,), jnp.int32).at[
-            jnp.where(single, jnp.cumsum(single) - 1, rows + group)].set(
-                jnp.arange(rows, dtype=jnp.int32), mode="drop")
-
-        def one_group(g, out):
-            mine = jax.lax.dynamic_slice_in_dim(order, g * group, group)
-            live = g * group + jnp.arange(group) < count
-            seen = jnp.where(live, valid[mine], 0)
-            at = starts[mine]
-
-            def first_tokens(tiles: int):
-                chosen = choose(table[mine], ctx_len[mine], seen,
-                                q_i[at][:, None], w[at][:, None], tiles)
-                with jax.named_scope("sparse_attend"):
-                    return stream(table[mine], seen, q_line[at][:, None],
-                                  chosen)[:, 0]
-
-            first = at_window(first_tokens, jnp.max(seen))
-            # a place past the count writes nothing
-            return out.at[jnp.where(live, at, tokens)].set(first, mode="drop")
-
-        out = jax.lax.fori_loop(
-            0, -(-count // group), one_group,
-            jnp.zeros((tokens, n, self.kv_lora_rank), q_line.dtype))
-        if width == 1:
-            return out
-
-        # ---- the rows that bring a chunk, one by one
-        def one_row(out, r):
-            def chunk(out):
-                # ``width`` places from the row's first token, or the batch's
-                # last ``width`` where that would pass its end: the row's
-                # tokens then lie ``shift`` places in
-                first = jnp.minimum(starts[r], tokens - width)
-                shift = starts[r] - first
-
-                def of(a):
-                    return jax.lax.dynamic_slice_in_dim(a, first, width, 0)[None]
-
-                def whole_chunk(tiles: int):
-                    chosen = choose(table[r][None], (ctx_len[r] - shift)[None],
-                                    valid[r][None], of(q_i), of(w), tiles)
-                    # the row's window of lines, whole blocks through its
-                    # table, for the kernel's plain tiles
-                    with jax.named_scope("sparse_attend"):
-                        lines = pool_l[table[r, :tiles * tile_blocks]]
-                        return masked_latent_attention(
-                            of(q_line)[0], lines.reshape(tiles * tile, -1),
-                            chosen[0], valid[r], lat=self.kv_lora_rank,
-                            sm_scale=float(self.scaling_factor),
-                            interpret=interpret)
-
-                mine = at_window(whole_chunk, valid[r])
-                # the row's own positions only: the places around them are
-                # other rows' tokens
-                old = jax.lax.dynamic_slice_in_dim(out, first, width, 0)
-                place = jnp.arange(width) - shift
-                keep = ((place >= 0) & (place < new_len[r]))[:, None, None]
-                return jax.lax.dynamic_update_slice_in_dim(
-                    out, jnp.where(keep, mine, old), first, 0)
-
-            return jax.lax.cond(new_len[r] > 1, chunk, lambda o: o, out), None
-
-        out, _ = jax.lax.scan(one_row, out, jnp.arange(rows, dtype=jnp.int32))
-        return out
+        return walk_rows(
+            index_pool=view.pool_v, block_table=view.block_table,
+            ctx_len=ctx_len, new_len=new_len, starts=starts, width=width,
+            topk=self.index_topk, q_i=q_i, w=w, queries=q_line,
+            out=jnp.zeros((tokens, n, self.kv_lora_rank), q_line.dtype),
+            choice=self._chosen, attend_single=stream,
+            attend_chunk=whole_chunk)
 
     def _chosen(self, scores, visible, k: int):
         """What each query attends over, as a mask over its row's slots."""

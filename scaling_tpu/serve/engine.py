@@ -377,17 +377,24 @@ class ServeEngine:
                 "spec_k > 0 with latent attention layers: the latent kernel "
                 "folds a row of one token or a prompt chunk, and a decode row "
                 "with drafts has not been held to the reference; set spec_k=0")
-        # of them the SPARSE ones (nn/sparse_latent_attention.py): a query
-        # attends over its index_topk best lines, chosen from index keys that
-        # are the second leaf of a line
+        # the SPARSE layers (latent: nn/sparse_latent_attention.py;
+        # grouped-query: nn/sparse_attention.py): a query attends over its
+        # index_topk best lines, chosen from index keys that are a leaf of a
+        # line (a latent line's second, a K / V line's third)
         self.sparse_layers = inference_module.architecture.sparse_layers
         self.index_topk = inference_module.architecture.index_topk
         if self.sparse_layers and self.config.enable_prefix_cache:
+            kind = "latent " if self.latent_layers else ""
             raise ValueError(
-                "enable_prefix_cache with sparse latent attention layers: a "
+                f"enable_prefix_cache with sparse {kind}attention layers: a "
                 "prefix hit and a copy-on-write fork over a line that holds "
                 "the indexer's keys have not been held to the reference; set "
                 "enable_prefix_cache=False")
+        if self.sparse_layers and self.config.spec_k > 0:
+            raise ValueError(
+                "spec_k > 0 with sparse attention layers: the row walk takes "
+                "a row of one token or a prompt chunk, and a decode row with "
+                "drafts has not been held to the reference; set spec_k=0")
         # KV tokens a tile of the paged kernel holds, at a shard's heads (a
         # sparse latent layer: index keys one step of a row's score loop
         # multiplies)
@@ -947,9 +954,9 @@ class ServeEngine:
     def _apply_cow(self, pairs) -> None:
         """Copy-on-write block forks the scheduler ordered this tick:
         duplicate pool block ``src`` into freshly-allocated ``dst``
-        across every layer (K, V, and int8 scales), in every cache line
-        of the layer's pool (a looped model's steps), BEFORE the tick's
-        programs run. Eager host-dispatched ops — forks never occur in
+        across every layer (K, V, int8 scales, a sparse layer's index
+        keys), in every cache line of the layer's pool (a looped model's
+        steps), BEFORE the tick's programs run. Eager host-dispatched ops — forks never occur in
         the steady state (full-block prefix sharing places writes past
         every shared block), so this path stays off the hot loop."""
         if not pairs:
@@ -957,9 +964,7 @@ class ServeEngine:
         p = self.pools
         for src, dst in pairs:
             src, dst = p.line_blocks(src), p.line_blocks(dst)
-            for arrs in (p.pool_k, p.pool_v, p.scale_k, p.scale_v):
-                if arrs is None:
-                    continue
+            for arrs in p.paged_leaves():
                 for i in range(len(arrs)):
                     arrs[i] = arrs[i].at[dst].set(arrs[i][src])
         self._counter("serve_cow_forks_total").inc(len(pairs))

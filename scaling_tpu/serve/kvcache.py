@@ -9,7 +9,11 @@ charged every row the longest row's memory). What the two leaves hold past
 the block is the layer's mixer's to say: softmax attention's are keys and
 values, ``(n_kv, h)`` each; a latent attention layer's have no head axis and
 differ in width, the normed KV latent ``(kv_lora_rank,)`` and the one rotary
-key ``(rope_line_width,)`` (nn/latent_attention.py).
+key ``(rope_line_width,)`` (nn/latent_attention.py). A SPARSE grouped-query
+layer's line has a THIRD leaf beside K and V, the indexer's one key a token,
+``(index_head_dim,)`` with no head axis (nn/sparse_attention.py): ``pool_i``,
+paged through the same tables, written by the same scatter, counted in
+``line_bytes``. A stack has it in every paged layer or in none.
 
 KV shapes come from an abstract probe of the real layer stack
 (``jax.eval_shape`` over ``prefill_forward``), the same idiom as
@@ -60,7 +64,10 @@ the lines are donated and aliased like the pools: ``(pool_k, pool_v, scale_k,
 scale_v)``, then for every per-slot kind, in the order the stack first meets
 them (``line_layers``), one list a field of its ``LINES``, each over that
 kind's layers in layer order. ``kinds``, the view class of every consuming
-layer in layer order, says which lists a state carries.
+layer in layer order, says which lists a state carries. A stack whose paged
+lines have a third leaf carries ``pool_i`` LAST, after the per-slot lists (one
+entry more than ``kinds`` accounts for); a two-leaf stack's state is what it
+was before there was a third leaf.
 
 **A layer under both rules** (a block whose attention and Mamba-2 mixer run
 side by side: ``parallel_ssm``). Its ``consumes`` is a tuple, the two mixers'
@@ -128,6 +135,11 @@ def build_layer_views(
     the views are then one a consuming layer, of its class (a per-slot kind's
     over the slots' lines of that layer), and lie in that order."""
     pool_k, pool_v, scale_k, scale_v = state[:4]
+    per_slot = line_layers(kinds)
+    # the third leaf of the paged lines, where the stack has one: the entry
+    # after the per-slot kinds' lists
+    line_lists = sum(len(kind.LINES) for kind in per_slot)
+    pool_i = state[4 + line_lists] if len(state) > 4 + line_lists else None
     kv_views = [
         PagedKVCacheView(
             pool_k=pool_k[i], pool_v=pool_v[i],
@@ -135,14 +147,15 @@ def build_layer_views(
             scale_k=None if scale_k is None else scale_k[i],
             scale_v=None if scale_v is None else scale_v[i],
             new_len=new_len, token_map=token_map,
+            pool_i=None if pool_i is None else pool_i[i],
         )
         for i in range(len(pool_k))
     ]
-    if len(state) == 4:
+    if not per_slot:
         return kv_views
     lists = iter(state[4:])
     by_kind = {PagedKVCacheView: iter(kv_views)}
-    for kind in line_layers(kinds):
+    for kind in per_slot:
         fields = [next(lists) for _ in kind.LINES]
         by_kind[kind] = iter([
             kind(**dict(zip(kind.LINES, lines)), context_len=context_len,
@@ -170,7 +183,8 @@ def state_from_views(views: List[PagedKVCacheView]) -> Tuple:
     ``new_len`` and token map are the program's inputs (or derived from
     them) and do not come back. Per-slot views among them put the lists of
     their lines after the four of the pools, grouped by the views' own class
-    (``line_layers``)."""
+    (``line_layers``); the paged lines' third leaf, where they have one, comes
+    last."""
     paged = [v for v in views if isinstance(v, PagedKVCacheView)]
     quantized = paged[0].scale_k is not None
     state = (
@@ -182,6 +196,8 @@ def state_from_views(views: List[PagedKVCacheView]) -> Tuple:
     for kind in line_layers(type(v) for v in views):
         state += tuple([getattr(v, field) for v in views if type(v) is kind]
                        for field in kind.LINES)
+    if paged[0].pool_i is not None:
+        state += ([v.pool_i for v in paged],)
     return state
 
 
@@ -199,7 +215,8 @@ class PagedKVPools:
                  scale_v: Optional[List[jax.Array]],
                  block_size: int, loop_steps: int = 1,
                  kinds: Optional[List[type]] = None,
-                 lines: Tuple[List[jax.Array], ...] = ()):
+                 lines: Tuple[List[jax.Array], ...] = (),
+                 pool_i: Optional[List[jax.Array]] = None):
         self.pool_k = pool_k
         self.pool_v = pool_v
         self.scale_k = scale_k
@@ -212,6 +229,9 @@ class PagedKVPools:
         # state order: what the state carries after the four of the pools
         self.kinds = kinds
         self.lines = tuple(lines)
+        # the third leaf of a sparse grouped-query layer's line, its index
+        # keys (None: lines of two leaves)
+        self.pool_i = pool_i
 
     @property
     def num_layers(self) -> int:
@@ -244,13 +264,23 @@ class PagedKVPools:
 
     def state(self) -> Tuple:
         """What the jitted programs take (donated) and return."""
+        third = () if self.pool_i is None else (self.pool_i,)
         return (self.pool_k, self.pool_v, self.scale_k, self.scale_v,
-                *self.lines)
+                *self.lines, *third)
 
     def absorb_state(self, state: Tuple) -> None:
         """Take back the updated state a jitted program returned."""
         self.pool_k, self.pool_v, self.scale_k, self.scale_v = state[:4]
+        if self.pool_i is not None:
+            *state, self.pool_i = state
         self.lines = tuple(state[4:])
+
+    def paged_leaves(self) -> Tuple:
+        """The lists of the paged lines' leaves that are there: what a
+        copy-on-write fork copies and ``device_bytes`` counts."""
+        return tuple(arrs for arrs in (self.pool_k, self.pool_v, self.scale_k,
+                                       self.scale_v, self.pool_i)
+                     if arrs is not None)
 
     @property
     def line_bytes(self) -> int:
@@ -260,13 +290,8 @@ class PagedKVPools:
             self.pool_k[0].shape[0] // self.loop_steps * self.block_size)
 
     def device_bytes(self) -> int:
-        total = 0
-        for arrs in (self.pool_k, self.pool_v, self.scale_k, self.scale_v):
-            if arrs is None:
-                continue
-            for a in arrs:
-                total += a.size * a.dtype.itemsize
-        return total
+        return sum(a.size * a.dtype.itemsize
+                   for arrs in self.paged_leaves() for a in arrs)
 
     def state_bytes(self) -> int:
         """Bytes of the per-slot lines (beside ``device_bytes``)."""
@@ -366,11 +391,25 @@ def init_pools(inference_module, num_blocks: int, block_size: int,
 
     pool_k: List[jax.Array] = []
     pool_v: List[jax.Array] = []
+    pool_i: List[jax.Array] = []
     scale_k: Optional[List[jax.Array]] = [] if kv_dtype == "int8" else None
     scale_v: Optional[List[jax.Array]] = [] if kv_dtype == "int8" else None
     from ..nn.paged_attention import packed_kv_dims
 
-    for k_aval, v_aval in kv_shapes:
+    for k_aval, v_aval, *third in kv_shapes:
+        if third:
+            # a sparse grouped-query layer: K and V as any other's, and the
+            # indexer's one key a token, no head axis
+            if kv_dtype != "native" or mesh is not None:
+                raise ValueError(
+                    "a sparse attention layer's cache line has an index key "
+                    "beside K and V: its rounding in an int8 pool is not "
+                    "measured and it has no head axis for the model axis to "
+                    "divide; serve it with kv_dtype='native' at "
+                    "model_parallel_size 1")
+            pool_i.append(placed(
+                (pool_blocks, block_size, third[0].shape[2]),
+                third[0].dtype, 2))
         if k_aval.ndim == 3:
             # a line without a head axis (latent attention): its two leaves
             # as the probe gave them, a token's values minor
@@ -398,9 +437,14 @@ def init_pools(inference_module, num_blocks: int, block_size: int,
             scale_v.append(
                 placed((pool_blocks, block_size, n_kv), jnp.float32, 2)
             )
+    if pool_i and len(pool_i) != len(pool_k):
+        raise ValueError(
+            f"{len(pool_i)} of {len(pool_k)} paged layers keep an index key: "
+            "a stack's paged lines have a third leaf in every layer or in "
+            "none")
     if not per_slot:
         return PagedKVPools(pool_k, pool_v, scale_k, scale_v, block_size,
-                            loop_steps)
+                            loop_steps, pool_i=pool_i or None)
     if mesh is not None:
         raise ValueError("per-slot state lines are not sharded: serve a "
                          "layer_pattern stack at model_parallel_size 1")
@@ -414,4 +458,4 @@ def init_pools(inference_module, num_blocks: int, block_size: int,
         lines += [[placed((num_slots, *a.shape[1:]), a.dtype, 1) for a in field]
                   for field in zip(*layers)]
     return PagedKVPools(pool_k, pool_v, scale_k, scale_v, block_size,
-                        loop_steps, kinds, lines)
+                        loop_steps, kinds, lines, pool_i or None)

@@ -314,6 +314,100 @@ def test_masked_latent_kernel_compiles_at_the_cells_size(one_chip, window):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def sparse_gqa_layer(one_chip, tokens):
+    """``serve-keye30b-longctx-burst``'s sparse grouped-query mixer over the
+    paged pool at one of its engine's two token widths, compiled for the
+    described chip: ``(compiled, mixer)``. 8 rows of up to 320 positions, 32
+    query heads over 4 KV heads of 128, lines of THREE leaves (K, V, a 64-lane
+    index key), 4,096 blocks a row."""
+    from scaling_tpu.nn.attention import PagedKVCacheView, packed_token_map
+    from scaling_tpu.nn.base_layer import ForwardContext
+    from scaling_tpu.nn.norm import NormType
+    from scaling_tpu.nn.rotary import RotaryConfig
+    from scaling_tpu.nn.sparse_attention import SparseSelfAttention
+    from scaling_tpu.serve.engine import packed_batch_shape
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    rows, max_blocks, hidden, width = 8, 4096, 2048, 320
+    blocks = rows * max_blocks + 1
+    mixer = SparseSelfAttention(
+        index_n_heads=16, index_head_dim=64, index_topk=2048,
+        hidden_size=hidden, num_attention_heads=32, num_kv_heads=4,
+        head_dim=128, qkv_in_one=False, bias=False, key_query_norm=True,
+        norm_type=NormType.RMS, dtype=jnp.bfloat16,
+        rotary_config=RotaryConfig(dimensions=128, base=10000000,
+                                   max_seq_length=65536))
+    params = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(mixer.init, jax.random.PRNGKey(0)))
+    batch = packed_batch_shape(tokens, width)
+
+    def layer(params, x, pool_k, pool_v, pool_i, table, ctx_len, new_len):
+        token_map = packed_token_map(new_len, batch, width)
+        pos = ctx_len[token_map.row] + token_map.offset
+        view = PagedKVCacheView(
+            pool_k=pool_k, pool_v=pool_v, pool_i=pool_i, block_table=table,
+            context_len=ctx_len, new_len=new_len, token_map=token_map)
+        y, new = mixer(params, x, ForwardContext(serving=True,
+                                                 paged_kernel="pallas"),
+                       position_ids=pos, kv_cache=view)
+        return y, new.pool_k, new.pool_v, new.pool_i
+
+    compiled = jax.jit(layer, donate_argnums=(2, 3, 4)).lower(
+        params, shape((*batch, hidden)),
+        shape((blocks, BLOCK_SIZE, 4, 128)), shape((blocks, BLOCK_SIZE, 4, 128)),
+        shape((blocks, BLOCK_SIZE, 64)),
+        shape((rows, max_blocks), jnp.int32), shape((rows,), jnp.int32),
+        shape((rows,), jnp.int32),
+    ).compile()
+    return compiled, mixer
+
+
+@pytest.mark.parametrize("tokens", [1024, 2560], ids=["small", "full"])
+def test_sparse_gqa_layer_compiles_at_the_cells_size(one_chip, tokens):
+    """The shared row walk over a line of three leaves, at both token widths
+    of the cell's engine: index keys gathered through the table, scores key
+    tile by key tile, each query's EXACT choice of 2,048 of up to 65,536 lines
+    as a threshold found by bisection (no sort, no approximate top-k in the
+    compiled program), K and V streamed under the mask. It compiles for the
+    chip, and a layer's temporaries stay inside what weights (6.25 GB) and
+    pool (4.56 GB) leave of the chip's 16 GB."""
+    compiled, mixer = sparse_gqa_layer(one_chip, tokens)
+    text = compiled.as_text()
+    assert "approx" not in text.lower() and not re.search(r" sort\(|topk", text, re.I)
+    assert " while(" in text and " conditional(" in text
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 2.5e9, memory.temp_size_in_bytes
+    # all three leaves are scattered into in place
+    assert memory.alias_size_in_bytes >= (8 * 4096 + 1) * 16 * (1024 + 64) * 2
+
+
+@pytest.mark.parametrize("window", [8192, 65536], ids=["eighth", "whole"])
+def test_masked_gqa_kernel_compiles_at_the_cells_size(one_chip, window):
+    """``serve-keye30b-longctx-burst``'s chunk rows
+    (nn/masked_gqa_attention.py): 320 positions x 32 heads against a row's
+    window of K and V lines (4 KV heads of 128) under a per-query mask, at the
+    smallest and the largest window the row walk uses. The layer's compile
+    above interprets the kernel (this process's backend is the CPU): Mosaic is
+    asked here."""
+    from scaling_tpu.nn.masked_gqa_attention import masked_gqa_attention
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def attend(q, keys, values, chosen, seen):
+        return masked_gqa_attention(
+            q, keys, values, chosen, seen, sm_scale=128 ** -0.5,
+            interpret=False)
+
+    compiled = jax.jit(attend).lower(
+        shape((320, 32, 128)), shape((window, 4, 128)), shape((window, 4, 128)),
+        shape((320, window), jnp.bool_), shape((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def lowered_mixed_program(one_chip, monkeypatch, bucket, heads, kv_heads,
                           layers=2, kv_layers=None, engine=None,
                           **architecture):

@@ -390,8 +390,12 @@ def test_speculative_rows_and_the_prefix_cache_are_refused_by_name(dsv32):
     ({}, {"index_topk": None}, "\\['index_n_heads', 'index_head_dim'\\] without "
                                "\\['index_topk'\\]"),
     ({}, {"index_head_dim": 8}, "index_head_dim 8 is narrower than qk_rope_head_dim 16"),
-    ({}, {"layer_pattern": ["attention", "mlp"] * 3, "rope_scaling": None},
-     "without 'latent' layers: the indexer"),
+    # (since PR 61 a pattern's 'attention' layers may have the indexer
+    # instead: nn/sparse_attention.py; a pattern with neither, or both, may not)
+    ({}, {"layer_pattern": ["mamba", "mlp"] * 3, "rope_scaling": None},
+     "without ONE kind of attention layer to make sparse: the indexer"),
+    ({}, {"layer_pattern": ["latent", "attention"] * 3},
+     "without ONE kind of attention layer to make sparse: the indexer"),
     ({}, {"moe_n_group": 3}, "moe_n_group 3 / moe_topk_group 2: the group-limited choice"),
     ({}, {"moe_topk_group": 5}, "moe_n_group 4 / moe_topk_group 5"),
     ({}, {"moe_top_k": 9, "moe_topk_group": 2}, "group-limited choice"),
