@@ -2,7 +2,7 @@
 kind, at ``serve-keye30b-longctx-burst``'s shapes (PR 61; chip only, ``--smoke``
 with ``JAX_PLATFORMS=cpu`` rehearses it at a toy size):
 
-    python benchmarks/sparse_gqa_forms.py [--smoke]
+    python benchmarks/sparse_gqa_forms.py [--smoke] [--choice]
 
 For one 320-query CHUNK row and for a pass of four ONE-token rows, at two
 visible lengths: the index scores, the choice as a threshold (bisection) and by
@@ -13,6 +13,15 @@ the one-token rows folded tile by tile in plain XLA), and (b) GATHERED, each
 query's ``index_topk`` single K and V lines fetched through the table and
 attended densely. Milliseconds on the host's clock around ``block_until_ready``
 (best of five after a warm call; the window is the visible length), one JSON line a measurement.
+
+The choice's parts apart (PR 62): the 33 passes of the bisection alone; the
+choice as it serves (random scores: no query has more ties than room, the one
+compare); the same call beside ONE query whose scores all tie (the whole call
+fills ties by position: the prefix sum); and the form before PR 62, which
+filled in every call. Measured only, never wired into the program: the
+threshold found two and three bits of the float's order at a time (3 or 7
+thresholds compared in one read of the bits, 16 or 11 reads for the 33).
+``--choice`` stops after the choice's lines.
 """
 
 import json
@@ -33,12 +42,31 @@ def best_ms(fn, *args, reps=5):
     return 1e3 * best
 
 
+def digits_threshold(bits, k: int, width: int):
+    """``sparse_rows.kth_largest``'s value, found ``width`` bits of the order a
+    read: the ``2 ** width - 1`` thresholds that differ in the next digit are
+    compared in one pass over ``bits`` and the largest that ``k`` scores still
+    reach is kept. 16 reads at 2 bits, 11 at 3."""
+    u = jax.lax.bitcast_convert_type(bits, jnp.uint32) ^ jnp.uint32(1 << 31)
+    found = jnp.zeros(bits.shape[:-1], jnp.uint32)
+    top = 32
+    while top > 0:
+        step = top % width or width
+        top -= step
+        digits = jnp.arange(1, 1 << step, dtype=jnp.uint32) << top
+        tried = found[..., None] | digits
+        enough = jnp.sum(u[..., None, :] >= tried[..., None], axis=-1) >= k
+        found = found | (jnp.sum(enough, axis=-1).astype(jnp.uint32) << top)
+    return jax.lax.bitcast_convert_type(found ^ jnp.uint32(1 << 31), jnp.int32)
+
+
 def main():
-    smoke = "--smoke" in sys.argv
+    smoke, choice_only = "--smoke" in sys.argv, "--choice" in sys.argv
     from scaling_tpu.nn.masked_gqa_attention import masked_gqa_attention
     from scaling_tpu.nn.paged_attention import paged_kernel_interpret
     from scaling_tpu.nn.sparse_rows import (
-        choose_lines, index_scores, threshold_choice, tile_of,
+        choose_lines, index_scores, kth_largest, ordered_bits,
+        threshold_choice, tile_of,
     )
 
     if not smoke and jax.default_backend() != "tpu":
@@ -90,9 +118,44 @@ def main():
             by_threshold = jax.jit(lambda s: threshold_choice(s, visible, topk))
             say(kind=kind, seen=seen, what="choice: threshold by bisection",
                 ms=best_ms(by_threshold, scores))
+            bits_of = lambda s: ordered_bits(jnp.where(visible, s, -jnp.inf))
+            passes = jax.jit(lambda s: kth_largest(bits_of(s), topk))
+            say(kind=kind, seen=seen, what="choice: the 33 passes alone",
+                ms=best_ms(passes, scores))
+            # one query of the call scores every line alike
+            tied = scores.at[0, 0].set(0.0)
+            say(kind=kind, seen=seen,
+                what="choice: a call that fills ties by position",
+                ms=best_ms(by_threshold, tied))
+
+            @jax.jit
+            def always_filling(s):      # the form before PR 62
+                bits = bits_of(s)
+                low = kth_largest(bits, topk)[..., None]
+                above, equal = bits > low, bits == low
+                room = topk - jnp.sum(above, axis=-1, keepdims=True)
+                return visible & (above | (
+                    equal & (jnp.cumsum(equal, axis=-1) <= room)))
+
+            say(kind=kind, seen=seen,
+                what="choice: the fill in every call (before PR 62)",
+                ms=best_ms(always_filling, scores),
+                same_set=bool(jnp.all(always_filling(tied) == by_threshold(tied))
+                              & jnp.all(always_filling(scores) == by_threshold(scores))))
+            for width in (2, 3):
+                by_digits = jax.jit(
+                    lambda s: digits_threshold(bits_of(s), topk, width))
+                say(kind=kind, seen=seen,
+                    what=f"threshold alone, {width} bits a read "
+                         f"({-(-32 // width)} reads; not wired in)",
+                    ms=best_ms(by_digits, scores),
+                    same_threshold=bool(jnp.all(by_digits(tied) == passes(tied))
+                                        & jnp.all(by_digits(scores) == passes(scores))))
             by_top_k = jax.jit(lambda s: choose_lines(s, visible, topk)[0])
             say(kind=kind, seen=seen, what="choice: jax.lax.top_k",
                 ms=best_ms(by_top_k, scores))
+            if choice_only:
+                continue
             chosen, idx = by_threshold(scores), by_top_k(scores)
 
             # (b) GATHERED: each query's chosen single lines through the table

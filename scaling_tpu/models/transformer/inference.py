@@ -421,7 +421,9 @@ class TransformerInferenceModule:
         ``moe_load`` (static; a routed model on block-paged caches) adds a
         third result: the (E,) int32 count of assignments each expert
         received from the rows' REAL positions, summed over the layers
-        (nn/moe.py ``serve``).
+        (nn/moe.py ``serve``); behind it, where the stack's attention is
+        sparse, one more int32: the calls of the layers' row walks that filled
+        ties by position (nn/sparse_rows.py ``threshold_choice``).
 
         ``exit_p`` (static; a looped model with an exit gate) adds a result:
         the float32 exit distribution over the steps, ``(loop_steps, ...)``
@@ -482,6 +484,12 @@ class TransformerInferenceModule:
         for i, layer in enumerate(self.module.layers):
             p = self.module._layer_params(params, i)
             if isinstance(layer, TRUNK_LAYERS):
+                if (paged and self.architecture.sparse_layers
+                        and "sparse_tie_breaks" not in x):
+                    # the sparse layers' count starts at the first trunk
+                    # layer, not at the first of them: one structure for
+                    # every call of a layer's one function
+                    x = dict(x, sparse_tie_breaks=jnp.int32(0))
                 # a layer is handed the state of ITS kind, the view its
                 # mixer declares (a TransformerLayer: attention's; a block of
                 # two mixers: the pair, as a tuple); an MLP (routed or dense)
@@ -541,7 +549,10 @@ class TransformerInferenceModule:
                 "silently wrong decode output"
             )
         if moe_load:
-            return x["activations"], new_caches, x["moe_load"]
+            load = x["moe_load"]
+            if "sparse_tie_breaks" in x:
+                load = jnp.concatenate([load, x["sparse_tie_breaks"][None]])
+            return x["activations"], new_caches, load
         return x["activations"], new_caches
 
     def _loop_plan(self):

@@ -312,6 +312,7 @@ class MixerLayer(BaseLayer):
         normed = self.norm(params["norm"], h, ctx)
         out = dict(x)
         state = None
+        tie_breaks = ()
         view = self.consumes
         if hasattr(view, "LINES"):  # a mixer that keeps lines a slot
             if kv_cache is not None and not isinstance(kv_cache, view):
@@ -345,7 +346,7 @@ class MixerLayer(BaseLayer):
                     kv_cache=kv_cache, return_kv=return_kv,
                 )
             if return_kv or kv_cache is not None:
-                y, state = y
+                y, state, *tie_breaks = y
         else:
             # a sparse mixer's operations carry the scope its metrics read, as
             # the latent mixers' do; a plain one's never had it
@@ -358,7 +359,12 @@ class MixerLayer(BaseLayer):
                     return_kv=return_kv,
                 )
             if return_kv or kv_cache is not None:
-                y, state = y
+                y, state, *tie_breaks = y
+        if tie_breaks:
+            # a sparse mixer's row walk over the engine's view: its calls that
+            # filled ties by position, summed over the layers
+            out["sparse_tie_breaks"] = (
+                x.get("sparse_tie_breaks", 0) + tie_breaks[0])
         out["activations"] = h + y.astype(h.dtype)
         if view is not None and (return_kv or kv_cache is not None):
             return out, state

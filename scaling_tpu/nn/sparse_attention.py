@@ -166,8 +166,9 @@ class SparseSelfAttention(ParallelSelfAttention):
         with jax.named_scope("indexer"):
             q_i, k_i, w = self._indexer(params, x, ctx, position_ids)
         if isinstance(kv_cache, PagedKVCacheView):
-            out, new_view = self._paged_sparse(q, k, v, q_i, k_i, w, kv_cache, ctx)
-            return self._project_out(params, out, ctx, b, s, new_view)
+            out, new_view, tie_breaks = self._paged_sparse(
+                q, k, v, q_i, k_i, w, kv_cache, ctx)
+            return *self._project_out(params, out, ctx, b, s, new_view), tie_breaks
         if kv_cache is not None:
             raise ValueError(
                 "a sparse attention layer takes a PagedKVCacheView (the "
@@ -199,7 +200,7 @@ class SparseSelfAttention(ParallelSelfAttention):
         """Write the batch's lines, three leaves each, to the rows' blocks
         (``paged_scatter_kv``, the ONE pool writer), then attend, row by row,
         over what each query chose. Returns ``((b, s, n, h), the updated
-        view)``.
+        view, the walk's calls that filled ties by position: int32)``.
 
         ``ctx.paged_kernel``: ``'pallas'`` is what serves
         (``sparse_rows.walk_rows``: a chunk row's window through
@@ -229,7 +230,7 @@ class SparseSelfAttention(ParallelSelfAttention):
             # kernel's builds are, so that a run asserts it was built
             count_kernel_build("paged_attention", interpret)
             count_kernel_build(KERNEL_NAME, interpret)
-            out = self._attend_rows(
+            out, tie_breaks = self._attend_rows(
                 q_i, w, q, new_view, ctx_len, new_len, starts, width, interpret)
         else:
             assert ctx.paged_kernel == "xla", (
@@ -240,15 +241,16 @@ class SparseSelfAttention(ParallelSelfAttention):
                 ctx_len, ctx_len + new_len)
             # (the row walk leaves zeros where no row owns a token)
             out = jnp.where(real.reshape(tokens, 1, 1), out, 0)
-        return out.reshape(b, s, n, h), new_view
+            tie_breaks = jnp.int32(0)   # top_k's order breaks them
+        return out.reshape(b, s, n, h), new_view, tie_breaks
 
     def _attend_rows(self, q_i, w, q, view, ctx_len, new_len, starts,
                      width: int, interpret: bool):
         """The attention of every token over the lines it chose: ``(tokens,
-        n, h)``; what no row owns gives zeros. The walk, the scores and the
-        choice are ``sparse_rows.walk_rows``'; what is this line's: K and V
-        in two leaves with a head axis, the GQA group folded beside the
-        positions."""
+        n, h)``; what no row owns gives zeros; and the walk's count of tie
+        breaks. The walk, the scores and the choice are
+        ``sparse_rows.walk_rows``'; what is this line's: K and V in two leaves
+        with a head axis, the GQA group folded beside the positions."""
         tokens, n, h = q.shape
         n_kv, group = self.num_kv_heads, self.num_repeat_kv
         block_size = view.pool_k.shape[1]

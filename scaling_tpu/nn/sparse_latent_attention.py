@@ -51,8 +51,10 @@ visible length; (2) finds each query's choice as a THRESHOLD: the
 passes of compare-and-count, no sort) and, among scores equal to it, the
 lowest positions that fill the count (``threshold_choice``: the same set as
 ``choose_lines``' ``top_k``, which stays the uncached form's and the tests'
-reference of it); (3) STREAMS its latent lines tile by tile, as dense latent
-attention would, and folds each tile into a float32 online softmax under the
+reference of it; the fill runs only in a call where a query whose result the
+walk keeps has more visible ties than room, and the walk counts those calls);
+(3) STREAMS its latent lines tile by tile, as dense latent attention would,
+and folds each tile into a float32 online softmax under the
 mask of what each query chose. This is exact and costs the DENSE attention's
 FLOPs (every visible line is multiplied, most are masked): on a v5e the
 alternative that multiplies only the chosen lines, a gather of ``index_topk``
@@ -164,9 +166,9 @@ class SparseLatentSelfAttention(LatentSelfAttention):
         with jax.named_scope("indexer"):
             q_i, k_i, w = self._indexer(params, x, c_q, ctx, position_ids)
         if isinstance(kv_cache, PagedKVCacheView):
-            out, new_view = self._paged_sparse(
+            out, new_view, tie_breaks = self._paged_sparse(
                 params, q_nope, q_rope, c_kv, k_r, q_i, k_i, w, kv_cache, ctx)
-            return self.dense(params["dense"], out, ctx), new_view
+            return self.dense(params["dense"], out, ctx), new_view, tie_breaks
         self._refuse_dense_cache(kv_cache)
         # --- expanded heads under the mask of the chosen lines
         if segment_ids is None:
@@ -195,7 +197,7 @@ class SparseLatentSelfAttention(LatentSelfAttention):
         """Write the batch's lines to the rows' blocks (``paged_scatter_kv``,
         the ONE pool writer), then attend, row by row, over what each query
         chose, in the absorbed form. Returns ``((b, s, n * v), the updated
-        view)``.
+        view, the walk's calls that filled ties by position: int32)``.
 
         ``ctx.paged_kernel``: ``'pallas'`` is what serves (``_attend_rows``:
         the rows' tiles streamed under each query's threshold, a chunk row's
@@ -230,7 +232,7 @@ class SparseLatentSelfAttention(LatentSelfAttention):
             # kernel's builds are, so that a run asserts it was built
             count_kernel_build("paged_attention", interpret)
             count_kernel_build(KERNEL_NAME, interpret)
-            out = self._attend_rows(
+            out, tie_breaks = self._attend_rows(
                 q_i, w, q_line, new_view, ctx_len, new_len, starts, width,
                 interpret)
         else:
@@ -242,15 +244,17 @@ class SparseLatentSelfAttention(LatentSelfAttention):
                 ctx_len, ctx_len + new_len)
             # (the row walk leaves zeros where no row owns a token)
             out = jnp.where(real.reshape(tokens, 1, 1), out, 0)
+            tie_breaks = jnp.int32(0)   # top_k's order breaks them
         out = jnp.einsum("tnc,cnv->tnv", out, w_uv)
-        return out.reshape(b, s, n * self.v_dim), new_view
+        return out.reshape(b, s, n * self.v_dim), new_view, tie_breaks
 
     def _attend_rows(self, q_i, w, q_line, view, ctx_len, new_len, starts,
                      width: int, interpret: bool):
         """The absorbed attention of every token over the lines it chose:
-        ``(tokens, n, kv_lora_rank)``; what no row owns gives zeros. The walk
-        over the rows, the scores and the choice are ``sparse_rows.walk_rows``'
-        (shared with the sparse grouped-query mixer); what is this line's: the
+        ``(tokens, n, kv_lora_rank)``; what no row owns gives zeros; and the
+        walk's count of tie breaks. The walk over the rows, the scores and the
+        choice are ``sparse_rows.walk_rows``' (shared with the sparse
+        grouped-query mixer); what is this line's: the
         one-token rows' latent tiles folded into an online softmax in plain
         XLA, a chunk row's window through ``masked_latent_attention``."""
         pool_l = view.pool_k

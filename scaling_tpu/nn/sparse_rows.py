@@ -15,7 +15,11 @@ threshold); ``threshold_choice`` the same set as a mask, without a sort: the
 ``index_topk``-th largest visible score by bisection on the float's ordered
 bits (``THRESHOLD_PASSES`` passes of compare-and-count) and, among the scores
 equal to it, the lowest positions that fill the count. EXACT, a tie going to
-the lower position.
+the lower position. That fill (a prefix sum over the whole block of scores)
+runs only in a call where some query has more visible scores at its threshold
+than room for them; every other call's choice is the one compare ``score >=
+threshold``, the same set. ``walk_rows`` counts the calls that filled
+(``tie_breaks_heard``), over the queries whose result it keeps.
 
 ``walk_rows`` is the serving path's walk over a tick's rows, the same for
 either line: the rows that bring ONE token (decode rows) are taken
@@ -31,6 +35,7 @@ is visible, and every loop over a row's tiles ends at its visible length.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Callable
 
 import jax
@@ -103,31 +108,67 @@ def ordered_bits(x: jax.Array) -> jax.Array:
     return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
 
 
-def threshold_choice(scores: jax.Array, visible: jax.Array, topk: int):
-    """The exact choice as a mask ``(..., lines)`` bool: each query's
-    ``min(topk, seen)`` visible lines of largest score, a tie going to the
-    lower position; ``choose_lines``' set without a sort. The ``topk``-th
-    largest visible score is found by bisection on the ordered bits (the
-    largest value that at least ``topk`` scores reach); everything above it is
-    chosen, and of the scores equal to it the first ``topk - (those above)``
-    by position."""
-    bits = ordered_bits(jnp.where(visible, scores, -jnp.inf))
+# who listens for the calls of ``threshold_choice`` that filled ties by
+# position (``tie_breaks_heard``): the function's result stays the mask alone
+_hearing: list = []
+
+
+@contextlib.contextmanager
+def tie_breaks_heard():
+    """The ``over`` (a bool scalar) of every ``threshold_choice`` called
+    inside, in a list: whether that call filled ties by position. Read it at
+    the level the calls were traced at."""
+    heard: list = []
+    _hearing.append(heard)
+    try:
+        yield heard
+    finally:
+        _hearing.remove(heard)
+
+
+def kth_largest(bits: jax.Array, k: int) -> jax.Array:
+    """The largest value that at least ``k`` of ``bits`` (..., lines) int32
+    reach, by bisection: ``THRESHOLD_PASSES`` passes of compare-and-count."""
     low = jnp.full(bits.shape[:-1], jnp.iinfo(jnp.int32).min, jnp.int32)
     high = jnp.full(bits.shape[:-1], jnp.iinfo(jnp.int32).max, jnp.int32)
 
     def halve(_, bounds):
-        # at least topk scores reach `low`; fewer reach `high` (or it is the top)
+        # at least k scores reach `low`; fewer reach `high` (or it is the top)
         low, high = bounds
         mid = (low >> 1) + (high >> 1) + (low & high & 1)
         mid = jnp.where(mid == low, high, mid)
-        enough = jnp.sum(bits >= mid[..., None], axis=-1) >= topk
+        enough = jnp.sum(bits >= mid[..., None], axis=-1) >= k
         return jnp.where(enough, mid, low), jnp.where(enough, high, mid)
 
-    low, _ = jax.lax.fori_loop(0, THRESHOLD_PASSES, halve, (low, high))
-    above = bits > low[..., None]
-    equal = bits == low[..., None]
-    room = topk - jnp.sum(above, axis=-1, keepdims=True)
-    return visible & (above | (equal & (jnp.cumsum(equal, axis=-1) <= room)))
+    return jax.lax.fori_loop(0, THRESHOLD_PASSES, halve, (low, high))[0]
+
+
+def threshold_choice(scores: jax.Array, visible: jax.Array, topk: int):
+    """The exact choice as a mask ``(..., lines)`` bool: each query's
+    ``min(topk, seen)`` visible lines of largest score, a tie going to the
+    lower position; ``choose_lines``' set without a sort. The ``topk``-th
+    largest visible score is found by bisection on the ordered bits
+    (``kth_largest``). Where no query has more visible scores reaching it than
+    ``topk``, those are the choice; in a call where some query has,
+    everything above the threshold is chosen, and of the visible scores equal
+    to it the first ``topk - (those above)`` by position: the same set either
+    way."""
+    bits = ordered_bits(jnp.where(visible, scores, -jnp.inf))
+    low = kth_largest(bits, topk)
+    # (what is invisible, all -inf, ties with itself under a query that sees
+    # fewer than topk: only VISIBLE ties can be more than there is room for)
+    reach = visible & (bits >= low[..., None])
+    over = jnp.any(jnp.sum(reach, axis=-1) > topk)
+    for heard in _hearing:
+        heard.append(over)
+
+    def lower_ties_first():
+        above = reach & (bits > low[..., None])
+        equal = reach & ~above
+        room = topk - jnp.sum(above, axis=-1, keepdims=True)
+        return above | (equal & (jnp.cumsum(equal, axis=-1) <= room))
+
+    return jax.lax.cond(over, lower_ties_first, lambda: reach)
 
 
 def tile_of(pool: jax.Array, tables: jax.Array, t, tile_blocks: int):
@@ -181,9 +222,11 @@ def walk_rows(
     choice: Callable,         # (scores, visible, k) -> mask: threshold_choice
     attend_single: Callable,
     attend_chunk: Callable,
-) -> jax.Array:
+):
     """The attention of every token over the lines it chose, written into
-    ``out``.
+    ``out``, and beside it one int32: the calls of ``choice`` that filled ties
+    by position (``threshold_choice``'s slow branch), which only a query whose
+    result is kept can cause.
 
     A tick pays for the rows' real shapes, not for ``rows x width`` padded
     queries against every window: the rows that bring ONE token are taken
@@ -213,10 +256,13 @@ def walk_rows(
     windows = _windows(num_tiles, -(-k // tile))
     valid = ctx_len + new_len
 
-    def choose(tables, base, seen, q_i, w, tiles: int):
+    def choose(tables, base, seen, owned, q_i, w, tiles: int):
         """What ``r`` rows of ``p`` consecutive queries from slot ``base``
         on attend to, each row over its own ``seen`` slots: q_i (r, p, j,
-        d), w (r, p, j) -> (r, p, tiles * tile) bool."""
+        d), w (r, p, j) -> (r, p, tiles * tile) bool, and whether the choice
+        filled ties by position (int32 0 / 1). A query that is not ``owned``
+        (r, p) sees nothing: its result is thrown away (another row's token,
+        padding), so its ties must not cost the call the fill."""
         r, p = q_i.shape[:2]
         with jax.named_scope("indexer"), jax.named_scope("index_select"):
             scores = jax.lax.fori_loop(
@@ -230,8 +276,11 @@ def walk_rows(
             slots = jnp.arange(tiles * tile, dtype=jnp.int32)
             at = base[:, None] + jnp.arange(p, dtype=jnp.int32)
             visible = ((slots < seen[:, None, None])
-                       & (slots <= at[..., None]))
-            return choice(scores, visible, k)
+                       & (slots <= at[..., None]) & owned[..., None])
+            with tie_breaks_heard() as heard:
+                chosen = choice(scores, visible, k)
+            return chosen, sum((over.astype(jnp.int32) for over in heard),
+                               jnp.int32(0))
 
     def at_window(fn, slots_seen):
         """``fn(tiles)`` at the first of ``windows`` that holds
@@ -251,30 +300,35 @@ def walk_rows(
         jnp.where(single, jnp.cumsum(single) - 1, rows + group)].set(
             jnp.arange(rows, dtype=jnp.int32), mode="drop")
 
-    def one_group(g, out):
+    def one_group(g, carry):
+        out, ties = carry
         mine = jax.lax.dynamic_slice_in_dim(order, g * group, group)
         live = g * group + jnp.arange(group) < count
         seen = jnp.where(live, valid[mine], 0)
         at = starts[mine]
 
         def first_tokens(tiles: int):
-            chosen = choose(table[mine], ctx_len[mine], seen,
-                            q_i[at][:, None], w[at][:, None], tiles)
+            chosen, filled = choose(table[mine], ctx_len[mine], seen,
+                                    live[:, None], q_i[at][:, None],
+                                    w[at][:, None], tiles)
             with jax.named_scope("sparse_attend"):
                 return attend_single(table[mine], seen, queries[at][:, None],
-                                     chosen, tiles)
+                                     chosen, tiles), filled
 
-        first = at_window(first_tokens, jnp.max(seen))
+        first, filled = at_window(first_tokens, jnp.max(seen))
         # a place past the count writes nothing
-        return out.at[jnp.where(live, at, tokens)].set(first, mode="drop")
+        return (out.at[jnp.where(live, at, tokens)].set(first, mode="drop"),
+                ties + filled)
 
-    out = jax.lax.fori_loop(0, -(-count // group), one_group, out)
+    carry = jax.lax.fori_loop(
+        0, -(-count // group), one_group, (out, jnp.int32(0)))
     if width == 1:
-        return out
+        return carry
 
     # ---- the rows that bring a chunk, one by one
-    def one_row(out, r):
-        def chunk(out):
+    def one_row(carry, r):
+        def chunk(carry):
+            out, ties = carry
             # ``width`` places from the row's first token, or the batch's
             # last ``width`` where that would pass its end: the row's
             # tokens then lie ``shift`` places in
@@ -284,24 +338,26 @@ def walk_rows(
             def of(a):
                 return jax.lax.dynamic_slice_in_dim(a, first, width, 0)[None]
 
-            def whole_chunk(tiles: int):
-                chosen = choose(table[r][None], (ctx_len[r] - shift)[None],
-                                valid[r][None], of(q_i), of(w), tiles)
-                with jax.named_scope("sparse_attend"):
-                    return attend_chunk(table[r], valid[r], of(queries)[0],
-                                        chosen[0], tiles)
-
-            mine = at_window(whole_chunk, valid[r])
             # the row's own positions only: the places around them are
             # other rows' tokens
-            old = jax.lax.dynamic_slice_in_dim(out, first, width, 0)
             place = jnp.arange(width) - shift
-            keep = ((place >= 0) & (place < new_len[r])).reshape(
-                (width,) + (1,) * (out.ndim - 1))
+            keep = (place >= 0) & (place < new_len[r])
+
+            def whole_chunk(tiles: int):
+                chosen, filled = choose(
+                    table[r][None], (ctx_len[r] - shift)[None],
+                    valid[r][None], keep[None], of(q_i), of(w), tiles)
+                with jax.named_scope("sparse_attend"):
+                    return attend_chunk(table[r], valid[r], of(queries)[0],
+                                        chosen[0], tiles), filled
+
+            mine, filled = at_window(whole_chunk, valid[r])
+            old = jax.lax.dynamic_slice_in_dim(out, first, width, 0)
+            keep = keep.reshape((width,) + (1,) * (out.ndim - 1))
             return jax.lax.dynamic_update_slice_in_dim(
-                out, jnp.where(keep, mine, old), first, 0)
+                out, jnp.where(keep, mine, old), first, 0), ties + filled
 
-        return jax.lax.cond(new_len[r] > 1, chunk, lambda o: o, out), None
+        return jax.lax.cond(new_len[r] > 1, chunk, lambda c: c, carry), None
 
-    out, _ = jax.lax.scan(one_row, out, jnp.arange(rows, dtype=jnp.int32))
-    return out
+    carry, _ = jax.lax.scan(one_row, carry, jnp.arange(rows, dtype=jnp.int32))
+    return carry

@@ -840,7 +840,8 @@ class ServeEngine:
         whatever follows the grid in the host's read, and at either width).
         A routed model's program returns ONE int32 vector instead of the
         grid for the host: the sampled grid flattened, then the (E,) load of the
-        tick's real positions (``_run_layers(moe_load=True)``), so that
+        tick's real positions (``_run_layers(moe_load=True)``; behind it a
+        sparse stack's count of tie breaks, ``_record_tie_breaks``), so that
         the load costs the tick no second host read. A looped model with
         an exit gate appends likewise the (loop_steps,) float32 exit
         distribution, summed over the sampled positions that hold a token,
@@ -1103,6 +1104,8 @@ class ServeEngine:
             if self.num_experts:
                 load = host_samples[n * sw:]
                 host_samples = host_samples[:n * sw].reshape(n, sw)
+                if self.sparse_layers:
+                    load = self._record_tie_breaks(load, emit)
                 *_, bounded = self._moe_rows[issued.width]
                 self._record_moe_load(load, emit, bounded)
             if self.loop_exit_gate:
@@ -1265,6 +1268,20 @@ class ServeEngine:
                 self.loop_steps * self.pools.num_layers)
         self.mixed_ticks[width] = self.mixed_ticks.get(width, 0) + 1
         self._counter("serve_mixed_ticks_total", width=width).inc()
+
+    def _record_tie_breaks(self, load, emit_span):
+        """A sparse stack's load ends in one more entry: the calls of the
+        tick's row walks (a layer, a chunk row or a pass of one-token rows)
+        whose choice filled ties by position, which only a query with more
+        visible scores at its threshold than room causes
+        (nn/sparse_rows.py). Counts it; returns the load without it. (The
+        count rides the experts' load: a sparse stack that routes nothing has
+        no such vector and is not counted.)"""
+        if not self.warmup_mode:
+            tie_breaks = int(load[-1])
+            self._counter("serve_sparse_tie_breaks_total").inc(tie_breaks)
+            emit_span.annotate(tie_breaks=tie_breaks)
+        return load[:-1]
 
     def _record_moe_load(self, load, emit_span, bounded: bool) -> None:
         """One tick's (E,) assignments of real positions, summed over the

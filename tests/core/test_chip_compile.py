@@ -223,6 +223,60 @@ def test_latent_kernel_compiles_at_the_cells_size(one_chip, tokens):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def conditionals_around(text):
+    """``{computation: how many conditionals enclose it}`` of a compiled
+    program's text, and ``{computation: its instructions}`` (a conditional's
+    branch counts one more than the computation that holds the conditional;
+    fusions, reducers and loop bodies count what holds them)."""
+    held, name = {}, None
+    for line in text.splitlines():
+        opened = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if opened:
+            name = opened.group(1)
+            held[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            held[name].append(line)
+    above = {}
+    for holder, lines in held.items():
+        for line in lines:
+            for callee in re.findall(
+                    r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", line):
+                above[callee] = (holder, 0)
+            for branches in re.findall(r"branch_computations=\{([^}]*)\}", line):
+                for callee in branches.split(","):
+                    above[callee.strip().lstrip("%")] = (holder, 1)
+            for callee in re.findall(
+                    r"(?:true|false)_computation=%?([\w.\-]+)", line):
+                above[callee] = (holder, 1)
+
+    def depth(name):
+        total = 0
+        while name in above:
+            name, step = above[name]
+            total += step
+        return total
+
+    return {name: depth(name) for name in held}, held
+
+
+def assert_ties_are_filled_under_a_conditional_of_their_own(text):
+    """The fill of ties by position (a prefix sum over a row's whole block of
+    scores: reduce-windows) lies ONE conditional deeper than the bisection's
+    passes over the same block, for a 320-query chunk row and for a pass of
+    four one-token rows: a call pays for it only where a kept query has more
+    ties than room (nn/sparse_rows.py ``threshold_choice``)."""
+    depth, held = conditionals_around(text)
+    for rows in ("1,320", "4,1"):
+        passes = {depth[name] for name, lines in held.items() for line in lines
+                  if re.search(rf" while\(.*s32\[{rows},\d{{4,}}\]", line)
+                  or re.search(rf"s32\[{rows},\d{{4,}}\].* while\(", line)}
+        sums = {depth[name] for name, lines in held.items() for line in lines
+                if re.search(rf"= s32\[{rows},[\d,]+\]\S* reduce-window\(", line)}
+        assert len(passes) == 1 and sums == {passes.pop() + 1}, (rows, passes, sums)
+
+
 def sparse_latent_layer(one_chip, tokens):
     """``serve-dsv32-longdoc-burst``'s sparse latent mixer over the paged
     pool at one of its engine's two token widths, compiled for the described
@@ -258,10 +312,10 @@ def sparse_latent_layer(one_chip, tokens):
         view = PagedKVCacheView(
             pool_k=pool_c, pool_v=pool_i, block_table=table,
             context_len=ctx_len, new_len=new_len, token_map=token_map)
-        y, new = mixer(params, x, ForwardContext(serving=True,
-                                                 paged_kernel="pallas"),
-                       position_ids=pos, kv_cache=view)
-        return y, new.pool_k, new.pool_v
+        y, new, tie_breaks = mixer(
+            params, x, ForwardContext(serving=True, paged_kernel="pallas"),
+            position_ids=pos, kv_cache=view)
+        return y, new.pool_k, new.pool_v, tie_breaks
 
     compiled = jax.jit(layer, donate_argnums=(2, 3)).lower(
         params, shape((*batch, hidden)),
@@ -289,6 +343,7 @@ def test_sparse_latent_layer_compiles_at_the_cells_size(one_chip, tokens):
     assert memory.temp_size_in_bytes < 2.5e9, memory.temp_size_in_bytes
     # both leaves are scattered into in place
     assert memory.alias_size_in_bytes >= (16 * 2048 + 1) * 16 * 768 * 2
+    assert_ties_are_filled_under_a_conditional_of_their_own(text)
 
 
 @pytest.mark.parametrize("window", [4096, 32768], ids=["eighth", "whole"])
@@ -350,10 +405,10 @@ def sparse_gqa_layer(one_chip, tokens):
         view = PagedKVCacheView(
             pool_k=pool_k, pool_v=pool_v, pool_i=pool_i, block_table=table,
             context_len=ctx_len, new_len=new_len, token_map=token_map)
-        y, new = mixer(params, x, ForwardContext(serving=True,
-                                                 paged_kernel="pallas"),
-                       position_ids=pos, kv_cache=view)
-        return y, new.pool_k, new.pool_v, new.pool_i
+        y, new, tie_breaks = mixer(
+            params, x, ForwardContext(serving=True, paged_kernel="pallas"),
+            position_ids=pos, kv_cache=view)
+        return y, new.pool_k, new.pool_v, new.pool_i, tie_breaks
 
     compiled = jax.jit(layer, donate_argnums=(2, 3, 4)).lower(
         params, shape((*batch, hidden)),
@@ -382,6 +437,7 @@ def test_sparse_gqa_layer_compiles_at_the_cells_size(one_chip, tokens):
     assert memory.temp_size_in_bytes < 2.5e9, memory.temp_size_in_bytes
     # all three leaves are scattered into in place
     assert memory.alias_size_in_bytes >= (8 * 4096 + 1) * 16 * (1024 + 64) * 2
+    assert_ties_are_filled_under_a_conditional_of_their_own(text)
 
 
 @pytest.mark.parametrize("window", [8192, 65536], ids=["eighth", "whole"])

@@ -20,7 +20,10 @@ from scaling_tpu.nn.masked_gqa_attention import masked_gqa_attention
 from scaling_tpu.nn.norm import NormType
 from scaling_tpu.nn.rotary import RotaryConfig
 from scaling_tpu.nn.sparse_attention import SparseSelfAttention
-from scaling_tpu.nn.sparse_rows import SINGLE_ROWS, chosen_mask, threshold_choice
+from scaling_tpu.nn.sparse_rows import (
+    SINGLE_ROWS, chosen_mask, index_scores, threshold_choice, tie_breaks_heard,
+    walk_rows,
+)
 
 HIDDEN, HEADS, KV_HEADS, HEAD_DIM, TOPK, BLOCK = 64, 8, 2, 16, 8, 4
 INDEX_HEADS, INDEX_DIM = 3, 12
@@ -91,7 +94,7 @@ def chunked(mixer, params, x, sizes, paged_kernel):
     leaves = pools(1)
     out, done = [], 0
     for n in sizes:
-        y, view = mixer(
+        y, view, _ = mixer(
             params, x[:, done:done + n],
             ForwardContext(serving=True, paged_kernel=paged_kernel),
             position_ids=jnp.arange(done, done + n, dtype=jnp.int32)[None],
@@ -155,6 +158,143 @@ def test_equal_scores_keep_the_lower_positions_in_both_forms(mixer, params, monk
     first = np.asarray(chosen_mask(jnp.zeros((30, 30)), visible, TOPK))
     assert all(np.flatnonzero(first[t]).tolist() == list(range(min(TOPK, t + 1)))
                for t in range(30))
+
+
+# ---- where the choice fills ties by position -----------------------------
+
+LINES = 32
+
+
+def _distinct(seen):
+    """Three queries over 32 lines, no two scores of a query equal; query
+    ``q`` sees its first ``seen[q]`` lines."""
+    rng = np.random.default_rng(11)
+    scores = np.stack([rng.permutation(LINES) for _ in seen]).astype(np.float32)
+    return scores - 7.5, np.arange(LINES)[None] < np.asarray(seen)[:, None]
+
+
+def _tied_at_the_threshold(below):
+    """Query 0's TOPK-th largest score shared by the line ranked above it and
+    by the ``below`` ranked next below it, wherever they lie."""
+    scores, visible = _distinct((LINES, 20, 9))
+    ranked = np.argsort(-scores[0])
+    scores[0, ranked[TOPK - 2:TOPK + below]] = scores[0, ranked[TOPK - 1]]
+    return scores, visible
+
+
+def _rounded():
+    rng = np.random.default_rng(12)
+    return (np.round(rng.normal(size=(3, LINES)) * 2) / 2).astype(np.float32), \
+        np.arange(LINES)[None] < np.asarray([[LINES], [20], [9]])
+
+
+CHOICES = {
+    # name: (scores (3, 32), visible (3, 32)), whether the call fills ties
+    "no ties": (lambda: _distinct((LINES, 20, 9)), False),
+    "ties within room": (lambda: _tied_at_the_threshold(0), False),
+    "ties over room": (lambda: _tied_at_the_threshold(2), True),
+    "a query that sees fewer than topk": (
+        lambda: (np.zeros((3, LINES), np.float32),
+                 np.arange(LINES)[None] < np.asarray([[5], [3], [TOPK]])), False),
+    "a query that sees nothing": (lambda: _distinct((LINES, 0, 9)), False),
+    "rounded scores": (_rounded, True),
+    "all-zero scores": (
+        lambda: (np.zeros((3, LINES), np.float32),
+                 np.arange(LINES)[None] < np.asarray([[LINES], [20], [9]])), True),
+}
+
+
+def _more_at_the_threshold_than_room(scores, visible):
+    """By a sort, in numpy: does some query have more visible scores at or
+    above its TOPK-th largest than TOPK?"""
+    for row, sees in zip(scores, visible):
+        v = row[sees]
+        if len(v) > TOPK and (v >= np.sort(v)[-TOPK]).sum() > TOPK:
+            return True
+    return False
+
+
+def _walked_chunk(new_len):
+    """The walk over ONE chunk row of 6 places at context 20 of which
+    ``new_len`` are its tokens; places 3-5 bring an index query of zeros, so
+    all their scores tie, over 24-26 visible lines. The attention is the mask
+    itself. Returns the masks (6, 64), top_k's masks, the count, what the
+    choice's calls said."""
+    rng = np.random.default_rng(13)
+    width, ctx = 6, 20
+    # (of one sign: no score is the 0.0 of an index query no key agrees with)
+    index_pool = jnp.asarray(np.abs(rng.normal(
+        size=(MAX_BLOCKS + 1, BLOCK, INDEX_DIM))), jnp.float32)
+    q_i = np.abs(rng.normal(size=(width, INDEX_HEADS, INDEX_DIM))).astype(np.float32)
+    q_i[3:] = 0.0
+    w = jnp.asarray(rng.normal(size=(width, INDEX_HEADS)), jnp.float32)
+    window = MAX_BLOCKS * BLOCK
+    said = []
+
+    def choice(scores, visible, k):
+        with tie_breaks_heard() as heard:
+            mask = threshold_choice(scores, visible, k)
+        said.extend(heard)
+        return mask
+
+    out, ties = walk_rows(
+        index_pool=index_pool, block_table=tables(1),
+        ctx_len=jnp.asarray([ctx], jnp.int32), new_len=jnp.asarray([new_len], jnp.int32),
+        starts=jnp.zeros((1,), jnp.int32), width=width, topk=TOPK,
+        q_i=jnp.asarray(q_i), w=w, queries=jnp.zeros((width, window)),
+        out=jnp.zeros((width, window)), choice=choice,
+        attend_single=lambda tables, seen, q, chosen, tiles: chosen[:, 0].astype(
+            jnp.float32),
+        attend_chunk=lambda table, seen, q, chosen, tiles: chosen.astype(jnp.float32))
+    scores = index_scores(jnp.asarray(q_i), index_pool[tables(1)[0]].reshape(
+        window, INDEX_DIM), w)
+    slots = np.arange(window)[None]
+    visible = (slots < ctx + new_len) & (slots <= ctx + np.arange(width)[:, None])
+    want = np.asarray(chosen_mask(scores, jnp.asarray(visible), TOPK))
+    return np.asarray(out) > 0, want, int(ties), [bool(x) for x in said]
+
+
+UNOWNED, OWNED = "an unowned place whose scores all tie", "that place owned"
+
+
+@pytest.mark.parametrize("case, beside_a_query_that_fills", [
+    *((case, beside) for case in CHOICES for beside in (False, True)),
+    (UNOWNED, False), (OWNED, False),
+], ids=lambda v: {False: "alone", True: "beside a query that fills"}.get(v, v))
+def test_the_choice_is_top_ks_set_and_fills_ties_only_over_room(
+        case, beside_a_query_that_fills):
+    """``threshold_choice``'s mask is ``chosen_mask``'s set (``jax.lax.top_k``,
+    a tie to the lower position) in every case, in the branch the case names
+    (the fill by position only where a query has more VISIBLE scores at its
+    threshold than room) and, beside a query that forces the fill on the
+    whole call, in the other branch too. In the walk a place whose result is
+    thrown away sends no call down the fill."""
+    if case in (UNOWNED, OWNED):
+        owned = case == OWNED
+        with jax.disable_jit():
+            got, want, ties, said = _walked_chunk(6 if owned else 3)
+        kept = 6 if owned else 3
+        assert (got[:kept] == want[:kept]).all() and not got[kept:].any()
+        assert want[:kept].sum(axis=-1).tolist() == [TOPK] * kept
+        assert ties == int(owned) and said == [owned]
+        if owned:   # the first lines: a tie goes to the lower position
+            assert np.flatnonzero(got[5]).tolist() == list(range(TOPK))
+        return
+    make, fills = CHOICES[case]
+    scores, visible = make()
+    assert _more_at_the_threshold_than_room(scores, visible) == fills
+    if beside_a_query_that_fills:
+        scores = np.concatenate([scores, np.zeros((1, LINES), np.float32)])
+        visible = np.concatenate([visible, np.ones((1, LINES), bool)])
+        fills = True
+    with tie_breaks_heard() as heard:
+        got = np.asarray(threshold_choice(
+            jnp.asarray(scores), jnp.asarray(visible), TOPK))
+    assert [bool(over) for over in heard] == [fills]
+    want = np.asarray(chosen_mask(jnp.asarray(scores), jnp.asarray(visible), TOPK))
+    assert (got == want).all()
+    assert got.sum(axis=-1).tolist() == np.minimum(visible.sum(axis=-1), TOPK).tolist()
+    assert not (got & ~visible).any()
 
 
 def test_the_kernel_is_the_mask_everything_form():
@@ -243,7 +383,7 @@ def test_a_token_major_tick_of_chunk_rows_and_decode_rows(
         c = int(ctx_len[r])
         if not c:
             continue
-        _, view = mixer(params, seqs[r][:, :c], ForwardContext(serving=True),
+        _, view, _ = mixer(params, seqs[r][:, :c], ForwardContext(serving=True),
                         position_ids=jnp.arange(c, dtype=jnp.int32)[None],
                         kv_cache=view_of(leaves, table[r:r + 1], [0], [c]))
         leaves = leaves_of(view)
@@ -255,7 +395,7 @@ def test_a_token_major_tick_of_chunk_rows_and_decode_rows(
     pos = jnp.asarray(np.where(real, np.asarray(ctx_len)[row] + offset, 0)).reshape(shape)
     outs = {}
     for kernel in ("pallas", "xla"):
-        y, new = mixer(params, x, ForwardContext(serving=True, paged_kernel=kernel),
+        y, new, _ = mixer(params, x, ForwardContext(serving=True, paged_kernel=kernel),
                        position_ids=pos,
                        kv_cache=view_of(leaves, table, ctx_len, new_len, token_map))
         outs[kernel] = np.asarray(y).reshape(-1, HIDDEN)

@@ -131,7 +131,7 @@ def chunked(mixer, params, x, sizes, paged_kernel):
             pool_k=pool_c, pool_v=pool_i, block_table=tables(1),
             context_len=jnp.asarray([done], jnp.int32),
             new_len=jnp.asarray([n], jnp.int32))
-        y, view = mixer(
+        y, view, _ = mixer(
             params, x[:, done:done + n],
             ForwardContext(serving=True, paged_kernel=paged_kernel),
             position_ids=jnp.arange(done, done + n, dtype=jnp.int32)[None],
@@ -202,7 +202,7 @@ def test_a_token_major_tick_of_chunk_rows_and_decode_rows(
         view = PagedKVCacheView(
             pool_k=pool_c, pool_v=pool_i, block_table=table[r:r + 1],
             context_len=jnp.zeros((1,), jnp.int32), new_len=jnp.asarray([c], jnp.int32))
-        _, view = mixer(params, seqs[r][:, :c], ForwardContext(serving=True),
+        _, view, _ = mixer(params, seqs[r][:, :c], ForwardContext(serving=True),
                         position_ids=jnp.arange(c, dtype=jnp.int32)[None], kv_cache=view)
         pool_c, pool_i = view.pool_k, view.pool_v
     token_map = packed_token_map(new_len, shape, width)
@@ -216,7 +216,7 @@ def test_a_token_major_tick_of_chunk_rows_and_decode_rows(
         view = PagedKVCacheView(
             pool_k=pool_c, pool_v=pool_i, block_table=table,
             context_len=ctx_len, new_len=new_len, token_map=token_map)
-        y, new = mixer(params, x, ForwardContext(serving=True, paged_kernel=kernel),
+        y, new, _ = mixer(params, x, ForwardContext(serving=True, paged_kernel=kernel),
                        position_ids=pos, kv_cache=view)
         outs[kernel] = np.asarray(y).reshape(-1, HIDDEN)
     for r in range(rows):

@@ -314,6 +314,45 @@ def test_index_keys_that_are_never_written_serve_other_tokens(dsv32, undisturbed
     assert [got[i] for i in range(len(requests))] != want
 
 
+def test_a_tie_over_room_is_filled_by_position_and_counted(dsv32, reference, tmp_path):
+    """The fill of ties by position runs only in a call whose kept queries
+    have more visible scores at their threshold than room, and the engine
+    counts those calls on the tick's one host read. A first sparse layer whose
+    index weights are zero scores every line 0.0: its rows tie everywhere, and
+    from the 17th line on (index_topk 16) a query's ties are more than its
+    room. The engine serves the reference's tokens (a tie goes to the lower
+    position in both), ``serve.emit`` carries ``tie_breaks`` 1 from the tick
+    whose chunk passes 16 lines on, the counter adds them up; the model as it
+    is counts none."""
+    first = next(name for name in sorted(dsv32.params)
+                 if "index_w_proj" in dsv32.params[name].get("mixer", {}))
+    layer = dsv32.params[first]
+    weight = layer["mixer"]["index_w_proj"]["weight"]
+    tied = TransformerInferenceModule(dsv32.config, dsv32.module, {
+        **dsv32.params, first: {**layer, "mixer": {
+            **layer["mixer"], "index_w_proj": {"weight": jnp.zeros_like(weight)}}}})
+    prompt = prompts((20,), seed=11)[0]
+    want = list(prompt)
+    for _ in range(3):
+        want.append(int(reference_logits(tied, reference, want)[-1].argmax()))
+    for inf, tie_breaks in ((tied, [0, 0, 1, 1, 1]), (dsv32, [0] * 5)):
+        engine = engine_of(inf, num_slots=1)
+        obs.start_capture(str(tmp_path / str(sum(tie_breaks))))
+        try:
+            got = served(engine, [prompt], 3)
+        finally:
+            capture = obs.stop_capture()
+        # chunks of 8, 8 and 4 (the last one's queries see 17-20 lines), then
+        # two decode ticks
+        emits = [f for n, _, _, f in capture.spans if n == "serve.emit"]
+        assert [f["tie_breaks"] for f in emits] == tie_breaks
+        assert capture.counters.get("serve_sparse_tie_breaks_total", 0) == sum(tie_breaks)
+        if inf is tied:
+            assert got[0] == want[len(prompt):]
+        # the load behind it is the experts' alone
+        assert all(f["load_max"] >= f["load_mean"] > 0 for f in emits)
+
+
 def test_the_spans_say_what_was_scored_chosen_and_read(dsv32, tmp_path):
     """``serve.mixed`` of a sparse model: ``sparse_layers``, ``index_lines``,
     ``index_pairs``, ``chosen_pairs``, counted on the host from the tick's
