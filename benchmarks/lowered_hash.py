@@ -6,8 +6,12 @@ inference pass of a serve cell, over abstract weights (nothing runs).
 
 Run from two checkouts, equal lines say a change left those cells' programs
 as they were (PR 58: the sequence-parallel region boundaries are taken only
-on a mesh with a model axis). Lowering only: no TPU, a few GB of host memory
-for the text of the largest configuration.
+on a mesh with a model axis). Lowering only: no TPU, no time measured, a few
+GB of host memory for the text of the largest configuration.
+
+What a serve cell's line does NOT cover: the mixed program its ticks run
+(``ServeEngine``'s, over the paged pool). ROADMAP D22 is that gap, and this
+script goes when the audit's goldens hold those programs.
 """
 
 import hashlib

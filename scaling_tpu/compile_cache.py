@@ -1,8 +1,8 @@
 """The one place that decides where JAX's persistent compile cache lives.
 
-Every entry point (``chip_smoke.py``, ``bench.py``, the trainer example,
-``python -m scaling_tpu.serve bench``, the analysis CLI, the benchmarks
-and ``tests/conftest.py``) calls ``enable_compile_cache()`` once before
+Every entry point (``chip_smoke.py``, ``benchmark/run.py``, the trainer
+example, ``python -m scaling_tpu.serve bench``, the analysis CLI and
+``tests/conftest.py``) calls ``enable_compile_cache()`` once before
 its first compile. Where ``JAX_COMPILATION_CACHE_DIR`` is set the
 directory is the environment's to place and no directory is set in code;
 otherwise it is one fixed directory inside the checkout (the path is

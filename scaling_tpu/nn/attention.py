@@ -270,10 +270,9 @@ def flash_path_active(
 ) -> bool:
     """Single source of truth for the flash-vs-XLA kernel gate.
 
-    ``ParallelSelfAttention.__call__`` decides through this, and bench.py
-    reports through it, so the artifact's ``kernel`` label cannot drift
-    from the path that actually ran (mirrors the reference's kernel switch,
-    masked_softmax_config.py:8-37)."""
+    ``ParallelSelfAttention.__call__`` decides through this alone (mirrors
+    the reference's kernel switch, masked_softmax_config.py:8-37); which
+    path ran is read from ``obs.kernel_build_count``, never from a label."""
     if not kernel_is_flash or has_kv_cache or has_scores_manipulation:
         return False
     if not causal or context_parallel_size > 1:

@@ -94,7 +94,8 @@ def count_kernel_build(kernel: str, interpret: bool) -> None:
 
 def kernel_build_count(kernel: str, interpret: bool) -> int:
     """How often ``kernel`` was built so far, compiled (``interpret=False``)
-    or for the interpreter (chip_smoke.py and bench.py assert on it)."""
+    or for the interpreter (chip_smoke.py and the benchmark's ``correct``
+    assert on it)."""
     return int(_kernel_builds(kernel, interpret).value)
 
 

@@ -18,10 +18,8 @@ driven through this wrapper, which:
 - runs in interpreter mode off-TPU so the flash path stays testable on the
   CPU mesh harness.
 
-Block sizes default to 1024/1024 (fastest fwd+bwd in the v5e micro-sweep;
-2048-wide blocks exceed VMEM), snap down to sequence-length divisors, and
-can be overridden via ``SCALING_TPU_FLASH_BLOCK_Q`` /
-``SCALING_TPU_FLASH_BLOCK_KV``.
+Block sizes are 1024/1024 (fastest fwd+bwd in the v5e micro-sweep;
+2048-wide blocks exceed VMEM) and snap down to sequence-length divisors.
 
 Local-window heads are fused too (per-head LocalMask in the splash mask
 set). Unsupported cases (KV cache decode, attention-score manipulation,
@@ -33,7 +31,6 @@ switch (masked_softmax_config.py:8-37).
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -47,9 +44,7 @@ _MIN_BLOCK = 128
 def _block_sizes():
     # 1024/1024 won the v5e fwd+bwd micro-sweep at seq 2048 (8.68ms vs 8.99
     # for 512/512; 2048-wide blocks exceed VMEM and fail to compile)
-    q = int(os.environ.get("SCALING_TPU_FLASH_BLOCK_Q", "1024"))
-    kv = int(os.environ.get("SCALING_TPU_FLASH_BLOCK_KV", "1024"))
-    return q, kv
+    return 1024, 1024
 
 
 def flash_attention_supported(

@@ -12,7 +12,7 @@ metrics.jsonl``), per-request ``serve-request`` + final ``serve-summary``
 events through ``logger.log_event`` — so ``python -m scaling_tpu.obs
 report <run-dir>`` grows a serving section, and the
 ``--assert-serve-throughput`` / ``--assert-ttft`` gates work both here
-(self-gating, like ``bench.py --assert-mfu``) and on the analyzer over
+(self-gating) and on the analyzer over
 the run dir (CI reads the artifacts, not the console).
 
 The model is a randomly initialised toy transformer by default (the

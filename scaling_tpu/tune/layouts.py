@@ -114,9 +114,10 @@ class ModelSpec:
         )
 
 
-# The bench arms (bench.py ``build``): heads = hidden // 128, kv heads =
-# max(1, hidden // 512), seq 2048, swiglu 2.75 — kept in sync by the
-# ModelSpec-vs-get_tflops pin test.
+# The tuner's named shapes (the 0.5B one is the width ``chip_smoke.py``
+# trains and serves): heads = hidden // 128, kv heads = max(1, hidden //
+# 512), seq 2048, swiglu 2.75 — kept in sync by the ModelSpec-vs-get_tflops
+# pin test.
 BENCH_MODELS = {
     "0.5b": ModelSpec(
         hidden_size=2048, num_layers=8, num_attention_heads=16,

@@ -60,6 +60,15 @@ def test_block_sizes_snap_to_seq_divisors():
     assert _snap_block(512, 128) == 128
 
 
+def test_block_sizes_are_constants_no_environment_reaches(monkeypatch):
+    """1024 / 1024 is the v5e sweep's winner; the knob that overrode it had
+    one writer, a script no record ran, and went with it."""
+    from scaling_tpu.ops.flash_attention import _block_sizes
+
+    monkeypatch.setenv("SCALING_TPU_FLASH_BLOCK_Q", "512")
+    assert _block_sizes() == (1024, 1024)
+
+
 @pytest.fixture()
 def interpret_pallas():
     """Run TPU Pallas kernels interpreted on the CPU harness; the context

@@ -23,7 +23,9 @@ control plane is the only cross-host channel, which is exactly what the
 supervision layer must survive on when collectives are hung).
 
 Payload keys: ``workdir``, ``steps``, ``save_interval``,
-``barrier_timeout`` (seconds).
+``barrier_timeout`` (seconds), and optionally ``step_delay`` (seconds
+slept after each step's loss is on disk: a drill that signals from
+outside needs the run to outlast its own poll; the losses are the same).
 
 Exit codes: 0 clean (finished or coordinated preemption), 75 aborted by
 the supervisor / barrier timeout, 42 NonFiniteLossError. SIGKILL shows
@@ -33,6 +35,7 @@ as -9 to the supervisor.
 import json
 import os
 import sys
+import time
 from pathlib import Path
 
 REPO = Path(__file__).resolve().parents[3]
@@ -153,6 +156,7 @@ def main() -> int:
         )
     trainer.initialize(load_checkpoint=True)
     resumed_from = trainer.context.iterations
+    step_delay = float(spec.get("step_delay", 0.0))
 
     def record_loss(_trainer, output, metrics):
         with open(losses_path, "a") as f:
@@ -161,6 +165,7 @@ def main() -> int:
             }) + "\n")
             f.flush()
             os.fsync(f.fileno())
+        time.sleep(step_delay)
         return metrics
 
     try:

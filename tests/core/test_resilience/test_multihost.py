@@ -289,10 +289,14 @@ def test_sigterm_to_supervisor_drains_all_hosts_same_boundary(baseline):
     as SIGTERM to every worker (never a raw flag write, which two
     lockstep hosts could observe on opposite sides of a barrier
     release and split their exit boundaries). Both hosts must save at
-    the same step and exit 0; the epoch is clean, no relaunch."""
-    import signal
-    import time
+    the same step and exit 0; the epoch is clean, no relaunch.
 
+    The 8 steps take ~4 ms each, less than any poll from outside: each
+    host sleeps a second after every step (``step_delay``), so the
+    signal sent at the first sight of both loss files lands with seven
+    seconds of training ahead. A run that finished by itself fails as
+    that, by ``stop < steps``, before anything is said of the drain."""
+    steps = 8
     tmp, gold = baseline
     workdir = tmp / "supterm"
     spec = {
@@ -300,8 +304,8 @@ def test_sigterm_to_supervisor_drains_all_hosts_same_boundary(baseline):
         "num_hosts": 2,
         "control_dir": str(workdir / "control"),
         "payload": {
-            "workdir": str(workdir), "steps": 8, "save_interval": 3,
-            "barrier_timeout": 30.0,
+            "workdir": str(workdir), "steps": steps, "save_interval": 3,
+            "barrier_timeout": 30.0, "step_delay": 1.0,
         },
         "restart_budget": 1,
     }
@@ -332,7 +336,7 @@ def test_sigterm_to_supervisor_drains_all_hosts_same_boundary(baseline):
             if ((workdir / "host0_losses.jsonl").is_file()
                     and (workdir / "host1_losses.jsonl").is_file()):
                 break
-            time.sleep(0.3)
+            time.sleep(0.05)
         else:
             pytest.fail("workers never started training")
         p.send_signal(signal.SIGTERM)
@@ -342,9 +346,10 @@ def test_sigterm_to_supervisor_drains_all_hosts_same_boundary(baseline):
             os.killpg(p.pid, signal.SIGKILL)
             p.wait(timeout=30)
     r0, r1 = read_result(workdir, 0), read_result(workdir, 1)
+    stop = r0["iterations"]
+    assert stop < steps, "the run was over before the signal: no drill"
     assert r0["preempted"] is True and r1["preempted"] is True
     assert r0["iterations"] == r1["iterations"]  # SAME boundary
-    stop = r0["iterations"]
     for host in (0, 1):
         losses = read_losses(workdir, host)
         assert sorted(losses) == list(range(1, stop + 1))

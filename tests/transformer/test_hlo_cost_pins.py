@@ -158,12 +158,12 @@ def test_collective_bytes_flat_in_gradient_accumulation(devices):
 
 @pytest.mark.slow
 def test_bench_half_b_shape_flops_and_memory_drift():
-    """The exact 0.5B shape bench.py measures on the chip: FLOPs within
-    the analytic band, plus a memory DRIFT pin. The absolute bytes here
-    are not the chip's (this CPU compile takes the `torch` attention path,
-    which saves per-layer s² score tensors the splash kernel never
-    materializes — measured 58.8 GB vs the ~9 GB the chip needs), but a
-    jump past the band still means someone made the step hold more live
+    """A 0.5B dense shape (hidden 2048, 8 layers, seq 2048, micro-batch 4):
+    FLOPs within the analytic band, plus a memory DRIFT pin. The absolute
+    bytes here are not the chip's (this CPU compile takes the `torch`
+    attention path, which saves per-layer s² score tensors the splash kernel
+    never materializes — measured 58.8 GB vs the ~9 GB the chip needs), but
+    a jump past the band still means someone made the step hold more live
     state."""
     config = make_config(seq=2048, mbs=4, hidden=2048, layers=8, vocab=32768)
     compiled = compile_step(config)
@@ -243,48 +243,12 @@ def test_peft_optimizer_state_holds_adapters_only(devices):
     assert lora < 0.02 * full, (lora, full)
 
 
-@pytest.mark.slow
-def test_baseline4_layout_compile_pin_small_proxy():
-    """benchmarks/compile_pin_7b.py is the chip-free evidence for the
-    BASELINE #4 layout (TP=4 × PP=2 × DP=8 + ZeRO-1 + remat on 64 virtual
-    devices); this runs its CI-sized proxy in a subprocess (own process:
-    the 64-device count can't coexist with the suite's 8) and checks the
-    JSON contract the artifact relies on."""
-    import json as _json
-    import os as _os
-    import subprocess as _sp
-    import sys as _sys
-
-    repo = _os.path.dirname(_os.path.dirname(_os.path.dirname(
-        _os.path.abspath(__file__))))
-    p = _sp.run(
-        [_sys.executable, _os.path.join(repo, "benchmarks", "compile_pin_7b.py"),
-         "--small"],
-        capture_output=True, text=True, timeout=900,
-    )
-    assert p.returncode == 0, p.stderr[-2000:]
-    rec = _json.loads(p.stdout.strip().splitlines()[-1])
-    assert rec["model"] == "small-proxy"
-    assert rec["devices"] == 64
-    assert rec["fits_v5p_95g"] is True
-    assert rec["per_chip_gb"] < 1.0
-    assert rec["collective_bytes_per_iter"]
-    # the useful-token MFU ceiling n_micro/(n_micro+pp-1) — identical for
-    # the spatial pipeline (fill/drain garbage) and non-interleaved 1F1B
-    # (bubble) — must be reported per layout (VERDICT r4 #7)
-    pl = rec["pipeline"]
-    assert pl["pp"] == 2
-    assert pl["useful_token_mfu_ceiling"] == pytest.approx(
-        pl["n_micro"] / (pl["n_micro"] + pl["pp"] - 1), abs=1e-4
-    )
-    assert pl["scan_carries_mb_per_device"] > 0
-
-
 def test_abstract_state_mirrors_init_state(devices):
-    """benchmarks/compile_pin_7b.py trusts Optimizer.abstract_state to be a
+    """benchmarks/lowered_hash.py trusts Optimizer.abstract_state to be a
     faithful aval mirror of init_state — structure, shapes, dtypes, and
     the ZeRO master shardings eval_shape would drop. A drift (say, a new
-    OptimizerState field) must fail here, not silently skew the 7B pin."""
+    OptimizerState field) must fail here, not silently move a train
+    cell's hash."""
     config = make_config(mp=2, dp=4, zero=True)
     topology = Topology(config.topology)
     module = init_model(config, topology)
