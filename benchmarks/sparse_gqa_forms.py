@@ -6,13 +6,19 @@ with ``JAX_PLATFORMS=cpu`` rehearses it at a toy size):
 
 For one 320-query CHUNK row and for a pass of four ONE-token rows, at two
 visible lengths: the index scores, the choice as a threshold (bisection) and by
-``jax.lax.top_k``, then attention over the choice (a) STREAMED, every visible
-line multiplied under the mask (the chunk row through
-``nn/masked_gqa_attention.py`` with its window gathered through the table,
-the one-token rows folded tile by tile in plain XLA), and (b) GATHERED, each
-query's ``index_topk`` single K and V lines fetched through the table and
-attended densely. Milliseconds on the host's clock around ``block_until_ready``
-(best of five after a warm call; the window is the visible length), one JSON line a measurement.
+``jax.lax.top_k``, then attention over the choice in three forms. (a) STREAMED,
+every visible line multiplied under the mask: the chunk row through
+``nn/masked_gqa_attention.py`` with its window gathered through the table
+(WHAT SERVES a chunk row), the one-token rows folded tile by tile in plain XLA
+with their tiles gathered through the table (what served them until PR 64;
+kept here, and only here, as the yardstick). (b) GATHERED, each query's
+``index_topk`` single K and V lines fetched through the table and attended
+densely (never served: it needs the choice as indices). (c) PAGED, the
+one-token rows only: ``nn/paged_attention.py``'s kernel with the choice as its
+mask operand, each row's own blocks by DMA through its whole table up to its
+own length (WHAT SERVES the one-token rows since PR 64). Milliseconds on the
+host's clock around ``block_until_ready`` (best of five after a warm call; the
+window is the visible length), one JSON line a measurement.
 
 The choice's parts apart (PR 62): the 33 passes of the bisection alone; the
 choice as it serves (random scores: no query has more ties than room, the one
@@ -63,7 +69,9 @@ def digits_threshold(bits, k: int, width: int):
 def main():
     smoke, choice_only = "--smoke" in sys.argv, "--choice" in sys.argv
     from scaling_tpu.nn.masked_gqa_attention import masked_gqa_attention
-    from scaling_tpu.nn.paged_attention import paged_kernel_interpret
+    from scaling_tpu.nn.paged_attention import (
+        paged_decode_attention, paged_kernel_interpret,
+    )
     from scaling_tpu.nn.sparse_rows import (
         choose_lines, index_scores, kth_largest, ordered_bits,
         threshold_choice, tile_of,
@@ -230,6 +238,28 @@ def main():
             say(kind=kind, seen=seen, what="largest difference of the two forms",
                 value=float(jnp.max(jnp.abs(
                     a.astype(jnp.float32) - b.astype(jnp.float32)))))
+            if p > 1:
+                continue
+
+            # (c) PAGED: the rows' own blocks through the paged kernel, the
+            # choice its mask operand; the rows' WHOLE tables, as the walk
+            # hands them over (the kernel ends at a row's own length)
+            valid = jnp.full((rows,), seen, jnp.int32)
+
+            @jax.jit
+            def paged(q, chosen, pool_k, pool_v):
+                return paged_decode_attention(
+                    q, pool_k, pool_v, tables[:rows], valid, valid - 1,
+                    sm_scale=scale, num_repeat_kv=group, chosen=chosen[:, 0],
+                    interpret=interpret)
+
+            say(kind=kind, seen=seen, what="attend: PAGED kernel under the mask",
+                ms=best_ms(paged, q, chosen, pool_k, pool_v))
+            say(kind=kind, seen=seen,
+                what="largest difference of PAGED and STREAMED",
+                value=float(jnp.max(jnp.abs(
+                    paged(q, chosen, pool_k, pool_v).astype(jnp.float32)
+                    - a.astype(jnp.float32)))))
 
 
 if __name__ == "__main__":

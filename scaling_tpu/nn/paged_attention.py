@@ -65,7 +65,19 @@ Variants share one kernel body:
   the scales go onto the products (``q . (k * scale) = (q . k) * scale``,
   ``p @ (v * scale) = (p * scale) @ v``); the wrapper gathers the rows'
   scales (1/h of the window's bytes) so a tile's lie along the lanes. The
-  f32 window the XLA path materialized in HBM never exists here either.
+  f32 window the XLA path materialized in HBM never exists here either;
+- masked (``chosen``: the sparse grouped-query mixer's rows of ONE token,
+  ``nn/sparse_attention.py``): each row brings a mask over its slots, handed
+  over as the scales are, one lane-dense int32 strip a row, and ANDed into a
+  tile's ``allowed``: a slot is visible iff the contract below admits it AND
+  the row chose it. Every visible line is still fetched and multiplied (the
+  choice is a mask, not a gather); what the kernel adds to the XLA fold it
+  replaced is each row's OWN blocks by DMA up to its OWN length, and no tile
+  for a row that sees nothing. A masked call of one position a row folds a
+  row's ``group`` query rows alone (padded to 16), and waits for a whole tile
+  at once instead of block by block (at 16 KiB blocks: 7-9% and 10% of a
+  call, PERF.md, PR 64). The mask's absence is a static branch: a maskless
+  call builds the kernel it always did, operand for operand.
 
 Masking follows the paged-decode contract exactly (``nn/attention.py``
 ``_paged_attention``): LOGICAL slot indices are the causal clock; slot
@@ -217,17 +229,22 @@ def _paged_attention_kernel(
     q_ref,        # (1, n_kv, m, h) VMEM: queries folded per KV head
     pool_k_ref,   # (num_blocks, block_size, n_kv, h) left in HBM
     pool_v_ref,
-    *rest,        # [scale_k_ref, scale_v_ref,] o_ref, then the scratch
+    *rest,        # [scale_k_ref, scale_v_ref,] [chosen_ref,] o_ref, the scratch
     block_size: int,
     tile_blocks: int,
     sm_scale: float,
     group: int,
     m_short: int,
     quantized: bool,
+    masked: bool,
+    single: bool,
 ):
     if quantized:
         # (1, n_kv, window) VMEM: the row's scales, one lane a slot
         scale_k_ref, scale_v_ref, *rest = rest
+    if masked:
+        # (1, 1, window) VMEM int32: the row's choice, one lane a slot
+        chosen_ref, *rest = rest
     o_ref, k_buf, v_buf, sems, m_ref, l_ref, acc_ref = rest
     pools = ((pool_k_ref, k_buf), (pool_v_ref, v_buf))
     _, n_kv, m_full, _ = q_ref.shape
@@ -280,7 +297,24 @@ def _paged_attention_kernel(
                 copy.wait()
             return carry
 
-        jax.lax.fori_loop(0, blocks_held(t), one, 0)
+        if not masked:
+            jax.lax.fori_loop(0, blocks_held(t), one, 0)
+            return
+        # a whole tile is waited for at once, by its bytes: a tenth off a
+        # call at 16 KiB blocks (PERF.md, PR 64); the maskless rows' turn
+        # is ROADMAP S11's
+        whole = blocks_held(t) == tile_blocks
+
+        @pl.when(whole)
+        def _at_once():
+            for which, (_, buf) in enumerate(pools):
+                pltpu.make_async_copy(
+                    buf.at[slot], buf.at[slot], sems.at[slot, which]
+                ).wait()
+
+        @pl.when(jnp.logical_not(whole))
+        def _block_by_block():
+            jax.lax.fori_loop(0, blocks_held(t), one, 0)
 
     m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -328,8 +362,10 @@ def _paged_attention_kernel(
                 jnp.int32, (1, tile), 1
             )
             allowed = (kv_slot < valid_len) & (kv_slot <= q_slot)
-            if quantized:
+            if quantized or masked:
                 span = pl.ds(pl.multiple_of(t * tile, tile), tile)
+            if masked:
+                allowed = allowed & (chosen_ref[0, :, span] != 0)
             def fold(g, k, v):
                 q = q_ref[0, g, :m_run, :]
                 k, v = k.astype(q.dtype), v.astype(q.dtype)
@@ -369,7 +405,10 @@ def _paged_attention_kernel(
 
         jax.lax.fori_loop(0, num_tiles, one_tile, 0)
 
-    if m_short < m_full:
+    if single:
+        # every row of the call brings ONE position: its group's rows
+        attend(m_short)
+    elif m_short < m_full:
         few = valid_len - base <= _SHORT_QUERIES
         pl.when(few)(lambda: attend(m_short))
         pl.when(jnp.logical_not(few))(lambda: attend(m_full))
@@ -394,13 +433,20 @@ def paged_decode_attention(
     num_repeat_kv: int = 1,
     scale_k: Optional[jax.Array] = None,  # (num_blocks, block_size, n_kv)
     scale_v: Optional[jax.Array] = None,
+    chosen: Optional[jax.Array] = None,   # (rows, slots) bool
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Flash-style paged attention over a block pool; returns (rows, s, n, h).
 
     The pool must already contain the query tokens' K/V (the caller
     scatters through ``nn.attention.paged_scatter_kv`` first — ONE pool
-    writer, so kernel and XLA fallback read identical bytes)."""
+    writer, so kernel and XLA fallback read identical bytes).
+
+    With ``chosen``, slot ``k`` of a row is visible to a query of the row
+    iff the paged contract admits it AND ``chosen[row, k]`` (a slot past the
+    mask's width is not chosen); a row that chose nothing gives zeros, as a
+    row whose ``valid_len`` is 0 does. Without it the kernel built is the
+    maskless one, operand for operand."""
     _ensure_pallas()
     _, block_size, n_kv, h = pool_k.shape
     if interpret is None:
@@ -414,7 +460,8 @@ def paged_decode_attention(
         out = paged_decode_attention(
             _pack_queries(q, n_kv * pack, pack), pool_k, pool_v, block_table,
             valid_len, q_slot_base, sm_scale=sm_scale,
-            num_repeat_kv=pack * num_repeat_kv, interpret=interpret)
+            num_repeat_kv=pack * num_repeat_kv, chosen=chosen,
+            interpret=interpret)
         # a query head's output lies in the lanes of its own KV head
         out = out.reshape(rows, s, n_kv, pack, num_repeat_kv, pack, h_q)
         own = jnp.arange(pack)
@@ -423,7 +470,8 @@ def paged_decode_attention(
     count_kernel_build("paged_attention", interpret)
     return _paged_call(
         q, pool_k, pool_v, block_table, valid_len, q_slot_base,
-        scale_k, scale_v, sm_scale=float(sm_scale), group=num_repeat_kv,
+        scale_k, scale_v, chosen, sm_scale=float(sm_scale),
+        group=num_repeat_kv,
         tile_blocks=_blocks_per_tile(
             block_size, block_table.shape[1], n_kv, h, pool_k.dtype.itemsize
         ),
@@ -494,13 +542,13 @@ def _pipeline_carry(valid_len: jax.Array, tile: int):
 )
 def _paged_call(
     q, pool_k, pool_v, block_table, valid_len, q_slot_base, scale_k, scale_v,
-    *, sm_scale: float, group: int, tile_blocks: int, interpret: bool,
+    chosen, *, sm_scale: float, group: int, tile_blocks: int, interpret: bool,
 ):
     rows, s, n, h = q.shape
     _, block_size, n_kv, _ = pool_k.shape
     max_blocks = block_table.shape[1]
     assert n == n_kv * group, (n, n_kv, group)
-    quantized = scale_k is not None
+    quantized, masked = scale_k is not None, chosen is not None
     tile = tile_blocks * block_size
     # fold the GQA group into the matmul's rows: per KV head the queries are
     # (s_pad * group, h), position-major, so the first positions of a row
@@ -508,6 +556,11 @@ def _paged_call(
     s_pad = _round_up(s, 8)
     m_full = s_pad * group
     m_short = min(m_full, _round_up(_SHORT_QUERIES * group, 16))
+    # under a mask, a call of ONE position a row folds a row's group alone
+    # (8 x the rows cost Keye's shapes 7-9% of a call: PERF.md, PR 64)
+    single = masked and s == 1
+    if single:
+        m_short = min(m_full, _round_up(group, 16))
     folded = jnp.pad(q, ((0, 0), (0, s_pad - s), (0, 0), (0, 0)))
     folded = folded.reshape(rows, s_pad, n_kv, group, h)
     folded = folded.transpose(0, 2, 1, 3, 4).reshape(rows, n_kv, m_full, h)
@@ -535,6 +588,18 @@ def _paged_call(
             in_specs.append(
                 pl.BlockSpec((1, n_kv, window), lambda bi, *_: (bi, 0, 0))
             )
+    if masked:
+        # the rows' choices as int32, as ``masked_gqa_attention`` takes its
+        # mask (1/512 of a row's K and V bytes at Keye's line; int8 read the
+        # same to 1-2%: PERF.md, PR 64), slot-minor and padded to whole tiles:
+        # a tile's is one lane-dense (1, tile) strip
+        window = pl.cdiv(max_blocks, tile_blocks) * tile
+        strip = chosen[:, :window].astype(jnp.int32)
+        strip = jnp.pad(strip, ((0, 0), (0, window - strip.shape[1])))
+        operands.append(strip[:, None, :])
+        in_specs.append(
+            pl.BlockSpec((1, 1, window), lambda bi, *_: (bi, 0, 0))
+        )
     scratch += [
         pltpu.SemaphoreType.DMA((2, 2)),
         pltpu.VMEM((n_kv, m_full, 1), jnp.float32),   # running max m
@@ -556,7 +621,8 @@ def _paged_call(
     kernel = functools.partial(
         _paged_attention_kernel,
         block_size=block_size, tile_blocks=tile_blocks, sm_scale=sm_scale,
-        group=group, m_short=m_short, quantized=quantized,
+        group=group, m_short=m_short, quantized=quantized, masked=masked,
+        single=single,
     )
     out = pl.pallas_call(
         kernel,

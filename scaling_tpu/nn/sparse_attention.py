@@ -42,15 +42,21 @@ LayerNorm and rotary, ``(index_head_dim,)`` with no head axis: at Keye's sizes
   single lines a query is bound by ~17 ns a gathered row of 1 KB on a v5e,
   22-24 ms a 320-query chunk against 3.0-4.5 ms of stream at 16k-48k visible
   lines: ``benchmarks/sparse_gqa_forms.py``; PERF.md, PR 61);
-- the rows of ONE token fold their K and V tiles into a float32 online softmax
-  in plain XLA, ``SINGLE_ROWS`` rows a pass (gathering their chosen lines is
-  up to 2 x faster but needs the choice as indices, which costs more than it
-  saves today: the same measurement).
+- the rows of ONE token attend through the paged kernel
+  (``nn/paged_attention.py``) with the choice as its mask operand,
+  ``SINGLE_ROWS`` rows a pass: each row's own blocks are fetched by DMA
+  through its table up to its own length, a place of the pass that holds no
+  row runs no tile, and the kernel is built once a layer (the walk pads every
+  window's choice to the whole window). At Keye's 16 KiB blocks the kernel
+  streams ~400 GB/s against ~210 for a gather of tiles through XLA, which it
+  replaced (PERF.md, PR 64; gathering the chosen lines needs the choice as
+  indices, which costs more than it saves: PR 61).
 
 Scopes (inside the layer's ``attn``): ``indexer`` holds everything the indexer
 adds (its three projections, LayerNorm, rotary, scores and choice),
 ``index_select`` inside it the scores and the choice, ``sparse_attend`` the
-gather of a row's window and the attention under the mask. The scatter of the
+gather of a chunk row's window and the attention under the mask (the
+one-token rows' ``paged_attention`` calls lie here). The scatter of the
 line's three leaves is one call and lies in neither.
 
 Not built, refused by name (here, config validation, ``serve/kvcache.py``,
@@ -78,13 +84,13 @@ from .base_layer import ForwardContext
 from .linear import ColumnParallelLinear
 from .masked_gqa_attention import KERNEL_NAME, masked_gqa_attention
 from .norm import NormType, get_norm
-from .paged_attention import paged_kernel_interpret
+from .paged_attention import paged_decode_attention, paged_kernel_interpret
 from .param import tree_prefix
 from .rotary import RotaryConfig, RotaryEmbedding
 from .seq_packing import segment_ids_to_mask
 from .sparse_rows import (
     chosen_mask, index_scores, index_tile_tokens, row_addresses,
-    threshold_choice, tile_of, walk_rows,
+    threshold_choice, walk_rows,
 )
 
 INDEX_PARTS = ("index_q_proj", "index_k_proj", "index_k_norm", "index_w_proj")
@@ -205,7 +211,8 @@ class SparseSelfAttention(ParallelSelfAttention):
         ``ctx.paged_kernel``: ``'pallas'`` is what serves
         (``sparse_rows.walk_rows``: a chunk row's window through
         ``nn/masked_gqa_attention.py`` under each query's threshold, the
-        one-token rows' tiles folded in plain XLA); ``'xla'`` gathers each
+        one-token rows' own blocks through ``nn/paged_attention.py`` under
+        theirs); ``'xla'`` gathers each
         token's WHOLE window, chooses by ``top_k`` and masks: the tests'
         reference of it."""
         if view.quantized or view.pool_i is None:
@@ -226,9 +233,6 @@ class SparseSelfAttention(ParallelSelfAttention):
         w = w.reshape(tokens, self.index_heads)
         if ctx.paged_kernel == "pallas":
             interpret = paged_kernel_interpret()
-            # the stack's paged attention: counted under the name the paged
-            # kernel's builds are, so that a run asserts it was built
-            count_kernel_build("paged_attention", interpret)
             count_kernel_build(KERNEL_NAME, interpret)
             out, tie_breaks = self._attend_rows(
                 q_i, w, q, new_view, ctx_len, new_len, starts, width, interpret)
@@ -257,43 +261,15 @@ class SparseSelfAttention(ParallelSelfAttention):
         tile = index_tile_tokens(block_size, view.block_table.shape[1])
         tile_blocks = tile // block_size
 
-        def stream(tables, seen, q, chosen, tiles: int):
-            """The rows' K and V tiles folded into an online softmax under
-            ``chosen``, in plain XLA (the batch of one-token rows): q (r, 1,
-            n, h) -> (r, n, h)."""
-            r = q.shape[0]
-            q = q.reshape(r, n_kv, group, h)
-
-            def fold(t, carry):
-                top, total, acc = carry
-                # (a pool of narrow heads keeps several a lane row: back to
-                # heads)
-                keys = tile_of(view.pool_k, tables, t, tile_blocks).reshape(
-                    r, tile, n_kv, h)
-                values = tile_of(view.pool_v, tables, t, tile_blocks).reshape(
-                    r, tile, n_kv, h)
-                s = jnp.einsum("rgjh,rkgh->rgjk", q, keys,
-                               preferred_element_type=jnp.float32)
-                mask = jax.lax.dynamic_slice_in_dim(
-                    chosen[:, 0], t * tile, tile, 1)
-                s = jnp.where(mask[:, None, None, :],
-                              s * self.scaling_factor, -jnp.inf)
-                new_top = jnp.maximum(top, s.max(axis=-1))
-                safe = jnp.where(new_top == -jnp.inf, 0.0, new_top)
-                e = jnp.exp(s - safe[..., None])
-                alpha = jnp.exp(top - safe)
-                acc = alpha[..., None] * acc + jnp.einsum(
-                    "rgjk,rkgh->rgjh", e.astype(values.dtype), values,
-                    preferred_element_type=jnp.float32)
-                return new_top, alpha * total + e.sum(axis=-1), acc
-
-            _, total, acc = jax.lax.fori_loop(
-                0, -(-jnp.max(seen) // tile), fold, (
-                    jnp.full((r, n_kv, group), -jnp.inf, jnp.float32),
-                    jnp.zeros((r, n_kv, group), jnp.float32),
-                    jnp.zeros((r, n_kv, group, h), jnp.float32)))
-            return (acc / jnp.where(total == 0.0, 1.0, total)[..., None]
-                    ).astype(q.dtype).reshape(r, n, h)
+        def own_blocks(tables, seen, q, chosen):
+            """The rows of ONE token through the paged kernel under their
+            masks: each row's own blocks by DMA through its table, up to its
+            own length, a place that sees nothing at no cost: q (r, 1, n, h)
+            -> (r, n, h)."""
+            return paged_decode_attention(
+                q, view.pool_k, view.pool_v, tables, seen, seen - 1,
+                sm_scale=float(self.scaling_factor), num_repeat_kv=group,
+                chosen=chosen[:, 0], interpret=interpret)[:, 0]
 
         def whole_chunk(table, seen, q, chosen, tiles: int):
             # the row's window of K and of V, whole blocks through its table,
@@ -309,7 +285,7 @@ class SparseSelfAttention(ParallelSelfAttention):
             ctx_len=ctx_len, new_len=new_len, starts=starts, width=width,
             topk=self.index_topk, q_i=q_i, w=w, queries=q,
             out=jnp.zeros((tokens, n, h), q.dtype), choice=self._chosen,
-            attend_single=stream, attend_chunk=whole_chunk)
+            attend_single=own_blocks, attend_chunk=whole_chunk)
 
     def _attend_gathered_windows(self, q_i, w, q, view, row, offset,
                                  ctx_len, valid_len):

@@ -263,7 +263,7 @@ class SparseLatentSelfAttention(LatentSelfAttention):
         tile = index_tile_tokens(block_size, view.block_table.shape[1])
         tile_blocks = tile // block_size
 
-        def stream(tables, seen, q_line, chosen, tiles: int):
+        def stream(tables, seen, q_line, chosen):
             """The rows' latent tiles folded into an online softmax under
             ``chosen``, in plain XLA (the batch of one-token rows: a tile of
             scores there is ``heads`` rows a row): q_line (r, 1, n, line) ->

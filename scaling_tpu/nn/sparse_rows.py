@@ -30,7 +30,11 @@ visible length, (2) finds each query's choice as a threshold, and (3) hands
 its queries and the mask of what each chose to the mixer's own attention over
 its own lines (``attend_single`` / ``attend_chunk``: what the line's layout
 decides). Scores and masks span the smallest of ``_windows`` that holds what
-is visible, and every loop over a row's tiles ends at its visible length.
+is visible, and every loop over a row's tiles ends at its visible length. A
+chunk row attends inside the branch of its window; a pass of one-token rows
+AFTER it, their choice padded to the whole window, so that a mixer whose
+``attend_single`` is a kernel (the grouped-query one's: the paged kernel under
+a mask) builds it once a layer and not once a window.
 """
 
 from __future__ import annotations
@@ -49,9 +53,10 @@ INDEX_TILE = 2048
 # float32's order
 THRESHOLD_PASSES = 33
 # rows of ONE token (decode rows) a pass of the row walk takes together: a
-# pass streams the windows of all its rows up to the longest, so a tick's few
-# decode rows beside a prompt's chunk must not pay for every slot, and a walk
-# row by row would pay the bisection's latency a row
+# pass scores the windows of all its rows up to the longest (and a mixer that
+# folds in plain XLA streams them so), so a tick's few decode rows beside a
+# prompt's chunk must not pay for every slot, and a walk row by row would pay
+# the bisection's latency a row
 SINGLE_ROWS = 4
 
 
@@ -236,11 +241,14 @@ def walk_rows(
     (a rolled loop), each at its ``width`` positions.
 
     ``attend_single(tables (g, blocks), seen (g,), queries (g, 1, ...),
-    chosen (g, 1, tiles x tile) bool, tiles)`` -> ``(g, ...)`` and
-    ``attend_chunk(table (blocks,), seen (), queries (width, ...), chosen
-    (width, tiles x tile) bool, tiles)`` -> ``(width, ...)`` are the mixer's
-    attention over its own lines under the mask (``tables`` are padded to
-    whole tiles with the trash block; ``tiles`` is static). Both run under the
+    chosen (g, 1, window) bool)`` -> ``(g, ...)`` and ``attend_chunk(table
+    (blocks,), seen (), queries (width, ...), chosen (width, tiles x tile)
+    bool, tiles)`` -> ``(width, ...)`` are the mixer's attention over its own
+    lines under the mask (``tables`` are padded to whole tiles with the trash
+    block; ``tiles`` is static). A chunk row's is traced once a window of
+    ``_windows``; the one-token rows' ONCE, after their pass has chosen, at
+    the whole window's width (a place past the count of such rows has ``seen``
+    0 and chose nothing: it must cost nothing, or little). Both run under the
     scope ``sparse_attend``, the scores and the choice under ``indexer`` /
     ``index_select``."""
     tokens = queries.shape[0]
@@ -311,11 +319,15 @@ def walk_rows(
             chosen, filled = choose(table[mine], ctx_len[mine], seen,
                                     live[:, None], q_i[at][:, None],
                                     w[at][:, None], tiles)
-            with jax.named_scope("sparse_attend"):
-                return attend_single(table[mine], seen, queries[at][:, None],
-                                     chosen, tiles), filled
+            # at the whole window's width whichever window chose: what
+            # attends is then traced once a walk, not once a window
+            return jnp.pad(chosen, ((0, 0), (0, 0),
+                                    (0, (num_tiles - tiles) * tile))), filled
 
-        first, filled = at_window(first_tokens, jnp.max(seen))
+        chosen, filled = at_window(first_tokens, jnp.max(seen))
+        with jax.named_scope("sparse_attend"):
+            first = attend_single(table[mine], seen, queries[at][:, None],
+                                  chosen)
         # a place past the count writes nothing
         return (out.at[jnp.where(live, at, tokens)].set(first, mode="drop"),
                 ties + filled)

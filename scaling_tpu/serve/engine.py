@@ -1250,6 +1250,14 @@ class ServeEngine:
                 index_lines * self.sparse_layers)
             self._counter("serve_sparse_chosen_pairs_total").inc(
                 int(chosen.sum()) * self.sparse_layers)
+            if not self.latent_layers:
+                # the rows of ONE token: a grouped-query sparse layer attends
+                # each through the paged kernel under the mask of its choice
+                # (a latent one still folds them in plain XLA)
+                single_rows = int(np.count_nonzero(new_lens == 1))
+                mixed_span.annotate(sparse_single_rows=single_rows)
+                self._counter("serve_sparse_single_rows_total").inc(
+                    single_rows * self.sparse_layers)
         if self.par_lines:
             mixed_span.annotate(par_lines=self.par_lines)
             self._counter("serve_parallel_mixer_passes_total").inc(

@@ -157,6 +157,13 @@ def test_paged_kernel_compiles_at_group_5(one_chip, s):
     compile_paged_kernel(one_chip, 96, 20, 4, 40, s, "native")
 
 
+def kernel_operands(lowered) -> int:
+    """Operands of the ONE Mosaic kernel in a lowered program's text."""
+    (operands,) = re.findall(
+        r"stablehlo\.custom_call @tpu_custom_call\(([^)]*)\)", lowered.as_text())
+    return len(operands.split(","))
+
+
 def compile_paged_kernel(one_chip, slots, q_heads, kv_heads, max_blocks, s,
                          kv_dtype, head_dim=None):
     def shape(dims, dtype):
@@ -181,12 +188,15 @@ def compile_paged_kernel(one_chip, slots, q_heads, kv_heads, max_blocks, s,
             interpret=False, **scales,
         )
 
-    compiled = jax.jit(attend).lower(
+    lowered = jax.jit(attend).lower(
         shape((slots, s, q_heads, HEAD_DIM), jnp.bfloat16), pool, pool,
         shape((slots, max_blocks), jnp.int32), shape((slots,), jnp.int32),
         shape((slots,), jnp.int32), scales,
-    ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    )
+    # a maskless call's kernel has the operands it had: five prefetched
+    # scalars, the queries, two pools (and an int8 pool's two strips of scales)
+    assert kernel_operands(lowered) == (10 if quantized else 8)
+    assert "tpu_custom_call" in lowered.compile().as_text()
 
 
 @pytest.mark.parametrize("tokens", [512, 5120], ids=["small", "full"])
@@ -369,12 +379,16 @@ def test_masked_latent_kernel_compiles_at_the_cells_size(one_chip, window):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def sparse_gqa_layer(one_chip, tokens):
+def sparse_gqa_layer(one_chip, tokens, monkeypatch):
     """``serve-keye30b-longctx-burst``'s sparse grouped-query mixer over the
     paged pool at one of its engine's two token widths, compiled for the
-    described chip: ``(compiled, mixer)``. 8 rows of up to 320 positions, 32
-    query heads over 4 KV heads of 128, lines of THREE leaves (K, V, a 64-lane
-    index key), 4,096 blocks a row."""
+    described chip, its two Pallas kernels by Mosaic (not interpreted, though
+    this process's backend is the CPU): ``(compiled, mixer)``. 8 rows of up to
+    320 positions, 32 query heads over 4 KV heads of 128, lines of THREE
+    leaves (K, V, a 64-lane index key), 4,096 blocks a row."""
+    monkeypatch.setattr(
+        "scaling_tpu.nn.sparse_attention.paged_kernel_interpret",
+        lambda platform=None: False)
     from scaling_tpu.nn.attention import PagedKVCacheView, packed_token_map
     from scaling_tpu.nn.base_layer import ForwardContext
     from scaling_tpu.nn.norm import NormType
@@ -421,16 +435,19 @@ def sparse_gqa_layer(one_chip, tokens):
 
 
 @pytest.mark.parametrize("tokens", [1024, 2560], ids=["small", "full"])
-def test_sparse_gqa_layer_compiles_at_the_cells_size(one_chip, tokens):
+def test_sparse_gqa_layer_compiles_at_the_cells_size(one_chip, tokens, monkeypatch):
     """The shared row walk over a line of three leaves, at both token widths
     of the cell's engine: index keys gathered through the table, scores key
     tile by key tile, each query's EXACT choice of 2,048 of up to 65,536 lines
     as a threshold found by bisection (no sort, no approximate top-k in the
-    compiled program), K and V streamed under the mask. It compiles for the
-    chip, and a layer's temporaries stay inside what weights (6.25 GB) and
-    pool (4.56 GB) leave of the chip's 16 GB."""
-    compiled, mixer = sparse_gqa_layer(one_chip, tokens)
+    compiled program), K and V streamed under the mask: a chunk row's by
+    ``masked_gqa_attention``, built once a window of the walk's four, the
+    one-token rows' by the paged kernel under their masks, built ONCE. It
+    compiles for the chip, and a layer's temporaries stay inside what weights
+    (6.25 GB) and pool (4.56 GB) leave of the chip's 16 GB."""
+    compiled, mixer = sparse_gqa_layer(one_chip, tokens, monkeypatch)
     text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 4 + 1
     assert "approx" not in text.lower() and not re.search(r" sort\(|topk", text, re.I)
     assert " while(" in text and " conditional(" in text
     memory = compiled.memory_analysis()
@@ -445,9 +462,9 @@ def test_masked_gqa_kernel_compiles_at_the_cells_size(one_chip, window):
     """``serve-keye30b-longctx-burst``'s chunk rows
     (nn/masked_gqa_attention.py): 320 positions x 32 heads against a row's
     window of K and V lines (4 KV heads of 128) under a per-query mask, at the
-    smallest and the largest window the row walk uses. The layer's compile
-    above interprets the kernel (this process's backend is the CPU): Mosaic is
-    asked here."""
+    smallest and the largest window the row walk uses (the layer's compile
+    above holds the kernel at all four; alone it says which kernel Mosaic
+    refused)."""
     from scaling_tpu.nn.masked_gqa_attention import masked_gqa_attention
 
     def shape(dims, dtype=jnp.bfloat16):
@@ -462,6 +479,29 @@ def test_masked_gqa_kernel_compiles_at_the_cells_size(one_chip, window):
         shape((320, 32, 128)), shape((window, 4, 128)), shape((window, 4, 128)),
         shape((320, window), jnp.bool_), shape((), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_masked_paged_kernel_compiles_at_the_cells_size(one_chip):
+    """``serve-keye30b-longctx-burst``'s rows of ONE token
+    (nn/paged_attention.py with a mask operand): a pass of four rows, 32 query
+    heads over 4 KV heads of 128 in 16 KiB blocks, tables of 4,096 blocks in
+    SMEM, each row's choice a 65,536-slot int32 strip in VMEM; ONE operand more
+    than the maskless call (the layer's compile above holds this kernel too;
+    alone it compiles in two seconds and says which kernel Mosaic refused)."""
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def attend(q, pool_k, pool_v, table, seen, chosen):
+        return paged_decode_attention(
+            q, pool_k, pool_v, table, seen, seen - 1, sm_scale=128 ** -0.5,
+            num_repeat_kv=8, chosen=chosen, interpret=False)
+
+    pool = shape((8 * 4096 + 1, BLOCK_SIZE, 4, 128))
+    lowered = jax.jit(attend).lower(
+        shape((4, 1, 32, 128)), pool, pool, shape((4, 4096), jnp.int32),
+        shape((4,), jnp.int32), shape((4, 65536), jnp.bool_))
+    assert kernel_operands(lowered) == 9
+    assert "tpu_custom_call" in lowered.compile().as_text()
 
 
 def lowered_mixed_program(one_chip, monkeypatch, bucket, heads, kv_heads,

@@ -11,7 +11,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from scaling_tpu.nn import sparse_attention, sparse_latent_attention, sparse_rows
+from scaling_tpu.nn import (
+    paged_attention, sparse_attention, sparse_latent_attention, sparse_rows,
+)
 from scaling_tpu.nn.attention import (
     PagedKVCacheView, ParallelSelfAttention, packed_token_map,
 )
@@ -243,7 +245,7 @@ def _walked_chunk(new_len):
         starts=jnp.zeros((1,), jnp.int32), width=width, topk=TOPK,
         q_i=jnp.asarray(q_i), w=w, queries=jnp.zeros((width, window)),
         out=jnp.zeros((width, window)), choice=choice,
-        attend_single=lambda tables, seen, q, chosen, tiles: chosen[:, 0].astype(
+        attend_single=lambda tables, seen, q, chosen: chosen[:, 0].astype(
             jnp.float32),
         attend_chunk=lambda table, seen, q, chosen, tiles: chosen.astype(jnp.float32))
     scores = index_scores(jnp.asarray(q_i), index_pool[tables(1)[0]].reshape(
@@ -364,14 +366,20 @@ def test_one_choice_a_token_is_shared_by_every_head_and_group(mixer, params, mon
     # more rows of one token than a pass takes (SINGLE_ROWS), and not a whole
     # number of passes: 6 of 8 rows, a chunk row and an idle row among them
     ([30, 9, 17, 12, 0, 33, 21, 5], [1, 1, 1, 6, 0, 1, 1, 1], 4, (2, 6)),
-], ids=["one-decode-row", "six-decode-rows"])
+    # one pass: two decode rows that see fewer lines than ``index_topk`` (3 and
+    # 7 of 8), two whose contexts end mid-block and mid-tile (38 and 30 lines
+    # in blocks of 4 and the kernel's tiles of 8)
+    ([2, 12, 0, 37, 29, 6], [1, 6, 0, 1, 1, 1], 2, (2, 6)),
+], ids=["one-decode-row", "six-decode-rows", "short-and-mid-tile-decode-rows"])
 def test_a_token_major_tick_of_chunk_rows_and_decode_rows(
-        mixer, params, contexts, news, idle, shape):
+        mixer, params, monkeypatch, contexts, news, idle, shape):
     """Rows in one packed batch: every row's output is its own sequence's
     uncached output at those positions, and only its own lines were written,
-    in all three leaves."""
+    in all three leaves. The one-token rows' kernel runs tiles of 8 slots, so
+    that their contexts span several."""
+    monkeypatch.setattr(paged_attention, "_TILE_TOKENS", 8)
     rows, width = len(news), 6
-    assert sum(n == 1 for n in news) in (1, SINGLE_ROWS + 2)
+    assert sum(n == 1 for n in news) in (1, SINGLE_ROWS, SINGLE_ROWS + 2)
     ctx_len = jnp.asarray(contexts, jnp.int32)
     new_len = jnp.asarray(news, jnp.int32)
     seqs = [jax.random.normal(jax.random.PRNGKey(10 + r), (1, 40, HIDDEN))
@@ -414,7 +422,10 @@ def test_a_token_major_tick_of_chunk_rows_and_decode_rows(
 
 def test_the_row_walk_pays_for_real_shapes(mixer):
     """The lowered walk holds a rolled loop over the rows, a branch a window,
-    loops over a row's tiles, and no sort: the choice is a threshold."""
+    loops over a row's tiles, and no sort: the choice is a threshold. The
+    one-token rows attend through the paged kernel, called ONCE (not once a
+    window), and no pass of four gathers a ``(rows, tile, n_kv, h)`` tile of
+    K or V through the table."""
     view = view_of(pools(4), tables(4), [0] * 4, [0] * 4)
     text = jax.jit(lambda *a: mixer._attend_rows(*a, 6, True)).lower(
         jnp.zeros((12, INDEX_HEADS, INDEX_DIM)), jnp.zeros((12, INDEX_HEADS)),
@@ -422,6 +433,11 @@ def test_the_row_walk_pays_for_real_shapes(mixer):
         jnp.zeros((4,), jnp.int32), jnp.zeros((4,), jnp.int32)).as_text()
     assert "stablehlo.while" in text and "stablehlo.case" in text
     assert "stablehlo.sort" not in text and "top_k" not in text
+    assert text.count("call @_paged_call(") == 1
+    # a pass's tile of K or V as XLA would gather it: 4 rows x 16 blocks of
+    # (4, 2, 16); of the index keys (4, 12), which ARE gathered so
+    assert "tensor<4x16x4x12xf32>" in text
+    assert "tensor<4x16x4x2x16xf32>" not in text
 
 
 def test_what_the_mixer_does_not_build_is_refused_by_name(mixer, params):

@@ -9,7 +9,10 @@ rows, a short-query path); its cases force several tiles a row by
 shrinking the tile the shapes would derive. ISSUE 41 ran the kernel's
 double buffer across rows (a row's first tile is fetched under the last
 fold of the active row before it); its cases mix rows of 1, 2 and 3
-tiles with inactive rows anywhere, and permute the rows of a call."""
+tiles with inactive rows anywhere, and permute the rows of a call. ISSUE 64
+gave it an optional mask operand (a row's choice of slots: the sparse
+grouped-query mixer's one-token rows); its cases hold the masked call to the
+dense reference under the mask and the maskless call to the operands it had."""
 
 import numpy as np
 import pytest
@@ -26,9 +29,11 @@ from scaling_tpu.nn.paged_attention import (  # noqa: E402
 BS, MAXB, NB, H = 4, 4, 9, 16
 
 
-def dense_reference(q, pool_k, pool_v, tab, valid_len, base, n_rep):
+def dense_reference(q, pool_k, pool_v, tab, valid_len, base, n_rep,
+                    chosen=None):
     """Gather-the-window attention, mirroring the XLA fallback's masking
-    discipline (slot < valid_len, slot <= q_slot)."""
+    discipline (slot < valid_len, slot <= q_slot), under a row's ``chosen``
+    (rows, window) where given."""
     b, s, n, h = q.shape
     window = tab.shape[1] * pool_k.shape[1]
     gk = pool_k[tab].reshape(b, window, -1, h)
@@ -46,6 +51,8 @@ def dense_reference(q, pool_k, pool_v, tab, valid_len, base, n_rep):
     allowed = (slots_k[:, None, :] < valid_len[:, None, None]) & (
         slots_k[:, None, :] <= slots_q[:, :, None]
     )
+    if chosen is not None:
+        allowed = allowed & chosen[:, None, :]
     scores = jnp.einsum("bqnh,bknh->bnqk", q, gk) * h ** -0.5
     scores = jnp.where(allowed[:, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
@@ -403,6 +410,96 @@ def test_what_crosses_a_grid_step_is_a_function_of_valid_len(
         jnp.minimum(jnp.asarray(valid, jnp.int32), 24), 8)
     assert got_before.tolist() == before
     assert got_next.tolist() == following
+
+
+# ---- ISSUE 64: a mask operand. A row's choice of slots, one lane-dense strip
+# a row; the rows of ONE position fold their group's rows alone and wait for a
+# whole tile at once
+
+def masked_case(group, s):
+    """Four rows over tiles of 8 tokens (2 blocks of 4): a row in its first
+    tile, one that ends mid-block and mid-tile, one on its table's last slot,
+    an inactive one; ``chosen`` keeps about half of every row's slots, the
+    last row's first position among them."""
+    rng = np.random.default_rng(64)
+    case = tiled_case(
+        rng, block_size=4, max_blocks=7, n_kv=2, group=group, s=s,
+        ctx=[3, 18 - s, 28 - s, 0], new_len=[s, s, s, 0])
+    chosen = rng.random((4, 28)) < 0.5
+    chosen[:3, 0] = True
+    return case, jnp.asarray(chosen)
+
+
+@pytest.mark.parametrize("group", [1, 4, 8])
+@pytest.mark.parametrize("s", [1, 5], ids=["one-position", "five-positions"])
+@pytest.mark.parametrize("mask", ["all-true", "random", "empty", "narrower"])
+def test_a_rows_choice_masks_its_slots(monkeypatch, group, s, mask):
+    """Slot ``k`` is visible iff the paged contract admits it AND the row
+    chose it: a choice of everything is the maskless call; a random one is
+    the dense reference under it; a row that chose nothing, or sees nothing,
+    gives zeros; a mask narrower than the table's window chooses nothing past
+    its width."""
+    monkeypatch.setattr(paged_attention, "_TILE_TOKENS", 8)
+    (q, pk, pv, tab, ctx, new), chosen = masked_case(group, s)
+    if mask == "all-true":
+        out = check_rows(q, pk, pv, tab, ctx, new, group,
+                         chosen=jnp.ones_like(chosen))
+        want = check_rows(q, pk, pv, tab, ctx, new, group)
+        assert_real_positions(out, want, new, 1e-6)
+        return
+    if mask == "empty":
+        chosen = chosen.at[1].set(False)
+    if mask == "narrower":      # 13 slots of 28: the rest is not chosen
+        out = check_rows(q, pk, pv, tab, ctx, new, group, chosen=chosen[:, :13])
+        chosen = chosen.at[:, 13:].set(False)
+    else:
+        out = check_rows(q, pk, pv, tab, ctx, new, group, chosen=chosen)
+    ref = dense_reference(q, pk, pv, tab, ctx + new, ctx, group, chosen)
+    live = [0, 2] if mask == "empty" else [0, 1, 2]
+    for row in live:
+        np.testing.assert_allclose(
+            np.asarray(out[row]), np.asarray(ref[row]), atol=1e-5,
+            err_msg=f"row {row}")
+    # the inactive row, and the row that chose nothing
+    assert not np.asarray(out[3]).any()
+    if mask == "empty":
+        assert not np.asarray(out[1]).any()
+
+
+def pallas_operands(**kwargs):
+    """``(prefetched scalars, all operands)`` of the call's ``pallas_call``,
+    read from its jaxpr (no kernel is built: nothing is lowered)."""
+    rng = np.random.default_rng(0)
+    q, pk, pv, tab, ctx, new = tiled_case(
+        rng, block_size=4, max_blocks=4, n_kv=2, group=2, s=1,
+        ctx=[5, 0], new_len=[1, 1])
+    if kwargs.pop("int8", False):
+        (pk, sk), (pv, sv) = kv_quantize_int8(pk), kv_quantize_int8(pv)
+        kwargs.update(scale_k=sk, scale_v=sv)
+    jaxpr = jax.make_jaxpr(lambda *a: paged_decode_attention(
+        *a, sm_scale=0.25, num_repeat_kv=2, interpret=True, **kwargs))(
+            q, pk, pv, tab, ctx + new, ctx)
+    calls = []
+
+    def find(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                calls.append(eqn)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                find(sub)
+
+    find(jaxpr.jaxpr)
+    (call,) = calls
+    return call.params["grid_mapping"].num_index_operands, len(call.invars)
+
+
+def test_a_maskless_call_has_the_operands_it_had():
+    """Five prefetched scalars (table, valid_len, base, tiles before, next
+    row), the queries and two pools; an int8 pool adds its two strips of
+    scales, a mask ONE strip more, and nothing else."""
+    assert pallas_operands() == (5, 8)
+    assert pallas_operands(int8=True) == (5, 10)
+    assert pallas_operands(chosen=jnp.ones((2, 16), bool)) == (5, 9)
 
 
 def test_group_16_over_2_kv_heads_agrees_with_the_gather_formulation():

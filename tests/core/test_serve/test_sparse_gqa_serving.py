@@ -272,10 +272,10 @@ def test_a_choice_made_a_head_and_not_a_token_is_told_apart(keye, sequence, monk
         n = queries.shape[1]
 
         def split(attend):
-            def twice(tables, seen, q, chosen, tiles):
+            def twice(tables, seen, q, chosen, *tiles):
                 shifted = jnp.roll(chosen, 1, axis=-2).at[..., 0, :].set(chosen[..., 0, :])
-                own = attend(tables, seen, q, chosen, tiles)
-                other = attend(tables, seen, q, shifted, tiles)
+                own = attend(tables, seen, q, chosen, *tiles)
+                other = attend(tables, seen, q, shifted, *tiles)
                 return jnp.concatenate([own[..., :n // 2, :], other[..., n // 2:, :]], -2)
             return twice
 
@@ -457,8 +457,9 @@ def test_a_tie_over_room_is_filled_by_position_and_counted(keye, reference, tmp_
 def test_the_spans_say_what_was_scored_chosen_and_read(keye, tmp_path):
     """``serve.mixed`` of a sparse model of either kind: ``sparse_layers``,
     ``index_lines``, ``index_pairs``, ``chosen_pairs``, counted on the host
-    from the tick's row lengths; the two counters add them up over the
-    layers; no latent field."""
+    from the tick's row lengths, and ``sparse_single_rows``, the rows of one
+    token (the paged kernel's, under their masks); the three counters add them
+    up over the layers; no latent field."""
     engine = engine_of(keye, num_slots=1)
     obs.start_capture(str(tmp_path))
     try:
@@ -481,6 +482,9 @@ def test_the_spans_say_what_was_scored_chosen_and_read(keye, tmp_path):
         f["index_lines"] for f in spans)
     assert capture.counters["serve_sparse_chosen_pairs_total"] == SPARSE_LAYERS * sum(
         f["chosen_pairs"] for f in spans)
+    # the two decode ticks' rows went through the paged kernel, a layer each
+    assert [f["sparse_single_rows"] for f in spans] == [0] * 5 + [1, 1]
+    assert capture.counters["serve_sparse_single_rows_total"] == SPARSE_LAYERS * 2
     assert obs.kernel_build_count("masked_gqa_attention", interpret=True) > 0
     assert obs.kernel_build_count("masked_gqa_attention", interpret=False) == 0
     assert obs.kernel_build_count("paged_attention", interpret=True) > 0

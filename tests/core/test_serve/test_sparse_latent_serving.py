@@ -380,6 +380,9 @@ def test_the_spans_say_what_was_scored_chosen_and_read(dsv32, tmp_path):
         f["index_lines"] for f in spans)
     assert capture.counters["serve_sparse_chosen_pairs_total"] == SPARSE_LAYERS * sum(
         f["chosen_pairs"] for f in spans)
+    # a latent line's one-token rows do not go through the paged kernel
+    assert not any("sparse_single_rows" in f for f in spans)
+    assert "serve_sparse_single_rows_total" not in capture.counters
     assert obs.kernel_build_count("masked_latent_attention", interpret=True) > 0
     assert obs.kernel_build_count("masked_latent_attention", interpret=False) == 0
 
