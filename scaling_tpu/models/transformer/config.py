@@ -385,6 +385,29 @@ class TransformerArchitectureConfig(BaseConfig):
         "kind and the residual (x <- x + Mixer(Norm(x))); absent: the "
         "homogeneous stack of attention + MLP layers",
     )
+    hc_streams: int = Field(
+        1, description="residual streams a token carries through a "
+        "layer_pattern stack (manifold-constrained hyper-connections, "
+        "nn/hyper_connection.py: the published hc_mult): every single-mixer "
+        "layer reads a learned mix of them and writes its output back into "
+        "all of them under a doubly stochastic mixing matrix; the stream is "
+        "(batch, seq, hc_streams * hidden_size), the embedding in every "
+        "stream at the start, folded into one by a learned gate before the "
+        "final norm. 1 is the plain residual x <- x + Mixer(Norm(x)). Served "
+        "only", ge=1)
+    hc_sinkhorn_iters: int = Field(
+        20, description="Sinkhorn steps that project a mapping's hc_streams x "
+        "hc_streams matrix onto the doubly stochastic ones: the softmax over "
+        "rows then the columns, then rows and columns hc_sinkhorn_iters - 1 "
+        "times more", ge=1)
+    hc_eps: float = Field(
+        1e-6, description="added to a mapping's gates and to every sum a "
+        "Sinkhorn step divides by", ge=0.0)
+    hc_res_clamp_min: float = Field(
+        -30.0, description="a mapping's mixing logits are clipped to "
+        "[hc_res_clamp_min, hc_res_clamp_max] before the exponential (the "
+        "published mhc_h_res_clamp_min / _max)")
+    hc_res_clamp_max: float = Field(30.0, description="see hc_res_clamp_min")
     mamba_num_heads: int = Field(
         64, description="heads of a Mamba-2 mixer; its inner width is "
         "mamba_num_heads * mamba_head_dim", gt=0)
@@ -583,6 +606,8 @@ class TransformerArchitectureConfig(BaseConfig):
                 "index_n_heads, index_head_dim and index_topk together, and "
                 "a layer_pattern's 'latent' layers have one, or, in a pattern "
                 "without them, its 'attention' layers")
+        if self.hc_streams > 1:
+            self._validate_hyper_connection()
         if self.layer_pattern is not None:
             self._validate_pattern()
         elif self.rope_scaling is not None:
@@ -674,6 +699,25 @@ class TransformerArchitectureConfig(BaseConfig):
             raise ValueError(
                 "parallel_ssm with num_local_attention_heads: windowed heads "
                 "beside a recurrent state are not built")
+
+    def _validate_hyper_connection(self):
+        """What a stack of hc_streams > 1 does not build, each by name."""
+        if self.loop_steps > 1:
+            raise ValueError(
+                "hc_streams > 1 with loop_steps > 1: a looped trunk starts a "
+                "step from the final norm's ONE stream; a looped "
+                "hyper-connected trunk is not built")
+        if self.layer_pattern is None:
+            raise ValueError(
+                "hc_streams > 1 without layer_pattern: the mapping belongs to "
+                "a sub-layer (one norm, one mixer), which is the pattern "
+                "stack's single-mixer layer; the homogeneous TransformerLayer "
+                "(attention and MLP in one layer, parallel_ssm) keeps the "
+                "plain residual")
+        if self.hc_res_clamp_min >= self.hc_res_clamp_max:
+            raise ValueError(
+                f"hc_res_clamp_min {self.hc_res_clamp_min} is not under "
+                f"hc_res_clamp_max {self.hc_res_clamp_max}")
 
     def _validate_pattern(self):
         """What a ``layer_pattern`` stack does not build, each by name."""

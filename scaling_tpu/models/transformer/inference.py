@@ -245,6 +245,15 @@ def request_sample_key(base_key: jax.Array, req_id: jax.Array,
     )
 
 
+def gather_positions(a: jax.Array, index: jax.Array) -> jax.Array:
+    """``a`` (batch, seq, ...) at the FLAT positions ``index`` into ``batch *
+    seq``: ``index.shape + a.shape[2:]``. Only the two token axes are
+    flattened: what follows them is a position's own, be it one width or
+    (streams, width); flattened down to the last axis, a trunk of several
+    streams a position would give rows of OTHER positions without an error."""
+    return a.reshape((-1,) + a.shape[2:])[index]
+
+
 class TransformerInferenceModule:
     """Single-host inference over a trained checkpoint."""
 
@@ -446,7 +455,7 @@ class TransformerInferenceModule:
             pick = None
             if gather_index is not None:
                 def pick(h):
-                    return h.reshape(-1, h.shape[-1])[gather_index]
+                    return gather_positions(h, gather_index)
             logits, new_caches, p = self._run_looped(
                 params, batch, ctx, caches=caches, offset=offset, pick=pick,
                 exit_p=exit_p)
@@ -524,8 +533,8 @@ class TransformerInferenceModule:
                     li += len(consumes)
                 if i == last_tl:
                     x = dict(x)
-                    a = x["activations"]
-                    x["activations"] = a.reshape(-1, a.shape[-1])[gather_index]
+                    x["activations"] = gather_positions(
+                        x["activations"], gather_index)
             elif isinstance(layer, PipelinedBody):
                 if caches is not None:
                     raise ValueError(

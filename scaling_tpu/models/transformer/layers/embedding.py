@@ -135,6 +135,11 @@ class EmbeddingInput(BaseLayer):
             )
 
         embeddings = ctx.dropout(embeddings, self.dropout_rate)
+        if self.architecture.hc_streams > 1:
+            # every residual stream starts as the embedding: stream j is
+            # lanes [j * hidden, (j + 1) * hidden) (nn/hyper_connection.py)
+            embeddings = jnp.tile(
+                embeddings, (1, 1, self.architecture.hc_streams))
 
         b, s = token_ids.shape
         position_ids = batch.get("position_ids")

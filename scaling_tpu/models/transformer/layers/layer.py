@@ -32,6 +32,7 @@ from ....nn import (
 )
 from ....nn.attention import PagedKVCacheView
 from ....nn.base_layer import multiplied
+from ....nn.hyper_connection import HyperConnection
 from ....nn.rotary import RotaryConfig
 from ....nn.latent_attention import LatentSelfAttention
 from ....nn.sparse_attention import SparseSelfAttention
@@ -132,6 +133,17 @@ def dense_mlp(arch: TransformerArchitectureConfig, bitfit=None) -> BaseLayer:
     )
 
 
+def hyper_connection(arch: TransformerArchitectureConfig) -> Optional[HyperConnection]:
+    """The mapping of one sub-layer of a stack of ``hc_streams`` residual
+    streams (``nn/hyper_connection.py``); None for the plain residual."""
+    if arch.hc_streams == 1:
+        return None
+    return HyperConnection(
+        arch.hidden_size, arch.hc_streams, arch.hc_sinkhorn_iters, arch.hc_eps,
+        (arch.hc_res_clamp_min, arch.hc_res_clamp_max),
+        arch.layernorm.layernorm_epsilon)
+
+
 def mamba_mixer(arch: TransformerArchitectureConfig) -> Mamba2Mixer:
     """The Mamba-2 mixer the configuration describes (``nn/mamba.py``)."""
     return Mamba2Mixer(
@@ -151,7 +163,11 @@ class MixerLayer(BaseLayer):
     """A layer of a ``layer_pattern`` stack: ONE norm, ONE mixer of the
     layer's kind, the residual: ``x <- x + Mixer(Norm(x))`` (Nemotron-H's
     block; LFM2's block, an operator then an FFN each behind its own norm, is
-    two of them)."""
+    two of them). With ``hc_streams > 1`` the residual is that many streams
+    and the layer has a mapping of its own (``self.hc``,
+    ``nn/hyper_connection.py``): the norm reads ``u``, the mapping's mix of
+    the streams, and the mixer's output goes back into all of them, ``X <-
+    H_res X + H_post Mixer(Norm(u))``."""
 
     def __init__(self, architecture: TransformerArchitectureConfig, layer_index: int = 0):
         arch = architecture
@@ -160,6 +176,7 @@ class MixerLayer(BaseLayer):
         self.kind = arch.layer_pattern[layer_index]
         dtype = arch.dtype
         self.norm = get_norm(arch.norm_type, arch.hidden_size, arch.layernorm, dtype)
+        self.hc = hyper_connection(arch)
         # an indexer, where the configuration has one, makes this stack's
         # latent or attention layers sparse (config.py: one kind of them)
         sparse = {} if arch.index_topk is None else dict(
@@ -293,11 +310,18 @@ class MixerLayer(BaseLayer):
                 mixer["shared_out"] = scaled(mixer["shared_out"], scale)
         else:
             mixer["dense"]["weight"] = scaled(mixer["dense"]["weight"], scale)
-        return {"norm": self.norm.init(k1), "mixer": mixer}
+        params = {"norm": self.norm.init(k1), "mixer": mixer}
+        if self.hc is not None:
+            # a key of its own: norm's and mixer's stay what they were
+            params["hc"] = self.hc.init(jax.random.fold_in(key, 2))
+        return params
 
     def param_metas(self) -> dict:
-        return {"norm": tree_prefix(self.norm.param_metas(), "norm"),
-                "mixer": tree_prefix(self.mixer.param_metas(), "mixer")}
+        metas = {"norm": tree_prefix(self.norm.param_metas(), "norm"),
+                 "mixer": tree_prefix(self.mixer.param_metas(), "mixer")}
+        if self.hc is not None:
+            metas["hc"] = tree_prefix(self.hc.param_metas(), "hc")
+        return metas
 
     def __call__(self, params: dict, x: dict, ctx: ForwardContext,
                  kv_cache=None, cache_offset=None, return_kv: bool = False,
@@ -309,7 +333,12 @@ class MixerLayer(BaseLayer):
         updated view. ``real`` ((b, s) bool): the positions that hold a
         token, for the routed MLP's load count when serving."""
         h = x["activations"]
-        normed = self.norm(params["norm"], h, ctx)
+        mix = None
+        if self.hc is None:
+            normed = self.norm(params["norm"], h, ctx)
+        else:
+            u, mix = self.hc.pre(params["hc"], h)
+            normed = self.norm(params["norm"], u, ctx)
         out = dict(x)
         state = None
         tie_breaks = ()
@@ -365,7 +394,10 @@ class MixerLayer(BaseLayer):
             # filled ties by position, summed over the layers
             out["sparse_tie_breaks"] = (
                 x.get("sparse_tie_breaks", 0) + tie_breaks[0])
-        out["activations"] = h + y.astype(h.dtype)
+        if mix is None:
+            out["activations"] = h + y.astype(h.dtype)
+        else:
+            out["activations"] = self.hc.post(h, y, mix)
         if view is not None and (return_kv or kv_cache is not None):
             return out, state
         return out

@@ -22,6 +22,7 @@ from ....nn import (
     xavier_normal_init,
 )
 from ....nn.base_layer import multiplied
+from ....nn.hyper_connection import HyperReadout
 from ....nn.linear import column_parallel_matmul
 from ....parallel.sharding import constrain, shard_logits, vocab_shards
 from ....topology.topology import MODEL_AXIS
@@ -40,6 +41,12 @@ class LayerNormWrapper(BaseLayer):
                              arch.dtype, bitfit)
         self.record_embeddings = record_embeddings
         self.random_signs = arch.layer_pattern is not None and arch.weight_tying
+        # hc_streams residual streams are folded into one before the norm
+        self.readout: Optional[HyperReadout] = None
+        if arch.hc_streams > 1:
+            self.readout = HyperReadout(
+                arch.hidden_size, arch.hc_streams, arch.hc_eps,
+                arch.layernorm.layernorm_epsilon)
 
     def init(self, key: jax.Array) -> dict:
         """A norm's own init; before a head TIED to the table of a
@@ -57,14 +64,23 @@ class LayerNormWrapper(BaseLayer):
             weight = params["weight"]
             signs = jax.random.rademacher(key, weight.shape, jnp.float32)
             params["weight"] = weight * signs.astype(weight.dtype)
-        return {"norm": params}
+        params = {"norm": params}
+        if self.readout is not None:
+            params["hc"] = self.readout.init(jax.random.fold_in(key, 1))
+        return params
 
     def param_metas(self) -> dict:
-        return {"norm": tree_prefix(self.norm.param_metas(), "norm")}
+        metas = {"norm": tree_prefix(self.norm.param_metas(), "norm")}
+        if self.readout is not None:
+            metas["hc"] = tree_prefix(self.readout.param_metas(), "hc")
+        return metas
 
     def __call__(self, params: dict, x: dict, ctx: ForwardContext) -> dict:
         out = dict(x)
-        out["activations"] = self.norm(params["norm"], x["activations"], ctx)
+        h = x["activations"]
+        if self.readout is not None:
+            h = self.readout(params["hc"], h)
+        out["activations"] = self.norm(params["norm"], h, ctx)
         if self.record_embeddings:
             out["embeddings"] = out["activations"]
         return out

@@ -377,6 +377,13 @@ class ServeEngine:
                 "spec_k > 0 with latent attention layers: the latent kernel "
                 "folds a row of one token or a prompt chunk, and a decode row "
                 "with drafts has not been held to the reference; set spec_k=0")
+        # residual streams a token carries (nn/hyper_connection.py; 1: the
+        # plain residual) and the sub-layers that have a mapping of their
+        # own: the streams are activations and are never cached
+        self.hc_streams = inference_module.architecture.hc_streams
+        self.hc_sublayers = sum(
+            getattr(layer, "hc", None) is not None
+            for layer in inference_module.module.layers)
         # the SPARSE layers (latent: nn/sparse_latent_attention.py;
         # grouped-query: nn/sparse_attention.py): a query attends over its
         # index_topk best lines, chosen from index keys that are a leaf of a
@@ -1258,6 +1265,13 @@ class ServeEngine:
                 mixed_span.annotate(sparse_single_rows=single_rows)
                 self._counter("serve_sparse_single_rows_total").inc(
                     single_rows * self.sparse_layers)
+        if self.hc_sublayers:
+            # what the residual path moves this tick: every real token's
+            # streams through every sub-layer's mapping
+            mixed_span.annotate(hc_streams=self.hc_streams,
+                                hc_sublayers=self.hc_sublayers)
+            self._counter("serve_hc_token_sublayers_total").inc(
+                tokens * self.hc_sublayers)
         if self.par_lines:
             mixed_span.annotate(par_lines=self.par_lines)
             self._counter("serve_parallel_mixer_passes_total").inc(
@@ -1749,6 +1763,8 @@ class ServeEngine:
             "kv_line_bytes": self.pools.line_bytes,
             "latent_layers": self.latent_layers,
             "sparse_layers": self.sparse_layers,
+            "hc_streams": self.hc_streams,
+            "hc_sublayers": self.hc_sublayers,
             # layers that keep a line a slot (Mamba-2 mixers' recurrent state,
             # short convolutions' tails; 0: a model without them) and the
             # bytes of those lines

@@ -233,6 +233,45 @@ def test_latent_kernel_compiles_at_the_cells_size(one_chip, tokens):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("tokens", [896, 8192], ids=["small", "full"])
+def test_hyper_connection_compiles_at_the_cells_size(one_chip, monkeypatch, tokens):
+    """``serve-xing29b-rag-burst``'s residual path (nn/hyper_connection.py) at
+    both token widths of its engine (32 slots, ``prefill_chunk`` 256): a
+    mapping's ``pre`` and ``post`` over four bf16 streams of 3,584. What only
+    the chip's compiler shows: the Sinkhorn steps are a Mosaic kernel (as plain
+    XLA the 20 steps fuse into one operation that takes minutes to compile: 6
+    steps 4 s, 14 over a minute, 20 not in ten; this compiles in ~5 s); ``vec(X)
+    phi`` is ONE bf16 matmul onto 72 output lanes (phi's three bf16 terms), no
+    float32 copy of the 14,336-wide stream; the stream stays ``(tokens, 4 x
+    3584)``, no array with the 4 on its sublanes."""
+    from scaling_tpu.nn.hyper_connection import HyperConnection
+
+    monkeypatch.setattr(
+        "scaling_tpu.nn.paged_attention.paged_kernel_interpret",
+        lambda platform=None: False)
+    mapping = HyperConnection(3584, 4, 20, 1e-6, (-30.0, 30.0), 1e-6)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def sublayer(params, x, y):
+        u, mix = mapping.pre(params, x)
+        return mapping.post(x, (u + y).astype(x.dtype), mix)
+
+    params = jax.eval_shape(mapping.init, jax.random.PRNGKey(0))
+    text = jax.jit(sublayer).lower(
+        jax.tree.map(on_chip, params),
+        on_chip(jax.ShapeDtypeStruct((1, tokens, 4 * 3584), jnp.bfloat16)),
+        on_chip(jax.ShapeDtypeStruct((1, tokens, 3584), jnp.bfloat16)),
+    ).compile().as_text()
+    kernels = custom_calls(text)
+    assert len(kernels) == 1 and "hc_sinkhorn" in kernels[0]
+    entry = text[text.index("\nENTRY "):]     # what is materialised
+    assert re.search(rf"f32\[72,{tokens}\]\S* fusion\(", entry)      # the matmul
+    assert not re.search(rf"f32\[(1,)?{tokens},14336\]", entry)
+    assert not re.search(rf"\[(1,)?{tokens},4,3584\]", text)
+
+
 def conditionals_around(text):
     """``{computation: how many conditionals enclose it}`` of a compiled
     program's text, and ``{computation: its instructions}`` (a conditional's
