@@ -1353,6 +1353,21 @@ def _ensure_devices(need: int) -> None:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
+    """The bench, with SIGTERM's handler put back as it was found: a sweep
+    arm or the single engine chains a drain handler onto it
+    (``install_drain_handler``), and a caller IN PROCESS (a test, a
+    notebook) must not keep a handler that drains an engine long gone."""
+    import signal
+
+    found = signal.getsignal(signal.SIGTERM)
+    try:
+        return _main(argv)
+    finally:
+        if found is not None and signal.getsignal(signal.SIGTERM) is not found:
+            signal.signal(signal.SIGTERM, found)
+
+
+def _main(argv: Optional[List[str]]) -> int:
     parser = argparse.ArgumentParser(
         prog="python -m scaling_tpu.serve bench",
         description="continuous-batching serving benchmark (docs/SERVING.md)",

@@ -28,7 +28,13 @@ REPO = Path(__file__).resolve().parents[2]
 ENV_FLAG = "SCALING_TPU_IN_TEST_SUBPROCESS"
 
 
-def run_in_subprocess(timeout: float = 600):
+# what a wait on a child process is given when nothing says otherwise: a few
+# times the longest healthy child of tier-1 (45 s under six loaded workers),
+# far under the per-case limit (tests/conftest.py CASE_LIMIT_S)
+CHILD_LIMIT_S = 120
+
+
+def run_in_subprocess(timeout: float = CHILD_LIMIT_S):
     """Decorator factory: run this test alone in a child pytest."""
 
     def deco(fn):

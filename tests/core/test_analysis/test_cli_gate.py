@@ -19,7 +19,7 @@ REPO = Path(__file__).resolve().parents[3]
 FIXTURES = Path(__file__).resolve().parent / "fixtures"
 
 
-def run_cli(*args, timeout=600):
+def run_cli(*args, timeout=120):
     return subprocess.run(
         [sys.executable, "-m", "scaling_tpu.analysis", *args],
         cwd=REPO,
@@ -37,7 +37,7 @@ def test_lint_gate_clean_tree_exits_zero(tmp_path):
     summary the gate diffs structurally, with STA009-STA015 present and
     pinned at zero unsuppressed."""
     out = tmp_path / "lint.json"
-    p = run_cli("lint", "--json", str(out), timeout=300)
+    p = run_cli("lint", "--json", str(out))
     assert p.returncode == 0, p.stdout[-2000:] + p.stderr[-2000:]
     assert "lint: 0 finding(s)" in p.stdout
     payload = json.loads(out.read_text())
@@ -83,7 +83,7 @@ def test_protocol_gate_matches_golden(tmp_path):
     stats/shutdown ops and the control plane's barrier/heartbeat ops
     must all be present with their reply keys."""
     out = tmp_path / "protocol.json"
-    p = run_cli("protocol", "--json", str(out), timeout=300)
+    p = run_cli("protocol", "--json", str(out))
     assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-2000:]
     payload = json.loads(out.read_text())
     assert payload["schema_version"] == 3

@@ -16,6 +16,8 @@ from scaling_tpu.nn.latent_attention import LatentSelfAttention
 from scaling_tpu.nn.norm import LayerNormConfig
 from scaling_tpu.nn.rotary import RopeScalingConfig, RotaryConfig
 
+from .one_program import jitted
+
 H, HEADS, Q_LORA, KV_LORA, NOPE, ROPE, V = 256, 4, 96, 64, 32, 16, 32
 BLOCK, MAX_BLOCKS = 4, 40
 
@@ -69,13 +71,13 @@ def test_absorbed_over_the_pool_is_expanded_without_a_cache(paged_kernel):
     params = m.init(jax.random.PRNGKey(0))
     s = 24
     x = jax.random.normal(jax.random.PRNGKey(1), (1, s, H))
-    ctx = ForwardContext(paged_kernel=paged_kernel)
-    want = m(params, x, ctx, position_ids=jnp.arange(s)[None])
+    call = jitted(m, ForwardContext(paged_kernel=paged_kernel))
+    want = call(params, x, jnp.arange(s)[None])
     view, done, got = empty_view(1), 0, []
     for real, width in ((12, 12), (7, 12), (1, 1), (1, 1), (1, 1), (1, 1), (1, 1)):
         chunk = jnp.zeros((1, width, H)).at[:, :real].set(x[:, done:done + real])
         pos = (done + jnp.arange(width))[None]
-        y, view = m(params, chunk, ctx, position_ids=pos, kv_cache=view._replace(
+        y, view = call(params, chunk, pos, view._replace(
             context_len=jnp.asarray([done], jnp.int32),
             new_len=jnp.asarray([real], jnp.int32)))
         got.append(y[:, :real])
@@ -144,16 +146,15 @@ def test_a_row_major_batch_and_a_token_major_one_attend_alike():
         pool_k=jnp.asarray(rng.normal(size=view.pool_k.shape), jnp.float32),
         pool_v=view.pool_v.at[..., :ROPE].set(rng.normal(size=(*view.pool_v.shape[:2], ROPE))),
         context_len=ctx_len, new_len=jnp.asarray(new))
-    ctx = ForwardContext()
+    call = jitted(m, ForwardContext())
     pos = ctx_len[:, None] + jnp.arange(8)[None]
-    y_rows, _ = m(params, rows_x, ctx, position_ids=pos, kv_cache=view)
+    y_rows, _ = call(params, rows_x, pos, view)
     packed = jnp.concatenate([rows_x[r, :n] for r, n in enumerate(new)]
                              + [jnp.zeros((2, H))]).reshape(2, 8, H)
     token_map = packed_token_map(jnp.asarray(new), (2, 8), 8)
     ppos = jnp.where(token_map.offset < jnp.asarray(new)[token_map.row],
                      ctx_len[token_map.row] + token_map.offset, 0)
-    y_packed, _ = m(params, packed, ctx, position_ids=ppos,
-                    kv_cache=view._replace(token_map=token_map))
+    y_packed, _ = call(params, packed, ppos, view._replace(token_map=token_map))
     flat = y_packed.reshape(16, H)
     at = 0
     for r, n in enumerate(new):

@@ -27,6 +27,8 @@ from scaling_tpu.nn.sparse_rows import (
     walk_rows,
 )
 
+from .one_program import served, uncached
+
 HIDDEN, HEADS, KV_HEADS, HEAD_DIM, TOPK, BLOCK = 64, 8, 2, 16, 8, 4
 INDEX_HEADS, INDEX_DIM = 3, 12
 MAX_BLOCKS = 16               # a row's window: 64 slots
@@ -72,12 +74,6 @@ def tables(rows):
     return 1 + jnp.arange(rows * MAX_BLOCKS, dtype=jnp.int32).reshape(rows, MAX_BLOCKS)
 
 
-def uncached(mixer, params, x):
-    s = x.shape[1]
-    return mixer(params, x, ForwardContext(),
-                 position_ids=jnp.arange(s, dtype=jnp.int32)[None])
-
-
 def view_of(leaves, table, ctx_len, new_len, token_map=None):
     pool_k, pool_v, pool_i = leaves
     return PagedKVCacheView(
@@ -94,13 +90,13 @@ def chunked(mixer, params, x, sizes, paged_kernel):
     """One sequence through a pool of its own, ``sizes`` positions a call,
     row-major batches of one row."""
     leaves = pools(1)
+    step = served(mixer, paged_kernel)
     out, done = [], 0
     for n in sizes:
-        y, view, _ = mixer(
+        y, view, _ = step(
             params, x[:, done:done + n],
-            ForwardContext(serving=True, paged_kernel=paged_kernel),
-            position_ids=jnp.arange(done, done + n, dtype=jnp.int32)[None],
-            kv_cache=view_of(leaves, tables(1), [done], [n]))
+            done + jnp.arange(n, dtype=jnp.int32)[None],
+            view_of(leaves, tables(1), [done], [n]))
         leaves = leaves_of(view)
         out.append(y)
         done += n
@@ -339,8 +335,7 @@ def test_one_choice_a_token_is_shared_by_every_head_and_group(mixer, params, mon
 
     monkeypatch.setattr(SparseSelfAttention, "_chosen", recording)
     x = jax.random.normal(jax.random.PRNGKey(5), (1, 14, HIDDEN))
-    with jax.disable_jit():
-        got, _ = chunked(mixer, params, x, [6] * 2 + [1] * 2, "pallas")
+    got, _ = chunked(mixer, params, x, [6] * 2 + [1] * 2, "pallas")   # (traced: shapes)
     assert shapes and all(len(shape) == 3 and shape[:2] in ((1, 6), (1, 1))
                           for shape in shapes)
     monkeypatch.undo()
@@ -386,15 +381,14 @@ def test_a_token_major_tick_of_chunk_rows_and_decode_rows(
             for r in range(rows)]
     leaves = pools(rows)
     table = tables(rows)
-    # each row's context, written by a row-major call of its own
+    # each row's context, written by a row-major call of its own: the whole
+    # sequence's places, of which the row owns its context's (one call shape)
+    write = served(mixer, "xla")
     for r in range(rows):
-        c = int(ctx_len[r])
-        if not c:
-            continue
-        _, view, _ = mixer(params, seqs[r][:, :c], ForwardContext(serving=True),
-                        position_ids=jnp.arange(c, dtype=jnp.int32)[None],
-                        kv_cache=view_of(leaves, table[r:r + 1], [0], [c]))
-        leaves = leaves_of(view)
+        if int(ctx_len[r]):
+            _, view, _ = write(params, seqs[r], jnp.arange(40, dtype=jnp.int32)[None],
+                               view_of(leaves, table[r:r + 1], [0], ctx_len[r:r + 1]))
+            leaves = leaves_of(view)
     token_map = packed_token_map(new_len, shape, width)
     row, offset = np.asarray(token_map.row).reshape(-1), np.asarray(token_map.offset).reshape(-1)
     real = offset < np.asarray(new_len)[row]
@@ -403,15 +397,15 @@ def test_a_token_major_tick_of_chunk_rows_and_decode_rows(
     pos = jnp.asarray(np.where(real, np.asarray(ctx_len)[row] + offset, 0)).reshape(shape)
     outs = {}
     for kernel in ("pallas", "xla"):
-        y, new, _ = mixer(params, x, ForwardContext(serving=True, paged_kernel=kernel),
-                       position_ids=pos,
-                       kv_cache=view_of(leaves, table, ctx_len, new_len, token_map))
+        y, new, _ = served(mixer, kernel)(
+            params, x, pos, view_of(leaves, table, ctx_len, new_len, token_map))
         outs[kernel] = np.asarray(y).reshape(-1, HIDDEN)
     for r in range(rows):
         n, c = int(new_len[r]), int(ctx_len[r])
         if not n:
             continue
-        want = np.asarray(uncached(mixer, params, seqs[r][:, :c + n])[0, c:])
+        # (causal: a position's output is what it is whatever follows it)
+        want = np.asarray(uncached(mixer, params, seqs[r])[0, c:c + n])
         for kernel, got in outs.items():
             np.testing.assert_allclose(got[(row == r) & real], want, atol=3e-5,
                                        err_msg=f"row {r} {kernel}")

@@ -12,12 +12,13 @@ import numpy as np
 import pytest
 
 from scaling_tpu.nn.attention import PagedKVCacheView, packed_token_map
-from scaling_tpu.nn.base_layer import ForwardContext
 from scaling_tpu.nn.rotary import RopeScalingConfig, RotaryConfig
 from scaling_tpu.nn.sparse_latent_attention import (
     SINGLE_ROWS, SparseLatentSelfAttention, choose_lines, index_scores, index_tile_tokens,
     threshold_choice,
 )
+
+from .one_program import served, uncached
 
 HIDDEN, HEADS, TOPK, BLOCK = 64, 4, 8, 4
 MAX_BLOCKS = 16               # a row's window: 64 slots
@@ -115,27 +116,19 @@ def test_equal_scores_keep_the_lower_positions():
     assert not threshold_choice(wild, jnp.zeros((1, 8), bool), 3).any()
 
 
-def uncached(mixer, params, x):
-    s = x.shape[1]
-    return mixer(params, x, ForwardContext(),
-                 position_ids=jnp.arange(s, dtype=jnp.int32)[None])
-
-
 def chunked(mixer, params, x, sizes, paged_kernel):
     """One sequence through a pool of its own, ``sizes`` positions a call,
     row-major batches of one row."""
     pool_c, pool_i = pools(1, mixer)
+    step = served(mixer, paged_kernel)
     out, done = [], 0
     for n in sizes:
         view = PagedKVCacheView(
             pool_k=pool_c, pool_v=pool_i, block_table=tables(1),
             context_len=jnp.asarray([done], jnp.int32),
             new_len=jnp.asarray([n], jnp.int32))
-        y, view, _ = mixer(
-            params, x[:, done:done + n],
-            ForwardContext(serving=True, paged_kernel=paged_kernel),
-            position_ids=jnp.arange(done, done + n, dtype=jnp.int32)[None],
-            kv_cache=view)
+        y, view, _ = step(params, x[:, done:done + n],
+                          done + jnp.arange(n, dtype=jnp.int32)[None], view)
         pool_c, pool_i = view.pool_k, view.pool_v
         out.append(y)
         done += n
@@ -194,16 +187,16 @@ def test_a_token_major_tick_of_chunk_rows_and_decode_rows(
             for r in range(rows)]
     pool_c, pool_i = pools(rows, mixer)
     table = tables(rows)
-    # each row's context, written by a row-major call of its own
+    # each row's context, written by a row-major call of its own: the whole
+    # sequence's places, of which the row owns its context's (one call shape)
+    write = served(mixer, "xla")
     for r in range(rows):
-        c = int(ctx_len[r])
-        if not c:
+        if not int(ctx_len[r]):
             continue
         view = PagedKVCacheView(
             pool_k=pool_c, pool_v=pool_i, block_table=table[r:r + 1],
-            context_len=jnp.zeros((1,), jnp.int32), new_len=jnp.asarray([c], jnp.int32))
-        _, view, _ = mixer(params, seqs[r][:, :c], ForwardContext(serving=True),
-                        position_ids=jnp.arange(c, dtype=jnp.int32)[None], kv_cache=view)
+            context_len=jnp.zeros((1,), jnp.int32), new_len=ctx_len[r:r + 1])
+        _, view, _ = write(params, seqs[r], jnp.arange(40, dtype=jnp.int32)[None], view)
         pool_c, pool_i = view.pool_k, view.pool_v
     token_map = packed_token_map(new_len, shape, width)
     row, offset = np.asarray(token_map.row).reshape(-1), np.asarray(token_map.offset).reshape(-1)
@@ -216,14 +209,14 @@ def test_a_token_major_tick_of_chunk_rows_and_decode_rows(
         view = PagedKVCacheView(
             pool_k=pool_c, pool_v=pool_i, block_table=table,
             context_len=ctx_len, new_len=new_len, token_map=token_map)
-        y, new, _ = mixer(params, x, ForwardContext(serving=True, paged_kernel=kernel),
-                       position_ids=pos, kv_cache=view)
+        y, new, _ = served(mixer, kernel)(params, x, pos, view)
         outs[kernel] = np.asarray(y).reshape(-1, HIDDEN)
     for r in range(rows):
         n, c = int(new_len[r]), int(ctx_len[r])
         if not n:
             continue
-        want = np.asarray(uncached(mixer, params, seqs[r][:, :c + n])[0, c:])
+        # (causal: a position's output is what it is whatever follows it)
+        want = np.asarray(uncached(mixer, params, seqs[r])[0, c:c + n])
         for kernel, got in outs.items():
             np.testing.assert_allclose(got[(row == r) & real], want, atol=3e-5,
                                        err_msg=f"row {r} {kernel}")

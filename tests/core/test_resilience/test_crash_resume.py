@@ -32,7 +32,7 @@ SCRIPT = Path(__file__).resolve().parent / "resilience_script.py"
 WRITES_PER_SAVE = 8
 
 
-def run_script(tmp_dir: Path, name: str, faults: str = "", timeout: float = 300,
+def run_script(tmp_dir: Path, name: str, faults: str = "", timeout: float = 120,
                **spec_extra):
     workdir = tmp_dir / name
     spec = {

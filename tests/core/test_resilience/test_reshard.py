@@ -188,7 +188,7 @@ def _assert_restores_bit_equal(saver, cfg_load):
 
 
 # ------------------------------------------------- reshard parity matrix
-@run_in_subprocess(timeout=420)
+@run_in_subprocess()
 def test_reshard_dp2pp2_to_dp1pp2_bit_equal(request, tmp_path, data_prefix,
                                             dp2pp2_save):
     """The fast matrix representative, plus the commit contract:
@@ -300,7 +300,7 @@ def test_reshard_vpp2_to_pp1_bit_equal(request, tmp_path, data_prefix):
     _assert_restores_bit_equal(t, cfg_load)
 
 
-@run_in_subprocess(timeout=420)
+@run_in_subprocess()
 def test_run_with_resume_continues_loss_exact_at_new_shape(
     request, tmp_path, data_prefix
 ):
@@ -354,7 +354,7 @@ def test_run_with_resume_continues_loss_exact_at_new_shape(
 
 
 # ------------------------------------- fault points + backward compat
-@run_in_subprocess(timeout=420)
+@run_in_subprocess()
 def test_restore_faults_and_legacy_compat(request, tmp_path, data_prefix):
     """One cheap single-device run leaving two committed checkpoints
     (steps 3 and 6) drives all four restore-robustness contracts:

@@ -42,7 +42,7 @@ def bench_run(tmp_path_factory):
            "SCALING_TPU_TEST_CACHE": "off"}
     env.pop("SCALING_TPU_EVENTS_PATH", None)
     p = subprocess.run(cmd, cwd=REPO, env=env, capture_output=True,
-                       text=True, timeout=420)
+                       text=True, timeout=120)
     assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
     return run_dir, stats_json, p.stdout
 
@@ -132,7 +132,7 @@ def prefix_bench_run(tmp_path_factory):
            "SCALING_TPU_TEST_CACHE": "off"}
     env.pop("SCALING_TPU_EVENTS_PATH", None)
     p = subprocess.run(cmd, cwd=REPO, env=env, capture_output=True,
-                       text=True, timeout=420)
+                       text=True, timeout=120)
     assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
     return run_dir, stats_json, p.stdout
 
@@ -216,7 +216,7 @@ def _bench_cmd_env(run_dir, faults=None, extra=(), args=CHAOS_ARGS):
 def _run_chaos_bench(run_dir, faults=None, extra=()):
     cmd, env = _bench_cmd_env(run_dir, faults=faults, extra=extra)
     return subprocess.run(cmd, cwd=REPO, env=env, capture_output=True,
-                          text=True, timeout=420)
+                          text=True, timeout=120)
 
 
 # a slow open-loop tail (20 requests at 1/s) keeps the bench busy long

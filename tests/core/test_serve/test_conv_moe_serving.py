@@ -23,6 +23,8 @@ from scaling_tpu.nn.moe import ParallelMoEMLP
 from scaling_tpu.nn.short_conv import ConvTailView
 from scaling_tpu.serve.engine import EngineConfig, ServeEngine
 
+from . import reference_walk
+
 VOCAB = 96
 OPS = ["conv", "conv", "attention", "conv"]
 FFNS = ["mlp", "moe", "moe", "moe"]
@@ -102,19 +104,9 @@ def undisturbed(lfm2, reference):
     weights = view.reference_weights(lfm2.params, ARCH)
     spec = view.reference_spec(ARCH)
     requests = prompts((9, 21, 14, 30, 17))
-    want, margins = [], []
-    for p in requests:
-        tokens = list(p)
-        for _ in range(10):
-            logits = np.asarray(ref.forward(weights, jnp.asarray(tokens), spec)[-1])
-            top2 = np.sort(logits)[-2:]
-            margins.append(float(top2[1] - top2[0]))
-            tokens.append(int(logits.argmax()))
-        want.append(tokens[len(p):])
-    # greedy tokens compare exactly only where no near-tie can break the
-    # other way under another order of summation (float32: ~1e-5)
-    assert min(margins) > 1e-3
-    return requests, want
+    return requests, reference_walk.greedy_by_reference(
+        lambda tokens: ref.forward(weights, jnp.asarray(tokens), spec), requests, 10,
+        least_margin=1e-3)
 
 
 def test_the_state_pool_is_one_tail_per_slot_and_conv_layer(lfm2):

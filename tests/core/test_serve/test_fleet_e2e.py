@@ -23,6 +23,7 @@ import subprocess
 import sys
 import time
 from pathlib import Path
+from types import SimpleNamespace
 
 import pytest
 
@@ -46,7 +47,7 @@ def _env():
     return env
 
 
-def run_bench_cli(run_dir, *extra, timeout=420):
+def run_bench_cli(run_dir, *extra, timeout=120):
     cmd = [sys.executable, "-m", "scaling_tpu.serve", "bench",
            *WORK_ARGS, *MODEL_ARGS,
            "--run-dir", str(run_dir), "--json", str(run_dir / "stats.json"),
@@ -217,6 +218,7 @@ def test_spec_k_sweep_reports_optimal_k(tmp_path, monkeypatch, capsys):
         "SCALING_TPU_EVENTS_PATH", str(run_dir / "events.jsonl")
     )
     monkeypatch.setenv("SCALING_TPU_TEST_CACHE", "off")
+    found = signal.getsignal(signal.SIGTERM)
     rc = bench_main([
         "--requests", "6", "--rate", "50", "--seed", "5",
         "--prompt-len", "4", "8", "--output-len", "6", "10",
@@ -228,6 +230,8 @@ def test_spec_k_sweep_reports_optimal_k(tmp_path, monkeypatch, capsys):
     ])
     out = capsys.readouterr().out
     assert rc == 0, out
+    # each arm chained a drain handler onto SIGTERM: none outlives the call
+    assert signal.getsignal(signal.SIGTERM) is found
     stats = json.loads((run_dir / "stats.json").read_text())
     ks = [row["spec_k"] for row in stats["spec_k_sweep"]]
     assert ks == [0, 3]
@@ -245,3 +249,41 @@ def test_spec_k_sweep_reports_optimal_k(tmp_path, monkeypatch, capsys):
     out = capsys.readouterr().out
     assert rc == 0
     assert "spec-k sweep: best k=" in out
+
+
+@pytest.mark.parametrize("ends", ["returns", "raises"])
+def test_bench_main_puts_sigterms_handler_back_as_it_found_it(monkeypatch, ends):
+    """``main`` in process: the drain handlers its engines chain onto SIGTERM
+    (one a sweep arm, one for the single engine) are gone when it returns or
+    raises, and the handler that was there is there again."""
+    from scaling_tpu.serve import bench
+    from scaling_tpu.serve.engine import install_drain_handler
+
+    drained = []
+
+    def mine(signum, frame):
+        drained.append("mine")
+
+    def arms(argv):
+        for arm in ("arm 0", "arm 3"):
+            install_drain_handler(SimpleNamespace(
+                begin_drain=lambda arm=arm: drained.append(arm)))
+        assert signal.getsignal(signal.SIGTERM) is not mine
+        if ends == "raises":
+            raise RuntimeError("scheduler livelock?")
+        return 0
+
+    monkeypatch.setattr(bench, "_main", arms)
+    found = signal.signal(signal.SIGTERM, mine)
+    try:
+        if ends == "raises":
+            with pytest.raises(RuntimeError, match="livelock"):
+                bench.main([])
+        else:
+            assert bench.main([]) == 0
+        assert signal.getsignal(signal.SIGTERM) is mine
+        signal.raise_signal(signal.SIGTERM)
+        # the engines of a bench long finished are not drained
+        assert drained == ["mine"]
+    finally:
+        signal.signal(signal.SIGTERM, found)

@@ -24,7 +24,8 @@ from scaling_tpu.models.transformer.inference import (
 from scaling_tpu.models.transformer.model import init_model
 from scaling_tpu.nn import hyper_connection
 from scaling_tpu.serve.engine import EngineConfig, ServeEngine
-from scaling_tpu.serve.kvcache import build_layer_views, state_from_views
+
+from . import reference_walk
 
 VOCAB, HIDDEN, STREAMS = 128, 128, 4
 BRANCH_SCALE = 4.0
@@ -122,38 +123,11 @@ def engine_of(inf, **config):
         "enable_prefix_cache": False, **config}))
 
 
-def paged_logits(inf, tokens, chunk, paged_kernel="xla", block_size=4):
-    """Logits of every position of ONE sequence served through the latent
-    pool: ``chunk`` positions a call, the last four one by one (decode rows),
-    the rows' lines written by the calls before; one jitted pass a call
-    shape."""
-    engine = engine_of(inf, num_slots=1, block_size=block_size,
-                       num_blocks=64 // block_size + 1,
-                       max_blocks_per_seq=64 // block_size)
-    state = engine._pool_state()
-    table = jnp.arange(1, 64 // block_size + 1, dtype=jnp.int32)[None]
-
-    @jax.jit
-    def step(params, state, ids, done):
-        n = ids.shape[1]
-        pos = done + jnp.arange(n, dtype=jnp.int32)[None]
-        views = build_layer_views(
-            state, table, done[None], jnp.asarray([n], jnp.int32),
-            kinds=engine.pools.kinds)
-        logits, new_views = inf._run_layers(
-            params, inf._make_batch(ids, pos), views, None,
-            paged_kernel=paged_kernel)
-        return logits[0], state_from_views(new_views)
-
-    out, done = [], 0
-    sizes = [chunk] * ((len(tokens) - 4) // chunk)
-    sizes += [1] * (len(tokens) - sum(sizes))
-    for n in sizes:
-        ids = jnp.asarray(tokens[done:done + n], jnp.int32)[None]
-        logits, state = step(inf.params, state, ids, jnp.int32(done))
-        out.append(np.asarray(logits))
-        done += n
-    return np.concatenate(out)
+def paged_logits(inf, tokens, chunk, paged_kernel="xla"):
+    """``reference_walk.paged_logits`` through a pool of one row of 64 slots."""
+    engine = engine_of(inf, num_slots=1, num_blocks=64 // 4 + 1,
+                       max_blocks_per_seq=64 // 4)
+    return reference_walk.paged_logits(inf, engine, tokens, chunk, paged_kernel)
 
 
 # float32 on both sides: what separates the served form (absorbed attention

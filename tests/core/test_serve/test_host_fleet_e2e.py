@@ -65,7 +65,7 @@ def _env(**extra):
     return env
 
 
-def run_bench(run_dir, *extra, env=None, timeout=420):
+def run_bench(run_dir, *extra, env=None, timeout=120):
     run_dir.mkdir(parents=True, exist_ok=True)
     hosts = run_dir / "hosts.txt"
     hosts.write_text(HOSTSFILE)

@@ -17,6 +17,8 @@ from scaling_tpu.nn.attention import PagedKVCacheView
 from scaling_tpu.nn.mamba import RecurrentStateView
 from scaling_tpu.serve.engine import EngineConfig, ServeEngine
 
+from . import reference_walk
+
 VOCAB = 96
 KINDS = {"M": "mamba", "E": "moe", "*": "attention"}
 PATTERN = "MEM*EM"
@@ -83,16 +85,8 @@ def undisturbed(hybrid):
     no state pool and no batching can have touched. Beside each token, how far
     the runner-up lies below it."""
     requests = prompts((9, 21, 14, 30, 17))
-    want, margins = [], []
-    for p in requests:
-        out = hybrid.generate(p, max_tokens=10, use_cache=False)
-        want.append(out.completion_ids)
-        top2 = np.sort(np.asarray(out.logits), -1)[:, -2:]
-        margins.append(float((top2[:, 1] - top2[:, 0]).min()))
-    # greedy tokens compare exactly only where no near-tie can break the
-    # other way under another order of summation (float32: ~1e-5)
-    assert min(margins) > 1e-3
-    return requests, want
+    return requests, reference_walk.greedy_by_reference(
+        lambda tokens: hybrid.logits(jnp.asarray(tokens))[0], requests, 10)
 
 
 def test_the_state_pool_is_one_line_per_slot_and_mamba_layer(hybrid):
@@ -265,8 +259,9 @@ def test_a_tick_with_more_chunk_rows_than_the_small_width_gathers_runs_whole(
     rides beside six decode rows in the small program. Every request gets the
     uncached forward's tokens."""
     requests = prompts((3, 4, 5, 6, 7, 8, 40), seed=5)
-    want = [hybrid.generate(p, max_tokens=6, use_cache=False).completion_ids
-            for p in requests]
+    want = reference_walk.greedy_by_reference(
+        lambda tokens: hybrid.logits(jnp.asarray(tokens))[0], requests, 6,
+        least_margin=0)
     engine = engine_of(hybrid, num_slots=16, prefill_chunk=32, token_budget=128,
                        max_blocks_per_seq=16, num_blocks=16 * 16 + 1)
     assert engine.config.mixed_widths == (128, 512)

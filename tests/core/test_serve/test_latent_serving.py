@@ -23,6 +23,8 @@ from scaling_tpu.nn.attention import PagedKVCacheView
 from scaling_tpu.serve.engine import EngineConfig, ServeEngine
 from scaling_tpu.serve.kvcache import build_layer_views
 
+from . import reference_walk
+
 VOCAB = 128
 PATTERN = ["latent", "mlp", "latent", "moe", "latent", "moe"]
 LATENT_LAYERS = PATTERN.count("latent")
@@ -104,46 +106,15 @@ def undisturbed(kimi, reference):
     weights = view.reference_weights(kimi.params, ARCH)
     spec = view.reference_spec(ARCH)
     requests = prompts((9, 37, 14, 50, 21), seed=2)
-    want, margins = [], []
-    for p in requests:
-        tokens = list(p)
-        for _ in range(8):
-            logits = np.asarray(ref.forward(weights, jnp.asarray(tokens), spec)[-1])
-            top2 = np.sort(logits)[-2:]
-            margins.append(float(top2[1] - top2[0]))
-            tokens.append(int(logits.argmax()))
-        want.append(tokens[len(p):])
-    assert min(margins) > 1e-3
-    return requests, want
+    return requests, reference_walk.greedy_by_reference(
+        lambda tokens: ref.forward(weights, jnp.asarray(tokens), spec), requests, 8)
 
 
-def paged_logits(inf, tokens, chunk, paged_kernel, block_size=4):
-    """Logits of every position of ONE sequence served through the latent
-    pool: ``chunk`` positions a call (the last ones one by one: decode rows),
-    row-major batches of one row, the rows' lines written by the calls
-    before."""
-    engine = engine_of(inf, num_slots=1, block_size=block_size,
-                       num_blocks=128 // block_size + 1,
-                       max_blocks_per_seq=128 // block_size)
-    state = engine._pool_state()
-    table = jnp.arange(1, 128 // block_size + 1, dtype=jnp.int32)[None]
-    out, done = [], 0
-    sizes = [chunk] * ((len(tokens) - 4) // chunk)
-    sizes += [1] * (len(tokens) - sum(sizes))
-    for n in sizes:
-        ids = jnp.asarray(tokens[done:done + n], jnp.int32)[None]
-        pos = jnp.arange(done, done + n, dtype=jnp.int32)[None]
-        views = build_layer_views(
-            state, table, jnp.asarray([done], jnp.int32),
-            jnp.asarray([n], jnp.int32), kinds=engine.pools.kinds)
-        logits, new_views = inf._run_layers(
-            inf.params, inf._make_batch(ids, pos), views, None,
-            paged_kernel=paged_kernel)
-        from scaling_tpu.serve.kvcache import state_from_views
-        state = state_from_views(new_views)
-        out.append(np.asarray(logits[0]))
-        done += n
-    return np.concatenate(out)
+def paged_logits(inf, tokens, chunk, paged_kernel):
+    """``reference_walk.paged_logits`` through a pool of one row of 128 slots."""
+    engine = engine_of(inf, num_slots=1, num_blocks=128 // 4 + 1,
+                       max_blocks_per_seq=128 // 4)
+    return reference_walk.paged_logits(inf, engine, tokens, chunk, paged_kernel)
 
 
 # float32 on both sides: what separates the absorbed form over the pool from

@@ -70,10 +70,11 @@ def served(engine, requests, max_new):
 @pytest.fixture(scope="module")
 def undisturbed(looped):
     """What ``generate`` (dense caches, one a line, steps unrolled) gives
-    for each prompt alone."""
+    for each prompt, as ONE left-padded batch (a prompt a call would compile
+    the passes a length)."""
     requests = prompts((9, 21, 14, 30, 17))
-    return requests, [looped.generate(p, max_tokens=10).completion_ids
-                      for p in requests]
+    return requests, [out.completion_ids
+                      for out in looped.generate(requests, max_tokens=10)]
 
 
 def test_a_tokens_cache_is_one_line_per_step_and_layer(looped):

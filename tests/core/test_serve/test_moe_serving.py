@@ -141,9 +141,9 @@ def test_packed_ticks_drop_no_assignment_and_count_real_positions_only():
     moved = obs.get_registry().snapshot()["counters"][
         "serve_moe_assignments_total"] - before
     assert moved == sum(n + 4 - 1 for n in lengths) * TOP_K * LAYERS
-    for prompt, seq in zip(prompts, seqs):
-        want = engine.inf.generate(prompt, max_tokens=4, use_cache=True)
-        assert seq.generated == want.completion_ids
+    # (ONE left-padded batch: a prompt a call would compile the pass a length)
+    want = engine.inf.generate(prompts, max_tokens=4, use_cache=True)
+    assert [seq.generated for seq in seqs] == [w.completion_ids for w in want]
 
 
 def test_projection_scope_needs_the_norm_switched_on():

@@ -9,10 +9,9 @@ rows, a short-query path); its cases force several tiles a row by
 shrinking the tile the shapes would derive. ISSUE 41 ran the kernel's
 double buffer across rows (a row's first tile is fetched under the last
 fold of the active row before it); its cases mix rows of 1, 2 and 3
-tiles with inactive rows anywhere, and permute the rows of a call. ISSUE 64
-gave it an optional mask operand (a row's choice of slots: the sparse
-grouped-query mixer's one-token rows); its cases hold the masked call to the
-dense reference under the mask and the maskless call to the operands it had."""
+tiles with inactive rows anywhere, and permute the rows of a call. The mask
+operand (ISSUE 64), groups of 16 and heads narrower than the lanes:
+``test_paged_kernel_masks_and_lanes.py``."""
 
 import numpy as np
 import pytest
@@ -415,202 +414,3 @@ def test_what_crosses_a_grid_step_is_a_function_of_valid_len(
 # ---- ISSUE 64: a mask operand. A row's choice of slots, one lane-dense strip
 # a row; the rows of ONE position fold their group's rows alone and wait for a
 # whole tile at once
-
-def masked_case(group, s):
-    """Four rows over tiles of 8 tokens (2 blocks of 4): a row in its first
-    tile, one that ends mid-block and mid-tile, one on its table's last slot,
-    an inactive one; ``chosen`` keeps about half of every row's slots, the
-    last row's first position among them."""
-    rng = np.random.default_rng(64)
-    case = tiled_case(
-        rng, block_size=4, max_blocks=7, n_kv=2, group=group, s=s,
-        ctx=[3, 18 - s, 28 - s, 0], new_len=[s, s, s, 0])
-    chosen = rng.random((4, 28)) < 0.5
-    chosen[:3, 0] = True
-    return case, jnp.asarray(chosen)
-
-
-@pytest.mark.parametrize("group", [1, 4, 8])
-@pytest.mark.parametrize("s", [1, 5], ids=["one-position", "five-positions"])
-@pytest.mark.parametrize("mask", ["all-true", "random", "empty", "narrower"])
-def test_a_rows_choice_masks_its_slots(monkeypatch, group, s, mask):
-    """Slot ``k`` is visible iff the paged contract admits it AND the row
-    chose it: a choice of everything is the maskless call; a random one is
-    the dense reference under it; a row that chose nothing, or sees nothing,
-    gives zeros; a mask narrower than the table's window chooses nothing past
-    its width."""
-    monkeypatch.setattr(paged_attention, "_TILE_TOKENS", 8)
-    (q, pk, pv, tab, ctx, new), chosen = masked_case(group, s)
-    if mask == "all-true":
-        out = check_rows(q, pk, pv, tab, ctx, new, group,
-                         chosen=jnp.ones_like(chosen))
-        want = check_rows(q, pk, pv, tab, ctx, new, group)
-        assert_real_positions(out, want, new, 1e-6)
-        return
-    if mask == "empty":
-        chosen = chosen.at[1].set(False)
-    if mask == "narrower":      # 13 slots of 28: the rest is not chosen
-        out = check_rows(q, pk, pv, tab, ctx, new, group, chosen=chosen[:, :13])
-        chosen = chosen.at[:, 13:].set(False)
-    else:
-        out = check_rows(q, pk, pv, tab, ctx, new, group, chosen=chosen)
-    ref = dense_reference(q, pk, pv, tab, ctx + new, ctx, group, chosen)
-    live = [0, 2] if mask == "empty" else [0, 1, 2]
-    for row in live:
-        np.testing.assert_allclose(
-            np.asarray(out[row]), np.asarray(ref[row]), atol=1e-5,
-            err_msg=f"row {row}")
-    # the inactive row, and the row that chose nothing
-    assert not np.asarray(out[3]).any()
-    if mask == "empty":
-        assert not np.asarray(out[1]).any()
-
-
-def pallas_operands(**kwargs):
-    """``(prefetched scalars, all operands)`` of the call's ``pallas_call``,
-    read from its jaxpr (no kernel is built: nothing is lowered)."""
-    rng = np.random.default_rng(0)
-    q, pk, pv, tab, ctx, new = tiled_case(
-        rng, block_size=4, max_blocks=4, n_kv=2, group=2, s=1,
-        ctx=[5, 0], new_len=[1, 1])
-    if kwargs.pop("int8", False):
-        (pk, sk), (pv, sv) = kv_quantize_int8(pk), kv_quantize_int8(pv)
-        kwargs.update(scale_k=sk, scale_v=sv)
-    jaxpr = jax.make_jaxpr(lambda *a: paged_decode_attention(
-        *a, sm_scale=0.25, num_repeat_kv=2, interpret=True, **kwargs))(
-            q, pk, pv, tab, ctx + new, ctx)
-    calls = []
-
-    def find(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                calls.append(eqn)
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                find(sub)
-
-    find(jaxpr.jaxpr)
-    (call,) = calls
-    return call.params["grid_mapping"].num_index_operands, len(call.invars)
-
-
-def test_a_maskless_call_has_the_operands_it_had():
-    """Five prefetched scalars (table, valid_len, base, tiles before, next
-    row), the queries and two pools; an int8 pool adds its two strips of
-    scales, a mask ONE strip more, and nothing else."""
-    assert pallas_operands() == (5, 8)
-    assert pallas_operands(int8=True) == (5, 10)
-    assert pallas_operands(chosen=jnp.ones((2, 16), bool)) == (5, 9)
-
-
-def test_group_16_over_2_kv_heads_agrees_with_the_gather_formulation():
-    """Nemotron-3-Nano's attention: 32 query heads over 2 KV heads x 128 (GQA
-    group 16; 2 KV heads are under a sublane tile, one packed word a token),
-    through ``ParallelSelfAttention._paged_attention`` on a mixed tick (a
-    decode row, a chunk row, an empty one): the Pallas kernel against the
-    ``'xla'`` gather formulation it is held to. bf16 inputs, float32
-    accumulation on both sides: the outputs differ by the rounding of the
-    probabilities to bf16 before PV (2**-9 of values under 1 in magnitude)."""
-    from scaling_tpu.nn.attention import PagedKVCacheView, ParallelSelfAttention
-    from scaling_tpu.nn.base_layer import ForwardContext
-
-    hidden, heads, kv_heads, head_dim = 96, 32, 2, 128
-    attn = ParallelSelfAttention(
-        hidden, heads, num_kv_heads=kv_heads, head_dim=head_dim, qkv_in_one=False,
-        bias=False, dtype=jnp.bfloat16, relative_position_embedding_type="none")
-    params = attn.init(jax.random.PRNGKey(0))
-    assert params["query"]["weight"].shape == (hidden, heads * head_dim)
-    assert params["key"]["weight"].shape == (hidden, kv_heads * head_dim)
-    assert params["dense"]["weight"].shape == (heads * head_dim, hidden)
-    rows, s, block, max_blocks = 3, 8, 16, 4
-    x = jax.random.normal(jax.random.PRNGKey(1), (rows, s, hidden), jnp.bfloat16)
-    pool = jax.random.normal(
-        jax.random.PRNGKey(2), (rows * max_blocks + 1, block, kv_heads, head_dim),
-        jnp.bfloat16)
-    view = PagedKVCacheView(
-        pool_k=pool, pool_v=pool[::-1],
-        block_table=1 + jnp.arange(rows * max_blocks, dtype=jnp.int32).reshape(rows, -1),
-        context_len=jnp.asarray([37, 16, 0], jnp.int32),
-        new_len=jnp.asarray([1, 8, 0], jnp.int32))
-    outs = {}
-    for kernel in ("pallas", "xla"):
-        out, new_view = attn(params, x, ForwardContext(paged_kernel=kernel),
-                             kv_cache=view)
-        outs[kernel] = np.asarray(out.astype(jnp.float32))
-        assert new_view.pool_k.shape == pool.shape
-    for row, n in ((0, 1), (1, 8)):
-        np.testing.assert_allclose(outs["pallas"][row, :n], outs["xla"][row, :n],
-                                   atol=2e-2, rtol=2e-2)
-        assert np.abs(outs["xla"][row, :n]).max() > 0.1
-
-
-@pytest.mark.parametrize("h,n_kv,n_rep", [(64, 8, 4), (64, 2, 1), (32, 4, 2)],
-                         ids=["lfm2-32q8kv-h64", "h64-group1", "h32-four-a-row"])
-@pytest.mark.parametrize("s", [1, 8])
-def test_heads_narrower_than_the_lanes_share_a_lane_row(h, n_kv, n_rep, s):
-    """LFM2's attention has heads of 64 where the kernel's strided read wants
-    rows of 128 lanes: the pool is MADE with two KV heads side by side in a
-    lane row (``packed_kv_dims``), each query widened with zeros outside its
-    own KV head's lanes. A decode row, a chunk row and an empty one against
-    the dense window over the same values head by head."""
-    rng = np.random.default_rng(h + s)
-    rows, blocks = 3, 6
-    pool_k = jnp.asarray(rng.normal(size=(rows * blocks + 1, BS, n_kv, h)), jnp.float32)
-    pool_v = jnp.asarray(rng.normal(size=(rows * blocks + 1, BS, n_kv, h)), jnp.float32)
-    tab = 1 + jnp.arange(rows * blocks, dtype=jnp.int32).reshape(rows, blocks)
-    q = jnp.asarray(rng.normal(size=(rows, s, n_kv * n_rep, h)), jnp.float32)
-    valid = jnp.asarray([21, s, 0], jnp.int32)
-    base = jnp.asarray([21 - s, 0, 0], jnp.int32)
-    packed = paged_attention.packed_kv_dims(n_kv, h)
-    assert packed == (n_kv * h // 128, 128)
-    assert paged_attention.packed_kv_dims(8, 128) == (8, 128)
-    assert paged_attention.packed_kv_dims(3, 64) == (3, 64)    # no whole lane rows
-    assert paged_attention.packed_kv_dims(4, 48) == (4, 48)
-    got = paged_decode_attention(
-        q, pool_k.reshape(*pool_k.shape[:2], *packed),
-        pool_v.reshape(*pool_v.shape[:2], *packed), tab, valid, base,
-        sm_scale=h ** -0.5, num_repeat_kv=n_rep)
-    want = dense_reference(q, pool_k, pool_v, tab, valid, base, n_rep)
-    np.testing.assert_allclose(np.asarray(got[:2]), np.asarray(want[:2]), atol=2e-5)
-    assert np.isfinite(np.asarray(got)).all()
-    # the widened queries: a head's own lanes hold it, the others zeros
-    wide = paged_attention._pack_queries(q, n_kv, 128 // h)
-    assert wide.shape == (rows, s, n_kv * n_rep, 128)
-    assert int((wide != 0).sum()) == int((q != 0).sum())
-
-
-def test_a_pool_of_narrow_heads_is_made_and_written_packed():
-    """``init_pools`` makes a native pool of 64-wide heads two a lane row, the
-    ONE writer regroups a token's K and V to it, and both formulations of
-    the paged branch read it (``ParallelSelfAttention._paged_attention``)."""
-    from scaling_tpu.nn.attention import PagedKVCacheView, ParallelSelfAttention
-    from scaling_tpu.nn.base_layer import ForwardContext
-    from scaling_tpu.nn.norm import NormType
-
-    hidden, heads, kv_heads, head_dim = 96, 8, 4, 64
-    attn = ParallelSelfAttention(
-        hidden, heads, num_kv_heads=kv_heads, head_dim=head_dim, qkv_in_one=False,
-        bias=False, key_query_norm=True, norm_type=NormType.RMS,
-        relative_position_embedding_type="none")
-    params = attn.init(jax.random.PRNGKey(0))
-    rows, s, block, max_blocks = 3, 8, 4, 6
-    x = jax.random.normal(jax.random.PRNGKey(1), (rows, s, hidden))
-    pool = jax.random.normal(
-        jax.random.PRNGKey(2), (rows * max_blocks + 1, block, kv_heads, head_dim))
-    table = 1 + jnp.arange(rows * max_blocks, dtype=jnp.int32).reshape(rows, -1)
-    lens = dict(context_len=jnp.asarray([13, 4, 0], jnp.int32),
-                new_len=jnp.asarray([1, 8, 0], jnp.int32))
-    plain = PagedKVCacheView(pool_k=pool, pool_v=pool[::-1], block_table=table, **lens)
-    packed_dims = (*pool.shape[:2], 2, 128)
-    packed = plain._replace(pool_k=pool.reshape(packed_dims),
-                            pool_v=pool[::-1].reshape(packed_dims))
-    outs = {}
-    for name, view, kernel in (("plain-xla", plain, "xla"), ("packed-xla", packed, "xla"),
-                               ("packed-pallas", packed, "pallas")):
-        out, new_view = attn(params, x, ForwardContext(paged_kernel=kernel), kv_cache=view)
-        assert new_view.pool_k.shape == view.pool_k.shape
-        outs[name] = (np.asarray(out), np.asarray(new_view.pool_k).reshape(pool.shape))
-    for name in ("packed-xla", "packed-pallas"):
-        for row, n in ((0, 1), (1, 8)):
-            np.testing.assert_allclose(outs[name][0][row, :n],
-                                       outs["plain-xla"][0][row, :n], atol=2e-5)
-        np.testing.assert_array_equal(outs[name][1], outs["plain-xla"][1])

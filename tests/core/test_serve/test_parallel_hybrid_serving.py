@@ -25,6 +25,8 @@ from scaling_tpu.nn.mamba import RecurrentStateView
 from scaling_tpu.serve.engine import EngineConfig, ServeEngine
 from scaling_tpu.serve.kvcache import build_layer_views, line_layers
 
+from . import reference_walk
+
 VOCAB, LAYERS = 96, 3
 TOPOLOGY = {"model_parallel_size": 1, "pipe_parallel_size": 1,
             "data_parallel_size": 1, "micro_batch_size": 1,
@@ -113,19 +115,9 @@ def undisturbed(falcon, reference):
     weights = view.reference_weights(falcon.params, ARCH)
     spec = view.reference_spec(ARCH)
     requests = prompts((9, 21, 14, 30, 17))
-    want, margins = [], []
-    for p in requests:
-        tokens = list(p)
-        for _ in range(10):
-            logits = np.asarray(ref.forward(weights, jnp.asarray(tokens), spec)[-1])
-            top2 = np.sort(logits)[-2:]
-            margins.append(float(top2[1] - top2[0]))
-            tokens.append(int(logits.argmax()))
-        want.append(tokens[len(p):])
-    # greedy tokens compare exactly only where no near-tie can break the
-    # other way under another order of summation (float32: ~1e-5)
-    assert min(margins) > 1e-4
-    return requests, want
+    return requests, reference_walk.greedy_by_reference(
+        lambda tokens: ref.forward(weights, jnp.asarray(tokens), spec), requests, 10,
+        least_margin=1e-4)
 
 
 def test_every_layer_keeps_a_paged_line_and_a_recurrent_line_a_slot(falcon):
@@ -372,8 +364,9 @@ def test_a_short_mixed_run_advances_its_rows_in_the_forms_it_did(falcon, tmp_pat
     tick and in the counters, and every request gets the uncached forward's
     tokens."""
     requests = prompts((3, 4, 5, 6, 7, 8, 40), seed=5)
-    want = [falcon.generate(p, max_tokens=6, use_cache=False).completion_ids
-            for p in requests]
+    want = reference_walk.greedy_by_reference(
+        lambda tokens: falcon.logits(jnp.asarray(tokens))[0], requests, 6,
+        least_margin=0)
     engine = engine_of(falcon, num_slots=16, prefill_chunk=32, token_budget=128,
                        max_blocks_per_seq=16, num_blocks=16 * 16 + 1)
     assert engine.config.mixed_widths == (128, 512)

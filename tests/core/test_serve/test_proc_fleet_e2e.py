@@ -54,7 +54,7 @@ def _env(**extra):
     return env
 
 
-def run_bench(run_dir, *extra, env=None, timeout=420):
+def run_bench(run_dir, *extra, env=None, timeout=120):
     run_dir.mkdir(parents=True, exist_ok=True)
     cmd = [sys.executable, "-m", "scaling_tpu.serve", "bench", *SHAPE,
            "--run-dir", str(run_dir), "--json", str(run_dir / "stats.json"),

@@ -21,7 +21,9 @@ from scaling_tpu.models.transformer.inference import TransformerInferenceModule
 from scaling_tpu.models.transformer.model import init_model
 from scaling_tpu.nn import sparse_latent_attention
 from scaling_tpu.serve.engine import EngineConfig, ServeEngine
-from scaling_tpu.serve.kvcache import build_layer_views, state_from_views
+from scaling_tpu.serve.kvcache import build_layer_views
+
+from . import reference_walk
 
 VOCAB = 128
 TOPK = 16
@@ -111,45 +113,15 @@ def undisturbed(dsv32, reference):
     """Each prompt alone, greedy, by the plain REFERENCE's full forward: the
     tokens, and how far the runner-up lies below each."""
     requests = prompts((40, 97, 61, 200, 130), seed=2)
-    want, margins = [], []
-    for p in requests:
-        tokens = list(p)
-        for _ in range(6):
-            logits = reference_logits(dsv32, reference, tokens)[-1]
-            top2 = np.sort(logits)[-2:]
-            margins.append(float(top2[1] - top2[0]))
-            tokens.append(int(logits.argmax()))
-        want.append(tokens[len(p):])
-    assert min(margins) > 1e-3
-    return requests, want
+    return requests, reference_walk.greedy_by_reference(
+        lambda tokens: reference_logits(dsv32, reference, tokens), requests, 6)
 
 
-def paged_logits(inf, tokens, chunk, paged_kernel, block_size=4, chosen_out=None):
-    """Logits of every position of ONE sequence served through the pool:
-    ``chunk`` positions a call (the last ones one by one: decode rows),
-    row-major batches of one row. ``chosen_out``, a list, takes what
-    ``_choose_paged`` returned, a call and layer at a time."""
-    engine = engine_of(inf, num_slots=1, block_size=block_size,
-                       num_blocks=WINDOW // block_size + 1,
-                       max_blocks_per_seq=WINDOW // block_size)
-    state = engine._pool_state()
-    table = jnp.arange(1, WINDOW // block_size + 1, dtype=jnp.int32)[None]
-    out, done = [], 0
-    sizes = [chunk] * ((len(tokens) - 4) // chunk)
-    sizes += [1] * (len(tokens) - sum(sizes))
-    for n in sizes:
-        ids = jnp.asarray(tokens[done:done + n], jnp.int32)[None]
-        pos = jnp.arange(done, done + n, dtype=jnp.int32)[None]
-        views = build_layer_views(
-            state, table, jnp.asarray([done], jnp.int32),
-            jnp.asarray([n], jnp.int32), kinds=engine.pools.kinds)
-        logits, new_views = inf._run_layers(
-            inf.params, inf._make_batch(ids, pos), views, None,
-            paged_kernel=paged_kernel)
-        state = state_from_views(new_views)
-        out.append(np.asarray(logits[0]))
-        done += n
-    return np.concatenate(out)
+def paged_logits(inf, tokens, chunk, paged_kernel):
+    """``reference_walk.paged_logits`` through a pool of one row's window."""
+    engine = engine_of(inf, num_slots=1, num_blocks=WINDOW // 4 + 1,
+                       max_blocks_per_seq=WINDOW // 4)
+    return reference_walk.paged_logits(inf, engine, tokens, chunk, paged_kernel)
 
 
 # float32 on both sides: what separates the absorbed form over gathered lines
@@ -189,14 +161,14 @@ def test_the_program_chooses_the_references_lines(dsv32, sequence, monkeypatch):
 
     def recording(self, scores, visible, k):
         mask = choose(self, scores, visible, k)
-        masks.append(np.asarray(mask))
+        # in the order the program runs them: a call, then a layer
+        jax.debug.callback(lambda m: masks.append(np.asarray(m)), mask, ordered=True)
         return mask
 
     monkeypatch.setattr(
         sparse_latent_attention.SparseLatentSelfAttention, "_chosen", recording)
     sizes = [8] * 5 + [1] * 4
-    with jax.disable_jit():
-        paged_logits(dsv32, tokens[:44], 8, "pallas")
+    paged_logits(dsv32, tokens[:44], 8, "pallas")
     # a call chooses once a layer: a token's in a pass over the one-token
     # rows, a chunk's in its own walk (no pass is made for a call without
     # one-token rows)
@@ -332,9 +304,9 @@ def test_a_tie_over_room_is_filled_by_position_and_counted(dsv32, reference, tmp
         **dsv32.params, first: {**layer, "mixer": {
             **layer["mixer"], "index_w_proj": {"weight": jnp.zeros_like(weight)}}}})
     prompt = prompts((20,), seed=11)[0]
-    want = list(prompt)
-    for _ in range(3):
-        want.append(int(reference_logits(tied, reference, want)[-1].argmax()))
+    want = prompt + reference_walk.greedy_by_reference(
+        lambda tokens: reference_logits(tied, reference, tokens), [prompt], 3,
+        least_margin=0)[0]
     for inf, tie_breaks in ((tied, [0, 0, 1, 1, 1]), (dsv32, [0] * 5)):
         engine = engine_of(inf, num_slots=1)
         obs.start_capture(str(tmp_path / str(sum(tie_breaks))))
@@ -464,5 +436,6 @@ def test_uncached_generate_is_the_references_full_forward(dsv32, reference):
     want = reference_logits(dsv32, reference, tokens)
     batch = dsv32._make_batch(jnp.asarray(tokens, jnp.int32)[None],
                               jnp.arange(50, dtype=jnp.int32)[None])
-    got = np.asarray(dsv32._run_layers(dsv32.params, batch, None, None)[0][0])
+    got = np.asarray(jax.jit(
+        lambda p: dsv32._run_layers(p, batch, None, None)[0])(dsv32.params)[0])
     np.testing.assert_allclose(got, want, atol=LOGIT_ATOL)

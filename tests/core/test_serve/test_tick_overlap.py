@@ -37,9 +37,10 @@ def toy():
 
 @pytest.fixture(scope="module")
 def want(toy):
-    """``generate()`` on each prompt alone, two tokens past MAX_NEW."""
-    return [toy.generate(p, max_tokens=MAX_NEW + 2,
-                         use_cache=True).completion_ids for p in PROMPTS]
+    """``generate()`` on the prompts (ONE left-padded batch: a prompt a call
+    would compile the passes a length), two tokens past MAX_NEW."""
+    return [out.completion_ids for out in toy.generate(
+        PROMPTS, max_tokens=MAX_NEW + 2, use_cache=True)]
 
 
 def make_engine(inf, synchronous=False, **config):

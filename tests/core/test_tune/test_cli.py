@@ -16,7 +16,7 @@ from scaling_tpu.tune import cli
 REPO = Path(__file__).resolve().parents[3]
 
 
-def run_cli(*args, timeout=240):
+def run_cli(*args, timeout=120):
     return subprocess.run(
         [sys.executable, "-m", "scaling_tpu.tune", *args],
         cwd=REPO,
@@ -186,7 +186,7 @@ def test_best_layout_runs_through_dryrun_entrypoint(report):
     }
     p = subprocess.run(
         [sys.executable, "-c", code], cwd=REPO, env=env,
-        capture_output=True, text=True, timeout=420,
+        capture_output=True, text=True, timeout=120,
     )
     assert p.returncode == 0, p.stdout[-2000:] + p.stderr[-2000:]
     assert "dryrun ok" in p.stdout

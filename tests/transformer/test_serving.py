@@ -58,11 +58,9 @@ def trained_inference(tmp_path_factory):
 
 @pytest.fixture(scope="module")
 def reference_completions(trained_inference):
-    return [
-        trained_inference.generate(p, max_tokens=MAX_NEW,
-                                   use_cache=True).completion_ids
-        for p in PROMPTS
-    ]
+    # ONE left-padded batch: a prompt a call would compile the passes a length
+    return [out.completion_ids for out in trained_inference.generate(
+        PROMPTS, max_tokens=MAX_NEW, use_cache=True)]
 
 
 def run_engine(inf, prompts, **cfg_overrides):
@@ -221,11 +219,8 @@ def test_shared_prefix_reuse_is_token_exact_and_skips_prefill(
     tails = [[1, 2], [3, 4], [5, 6, 7], [8], [9, 10], [11, 12], [13],
              [14, 15]]
     prompts = [prefix + t for t in tails]
-    refs = [
-        trained_inference.generate(p, max_tokens=4,
-                                   use_cache=True).completion_ids
-        for p in prompts
-    ]
+    refs = [out.completion_ids for out in trained_inference.generate(
+        prompts, max_tokens=4, use_cache=True)]
     engine = ServeEngine(trained_inference, EngineConfig(
         num_slots=8, block_size=4, num_blocks=64, max_blocks_per_seq=8,
         token_budget=64, prefill_chunk=4,
@@ -258,11 +253,8 @@ def test_prefix_hit_survives_preemption_and_stays_exact(trained_inference):
     registered blocks) and still emits the exact greedy output."""
     prefix = [(i % 17) + 1 for i in range(12)]
     prompts = [prefix + [1, 2], prefix + [3, 4], prefix + [5, 6]]
-    refs = [
-        trained_inference.generate(p, max_tokens=4,
-                                   use_cache=True).completion_ids
-        for p in prompts
-    ]
+    refs = [out.completion_ids for out in trained_inference.generate(
+        prompts, max_tokens=4, use_cache=True)]
     engine = ServeEngine(trained_inference, EngineConfig(
         num_slots=4, block_size=4, num_blocks=11, max_blocks_per_seq=8,
         token_budget=64, prefill_chunk=4,
@@ -725,11 +717,8 @@ def test_completed_slots_are_recycled(trained_inference):
     """More concurrent requests than decode slots: completions must free
     slots that later admissions reuse within one engine run."""
     prompts = [[(3 * i + j) % 17 + 1 for j in range(3 + i)] for i in range(6)]
-    refs = [
-        trained_inference.generate(p, max_tokens=4,
-                                   use_cache=True).completion_ids
-        for p in prompts
-    ]
+    refs = [out.completion_ids for out in trained_inference.generate(
+        prompts, max_tokens=4, use_cache=True)]
     engine = ServeEngine(trained_inference, EngineConfig(
         num_slots=2, block_size=4, num_blocks=32, max_blocks_per_seq=8,
         token_budget=64,
