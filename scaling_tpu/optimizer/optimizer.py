@@ -11,9 +11,12 @@ TPU-native re-design: the whole step is one pure function inside jit. The
 reference's ZeRO-1 machinery — NCCL-aligned flat buffers, DP partitions,
 grad copy prequel, all-gather sequel (parameter_group.py:26-472) — is
 replaced by sharding the fp32 master + moment trees over the ``data`` mesh
-axis with ``NamedSharding``; XLA inserts the reduce-scatter/all-gather pair
-around the (sharded) update. Overflow skip uses ``jnp.where`` on the whole
-state instead of aborting the step.
+axis with ``NamedSharding``. The compute copy lives in the same placement
+between steps: ``step`` constrains each gradient to it (a reduce-scatter) and
+returns the new copy as the shard it was cast from; the NEXT step gathers it
+once on entry (``gather_params``), under the forward, where the reference
+gathers at the end of ``step()`` with nothing left to hide it. Overflow skip
+uses ``jnp.where`` on the whole state instead of aborting the step.
 """
 
 from __future__ import annotations
@@ -205,14 +208,88 @@ class Optimizer:
             )
         return NamedSharding(self.topology.mesh, P(*spec))
 
-    def _param_sharding(self, meta: ParamMeta, shape: tuple):
-        """Where the compute copy of a parameter lives: its own spec, plus
-        the data axis only under ZeRO stage 3 (``shard_params``'s rule)."""
+    def _gathers_on_entry(self) -> bool:
+        """ZeRO stage 1 over a data axis wider than 1: the compute copy
+        crosses the step boundary as the data shard its master lives on and
+        the step gathers it once, on entry. (Stage 3 keeps it so as well,
+        and lets GSPMD gather it at each use.)"""
+        return (
+            self.topology is not None
+            and self.config.zero
+            and self.config.zero_stage == 1
+            and self.topology.data_parallel_size > 1
+        )
+
+    def _between_steps_sharding(self, leaf, meta: ParamMeta, gi: int):
+        """Where an optimized leaf's compute copy lives between steps under
+        ZeRO (the masters' placement); None for a leaf that stays where it
+        is: no mesh, no ZeRO, frozen (no master)."""
+        if self.topology is None or not self.config.zero or gi < 0:
+            return None
+        return self._master_sharding(meta, leaf.shape)
+
+    def place_params(self, params: Any, donate: bool = False) -> Any:
+        """``params`` as a ZeRO step takes and returns them. A leaf already
+        in the masters' placement passes untouched; those placed by their
+        own spec are put there together, by ONE jitted identity (every chip
+        keeps a slice of what it holds: no traffic; leaf by leaf through
+        ``device_put`` the 116 leaves of a 7B cell took 11 s on four chips),
+        so that a caller who placed the weights without the data axis still
+        meets the ONE program the step lowers. ``donate`` deletes the leaves
+        that were moved, as the step's donation would have: both copies and
+        a step's temporaries do not fit beside a 7B cell's state. Shapes
+        (``ShapeDtypeStruct``) are re-labelled, for ``lower``."""
+        leaves, td = jax.tree.flatten(params)
+        move, targets = [], []
+        for i, (p, m, gi) in enumerate(
+            zip(leaves, self._meta_leaves, self._group_index)
+        ):
+            sh = self._between_steps_sharding(p, m, gi)
+            if sh is None:
+                continue
+            if isinstance(p, jax.ShapeDtypeStruct):
+                leaves[i] = jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=sh)
+            elif not (
+                isinstance(p, jax.Array) and p.sharding.is_equivalent_to(sh, p.ndim)
+            ):
+                move.append(i)
+                targets.append(sh)
+        if move:
+            sources = [leaves[i] for i in move]
+            moved = jax.jit(lambda xs: xs, out_shardings=targets)(sources)
+            for i, new, old in zip(move, moved, sources):
+                leaves[i] = new
+                if donate and isinstance(old, jax.Array):
+                    old.delete()
+        return jax.tree.unflatten(td, leaves)
+
+    def gather_params(self, params: Any) -> tuple[Any, int]:
+        """Inside a jitted step, before anything consumes ``params``: ZeRO-1's
+        ONE gather of each optimized leaf from the masters' placement to its
+        own spec. Said outside ``value_and_grad`` it happens once a step (the
+        backward reads the gathered copy), bf16 on the wire, and depends on
+        nothing but its consumer, so the compiler can run a layer's gather
+        under the layers before it. Returns the gathered tree and how many
+        leaves it gathered (``step`` scatters as many gradients back): 0
+        without ZeRO-1 over a data axis."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        if self.config.zero and self.config.zero_stage == 3:
-            return self._master_sharding(meta, shape)
-        return NamedSharding(self.topology.mesh, P(*meta.partition_spec))
+        if not self._gathers_on_entry():
+            return params, 0
+        leaves, td = jax.tree.flatten(params)
+        gathered = 0
+        for i, (p, m, gi) in enumerate(
+            zip(leaves, self._meta_leaves, self._group_index)
+        ):
+            own = NamedSharding(self.topology.mesh, P(*m.partition_spec))
+            # frozen, or no dimension the data axis divides: nothing to move
+            if gi < 0 or self._master_sharding(m, p.shape).is_equivalent_to(
+                own, p.ndim
+            ):
+                continue
+            leaves[i] = jax.lax.with_sharding_constraint(p, own)
+            gathered += 1
+        return jax.tree.unflatten(td, leaves), gathered
 
     def abstract_state(self, params: Any) -> OptimizerState:
         """``init_state``'s output as ShapeDtypeStructs with the ZeRO
@@ -352,6 +429,20 @@ class Optimizer:
     ) -> tuple[Any, OptimizerState, OptimizerStepOutput]:
         c = self.config
         g_leaves = jax.tree.leaves(grads)
+        if self._gathers_on_entry():
+            # each gradient onto the shard that consumes it, BEFORE the
+            # overflow check and the norm read it: a reduce-scatter over the
+            # data axis, then a sum over the shard and a scalar all-reduce,
+            # never an all-reduce onto every data rank that then uses its
+            # 1/dp. (The chip's compiler derived as much from the masters'
+            # placement, fused as ``all-reduce-scatter``; said here it does
+            # not hang on what propagation finds.)
+            g_leaves = [
+                jax.lax.with_sharding_constraint(g, self._master_sharding(m, g.shape))
+                if gi >= 0
+                else g
+                for g, m, gi in zip(g_leaves, self._meta_leaves, self._group_index)
+            ]
         p_leaves = jax.tree.leaves(params)
         m_leaves = jax.tree.leaves(state.master)
         a_leaves = jax.tree.leaves(state.exp_avg)
@@ -361,7 +452,7 @@ class Optimizer:
         # applies under dynamic loss scaling (reference semantics: without a
         # scaler a non-finite grad propagates loudly instead of freezing the
         # run); the raw flag is always surfaced in the output.
-        raw_overflow = has_inf_or_nan_tree(grads)
+        raw_overflow = has_inf_or_nan_tree(g_leaves)
         overflow = raw_overflow if c.loss_scaler.enable else jnp.asarray(False)
         scaler_state, scaler_out = self.loss_scaler.step(state.loss_scaler, overflow)
 
@@ -432,16 +523,16 @@ class Optimizer:
             new_a.append(a2)
             new_s.append(s2)
             p2 = m2.astype(compute_dtype or p.dtype)
-            if self.topology is not None and c.zero:
-                # ZeRO-1's parameter re-gather, said out loud: without it
-                # the compiler leaves the new compute copy sharded over the
-                # data axis like the master it was cast from, the step's
-                # outputs stop matching its inputs' shardings, and step 2
-                # compiles a second executable (47 s on four chips,
-                # PERF.md "First on-chip run")
-                p2 = jax.lax.with_sharding_constraint(
-                    p2, self._param_sharding(m, p2.shape)
-                )
+            between_steps = self._between_steps_sharding(p2, m, gi)
+            if between_steps is not None:
+                # the new compute copy stays on the shard it was cast from
+                # (no traffic) and crosses the step boundary there: the next
+                # step's entry gathers it (``gather_params``), where there is
+                # compute to hide the gather under. Said out loud so that the
+                # step's outputs are placed as ``place_params`` places its
+                # inputs, whatever the compiler would have chosen: another
+                # placement is a second executable at step 2
+                p2 = jax.lax.with_sharding_constraint(p2, between_steps)
             new_p.append(p2)
 
         unflatten = lambda ls: jax.tree.unflatten(jax.tree.structure(params), ls)  # noqa: E731

@@ -127,6 +127,29 @@ def _del_path(tree: dict, path: str) -> dict:
     return tree
 
 
+def _jit_train_step(step: Callable, optimizer: Optimizer, donate: bool) -> Callable:
+    """``jax.jit(step)`` behind ``optimizer.place_params``, called and lowered
+    like the jitted function: ``step(params, opt_state, micro_batches, key)``
+    and ``step.lower(...)``.
+
+    Under ZeRO the step takes and returns the compute copy in the masters'
+    placement. A caller may hold weights placed by their own specs alone (the
+    first call after ``shard_params``, a checkpoint just loaded). ``jit`` would
+    lower a second program for those, so the weights are placed here, before
+    the ONE program: a local slice on the first call, a comparison of
+    shardings a leaf on every later one."""
+    jitted = jax.jit(step, donate_argnums=(0, 1) if donate else ())
+    if optimizer.topology is None or not optimizer.config.zero:
+        return jitted  # nothing to place: the jitted function itself
+
+    def placed_step(params, *rest):
+        return jitted(optimizer.place_params(params, donate=donate), *rest)
+
+    placed_step.lower = lambda params, *rest: jitted.lower(
+        optimizer.place_params(params), *rest)
+    return placed_step
+
+
 @dataclass
 class TiedInfo:
     key: str
@@ -453,11 +476,32 @@ class ParallelModule:
         # says otherwise, and where SP is off or a constraint was kept
         manual_boundaries = get_registry().gauge("train_sp_manual_boundaries")
         manual_boundaries.set(0)
+        # ZeRO-1's data-axis traffic (optimizer.py): leaves gathered once on
+        # entry, gradients reduce-scattered onto the masters' placement; set
+        # when the step is traced, 0 where ZeRO is off or the data axis is 1
+        entry_gathers = get_registry().gauge("train_zero_entry_gathers")
+        scattered_grads = get_registry().gauge("train_zero_scattered_grads")
+        entry_gathers.set(0)
+        scattered_grads.set(0)
 
         scaler_enabled = optimizer.config.loss_scaler.enable
 
+        def log_traced(zero_leaves: int):
+            """Called while a step is traced, after the forward: what the
+            traced program does, on one line."""
+            entry_gathers.set(zero_leaves)
+            scattered_grads.set(zero_leaves)
+            logger.info(
+                f"train step: {int(manual_boundaries.value)} tensor-parallel "
+                "region(s) entered through explicit collectives; ZeRO-1 over "
+                f"the data axis: {zero_leaves} leaves gathered on entry "
+                f"(train_zero_entry_gathers), {zero_leaves} gradients "
+                "reduce-scattered onto the masters' placement "
+                "(train_zero_scattered_grads)")
+
         if self._has_spatial_pp:
-            return self._build_spatial_train_step(optimizer, loss_function, donate)
+            return self._build_spatial_train_step(
+                optimizer, loss_function, donate, log_traced)
 
         def microbatch_loss(params, mb, dropout_key, loss_scale):
             # PEFT: frozen leaves produce constant-zero grads, so XLA drops
@@ -474,6 +518,7 @@ class ParallelModule:
 
         def step(params, opt_state, micro_batches, dropout_key):
             loss_scale = opt_state.loss_scaler.current_scale
+            params, zero_leaves = optimizer.gather_params(params)
 
             grad_fn = jax.value_and_grad(microbatch_loss, has_aux=True)
 
@@ -500,9 +545,7 @@ class ParallelModule:
                 loss_scale,
             )
             zero_metrics = jax.tree.map(lambda m: jnp.zeros((), jnp.float32), metrics0)
-            logger.info(
-                f"train step: {int(manual_boundaries.value)} tensor-parallel "
-                "region(s) entered through explicit collectives")
+            log_traced(zero_leaves)
 
             if gas == 1:
                 (grads, loss_sum, metrics_sum), _ = body(
@@ -522,10 +565,11 @@ class ParallelModule:
             metrics = jax.tree.map(lambda m: m / gas, metrics_sum)
             return new_params, new_opt_state, loss, metrics, opt_out
 
-        return jax.jit(step, donate_argnums=(0, 1) if donate else ())
+        return _jit_train_step(step, optimizer, donate)
 
     def _build_spatial_train_step(
-        self, optimizer, loss_function: Callable, donate: bool
+        self, optimizer, loss_function: Callable, donate: bool,
+        log_traced: Callable,
     ) -> Callable:
         """Train step for pipe_parallel_size > 1: all micro-batches flow
         through the stage-stacked body at once (spatial GPipe); edge layers
@@ -607,15 +651,17 @@ class ParallelModule:
 
         def step(params, opt_state, micro_batches, dropout_key):
             loss_scale = opt_state.loss_scaler.current_scale
+            params, zero_leaves = optimizer.gather_params(params)
             (_, (loss, metrics)), grads = jax.value_and_grad(
                 spatial_loss, has_aux=True
             )(params, micro_batches, dropout_key, loss_scale)
+            log_traced(zero_leaves)
             new_params, new_opt_state, opt_out = optimizer.step(
                 params, grads, opt_state, compute_dtype=self.compute_dtype
             )
             return new_params, new_opt_state, loss, metrics, opt_out
 
-        return jax.jit(step, donate_argnums=(0, 1) if donate else ())
+        return _jit_train_step(step, optimizer, donate)
 
     def build_eval_step(self, loss_function: Callable) -> Callable:
         def eval_step(params, micro_batch):

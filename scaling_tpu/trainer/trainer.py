@@ -330,7 +330,11 @@ class BaseTrainer:
         )
         opt_cfg = self.optimizer.config
         fsdp = opt_cfg.zero and opt_cfg.zero_stage == 3
-        self.params = self.module.shard_params(params, fsdp_data_axis=fsdp)
+        # under ZeRO the compute copy lives between steps where its master
+        # does (stage 1: the step gathers it on entry; stage 3: at each use),
+        # so a checkpoint loaded below lands there too (``ckpt_unview``)
+        self.params = self.optimizer.place_params(
+            self.module.shard_params(params, fsdp_data_axis=fsdp))
         self.opt_state = self.optimizer.init_state(self.params)
 
         loaded = False

@@ -233,8 +233,14 @@ def analytic_collectives(model: ModelSpec, layout: Layout) -> List[dict]:
             recs.append({"op": "all-gather", "axis": "data", "count": 2,
                          "bytes": 2 * params_shard * BF16})
         else:
-            recs.append({"op": "all-reduce", "axis": "data", "count": 1,
-                         "bytes": params_shard * F32})
+            # ZeRO-1 (optimizer/optimizer.py): each gradient is
+            # reduce-scattered onto the shard whose master consumes it, and
+            # the updated compute copy is gathered once, on the next step's
+            # entry; both in the compute type (bf16 on the wire)
+            recs.append({"op": "reduce-scatter", "axis": "data", "count": 1,
+                         "bytes": params_shard * BF16})
+            recs.append({"op": "all-gather", "axis": "data", "count": 1,
+                         "bytes": params_shard * BF16})
     if L.mp > 1:
         # Megatron TP: 2 activation reductions per layer forward + 2
         # backward, per micro-batch (SP recasts them as RS+AG at equal

@@ -989,7 +989,8 @@ def pharia_step(topo):
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 3  # splash: forward, dq, dkv
     gauges = {name: get_registry().gauge(name).value
-              for name in ("train_loss_vocab_shards", "train_sp_manual_boundaries")}
+              for name in ("train_loss_vocab_shards", "train_sp_manual_boundaries",
+                           "train_zero_entry_gathers", "train_zero_scattered_grads")}
     return SimpleNamespace(
         text=text, memory=compiled.memory_analysis(), gauges=gauges,
         vocab=vocab, hidden=hidden, seq=seq)
@@ -1086,3 +1087,61 @@ def test_pharia_train_step_crosses_tp_regions_by_reduce_scatter(pharia_step):
     # the gathered rows are kept for the weight gradients: 1.76e9 (1.71e9
     # where GSPMD gathered them again in the backward)
     assert pharia_step.memory.temp_size_in_bytes < 1.9e9
+
+
+def test_pharia_train_step_gathers_each_weight_once_on_entry(pharia_step):
+    """The same compiled step (ISSUE 67): ZeRO-1's traffic over the data
+    pairs (devices 0,2 and 1,3) is off the step's tail. The compute copy
+    comes in as the shard its master lives on and each matrix is gathered
+    ONCE, before the forward reads it (nothing gathers a weight again in the
+    backward, nothing gathers one after the update); the MLP's two and the
+    head's, 24 of the 29 ms a step that the parent spent synchronously behind
+    the optimizer, are ASYNCHRONOUS collectives that the compiler runs
+    beside a matmul; and no gathered weight is copied into a donated buffer
+    (the parent re-laid out every one: 16.4 ms of ``copy`` a step at depth
+    7). Each weight gradient crosses the data pairs fused as
+    ``all-reduce-scatter`` and yields the shard that the master consumes.
+
+    The attention's ``[4608,2304]`` / ``[2304,4608]`` and the embedding's
+    gathers stay synchronous (3.2 + 6.3 ms at depth 7): a later PR that
+    makes them asynchronous, or that brings back a tail gather, changes the
+    counts here."""
+    text, hidden = pharia_step.text, pharia_step.hidden
+    half_vocab, mlp = pharia_step.vocab // 2, 2 * hidden
+    leaves = pharia_step.gauges["train_zero_entry_gathers"]
+    assert leaves == pharia_step.gauges["train_zero_scattered_grads"] > 10
+    over_data = "replica_groups=[2,2]<=[2,2]T(1,0)"
+    gathers = [line.strip() for line in text.splitlines()
+               if " all-gather(" in line and over_data in line]
+    computations = text.split("\n\n")
+
+    def asynchronous(shape):
+        """Gathers yielding ``shape`` inside a fused AsyncCollectiveStart."""
+        return sum(f"= {shape}{{" in c and " all-gather(" in c
+                   and 'custom_call_target="AsyncCollectiveStart"' in c
+                   and "\nENTRY " not in c for c in computations)
+
+    entry = text[text.index("\nENTRY "):].split("\n\n")[0]
+    matrices = {  # shape a chip holds under TP=2 -> asynchronous or not
+        f"bf16[{hidden},{mlp}]": True, f"bf16[{mlp},{hidden}]": True,
+        f"bf16[{hidden},{half_vocab}]": True, f"bf16[{half_vocab},{hidden}]": False,
+        f"bf16[{hidden},{hidden // 2}]": False, f"bf16[{hidden // 2},{hidden}]": False,
+    }
+    for shape, is_async in matrices.items():
+        # one collective = one channel (an asynchronous one is written out
+        # in the fusions that start it, run beside it and end it)
+        made = {re.search(r"channel_id=(\d+)", line).group(1)
+                for line in gathers if f"= {shape}{{" in line}
+        assert len(made) == 1, (shape, made)
+        assert asynchronous(shape) == int(is_async), shape
+        copies = [line.strip()[:160] for line in entry.splitlines()
+                  if f"= {shape}{{" in line and " copy(" in line]
+        assert not copies, copies
+        # the gradient: reduced over the data pairs only inside the fusion
+        # that also slices it, never as a whole array at the top level
+        whole = [line.strip()[:160] for line in entry.splitlines()
+                 if f"= {shape}{{" in line and " all-reduce(" in line]
+        assert not whole, whole
+    fused = re.findall(r"\n%all-reduce-scatter\.\d+ \(input\S*: (bf16\[[0-9,]+\])", text)
+    assert {f"bf16[{hidden},{half_vocab}]", f"bf16[{hidden},{mlp}]",
+            f"bf16[{mlp},{hidden}]"} <= set(fused), fused

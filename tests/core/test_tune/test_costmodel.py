@@ -147,6 +147,21 @@ def test_analytic_inventory_schema_matches_hlo_inventory():
         assert rec["axis"] in ("pipe", "data", "context", "model")
 
 
+@pytest.mark.parametrize("stage,want", [
+    (1, {"reduce-scatter": (1, 2), "all-gather": (1, 2)}),
+    (3, {"reduce-scatter": (1, 4), "all-gather": (2, 4)}),
+])
+def test_data_axis_records_are_what_the_zero_step_emits(stage, want):
+    """ZeRO-1 crosses the data axis as a reduce-scatter of the gradients
+    and ONE gather of the updated compute copy, both bf16 (2 bytes a
+    parameter of the TP / PP shard): never the float32 all-reduce of a
+    replicated optimizer. Stage 3 gathers twice (forward and backward)."""
+    layout = _layout(pp=1, dp=4, mp=2, zero_stage=stage)
+    recs = [r for r in analytic_collectives(MODEL, layout) if r["axis"] == "data"]
+    shard = MODEL.parameter_count // 2
+    assert {r["op"]: (r["count"], r["bytes"] // shard) for r in recs} == want
+
+
 def test_calibration_from_run_dir_reads_mfu(tmp_path):
     run = tmp_path / "run"
     run.mkdir()
