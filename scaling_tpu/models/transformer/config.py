@@ -65,6 +65,21 @@ class LayerKind(Enum):
     # multi-head latent attention (nn/latent_attention.py): low-rank q and kv
     # projections, ONE latent line a token in the paged pool
     LATENT = "latent"
+    # softmax attention over a sliding window, with a head count, a window and
+    # a rotary of its own beside the 'attention' layers' (nn/window_attention.py):
+    # served, it keeps a RING of lines a slot, not pages
+    WINDOW = "window"
+
+
+class AttentionGate(Enum):
+    """The gate on the output of a ``layer_pattern`` stack's softmax attention
+    layers ('attention' and 'window'): none, or ``per_head``: ``g =
+    sigmoid(x W_g)``, one value a query head from the layer's normed input,
+    on the head's output before the output projection (the head-wise gate of
+    arXiv:2505.06708)."""
+
+    NONE = "none"
+    PER_HEAD = "per_head"
 
 
 class MoERouter(Enum):
@@ -362,7 +377,26 @@ class TransformerArchitectureConfig(BaseConfig):
         None, description="a 'latent' head's value size", gt=0)
     rope_scaling: Optional[RopeScalingConfig] = Field(
         None, description="the checkpoint's rope_scaling (YaRN alone is "
-        "built, nn/rotary.py); applied by 'latent' layers")
+        "built, nn/rotary.py); applied by a layer_pattern's 'latent' layers "
+        "and by its 'attention' layers, on the rotary_percentage of a head "
+        "that is rotated; never by its 'window' layers")
+    window_size: Optional[int] = Field(
+        None, description="a 'window' layer: lines a query sees, itself "
+        "included (query t attends over keys s with t - window_size < s <= t: "
+        "the published sliding_window)", gt=0)
+    window_num_attention_heads: Optional[int] = Field(
+        None, description="a 'window' layer's query heads, over the same "
+        "attention_num_kv_heads and attention_head_dim as the 'attention' "
+        "layers'; absent: num_attention_heads", gt=0)
+    window_rotary_embedding_base: int = Field(
+        10000, description="a 'window' layer's rotary base theta; its "
+        "frequencies are the base's, unscaled")
+    window_rotary_percentage: float = Field(
+        1.0, description="fraction of a 'window' layer's head that is rotated",
+        gt=0.0, le=1.0)
+    attention_gate: AttentionGate = Field(
+        AttentionGate.NONE, description="a gate on the output of a "
+        "layer_pattern's 'attention' and 'window' layers (see AttentionGate)")
     index_n_heads: Optional[int] = Field(
         None, description="a SPARSE attention layer: a layer_pattern's "
         "'latent' layers (nn/sparse_latent_attention.py) or, in a pattern "
@@ -608,6 +642,12 @@ class TransformerArchitectureConfig(BaseConfig):
                 "without them, its 'attention' layers")
         if self.hc_streams > 1:
             self._validate_hyper_connection()
+        if (self.attention_gate != AttentionGate.NONE
+                and self.layer_pattern is None):
+            raise ValueError(
+                "attention_gate without layer_pattern: the gate is built for "
+                "the pattern stack's 'attention' and 'window' layers; the "
+                "homogeneous TransformerLayer has none")
         if self.layer_pattern is not None:
             self._validate_pattern()
         elif self.rope_scaling is not None:
@@ -792,11 +832,18 @@ class TransformerArchitectureConfig(BaseConfig):
                     f"index_head_dim {self.index_head_dim} is narrower than "
                     f"qk_rope_head_dim {self.qk_rope_head_dim}: an indexer "
                     "head's first qk_rope_head_dim lanes are rotary")
-        elif self.rope_scaling is not None:
+        elif (self.rope_scaling is not None
+              and LayerKind.ATTENTION not in self.layer_pattern):
             raise ValueError(
-                "rope_scaling without 'latent' layers: only the latent "
-                "attention mixer applies YaRN; the other attention mixers' "
-                "rotary tables take the base frequencies")
+                "rope_scaling without 'latent' or 'attention' layers: the "
+                "latent and the grouped-query attention mixers apply YaRN; a "
+                "'window' layer's rotary table takes the base frequencies")
+        if LayerKind.WINDOW in self.layer_pattern:
+            self._validate_window()
+        elif self.window_size is not None or self.window_num_attention_heads:
+            raise ValueError(
+                "window_size / window_num_attention_heads without 'window' "
+                "layers in layer_pattern: they size that kind alone")
         if self.index_topk is not None and LayerKind.ATTENTION in self.layer_pattern:
             # a sparse grouped-query layer (nn/sparse_attention.py): what it
             # does not build, each by name
@@ -821,6 +868,80 @@ class TransformerArchitectureConfig(BaseConfig):
                 raise ValueError(
                     "index_* with num_local_attention_heads: a window over a "
                     "learned choice of lines is not built")
+            if self.rope_scaling is not None:
+                raise ValueError(
+                    "index_* with rope_scaling on 'attention' layers: the "
+                    "indexer's rotary takes the base frequencies and a scaled "
+                    "head beside it has not been held to a reference")
+            if self.attention_gate != AttentionGate.NONE:
+                raise ValueError(
+                    "index_* with attention_gate: a gated sparse attention "
+                    "layer is not built")
+        if self.num_local_attention_heads:
+            raise ValueError(
+                "num_local_attention_heads with layer_pattern: the "
+                "single-mixer attention layer builds no per-head windows; a "
+                "window a layer is the pattern's 'window' kind")
+
+    def _validate_window(self):
+        """What a 'window' layer does not build, each by name."""
+        if self.window_size is None:
+            raise ValueError(
+                "layer_pattern with 'window' layers needs window_size: the "
+                "lines a query of such a layer sees")
+        if (self.relative_position_embedding_type
+                != RelativePositionEmbeddingType.ROTARY):
+            raise ValueError(
+                "layer_pattern with 'window' layers and "
+                "relative_position_embedding_type "
+                f"{self.relative_position_embedding_type.value!r}: a window "
+                "layer's position is its rotary at window_rotary_embedding_"
+                "base; use 'rotary'")
+        if self.attention_num_kv_heads is None:
+            raise ValueError(
+                "layer_pattern with 'window' layers needs "
+                "attention_num_kv_heads: a window layer has a query, a key "
+                "and a value projection of its own and shares the KV heads' "
+                "count with the 'attention' layers")
+        heads = self.window_num_attention_heads or self.num_attention_heads
+        if heads % self.attention_num_kv_heads:
+            raise ValueError(
+                f"window_num_attention_heads {heads} is not a multiple of "
+                f"attention_num_kv_heads {self.attention_num_kv_heads}")
+        if not self.causal:
+            raise ValueError(
+                "layer_pattern with 'window' layers and causal false: the "
+                "window reaches back from a query, never ahead")
+        if self.num_local_attention_heads:
+            raise ValueError(
+                "'window' layers with num_local_attention_heads: a window a "
+                "LAYER and windows a HEAD are two mechanisms; the pattern "
+                "stack builds the first")
+        if self.index_topk is not None:
+            raise ValueError(
+                "'window' layers with index_* : an indexer's choice inside a "
+                "window is not built")
+        if self.key_query_norm:
+            raise ValueError(
+                "'window' layers with key_query_norm: not held to a "
+                "reference yet (the mixer would build it: "
+                "nn/window_attention.py); set it false")
+
+    def refuse_paged_serving(self) -> None:
+        """What the paged serving engine does not serve of an architecture
+        that trains and runs uncached, by name, before anything is traced
+        (``ServeEngine`` calls it)."""
+        if self.num_local_attention_heads:
+            raise ValueError(
+                "num_local_attention_heads with the paged serving engine: "
+                "the paged kernel holds a row's tiles under ONE causal mask, "
+                "per-head local windows are not built on it; serve a window "
+                "a LAYER (layer_pattern's 'window' kind) or run uncached")
+
+    @property
+    def window_layers(self) -> int:
+        """Layers whose mixer is windowed attention (a ring a slot)."""
+        return (self.layer_pattern or []).count(LayerKind.WINDOW)
 
     @property
     def latent_layers(self) -> int:
@@ -1005,6 +1126,12 @@ class TransformerConfig(BaseConfig):
                         f"{why} neither stage-stacked nor tensor-parallel "
                         "yet; use 1"
                     )
+        if arch.window_layers and self.topology.context_parallel_size > 1:
+            raise ValueError(
+                "'window' layers with context_parallel_size "
+                f"{self.topology.context_parallel_size}: a window over a "
+                "sequence sharded on the context axis is not built (ring and "
+                "ulysses attend over the whole sequence); use 1")
         return self
 
     @classmethod

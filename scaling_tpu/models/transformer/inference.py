@@ -842,7 +842,7 @@ class TransformerInferenceModule:
         return caches
 
     def prefill_forward(self, params, token_ids, position_ids,
-                        segment_ids=None, last_index=None):
+                        segment_ids=None, last_index=None, row_width: int = 1):
         """Traceable prompt pass: full stack with ``return_kv=True`` (the
         flash kernel stays active — no cache is CONSUMED here), returning
         (logits for one position, per-layer (k, v)).
@@ -852,10 +852,14 @@ class TransformerInferenceModule:
         serving engine's bucketed prefill uses, sample at prompt_len-1.
         Shared by ``generate``'s dense-cache prefill and the serving
         engine's paged prefill (serve/engine.py), so the two products of
-        one prompt pass can never diverge."""
+        one prompt pass can never diverge. ``row_width`` (static): the most
+        tokens a row of a served tick will bring, which the pools' probe hands
+        on for the layers whose final state is sized by it (a window layer's
+        ring)."""
         from ...parallel.pipeline import PipelinedBody
 
         ctx = self._make_ctx()
+        ctx.serve_row_width = row_width
         if self.architecture.loop_steps > 1:
             # a looped model: the K/V of every (step, layer), in line order
             def pick(h):

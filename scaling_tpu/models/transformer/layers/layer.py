@@ -39,8 +39,10 @@ from ....nn.sparse_attention import SparseSelfAttention
 from ....nn.sparse_latent_attention import SparseLatentSelfAttention
 from ....nn.mamba import Mamba2Mixer
 from ....nn.short_conv import GatedShortConv
+from ....nn.window_attention import WindowSelfAttention
 from ..config import (
     AdapterConfig,
+    AttentionGate,
     KeyQueryNormScope,
     LayerKind,
     MLPType,
@@ -213,20 +215,36 @@ class MixerLayer(BaseLayer):
                 dtype=dtype,
             )
         else:
+            # softmax attention, 'attention' or 'window': a window layer has a
+            # head count and a rotary of its own and takes no scaling
+            window = self.kind == LayerKind.WINDOW
+            heads = arch.num_attention_heads
+            if window and arch.window_num_attention_heads:
+                heads = arch.window_num_attention_heads
             rotary_config = None
-            head_dim = (arch.attention_head_dim
-                        or arch.hidden_size // arch.num_attention_heads)
+            head_dim = arch.attention_head_dim or arch.hidden_size // heads
             if arch.relative_position_embedding_type != RelativePositionEmbeddingType.NONE:
+                share, base, scaling = (
+                    (arch.window_rotary_percentage,
+                     arch.window_rotary_embedding_base, None) if window else
+                    (arch.rotary_percentage, arch.rotary_embedding_base,
+                     arch.rope_scaling))
                 rotary_config = RotaryConfig(
-                    dimensions=max(2, int(head_dim * arch.rotary_percentage)),
-                    base=arch.rotary_embedding_base,
+                    dimensions=max(2, int(head_dim * share)),
+                    base=base,
                     max_seq_length=arch.sequence_length,
+                    scaling=scaling,
                 )
+            own = {} if sparse else dict(
+                output_gate=arch.attention_gate == AttentionGate.PER_HEAD)
+            if window:
+                own["window_size"] = arch.window_size
             self.mixer = (SparseSelfAttention if sparse
+                          else WindowSelfAttention if window
                           else ParallelSelfAttention)(
-                **sparse,
+                **sparse, **own,
                 hidden_size=arch.hidden_size,
-                num_attention_heads=arch.num_attention_heads,
+                num_attention_heads=heads,
                 masked_softmax_config=arch.masked_softmax,
                 causal=arch.causal,
                 rotary_config=rotary_config,
@@ -350,8 +368,18 @@ class MixerLayer(BaseLayer):
                     "serving engine's state pool), not a KV cache: cached "
                     "generate() is not built for a layer_pattern stack; use "
                     "use_cache=False or ServeEngine")
-            y = self.mixer(params["mixer"], normed, ctx, state=kv_cache,
-                           return_state=return_kv)
+            if self.kind == LayerKind.WINDOW:
+                # attention all the same: its rotary and its mask are the
+                # positions' and the segments'
+                with jax.named_scope("window_attn"):
+                    y = self.mixer(
+                        params["mixer"], normed, ctx,
+                        segment_ids=x["segment_ids"],
+                        position_ids=x["position_ids"],
+                        state=kv_cache, return_state=return_kv)
+            else:
+                y = self.mixer(params["mixer"], normed, ctx, state=kv_cache,
+                               return_state=return_kv)
             if return_kv or kv_cache is not None:
                 y, state = y
         elif self.kind == LayerKind.MOE:
@@ -379,8 +407,12 @@ class MixerLayer(BaseLayer):
         else:
             # a sparse mixer's operations carry the scope its metrics read, as
             # the latent mixers' do; a plain one's never had it
+            # (nor does one beside window layers: ``full_attn`` there, so that
+            # each kind's share of a tick can be read)
             sparse = isinstance(self.mixer, SparseSelfAttention)
-            with jax.named_scope("attn") if sparse else contextlib.nullcontext():
+            scope = ("attn" if sparse else
+                     "full_attn" if self.architecture.window_layers else None)
+            with jax.named_scope(scope) if scope else contextlib.nullcontext():
                 y = self.mixer(
                     params["mixer"], normed, ctx,
                     segment_ids=x["segment_ids"], position_ids=x["position_ids"],

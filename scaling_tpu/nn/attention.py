@@ -343,6 +343,7 @@ class ParallelSelfAttention(BaseLayer):
         num_kv_heads: Optional[int] = None,
         head_dim: Optional[int] = None,
         key_multiplier: float = 1.0,
+        output_gate: bool = False,
     ):
         assert head_dim is not None or hidden_size % num_attention_heads == 0, (
             f"hidden size ({hidden_size}) must be divisible by "
@@ -401,6 +402,14 @@ class ParallelSelfAttention(BaseLayer):
         self.dense = RowParallelLinear(
             width, hidden_size, parallel_input=True, parallel_output=True, **common
         )
+        # a per-head gate on the heads' output, before ``dense``: g =
+        # sigmoid(x W_g), one value a query head, from the layer's input (the
+        # head-wise gate of arXiv:2505.06708); no bias
+        self.gate = None
+        if output_gate:
+            self.gate = ColumnParallelLinear(
+                hidden_size, num_attention_heads, parallel_output=True,
+                bias=False, dtype=dtype, init_method=init_method)
 
         # rotary
         self.rotary_embedding: Any = None
@@ -462,6 +471,8 @@ class ParallelSelfAttention(BaseLayer):
                 params["key"]["weight"], 1.0 / self.key_multiplier)
             params["value"] = self.value.init(keys[2])
         params["dense"] = self.dense.init(keys[3])
+        if self.gate is not None:
+            params["gate"] = self.gate.init(keys[7])
         if self.key_query_norm:
             params["norm_query"] = self.norm_query.init(keys[4])
             params["norm_key"] = self.norm_key.init(keys[5])
@@ -478,6 +489,8 @@ class ParallelSelfAttention(BaseLayer):
             metas["key"] = tree_prefix(self.key.param_metas(), "key")
             metas["value"] = tree_prefix(self.value.param_metas(), "value")
         metas["dense"] = tree_prefix(self.dense.param_metas(), "dense")
+        if self.gate is not None:
+            metas["gate"] = tree_prefix(self.gate.param_metas(), "gate")
         if self.key_query_norm:
             metas["norm_query"] = tree_prefix(self.norm_query.param_metas(), "norm_query")
             metas["norm_key"] = tree_prefix(self.norm_key.param_metas(), "norm_key")
@@ -570,11 +583,10 @@ class ParallelSelfAttention(BaseLayer):
                 "attention_scores_manipulation is unsupported on the paged "
                 "decode path"
             )
-            assert self.num_local_attention_heads == 0, (
-                "local-window heads are unsupported on the paged decode path"
-            )
+            # (per-head local windows are refused before anything is traced:
+            # config.py ``refuse_paged_serving``)
             out, new_view = self._paged_attention(q, k, v, kv_cache, b, s, ctx)
-            return self._project_out(params, out, ctx, b, s, new_view)
+            return self._project_out(params, out, ctx, b, s, new_view, x)
 
         if kv_cache is not None:
             # incremental decode / token-slice pipelining: append new k/v at
@@ -653,7 +665,7 @@ class ParallelSelfAttention(BaseLayer):
                 local_window=self.local_attention_window_size,
                 mesh=ctx.mesh,
             )
-            return self._project_out(params, out, ctx, b, s, new_kv)
+            return self._project_out(params, out, ctx, b, s, new_kv, x)
 
         if ctx.context_parallel_size > 1 and kv_cache is None:
             # context parallelism: sequence sharded over the context mesh
@@ -712,7 +724,7 @@ class ParallelSelfAttention(BaseLayer):
                     q, kr, vr, segment_ids, ctx.mesh,
                     causal=self.causal, sm_scale=self.scaling_factor,
                 )
-            return self._project_out(params, out, ctx, b, s, new_kv)
+            return self._project_out(params, out, ctx, b, s, new_kv, x)
 
         k = repeat_kv(k, self.num_repeat_kv)
         v = repeat_kv(v, self.num_repeat_kv)
@@ -745,7 +757,7 @@ class ParallelSelfAttention(BaseLayer):
                 attention_scores_manipulation_log_additive,
             )
 
-        return self._project_out(params, out, ctx, b, s, new_kv)
+        return self._project_out(params, out, ctx, b, s, new_kv, x)
 
     def _paged_attention(self, q, k, v, view: PagedKVCacheView, b: int, s: int,
                          ctx: ForwardContext):
@@ -907,8 +919,16 @@ class ParallelSelfAttention(BaseLayer):
             q, gk, gv, mask, self.scaling_factor, self.masked_softmax, None
         )
 
-    def _project_out(self, params, out, ctx, b, s, new_kv):
-        """Shared epilogue: heads -> hidden, dense projection + LoRA delta."""
+    def _project_out(self, params, out, ctx, b, s, new_kv, x=None):
+        """Shared epilogue: heads -> hidden, dense projection + LoRA delta;
+        with a gate, each head's output times its gate first (``x``: the
+        layer's input, which the gate reads)."""
+        if self.gate is not None:
+            with jax.named_scope("gate"):
+                g = jax.nn.sigmoid(
+                    self.gate(params["gate"], x, ctx).astype(jnp.float32))
+                out = (out.reshape(b, s, self.num_attention_heads, self.head_dim)
+                       .astype(jnp.float32) * g[..., None]).astype(out.dtype)
         out = out.reshape(b, s, self.attention_width)
         y = self.dense(params["dense"], out, ctx)
         if self.lora_config:

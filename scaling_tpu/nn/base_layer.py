@@ -48,6 +48,10 @@ class ForwardContext:
     # programs). A routed MLP then drops no assignment and computes no
     # auxiliary loss (nn/moe.py, "Serving").
     serving: bool = False
+    # the most tokens a row of a served tick brings (static): what the serving
+    # pools' probe sizes a window layer's ring for (nn/window_attention.py);
+    # 1 wherever nothing is probed
+    serve_row_width: int = 1
 
     _key_counter: int = 0
     # the inputs of the TP regions this pass has entered through sequence

@@ -137,6 +137,12 @@ def paged_kernel_interpret(platform: Optional[str] = None) -> bool:
 _TILE_TOKENS = 512
 # VMEM the two double-buffered pool tiles (K and V) may take together
 _TILE_VMEM_BYTES = 8 << 20
+# VMEM a kernel may take without asking (Mosaic's scoped default is 16 MiB);
+# a call whose blocks need more (wide chunk rows at a large GQA group: every
+# row's queries, output and softmax state are whole in VMEM) asks for what it
+# needs, up to ``_VMEM_CEILING_BYTES`` of a v5e core's 128 MiB
+_VMEM_DEFAULT_BYTES = 12 << 20
+_VMEM_CEILING_BYTES = 100 << 20
 # query positions of the short-query path: a decode row has 1 real
 # position, a decode row with drafts spec_k + 1
 _SHORT_QUERIES = 8
@@ -624,6 +630,16 @@ def _paged_call(
         group=group, m_short=m_short, quantized=quantized, masked=masked,
         single=single,
     )
+    # what a row's blocks take in VMEM: queries and output (double-buffered),
+    # the float32 accumulator, m and l (one value a row, a lane row each), the
+    # K and V tile buffers, the scores of one KV head. The kernels the cells
+    # had stay under the default and are built with the parameters they had.
+    row = n_kv * m_full * h
+    needed = (4 * row * q.dtype.itemsize + 4 * row + 2 * 4 * n_kv * m_full * 128
+              + 4 * tile * max(n_kv, 8 * max(1, 4 // pool_k.dtype.itemsize))
+              * h * pool_k.dtype.itemsize + 3 * 4 * m_full * tile)
+    limit = {} if needed <= _VMEM_DEFAULT_BYTES else {
+        "vmem_limit_bytes": min(_VMEM_CEILING_BYTES, needed + needed // 4)}
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -631,7 +647,7 @@ def _paged_call(
         # the rows run in order on one core: a row's first tile is started
         # by the row before it
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)
+            dimension_semantics=("arbitrary",), **limit
         ),
         interpret=interpret,
         name="paged_attention",  # the trace's and the HLO's name for it

@@ -336,9 +336,23 @@ class ServeEngine:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             self._replicated = NamedSharding(self.mesh, P())
+        arch = inference_module.architecture
+        arch.refuse_paged_serving()
+        # window attention layers (nn/window_attention.py): each keeps a RING
+        # of lines a slot, whatever the context; the pools, the block tables
+        # and the scheduler's block count are the full layers' alone
+        self.window_layers = arch.window_layers
+        self.window_size = arch.window_size
+        if self.window_layers and self.config.kv_dtype != "native":
+            raise ValueError(
+                f"kv_dtype {self.config.kv_dtype!r} with window attention "
+                "layers: a ring's lines are kept in the model's dtype and "
+                "their rounding in int8 is not measured; use "
+                "kv_dtype='native'")
         self.pools: PagedKVPools = init_pools(
             inference_module, self.config.num_blocks, self.config.block_size,
             kv_dtype=self.config.kv_dtype, num_slots=self.config.num_slots,
+            row_width=self.config.mixed_width,
         )
         # the layers that keep a line a slot, by the name their kind's spans
         # and counters carry: a state that is advanced, never indexed by
@@ -461,7 +475,6 @@ class ServeEngine:
         # in the tick's one host read, how many assignments of real
         # positions each expert received (0: a dense model, which pays
         # nothing for it)
-        arch = inference_module.architecture
         routed = arch.has_routed_layers
         # the experts this program HOLDS; a share of them also counts the
         # assignments that fell on absent experts (one more entry)
@@ -535,6 +548,17 @@ class ServeEngine:
         # lost update that read 0 would silently skip live deadlines.
         self._deadline_live = 0
         self._deadline_lock = threading.Lock()
+        if self.window_layers:
+            # what the window layers keep, fixed when the pools are built: it
+            # does not grow with the context
+            fields = [kind for kind in line_layers(self.pools.kinds)
+                      for _ in kind.LINES]     # the kind of each list of lines
+            rings = [a for kind, lines in zip(fields, self.pools.lines)
+                     if kind.NAME == "window" for a in lines]
+            self.window_ring_lines = int(rings[0].shape[1])
+            self._gauge("serve_window_ring_lines").set(self.window_ring_lines)
+            self._gauge("serve_window_ring_gb").set(
+                sum(a.size * a.dtype.itemsize for a in rings) / 1e9)
 
     # ------------------------------------------------------------- intake
     def submit(self, prompt: List[int], max_new_tokens: int,
@@ -1265,6 +1289,31 @@ class ServeEngine:
                 mixed_span.annotate(sparse_single_rows=single_rows)
                 self._counter("serve_sparse_single_rows_total").inc(
                     single_rows * self.sparse_layers)
+        if self.window_layers:
+            # what a window layer's attention does this tick: its rows, those
+            # whose context is past the window (there the window cuts what a
+            # full layer would read), the ring lines the rows' queries see and
+            # the (query, visible line) pairs
+            n_new = new_lens.astype(np.int64)
+            first = ctx.astype(np.int64)                  # a row's first query
+            w = self.window_size
+            past = int(np.count_nonzero(held[new_lens > 0] > w))
+            # query i of a row sees min(first + i + 1, w) lines
+            upto = np.clip(w - first, 0, n_new)   # queries that see under w
+            pairs = (upto * (first + 1) + upto * (upto - 1) // 2
+                     + (n_new - upto) * w)
+            lines = np.where(n_new > 0, np.minimum(held, w - 1 + n_new), 0)
+            mixed_span.annotate(
+                window_layers=self.window_layers,
+                window_rows_past=past, window_visible_lines=int(lines.sum()),
+                window_pairs=int(pairs.sum()),
+                # and a full layer's pairs beside them: every line up to the
+                # query's own
+                full_pairs=int((n_new * first + n_new * (n_new + 1) // 2).sum()))
+            self._counter("serve_window_rows_total").inc(
+                rows * self.window_layers)
+            self._counter("serve_window_rows_past_window_total").inc(
+                past * self.window_layers)
         if self.hc_sublayers:
             # what the residual path moves this tick: every real token's
             # streams through every sub-layer's mapping
@@ -1765,6 +1814,7 @@ class ServeEngine:
             "sparse_layers": self.sparse_layers,
             "hc_streams": self.hc_streams,
             "hc_sublayers": self.hc_sublayers,
+            "window_layers": self.window_layers,
             # layers that keep a line a slot (Mamba-2 mixers' recurrent state,
             # short convolutions' tails; 0: a model without them) and the
             # bytes of those lines

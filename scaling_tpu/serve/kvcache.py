@@ -57,7 +57,9 @@ rules, no kind:
   probe's final state of the layer, one leaf a field in ``LINES``' order,
   with its leading dimension set to ``num_slots``. Nothing is paged, the
   scheduler counts no block for it. (Mamba-2's ``ssm`` and ``conv``,
-  nn/mamba.py; a gated short convolution's ``tail``, nn/short_conv.py.)
+  nn/mamba.py; a gated short convolution's ``tail``, nn/short_conv.py; a
+  window attention layer's ring of ``k`` and ``v``, nn/window_attention.py:
+  the last lines a query may still see, position ``p`` at line ``p % ring``.)
 
 The state the engine's program takes and returns is ONE structure, so that
 the lines are donated and aliased like the pools: ``(pool_k, pool_v, scale_k,
@@ -300,12 +302,15 @@ class PagedKVPools:
 
 
 def init_pools(inference_module, num_blocks: int, block_size: int,
-               kv_dtype: str = "native", num_slots: int = 0) -> PagedKVPools:
+               kv_dtype: str = "native", num_slots: int = 0,
+               row_width: int = 1) -> PagedKVPools:
     """Allocate zeroed pools shaped by probing the real layer stack.
 
     A stack with layers that keep a line a slot also gets their lines,
     ``num_slots`` of each, shaped by the same probe (the final state of a
-    one-token pass).
+    one-token pass; ``row_width``, the most tokens a row will bring to a
+    tick, is handed to the probe for the kinds whose line is sized by it: a
+    window layer's ring, nn/window_attention.py).
 
     ``kv_dtype``: ``'native'`` keeps the probe's KV dtype (the model's
     compute dtype); ``'int8'`` stores int8 values + float32 scales.
@@ -321,7 +326,8 @@ def init_pools(inference_module, num_blocks: int, block_size: int,
     probe_pos = jnp.zeros((1, 1), jnp.int32)
 
     def probe(p, t, po):
-        return inference_module.prefill_forward(p, t, po)[1]
+        return inference_module.prefill_forward(
+            p, t, po, row_width=row_width)[1]
 
     kv_shapes = jax.eval_shape(probe, params, probe_tokens, probe_pos)
     # a pattern stack's probe holds the final state a consuming mixer, in

@@ -157,6 +157,16 @@ def test_paged_kernel_compiles_at_group_5(one_chip, s):
     compile_paged_kernel(one_chip, 96, 20, 4, 40, s, "native")
 
 
+def test_paged_kernel_compiles_at_a_chunk_of_256_and_group_6(one_chip):
+    """``serve-laguna-s-mixedlen-burst``'s full layers: 48 query heads over 8
+    KV heads x 128, 24 slots of 2,048 blocks, chunk rows of 256 positions: the
+    folded query block is 1,536 rows a KV head, and a row's queries, output
+    and softmax state take ~36 MB of VMEM where Mosaic gives 16 MiB unasked:
+    the call asks for what its blocks need (``vmem_limit_bytes``), which the
+    cells' narrower calls never do (their kernels are built as they were)."""
+    compile_paged_kernel(one_chip, 24, 48, 8, 2048, 256, "native", head_dim=128)
+
+
 def kernel_operands(lowered) -> int:
     """Operands of the ONE Mosaic kernel in a lowered program's text."""
     (operands,) = re.findall(
@@ -518,6 +528,73 @@ def test_masked_gqa_kernel_compiles_at_the_cells_size(one_chip, window):
         shape((320, 32, 128)), shape((window, 4, 128)), shape((window, 4, 128)),
         shape((320, window), jnp.bool_), shape((), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def window_layer(one_chip, tokens, monkeypatch):
+    """``serve-laguna-s-mixedlen-burst``'s window attention mixer over its
+    rings at one of its engine's two token widths, compiled for the described
+    chip, its kernel by Mosaic: 24 rows of up to 256 positions, 72 query heads
+    over 8 KV heads of 128, a ring of 1,024 lines a slot, the per-head gate."""
+    monkeypatch.setattr(
+        "scaling_tpu.nn.window_attention.paged_kernel_interpret",
+        lambda platform=None: False)
+    from scaling_tpu.nn.attention import packed_token_map
+    from scaling_tpu.nn.base_layer import ForwardContext
+    from scaling_tpu.nn.rotary import RotaryConfig
+    from scaling_tpu.nn.window_attention import (
+        WindowRingView, WindowSelfAttention, ring_lines,
+    )
+    from scaling_tpu.serve.engine import packed_batch_shape
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    rows, hidden, width = 24, 3072, 256
+    ring = ring_lines(512, width)
+    mixer = WindowSelfAttention(
+        window_size=512, output_gate=True, hidden_size=hidden,
+        num_attention_heads=72, num_kv_heads=8, head_dim=128, qkv_in_one=False,
+        bias=False, dtype=jnp.bfloat16,
+        rotary_config=RotaryConfig(dimensions=128, base=10000,
+                                   max_seq_length=32768))
+    params = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(mixer.init, jax.random.PRNGKey(0)))
+    batch = packed_batch_shape(tokens, width)
+
+    def layer(params, x, ring_k, ring_v, ctx_len, new_len):
+        token_map = packed_token_map(new_len, batch, width)
+        pos = ctx_len[token_map.row] + token_map.offset
+        view = WindowRingView(k=ring_k, v=ring_v, context_len=ctx_len,
+                              new_len=new_len, token_map=token_map)
+        y, new = mixer(
+            params, x, ForwardContext(serving=True, paged_kernel="pallas"),
+            position_ids=pos, state=view)
+        return y, new.k, new.v
+
+    return jax.jit(layer, donate_argnums=(2, 3)).lower(
+        params, shape((*batch, hidden)),
+        shape((rows, ring, 8 * 128)), shape((rows, ring, 8 * 128)),
+        shape((rows,), jnp.int32), shape((rows,), jnp.int32),
+    ).compile()
+
+
+@pytest.mark.parametrize("tokens", [896, 6144], ids=["small", "full"])
+def test_window_layer_compiles_at_the_cells_size(one_chip, tokens, monkeypatch):
+    """The walk over the rows' rings at both token widths of the cell's
+    engine: the one-token rows in ONE call of ``window_ring_attention`` (24
+    rows, tiles of 256 lines), then a rolled loop over the 24 slots with a
+    branch for a chunk row (one row, tiles of 512): the kernel built twice
+    (group 9, rings of 1,024 lines read where they lie, no mask operand: a
+    mask a position broadcast over a group of 9 took Mosaic four minutes),
+    both rings scattered into in place."""
+    compiled = window_layer(one_chip, tokens, monkeypatch)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert " while(" in text and " conditional(" in text
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 2.0e9, memory.temp_size_in_bytes
+    assert memory.alias_size_in_bytes >= 2 * 24 * 1024 * 8 * 128 * 2
 
 
 def test_masked_paged_kernel_compiles_at_the_cells_size(one_chip):

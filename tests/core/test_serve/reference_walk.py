@@ -41,14 +41,24 @@ def greedy_by_reference(logits_of, requests, new_tokens, least_margin=1e-3):
 
 def paged_logits(inf, engine, tokens, chunk, paged_kernel):
     """Logits of every position of ONE sequence served through ``engine``'s
-    pool (an engine of one slot whose row owns blocks 1, 2, ...): ``chunk``
-    positions a call, the last four or more one by one (decode rows),
-    row-major batches of one row, the row's lines written by the calls
-    before; one jitted pass a call shape, traced anew on every call of this
-    function (a test that alters a part of the model sees it traced)."""
+    pool: ``chunk`` positions a call, the last four or more one by one (decode
+    rows); ``paged_walk`` with that schedule."""
+    sizes = [chunk] * ((len(tokens) - 4) // chunk)
+    sizes += [1] * (len(tokens) - sum(sizes))
+    return paged_walk(inf, engine, tokens, sizes, paged_kernel)[0]
+
+
+def paged_walk(inf, engine, tokens, sizes, paged_kernel, state=None):
+    """``(logits of every position, the state after)`` of ONE sequence served
+    through ``engine``'s state (an engine of one slot whose row owns blocks 1,
+    2, ...): ``sizes`` positions a call from position 0 on, row-major batches
+    of one row, the row's lines written by the calls before; one jitted pass a
+    call shape, traced anew on every call of this function (a test that alters
+    a part of the model sees it traced). ``state``: what an earlier walk left
+    (a reused slot), the engine's fresh state by default."""
     block_size = engine.config.block_size
     blocks = engine.config.max_blocks_per_seq
-    assert len(tokens) <= blocks * block_size
+    assert sum(sizes) == len(tokens) <= blocks * block_size
     table = jnp.arange(1, blocks + 1, dtype=jnp.int32)[None]
 
     @jax.jit
@@ -63,13 +73,11 @@ def paged_logits(inf, engine, tokens, chunk, paged_kernel):
             paged_kernel=paged_kernel)
         return logits[0], state_from_views(new_views)
 
-    state = engine._pool_state()
+    state = engine._pool_state() if state is None else state
     out, done = [], 0
-    sizes = [chunk] * ((len(tokens) - 4) // chunk)
-    sizes += [1] * (len(tokens) - sum(sizes))
     for n in sizes:
         ids = jnp.asarray(tokens[done:done + n], jnp.int32)[None]
         logits, state = step(inf.params, state, ids, jnp.int32(done))
         out.append(np.asarray(logits))
         done += n
-    return np.concatenate(out)
+    return np.concatenate(out), state

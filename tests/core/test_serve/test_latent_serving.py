@@ -244,8 +244,9 @@ def test_speculative_rows_are_refused_by_name(kimi):
     ({}, {"qk_rope_head_dim": 15}, "qk_rope_head_dim 15 is odd"),
     ({}, {"relative_position_embedding_type": "none"},
      "a latent head's position is its rotary slice"),
-    ({}, {"layer_pattern": ["attention", "mlp"] * 3, "num_attention_heads": 4},
-     "rope_scaling without 'latent' layers"),
+    # (since PR 68 a pattern's 'attention' layers apply YaRN too)
+    ({}, {"layer_pattern": ["conv", "mlp"] * 3, "num_attention_heads": 4},
+     "rope_scaling without 'latent' or 'attention' layers"),
 ])
 def test_a_layout_the_stack_does_not_build_is_refused_by_name(topology, arch, message):
     with pytest.raises(ValueError, match=message):
