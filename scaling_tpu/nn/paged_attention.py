@@ -16,9 +16,28 @@ next tile in flight while this one is folded into a flash-style online
 softmax (running max ``m``, normalizer ``l``, unnormalized accumulator in
 f32 scratch — Dao et al., arxiv 2205.14135). Each KV byte moves HBM->VMEM
 exactly once, no ``(rows, window)`` buffer ever exists, and what a row
-cannot see costs nothing: the loop ends at the row's last tile and the
-last tile fetches only the blocks that hold visible slots (an inactive
-row runs no tile at all).
+cannot see costs nothing: the loop ends at the row's last tile, the last
+tile fetches only the blocks that hold visible slots (an inactive row runs
+no tile at all), and of a tile a row of few positions folds only the
+SUB-TILES that hold a visible slot.
+
+A sub-tile is ``_SUB_TOKENS`` = 128 tokens of a tile (``_blocks_per_sub``:
+8 blocks of 16; a tile that is not whole sub-tiles is its own one). The
+tile stays the unit of the DMAs' pipeline. How many of its sub-tiles hold a
+slot is read from ``valid_len``, and each count is one static branch of the
+kernel (ISSUE 69): its WAIT takes the whole sub-tiles at once, one wait a
+pool by their bytes, and the part-held last one block by block, for every
+row kind alike; and, for the rows of few positions, its FOLD has the static
+width of that many sub-tiles, with one softmax update a tile, so a decode
+row that holds 300 lines unpacks, multiplies and exponentiates 384 of its
+tile's 512 slots. Those rows fold all KV heads TOGETHER: every head's QK^T
+first, one softmax update over the stacked ``(n_kv, rows, width)`` scores,
+then every head's PV. Head by head, as the chunk rows still fold, a head's
+update is a chain of a dozen dependent steps on a register or two of scores
+(16 query rows), and a call of 16 decode rows spent 84 us in those chains
+where its DMAs take 54 (PERF.md, PR 69); together they pipeline, and the
+fold is a third of that. Chunk rows wait the same way and fold the tile
+whole, a head at a time: their scores are ``s * group`` rows deep already.
 
 The double buffer runs over the CALL's flat list of (row, tile) steps, not
 row by row: while a row's last tile is folded, the first tile of the next
@@ -47,14 +66,15 @@ queries out per KV head as ``(s * group, h)``, position-major, so per KV
 head QK^T is ``(s * group, h) @ (h, tile)`` and PV is ``(s * group, tile)
 @ (tile, h)``, both with float32 accumulation; K and V are never cast to
 float32 nor repeated across the group. One head's ``(tile, h)`` matrix is
-a sublane-strided read of the ``(tile, n_kv, h)`` buffer (``_head_tiles``:
+a sublane-strided read of the ``(tile, n_kv, h)`` buffer (``_heads``:
 32-bit words, so bf16 and int8 heads are unpacked from the words that pack
 them). The probabilities meet V in the queries' dtype (bf16 when serving;
 ``l`` sums them in float32), as the splash kernel's do. Rows with at most
 ``_SHORT_QUERIES`` real positions — decode rows, decode rows with drafts —
-run the same loop over their first folded rows only; prefill chunks take
-the full width. Which path a row takes is read from ``valid_len -
-q_slot_base``, the row's ``new_len``.
+run the loop over their first folded rows only, over the sub-tiles that
+hold a slot and all heads together; prefill chunks take the full width, the
+whole tile and a head at a time. Which path a row takes is read from
+``valid_len - q_slot_base``, the row's ``new_len``.
 
 Variants share one kernel body:
 
@@ -74,10 +94,9 @@ Variants share one kernel body:
   choice is a mask, not a gather); what the kernel adds to the XLA fold it
   replaced is each row's OWN blocks by DMA up to its OWN length, and no tile
   for a row that sees nothing. A masked call of one position a row folds a
-  row's ``group`` query rows alone (padded to 16), and waits for a whole tile
-  at once instead of block by block (at 16 KiB blocks: 7-9% and 10% of a
-  call, PERF.md, PR 64). The mask's absence is a static branch: a maskless
-  call builds the kernel it always did, operand for operand.
+  row's ``group`` query rows alone (padded to 16: at 16 KiB blocks 7-9% of a
+  call, PERF.md, PR 64); it waits as every call does. The mask's absence is
+  a static branch: a maskless call has the operands it always had.
 
 Masking follows the paged-decode contract exactly (``nn/attention.py``
 ``_paged_attention``): LOGICAL slot indices are the causal clock; slot
@@ -102,6 +121,7 @@ exercise the REAL kernel body, not a stand-in.
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import Optional
 
 import jax
@@ -135,6 +155,10 @@ def paged_kernel_interpret(platform: Optional[str] = None) -> bool:
 # in flight to approach the HBM rate, few enough that the float32 scores of
 # one KV head stay a few hundred KiB
 _TILE_TOKENS = 512
+# KV tokens one SUB-TILE holds, the unit a tile is waited for and folded in:
+# one lane row of scores, so a row that holds 300 lines folds three of a
+# tile's four and never waits for, unpacks or multiplies the fourth
+_SUB_TOKENS = 128
 # VMEM the two double-buffered pool tiles (K and V) may take together
 _TILE_VMEM_BYTES = 8 << 20
 # VMEM a kernel may take without asking (Mosaic's scoped default is 16 MiB);
@@ -167,11 +191,29 @@ def _blocks_per_tile(block_size: int, max_blocks: int, n_kv: int, h: int,
     return max(1, min(max_blocks, _TILE_TOKENS // block_size, by_vmem))
 
 
+def _blocks_per_sub(block_size: int, tile_blocks: int) -> int:
+    """Consecutive blocks of a tile one sub-tile takes: a function of the
+    shapes. ``_SUB_TOKENS`` tokens where whole blocks make them up and the
+    tile is whole sub-tiles; any other tile is its own one sub-tile."""
+    sub_blocks, rest = divmod(_SUB_TOKENS, block_size)
+    if rest or tile_blocks % sub_blocks:
+        return tile_blocks
+    return sub_blocks
+
+
 def kernel_tile_tokens(block_size: int, max_blocks: int, n_kv: int, h: int,
                        itemsize: int) -> int:
     """KV tokens one tile of the kernel holds at these shapes."""
     return block_size * _blocks_per_tile(
         block_size, max_blocks, n_kv, h, itemsize
+    )
+
+
+def kernel_sub_tokens(block_size: int, max_blocks: int, n_kv: int, h: int,
+                      itemsize: int) -> int:
+    """KV tokens one sub-tile of the kernel's tile holds at these shapes."""
+    return block_size * _blocks_per_sub(
+        block_size, _blocks_per_tile(block_size, max_blocks, n_kv, h, itemsize)
     )
 
 
@@ -186,42 +228,31 @@ def _unpack_head(words, i: int, packing: int):
     return (words << (24 - 8 * i)) >> 24
 
 
-def _for_each_head(k_tile, v_tile, fold) -> None:
-    """``fold(g, k, v)`` for every KV head ``g`` of two ``(tile, n_kv, h)``
-    VMEM tiles, ``k`` and ``v`` being the head's ``(tile, h)`` matrices.
+def _heads(tile_ref, width: int):
+    """``(g, matrix)`` for every KV head ``g`` of a ``(tile, n_kv, h)`` VMEM
+    tile, the ``(width, h)`` matrix of the head's first ``width`` tokens.
 
-    Heads are the tiles' second-minor dim, so one head's rows lie ``n_kv``
+    Heads are the tile's second-minor dim, so one head's rows lie ``n_kv``
     apart: a sublane-strided load, which Mosaic has for 32-bit words only.
     Narrower pools pack 2 (bf16) or 4 (int8) consecutive heads of a token
-    into a word, so the tile is read as words, a column of words at a time
-    (a loop unrolled when lowered: ``fold`` is traced once a packed head,
-    not once a head), and each word's heads are unpacked with shifts. A
-    head count the packing does not divide (an int8 pool sharded down to 2
-    heads) reads head by head."""
-    tile, n_kv, h = k_tile.shape
-    packing = 4 // k_tile.dtype.itemsize
+    into a word, so the tile is read as words, a column of words at a time,
+    and each word's heads are unpacked with shifts. A head count the packing
+    does not divide (an int8 pool sharded down to 2 heads) reads head by
+    head."""
+    tile, n_kv, h = tile_ref.shape
+    packing = 4 // tile_ref.dtype.itemsize
     if n_kv % packing:
         for g in range(n_kv):
-            fold(g, k_tile[:, g, :], v_tile[:, g, :])
+            yield g, tile_ref[pl.ds(0, width), g, :]
         return
-    words = [buf.reshape(tile * n_kv, h) for buf in (k_tile, v_tile)]
+    words = tile_ref.reshape(tile * n_kv, h)
     if packing > 1:
-        words = [buf.bitcast(jnp.int32) for buf in words]
+        words = words.bitcast(jnp.int32)
     columns = n_kv // packing
-
-    def column(j, carry):
-        k_words, v_words = (
-            buf[pl.ds(j, tile, stride=columns), :] for buf in words
-        )
+    for j in range(columns):
+        column = words[pl.ds(j, width, stride=columns), :]
         for i in range(packing):
-            fold(
-                j * packing + i,
-                _unpack_head(k_words, i, packing),
-                _unpack_head(v_words, i, packing),
-            )
-        return carry
-
-    jax.lax.fori_loop(0, columns, column, 0, unroll=True)
+            yield j * packing + i, _unpack_head(column, i, packing)
 
 
 def _paged_attention_kernel(
@@ -238,6 +269,7 @@ def _paged_attention_kernel(
     *rest,        # [scale_k_ref, scale_v_ref,] [chosen_ref,] o_ref, the scratch
     block_size: int,
     tile_blocks: int,
+    sub_blocks: int,
     sm_scale: float,
     group: int,
     m_short: int,
@@ -254,7 +286,7 @@ def _paged_attention_kernel(
     o_ref, k_buf, v_buf, sems, m_ref, l_ref, acc_ref = rest
     pools = ((pool_k_ref, k_buf), (pool_v_ref, v_buf))
     _, n_kv, m_full, _ = q_ref.shape
-    tile = tile_blocks * block_size
+    tile, sub = tile_blocks * block_size, sub_blocks * block_size
     row = pl.program_id(0)
     valid_len = valid_ref[row]
     base = base_ref[row]
@@ -296,31 +328,31 @@ def _paged_attention_kernel(
 
         jax.lax.fori_loop(0, blocks_held(t, of_row), one, 0)
 
-    def wait_tile(t, slot):
+    def wait_tile(t, slot, subs: int):
+        """Wait for what tile ``t`` holds of the row's blocks when that is
+        more than ``subs - 1`` and at most ``subs`` sub-tiles: the row's own
+        count, whoever started them. A wait counts the bytes of its shape
+        only: whole sub-tiles are ONE wait a pool by their bytes (a tenth off
+        a call at 16 KiB blocks: PERF.md, PR 64), and any block stands for a
+        block of the part-held last one."""
+        def at_once(whole: int):
+            for which, (_, buf) in enumerate(pools):
+                part = buf.at[slot, pl.ds(0, whole * sub)]
+                pltpu.make_async_copy(part, part, sems.at[slot, which]).wait()
+
         def one(i, carry):
-            # a wait counts the bytes of its shape only: any block will do
             for copy in block_copies(0, i, slot):
                 copy.wait()
             return carry
 
-        if not masked:
-            jax.lax.fori_loop(0, blocks_held(t), one, 0)
-            return
-        # a whole tile is waited for at once, by its bytes: a tenth off a
-        # call at 16 KiB blocks (PERF.md, PR 64); the maskless rows' turn
-        # is ROADMAP S11's
-        whole = blocks_held(t) == tile_blocks
+        rest = blocks_held(t) - (subs - 1) * sub_blocks
+        pl.when(rest == sub_blocks)(lambda: at_once(subs))
 
-        @pl.when(whole)
-        def _at_once():
-            for which, (_, buf) in enumerate(pools):
-                pltpu.make_async_copy(
-                    buf.at[slot], buf.at[slot], sems.at[slot, which]
-                ).wait()
-
-        @pl.when(jnp.logical_not(whole))
-        def _block_by_block():
-            jax.lax.fori_loop(0, blocks_held(t), one, 0)
+        @pl.when(rest < sub_blocks)
+        def _the_last_block_by_block():
+            if subs > 1:
+                at_once(subs - 1)
+            jax.lax.fori_loop(0, rest, one, 0)
 
     m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -338,13 +370,84 @@ def _paged_attention_kernel(
         None if q_ref.dtype == jnp.float32 else jax.lax.Precision.DEFAULT
     )
 
-    def attend(m_run: int):
-        """Fold every tile of the row into the first ``m_run`` folded
-        query rows' online softmax (running max, normaliser, accumulator:
-        float32)."""
+    def attend(m_run: int, by_sub: bool):
+        """Fold every tile of the row into the first ``m_run`` folded query
+        rows' online softmax (running max, normaliser, accumulator: float32).
+        ``by_sub``: the rows of few positions' way. Of a tile only the
+        sub-tiles that hold a visible slot are folded (one of the static
+        widths 1 .. 4 sub-tiles, ONE softmax update a tile), and all KV heads
+        are folded together: every head's scores first, one update over the
+        stacked scores, then every head's values. Head by head the update is
+        a chain of a dozen dependent steps on two registers of scores, and a
+        call spent more time in those chains than in its DMAs (PERF.md, PR
+        69). The full-width way folds the whole tile a head at a time: its
+        scores are ``m_run`` rows deep already."""
         q_slot = base + jax.lax.broadcasted_iota(
             jnp.int32, (m_run, 1), 0
         ) // group
+
+        def fold_span(slot, first, width: int):
+            """The first ``width`` tokens of the tile in buffer ``slot``,
+            the row's slots ``[first, first + width)``."""
+            kv_slot = first + jax.lax.broadcasted_iota(
+                jnp.int32, (1, width), 1
+            )
+            allowed = (kv_slot < valid_len) & (kv_slot <= q_slot)
+            if quantized or masked:
+                span = pl.ds(pl.multiple_of(first, tile), width)
+            if masked:
+                allowed = allowed & (chosen_ref[0, :, span] != 0)
+            keys = _heads(k_buf.at[slot], width)
+            values = _heads(v_buf.at[slot], width)
+            together = n_kv if by_sub else 1
+            for g0 in range(0, n_kv, together):
+                scores = []
+                for g, k in itertools.islice(keys, together):
+                    # (s_q * group, h) @ (h, width): one MXU matmul a KV head
+                    qk = jax.lax.dot_general(
+                        q_ref[0, g, :m_run, :], k.astype(q_ref.dtype),
+                        (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                        precision=precision,
+                    ) * sm_scale
+                    if quantized:
+                        # int8 is exact in the queries' dtype; the writer's
+                        # kv_quantize_int8 scales, one a slot, go onto the
+                        # products: q . (k * scale) = (q . k) * scale
+                        qk = qk * scale_k_ref[0, pl.ds(g, 1), span]
+                    scores.append(qk)
+                # a head alone stays the matrix it is: stacked, a chunk
+                # row's scores would be copied (Laguna's: 3 MiB a head)
+                alone = together == 1
+                at = g0 if alone else pl.ds(g0, together)
+                scores = scores[0] if alone else jnp.stack(scores)
+                scores = jnp.where(allowed, scores, -jnp.inf)
+                # online softmax: all-masked spans keep m at -inf; the safe
+                # shift avoids exp(-inf - -inf) = nan without branching
+                m_old = m_ref[at, :m_run]
+                m_new = jnp.maximum(
+                    m_old, scores.max(axis=-1, keepdims=True)
+                )
+                m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+                p = jnp.exp(scores - m_safe)
+                alpha = jnp.exp(m_old - m_safe)
+                l_ref[at, :m_run] = (
+                    alpha * l_ref[at, :m_run] + p.sum(axis=-1, keepdims=True)
+                )
+                m_ref[at, :m_run] = m_new
+                for g, v in itertools.islice(values, together):
+                    p_g, alpha_g = (
+                        (p, alpha) if alone else (p[g - g0], alpha[g - g0])
+                    )
+                    if quantized:
+                        p_g = p_g * scale_v_ref[0, pl.ds(g, 1), span]
+                    acc_ref[g, :m_run] = (
+                        alpha_g * acc_ref[g, :m_run] + jnp.dot(
+                            p_g.astype(q_ref.dtype), v.astype(q_ref.dtype),
+                            preferred_element_type=jnp.float32,
+                            precision=precision,
+                        )
+                    )
 
         def one_tile(t, carry):
             slot = (tiles_before + t) % 2
@@ -362,64 +465,32 @@ def _paged_attention_kernel(
             def _prefetch_next_row():
                 start_tile(0, 1 - slot, next_row)
 
-            # the row's own count, whoever started the tile
-            wait_tile(t, slot)
-            kv_slot = t * tile + jax.lax.broadcasted_iota(
-                jnp.int32, (1, tile), 1
-            )
-            allowed = (kv_slot < valid_len) & (kv_slot <= q_slot)
-            if quantized or masked:
-                span = pl.ds(pl.multiple_of(t * tile, tile), tile)
-            if masked:
-                allowed = allowed & (chosen_ref[0, :, span] != 0)
-            def fold(g, k, v):
-                q = q_ref[0, g, :m_run, :]
-                k, v = k.astype(q.dtype), v.astype(q.dtype)
-                # (s_q * group, h) @ (h, tile): one MXU matmul a KV head
-                scores = jax.lax.dot_general(
-                    q, k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32, precision=precision,
-                ) * sm_scale
-                if quantized:
-                    # int8 is exact in the queries' dtype; the writer's
-                    # kv_quantize_int8 scales, one a slot, go onto the
-                    # products: q . (k * scale) = (q . k) * scale
-                    scores = scores * scale_k_ref[0, pl.ds(g, 1), span]
-                scores = jnp.where(allowed, scores, -jnp.inf)
-                # online softmax: all-masked tiles keep m at -inf; the safe
-                # shift avoids exp(-inf - -inf) = nan without branching
-                m_old = m_ref[g, :m_run]
-                m_new = jnp.maximum(
-                    m_old, scores.max(axis=-1, keepdims=True)
-                )
-                m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
-                p = jnp.exp(scores - m_safe)
-                alpha = jnp.exp(m_old - m_safe)
-                l_ref[g, :m_run] = (
-                    alpha * l_ref[g, :m_run] + p.sum(axis=-1, keepdims=True)
-                )
-                if quantized:
-                    p = p * scale_v_ref[0, pl.ds(g, 1), span]
-                acc_ref[g, :m_run] = alpha * acc_ref[g, :m_run] + jnp.dot(
-                    p.astype(v.dtype), v, preferred_element_type=jnp.float32,
-                    precision=precision,
-                )
-                m_ref[g, :m_run] = m_new
+            # the sub-tiles that hold a slot the row can see: one branch a
+            # count, its wait and its fold of a static width
+            subs_held = pl.cdiv(jnp.minimum(valid_len - t * tile, tile), sub)
+            for subs in range(1, tile // sub + 1):
+                @pl.when(subs_held == subs)
+                def _held():
+                    wait_tile(t, slot, subs)
+                    if by_sub:
+                        fold_span(slot, t * tile, subs * sub)
 
-            _for_each_head(k_buf.at[slot], v_buf.at[slot], fold)
+            if not by_sub:
+                fold_span(slot, t * tile, tile)
             return carry
 
         jax.lax.fori_loop(0, num_tiles, one_tile, 0)
 
     if single:
         # every row of the call brings ONE position: its group's rows
-        attend(m_short)
+        attend(m_short, True)
     elif m_short < m_full:
         few = valid_len - base <= _SHORT_QUERIES
-        pl.when(few)(lambda: attend(m_short))
-        pl.when(jnp.logical_not(few))(lambda: attend(m_full))
+        pl.when(few)(lambda: attend(m_short, True))
+        pl.when(jnp.logical_not(few))(lambda: attend(m_full, False))
     else:
-        attend(m_full)
+        # a query block this narrow is the short path's own
+        attend(m_full, True)
 
     l = l_ref[...]
     # folded rows that saw no slot (an inactive row, or the rows the short
@@ -556,6 +627,7 @@ def _paged_call(
     assert n == n_kv * group, (n, n_kv, group)
     quantized, masked = scale_k is not None, chosen is not None
     tile = tile_blocks * block_size
+    sub_blocks = _blocks_per_sub(block_size, tile_blocks)
     # fold the GQA group into the matmul's rows: per KV head the queries are
     # (s_pad * group, h), position-major, so the first positions of a row
     # are the first folded rows. 16 rows fill a packed bf16 register.
@@ -626,7 +698,8 @@ def _paged_call(
     )
     kernel = functools.partial(
         _paged_attention_kernel,
-        block_size=block_size, tile_blocks=tile_blocks, sm_scale=sm_scale,
+        block_size=block_size, tile_blocks=tile_blocks,
+        sub_blocks=sub_blocks, sm_scale=sm_scale,
         group=group, m_short=m_short, quantized=quantized, masked=masked,
         single=single,
     )

@@ -73,7 +73,7 @@ from ..nn.base_layer import state_views
 from ..nn.latent_paged_attention import latent_tile_tokens
 from ..nn.sparse_latent_attention import index_tile_tokens
 from ..nn.mamba import RecurrentStateView, split_capacity
-from ..nn.paged_attention import kernel_tile_tokens
+from ..nn.paged_attention import kernel_sub_tokens, kernel_tile_tokens
 from ..resilience.faults import get_fault_plan
 from .kvcache import (
     PagedKVPools,
@@ -418,20 +418,23 @@ class ServeEngine:
                 "drafts has not been held to the reference; set spec_k=0")
         # KV tokens a tile of the paged kernel holds, at a shard's heads (a
         # sparse latent layer: index keys one step of a row's score loop
-        # multiplies)
+        # multiplies), and a SUB-TILE of it, the unit the paged kernel waits
+        # for and folds (the other two fold whole tiles)
         if self.sparse_layers:
-            self._kv_tile = index_tile_tokens(
+            self._kv_tile = self._kv_sub = index_tile_tokens(
                 self.config.block_size, self.config.max_blocks_per_seq)
         elif self.pools.pool_k[0].ndim == 3:   # a line without a head axis
-            self._kv_tile = latent_tile_tokens(
+            self._kv_tile = self._kv_sub = latent_tile_tokens(
                 self.config.block_size, self.config.max_blocks_per_seq)
         else:
             _, _, n_kv, head = self.pools.pool_k[0].shape
-            self._kv_tile = kernel_tile_tokens(
+            shapes = (
                 self.config.block_size, self.config.max_blocks_per_seq,
                 n_kv // self.model_parallel, head,
                 self.pools.pool_k[0].dtype.itemsize,
             )
+            self._kv_tile = kernel_tile_tokens(*shapes)
+            self._kv_sub = kernel_sub_tokens(*shapes)
         n = self.config.num_slots
         # the ONE host operand of a tick and where its fields lie
         self._layout = TickLayout(n, self.config.max_blocks_per_seq)
@@ -1211,7 +1214,8 @@ class ServeEngine:
         np = self._np
         # rows that hold a visible slot and the paged kernel's tiles
         # among them: of a call's kv_tiles fetches, kv_rows - 1 are
-        # first tiles that start under another row's fold
+        # first tiles that start under another row's fold; kv_subtiles
+        # of the tiles' sub-tiles hold a slot, and only those are folded
         held = ctx + new_lens
         # the predicate of the program's sampler (sample_rows), known
         # before the call: the rows that bring a token and a temperature
@@ -1221,6 +1225,7 @@ class ServeEngine:
             sampled_rows=sampled_rows,
             kv_rows=int(np.count_nonzero(held)),
             kv_tiles=int((-(-held // self._kv_tile)).sum()),
+            kv_subtiles=int((-(-held // self._kv_sub)).sum()),
         )
         self.sampled_ticks += sampled_rows > 0
         self._counter(

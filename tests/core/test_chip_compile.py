@@ -126,7 +126,7 @@ def test_paged_kernel_compiles_at_group_16_over_2_kv_heads(one_chip, s):
     """``serve-nemotron3nano-reason-burst``'s attention: 32 query heads over
     2 KV heads x 128, 64 slots of 40 blocks. 2 KV heads are under a sublane
     tile: a bf16 pool packs them into ONE 32-bit word a token and the kernel
-    reads that word's column (``_for_each_head``), which Mosaic takes. An
+    reads that word's column (``_heads``), which Mosaic takes. An
     int8 pool of 2 heads it refuses (a slice of 2 along a dimension tiled by
     4): the cell serves the native dtype, and `kv_dtype='int8'` at 2 KV
     heads is an open item (PERF.md section 7)."""
@@ -165,6 +165,15 @@ def test_paged_kernel_compiles_at_a_chunk_of_256_and_group_6(one_chip):
     the call asks for what its blocks need (``vmem_limit_bytes``), which the
     cells' narrower calls never do (their kernels are built as they were)."""
     compile_paged_kernel(one_chip, 24, 48, 8, 2048, 256, "native", head_dim=128)
+
+
+def test_paged_kernel_compiles_at_group_6_over_8_kv_heads(one_chip):
+    """Laguna's full layer again, at a query block of 32: the sub-tiles' loop
+    (a strided load of a head's words from a start that is no constant) at a
+    group of 6, whose 48 folded rows of a decode row are no power of two. The
+    cell's own block of 256 is the case above: 13-19 s of Mosaic, too dear to
+    have twice in tier-1; this width compiles in ~2 s."""
+    compile_paged_kernel(one_chip, 24, 48, 8, 2048, 32, "native", head_dim=128)
 
 
 def kernel_operands(lowered) -> int:

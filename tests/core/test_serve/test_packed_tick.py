@@ -424,6 +424,52 @@ def test_the_span_counts_the_rows_and_tiles_the_kernel_reads(
         len(held), sum(-(-h // 32) for h in held))
 
 
+# ---------- the sub-tiles of those tiles that a tick's rows hold (ISSUE 69)
+@pytest.fixture(scope="module")
+def sub_tiled_ticks(models, tmp_path_factory):
+    """A prompt of 130 tokens streaming in beside a short request, the paged
+    kernel's tile shrunk to 64 tokens (4 blocks) and its sub-tile to one block
+    of 16, so that a row grows from one sub-tile to nine while the other keeps
+    its one: the ``serve.mixed`` span fields of the first six ticks."""
+    from scaling_tpu.nn import paged_attention
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(paged_attention, "_TILE_TOKENS", 64)
+        patch.setattr(paged_attention, "_SUB_TOKENS", 16)
+        engine = make_engine(models["dense"], max_blocks_per_seq=12,
+                             num_blocks=SLOTS * 12 + 1)
+        assert (engine._kv_tile, engine._kv_sub) == (64, 16)
+        rng = np.random.default_rng(69)
+        obs.start_capture(tmp_path_factory.mktemp("subtiled") / "trace")
+        try:
+            engine.submit(list(rng.integers(1, 60, 130)), 3)
+            engine.submit(list(rng.integers(1, 60, 5)), 8)
+            for _ in range(6):
+                engine.tick()
+        finally:
+            capture = obs.stop_capture()
+    return [f for name, _, _, f in capture.spans if name == "serve.mixed"]
+
+
+@pytest.mark.parametrize("tick,held", [
+    (0, (32, 5)),     # two sub-tiles of the long row's first tile, one of the short's
+    (1, (64, 6)),     # its first tile whole
+    (2, (96, 7)),     # half of its second
+    (3, (128, 8)),    # two whole tiles: nothing of them is left out
+    (4, (130, 9)),    # the ninth sub-tile: a quarter of a third tile
+    (5, (131, 10)),   # both rows decode
+])
+def test_the_span_counts_the_sub_tiles_the_kernel_folds(
+    sub_tiled_ticks, tick, held
+):
+    """``kv_subtiles`` over 4 x ``kv_tiles`` is the share of the tiles' fold
+    that the kernel's sub-tiles leave."""
+    fields = sub_tiled_ticks[tick]
+    assert fields["kv_tiles"] == sum(-(-h // 64) for h in held)
+    assert fields["kv_subtiles"] == sum(-(-h // 16) for h in held)
+    assert fields["kv_subtiles"] <= 4 * fields["kv_tiles"]
+
+
 # ------------- the tick says which branch its sampler takes (ISSUE 47)
 @pytest.fixture(scope="module")
 def sampler_ticks(models, tmp_path_factory):

@@ -307,6 +307,26 @@ def test_blocks_per_tile_is_a_function_of_the_shapes(
         block_size, max_blocks, n_kv, h, itemsize) == expect
 
 
+@pytest.mark.parametrize(
+    "block_size,tile_blocks,expect",
+    [
+        (16, 32, 8),     # every cell: four sub-tiles of 128 tokens a tile
+        (16, 8, 8),      # a slot of 128 tokens: the tile is one sub-tile
+        (16, 12, 12),    # 8 does not divide 12: the tile is its own sub-tile
+        (16, 3, 3),
+        (32, 16, 4),
+        (128, 4, 1),
+        (256, 2, 2),     # a block larger than a sub-tile
+        (48, 8, 8),      # blocks that do not make up 128 tokens
+        (4, 2, 2),       # the CPU tests' tiles of 8 tokens
+    ],
+)
+def test_blocks_per_sub_is_a_function_of_the_shapes(
+    block_size, tile_blocks, expect
+):
+    assert paged_attention._blocks_per_sub(block_size, tile_blocks) == expect
+
+
 # ---- ISSUE 41: the double buffer runs over the call's flat list of (row,
 # tile) steps. Tiles of 8 tokens (2 blocks of 4), so contexts of 1-8 / 9-16
 # / 17-24 tokens are rows of 1 / 2 / 3 tiles; a row's first tile lies in
@@ -412,5 +432,81 @@ def test_what_crosses_a_grid_step_is_a_function_of_valid_len(
 
 
 # ---- ISSUE 64: a mask operand. A row's choice of slots, one lane-dense strip
-# a row; the rows of ONE position fold their group's rows alone and wait for a
-# whole tile at once
+# a row; the rows of ONE position fold their group's rows alone
+# (``test_paged_kernel_masks_and_lanes.py``)
+
+
+# ---- ISSUE 69: a tile is waited for and folded in SUB-TILES of 128 tokens, up
+# to the last one that holds a slot the row can see. Blocks of 16 tokens at the
+# tile the shapes derive (no monkeypatch): 40 blocks a row are tiles of 32
+# blocks = 512 tokens = four sub-tiles of 8 blocks; 12 blocks a row are ONE tile
+# of 12 blocks, which 8 does not divide: that tile is its own one sub-tile.
+
+SUB_TILE_ROWS = {
+    # max_blocks, valid_len per row (0: inactive); the SECOND row's K and V
+    # are large, and it leaves them in both buffers' tails for the rows after
+    "sub-tiles-of-128": (40, [
+        0, 640, 127, 128, 129, 0, 255, 256, 257, 383, 384, 385, 0, 511, 512,
+        513, 640, 0]),
+    "a-tile-of-12-blocks-is-its-own-sub-tile": (12, [
+        0, 192, 127, 128, 129, 0, 191, 192, 1, 0]),
+}
+LARGE = 1e4
+
+
+@pytest.mark.parametrize("kv_dtype", ["native", "int8"])
+@pytest.mark.parametrize("mask", ["maskless", "masked"])
+@pytest.mark.parametrize("rows", ["decode-s1", "decode-in-a-mixed-call",
+                                  "chunk"])
+@pytest.mark.parametrize("case", list(SUB_TILE_ROWS))
+def test_rows_end_at_and_around_every_sub_tile_boundary(
+    case, rows, mask, kv_dtype
+):
+    """A row that ends one slot before, on and one slot past each sub-tile's
+    edge reads every slot it holds and none it does not, on the short path
+    (alone in a call of one position, which under a mask folds its group's rows
+    alone, and beside chunk rows) and on the chunk rows' full-width path; what
+    the row before left in a buffer's unheld tail, large as it may be, meets a
+    probability of exactly 0."""
+    max_blocks, valid = SUB_TILE_ROWS[case]
+    tile_blocks = paged_attention._blocks_per_tile(16, max_blocks, 2, H, 4)
+    sub_blocks = paged_attention._blocks_per_sub(16, tile_blocks)
+    assert (tile_blocks, sub_blocks) == ((32, 8) if max_blocks == 40
+                                         else (12, 12))
+    s = 1 if rows == "decode-s1" else 16
+    valid = np.asarray(valid, np.int32)
+    new = np.minimum(valid, s if rows == "chunk" else 1)
+    # only the chunk rows are past the short path's positions
+    assert (rows == "chunk") == bool(
+        (new > paged_attention._SHORT_QUERIES).any())
+    rng = np.random.default_rng(69)
+    q, pk, pv, tab, ctx, new = tiled_case(
+        rng, block_size=16, max_blocks=max_blocks, n_kv=2, group=2, s=s,
+        ctx=valid - new, new_len=new,
+    )
+    big = slice(1 + max_blocks, 1 + 2 * max_blocks)   # the second row's blocks
+    pk, pv = pk.at[big].multiply(LARGE), pv.at[big].multiply(LARGE)
+    q = q.at[1].divide(LARGE)       # its own scores stay of order one
+    kwargs, ref_k, ref_v = {}, pk, pv
+    if kv_dtype == "int8":
+        pk, sk = kv_quantize_int8(pk)
+        pv, sv = kv_quantize_int8(pv)
+        kwargs = {"scale_k": sk, "scale_v": sv}
+        ref_k = pk.astype(jnp.float32) * sk[..., None]
+        ref_v = pv.astype(jnp.float32) * sv[..., None]
+    chosen = None
+    if mask == "masked":
+        chosen = rng.random((len(valid), max_blocks * 16)) < 0.5
+        # every query keeps its own slot, so no row chose nothing
+        chosen |= np.arange(max_blocks * 16) >= (np.asarray(ctx))[:, None]
+        chosen = kwargs["chosen"] = jnp.asarray(chosen)
+    out = check_rows(q, pk, pv, tab, ctx, new, 2, **kwargs)
+    ref = dense_reference(q, ref_k, ref_v, tab, ctx + new, ctx, 2, chosen)
+    for row, real in enumerate(np.asarray(new)):
+        scale = LARGE if row == 1 else 1.0
+        np.testing.assert_allclose(
+            np.asarray(out[row, :real]) / scale,
+            np.asarray(ref[row, :real]) / scale, atol=1e-5,
+            err_msg=f"row {row} holds {valid[row]} slots",
+        )
+    assert not bool(jnp.any(out[valid == 0]))

@@ -51,9 +51,10 @@ def closing_order(first, calls):
             out.append(("serve.retire", step))
         out.append(("serve.tick", step))
     return out
-# what serve.mixed's row held at the parent of ISSUE 57, for a dense model
+# what serve.mixed's row held at the parent of ISSUE 57, for a dense model,
+# and the sub-tiles its rows hold (ISSUE 69)
 MIXED_FIELDS = {"decodes", "chunks", "width", "tokens", "sampled_rows",
-                "kv_rows", "kv_tiles"}
+                "kv_rows", "kv_tiles", "kv_subtiles"}
 
 
 @pytest.fixture(scope="module")
