@@ -69,6 +69,10 @@ class LayerKind(Enum):
     # a rotary of its own beside the 'attention' layers' (nn/window_attention.py):
     # served, it keeps a RING of lines a slot, not pages
     WINDOW = "window"
+    # gated delta rule (nn/gated_delta.py): a recurrent layer whose transition
+    # is NOT diagonal (the state is read, corrected by what it holds for the
+    # key, written); served, it keeps a float32 state and a conv tail a slot
+    DELTA = "delta"
 
 
 class AttentionGate(Enum):
@@ -76,10 +80,14 @@ class AttentionGate(Enum):
     layers ('attention' and 'window'): none, or ``per_head``: ``g =
     sigmoid(x W_g)``, one value a query head from the layer's normed input,
     on the head's output before the output projection (the head-wise gate of
-    arXiv:2505.06708)."""
+    arXiv:2505.06708); or ``elementwise``: a value a LANE of every head's
+    output, ``sigmoid(gate)`` with ``[q | gate] = x W_q`` head by head, the
+    query projection twice as wide and no leaf of its own (Qwen3-Next's;
+    'attention' layers only)."""
 
     NONE = "none"
     PER_HEAD = "per_head"
+    ELEMENTWISE = "elementwise"
 
 
 class MoERouter(Enum):
@@ -244,6 +252,9 @@ LATENT_FIELDS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
 # what makes the 'latent' layers (nn/sparse_latent_attention.py) or, in a
 # stack without them, the 'attention' layers (nn/sparse_attention.py) SPARSE
 INDEX_FIELDS = ("index_n_heads", "index_head_dim", "index_topk")
+# what sizes a 'delta' layer (nn/gated_delta.py)
+DELTA_FIELDS = ("delta_num_key_heads", "delta_num_value_heads",
+                "delta_key_head_dim", "delta_value_head_dim")
 
 
 class TransformerArchitectureConfig(BaseConfig):
@@ -340,6 +351,9 @@ class TransformerArchitectureConfig(BaseConfig):
         "false); absent: no shared expert",
         gt=0,
     )
+    moe_shared_expert_gate: bool = Field(
+        False, description="the shared expert's output is scaled by "
+        "sigmoid(x w_s), one value a token (Qwen3-Next's shared_expert_gate)")
     moe_experts_first: int = Field(
         0,
         description="first expert this program holds: the routed layers hold "
@@ -442,6 +456,19 @@ class TransformerArchitectureConfig(BaseConfig):
         "[hc_res_clamp_min, hc_res_clamp_max] before the exponential (the "
         "published mhc_h_res_clamp_min / _max)")
     hc_res_clamp_max: float = Field(30.0, description="see hc_res_clamp_min")
+    delta_num_key_heads: Optional[int] = Field(
+        None, description="a 'delta' layer (nn/gated_delta.py): heads of q "
+        "and k (linear_num_key_heads); with the three sizes below, all four "
+        "or none; its conv has conv_kernel taps", gt=0)
+    delta_num_value_heads: Optional[int] = Field(
+        None, description="a 'delta' layer's heads of v, z and the state, a "
+        "multiple of delta_num_key_heads (linear_num_value_heads)", gt=0)
+    delta_key_head_dim: Optional[int] = Field(
+        None, description="lanes of a 'delta' layer's q and k head: the "
+        "state's rows (linear_key_head_dim)", gt=0)
+    delta_value_head_dim: Optional[int] = Field(
+        None, description="lanes of a 'delta' layer's v head: the state's "
+        "columns (linear_value_head_dim)", gt=0)
     mamba_num_heads: int = Field(
         64, description="heads of a Mamba-2 mixer; its inner width is "
         "mamba_num_heads * mamba_head_dim", gt=0)
@@ -642,6 +669,15 @@ class TransformerArchitectureConfig(BaseConfig):
                 "without them, its 'attention' layers")
         if self.hc_streams > 1:
             self._validate_hyper_connection()
+        if self.layernorm.weight_offset and self.norm_type != NormType.RMS:
+            raise ValueError(
+                "layernorm.weight_offset with norm_type "
+                f"{self.norm_type.value!r}: the offset-from-one weight is a "
+                "kind of the RMS norm")
+        if self.moe_shared_expert_gate and not self.moe_shared_expert_width:
+            raise ValueError(
+                "moe_shared_expert_gate without moe_shared_expert_width: the "
+                "gate scales the shared expert's output")
         if (self.attention_gate != AttentionGate.NONE
                 and self.layer_pattern is None):
             raise ValueError(
@@ -838,6 +874,23 @@ class TransformerArchitectureConfig(BaseConfig):
                 "rope_scaling without 'latent' or 'attention' layers: the "
                 "latent and the grouped-query attention mixers apply YaRN; a "
                 "'window' layer's rotary table takes the base frequencies")
+        given = [n for n in DELTA_FIELDS if getattr(self, n) is not None]
+        if LayerKind.DELTA in self.layer_pattern:
+            self._validate_delta(given)
+        elif given:
+            raise ValueError(
+                f"{given} without 'delta' layers in layer_pattern: they size "
+                "that kind alone")
+        if (self.attention_gate == AttentionGate.ELEMENTWISE
+                and (LayerKind.WINDOW in self.layer_pattern
+                     or self.index_topk is not None
+                     or (self.attention_qkv_in_one
+                         and self.attention_num_kv_heads is None))):
+            raise ValueError(
+                "attention_gate 'elementwise' with 'window' layers, with "
+                "index_* or with attention_qkv_in_one: the gate a lane comes "
+                "out of a doubled query projection of a plain 'attention' "
+                "layer; a window ring or a sparse choice under it is not built")
         if LayerKind.WINDOW in self.layer_pattern:
             self._validate_window()
         elif self.window_size is not None or self.window_num_attention_heads:
@@ -883,6 +936,30 @@ class TransformerArchitectureConfig(BaseConfig):
                 "single-mixer attention layer builds no per-head windows; a "
                 "window a layer is the pattern's 'window' kind")
 
+    def _validate_delta(self, given):
+        """What a 'delta' layer does not build, each by name."""
+        if len(given) < len(DELTA_FIELDS):
+            raise ValueError(
+                "layer_pattern with 'delta' layers needs "
+                f"{[n for n in DELTA_FIELDS if n not in given]}: a gated "
+                "delta-rule layer is sized by delta_num_key_heads, "
+                "delta_num_value_heads, delta_key_head_dim and "
+                "delta_value_head_dim")
+        if self.delta_num_value_heads % self.delta_num_key_heads:
+            raise ValueError(
+                f"delta_num_value_heads {self.delta_num_value_heads} is not "
+                f"a multiple of delta_num_key_heads {self.delta_num_key_heads}")
+        for kind in (LayerKind.MAMBA, LayerKind.CONV, LayerKind.WINDOW,
+                     LayerKind.LATENT):
+            if kind in self.layer_pattern:
+                raise ValueError(
+                    f"'delta' layers beside '{kind.value}' layers: two kinds "
+                    "of line a slot (or a latent line) in one stack have not "
+                    "been held to a reference; not supported")
+        if self.hc_streams > 1:
+            raise ValueError(
+                "'delta' layers with hc_streams > 1: not held to a reference")
+
     def _validate_window(self):
         """What a 'window' layer does not build, each by name."""
         if self.window_size is None:
@@ -927,10 +1004,15 @@ class TransformerArchitectureConfig(BaseConfig):
                 "reference yet (the mixer would build it: "
                 "nn/window_attention.py); set it false")
 
-    def refuse_paged_serving(self) -> None:
+    def refuse_paged_serving(self, kv_dtype: str = "native") -> None:
         """What the paged serving engine does not serve of an architecture
         that trains and runs uncached, by name, before anything is traced
         (``ServeEngine`` calls it)."""
+        if self.delta_layers and kv_dtype != "native":
+            raise ValueError(
+                f"kv_dtype {kv_dtype!r} with 'delta' layers: an int8 pool "
+                "beside a float32 recurrent state has not been held to a "
+                "reference; use kv_dtype='native'")
         if self.num_local_attention_heads:
             raise ValueError(
                 "num_local_attention_heads with the paged serving engine: "
@@ -942,6 +1024,11 @@ class TransformerArchitectureConfig(BaseConfig):
     def window_layers(self) -> int:
         """Layers whose mixer is windowed attention (a ring a slot)."""
         return (self.layer_pattern or []).count(LayerKind.WINDOW)
+
+    @property
+    def delta_layers(self) -> int:
+        """Layers whose mixer is the gated delta rule (a line a slot)."""
+        return (self.layer_pattern or []).count(LayerKind.DELTA)
 
     @property
     def latent_layers(self) -> int:

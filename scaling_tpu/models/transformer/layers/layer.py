@@ -105,6 +105,7 @@ def routed_mlp(arch: TransformerArchitectureConfig) -> BaseLayer:
         router=arch.moe_router.value,
         routed_scaling_factor=arch.moe_routed_scaling_factor,
         shared_expert_width=arch.moe_shared_expert_width,
+        shared_expert_gate=arch.moe_shared_expert_gate,
         experts_first=arch.moe_experts_first,
         experts_held=arch.moe_experts_held,
         n_group=arch.moe_n_group,
@@ -161,6 +162,25 @@ def mamba_mixer(arch: TransformerArchitectureConfig) -> Mamba2Mixer:
     )
 
 
+def delta_mixer(arch: TransformerArchitectureConfig) -> BaseLayer:
+    """The gated delta-rule mixer the configuration describes
+    (``nn/gated_delta.py``, imported where a 'delta' layer is built: no other
+    stack's set-up pays for it)."""
+    from ....nn.gated_delta import GatedDeltaMixer
+
+    return GatedDeltaMixer(
+        hidden_size=arch.hidden_size,
+        num_key_heads=arch.delta_num_key_heads,
+        num_value_heads=arch.delta_num_value_heads,
+        key_head_dim=arch.delta_key_head_dim,
+        value_head_dim=arch.delta_value_head_dim,
+        conv_kernel=arch.conv_kernel,
+        norm_eps=arch.layernorm.layernorm_epsilon,
+        time_step_min=arch.time_step_min, time_step_max=arch.time_step_max,
+        time_step_floor=arch.time_step_floor, dtype=arch.dtype,
+    )
+
+
 class MixerLayer(BaseLayer):
     """A layer of a ``layer_pattern`` stack: ONE norm, ONE mixer of the
     layer's kind, the residual: ``x <- x + Mixer(Norm(x))`` (Nemotron-H's
@@ -187,6 +207,8 @@ class MixerLayer(BaseLayer):
             index_topk=arch.index_topk)
         if self.kind == LayerKind.MAMBA:
             self.mixer: BaseLayer = mamba_mixer(arch)
+        elif self.kind == LayerKind.DELTA:
+            self.mixer = delta_mixer(arch)
         elif self.kind == LayerKind.MOE:
             self.mixer = routed_mlp(arch)
         elif self.kind == LayerKind.CONV:
@@ -237,6 +259,8 @@ class MixerLayer(BaseLayer):
                 )
             own = {} if sparse else dict(
                 output_gate=arch.attention_gate == AttentionGate.PER_HEAD)
+            if arch.attention_gate == AttentionGate.ELEMENTWISE:
+                own["lane_gate"] = True   # config.py: plain 'attention' alone
             if window:
                 own["window_size"] = arch.window_size
             self.mixer = (SparseSelfAttention if sparse
@@ -317,7 +341,7 @@ class MixerLayer(BaseLayer):
 
         scaled = multiplied   # float32 product, the leaf's dtype back
 
-        if self.kind in (LayerKind.MAMBA, LayerKind.CONV):
+        if self.kind in (LayerKind.MAMBA, LayerKind.CONV, LayerKind.DELTA):
             mixer["out_proj"]["weight"] = scaled(mixer["out_proj"]["weight"], scale)
         elif self.kind == LayerKind.MLP:
             out = "down_proj" if "down_proj" in mixer else "dense_out"
@@ -410,8 +434,11 @@ class MixerLayer(BaseLayer):
             # (nor does one beside window layers: ``full_attn`` there, so that
             # each kind's share of a tick can be read)
             sparse = isinstance(self.mixer, SparseSelfAttention)
+            # (nor does one beside delta layers: ``gated_attn`` there)
+            arch = self.architecture
             scope = ("attn" if sparse else
-                     "full_attn" if self.architecture.window_layers else None)
+                     "full_attn" if arch.window_layers else
+                     "gated_attn" if arch.delta_layers else None)
             with jax.named_scope(scope) if scope else contextlib.nullcontext():
                 y = self.mixer(
                     params["mixer"], normed, ctx,

@@ -344,6 +344,7 @@ class ParallelSelfAttention(BaseLayer):
         head_dim: Optional[int] = None,
         key_multiplier: float = 1.0,
         output_gate: bool = False,
+        lane_gate: bool = False,
     ):
         assert head_dim is not None or hidden_size % num_attention_heads == 0, (
             f"hidden size ({hidden_size}) must be divisible by "
@@ -395,7 +396,11 @@ class ParallelSelfAttention(BaseLayer):
             )
         else:
             kv_size = self.num_kv_heads * self.head_dim
-            self.query = ColumnParallelLinear(hidden_size, width, parallel_output=True, **common)
+            # with a gate a LANE the query projection is doubled: a head's
+            # query, then its gate's logits (``lane_gate``, below)
+            self.query = ColumnParallelLinear(
+                hidden_size, width * (2 if lane_gate else 1),
+                parallel_output=True, **common)
             self.key = ColumnParallelLinear(hidden_size, kv_size, parallel_output=True, **common)
             self.value = ColumnParallelLinear(hidden_size, kv_size, parallel_output=True, **common)
 
@@ -405,6 +410,13 @@ class ParallelSelfAttention(BaseLayer):
         # a per-head gate on the heads' output, before ``dense``: g =
         # sigmoid(x W_g), one value a query head, from the layer's input (the
         # head-wise gate of arXiv:2505.06708); no bias
+        # or a gate a LANE of every head's output, ``g = sigmoid(gate)`` with
+        # ``[q | gate] = x W_q`` head by head (Qwen3-Next's attention): no leaf
+        # of its own, the query projection is twice as wide
+        self.lane_gate = lane_gate
+        assert not lane_gate or not (output_gate or qkv_in_one), (
+            "a gate a lane comes out of a query projection of its own and is "
+            "the layer's one gate")
         self.gate = None
         if output_gate:
             self.gate = ColumnParallelLinear(
@@ -506,7 +518,8 @@ class ParallelSelfAttention(BaseLayer):
             qkv = qkv.reshape(b, s, self.num_attention_heads, 3 * self.head_dim)
             q, k, v = jnp.split(qkv, 3, axis=-1)
         else:
-            q = self.query(params["query"], x, ctx).reshape(b, s, self.num_attention_heads, self.head_dim)
+            q = self.query(params["query"], x, ctx).reshape(
+                b, s, self.num_attention_heads, -1)  # with a lane gate: 2 head_dim
             k = self.key(params["key"], x, ctx).reshape(b, s, self.num_kv_heads, self.head_dim)
             v = self.value(params["value"], x, ctx).reshape(b, s, self.num_kv_heads, self.head_dim)
         # LoRA deltas
@@ -530,12 +543,16 @@ class ParallelSelfAttention(BaseLayer):
         return q, k, v
 
     def _heads(self, params: dict, x: jax.Array, ctx: ForwardContext,
-               position_ids):
+               position_ids, lane_gate: bool = False):
         """``(q (b, s, n, h), k (b, s, n_kv, h), v (b, s, n_kv, h))`` as the
         attention meets them: projected, the key's multiplier, the key/query
-        norm and rotary applied."""
+        norm and rotary applied. ``lane_gate``: a fourth, the gate's logits
+        (b, s, n, h) that came out of the query projection beside q."""
         b, s, _ = x.shape
         q, k, v = self._qkv(params, x, ctx)
+        gate = None
+        if self.lane_gate:
+            q, gate = q[..., :self.head_dim], q[..., self.head_dim:]
         k = multiplied(k, self.key_multiplier)
 
         if self.key_query_norm and self.key_query_norm_over_projection:
@@ -554,7 +571,7 @@ class ParallelSelfAttention(BaseLayer):
 
         if self.rotary_embedding is not None:
             q, k = self.rotary_embedding(q, k, position_ids, position_ids)
-        return q, k, v
+        return (q, k, v, gate) if lane_gate else (q, k, v)
 
     def __call__(
         self,
@@ -570,7 +587,11 @@ class ParallelSelfAttention(BaseLayer):
         return_kv: bool = False,
     ):
         b, s, _ = x.shape
-        q, k, v = self._heads(params, x, ctx, position_ids)
+        if self.lane_gate:
+            # what the epilogue's gate reads is then the logits, not the input
+            q, k, v, x = self._heads(params, x, ctx, position_ids, lane_gate=True)
+        else:
+            q, k, v = self._heads(params, x, ctx, position_ids)
 
         new_kv = (k, v) if return_kv else None
 
@@ -922,7 +943,11 @@ class ParallelSelfAttention(BaseLayer):
     def _project_out(self, params, out, ctx, b, s, new_kv, x=None):
         """Shared epilogue: heads -> hidden, dense projection + LoRA delta;
         with a gate, each head's output times its gate first (``x``: the
-        layer's input, which the gate reads)."""
+        layer's input, which the gate reads; of a gate a lane its logits)."""
+        if self.lane_gate:
+            with jax.named_scope("gate"):
+                g = jax.nn.sigmoid(x.astype(jnp.float32))
+                out = (out.reshape(g.shape).astype(jnp.float32) * g).astype(out.dtype)
         if self.gate is not None:
             with jax.named_scope("gate"):
                 g = jax.nn.sigmoid(

@@ -1,0 +1,531 @@
+"""Gated delta-rule mixer (Yang et al. 2024, "Gated Delta Networks",
+arXiv:2412.06464): ``jax.numpy``, and a Pallas kernel for the single step.
+
+The linear-attention layer of the Qwen3-Next family. ``x`` (.., H) is the
+normed residual stream; ``nk`` key heads of ``dk`` lanes, ``nv`` value heads
+of ``dv`` lanes, value head ``j`` reads key head ``j // (nv / nk)``:
+
+- ``[q | k | v | z] = x W_in`` (``nk dk | nk dk | nv dv | nv dv`` columns, no
+  bias), ``[b | a] = x W_ba`` (``nv | nv``). The columns lie kind by kind; a
+  released checkpoint orders them by key head, and the benchmark's view
+  (``benchmark/views/gdn_moe_decoder.py``) is where the two orders meet;
+- ``c = silu(causal depthwise conv_K([q | k | v]))``, no bias;
+- ``beta = sigmoid(b)``, ``g = -exp(A_log) * softplus(a + dt_bias)``, a value a
+  value head, float32;
+- ``q <- q rsqrt(sum q^2 + 1e-6) dk^-0.5``, ``k <- k rsqrt(sum k^2 + 1e-6)``;
+- a head's state ``S`` (``dk x dv``, float32, from zeros) is read, corrected
+  by what it already holds for the key, and written:
+  ``S~ = exp(g_t) S_{t-1}``, ``u_t = beta_t (v_t - S~^T k_t)``,
+  ``S_t = S~ + k_t u_t^T``, ``o_t = S_t^T q_t``. The transition ``exp(g) (I -
+  beta k k^T)`` is NOT diagonal: Mamba-2's closed form (``nn/mamba.py``
+  ``ssd_chunk``) does not hold;
+- ``y = w_n (o rsqrt(mean o^2 + eps)) silu(z)`` over each head's ``dv`` lanes
+  (the norm first, then the gate; one plain weight of ``dv``), ``out = y
+  W_out``.
+
+``A_log`` and ``dt_bias`` are float32 leaves; the convolution, the recurrence
+and the gated norm run in float32 whatever the model's dtype.
+
+**One chunk, one read of the state** (``delta_chunk``): ``C`` positions
+advance from a state ``S_0``. With ``G_i = g_1 + .. + g_i``:
+
+    (I + L) U = diag(beta) (V - diag(exp G) K S_0),
+    L_ij = beta_i exp(G_i - G_j) (k_i . k_j) for j < i, else 0;
+    o_i = exp(G_i) S_0^T q_i + sum_{j <= i} exp(G_i - G_j) (q_i . k_j) u_j;
+    S_C = exp(G_C) S_0 + sum_j exp(G_C - G_j) k_j u_j^T.
+
+``I + L`` is unit lower triangular, ``C x C`` a head. Solving it by
+substitution is ``C`` dependent steps; ``unit_lower_inverse`` joins blocks
+pairwise from 1 x 1 up, two small products a level (``[[A, 0], [C, B]]^-1 =
+[[A^-1, 0], [-B^-1 C A^-1, B^-1]]``): the same arithmetic as a substitution by
+blocks, ``log2 C`` levels deep. ``exp(G_i - G_j)`` is formed from the
+difference, masked before the exponential, never as a quotient of two
+exponentials. A position with ``beta = 0`` and ``g = 0`` neither decays the
+state nor writes to it: that is how what is no token (a chunk's padding, an
+empty slot) leaves the state as it was, as ``dt = 0`` does in ``ssd_chunk``.
+
+Two callers, the contract ``Mamba2Mixer`` keeps:
+
+- uncached (``logits()``, the tests): each sequence is walked in chunks of
+  ``CHUNK`` positions from a zero state;
+- served (``state`` a :class:`DeltaStateView`): one line a (slot, layer),
+  ``state (slots, nv, dk, dv)`` float32 and ``conv (slots, 2 nk dk + nv dv, K
+  - 1)``, every row advanced from ITS line. Below the full width a row of ONE
+  token takes the single step where it lies (``_step_rows``, through
+  ``delta_step``: a kernel that holds a slot's state in VMEM, reads it once,
+  takes both read-outs ``S^T k`` and ``S^T q`` from it and the output from ``o
+  = exp(g) S^T q + (q . k) u``, and writes the new state once over the old
+  one; the update depends on a read-out, so the compiler's own fusions pass
+  over the state twice) and the at most ``split_capacity`` rows of more are
+  gathered, run the chunk form from their lines and are written back; at the
+  full width every row runs the chunk form. A row whose ``context_len`` is 0
+  starts from zeros in either form; an empty place is untouched.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+
+from ..obs import count_kernel_build
+from . import paged_attention as _paged
+from .attention import PagedTokenMap
+from .base_layer import BaseLayer, ForwardContext
+from .mamba import causal_conv, split_capacity
+from .param import ParamMeta
+from ..topology.topology import MODEL_AXIS
+
+F32 = jnp.float32
+HIGHEST = jax.lax.Precision.HIGHEST
+# positions a chunk advances at once: the triangular system is CHUNK x CHUNK a
+# head (the released kernels' chunk is 64)
+CHUNK = 64
+L2_EPS = 1e-6
+KERNEL_NAME = "delta_step"
+
+
+class DeltaStateView(NamedTuple):
+    """One gated delta-rule layer's lines of the serving engine's pool of
+    lines a slot (serve/kvcache.py), plus the tick's addressing:
+    ``RecurrentStateView``'s counterpart for this mixer. Row ``r`` of the tick
+    is slot ``r``'s line."""
+
+    LINES = ("state", "conv")
+    NAME = "delta"
+    # rows of one token step, rows of more run the chunk form: the engine
+    # picks a tick's width by ``split_capacity`` and counts both
+    SPLITS = True
+
+    state: jax.Array        # (slots, nv, dk, dv) float32
+    conv: jax.Array         # (slots, 2 nk dk + nv dv, K - 1) last conv inputs
+    context_len: jax.Array  # (slots,) int32 tokens the state has seen
+    new_len: jax.Array      # (slots,) int32 real tokens the row brings
+    token_map: Optional[PagedTokenMap] = None  # token-major batches
+
+
+def _block_product(a, b):
+    """``(n, i, j, N) x (n, j, k, N) -> (n, i, k, N)``: small matrices whose
+    batch is the long minor axis, as a multiply and a sum."""
+    return jnp.sum(a[:, :, :, None, :] * b[:, None, :, :, :], axis=2)
+
+
+def _pad_positions(t, pad: int):
+    """``pad`` more places on axis 1 (a row's positions), zeros."""
+    return jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+
+
+def unit_lower_inverse(L):
+    """``(I + L)^-1`` for ``L`` (.., C, C) STRICTLY lower triangular, float32,
+    ``C`` a power of two. Blocks joined pairwise from 1 x 1 up (``[[A, 0], [C,
+    B]]^-1 = [[A^-1, 0], [-B^-1 C A^-1, B^-1]]``): ``log2 C`` levels of two
+    small products, where substitution row by row is ``C`` dependent steps. The
+    blocks lie ``(block, row, column, every head of every row of the tick)`` and
+    a product is a multiply and a sum over that long minor axis: as ``(.., 16,
+    16)`` each 16-wide row pads to a register's 128 lanes, and each row update
+    of a substitution cost 24 us on the chip, 90 of them a tick (PERF.md, PR
+    71)."""
+    C = L.shape[-1]
+    assert C & (C - 1) == 0, C
+    lead = L.shape[:-2]
+    Lt = jnp.moveaxis(L.reshape(-1, C, C), 0, -1)          # (C, C, N)
+    N = Lt.shape[-1]
+    T = jnp.ones((C, 1, 1, N), L.dtype)                    # C blocks of 1 x 1
+    size = 1
+    while size < C:
+        n = C // (2 * size)
+        at = jnp.arange(n)
+        # the block under each pair's diagonal, (n, size, size, N)
+        below = Lt.reshape(n, 2, size, n, 2, size, N)[at, 1, :, at, 0]
+        T11, T22 = T[0::2], T[1::2]
+        T21 = -_block_product(_block_product(T22, below), T11)
+        T = jnp.concatenate([
+            jnp.concatenate([T11, jnp.zeros_like(T11)], 2),
+            jnp.concatenate([T21, T22], 2)], 1)
+        size *= 2
+    return jnp.moveaxis(T[0], -1, 0).reshape(*lead, C, C)
+
+
+def delta_chunk(q, k, v, g, beta, S0, fresh=None):
+    """Advance every row by one chunk, float32.
+
+    ``q`` and ``k`` (r, C, nk, dk), normalised; ``v`` (r, C, nv, dv); ``g``
+    (<= 0) and ``beta`` (r, C, nv), both 0 where the position is no token;
+    ``S0`` (r, nv, dk, dv). ``fresh`` (r,) bool: rows that start from zeros
+    whatever ``S0`` holds; the choice is made on what is COMPUTED from ``S0``,
+    so no zeroed copy of the states is ever written. Returns ``(o (r, C, nv,
+    dv), S_C (r, nv, dk, dv))``."""
+    r, C, nk, dk = q.shape
+    nv, dv = v.shape[2:]
+    per = nv // nk
+    width = 1 << (C - 1).bit_length()      # the system's side: a power of two
+    pad = width - C
+    if pad:
+        q, k, v, g, beta = (_pad_positions(t, pad) for t in (q, k, v, g, beta))
+    G = jnp.cumsum(g, axis=1)                               # (r, C, nv), <= 0
+    # D[i, j] = exp(G_i - G_j) for j <= i; masked BEFORE the exponential
+    # (above the diagonal the difference is positive and may overflow)
+    diff = G[:, :, None, :] - G[:, None, :, :]              # (r, i, j, nv)
+    causal = jnp.tril(jnp.ones((width, width), bool))[None, :, :, None]
+    D = jnp.exp(jnp.where(causal, diff, -jnp.inf))
+    D = D.reshape(r, width, width, nk, per)
+    kk = jnp.einsum("rihd,rjhd->rijh", k, k, precision=HIGHEST)
+    qk = jnp.einsum("rihd,rjhd->rijh", q, k, precision=HIGHEST)
+    strict = jnp.tril(jnp.ones((width, width), bool), -1)[None, :, :, None, None]
+    b5 = beta.reshape(r, width, 1, nk, per)
+    L = jnp.where(strict, b5 * D * kk[..., None], 0.0)
+    T = unit_lower_inverse(jnp.moveaxis(L, (1, 2), (-2, -1)))  # (r, nk, per, i, j)
+    S0g = S0.reshape(r, nk, per, dk, dv)
+    decay = jnp.exp(G).reshape(r, width, nk, per)
+    kS = jnp.einsum("rihd,rhpdv->rihpv", k, S0g, precision=HIGHEST)
+    qS = jnp.einsum("rihd,rhpdv->rihpv", q, S0g, precision=HIGHEST)
+    kS, qS = kS * decay[..., None], qS * decay[..., None]
+    carried = S0g * jnp.exp(G[:, -1]).reshape(r, nk, per, 1, 1)
+    if fresh is not None:
+        zero = fresh[:, None, None, None, None]
+        kS, qS = jnp.where(zero, 0.0, kS), jnp.where(zero, 0.0, qS)
+        carried = jnp.where(zero, 0.0, carried)
+    vg = v.reshape(r, width, nk, per, dv)
+    rhs = (vg - kS) * beta.reshape(r, width, nk, per, 1)
+    U = jnp.einsum("rhpij,rjhpv->rihpv", T, rhs, precision=HIGHEST)
+    o = qS + jnp.einsum("rijhp,rjhpv->rihpv", D * qk[..., None], U,
+                        precision=HIGHEST)
+    # what position j writes, decayed to the chunk's end
+    tail = jnp.exp(G[:, -1:, :] - G).reshape(r, width, nk, per)
+    S = carried + jnp.einsum("rjhd,rjhpv->rhpdv", k, U * tail[..., None],
+                             precision=HIGHEST)
+    return o.reshape(r, width, nv, dv)[:, :C], S.reshape(S0.shape)
+
+
+def _delta_step_kernel(kq_ref, rows_ref, state_ref, new_ref, o_ref, *,
+                       nk: int, per: int):
+    """One slot's step, every head: ``kq_ref`` (1, dk, 2 nk), a key head's k
+    (then q) a LANE, so that a head's key is a column that broadcasts over the
+    state's lanes; ``rows_ref`` (1, 4, nv, dv), a value head's four lane rows
+    ``a = beta v``, ``c = beta decay``, ``d = decay`` (0: start from zeros),
+    ``e = q . k``; the state (1, nv, dk, dv), read once and written once over
+    itself."""
+    for kh in range(nk):
+        k_col = kq_ref[0, :, kh:kh + 1]                        # (dk, 1)
+        q_col = kq_ref[0, :, nk + kh:nk + kh + 1]
+        for h in range(kh * per, (kh + 1) * per):
+            a, c, d, e = (rows_ref[0, i, h:h + 1, :] for i in range(4))  # (1, dv)
+            # zeros chosen on the state itself: a reused slot may hold anything
+            S = jnp.where(d != 0.0, state_ref[0, h], 0.0)      # (dk, dv)
+            kS = jnp.sum(S * k_col, axis=0, keepdims=True)     # (1, dv)
+            qS = jnp.sum(S * q_col, axis=0, keepdims=True)
+            u = a - c * kS
+            new_ref[0, h] = d * S + k_col * u
+            # o = S_t^T q without reading S_t back
+            o_ref[0, h:h + 1, :] = d * qS + e * u
+
+
+def delta_step(q, k, v, g, beta, state, fresh, interpret: bool):
+    """The recurrence's single step for every row, a Pallas kernel that holds
+    a slot's state in VMEM: read once, used twice (the read-outs for the key
+    and for the query), written once. As plain ``jax.numpy`` the chip's
+    compiler makes two passes over the state, one for the two read-outs and
+    one for the update, which depends on the first (PERF.md, PR 71).
+
+    ``q`` and ``k`` (r, nk, dk) normalised, ``v`` (r, nv, dv), ``g`` and
+    ``beta`` (r, nv) (both 0: the row keeps its state), ``state`` (r, nv, dk,
+    dv) float32, ``fresh`` (r,) bool: rows that start from zeros. Returns ``(o
+    (r, nv, dv), state)``."""
+    _paged._ensure_pallas()
+    pl, pltpu = _paged.pl, _paged.pltpu
+    count_kernel_build(KERNEL_NAME, interpret)
+    r, nk, dk = q.shape
+    nv, dv = v.shape[1:]
+    per = nv // nk
+    decay = jnp.where(fresh[:, None], 0.0, jnp.exp(g))
+
+    def lanes(x):  # a value a head as a row of lanes
+        return jnp.broadcast_to(x[:, :, None], (r, nv, dv))
+
+    rows = jnp.stack([beta[:, :, None] * v, lanes(beta * decay), lanes(decay),
+                      lanes(jnp.repeat(jnp.sum(q * k, -1), per, axis=1))], axis=1)
+    kq = jnp.swapaxes(jnp.concatenate([k, q], axis=1), 1, 2)   # (r, dk, 2 nk)
+    block = 2 * nv * dk * dv * 4                               # state in and out
+    new_state, o = pl.pallas_call(
+        functools.partial(_delta_step_kernel, nk=nk, per=per),
+        grid=(r,),
+        in_specs=[pl.BlockSpec((1, dk, 2 * nk), lambda i: (i, 0, 0)),
+                  pl.BlockSpec((1, 4, nv, dv), lambda i: (i, 0, 0, 0)),
+                  pl.BlockSpec((1, nv, dk, dv), lambda i: (i, 0, 0, 0))],
+        out_specs=[pl.BlockSpec((1, nv, dk, dv), lambda i: (i, 0, 0, 0)),
+                   pl.BlockSpec((1, nv, dv), lambda i: (i, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(state.shape, F32),
+                   jax.ShapeDtypeStruct((r, nv, dv), F32)],
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            # both blocks of the state double-buffered, and room beside them
+            vmem_limit_bytes=max(2 * block + (8 << 20), 16 << 20)),
+        interpret=interpret,
+        name=KERNEL_NAME,  # the trace's and the HLO's name for it
+    )(kq, rows, state)
+    return o, new_state
+
+
+class GatedDeltaMixer(BaseLayer):
+    # the view of the serving state a layer with this mixer is handed
+    STATE_VIEW = DeltaStateView
+
+    def __init__(self, hidden_size: int, num_key_heads: int,
+                 num_value_heads: int, key_head_dim: int, value_head_dim: int,
+                 conv_kernel: int, norm_eps: float = 1e-6,
+                 time_step_min: float = 0.001, time_step_max: float = 0.1,
+                 time_step_floor: float = 1e-4, dtype=None):
+        assert num_value_heads % num_key_heads == 0, (
+            num_value_heads, num_key_heads)
+        self.hidden_size = hidden_size
+        self.nk, self.nv = num_key_heads, num_value_heads
+        self.dk, self.dv = key_head_dim, value_head_dim
+        self.conv_kernel = conv_kernel
+        self.norm_eps = norm_eps
+        self.time_step = (time_step_min, time_step_max, time_step_floor)
+        self.dtype = dtype or jnp.float32
+        self.key_dim = num_key_heads * key_head_dim
+        self.value_dim = num_value_heads * value_head_dim
+        self.conv_dim = 2 * self.key_dim + self.value_dim
+        self.in_width = self.conv_dim + self.value_dim
+
+    # ------------------------------------------------------------------ init
+    def init(self, key: jax.Array) -> dict:
+        """Seeded init: ``A`` uniform in [1, 16] and the time step log-uniform
+        in [time_step_min, time_step_max] (stored as its inverse softplus,
+        ``dt_bias``), as ``Mamba2Mixer`` and the delta rule's released code
+        start them, so that a fresh state's memory is tens of positions
+        long; the conv uniform in +-1/sqrt(K), matrices Xavier-normal."""
+        ks = jax.random.split(key, 6)
+        H, K = self.hidden_size, self.conv_kernel
+        lo, hi, floor = self.time_step
+
+        def xavier(k, shape):
+            std = math.sqrt(2.0 / (shape[0] + shape[1]))
+            return (jax.random.normal(k, shape) * std).astype(self.dtype)
+
+        dt = jnp.exp(jax.random.uniform(ks[2], (self.nv,))
+                     * (math.log(hi) - math.log(lo)) + math.log(lo))
+        dt = jnp.maximum(dt, floor)
+        bound = 1.0 / math.sqrt(K)
+        return {
+            "in_proj": {"weight": xavier(ks[0], (H, self.in_width))},
+            "ba_proj": {"weight": xavier(ks[5], (H, 2 * self.nv))},
+            "conv": {"weight": jax.random.uniform(
+                ks[1], (self.conv_dim, K), minval=-bound, maxval=bound
+            ).astype(self.dtype)},
+            "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(F32),
+            "A_log": jnp.log(jax.random.uniform(
+                ks[3], (self.nv,), minval=1.0, maxval=16.0)).astype(F32),
+            "norm": {"weight": jnp.ones((self.dv,), self.dtype)},
+            "out_proj": {"weight": xavier(ks[4], (self.value_dim, H))},
+        }
+
+    def param_metas(self) -> dict:
+        def replicated(name, dims):
+            return ParamMeta(parameter_name=name,
+                             partition_spec=(None,) * dims,
+                             is_model_parallel_duplicate=True)
+
+        # model parallelism over a pattern stack is refused (config.py): the
+        # specs say how the matrices WOULD split, nothing runs sharded yet
+        return {
+            "in_proj": {"weight": replicated("in_proj.weight", 2)},
+            "ba_proj": {"weight": replicated("ba_proj.weight", 2)},
+            "conv": {"weight": replicated("conv.weight", 2)},
+            "dt_bias": replicated("dt_bias", 1),
+            "A_log": replicated("A_log", 1),
+            "norm": {"weight": replicated("norm.weight", 1)},
+            "out_proj": {"weight": ParamMeta(
+                parameter_name="out_proj.weight",
+                partition_spec=(MODEL_AXIS, None), is_model_parallel=True,
+                model_parallel_dimension=0)},
+        }
+
+    # --------------------------------------------------------------- forward
+    def _conv(self, params, window):
+        return causal_conv(window, params["conv"]["weight"], jnp.zeros((), F32))
+
+    def _delta_inputs(self, params, conved, ba, real):
+        """The recurrence's operands from the conv's output (.., conv_dim)
+        float32 and the raw ``[b | a]`` (.., 2 nv); ``real`` (..) bool or
+        None: ``(q, k (.., nk, dk), v (.., nv, dv), g, beta (.., nv))``."""
+        lead = conved.shape[:-1]
+        q = conved[..., :self.key_dim].reshape(*lead, self.nk, self.dk)
+        k = conved[..., self.key_dim:2 * self.key_dim].reshape(
+            *lead, self.nk, self.dk)
+        v = conved[..., 2 * self.key_dim:].reshape(*lead, self.nv, self.dv)
+        q = q * jax.lax.rsqrt(
+            jnp.sum(q * q, -1, keepdims=True) + L2_EPS) * self.dk ** -0.5
+        k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + L2_EPS)
+        ba = ba.astype(F32)
+        beta = jax.nn.sigmoid(ba[..., :self.nv])
+        g = -jnp.exp(params["A_log"]) * jax.nn.softplus(
+            ba[..., self.nv:] + params["dt_bias"])
+        if real is not None:
+            beta = jnp.where(real[..., None], beta, 0.0)
+            g = jnp.where(real[..., None], g, 0.0)
+        return q, k, v, g, beta
+
+    def _gated_out(self, params, o, z):
+        """Each head's ``o`` RMS-normed, times ``silu(z)``, projected out.
+        ``o`` float32 (.., nv, dv), ``z`` the model's dtype (.., nv dv)."""
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + self.norm_eps)
+        o = o * params["norm"]["weight"].astype(F32)
+        y = o.reshape(z.shape) * jax.nn.silu(z.astype(F32))
+        return y.astype(z.dtype) @ params["out_proj"]["weight"].astype(z.dtype)
+
+    def __call__(self, params: dict, x: jax.Array, ctx: ForwardContext,
+                 state: Optional[DeltaStateView] = None,
+                 return_state: bool = False):
+        """``x`` (b, s, H). Without ``state`` each of the ``b`` sequences is
+        walked whole from a zero state (``return_state``: also its final
+        ``(state, conv)`` lines); with ``state`` the batch is the tick's, and
+        the second result is the view with its lines advanced."""
+        with jax.named_scope("delta"):
+            proj = x @ params["in_proj"]["weight"].astype(x.dtype)
+            ba = x @ params["ba_proj"]["weight"].astype(x.dtype)
+            qkv, z = proj[..., :self.conv_dim], proj[..., self.conv_dim:]
+            if state is not None:
+                o, new_view = self._serve(params, qkv, ba, state)
+                return self._gated_out(params, o, z), new_view
+            o, lines = self._whole(params, qkv, ba)
+            out = self._gated_out(params, o, z)
+            return (out, lines) if return_state else out
+
+    def _advance(self, q, k, v, g, beta, S0, fresh):
+        """``(r, w)`` whole rows from their states: one chunk, or ``CHUNK``
+        positions at a time where a row is wider."""
+        with jax.named_scope("delta_rule"):
+            r, w = g.shape[:2]
+            if w <= CHUNK:
+                return delta_chunk(q, k, v, g, beta, S0, fresh)
+            pad = -w % CHUNK
+            parts = tuple(
+                jnp.moveaxis(_pad_positions(t, pad).reshape(
+                    r, -1, CHUNK, *t.shape[2:]), 1, 0)
+                for t in (q, k, v, g, beta))
+            first = jnp.arange(parts[0].shape[0]) == 0
+
+            def step(S, part):
+                *operands, is_first = part
+                zero = None if fresh is None else fresh & is_first
+                o, S = delta_chunk(*operands, S, zero)
+                return S, o
+
+            S, o = jax.lax.scan(step, S0, (*parts, first))
+            o = jnp.moveaxis(o, 0, 1).reshape(r, w + pad, *o.shape[3:])
+            return o[:, :w], S
+
+    def _whole(self, params, qkv, ba):
+        """Every sequence of a ``(b, s)`` batch from a zero state."""
+        b, s, _ = qkv.shape
+        K = self.conv_kernel
+        window = jnp.pad(qkv, ((0, 0), (K - 1, 0), (0, 0)))
+        operands = self._delta_inputs(params, self._conv(params, window), ba, None)
+        S0 = jnp.zeros((b, self.nv, self.dk, self.dv), F32)
+        o, S = self._advance(*operands, S0, None)
+        tail = jnp.swapaxes(window[:, s:], 1, 2)            # (b, conv_dim, K-1)
+        return o, (S, tail)
+
+    def _serve(self, params, qkv, ba, view: DeltaStateView):
+        """The tick's batch ``(g, s)`` against the slots' lines: whole rows
+        where the batch has a place for every row's widest chunk, else each
+        row in the form its ``new_len`` asks for. Returns ``(o (g, s, nv,
+        dv), the view advanced)``."""
+        g, s = qkv.shape[:2]
+        lines = (view.state, view.conv, view.context_len.astype(jnp.int32),
+                 view.new_len.astype(jnp.int32))
+        tmap = view.token_map
+        if tmap is None:  # row-major: position (r, j) is row r's j-th token
+            o, S, tail = self._chunk_rows(params, qkv, ba, *lines)
+        else:
+            rows, w = tmap.row_tokens.shape
+            qkv, ba = qkv.reshape(g * s, -1), ba.reshape(g * s, -1)
+            if g * s < rows * w:
+                o, S, tail = self._split_rows(params, qkv, ba, *lines, tmap)
+            else:
+                flat = tmap.row_tokens
+                o, S, tail = self._chunk_rows(params, qkv[flat], ba[flat], *lines)
+                # back to the batch's token order
+                o = o[tmap.row, jnp.minimum(tmap.offset, w - 1)]
+            o = o.reshape(g, s, self.nv, self.dv)
+        return o, view._replace(state=S.astype(view.state.dtype),
+                                conv=tail.astype(view.conv.dtype))
+
+    def _chunk_rows(self, params, qkv, ba, state, conv, ctx_len, new_len):
+        """The whole-rows form: every row of ``(r, w)`` advances from its line
+        by the chunk form, ``new_len`` of its places real. Returns ``(o (r, w,
+        nv, dv), state, conv)``, float32 but the tail (``qkv``'s dtype)."""
+        r, w = ba.shape[:2]
+        K = self.conv_kernel
+        real = jnp.arange(w, dtype=jnp.int32)[None, :] < new_len[:, None]
+        # a row at context 0 starts from zeros, whatever its slot held
+        fresh = (ctx_len == 0) & (new_len > 0)
+        tail = jnp.where(fresh[:, None, None], 0, conv)
+        window = jnp.concatenate(
+            [jnp.swapaxes(tail, 1, 2).astype(qkv.dtype), qkv], axis=1)
+        operands = self._delta_inputs(
+            params, self._conv(params, window), ba, real)
+        o, S = self._advance(*operands, state.astype(F32), fresh)
+        # each channel's last K - 1 inputs, the row's new ones included: the
+        # window's places new_len .. new_len + K - 2 (new_len 0: the old tail)
+        last = new_len[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None, :]
+        new_tail = jnp.take_along_axis(window, last[:, :, None], axis=1)
+        return o, S, jnp.swapaxes(new_tail, 1, 2)
+
+    def _step_rows(self, params, qkv, ba, state, conv, ctx_len, new_len):
+        """The recurrence's single step for the rows that bring ONE token,
+        ``qkv`` (r, conv_dim) and ``ba`` (r, 2 nv) each row's first place of
+        the tick. Every other row carries ``beta = g = 0`` and keeps its
+        lines. Returns ``(o (r, nv, dv), state, conv)``."""
+        steps = new_len == 1
+        fresh = steps & (ctx_len == 0)
+        tail = jnp.where(fresh[:, None, None], 0, conv).astype(qkv.dtype)
+        window = jnp.concatenate([tail, qkv[:, :, None]], axis=2)  # (r, c, K)
+        conved = self._conv(params, jnp.swapaxes(window, 1, 2))[:, 0]
+        q, k, v, g, beta = self._delta_inputs(params, conved, ba, steps)
+        new_tail = jnp.where(steps[:, None, None], window[:, :, 1:], tail)
+        with jax.named_scope("delta_rule"):
+            o, S = delta_step(q, k, v, g, beta, state.astype(F32), fresh,
+                              _paged.paged_kernel_interpret())
+        return o, S, new_tail
+
+    def _split_rows(self, params, qkv, ba, state, conv, ctx_len, new_len, tmap):
+        """A token-major batch ``(T, ..)`` narrower than ``rows x w``: rows
+        that bring one token step where they lie; the at most ``T // w`` that
+        bring more (the caller sees to that: ``split_capacity``) are gathered,
+        advanced as whole rows and written back over their lines, as
+        ``Mamba2Mixer._split_rows`` does. Returns ``(o (T, nv, dv), state,
+        conv)``."""
+        rows, w = tmap.row_tokens.shape
+        R = split_capacity(qkv.shape[0], w)
+        first = tmap.row_tokens[:, 0]
+        o_step, S, tail = self._step_rows(
+            params, qkv[first], ba[first], state, conv, ctx_len, new_len)
+        multi = new_len > 1
+        # the multi-token rows in slot order, then `rows`: past the pool, so
+        # that nothing of a place no row fills is written back. A chunk row
+        # has stepped with beta = g = 0: its lines are still the old ones
+        at, = jnp.nonzero(multi, size=R, fill_value=rows)
+        held = jnp.minimum(at, rows - 1)
+        flat = tmap.row_tokens[held]                         # (R, w)
+        # each line by a read of its own (nn/mamba.py: a general gather over a
+        # state wider than the lanes first copies EVERY slot's line)
+        S_held = jnp.stack([
+            jax.lax.dynamic_index_in_dim(S, row, 0, keepdims=False)
+            for row in held])
+        o_chunk, S_chunk, tail_chunk = self._chunk_rows(
+            params, qkv[flat], ba[flat], S_held, tail[held], ctx_len[held],
+            jnp.where(at < rows, new_len[held], 0))
+        S = S.at[at].set(S_chunk, mode="drop")
+        tail = tail.at[at].set(tail_chunk.astype(tail.dtype), mode="drop")
+        # a token reads its row's step, or its place in its row's chunk
+        place = jnp.cumsum(multi)[tmap.row] - 1
+        place = jnp.clip(place, 0, R - 1) * w + jnp.minimum(tmap.offset, w - 1)
+        o = jnp.concatenate([o_step, o_chunk.reshape(R * w, self.nv, self.dv)])
+        return o[jnp.where(multi[tmap.row], rows + place, tmap.row)], S, tail

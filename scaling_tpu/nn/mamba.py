@@ -93,6 +93,9 @@ class RecurrentStateView(NamedTuple):
     # tick's addressing), and the name this kind's spans and counters carry
     LINES = ("ssm", "conv")
     NAME = "ssm"
+    # rows of one token step, rows of more run the chunk form: the engine
+    # picks a tick's width by ``split_capacity`` and counts both
+    SPLITS = True
 
     ssm: jax.Array          # (slots, heads, head_dim, N) float32
     conv: jax.Array         # (slots, inner + 2 G N, K - 1) last conv inputs

@@ -70,7 +70,9 @@ with ``n_group > 1`` the choice is GROUP-LIMITED: the experts lie in
 ``_group_limited``, over all ``num_experts`` outputs whatever share is held),
 ``glu`` (false: two matrices an expert,
 ``act(x W_in) W_out``), and a ``shared expert`` of a width of its own that
-every token runs, added once inside the ``moe`` scope.
+every token runs, added once inside the ``moe`` scope; with
+``shared_expert_gate`` its output is first scaled by ``sigmoid(x w_s)``, one
+value a token (leaf ``shared_scale``, ``(h, 1)``: Qwen3-Next's).
 
 **A share of the experts** (``experts_first``, ``experts_held``): the layer
 is TOLD which contiguous range ``[first, first + held)`` of the
@@ -149,6 +151,7 @@ class ParallelMoEMLP(BaseLayer):
         router: str = "softmax",
         routed_scaling_factor: float = 1.0,
         shared_expert_width: Optional[int] = None,
+        shared_expert_gate: bool = False,
         experts_first: int = 0,
         experts_held: Optional[int] = None,
         n_group: int = 1,
@@ -171,6 +174,10 @@ class ParallelMoEMLP(BaseLayer):
         self.n_group, self.topk_group = n_group, topk_group
         self.routed_scaling_factor = routed_scaling_factor
         self.shared_expert_width = shared_expert_width
+        # the shared expert's output times sigmoid(x w_s), one value a token
+        # (Qwen2-MoE's and Qwen3-Next's shared_expert_gate)
+        self.shared_expert_gate = shared_expert_gate
+        assert shared_expert_width or not shared_expert_gate
         self.experts_first = experts_first
         self.experts_held = (
             num_experts - experts_first if experts_held is None else experts_held
@@ -224,7 +231,7 @@ class ParallelMoEMLP(BaseLayer):
         fs = self.shared_expert_width
         for i, name in enumerate(self._shared_leaves()):
             # keys of their own: the four above stay what they were
-            shape = (fs, h) if name == "shared_out" else (h, fs)
+            shape = {"shared_out": (fs, h), "shared_scale": (h, 1)}.get(name, (h, fs))
             params[name] = expert_init(
                 jax.random.fold_in(key, 4 + i), shape, self.dtype)
         return params
@@ -233,7 +240,8 @@ class ParallelMoEMLP(BaseLayer):
         """The shared expert's leaves (none without one)."""
         if not self.shared_expert_width:
             return ()
-        return ("shared_in", "shared_out") + (("shared_gate",) if self.glu else ())
+        return (("shared_in", "shared_out") + (("shared_gate",) if self.glu else ())
+                + (("shared_scale",) if self.shared_expert_gate else ()))
 
     def param_metas(self) -> dict:
         def expert_meta(name, spec):
@@ -264,6 +272,11 @@ class ParallelMoEMLP(BaseLayer):
                 is_model_parallel_duplicate=True,
             )
         for name in self._shared_leaves():
+            if name == "shared_scale":
+                metas[name] = ParamMeta(
+                    parameter_name=name, partition_spec=(None, None),
+                    is_model_parallel_duplicate=True)
+                continue
             spec = (MODEL_AXIS, None) if name == "shared_out" else (None, MODEL_AXIS)
             metas[name] = ParamMeta(
                 parameter_name=name, partition_spec=spec,
@@ -371,7 +384,12 @@ class ParallelMoEMLP(BaseLayer):
             act = self.activation_fn(x @ params["shared_gate"].astype(x.dtype)) * up
         else:
             act = self.activation_fn(up)
-        return y + act @ params["shared_out"].astype(x.dtype)
+        shared = act @ params["shared_out"].astype(x.dtype)
+        if self.shared_expert_gate:
+            scale = jax.nn.sigmoid(
+                (x @ params["shared_scale"].astype(x.dtype)).astype(jnp.float32))
+            shared = (shared.astype(jnp.float32) * scale).astype(shared.dtype)
+        return y + shared
 
     def _route(self, params: dict, x: jax.Array):
         """Router probabilities over all experts in float32 (b, s, E), and

@@ -50,6 +50,12 @@ class LayerNormConfig(BaseConfig):
     layernorm_epsilon: float = Field(
         1e-5, description="A value added to the denominator for numerical stability"
     )
+    weight_offset: bool = Field(
+        False,
+        description="an RMS norm's learned weight is an OFFSET from one: y = "
+        "x rsqrt(mean x^2 + eps) (1 + w), w starting at zeros (Qwen3-Next's "
+        "and Gemma's RMSNorm); false: y = .. w, w starting at ones",
+    )
 
 
 def _norm_meta(name: str) -> ParamMeta:
@@ -116,13 +122,16 @@ class RMSNorm(BaseLayer):
         self.bitfit_bias_name = bitfit_bias_name  # rmsnorm has no bias; kept for API parity
 
     def init(self, key: jax.Array) -> dict:
-        return {"weight": jnp.ones((self.dimensions,), dtype=self.dtype)}
+        start = jnp.zeros if self.config.weight_offset else jnp.ones
+        return {"weight": start((self.dimensions,), dtype=self.dtype)}
 
     def param_metas(self) -> dict:
         return {"weight": _norm_meta("weight")}
 
     def __call__(self, params: dict, x: jax.Array, ctx: ForwardContext) -> jax.Array:
-        if self.config.optimization_type == LayerNormOptimizationType.FUSED:
+        offset = self.config.weight_offset
+        if (self.config.optimization_type == LayerNormOptimizationType.FUSED
+                and not offset):
             from ..ops.rms_norm import (
                 rms_norm_fused,
                 rms_norm_fused_shardable,
@@ -152,7 +161,8 @@ class RMSNorm(BaseLayer):
         x32 = x.astype(jnp.float32)
         var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
         y = x32 * jax.lax.rsqrt(var + self.config.layernorm_epsilon)
-        return (y * params["weight"].astype(jnp.float32)).astype(dtype)
+        weight = params["weight"].astype(jnp.float32)
+        return (y * (1.0 + weight if offset else weight)).astype(dtype)
 
 
 def get_norm(

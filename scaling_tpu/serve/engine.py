@@ -231,6 +231,11 @@ class EngineConfig:
     # self-drafting speculative decoding: n-gram drafts scored k-at-once
     # through the mixed program's s>1 rows; 0 = off
     spec_k: int = 0
+    # prompts streaming a chunk each that the SMALL token width has room for
+    # beside a decode row in every slot (``mixed_widths``). An engine of many
+    # slots and short chunks raises it so that the common tick, whose prompt
+    # tokens keep its slots full, still runs at the small width
+    small_bucket_chunks: int = SMALL_BUCKET_CHUNKS
     sample_seed: int = 0  # base key for per-request sampling
     flush_interval: int = 50  # registry flush cadence (ticks)
     # ---- resilience (docs/SERVING.md "Resilience") ----
@@ -275,14 +280,14 @@ class EngineConfig:
         configuration: the full width ``num_slots * mixed_width`` holds
         whatever the scheduler admits; the small one holds the common
         tick, a decode row (1 + ``spec_k`` tokens) in every slot and
-        ``SMALL_BUCKET_CHUNKS`` prompts streaming a chunk each, rounded
+        ``small_bucket_chunks`` prompts streaming a chunk each, rounded
         up to whole ``LANES`` (below the chip's ridge a tick costs one
         read of the weights whatever it holds, so a finer bucket buys
         nothing and a third program costs its warm-up). Engines whose
         full width is no larger build the one program."""
         full = self.num_slots * self.mixed_width
         small = (self.num_slots * (self.spec_k + 1)
-                 + SMALL_BUCKET_CHUNKS * self.prefill_chunk)
+                 + self.small_bucket_chunks * self.prefill_chunk)
         small = -(-small // LANES) * LANES
         return (small, full) if small < full else (full,)
 
@@ -337,7 +342,7 @@ class ServeEngine:
 
             self._replicated = NamedSharding(self.mesh, P())
         arch = inference_module.architecture
-        arch.refuse_paged_serving()
+        arch.refuse_paged_serving(self.config.kv_dtype)
         # window attention layers (nn/window_attention.py): each keeps a RING
         # of lines a slot, whatever the context; the pools, the block tables
         # and the scheduler's block count are the full layers' alone
@@ -371,8 +376,14 @@ class ServeEngine:
             raise ValueError(
                 f"spec_k > 0 with {kept}: a rejected draft has already "
                 "advanced the lines and there is no rollback; set spec_k=0")
-        # Mamba-2's lines advance in a form of their own (nn/mamba.py)
-        self.ssm_lines = self.line_layers.get(RecurrentStateView.NAME, 0)
+        # recurrent lines advance in a form of their own, a step or a chunk
+        # by what a row brings (nn/mamba.py, nn/gated_delta.py: the kinds whose
+        # view says ``SPLITS``), by the name their spans and counters carry
+        self.split_lines = {
+            kind.NAME: layers
+            for kind, layers in line_layers(self.pools.kinds).items()
+            if getattr(kind, "SPLITS", False)}
+        self.ssm_lines = self.split_lines.get(RecurrentStateView.NAME, 0)
         # layers whose two mixers run side by side and keep state under both
         # rules, a paged line and a line a slot (parallel_ssm)
         self.par_lines = sum(
@@ -1084,7 +1095,7 @@ class ServeEngine:
                 # recurrent lines advance row by row below the full width,
                 # which holds so many multi-token rows (nn/mamba.py)
                 multi = (int(np.count_nonzero(new_lens > 1))
-                         if self.ssm_lines else 0)
+                         if self.split_lines else 0)
                 width = next(
                     w for w in cfg.mixed_widths if len(real) <= w
                     and multi <= split_capacity(w, cfg.mixed_width))
@@ -1239,16 +1250,16 @@ class ServeEngine:
                                    f"{kind}_lines": lines})
             self._counter(
                 f"serve_{kind}_state_updates_total").inc(rows * lines)
-        if self.ssm_lines:
+        for kind, lines in self.split_lines.items():
             # the form that advanced them (nn/mamba.py): at the full
             # width whole rows, below it a step or a gathered chunk
-            mixed_span.annotate(
-                ssm_step_rows=rows - multi, ssm_chunk_rows=multi)
+            mixed_span.annotate(**{f"{kind}_step_rows": rows - multi,
+                                   f"{kind}_chunk_rows": multi})
             paths = ({"whole": rows} if width == self.config.mixed_widths[-1]
                      else {"step": rows - multi, "chunk": multi})
             for path, count in paths.items():
-                self._counter("serve_ssm_rows_total", path=path).inc(
-                    count * self.ssm_lines)
+                self._counter(f"serve_{kind}_rows_total", path=path).inc(
+                    count * lines)
         if self.latent_layers:
             # what a latent layer's attention reads and multiplies
             # this tick: the lines of its rows (context + new), and
@@ -1824,6 +1835,8 @@ class ServeEngine:
             # short convolutions' tails; 0: a model without them) and the
             # bytes of those lines
             "state_lines": self.pools.state_lines,
+            # the same by kind ({"ssm": 23}, {"delta": 6}, ..)
+            "line_layers": dict(self.line_layers),
             "state_pool_bytes": self.pools.state_bytes(),
             # median ms of each serve.* span over this engine's last
             # TICK_PHASES_TICKS ticks, read from the span recorder on

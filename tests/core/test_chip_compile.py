@@ -176,6 +176,23 @@ def test_paged_kernel_compiles_at_group_6_over_8_kv_heads(one_chip):
     compile_paged_kernel(one_chip, 24, 48, 8, 2048, 32, "native", head_dim=128)
 
 
+@pytest.mark.parametrize(
+    "s", [1, 32], ids=["decode-s1", "prefill-chunk-s32"]
+)
+def test_paged_kernel_compiles_at_heads_of_256(one_chip, s):
+    """``serve-qwen3next-80b-extract-burst``'s attention: 16 query heads over 2
+    KV heads x 256 lanes, 256 slots of 128 blocks. A head of two lane tiles: the
+    pool line is ``(2, 256)`` as the probe gives it (nothing to pack), a tile
+    is 256 tokens (the VMEM budget of K and V, double-buffered, at 16 KiB a
+    block) and two sub-tiles of 128; Mosaic takes it unchanged."""
+    from scaling_tpu.nn.paged_attention import kernel_sub_tokens, kernel_tile_tokens
+
+    assert packed_kv_dims(2, 256) == (2, 256)
+    assert kernel_tile_tokens(BLOCK_SIZE, 128, 2, 256, 2) == 256
+    assert kernel_sub_tokens(BLOCK_SIZE, 128, 2, 256, 2) == 128
+    compile_paged_kernel(one_chip, 256, 16, 2, 128, s, "native", head_dim=256)
+
+
 def kernel_operands(lowered) -> int:
     """Operands of the ONE Mosaic kernel in a lowered program's text."""
     (operands,) = re.findall(
@@ -901,6 +918,55 @@ def test_a_one_token_row_reads_its_mamba_state_once_at_the_cells_size(one_chip, 
         # own head-major tensors begin as one would: Nemotron's case holds it)
         assert not re.search(rf"\[{slots},{w},\d", entry)
     assert f"f32[8,{line}]" in entry               # the gathered chunk rows
+
+
+def test_a_one_token_row_reads_its_delta_state_once_at_the_cells_size(
+        one_chip, monkeypatch):
+    """The gated delta-rule mixer alone at ``serve-qwen3next-80b-extract-burst``'s
+    size (256 slots x 32 value heads x 128 x 128 float32: 537 MB a layer), a
+    token-major tick of 768 places for rows of up to 32: the rows that bring
+    one token advance in ONE kernel (``delta_step``, compiled by Mosaic) that
+    takes the donated state and gives the new state, written over the old one,
+    and the read-out; the at most 24 rows that bring a chunk are sliced out
+    line by line and scattered back in place. The donated state has no other
+    reader and is never copied. As plain ``jax.numpy`` the step was TWO
+    fusions over the state, the read-outs' and the update's (PERF.md, PR 71)."""
+    from scaling_tpu.nn.attention import packed_token_map
+    from scaling_tpu.nn.base_layer import ForwardContext
+    from scaling_tpu.nn.gated_delta import DeltaStateView, GatedDeltaMixer
+
+    monkeypatch.setattr(
+        "scaling_tpu.nn.paged_attention.paged_kernel_interpret",
+        lambda platform=None: False)
+    w, places, slots, hidden = 32, 768, 256, 2048
+    layer = GatedDeltaMixer(hidden, 16, 32, 128, 128, 4, dtype=jnp.bfloat16)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def tick(params, x, state, conv, ctx_len, new_len):
+        view = DeltaStateView(state, conv, ctx_len, new_len,
+                              packed_token_map(new_len, x.shape[:2], w))
+        out, view = layer(params, x, ForwardContext(), state=view)
+        return out, view.state, view.conv
+
+    params = jax.tree.map(lambda x: shape(x.shape, x.dtype),
+                          jax.eval_shape(layer.init, jax.random.PRNGKey(0)))
+    text = jax.jit(tick, donate_argnums=(2, 3)).lower(
+        params, shape((places // w, w, hidden), jnp.bfloat16),
+        shape((slots, 32, 128, 128), jnp.float32),
+        shape((slots, layer.conv_dim, 3), jnp.bfloat16),
+        shape((slots,), jnp.int32), shape((slots,), jnp.int32),
+    ).compile().as_text()
+    entry = text[text.index("\nENTRY "):]
+    state = r"f32\[256,32,128,128\]"
+    readers = [op for op in entry.splitlines()
+               if re.search(r"\(.*%state", op) and " parameter(" not in op]
+    assert len(readers) == 1 and "tpu_custom_call" in readers[0], readers
+    assert re.match(rf"\s*%delta_step\S* = \({state}", readers[0])
+    assert not whole_copies(text, state)
+    assert "delta/scatter" in entry and "f32[24,32,128,128]" in entry
+    assert not re.search(rf"{state}[^=]* while\(", entry)
 
 
 ROUTED_CELLS = {

@@ -1,0 +1,340 @@
+"""The gated delta-rule mixer alone (``scaling_tpu/nn/gated_delta.py``): the
+step against the recurrence written as a Python loop, the chunk form (a unit
+lower-triangular system a head) against the step, what is no token, the
+extremes of its two gates, and the served path's regrouping."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from scaling_tpu.nn.attention import packed_token_map
+from scaling_tpu.nn.base_layer import ForwardContext
+from scaling_tpu.nn.gated_delta import (
+    DeltaStateView, GatedDeltaMixer, delta_chunk, delta_step, unit_lower_inverse)
+from scaling_tpu.nn.mamba import split_capacity
+
+H, NK, NV, DK, DV, K = 48, 2, 4, 16, 8, 4
+CONV = 2 * NK * DK + NV * DV
+# float32 against float64 (or float32 in another order of summation): a few
+# roundings of values of magnitude ~1
+ATOL = 3e-5
+
+
+def loop(q, k, v, g, beta, S0):
+    """The recurrence as it is written, one row, numpy float64: S~ = exp(g_t)
+    S; u = beta_t (v_t - S~^T k_t); S = S~ + k_t u^T; o_t = S^T q_t."""
+    q, k, v, g, beta, S = (np.asarray(a, np.float64) for a in (q, k, v, g, beta, S0))
+    per = v.shape[1] // k.shape[1]
+    outs = []
+    for t in range(q.shape[0]):
+        kt, qt = np.repeat(k[t], per, 0), np.repeat(q[t], per, 0)     # (nv, dk)
+        S = np.exp(g[t])[:, None, None] * S
+        u = beta[t][:, None] * (v[t] - np.einsum("hkv,hk->hv", S, kt))
+        S = S + kt[:, :, None] * u[:, None, :]
+        outs.append(np.einsum("hkv,hk->hv", S, qt))
+    return np.stack(outs), S
+
+
+def operands(key, rows, w, g_scale=1.0):
+    ks = jax.random.split(key, 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(jax.random.normal(ks[0], (rows, w, NK, DK))) * DK ** -0.5
+    k = unit(jax.random.normal(ks[1], (rows, w, NK, DK)))
+    v = jax.random.normal(ks[2], (rows, w, NV, DV))
+    g = -g_scale * jax.nn.softplus(jax.random.normal(ks[3], (rows, w, NV)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (rows, w, NV)))
+    S0 = jax.random.normal(ks[5], (rows, NV, DK, DV))
+    return q, k, v, g, beta, S0
+
+
+def test_the_inverse_of_a_unit_lower_triangle():
+    for C in (1, 2, 16, 32, 64):
+        L = jnp.tril(jax.random.normal(jax.random.PRNGKey(C), (3, 2, C, C)), -1)
+        T = jax.jit(unit_lower_inverse)(L)
+        want = np.linalg.inv(np.eye(C) + np.asarray(L, np.float64))
+        np.testing.assert_allclose(np.asarray(T), want, atol=1e-3 * np.abs(want).max())
+
+
+@pytest.fixture(scope="module")
+def mixer():
+    layer = GatedDeltaMixer(H, NK, NV, DK, DV, K)
+    params = layer.init(jax.random.PRNGKey(0))
+    # away from the init (a norm of ones)
+    leaves, treedef = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
+    params = jax.tree.unflatten(treedef, [
+        x + 0.2 * jax.random.normal(k, x.shape) for x, k in zip(leaves, keys)])
+    return layer, params
+
+
+def step_rows(q, k, v, g, beta, S0):
+    """``_step_rows``'s recurrence on prepared operands: one token a row."""
+    outs, S = [], S0
+    per = NV // NK
+    for t in range(q.shape[1]):
+        Sg = S.reshape(-1, NK, per, DK, DV)
+        kt, qt = k[:, t], q[:, t]
+        decay = jnp.exp(g[:, t]).reshape(-1, NK, per, 1)
+        kS = jnp.sum(Sg * kt[:, :, None, :, None], -2)
+        qS = jnp.sum(Sg * qt[:, :, None, :, None], -2)
+        u = beta[:, t].reshape(-1, NK, per, 1) * (
+            v[:, t].reshape(-1, NK, per, DV) - decay * kS)
+        S = (decay[..., None] * Sg + kt[:, :, None, :, None] * u[:, :, :, None, :]
+             ).reshape(S0.shape)
+        outs.append((decay * qS + jnp.sum(qt * kt, -1)[:, :, None, None] * u
+                     ).reshape(-1, NV, DV))
+    return jnp.stack(outs, 1), S
+
+
+def test_the_step_equals_the_recurrence_as_a_python_loop():
+    q, k, v, g, beta, S0 = operands(jax.random.PRNGKey(3), 2, 6)
+    o, S = step_rows(q, k, v, g, beta, S0)
+    for r in range(2):
+        want_o, want_S = loop(q[r], k[r], v[r], g[r], beta[r], S0[r])
+        np.testing.assert_allclose(np.asarray(o[r]), want_o, atol=ATOL, rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(S[r]), want_S, atol=ATOL, rtol=1e-5)
+
+
+@pytest.mark.parametrize("rows", [1, 3], ids=["the smallest grid", "three slots"])
+def test_the_step_kernel_interpreted_equals_the_python_loop(rows):
+    """``delta_step`` (a Pallas kernel; interpreted here, its own arithmetic):
+    a slot a grid step, the state read once and written over itself. A row
+    with ``beta = g = 0`` keeps its state bit for bit; a fresh row starts
+    from zeros though its state holds NaNs."""
+    from scaling_tpu.obs import kernel_build_count
+
+    q, k, v, g, beta, S0 = operands(jax.random.PRNGKey(17), rows, 1)
+    fresh = jnp.zeros((rows,), bool)
+    before = kernel_build_count("delta_step", interpret=True)
+    o, S = jax.jit(lambda *a: delta_step(*a, interpret=True))(
+        q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], S0, fresh)
+    assert kernel_build_count("delta_step", interpret=True) == before + 1
+    for r in range(rows):
+        want_o, want_S = loop(q[r], k[r], v[r], g[r], beta[r], S0[r])
+        np.testing.assert_allclose(np.asarray(o[r]), want_o[0], atol=ATOL, rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(S[r]), want_S, atol=ATOL, rtol=1e-5)
+    zeros = jnp.zeros_like(g[:, 0])
+    _, kept = delta_step(q[:, 0], k[:, 0], v[:, 0], zeros, zeros, S0, fresh,
+                         interpret=True)
+    assert np.array_equal(np.asarray(kept), np.asarray(S0))          # bit for bit
+    o, S = delta_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                      jnp.full_like(S0, jnp.nan), ~fresh, interpret=True)
+    want_o, want_S = loop(q[0], k[0], v[0], g[0], beta[0], np.zeros_like(S0[0]))
+    np.testing.assert_allclose(np.asarray(o[0]), want_o[0], atol=ATOL, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(S[0]), want_S, atol=ATOL, rtol=1e-5)
+
+
+@pytest.mark.parametrize("w", [1, 2, 31, 32, 33])
+def test_the_chunk_form_equals_the_step(w):
+    q, k, v, g, beta, S0 = operands(jax.random.PRNGKey(w), 3, w)
+    o, S = jax.jit(delta_chunk)(q, k, v, g, beta, S0)
+    for r in range(3):
+        want_o, want_S = loop(q[r], k[r], v[r], g[r], beta[r], S0[r])
+        np.testing.assert_allclose(np.asarray(o[r]), want_o, atol=ATOL, rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(S[r]), want_S, atol=ATOL, rtol=1e-5)
+
+
+def test_a_chunk_with_padding_leaves_the_state_as_the_real_positions_do():
+    q, k, v, g, beta, S0 = operands(jax.random.PRNGKey(7), 2, 8)
+    # row 0: only its first 3 positions are tokens; row 1: none is
+    real = (jnp.arange(8)[None, :] < jnp.asarray([3, 0])[:, None])[..., None]
+    o, S = delta_chunk(q, k, v, jnp.where(real, g, 0.0),
+                       jnp.where(real, beta, 0.0), S0)
+    want_o, want = loop(q[0, :3], k[0, :3], v[0, :3], g[0, :3], beta[0, :3], S0[0])
+    np.testing.assert_allclose(np.asarray(o[0, :3]), want_o, atol=ATOL, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(S[0]), want, atol=ATOL, rtol=1e-5)
+    assert np.array_equal(np.asarray(S[1]), np.asarray(S0[1]))      # bit for bit
+
+
+def test_a_fresh_row_starts_from_zeros_whatever_its_state_holds():
+    q, k, v, g, beta, S0 = operands(jax.random.PRNGKey(8), 2, 5)
+    fresh = jnp.asarray([True, False])
+    o, S = delta_chunk(q, k, v, g, beta, S0.at[0].set(jnp.nan), fresh)
+    want_o, want_S = loop(q[0], k[0], v[0], g[0], beta[0], np.zeros_like(S0[0]))
+    np.testing.assert_allclose(np.asarray(o[0]), want_o, atol=ATOL, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(S[0]), want_S, atol=ATOL, rtol=1e-5)
+    want_o, want_S = loop(q[1], k[1], v[1], g[1], beta[1], S0[1])
+    np.testing.assert_allclose(np.asarray(S[1]), want_S, atol=ATOL, rtol=1e-5)
+
+
+@pytest.mark.parametrize("case", ["g near -20", "beta near 0", "beta near 1"])
+def test_the_gates_extremes_stay_finite_and_right(case):
+    q, k, v, g, beta, S0 = operands(jax.random.PRNGKey(11), 2, 32)
+    if case == "g near -20":
+        g = jnp.full_like(g, -20.0)
+    else:
+        beta = jnp.full_like(beta, 1e-7 if case == "beta near 0" else 1.0 - 1e-7)
+    o, S = jax.jit(delta_chunk)(q, k, v, g, beta, S0)
+    assert np.isfinite(np.asarray(o)).all() and np.isfinite(np.asarray(S)).all()
+    want_o, want_S = loop(q[0], k[0], v[0], g[0], beta[0], S0[0])
+    np.testing.assert_allclose(np.asarray(o[0]), want_o, atol=ATOL, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(S[0]), want_S, atol=ATOL, rtol=1e-5)
+
+
+def test_the_init_and_the_leaves(mixer):
+    layer, _ = mixer
+    params = layer.init(jax.random.PRNGKey(5))
+    A = np.exp(np.asarray(params["A_log"]))
+    dt = np.asarray(jax.nn.softplus(params["dt_bias"]))
+    assert (1.0 <= A).all() and (A <= 16.0).all()
+    assert (dt >= 0.001 - 1e-7).all() and (dt <= 0.1 + 1e-6).all()
+    assert params["in_proj"]["weight"].shape == (H, CONV + NV * DV)
+    assert params["ba_proj"]["weight"].shape == (H, 2 * NV)
+    assert params["conv"]["weight"].shape == (CONV, K)
+    assert "bias" not in params["conv"]
+    assert params["norm"]["weight"].shape == (DV,)
+    for name in ("A_log", "dt_bias"):
+        assert params[name].dtype == jnp.float32
+    assert jax.tree.structure(params) == jax.tree.structure(layer.param_metas())
+
+
+def test_a_sequence_longer_than_a_chunk_is_walked_chunk_by_chunk(mixer, monkeypatch):
+    """75 positions = a chunk of 64 and a ragged one of 11: equal to chunks of
+    16 (5 chunks, 5 positions of padding) and to one chunk of 75 (padded to
+    128 inside)."""
+    from scaling_tpu.nn import gated_delta
+
+    layer, params = mixer
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 75, H))
+    outs = []
+    for chunk in (64, 16, 75):
+        monkeypatch.setattr(gated_delta, "CHUNK", chunk)
+        out, (S, tail) = jax.jit(lambda x: layer(
+            params, x, ForwardContext(), return_state=True))(x)
+        outs.append((np.asarray(out), np.asarray(S), np.asarray(tail)))
+    for out, S, tail in outs[1:]:
+        np.testing.assert_allclose(out, outs[0][0], atol=ATOL, rtol=1e-5)
+        np.testing.assert_allclose(S, outs[0][1], atol=ATOL, rtol=1e-5)
+        assert np.array_equal(tail, outs[0][2])
+    assert outs[0][2].shape == (2, CONV, K - 1)
+
+
+def run_tick(layer, params, x_rows, lines, ctx_len, new_len, w, width=None):
+    """One tick of the served path: row ``r`` brings ``x_rows[r]`` (new_len[r],
+    H). ``width`` None: the row-major caller; else token-major at that many
+    places. Returns ``([each row's outputs], state, conv)``."""
+    state, conv = lines
+    if width is None:
+        batch = jnp.stack([jnp.pad(r, ((0, w - r.shape[0]), (0, 0))) for r in x_rows])
+        tmap = None
+    else:
+        packed = jnp.concatenate(x_rows)
+        batch = jnp.pad(packed, ((0, width - packed.shape[0]), (0, 0)))
+        batch = batch.reshape(width // w, w, H)
+        tmap = packed_token_map(jnp.asarray(new_len, jnp.int32), batch.shape[:2], w)
+    out, view = jax.jit(lambda b, v: layer(params, b, ForwardContext(), state=v))(
+        batch, DeltaStateView(
+            state, conv, jnp.asarray(ctx_len, jnp.int32),
+            jnp.asarray(new_len, jnp.int32), tmap))
+    out = np.asarray(out)
+    if width is None:
+        outs = [out[r, :n] for r, n in enumerate(new_len)]
+    else:
+        ends = np.cumsum(new_len)
+        outs = [out.reshape(-1, H)[e - n:e] for e, n in zip(ends, new_len)]
+    return outs, np.asarray(view.state), np.asarray(view.conv)
+
+
+@pytest.mark.parametrize("token_major", [False, True], ids=["row-major", "token-major"])
+def test_state_carried_across_ticks_equals_one_pass(mixer, token_major):
+    """A row served a chunk of 32, then 5 + 1 + 1 + 1 tokens against its line,
+    beside an empty slot and a row that starts later in a REUSED slot (its
+    lines hold an old occupant's values): each row's outputs are its
+    sequence's in one uncached pass. Token-major, at the fewest whole rows of
+    places that hold a tick's tokens, the ticks cross both forms: whole rows,
+    a step beside a gathered chunk, steps alone."""
+    layer, params = mixer
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 40, H))
+    want = np.asarray(jax.jit(lambda x: layer(params, x, ForwardContext()))(x))
+    slots, w = 3, 32
+    lines = (jnp.full((slots, NV, DK, DV), 7.0),           # an old occupant's
+             jnp.full((slots, CONV, K - 1), 7.0))
+    got = {0: [], 2: []}
+    seen = [0, 0]
+    for n0, n2 in ((32, 0), (5, 32), (1, 1), (1, 1), (1, 6)):
+        new_len, ctx_len = [n0, 0, n2], [seen[0], 0, seen[1]]
+        rows = [x[0, seen[0]:seen[0] + n0], x[1, :0], x[1, seen[1]:seen[1] + n2]]
+        width = -(-(n0 + n2) // w) * w if token_major else None
+        outs, *lines = run_tick(layer, params, rows, lines, ctx_len, new_len, w, width)
+        for slot in got:
+            got[slot].append(outs[slot])
+        seen = [seen[0] + n0, seen[1] + n2]
+    np.testing.assert_allclose(np.concatenate(got[0]), want[0], atol=ATOL, rtol=1e-5)
+    np.testing.assert_allclose(np.concatenate(got[2]), want[1], atol=ATOL, rtol=1e-5)
+    # the empty slot's lines were never written
+    assert np.array_equal(lines[0][1], np.full((NV, DK, DV), 7.0))
+    assert np.array_equal(lines[1][1], np.full_like(lines[1][1], 7.0))
+
+
+def width_for(new_len, widths, w):
+    """The engine's rule (serve/engine.py): the smallest width that holds the
+    tick's tokens AND its multi-token rows."""
+    return next(T for T in widths if sum(new_len) <= T
+                and sum(n > 1 for n in new_len) <= split_capacity(T, w))
+
+
+# 8 slots, rows of up to 4 tokens: a small program of 16 places (R = 4
+# multi-token rows) and the full one of 32. (new_len, ctx_len) a case
+SPLIT_W, SPLIT_WIDTHS = 4, (16, 32)
+SPLIT_TICKS = {
+    "every row decodes": ([1] * 8, [5, 9, 3, 7, 1, 2, 8, 4]),
+    "decode rows among chunks of 2..w": ([1, 3, 1, 2, 4, 1, 1, 1],
+                                         [5, 4, 3, 8, 4, 7, 6, 2]),
+    "empty rows": ([0, 1, 0, 0, 1, 0, 2, 0], [0, 3, 5, 0, 2, 9, 4, 0]),
+    "context 0 brings ONE token": ([1, 1, 1, 1, 0, 2, 0, 1],
+                                   [0, 3, 0, 6, 0, 5, 2, 0]),
+    "chunks in reused slots": ([3, 1, 4, 1, 0, 1, 2, 1], [0, 2, 0, 5, 0, 0, 0, 9]),
+    "exactly R multi-token rows": ([2, 2, 3, 2, 1, 1, 1, 1],
+                                   [0, 4, 8, 0, 1, 0, 3, 2]),
+    "R + 1 multi-token rows": ([2, 2, 2, 2, 2, 1, 1, 1], [0, 4, 8, 0, 1, 0, 3, 2]),
+    "a chunk row in the last slot beside a filler place": (
+        [1, 1, 1, 0, 1, 1, 1, 3], [3, 5, 2, 0, 7, 6, 1, 6]),
+}
+
+
+@pytest.mark.parametrize("reference", ["row-major", "token-major at the full width"])
+@pytest.mark.parametrize("case", list(SPLIT_TICKS))
+def test_each_row_in_its_own_form_equals_the_whole_rows_form(mixer, case, reference):
+    """Below the full width a row that brings one token takes the single step
+    and the few that bring more are gathered into a chunk: outputs, state
+    lines and conv tails are the whole-rows form's, whichever caller reaches
+    that. A row at context 0 starts from zeros though its slot holds an old
+    occupant's NaNs; an empty row's lines are not touched."""
+    layer, params = mixer
+    new_len, ctx_len = SPLIT_TICKS[case]
+    small, full = SPLIT_WIDTHS
+    width = width_for(new_len, SPLIT_WIDTHS, SPLIT_W)
+    assert width == (full if case.startswith("R + 1") else small)
+    slots, w = len(new_len), SPLIT_W
+    ks = jax.random.split(jax.random.PRNGKey(len(case)), 3)
+    starts_over = [r for r in range(slots) if ctx_len[r] == 0 and new_len[r] > 0]
+    lines = tuple(
+        jax.random.normal(k, shape).at[jnp.asarray(starts_over, int)].set(jnp.nan)
+        for k, shape in ((ks[0], (slots, NV, DK, DV)), (ks[1], (slots, CONV, K - 1))))
+    x = jax.random.normal(ks[2], (slots, w, H))
+    x_rows = [x[r, :n] for r, n in enumerate(new_len)]
+    got = run_tick(layer, params, x_rows, lines, ctx_len, new_len, w, width)
+    want = run_tick(layer, params, x_rows, lines, ctx_len, new_len, w,
+                    None if reference == "row-major" else full)
+    for r, n in enumerate(new_len):
+        np.testing.assert_allclose(got[0][r], want[0][r], atol=ATOL, rtol=1e-5)
+        assert not n or np.isfinite(got[0][r]).all()
+        if not n:   # bit for bit what the slot held
+            assert np.array_equal(got[1][r], np.asarray(lines[0][r]), equal_nan=True)
+            assert np.array_equal(got[2][r], np.asarray(lines[1][r]), equal_nan=True)
+    np.testing.assert_allclose(got[1], want[1], atol=ATOL, rtol=1e-5)
+    np.testing.assert_allclose(got[2], want[2], atol=ATOL, rtol=1e-5)
+
+
+def test_a_bfloat16_state_is_not_the_float32_one():
+    """What the serving pool must not do: 40 steps with the state rounded to
+    bfloat16 after each leave the outputs off by 30 x ATOL and more."""
+    q, k, v, g, beta, S0 = operands(jax.random.PRNGKey(13), 1, 40, g_scale=0.05)
+    want, _ = loop(q[0], k[0], v[0], g[0], beta[0], S0[0])
+    S, outs = S0, []
+    for t in range(40):
+        o, S = step_rows(*(a[:, t:t + 1] for a in (q, k, v, g, beta)), S)
+        S = S.astype(jnp.bfloat16).astype(jnp.float32)
+        outs.append(np.asarray(o[0, 0]))
+    assert np.abs(np.stack(outs) - want).max() > 30 * ATOL
