@@ -44,6 +44,7 @@ class EmbeddingInput(BaseLayer):
             embedding_dim=architecture.hidden_size,
             dtype=architecture.dtype,
             finetunable_token_ids=architecture.finetunable_token_ids or None,
+            row_lookup=not architecture.weight_tying,
             **extra,
         )
         self.dropout_rate = architecture.dropout_embedding

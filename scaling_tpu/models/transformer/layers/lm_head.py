@@ -206,8 +206,19 @@ class TransformerLMHead(BaseLayer):
         # without the layout its call would pin: the reference's gather, or
         # (data, seq, model) where stages put the vocabulary over (pipe, model)
         h = x["activations"]
-        logits = column_parallel_matmul(
-            h, params["linear"]["weight"].astype(h.dtype), ctx)
+        weight = params["linear"]["weight"].astype(h.dtype)
+        if ctx.zero_gathers_on_entry:
+            # ZeRO-1 gathers the weight on the step's entry, the region below
+            # gathers the rows: say that the weight's gather comes first, so
+            # that it can start under the last layer's matmul. Left to itself
+            # the scheduler may order the rows' gather first, and the weight's
+            # 590 MB (train-pharia7b-4chip) then cross the data pairs with
+            # nothing beside them: 6.3 ms where 3.9 are waited for (PERF.md,
+            # PR 72). ONE matmul reads these rows; where siblings share a
+            # gather (query / key / value, gate / up) the same barrier splits
+            # it and costs more than it hides (+3.8 ms a step there)
+            h, weight = jax.lax.optimization_barrier((h, weight))
+        logits = column_parallel_matmul(h, weight, ctx)
         if logits.ndim == 3:
             logits = shard_logits(logits, ctx.mesh)
         if self.logit_mult is not None:

@@ -37,6 +37,12 @@ class ForwardContext:
     context_parallel_variant: str = "ring"
     # mesh is needed for explicit collectives; None on single device
     mesh: Optional[Any] = None
+    # a train step under ZeRO-1 over a data axis (static): the weights arrive
+    # as the shards their masters live on and ``Optimizer.gather_params``
+    # has gathered all but those a layer consumes there
+    # (parallel/sharding.py, ``lookup_on_data_shard``). ``build_train_step``
+    # sets it from the optimizer; False in every other pass
+    zero_gathers_on_entry: bool = False
     # paged-decode attention back-end (static), decided HERE: 'pallas'
     # streams blocks through the flash-style kernel
     # (nn/paged_attention.py) and is what serves; 'xla' gathers each

@@ -36,6 +36,8 @@ import jax.numpy as jnp
 
 from ..parallel.sharding import (
     constrain,
+    lookup_on_data_shard,
+    lookup_rows_on_data_shard,
     shard_activation_replicated_h,
     shard_activation_sp,
     shard_activation_tp,
@@ -206,7 +208,12 @@ class VocabParallelEmbedding(BaseLayer):
         dtype=jnp.float32,
         init_method: Callable = xavier_normal_init,
         finetunable_token_ids: Optional[list[int]] = None,
+        row_lookup: bool = True,
     ):
+        # every consumer reads the table by row (``ParamMeta.row_lookup``):
+        # False where it is another layer's matrix too (the head's, through
+        # a ``TiedLayerSpec``)
+        self.row_lookup = row_lookup
         self.num_embeddings = num_embeddings
         self.embedding_dim = embedding_dim
         self.dtype = dtype
@@ -226,13 +233,21 @@ class VocabParallelEmbedding(BaseLayer):
                 is_model_parallel=True,
                 model_parallel_dimension=0,
                 lr_group="embedding",
+                row_lookup=self.row_lookup,
             )
         }
 
     def __call__(self, params: dict, token_ids: jax.Array, ctx: ForwardContext) -> jax.Array:
+        weight = params["weight"]
+        if lookup_on_data_shard(self.param_metas()["weight"], weight.shape,
+                                ctx.mesh, ctx.zero_gathers_on_entry):
+            # the step left the table on ZeRO-1's shard: look up there and
+            # exchange the rows
+            return lookup_rows_on_data_shard(
+                token_ids, weight.astype(self.dtype), ctx.mesh,
+                ctx.sequence_parallel)
         # gather from the vocab-sharded table; XLA handles the out-of-shard
         # masking + psum that the reference hand-codes
-        weight = params["weight"]
         y = weight.astype(self.dtype)[token_ids]
         if ctx.sequence_parallel:
             y = shard_activation_sp(y, ctx.mesh)

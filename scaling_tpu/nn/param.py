@@ -42,6 +42,10 @@ class ParamMeta:
     lr_group: str = "default"
     # marks norm params whose grads need mp-summing under sequence parallel
     is_sequence_parallel_norm: bool = False
+    # every consumer of the leaf reads ROWS of it by index and none multiplies
+    # by it (an untied embedding table): ZeRO-1 may leave it on the masters'
+    # shard for the lookup (parallel/sharding.py, ``lookup_on_data_shard``)
+    row_lookup: bool = False
 
     @property
     def key(self) -> str:

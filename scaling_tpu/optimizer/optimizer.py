@@ -208,7 +208,7 @@ class Optimizer:
             )
         return NamedSharding(self.topology.mesh, P(*spec))
 
-    def _gathers_on_entry(self) -> bool:
+    def gathers_on_entry(self) -> bool:
         """ZeRO stage 1 over a data axis wider than 1: the compute copy
         crosses the step boundary as the data shard its master lives on and
         the step gathers it once, on entry. (Stage 3 keeps it so as well,
@@ -263,21 +263,27 @@ class Optimizer:
                     old.delete()
         return jax.tree.unflatten(td, leaves)
 
-    def gather_params(self, params: Any) -> tuple[Any, int]:
+    def gather_params(self, params: Any) -> tuple[Any, int, int]:
         """Inside a jitted step, before anything consumes ``params``: ZeRO-1's
         ONE gather of each optimized leaf from the masters' placement to its
         own spec. Said outside ``value_and_grad`` it happens once a step (the
         backward reads the gathered copy), bf16 on the wire, and depends on
         nothing but its consumer, so the compiler can run a layer's gather
-        under the layers before it. Returns the gathered tree and how many
-        leaves it gathered (``step`` scatters as many gradients back): 0
-        without ZeRO-1 over a data axis."""
+        under the layers before it. A leaf that its layer consumes ON the
+        shard (``lookup_on_data_shard``: an untied embedding table, of which
+        a step reads a few thousand rows) stays where it is, and its gradient
+        comes back data-reduced in the same placement. Returns the tree, how
+        many leaves it gathered (``step`` scatters as many gradients back)
+        and how many it left for a lookup on the shard: 0 and 0 without
+        ZeRO-1 over a data axis."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        if not self._gathers_on_entry():
-            return params, 0
+        from ..parallel.sharding import lookup_on_data_shard
+
+        if not self.gathers_on_entry():
+            return params, 0, 0
         leaves, td = jax.tree.flatten(params)
-        gathered = 0
+        gathered = looked_up = 0
         for i, (p, m, gi) in enumerate(
             zip(leaves, self._meta_leaves, self._group_index)
         ):
@@ -287,9 +293,14 @@ class Optimizer:
                 own, p.ndim
             ):
                 continue
+            if lookup_on_data_shard(
+                m, p.shape, self.topology.mesh, self.gathers_on_entry()
+            ):
+                looked_up += 1
+                continue
             leaves[i] = jax.lax.with_sharding_constraint(p, own)
             gathered += 1
-        return jax.tree.unflatten(td, leaves), gathered
+        return jax.tree.unflatten(td, leaves), gathered, looked_up
 
     def abstract_state(self, params: Any) -> OptimizerState:
         """``init_state``'s output as ShapeDtypeStructs with the ZeRO
@@ -429,14 +440,16 @@ class Optimizer:
     ) -> tuple[Any, OptimizerState, OptimizerStepOutput]:
         c = self.config
         g_leaves = jax.tree.leaves(grads)
-        if self._gathers_on_entry():
+        if self.gathers_on_entry():
             # each gradient onto the shard that consumes it, BEFORE the
             # overflow check and the norm read it: a reduce-scatter over the
             # data axis, then a sum over the shard and a scalar all-reduce,
             # never an all-reduce onto every data rank that then uses its
             # 1/dp. (The chip's compiler derived as much from the masters'
             # placement, fused as ``all-reduce-scatter``; said here it does
-            # not hang on what propagation finds.)
+            # not hang on what propagation finds.) The gradient of a leaf
+            # looked up on its shard (``gather_params``) is there already:
+            # for it this is the identity.
             g_leaves = [
                 jax.lax.with_sharding_constraint(g, self._master_sharding(m, g.shape))
                 if gi >= 0

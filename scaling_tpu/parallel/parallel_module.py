@@ -150,6 +150,17 @@ def _jit_train_step(step: Callable, optimizer: Optimizer, donate: bool) -> Calla
     return placed_step
 
 
+def _tied_meta(meta: ParamMeta, key: str) -> ParamMeta:
+    if meta.row_lookup:
+        # ZeRO-1 would leave the leaf on its shard for the owner's lookup,
+        # and the consumer that multiplies by it would gather it at each use
+        raise ValueError(
+            f"{meta.parameter_name}: tied under {key!r} but declared "
+            "row_lookup; build its layer without "
+            "(VocabParallelEmbedding(row_lookup=False))")
+    return type(meta)(**{**meta.__dict__, "tied_key": key})
+
+
 @dataclass
 class TiedInfo:
     key: str
@@ -247,7 +258,7 @@ class ParallelModule:
                 meta = _get_path(metas[owner_name], attr)
                 metas[owner_name] = _set_path(
                     metas[owner_name], attr,
-                    type(meta)(**{**meta.__dict__, "tied_key": info.key}),
+                    _tied_meta(meta, info.key),
                 )
             for c in info.consumers:
                 for attr in info.attributes:
@@ -353,7 +364,7 @@ class ParallelModule:
                 meta = _get_path(metas[owner_name], attr)
                 metas[owner_name] = _set_path(
                     metas[owner_name], attr,
-                    type(meta)(**{**meta.__dict__, "tied_key": info.key}),
+                    _tied_meta(meta, info.key),
                 )
             for c in info.consumers:
                 for attr in info.attributes:
@@ -425,11 +436,13 @@ class ParallelModule:
                 x = layer(layer_p, x, ctx)
         return x
 
-    def _make_ctx(self, deterministic: bool, dropout_key) -> ForwardContext:
+    def _make_ctx(self, deterministic: bool, dropout_key,
+                  zero_gathers_on_entry: bool = False) -> ForwardContext:
         topo = self.topology
         return ForwardContext(
             dropout_key=dropout_key,
             deterministic=deterministic,
+            zero_gathers_on_entry=zero_gathers_on_entry,
             sequence_parallel=bool(topo and topo.sequence_parallel),
             model_parallel_size=topo.model_parallel_size if topo else 1,
             context_parallel_size=topo.context_parallel_size if topo else 1,
@@ -477,27 +490,33 @@ class ParallelModule:
         manual_boundaries = get_registry().gauge("train_sp_manual_boundaries")
         manual_boundaries.set(0)
         # ZeRO-1's data-axis traffic (optimizer.py): leaves gathered once on
-        # entry, gradients reduce-scattered onto the masters' placement; set
-        # when the step is traced, 0 where ZeRO is off or the data axis is 1
+        # entry, gradients reduce-scattered onto the masters' placement, and
+        # leaves neither gathered nor scattered because their layer looks
+        # rows up on the shard; set when the step is traced, 0 where ZeRO is
+        # off or the data axis is 1
         entry_gathers = get_registry().gauge("train_zero_entry_gathers")
         scattered_grads = get_registry().gauge("train_zero_scattered_grads")
+        shard_lookups = get_registry().gauge("train_zero_shard_lookups")
         entry_gathers.set(0)
         scattered_grads.set(0)
+        shard_lookups.set(0)
 
         scaler_enabled = optimizer.config.loss_scaler.enable
 
-        def log_traced(zero_leaves: int):
+        def log_traced(zero_leaves: int, looked_up: int):
             """Called while a step is traced, after the forward: what the
             traced program does, on one line."""
             entry_gathers.set(zero_leaves)
             scattered_grads.set(zero_leaves)
+            shard_lookups.set(looked_up)
             logger.info(
                 f"train step: {int(manual_boundaries.value)} tensor-parallel "
                 "region(s) entered through explicit collectives; ZeRO-1 over "
                 f"the data axis: {zero_leaves} leaves gathered on entry "
                 f"(train_zero_entry_gathers), {zero_leaves} gradients "
                 "reduce-scattered onto the masters' placement "
-                "(train_zero_scattered_grads)")
+                f"(train_zero_scattered_grads), {looked_up} looked up on "
+                "their shard (train_zero_shard_lookups)")
 
         if self._has_spatial_pp:
             return self._build_spatial_train_step(
@@ -507,7 +526,9 @@ class ParallelModule:
             # PEFT: frozen leaves produce constant-zero grads, so XLA drops
             # their weight-grad matmuls and DP syncs (optimizer.py)
             params = optimizer.freeze_frozen_params(params)
-            ctx = self._make_ctx(deterministic=False, dropout_key=dropout_key)
+            ctx = self._make_ctx(
+                deterministic=False, dropout_key=dropout_key,
+                zero_gathers_on_entry=optimizer.gathers_on_entry())
             out = self.forward(params, mb, ctx)
             manual_boundaries.set(ctx.sp_manual_boundaries)
             loss, metrics = loss_function(out, mb)
@@ -518,7 +539,7 @@ class ParallelModule:
 
         def step(params, opt_state, micro_batches, dropout_key):
             loss_scale = opt_state.loss_scaler.current_scale
-            params, zero_leaves = optimizer.gather_params(params)
+            params, zero_leaves, looked_up = optimizer.gather_params(params)
 
             grad_fn = jax.value_and_grad(microbatch_loss, has_aux=True)
 
@@ -545,7 +566,7 @@ class ParallelModule:
                 loss_scale,
             )
             zero_metrics = jax.tree.map(lambda m: jnp.zeros((), jnp.float32), metrics0)
-            log_traced(zero_leaves)
+            log_traced(zero_leaves, looked_up)
 
             if gas == 1:
                 (grads, loss_sum, metrics_sum), _ = body(
@@ -651,11 +672,13 @@ class ParallelModule:
 
         def step(params, opt_state, micro_batches, dropout_key):
             loss_scale = opt_state.loss_scaler.current_scale
-            params, zero_leaves = optimizer.gather_params(params)
+            # under stages no leaf is looked up on its shard
+            # (``lookup_on_data_shard``): the contexts below say nothing
+            params, zero_leaves, looked_up = optimizer.gather_params(params)
             (_, (loss, metrics)), grads = jax.value_and_grad(
                 spatial_loss, has_aux=True
             )(params, micro_batches, dropout_key, loss_scale)
-            log_traced(zero_leaves)
+            log_traced(zero_leaves, looked_up)
             new_params, new_opt_state, opt_out = optimizer.step(
                 params, grads, opt_state, compute_dtype=self.compute_dtype
             )

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..ops.row_scatter import take_rows
 from ..topology.topology import CONTEXT_AXIS, DATA_AXIS, MODEL_AXIS, PIPE_AXIS
 
 
@@ -206,6 +207,83 @@ def sp_leave(x: jax.Array, weight: jax.Array, mesh: Mesh) -> jax.Array:
         in_specs=(P(DATA_AXIS, None, MODEL_AXIS), P(MODEL_AXIS, None)),
         out_specs=P(DATA_AXIS, MODEL_AXIS, None),
     )(x, weight)
+
+
+def lookup_on_data_shard(meta, shape: tuple, mesh: Optional[Mesh],
+                         gathers_on_entry: bool) -> bool:
+    """The ONE place that decides whether a leaf is consumed where ZeRO-1
+    keeps it between steps (the masters' placement, the data axis on its
+    columns) and never gathered: ``Optimizer.gather_params`` leaves such a
+    leaf alone and ``VocabParallelEmbedding`` looks its tokens up through
+    :func:`lookup_rows_on_data_shard`, both by this answer.
+
+    All of: the step is entered with the compute copy on the masters' shards
+    (``Optimizer.gathers_on_entry``: ZeRO-1 over a data axis wider than 1;
+    False wherever no such optimizer built the pass: evaluation, inference,
+    every serve program); the leaf is only ever read by row
+    (``ParamMeta.row_lookup``: a table that is the head's matrix too still
+    needs the gather); no pipe or context axis in play, as for
+    :func:`sp_boundary_is_manual`; and the masters' rule really put the data
+    axis on the columns of a ``(model, None)`` table (the data axis divides
+    them)."""
+    if not gathers_on_entry or not meta.row_lookup or mesh is None:
+        return False
+    if any(_axis_in_mesh(mesh, axis) and mesh.shape[axis] > 1
+           for axis in (PIPE_AXIS, CONTEXT_AXIS)):
+        return False
+    return spec_with_data_axis(
+        meta.partition_spec, shape, mesh.shape[DATA_AXIS]
+    ) == (MODEL_AXIS, DATA_AXIS)
+
+
+def lookup_rows_on_data_shard(token_ids: jax.Array, table: jax.Array,
+                              mesh: Mesh, to_sp: bool) -> jax.Array:
+    """``table[token_ids]`` for ``token_ids`` ``(b, s)`` (batch over the data
+    axis) against a ``table`` ``(vocab, h)`` that lies as ZeRO-1's masters do:
+    the vocabulary over the model axis, the COLUMNS over the data axis.
+    Returns ``(b, s, h)`` with ``h`` whole: in the SP layout where ``to_sp``,
+    else replicated over the model axis.
+
+    One manual region. Every chip looks up the tokens of ALL data ranks (their
+    ids are gathered: 4 bytes a token) in the columns it holds; a row that
+    another model rank holds reads as zeros and its cotangent is dropped; the
+    partial rows are summed over the model axis (a reduce-scatter on the
+    leading dimension of 2-D rows where the result is sequence parallel:
+    :func:`_rows_first`), and an all-to-all over the data axis hands each
+    rank ITS tokens' other columns: a few MB of rows cross the links where
+    the whole table did. The backward, by transposition: the exchange back,
+    the all-gather over the model axis, and ONE sum of every data rank's
+    rows into the shard (``ops/row_scatter.py``: a kernel on the chip, where
+    XLA's scatter-add is a serial loop over the rows), which is the
+    data-reduced gradient already in the masters' placement. A table that
+    arrives gathered is sliced locally by ``in_specs`` and gives the same
+    rows."""
+    mp = mesh.shape[MODEL_AXIS]
+    b, s = token_ids.shape
+    assert b % mesh.shape[DATA_AXIS] == 0, (token_ids.shape, mesh)
+    scatter = to_sp and mp > 1 and s % mp == 0
+
+    def region(ids, shard):
+        ids = jax.lax.all_gather(ids, DATA_AXIS, axis=0, tiled=True)
+        # another model rank's row falls outside the shard: the lookup reads
+        # zeros there and its gradient drops the row
+        rows = take_rows(
+            shard, ids - jax.lax.axis_index(MODEL_AXIS) * shard.shape[0])
+        if scatter:
+            rows = _batch_first(
+                jax.lax.psum_scatter(_rows_first(rows), MODEL_AXIS,
+                                     scatter_dimension=0, tiled=True), b)
+        else:
+            rows = jax.lax.psum(rows, MODEL_AXIS)
+        return jax.lax.all_to_all(
+            rows, DATA_AXIS, split_axis=0, concat_axis=2, tiled=True)
+
+    y = jax.shard_map(
+        region, mesh=mesh,
+        in_specs=(P(DATA_AXIS, None), P(MODEL_AXIS, DATA_AXIS)),
+        out_specs=P(DATA_AXIS, MODEL_AXIS if scatter else None, None),
+    )(token_ids, table)
+    return shard_activation_sp(y, mesh) if to_sp and not scatter else y
 
 
 def shard_param(x: jax.Array, mesh: Optional[Mesh], spec: tuple) -> jax.Array:

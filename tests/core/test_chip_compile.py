@@ -1077,12 +1077,31 @@ def test_a_small_share_of_the_experts_meets_one_call_a_matrix_a_pass(
     assert "ragged-dot" not in text and "[4096,7168]" not in text
 
 
+@pytest.mark.parametrize("num_rows,width,rows,dtype", [
+    (64000, 2304, 8192, jnp.bfloat16), (64000, 2304, 8192, jnp.float32),
+    (32000, 4096, 4096, jnp.bfloat16)],
+    ids=["pharia-shard-bf16", "pharia-shard-f32", "mistral-table-bf16"])
+def test_scatter_add_rows_compiles(one_chip, num_rows, width, rows, dtype):
+    """``ops/row_scatter.py`` at ``train-pharia7b-4chip``'s shape (both data
+    ranks' 8,192 cotangent rows of 2,304 columns into the ``[64000, 2304]``
+    shard; in float32 too, a float32 run's type) and at a whole one-chip
+    table's: ONE Mosaic call, and XLA's ``scatter`` nowhere."""
+    from scaling_tpu.ops.row_scatter import scatter_add_rows
+
+    text = jax.jit(functools.partial(
+        scatter_add_rows, num_rows=num_rows, interpret=False)).lower(
+        jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((rows, width), dtype, sharding=one_chip),
+    ).compile().as_text()
+    assert len(custom_calls(text)) == 1 and " scatter(" not in text
+
+
 @pytest.fixture(scope="module")
 def pharia_step(topo):
     """``train-pharia7b-4chip``'s own step (the benchmark's configuration and
     traffic files, TP=2 x DP=2 + ZeRO-1 + SP) at depth 1, compiled ONCE for
     the described 2x2 over abstract weights and optimizer state, with the
-    splash kernel as the chip runs it: the optimised text, the compiler's
+    splash kernel and the embedding gradient's kernel as the chip runs them: the optimised text, the compiler's
     memory analysis, the gauges ``build_train_step`` and the trace set, and
     the cell's sizes."""
     import json
@@ -1134,6 +1153,8 @@ def pharia_step(topo):
         patch.setattr(
             "scaling_tpu.ops.flash_attention.flash_attention_supported",
             lambda seq_len, head_dim, platform=None: True)
+        patch.setattr("scaling_tpu.ops.row_scatter.row_scatter_interpret",
+                      lambda platform=None: False)
         step = module.build_train_step(optimizer, loss_function)
         compiled = step.lower(
             params, opt_state, batch,
@@ -1142,7 +1163,8 @@ def pharia_step(topo):
     assert text.count("tpu_custom_call") >= 3  # splash: forward, dq, dkv
     gauges = {name: get_registry().gauge(name).value
               for name in ("train_loss_vocab_shards", "train_sp_manual_boundaries",
-                           "train_zero_entry_gathers", "train_zero_scattered_grads")}
+                           "train_zero_entry_gathers", "train_zero_scattered_grads",
+                           "train_zero_shard_lookups")}
     return SimpleNamespace(
         text=text, memory=compiled.memory_analysis(), gauges=gauges,
         vocab=vocab, hidden=hidden, seq=seq)
@@ -1212,7 +1234,9 @@ def test_pharia_train_step_crosses_tp_regions_by_reduce_scatter(pharia_step):
     its own keeps GSPMD's backward all-reduce and adds a scatter behind it).
     Five scatters at depth 1: attention and MLP forward, and the backward of
     the head's, the MLP's and the attention's inputs (query, key and value
-    share one); 4 a layer + 1 at any depth."""
+    share one); 4 a layer + 1 at any depth. Since ISSUE 72 a sixth: the
+    embedding's rows, looked up on ZeRO-1's shard, are summed over the model
+    pair into the same layout (``lookup_rows_on_data_shard``)."""
     text, hidden, seq = pharia_step.text, pharia_step.hidden, pharia_step.seq
     # the attention, the MLP and the head each entered by hand
     assert pharia_step.gauges["train_sp_manual_boundaries"] == 3
@@ -1226,8 +1250,8 @@ def test_pharia_train_step_crosses_tp_regions_by_reduce_scatter(pharia_step):
 
     assert not yielding("all-reduce", whole), yielding("all-reduce", whole)[:3]
     scatters = yielding("reduce-scatter", half)
-    assert len(scatters) == 5, scatters
-    assert len([line for line in collectives if " reduce-scatter" in line]) == 5
+    assert len(scatters) == 6, scatters
+    assert len([line for line in collectives if " reduce-scatter" in line]) == 6
     assert all("replica_groups={{0,1},{2,3}}" in line for line in scatters)
     # the activation gathered over the model pairs, at the top level: one a
     # region forward (3), and what the backward gathers again (today 1)
@@ -1254,10 +1278,19 @@ def test_pharia_train_step_gathers_each_weight_once_on_entry(pharia_step):
     7). Each weight gradient crosses the data pairs fused as
     ``all-reduce-scatter`` and yields the shard that the master consumes.
 
-    The attention's ``[4608,2304]`` / ``[2304,4608]`` and the embedding's
-    gathers stay synchronous (3.2 + 6.3 ms at depth 7): a later PR that
-    makes them asynchronous, or that brings back a tail gather, changes the
-    counts here."""
+    Which gathers the compiler runs beside a matmul is its scheduler's
+    choice, and this depth-1 step is the FIRST layer alone. Since ISSUE 72
+    nothing gathers the embedding's table (the next case) and the first
+    layer's gathers start under the lookup instead of behind a 6.3 ms
+    gather: the attention's ``[4608,2304]`` / ``[2304,4608]`` are
+    asynchronous here and the MLP-in's ``[4608,9216]`` is not (0.9 ms on the
+    chip's line, once a step); from the second layer on the chip's step
+    keeps the parent's pattern (depth 7, compiled the same way: MLP-in 6 of
+    7, MLP-out 7 of 7 asynchronous, the attention's 14 synchronous, 3.2 ms).
+    The head's stays asynchronous only because ``TransformerLMHead`` orders
+    the weight's gather before the rows' (without it: synchronous, 6.3 ms
+    where 3.9 are waited for). A later PR that brings back a tail gather, or
+    loses the head's overlap, changes the counts here."""
     text, hidden = pharia_step.text, pharia_step.hidden
     half_vocab, mlp = pharia_step.vocab // 2, 2 * hidden
     leaves = pharia_step.gauges["train_zero_entry_gathers"]
@@ -1275,9 +1308,9 @@ def test_pharia_train_step_gathers_each_weight_once_on_entry(pharia_step):
 
     entry = text[text.index("\nENTRY "):].split("\n\n")[0]
     matrices = {  # shape a chip holds under TP=2 -> asynchronous or not
-        f"bf16[{hidden},{mlp}]": True, f"bf16[{mlp},{hidden}]": True,
-        f"bf16[{hidden},{half_vocab}]": True, f"bf16[{half_vocab},{hidden}]": False,
-        f"bf16[{hidden},{hidden // 2}]": False, f"bf16[{hidden // 2},{hidden}]": False,
+        f"bf16[{hidden},{mlp}]": False, f"bf16[{mlp},{hidden}]": True,
+        f"bf16[{hidden},{half_vocab}]": True,
+        f"bf16[{hidden},{hidden // 2}]": True, f"bf16[{hidden // 2},{hidden}]": True,
     }
     for shape, is_async in matrices.items():
         # one collective = one channel (an asynchronous one is written out
@@ -1294,6 +1327,58 @@ def test_pharia_train_step_gathers_each_weight_once_on_entry(pharia_step):
         whole = [line.strip()[:160] for line in entry.splitlines()
                  if f"= {shape}{{" in line and " all-reduce(" in line]
         assert not whole, whole
-    fused = re.findall(r"\n%all-reduce-scatter\.\d+ \(input\S*: (bf16\[[0-9,]+\])", text)
+    fused = re.findall(r"\n%all-reduce-scatter(?:\.\d+)? \(input\S*: (bf16\[[0-9,]+\])", text)
     assert {f"bf16[{hidden},{half_vocab}]", f"bf16[{hidden},{mlp}]",
             f"bf16[{mlp},{hidden}]"} <= set(fused), fused
+
+
+def test_pharia_train_step_looks_tokens_up_on_the_zero_shard(pharia_step):
+    """The same compiled step (ISSUE 72): the embedding's table, a chip's
+    ``bf16[64000,4608]`` under TP=2, never exists. Until PR 72 the step's
+    first operation gathered it over the data pairs from the masters'
+    ``[64000,2304]`` shards (6.3 ms at any depth, nothing to run under) to
+    read 4,096 rows of it. Now the tokens of BOTH data ranks are looked up in
+    the shard's columns (their ids gathered over the data pairs: 32 KB), the
+    partial rows reduce-scattered over the model pairs, and ONE all-to-all
+    over the data pairs hands each rank its tokens' other columns: 9 MB where
+    295 MB went. The backward is the exchange back and ONE sum of 8,192 rows
+    of 2,304 into the shard: the gradient is born data-reduced in the
+    masters' placement. (The parent's compiler had placed the sum on the
+    shard already, by way of an all-to-all over all four chips and a pairwise
+    permute, as XLA's ``scatter``: a serial loop over the rows, 6.3 ms on the
+    chip. It is ``ops/row_scatter.py``'s kernel now and no ``scatter`` is
+    left in the step.) One leaf fewer is gathered and scattered than the
+    parent's 16, and the temporaries fall (1.65e9 -> 1.57e9)."""
+    text, hidden, seq = pharia_step.text, pharia_step.hidden, pharia_step.seq
+    half_vocab = pharia_step.vocab // 2
+    gauges = pharia_step.gauges
+    assert gauges["train_zero_shard_lookups"] == 1
+    assert (gauges["train_zero_entry_gathers"]
+            == gauges["train_zero_scattered_grads"] == 15)
+    # nothing yields or takes the whole table: no gather, no fused
+    # all-reduce-scatter, no temporary
+    assert f"[{half_vocab},{hidden}]" not in text
+    over_data = "replica_groups={{0,2},{1,3}}"
+    exchanges = [line.strip() for line in text.splitlines()
+                 if " all-to-all(" in line]
+    assert len(exchanges) == 2 and all(over_data in e for e in exchanges), exchanges
+    rows = 2 * (seq // 2) * (hidden // 2)  # both ranks' tokens, half the columns
+    for exchange, phase in zip(exchanges, ("jvp()", "transpose(jvp())")):
+        assert f'op_name="jit(step)/{phase}/shard_map/all_to_all"' in exchange
+        dims = re.search(r"= bf16\[([0-9,]+)\]", exchange).group(1)
+        assert np.prod([int(d) for d in dims.split(",")]) == rows, exchange
+    assert f"= bf16[2,{seq // 2},{hidden // 2}]" in exchanges[0]
+    # the ids of the other data rank's batch, not its table
+    ids = [line for line in text.splitlines() if " all-gather(" in line
+           and "= s32[" in line and "shard_map/all_gather" in line]
+    assert len(ids) == 1 and f"s32[2,1,{seq}]" in ids[0], ids
+    # the gradient: ONE call of the kernel yields the shard's, and XLA
+    # scatters nothing
+    assert " scatter(" not in text
+    sums = [line.strip()[:120] for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            and line.strip().startswith("%scatter_add_rows")]
+    assert len(sums) == 1, sums
+    assert f"= bf16[{half_vocab},{hidden // 2}]" in sums[0]
+    assert pharia_step.memory.temp_size_in_bytes < 1.9e9
+    assert pharia_step.memory.temp_size_in_bytes < 1.6e9  # the parent: 1.654e9

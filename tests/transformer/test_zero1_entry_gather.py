@@ -23,12 +23,14 @@ from scaling_tpu.models.transformer.model import (
 )
 from scaling_tpu.nn import ParamMeta
 from scaling_tpu.obs import get_registry
+from scaling_tpu.parallel.sharding import lookup_on_data_shard
 from scaling_tpu.topology import Topology
 from scaling_tpu.topology.topology import DATA_AXIS
 
 from .test_training_vocab_parallel import make_batch, make_config
 
-GAUGES = ("train_zero_entry_gathers", "train_zero_scattered_grads")
+GAUGES = ("train_zero_entry_gathers", "train_zero_scattered_grads",
+          "train_zero_shard_lookups")
 
 
 def built(zero=True, mp=2, dp=2, precision="bfloat16", **kw):
@@ -55,6 +57,13 @@ def data_sharded_leaves(optimizer, params):
                for e in spec if e is not None):
             out.append((m, p))
     return out
+
+
+def looked_up_on_shard(optimizer, moved):
+    """Of ``data_sharded_leaves``, those never gathered (ISSUE 72): the
+    untied embedding table, whose rows are looked up where it lies."""
+    return [(m, p) for m, p in moved if lookup_on_data_shard(
+        m, p.shape, optimizer.topology.mesh, optimizer.gathers_on_entry())]
 
 
 def in_masters_placement(optimizer, params):
@@ -103,9 +112,11 @@ def collectives_over(text, mesh, op, axis):
 def test_one_gather_a_leaf_and_outputs_stay_sharded(devices):
     """(b) The compiled TP=2 x DP=2 ZeRO-1 step gathers each data-sharded
     leaf over ``data`` once (the backward reads the gathered copy: a second
-    gather a leaf is ZeRO-3's traffic), gathers nothing over ``data`` besides,
-    and returns every compute copy as the shard it was cast from: no gather
-    is left at the step's tail. (On the CPU a reduce-scatter is compiled as
+    gather a leaf is ZeRO-3's traffic) but the embedding table, which it
+    never gathers (its rows are looked up on the shard and exchanged: one
+    all-to-all over ``data`` forward, one backward), gathers nothing over
+    ``data`` besides, and returns every compute copy as the shard it was cast
+    from: no gather is left at the step's tail. (On the CPU a reduce-scatter is compiled as
     all-reduce + slice, so what the gradients cross chips as is held where
     the chip's compiler runs: tests/core/test_chip_compile.py.)"""
     module, optimizer, params, opt_state, step = built()
@@ -114,6 +125,9 @@ def test_one_gather_a_leaf_and_outputs_stay_sharded(devices):
     mesh = module.topology.mesh
     moved = data_sharded_leaves(optimizer, params)
     assert len(moved) > 10
+    (table_meta, table), = looked_up_on_shard(optimizer, moved)
+    assert table_meta.parameter_name == "embedding.weight"
+    moved = [(m, p) for m, p in moved if m is not table_meta]
     gathers = collectives_over(compiled.as_text(), mesh, "all-gather", DATA_AXIS)
     # each gathered to the shape its own spec leaves on a chip, as often as
     # leaves have that shape (the CPU compiler computes bf16 as f32: dims only)
@@ -125,6 +139,9 @@ def test_one_gather_a_leaf_and_outputs_stay_sharded(devices):
         tuple(int(d) for d in re.search(r"\[([0-9,]*)\]", g[0]).group(1).split(","))
         for g in gathers if re.match(r"(bf16|f32)\[", g[0]))
     assert {shape: got[shape] for shape in want} == dict(want), (got, want)
+    assert got[(table.shape[0] // 2, table.shape[1])] == 0, got  # the table
+    exchanges = collectives_over(compiled.as_text(), mesh, "all-to-all", DATA_AXIS)
+    assert len(exchanges) == 2, exchanges
     out_params = compiled.output_shardings[0]
     for sh, (p, m) in zip(jax.tree.leaves(out_params),
                           zip(jax.tree.leaves(params), optimizer._meta_leaves)):
@@ -133,20 +150,23 @@ def test_one_gather_a_leaf_and_outputs_stay_sharded(devices):
 
 @pytest.mark.parametrize("dp", [2, 1], ids=["dp2", "dp1"])
 def test_gauges_count_the_leaves_moved(devices, dp):
-    """(d) Both gauges read the leaves whose master carries the data axis
-    (24 of the toy's 28: four have no dimension that 2 divides) once a step
-    over a data axis has been traced, and 0 where the axis is 1 (one chip's
-    program is the one it was: no constraint, no placement)."""
+    """(d) The first two gauges read the leaves whose master carries the data
+    axis (24 of the toy's 28: four have no dimension that 2 divides) less the
+    one looked up on its shard, which the third counts, once a step over a
+    data axis has been traced, and 0 where the axis is 1 (one chip's program
+    is the one it was: no constraint, no placement, no manual lookup)."""
     registry = get_registry()
     for name in GAUGES:
         registry.gauge(name).set(-1)
     module, optimizer, params, opt_state, step = built(dp=dp)
-    assert [registry.gauge(n).value for n in GAUGES] == [0, 0]  # built, not traced
+    assert [registry.gauge(n).value for n in GAUGES] == [0, 0, 0]  # built, not traced
     batch = module.shard_batch(make_batch(), stacked=True)
     step.lower(params, opt_state, batch, jax.random.PRNGKey(0))
-    moved = len(data_sharded_leaves(optimizer, params))
-    assert moved == (24 if dp == 2 else 0)
-    assert [registry.gauge(n).value for n in GAUGES] == [moved, moved]
+    leaves = data_sharded_leaves(optimizer, params)
+    moved, looked_up = len(leaves), len(looked_up_on_shard(optimizer, leaves))
+    assert (moved, looked_up) == ((24, 1) if dp == 2 else (0, 0))
+    assert [registry.gauge(n).value for n in GAUGES] == [
+        moved - looked_up, moved - looked_up, looked_up]
     if dp == 1:
         assert in_masters_placement(optimizer, params)  # the spec itself
 
@@ -158,7 +178,7 @@ def test_zero_off_builds_the_jitted_function_itself(devices):
     assert hasattr(step, "trace") and hasattr(step, "eval_shape")
     batch = module.shard_batch(make_batch(), stacked=True)
     step.lower(params, opt_state, batch, jax.random.PRNGKey(0))
-    assert [get_registry().gauge(n).value for n in GAUGES] == [0, 0]
+    assert [get_registry().gauge(n).value for n in GAUGES] == [0, 0, 0]
 
 
 def test_five_steps_equal_zero_off(devices):
