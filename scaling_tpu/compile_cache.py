@@ -7,6 +7,10 @@ its first compile. Where ``JAX_COMPILATION_CACHE_DIR`` is set the
 directory is the environment's to place and no directory is set in code;
 otherwise it is one fixed directory inside the checkout (the path is
 part of the cache key, so a directory that moves never hits).
+
+It is also where the program starts to listen to JAX's compile events
+(``obs/compile_events.py``): whatever is traced, lowered, compiled or read
+back from this cache afterwards is a row of the recorder by program name.
 """
 
 from __future__ import annotations
@@ -25,6 +29,9 @@ def enable_compile_cache() -> Optional[str]:
     the cache have mis-executed on the CPU backend, tests/core/subproc.py)."""
     import jax
 
+    from .obs import compile_events
+
+    compile_events.install()
     if os.environ.get("SCALING_TPU_TEST_CACHE", "").lower() == "off":
         jax.config.update("jax_enable_compilation_cache", False)
         return None

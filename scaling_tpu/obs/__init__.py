@@ -7,6 +7,9 @@
   the supervision events.
 - :mod:`.recorder` — the bounded ring every closed span lands in,
   exactly, capture or not: ``recorded_spans`` / ``record_span``.
+- :mod:`.compile_events` — JAX's trace / lower / compile / cache-load
+  events as rows of that ring and counters of the registry, by program
+  name: the account of a process's set-up.
 - :mod:`.capture` — the one start/stop control for all tracing of a
   running process: the profiler, the spans' annotations on its clock,
   and the recorder's rows between its two markers.
@@ -37,7 +40,13 @@ from .hardware import (
     mfu,
     update_hardware_gauges,
 )
-from .recorder import Row, record_span, recorded_spans, recorded_tail
+from .recorder import (
+    Row,
+    process_start_s,
+    record_span,
+    recorded_spans,
+    recorded_tail,
+)
 from .registry import (
     Counter,
     Gauge,
@@ -82,6 +91,7 @@ __all__ = [
     "last_capture",
     "mfu",
     "new_trace_id",
+    "process_start_s",
     "record_span",
     "recorded_spans",
     "recorded_tail",

@@ -34,6 +34,7 @@ No jax, no logger: ``obs/spans.py`` and ``obs/capture.py`` import this.
 from __future__ import annotations
 
 import collections
+import os
 import time
 from typing import Any, Dict, List, NamedTuple, Optional
 
@@ -41,6 +42,31 @@ RING_ROWS = 131_072
 CAPTURE_MARKER = "obs.capture"
 
 clock = time.monotonic  # THE clock of every row, in seconds (see above)
+_IMPORTED = clock()     # what ``process_start_s`` falls back to
+_process_start: Optional[float] = None
+
+
+def process_start_s() -> float:
+    """When this process started, in seconds on :data:`clock`: the kernel's
+    own stamp (``/proc/self/stat`` field 22, ticks of 10 ms since boot)
+    laid onto the monotonic clock through ``CLOCK_BOOTTIME``, so that the
+    interpreter's start and the imports before this module lie inside what
+    is counted from it. Where the stamp cannot be read, the moment this
+    module was imported. Read once a process, then kept."""
+    global _process_start
+    if _process_start is None:
+        try:
+            with open("/proc/self/stat", "rb") as f:
+                # the command's name may hold spaces and brackets: the
+                # fields are counted from the last ")", state (3) first
+                ticks = int(f.read().rsplit(b")", 1)[1].split()[19])
+            age = (time.clock_gettime(time.CLOCK_BOOTTIME)
+                   - ticks / os.sysconf("SC_CLK_TCK"))
+            start = clock() - age
+        except (OSError, ValueError, IndexError, AttributeError):
+            start = _IMPORTED
+        _process_start = min(start, _IMPORTED)
+    return _process_start
 
 
 class Row(NamedTuple):

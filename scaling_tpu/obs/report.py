@@ -490,6 +490,11 @@ def serving_section(data: RunData) -> Tuple[List[str], Dict[str, float]]:
             f"  engine: ticks={int(s.get('ticks', 0))} "
             f"preemptions={int(s.get('preemptions', 0))} "
             f"prefill_compiles={int(s.get('prefill_compiles', 0))}"
+            # closures built, against programs lowered after they all were
+            # (a recompile; the single engine's summary carries it)
+            + (f" programs_lowered_since_ready="
+               f"{int(s['programs_lowered_since_ready'])}"
+               if s.get("programs_lowered_since_ready") is not None else "")
         )
         # raw-speed rails (docs/SERVING.md "Raw speed"): shared-prefix
         # reuse and self-drafting speculation report their win here —

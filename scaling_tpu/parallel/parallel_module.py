@@ -39,6 +39,7 @@ from ..nn.base_layer import (
 from ..logging import logger
 from ..nn.param import ParamMeta, named_parameters, tree_with_layer
 from ..obs.registry import get_registry
+from ..obs.spans import span
 from ..topology import ActivationCheckpointingType, Topology
 from ..topology.topology import MODEL_AXIS, PIPE_AXIS
 
@@ -474,7 +475,16 @@ class ParallelModule:
         (grad_accumulation_steps, dp * micro_batch_size, ...) arrays.
         Output loss/metrics are means over micro batches (reference:
         parallel_module.py:288, optimizer.py:99-105).
+
+        ``train.build_step`` is the Python that assembles the step, once a
+        process; the step's first CALL traces, lowers and compiles it, which
+        is the ``compile.*`` rows named ``jit(step)`` (obs/compile_events.py).
         """
+        with span("train.build_step"):
+            return self._assemble_train_step(optimizer, loss_function, donate)
+
+    def _assemble_train_step(self, optimizer: Optimizer,
+                             loss_function: Callable, donate: bool) -> Callable:
         if self.forward_refusal is not None:
             raise NotImplementedError(self.forward_refusal)
         gas = self.topology.gradient_accumulation_steps if self.topology else 1

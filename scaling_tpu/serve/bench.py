@@ -287,6 +287,7 @@ def run_bench(engine, workload, time_scale: float = 1.0,
         "preemptions": engine.scheduler.preemption_count,
         "ticks": engine.tick_index - start_ticks,
         "prefill_compiles": engine.prefill_program_count,
+        "programs_lowered_since_ready": engine.programs_lowered_since_ready,
         "max_concurrent_prefills": engine.max_concurrent_prefills,
         # raw-speed rails (ISSUE 11): prefill work actually paid after
         # shared-prefix reuse, and the self-drafting accept rate

@@ -62,6 +62,7 @@ sampled rows, including mid-speculation.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import statistics
 import threading
 import time
@@ -69,6 +70,7 @@ from typing import Dict, List, NamedTuple, Optional, Tuple
 
 from .. import obs
 from ..logging import logger
+from ..obs import compile_events
 from ..nn.base_layer import state_views
 from ..nn.latent_paged_attention import latent_tile_tokens
 from ..nn.sparse_latent_attention import index_tile_tokens
@@ -316,9 +318,26 @@ class EngineConfig:
         )
 
 
+def _spanned_init(init):
+    """``serve.init`` around the engine's construction (pools, tables,
+    scheduler, layout): once an engine, and closed as a failed span where a
+    configuration is refused."""
+
+    @functools.wraps(init)
+    def spanned(self, *args, **kwargs):
+        with obs.span("serve.init") as init_span:
+            init(self, *args, **kwargs)
+            init_span.annotate(num_slots=self.config.num_slots,
+                               kv_lines=self.pools.kv_lines,
+                               pool_bytes=self.pools.device_bytes())
+
+    return spanned
+
+
 class ServeEngine:
     """Continuous-batching engine over a ``TransformerInferenceModule``."""
 
+    @_spanned_init
     def __init__(self, inference_module, config: Optional[EngineConfig] = None):
         import jax
 
@@ -479,6 +498,8 @@ class ServeEngine:
         # token width -> the fused mixed program built at it: every width
         # of config.mixed_widths, all lowered at the first tick
         self._mixed_fns: Dict[int, object] = {}
+        # jax_programs_lowered_total as ``serve.lower`` closed (None before)
+        self._lowered_at_ready: Optional[int] = None
         # token width -> ticks run at it (warm-up apart): how often the
         # small program serves
         self.mixed_ticks: Dict[int, int] = {}
@@ -969,17 +990,27 @@ class ServeEngine:
         engine's first tick, through the very call path later ticks take.
         A width first needed minutes into serving must not pay its
         lowering then."""
-        # twice: a program's second call takes ``prev`` from a program, as
-        # every later one does, and must find the first call's executable
-        for width in 2 * self.config.mixed_widths:
-            fn = self._mixed_fns.get(width)
-            if fn is None:
-                fn = self._mixed_fns[width] = self._build_mixed_fn(width)
-            empty, _ = self._layout.host(width)
-            _, self._prev, state = fn(
-                self.inf.params, self._pool_state(), self._dev(empty),
-                self._base_key, self._prev)
-            self._absorb(state)
+        # a span of its own, warm-up or not (the tick's spans are silenced
+        # there, _span): this is where an engine's set-up goes, and the
+        # ``compile.*`` rows of ``mixed_<width>`` fall inside it
+        with obs.span("serve.lower", widths=list(self.config.mixed_widths)):
+            # twice: a program's second call takes ``prev`` from a program,
+            # as every later one does, and must find the first call's
+            # executable
+            for width in 2 * self.config.mixed_widths:
+                fn = self._mixed_fns.get(width)
+                if fn is None:
+                    fn = self._mixed_fns[width] = self._build_mixed_fn(width)
+                empty, _ = self._layout.host(width)
+                _, self._prev, state = fn(
+                    self.inf.params, self._pool_state(), self._dev(empty),
+                    self._base_key, self._prev)
+                self._absorb(state)
+        # ready: every program a tick can run is lowered. What is lowered in
+        # this process from here on is a recompile (stats_snapshot)
+        self._lowered_at_ready = compile_events.programs_lowered()
+        self._gauge("serve_ready_seconds").set(
+            time.monotonic() - obs.process_start_s())
 
     # ------------------------------------------------------------- ticking
     def _reset_rows(self, slots: List[int]) -> None:
@@ -1756,10 +1787,27 @@ class ServeEngine:
 
     @property
     def prefill_program_count(self) -> int:
-        """Compiled mixed programs: one per token width of
-        ``config.mixed_widths`` from the first tick on, so at most 2 for
-        an engine's whole life."""
+        """Mixed programs BUILT (``prefill_compiles``): the jitted closures
+        this engine holds, one per token width of ``config.mixed_widths``
+        from the first tick on, so at most 2 for an engine's whole life. A
+        closure that is traced and lowered again does not move it:
+        ``programs_lowered_since_ready`` counts that."""
         return len(self._mixed_fns)
+
+    @property
+    def programs_lowered_since_ready(self) -> Optional[int]:
+        """Programs JAX has lowered in this PROCESS since this engine's
+        ``serve.lower`` closed (``jax_programs_lowered_total`` now minus its
+        value then): 0 in a healthy engine, whose every tick finds its
+        program; anything else is a recompile, an eager operation on the
+        tick's path or a jitted function met at a new shape, and the
+        ``compile.*`` rows name it. None before the first tick. The counter
+        is the process's: a second engine built in the same process shows
+        its set-up in the first's number, and a process that never passed
+        ``enable_compile_cache()`` counts nothing."""
+        if self._lowered_at_ready is None:
+            return None
+        return compile_events.programs_lowered() - self._lowered_at_ready
 
     def stats_snapshot(self) -> dict:
         """One JSON-safe dict of the engine's load + lifetime tallies —
@@ -1798,7 +1846,10 @@ class ServeEngine:
             "prefilled_tokens": self.prefilled_tokens,
             "spec_drafted_tokens": self.spec_drafted_tokens,
             "spec_accepted_tokens": self.spec_accepted_tokens,
+            # closures built, at most one a token width; and the programs
+            # lowered since they all were: the recompile alarm
             "prefill_compiles": self.prefill_program_count,
+            "programs_lowered_since_ready": self.programs_lowered_since_ready,
             "max_concurrent_prefills": self.max_concurrent_prefills,
             # by token width (JSON keys are strings): ticks run at it (the
             # real tokens they held: serve.mixed's `tokens` beside `width`)

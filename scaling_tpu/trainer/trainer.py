@@ -35,7 +35,7 @@ from ..config import BaseConfig
 from ..context import BaseContext
 from ..data import DataLoader
 from ..logging import logger
-from ..obs import StepTelemetry, span
+from ..obs import StepTelemetry, get_registry, process_start_s, span
 from ..optimizer.optimizer import Optimizer, OptimizerState
 from ..parallel.parallel_module import (
     EvaluationStepOutput,
@@ -946,6 +946,7 @@ class BaseTrainer:
         watchdog: Optional[StepStallWatchdog] = None,
     ) -> None:
         watchdog_armed = False
+        first_step_done = False
         while self.context.iterations < self.config.train_iterations:
             if watchdog is not None and watchdog_armed:
                 watchdog.beat(self.context.iterations)
@@ -963,6 +964,13 @@ class BaseTrainer:
                 self._preemption_exit()
                 return
             output = self.train_step()
+            if not first_step_done:
+                # the first step holds the step's trace, lowering and
+                # compile (the ``compile.*`` rows named ``jit(step)``): what
+                # a restart costs before it trains again
+                first_step_done = True
+                get_registry().gauge("train_first_step_seconds").set(
+                    time.monotonic() - process_start_s())
             if watchdog is not None and not watchdog_armed:
                 watchdog_armed = True
                 watchdog.start()  # steady-state steps from here on

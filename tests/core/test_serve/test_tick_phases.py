@@ -133,8 +133,12 @@ def test_warmup_ticks_emit_no_phase_span(toy_inference, events):
     e.warmup_mode = True
     e.submit([1, 2], 2)
     e.run_until_done()
-    assert not events.exists() or not [
-        r for r in read(events) if r.get("event") == "span"]
+    # the engine's set-up is spanned, warm-up or not (once an engine, never
+    # in a tick: the analyzer's tick attribution names its spans and does
+    # not read these); the tick's phases are silent
+    spans = [r["span"] for r in read(events)
+             if r.get("event") == "span"] if events.exists() else []
+    assert sorted(spans) == ["serve.init", "serve.lower"]
 
 
 def drive_single(engine, tmp_path):
