@@ -7,7 +7,8 @@ chip only, ``--smoke`` with ``JAX_PLATFORMS=cpu`` rehearses it at a toy size):
 
 For every shape (a cell's slots, heads, query block and table, or one that no
 cell serves: an int8 pool, Pharia's group of 9) and every number of lines a row
-holds (100 / 300 / 512 / 7,000 where a slot is that long), one call of
+holds (100 / 300 / 512 / 7,000 where a slot is that long; ``--held 620`` is what
+``qwen3next``'s rows hold in its cell), one call of
 ``paged_decode_attention`` over rows that all hold that many lines, their blocks
 scattered through the pool: ``decode`` rows of one token (the short path), and
 ``chunk`` rows that bring a whole query block (the full-width path). The call is
@@ -39,6 +40,7 @@ SHAPES = {
     "olmoe": (16, 16, 16, 128, 32, 256, "bf16", False),
     "ouro-looped": (16, 16, 16, 128, 32, 40, "bf16", False),
     "nemotron3nano": (64, 32, 2, 128, 32, 40, "bf16", False),
+    "qwen3next": (256, 16, 2, 256, 32, 128, "bf16", False),    # heads of 256 lanes
     "lfm2": (64, 32, 8, 64, 32, 40, "bf16", False),            # two heads a lane row
     "falconh1": (96, 20, 4, 128, 32, 40, "bf16", False),
     "keye-one-token": (8, 32, 4, 128, 1, 4096, "bf16", True),  # under its choice
@@ -51,6 +53,7 @@ HELD = (100, 300, 512, 7000)
 SMOKE_SHAPES = {
     "toy": (3, 4, 2, 16, 4, 40, "bf16", False),
     "toy-int8-masked": (3, 4, 2, 16, 1, 40, "int8", True),
+    "toy-head-major": (3, 4, 2, 256, 4, 40, "bf16", False),
 }
 
 
@@ -61,8 +64,8 @@ def operands(shape, held, kind, seed=0):
     import jax
     import jax.numpy as jnp
 
+    from scaling_tpu.nn import paged_attention
     from scaling_tpu.nn.attention import kv_quantize_int8
-    from scaling_tpu.nn.paged_attention import packed_kv_dims
 
     slots, n, n_kv, h, s, max_blocks, dtype, masked = shape
     keys = jax.random.split(jax.random.PRNGKey(seed), 4)
@@ -74,7 +77,12 @@ def operands(shape, held, kind, seed=0):
         (pools[0], sk), (pools[1], sv) = map(kv_quantize_int8, pools)
         scales = {"scale_k": sk, "scale_v": sv}
     else:   # as init_pools makes a native pool
-        pools = [p.reshape(*pool_dims[:2], *packed_kv_dims(n_kv, h)) for p in pools]
+        packed = paged_attention.packed_kv_dims(n_kv, h)
+        pools = [p.reshape(*pool_dims[:2], *packed) for p in pools]
+        # (a checkout from before PR 74 has token-major pools alone)
+        dims = getattr(paged_attention, "kv_pool_dims", None)
+        if dims and dims(BLOCK, n_kv, h, 2)[1] == 1:   # head-major blocks
+            pools = [p.transpose(0, 2, 1, 3) for p in pools]
     table = 1 + jax.random.permutation(keys[2], slots * blocks).reshape(slots, blocks)
     table = jnp.pad(table.astype(jnp.int32), ((0, 0), (0, max_blocks - blocks)))
     new = min(held, s) if kind == "chunk" else 1

@@ -33,6 +33,7 @@ from .linear import ColumnParallelLinear, RowParallelLinear, xavier_normal_init
 from .lora import LoRAModuleType, LoRaConfig, ParallelLoRa
 from .masked_softmax import MaskedSoftmax, MaskedSoftmaxConfig, MaskedSoftmaxKernel
 from .norm import LayerNormConfig, NormType, get_norm
+from .paged_attention import kv_block_layout
 from .param import tree_prefix
 from .rotary import (
     RelativePositionEmbeddingType,
@@ -110,7 +111,11 @@ class PagedKVCacheView(NamedTuple):
     never allocated to content, it absorbs writes from inactive rows and
     padding so the jitted decode step needs no per-row branching.
 
-    ``pool_k``/``pool_v`` are ``(num_blocks, block_size, n_kv, h)``;
+    ``pool_k``/``pool_v`` are ``(num_blocks, block_size, n_kv, h)``, or
+    HEAD-MAJOR ``(num_blocks, n_kv, block_size, h)`` where a head is wider
+    than the 128 lanes or alone
+    (``paged_attention.head_major_kv``; every reader asks
+    ``paged_attention.kv_block_layout`` which, none reshapes a pool itself);
     float (dense) or int8 with per-slot-per-head ``scale_k``/``scale_v``
     of shape ``(num_blocks, block_size, n_kv)`` (quantized KV). A latent
     attention layer's line has no head axis and two leaves of unequal width:
@@ -225,25 +230,31 @@ def paged_scatter_kv(view: PagedKVCacheView, flat: jax.Array,
     (``(n, index_head_dim)``): the third leaf of a sparse layer's line, its
     index keys, to the same slots of ``pool_i``. Returns the
     view with updated pools (tables/lengths untouched)."""
-    num_blocks, block_size = view.pool_k.shape[0], view.pool_k.shape[1]
-    flat_len = num_blocks * block_size
-    pk = view.pool_k.reshape(flat_len, *view.pool_k.shape[2:])
-    pv = view.pool_v.reshape(flat_len, *view.pool_v.shape[2:])
+    # the pools as the rows ONE scatter addresses: a token's line at its slot,
+    # or, of a pool that lies head-major, a row a head
+    # (paged_attention.head_major_kv)
+    k_dims, v_dims, at = view.pool_k.shape[2:], view.pool_v.shape[2:], flat
+    if view.pool_k.ndim == 4 and not view.quantized:
+        layout = kv_block_layout(view.pool_k, math.prod(k_rows.shape[1:]))
+        k_dims, at = layout.scatter_rows(flat, view.pool_k.shape[-1])
+        v_dims = k_dims
+    pk = view.pool_k.reshape(-1, *k_dims)
+    pv = view.pool_v.reshape(-1, *v_dims)
     scale_k, scale_v = view.scale_k, view.scale_v
     if view.quantized:
         qk, sk = kv_quantize_int8(k_rows)
         qv, sv = kv_quantize_int8(v_rows)
         pk = pk.at[flat].set(qk)
         pv = pv.at[flat].set(qv)
-        scale_k = view.scale_k.reshape(flat_len, -1)
-        scale_v = view.scale_v.reshape(flat_len, -1)
+        scale_k = view.scale_k.reshape(pk.shape[0], -1)
+        scale_v = view.scale_v.reshape(pv.shape[0], -1)
         scale_k = scale_k.at[flat].set(sk).reshape(view.scale_k.shape)
         scale_v = scale_v.at[flat].set(sv).reshape(view.scale_v.shape)
     else:
         # a pool of narrow heads keeps several a lane row
         # (paged_attention.packed_kv_dims): the same values, regrouped
-        pk = pk.at[flat].set(k_rows.reshape(-1, *pk.shape[1:]).astype(pk.dtype))
-        pv = pv.at[flat].set(v_rows.reshape(-1, *pv.shape[1:]).astype(pv.dtype))
+        pk = pk.at[at].set(k_rows.reshape(-1, *k_dims).astype(pk.dtype))
+        pv = pv.at[at].set(v_rows.reshape(-1, *v_dims).astype(pv.dtype))
     view = view._replace(
         pool_k=pk.reshape(view.pool_k.shape),
         pool_v=pv.reshape(view.pool_v.shape),
@@ -251,7 +262,7 @@ def paged_scatter_kv(view: PagedKVCacheView, flat: jax.Array,
     )
     if i_rows is None:
         return view
-    pi = view.pool_i.reshape(flat_len, -1)
+    pi = view.pool_i.reshape(-1, view.pool_i.shape[-1])
     pi = pi.at[flat].set(i_rows.astype(pi.dtype))
     return view._replace(pool_i=pi.reshape(view.pool_i.shape))
 
@@ -810,7 +821,8 @@ class ParallelSelfAttention(BaseLayer):
           unfused attention. Independent of the kernel, and pure extra
           HBM traffic on a chip.
         """
-        block_size = view.pool_k.shape[1]
+        block_size = kv_block_layout(
+            view.pool_k, k.shape[2] * k.shape[3]).block_size
         rows, max_blocks = view.block_table.shape
         window = max_blocks * block_size
         ctx_len = view.context_len.astype(jnp.int32)
@@ -883,9 +895,13 @@ class ParallelSelfAttention(BaseLayer):
             from jax.sharding import PartitionSpec as P
 
             heads = P(None, None, MODEL_AXIS, None)
+            # a head-major pool's head axis is dim 1, the sharded one still
+            pool = P(None, MODEL_AXIS, None, None) if kv_block_layout(
+                view.pool_k, q.shape[2] // self.num_repeat_kv * q.shape[3]
+            ).head_major else heads
             rep2, rep1 = P(None, None), P(None)
             quant = view.quantized
-            in_specs = [heads, heads, heads, rep2, rep1, rep1]
+            in_specs = [heads, pool, pool, rep2, rep1, rep1]
             if quant:
                 in_specs += [P(None, None, MODEL_AXIS)] * 2
 
@@ -910,8 +926,11 @@ class ParallelSelfAttention(BaseLayer):
         )
 
         # --- gather: each row's blocks as one contiguous KV window
-        gk = view.pool_k[view.block_table]  # (rows, max_blocks, bs, n_kv, h)
-        gv = view.pool_v[view.block_table]
+        layout = kv_block_layout(
+            view.pool_k, q.shape[2] // self.num_repeat_kv * q.shape[3])
+        # (rows, max_blocks, *a block's dims) -> (rows, window, n_kv, h)
+        gk = layout.lines(view.pool_k[view.block_table])
+        gv = layout.lines(view.pool_v[view.block_table])
         # (a pool of narrow heads keeps several a lane row: back to heads)
         gk = gk.reshape(rows, window, -1, q.shape[-1])
         gv = gv.reshape(rows, window, -1, q.shape[-1])

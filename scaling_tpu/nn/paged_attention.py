@@ -68,7 +68,14 @@ head QK^T is ``(s * group, h) @ (h, tile)`` and PV is ``(s * group, tile)
 float32 nor repeated across the group. One head's ``(tile, h)`` matrix is
 a sublane-strided read of the ``(tile, n_kv, h)`` buffer (``_heads``:
 32-bit words, so bf16 and int8 heads are unpacked from the words that pack
-them). The probabilities meet V in the queries' dtype (bf16 when serving;
+them). A pool whose heads are wider than the 128 lanes, or that has one KV
+head, lies HEAD-MAJOR, ``(num_blocks, n_kv, block_size, h)``
+(``head_major_kv``: there the word view is a relayout of the tile or no
+load Mosaic has; ISSUE 74): its VMEM tile is ``(n_kv, tile, h)``, a block's
+DMA lands in every head's rows, and a head's matrix is a plain dense read.
+Which layout a pool has the kernel reads off its shape
+(``kv_block_layout``); the pipeline, the waits and the fold are the same
+code for both. The probabilities meet V in the queries' dtype (bf16 when serving;
 ``l`` sums them in float32), as the splash kernel's do. Rows with at most
 ``_SHORT_QUERIES`` real positions — decode rows, decode rows with drafts —
 run the loop over their first folded rows only, over the sub-tiles that
@@ -122,7 +129,7 @@ from __future__ import annotations
 
 import functools
 import itertools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -177,16 +184,27 @@ def _round_up(x: int, m: int) -> int:
 
 
 def _blocks_per_tile(block_size: int, max_blocks: int, n_kv: int, h: int,
-                     itemsize: int) -> int:
+                     itemsize: int, head_major: bool = False) -> int:
     """Consecutive table entries one tile takes: a function of the shapes.
 
-    The VMEM tile of a pool keeps the pool's ``(tokens, n_kv, h)`` order,
-    whose ``(n_kv, h)`` minor dims pad to whole ``(8 * 4 / itemsize, 128)``
-    memory tiles; K and V, double-buffered, are four such tiles."""
-    sublanes = 8 * max(1, 4 // itemsize)
-    block_bytes = (
-        block_size * _round_up(n_kv, sublanes) * _round_up(h, 128) * itemsize
-    )
+    K and V, double-buffered, are four VMEM tiles. A head-major pool's
+    (``head_major_kv``) is ``(n_kv, tokens, h)``: whole memory tiles, its own
+    bytes. A token-major pool's keeps the pool's ``(tokens, n_kv, h)`` order
+    and is reckoned with its ``(n_kv, h)`` minor dims padded to whole ``(8 * 4
+    / itemsize, 128)`` memory tiles. That is an upper bound, not what Mosaic
+    does for few heads: it keeps a bf16 ``(.., 2, 256)`` buffer in ``(2, 128)``
+    memory tiles (a tile of 1,024 such tokens compiles inside the 16 MiB
+    default, which 16 sublanes a token would not: PERF.md, PR 74), so the
+    bound halved a 2 x 256 tile to 256 tokens for nothing; the shapes that
+    still take this branch (4 KV heads and more, int8) keep the tiles they
+    had."""
+    if head_major:
+        block_bytes = block_size * n_kv * _round_up(h, 128) * itemsize
+    else:
+        sublanes = 8 * max(1, 4 // itemsize)
+        block_bytes = (
+            block_size * _round_up(n_kv, sublanes) * _round_up(h, 128) * itemsize
+        )
     by_vmem = _TILE_VMEM_BYTES // (4 * block_bytes)
     return max(1, min(max_blocks, _TILE_TOKENS // block_size, by_vmem))
 
@@ -202,18 +220,19 @@ def _blocks_per_sub(block_size: int, tile_blocks: int) -> int:
 
 
 def kernel_tile_tokens(block_size: int, max_blocks: int, n_kv: int, h: int,
-                       itemsize: int) -> int:
+                       itemsize: int, head_major: bool = False) -> int:
     """KV tokens one tile of the kernel holds at these shapes."""
     return block_size * _blocks_per_tile(
-        block_size, max_blocks, n_kv, h, itemsize
+        block_size, max_blocks, n_kv, h, itemsize, head_major
     )
 
 
 def kernel_sub_tokens(block_size: int, max_blocks: int, n_kv: int, h: int,
-                      itemsize: int) -> int:
+                      itemsize: int, head_major: bool = False) -> int:
     """KV tokens one sub-tile of the kernel's tile holds at these shapes."""
     return block_size * _blocks_per_sub(
-        block_size, _blocks_per_tile(block_size, max_blocks, n_kv, h, itemsize)
+        block_size,
+        _blocks_per_tile(block_size, max_blocks, n_kv, h, itemsize, head_major),
     )
 
 
@@ -228,17 +247,23 @@ def _unpack_head(words, i: int, packing: int):
     return (words << (24 - 8 * i)) >> 24
 
 
-def _heads(tile_ref, width: int):
-    """``(g, matrix)`` for every KV head ``g`` of a ``(tile, n_kv, h)`` VMEM
-    tile, the ``(width, h)`` matrix of the head's first ``width`` tokens.
+def _heads(tile_ref, width: int, head_major: bool):
+    """``(g, matrix)`` for every KV head ``g`` of a VMEM tile, the ``(width,
+    h)`` matrix of the head's first ``width`` tokens.
 
-    Heads are the tile's second-minor dim, so one head's rows lie ``n_kv``
+    A head-major tile ``(n_kv, tile, h)`` holds each head's matrix dense: a
+    plain read. In a token-major ``(tile, n_kv, h)`` one heads are the tile's
+    second-minor dim, so one head's rows lie ``n_kv``
     apart: a sublane-strided load, which Mosaic has for 32-bit words only.
     Narrower pools pack 2 (bf16) or 4 (int8) consecutive heads of a token
     into a word, so the tile is read as words, a column of words at a time,
     and each word's heads are unpacked with shifts. A head count the packing
     does not divide (an int8 pool sharded down to 2 heads) reads head by
     head."""
+    if head_major:
+        for g in range(tile_ref.shape[0]):
+            yield g, tile_ref[g, pl.ds(0, width), :]
+        return
     tile, n_kv, h = tile_ref.shape
     packing = 4 // tile_ref.dtype.itemsize
     if n_kv % packing:
@@ -264,8 +289,8 @@ def _paged_attention_kernel(
     next_ref,     # (rows,) int32 next row that holds a visible slot, or -1
     # blocks
     q_ref,        # (1, n_kv, m, h) VMEM: queries folded per KV head
-    pool_k_ref,   # (num_blocks, block_size, n_kv, h) left in HBM
-    pool_v_ref,
+    pool_k_ref,   # (num_blocks, block_size, n_kv, h) left in HBM; a
+    pool_v_ref,   # head-major pool (num_blocks, n_kv, block_size, h)
     *rest,        # [scale_k_ref, scale_v_ref,] [chosen_ref,] o_ref, the scratch
     block_size: int,
     tile_blocks: int,
@@ -276,6 +301,7 @@ def _paged_attention_kernel(
     quantized: bool,
     masked: bool,
     single: bool,
+    head_major: bool,
 ):
     if quantized:
         # (1, n_kv, window) VMEM: the row's scales, one lane a slot
@@ -308,12 +334,19 @@ def _paged_attention_kernel(
             pl.cdiv(valid_ref[of_row] - t * tile, block_size), 0, tile_blocks
         )
 
+    def tokens_of(buf, slot, first, count):
+        """Tokens ``[first, first + count)`` of the tile in buffer ``slot``:
+        the tile's major dim, or every head's rows of a head-major one."""
+        if head_major:
+            return buf.at[slot, :, pl.ds(first, count)]
+        return buf.at[slot, pl.ds(first, count)]
+
     def block_copies(block, i, slot):
         """The DMAs of pool block ``block`` to place ``i`` of a tile."""
         return [
             pltpu.make_async_copy(
                 pool.at[block],
-                buf.at[slot, pl.ds(i * block_size, block_size)],
+                tokens_of(buf, slot, i * block_size, block_size),
                 sems.at[slot, which],
             )
             for which, (pool, buf) in enumerate(pools)
@@ -337,7 +370,7 @@ def _paged_attention_kernel(
         block of the part-held last one."""
         def at_once(whole: int):
             for which, (_, buf) in enumerate(pools):
-                part = buf.at[slot, pl.ds(0, whole * sub)]
+                part = tokens_of(buf, slot, 0, whole * sub)
                 pltpu.make_async_copy(part, part, sems.at[slot, which]).wait()
 
         def one(i, carry):
@@ -397,8 +430,8 @@ def _paged_attention_kernel(
                 span = pl.ds(pl.multiple_of(first, tile), width)
             if masked:
                 allowed = allowed & (chosen_ref[0, :, span] != 0)
-            keys = _heads(k_buf.at[slot], width)
-            values = _heads(v_buf.at[slot], width)
+            keys = _heads(k_buf.at[slot], width, head_major)
+            values = _heads(v_buf.at[slot], width, head_major)
             together = n_kv if by_sub else 1
             for g0 in range(0, n_kv, together):
                 scores = []
@@ -500,8 +533,8 @@ def _paged_attention_kernel(
 
 def paged_decode_attention(
     q: jax.Array,               # (rows, s, n, h) rotary-applied queries
-    pool_k: jax.Array,          # (num_blocks, block_size, n_kv, h)
-    pool_v: jax.Array,
+    pool_k: jax.Array,          # (num_blocks, block_size, n_kv, h), or
+    pool_v: jax.Array,          # head-major (num_blocks, n_kv, block_size, h)
     block_table: jax.Array,     # (rows, max_blocks) int32; 0 = trash
     valid_len: jax.Array,       # (rows,) int32 slots visible per row
     q_slot_base: jax.Array,     # (rows,) int32 slot of first query token
@@ -525,7 +558,9 @@ def paged_decode_attention(
     row whose ``valid_len`` is 0 does. Without it the kernel built is the
     maskless one, operand for operand."""
     _ensure_pallas()
-    _, block_size, n_kv, h = pool_k.shape
+    h = pool_k.shape[-1]
+    block_size, n_kv, head_major = kv_block_layout(
+        pool_k, q.shape[2] // num_repeat_kv * q.shape[3])
     if interpret is None:
         interpret = paged_kernel_interpret()
     pack = h // q.shape[-1]
@@ -550,9 +585,10 @@ def paged_decode_attention(
         scale_k, scale_v, chosen, sm_scale=float(sm_scale),
         group=num_repeat_kv,
         tile_blocks=_blocks_per_tile(
-            block_size, block_table.shape[1], n_kv, h, pool_k.dtype.itemsize
+            block_size, block_table.shape[1], n_kv, h, pool_k.dtype.itemsize,
+            head_major,
         ),
-        interpret=interpret,
+        head_major=head_major, interpret=interpret,
     )
 
 
@@ -575,6 +611,101 @@ def packed_kv_dims(n_kv: int, h: int):
     if pack == 1 or n_kv % pack:
         return n_kv, h
     return n_kv // pack, pack * h
+
+
+def head_major_kv(block_size: int, n_kv: int, h: int, itemsize: int) -> bool:
+    """Whether a native pool whose line is ``(n_kv, h)`` (after
+    ``packed_kv_dims``; a shard's heads) keeps its blocks HEAD-MAJOR, ``(n_kv,
+    block_size, h)``, and not token-major, ``(block_size, n_kv, h)``: where a
+    head is wider than the 128 lanes, or there is one KV head.
+
+    The kernel reads a head's matrix out of a token-major VMEM tile through a
+    view of it as 32-bit words, a row of 128 lanes each (``_heads``). At ``h =
+    128`` that view is the tile's own bytes. At 256 lanes a token's memory
+    tiles alternate between its lane halves, so the view is a relayout of the
+    whole tile: at 2 KV heads the fold alone took 1.30 ms of a 1.77 ms call
+    where the DMAs alone take 0.76 (Qwen3-Next's 256 rows of 620 lines;
+    PERF.md, PR 74), and at any other head count Mosaic has no such strided
+    load at all; a single head cannot be sliced out of a dim tiled by 2.
+    Head-major, a head's ``(tile, h)`` matrix is dense in the VMEM tile: a
+    plain read, the fold alone 0.42 ms (0.27 of it an empty body's), the call
+    0.84, and every such shape compiles. (A head-major block is also whole
+    ``(16, 128)`` memory tiles of 4 KiB where a token-major one of 2 heads is
+    512 B tiles; the DMAs' rate turned out NOT to follow that: the DMAs alone
+    take the same time from either.) Pools of 128-lane heads measured the same in
+    both layouts and keep the one, and the lowered text, they had; blocks or
+    heads that are no whole memory tiles stay token-major, whose tokens are a
+    major dim any DMA may slice. As ``packed_kv_dims``, the pool is MADE so
+    (serve/kvcache.py) and written so (``nn.attention.paged_scatter_kv``): a
+    view of the other layout inside a program is a copy of the whole pool."""
+    sublanes = 8 * max(1, 4 // itemsize)
+    whole_tiles = block_size % sublanes == 0 and h % _LANES == 0
+    return whole_tiles and (h > _LANES or n_kv == 1)
+
+
+def kv_pool_dims(block_size: int, n_kv: int, h: int, itemsize: int,
+                 shards: int = 1):
+    """A native pool's dims past its blocks, for a token's ``(n_kv, h)`` K (or
+    V) sharded ``shards`` ways over its heads, and the POOL's head axis:
+    ``packed_kv_dims``' line, token-major ``((block_size, n_kv, h), 2)`` or
+    head-major ``((n_kv, block_size, h), 1)`` as ``head_major_kv`` says of a
+    shard's heads."""
+    if shards == 1:
+        n_kv, h = packed_kv_dims(n_kv, h)
+    if head_major_kv(block_size, n_kv // shards, h, itemsize):
+        return (n_kv, block_size, h), 1
+    return (block_size, n_kv, h), 2
+
+
+class KVBlockLayout(NamedTuple):
+    """How a 4-d K (or V) pool's blocks lie, read off its shape."""
+
+    block_size: int
+    n_kv: int           # heads a line keeps (after packed_kv_dims)
+    head_major: bool    # (num_blocks, n_kv, block_size, h)
+
+    def lines(self, blocks: jax.Array) -> jax.Array:
+        """Gathered blocks ``(.., b, *block dims)`` as the tokens they hold,
+        in order: ``(.., b * block_size, n_kv, h)``."""
+        if self.head_major:
+            blocks = jnp.swapaxes(blocks, -3, -2)
+        lead = blocks.shape[:-4]
+        return blocks.reshape(*lead, -1, *blocks.shape[-2:])
+
+    def scatter_rows(self, flat: jax.Array, width: int):
+        """``(dims, index)`` for the pool's ONE row scatter: the pool seen as
+        rows of ``dims`` and the row each of a batch's values goes to, for
+        tokens at flat slots ``flat`` ``(n,)`` (block id x block_size +
+        offset) whose K (or V) is ``(n, n_kv, width)``. Token-major a token's
+        line is one row at its slot. Head-major it is ``n_kv`` rows of
+        ``width``, one in each head's rows of the block: still ONE scatter,
+        into the pool seen as rows of ``width`` (addressed as ``pool.at[block,
+        :, offset]`` XLA copies the whole pool a call to scatter into a
+        transposed one: 1.0 ms for a pool of 164 MB where this takes 0.12 and
+        the token-major scatter 0.06; XLA's row scatter is serial in its
+        updates: PERF.md, PR 74)."""
+        if not self.head_major:
+            return (self.n_kv, width), flat
+        block, offset = jnp.divmod(flat, self.block_size)
+        head = jnp.arange(self.n_kv, dtype=flat.dtype)
+        index = (block[:, None] * self.n_kv + head) * self.block_size + offset[:, None]
+        return (width,), index.reshape(-1)
+
+
+def kv_block_layout(pool: jax.Array, line_width: int) -> KVBlockLayout:
+    """The layout of a 4-d pool whose token's K (or V) is ``line_width`` values
+    (its KV heads x their width): the pool's shape says which it is, dim 1
+    holding the line's heads or dim 2. Where a block has as many tokens as a
+    line has heads the shape cannot say, and the pool is taken to lie as
+    ``init_pools`` would have made it (``head_major_kv``; an int8 pool is
+    never head-major)."""
+    n_kv = line_width // pool.shape[-1]
+    if pool.shape[1] != pool.shape[2]:
+        head_major = pool.shape[1] == n_kv
+    else:
+        head_major = pool.dtype != jnp.int8 and head_major_kv(
+            pool.shape[1], n_kv, pool.shape[-1], pool.dtype.itemsize)
+    return KVBlockLayout(pool.shape[2 if head_major else 1], n_kv, head_major)
 
 
 def _pack_queries(q: jax.Array, n_kv: int, pack: int) -> jax.Array:
@@ -615,16 +746,19 @@ def _pipeline_carry(valid_len: jax.Array, tile: int):
 # shapes, and tracing the body (two query paths, the DMA loops) is slow
 # Python — done once here, not once a layer, and lowered as one function
 @functools.partial(
-    jax.jit, static_argnames=("sm_scale", "group", "tile_blocks", "interpret")
+    jax.jit, static_argnames=("sm_scale", "group", "tile_blocks", "head_major",
+                              "interpret")
 )
 def _paged_call(
     q, pool_k, pool_v, block_table, valid_len, q_slot_base, scale_k, scale_v,
-    chosen, *, sm_scale: float, group: int, tile_blocks: int, interpret: bool,
+    chosen, *, sm_scale: float, group: int, tile_blocks: int,
+    head_major: bool, interpret: bool,
 ):
     rows, s, n, h = q.shape
-    _, block_size, n_kv, _ = pool_k.shape
+    n_kv = n // group
+    block_size = pool_k.shape[2 if head_major else 1]
     max_blocks = block_table.shape[1]
-    assert n == n_kv * group, (n, n_kv, group)
+    assert pool_k.shape[1 if head_major else 2] == n_kv, (pool_k.shape, n, group)
     quantized, masked = scale_k is not None, chosen is not None
     tile = tile_blocks * block_size
     sub_blocks = _blocks_per_sub(block_size, tile_blocks)
@@ -648,9 +782,10 @@ def _paged_call(
 
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [pl.BlockSpec((1, n_kv, m_full, h), _row), in_hbm, in_hbm]
+    tile_dims = (2, n_kv, tile, h) if head_major else (2, tile, n_kv, h)
     scratch = [
-        pltpu.VMEM((2, tile, n_kv, h), pool_k.dtype),
-        pltpu.VMEM((2, tile, n_kv, h), pool_v.dtype),
+        pltpu.VMEM(tile_dims, pool_k.dtype),
+        pltpu.VMEM(tile_dims, pool_v.dtype),
     ]
     operands = [folded, pool_k, pool_v]
     if quantized:
@@ -701,7 +836,7 @@ def _paged_call(
         block_size=block_size, tile_blocks=tile_blocks,
         sub_blocks=sub_blocks, sm_scale=sm_scale,
         group=group, m_short=m_short, quantized=quantized, masked=masked,
-        single=single,
+        single=single, head_major=head_major,
     )
     # what a row's blocks take in VMEM: queries and output (double-buffered),
     # the float32 accumulator, m and l (one value a row, a lane row each), the
@@ -709,7 +844,8 @@ def _paged_call(
     # had stay under the default and are built with the parameters they had.
     row = n_kv * m_full * h
     needed = (4 * row * q.dtype.itemsize + 4 * row + 2 * 4 * n_kv * m_full * 128
-              + 4 * tile * max(n_kv, 8 * max(1, 4 // pool_k.dtype.itemsize))
+              + 4 * tile * (n_kv if head_major else
+                            max(n_kv, 8 * max(1, 4 // pool_k.dtype.itemsize)))
               * h * pool_k.dtype.itemsize + 3 * 4 * m_full * tile)
     limit = {} if needed <= _VMEM_DEFAULT_BYTES else {
         "vmem_limit_bytes": min(_VMEM_CEILING_BYTES, needed + needed // 4)}

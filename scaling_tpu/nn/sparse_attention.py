@@ -84,7 +84,9 @@ from .base_layer import ForwardContext
 from .linear import ColumnParallelLinear
 from .masked_gqa_attention import KERNEL_NAME, masked_gqa_attention
 from .norm import NormType, get_norm
-from .paged_attention import paged_decode_attention, paged_kernel_interpret
+from .paged_attention import (
+    kv_block_layout, paged_decode_attention, paged_kernel_interpret,
+)
 from .param import tree_prefix
 from .rotary import RotaryConfig, RotaryEmbedding
 from .seq_packing import segment_ids_to_mask
@@ -257,7 +259,8 @@ class SparseSelfAttention(ParallelSelfAttention):
         with a head axis, the GQA group folded beside the positions."""
         tokens, n, h = q.shape
         n_kv, group = self.num_kv_heads, self.num_repeat_kv
-        block_size = view.pool_k.shape[1]
+        layout = kv_block_layout(view.pool_k, n_kv * h)
+        block_size = layout.block_size
         tile = index_tile_tokens(block_size, view.block_table.shape[1])
         tile_blocks = tile // block_size
 
@@ -276,8 +279,8 @@ class SparseSelfAttention(ParallelSelfAttention):
             # for the kernel's plain tiles
             blocks = table[:tiles * tile_blocks]
             return masked_gqa_attention(
-                q, view.pool_k[blocks].reshape(tiles * tile, n_kv, h),
-                view.pool_v[blocks].reshape(tiles * tile, n_kv, h), chosen,
+                q, layout.lines(view.pool_k[blocks]),
+                layout.lines(view.pool_v[blocks]), chosen,
                 seen, sm_scale=float(self.scaling_factor), interpret=interpret)
 
         return walk_rows(
@@ -295,9 +298,10 @@ class SparseSelfAttention(ParallelSelfAttention):
         ``_attend_rows``; the tests' reference of it."""
         tokens, n, h = q.shape
         n_kv, group = self.num_kv_heads, self.num_repeat_kv
-        window = view.block_table.shape[1] * view.pool_k.shape[1]
+        layout = kv_block_layout(view.pool_k, n_kv * h)
+        window = view.block_table.shape[1] * layout.block_size
 
-        def windows(pool, *tail):
+        def windows(pool, *tail):   # of a leaf without a head axis
             return pool[view.block_table].reshape(-1, window, *tail)[row]
 
         slots = jnp.arange(window, dtype=jnp.int32)[None, :]
@@ -306,7 +310,8 @@ class SparseSelfAttention(ParallelSelfAttention):
         scores = index_scores(
             q_i[:, None], windows(view.pool_i, self.index_dim), w[:, None])[:, 0]
         chosen = chosen_mask(scores, visible, self.index_topk)
-        keys, values = windows(view.pool_k, n_kv, h), windows(view.pool_v, n_kv, h)
+        keys = layout.lines(view.pool_k[view.block_table])[row]
+        values = layout.lines(view.pool_v[view.block_table])[row]
         s = jnp.einsum("tgjh,twgh->tgjw", q.reshape(tokens, n_kv, group, h), keys,
                        preferred_element_type=jnp.float32)
         s = jnp.where(chosen[:, None, None, :], s * self.scaling_factor, -jnp.inf)

@@ -200,8 +200,11 @@ def row_addresses(view, batch_shape):
     else:
         new_len = view.new_len.astype(jnp.int32)
     row, offset, real = view.token_rows((b, s))
+    # a leaf without a head axis says the block's size in its dim 1 (a 4-d K
+    # pool may lie head-major: paged_attention.kv_block_layout)
+    sized = view.pool_k if view.pool_i is None else view.pool_i
     flat = paged_flat_slots(
-        view.block_table, ctx_len[row] + offset, view.pool_k.shape[1], row)
+        view.block_table, ctx_len[row] + offset, sized.shape[1], row)
     flat = jnp.where(real, flat, 0).reshape(-1)
     if view.token_map is None:      # row-major: row r's tokens at r * s
         starts, width = jnp.arange(rows, dtype=jnp.int32) * s, s

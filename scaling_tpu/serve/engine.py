@@ -63,6 +63,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import statistics
 import threading
 import time
@@ -75,7 +76,9 @@ from ..nn.base_layer import state_views
 from ..nn.latent_paged_attention import latent_tile_tokens
 from ..nn.sparse_latent_attention import index_tile_tokens
 from ..nn.mamba import RecurrentStateView, split_capacity
-from ..nn.paged_attention import kernel_sub_tokens, kernel_tile_tokens
+from ..nn.paged_attention import (
+    kernel_sub_tokens, kernel_tile_tokens, kv_block_layout,
+)
 from ..resilience.faults import get_fault_plan
 from .kvcache import (
     PagedKVPools,
@@ -457,11 +460,13 @@ class ServeEngine:
             self._kv_tile = self._kv_sub = latent_tile_tokens(
                 self.config.block_size, self.config.max_blocks_per_seq)
         else:
-            _, _, n_kv, head = self.pools.pool_k[0].shape
+            pool = self.pools.pool_k[0]
+            _, n_kv, head_major = kv_block_layout(
+                pool, math.prod(pool.shape[1:]) // self.config.block_size)
             shapes = (
                 self.config.block_size, self.config.max_blocks_per_seq,
-                n_kv // self.model_parallel, head,
-                self.pools.pool_k[0].dtype.itemsize,
+                n_kv // self.model_parallel, pool.shape[3],
+                pool.dtype.itemsize, head_major,
             )
             self._kv_tile = kernel_tile_tokens(*shapes)
             self._kv_sub = kernel_sub_tokens(*shapes)

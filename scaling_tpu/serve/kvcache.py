@@ -400,7 +400,7 @@ def init_pools(inference_module, num_blocks: int, block_size: int,
     pool_i: List[jax.Array] = []
     scale_k: Optional[List[jax.Array]] = [] if kv_dtype == "int8" else None
     scale_v: Optional[List[jax.Array]] = [] if kv_dtype == "int8" else None
-    from ..nn.paged_attention import packed_kv_dims
+    from ..nn.paged_attention import kv_pool_dims
 
     for k_aval, v_aval, *third in kv_shapes:
         if third:
@@ -430,12 +430,16 @@ def init_pools(inference_module, num_blocks: int, block_size: int,
                     (pool_blocks, block_size, aval.shape[2]), aval.dtype, 2))
             continue
         n_kv, h = k_aval.shape[2], k_aval.shape[3]
-        if kv_dtype == "native" and mesh is None:
-            # heads narrower than the 128 lanes lie several a lane row
-            n_kv, h = packed_kv_dims(n_kv, h)
         store = jnp.int8 if kv_dtype == "int8" else k_aval.dtype
-        pool_k.append(placed((pool_blocks, block_size, n_kv, h), store, 2))
-        pool_v.append(placed((pool_blocks, block_size, n_kv, h), store, 2))
+        dims, head_dim = (block_size, n_kv, h), 2
+        if kv_dtype == "native":
+            # heads narrower than the 128 lanes lie several a lane row; blocks
+            # of heads wider than the lanes, or of one head, lie head-major
+            dims, head_dim = kv_pool_dims(
+                block_size, n_kv, h, jnp.dtype(store).itemsize,
+                1 if mesh is None else mp)
+        pool_k.append(placed((pool_blocks, *dims), store, head_dim))
+        pool_v.append(placed((pool_blocks, *dims), store, head_dim))
         if kv_dtype == "int8":
             scale_k.append(
                 placed((pool_blocks, block_size, n_kv), jnp.float32, 2)

@@ -129,7 +129,16 @@ def test_paged_kernel_compiles_at_group_16_over_2_kv_heads(one_chip, s):
     reads that word's column (``_heads``), which Mosaic takes. An
     int8 pool of 2 heads it refuses (a slice of 2 along a dimension tiled by
     4): the cell serves the native dtype, and `kv_dtype='int8'` at 2 KV
-    heads is an open item (PERF.md section 7)."""
+    heads is an open item (PERF.md section 7). Heads of 128 lanes: the pool
+    stays token-major (PR 74 measured it the same head-major) with the tile
+    it had, 512 tokens in four sub-tiles."""
+    from scaling_tpu.nn.paged_attention import (
+        kernel_sub_tokens, kernel_tile_tokens, kv_pool_dims,
+    )
+
+    assert kv_pool_dims(BLOCK_SIZE, 2, HEAD_DIM, 2) == ((BLOCK_SIZE, 2, HEAD_DIM), 2)
+    assert kernel_tile_tokens(BLOCK_SIZE, 40, 2, HEAD_DIM, 2) == 512
+    assert kernel_sub_tokens(BLOCK_SIZE, 40, 2, HEAD_DIM, 2) == 128
     compile_paged_kernel(one_chip, 64, 32, 2, 40, s, "native")
 
 
@@ -182,15 +191,34 @@ def test_paged_kernel_compiles_at_group_6_over_8_kv_heads(one_chip):
 def test_paged_kernel_compiles_at_heads_of_256(one_chip, s):
     """``serve-qwen3next-80b-extract-burst``'s attention: 16 query heads over 2
     KV heads x 256 lanes, 256 slots of 128 blocks. A head of two lane tiles: the
-    pool line is ``(2, 256)`` as the probe gives it (nothing to pack), a tile
-    is 256 tokens (the VMEM budget of K and V, double-buffered, at 16 KiB a
-    block) and two sub-tiles of 128; Mosaic takes it unchanged."""
-    from scaling_tpu.nn.paged_attention import kernel_sub_tokens, kernel_tile_tokens
+    pool line is ``(2, 256)`` as the probe gives it (nothing to pack), and since
+    PR 74 its blocks lie HEAD-MAJOR (``head_major_kv``: a head's matrix is dense
+    in the VMEM tile, where the token-major tile's word view was a relayout of
+    the whole tile), reckoned at their own bytes: a tile is 512 tokens (256
+    when the token-major bound counted 16 sublanes a token) and four sub-tiles
+    of 128, as every other cell's."""
+    from scaling_tpu.nn.paged_attention import (
+        kernel_sub_tokens, kernel_tile_tokens, kv_pool_dims,
+    )
 
     assert packed_kv_dims(2, 256) == (2, 256)
-    assert kernel_tile_tokens(BLOCK_SIZE, 128, 2, 256, 2) == 256
-    assert kernel_sub_tokens(BLOCK_SIZE, 128, 2, 256, 2) == 128
+    assert kv_pool_dims(BLOCK_SIZE, 2, 256, 2) == ((2, BLOCK_SIZE, 256), 1)
+    assert kernel_tile_tokens(BLOCK_SIZE, 128, 2, 256, 2, head_major=True) == 512
+    assert kernel_sub_tokens(BLOCK_SIZE, 128, 2, 256, 2, head_major=True) == 128
     compile_paged_kernel(one_chip, 256, 16, 2, 128, s, "native", head_dim=256)
+
+
+@pytest.mark.parametrize("kv_heads,head_dim", [(1, 128), (8, 256)],
+                         ids=["one-kv-head", "eight-heads-of-256"])
+def test_paged_kernel_compiles_head_major_where_token_major_cannot(
+        one_chip, kv_heads, head_dim):
+    """Lines no cell serves, which Mosaic refused token-major on the chip (my
+    chip run, PR 74): one KV head is "a slice of 1 along a dimension tiled by
+    2", and heads of 256 lanes at any count but 2 need a strided load that
+    exists for rows of 128 lanes only. Head-major a head's matrix is a plain
+    read, whatever the count and the width."""
+    compile_paged_kernel(one_chip, 32, 32, kv_heads, 40, 32, "native",
+                         head_dim=head_dim)
 
 
 def kernel_operands(lowered) -> int:
@@ -209,7 +237,10 @@ def compile_paged_kernel(one_chip, slots, q_heads, kv_heads, max_blocks, s,
     quantized = kv_dtype == "int8"
     pool_dims = (slots * max_blocks + 1, BLOCK_SIZE, kv_heads, HEAD_DIM)
     if not quantized:   # as init_pools makes a native pool
-        pool_dims = pool_dims[:2] + packed_kv_dims(kv_heads, HEAD_DIM)
+        from scaling_tpu.nn.paged_attention import kv_pool_dims
+
+        pool_dims = pool_dims[:1] + kv_pool_dims(
+            BLOCK_SIZE, kv_heads, HEAD_DIM, 2)[0]
     pool = shape(pool_dims, jnp.int8 if quantized else jnp.bfloat16)
     scales = (
         {"scale_k": shape(pool_dims[:3], jnp.float32),

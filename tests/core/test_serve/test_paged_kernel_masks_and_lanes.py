@@ -4,7 +4,9 @@ optional mask operand (a row's choice of slots: the sparse grouped-query
 mixer's one-token rows); its cases hold the masked call to the dense reference
 under the mask and the maskless call to the operands it had. A group of 16
 over 2 KV heads against the gather formulation; heads narrower than the lanes
-sharing a lane row, and the pool of narrow heads made and written packed."""
+sharing a lane row, and the pool of narrow heads made and written packed.
+ISSUE 74: pools of heads wider than the lanes (or of one head) laid head-major:
+the kernel against the float32 reference, the writer, the rule."""
 
 import numpy as np
 import pytest
@@ -221,3 +223,177 @@ def test_a_pool_of_narrow_heads_is_made_and_written_packed():
             np.testing.assert_allclose(outs[name][0][row, :n],
                                        outs["plain-xla"][0][row, :n], atol=2e-5)
         np.testing.assert_array_equal(outs[name][1], outs["plain-xla"][1])
+
+
+# ---- ISSUE 74: a pool of few KV heads lies HEAD-MAJOR, (num_blocks, n_kv,
+# block_size, h): a block is whole memory tiles and a head's matrix in the
+# kernel's VMEM tile is dense. The kernel reads which layout a pool has off its
+# shape (``kv_block_layout``); the cases hold it to the float32 reference at 2
+# KV heads of 128 and of 256 lanes.
+
+def head_major(pool):
+    return jnp.swapaxes(pool, 1, 2)
+
+
+HEAD_MAJOR_ROWS = {
+    # what the rows hold, of tiles of 512 tokens (40 blocks of 16 a row): none,
+    # one line, a part of a sub-tile, whole sub-tiles, a whole tile, a tile and
+    # a line, a tile and a part
+    "decode": (1, [0, 1, 100, 256, 512, 513, 640], "one"),
+    "chunk": (16, [0, 16, 100, 512, 513, 640], "all"),
+    # a decode row, a row with drafts, chunk rows, an inactive row in one call
+    "mixed": (16, [300, 5, 640, 16, 0, 513], [1, 5, 16, 13, 0, 16]),
+}
+
+
+@pytest.mark.parametrize("mask", ["maskless", "masked"])
+@pytest.mark.parametrize("rows", list(HEAD_MAJOR_ROWS))
+@pytest.mark.parametrize("h", [128, 256])
+def test_a_head_major_pool_of_two_kv_heads_matches_the_float32_reference(
+    h, rows, mask
+):
+    """bf16 K and V, float32 scores and softmax state: the kernel over a
+    head-major pool against the dense window over the same values in float32,
+    for decode rows, chunk rows and a mixed call, rows of 0 / 1 / part-held /
+    whole tiles, with and without a mask; and bit for bit what it gives over
+    the token-major pool of the same values where both take the same tiles."""
+    s, valid, new = HEAD_MAJOR_ROWS[rows]
+    valid = np.asarray(valid, np.int32)
+    new = {"one": 1, "all": s}[new] if isinstance(new, str) else new
+    new = np.minimum(valid, new).astype(np.int32)
+    group = 8
+    rng = np.random.default_rng(74)
+    q, pk, pv, tab, ctx, new = tiled_case(
+        rng, block_size=16, max_blocks=40, n_kv=2, group=group, s=s,
+        ctx=valid - new, new_len=new, dtype=jnp.bfloat16, h=h)
+    kwargs, chosen = {}, None
+    if mask == "masked":
+        chosen = rng.random((len(valid), 640)) < 0.5
+        chosen |= np.arange(640) >= np.asarray(ctx)[:, None]
+        chosen = kwargs["chosen"] = jnp.asarray(chosen)
+    assert paged_attention.kv_block_layout(head_major(pk), 2 * h) == (16, 2, True)
+    assert paged_attention.kv_block_layout(pk, 2 * h) == (16, 2, False)
+    out = check_rows(q, head_major(pk), head_major(pv), tab, ctx, new, group,
+                     **kwargs)
+    f32 = [a.astype(jnp.float32) for a in (q, pk, pv)]
+    ref = dense_reference(*f32, tab, ctx + new, ctx, group, chosen)
+    assert_real_positions(out, ref, new, 2e-2)
+    assert not bool(jnp.any(out[np.asarray(valid) == 0]))
+    if h == 128:    # the token-major tile is 512 tokens too: the same folds
+        same = check_rows(q, pk, pv, tab, ctx, new, group, **kwargs)
+        np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                      np.asarray(same, np.float32))
+
+
+@pytest.mark.parametrize("n_kv,h", [(1, 128), (4, 256)],
+                         ids=["one-kv-head", "four-heads-of-256"])
+def test_lines_mosaic_cannot_read_token_major_read_head_major(n_kv, h):
+    """One KV head, and heads of 256 lanes at a count other than 2: the lines
+    no cell serves that ``head_major_kv`` also lays head-major (token-major the
+    chip's compiler refuses them). A decode row, a row with drafts, a chunk row
+    and an empty one against the float32 reference."""
+    rng = np.random.default_rng(n_kv + h)
+    valid, new = np.asarray([300, 5, 530, 0], np.int32), [1, 5, 16, 0]
+    new = np.minimum(valid, new).astype(np.int32)
+    q, pk, pv, tab, ctx, new = tiled_case(
+        rng, block_size=16, max_blocks=40, n_kv=n_kv, group=4, s=16,
+        ctx=valid - new, new_len=new, dtype=jnp.bfloat16, h=h)
+    assert paged_attention.kv_pool_dims(16, n_kv, h, 2) == ((n_kv, 16, h), 1)
+    out = check_rows(q, head_major(pk), head_major(pv), tab, ctx, new, 4)
+    f32 = [a.astype(jnp.float32) for a in (q, pk, pv)]
+    ref = dense_reference(*f32, tab, ctx + new, ctx, 4)
+    assert_real_positions(out, ref, new, 2e-2)
+    assert not bool(jnp.any(out[3]))
+
+
+def test_a_pool_of_wide_heads_is_written_and_read_head_major():
+    """The ONE writer gives a token's K and V to a head-major pool head by
+    head, and both formulations of the paged branch read it back
+    (``ParallelSelfAttention._paged_attention``): the pool after the write is
+    the token-major pool after the same write bit for bit, and so is the XLA
+    fallback's output; the kernel's agrees with it."""
+    from scaling_tpu.nn.attention import PagedKVCacheView, ParallelSelfAttention
+    from scaling_tpu.nn.base_layer import ForwardContext
+    from scaling_tpu.nn.norm import NormType
+
+    hidden, heads, kv_heads, head_dim = 96, 4, 2, 256
+    attn = ParallelSelfAttention(
+        hidden, heads, num_kv_heads=kv_heads, head_dim=head_dim, qkv_in_one=False,
+        bias=False, key_query_norm=True, norm_type=NormType.RMS,
+        relative_position_embedding_type="none")
+    params = attn.init(jax.random.PRNGKey(0))
+    rows, s, block, max_blocks = 3, 8, 8, 4
+    assert paged_attention.kv_pool_dims(block, kv_heads, head_dim, 4) == (
+        (kv_heads, block, head_dim), 1)
+    x = jax.random.normal(jax.random.PRNGKey(1), (rows, s, hidden))
+    pool = jax.random.normal(
+        jax.random.PRNGKey(2), (rows * max_blocks + 1, block, kv_heads, head_dim))
+    table = 1 + jnp.arange(rows * max_blocks, dtype=jnp.int32).reshape(rows, -1)
+    lens = dict(context_len=jnp.asarray([19, 4, 0], jnp.int32),
+                new_len=jnp.asarray([1, 8, 0], jnp.int32))
+    plain = PagedKVCacheView(pool_k=pool, pool_v=pool[::-1], block_table=table, **lens)
+    major = plain._replace(pool_k=head_major(pool), pool_v=head_major(pool[::-1]))
+    outs = {}
+    for name, view, kernel in (("plain-xla", plain, "xla"), ("major-xla", major, "xla"),
+                               ("major-pallas", major, "pallas")):
+        out, new_view = attn(params, x, ForwardContext(paged_kernel=kernel), kv_cache=view)
+        assert new_view.pool_k.shape == view.pool_k.shape
+        written = new_view.pool_k if view is plain else head_major(new_view.pool_k)
+        outs[name] = (np.asarray(out), np.asarray(written))
+    assert (outs["plain-xla"][1] != np.asarray(pool)).any()     # something was written
+    for name in ("major-xla", "major-pallas"):
+        np.testing.assert_array_equal(outs[name][1], outs["plain-xla"][1])
+    for row, n in ((0, 1), (1, 8)):
+        np.testing.assert_array_equal(outs["major-xla"][0][row, :n],
+                                      outs["plain-xla"][0][row, :n])
+        np.testing.assert_allclose(outs["major-pallas"][0][row, :n],
+                                   outs["plain-xla"][0][row, :n], atol=2e-5)
+        assert np.abs(outs["plain-xla"][0][row, :n]).max() > 0.1
+
+
+# every serve cell's line: KV heads and head width as its config gives them,
+# the dims ``init_pools`` makes a block of 16 bf16 tokens in, head-major or not
+CELL_LINES = {
+    "mistral-7b": ((8, 128), (16, 8, 128), False),
+    "olmoe-1b-7b": ((16, 128), (16, 16, 128), False),
+    "ouro-2.6b": ((16, 128), (16, 16, 128), False),
+    "nemotron3-nano": ((2, 128), (16, 2, 128), False),
+    "lfm2-24b (two heads a lane row)": ((8, 64), (16, 4, 128), False),
+    "falcon-h1-34b": ((4, 128), (16, 4, 128), False),
+    "keye-vl-2.0": ((4, 128), (16, 4, 128), False),
+    "laguna-s-2.1": ((8, 128), (16, 8, 128), False),
+    "qwen3-next-80b": ((2, 256), (2, 16, 256), True),
+    # served by no cell: what Mosaic cannot read token-major at all
+    "one KV head": ((1, 128), (1, 16, 128), True),
+    "eight heads of 256 lanes": ((8, 256), (8, 16, 256), True),
+    "sixteen heads of 256 lanes": ((16, 256), (16, 16, 256), True),
+}
+
+
+@pytest.mark.parametrize("cell", list(CELL_LINES))
+def test_the_layout_is_a_function_of_the_line(cell):
+    """Head-major where a head is wider than the lanes or alone; every pool of
+    128-lane heads keeps the dims it had. The pool's shape then says which
+    layout it is to every reader, also where heads and tokens are as many."""
+    (n_kv, h), dims, major = CELL_LINES[cell]
+    head_axis = 1 if major else 2
+    assert paged_attention.kv_pool_dims(16, n_kv, h, 2) == (dims, head_axis)
+    pool = jnp.zeros((3, *dims), jnp.bfloat16)
+    layout = paged_attention.kv_block_layout(pool, n_kv * h)
+    assert layout == (16, dims[head_axis - 1], major)
+    # blocks or heads of no whole memory tiles stay token-major; so does a
+    # shard of several heads; an int8 pool is never head-major
+    assert paged_attention.kv_pool_dims(4, n_kv, h, 2)[1] == 2
+    assert not paged_attention.head_major_kv(16, n_kv, 48, 2)
+    assert not paged_attention.kv_block_layout(
+        jnp.zeros((3, 16, 16, 256), jnp.int8), 16 * 256).head_major
+
+
+def test_a_shards_heads_decide_the_layout_of_a_sharded_pool():
+    """Under a model axis the pool keeps its GLOBAL heads, unpacked, and lies
+    as a shard's count says: two 128-lane heads over two shards are one a
+    shard."""
+    assert paged_attention.kv_pool_dims(16, 2, 128, 2, shards=2) == ((2, 16, 128), 1)
+    assert paged_attention.kv_pool_dims(16, 4, 128, 2, shards=2) == ((16, 4, 128), 2)
+    assert paged_attention.kv_pool_dims(16, 8, 64, 2, shards=2) == ((16, 8, 64), 2)
+    assert paged_attention.kv_pool_dims(16, 4, 256, 2, shards=2) == ((4, 16, 256), 1)
