@@ -73,11 +73,17 @@ class LayerKind(Enum):
     # is NOT diagonal (the state is read, corrected by what it holds for the
     # key, written); served, it keeps a float32 state and a conv tail a slot
     DELTA = "delta"
+    # latent attention over a sliding window, with sizes (ranks, heads, head
+    # widths, rotary base) of its own beside the 'latent' layers'
+    # (nn/window_latent_attention.py): served, it keeps a RING of latent lines
+    # a slot, not pages
+    WINDOW_LATENT = "window_latent"
 
 
 class AttentionGate(Enum):
     """The gate on the output of a ``layer_pattern`` stack's softmax attention
-    layers ('attention' and 'window'): none, or ``per_head``: ``g =
+    layers ('attention', 'window', 'latent' and 'window_latent'): none, or
+    ``per_head``: ``g =
     sigmoid(x W_g)``, one value a query head from the layer's normed input,
     on the head's output before the output projection (the head-wise gate of
     arXiv:2505.06708); or ``elementwise``: a value a LANE of every head's
@@ -252,6 +258,9 @@ LATENT_FIELDS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
 # what makes the 'latent' layers (nn/sparse_latent_attention.py) or, in a
 # stack without them, the 'attention' layers (nn/sparse_attention.py) SPARSE
 INDEX_FIELDS = ("index_n_heads", "index_head_dim", "index_topk")
+# what sizes a 'window_latent' layer: window_latent_<name>
+WINDOW_LATENT_FIELDS = tuple(
+    f"window_latent_{name}" for name in ("num_attention_heads",) + LATENT_FIELDS)
 # what sizes a 'delta' layer (nn/gated_delta.py)
 DELTA_FIELDS = ("delta_num_key_heads", "delta_num_value_heads",
                 "delta_key_head_dim", "delta_value_head_dim")
@@ -395,9 +404,9 @@ class TransformerArchitectureConfig(BaseConfig):
         "and by its 'attention' layers, on the rotary_percentage of a head "
         "that is rotated; never by its 'window' layers")
     window_size: Optional[int] = Field(
-        None, description="a 'window' layer: lines a query sees, itself "
-        "included (query t attends over keys s with t - window_size < s <= t: "
-        "the published sliding_window)", gt=0)
+        None, description="a 'window' or 'window_latent' layer: lines a query "
+        "sees, itself included (query t attends over keys s with t - "
+        "window_size < s <= t: the published sliding_window)", gt=0)
     window_num_attention_heads: Optional[int] = Field(
         None, description="a 'window' layer's query heads, over the same "
         "attention_num_kv_heads and attention_head_dim as the 'attention' "
@@ -408,9 +417,33 @@ class TransformerArchitectureConfig(BaseConfig):
     window_rotary_percentage: float = Field(
         1.0, description="fraction of a 'window' layer's head that is rotated",
         gt=0.0, le=1.0)
+    window_latent_num_attention_heads: Optional[int] = Field(
+        None, description="a 'window_latent' layer's heads", gt=0)
+    window_latent_q_lora_rank: Optional[int] = Field(
+        None, description="a 'window_latent' layer's query latent", gt=0)
+    window_latent_kv_lora_rank: Optional[int] = Field(
+        None, description="a 'window_latent' layer's KV latent c_kv: what a "
+        "token leaves in the layer's ring beside its ONE rotary key", gt=0)
+    window_latent_qk_nope_head_dim: Optional[int] = Field(
+        None, description="a 'window_latent' head's query/key part without "
+        "position", gt=0)
+    window_latent_qk_rope_head_dim: Optional[int] = Field(
+        None, description="a 'window_latent' head's rotary query part and the "
+        "layer's ONE rotary key", gt=0)
+    window_latent_v_head_dim: Optional[int] = Field(
+        None, description="a 'window_latent' head's value size", gt=0)
+    window_latent_rotary_embedding_base: int = Field(
+        10000, description="a 'window_latent' layer's rotary base theta; its "
+        "frequencies are the base's, unscaled")
+    latent_lora_rescale: bool = Field(
+        False, description="a 'latent' or 'window_latent' layer multiplies "
+        "what comes out of its two latent norms by (hidden_size / rank) ** "
+        "0.5, the query latent by its q_lora_rank's and the KV latent by its "
+        "kv_lora_rank's; the rotary key is not scaled")
     attention_gate: AttentionGate = Field(
         AttentionGate.NONE, description="a gate on the output of a "
-        "layer_pattern's 'attention' and 'window' layers (see AttentionGate)")
+        "layer_pattern's 'attention', 'window', 'latent' and 'window_latent' "
+        "layers (see AttentionGate)")
     index_n_heads: Optional[int] = Field(
         None, description="a SPARSE attention layer: a layer_pattern's "
         "'latent' layers (nn/sparse_latent_attention.py) or, in a pattern "
@@ -883,20 +916,37 @@ class TransformerArchitectureConfig(BaseConfig):
                 "that kind alone")
         if (self.attention_gate == AttentionGate.ELEMENTWISE
                 and (LayerKind.WINDOW in self.layer_pattern
+                     or LayerKind.LATENT in self.layer_pattern
+                     or LayerKind.WINDOW_LATENT in self.layer_pattern
                      or self.index_topk is not None
                      or (self.attention_qkv_in_one
                          and self.attention_num_kv_heads is None))):
             raise ValueError(
-                "attention_gate 'elementwise' with 'window' layers, with "
-                "index_* or with attention_qkv_in_one: the gate a lane comes "
-                "out of a doubled query projection of a plain 'attention' "
-                "layer; a window ring or a sparse choice under it is not built")
+                "attention_gate 'elementwise' with 'window', 'latent' or "
+                "'window_latent' layers, with index_* or with "
+                "attention_qkv_in_one: the gate a lane comes out of a doubled "
+                "query projection of a plain 'attention' layer; a window "
+                "ring, a latent head or a sparse choice under it is not built")
         if LayerKind.WINDOW in self.layer_pattern:
             self._validate_window()
-        elif self.window_size is not None or self.window_num_attention_heads:
+        elif self.window_num_attention_heads or (
+                self.window_size is not None
+                and LayerKind.WINDOW_LATENT not in self.layer_pattern):
             raise ValueError(
                 "window_size / window_num_attention_heads without 'window' "
                 "layers in layer_pattern: they size that kind alone")
+        given = [n for n in WINDOW_LATENT_FIELDS if getattr(self, n) is not None]
+        if LayerKind.WINDOW_LATENT in self.layer_pattern:
+            self._validate_window_latent(given)
+        elif given:
+            raise ValueError(
+                f"{given} without 'window_latent' layers in layer_pattern: "
+                "they size that kind alone")
+        if self.latent_lora_rescale and not (
+                self.latent_layers or self.window_latent_layers):
+            raise ValueError(
+                "latent_lora_rescale without 'latent' or 'window_latent' "
+                "layers: it scales what their two latent norms give")
         if self.index_topk is not None and LayerKind.ATTENTION in self.layer_pattern:
             # a sparse grouped-query layer (nn/sparse_attention.py): what it
             # does not build, each by name
@@ -950,7 +1000,7 @@ class TransformerArchitectureConfig(BaseConfig):
                 f"delta_num_value_heads {self.delta_num_value_heads} is not "
                 f"a multiple of delta_num_key_heads {self.delta_num_key_heads}")
         for kind in (LayerKind.MAMBA, LayerKind.CONV, LayerKind.WINDOW,
-                     LayerKind.LATENT):
+                     LayerKind.LATENT, LayerKind.WINDOW_LATENT):
             if kind in self.layer_pattern:
                 raise ValueError(
                     f"'delta' layers beside '{kind.value}' layers: two kinds "
@@ -1004,6 +1054,42 @@ class TransformerArchitectureConfig(BaseConfig):
                 "reference yet (the mixer would build it: "
                 "nn/window_attention.py); set it false")
 
+    def _validate_window_latent(self, given):
+        """What a 'window_latent' layer does not build, each by name."""
+        missing = [n for n in WINDOW_LATENT_FIELDS if n not in given]
+        if missing or self.window_size is None:
+            raise ValueError(
+                "layer_pattern with 'window_latent' layers needs "
+                f"{missing or ['window_size']}: a windowed latent attention "
+                "layer is sized by window_size and by its own "
+                f"{list(WINDOW_LATENT_FIELDS)}")
+        if self.window_latent_qk_rope_head_dim % 2:
+            raise ValueError(
+                "window_latent_qk_rope_head_dim "
+                f"{self.window_latent_qk_rope_head_dim} is odd: rotary turns "
+                "pairs of lanes")
+        if (self.relative_position_embedding_type
+                != RelativePositionEmbeddingType.ROTARY):
+            raise ValueError(
+                "layer_pattern with 'window_latent' layers and "
+                "relative_position_embedding_type "
+                f"{self.relative_position_embedding_type.value!r}: a latent "
+                "head's position is its rotary slice; use 'rotary'")
+        if not self.causal:
+            raise ValueError(
+                "layer_pattern with 'window_latent' layers and causal false: "
+                "the window reaches back from a query, never ahead")
+        for kind in (LayerKind.WINDOW, LayerKind.MAMBA, LayerKind.CONV):
+            if kind in self.layer_pattern:
+                raise ValueError(
+                    f"'window_latent' layers beside '{kind.value}' layers: "
+                    "two kinds of line a slot in one stack beside a latent "
+                    "ring have not been held to a reference; not supported")
+        if self.hc_streams > 1:
+            raise ValueError(
+                "'window_latent' layers with hc_streams > 1: not held to a "
+                "reference")
+
     def refuse_paged_serving(self, kv_dtype: str = "native") -> None:
         """What the paged serving engine does not serve of an architecture
         that trains and runs uncached, by name, before anything is traced
@@ -1024,6 +1110,12 @@ class TransformerArchitectureConfig(BaseConfig):
     def window_layers(self) -> int:
         """Layers whose mixer is windowed attention (a ring a slot)."""
         return (self.layer_pattern or []).count(LayerKind.WINDOW)
+
+    @property
+    def window_latent_layers(self) -> int:
+        """Layers whose mixer is windowed latent attention (a ring of latent
+        lines a slot)."""
+        return (self.layer_pattern or []).count(LayerKind.WINDOW_LATENT)
 
     @property
     def delta_layers(self) -> int:
@@ -1213,12 +1305,14 @@ class TransformerConfig(BaseConfig):
                         f"{why} neither stage-stacked nor tensor-parallel "
                         "yet; use 1"
                     )
-        if arch.window_layers and self.topology.context_parallel_size > 1:
-            raise ValueError(
-                "'window' layers with context_parallel_size "
-                f"{self.topology.context_parallel_size}: a window over a "
-                "sequence sharded on the context axis is not built (ring and "
-                "ulysses attend over the whole sequence); use 1")
+        for kind, layers in (("window", arch.window_layers),
+                             ("window_latent", arch.window_latent_layers)):
+            if layers and self.topology.context_parallel_size > 1:
+                raise ValueError(
+                    f"'{kind}' layers with context_parallel_size "
+                    f"{self.topology.context_parallel_size}: a window over a "
+                    "sequence sharded on the context axis is not built (ring "
+                    "and ulysses attend over the whole sequence); use 1")
         return self
 
     @classmethod

@@ -181,6 +181,35 @@ def delta_mixer(arch: TransformerArchitectureConfig) -> BaseLayer:
     )
 
 
+def latent_sizes(arch: TransformerArchitectureConfig, prefix: str = "") -> dict:
+    """What sizes a latent attention mixer, as the configuration names it: the
+    'latent' kind's fields or, under ``prefix`` ``window_latent_``, that
+    kind's own (its rotary takes the base frequencies, never a scaling)."""
+    def size(name):
+        return getattr(arch, prefix + name)
+
+    return dict(
+        hidden_size=arch.hidden_size,
+        num_attention_heads=size("num_attention_heads"),
+        q_lora_rank=size("q_lora_rank"),
+        kv_lora_rank=size("kv_lora_rank"),
+        qk_nope_head_dim=size("qk_nope_head_dim"),
+        qk_rope_head_dim=size("qk_rope_head_dim"),
+        v_head_dim=size("v_head_dim"),
+        rotary_config=RotaryConfig(
+            dimensions=size("qk_rope_head_dim"),
+            base=size("rotary_embedding_base"),
+            max_seq_length=arch.sequence_length,
+            scaling=None if prefix else arch.rope_scaling,
+        ),
+        layernorm_config=arch.layernorm,
+        masked_softmax_config=arch.masked_softmax,
+        dtype=arch.dtype,
+        output_gate=arch.attention_gate == AttentionGate.PER_HEAD,
+        lora_rescale=arch.latent_lora_rescale,
+    )
+
+
 class MixerLayer(BaseLayer):
     """A layer of a ``layer_pattern`` stack: ONE norm, ONE mixer of the
     layer's kind, the residual: ``x <- x + Mixer(Norm(x))`` (Nemotron-H's
@@ -218,24 +247,15 @@ class MixerLayer(BaseLayer):
         elif self.kind == LayerKind.LATENT:
             self.mixer = (SparseLatentSelfAttention if sparse
                           else LatentSelfAttention)(
-                **sparse,
-                hidden_size=arch.hidden_size,
-                num_attention_heads=arch.num_attention_heads,
-                q_lora_rank=arch.q_lora_rank,
-                kv_lora_rank=arch.kv_lora_rank,
-                qk_nope_head_dim=arch.qk_nope_head_dim,
-                qk_rope_head_dim=arch.qk_rope_head_dim,
-                v_head_dim=arch.v_head_dim,
-                rotary_config=RotaryConfig(
-                    dimensions=arch.qk_rope_head_dim,
-                    base=arch.rotary_embedding_base,
-                    max_seq_length=arch.sequence_length,
-                    scaling=arch.rope_scaling,
-                ),
-                layernorm_config=arch.layernorm,
-                masked_softmax_config=arch.masked_softmax,
-                dtype=dtype,
-            )
+                **sparse, **latent_sizes(arch))
+        elif self.kind == LayerKind.WINDOW_LATENT:
+            # the latent mixer at the window_latent_* sizes, under a window,
+            # no indexer; its kernel's module is imported where it is built
+            from ....nn.window_latent_attention import WindowLatentSelfAttention
+
+            self.mixer = WindowLatentSelfAttention(
+                window_size=arch.window_size,
+                **latent_sizes(arch, "window_latent_"))
         else:
             # softmax attention, 'attention' or 'window': a window layer has a
             # head count and a rotary of its own and takes no scaling
@@ -392,10 +412,10 @@ class MixerLayer(BaseLayer):
                     "serving engine's state pool), not a KV cache: cached "
                     "generate() is not built for a layer_pattern stack; use "
                     "use_cache=False or ServeEngine")
-            if self.kind == LayerKind.WINDOW:
+            if self.kind in (LayerKind.WINDOW, LayerKind.WINDOW_LATENT):
                 # attention all the same: its rotary and its mask are the
                 # positions' and the segments'
-                with jax.named_scope("window_attn"):
+                with jax.named_scope(f"{self.kind.value}_attn"):
                     y = self.mixer(
                         params["mixer"], normed, ctx,
                         segment_ids=x["segment_ids"],

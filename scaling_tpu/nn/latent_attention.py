@@ -50,6 +50,14 @@ YaRN (``nn/rotary.py``): the rotary tables take the static YaRN frequencies
 and the softmax scale its factor, ``(nope + rope) ** -0.5 *
 m(mscale_all_dim) ** 2``.
 
+The sizes are the constructor's, so a stack may hold this mixer at two sets of
+them (``nn/window_latent_attention.py`` is this class under a window, with a
+ring of lines a slot). Two options, both off by default: ``output_gate``, the
+head-wise gate of arXiv:2505.06708 (``g = sigmoid(x W_g)``, one value a head
+from the layer's normed input, on ``o_h`` before ``W_O``; scope ``gate``), and
+``lora_rescale``: ``c_q`` and ``c_kv`` leave their norms times ``(hidden /
+rank) ** 0.5`` (the rotary key is not scaled).
+
 Not built, refused by name where it is asked for (config validation,
 ``serve/kvcache.py``): int8 latent lines, model-parallel latent layers
 (a latent line has no head axis to shard: a deployment replicates the
@@ -98,6 +106,8 @@ class LatentSelfAttention(BaseLayer):
         masked_softmax_config: Optional[MaskedSoftmaxConfig] = None,
         dtype=jnp.float32,
         init_method: Callable = xavier_normal_init,
+        output_gate: bool = False,
+        lora_rescale: bool = False,
     ):
         assert rotary_config.dimensions == qk_rope_head_dim, (
             "the rotary slice of a latent head is qk_rope_head_dim wide")
@@ -131,18 +141,28 @@ class LatentSelfAttention(BaseLayer):
         self.rotary_embedding = RotaryEmbedding(rotary_config)
         self.masked_softmax = MaskedSoftmax(
             masked_softmax_config or MaskedSoftmaxConfig())
+        # what the two latents leave their norms times (1: no rescale)
+        self.q_scale = (hidden_size / q_lora_rank) ** 0.5 if lora_rescale else 1.0
+        self.kv_scale = (hidden_size / kv_lora_rank) ** 0.5 if lora_rescale else 1.0
+        # the leaves, in init's order: a gate is one more, the LAST (a mixer
+        # without one splits its key as it always did)
+        self.parts = self.PARTS
+        if output_gate:
+            self.gate = ColumnParallelLinear(
+                hidden_size, n, parallel_output=True, **common)
+            self.parts += ("gate",)
 
     PARTS = ("q_a_proj", "q_a_norm", "q_b_proj", "kv_a_proj", "kv_a_norm",
              "kv_b_proj", "dense")
 
     def init(self, key: jax.Array) -> dict:
-        keys = jax.random.split(key, len(self.PARTS))
+        keys = jax.random.split(key, len(self.parts))
         return {name: getattr(self, name).init(k)
-                for name, k in zip(self.PARTS, keys)}
+                for name, k in zip(self.parts, keys)}
 
     def param_metas(self) -> dict:
         return {name: tree_prefix(getattr(self, name).param_metas(), name)
-                for name in self.PARTS}
+                for name in self.parts}
 
     # --------------------------------------------------------------- forward
     def _latents(self, params: dict, x: jax.Array, ctx: ForwardContext,
@@ -153,18 +173,28 @@ class LatentSelfAttention(BaseLayer):
         were projected from (a sparse layer's indexer reads it too)."""
         b, s, _ = x.shape
         n = self.num_heads
-        c_q = self.q_a_norm(
-            params["q_a_norm"], self.q_a_proj(params["q_a_proj"], x, ctx), ctx)
+        c_q = self._rescaled(self.q_a_norm(
+            params["q_a_norm"], self.q_a_proj(params["q_a_proj"], x, ctx), ctx),
+            self.q_scale)
         q = self.q_b_proj(params["q_b_proj"], c_q, ctx).reshape(
             b, s, n, self.nope + self.rope)
         q_nope, q_rope = q[..., :self.nope], q[..., self.nope:]
         kv = self.kv_a_proj(params["kv_a_proj"], x, ctx)
-        c_kv = self.kv_a_norm(
-            params["kv_a_norm"], kv[..., :self.kv_lora_rank], ctx)
+        c_kv = self._rescaled(self.kv_a_norm(
+            params["kv_a_norm"], kv[..., :self.kv_lora_rank], ctx),
+            self.kv_scale)
         k_r = kv[..., self.kv_lora_rank:][:, :, None, :]   # ONE key, no head
         q_rope, k_r = self.rotary_embedding(
             q_rope, k_r, position_ids, position_ids)
         return q_nope, q_rope, c_kv, k_r[:, :, 0, :], c_q
+
+    @staticmethod
+    def _rescaled(latent, scale: float):
+        """A latent times ``lora_rescale``'s factor: a float32 product, the
+        latent's dtype back."""
+        if scale == 1.0:
+            return latent
+        return (latent.astype(jnp.float32) * scale).astype(latent.dtype)
 
     def _up_weights(self, params: dict, dtype):
         """``(W_UK (kv_lora_rank, n, nope), W_UV (kv_lora_rank, n, v))``: two
@@ -178,6 +208,36 @@ class LatentSelfAttention(BaseLayer):
         """The two leaves of the line a token leaves behind."""
         pad = self.rope_line - self.rope
         return c_kv, jnp.pad(k_r, ((0, 0),) * (k_r.ndim - 1) + ((0, pad),))
+
+    def _whole_line(self, c_kv, k_r):
+        """The line as ONE leaf: the latent, the rotary key in its lane row
+        after it."""
+        return jnp.concatenate(self._line(c_kv, k_r), axis=-1)
+
+    def _query_line(self, params, q_nope, q_rope):
+        """A query against a whole line, absorbed: ``[q_nope W_UK^T, q_rope,
+        zeros] . [c_kv, k_r, 0]``: ``((tokens, n, line lanes), W_UV)``."""
+        b, s, n = q_nope.shape[:3]
+        w_uk, w_uv = self._up_weights(params, q_nope.dtype)
+        q_lat = jnp.einsum("bsnd,cnd->bsnc", q_nope, w_uk)
+        q_line = jnp.concatenate([
+            q_lat, q_rope,
+            jnp.zeros((b, s, n, self.rope_line - self.rope), q_lat.dtype),
+        ], axis=-1)
+        return q_line.reshape(b * s, n, -1), w_uv
+
+    def _project_out(self, params, out, x, ctx):
+        """Heads -> hidden: ``out`` (b, s, n * v) through the output
+        projection; with a gate, each head's output times its gate first
+        (``x``: the layer's input, which the gate reads)."""
+        if "gate" in self.parts:
+            with jax.named_scope("gate"):
+                g = jax.nn.sigmoid(
+                    self.gate(params["gate"], x, ctx).astype(jnp.float32))
+                heads = out.reshape(*out.shape[:2], self.num_heads, self.v_dim)
+                out = (heads.astype(jnp.float32) * g[..., None]).astype(
+                    out.dtype).reshape(out.shape)
+        return self.dense(params["dense"], out, ctx)
 
     def __call__(
         self,
@@ -196,13 +256,13 @@ class LatentSelfAttention(BaseLayer):
         if isinstance(kv_cache, PagedKVCacheView):
             out, new_view = self._paged_attention(
                 params, q_nope, q_rope, c_kv, k_r, kv_cache, ctx)
-            return self.dense(params["dense"], out, ctx), new_view
+            return self._project_out(params, out, x, ctx), new_view
         self._refuse_dense_cache(kv_cache)
         if segment_ids is None:
             segment_ids = jnp.zeros((b, s), dtype=jnp.int32)
         mask = segment_ids_to_mask(segment_ids, None, causal=True,
                                    positions_q=None, positions_k=None)
-        y = self._expanded(params, q_nope, q_rope, c_kv, k_r, mask, ctx)
+        y = self._expanded(params, x, q_nope, q_rope, c_kv, k_r, mask, ctx)
         if return_kv:
             return y, self._line(c_kv, k_r)
         return y
@@ -215,10 +275,10 @@ class LatentSelfAttention(BaseLayer):
                 "serving engine's pool), not a dense cache: cached generate() "
                 "is not built for it; use use_cache=False or ServeEngine")
 
-    def _expanded(self, params, q_nope, q_rope, c_kv, k_r, forbidden, ctx):
+    def _expanded(self, params, x, q_nope, q_rope, c_kv, k_r, forbidden, ctx):
         """The expanded form: every head's keys and values from the latent,
-        the softmax under ``forbidden`` (b, 1, s, s), the output projection:
-        ``(b, s, hidden)``."""
+        the softmax under ``forbidden`` (b, 1, s, s), the gate (of the
+        layer's input ``x``) and the output projection: ``(b, s, hidden)``."""
         b, s, n = *q_nope.shape[:2], self.num_heads
         kv = self.kv_b_proj(params["kv_b_proj"], c_kv, ctx).reshape(
             b, s, n, self.nope + self.v_dim)
@@ -230,7 +290,8 @@ class LatentSelfAttention(BaseLayer):
         out = multi_head_attention(
             q, k, kv[..., self.nope:], forbidden, self.scaling_factor,
             self.masked_softmax)
-        return self.dense(params["dense"], out.reshape(b, s, n * self.v_dim), ctx)
+        return self._project_out(
+            params, out.reshape(b, s, n * self.v_dim), x, ctx)
 
     def _paged_attention(self, params, q_nope, q_rope, c_kv, k_r,
                          view: PagedKVCacheView, ctx: ForwardContext):

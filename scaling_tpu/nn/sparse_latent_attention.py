@@ -65,6 +65,9 @@ selection, so this version reads low on them. ONE form serves chunk rows and
 decode rows; the expanded form would up-project every line for every query
 with nothing shared.
 
+With ``output_gate`` (the parent's) the head-wise gate is applied after
+``sparse_attend``, before the output projection, under ``gate``.
+
 Scopes (inside the layer's ``attn``): ``indexer`` holds everything the
 indexer adds (its three projections, LayerNorm, rotary, the scatter of its
 key, scores and choice), ``index_select`` inside it the scores and the
@@ -168,7 +171,8 @@ class SparseLatentSelfAttention(LatentSelfAttention):
         if isinstance(kv_cache, PagedKVCacheView):
             out, new_view, tie_breaks = self._paged_sparse(
                 params, q_nope, q_rope, c_kv, k_r, q_i, k_i, w, kv_cache, ctx)
-            return self.dense(params["dense"], out, ctx), new_view, tie_breaks
+            return (self._project_out(params, out, x, ctx), new_view,
+                    tie_breaks)
         self._refuse_dense_cache(kv_cache)
         # --- expanded heads under the mask of the chosen lines
         if segment_ids is None:
@@ -179,7 +183,7 @@ class SparseLatentSelfAttention(LatentSelfAttention):
             visible = ~forbidden[:, 0]                          # (b, s, s)
             chosen = chosen_mask(
                 index_scores(q_i, k_i, w), visible, self.index_topk)
-        y = self._expanded(params, q_nope, q_rope, c_kv, k_r,
+        y = self._expanded(params, x, q_nope, q_rope, c_kv, k_r,
                            ~chosen[:, None], ctx)
         if return_kv:
             return y, self._sparse_line(c_kv, k_r, k_i)
@@ -188,8 +192,7 @@ class SparseLatentSelfAttention(LatentSelfAttention):
     def _sparse_line(self, c_kv, k_r, k_i):
         """The two leaves of the line a token leaves behind: the latent with
         the rotary key (in its lane row) after it, and the index key."""
-        c_kv, k_r = self._line(c_kv, k_r)
-        return jnp.concatenate([c_kv, k_r], axis=-1), k_i
+        return self._whole_line(c_kv, k_r), k_i
 
     # ----------------------------------------------------------------- paged
     def _paged_sparse(self, params, q_nope, q_rope, c_kv, k_r, q_i, k_i, w,
@@ -217,13 +220,7 @@ class SparseLatentSelfAttention(LatentSelfAttention):
         with jax.named_scope("indexer"):   # the scatter writes both leaves
             new_view = paged_scatter_kv(
                 view, flat, line.reshape(tokens, -1), key.reshape(tokens, -1))
-        w_uk, w_uv = self._up_weights(params, q_nope.dtype)
-        q_lat = jnp.einsum("bsnd,cnd->bsnc", q_nope, w_uk)
-        # a query against a whole line: [q', q_rope, zeros] . [c_kv, k_r, 0]
-        q_line = jnp.concatenate([
-            q_lat, q_rope,
-            jnp.zeros((b, s, n, self.rope_line - self.rope), q_lat.dtype),
-        ], axis=-1).reshape(tokens, n, -1)
+        q_line, w_uv = self._query_line(params, q_nope, q_rope)
         q_i = q_i.reshape(tokens, self.index_heads, self.index_dim)
         w = w.reshape(tokens, self.index_heads)
         if ctx.paged_kernel == "pallas":

@@ -107,6 +107,114 @@ def ring_lines(window: int, row_width: int) -> int:
     return max(8, 1 << (need - 1).bit_length())
 
 
+class RingRows(NamedTuple):
+    """A tick's addressing of the slots' rings (``ring_rows``)."""
+
+    ctx_len: jax.Array   # (slots,) int32 tokens a row has cached
+    new_len: jax.Array   # (slots,) int32 real tokens it brings
+    last: jax.Array      # (slots,) int32 the last position it will have written
+    starts: jax.Array    # (slots,) int32 a row's first token in the batch
+    width: int           # the most tokens a row brings
+    row: jax.Array       # (tokens,) int32 a token's row
+    at: jax.Array        # (tokens,) int32 its position
+    real: jax.Array      # (tokens,) bool: it is a token
+    line: jax.Array      # (tokens,) int32 its line of the flattened rings;
+    #                      past them for what is no token (dropped)
+
+
+def ring_rows(view, shape, window: int, batch_shape) -> RingRows:
+    """Where a tick's tokens lie in the rings of a view that keeps a ring a
+    slot (``shape``: its ``(slots, ring)``), and the invariant that lets a
+    tick write before it attends: ``ring >= window - 1 + row width``."""
+    slots, ring = shape
+    tmap = view.token_map
+    if tmap is None:
+        tmap = row_major_map(*batch_shape)
+    ctx_len = view.context_len.astype(jnp.int32)
+    new_len = view.new_len.astype(jnp.int32)
+    width = tmap.row_tokens.shape[1]
+    if ring < window - 1 + width:
+        raise ValueError(
+            f"a ring of {ring} lines under rows of up to {width} tokens "
+            f"and a window of {window}: a chunk's writes would "
+            "land on lines its queries still read; the ring holds window "
+            "- 1 + row width lines at least (serve/engine.py sizes it)")
+    row, offset = tmap.row.reshape(-1), tmap.offset.reshape(-1)
+    real = offset < new_len[row]
+    at = ctx_len[row] + offset
+    # what is no token is dropped: a ring has no trash line
+    line = jnp.where(real, row * ring + at % ring, slots * ring)
+    return RingRows(ctx_len, new_len, ctx_len + new_len - 1,
+                    tmap.row_tokens[:, 0], width, row, at, real, line)
+
+
+def ring_written(lines: jax.Array, line: jax.Array, new: jax.Array):
+    """``lines`` (slots, ring, lanes) with the tick's ``new`` values, a token
+    a row of them, at ``line`` (``RingRows.line``): the ONE scatter of a
+    ring."""
+    flat = lines.reshape(lines.shape[0] * lines.shape[1], -1)
+    return flat.at[line].set(
+        new.reshape(line.shape[0], -1).astype(lines.dtype),
+        mode="drop").reshape(lines.shape)
+
+
+def final_ring(lines: jax.Array, window: int, row_width: int):
+    """What an uncached pass over ``lines`` (b, s, lanes) leaves in a ring
+    sized for ticks whose rows bring ``row_width`` tokens: ``(b, ring,
+    lanes)``, position ``p`` at line ``p % ring``, zeros where nothing is."""
+    s = lines.shape[1]
+    held = line_positions(jnp.int32(s - 1), ring_lines(window, row_width))
+    return jnp.where((held >= 0)[None, :, None],
+                     lines[:, jnp.maximum(held, 0)], 0)
+
+
+def walk_ring_rows(attend, q, at: RingRows, window: int, ring: int):
+    """Every row's queries ``q`` (tokens, n, lanes) over its own ring through
+    a ring kernel, at the row's real shape: the rows of ONE token all in one
+    call (a slot that decodes nothing folds nothing), the rows that bring a
+    chunk one by one (a rolled loop; a slot without one costs a branch). What
+    no row owns stays zero. ``attend(q (rows, positions, n, lanes), slot, at,
+    last, first, live, tile=)`` is the kernel over the layer's rings."""
+    tokens, n = q.shape[:2]
+    ctx_len, new_len, last, starts, width = (
+        at.ctx_len, at.new_len, at.last, at.starts, at.width)
+    rows = new_len.shape[0]
+    # the first position a row's first query sees
+    first = ctx_len - (window - 1)
+    single = new_len == 1
+    ones = attend(
+        q[starts][:, None], jnp.arange(rows, dtype=jnp.int32),
+        ctx_len[:, None], last, first, single,
+        tile=ring_tile(ring, SINGLE_TILE))
+    out = jnp.zeros((tokens, n, ones.shape[-1]), q.dtype).at[
+        jnp.where(single, starts, tokens)].set(ones[:, 0], mode="drop")
+    if width == 1:
+        return out
+
+    def chunk(r, out):
+        # ``width`` places from the row's first token, or the batch's
+        # last ``width`` where that would pass its end: the row's tokens
+        # then lie ``shift`` places in, among other rows'
+        begin = jnp.minimum(starts[r], tokens - width)
+        place = jnp.arange(width, dtype=jnp.int32) - (starts[r] - begin)
+        keep = (place >= 0) & (place < new_len[r])
+        mine = attend(
+            jax.lax.dynamic_slice_in_dim(q, begin, width, 0)[None], r[None],
+            jnp.where(keep, ctx_len[r] + place, NOBODY)[None],
+            last[r][None], first[r][None], jnp.ones((1,), bool),
+            tile=ring_tile(ring, KEY_TILE))[0]
+        old = jax.lax.dynamic_slice_in_dim(out, begin, width, 0)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, jnp.where(keep[:, None, None], mine, old), begin, 0)
+
+    def one_row(out, r):
+        return jax.lax.cond(new_len[r] > 1, chunk, lambda r, out: out,
+                            r, out), None
+
+    out, _ = jax.lax.scan(one_row, out, jnp.arange(rows, dtype=jnp.int32))
+    return out
+
+
 class WindowSelfAttention(ParallelSelfAttention):
     STATE_VIEW = WindowRingView
 
@@ -150,12 +258,9 @@ class WindowSelfAttention(ParallelSelfAttention):
             ~allowed[:, None], self.scaling_factor, self.masked_softmax)
         rings = None
         if return_state:
-            ring = ring_lines(self.window_size, ctx.serve_row_width)
-            held = line_positions(jnp.int32(s - 1), ring)         # (ring,)
             rings = tuple(
-                jnp.where((held >= 0)[None, :, None],
-                          a.reshape(b, s, -1)[:, jnp.maximum(held, 0)], 0)
-                for a in (k, v))
+                final_ring(a.reshape(b, s, -1), self.window_size,
+                           ctx.serve_row_width) for a in (k, v))
         return self._project_out(params, out, ctx, b, s, rings, x)
 
     # ---------------------------------------------------------------- served
@@ -164,94 +269,32 @@ class WindowSelfAttention(ParallelSelfAttention):
         row, under the window's mask: ``((g, s, n, h), the updated view)``."""
         g, s, n, h = q.shape
         tokens = g * s
-        slots, ring = view.k.shape[:2]
-        tmap = view.token_map
-        if tmap is None:
-            tmap = row_major_map(g, s)
-        ctx_len = view.context_len.astype(jnp.int32)
-        new_len = view.new_len.astype(jnp.int32)
-        width = tmap.row_tokens.shape[1]
-        if ring < self.window_size - 1 + width:
-            raise ValueError(
-                f"a ring of {ring} lines under rows of up to {width} tokens "
-                f"and a window of {self.window_size}: a chunk's writes would "
-                "land on lines its queries still read; the ring holds window "
-                "- 1 + row width lines at least (serve/engine.py sizes it)")
-        row, offset = tmap.row.reshape(-1), tmap.offset.reshape(-1)
-        real = offset < new_len[row]
-        at = ctx_len[row] + offset
-        # what is no token is dropped: a ring has no trash line
-        line = jnp.where(real, row * ring + at % ring, slots * ring)
-
-        def written(lines, new):
-            flat = lines.reshape(slots * ring, -1)
-            return flat.at[line].set(
-                new.reshape(tokens, -1).astype(lines.dtype),
-                mode="drop").reshape(lines.shape)
-
-        view = view._replace(k=written(view.k, k), v=written(view.v, v))
+        at = ring_rows(view, view.k.shape[:2], self.window_size, (g, s))
+        view = view._replace(k=ring_written(view.k, at.line, k),
+                             v=ring_written(view.v, at.line, v))
         q = q.reshape(tokens, n, h)
-        last = ctx_len + new_len - 1
         with jax.named_scope("window_attend"):
             if ctx.paged_kernel == "pallas":
-                out = self._walk_rows(q, view, ctx_len, new_len, last,
-                                      tmap.row_tokens[:, 0], width)
+                out = self._walk_rows(q, view, at)
             else:
                 assert ctx.paged_kernel == "xla", (
                     f"unknown paged_kernel {ctx.paged_kernel!r} (expected "
                     "'pallas' or 'xla')")
-                out = self._attend_gathered_rings(q, view, row, at, real, last)
+                out = self._attend_gathered_rings(
+                    q, view, at.row, at.at, at.real, at.last)
         return out.reshape(g, s, n, h), view
 
-    def _walk_rows(self, q, view: WindowRingView, ctx_len, new_len, last,
-                   starts, width: int):
-        """Every row's queries over its own ring through the ring kernel, at
-        the row's real shape: the rows of ONE token all in one call (a slot
-        that decodes nothing folds nothing), the rows that bring a chunk one
-        by one (a rolled loop; a slot without one costs a branch). What no row
-        owns stays zero."""
-        tokens, n, h = q.shape
-        rows, ring = new_len.shape[0], view.k.shape[1]
+    def _walk_rows(self, q, view: WindowRingView, at: RingRows):
+        """Every row's queries over its own ring through the ring kernel
+        (``walk_ring_rows``: the rows' real shapes)."""
         interpret = paged_kernel_interpret()
         count_kernel_build(KERNEL_NAME, interpret)
         attend = functools.partial(
             window_ring_attention, window=self.window_size,
             sm_scale=float(self.scaling_factor), interpret=interpret)
-        # the first position a row's first query sees
-        first = ctx_len - (self.window_size - 1)
-        single = new_len == 1
-        ones = attend(
-            q[starts][:, None], view.k, view.v,
-            jnp.arange(rows, dtype=jnp.int32), ctx_len[:, None], last, first,
-            single, tile=ring_tile(ring, SINGLE_TILE))
-        out = jnp.zeros((tokens, n, h), q.dtype).at[
-            jnp.where(single, starts, tokens)].set(ones[:, 0], mode="drop")
-        if width == 1:
-            return out
-
-        def chunk(r, out):
-            # ``width`` places from the row's first token, or the batch's
-            # last ``width`` where that would pass its end: the row's tokens
-            # then lie ``shift`` places in, among other rows'
-            begin = jnp.minimum(starts[r], tokens - width)
-            place = jnp.arange(width, dtype=jnp.int32) - (starts[r] - begin)
-            keep = (place >= 0) & (place < new_len[r])
-            mine = attend(
-                jax.lax.dynamic_slice_in_dim(q, begin, width, 0)[None],
-                view.k, view.v, r[None],
-                jnp.where(keep, ctx_len[r] + place, NOBODY)[None],
-                last[r][None], first[r][None], jnp.ones((1,), bool),
-                tile=ring_tile(ring, KEY_TILE))[0]
-            old = jax.lax.dynamic_slice_in_dim(out, begin, width, 0)
-            return jax.lax.dynamic_update_slice_in_dim(
-                out, jnp.where(keep[:, None, None], mine, old), begin, 0)
-
-        def one_row(out, r):
-            return jax.lax.cond(new_len[r] > 1, chunk, lambda r, out: out,
-                                r, out), None
-
-        out, _ = jax.lax.scan(one_row, out, jnp.arange(rows, dtype=jnp.int32))
-        return out
+        return walk_ring_rows(
+            lambda q, *rows, tile: attend(q, view.k, view.v, *rows, tile=tile),
+            q, at, self.window_size, view.k.shape[1])
 
     def _attend_gathered_rings(self, q, view: WindowRingView, row, at, real,
                                last):

@@ -32,7 +32,8 @@ kernel a call); ``interpret=True`` runs the kernel's own arithmetic under the
 interpreter. Operands as given, float32 accumulation.
 
 The tiles come from the shapes (``grouped_tiles``), never from a model's
-name: see there.
+name: see there; a call whose buffers would pass VMEM (many rows of a narrow
+``k`` into a wide ``n``) takes a narrower column tile (``fitting_columns``).
 """
 
 from __future__ import annotations
@@ -53,6 +54,11 @@ _ROW_ALIGN = 16
 _RHS_VMEM_BYTES = 28 << 20
 # and the rows of lhs one call keeps resident (more are cut into calls)
 _LHS_VMEM_BYTES = 16 << 20
+# what a call's buffers may take of a v5e's 128 MiB in all (``lhs`` once, the
+# matrix tile and the output's column tile twice), and what a call that would
+# pass it is narrowed to
+_CALL_VMEM_BYTES = 126 << 20
+_NARROWED_VMEM_BYTES = 96 << 20
 
 
 def grouped_matmul_interpret(platform: Optional[str] = None) -> Optional[bool]:
@@ -81,6 +87,23 @@ def grouped_tiles(m: int, k: int, n: int, groups: int,
         tn = max(_LANES,
                  _RHS_VMEM_BYTES // (2 * k * itemsize) // _LANES * _LANES)
     return tm, tn
+
+
+def fitting_columns(rows: int, k: int, n: int, tn: int, itemsize: int) -> int:
+    """``tn``, or, where ``rows`` of ``lhs``, two ``(k, tn)`` matrix tiles and
+    two ``(rows, tn)`` output tiles would not fit VMEM (many rows of a narrow
+    ``k`` into a wide ``n``: 5,376 x 1,536 into 5,120), the lane multiple that
+    cuts ``n`` into the fewest equal column tiles that do. A matrix is still
+    read once, a column tile a step."""
+    def need(tn):
+        return itemsize * (rows * k + 2 * tn * (k + rows))
+
+    if need(tn) <= _CALL_VMEM_BYTES:
+        return tn
+    widest = (_NARROWED_VMEM_BYTES - itemsize * rows * k) // (
+        2 * itemsize * (k + rows))
+    tiles = -(-n // max(widest, _LANES))
+    return -(-n // tiles // _LANES) * _LANES
 
 
 def _kernel(gid_ref, start_ref, end_ref, lhs_ref, rhs_ref, out_ref, *,
@@ -181,6 +204,13 @@ def grouped_matmul(
     groups, _, n = rhs.shape
     count_kernel_build("grouped_matmul", interpret)
     tm, tn = tiles or grouped_tiles(m, k, n, groups, lhs.dtype.itemsize)
+    pad = -m % tm  # none at a serving tick's widths
+    # rows one call keeps in VMEM: all of a serving tick's; a longer buffer
+    # (a whole prompt's assignments) is cut into calls, each with the rows
+    # of every group that fall inside it
+    block = max(tm, _LHS_VMEM_BYTES // (k * lhs.dtype.itemsize) // tm * tm)
+    if tiles is None:
+        tn = fitting_columns(min(m + pad, block), k, n, tn, lhs.dtype.itemsize)
     # the chip keeps an array whose minor dimension is no lane multiple with
     # a dimension that is one as its minor (Nemotron's (64, 2688, 1856): the
     # 2688 lie along the lanes), and a kernel takes its operands row-major:
@@ -194,13 +224,8 @@ def grouped_matmul(
         _grouped_call, tiles=(tm, tn),
         transposed=transposed, interpret=interpret)
     sizes = group_sizes.astype(jnp.int32)
-    pad = -m % tm  # none at a serving tick's widths
     if pad:
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
-    # rows one call keeps in VMEM: all of a serving tick's; a longer buffer
-    # (a whole prompt's assignments) is cut into calls, each with the rows
-    # of every group that fall inside it
-    block = max(tm, _LHS_VMEM_BYTES // (k * lhs.dtype.itemsize) // tm * tm)
     if m + pad <= block:
         out = call(lhs, rhs, sizes)
     else:

@@ -370,7 +370,11 @@ class ServeEngine:
         # and the scheduler's block count are the full layers' alone
         self.window_layers = arch.window_layers
         self.window_size = arch.window_size
-        if self.window_layers and self.config.kv_dtype != "native":
+        # windowed LATENT layers (nn/window_latent_attention.py): a ring of
+        # latent lines a slot, beside the sparse latent layers' pages
+        self.window_latent_layers = arch.window_latent_layers
+        if ((self.window_layers or self.window_latent_layers)
+                and self.config.kv_dtype != "native"):
             raise ValueError(
                 f"kv_dtype {self.config.kv_dtype!r} with window attention "
                 "layers: a ring's lines are kept in the model's dtype and "
@@ -588,17 +592,20 @@ class ServeEngine:
         # lost update that read 0 would silently skip live deadlines.
         self._deadline_live = 0
         self._deadline_lock = threading.Lock()
-        if self.window_layers:
+        if self.window_layers or self.window_latent_layers:
             # what the window layers keep, fixed when the pools are built: it
             # does not grow with the context
             fields = [kind for kind in line_layers(self.pools.kinds)
                       for _ in kind.LINES]     # the kind of each list of lines
-            rings = [a for kind, lines in zip(fields, self.pools.lines)
-                     if kind.NAME == "window" for a in lines]
-            self.window_ring_lines = int(rings[0].shape[1])
-            self._gauge("serve_window_ring_lines").set(self.window_ring_lines)
-            self._gauge("serve_window_ring_gb").set(
-                sum(a.size * a.dtype.itemsize for a in rings) / 1e9)
+            for name in ("window", "window_latent"):
+                rings = [a for kind, lines in zip(fields, self.pools.lines)
+                         if kind.NAME == name for a in lines]
+                if not rings:
+                    continue
+                setattr(self, f"{name}_ring_lines", int(rings[0].shape[1]))
+                self._gauge(f"serve_{name}_ring_lines").set(rings[0].shape[1])
+                self._gauge(f"serve_{name}_ring_gb").set(
+                    sum(a.size * a.dtype.itemsize for a in rings) / 1e9)
 
     # ------------------------------------------------------------- intake
     def submit(self, prompt: List[int], max_new_tokens: int,
@@ -1341,7 +1348,7 @@ class ServeEngine:
                 mixed_span.annotate(sparse_single_rows=single_rows)
                 self._counter("serve_sparse_single_rows_total").inc(
                     single_rows * self.sparse_layers)
-        if self.window_layers:
+        if self.window_layers or self.window_latent_layers:
             # what a window layer's attention does this tick: its rows, those
             # whose context is past the window (there the window cuts what a
             # full layer would read), the ring lines the rows' queries see and
@@ -1355,6 +1362,7 @@ class ServeEngine:
             pairs = (upto * (first + 1) + upto * (upto - 1) // 2
                      + (n_new - upto) * w)
             lines = np.where(n_new > 0, np.minimum(held, w - 1 + n_new), 0)
+        if self.window_layers:
             mixed_span.annotate(
                 window_layers=self.window_layers,
                 window_rows_past=past, window_visible_lines=int(lines.sum()),
@@ -1366,6 +1374,21 @@ class ServeEngine:
                 rows * self.window_layers)
             self._counter("serve_window_rows_past_window_total").inc(
                 past * self.window_layers)
+        if self.window_latent_layers:
+            # the same of the windowed latent layers, and the form that
+            # attended: the rows of one token in one call of the ring kernel,
+            # a chunk row in a call of its own
+            single = int(np.count_nonzero(new_lens == 1))
+            mixed_span.annotate(
+                window_latent_layers=self.window_latent_layers,
+                window_latent_rows_past=past,
+                window_latent_visible_lines=int(lines.sum()),
+                window_latent_pairs=int(pairs.sum()),
+                window_latent_single_rows=single,
+                window_latent_chunk_rows=rows - single)
+            for path, count in (("single", single), ("chunk", rows - single)):
+                self._counter("serve_window_latent_rows_total", path=path).inc(
+                    count * self.window_latent_layers)
         if self.hc_sublayers:
             # what the residual path moves this tick: every real token's
             # streams through every sub-layer's mapping
@@ -1887,6 +1910,7 @@ class ServeEngine:
             "hc_streams": self.hc_streams,
             "hc_sublayers": self.hc_sublayers,
             "window_layers": self.window_layers,
+            "window_latent_layers": self.window_latent_layers,
             # layers that keep a line a slot (Mamba-2 mixers' recurrent state,
             # short convolutions' tails; 0: a model without them) and the
             # bytes of those lines

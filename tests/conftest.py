@@ -104,6 +104,8 @@ HEAD_OF_THE_RUN = (
     "tests/core/test_serve/test_hc_latent_serving.py",
     "tests/core/test_serve/test_layered_gqa_serving.py",
     "tests/core/test_serve/test_delta_engine.py",
+    "tests/core/test_serve/test_layered_latent_serving.py",
+    "tests/core/test_nn/test_window_latent_attention.py",
     "tests/core/test_nn/test_gated_delta.py",
     "tests/core/test_training/test_training.py",
     "tests/core/test_resilience/test_multihost.py",

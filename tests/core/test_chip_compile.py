@@ -654,6 +654,75 @@ def test_window_layer_compiles_at_the_cells_size(one_chip, tokens, monkeypatch):
     assert memory.alias_size_in_bytes >= 2 * 24 * 1024 * 8 * 128 * 2
 
 
+def window_latent_layer(one_chip, tokens, monkeypatch):
+    """``serve-dots3-mixedlen64k-burst``'s windowed latent mixer over its rings
+    at one of its engine's two token widths, compiled for the described chip,
+    its kernel by Mosaic: 16 rows of up to 256 positions, 64 heads absorbed
+    over ONE line of 1,024 + 128 lanes, a ring of 1,024 lines a slot, the
+    head-wise gate, the latents rescaled."""
+    monkeypatch.setattr(
+        "scaling_tpu.nn.window_latent_attention.paged_kernel_interpret",
+        lambda platform=None: False)
+    from scaling_tpu.nn.attention import packed_token_map
+    from scaling_tpu.nn.base_layer import ForwardContext
+    from scaling_tpu.nn.rotary import RotaryConfig
+    from scaling_tpu.nn.window_attention import ring_lines
+    from scaling_tpu.nn.window_latent_attention import (
+        LatentRingView, WindowLatentSelfAttention,
+    )
+    from scaling_tpu.serve.engine import packed_batch_shape
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    rows, hidden, width = 16, 5120, 256
+    ring = ring_lines(513, width)
+    mixer = WindowLatentSelfAttention(
+        window_size=513, output_gate=True, lora_rescale=True, hidden_size=hidden,
+        num_attention_heads=64, q_lora_rank=1024, kv_lora_rank=1024,
+        qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=128,
+        dtype=jnp.bfloat16,
+        rotary_config=RotaryConfig(dimensions=64, base=50000,
+                                   max_seq_length=65536))
+    params = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(mixer.init, jax.random.PRNGKey(0)))
+    batch = packed_batch_shape(tokens, width)
+
+    def layer(params, x, lines, ctx_len, new_len):
+        token_map = packed_token_map(new_len, batch, width)
+        pos = ctx_len[token_map.row] + token_map.offset
+        view = LatentRingView(line=lines, context_len=ctx_len,
+                              new_len=new_len, token_map=token_map)
+        y, new = mixer(
+            params, x, ForwardContext(serving=True, paged_kernel="pallas"),
+            position_ids=pos, state=view)
+        return y, new.line
+
+    return jax.jit(layer, donate_argnums=(2,)).lower(
+        params, shape((*batch, hidden)), shape((rows, ring, 1024 + 128)),
+        shape((rows,), jnp.int32), shape((rows,), jnp.int32),
+    ).compile()
+
+
+@pytest.mark.parametrize("tokens", [896, 4096], ids=["small", "full"])
+def test_window_latent_layer_compiles_at_the_cells_size(one_chip, tokens, monkeypatch):
+    """The walk over the rows' rings of latent lines at both token widths of
+    the cell's engine: the one-token rows in ONE call of
+    ``latent_ring_attention`` (16 rows, tiles of 256 lines), then a rolled loop
+    over the 16 slots with a branch for a chunk row (one row, 16 query blocks
+    of 16 positions x 64 heads, tiles of 512): the kernel built twice, the ring
+    read where it lies as key AND value (one operand), scattered into in
+    place."""
+    compiled = window_latent_layer(one_chip, tokens, monkeypatch)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert " while(" in text and " conditional(" in text
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 2.0e9, memory.temp_size_in_bytes
+    assert memory.alias_size_in_bytes >= 16 * 1024 * 1152 * 2
+
+
 def test_masked_paged_kernel_compiles_at_the_cells_size(one_chip):
     """``serve-keye30b-longctx-burst``'s rows of ONE token
     (nn/paged_attention.py with a mask operand): a pass of four rows, 32 query
@@ -1060,6 +1129,44 @@ def test_a_served_routed_layer_is_grouped_matmuls_under_the_moe_scope(
     for dims in (f"{held},{h},{f}", f"{held},{f},{h}"):
         copies = whole_copies(text, rf"bf16\[{dims}\]")
         assert not copies, f"{len(copies)} copies of a whole expert leaf"
+
+
+@pytest.mark.parametrize("places", [896, 4096], ids=["small", "full"])
+def test_many_rows_into_a_wide_output_fit_vmem(one_chip, monkeypatch, places):
+    """``serve-dots3-mixedlen64k-burst``'s routed layer (32 of 256 SwiGLU
+    experts held, k = 8, 5,120 x 1,536, a shared expert) at both token widths:
+    at the full width a call of the down projection holds 5,376 rows of 1,536
+    and would keep two ``(5376, 4736)`` output tiles beside them, 141 MiB of
+    the chip's 128 (the compile failed on the chip); ``fitting_columns``
+    narrows the column tile to two of 2,560. Every call under ``/moe/``."""
+    from scaling_tpu.nn.moe import ParallelMoEMLP
+    from scaling_tpu.ops.grouped_matmul import fitting_columns, grouped_tiles
+
+    monkeypatch.setattr(
+        "scaling_tpu.ops.grouped_matmul.grouped_matmul_interpret",
+        lambda platform=None: False)
+    assert grouped_tiles(16384, 1536, 5120, 32) == (128, 4736)
+    assert fitting_columns(5376, 1536, 5120, 4736, 2) == 2560
+    # what compiled before is as it was: Laguna's widest call (124 MiB)
+    assert fitting_columns(8192, 1024, 3072, 3072, 2) == 3072
+    assert fitting_columns(1536, 5120, 1536, 1408, 2) == 1408
+    layer = ParallelMoEMLP(
+        intermediate_feature_factor=1.0, dtype=jnp.bfloat16, io_features=5120,
+        intermediate=1536, num_experts=256, experts_held=32, top_k=8,
+        router="sigmoid_bias", norm_topk_eps=1e-20, shared_expert_width=1536)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda x: shape(x.shape, x.dtype),
+                          jax.eval_shape(layer.init, jax.random.PRNGKey(0)))
+    rows = places // 32
+    text = jax.jit(layer.serve).lower(
+        params, shape((rows, 32, 5120), jnp.bfloat16),
+        shape((rows, 32), jnp.bool_)).compile().as_text()
+    calls = custom_calls(text)
+    assert calls and all(scope_of(call) == "moe" for call in calls), calls
+    assert "ragged-dot" not in text
 
 
 def test_a_small_share_of_the_experts_meets_one_call_a_matrix_a_pass(
