@@ -1,5 +1,6 @@
 """Gated delta-rule mixer (Yang et al. 2024, "Gated Delta Networks",
-arXiv:2412.06464): ``jax.numpy``, and a Pallas kernel for the single step.
+arXiv:2412.06464): ``jax.numpy``, and a Pallas kernel that takes a row of one
+token from the input projection to the output projection.
 
 The linear-attention layer of the Qwen3-Next family. ``x`` (.., H) is the
 normed residual stream; ``nk`` key heads of ``dk`` lanes, ``nv`` value heads
@@ -49,17 +50,25 @@ Two callers, the contract ``Mamba2Mixer`` keeps:
 - uncached (``logits()``, the tests): each sequence is walked in chunks of
   ``CHUNK`` positions from a zero state;
 - served (``state`` a :class:`DeltaStateView`): one line a (slot, layer),
-  ``state (slots, nv, dk, dv)`` float32 and ``conv (slots, 2 nk dk + nv dv, K
-  - 1)``, every row advanced from ITS line. Below the full width a row of ONE
-  token takes the single step where it lies (``_step_rows``, through
-  ``delta_step``: a kernel that holds a slot's state in VMEM, reads it once,
-  takes both read-outs ``S^T k`` and ``S^T q`` from it and the output from ``o
-  = exp(g) S^T q + (q . k) u``, and writes the new state once over the old
-  one; the update depends on a read-out, so the compiler's own fusions pass
-  over the state twice) and the at most ``split_capacity`` rows of more are
-  gathered, run the chunk form from their lines and are written back; at the
-  full width every row runs the chunk form. A row whose ``context_len`` is 0
-  starts from zeros in either form; an empty place is untouched.
+  ``state (slots, nv, dk, dv)`` float32 and the conv tail ``conv (slots, K - 1,
+  C / 128, 128)``, ``C = 2 nk dk + nv dv`` channels: a tap a plane of whole
+  tiles with the channels on the lanes (``conv_line``), every row advanced
+  from ITS line. Below the full width a row of ONE token takes the single step
+  where it lies, from ``in_proj``'s output to ``out_proj``'s input in one
+  Pallas kernel (``delta_step``; ``_split_rows`` calls it): a slot a grid
+  step, the row's token of ``[q | k | v | z]`` and ``[b | a]`` found among the
+  tick's places through the prefetched index of its first place. In VMEM and
+  in float32 it takes the conv over the slot's taps and its ``silu``, the two
+  L2 norms, ``beta``, ``g`` and the decay, ``q . k``; holds the slot's state,
+  reads it once, takes both read-outs ``S^T k`` and ``S^T q`` from it and the
+  output from ``o = exp(g) S^T q + (q . k) u``, and writes the new state once
+  over the old one (the update depends on a read-out, so the compiler's own
+  fusions pass over the state twice); then the gated RMS norm a head. It
+  writes the new tail over the old one and the gated output in the model's
+  dtype. The at most ``split_capacity`` rows of more are gathered, run the
+  chunk form from their lines and are written back; at the full width every
+  row runs the chunk form. A row whose ``context_len`` is 0 starts from zeros
+  in either form; an empty place is untouched.
 """
 
 from __future__ import annotations
@@ -86,6 +95,8 @@ HIGHEST = jax.lax.Precision.HIGHEST
 CHUNK = 64
 L2_EPS = 1e-6
 KERNEL_NAME = "delta_step"
+# channels a row of a conv line's plane: the chip's lanes
+LANES = 128
 
 
 class DeltaStateView(NamedTuple):
@@ -101,10 +112,26 @@ class DeltaStateView(NamedTuple):
     SPLITS = True
 
     state: jax.Array        # (slots, nv, dk, dv) float32
-    conv: jax.Array         # (slots, 2 nk dk + nv dv, K - 1) last conv inputs
+    conv: jax.Array         # (slots, K - 1, C / 128, 128) the last K - 1 conv
+    #                         inputs of the C = 2 nk dk + nv dv channels, oldest
+    #                         first, as ``conv_line`` lays them
     context_len: jax.Array  # (slots,) int32 tokens the state has seen
     new_len: jax.Array      # (slots,) int32 real tokens the row brings
     token_map: Optional[PagedTokenMap] = None  # token-major batches
+
+
+def conv_line(tail):
+    """A conv tail ``(.., K - 1, C)`` as its line lies in the pool, ``(.., K -
+    1, C / LANES, LANES)``: a tap a plane of whole ``(8, 128)`` tiles with the
+    channels on the lanes, so that the step's kernel takes a slot's taps as
+    they lie. (As ``(slots, K - 1, C)`` or ``(slots, C, K - 1)`` the chip
+    keeps a leaf tap-major, ``(K - 1, slots, C)``, to spare the padding of 3
+    to 8: a slot's taps are then rows of three planes, and the kernel's block
+    a copy of the whole leaf before and after. PERF.md, PR 76.) Where ``C`` is
+    no multiple of ``LANES`` (the tests' toys) a plane is one row."""
+    C = tail.shape[-1]
+    lanes = LANES if C % LANES == 0 else C
+    return tail.reshape(*tail.shape[:-1], C // lanes, lanes)
 
 
 def _block_product(a, b):
@@ -200,19 +227,89 @@ def delta_chunk(q, k, v, g, beta, S0, fresh=None):
     return o.reshape(r, width, nv, dv)[:, :C], S.reshape(S0.shape)
 
 
-def _delta_step_kernel(kq_ref, rows_ref, state_ref, new_ref, o_ref, *,
-                       nk: int, per: int):
-    """One slot's step, every head: ``kq_ref`` (1, dk, 2 nk), a key head's k
-    (then q) a LANE, so that a head's key is a column that broadcasts over the
-    state's lanes; ``rows_ref`` (1, 4, nv, dv), a value head's four lane rows
-    ``a = beta v``, ``c = beta decay``, ``d = decay`` (0: start from zeros),
-    ``e = q . k``; the state (1, nv, dk, dv), read once and written once over
-    itself."""
+def _column(row):
+    """``(1, n) -> (n, 1)`` through the diagonal of its broadcast: a select and
+    a reduction over the lanes, a few registers for the ``n`` of a layer's
+    heads (no transpose of an unaligned shape is asked of Mosaic)."""
+    n = row.shape[1]
+    diagonal = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+                == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    return jnp.sum(jnp.where(diagonal, jnp.broadcast_to(row, (n, n)), 0.0),
+                   axis=1, keepdims=True)
+
+
+def _delta_step_kernel(first_ref, ctx_ref, len_ref, proj_ref, ba_ref, tail_ref,
+                       w_ref, a_log_ref, dt_ref, norm_ref, state_ref,
+                       new_ref, new_tail_ref, y_ref,
+                       rows_scr, e_scr, kq_scr, o_scr, *,
+                       nk: int, dk: int, eps: float):
+    """One slot's step from ``in_proj``'s output to ``out_proj``'s input.
+
+    ``first_ref`` / ``ctx_ref`` / ``len_ref`` (slots,) int32 in SMEM: the
+    row's first place of the tick, the tokens its state has seen and the
+    tokens it brings. ``proj_ref`` (tb, 2 nk dk + 2 nv dv) and ``ba_ref`` (tb,
+    2 nv): the block of ``tb`` places that holds the row's token (the index
+    map chose it); ``tail_ref`` (1, K - 1, C / L, L) the slot's last conv
+    inputs as ``conv_line`` lays them; ``w_ref`` (K, C / L, L) float32 the
+    conv's weight, a tap a plane; ``a_log_ref`` / ``dt_ref`` (1, nv);
+    ``norm_ref`` (1, dv); the state (1, nv, dk, dv), read once and written
+    once over itself. A row that brings no single token steps with ``beta = g
+    = 0``: state and tail are written as they were.
+
+    The prologue is vector work over whole ``(heads, lanes)`` tiles and leaves
+    what the loop over the heads reads in VMEM: ``rows_scr`` (3, nv, dv), a
+    value head's lane rows ``a = beta v``, ``c = beta decay``, ``d = decay``
+    (0: start from zeros); ``e_scr`` (nk, dv), ``q . k`` a key head;
+    ``kq_scr`` (dk, 2 nk), a key head's k (then q) a LANE, so that a head's
+    key is a column that broadcasts over the state's lanes. The loop leaves a
+    head's read-out a row of ``o_scr`` (nv, dv) for the gated norm."""
+    i = _paged.pl.program_id(0)
+    nv, dv = o_scr.shape
+    per = nv // nk
+    kd = nk * dk
+    taps, tiles, lanes = tail_ref.shape[1:]
+    C = tiles * lanes
+    steps = len_ref[i] == 1
+    fresh = jnp.logical_and(steps, ctx_ref[i] == 0)
+    # the row's token among the block's places, float32
+    tb = proj_ref.shape[0]
+    at = jax.lax.broadcasted_iota(jnp.int32, (tb, 1), 0) == first_ref[i] % tb
+    tok = jnp.sum(jnp.where(at, proj_ref[...].astype(F32), 0.0), axis=0,
+                  keepdims=True)                                # (1, W)
+    ba = jnp.sum(jnp.where(at, ba_ref[...].astype(F32), 0.0), axis=0,
+                 keepdims=True)                                 # (1, 2 nv)
+    # the depthwise conv over the K taps: the slot's tail, then the token
+    tail = tail_ref[0]                                          # (K - 1, ..)
+    old = jnp.where(fresh, 0.0, tail.astype(F32))
+    new = tok[:, :C].reshape(tiles, lanes)
+    conved = new * w_ref[taps]
+    for j in range(taps):
+        conved = conved + old[j] * w_ref[j]
+    conved = (conved * jax.nn.sigmoid(conved)).reshape(1, C)    # silu
+    new_tail_ref[0] = jnp.where(
+        steps, jnp.concatenate([old[1:], new[None]], axis=0).astype(tail.dtype),
+        tail)
+    q = conved[:, :kd].reshape(nk, dk)
+    k = conved[:, kd:2 * kd].reshape(nk, dk)
+    v = conved[:, 2 * kd:].reshape(nv, dv)
+    q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + L2_EPS) * dk ** -0.5
+    k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + L2_EPS)
+    kq_scr[...] = jnp.concatenate([k, q], axis=0).T             # (dk, 2 nk)
+    e_scr[...] = jnp.broadcast_to(jnp.sum(q * k, -1, keepdims=True), (nk, dv))
+    # the gates, a value a value head: rows of nv lanes, then columns
+    beta = jnp.where(steps, jax.nn.sigmoid(ba[:, :nv]), 0.0)
+    g = jnp.where(steps, -jnp.exp(a_log_ref[...]) * jax.nn.softplus(
+        ba[:, nv:] + dt_ref[...]), 0.0)
+    decay = jnp.where(fresh, 0.0, jnp.exp(g))
+    rows_scr[0] = _column(beta) * v
+    rows_scr[1] = jnp.broadcast_to(_column(beta * decay), (nv, dv))
+    rows_scr[2] = jnp.broadcast_to(_column(decay), (nv, dv))
     for kh in range(nk):
-        k_col = kq_ref[0, :, kh:kh + 1]                        # (dk, 1)
-        q_col = kq_ref[0, :, nk + kh:nk + kh + 1]
+        k_col = kq_scr[:, kh:kh + 1]                           # (dk, 1)
+        q_col = kq_scr[:, nk + kh:nk + kh + 1]
+        e = e_scr[kh:kh + 1, :]                                # (1, dv)
         for h in range(kh * per, (kh + 1) * per):
-            a, c, d, e = (rows_ref[0, i, h:h + 1, :] for i in range(4))  # (1, dv)
+            a, c, d = (rows_scr[j, h:h + 1, :] for j in range(3))
             # zeros chosen on the state itself: a reused slot may hold anything
             S = jnp.where(d != 0.0, state_ref[0, h], 0.0)      # (dk, dv)
             kS = jnp.sum(S * k_col, axis=0, keepdims=True)     # (1, dv)
@@ -220,54 +317,101 @@ def _delta_step_kernel(kq_ref, rows_ref, state_ref, new_ref, o_ref, *,
             u = a - c * kS
             new_ref[0, h] = d * S + k_col * u
             # o = S_t^T q without reading S_t back
-            o_ref[0, h:h + 1, :] = d * qS + e * u
+            o_scr[h:h + 1, :] = d * qS + e * u
+    # each head's read-out RMS-normed, then the gate
+    o = o_scr[...]
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + eps)
+    z = tok[:, C:].reshape(nv, dv)
+    y_ref[0] = (o * norm_ref[...].astype(F32) * (z * jax.nn.sigmoid(z))
+                ).astype(y_ref.dtype)
 
 
-def delta_step(q, k, v, g, beta, state, fresh, interpret: bool):
-    """The recurrence's single step for every row, a Pallas kernel that holds
-    a slot's state in VMEM: read once, used twice (the read-outs for the key
-    and for the query), written once. As plain ``jax.numpy`` the chip's
-    compiler makes two passes over the state, one for the two read-outs and
-    one for the update, which depends on the first (PERF.md, PR 71).
+def delta_step(proj, ba, first, ctx_len, new_len, state, tail, conv_weight,
+               a_log, dt_bias, norm_weight, eps: float, interpret: bool):
+    """The single step of every row that brings ONE token, from ``in_proj``'s
+    output to ``out_proj``'s input, a Pallas kernel that holds a slot's state
+    in VMEM: read once, used twice (the read-outs for the key and for the
+    query), written once over itself. Around the step it does, in float32 and
+    in VMEM, what the step's operands and its read-out need: the depthwise
+    conv over the tail and its ``silu``, q and k normalised, ``beta``, ``g``
+    and the decay, ``q . k``, then the gated RMS norm a head. As plain
+    ``jax.numpy`` the chip's compiler makes two passes over the state (PERF.md,
+    PR 71) and ~45 operations a layer of the rest, each over every slot's row
+    (PR 76).
 
-    ``q`` and ``k`` (r, nk, dk) normalised, ``v`` (r, nv, dv), ``g`` and
-    ``beta`` (r, nv) (both 0: the row keeps its state), ``state`` (r, nv, dk,
-    dv) float32, ``fresh`` (r,) bool: rows that start from zeros. Returns ``(o
-    (r, nv, dv), state)``."""
+    ``proj`` (T, 2 nk dk + 2 nv dv) = ``[q | k | v | z]`` and ``ba`` (T, 2
+    nv) = ``[b | a]``, the tick's places in the model's dtype; ``first``
+    (slots,) int32 the place of each row's first token, ascending (scalar
+    prefetch: the index map picks the block of places that holds it);
+    ``ctx_len`` and ``new_len`` (slots,) int32; ``state`` (slots, nv, dk, dv)
+    float32; ``tail`` (slots, K - 1, C / L, L), a ``conv_line``;
+    ``conv_weight`` (C, K); ``a_log``, ``dt_bias`` (nv,); ``norm_weight``
+    (dv,). A row steps where ``new_len ==
+    1`` (from zeros where ``ctx_len == 0``); any other row keeps its state and
+    its tail bit for bit and its output is not to be read. Returns ``(y
+    (slots, nv dv) in ``proj``'s dtype, state, tail)``."""
     _paged._ensure_pallas()
     pl, pltpu = _paged.pl, _paged.pltpu
     count_kernel_build(KERNEL_NAME, interpret)
-    r, nk, dk = q.shape
-    nv, dv = v.shape[1:]
-    per = nv // nk
-    decay = jnp.where(fresh[:, None], 0.0, jnp.exp(g))
+    r, nv, dk, dv = state.shape
+    T, width = proj.shape
+    taps, tiles, lanes = tail.shape[1:]
+    C = tiles * lanes
+    nk = (C - nv * dv) // (2 * dk)
+    assert width == C + nv * dv and C == 2 * nk * dk + nv * dv, (
+        proj.shape, tail.shape, state.shape)
+    # places a block: a packed tile of the model's dtype, or the whole tick
+    # (Mosaic takes no block of ONE row of a 2-D operand, and no dynamic row
+    # of a packed dtype: the kernel selects the row among the block's)
+    tb = min(T, 16)
 
-    def lanes(x):  # a value a head as a row of lanes
-        return jnp.broadcast_to(x[:, :, None], (r, nv, dv))
+    def place(i, first, *_):    # the block that holds the row's first place
+        return first[i] // tb, 0
 
-    rows = jnp.stack([beta[:, :, None] * v, lanes(beta * decay), lanes(decay),
-                      lanes(jnp.repeat(jnp.sum(q * k, -1), per, axis=1))], axis=1)
-    kq = jnp.swapaxes(jnp.concatenate([k, q], axis=1), 1, 2)   # (r, dk, 2 nk)
-    block = 2 * nv * dk * dv * 4                               # state in and out
-    new_state, o = pl.pallas_call(
-        functools.partial(_delta_step_kernel, nk=nk, per=per),
-        grid=(r,),
-        in_specs=[pl.BlockSpec((1, dk, 2 * nk), lambda i: (i, 0, 0)),
-                  pl.BlockSpec((1, 4, nv, dv), lambda i: (i, 0, 0, 0)),
-                  pl.BlockSpec((1, nv, dk, dv), lambda i: (i, 0, 0, 0))],
-        out_specs=[pl.BlockSpec((1, nv, dk, dv), lambda i: (i, 0, 0, 0)),
-                   pl.BlockSpec((1, nv, dv), lambda i: (i, 0, 0))],
+    def slot(rank):             # the slot's own block of an operand
+        return lambda i, *_: (i,) + (0,) * (rank - 1)
+
+    def whole(rank):            # the same block every step: brought in once
+        return lambda i, *_: (0,) * rank
+
+    block = 2 * nv * dk * dv * 4                                # state in and out
+    beside = 2 * (tb * (width + 2 * nv) + 2 * taps * C) * proj.dtype.itemsize
+    new_state, new_tail, y = pl.pallas_call(
+        functools.partial(_delta_step_kernel, nk=nk, dk=dk, eps=eps),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(r,),
+            in_specs=[pl.BlockSpec((tb, width), place),
+                      pl.BlockSpec((tb, 2 * nv), place),
+                      pl.BlockSpec((1, taps, tiles, lanes), slot(4)),
+                      pl.BlockSpec((taps + 1, tiles, lanes), whole(3)),
+                      pl.BlockSpec((1, nv), whole(2)),
+                      pl.BlockSpec((1, nv), whole(2)),
+                      pl.BlockSpec((1, dv), whole(2)),
+                      pl.BlockSpec((1, nv, dk, dv), slot(4))],
+            out_specs=[pl.BlockSpec((1, nv, dk, dv), slot(4)),
+                       pl.BlockSpec((1, taps, tiles, lanes), slot(4)),
+                       pl.BlockSpec((1, nv, dv), slot(3))],
+            scratch_shapes=[pltpu.VMEM((3, nv, dv), F32),
+                            pltpu.VMEM((nk, dv), F32),
+                            pltpu.VMEM((dk, 2 * nk), F32),
+                            pltpu.VMEM((nv, dv), F32)]),
         out_shape=[jax.ShapeDtypeStruct(state.shape, F32),
-                   jax.ShapeDtypeStruct((r, nv, dv), F32)],
-        input_output_aliases={2: 0},
+                   jax.ShapeDtypeStruct(tail.shape, tail.dtype),
+                   jax.ShapeDtypeStruct((r, nv, dv), proj.dtype)],
+        # operands counted with the three prefetched: state and tail in place
+        input_output_aliases={10: 0, 5: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
-            # both blocks of the state double-buffered, and room beside them
-            vmem_limit_bytes=max(2 * block + (8 << 20), 16 << 20)),
+            # both blocks of the state double-buffered, the small blocks
+            # beside them, and room
+            vmem_limit_bytes=max(2 * block + beside + (8 << 20), 16 << 20)),
         interpret=interpret,
         name=KERNEL_NAME,  # the trace's and the HLO's name for it
-    )(kq, rows, state)
-    return o, new_state
+    )(first, ctx_len, new_len, proj, ba, tail,
+      conv_weight.T.astype(F32).reshape(taps + 1, tiles, lanes),
+      a_log.reshape(1, nv), dt_bias.reshape(1, nv), norm_weight.reshape(1, dv),
+      state)
+    return y.reshape(r, nv * dv), new_state, new_tail
 
 
 class GatedDeltaMixer(BaseLayer):
@@ -371,13 +515,13 @@ class GatedDeltaMixer(BaseLayer):
             g = jnp.where(real[..., None], g, 0.0)
         return q, k, v, g, beta
 
-    def _gated_out(self, params, o, z):
-        """Each head's ``o`` RMS-normed, times ``silu(z)``, projected out.
-        ``o`` float32 (.., nv, dv), ``z`` the model's dtype (.., nv dv)."""
+    def _gated(self, params, o, z):
+        """Each head's ``o`` RMS-normed, times ``silu(z)``: ``out_proj``'s
+        input. ``o`` float32 (.., nv, dv), ``z`` the model's dtype (.., nv
+        dv)."""
         o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + self.norm_eps)
         o = o * params["norm"]["weight"].astype(F32)
-        y = o.reshape(z.shape) * jax.nn.silu(z.astype(F32))
-        return y.astype(z.dtype) @ params["out_proj"]["weight"].astype(z.dtype)
+        return (o.reshape(z.shape) * jax.nn.silu(z.astype(F32))).astype(z.dtype)
 
     def __call__(self, params: dict, x: jax.Array, ctx: ForwardContext,
                  state: Optional[DeltaStateView] = None,
@@ -389,13 +533,12 @@ class GatedDeltaMixer(BaseLayer):
         with jax.named_scope("delta"):
             proj = x @ params["in_proj"]["weight"].astype(x.dtype)
             ba = x @ params["ba_proj"]["weight"].astype(x.dtype)
-            qkv, z = proj[..., :self.conv_dim], proj[..., self.conv_dim:]
             if state is not None:
-                o, new_view = self._serve(params, qkv, ba, state)
-                return self._gated_out(params, o, z), new_view
-            o, lines = self._whole(params, qkv, ba)
-            out = self._gated_out(params, o, z)
-            return (out, lines) if return_state else out
+                y, lines = self._serve(params, proj, ba, state)
+            else:
+                y, lines = self._whole(params, proj, ba)
+            out = y @ params["out_proj"]["weight"].astype(x.dtype)
+            return (out, lines) if state is not None or return_state else out
 
     def _advance(self, q, k, v, g, beta, S0, fresh):
         """``(r, w)`` whole rows from their states: one chunk, or ``CHUNK``
@@ -421,40 +564,46 @@ class GatedDeltaMixer(BaseLayer):
             o = jnp.moveaxis(o, 0, 1).reshape(r, w + pad, *o.shape[3:])
             return o[:, :w], S
 
-    def _whole(self, params, qkv, ba):
-        """Every sequence of a ``(b, s)`` batch from a zero state."""
-        b, s, _ = qkv.shape
+    def _whole(self, params, proj, ba):
+        """Every sequence of a ``(b, s)`` batch from a zero state: ``(y (b, s,
+        nv dv), (state, conv))``."""
+        b, s, _ = proj.shape
         K = self.conv_kernel
-        window = jnp.pad(qkv, ((0, 0), (K - 1, 0), (0, 0)))
+        window = jnp.pad(proj[..., :self.conv_dim], ((0, 0), (K - 1, 0), (0, 0)))
         operands = self._delta_inputs(params, self._conv(params, window), ba, None)
         S0 = jnp.zeros((b, self.nv, self.dk, self.dv), F32)
         o, S = self._advance(*operands, S0, None)
-        tail = jnp.swapaxes(window[:, s:], 1, 2)            # (b, conv_dim, K-1)
-        return o, (S, tail)
+        y = self._gated(params, o, proj[..., self.conv_dim:])
+        return y, (S, conv_line(window[:, s:]))
 
-    def _serve(self, params, qkv, ba, view: DeltaStateView):
+    def _serve(self, params, proj, ba, view: DeltaStateView):
         """The tick's batch ``(g, s)`` against the slots' lines: whole rows
         where the batch has a place for every row's widest chunk, else each
-        row in the form its ``new_len`` asks for. Returns ``(o (g, s, nv,
-        dv), the view advanced)``."""
-        g, s = qkv.shape[:2]
+        row in the form its ``new_len`` asks for. Returns ``(y (g, s, nv dv),
+        the view advanced)``."""
+        g, s = proj.shape[:2]
         lines = (view.state, view.conv, view.context_len.astype(jnp.int32),
                  view.new_len.astype(jnp.int32))
+        C = self.conv_dim
         tmap = view.token_map
         if tmap is None:  # row-major: position (r, j) is row r's j-th token
-            o, S, tail = self._chunk_rows(params, qkv, ba, *lines)
+            o, S, tail = self._chunk_rows(params, proj[..., :C], ba, *lines)
+            y = self._gated(params, o, proj[..., C:])
         else:
             rows, w = tmap.row_tokens.shape
-            qkv, ba = qkv.reshape(g * s, -1), ba.reshape(g * s, -1)
+            proj, ba = proj.reshape(g * s, -1), ba.reshape(g * s, -1)
             if g * s < rows * w:
-                o, S, tail = self._split_rows(params, qkv, ba, *lines, tmap)
+                y, S, tail = self._split_rows(params, proj, ba, *lines, tmap)
             else:
                 flat = tmap.row_tokens
-                o, S, tail = self._chunk_rows(params, qkv[flat], ba[flat], *lines)
+                o, S, tail = self._chunk_rows(
+                    params, proj[:, :C][flat], ba[flat], *lines)
                 # back to the batch's token order
-                o = o[tmap.row, jnp.minimum(tmap.offset, w - 1)]
-            o = o.reshape(g, s, self.nv, self.dv)
-        return o, view._replace(state=S.astype(view.state.dtype),
+                o = o[tmap.row.reshape(-1),
+                      jnp.minimum(tmap.offset.reshape(-1), w - 1)]
+                y = self._gated(params, o, proj[:, C:])
+            y = y.reshape(g, s, self.value_dim)
+        return y, view._replace(state=S.astype(view.state.dtype),
                                 conv=tail.astype(view.conv.dtype))
 
     def _chunk_rows(self, params, qkv, ba, state, conv, ctx_len, new_len):
@@ -466,9 +615,8 @@ class GatedDeltaMixer(BaseLayer):
         real = jnp.arange(w, dtype=jnp.int32)[None, :] < new_len[:, None]
         # a row at context 0 starts from zeros, whatever its slot held
         fresh = (ctx_len == 0) & (new_len > 0)
-        tail = jnp.where(fresh[:, None, None], 0, conv)
-        window = jnp.concatenate(
-            [jnp.swapaxes(tail, 1, 2).astype(qkv.dtype), qkv], axis=1)
+        tail = jnp.where(fresh[:, None, None], 0, conv.reshape(r, K - 1, -1))
+        window = jnp.concatenate([tail.astype(qkv.dtype), qkv], axis=1)
         operands = self._delta_inputs(
             params, self._conv(params, window), ba, real)
         o, S = self._advance(*operands, state.astype(F32), fresh)
@@ -476,37 +624,25 @@ class GatedDeltaMixer(BaseLayer):
         # window's places new_len .. new_len + K - 2 (new_len 0: the old tail)
         last = new_len[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None, :]
         new_tail = jnp.take_along_axis(window, last[:, :, None], axis=1)
-        return o, S, jnp.swapaxes(new_tail, 1, 2)
+        return o, S, conv_line(new_tail)
 
-    def _step_rows(self, params, qkv, ba, state, conv, ctx_len, new_len):
-        """The recurrence's single step for the rows that bring ONE token,
-        ``qkv`` (r, conv_dim) and ``ba`` (r, 2 nv) each row's first place of
-        the tick. Every other row carries ``beta = g = 0`` and keeps its
-        lines. Returns ``(o (r, nv, dv), state, conv)``."""
-        steps = new_len == 1
-        fresh = steps & (ctx_len == 0)
-        tail = jnp.where(fresh[:, None, None], 0, conv).astype(qkv.dtype)
-        window = jnp.concatenate([tail, qkv[:, :, None]], axis=2)  # (r, c, K)
-        conved = self._conv(params, jnp.swapaxes(window, 1, 2))[:, 0]
-        q, k, v, g, beta = self._delta_inputs(params, conved, ba, steps)
-        new_tail = jnp.where(steps[:, None, None], window[:, :, 1:], tail)
-        with jax.named_scope("delta_rule"):
-            o, S = delta_step(q, k, v, g, beta, state.astype(F32), fresh,
-                              _paged.paged_kernel_interpret())
-        return o, S, new_tail
-
-    def _split_rows(self, params, qkv, ba, state, conv, ctx_len, new_len, tmap):
+    def _split_rows(self, params, proj, ba, state, conv, ctx_len, new_len, tmap):
         """A token-major batch ``(T, ..)`` narrower than ``rows x w``: rows
-        that bring one token step where they lie; the at most ``T // w`` that
-        bring more (the caller sees to that: ``split_capacity``) are gathered,
+        that bring one token step where they lie, ``delta_step`` taking each
+        from ``proj`` to its gated output; the at most ``T // w`` that bring
+        more (the caller sees to that: ``split_capacity``) are gathered,
         advanced as whole rows and written back over their lines, as
-        ``Mamba2Mixer._split_rows`` does. Returns ``(o (T, nv, dv), state,
+        ``Mamba2Mixer._split_rows`` does. Returns ``(y (T, nv dv), state,
         conv)``."""
         rows, w = tmap.row_tokens.shape
-        R = split_capacity(qkv.shape[0], w)
-        first = tmap.row_tokens[:, 0]
-        o_step, S, tail = self._step_rows(
-            params, qkv[first], ba[first], state, conv, ctx_len, new_len)
+        R = split_capacity(proj.shape[0], w)
+        with jax.named_scope("delta_rule"):
+            y_step, S, tail = delta_step(
+                proj, ba, tmap.row_tokens[:, 0], ctx_len, new_len,
+                state.astype(F32), conv.astype(proj.dtype),
+                params["conv"]["weight"], params["A_log"], params["dt_bias"],
+                params["norm"]["weight"], self.norm_eps,
+                _paged.paged_kernel_interpret())
         multi = new_len > 1
         # the multi-token rows in slot order, then `rows`: past the pool, so
         # that nothing of a place no row fills is written back. A chunk row
@@ -520,12 +656,17 @@ class GatedDeltaMixer(BaseLayer):
             jax.lax.dynamic_index_in_dim(S, row, 0, keepdims=False)
             for row in held])
         o_chunk, S_chunk, tail_chunk = self._chunk_rows(
-            params, qkv[flat], ba[flat], S_held, tail[held], ctx_len[held],
-            jnp.where(at < rows, new_len[held], 0))
+            params, proj[:, :self.conv_dim][flat], ba[flat], S_held, tail[held],
+            ctx_len[held], jnp.where(at < rows, new_len[held], 0))
         S = S.at[at].set(S_chunk, mode="drop")
         tail = tail.at[at].set(tail_chunk.astype(tail.dtype), mode="drop")
-        # a token reads its row's step, or its place in its row's chunk
-        place = jnp.cumsum(multi)[tmap.row] - 1
-        place = jnp.clip(place, 0, R - 1) * w + jnp.minimum(tmap.offset, w - 1)
-        o = jnp.concatenate([o_step, o_chunk.reshape(R * w, self.nv, self.dv)])
-        return o[jnp.where(multi[tmap.row], rows + place, tmap.row)], S, tail
+        # a token of a chunk row reads its place in its row's chunk and is
+        # gated where it lies (its z needs no gather); any other reads its
+        # row's step, gated by the kernel
+        row, offset = tmap.row.reshape(-1), tmap.offset.reshape(-1)
+        place = jnp.clip(jnp.cumsum(multi)[row] - 1, 0, R - 1)
+        place = place * w + jnp.minimum(offset, w - 1)
+        y_chunk = self._gated(
+            params, o_chunk.reshape(R * w, self.nv, self.dv)[place],
+            proj[:, self.conv_dim:])
+        return jnp.where(multi[row][:, None], y_chunk, y_step[row]), S, tail

@@ -1026,14 +1026,23 @@ def test_a_one_token_row_reads_its_delta_state_once_at_the_cells_size(
     size (256 slots x 32 value heads x 128 x 128 float32: 537 MB a layer), a
     token-major tick of 768 places for rows of up to 32: the rows that bring
     one token advance in ONE kernel (``delta_step``, compiled by Mosaic) that
-    takes the donated state and gives the new state, written over the old one,
-    and the read-out; the at most 24 rows that bring a chunk are sliced out
-    line by line and scattered back in place. The donated state has no other
-    reader and is never copied. As plain ``jax.numpy`` the step was TWO
-    fusions over the state, the read-outs' and the update's (PERF.md, PR 71)."""
+    takes the donated state and gives the new state, written over the old one;
+    the at most 24 rows that bring a chunk are sliced out line by line and
+    scattered back in place. The donated state has no other reader and is
+    never copied. As plain ``jax.numpy`` the step was TWO fusions over the
+    state, the read-outs' and the update's (PERF.md, PR 71).
+
+    Since PR 76 the kernel takes a one-token row from ``in_proj``'s output to
+    ``out_proj``'s input: the conv leaf (a tap a plane of whole tiles:
+    ``gated_delta.conv_line``) reaches it by data movement alone and nothing
+    else reads it, none of the operands the step once had built in HBM (the
+    float32 window, the lane-broadcast rows, the float32 read-outs joined
+    over all places) exists, and of the entry's operations few are shaped by
+    the 256 rows (46 by this count on the parent, PR 75)."""
     from scaling_tpu.nn.attention import packed_token_map
     from scaling_tpu.nn.base_layer import ForwardContext
-    from scaling_tpu.nn.gated_delta import DeltaStateView, GatedDeltaMixer
+    from scaling_tpu.nn.gated_delta import (
+        DeltaStateView, GatedDeltaMixer, conv_line)
 
     monkeypatch.setattr(
         "scaling_tpu.nn.paged_attention.paged_kernel_interpret",
@@ -1052,21 +1061,50 @@ def test_a_one_token_row_reads_its_delta_state_once_at_the_cells_size(
 
     params = jax.tree.map(lambda x: shape(x.shape, x.dtype),
                           jax.eval_shape(layer.init, jax.random.PRNGKey(0)))
+    line = jax.eval_shape(conv_line, shape((slots, 3, layer.conv_dim), jnp.bfloat16))
+    assert line.shape == (slots, 3, 64, 128)
     text = jax.jit(tick, donate_argnums=(2, 3)).lower(
         params, shape((places // w, w, hidden), jnp.bfloat16),
         shape((slots, 32, 128, 128), jnp.float32),
-        shape((slots, layer.conv_dim, 3), jnp.bfloat16),
+        shape(line.shape, jnp.bfloat16),
         shape((slots,), jnp.int32), shape((slots,), jnp.int32),
     ).compile().as_text()
     entry = text[text.index("\nENTRY "):]
+    ops = [op for op in entry.splitlines()
+           if " = " in op and " parameter(" not in op]
     state = r"f32\[256,32,128,128\]"
-    readers = [op for op in entry.splitlines()
-               if re.search(r"\(.*%state", op) and " parameter(" not in op]
+    readers = [op for op in ops if re.search(r"\(.*%state", op)]
     assert len(readers) == 1 and "tpu_custom_call" in readers[0], readers
     assert re.match(rf"\s*%delta_step\S* = \({state}", readers[0])
     assert not whole_copies(text, state)
     assert "delta/scatter" in entry and "f32[24,32,128,128]" in entry
     assert not re.search(rf"{state}[^=]* while\(", entry)
+    # the conv leaf: the compiler may stage it on its way (asynchronous
+    # slices or a copy into the chip's fast memory), the kernel computes on it
+    assert re.search(r"%delta_step\S* = \(" + state + r"\S*, bf16\[256,3,64,128\]",
+                     readers[0])
+    (leaf,) = re.findall(r"%(conv\.?\d*) = \S+ parameter\(", entry)
+    frontier, moved, computing = [leaf], set(), set()
+    while frontier:
+        name = frontier.pop()
+        if name in moved:
+            continue
+        moved.add(name)
+        for op in ops:
+            if not re.search(rf"%{re.escape(name)}[,)]", op.split(" = ", 1)[1]):
+                continue
+            if re.search(r" (slice|copy)-(start|done)\(", op) or "ConcatBitcast" in op:
+                frontier.append(re.match(r"\s*%(\S+) = ", op).group(1))
+            else:
+                computing.add(op)
+    assert len(computing) == 1 and "tpu_custom_call" in min(computing), computing
+    for gone in ("f32[256,4,8192]", "f32[256,4,32,128]", "f32[1024,32,128]",
+                 "[256,8192,3]", "[256,3,8192]"):
+        assert gone not in entry, gone
+    by_rows = [op for op in ops if re.match(r"\s*(ROOT )?%\S+ = \(?\w+\[256,", op)
+               and not re.search(r" (get-tuple-element|bitcast|tuple|\S+-start)\(", op)
+               and "tpu_custom_call" not in op]
+    assert len(by_rows) < 15, by_rows
 
 
 ROUTED_CELLS = {

@@ -96,33 +96,94 @@ def test_the_step_equals_the_recurrence_as_a_python_loop():
         np.testing.assert_allclose(np.asarray(S[r]), want_S, atol=ATOL, rtol=1e-5)
 
 
-@pytest.mark.parametrize("rows", [1, 3], ids=["the smallest grid", "three slots"])
-def test_the_step_kernel_interpreted_equals_the_python_loop(rows):
-    """``delta_step`` (a Pallas kernel; interpreted here, its own arithmetic):
-    a slot a grid step, the state read once and written over itself. A row
-    with ``beta = g = 0`` keeps its state bit for bit; a fresh row starts
-    from zeros though its state holds NaNs."""
+def step_reference(params, proj, ba, tail, S0, eps=1e-6):
+    """One row from ``in_proj``'s output to ``out_proj``'s input as it is
+    written, numpy float64: ``proj`` (W,), ``ba`` (2 nv,), ``tail`` (K - 1,
+    C), ``S0`` (nv, dk, dv). Returns ``(y (nv dv,), state, tail)``."""
+    f64 = lambda a: np.asarray(jnp.asarray(a, jnp.float32), np.float64)  # noqa: E731
+    proj, ba, tail, S0 = f64(proj), f64(ba), f64(tail), f64(S0)
+    silu = lambda a: a / (1.0 + np.exp(-a))                       # noqa: E731
+    window = np.concatenate([tail, proj[None, :CONV]])
+    c = silu(np.sum(window * f64(params["conv"]["weight"]).T, axis=0))
+    q, k, v = (c[:NK * DK].reshape(NK, DK), c[NK * DK:2 * NK * DK].reshape(NK, DK),
+               c[2 * NK * DK:].reshape(NV, DV))
+    q = q / np.sqrt(np.sum(q * q, -1, keepdims=True) + 1e-6) * DK ** -0.5
+    k = k / np.sqrt(np.sum(k * k, -1, keepdims=True) + 1e-6)
+    beta = 1.0 / (1.0 + np.exp(-ba[:NV]))
+    g = -np.exp(f64(params["A_log"])) * np.logaddexp(
+        0.0, ba[NV:] + f64(params["dt_bias"]))
+    o, S = loop(q[None], k[None], v[None], g[None], beta[None], S0)
+    o = o[0] / np.sqrt(np.mean(o[0] * o[0], -1, keepdims=True) + eps)
+    y = o * f64(params["norm"]["weight"]) * silu(proj[CONV:].reshape(NV, DV))
+    return y.reshape(-1), S, window[1:]
+
+
+# (first place, context, new tokens) a slot; the model's dtype
+STEP_CASES = {
+    "the smallest grid": ([(0, 4, 1)], jnp.float32),
+    # a row's token lies in whichever block of 16 places its index falls
+    "three slots over two blocks of places": (
+        [(3, 9, 1), (17, 2, 1), (30, 5, 1)], jnp.float32),
+    "a fresh row over a dirty slot": ([(0, 7, 1), (1, 0, 1)], jnp.float32),
+    "rows of 0 and of 5 tokens beside a stepping row": (
+        [(0, 3, 1), (1, 8, 5), (6, 4, 0), (6, 0, 0), (6, 2, 1)], jnp.float32),
+    "bfloat16 in and out, float32 inside": (
+        [(2, 6, 1), (3, 0, 1), (20, 11, 1)], jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", list(STEP_CASES))
+def test_the_step_kernel_interpreted_equals_the_python_loop(mixer, case):
+    """``delta_step`` (a Pallas kernel; interpreted here, its own arithmetic)
+    from ``proj`` / ``ba`` / the slot's conv tail to the gated output, the new
+    state and the new tail: a slot a grid step, the row's token found among
+    the tick's places through the prefetched index, the state read once and
+    written over itself. A row that brings no single token keeps state AND
+    tail bit for bit; a fresh row starts from zeros though its lines hold
+    NaNs; with bfloat16 operands the state is still the float32 loop's."""
+    from scaling_tpu.nn.gated_delta import conv_line
     from scaling_tpu.obs import kernel_build_count
 
-    q, k, v, g, beta, S0 = operands(jax.random.PRNGKey(17), rows, 1)
-    fresh = jnp.zeros((rows,), bool)
+    layer, params = mixer
+    rows, dtype = STEP_CASES[case]
+    first, ctx_len, new_len = (jnp.asarray(col, jnp.int32) for col in zip(*rows))
+    r, places = len(rows), 32
+    params = jax.tree.map(
+        lambda x: x if x.shape == (NV,) else x.astype(dtype), params)
+    ks = jax.random.split(jax.random.PRNGKey(len(case)), 4)
+    proj = jax.random.normal(ks[0], (places, layer.in_width)).astype(dtype)
+    ba = jax.random.normal(ks[1], (places, 2 * NV)).astype(dtype)
+    S0 = jax.random.normal(ks[2], (r, NV, DK, DV))
+    tail = jax.random.normal(ks[3], (r, K - 1, CONV)).astype(dtype)
+    fresh = np.asarray((ctx_len == 0) & (new_len == 1))
+    dirty = jnp.asarray(fresh)[:, None, None]
+    S0 = jnp.where(dirty[..., None], jnp.nan, S0)
+    tail = jnp.where(dirty, jnp.nan, tail)
     before = kernel_build_count("delta_step", interpret=True)
-    o, S = jax.jit(lambda *a: delta_step(*a, interpret=True))(
-        q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], S0, fresh)
+    y, S, new_tail = jax.jit(lambda *a: delta_step(
+        *a, params["conv"]["weight"], params["A_log"], params["dt_bias"],
+        params["norm"]["weight"], layer.norm_eps, interpret=True))(
+        proj, ba, first, ctx_len, new_len, S0, conv_line(tail))
     assert kernel_build_count("delta_step", interpret=True) == before + 1
-    for r in range(rows):
-        want_o, want_S = loop(q[r], k[r], v[r], g[r], beta[r], S0[r])
-        np.testing.assert_allclose(np.asarray(o[r]), want_o[0], atol=ATOL, rtol=1e-5)
-        np.testing.assert_allclose(np.asarray(S[r]), want_S, atol=ATOL, rtol=1e-5)
-    zeros = jnp.zeros_like(g[:, 0])
-    _, kept = delta_step(q[:, 0], k[:, 0], v[:, 0], zeros, zeros, S0, fresh,
-                         interpret=True)
-    assert np.array_equal(np.asarray(kept), np.asarray(S0))          # bit for bit
-    o, S = delta_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
-                      jnp.full_like(S0, jnp.nan), ~fresh, interpret=True)
-    want_o, want_S = loop(q[0], k[0], v[0], g[0], beta[0], np.zeros_like(S0[0]))
-    np.testing.assert_allclose(np.asarray(o[0]), want_o[0], atol=ATOL, rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(S[0]), want_S, atol=ATOL, rtol=1e-5)
+    assert y.dtype == dtype and new_tail.dtype == dtype and S.dtype == jnp.float32
+    assert new_tail.shape == (r, K - 1, 1, CONV)
+    y, new_tail = np.asarray(y, np.float32), np.asarray(new_tail[:, :, 0], np.float32)
+    # a bfloat16 result is the float32 one rounded once
+    out_tol = dict(atol=ATOL, rtol=1e-5) if dtype == jnp.float32 else dict(
+        atol=2e-2, rtol=2 ** -7)
+    for row, (place, _, n) in enumerate(rows):
+        if n != 1:   # bit for bit what the slot held
+            assert np.array_equal(np.asarray(S[row]), np.asarray(S0[row]))
+            assert np.array_equal(new_tail[row], np.asarray(tail[row], np.float32))
+            continue
+        zero = fresh[row]
+        want_y, want_S, want_tail = step_reference(
+            params, proj[place], ba[place],
+            jnp.zeros_like(tail[row]) if zero else tail[row],
+            jnp.zeros_like(S0[row]) if zero else S0[row], layer.norm_eps)
+        np.testing.assert_allclose(y[row], want_y, **out_tol)
+        np.testing.assert_allclose(np.asarray(S[row]), want_S, atol=ATOL, rtol=1e-5)
+        assert np.array_equal(new_tail[row], want_tail.astype(np.float32))
 
 
 @pytest.mark.parametrize("w", [1, 2, 31, 32, 33])
@@ -207,7 +268,7 @@ def test_a_sequence_longer_than_a_chunk_is_walked_chunk_by_chunk(mixer, monkeypa
         np.testing.assert_allclose(out, outs[0][0], atol=ATOL, rtol=1e-5)
         np.testing.assert_allclose(S, outs[0][1], atol=ATOL, rtol=1e-5)
         assert np.array_equal(tail, outs[0][2])
-    assert outs[0][2].shape == (2, CONV, K - 1)
+    assert outs[0][2].shape == (2, K - 1, 1, CONV)
 
 
 def run_tick(layer, params, x_rows, lines, ctx_len, new_len, w, width=None):
@@ -249,7 +310,7 @@ def test_state_carried_across_ticks_equals_one_pass(mixer, token_major):
     want = np.asarray(jax.jit(lambda x: layer(params, x, ForwardContext()))(x))
     slots, w = 3, 32
     lines = (jnp.full((slots, NV, DK, DV), 7.0),           # an old occupant's
-             jnp.full((slots, CONV, K - 1), 7.0))
+             jnp.full((slots, K - 1, 1, CONV), 7.0))
     got = {0: [], 2: []}
     seen = [0, 0]
     for n0, n2 in ((32, 0), (5, 32), (1, 1), (1, 1), (1, 6)):
@@ -311,7 +372,7 @@ def test_each_row_in_its_own_form_equals_the_whole_rows_form(mixer, case, refere
     starts_over = [r for r in range(slots) if ctx_len[r] == 0 and new_len[r] > 0]
     lines = tuple(
         jax.random.normal(k, shape).at[jnp.asarray(starts_over, int)].set(jnp.nan)
-        for k, shape in ((ks[0], (slots, NV, DK, DV)), (ks[1], (slots, CONV, K - 1))))
+        for k, shape in ((ks[0], (slots, NV, DK, DV)), (ks[1], (slots, K - 1, 1, CONV))))
     x = jax.random.normal(ks[2], (slots, w, H))
     x_rows = [x[r, :n] for r, n in enumerate(new_len)]
     got = run_tick(layer, params, x_rows, lines, ctx_len, new_len, w, width)

@@ -152,11 +152,11 @@ def test_chunks_then_decode_are_the_references_full_forward(
     np.testing.assert_allclose(got, wanted, atol=LOGIT_ATOL)
     assert wanted.std() > 0.3    # the logits say something
     # the state: one pool (the attention layer, lines of 2 x 256), three
-    # float32 states and three conv tails a slot
+    # float32 states and three conv tails a slot, a tap a plane of 128 lanes
     assert len(state[0]) == 1 and state[0][0].shape[2:] == (2, 256)
     assert [a.shape for a in state[4]] == [(1, NV, DK, DV)] * DELTA_LAYERS
     assert [a.shape for a in state[5]] == [
-        (1, 2 * NK * DK + NV * DV, 3)] * DELTA_LAYERS
+        (1, 3, (2 * NK * DK + NV * DV) // 128, 128)] * DELTA_LAYERS
     assert all(a.dtype == jnp.float32 for a in state[4])
 
 
