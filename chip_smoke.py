@@ -60,7 +60,7 @@ TOY = dict(
 )
 BLOCK_SIZE = 16      # EngineConfig default
 PREFILL_CHUNK = 32   # EngineConfig default
-SPEC_ROWS = 5        # s = spec_k + 1 at the `--spec-k 4` of docs/SERVING.md
+TAIL_ROWS = 5        # the tokens a prompt's last chunk may be left with
 
 # bf16 keeps 8 significant bits: a value of magnitude m is rounded by up to
 # m * 2**-9. The splash kernel rounds the probabilities to bf16 before the
@@ -599,7 +599,7 @@ def phase_kernels(size, args, device) -> dict:
     table = 1 + np.arange(rows * max_blocks, dtype=np.int32).reshape(
         rows, max_blocks)
     rng.shuffle(table.reshape(-1))
-    for s in (1, PREFILL_CHUNK, SPEC_ROWS):
+    for s in (1, PREFILL_CHUNK, TAIL_ROWS):
         ctx = rng.integers(0, size["context"] - s, size=rows).astype(np.int32)
         ctx[0], ctx[1] = 0, size["context"] - s
         tab = table.copy()

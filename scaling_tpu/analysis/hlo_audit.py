@@ -466,17 +466,17 @@ def _count_pallas_custom_calls(text: str) -> int:
 
 def audit_serve_decode_section(num_slots=8, block_size=16,
                                max_blocks=4, prefill_chunk=32,
-                               spec_k=3, mp=1) -> dict:
+                               mp=1) -> dict:
     """The serving engine's MIXED program (serve/engine.py, ISSUE 11,
     token-major since ISSUE 33): ONE jitted step per tick covers the
-    whole slot set — decode rows (last token + up to ``spec_k``
-    speculative drafts) and prefill-chunk rows alike, their real tokens
+    whole slot set — one-token decode rows and prefill-chunk rows
+    alike, their real tokens
     packed back to back into one of the engine's (at most two) token
     widths, tagged purely by traced per-row lengths. Its recompile-key
     signature is the no-recompile-storm contract: the key bakes the
-    (chunk, draft-length) widths plus the engine shape config, and
-    NOTHING per-request — a scheduler change that moves prompt lengths,
-    prefill offsets, or draft contents into the signature shows up as
+    chunk width plus the engine shape config, and
+    NOTHING per-request — a scheduler change that moves prompt lengths
+    or prefill offsets into the signature shows up as
     golden drift here, not as a compile storm on the chip.
 
     The section is the program of the SMALL width, which nearly every
@@ -517,7 +517,7 @@ def audit_serve_decode_section(num_slots=8, block_size=16,
     engine = ServeEngine(inf, EngineConfig(
         num_slots=num_slots, block_size=block_size,
         num_blocks=2 * max_blocks + 1, max_blocks_per_seq=max_blocks,
-        token_budget=64, prefill_chunk=prefill_chunk, spec_k=spec_k,
+        token_budget=64, prefill_chunk=prefill_chunk,
     ))
     base_key = engine._dev(jax.random.PRNGKey(0))
     small, full = engine.config.mixed_widths
@@ -536,12 +536,7 @@ def audit_serve_decode_section(num_slots=8, block_size=16,
         "block_size": block_size, "max_blocks_per_seq": max_blocks,
         "kv_dtype": engine.config.kv_dtype,
         "prefill_chunk": prefill_chunk,
-        "spec_k": spec_k,
         "mixed_width": engine.config.mixed_width,
-        # positions gathered per row before the vocab projection — a
-        # change that silently re-projects every position shows up as
-        # golden drift, not a quiet FLOPs regression
-        "sample_width": engine.config.sample_width,
         # the token widths the engine builds programs at, and this one's
         "token_widths": [small, full],
         "token_width": small,

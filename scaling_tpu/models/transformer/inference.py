@@ -422,7 +422,7 @@ class TransformerInferenceModule:
         post-trunk layers — which are position-pointwise, so only the
         positions that will actually be SAMPLED pay the final norm and
         the vocab projection (the serving engine's mixed program samples
-        ≤ spec_k+1 positions of each row out of the tick's packed
+        ONE position of each row, its last, out of the tick's packed
         tokens; projecting all of them priced a logit block nobody
         read). The returned logits are then (rows, w, vocab), entry
         ``(r, j)`` that of position ``gather_index[r, j]``.

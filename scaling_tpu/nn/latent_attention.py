@@ -61,7 +61,7 @@ rank) ** 0.5`` (the rotary key is not scaled).
 Not built, refused by name where it is asked for (config validation,
 ``serve/kvcache.py``): int8 latent lines, model-parallel latent layers
 (a latent line has no head axis to shard: a deployment replicates the
-attention), speculative rows, training.
+attention), training.
 """
 
 from __future__ import annotations

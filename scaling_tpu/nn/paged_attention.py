@@ -77,7 +77,7 @@ Which layout a pool has the kernel reads off its shape
 (``kv_block_layout``); the pipeline, the waits and the fold are the same
 code for both. The probabilities meet V in the queries' dtype (bf16 when serving;
 ``l`` sums them in float32), as the splash kernel's do. Rows with at most
-``_SHORT_QUERIES`` real positions — decode rows, decode rows with drafts —
+``_SHORT_QUERIES`` real positions — decode rows, a prompt's short tail —
 run the loop over their first folded rows only, over the sub-tiles that
 hold a slot and all heads together; prefill chunks take the full width, the
 whole tile and a head at a time. Which path a row takes is read from
@@ -109,10 +109,10 @@ Masking follows the paged-decode contract exactly (``nn/attention.py``
 ``_paged_attention``): LOGICAL slot indices are the causal clock; slot
 ``k`` is visible to query slot ``q`` iff ``k < valid_len`` (written) and
 ``k <= q`` (causal). Queries may be a single decode token (s=1), a
-prefill CHUNK (s=chunk), or a decode token plus its speculative DRAFTS
-(s=k+1 — the engine's mixed program scores all k candidates in this one
-call; rejected candidates' writes are simply re-covered by the next
-call because ``valid_len`` never admits them) — K/V are scattered into
+prefill CHUNK (s=chunk), or the few tokens a prompt's last chunk is
+left with (1 < s <= ``_SHORT_QUERIES``: the short path at one of its
+static widths; a position ``valid_len`` does not admit is never
+visible, whoever wrote it). K/V are scattered into
 the pool by the caller before attending, and the same per-row
 ``valid_len``/``q_slot_base`` math serves every row kind, so one fused
 program covers a whole mixed tick (serve/engine.py ``_build_mixed_fn``).
@@ -175,7 +175,7 @@ _TILE_VMEM_BYTES = 8 << 20
 _VMEM_DEFAULT_BYTES = 12 << 20
 _VMEM_CEILING_BYTES = 100 << 20
 # query positions of the short-query path: a decode row has 1 real
-# position, a decode row with drafts spec_k + 1
+# position, the tail of a prompt's last chunk up to this many
 _SHORT_QUERIES = 8
 
 

@@ -60,7 +60,7 @@ one-token rows' ``paged_attention`` calls lie here). The scatter of the
 line's three leaves is one call and lies in neither.
 
 Not built, refused by name (here, config validation, ``serve/kvcache.py``,
-``serve/engine.py``): int8 lines, model-parallel layers, speculative rows,
+``serve/engine.py``): int8 lines, model-parallel layers,
 training, the prefix cache, a dense ``generate()`` cache, local-window heads,
 LoRA, score manipulation.
 """

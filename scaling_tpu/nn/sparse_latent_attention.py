@@ -75,7 +75,7 @@ choice, ``sparse_attend`` the gather of the chosen lines and the attention
 over them.
 
 Not built, refused by name (config validation, ``serve/kvcache.py``,
-``serve/engine.py``): int8 lines, model-parallel layers, speculative rows,
+``serve/engine.py``): int8 lines, model-parallel layers,
 training, the prefix cache (hits and copy-on-write over the third leaf have
 not been held to the reference).
 """

@@ -48,8 +48,8 @@ Scopes (inside the layer's ``window_attn``): ``window_attend`` holds the walk
 (the kernel's calls), ``gate`` the per-head gate. The scatter lies in neither.
 
 Not built, refused by name (config validation, ``serve/engine.py``): int8
-rings, model-parallel layers, speculative rows (a rejected draft would have
-overwritten ring lines), training, pipeline stages, context parallelism, the
+rings, model-parallel layers, rows that rewind (a ring line, once
+overwritten, is gone), training, pipeline stages, context parallelism, the
 prefix cache, a dense ``generate()`` cache, an indexer, LoRA.
 """
 

@@ -32,7 +32,7 @@ holds the walk (the kernel's calls), ``gate`` the head-wise gate. The scatter
 lies in neither.
 
 Not built, refused by name (config validation, ``serve/engine.py``): int8
-rings, model-parallel layers, speculative rows, training, pipeline stages,
+rings, model-parallel layers, training, pipeline stages,
 context parallelism, the prefix cache, a dense ``generate()`` cache, an indexer
 inside the window, GQA ``window`` layers in the same stack.
 """

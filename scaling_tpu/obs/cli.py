@@ -62,11 +62,6 @@ def main(argv=None) -> int:
     parser.add_argument("--assert-ttft", type=float, metavar="CEIL",
                         help="fail (exit 1) when serving p99 "
                         "time-to-first-token exceeds CEIL seconds")
-    parser.add_argument("--assert-spec-accept-rate", type=float,
-                        metavar="FLOOR",
-                        help="fail (exit 1) when the speculative-decoding "
-                        "accept rate is below FLOOR, or the run recorded "
-                        "no speculation telemetry (docs/SERVING.md)")
     parser.add_argument("--assert-max-resizes", type=int, metavar="CEIL",
                         help="fail (exit 1) when a supervised run resized "
                         "(downsize OR elastic upsize) more than CEIL "
@@ -125,7 +120,6 @@ def main(argv=None) -> int:
         tuner_stats=tuner_stats,
         assert_serve_throughput=args.assert_serve_throughput,
         assert_ttft=args.assert_ttft,
-        assert_spec_accept_rate=args.assert_spec_accept_rate,
         assert_max_downsizes=args.assert_max_downsizes,
         assert_max_resizes=args.assert_max_resizes,
         assert_max_shed_rate=args.assert_max_shed_rate,
@@ -137,7 +131,6 @@ def main(argv=None) -> int:
             or args.assert_tuner_calibration is not None
             or args.assert_serve_throughput is not None
             or args.assert_ttft is not None
-            or args.assert_spec_accept_rate is not None
             or args.assert_max_downsizes is not None
             or args.assert_max_resizes is not None
             or args.assert_max_shed_rate is not None

@@ -496,9 +496,9 @@ def serving_section(data: RunData) -> Tuple[List[str], Dict[str, float]]:
                f"{int(s['programs_lowered_since_ready'])}"
                if s.get("programs_lowered_since_ready") is not None else "")
         )
-        # raw-speed rails (docs/SERVING.md "Raw speed"): shared-prefix
-        # reuse and self-drafting speculation report their win here —
-        # the artifacts a prefix/spec perf claim is judged on
+        # raw-speed rail (docs/SERVING.md "Raw speed"): shared-prefix
+        # reuse reports its win here — the artifact a prefix perf claim
+        # is judged on
         hit = s.get("prefix_hit_tokens")
         if hit:
             stats["serve_prefix_hit_rate"] = float(
@@ -510,14 +510,6 @@ def serving_section(data: RunData) -> Tuple[List[str], Dict[str, float]]:
                 f"({int(s.get('prompt_tokens', 0))} prompt tokens "
                 f"submitted; hit rate "
                 f"{stats['serve_prefix_hit_rate']:.1%})"
-            )
-        if s.get("spec_accept_rate") is not None:
-            stats["serve_spec_accept_rate"] = float(s["spec_accept_rate"])
-            lines.append(
-                f"  speculation: accepted "
-                f"{int(s.get('spec_accepted_tokens', 0))}/"
-                f"{int(s.get('spec_drafted_tokens', 0))} drafts "
-                f"(accept rate {stats['serve_spec_accept_rate']:.1%})"
             )
         # resilience rails (docs/SERVING.md "Resilience"): overload
         # sheds, deadline timeouts, supervised restarts, drain state —
@@ -611,17 +603,6 @@ def serving_section(data: RunData) -> Tuple[List[str], Dict[str, float]]:
             if missing:
                 line += f" MISSING={missing}"
             lines.append(line)
-        if s.get("spec_k_sweep"):
-            # the --spec-k-sweep arm: every draft length's measured
-            # tokens/s + accept rate, best-k first-class
-            lines.append(
-                f"  spec-k sweep: best k={s.get('spec_k_best')} of "
-                + ", ".join(
-                    f"k={row.get('spec_k')}:"
-                    f"{float(row.get('tokens_per_s', 0.0)):.1f}t/s"
-                    for row in s["spec_k_sweep"]
-                )
-            )
     elif reqs:
         # crashed/partial run: derive throughput from what finished
         tokens = sum(int(e.get("output_tokens", 0)) for e in reqs)
@@ -753,32 +734,18 @@ def serving_section(data: RunData) -> Tuple[List[str], Dict[str, float]]:
             + f", top critical-path phase: {top} "
             f"({counts.get(top, 0)} trace(s)) — see `obs trace`"
         )
-    # tick-time attribution: the engine's program (issued under
-    # serve.mixed, its samples waited for under serve.mixed.wait, one
-    # tick() call later) against the host-side drafting that speculation
-    # adds before it; counted once a program
-    phases = (
-        ("mixed", ("serve.mixed", "serve.mixed.wait")),
-        ("draft", ("serve.draft",)),
-    )
-    sums: Dict[str, Tuple[float, int]] = {}
+    # tick time: the engine's program (issued under serve.mixed, its
+    # samples waited for under serve.mixed.wait, one tick() call later);
+    # counted once a program
+    total, programs = 0.0, 0
     for sp in data.spans:
-        for label, names in phases:
-            if sp.get("span") in names and sp.get("dur_s") is not None:
-                total, count = sums.get(label, (0.0, 0))
-                sums[label] = (total + float(sp["dur_s"]),
-                               count + (sp["span"] == names[0]))
-    if sums:
-        grand = sum(t for t, _ in sums.values())
-        parts = []
-        for label, _ in phases:
-            if label not in sums:
-                continue
-            t, count = sums[label]
-            share = t / grand if grand > 0 else 0.0
-            stats[f"serve_{label.replace('-', '_')}_s"] = t
-            parts.append(f"{label} {share:.0%} ({t:.3f}s/{count})")
-        lines.append("  tick time: " + "  ".join(parts))
+        if (sp.get("span") in ("serve.mixed", "serve.mixed.wait")
+                and sp.get("dur_s") is not None):
+            total += float(sp["dur_s"])
+            programs += sp["span"] == "serve.mixed"
+    if total or programs:
+        stats["serve_mixed_s"] = total
+        lines.append(f"  tick time: mixed {total:.3f}s/{programs}")
     return lines, stats
 
 
@@ -882,7 +849,6 @@ def check_gates(data: RunData, assert_mfu: Optional[float] = None,
                 tuner_stats: Optional[Dict[str, float]] = None,
                 assert_serve_throughput: Optional[float] = None,
                 assert_ttft: Optional[float] = None,
-                assert_spec_accept_rate: Optional[float] = None,
                 assert_max_downsizes: Optional[int] = None,
                 assert_max_resizes: Optional[int] = None,
                 assert_max_shed_rate: Optional[float] = None,
@@ -899,7 +865,6 @@ def check_gates(data: RunData, assert_mfu: Optional[float] = None,
     failures: List[str] = []
     serving_gates = (assert_serve_throughput is not None
                      or assert_ttft is not None
-                     or assert_spec_accept_rate is not None
                      or assert_max_shed_rate is not None
                      or assert_max_serve_timeouts is not None
                      or assert_max_replica_skew is not None
@@ -974,19 +939,6 @@ def check_gates(data: RunData, assert_mfu: Optional[float] = None,
                     f"{int(sstats.get('serve_fleet_hosts', 0))} in the "
                     "placement plan) — the fleet ran without them; "
                     "check the hosts line and ssh reachability"
-                )
-        if assert_spec_accept_rate is not None:
-            rate = sstats.get("serve_spec_accept_rate")
-            if rate is None:
-                failures.append(
-                    "assert-spec-accept-rate: no speculative-decoding "
-                    "telemetry in the run dir (serve-summary carries no "
-                    "spec_accept_rate — was the bench run with --spec-k?)"
-                )
-            elif rate < assert_spec_accept_rate:
-                failures.append(
-                    f"assert-spec-accept-rate: accept rate {rate:.3f} "
-                    f"< floor {assert_spec_accept_rate:.3f}"
                 )
         if assert_serve_throughput is not None:
             tps = sstats.get("serve_tokens_per_s")

@@ -11,8 +11,7 @@ The "millions of users" half of the north star: turns the single-request
   budget, preemption on pool exhaustion, completed-slot recycling.
 - :mod:`.engine` — the jitted device program: ONE fused mixed program
   per tick covering the whole slot set — prefill-chunk rows and
-  decode rows (each carrying up to ``spec_k`` self-drafted speculative
-  candidates, accepted pathwise-exactly at any temperature) tagged by
+  one-token decode rows tagged by
   traced lengths (paged attention streamed through the Pallas kernel
   in ``nn/paged_attention.py``); per-request temperature/top-k/top-p
   sampling as traced per-row arrays (no per-request recompiles;
@@ -21,12 +20,11 @@ The "millions of users" half of the north star: turns the single-request
   straight into new sequences' tables, so a prompt family pays its
   prefill once (docs/SERVING.md "Raw speed").
 - :mod:`.bench` / ``python -m scaling_tpu.serve bench`` — Poisson
-  load generator reporting tokens/s, TTFT/ITL percentiles, prefix-hit
-  and speculative-accept rates through ``obs.get_registry()``, gated
+  load generator reporting tokens/s, TTFT/ITL percentiles and the
+  prefix-hit rate through ``obs.get_registry()``, gated
   by ``--assert-serve-throughput`` / ``--assert-ttft`` (mirroring the
-  training MFU gates; ``--assert-spec-accept-rate`` /
-  ``--assert-max-shed-rate`` / ``--assert-max-serve-timeouts`` ride
-  the analyzer).
+  training MFU gates; ``--assert-max-shed-rate`` /
+  ``--assert-max-serve-timeouts`` ride the analyzer).
 - :mod:`.router` — the FLEET (docs/SERVING.md "The fleet"): N
   data-parallel engine replicas behind ``FleetRouter`` — least-loaded
   + hash-based prefix-affinity dispatch, retry-elsewhere on
@@ -70,7 +68,6 @@ from .scheduler import (
     SchedulerConfig,
     Sequence,
     SequenceState,
-    ngram_propose,
 )
 
 __all__ = [
@@ -88,7 +85,6 @@ __all__ = [
     "Sequence",
     "SequenceState",
     "journal_path",
-    "ngram_propose",
     "open_journal",
     "replay_journal",
 ]

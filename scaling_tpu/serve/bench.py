@@ -289,19 +289,12 @@ def run_bench(engine, workload, time_scale: float = 1.0,
         "prefill_compiles": engine.prefill_program_count,
         "programs_lowered_since_ready": engine.programs_lowered_since_ready,
         "max_concurrent_prefills": engine.max_concurrent_prefills,
-        # raw-speed rails (ISSUE 11): prefill work actually paid after
-        # shared-prefix reuse, and the self-drafting accept rate
+        # prefill work actually paid after shared-prefix reuse
         "prefix_hit_tokens": hit,
         "prefix_hit_rate": (
             round(hit / (hit + prefilled), 4) if hit + prefilled else 0.0
         ),
         "prefilled_tokens": prefilled,
-        "spec_drafted_tokens": engine.spec_drafted_tokens,
-        "spec_accepted_tokens": engine.spec_accepted_tokens,
-        "spec_accept_rate": (
-            round(engine.spec_accept_rate, 4)
-            if engine.spec_accept_rate is not None else None
-        ),
         "engine": engine_shape_stats(engine),
     }
     if extra_stats:
@@ -324,7 +317,6 @@ def engine_shape_stats(engine, replicas: int = 1) -> dict:
         "num_blocks": cfg.num_blocks,
         "token_budget": cfg.token_budget,
         "prefill_chunk": cfg.prefill_chunk,
-        "spec_k": cfg.spec_k,
     }
 
 
@@ -459,8 +451,6 @@ def run_fleet_bench(router, workload, time_scale: float = 1.0,
     attempts = total_shed + total_timeouts + len(completed) + c_completed
     hit = sum(e.scheduler.prefix_hit_tokens for e in engines)
     prefilled = sum(e.prefilled_tokens for e in engines)
-    drafted = sum(e.spec_drafted_tokens for e in engines)
-    accepted = sum(e.spec_accepted_tokens for e in engines)
     rstats = router.stats()
     replica_rows = []
     for h in handles:
@@ -510,11 +500,6 @@ def run_fleet_bench(router, workload, time_scale: float = 1.0,
             round(hit / (hit + prefilled), 4) if hit + prefilled else 0.0
         ),
         "prefilled_tokens": prefilled,
-        "spec_drafted_tokens": drafted,
-        "spec_accepted_tokens": accepted,
-        "spec_accept_rate": (
-            round(accepted / drafted, 4) if drafted else None
-        ),
         "replicas": len(handles),
         "replica_stats": replica_rows,
         "router": rstats,
@@ -888,7 +873,6 @@ def _run_fleet_proc(args, workload, run_dir, journal_base) -> dict:
             "token_budget": args.token_budget, "kv_dtype": args.kv_dtype,
             "prefill_chunk": args.prefill_chunk,
             "enable_prefix_cache": not args.no_prefix_cache,
-            "spec_k": args.spec_k,
             "default_deadline_ms": args.deadline_ms,
             "default_ttft_deadline_ms": args.ttft_deadline_ms,
             "shed_high_watermark": args.shed_high_watermark,
@@ -1138,7 +1122,6 @@ def _run_fleet_proc(args, workload, run_dir, journal_base) -> dict:
 
     rstats = router.stats()
     agg_keys = ("preemptions", "prefix_hit_tokens", "prefilled_tokens",
-                "spec_drafted_tokens", "spec_accepted_tokens",
                 "prefill_compiles")
     agg = dict.fromkeys(agg_keys, 0)
     ticks = 0
@@ -1175,7 +1158,6 @@ def _run_fleet_proc(args, workload, run_dir, journal_base) -> dict:
         })
     hit = agg["prefix_hit_tokens"]
     prefilled = agg["prefilled_tokens"]
-    drafted = agg["spec_drafted_tokens"]
     stats = {
         "requests": len(outputs),
         "requests_timeout": timeouts,
@@ -1204,12 +1186,6 @@ def _run_fleet_proc(args, workload, run_dir, journal_base) -> dict:
             round(hit / (hit + prefilled), 4) if hit + prefilled else 0.0
         ),
         "prefilled_tokens": prefilled,
-        "spec_drafted_tokens": drafted,
-        "spec_accepted_tokens": agg["spec_accepted_tokens"],
-        "spec_accept_rate": (
-            round(agg["spec_accepted_tokens"] / drafted, 4)
-            if drafted else None
-        ),
         "replicas": len(router.replicas),
         "replica_stats": replica_rows,
         "router": rstats,
@@ -1219,7 +1195,6 @@ def _run_fleet_proc(args, workload, run_dir, journal_base) -> dict:
             "num_blocks": args.num_blocks,
             "token_budget": args.token_budget,
             "prefill_chunk": args.prefill_chunk,
-            "spec_k": args.spec_k,
         },
         # the process-fleet story (obs report's fleet section + the
         # --assert-max-replica-restarts gate read these)
@@ -1260,48 +1235,6 @@ def _run_fleet_proc(args, workload, run_dir, journal_base) -> dict:
     logger.log_event("serve-summary", **stats)
     stats["outputs"] = {str(r): outputs[r] for r in sorted(outputs)}
     get_registry().flush_step(ticks)
-    return stats
-
-
-def _run_spec_sweep(args, sweep_ks, workload, make_engine,
-                    warmup_engine) -> dict:
-    """``--spec-k-sweep``: the SAME workload once per draft length k on
-    a fresh engine each, then a FINAL serve-summary (the last one in the
-    run dir — the one the analyzer and gates read) carrying the winning
-    arm's stats plus the whole sweep table. The tokens/s-optimal k is
-    the answer the ROADMAP raw-speed follow-on asked for; accept rate
-    per k rides along so the ``--assert-spec-accept-rate`` gate judges
-    the winner."""
-    from ..logging import logger
-    from .engine import install_drain_handler
-
-    arms = []
-    for k in sweep_ks:
-        eng = make_engine(spec_k=k)
-        install_drain_handler(eng)  # chains: SIGTERM drains current arm
-        if args.warmup > 0:
-            warmup_engine(eng)
-        arm = run_bench(
-            eng, list(workload), max_wall_s=args.max_wall_s,
-            tick_timeout_s=args.tick_timeout_s,
-            extra_stats={"spec_k": k},
-        )
-        arms.append(arm)
-        if eng.draining:
-            break  # SIGTERM mid-sweep: don't start another arm
-    best = max(arms, key=lambda a: a["tokens_per_s"])
-    stats = dict(best)
-    stats["spec_k_best"] = best["spec_k"]
-    stats["spec_k_sweep"] = [
-        {
-            "spec_k": a["spec_k"],
-            "tokens_per_s": a["tokens_per_s"],
-            "spec_accept_rate": a["spec_accept_rate"],
-            "ttft_p99_s": a["ttft_p99_s"],
-        }
-        for a in arms
-    ]
-    logger.log_event("serve-summary", **stats)
     return stats
 
 
@@ -1354,8 +1287,8 @@ def _ensure_devices(need: int) -> None:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
-    """The bench, with SIGTERM's handler put back as it was found: a sweep
-    arm or the single engine chains a drain handler onto it
+    """The bench, with SIGTERM's handler put back as it was found: the
+    single engine chains a drain handler onto it
     (``install_drain_handler``), and a caller IN PROCESS (a test, a
     notebook) must not keep a handler that drains an engine long gone."""
     import signal
@@ -1399,18 +1332,6 @@ def _main(argv: Optional[List[str]]) -> int:
                         help="Sarathi-style chunked prefill: tokens per "
                         "chunk (prompts stream into the pool sharing the "
                         "tick budget with decodes)")
-    parser.add_argument("--spec-k", type=int, default=0,
-                        help="self-drafting speculative decoding: n-gram "
-                        "draft tokens scored per decode row per tick "
-                        "(0 = off)")
-    parser.add_argument("--spec-k-sweep", metavar="LIST",
-                        help="A/B the draft length: comma list of k "
-                        "values; the SAME workload runs once per k on a "
-                        "fresh engine, the serve-summary reports every "
-                        "arm plus the tokens/s-optimal k, and the "
-                        "--assert-spec-accept-rate gate judges the "
-                        "winning arm (single-replica; disables the "
-                        "journal — a sweep is a measurement drill)")
     # ---- the fleet (docs/SERVING.md "The fleet") ----
     parser.add_argument("--replicas", type=int, default=1,
                         help="data-parallel engine replicas behind the "
@@ -1568,26 +1489,6 @@ def _main(argv: Optional[List[str]]) -> int:
     if args.replicas < 1 or args.mp < 1:
         parser.error("--replicas and --mp must be >= 1")
     fleet = args.replicas > 1
-    sweep_ks: Optional[List[int]] = None
-    if args.spec_k_sweep:
-        try:
-            sweep_ks = sorted({
-                int(x) for x in args.spec_k_sweep.split(",") if x.strip()
-            })
-        except ValueError:
-            sweep_ks = None
-        if not sweep_ks or any(k < 0 for k in sweep_ks):
-            parser.error(
-                f"bad --spec-k-sweep {args.spec_k_sweep!r} "
-                "(want a comma list of ints >= 0)"
-            )
-        if fleet:
-            parser.error("--spec-k-sweep is single-replica (the sweep "
-                         "measures the engine, not the router)")
-        if args.resume:
-            parser.error("--spec-k-sweep runs without a journal (it is "
-                         "a measurement drill); --resume has nothing to "
-                         "replay")
     if args.checkpoint and fleet:
         parser.error(
             "--replicas > 1 serves the toy model only (an in-process "
@@ -1608,8 +1509,6 @@ def _main(argv: Optional[List[str]]) -> int:
             parser.error("--replicas-proc serves the toy model only "
                          "(workers rebuild the model from the config "
                          "they are handed)")
-        if sweep_ks is not None:
-            parser.error("--spec-k-sweep is single-replica")
         if args.resume:
             parser.error("--replicas-proc recovers in-run (the "
                          "supervisor harvests dead replicas' journals); "
@@ -1690,7 +1589,7 @@ def _main(argv: Optional[List[str]]) -> int:
     if args.shared_prefix_len > 0 and args.prefix_families < 1:
         parser.error("--prefix-families must be >= 1")
 
-    def make_engine(replica_id=None, inf_override=None, spec_k=None):
+    def make_engine(replica_id=None, inf_override=None):
         return ServeEngine(inf_override or inf, EngineConfig(
             num_slots=args.num_slots, block_size=args.block_size,
             num_blocks=args.num_blocks,
@@ -1698,7 +1597,6 @@ def _main(argv: Optional[List[str]]) -> int:
             token_budget=args.token_budget, kv_dtype=args.kv_dtype,
             prefill_chunk=args.prefill_chunk,
             enable_prefix_cache=not args.no_prefix_cache,
-            spec_k=args.spec_k if spec_k is None else spec_k,
             default_deadline_ms=args.deadline_ms,
             default_ttft_deadline_ms=args.ttft_deadline_ms,
             shed_high_watermark=args.shed_high_watermark,
@@ -1731,9 +1629,6 @@ def _main(argv: Optional[List[str]]) -> int:
     elif fleet:
         stats = _run_fleet(args, infs, workload, journal_base, make_engine,
                            warmup_engine)
-    elif sweep_ks is not None:
-        stats = _run_spec_sweep(args, sweep_ks, workload, make_engine,
-                                warmup_engine)
     else:
         engine = make_engine()
         # SIGTERM -> graceful drain: stop admitting, finish in-flight,
@@ -1859,26 +1754,11 @@ def _main(argv: Optional[List[str]]) -> int:
                   f"reported={stats['hosts_reported']} "
                   f"submit_dups={stats['submit_dups']} "
                   f"rpc_retries={stats['rpc_retries']}")
-    if stats.get("spec_k_sweep"):
-        print(f"  spec-k sweep (best k={stats['spec_k_best']}):")
-        for row in stats["spec_k_sweep"]:
-            ar = row["spec_accept_rate"]
-            mark = " <- best" if row["spec_k"] == stats["spec_k_best"] else ""
-            print(f"    k={row['spec_k']}: {row['tokens_per_s']:.1f} tok/s "
-                  f"accept="
-                  f"{'n/a' if ar is None else format(ar, '.1%')}{mark}")
     if stats["prefix_hit_tokens"]:
         print(f"  prefix cache: {stats['prefix_hit_tokens']} tokens hit, "
               f"{stats['prefilled_tokens']} prefilled "
               f"({stats['prompt_tokens']} prompt tokens submitted; "
               f"hit rate {stats['prefix_hit_rate']:.1%})")
-    if stats["spec_accept_rate"] is not None:
-        # a sweep's final stats describe the WINNING arm, not --spec-k
-        spec_k = stats.get("engine", {}).get("spec_k", args.spec_k)
-        print(f"  speculation: k={spec_k} accepted "
-              f"{stats['spec_accepted_tokens']}/"
-              f"{stats['spec_drafted_tokens']} drafts "
-              f"(accept rate {stats['spec_accept_rate']:.1%})")
     print(f"  output tokens/s: {stats['tokens_per_s']:.1f} "
           f"({stats['output_tokens']} tokens)")
     if stats["ttft_p50_s"] is not None:

@@ -4,22 +4,22 @@ Per tick the scheduler mixes prompt prefill work with decode work for
 every running sequence; the engine runs it all as **ONE fused
 Sarathi-style mixed program**: every slot row is either a prefill CHUNK
 (prompts stream into the paged pool in fixed-size chunks) or a decode
-row carrying its last token plus up to ``spec_k`` self-drafted
-speculative candidates — tagged purely by traced per-row lengths, so a
-tick with 4 prefilling prompts dispatches 1 executable, not 5.
+row bringing its ONE last token — tagged purely by traced per-row
+lengths, so a tick with 4 prefilling prompts dispatches 1 executable,
+not 5. A row samples one position, its last real one. The engine does
+not speculate: no row carries drafted tokens.
 
-Shared-prefix block reuse and speculative acceptance ride the tick
-(docs/SERVING.md "Raw speed"): the scheduler's prefix trie maps cached
-prompt blocks straight into new sequences' tables (prefill skipped for
-the shared prefix; copy-on-write forks applied by ``_apply_cow`` before
-programs run), and ``_accept_speculative`` emits the longest sampled
-run consistent with the drafts — pathwise-exact at any temperature
-because every scored position draws with the (request, position) key
-plain decode would use.
+Shared-prefix block reuse rides the tick (docs/SERVING.md "Raw speed"):
+the scheduler's prefix trie maps cached prompt blocks straight into new
+sequences' tables (prefill skipped for the shared prefix; copy-on-write
+forks applied by ``_apply_cow`` before programs run).
 
 Paged attention streams KV blocks through the Pallas paged-decode
 kernel (nn/paged_attention.py — interpreted off-TPU so the CPU mesh
-runs the real kernel body).
+runs the real kernel body). Layers that keep a line a slot instead (a
+window layer's ring, a recurrent, convolution or delta-rule state) are
+advanced, never indexed by position, so the prefix cache is refused
+beside them by name (``__init__``).
 
 The program's batch is TOKEN-MAJOR: the tick's real tokens, packed back
 to back in slot order into one of (at most) two token widths that follow
@@ -31,8 +31,8 @@ No per-request recompiles, by construction: the mixed program compiles
 once per token width, every width at the engine's first tick — its
 shapes are the fixed ``(width,)`` tokens and ``(num_slots,
 max_blocks_per_seq)`` tables, and sequence raggedness (prompt lengths,
-prefill offsets, draft lengths) lives in block tables / context lengths
-/ new_lens, never in shapes. All of that host state travels as ONE int32
+prefill offsets) lives in block tables / context lengths / new_lens,
+never in shapes. All of that host state travels as ONE int32
 operand a tick (``TickLayout``), sliced apart on the device: a tick costs
 one host-to-device transfer.
 
@@ -42,7 +42,8 @@ chip never waits for the host's emit / schedule / build. The one thing the
 host needs of tick N to build tick N+1, a decoding row's last token, is fed
 from program N's samples on the device (``prev``); the scheduler works on
 the projected state (``Sequence.in_flight``), and where it needs a token's
-value (speculation, a possible preemption, a deadline) the read comes first.
+value (a possible preemption, a deadline) the read comes first
+(``_read_first``, ``SYNC_REASONS``).
 All signatures are pinned in the ``serve_decode`` HLO-audit section
 (analysis/goldens/serve_decode.json): a scheduler shape-bucketing or
 kernel change that would trigger a recompile storm on the chip shows up
@@ -56,7 +57,7 @@ temperatures; no sort, no draw). Sample keys derive from (request id,
 token position) — ``inference.request_sample_key`` — so a preempted-
 and-resumed sequence redraws the SAME tokens and recompute-style
 preemption (scheduler.py) stays invisible in the output even for
-sampled rows, including mid-speculation.
+sampled rows.
 """
 
 from __future__ import annotations
@@ -109,11 +110,11 @@ SMALL_BUCKET_CHUNKS = 3
 IN_FLIGHT = -1
 # why a tick was not issued ahead of the read of the one before it
 # (``serve_ticks_synchronous_total``'s ``reason``)
-SYNC_REASONS = ("spec", "preempt", "deadline", "first", "drained")
+SYNC_REASONS = ("preempt", "deadline", "first", "drained")
 # ticks whose spans ``stats_snapshot()["tick_phases_ms"]`` takes its medians over
 TICK_PHASES_TICKS = 512
 # the spans that tile a tick: ``serve.tick`` and ``serve.mixed`` only hold
-# them, and ``serve.draft`` / ``.preempt`` / ``.cow`` nest in ``serve.schedule``
+# them, and ``serve.preempt`` / ``.cow`` nest in ``serve.schedule``
 LEAF_PHASES = frozenset((
     "serve.schedule", "serve.mixed.build", "serve.mixed.dispatch",
     "serve.mixed.wait", "serve.emit", "serve.retire"))
@@ -209,9 +210,8 @@ class IssuedTick:
     width: int
     sampled: object  # the program's samples, on the device
     # rows that will have produced a token, each with the slot it ran in:
-    # chunk rows that complete their prompt (and the column of the row's
-    # sample), decode rows
-    firsts: List[Tuple[Sequence, int, int]]
+    # chunk rows that complete their prompt, decode rows
+    firsts: List[Tuple[Sequence, int]]
     decodes: List[Tuple[Sequence, int]]
     prefilled: int  # prompt tokens its chunk rows brought
     positions: int  # sampled positions that hold a token
@@ -233,9 +233,6 @@ class EngineConfig:
     # shared-prefix KV block reuse (RadixAttention-style trie admission;
     # see SchedulerConfig.prefix_cache)
     enable_prefix_cache: bool = True
-    # self-drafting speculative decoding: n-gram drafts scored k-at-once
-    # through the mixed program's s>1 rows; 0 = off
-    spec_k: int = 0
     # prompts streaming a chunk each that the SMALL token width has room for
     # beside a decode row in every slot (``mixed_widths``). An engine of many
     # slots and short chunks raises it so that the common tick, whose prompt
@@ -265,17 +262,16 @@ class EngineConfig:
     replica_id: Optional[int] = None
 
     def __post_init__(self):
-        # the scheduler's checks (prefill_chunk, spec_k, the watermarks)
+        # the scheduler's checks (prefill_chunk, the watermarks)
         # where the value was written, not at the engine's first tick
         self.scheduler_config()
 
     @property
     def mixed_width(self) -> int:
-        """The most tokens one row brings to a tick: chunk rows up to
-        ``prefill_chunk``, speculative decode rows ``spec_k + 1`` (last
-        accepted token + k drafts). The width of the per-row query
-        blocks the paged kernel attends over."""
-        return max(self.prefill_chunk, self.spec_k + 1)
+        """The most tokens one row brings to a tick: a chunk row up to
+        ``prefill_chunk``, a decode row one. The width of the per-row
+        query blocks the paged kernel attends over."""
+        return self.prefill_chunk
 
     @property
     def mixed_widths(self) -> Tuple[int, ...]:
@@ -284,27 +280,17 @@ class EngineConfig:
         tokens (``sum(new_len)``). At most two, fixed by the
         configuration: the full width ``num_slots * mixed_width`` holds
         whatever the scheduler admits; the small one holds the common
-        tick, a decode row (1 + ``spec_k`` tokens) in every slot and
+        tick, a decode row (one token) in every slot and
         ``small_bucket_chunks`` prompts streaming a chunk each, rounded
         up to whole ``LANES`` (below the chip's ridge a tick costs one
         read of the weights whatever it holds, so a finer bucket buys
         nothing and a third program costs its warm-up). Engines whose
         full width is no larger build the one program."""
         full = self.num_slots * self.mixed_width
-        small = (self.num_slots * (self.spec_k + 1)
+        small = (self.num_slots
                  + self.small_bucket_chunks * self.prefill_chunk)
         small = -(-small // LANES) * LANES
         return (small, full) if small < full else (full,)
-
-    @property
-    def sample_width(self) -> int:
-        """Positions per row the mixed program actually SAMPLES: a
-        decode row reads its last token's sample plus one per draft
-        (``spec_k + 1`` at most), a finishing chunk row exactly one.
-        The program gathers this window of trunk activations per row
-        BEFORE the vocab projection, so the lm_head prices ``num_slots
-        * sample_width`` positions whatever the tick's token width."""
-        return min(self.mixed_width, self.spec_k + 1)
 
     def scheduler_config(self) -> SchedulerConfig:
         return SchedulerConfig(
@@ -314,7 +300,6 @@ class EngineConfig:
             token_budget=self.token_budget,
             prefill_chunk=self.prefill_chunk,
             prefix_cache=self.enable_prefix_cache,
-            spec_k=self.spec_k,
             shed_high_watermark=self.shed_high_watermark,
             shed_low_watermark=self.shed_low_watermark,
             max_waiting=self.max_waiting,
@@ -391,17 +376,13 @@ class ServeEngine:
         # approximated
         self.line_layers = {kind.NAME: layers for kind, layers
                             in line_layers(self.pools.kinds).items()}
-        kept = f"layers that keep a line a slot ({self.line_layers})"
         if self.line_layers and self.config.enable_prefix_cache:
             raise ValueError(
-                f"enable_prefix_cache with {kept}: a prefix hit starts a row "
+                "enable_prefix_cache with layers that keep a line a slot "
+                f"({self.line_layers}): a prefix hit starts a row "
                 "past tokens its lines never saw (only the KV of a shared "
                 "prefix is kept, no snapshot of the lines); set "
                 "enable_prefix_cache=False")
-        if self.line_layers and self.config.spec_k > 0:
-            raise ValueError(
-                f"spec_k > 0 with {kept}: a rejected draft has already "
-                "advanced the lines and there is no rollback; set spec_k=0")
         # recurrent lines advance in a form of their own, a step or a chunk
         # by what a row brings (nn/mamba.py, nn/gated_delta.py: the kinds whose
         # view says ``SPLITS``), by the name their spans and counters carry
@@ -420,14 +401,8 @@ class ServeEngine:
         self._np = np
         self._jax = jax
         # latent attention layers (nn/latent_attention.py): their lines are
-        # paged like K and V, written once a token and never rewound, but the
-        # kernel's rows are a decode token or a prompt chunk, not drafts
+        # paged like K and V, written once a token and never rewound
         self.latent_layers = inference_module.architecture.latent_layers
-        if self.latent_layers and self.config.spec_k > 0:
-            raise ValueError(
-                "spec_k > 0 with latent attention layers: the latent kernel "
-                "folds a row of one token or a prompt chunk, and a decode row "
-                "with drafts has not been held to the reference; set spec_k=0")
         # residual streams a token carries (nn/hyper_connection.py; 1: the
         # plain residual) and the sub-layers that have a mapping of their
         # own: the streams are activations and are never cached
@@ -448,11 +423,6 @@ class ServeEngine:
                 "prefix hit and a copy-on-write fork over a line that holds "
                 "the indexer's keys have not been held to the reference; set "
                 "enable_prefix_cache=False")
-        if self.sparse_layers and self.config.spec_k > 0:
-            raise ValueError(
-                "spec_k > 0 with sparse attention layers: the row walk takes "
-                "a row of one token or a prompt chunk, and a decode row with "
-                "drafts has not been held to the reference; set spec_k=0")
         # KV tokens a tile of the paged kernel holds, at a shard's heads (a
         # sparse latent layer: index keys one step of a row's score loop
         # multiplies), and a SUB-TILE of it, the unit the paged kernel waits
@@ -490,8 +460,8 @@ class ServeEngine:
         self._base_key = self._dev(
             jax.random.PRNGKey(self.config.sample_seed)
         )
-        # the last program's (num_slots, sample_width) samples, on the
-        # device: the next program's ``prev`` (zeros before the first)
+        # the last program's (num_slots, 1) samples, on the device: the
+        # next program's ``prev`` (zeros before the first)
         self._prev = self._first_prev()
         # the tick whose program is issued and not yet read, if any, and
         # the thread that issued it
@@ -574,8 +544,6 @@ class ServeEngine:
         # spans the recorder holds from before this are another engine's
         self._created_ns = time.monotonic_ns()
         self.prefilled_tokens = 0  # prompt tokens actually prefilled
-        self.spec_drafted_tokens = 0
-        self.spec_accepted_tokens = 0
         # resilience state (docs/SERVING.md "Resilience"): graceful
         # drain, overload-shed / deadline-timeout tallies, and the
         # crash-replay request journal
@@ -764,8 +732,7 @@ class ServeEngine:
         off it committed to the device the params or the pools are
         committed to, and uncommitted where they are not (a jitted call's
         outputs are committed if any operand is)."""
-        zeros = self._np.zeros(
-            (self.config.num_slots, self.config.sample_width), self._np.int32)
+        zeros = self._np.zeros((self.config.num_slots, 1), self._np.int32)
         if self._replicated is not None:
             return self._jax.device_put(zeros, self._replicated)
         for leaf in self._jax.tree_util.tree_leaves(
@@ -828,16 +795,14 @@ class ServeEngine:
 
     def _sample_grid(self, logits, temps, topps, topks, reqids, gen0,
                      base_key):
-        """Sample EVERY position of a (rows, s, vocab) logit grid with
+        """Sample every position of a (rows, s, vocab) logit grid with
         the key plain decode would use there: position ``i`` of a row
-        draws with ``fold_in(fold_in(base, req), gen0 + i)``. This is
-        what makes speculative acceptance PATHWISE exact at any
-        temperature — the verifier computes the very token plain decode
-        would have emitted, not merely one from the same distribution —
-        and what lets chunk rows sample their first token at the last
-        real position with the key of the request's first generated
-        token (``gen0`` is per-row: chunk rows offset it so position
-        ``new_len - 1`` folds the true generated count)."""
+        draws with ``fold_in(fold_in(base, req), gen0 + i)``. The mixed
+        program hands it ONE position a row (``s = 1``), the row's last
+        real one, with ``gen0`` the tokens the request has generated by
+        then: a chunk row that completes its prompt so draws its first
+        token with the key of the request's first generated token, and a
+        preempted and resumed request redraws the tokens it had."""
         from ..models.transformer.inference import (
             request_sample_key, sample_rows,
         )
@@ -861,9 +826,8 @@ class ServeEngine:
 
     def _build_mixed_fn(self, width: int):
         """ONE fused Sarathi-style program per tick, over a TOKEN-MAJOR
-        batch: the tick's real tokens (a decode row's last token plus up
-        to ``spec_k`` drafted candidates, a chunk row's ``<=
-        prefill_chunk`` prompt tokens, nothing for an empty slot) lie
+        batch: the tick's real tokens (a decode row's last token, a chunk
+        row's ``<= prefill_chunk`` prompt tokens, nothing for an empty slot) lie
         back to back in slot order in ``tokens`` (``width``,), and the
         trunk (norms, QKV, rotary, MLP or routed MLP, output projection)
         runs over those ``width`` positions, shaped
@@ -880,34 +844,27 @@ class ServeEngine:
         tables, lengths, sampler rows, the tokens last) and the program
         opens with static slices of it, so it takes five arguments:
         params, the donated pool state, that vector, the key, and
-        ``prev``: the ``(num_slots, sample_width)`` samples of the program
+        ``prev``: the ``(num_slots, 1)`` samples of the program
         before this one, which never left the device (zeros before the
         first). The engine issues a tick before it has read the one before
         (``tick``), so a decode row whose last token is still in flight
         brings ``IN_FLIGHT`` in its place and the program opens with
         ``tokens = where(tokens < 0, prev[row, 0], tokens)``: a row that
         decoded and a chunk row that completed its prompt in the tick before
-        both sampled at column 0 (a speculating engine, whose rows sample
-        wider, reads every tick before it schedules and never brings the
-        sentinel). Rotary positions are ``ctx_lens[row] + offset``. The paged branch
+        both sampled their one position. Rotary positions are
+        ``ctx_lens[row] + offset``. The paged branch
         (``Attention._paged_attention``) scatters each token's K/V through
         its row's table (what is no token goes to the trash block; rows
         never share pool blocks, so fusing their writes is exact),
         regroups the queries to the ``(num_slots, mixed_width)`` blocks
         the kernel takes and gathers its output back to token order.
 
-        EVERY sampled position draws with its plain-decode key
-        (``_sample_grid``): decode rows read positions ``0..new_len-1``
-        for speculative acceptance, a chunk row that completes its
-        prompt reads position ``new_len - 1``. Only ``sample_width``
-        (= min(mixed_width, spec_k+1)) positions per row are ever read,
-        so the program GATHERS each row's sampling window of trunk
-        activations before the vocab projection (ISSUE 13 satellite): row
-        window = positions ``g0 .. g0 + sample_width - 1`` of the row's
-        tokens with ``g0 = clip(new_len - sample_width, 0)`` — covers
-        positions ``0..new_len-1`` for decode rows (new_len ≤ spec_k+1 ⇒
-        g0 = 0) and position ``new_len - 1`` for chunk rows, while the
-        lm_head prices ``num_slots * sample_width`` positions.
+        A row samples ONE position, its last real one (``new_len - 1``: a
+        decode row's token, the end of a chunk row that completes its
+        prompt), with its plain-decode key (``_sample_grid``). The program
+        GATHERS that position of every row from the trunk's activations
+        before the vocab projection, so the head prices ``num_slots``
+        positions whatever the tick's token width.
 
         One program per width of ``EngineConfig.mixed_widths`` (two at
         most), all lowered at the engine's first tick — pinned in the
@@ -927,7 +884,6 @@ class ServeEngine:
         from ..nn.attention import packed_token_map
 
         jnp = self._jax.numpy
-        sample_width = self.config.sample_width
         row_width = self.config.mixed_width
         shape = packed_batch_shape(width, row_width)
         routed = self.num_experts > 0
@@ -950,29 +906,33 @@ class ServeEngine:
             batch = self.inf._make_batch(tokens, pos)
             views = build_layer_views(state, tables, ctx_lens, new_lens,
                                       token_map, kinds=self.pools.kinds)
-            g0 = jnp.clip(new_lens - sample_width, 0,
-                          row_width - sample_width)
-            window = g0[:, None] + jnp.arange(sample_width, dtype=jnp.int32)
+            # the row's sampled position (0 where it brings none). The
+            # window keeps its unit axis and its sum with a one-element iota,
+            # and ``_sample_grid`` its grid of one column: without them the
+            # program computes the same values from ANOTHER lowered text,
+            # which is the compile cache's key and what the ledger measured
+            last = jnp.clip(new_lens - 1, 0, row_width - 1)
+            window = last[:, None] + jnp.arange(1, dtype=jnp.int32)
             logits, new_views, *extra = self.inf._run_layers(
                 params, batch, views, None,
                 gather_index=jnp.take_along_axis(
                     token_map.row_tokens, window, axis=1),
                 moe_load=routed, exit_p=gated,
             )
-            # gathered index j is the row's token g0 + j: shift the
-            # per-row key-fold base so every sample still draws with the
-            # (request, position) key plain decode would use there
+            # ``gen0`` is the key-fold base of the row's FIRST token: at its
+            # last, the sample draws with the (request, position) key plain
+            # decode would use there
             with self._jax.named_scope("head"):  # beside final norm and head
                 sampled = self._sample_grid(
                     logits, tick.temps, tick.topps, tick.topks, tick.reqids,
-                    tick.gen0 + g0, base_key
+                    tick.gen0 + last, base_key
                 )
             feed = sampled  # the next program's ``prev``
             if routed:
                 sampled = jnp.concatenate([sampled.reshape(-1), extra[0]])
             if gated:
-                # (loop_steps, rows, sample_width): the window's positions
-                # that hold a token
+                # (loop_steps, rows, 1): the sampled positions that hold a
+                # token
                 held = window < new_lens[:, None]
                 exit_p = jnp.sum(jnp.where(held[None], extra[-1], 0.0),
                                  axis=(1, 2))
@@ -1064,16 +1024,15 @@ class ServeEngine:
         every prefill chunk AND the whole decode batch, the rows' real
         tokens packed back to back in slot order into the smallest token
         width that holds them, each row tagged by its traced
-        ``new_len``/``ctx_len``. Decode rows carry their speculative
-        drafts; a decode row whose last token is still in flight carries
-        ``IN_FLIGHT`` for it and the program takes the token from ``prev``,
-        the samples of the program before it, on the device.
+        ``new_len``/``ctx_len``. A decode row whose last token is still
+        in flight carries ``IN_FLIGHT`` for it and the program takes the
+        token from ``prev``, the samples of the program before it, on the
+        device.
 
         The rows' sequences are PROJECTED past this tick as it is issued
         (``num_cached`` by what the row brings, ``in_flight`` by the token
         it will produce), so the next tick can be scheduled and issued
-        before this one is read (``_read``: acceptance happens host-side
-        on the returned per-position samples, ``_accept_speculative``)."""
+        before this one is read (``_read``)."""
         np = self._np
         cfg = self.config
         if not self._mixed_fns:
@@ -1085,14 +1044,13 @@ class ServeEngine:
                         **traces) as mixed_span:
             with self._span("serve.mixed.build", step=step):
                 n = cfg.num_slots
-                sw = cfg.sample_width  # sampled grid covers g0..g0+sw-1
                 row_tokens: List[List[int]] = [[]] * n  # by slot
                 # written at the widest token width, handed over at the
                 # tick's own: the tokens lie last, so that is a prefix
                 packed, tick = self._layout.host(cfg.mixed_widths[-1])
                 tables, ctx, new_lens, gen0 = (
                     tick.tables, tick.ctx_lens, tick.new_lens, tick.gen0)
-                firsts = []  # (seq, slot, column of its row's sample)
+                firsts = []  # (seq, slot)
                 prefilled = 0
                 for seq in t.prefills:
                     slot = seq.slot
@@ -1116,20 +1074,16 @@ class ServeEngine:
                     seq.num_cached = start + n_real
                     prefilled += n_real
                     if seq.num_cached == seq.prefill_len:
-                        # original position n_real - 1, gathered at index
-                        # n_real - 1 - g0 with g0 = max(n_real - sw, 0)
-                        firsts.append((seq, slot, min(n_real, sw) - 1))
+                        firsts.append((seq, slot))
                         seq.in_flight += 1
                 for seq in t.decodes:
                     slot = seq.slot
                     last = IN_FLIGHT if seq.in_flight else seq.generated[-1]
-                    row_tokens[slot] = [last, *seq.draft]
-                    new_lens[slot] = 1 + len(seq.draft)
+                    row_tokens[slot] = [last]
+                    new_lens[slot] = 1
                     ctx[slot] = seq.num_cached
                     tables[slot, :len(seq.blocks)] = seq.blocks
                     gen0[slot] = len(seq.generated) + seq.in_flight
-                    # the last token's write always stands; what a draft
-                    # adds is known at acceptance
                     seq.num_cached += 1
                     seq.in_flight += 1
                 # inactive rows keep all-trash tables + new_len 0: they
@@ -1173,72 +1127,64 @@ class ServeEngine:
             step=step, tick=t, width=width, sampled=sampled, firsts=firsts,
             decodes=[(seq, seq.slot) for seq in t.decodes],
             prefilled=prefilled,
-            positions=int(np.minimum(new_lens, sw).sum()), traces=traces)
+            positions=int(np.count_nonzero(new_lens)), traces=traces)
 
     def _read(self, issued: IssuedTick) -> None:
         """What a tick owes once its program's samples are on the host, its
         spans under the ``step`` it was issued at: ``serve.mixed.wait``,
-        ``serve.emit`` (acceptance, a token appended and stamped for every
-        row, the tick's counters) and ``serve.retire``. A row whose sequence
+        ``serve.emit`` (a token appended and stamped for every row, the
+        tick's counters) and ``serve.retire``. A row whose sequence
         ended at the EOS read a tick before (``_emit_row``) is dropped."""
         np = self._np
         step = issued.step
-        n, sw = self.config.num_slots, self.config.sample_width
+        n = self.config.num_slots
         with self._span("serve.mixed.wait", step=step, **issued.traces):
             # the tick's ONE deliberate device->host pull: the sampled
             # token grid must land on host to be emitted to callers
             host_samples = np.asarray(issued.sampled)
         with self._span("serve.emit", step=step) as emit:
+            # a slot's one sample, then what a routed or a gated model's
+            # program appended to the same read
+            host_samples = host_samples.reshape(-1)
+            samples, tail = host_samples[:n].tolist(), host_samples[n:]
             if self.num_experts:
-                load = host_samples[n * sw:]
-                host_samples = host_samples[:n * sw].reshape(n, sw)
                 if self.sparse_layers:
-                    load = self._record_tie_breaks(load, emit)
+                    tail = self._record_tie_breaks(tail, emit)
                 *_, bounded = self._moe_rows[issued.width]
-                self._record_moe_load(load, emit, bounded)
+                self._record_moe_load(tail, emit, bounded)
             if self.loop_exit_gate:
-                exit_p = host_samples[n * sw:].view(np.float32)
-                host_samples = host_samples[:n * sw].reshape(n, sw)
-                self._record_exit(exit_p, issued.positions, emit)
+                self._record_exit(tail.view(np.float32), issued.positions,
+                                  emit)
             now = time.monotonic()
             rows = 0
-            drafted = self.spec_drafted_tokens
-            accepted = self.spec_accepted_tokens
             self._tick_prefilled += issued.prefilled
-            for seq, slot, column in issued.firsts:
-                rows += self._emit_row(
-                    seq, host_samples[slot, column:column + 1], now)
-            for seq, slot in issued.decodes:
-                rows += self._emit_row(seq, host_samples[slot], now)
-            self._flush_tick_telemetry(
-                emit, rows, self.spec_drafted_tokens - drafted,
-                self.spec_accepted_tokens - accepted)
+            for seq, slot in issued.firsts + issued.decodes:
+                rows += self._emit_row(seq, samples[slot], now)
+            self._flush_tick_telemetry(emit, rows)
         with self._span("serve.retire", step=step) as retire_span:
             finished = self._retire_tick(issued.tick)
             if retire_span is not None:  # not warming up
                 retire_span.annotate(finished=finished)
 
-    def _emit_row(self, seq: Sequence, row_samples, now: float) -> bool:
-        """One row's samples to its sequence; False for a row issued behind
+    def _emit_row(self, seq: Sequence, tok: int, now: float) -> bool:
+        """One row's sample to its sequence; False for a row issued behind
         a token that turned out to be the EOS: its sequence was finished
         when that token was read, its sample is dropped (what the row wrote
         lies in blocks and lines the next admission resets)."""
         if seq.state is not SequenceState.RUNNING:
             return False
         seq.in_flight -= 1
-        self._accept_speculative(seq, row_samples, now)
+        self._emit_token(seq, tok, now)
         eos = seq.request.eos_token_id
-        if eos is not None and seq.generated[-1] == eos:
+        if eos is not None and tok == eos:
             seq.in_flight = 0  # the row already issued behind it is dropped
         return True
 
-    def _flush_tick_telemetry(self, emit_span, rows: int, drafted: int,
-                              accepted: int) -> None:
+    def _flush_tick_telemetry(self, emit_span, rows: int) -> None:
         """What the tick's rows emitted, counted once a tick where it was
         once a row or a token: the counters take their sums, the
         inter-token histogram one bulk observation. ``rows``: the rows
-        emitted for (chunk rows that finished their prompt, decode rows);
-        the speculative counters move only in a tick that drafted."""
+        emitted for (chunk rows that finished their prompt, decode rows)."""
         tokens, prefilled = self._tick_tokens, self._tick_prefilled
         itl, self._tick_itl = self._tick_itl, []
         self._tick_tokens = self._tick_prefilled = 0
@@ -1252,10 +1198,6 @@ class ServeEngine:
             self._counter("serve_tokens_generated_total").inc(tokens)
         if itl:
             self._histogram("serve_itl_seconds").observe_many(itl)
-        if drafted:
-            self._counter("serve_spec_drafted_tokens_total").inc(drafted)
-        if accepted:
-            self._counter("serve_spec_accepted_tokens_total").inc(accepted)
 
     def _annotate_mixed(self, mixed_span, width: int, tokens: int, ctx,
                         new_lens, multi: int) -> None:
@@ -1466,46 +1408,6 @@ class ServeEngine:
                 sum((u + 1) * p for u, p in enumerate(mean)), 6),
         )
 
-    def _accept_speculative(self, seq: Sequence, row_samples, now) -> None:
-        """Exact speculative acceptance (Leviathan et al., arxiv
-        2211.17192, specialized to pathwise-deterministic keys): every
-        scored position was sampled with the key plain decode would use
-        there, so position ``j``'s sample IS plain decode's next token
-        — PROVIDED the conditioning holds, i.e. every earlier draft
-        matched its sample. Emit the sample run up to and including the
-        first mismatch; advance the sequence (and so the per-request key
-        fold) by tokens ACCEPTED, never tokens scored — a preempted-and-
-        resumed sequence mid-speculation redraws identical tokens."""
-        draft = seq.draft
-        xs = [int(x) for x in row_samples[:len(draft) + 1]]
-        emitted = [xs[0]]
-        matched = 0
-        for j, d in enumerate(draft):
-            if d != xs[j]:
-                break
-            matched += 1
-            emitted.append(xs[j + 1])
-        # the request's budget and EOS cut the run exactly where plain
-        # decode would have stopped asking for tokens
-        emitted = emitted[:seq.remaining_tokens]
-        eos = seq.request.eos_token_id
-        if eos is not None and eos in emitted:
-            emitted = emitted[:emitted.index(eos) + 1]
-        accepted = min(matched, len(emitted) - 1)
-        if self.warmup_mode:
-            draft = []
-        # the counters take the tick's sums (_flush_tick_telemetry)
-        self.spec_drafted_tokens += len(draft)
-        self.spec_accepted_tokens += accepted if draft else 0
-        seq.draft = []
-        # KV validity: the context held the last token's write (counted as
-        # the row was issued), plus one slot per accepted draft — rejected
-        # drafts' slots are simply overwritten by the next call (the
-        # context never admits them)
-        seq.num_cached += len(emitted) - 1
-        for tok in emitted:
-            self._emit_token(seq, tok, now)
-
     def _emit_token(self, seq: Sequence, tok: int, now: float) -> None:
         seq.generated.append(tok)
         if self.journal is not None and not self.warmup_mode:
@@ -1628,8 +1530,8 @@ class ServeEngine:
     def tick(self) -> Tick:
         """One engine step, ONE TICK AHEAD of the tokens the host has read:
         schedule tick ``step`` from the projected state and issue its
-        program (``serve.schedule``: expire deadlines, draft speculative
-        candidates, schedule; ``serve.mixed``: build, dispatch), and only
+        program (``serve.schedule``: expire deadlines, schedule;
+        ``serve.mixed``: build, dispatch), and only
         THEN read the tick issued by the call before (``_read``: its
         ``serve.mixed.wait``, ``serve.emit`` and ``serve.retire`` carry ITS
         ``step``, one less), so the chip runs this program while the host
@@ -1639,8 +1541,8 @@ class ServeEngine:
         the call only reads what is in flight.
 
         Where the scheduler needs the tokens' VALUES the read comes first
-        and the tick is synchronous (``_read_first``: speculation, a
-        deadline that has run out, a pool that might preempt); the same
+        and the tick is synchronous (``_read_first``: a deadline that has
+        run out, a pool that might preempt); the same
         code, the read moved ahead of the schedule. ``seq.generated`` and
         ``seq.token_stamps`` hold only tokens the host has read, whenever
         this returns."""
@@ -1707,15 +1609,12 @@ class ServeEngine:
     def _read_first(self, now: float) -> Optional[str]:
         """Why the tick in flight must be read BEFORE the next is scheduled
         (None: it need not be), from what the engine can observe:
-        ``"spec"``, n-gram drafting and acceptance work on the tokens, every
-        tick; ``"deadline"``, a running request has run out of time and its
+        ``"deadline"``, a running request has run out of time and its
         cancellation must see its first token, if that is what is in
         flight; ``"preempt"``, the pool might preempt
         (``scheduler.may_preempt``: a bound) and a victim's
         ``resume_prompt`` must hold every token it was given. A tick read
         early for nothing costs one gap, never a token."""
-        if self.config.spec_k > 0:
-            return "spec"
         if self._issued is None:
             return None
         if any(seq.slot is not None for seq in self._expired(now)):
@@ -1732,9 +1631,6 @@ class ServeEngine:
         entries its LRU heap skipped on the way are a lifetime total,
         ``stats_snapshot()["evict_stale"]``)."""
         self._expire_deadlines(time.monotonic())
-        if self.config.spec_k > 0:
-            with self._span("serve.draft", step=step):
-                self.scheduler.propose_drafts()
         t = self.scheduler.schedule()
         if not self.warmup_mode:
             for seq in t.first_admitted:
@@ -1799,19 +1695,7 @@ class ServeEngine:
             self._journal_pending.clear()
         for name, value in self.scheduler.gauges().items():
             self._gauge(name).set(value)
-        if self.spec_drafted_tokens:
-            self._gauge("serve_spec_accept_rate").set(
-                self.spec_accepted_tokens / self.spec_drafted_tokens
-            )
         return finished
-
-    @property
-    def spec_accept_rate(self) -> Optional[float]:
-        """Accepted / drafted speculative tokens (None before any
-        drafting) — the self-drafting proposer's quality signal."""
-        if not self.spec_drafted_tokens:
-            return None
-        return self.spec_accepted_tokens / self.spec_drafted_tokens
 
     @property
     def prefill_program_count(self) -> int:
@@ -1872,8 +1756,6 @@ class ServeEngine:
             "evict_stale": (sched.prefix_cache.stale_skipped
                             if sched.prefix_cache is not None else 0),
             "prefilled_tokens": self.prefilled_tokens,
-            "spec_drafted_tokens": self.spec_drafted_tokens,
-            "spec_accepted_tokens": self.spec_accepted_tokens,
             # closures built, at most one a token width; and the programs
             # lowered since they all were: the recompile alarm
             "prefill_compiles": self.prefill_program_count,

@@ -12,23 +12,14 @@ queue with ``prompt + generated-so-far`` as its new prompt. Under greedy
 sampling the resumed sequence regenerates token-for-token, so preemption
 is invisible in the output — the paged-parity tests pin exactly that.
 
-Two raw-speed policies ride the same tick loop (ISSUE 11):
-
-- **Shared-prefix block reuse** (RadixAttention, arxiv 2312.07104):
-  :class:`PrefixCache` is a trie over FULL blocks of prompt tokens.
-  Admission walks the trie and maps every matched block straight into
-  the new sequence's table (refcounted — the allocator counts sequence
-  users per block), so N requests sharing a system prompt pay its
-  prefill ONCE; only the unmatched tail streams chunks. Freed cached
-  blocks are not returned to the free list — they become LRU-evictable
-  trie leaves, reclaimed only under pool pressure.
-- **Self-drafting speculative decoding** (Leviathan et al., arxiv
-  2211.17192): :func:`ngram_propose` drafts ``k`` candidate tokens per
-  decoding row from the row's own history; the engine scores all of
-  them in one kernel call and accepts the longest prefix that matches
-  what plain decode would have emitted (exact at any temperature — the
-  per-(request, position) sample keys make acceptance pathwise, not
-  merely distribution, equivalent).
+One raw-speed policy rides the same tick loop: **shared-prefix block
+reuse** (RadixAttention, arxiv 2312.07104). :class:`PrefixCache` is a trie
+over FULL blocks of prompt tokens. Admission walks the trie and maps every
+matched block straight into the new sequence's table (refcounted — the
+allocator counts sequence users per block), so N requests sharing a system
+prompt pay its prefill ONCE; only the unmatched tail streams chunks. Freed
+cached blocks are not returned to the free list — they become LRU-evictable
+trie leaves, reclaimed only under pool pressure.
 """
 
 from __future__ import annotations
@@ -104,9 +95,6 @@ class Sequence:
     # far this sequence's own full prompt blocks are registered in it
     prefix_cached: int = 0
     cached_upto: int = 0
-    # speculative decoding: this tick's drafted candidate tokens (set by
-    # propose_drafts, consumed by the engine's mixed program)
-    draft: List[int] = dataclasses.field(default_factory=list)
     # tokens a program has been issued for and the host has not read yet
     # (the engine runs one tick ahead of its reads): never in ``generated``
     # or ``token_stamps``, counted wherever a LENGTH decides. ``num_cached``
@@ -431,36 +419,6 @@ class PrefixCache:
         return freed
 
 
-# speculative drafting: how far back the n-gram proposer scans (and how
-# much history propose_drafts assembles) — one constant, two users
-NGRAM_SCAN_WINDOW = 512
-
-
-def ngram_propose(history: List[int], k: int, max_n: int = 3,
-                  max_scan: int = NGRAM_SCAN_WINDOW) -> List[int]:
-    """Self-drafting n-gram proposal: find the most recent earlier
-    occurrence of the history's final n-gram (longest n first) within
-    the last ``max_scan`` tokens and copy the tokens that followed it —
-    up to ``k`` candidates. Returns [] when nothing matches (the row
-    decodes plainly that tick). Host-side and model-free: the 'draft
-    model' is the sequence itself. ``max_scan`` bounds the per-tick host
-    cost at O(max_n * max_scan) per row regardless of context length —
-    recent history is where self-repetition lives anyway; an
-    incremental suffix index is the documented follow-on
-    (docs/SERVING.md)."""
-    if k <= 0 or len(history) < 2:
-        return []
-    window = history[-max_scan:] if len(history) > max_scan else history
-    for n in range(min(max_n, len(window) - 1), 0, -1):
-        pat = window[-n:]
-        for i in range(len(window) - n - 1, -1, -1):
-            if window[i:i + n] == pat:
-                cont = window[i + n:i + n + k]
-                if cont:
-                    return list(cont)
-    return []
-
-
 @dataclasses.dataclass
 class SchedulerConfig:
     # Sarathi-style chunked prefill: prompts stream into the pool in
@@ -475,10 +433,6 @@ class SchedulerConfig:
     token_budget: int = 512  # prompt+decode tokens admitted per tick
     # shared-prefix block reuse
     prefix_cache: bool = True
-    # self-drafting speculative decoding: candidate tokens drafted per
-    # decoding row per tick (0 = off), scored through the mixed
-    # program's chunk-width rows
-    spec_k: int = 0
     # overload shedding (docs/SERVING.md "Resilience"): above the HIGH
     # pool-pressure watermark new submissions are rejected with a
     # structured Backpressure instead of queueing unboundedly, and keep
@@ -499,8 +453,6 @@ class SchedulerConfig:
                 f"prefill_chunk must be an int >= 1, "
                 f"got {self.prefill_chunk!r}"
             )
-        if self.spec_k < 0:
-            raise ValueError(f"spec_k must be >= 0, got {self.spec_k}")
         high, low = self.shed_high_watermark, self.shed_low_watermark
         if high is not None and not 0.0 < high <= 1.0:
             raise ValueError(
@@ -700,35 +652,6 @@ class ContinuousBatchingScheduler:
             self.evict_seconds += time.monotonic() - t
         return self.allocator.alloc(n)
 
-    # -------------------------------------------------- speculative drafts
-    def propose_drafts(self) -> int:
-        """Draft up to ``spec_k`` candidate tokens for every decoding row
-        (n-gram self-drafting — no second model). Returns tokens drafted
-        this tick. Drafts are capped at ``remaining_tokens - 1`` so a
-        fully-accepted run (drafts + bonus token) lands exactly on the
-        request's budget. The engine calls this ahead of ``schedule()``
-        (under the ``serve.draft`` span) so GROW can book blocks for the
-        scored slots."""
-        k = self.config.spec_k
-        drafted = 0
-        for seq in self.running.values():
-            seq.draft = []
-            if k <= 0 or seq.prefilling or not seq.generated:
-                continue
-            cap = min(k, seq.remaining_tokens - 1)
-            if cap <= 0:
-                continue
-            # assemble only the scan window, not the full O(L) history
-            gen = seq.generated
-            w = NGRAM_SCAN_WINDOW
-            if len(gen) >= w:
-                hist = gen[-w:]
-            else:
-                hist = seq.request.prompt[-(w - len(gen)):] + gen
-            seq.draft = ngram_propose(hist, cap)
-            drafted += len(seq.draft)
-        return drafted
-
     # ------------------------------------------------- shared-prefix trie
     def _register_prefix_blocks(self) -> None:
         """Register every running sequence's freshly-prefilled FULL
@@ -813,20 +736,9 @@ class ContinuousBatchingScheduler:
                 continue  # evicted earlier in this very loop
             if seq.done:
                 continue  # its last token is in flight: it asks for nothing
-            # a decode row scores its last token plus this tick's drafts
-            # in one call — blocks must cover every scored slot (rejected
-            # drafts' slots are simply overwritten)
+            # blocks must cover every slot the row's next step writes
             step = self._next_step(seq)
             need = self.blocks_needed(seq.num_cached + step) - len(seq.blocks)
-            if need > self.available_blocks() and seq.draft:
-                # speculation is opportunistic: shed the drafts before
-                # preempting anyone for their scratch space
-                seq.draft = []
-                step = 1
-                need = (
-                    self.blocks_needed(seq.num_cached + step)
-                    - len(seq.blocks)
-                )
             if need > 0:
                 while (need > self.available_blocks()
                        and self._preempt_youngest(seq, preempted)):
@@ -947,11 +859,11 @@ class ContinuousBatchingScheduler:
 
     def _next_step(self, seq: Sequence) -> int:
         """Tokens a running sequence brings to its next tick: its next
-        prefill chunk, or its last token plus this tick's drafts."""
+        prefill chunk, or the one token of a decode row."""
         if seq.prefilling:
             return min(self.config.prefill_chunk,
                        seq.prefill_len - seq.num_cached)
-        return 1 + len(seq.draft)
+        return 1
 
     def may_preempt(self) -> bool:
         """Whether the next ``schedule()`` could preempt a running
@@ -1029,7 +941,6 @@ class ContinuousBatchingScheduler:
         # prefix_cached survives as a post-mortem stat; a re-admission
         # overwrites it with the fresh match
         seq.cached_upto = 0
-        seq.draft = []
         self.running.pop(seq.slot)
         self._free_slots.append(seq.slot)
         self._freed_slots.append(seq.slot)

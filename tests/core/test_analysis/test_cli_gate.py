@@ -160,9 +160,9 @@ def test_audit_gate_matches_golden(tmp_path):
 def test_audit_gate_serve_decode_matches_golden(tmp_path):
     """The serving engine's MIXED program reproduces its pinned golden
     (ISSUE 9; repinned for ISSUE 11's fused tick): ONE program per tick
-    covers decode rows (with speculative drafts) and prefill chunks —
+    covers one-token decode rows and prefill chunks —
     its signature carries no per-request shapes, no host callbacks, and
-    a stable recompile key baking the (chunk, draft-length) width — the
+    a stable recompile key baking the chunk width — the
     no-recompile-storm contract for the continuous-batching scheduler's
     shape bucketing."""
     out = tmp_path / "serve.json"
@@ -177,14 +177,12 @@ def test_audit_gate_serve_decode_matches_golden(tmp_path):
     static = sec["recompile_key"]["static"]
     assert static["kind"] == "serve_mixed_step"
     # shapes in the signature come from engine CONFIG, never per request:
-    # the chunk, the speculative draft length and the widths they make,
-    # and no selector of a program or a back-end (there is one of each)
+    # the chunk and the widths it makes, and no selector of a program or
+    # a back-end (there is one of each)
     assert set(static) == {
         "kind", "num_slots", "block_size", "max_blocks_per_seq", "kv_dtype",
-        "prefill_chunk", "spec_k", "mixed_width", "sample_width",
-        "token_widths", "token_width"}
-    assert static["mixed_width"] == max(static["prefill_chunk"],
-                                        static["spec_k"] + 1)
+        "prefill_chunk", "mixed_width", "token_widths", "token_width"}
+    assert static["mixed_width"] == static["prefill_chunk"]
     # the engine's two token widths (ISSUE 33): the section is the small
     # width's program, the full width's is pinned beside it — the same
     # function at another T: as many dots, no other collective, about

@@ -172,6 +172,9 @@ def test_event_record_is_byte_for_byte_what_it_was(case, tmp_path, monkeypatch):
     monkeypatch.setenv("SCALING_TPU_EVENTS_PATH", str(path))
     monkeypatch.setenv("SCALING_TPU_HOST_ID", "3")
     monkeypatch.setattr(spans_module, "_clock", iter([10.0, 10.25]).__next__)
+    # a row at a made-up time stays out of the process-wide ring, where every
+    # row lies after ``process.start``
+    monkeypatch.setattr(spans_module, "_record", [].append)
     monkeypatch.setattr("time.time", lambda: 1234.5)
     monkeypatch.setattr(spans_module, "new_span_id", lambda: "abcd1234")
     with obs.trace_context(trace_id):

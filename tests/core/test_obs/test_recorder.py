@@ -23,7 +23,19 @@ def _no_capture_left_on():
         obs.stop_capture()
 
 
-def test_rows_are_exact_and_in_closing_order(monkeypatch):
+@pytest.fixture
+def ring_restored():
+    """The process-wide ring as it was found: a test that writes rows at
+    made-up times leaves none behind (every row of the running program lies
+    after ``process.start``, and a later test may say so)."""
+    ring = recorder_module._recorder.ring
+    held = list(ring)
+    yield
+    ring.clear()
+    ring.extend(held)
+
+
+def test_rows_are_exact_and_in_closing_order(monkeypatch, ring_restored):
     """Four clock reads, two nested spans: each row is the span itself, to
     the nanosecond, the child first."""
     monkeypatch.setattr(spans_module, "_clock",
@@ -149,7 +161,7 @@ def test_a_capture_whose_markers_left_the_ring_holds_what_is_left():
     assert ring.between(start, stop) == []
 
 
-def test_record_span_writes_a_row_and_nothing_else(monkeypatch):
+def test_record_span_writes_a_row_and_nothing_else(monkeypatch, ring_restored):
     def boom(*a, **k):  # pragma: no cover - firing IS the failure
         raise AssertionError("record_span reached the registry")
 

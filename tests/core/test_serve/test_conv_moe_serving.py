@@ -193,10 +193,8 @@ def test_a_tail_that_is_never_written_serves_other_tokens(lfm2, undisturbed, mon
 @pytest.mark.parametrize("config,message", [
     ({"enable_prefix_cache": True},
      "keep a line a slot \\({'conv': 3}\\): a prefix hit .* lines never saw"),
-    ({"spec_k": 2},
-     "keep a line a slot \\({'conv': 3}\\): a rejected draft has already advanced"),
 ])
-def test_what_would_skip_or_rewind_the_tail_is_refused_by_name(lfm2, config, message):
+def test_what_would_skip_the_tail_is_refused_by_name(lfm2, config, message):
     with pytest.raises(ValueError, match=message):
         engine_of(lfm2, **config)
     # the default EngineConfig has the prefix cache on: refused too, not

@@ -11,9 +11,7 @@
   ``--assert-max-replica-skew`` gate passes on balanced dispatch, fails
   loudly on a run dir with no replica telemetry;
 - SIGTERM mid-bench drains the WHOLE fleet to exit 0 with per-replica
-  journal namespaces on disk;
-- ``--spec-k-sweep`` A/Bs draft lengths over one workload and reports
-  the tokens/s-optimal k through the accept-rate gate.
+  journal namespaces on disk.
 """
 
 import json
@@ -203,59 +201,11 @@ def test_sigterm_drains_whole_fleet_to_exit_zero(tmp_path):
     assert any(e["event"] == "serve-summary" for e in evs)
 
 
-def test_spec_k_sweep_reports_optimal_k(tmp_path, monkeypatch, capsys):
-    """--spec-k-sweep A/Bs draft length on one workload (in-process: the
-    sweep is the measurement, not the deployment): the final summary
-    carries every arm + the tokens/s-optimal k, and the accept-rate
-    gate judges the WINNING arm through `obs report`."""
-    from scaling_tpu.serve.bench import main as bench_main
-
-    run_dir = tmp_path / "sweep"
-    run_dir.mkdir()
-    # pin the events path via monkeypatch so the bench's setdefault
-    # cannot leak a tmp path into later tests' environment
-    monkeypatch.setenv(
-        "SCALING_TPU_EVENTS_PATH", str(run_dir / "events.jsonl")
-    )
-    monkeypatch.setenv("SCALING_TPU_TEST_CACHE", "off")
-    found = signal.getsignal(signal.SIGTERM)
-    rc = bench_main([
-        "--requests", "6", "--rate", "50", "--seed", "5",
-        "--prompt-len", "4", "8", "--output-len", "6", "10",
-        "--num-slots", "4", "--block-size", "4", "--num-blocks", "64",
-        "--max-blocks-per-seq", "8", "--token-budget", "64",
-        "--prefill-chunk", "4", "--spec-k-sweep", "0,3",
-        "--hidden", "32", "--layers", "2", "--vocab", "64", "--heads", "4",
-        "--run-dir", str(run_dir), "--json", str(run_dir / "stats.json"),
-    ])
-    out = capsys.readouterr().out
-    assert rc == 0, out
-    # each arm chained a drain handler onto SIGTERM: none outlives the call
-    assert signal.getsignal(signal.SIGTERM) is found
-    stats = json.loads((run_dir / "stats.json").read_text())
-    ks = [row["spec_k"] for row in stats["spec_k_sweep"]]
-    assert ks == [0, 3]
-    assert stats["spec_k_best"] in ks
-    assert "spec-k sweep (best k=" in out
-    # the k=3 arm really drafted (its accept rate is a number)
-    k3 = [r for r in stats["spec_k_sweep"] if r["spec_k"] == 3][0]
-    assert k3["spec_accept_rate"] is not None
-    # the analyzer reads the FINAL (winning-arm) summary; the accept
-    # gate passes at floor 0 iff the winner drafted, and the sweep line
-    # renders
-    from scaling_tpu.obs.cli import main as obs_main
-
-    rc = obs_main(["report", str(run_dir)])
-    out = capsys.readouterr().out
-    assert rc == 0
-    assert "spec-k sweep: best k=" in out
-
-
 @pytest.mark.parametrize("ends", ["returns", "raises"])
 def test_bench_main_puts_sigterms_handler_back_as_it_found_it(monkeypatch, ends):
-    """``main`` in process: the drain handlers its engines chain onto SIGTERM
-    (one a sweep arm, one for the single engine) are gone when it returns or
-    raises, and the handler that was there is there again."""
+    """``main`` in process: the drain handler its engine chains onto SIGTERM
+    is gone when it returns or raises, and the handler that was there is
+    there again."""
     from scaling_tpu.serve import bench
     from scaling_tpu.serve.engine import install_drain_handler
 

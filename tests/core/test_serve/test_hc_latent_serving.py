@@ -285,13 +285,11 @@ def test_a_stack_the_mapping_is_not_built_for_is_refused_by_name(arch, message):
         xing_config(**arch)
 
 
-def test_training_prefix_reuse_and_drafts_are_refused_by_name(xing):
+def test_training_and_pipeline_stages_are_refused_by_name(xing):
     from scaling_tpu.nn.base_layer import ForwardContext
 
     with pytest.raises(NotImplementedError, match="layer_pattern stack is served"):
         xing.module.forward(xing.params, {}, ForwardContext())
-    with pytest.raises(ValueError, match="spec_k > 0 with latent attention layers"):
-        engine_of(xing, spec_k=2)
     with pytest.raises(ValueError, match="layer_pattern with pipe_parallel_size 2"):
         xing_config({"pipe_parallel_size": 2})
 

@@ -152,10 +152,8 @@ def test_a_preempted_and_resumed_sequence_reproduces_its_tokens(hybrid, undistur
 @pytest.mark.parametrize("config,message", [
     ({"enable_prefix_cache": True},
      "keep a line a slot \\({'ssm': 3}\\): a prefix hit .* lines never saw"),
-    ({"spec_k": 2},
-     "keep a line a slot \\({'ssm': 3}\\): a rejected draft has already advanced"),
 ])
-def test_what_would_skip_or_rewind_the_state_is_refused_by_name(hybrid, config, message):
+def test_what_would_skip_the_state_is_refused_by_name(hybrid, config, message):
     with pytest.raises(ValueError, match=message):
         engine_of(hybrid, **config)
     # the default EngineConfig has the prefix cache on: refused too, not

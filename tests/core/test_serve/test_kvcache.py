@@ -229,7 +229,7 @@ def inference_modules(toy_inference):
     }
 
 
-def _program_and_args(inf, kv_dtype, spec_k, width=WIDTHS[0], **config):
+def _program_and_args(inf, kv_dtype, width=WIDTHS[0], **config):
     """The engine's program at one of its token widths, as the plain
     function under its ``jax.jit``, with toy arguments in its signature."""
     from scaling_tpu.serve.engine import EngineConfig, ServeEngine
@@ -237,7 +237,7 @@ def _program_and_args(inf, kv_dtype, spec_k, width=WIDTHS[0], **config):
     engine = ServeEngine(inf, EngineConfig(**{
         "num_slots": SLOTS, "block_size": 4, "num_blocks": 2 * MAX_BLOCKS + 1,
         "max_blocks_per_seq": MAX_BLOCKS, "token_budget": 64,
-        "prefill_chunk": CHUNK, "kv_dtype": kv_dtype, "spec_k": spec_k,
+        "prefill_chunk": CHUNK, "kv_dtype": kv_dtype,
         # refused for a stack that keeps a line a slot (a hit would skip
         # tokens the lines never saw)
         "enable_prefix_cache": inf.architecture.layer_pattern is None,
@@ -272,31 +272,28 @@ def _aliases(lowered):
     return aliases
 
 
-# (stack, spec_k, kv_dtype, KV layers, lists of lines, what follows the grid
-# in the first output): every stack whose state is donated
+# (stack, kv_dtype, KV layers, lists of lines, what follows the grid in the
+# first output): every stack whose state is donated
 ALIAS_CASES = [
-    pytest.param(model, spec_k, kv_dtype, kv_layers, lines, None,
+    pytest.param(model, kv_dtype, kv_layers, lines, None,
                  id=f"{name}-{kv_dtype}")
-    for name, model, spec_k, kv_layers, lines in [
-        ("mixed", "dense", 0, 3, 0), ("mixed-spec2", "dense", 2, 3, 0),
-        ("routed", "routed", 0, 3, 0), ("mp2", "mp2", 0, 3, 0),
-        ("looped", "looped", 0, 3, 0),
-        ("hybrid", "hybrid", 0, 1, 2)]  # 1 ssm + 1 conv line
+    for name, model, kv_layers, lines in [
+        ("mixed", "dense", 3, 0), ("routed", "routed", 3, 0),
+        ("mp2", "mp2", 3, 0), ("looped", "looped", 3, 0),
+        ("hybrid", "hybrid", 1, 2)]  # 1 ssm + 1 conv line
     for kv_dtype in ("native", "int8")
 ] + [
     # 3 ssm + 3 conv lines; the 4 held experts' load and the absent count
-    pytest.param("mamba2", 0, "native", 1, 6, 4 + 1, id="mamba2-wide"),
+    pytest.param("mamba2", "native", 1, 6, 4 + 1, id="mamba2-wide"),
     # 3 conv tails; the 8 experts' load
-    pytest.param("lfm2", 0, "native", 1, 3, 8, id="lfm2-wide"),
+    pytest.param("lfm2", "native", 1, 3, 8, id="lfm2-wide"),
 ]
 
 
 @pytest.mark.parametrize("bucket", [0, 1], ids=["small", "full"])
-@pytest.mark.parametrize("model,spec_k,kv_dtype,kv_layers,lines,tail",
-                         ALIAS_CASES)
+@pytest.mark.parametrize("model,kv_dtype,kv_layers,lines,tail", ALIAS_CASES)
 def test_donated_state_aliases_the_output_computed_from_it(
-        inference_modules, model, spec_k, kv_dtype, kv_layers, lines, tail,
-        bucket):
+        inference_modules, model, kv_dtype, kv_layers, lines, tail, bucket):
     """JAX pairs a donated buffer with an output of its shape and dtype
     in flattened order, so ``pool_v[3]`` is updated in place only if the
     program's lowered ``main`` says its argument aliases the output leaf
@@ -320,7 +317,7 @@ def test_donated_state_aliases_the_output_computed_from_it(
     also held to the structure, shapes and dtypes it was handed."""
     wide = tail is not None
     engine, fn, args = _program_and_args(
-        inference_modules[model], kv_dtype, spec_k,
+        inference_modules[model], kv_dtype,
         (WIDE_WIDTHS if wide else WIDTHS)[bucket], **(WIDE if wide else {}))
     if wide:
         sampled, _, state = jax.eval_shape(fn, *args)
@@ -329,7 +326,7 @@ def test_donated_state_aliases_the_output_computed_from_it(
         for got, held in zip(jax.tree_util.tree_leaves(state),
                              jax.tree_util.tree_leaves(args[1])):
             assert (got.shape, got.dtype) == (held.shape, held.dtype)
-        assert sampled.shape == (16 * engine.config.sample_width + tail,)
+        assert sampled.shape == (16 + tail,)  # a slot's one sample, then the tail
     lowered = jax.jit(fn, donate_argnums=(1,), keep_unused=True).lower(
         *args
     )
@@ -350,18 +347,17 @@ def test_donated_state_aliases_the_output_computed_from_it(
 def test_programs_return_the_state_in_pool_state_structure(
         inference_modules, model, kv_dtype, width):
     engine, fn, args = _program_and_args(
-        inference_modules[model], kv_dtype, 0, width)
+        inference_modules[model], kv_dtype, width)
     sampled, feed, state = jax.eval_shape(fn, *args)
-    sw = engine.config.sample_width
     tail = {"routed": engine.num_experts, "looped": LOOP_STEPS,
             "hybrid": 2 + 1}  # the held experts' load + the absent count
     assert sampled.shape == (
-        (SLOTS * sw + tail[model],) if model in tail else (SLOTS, sw)
+        (SLOTS + tail[model],) if model in tail else (SLOTS, 1)
     )
     # what the next program takes as ``prev``: the grid alone, one shape
     # whatever follows it in the host's read
     assert (feed.shape, feed.dtype) == (engine._prev.shape, engine._prev.dtype)
-    assert feed.shape == (SLOTS, sw)
+    assert feed.shape == (SLOTS, 1)
     structure = jax.tree_util.tree_structure
     assert structure(state) == structure(engine._pool_state())
     assert (state[2] is None) == (kv_dtype == "native")
@@ -521,7 +517,7 @@ def test_run_layers_on_paged_views_defaults_to_the_kernel(toy_inference):
     traces the Pallas call: the back-end's default is written once, on
     ``ForwardContext``, and a caller that names none cannot get the
     gather (which stays reachable by name, as the tests' reference)."""
-    engine, _, args = _program_and_args(toy_inference, "native", 0)
+    engine, _, args = _program_and_args(toy_inference, "native")
     params, state, packed = args[:3]
     tick = engine._layout.split(packed)
     tables, ctx_lens, new_lens = tick.tables, tick.ctx_lens, tick.new_lens

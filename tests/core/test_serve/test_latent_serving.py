@@ -225,11 +225,6 @@ def test_an_int8_pool_is_refused_by_name(kimi):
         engine_of(kimi, kv_dtype="int8")
 
 
-def test_speculative_rows_are_refused_by_name(kimi):
-    with pytest.raises(ValueError, match="spec_k > 0 with latent attention layers"):
-        engine_of(kimi, spec_k=2)
-
-
 @pytest.mark.parametrize("topology,arch,message", [
     ({"model_parallel_size": 2}, {}, "layer_pattern with model_parallel_size 2"),
     ({"pipe_parallel_size": 2}, {}, "layer_pattern with pipe_parallel_size 2"),

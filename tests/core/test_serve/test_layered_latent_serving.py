@@ -523,7 +523,6 @@ def test_what_the_layout_refuses_is_refused_by_name(topology, message):
 
 
 @pytest.mark.parametrize("engine, message", [
-    ({"spec_k": 2}, "spec_k > 0 with layers that keep a line a slot"),
     ({"enable_prefix_cache": True},
      "enable_prefix_cache with layers that keep a line a slot"),
     ({"kv_dtype": "int8"}, "kv_dtype 'int8' with window attention layers"),

@@ -103,16 +103,15 @@ def test_a_dense_model_pays_nothing(tmp_path):
 
 
 def test_one_program_serves_a_routed_model_whatever_the_tick_holds():
-    """A prompt shorter than a chunk, one many chunks long, drafts and a
+    """A prompt shorter than a chunk, one many chunks long and a
     preemption: the routed engine compiles its mixed program once and
     holds no other jitted callable."""
-    engine = make_engine({"spec_k": 3, "num_blocks": 7}, **ROUTED)
-    cycle = [(i % 5) + 1 for i in range(40)]  # n-grams the proposer finds
+    engine = make_engine({"num_blocks": 7}, **ROUTED)
+    cycle = [(i % 5) + 1 for i in range(40)]
     seqs = [engine.submit(p, 6) for p in (cycle, cycle[3:], [9, 8, 7])]
     engine.run_until_done()
     assert all(len(s.generated) == 6 for s in seqs)
     assert engine.scheduler.preemption_count > 0
-    assert engine.spec_drafted_tokens > 0
     width, = engine.config.mixed_widths  # a toy engine has one bucket
     assert jitted_programs(engine) == {"_mixed_fns": 1}
     assert list(engine._mixed_fns) == [width]

@@ -39,17 +39,32 @@ def make_engine(inf, **overrides):
 
 
 # ------------------------------------------------- the widths and the map
-@pytest.mark.parametrize("slots,chunk,spec_k,want", [
-    (16, 32, 0, (128, 512)),   # every cell of the benchmark
-    (16, 32, 3, (256, 512)),   # 16 rows of 1 + 3 drafts, 3 chunks: 160
-    (8, 32, 0, (128, 256)),
-    (4, 32, 0, (128,)),        # the full width is no larger: one program
-    (4, 4, 3, (16,)),
-    (64, 32, 0, (256, 2048)),
+# (num_slots, prefill_chunk, small_bucket_chunks) -> the widths: a decode row
+# in every slot and so many chunks, in whole lanes, under slots x chunk. One
+# row for every serve configuration of the benchmark, named: the pin that no
+# cell's widths move
+@pytest.mark.parametrize("slots,chunk,small_chunks,want", [
+    (16, 32, 3, (128, 512)),     # Mistral, OLMoE, Ouro
+    (64, 32, 3, (256, 2048)),    # Nemotron, LFM2
+    (96, 32, 3, (256, 3072)),    # Falcon-H1
+    (32, 160, 3, (512, 5120)),   # Kimi-K2
+    (16, 320, 3, (1024, 5120)),  # DeepSeek-V3.2-Exp
+    (8, 320, 3, (1024, 2560)),   # Keye
+    (32, 256, 3, (896, 8192)),   # Xing
+    (24, 256, 3, (896, 6144)),   # Laguna
+    (256, 32, 16, (768, 8192)),  # Qwen3-Next: many slots, short chunks
+    (256, 32, 3, (384, 8192)),   # (at the default its slots alone are 256)
+    (16, 256, 3, (896, 4096)),   # dots3
+    (8, 32, 3, (128, 256)),
+    (4, 32, 3, (128,)),          # the full width is no larger: one program
+    (4, 4, 3, (16,)),            # nor need it be whole lanes
 ], ids=lambda v: str(v))
-def test_token_widths_follow_from_the_configuration(slots, chunk, spec_k, want):
-    cfg = EngineConfig(num_slots=slots, prefill_chunk=chunk, spec_k=spec_k)
+def test_token_widths_follow_from_the_configuration(slots, chunk, small_chunks,
+                                                    want):
+    cfg = EngineConfig(num_slots=slots, prefill_chunk=chunk,
+                       small_bucket_chunks=small_chunks)
     assert cfg.mixed_widths == want
+    assert cfg.mixed_width == chunk
     assert all(w % 128 == 0 for w in want[:-1])
 
 
@@ -110,8 +125,7 @@ def models():
     }
 
 
-# (context length, real new tokens) by slot; a decode row's new tokens are
-# 1 + the drafts it carries (spec_k of them where the engine speculates)
+# (context length, real new tokens) by slot; a decode row brings one
 TICKS = {
     # 66 tokens: the small width
     "common": [(37, "decode"), None, (32, 32), (64, 11), (5, "decode"),
@@ -125,9 +139,9 @@ TICKS = {
 def row_major_program(engine):
     """The tick in the layout it had before tokens were packed: one batch
     row a slot, ``mixed_width`` positions each, attention by the gather
-    formulation, the sampling window sliced out of each row."""
+    formulation, the sampled position, its last, picked out of each row."""
     inf, cfg = engine.inf, engine.config
-    width, sw = cfg.mixed_width, cfg.sample_width
+    width = cfg.mixed_width
 
     def mixed(params, state, packed, base_key, prev):
         (tables, ctx_lens, new_lens, topks, reqids, gen0, temps, topps,
@@ -135,15 +149,14 @@ def row_major_program(engine):
         tokens = tokens.reshape(cfg.num_slots, width)
         pos = ctx_lens[:, None] + jnp.arange(width)[None, :]
         views = build_layer_views(state, tables, ctx_lens, new_lens)
-        g0 = jnp.clip(new_lens - sw, 0, width - sw)
-        index = (jnp.arange(cfg.num_slots) * width + g0)[:, None] \
-            + jnp.arange(sw)
+        last = jnp.clip(new_lens - 1, 0, width - 1)
+        index = (jnp.arange(cfg.num_slots) * width + last)[:, None]
         logits, new_views, *load = inf._run_layers(
             params, inf._make_batch(tokens, pos), views, None,
             paged_kernel="xla", gather_index=index,
             moe_load=engine.num_experts > 0)
         sampled = engine._sample_grid(
-            logits, temps, topps, topks, reqids, gen0 + g0, base_key)
+            logits, temps, topps, topks, reqids, gen0 + last, base_key)
         return sampled, state_from_views(new_views), load
 
     return jax.jit(mixed)
@@ -174,7 +187,7 @@ def random_tick(engine, rows, seed):
         if row is None:
             continue
         ctx[slot] = row[0]
-        new_lens[slot] = 1 + cfg.spec_k if row[1] == "decode" else row[1]
+        new_lens[slot] = 1 if row[1] == "decode" else row[1]
         tables[slot] = blocks[slot * MAX_BLOCKS:(slot + 1) * MAX_BLOCKS]
         tokens[slot, :new_lens[slot]] = rng.integers(1, 60, new_lens[slot])
     packed = np.concatenate([tokens[s, :new_lens[s]] for s in range(n)])
@@ -209,19 +222,17 @@ def call(engine, fn, state, shared, tokens):
 # the other six are ``slow`` (tests/conftest.py says what that means).
 PACKED_TICKS = [
     pytest.param(
-        model, spec_k, kv_dtype, tick, id=f"{name}-{kv_dtype}-{tick}",
-        marks=[pytest.mark.slow] if kv_dtype == "int8" and name != "dense" else [])
-    for name, model, spec_k in [("dense", "dense", 0), ("dense-spec2", "dense", 2),
-                                ("routed", "routed", 0), ("mp2", "mp2", 0)]
+        model, kv_dtype, tick, id=f"{model}-{kv_dtype}-{tick}",
+        marks=[pytest.mark.slow] if kv_dtype == "int8" and model != "dense" else [])
+    for model in ("dense", "routed", "mp2")
     for kv_dtype in ("native", "int8")
     for tick in TICKS
 ]
 
 
-@pytest.mark.parametrize("model,spec_k,kv_dtype,tick", PACKED_TICKS)
-def test_packed_tick_is_the_row_major_tick(models, model, spec_k, kv_dtype,
-                                           tick):
-    engine = make_engine(models[model], kv_dtype=kv_dtype, spec_k=spec_k)
+@pytest.mark.parametrize("model,kv_dtype,tick", PACKED_TICKS)
+def test_packed_tick_is_the_row_major_tick(models, model, kv_dtype, tick):
+    engine = make_engine(models[model], kv_dtype=kv_dtype)
     assert engine.config.mixed_widths == (SMALL, FULL)
     state, shared, tokens, packed = random_tick(engine, TICKS[tick], seed=3)
     width = SMALL if len(packed) <= SMALL else FULL
@@ -234,21 +245,20 @@ def test_packed_tick_is_the_row_major_tick(models, model, spec_k, kv_dtype,
     got, feed, got_state = call(
         engine, engine._build_mixed_fn(width), state, shared, padded)
 
-    n, sw, new_lens = SLOTS, engine.config.sample_width, shared["new_lens"]
+    n, new_lens = SLOTS, shared["new_lens"]
     got, want = np.asarray(got), np.asarray(want)
     # what the next program is fed is the grid itself
-    assert np.asarray(feed).tolist() == got[:n * sw].reshape(n, sw).tolist()
+    assert np.asarray(feed).tolist() == got[:n].reshape(n, 1).tolist()
     if engine.num_experts:
         # no assignment dropped, and only real positions counted: every
         # real token's top_k choices, in every layer
-        load, got = got[n * sw:], got[:n * sw].reshape(n, sw)
+        load, got = got[n:], got[:n].reshape(n, 1)
         assert load.tolist() == np.asarray(want_load[0]).tolist()
         assert load.sum() == len(packed) * 2 * 2
-    # what the host reads of the grid: a row's last min(new_len, sw)
-    # sampled positions
-    for slot in range(n):
-        read = min(new_lens[slot], sw)
-        assert got[slot, :read].tolist() == want[slot, :read].tolist(), slot
+    # what the host reads of the grid: the one sample of a row that
+    # brought a token
+    for slot in np.flatnonzero(new_lens):
+        assert got[slot].tolist() == want[slot].tolist(), slot
     # the pool: every block but the trash block, byte for byte (int8: the
     # same roundings), scales and all
     for g, w in zip(jax.tree_util.tree_leaves(got_state),

@@ -240,10 +240,8 @@ def test_the_comparison_sees_each_mixer(falcon, undisturbed, monkeypatch, droppe
 @pytest.mark.parametrize("config,message", [
     ({"enable_prefix_cache": True},
      "keep a line a slot \\({'ssm': 3}\\): a prefix hit .* lines never saw"),
-    ({"spec_k": 2},
-     "keep a line a slot \\({'ssm': 3}\\): a rejected draft has already advanced"),
 ])
-def test_what_would_skip_or_rewind_the_lines_is_refused_by_name(falcon, config, message):
+def test_what_would_skip_the_lines_is_refused_by_name(falcon, config, message):
     with pytest.raises(ValueError, match=message):
         engine_of(falcon, **config)
     with pytest.raises(ValueError, match="enable_prefix_cache"):

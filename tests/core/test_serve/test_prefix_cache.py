@@ -19,12 +19,11 @@ from scaling_tpu.serve.scheduler import (
 
 
 def make_sched(num_slots=4, block_size=4, num_blocks=32,
-               max_blocks_per_seq=8, token_budget=64, prefill_chunk=4,
-               spec_k=0):
+               max_blocks_per_seq=8, token_budget=64, prefill_chunk=4):
     return ContinuousBatchingScheduler(SchedulerConfig(
         num_slots=num_slots, block_size=block_size, num_blocks=num_blocks,
         max_blocks_per_seq=max_blocks_per_seq, token_budget=token_budget,
-        prefill_chunk=prefill_chunk, spec_k=spec_k,
+        prefill_chunk=prefill_chunk,
     ))
 
 

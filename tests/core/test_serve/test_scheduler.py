@@ -338,75 +338,6 @@ def test_prefill_chunk_validation(chunk):
         SchedulerConfig(prefill_chunk=chunk)
 
 
-# ------------------------------------------------- speculative drafting
-def test_ngram_propose_copies_after_longest_recent_match():
-    from scaling_tpu.serve.scheduler import ngram_propose
-
-    # trigram (7, 8, 9) recurs: the continuation after its last earlier
-    # occurrence is the draft
-    history = [7, 8, 9, 1, 2, 3, 7, 8, 9]
-    assert ngram_propose(history, 4) == [1, 2, 3, 7]
-    assert ngram_propose(history, 2) == [1, 2]
-    # no n-gram of the tail recurs -> no draft (plain decode this tick)
-    assert ngram_propose([1, 2, 3, 4], 4) == []
-    # unigram fallback when the bigram is fresh
-    assert ngram_propose([5, 1, 5], 3) == [1, 5]
-    assert ngram_propose([1, 2], 0) == []
-
-
-def test_propose_drafts_caps_at_remaining_budget_and_grows_blocks():
-    """A draft never overshoots the request: at most remaining - 1
-    candidates (full acceptance + bonus token lands exactly on budget),
-    and GROW books blocks for every scored slot."""
-    sched = make_chunked(block_size=2, token_budget=32, prefill_chunk=4)
-    sched.config.spec_k = 4
-    # history after prefill: [1, 2, 3, 1, 2, 3, 1, 2] + generated [1] —
-    # the final unigram recurs, with [2, 3, ...] as its continuation
-    seq = sched.add_request(Request(
-        req_id=0, prompt=[1, 2, 3, 1, 2, 3, 1, 2], max_new_tokens=3,
-    ))
-    settle_chunks(sched, sched.schedule())
-    settle_chunks(sched, sched.schedule())
-    assert not seq.prefilling and seq.generated == [1]
-    drafted = sched.propose_drafts()
-    # remaining = 2 -> at most 1 draft despite spec_k = 4
-    assert drafted == len(seq.draft) == 1
-    tick = sched.schedule()
-    assert tick.decodes == [seq]
-    # 8 cached + (1 token + 1 draft) scored slots = 10 -> 5 blocks at bs 2
-    assert len(seq.blocks) == 5
-
-
-def test_drafts_shed_before_preempting_for_scratch_space():
-    """Speculation is opportunistic: under pool pressure a row drops its
-    drafts (step shrinks to 1) rather than evicting a peer for the
-    rejected-slot scratch."""
-    sched = make_chunked(block_size=2, num_blocks=7, token_budget=32,
-                         prefill_chunk=2, prefix_cache=False)
-    sched.config.spec_k = 4
-    a = sched.add_request(Request(
-        req_id=0, prompt=[5, 6, 5, 6], max_new_tokens=6))
-    b = sched.add_request(Request(
-        req_id=1, prompt=[7, 8], max_new_tokens=4))
-    for _ in range(3):
-        settle_chunks(sched, sched.schedule())
-    assert not a.prefilling and not b.prefilling
-    # pool: 6 usable, a holds 2, b holds 1 -> 3 free
-    a.draft = [5, 6, 5, 6]  # would need 3 extra blocks (4+5 slots)
-    b.draft = [7, 8, 7, 8]
-    tick = sched.schedule()
-    assert not tick.preempted
-    assert a.state is SequenceState.RUNNING
-    assert b.state is SequenceState.RUNNING
-    # at least one row shed its draft instead of preempting the other
-    assert len(a.draft) + len(b.draft) < 8
-
-
-def test_negative_spec_k_rejected():
-    with pytest.raises(ValueError, match="spec_k"):
-        SchedulerConfig(prefill_chunk=4, spec_k=-1)
-
-
 def test_gauges_track_occupancy():
     sched = make_sched(block_size=2, num_blocks=9)
     submit(sched, 0, prompt_len=4, max_new=2)

@@ -503,9 +503,7 @@ def test_an_int8_pool_is_refused_by_name(keye):
         engine_of(keye, kv_dtype="int8")
 
 
-def test_speculative_rows_and_the_prefix_cache_are_refused_by_name(keye):
-    with pytest.raises(ValueError, match="spec_k > 0 with sparse attention layers"):
-        engine_of(keye, spec_k=2)
+def test_the_prefix_cache_is_refused_by_name(keye):
     with pytest.raises(ValueError, match="enable_prefix_cache with sparse attention "
                                          "layers"):
         engine_of(keye, enable_prefix_cache=True)

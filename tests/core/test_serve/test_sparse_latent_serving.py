@@ -391,9 +391,7 @@ def test_an_int8_pool_is_refused_by_name(dsv32):
         engine_of(dsv32, kv_dtype="int8")
 
 
-def test_speculative_rows_and_the_prefix_cache_are_refused_by_name(dsv32):
-    with pytest.raises(ValueError, match="spec_k > 0 with latent attention layers"):
-        engine_of(dsv32, spec_k=2)
+def test_the_prefix_cache_is_refused_by_name(dsv32):
     with pytest.raises(ValueError, match="enable_prefix_cache with sparse latent "
                                          "attention layers"):
         engine_of(dsv32, enable_prefix_cache=True)

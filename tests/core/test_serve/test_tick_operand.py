@@ -163,5 +163,4 @@ def test_the_lowered_program_takes_five_arguments(models, model, width):
     assert leaves[2:] == [1, 1, 1] and len(types) == sum(leaves)
     assert types[-3] == f"{engine._layout.size(width)}xi32"
     assert types[-2] == "2xui32"
-    assert types[-1] == (
-        f"{engine.config.num_slots}x{engine.config.sample_width}xi32")
+    assert types[-1] == f"{engine.config.num_slots}x1xi32"

@@ -47,7 +47,7 @@ def make_engine(inf, synchronous=False, **config):
     engine = ServeEngine(inf, EngineConfig(**{**ENGINE, **config}))
     if synchronous:
         # the synchronous path IS the overlapped one with the read first
-        engine._read_first = lambda now: "spec"
+        engine._read_first = lambda now: "preempt"
     return engine
 
 
@@ -244,20 +244,6 @@ def test_a_first_token_in_flight_meets_its_deadline(toy, want):
     assert engine.ticks_synchronous["deadline"] == 1
 
 
-# ----------------------------------------------------- (v) speculation
-def test_a_speculating_engine_reads_before_every_schedule(toy, want):
-    """n-gram drafting and acceptance work on the tokens' values: every
-    tick synchronous, counted ``reason=spec``, output unchanged."""
-    engine = make_engine(toy, spec_k=3)
-    seqs = [engine.submit(p, MAX_NEW) for p in PROMPTS]
-    drive(engine, seqs)
-    nothing_in_flight(engine, seqs)
-    assert tokens(seqs) == [w[:MAX_NEW] for w in want]
-    assert engine.ticks_overlapped == 0
-    assert engine.ticks_synchronous == {"spec": engine.tick_index}
-    assert engine.stats_snapshot()["tick_phases_ms"]["overlapped_pct"] == 0.0
-
-
 # ----------- (vi) the load vector, exit_p and the state lines a tick late
 def perturbed(config, seed=3):
     module = init_model(config, None)
@@ -335,7 +321,7 @@ def test_a_model_whose_read_carries_more_than_tokens_is_served_a_tick_ahead(
 
 # ------------------------------------- the counters, spans and snapshots
 def test_all_but_the_first_and_the_last_call_are_overlapped(toy, tmp_path):
-    """Without preemption, deadlines or speculation: the first ``tick()``
+    """Without preemption or deadlines: the first ``tick()``
     has nothing to run ahead of (``first``), the last only reads
     (``drained``), every other one issues a program ahead of the read of
     the one before; ``serve.tick`` says so, span by span."""

