@@ -1,6 +1,7 @@
 """Gated delta-rule mixer (Yang et al. 2024, "Gated Delta Networks",
-arXiv:2412.06464): ``jax.numpy``, and a Pallas kernel that takes a row of one
-token from the input projection to the output projection.
+arXiv:2412.06464): ``jax.numpy``, a Pallas kernel that takes a row of one
+token from the input projection to the output projection, and one that
+advances a row of several from its state line, in place.
 
 The linear-attention layer of the Qwen3-Next family. ``x`` (.., H) is the
 normed residual stream; ``nk`` key heads of ``dk`` lanes, ``nv`` value heads
@@ -48,7 +49,8 @@ empty slot) leaves the state as it was, as ``dt = 0`` does in ``ssd_chunk``.
 Two callers, the contract ``Mamba2Mixer`` keeps:
 
 - uncached (``logits()``, the tests): each sequence is walked in chunks of
-  ``CHUNK`` positions from a zero state;
+  ``CHUNK`` positions from a zero state, ``delta_chunk`` as plain
+  ``jax.numpy`` (differentiated, and the kernels' reference);
 - served (``state`` a :class:`DeltaStateView`): one line a (slot, layer),
   ``state (slots, nv, dk, dv)`` float32 and the conv tail ``conv (slots, K - 1,
   C / 128, 128)``, ``C = 2 nk dk + nv dv`` channels: a tap a plane of whole
@@ -65,10 +67,17 @@ Two callers, the contract ``Mamba2Mixer`` keeps:
   over the old one (the update depends on a read-out, so the compiler's own
   fusions pass over the state twice); then the gated RMS norm a head. It
   writes the new tail over the old one and the gated output in the model's
-  dtype. The at most ``split_capacity`` rows of more are gathered, run the
-  chunk form from their lines and are written back; at the full width every
-  row runs the chunk form. A row whose ``context_len`` is 0 starts from zeros
-  in either form; an empty place is untouched.
+  dtype. The at most ``split_capacity`` rows of more advance by the chunk
+  form through a second kernel (``delta_chunk_rows``): a grid over the
+  prefetched list of those rows, the state operand the WHOLE leaf the step
+  returned, aliased to the result; a row's line is brought into VMEM once,
+  every product that reads it is taken there (float32, ``HIGHEST``) and the
+  new state goes over the old line: no gathered copy of the states and no
+  scatter exists. What never touches the state (the conv over the rows'
+  places, ``T``, ``D (q . k)``, the decays) stays plain ``jax.numpy``. At the
+  full width every row runs that chunk form, the list naming every slot. A
+  row whose ``context_len`` is 0 starts from zeros in either form; an empty
+  place is untouched.
 """
 
 from __future__ import annotations
@@ -95,6 +104,9 @@ HIGHEST = jax.lax.Precision.HIGHEST
 CHUNK = 64
 L2_EPS = 1e-6
 KERNEL_NAME = "delta_step"
+CHUNK_KERNEL_NAME = "delta_chunk_rows"
+# key heads a step of the chunk rows' kernel's loop over them (unrolled inside)
+KEY_HEADS_A_STEP = 4
 # channels a row of a conv line's plane: the chip's lanes
 LANES = 128
 
@@ -176,6 +188,43 @@ def unit_lower_inverse(L):
     return jnp.moveaxis(T[0], -1, 0).reshape(*lead, C, C)
 
 
+def _chunk_operands(q, k, g, beta):
+    """What a chunk's advance needs that never touches the state, from
+    operands already padded to the system's side ``w``: ``(T, A (r, nv, w, w),
+    decay, tail (r, w, nv))``. ``T = (I + L)^-1``; ``A[i, j] = exp(G_i - G_j)
+    (q_i . k_j)`` for ``j <= i``, else 0; ``decay_i = exp(G_i)``; ``tail_j =
+    exp(G_C - G_j)``: what position ``j`` writes, decayed to the chunk's end."""
+    r, w, nk, _ = q.shape
+    nv = g.shape[2]
+    per = nv // nk
+    G = jnp.cumsum(g, axis=1)                               # (r, w, nv), <= 0
+    # D[i, j] = exp(G_i - G_j) for j <= i; masked BEFORE the exponential
+    # (above the diagonal the difference is positive and may overflow)
+    diff = G[:, :, None, :] - G[:, None, :, :]              # (r, i, j, nv)
+    causal = jnp.tril(jnp.ones((w, w), bool))[None, :, :, None]
+    D = jnp.exp(jnp.where(causal, diff, -jnp.inf))
+    D = D.reshape(r, w, w, nk, per)
+    kk = jnp.einsum("rihd,rjhd->rijh", k, k, precision=HIGHEST)
+    qk = jnp.einsum("rihd,rjhd->rijh", q, k, precision=HIGHEST)
+    strict = jnp.tril(jnp.ones((w, w), bool), -1)[None, :, :, None, None]
+    b5 = beta.reshape(r, w, 1, nk, per)
+    L = jnp.where(strict, b5 * D * kk[..., None], 0.0)
+    T = unit_lower_inverse(jnp.moveaxis(L, (1, 2), (-2, -1)))  # (r, nk, per, i, j)
+    A = jnp.moveaxis(D * qk[..., None], (1, 2), (-2, -1))
+    return (T.reshape(r, nv, w, w), A.reshape(r, nv, w, w), jnp.exp(G),
+            jnp.exp(G[:, -1:, :] - G))
+
+
+def _padded(q, k, v, g, beta):
+    """The operands with their positions padded to a power of two, the
+    triangular system's side."""
+    C = q.shape[1]
+    pad = (1 << (C - 1).bit_length()) - C
+    if pad:
+        q, k, v, g, beta = (_pad_positions(t, pad) for t in (q, k, v, g, beta))
+    return q, k, v, g, beta
+
+
 def delta_chunk(q, k, v, g, beta, S0, fresh=None):
     """Advance every row by one chunk, float32.
 
@@ -188,29 +237,16 @@ def delta_chunk(q, k, v, g, beta, S0, fresh=None):
     r, C, nk, dk = q.shape
     nv, dv = v.shape[2:]
     per = nv // nk
-    width = 1 << (C - 1).bit_length()      # the system's side: a power of two
-    pad = width - C
-    if pad:
-        q, k, v, g, beta = (_pad_positions(t, pad) for t in (q, k, v, g, beta))
-    G = jnp.cumsum(g, axis=1)                               # (r, C, nv), <= 0
-    # D[i, j] = exp(G_i - G_j) for j <= i; masked BEFORE the exponential
-    # (above the diagonal the difference is positive and may overflow)
-    diff = G[:, :, None, :] - G[:, None, :, :]              # (r, i, j, nv)
-    causal = jnp.tril(jnp.ones((width, width), bool))[None, :, :, None]
-    D = jnp.exp(jnp.where(causal, diff, -jnp.inf))
-    D = D.reshape(r, width, width, nk, per)
-    kk = jnp.einsum("rihd,rjhd->rijh", k, k, precision=HIGHEST)
-    qk = jnp.einsum("rihd,rjhd->rijh", q, k, precision=HIGHEST)
-    strict = jnp.tril(jnp.ones((width, width), bool), -1)[None, :, :, None, None]
-    b5 = beta.reshape(r, width, 1, nk, per)
-    L = jnp.where(strict, b5 * D * kk[..., None], 0.0)
-    T = unit_lower_inverse(jnp.moveaxis(L, (1, 2), (-2, -1)))  # (r, nk, per, i, j)
+    q, k, v, g, beta = _padded(q, k, v, g, beta)
+    width = q.shape[1]
+    T, A, decay, tail = _chunk_operands(q, k, g, beta)
+    T, A = (t.reshape(r, nk, per, width, width) for t in (T, A))
+    decay, tail = (t.reshape(r, width, nk, per) for t in (decay, tail))
     S0g = S0.reshape(r, nk, per, dk, dv)
-    decay = jnp.exp(G).reshape(r, width, nk, per)
     kS = jnp.einsum("rihd,rhpdv->rihpv", k, S0g, precision=HIGHEST)
     qS = jnp.einsum("rihd,rhpdv->rihpv", q, S0g, precision=HIGHEST)
     kS, qS = kS * decay[..., None], qS * decay[..., None]
-    carried = S0g * jnp.exp(G[:, -1]).reshape(r, nk, per, 1, 1)
+    carried = S0g * decay[:, -1, :, :, None, None]
     if fresh is not None:
         zero = fresh[:, None, None, None, None]
         kS, qS = jnp.where(zero, 0.0, kS), jnp.where(zero, 0.0, qS)
@@ -218,10 +254,7 @@ def delta_chunk(q, k, v, g, beta, S0, fresh=None):
     vg = v.reshape(r, width, nk, per, dv)
     rhs = (vg - kS) * beta.reshape(r, width, nk, per, 1)
     U = jnp.einsum("rhpij,rjhpv->rihpv", T, rhs, precision=HIGHEST)
-    o = qS + jnp.einsum("rijhp,rjhpv->rihpv", D * qk[..., None], U,
-                        precision=HIGHEST)
-    # what position j writes, decayed to the chunk's end
-    tail = jnp.exp(G[:, -1:, :] - G).reshape(r, width, nk, per)
+    o = qS + jnp.einsum("rhpij,rjhpv->rihpv", A, U, precision=HIGHEST)
     S = carried + jnp.einsum("rjhd,rjhpv->rhpdv", k, U * tail[..., None],
                              precision=HIGHEST)
     return o.reshape(r, width, nv, dv)[:, :C], S.reshape(S0.shape)
@@ -414,6 +447,175 @@ def delta_step(proj, ba, first, ctx_len, new_len, state, tail, conv_weight,
     return y.reshape(r, nv * dv), new_state, new_tail
 
 
+def _dot(a, b, contract=((1,), (0,))):
+    """A float32 product at the precision the state's products are held to."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())), precision=HIGHEST,
+                               preferred_element_type=F32)
+
+
+def _delta_chunk_kernel(src_ref, line_ref, real_ref, fresh_ref, q_ref, k_ref,
+                        v_ref, t_ref, a_ref, cols_ref, state_ref,
+                        new_ref, o_ref):
+    """One place of the list of chunk rows: its row's line of the state, read
+    once, every product that reads it, and the new state over the old one.
+
+    ``src_ref`` / ``line_ref`` / ``real_ref`` / ``fresh_ref`` (R,) int32 in
+    SMEM: the place whose blocks this grid step sits on (itself, or the last
+    filled place where no row fills it), that place's slot, whether a row
+    fills the place and whether it starts from zeros. ``q_ref`` / ``k_ref``
+    (1, w, nk dk), ``v_ref`` (1, w, nv dv), ``t_ref`` / ``a_ref`` (1, nv, w,
+    w), ``cols_ref`` (1, nk, w, 3 per) = a key head's ``[decay | beta |
+    tail]``, a value head a lane; the state (1, nv, dk, dv). A place no row
+    fills sits on the blocks of the place before it and touches nothing:
+    nothing is fetched for it and nothing written anew. (Were there no filled
+    place at all the blocks would still be written back once: place 0 then
+    copies its line through.)
+
+    The key heads are a LOOP of ``KEY_HEADS_A_STEP`` heads a step: every
+    index that depends on the head is a leading one or a whole tile of lanes,
+    and a step's body is traced and lowered once. (Unrolled over all 32 value
+    heads the kernel cost the cell's set-up 0.75 s a delta layer a program, 9
+    s in all; one key head a step costs the kernel 12 us of its 235 a call,
+    the scheduler having less to overlap: PERF.md, PR 78.)"""
+    pl = _paged.pl
+    i = pl.program_id(0)
+    nv, dk, dv = state_ref.shape[1:]
+    nk, w = cols_ref.shape[1:3]
+    per = nv // nk
+
+    @pl.when(real_ref[i] == 1)
+    def _():
+        fresh = fresh_ref[i] == 1
+        last = jax.lax.broadcasted_iota(jnp.int32, (w, dv), 0) == w - 1
+
+        def key_head(kh):
+            lanes = pl.ds(pl.multiple_of(kh * dk, dk), dk)
+            k = k_ref[0, :, lanes]                                  # (w, dk)
+            kq = jnp.concatenate([k, q_ref[0, :, lanes]], axis=0)
+            cols = cols_ref[0, kh]
+            for p in range(per):
+                h = kh * per + p
+                lanes = pl.ds(pl.multiple_of(h * dv, dv), dv)
+                decay, beta, tail = (
+                    cols[:, j * per + p:j * per + p + 1] for j in range(3))
+                # zeros chosen on the state itself: a reused slot may hold
+                # anything
+                S0 = jnp.where(fresh, 0.0, state_ref[0, h])         # (dk, dv)
+                read = _dot(kq, S0)                                 # K S0, Q S0
+                decay = jnp.broadcast_to(decay, (w, dv))
+                kS, qS = read[:w] * decay, read[w:] * decay
+                U = _dot(t_ref[0, h], beta * (v_ref[0, :, lanes] - kS))
+                o_ref[0, :, lanes] = qS + _dot(a_ref[0, h], U)
+                # exp(G_C), the last position's decay, as a row of lanes: a
+                # select and a sum (Mosaic broadcasts no single value along
+                # both axes, and a slice of the broadcast folds into one)
+                carry = jnp.sum(jnp.where(last, decay, 0.0), axis=0, keepdims=True)
+                new_ref[0, h] = carry * S0 + _dot(k, U * tail, ((0,), (0,)))
+
+        together = math.gcd(nk, KEY_HEADS_A_STEP)
+
+        def step(n, _):
+            for j in range(together):
+                key_head(n * together + j)
+            return _
+
+        jax.lax.fori_loop(0, nk // together, step, 0)
+
+    @pl.when(jnp.logical_and(real_ref[i] == 0, i == 0))
+    def _():
+        new_ref[...] = state_ref[...]
+
+
+def delta_chunk_rows(q, k, v, g, beta, state, fresh, at, interpret: bool):
+    """``delta_chunk`` for rows whose states are lines of the pool's leaf: a
+    Pallas kernel over the list of chunk rows that holds a row's state in VMEM
+    once, takes every product that reads it there (the two read-outs ``K S0``
+    and ``Q S0`` with their decay, ``U = T beta (V - K S0)``, ``o = Q S0 + A
+    U``, ``S = exp(G_C) S0 + K^T (U tail)``; float32, ``HIGHEST``) and writes
+    the new state over the old line. No gathered copy of the states and no
+    scatter exists: ``state`` is the WHOLE leaf, aliased to the result, and
+    the index map sends place ``i`` to slot ``at[i]``. As plain ``jax.numpy``
+    the chip's compiler read the gathered states five times and scattered them
+    back (PERF.md, PR 78). What never touches the state stays outside
+    (``_chunk_operands``).
+
+    ``q`` .. ``beta`` as ``delta_chunk`` takes them, a row a place of the
+    list; ``state`` (slots, nv, dk, dv) float32; ``fresh`` (R,) bool; ``at``
+    (R,) int32, the slot of each place's row, distinct, the filled places
+    FIRST and ``slots`` in every place no row fills: such a place moves
+    nothing and writes nothing. Returns ``(o (R, C, nv, dv), state)``, ``o``
+    undefined at a place no row fills."""
+    _paged._ensure_pallas()
+    count_kernel_build(CHUNK_KERNEL_NAME, interpret)
+    R, C, nk, dk = q.shape
+    slots, nv, _, dv = state.shape
+    q, k, v, g, beta = _padded(q, k, v, g, beta)
+    w = q.shape[1]
+    T, A, decay, tail = _chunk_operands(q, k, g, beta)
+    # a key head's columns side by side: (R, nk, w, [decay | beta | tail] x per)
+    cols = jnp.stack([decay, beta, tail], axis=2).reshape(R, w, 3, nk, nv // nk)
+    cols = jnp.moveaxis(cols, 3, 1).reshape(R, nk, w, 3 * (nv // nk))
+    real = at < slots
+    # an unfilled place sits on the last filled place's blocks. NOT on slot
+    # ``slots - 1``'s: under the aliasing its stale copy would be written over
+    # a line that a filled place of this very call advances
+    src = jnp.minimum(jnp.arange(R, dtype=jnp.int32),
+                      jnp.maximum(jnp.sum(real, dtype=jnp.int32) - 1, 0))
+    line = jnp.minimum(at[src], slots - 1).astype(jnp.int32)
+    new_state, o = _chunk_rows_call(
+        src, line, real.astype(jnp.int32), fresh.astype(jnp.int32),
+        q.reshape(R, w, nk * dk), k.reshape(R, w, nk * dk),
+        v.reshape(R, w, nv * dv), T, A, cols, state, interpret=interpret)
+    return o.reshape(R, w, nv, dv)[:, :C], new_state
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _chunk_rows_call(src, line, real, fresh, q, k, v, T, A, cols, state, *,
+                     interpret: bool):
+    """``delta_chunk_rows``' kernel call, a ``jit`` of its own inside the
+    caller's: a model's delta layers have one shape, so they share ONE trace
+    of the kernel and one lowering a program (the compiler inlines the calls).
+    Traced a layer, it was 2.6 s of the cell's set-up (PERF.md, PR 78)."""
+    pl, pltpu = _paged.pl, _paged.pltpu
+    R, w, _ = q.shape
+    nv, dk, dv = state.shape[1:]
+    nk = cols.shape[1]
+
+    def place(rank):            # the place's own block of an operand
+        return lambda i, src, *_: (src[i],) + (0,) * (rank - 1)
+
+    def slot(i, src, line, *_):
+        return line[i], 0, 0, 0
+
+    block = 2 * nv * dk * dv * 4                                # state in and out
+    beside = 2 * 4 * w * (2 * nk * dk + 2 * nv * dv + 2 * nv * max(w, LANES)
+                          + nk * LANES)
+    return pl.pallas_call(
+        _delta_chunk_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(R,),
+            in_specs=[pl.BlockSpec((1, w, nk * dk), place(3)),
+                      pl.BlockSpec((1, w, nk * dk), place(3)),
+                      pl.BlockSpec((1, w, nv * dv), place(3)),
+                      pl.BlockSpec((1, nv, w, w), place(4)),
+                      pl.BlockSpec((1, nv, w, w), place(4)),
+                      pl.BlockSpec((1, nk, w, 3 * (nv // nk)), place(4)),
+                      pl.BlockSpec((1, nv, dk, dv), slot)],
+            out_specs=[pl.BlockSpec((1, nv, dk, dv), slot),
+                       pl.BlockSpec((1, w, nv * dv), place(3))]),
+        out_shape=[jax.ShapeDtypeStruct(state.shape, F32),
+                   jax.ShapeDtypeStruct((R, w, nv * dv), F32)],
+        # operands counted with the four prefetched: the state in place
+        input_output_aliases={10: 0},
+        compiler_params=pltpu.CompilerParams(
+            # a place no row fills REVISITS the blocks of the one before it
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=max(2 * block + beside + (8 << 20), 16 << 20)),
+        interpret=interpret,
+        name=CHUNK_KERNEL_NAME,  # the trace's and the HLO's name for it
+    )(src, line, real, fresh, q, k, v, T, A, cols, state)
+
+
 class GatedDeltaMixer(BaseLayer):
     # the view of the serving state a layer with this mixer is handed
     STATE_VIEW = DeltaStateView
@@ -540,13 +742,18 @@ class GatedDeltaMixer(BaseLayer):
             out = y @ params["out_proj"]["weight"].astype(x.dtype)
             return (out, lines) if state is not None or return_state else out
 
-    def _advance(self, q, k, v, g, beta, S0, fresh):
+    def _advance(self, q, k, v, g, beta, S0, fresh, at=None):
         """``(r, w)`` whole rows from their states: one chunk, or ``CHUNK``
-        positions at a time where a row is wider."""
+        positions at a time where a row is wider. ``at`` None: ``S0`` holds a
+        state a row (the uncached pass: plain ``jax.numpy``, differentiated).
+        Else ``S0`` is the pool's whole leaf and row ``i`` advances its line
+        ``at[i]`` in place (``delta_chunk_rows``)."""
+        chunk = delta_chunk if at is None else functools.partial(
+            delta_chunk_rows, at=at, interpret=_paged.paged_kernel_interpret())
         with jax.named_scope("delta_rule"):
             r, w = g.shape[:2]
             if w <= CHUNK:
-                return delta_chunk(q, k, v, g, beta, S0, fresh)
+                return chunk(q, k, v, g, beta, S0, fresh)
             pad = -w % CHUNK
             parts = tuple(
                 jnp.moveaxis(_pad_positions(t, pad).reshape(
@@ -557,7 +764,7 @@ class GatedDeltaMixer(BaseLayer):
             def step(S, part):
                 *operands, is_first = part
                 zero = None if fresh is None else fresh & is_first
-                o, S = delta_chunk(*operands, S, zero)
+                o, S = chunk(*operands, S, zero)
                 return S, o
 
             S, o = jax.lax.scan(step, S0, (*parts, first))
@@ -606,11 +813,17 @@ class GatedDeltaMixer(BaseLayer):
         return y, view._replace(state=S.astype(view.state.dtype),
                                 conv=tail.astype(view.conv.dtype))
 
-    def _chunk_rows(self, params, qkv, ba, state, conv, ctx_len, new_len):
-        """The whole-rows form: every row of ``(r, w)`` advances from its line
-        by the chunk form, ``new_len`` of its places real. Returns ``(o (r, w,
-        nv, dv), state, conv)``, float32 but the tail (``qkv``'s dtype)."""
+    def _chunk_rows(self, params, qkv, ba, state, conv, ctx_len, new_len,
+                    at=None):
+        """The whole-rows form: every row of ``(r, w)`` advances by the chunk
+        form, ``new_len`` of its places real, from line ``at[r]`` of ``state``
+        (the pool's whole leaf, advanced in place; ``at`` as
+        ``delta_chunk_rows`` takes it, None: row ``r`` is slot ``r``) and from
+        its tail ``conv[r]``. Returns ``(o (r, w, nv, dv), state, conv)``,
+        float32 but the tail (``qkv``'s dtype)."""
         r, w = ba.shape[:2]
+        if at is None:
+            at = jnp.arange(r, dtype=jnp.int32)
         K = self.conv_kernel
         real = jnp.arange(w, dtype=jnp.int32)[None, :] < new_len[:, None]
         # a row at context 0 starts from zeros, whatever its slot held
@@ -619,7 +832,7 @@ class GatedDeltaMixer(BaseLayer):
         window = jnp.concatenate([tail.astype(qkv.dtype), qkv], axis=1)
         operands = self._delta_inputs(
             params, self._conv(params, window), ba, real)
-        o, S = self._advance(*operands, state.astype(F32), fresh)
+        o, S = self._advance(*operands, state.astype(F32), fresh, at)
         # each channel's last K - 1 inputs, the row's new ones included: the
         # window's places new_len .. new_len + K - 2 (new_len 0: the old tail)
         last = new_len[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None, :]
@@ -630,9 +843,9 @@ class GatedDeltaMixer(BaseLayer):
         """A token-major batch ``(T, ..)`` narrower than ``rows x w``: rows
         that bring one token step where they lie, ``delta_step`` taking each
         from ``proj`` to its gated output; the at most ``T // w`` that bring
-        more (the caller sees to that: ``split_capacity``) are gathered,
-        advanced as whole rows and written back over their lines, as
-        ``Mamba2Mixer._split_rows`` does. Returns ``(y (T, nv dv), state,
+        more (the caller sees to that: ``split_capacity``) are listed, their
+        places gathered, and advanced as whole rows, each state line where it
+        lies (``delta_chunk_rows``). Returns ``(y (T, nv dv), state,
         conv)``."""
         rows, w = tmap.row_tokens.shape
         R = split_capacity(proj.shape[0], w)
@@ -644,21 +857,16 @@ class GatedDeltaMixer(BaseLayer):
                 params["norm"]["weight"], self.norm_eps,
                 _paged.paged_kernel_interpret())
         multi = new_len > 1
-        # the multi-token rows in slot order, then `rows`: past the pool, so
-        # that nothing of a place no row fills is written back. A chunk row
-        # has stepped with beta = g = 0: its lines are still the old ones
+        # the multi-token rows in slot order, then `rows`: past the pool, the
+        # mark of a place no row fills, of which nothing is moved or written.
+        # A chunk row has stepped with beta = g = 0: its lines are still the
+        # old ones, and the chunk's kernel advances its state where it lies
         at, = jnp.nonzero(multi, size=R, fill_value=rows)
         held = jnp.minimum(at, rows - 1)
         flat = tmap.row_tokens[held]                         # (R, w)
-        # each line by a read of its own (nn/mamba.py: a general gather over a
-        # state wider than the lanes first copies EVERY slot's line)
-        S_held = jnp.stack([
-            jax.lax.dynamic_index_in_dim(S, row, 0, keepdims=False)
-            for row in held])
-        o_chunk, S_chunk, tail_chunk = self._chunk_rows(
-            params, proj[:, :self.conv_dim][flat], ba[flat], S_held, tail[held],
-            ctx_len[held], jnp.where(at < rows, new_len[held], 0))
-        S = S.at[at].set(S_chunk, mode="drop")
+        o_chunk, S, tail_chunk = self._chunk_rows(
+            params, proj[:, :self.conv_dim][flat], ba[flat], S, tail[held],
+            ctx_len[held], jnp.where(at < rows, new_len[held], 0), at)
         tail = tail.at[at].set(tail_chunk.astype(tail.dtype), mode="drop")
         # a token of a chunk row reads its place in its row's chunk and is
         # gated where it lies (its z needs no gather); any other reads its

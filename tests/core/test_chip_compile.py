@@ -1027,10 +1027,14 @@ def test_a_one_token_row_reads_its_delta_state_once_at_the_cells_size(
     token-major tick of 768 places for rows of up to 32: the rows that bring
     one token advance in ONE kernel (``delta_step``, compiled by Mosaic) that
     takes the donated state and gives the new state, written over the old one;
-    the at most 24 rows that bring a chunk are sliced out line by line and
-    scattered back in place. The donated state has no other reader and is
-    never copied. As plain ``jax.numpy`` the step was TWO fusions over the
-    state, the read-outs' and the update's (PERF.md, PR 71).
+    the at most 24 rows that bring a chunk advance in a second one
+    (``delta_chunk_rows``, since PR 78) that takes the first's state and
+    writes each row's line where it lies. The state has exactly these two
+    readers, chained and in place, and is never copied, gathered or scattered
+    into: before PR 78 the chunk rows' lines were sliced out one by one into
+    ``f32[24,32,128,128]``, read five times and scattered back. As plain
+    ``jax.numpy`` the step was TWO fusions over the state, the read-outs' and
+    the update's (PERF.md, PR 71).
 
     Since PR 76 the kernel takes a one-token row from ``in_proj``'s output to
     ``out_proj``'s input: the conv leaf (a tap a plane of whole tiles:
@@ -1076,8 +1080,25 @@ def test_a_one_token_row_reads_its_delta_state_once_at_the_cells_size(
     readers = [op for op in ops if re.search(r"\(.*%state", op)]
     assert len(readers) == 1 and "tpu_custom_call" in readers[0], readers
     assert re.match(rf"\s*%delta_step\S* = \({state}", readers[0])
+    # the step's state has ONE reader too, the chunk rows' kernel, whose state
+    # is the program's: two custom calls chained, each aliasing its state
+    (stepped,) = [re.match(r"\s*%(\S+) = ", op).group(1) for op in ops
+                  if re.search(rf"= {state}\S* get-tuple-element\(%delta_step\S*\), index=0", op)]
+    second = [op for op in ops if re.search(rf"%{re.escape(stepped)}[,)]",
+                                            op.split(" = ", 1)[1])]
+    assert len(second) == 1 and "tpu_custom_call" in second[0], second
+    assert re.match(rf"\s*%delta_chunk_rows\S* = \({state}", second[0])
+    for call, operand in ((readers[0], 10), (second[0], 10)):
+        assert re.search(
+            rf"output_to_operand_aliasing=\{{\{{0\}}: \({operand}, \{{\}}\)", call), call
+    (advanced,) = [re.match(r"\s*%(\S+) = ", op).group(1) for op in ops
+                   if re.search(rf"= {state}\S* get-tuple-element\(%delta_chunk_rows\S*\), index=0", op)]
+    assert re.search(rf"ROOT [^=]* = .* tuple\([^)]*%{re.escape(advanced)}[,)]", entry)
+    # nothing else is shaped as the state: two calls, their results, the root
+    assert len([op for op in ops if re.search(state, op)]) == 5
     assert not whole_copies(text, state)
-    assert "delta/scatter" in entry and "f32[24,32,128,128]" in entry
+    assert "f32[24,32,128,128]" not in entry     # no gathered copy of the lines
+    assert not re.search(rf"= {state}\S* (fusion|scatter)\(", entry)  # nor a scatter
     assert not re.search(rf"{state}[^=]* while\(", entry)
     # the conv leaf: the compiler may stage it on its way (asynchronous
     # slices or a copy into the chip's fast memory), the kernel computes on it

@@ -11,8 +11,10 @@ import pytest
 from scaling_tpu.nn.attention import packed_token_map
 from scaling_tpu.nn.base_layer import ForwardContext
 from scaling_tpu.nn.gated_delta import (
-    DeltaStateView, GatedDeltaMixer, delta_chunk, delta_step, unit_lower_inverse)
+    DeltaStateView, GatedDeltaMixer, delta_chunk, delta_chunk_rows, delta_step,
+    unit_lower_inverse)
 from scaling_tpu.nn.mamba import split_capacity
+from scaling_tpu.obs import kernel_build_count
 
 H, NK, NV, DK, DV, K = 48, 2, 4, 16, 8, 4
 CONV = 2 * NK * DK + NV * DV
@@ -142,7 +144,6 @@ def test_the_step_kernel_interpreted_equals_the_python_loop(mixer, case):
     tail bit for bit; a fresh row starts from zeros though its lines hold
     NaNs; with bfloat16 operands the state is still the float32 loop's."""
     from scaling_tpu.nn.gated_delta import conv_line
-    from scaling_tpu.obs import kernel_build_count
 
     layer, params = mixer
     rows, dtype = STEP_CASES[case]
@@ -233,6 +234,79 @@ def test_the_gates_extremes_stay_finite_and_right(case):
     np.testing.assert_allclose(np.asarray(S[0]), want_S, atol=ATOL, rtol=1e-5)
 
 
+def chunk_rows_plainly(q, k, v, g, beta, state, fresh, at, interpret=None):
+    """What ``delta_chunk_rows`` replaced: the rows' lines gathered, advanced by
+    ``delta_chunk`` and scattered back; a place past the pool is dropped."""
+    held = jnp.minimum(at, state.shape[0] - 1)
+    o, S = delta_chunk(q, k, v, g, beta, state[held], fresh)
+    return o, state.at[at].set(S, mode="drop")
+
+
+# (slots, places' width, the slot a place (== slots: no row fills it), tokens a
+# place, the places that start from zeros)
+CHUNK_KERNEL_CASES = {
+    "rows of 2..32 tokens, the rest padding": (6, 32, [0, 2, 3, 5], [2, 17, 31, 32], []),
+    "five places padded to the system's eight": (4, 5, [1, 2], [5, 3], []),
+    "one position a place": (3, 1, [0, 2], [1, 1], []),
+    "a fresh row over a line of NaNs": (4, 8, [1, 3], [8, 6], [0]),
+    # under a clamp to slot ``slots - 1`` an empty place would write that
+    # line's OLD copy over what the filled place advanced
+    "empty places, the last slot itself a chunk row": (
+        6, 8, [1, 5, 6, 6], [8, 8, 0, 0], []),
+    "one row, then empty places only": (5, 4, [2, 5, 5], [3, 0, 0], [0]),
+    "no place filled": (4, 4, [4, 4], [0, 0], []),
+    "every place filled, the neighbours only step": (6, 4, [0, 2, 4], [4, 2, 3], [1]),
+    "g near -20": (3, 32, [0, 2], [32, 32], []),
+    "beta near 0": (3, 32, [1, 2], [32, 20], []),
+    "beta near 1": (3, 32, [0, 1], [32, 32], []),
+}
+
+
+@pytest.mark.parametrize("case", list(CHUNK_KERNEL_CASES))
+def test_the_chunk_kernel_interpreted_equals_the_chunk_form(case):
+    """``delta_chunk_rows`` (a Pallas kernel; interpreted here, its own
+    arithmetic) over a list of places against ``delta_chunk`` on the gathered
+    lines: a place's read-out and its row's new line are the chunk form's, a
+    line no place names is bit for bit what it was (the pool's other rows only
+    step), a place no row fills moves nothing and writes nothing, whichever
+    slots the filled places name, and a fresh row starts from zeros though
+    its line holds NaNs."""
+    slots, w, at, lens, fresh_places = CHUNK_KERNEL_CASES[case]
+    R = len(at)
+    q, k, v, g, beta, _ = operands(jax.random.PRNGKey(len(case)), R, w)
+    if case == "g near -20":
+        g = jnp.full_like(g, -20.0)
+    elif case.startswith("beta near"):
+        beta = jnp.full_like(beta, 1e-7 if case == "beta near 0" else 1.0 - 1e-7)
+    real = (jnp.arange(w)[None, :] < jnp.asarray(lens)[:, None])[..., None]
+    g, beta = jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0)
+    fresh = jnp.zeros((R,), bool).at[jnp.asarray(fresh_places, int)].set(True)
+    state = jax.random.normal(jax.random.PRNGKey(R), (slots, NV, DK, DV))
+    state = state.at[jnp.asarray([at[place] for place in fresh_places], int)].set(
+        jnp.nan)
+    before = kernel_build_count("delta_chunk_rows", interpret=True)
+    o, S = jax.jit(lambda *a: delta_chunk_rows(*a, interpret=True))(
+        q, k, v, g, beta, state, fresh, jnp.asarray(at, jnp.int32))
+    assert kernel_build_count("delta_chunk_rows", interpret=True) == before + 1
+    want_o, want_S = jax.jit(chunk_rows_plainly)(
+        q, k, v, g, beta, state, fresh, jnp.asarray(at, jnp.int32))
+    assert o.shape == (R, w, NV, DV) and S.shape == state.shape
+    o, S, want_o, want_S = (np.asarray(a) for a in (o, S, want_o, want_S))
+    for slot in range(slots):
+        if slot not in at:   # bit for bit what the line held
+            assert np.array_equal(S[slot], np.asarray(state[slot]), equal_nan=True)
+            continue
+        place, n = at.index(slot), lens[at.index(slot)]
+        np.testing.assert_allclose(o[place], want_o[place], atol=ATOL, rtol=1e-5)
+        np.testing.assert_allclose(S[slot], want_S[slot], atol=ATOL, rtol=1e-5)
+        # and the recurrence's own over the row's real positions
+        S0 = np.zeros((NV, DK, DV)) if place in fresh_places else state[slot]
+        loop_o, loop_S = loop(q[place, :n], k[place, :n], v[place, :n],
+                              g[place, :n], beta[place, :n], S0)
+        np.testing.assert_allclose(o[place, :n], loop_o, atol=ATOL, rtol=1e-5)
+        np.testing.assert_allclose(S[slot], loop_S, atol=ATOL, rtol=1e-5)
+
+
 def test_the_init_and_the_leaves(mixer):
     layer, _ = mixer
     params = layer.init(jax.random.PRNGKey(5))
@@ -307,7 +381,11 @@ def test_state_carried_across_ticks_equals_one_pass(mixer, token_major):
     a step beside a gathered chunk, steps alone."""
     layer, params = mixer
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 40, H))
+    chunk_kernels = kernel_build_count("delta_chunk_rows", interpret=True)
     want = np.asarray(jax.jit(lambda x: layer(params, x, ForwardContext()))(x))
+    # the uncached pass is plain ``jax.numpy``; every served tick below
+    # advances its chunk rows through the kernel, their lines in place
+    assert kernel_build_count("delta_chunk_rows", interpret=True) == chunk_kernels
     slots, w = 3, 32
     lines = (jnp.full((slots, NV, DK, DV), 7.0),           # an old occupant's
              jnp.full((slots, K - 1, 1, CONV), 7.0))
@@ -323,9 +401,36 @@ def test_state_carried_across_ticks_equals_one_pass(mixer, token_major):
         seen = [seen[0] + n0, seen[1] + n2]
     np.testing.assert_allclose(np.concatenate(got[0]), want[0], atol=ATOL, rtol=1e-5)
     np.testing.assert_allclose(np.concatenate(got[2]), want[1], atol=ATOL, rtol=1e-5)
+    assert kernel_build_count("delta_chunk_rows", interpret=True) == chunk_kernels + 5
     # the empty slot's lines were never written
     assert np.array_equal(lines[0][1], np.full((NV, DK, DV), 7.0))
     assert np.array_equal(lines[1][1], np.full_like(lines[1][1], 7.0))
+
+
+def test_a_served_row_wider_than_a_chunk_advances_its_line_chunk_by_chunk(
+        mixer, monkeypatch):
+    """A row-major tick of rows wider than ``CHUNK`` (16 here: 40 and 23
+    positions are three chunks, the second row's last one all padding) walks
+    the chunks through the kernel, the pool's whole leaf the carry: each row's
+    outputs and lines are the uncached pass's, from zeros though the slots
+    held an old occupant's values."""
+    from scaling_tpu.nn import gated_delta
+
+    layer, params = mixer
+    monkeypatch.setattr(gated_delta, "CHUNK", 16)
+    x = jax.random.normal(jax.random.PRNGKey(9), (2, 40, H))
+    lens = [40, 23]
+    lines = (jnp.full((2, NV, DK, DV), 7.0), jnp.full((2, K - 1, 1, CONV), 7.0))
+    chunk_kernels = kernel_build_count("delta_chunk_rows", interpret=True)
+    outs, S, tail = run_tick(layer, params, [x[r, :n] for r, n in enumerate(lens)],
+                             lines, [0, 0], lens, 40)
+    assert kernel_build_count("delta_chunk_rows", interpret=True) == chunk_kernels + 1
+    for r, n in enumerate(lens):
+        want, (want_S, want_tail) = jax.jit(lambda x: layer(
+            params, x, ForwardContext(), return_state=True))(x[r:r + 1, :n])
+        np.testing.assert_allclose(outs[r], np.asarray(want[0]), atol=ATOL, rtol=1e-5)
+        np.testing.assert_allclose(S[r], np.asarray(want_S[0]), atol=ATOL, rtol=1e-5)
+        assert np.array_equal(tail[r], np.asarray(want_tail[0]))
 
 
 def width_for(new_len, widths, w):
@@ -354,14 +459,21 @@ SPLIT_TICKS = {
 }
 
 
-@pytest.mark.parametrize("reference", ["row-major", "token-major at the full width"])
+@pytest.mark.parametrize("reference", [
+    "row-major", "token-major at the full width",
+    "row-major, the lines gathered and advanced as plain jax.numpy"])
 @pytest.mark.parametrize("case", list(SPLIT_TICKS))
-def test_each_row_in_its_own_form_equals_the_whole_rows_form(mixer, case, reference):
+def test_each_row_in_its_own_form_equals_the_whole_rows_form(
+        mixer, case, reference, monkeypatch):
     """Below the full width a row that brings one token takes the single step
-    and the few that bring more are gathered into a chunk: outputs, state
-    lines and conv tails are the whole-rows form's, whichever caller reaches
-    that. A row at context 0 starts from zeros though its slot holds an old
-    occupant's NaNs; an empty row's lines are not touched."""
+    and the few that bring more advance through the chunk rows' kernel, each
+    line where it lies: outputs, state lines and conv tails are the
+    whole-rows form's, whichever caller reaches that, and whether that form
+    runs the kernel over every row or ``delta_chunk`` as plain ``jax.numpy``
+    over gathered lines. A row at context 0 starts from zeros though its slot
+    holds an old occupant's NaNs; an empty row's lines are not touched."""
+    from scaling_tpu.nn import gated_delta
+
     layer, params = mixer
     new_len, ctx_len = SPLIT_TICKS[case]
     small, full = SPLIT_WIDTHS
@@ -375,9 +487,13 @@ def test_each_row_in_its_own_form_equals_the_whole_rows_form(mixer, case, refere
         for k, shape in ((ks[0], (slots, NV, DK, DV)), (ks[1], (slots, K - 1, 1, CONV))))
     x = jax.random.normal(ks[2], (slots, w, H))
     x_rows = [x[r, :n] for r, n in enumerate(new_len)]
+    chunk_kernels = kernel_build_count("delta_chunk_rows", interpret=True)
     got = run_tick(layer, params, x_rows, lines, ctx_len, new_len, w, width)
+    assert kernel_build_count("delta_chunk_rows", interpret=True) == chunk_kernels + 1
+    if reference.endswith("plain jax.numpy"):
+        monkeypatch.setattr(gated_delta, "delta_chunk_rows", chunk_rows_plainly)
     want = run_tick(layer, params, x_rows, lines, ctx_len, new_len, w,
-                    None if reference == "row-major" else full)
+                    None if reference.startswith("row-major") else full)
     for r, n in enumerate(new_len):
         np.testing.assert_allclose(got[0][r], want[0][r], atol=ATOL, rtol=1e-5)
         assert not n or np.isfinite(got[0][r]).all()

@@ -200,10 +200,12 @@ NEW_TOKENS = 20
 
 
 def served_by(engine, requests, new_tokens):
+    """Every request the engine was given, these after any before, in the
+    order of submission."""
     for p in requests:
         engine.submit(p, max_new_tokens=new_tokens)
     got = {s.request.req_id: s.generated for s in engine.run_until_done()}
-    return [got[i] for i in range(len(requests))]
+    return [got[i] for i in range(len(got))]
 
 
 def assert_tokens_are_the_references(inf, reference, requests, got):
@@ -300,6 +302,35 @@ def test_rows_that_step_beside_rows_that_chunk_and_the_ticks_say_so(
     assert sum(by_path.values()) == capture.counters[
         "serve_delta_state_updates_total"]
     assert not any(k.startswith("serve_ssm_rows_total") for k in capture.counters)
+
+
+def test_step_rows_beside_several_chunk_rows_and_empty_slots_are_the_references(
+        gdn, reference, tmp_path):
+    """The program of 128 places lists up to 4 chunk rows for the delta
+    layers' kernel (``delta_chunk_rows``). Three short prompts fill three of
+    its places, the fourth and 13 slots empty; while they decode, two long
+    prompts arrive and bring two chunks a tick beside three rows that step:
+    more chunk rows than one, fewer than the list holds, step rows and empty
+    slots in one tick. Every token is the reference's."""
+    requests = prompts((3, 5, 4, 44, 51), seed=7)
+    engine = engine_of(gdn, num_slots=16, prefill_chunk=32, token_budget=128,
+                       max_blocks_per_seq=24, num_blocks=16 * 24 + 1)
+    assert engine.config.mixed_widths == (128, 512)
+    obs.start_capture(str(tmp_path))
+    try:
+        for p in requests[:3]:
+            engine.submit(p, max_new_tokens=NEW_TOKENS)
+        for _ in range(3):
+            engine.tick()
+        got = served_by(engine, requests[3:], NEW_TOKENS)     # all five
+    finally:
+        capture = obs.stop_capture()
+    mixed = [f for n, _, _, f in capture.spans if n == "serve.mixed"]
+    assert {f["width"] for f in mixed} == {128}
+    shapes = [(f["delta_step_rows"], f["delta_chunk_rows"]) for f in mixed]
+    assert shapes[0] == (0, 3)
+    assert (3, 2) in shapes
+    assert_tokens_are_the_references(gdn, reference, requests, got)
 
 
 def test_the_attention_layer_is_the_references(gdn, reference):
